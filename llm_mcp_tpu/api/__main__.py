@@ -23,23 +23,20 @@ def main() -> None:
     from ..utils.config import Config, enable_compile_cache
 
     cfg = Config()
-    enable_compile_cache()
 
-    if os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip().lower() == "cpu":
-        # an already-registered accelerator plugin ignores the env var; the
-        # config-level pin is the one mechanism it respects (CI / CPU sims)
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
-
-    import jax.numpy as jnp
-
-    from ..executor import EmbeddingEngine, GenerationEngine
     from .server import CoreServer
 
     gen_engines = {}
     embed_engines = {}
     if os.environ.get("TPU_DISABLE_ENGINES", "") not in ("1", "true"):
+        # a core with engines compiles for the device: place the cache
+        # before the first compile (JAX_COMPILATION_CACHE_DIR, else
+        # <checkout>/.jax_cache). JAX_PLATFORMS=cpu is JAX's own switch for
+        # CPU sims and needs no help from here.
+        enable_compile_cache()
+        import jax.numpy as jnp
+
+        from ..executor import EmbeddingEngine, GenerationEngine
         # multi-host first (must precede the first jax op), then the mesh:
         # TPU_MESH_SHAPE="dp=1,tp=8" shards the engines over it; empty = one
         # chip. make_global_mesh lays dp/pp over DCN on multi-slice fleets.

@@ -306,7 +306,7 @@ class GSPMDBackend(DispatchBackend):
 
 
 # ---------------------------------------------------------------------------
-# 2-process demo main (the boot smoke __graft_entry__ drives): one unified
+# 2-process demo main (tests/test_dispatch.py spawns it twice): one unified
 # engine, GSPMD backend, greedy tokens across the process boundary.
 # ---------------------------------------------------------------------------
 

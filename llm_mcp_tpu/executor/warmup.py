@@ -24,7 +24,7 @@ Knobs: `TPU_WARMUP` (default 1; `0` is a TRUE no-op — no planner, no
 synthetic compiles, byte-identical greedy output), `TPU_WARMUP_BG`
 (default 1; `0` skips the background phase — only the critical prefix
 warms). Background compiles only *stick* across boots when the
-persistent compile cache is on (`TPU_COMPILE_CACHE`): an AOT
+persistent compile cache is on (`utils/config.enable_compile_cache`): an AOT
 lower().compile() populates the XLA cache that the serve path's jit
 call then hits, skipping the dominant cost.
 

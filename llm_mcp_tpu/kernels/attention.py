@@ -26,8 +26,9 @@ This is why the engine cache is [L, B, Hkv, S, hd] (heads BEFORE sequence):
 a [.., S, 1, hd] block would tile as (1, 128) sublane-padded 8×, wasting
 most of the HBM bandwidth the decode step is bound by.
 
-Both kernels auto-fall back to interpret mode off-TPU so the full test suite
+Off the chip the kernels run in interpret mode, so the whole test suite
 exercises them on the CPU backend (tests/conftest.py forces JAX_PLATFORMS=cpu).
+A device that is neither a TPU nor the CPU is an error (utils/platform.py).
 """
 
 from __future__ import annotations
@@ -38,31 +39,33 @@ import os
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pltpu imports fail on some CPU-only builds; interpret mode needs pl only
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except Exception:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
-
-
-def _smem_spec() -> pl.BlockSpec:
-    """Whole-array spec for the [B] lengths input: SMEM on TPU (scalar reads
-    drive masking), memory-space-agnostic under interpret mode off-TPU."""
-    if _HAS_PLTPU:
-        return pl.BlockSpec(memory_space=pltpu.SMEM)
-    return pl.BlockSpec(memory_space=pl.ANY)  # pragma: no cover
+from ..telemetry.recorder import get_recorder
+from ..utils.platform import on_tpu as _on_tpu
 
 NEG_INF = float(-1e30)
 
+# Kernel-to-reference falls: a dispatcher that was asked for a compiled
+# (non-interpret) kernel and answered with the XLA reference math instead,
+# because a shape gate failed. Decided at trace time, so each entry counts
+# traced programs, not calls. chip_smoke.py asserts the table stays empty on
+# the serving path; the same fall lands in the flight recorder as
+# `kernel_fall`.
+reference_falls: dict[str, int] = {}
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+
+def _note_fall(kernel: str, reason: str, interp: bool) -> None:
+    if interp:
+        return  # interpret-mode tests take the exact math by design
+    reference_falls[kernel] = reference_falls.get(kernel, 0) + 1
+    get_recorder().event("kernel_fall", kernel=kernel, reason=reason)
+
+
+def _smem_spec() -> pl.BlockSpec:
+    """Whole-array SMEM spec for the [B] lengths input (scalar reads drive
+    masking)."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def pallas_supported(seq_len: int, head_dim: int) -> bool:
@@ -411,13 +414,28 @@ def _attend_q8_kernel(
 
 def _unpack_scale_lanes(srow, n_heads: int, scale_dtype):
     """In-kernel inverse of models/quant.py:pack_scales for one landed
-    block: [BS, hd] int8 scale-row bytes -> [n_heads, BS] scales. Byte
-    layout parity with pack_scales is pinned by the fused-layout parity
-    tests (a drifting layout would desync every dequant)."""
+    block: [BS, hd] int8 scale-row bytes -> [n_heads, BS] f32 scales.
+
+    Mosaic lowers no width-changing bitcast (int8 -> bf16/f32), so the bytes
+    are reassembled arithmetically. One s8 x s8 -> s32 MXU dot against a 0/1
+    selection matrix per scale byte both picks that byte's lanes and
+    transposes the block; masks and shifts then rebuild the bit pattern in
+    an int32, widened to an f32 pattern where the scale is bf16, and a
+    same-width bitcast reads it. Byte order is pack_scales' (little-endian);
+    layout parity is pinned by the fused-layout parity tests."""
     it = jnp.dtype(scale_dtype).itemsize
-    raw = srow[:, : n_heads * it].reshape(srow.shape[0], n_heads, it)
-    s = jax.lax.bitcast_convert_type(raw, scale_dtype)  # [BS, n_heads]
-    return jnp.swapaxes(s, 0, 1)
+    hd = srow.shape[1]
+    h = jax.lax.broadcasted_iota(jnp.int32, (n_heads, hd), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (n_heads, hd), 1)
+    bits = jnp.zeros((n_heads, srow.shape[0]), jnp.int32)
+    for byte in range(it):
+        # byte `byte` of head h's scale sits in lane h*it + byte
+        sel = jnp.where(lane == h * it + byte, 1.0, 0.0).astype(jnp.int8)
+        raw = jax.lax.dot_general(
+            sel, srow, (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32
+        )  # [n_heads, BS] sign-extended bytes
+        bits = bits | ((raw & 0xFF) << (8 * byte + 8 * (4 - it)))
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
 
 
 def _attend_q8_blocked_kernel(
@@ -543,8 +561,8 @@ def _attend_q8_blocked_kernel(
         if packed:
             ss = _unpack_scale_lanes(buf[2 * Hkv], 2 * Hkv, scale_dtype)
         else:
-            ss = s_buf[slot]
-        ss = ss.astype(jnp.float32)  # [2*Hkv, BS]
+            ss = s_buf[slot].astype(jnp.float32)
+        # ss: [2*Hkv, BS] f32
         kss, vss = ss[:Hkv], ss[Hkv:]
         s_i = jax.lax.dot_general(
             q8, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.int32
@@ -713,8 +731,8 @@ def _attend_q8_paged_kernel(
         if packed:
             ss = _unpack_scale_lanes(buf[2 * Hkv], 2 * Hkv, scale_dtype)
         else:
-            ss = s_buf[slot]
-        ss = ss.astype(jnp.float32)  # [2*Hkv, BS]
+            ss = s_buf[slot].astype(jnp.float32)
+        # ss: [2*Hkv, BS] f32
         kss, vss = ss[:Hkv], ss[Hkv:]
         s_i = jax.lax.dot_general(
             q8, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.int32
@@ -888,12 +906,6 @@ def decode_attend_q8(
     sc = scale or hd**-0.5
     _, p = fused_q8_heads(cache_k)
 
-    if not _HAS_PLTPU:  # pragma: no cover — CPU builds without pallas-tpu
-        return _decode_attend_q8_fallback(
-            q, new_k, new_v, cache_k, cache_v, layer, lengths, sc, slot_ids,
-            block_tables, pool_k,
-        )
-
     nk4 = new_k.reshape(B, Hkv, 1, hd)
     nv4 = new_v.reshape(B, Hkv, 1, hd)
     can_whole = S <= decode_pallas_max_seq(hd, Hkv, Hkv * G, quantized=True)
@@ -902,7 +914,8 @@ def decode_attend_q8(
     BS = next((c for c in (256, 128, 64, 32) if S % c == 0), 0)
     if not can_whole and BS == 0:
         # no whole-S fit and no int8-tileable block divides S: exact f32
-        # math of the CPU fallback (slower, never wrong)
+        # math of the reference (slower, never wrong)
+        _note_fall("decode_attend_q8", f"S={S}: no whole-S fit, no block size", interp)
         return _decode_attend_q8_fallback(
             q, new_k, new_v, cache_k, cache_v, layer, lengths, sc, slot_ids,
             block_tables, pool_k,
@@ -1088,6 +1101,7 @@ def decode_attend_q8(
     if not paged_ok:
         # table present but the ledger block size has no int8-tileable arm
         # (the engine gates physical mode on this; belt): exact gather math
+        _note_fall("decode_attend_q8", f"paged: block size {S}/{nbs} untileable", interp)
         return _decode_attend_q8_fallback(
             q, new_k, new_v, cache_k, cache_v, layer, lengths, sc, slot_ids,
             block_tables, pool_k,
@@ -1512,18 +1526,13 @@ def decode_attend_bf16(
     interp = _interpret() if interpret is None else interpret
     sc = scale or hd**-0.5
 
-    if not _HAS_PLTPU:  # pragma: no cover — CPU builds without pallas-tpu
-        return _decode_attend_bf16_fallback(
-            q, new_k, new_v, cache_k, cache_v, layer, lengths, sc, slot_ids,
-            block_tables, pool_k, pool_v,
-        )
-
     nk4 = new_k.reshape(B, Hkv, 1, hd)
     nv4 = new_v.reshape(B, Hkv, 1, hd)
     can_whole = S <= decode_pallas_max_seq(hd, Hkv, Hkv * G, quantized=False)
     # BS must divide S (a floored block count would silently drop the tail)
     BS = next((c for c in (256, 128, 64, 32) if S % c == 0), 0)
     if not can_whole and BS == 0:
+        _note_fall("decode_attend_bf16", f"S={S}: no whole-S fit, no block size", interp)
         return _decode_attend_bf16_fallback(
             q, new_k, new_v, cache_k, cache_v, layer, lengths, sc, slot_ids,
             block_tables, pool_k, pool_v,
@@ -1675,6 +1684,7 @@ def decode_attend_bf16(
     if not paged_ok or interp and mode != "paged":
         # engine gates physical mode on a tileable block size (belt), and
         # interpret runs keep a static arm choice — exact gather math
+        _note_fall("decode_attend_bf16", f"paged: block size {S}/{nbs} untileable", interp)
         return _decode_attend_bf16_fallback(
             q, new_k, new_v, cache_k, cache_v, layer, lengths, sc, slot_ids,
             block_tables, pool_k, pool_v,
@@ -2150,7 +2160,11 @@ def decode_attend_q8_mla(
     interp = _interpret() if interpret is None else interpret
     fits = mla_whole_s_fits(S, R, dr, H)
     BS = mla_block_size(S)
-    if not _HAS_PLTPU or (not fits and BS == 0) or (not interp and R % 128 != 0):
+    if (not fits and BS == 0) or (not interp and R % 128 != 0):
+        _note_fall(
+            "decode_attend_q8_mla", f"S={S} R={R}: no whole-S fit or block size",
+            interp,
+        )
         return _decode_attend_q8_mla_fallback(
             qt, qr, new_c, new_r, cache_c, cache_r, layer, lengths, scale, slot_ids,
             block_tables, pool_c, pool_r,
@@ -2331,6 +2345,7 @@ def decode_attend_q8_mla(
         # interpret runs keep a static arm choice (parity tests force the
         # paged kernel via LLM_MCP_TPU_Q8_DECODE=paged); unfit block sizes
         # take the exact gather math
+        _note_fall("decode_attend_q8_mla", f"paged: {nbs_t} blocks of {S} unfit", interp)
         return _decode_attend_q8_mla_fallback(
             qt, qr, new_c, new_r, cache_c, cache_r, layer, lengths, scale, slot_ids,
             block_tables, pool_c, pool_r,
@@ -2354,7 +2369,9 @@ def _append_q8_kernel(
     pay_ref,  # [L, 1, Hf, hd] int8 — this step's FUSED row: quantized K
     #           heads, V heads, packed-scale bytes (built by append_kv_q8
     #           in plain JAX — the kernel only selects, never quantizes)
-    s_ref,  # [L, 1, 2*Hkv] — this step's plain dequant scales
+    s_ref,  # [L, 1, 2*Hkv, BSS] — this step's plain dequant scales, already
+    #         broadcast along the lane tile (a [L, 1, 2*Hkv] block has a
+    #         second-to-last dim of 1 over Ba: no legal TPU tile)
     cq_ref,  # [L, 1, Hf, BSQ, hd] int8 — payload tile containing position w
     cs_ref,  # [L, 1, 2*Hkv, BSS] — scales tile containing position w
     oq_ref,  # outputs — aliased to the cache operands
@@ -2375,7 +2392,50 @@ def _append_q8_kernel(
     oq_ref[:, 0] = jnp.where(hit, pay_ref[:, 0][:, :, None, :], cq_ref[:, 0])
     lanes = jax.lax.broadcasted_iota(jnp.int32, (1, 1, block_s), 2)  # [1,1,BSS]
     hit_s = live & (lanes == ws)
-    os_ref[:, 0] = jnp.where(hit_s, s_ref[:, 0][:, :, None].astype(os_ref.dtype), cs_ref[:, 0])
+    os_ref[:, 0] = jnp.where(hit_s, s_ref[:, 0].astype(os_ref.dtype), cs_ref[:, 0])
+
+
+def _q8_step_rows(cache_k: dict, new_k, new_v):
+    """One decode step's K/V [L, Ba, Hkv, hd] in the FUSED cache's own form:
+    (payload [L, Ba, Hf, hd] int8 — K heads | V heads | packed-scale bytes,
+    plain scales [L, Ba, 2*Hkv])."""
+    from ..models.llama import quantize_kv  # local import: avoid cycle
+    from ..models.quant import pack_scales
+
+    hd = cache_k["q"].shape[-1]
+    sdt = cache_k["s"].dtype
+    kq = quantize_kv(new_k, scale_dtype=sdt)
+    vq = quantize_kv(new_v, scale_dtype=sdt)
+    s_new = jnp.concatenate([kq["s"], vq["s"]], axis=2)
+    pay = jnp.concatenate([kq["q"], vq["q"]], axis=2)
+    if cache_k["q"].shape[2] > cache_k["s"].shape[2]:
+        # the packed pseudo-head row for this position: [L, Ba, 1, hd]
+        pay = jnp.concatenate([pay, pack_scales(s_new[..., None], hd)[..., 0, :]], 2)
+    return pay, s_new
+
+
+def _append_q8_scatter(cache_k: dict, pay, s_new, rows, lengths) -> dict:
+    """The plain XLA scatter the append kernel is held to (and what
+    unaligned test shapes take): OOB (parked) rows drop by scatter
+    semantics. It copies the whole cache per call on the chip."""
+    L, _, Hf = cache_k["q"].shape[:3]
+    Hs = cache_k["s"].shape[2]
+    l_idx = jnp.arange(L)[:, None, None]
+    b_idx = rows[None, :, None]
+    w_idx = lengths[None, :, None]
+    return {
+        "q": cache_k["q"].at[l_idx, b_idx, jnp.arange(Hf)[None, None, :], w_idx].set(pay),
+        "s": cache_k["s"].at[l_idx, b_idx, jnp.arange(Hs)[None, None, :], w_idx].set(s_new),
+    }
+
+
+def append_kv_q8_reference(cache_k, cache_v, new_k, new_v, lengths, slot_ids=None):
+    """`append_kv_q8` by plain XLA scatter: the kernel's parity reference
+    (tests/test_kernel_parity.py, chip_smoke.py)."""
+    Ba = new_k.shape[1]
+    rows = jnp.arange(Ba, dtype=jnp.int32) if slot_ids is None else slot_ids
+    pay, s_new = _q8_step_rows(cache_k, new_k, new_v)
+    return _append_q8_scatter(cache_k, pay, s_new, rows, lengths), cache_v
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -2408,46 +2468,21 @@ def append_kv_q8(
     """
     L, B, Hf, S, hd = cache_k["q"].shape
     Hs = cache_k["s"].shape[2]
-    Hkv = Hs // 2
-    p = Hf - Hs
     Ba = new_k.shape[1]
-    sdt = cache_k["s"].dtype
     interp = _interpret() if interpret is None else interpret
     rows = (
         jnp.arange(Ba, dtype=jnp.int32)
         if slot_ids is None
         else slot_ids.astype(jnp.int32)
     )
-    from ..models.llama import quantize_kv  # local import: avoid cycle
-    from ..models.quant import pack_scales
-
-    kq = quantize_kv(new_k, scale_dtype=sdt)
-    vq = quantize_kv(new_v, scale_dtype=sdt)
-    s_new = jnp.concatenate([kq["s"], vq["s"]], axis=2)  # [L, Ba, 2*Hkv]
-    pay = jnp.concatenate([kq["q"], vq["q"]], axis=2)  # [L, Ba, 2*Hkv, hd]
-    if p:
-        # the packed pseudo-head row for this position: [L, Ba, 1, hd]
-        pay = jnp.concatenate([pay, pack_scales(s_new[..., None], hd)[..., 0, :]], 2)
+    pay, s_new = _q8_step_rows(cache_k, new_k, new_v)
 
     # mosaic int8 stores want full 128-lane rows; small-head test configs
-    # (hd 32/64) take the scatter fallback. Interpret mode keeps the kernel
-    # path at lane-aligned shapes so parity tests cover the real tile-
-    # rewrite body.
-    if not _HAS_PLTPU or hd % 128 != 0 or S % 128 != 0:
-        # XLA fallback (CPU tests / no pallas-tpu): plain scatter, with OOB
-        # (parked) rows dropped by scatter semantics.
-        l_idx = jnp.arange(L)[:, None, None]
-        b_idx = rows[None, :, None]
-        w_idx = lengths[None, :, None]
-        ck = {
-            "q": cache_k["q"]
-            .at[l_idx, b_idx, jnp.arange(Hf)[None, None, :], w_idx]
-            .set(pay),
-            "s": cache_k["s"]
-            .at[l_idx, b_idx, jnp.arange(Hs)[None, None, :], w_idx]
-            .set(s_new),
-        }
-        return ck, cache_v
+    # (hd 32/64) take the scatter. Interpret mode keeps the kernel path at
+    # lane-aligned shapes so parity tests cover the real tile-rewrite body.
+    if hd % 128 != 0 or S % 128 != 0:
+        _note_fall("append_kv_q8", f"hd={hd} S={S} not lane-aligned", interp)
+        return _append_q8_scatter(cache_k, pay, s_new, rows, lengths), cache_v
 
     BSQ = 32  # int8 sublane tile height: smallest in-place payload rewrite
     BSS = 128  # lane width: smallest in-place scales rewrite
@@ -2466,7 +2501,7 @@ def append_kv_q8(
         grid=(Ba,),
         in_specs=[
             pl.BlockSpec((L, 1, Hf, hd), lambda b, lens, ids: (0, b, 0, 0)),
-            pl.BlockSpec((L, 1, Hs), lambda b, lens, ids: (0, b, 0)),
+            pl.BlockSpec((L, 1, Hs, BSS), lambda b, lens, ids: (0, b, 0, 0)),
             pl.BlockSpec(
                 (L, 1, Hf, BSQ, hd), lambda b, lens, ids: (0, ids[b], 0, blkq(lens, b), 0)
             ),
@@ -2498,7 +2533,7 @@ def append_kv_q8(
         lengths.astype(jnp.int32),
         rows,
         pay,
-        s_new,
+        jnp.broadcast_to(s_new[..., None], (L, Ba, Hs, BSS)),
         cache_k["q"],
         cache_k["s"],
     )
@@ -2527,6 +2562,21 @@ def _append_bf16_kernel(
     hit = live & (rows == wq)
     ok_ref[:, 0] = jnp.where(hit, nk_ref[:, 0][:, :, None, :].astype(ok_ref.dtype), ck_ref[:, 0])
     ov_ref[:, 0] = jnp.where(hit, nv_ref[:, 0][:, :, None, :].astype(ov_ref.dtype), cv_ref[:, 0])
+
+
+def append_kv_bf16_reference(cache_k, cache_v, new_k, new_v, lengths, slot_ids=None):
+    """`append_kv_bf16` by plain XLA scatter, OOB (parked) rows dropped: the
+    kernel's parity reference and what unaligned test shapes take."""
+    L, _, Hkv = cache_k.shape[:3]
+    Ba = new_k.shape[1]
+    rows = jnp.arange(Ba, dtype=jnp.int32) if slot_ids is None else slot_ids
+    l_idx = jnp.arange(L)[:, None, None]
+    b_idx = rows[None, :, None]
+    h_idx = jnp.arange(Hkv)[None, None, :]
+    w_idx = lengths[None, :, None]
+    ck = cache_k.at[l_idx, b_idx, h_idx, w_idx].set(new_k.astype(cache_k.dtype))
+    cv = cache_v.at[l_idx, b_idx, h_idx, w_idx].set(new_v.astype(cache_v.dtype))
+    return ck, cv
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -2560,16 +2610,11 @@ def append_kv_bf16(
     # mosaic stores want full 128-lane rows; small-head test configs take
     # the scatter fallback. Interpret mode keeps the kernel path at lane-
     # aligned shapes so parity tests cover the real tile-rewrite body.
-    if not _HAS_PLTPU or hd % 128 != 0 or S % BQ != 0:
-        # XLA fallback (CPU tests / no pallas-tpu): plain scatter, with OOB
-        # (parked) rows dropped by scatter semantics.
-        l_idx = jnp.arange(L)[:, None, None]
-        b_idx = rows[None, :, None]
-        h_idx = jnp.arange(Hkv)[None, None, :]
-        w_idx = lengths[None, :, None]
-        ck = cache_k.at[l_idx, b_idx, h_idx, w_idx].set(new_k.astype(cache_k.dtype))
-        cv = cache_v.at[l_idx, b_idx, h_idx, w_idx].set(new_v.astype(cache_v.dtype))
-        return ck, cv
+    if hd % 128 != 0 or S % BQ != 0:
+        _note_fall("append_kv_bf16", f"hd={hd} S={S} not tile-aligned", interp)
+        return append_kv_bf16_reference(
+            cache_k, cache_v, new_k, new_v, lengths, slot_ids=rows
+        )
 
     kernel = functools.partial(_append_bf16_kernel, block_q=BQ, seq_len=S)
 
@@ -2709,6 +2754,15 @@ def resolve_ragged_impl() -> str:
     return "kernel" if _on_tpu() else "xla"
 
 
+def _ragged_kernel_asked(impl: str | None) -> bool:
+    """An explicit `impl=` outranks the resolver; anything but the two
+    names is a caller's bug, not a request for the reference."""
+    impl = impl or resolve_ragged_impl()
+    if impl not in ("kernel", "xla"):
+        raise ValueError(f"unknown ragged prefill impl {impl!r} (kernel | xla)")
+    return impl == "kernel"
+
+
 def ragged_block_size(seq_len: int, block_tokens: int | None = None) -> int:
     """KV block size for the ragged kernels' past streams. Under physical
     paging it MUST equal the ledger's block_tokens (logical block j covers
@@ -2749,313 +2803,297 @@ def _seg_of(offs_ref, idx, n_rows: int):
     return seg
 
 
-def _ragged_prefill_bf16_kernel(
+def _tile_token_index(rows: int, block_q: int, t0):
+    """[rows, 1] packed token index of each query row of a group-major tile
+    (row g*BQ + t is token t0 + t): row mod BQ by compare-and-subtract, which
+    lowers where a vector integer remainder may not."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    t_loc = row
+    for g in range(1, rows // block_q):
+        t_loc = t_loc - jnp.where(row >= g * block_q, block_q, 0)
+    return t0 + t_loc
+
+
+def _vmem_nbytes(shape, dtype) -> int:
+    """Bytes a block of `shape` takes in VMEM: the lane axis pads to 128."""
+    lanes = -(-shape[-1] // 128) * 128
+    n = 1
+    for d in shape[:-1]:
+        n *= d
+    return n * lanes * jnp.dtype(dtype).itemsize
+
+
+def ragged_q_block(T: int, groups: int, block_q: int = 128) -> int:
+    """Packed tokens per q-tile of the ragged GQA kernel. One tile holds
+    groups * BQ query rows per KV head; 256 rows keep the f32 score tile and
+    the online-softmax state a few dozen vregs per head (the first form of
+    this kernel held every head of a 128-token tile live at once — 2 MB
+    values the compiler spent minutes spilling). Power of two so it divides
+    the pow2 packed length."""
+    cap = max(8, 256 // max(1, groups))
+    cap = 1 << (cap.bit_length() - 1)
+    return max(1, min(block_q, T, cap))
+
+
+def _ragged_prefill_gqa_kernel(
     li_ref,  # [1] int32 (scalar prefetch) — layer index
     offs_ref,  # [R+1] int32 (scalar prefetch) — packed row boundaries
     starts_ref,  # [R] int32 (scalar prefetch) — cached-prefix length per row
     tbl_ref,  # [R * nbs] int32 (scalar prefetch) — flattened block tables
-    q_ref,  # [Hkv, BQ, G, hd] VMEM — this tile's post-rope queries
+    q_ref,  # [1, Hkv, G*BQ, hd] VMEM — this tile's post-rope queries, row
+    #         g*BQ + t (group-major, so one head's rows are one 2-D matmul
+    #         operand and the packed index of a row is t0 + row mod BQ)
     ks_ref,  # [Hkv, T, hd] VMEM — the chunk's own post-rope keys (packed)
     vs_ref,  # [Hkv, T, hd] VMEM
-    ck_hbm,  # [L, B, Hkv, S, hd] ANY — arena K (identity homes)
-    cv_hbm,  # ANY — arena V
-    pk_hbm,  # [L, PXB, Hkv, bt, hd] ANY — prefix pool K
-    pv_hbm,  # ANY — prefix pool V
-    o_ref,  # [Hkv, BQ, G, hd] VMEM out
-    kbuf,  # VMEM scratch [2, Hkv, BS, hd] (double buffer)
-    vbuf,
-    sems,  # DMA semaphores [2, 2]
-    *,
+    *rest,
     scale: float,
     block_s: int,
     seq_len: int,
     n_rows: int,
+    block_q: int,
+    quantized: bool,
 ):
-    """Ragged flash prefill over the split bf16 GQA cache: per packed q-tile,
-    one double-buffered block-indirect K/V stream per descriptor row (past),
-    then causal packed self tiles, all folded into one online softmax."""
+    """Ragged flash prefill over the GQA cache, both layouts: per packed
+    q-tile, one double-buffered block-indirect stream per descriptor row
+    (past), then causal packed self tiles, all folded into one online
+    softmax whose state (acc, m, l) lives in VMEM scratch per KV head.
+
+    quantized=False — split bf16 cache: K and V blocks ride two DMAs.
+    quantized=True — FUSED int8 cache: ONE payload DMA per past block (K and
+      V heads ride the same copy — the PR 7 one-DMA property); the packed-
+      scale pseudo-head is never streamed — per-row plain scales arrive
+      PRE-GATHERED in VMEM as [R, nbs, 2*Hkv, BS] f32, one leading-dim index
+      per block (a lane slice at a 64-token offset is not 128-aligned and
+      Mosaic refuses it). Dequant folds post-dot on score and value sides;
+      the self segment stays exact.
+
+    Every loop that multiplies program size is a `fori_loop` (descriptor
+    rows, blocks, KV heads): the body is compiled once, so compile time does
+    not grow with R, S or Hkv."""
+    if quantized:
+        (srow_ref, pay_hbm, pool_hbm, o_ref, pay_buf, sems,
+         acc_ref, m_ref, l_ref) = rest
+        n_arena = pay_hbm.shape[1]
+    else:
+        (ck_hbm, cv_hbm, pk_hbm, pv_hbm, o_ref, kbuf, vbuf, sems,
+         acc_ref, m_ref, l_ref) = rest
+        n_arena = ck_hbm.shape[1]
     qi = pl.program_id(0)
     li = li_ref[0]
     BS = block_s
-    Hkv, BQ, G, hd = q_ref.shape
+    BQ = block_q
+    Hkv, RQ, hd = q_ref.shape[1], q_ref.shape[2], q_ref.shape[3]
+    G = RQ // BQ
     nbs = seq_len // BS
-    pool_base = ck_hbm.shape[1] * nbs
+    pool_base = n_arena * nbs
     t0 = qi * BQ
+    cdt = q_ref.dtype  # matmul operand dtype (bf16 on the chip)
 
-    q = q_ref[...].astype(jnp.float32)  # [Hkv, BQ, G, hd]
-    t_idx = t0 + jax.lax.broadcasted_iota(jnp.int32, (BQ, 1), 0)  # packed idx
+    t_idx = _tile_token_index(RQ, BQ, t0)  # [RQ, 1] packed index of each query row
 
-    acc = jnp.zeros((Hkv, BQ, G, hd), jnp.float32)
-    m = jnp.full((Hkv, BQ, G, 1), NEG_INF, jnp.float32)
-    l = jnp.zeros((Hkv, BQ, G, 1), jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+
+    def fold(h, s, mask, v, vss):
+        """One online-softmax update of head h with scores s [RQ, N]."""
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_ref[h]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[h] = l_ref[h] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        if vss is not None:
+            p = p * vss
+        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
+            p.astype(cdt), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[h] = m_new
+
+    def scores(h, k):
+        return jax.lax.dot_general(
+            q_ref[0, h], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # [RQ, N]
 
     # ---- past segment: block-indirect stream per row with cached prefix
-    for r in range(n_rows):
+    def copies(phys, slot, arena):
+        if arena:
+            arow = phys // nbs
+            blk = pl.ds((phys % nbs) * BS, BS)
+            if quantized:
+                srcs = (pay_hbm.at[li, arow, pl.ds(0, 2 * Hkv), blk, :],)
+            else:
+                srcs = (ck_hbm.at[li, arow, :, blk, :],
+                        cv_hbm.at[li, arow, :, blk, :])
+        else:
+            prow = phys - pool_base
+            if quantized:
+                srcs = (pool_hbm.at[li, prow, pl.ds(0, 2 * Hkv)],)
+            else:
+                srcs = (pk_hbm.at[li, prow], pv_hbm.at[li, prow])
+        dsts = (pay_buf,) if quantized else (kbuf, vbuf)
+        return [
+            pltpu.make_async_copy(src, dst.at[slot], sems.at[slot, i])
+            for i, (src, dst) in enumerate(zip(srcs, dsts))
+        ]
+
+    def issue(r, j, slot, op):
+        phys = tbl_ref[r * nbs + j]
+        ina = phys < pool_base
+
+        @pl.when(ina)
+        def _arena():
+            for c in copies(phys, slot, True):
+                getattr(c, op)()
+
+        @pl.when(jnp.logical_not(ina))
+        def _pool():
+            for c in copies(phys, slot, False):
+                getattr(c, op)()
+
+    def past_row(r, carry):
         w = starts_ref[r]
         lo = offs_ref[r]
         hi = offs_ref[r + 1]
         # skip rows with no tokens in this tile or no cached prefix
         use = (hi > lo) & (lo < t0 + BQ) & (hi > t0) & (w > 0)
         nblk = jnp.where(use, jnp.minimum((w + BS - 1) // BS, nbs), 0)
-
-        def issue(j, slot, op, r=r):
-            phys = tbl_ref[r * nbs + j]
-            ina = phys < pool_base
-
-            @pl.when(ina)
-            def _arena():
-                arow = phys // nbs
-                aoff = (phys % nbs) * BS
-                for c in (
-                    pltpu.make_async_copy(
-                        ck_hbm.at[li, arow, :, pl.ds(aoff, BS), :],
-                        kbuf.at[slot],
-                        sems.at[slot, 0],
-                    ),
-                    pltpu.make_async_copy(
-                        cv_hbm.at[li, arow, :, pl.ds(aoff, BS), :],
-                        vbuf.at[slot],
-                        sems.at[slot, 1],
-                    ),
-                ):
-                    getattr(c, op)()
-
-            @pl.when(jnp.logical_not(ina))
-            def _pool():
-                prow = phys - pool_base
-                for c in (
-                    pltpu.make_async_copy(
-                        pk_hbm.at[li, prow], kbuf.at[slot], sems.at[slot, 0]
-                    ),
-                    pltpu.make_async_copy(
-                        pv_hbm.at[li, prow], vbuf.at[slot], sems.at[slot, 1]
-                    ),
-                ):
-                    getattr(c, op)()
+        sel_q = (t_idx >= lo) & (t_idx < hi)  # [RQ, 1]
 
         @pl.when(nblk > 0)
-        def _warm(issue=issue):
-            issue(0, 0, "start")
+        def _warm():
+            issue(r, 0, 0, "start")
 
-        sel_q = (t_idx >= lo) & (t_idx < hi)  # [BQ, 1]
-
-        def body(j, carry, issue=issue, sel_q=sel_q, w=w, nblk=nblk):
-            acc, m, l = carry
+        def block(j, carry):
             slot = jax.lax.rem(j, 2)
 
             @pl.when(j + 1 < nblk)
-            def _pf():
-                issue(j + 1, 1 - slot, "start")
+            def _prefetch():
+                issue(r, j + 1, 1 - slot, "start")
 
-            issue(j, slot, "wait")
-            k = kbuf[slot].astype(jnp.float32)  # [Hkv, BS, hd]
-            v = vbuf[slot].astype(jnp.float32)
-            s = (
-                jax.lax.dot_general(
-                    q, k, (((3,), (2,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32,
-                )
-                * scale
-            )  # [Hkv, BQ, G, BS]
+            issue(r, j, slot, "wait")
             k_pos = j * BS + jax.lax.broadcasted_iota(jnp.int32, (1, BS), 1)
-            mask = (sel_q & (k_pos < w))[None, :, None, :]
-            s = jnp.where(mask, s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-            alpha = jnp.exp(m - m_new)
-            l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc = acc * alpha + jax.lax.dot_general(
-                p, v, (((3,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )
-            return acc, m_new, l
+            mask = sel_q & (k_pos < w)  # [RQ, BS]
 
-        acc, m, l = jax.lax.fori_loop(0, nblk, body, (acc, m, l))
+            def head(h, carry):
+                if quantized:
+                    k = pay_buf[slot, h].astype(jnp.float32).astype(cdt)
+                    v = pay_buf[slot, Hkv + h].astype(jnp.float32).astype(cdt)
+                    kss = srow_ref[r, j, pl.ds(h, 1), :]  # [1, BS] f32
+                    vss = srow_ref[r, j, pl.ds(Hkv + h, 1), :]
+                    fold(h, scores(h, k) * kss, mask, v, vss)
+                else:
+                    k = kbuf[slot, h].astype(cdt)
+                    v = vbuf[slot, h].astype(cdt)
+                    fold(h, scores(h, k), mask, v, None)
+                return carry
+
+            return jax.lax.fori_loop(0, Hkv, head, carry)
+
+        return jax.lax.fori_loop(0, nblk, block, carry)
+
+    jax.lax.fori_loop(0, n_rows, past_row, 0)
 
     # ---- self segment: causal packed tiles, segment-equality masked
-    seg_q = _seg_of(offs_ref, t_idx, n_rows)  # [BQ, 1]
+    seg_q = _seg_of(offs_ref, t_idx, n_rows)  # [RQ, 1]
 
-    def sbody(tb, carry):
-        acc, m, l = carry
-        k = ks_ref[:, pl.ds(tb * BQ, BQ), :].astype(jnp.float32)
-        v = vs_ref[:, pl.ds(tb * BQ, BQ), :].astype(jnp.float32)
-        s = (
-            jax.lax.dot_general(
-                q, k, (((3,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )  # [Hkv, BQ, G, BQk]
-        u_idx = tb * BQ + jax.lax.broadcasted_iota(jnp.int32, (1, BQ), 1)
-        seg_k = _seg_of(offs_ref, u_idx, n_rows)  # [1, BQk]
-        mask = ((seg_q == seg_k) & (u_idx <= t_idx))[None, :, None, :]
-        s = jnp.where(mask, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p, v, (((3,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        return acc, m_new, l
+    def self_tile(tb, carry):
+        u0 = pl.multiple_of(tb * BQ, BQ)
+        u_idx = u0 + jax.lax.broadcasted_iota(jnp.int32, (1, BQ), 1)
+        seg_k = _seg_of(offs_ref, u_idx, n_rows)  # [1, BQ]
+        mask = (seg_q == seg_k) & (u_idx <= t_idx)  # [RQ, BQ]
 
-    acc, m, l = jax.lax.fori_loop(0, qi + 1, sbody, (acc, m, l))
-    out = jnp.where(l > 0, acc / jnp.where(l > 0, l, 1.0), 0.0)
-    o_ref[...] = out.astype(o_ref.dtype)
+        def head(h, carry):
+            k = ks_ref[h, pl.ds(u0, BQ), :].astype(cdt)
+            v = vs_ref[h, pl.ds(u0, BQ), :].astype(cdt)
+            fold(h, scores(h, k), mask, v, None)
+            return carry
+
+        return jax.lax.fori_loop(0, Hkv, head, carry)
+
+    jax.lax.fori_loop(0, qi + 1, self_tile, 0)
+
+    def finish(h, carry):
+        l = l_ref[h]
+        out = jnp.where(l > 0, acc_ref[h] / jnp.where(l > 0, l, 1.0), 0.0)
+        o_ref[0, h] = out.astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, Hkv, finish, 0)
 
 
-def _ragged_prefill_q8_kernel(
-    li_ref,  # [1] int32 (scalar prefetch)
-    offs_ref,  # [R+1] int32 (scalar prefetch)
-    starts_ref,  # [R] int32 (scalar prefetch)
-    tbl_ref,  # [R * nbs] int32 (scalar prefetch)
-    q_ref,  # [Hkv, BQ, G, hd] VMEM — post-rope queries (bf16)
-    ks_ref,  # [Hkv, T, hd] VMEM — self keys, exact bf16
-    vs_ref,  # [Hkv, T, hd] VMEM
-    srow_ref,  # [R, 2*Hkv, S] VMEM — pre-gathered plain dequant scales
-    pay_hbm,  # [L, B, 2*Hkv + p, S, hd] int8 ANY — fused arena payload
-    pool_pay_hbm,  # [L, PXB, 2*Hkv + p, bt, hd] int8 ANY — prefix pool
-    o_ref,  # [Hkv, BQ, G, hd] VMEM out
-    pay_buf,  # VMEM scratch [2, 2*Hkv, BS, hd] int8 (double buffer)
-    sems,  # DMA semaphores [2, 1]
-    *,
-    scale: float,
-    block_s: int,
-    seq_len: int,
-    n_rows: int,
+def _ragged_gqa_call(
+    kernel_kw, q, k_self, v_self, layer, offsets, starts, tbl, extra_in,
+    extra_specs, scratch, stream_bytes, interp, block_q,
 ):
-    """Ragged flash prefill over the FUSED int8 GQA cache. One payload DMA
-    per past block (K and V heads ride the same copy — the PR 7 one-DMA
-    property); the packed-scale pseudo-head is never streamed — per-row
-    plain scales arrive PRE-GATHERED whole-S in VMEM (`paged_gather` on the
-    "s" plane), dodging the narrow scale-row DMAs Mosaic rejects (see
-    `_attend_q8_mla_blocked_kernel`). Dequant folds post-dot on score and
-    value sides; the self segment stays exact bf16 from registers."""
-    qi = pl.program_id(0)
-    li = li_ref[0]
-    BS = block_s
-    Hkv, BQ, G, hd = q_ref.shape
-    nbs = seq_len // BS
-    pool_base = pay_hbm.shape[1] * nbs
-    t0 = qi * BQ
-
-    q = q_ref[...].astype(jnp.float32)
-    t_idx = t0 + jax.lax.broadcasted_iota(jnp.int32, (BQ, 1), 0)
-
-    acc = jnp.zeros((Hkv, BQ, G, hd), jnp.float32)
-    m = jnp.full((Hkv, BQ, G, 1), NEG_INF, jnp.float32)
-    l = jnp.zeros((Hkv, BQ, G, 1), jnp.float32)
-
-    for r in range(n_rows):
-        w = starts_ref[r]
-        lo = offs_ref[r]
-        hi = offs_ref[r + 1]
-        use = (hi > lo) & (lo < t0 + BQ) & (hi > t0) & (w > 0)
-        nblk = jnp.where(use, jnp.minimum((w + BS - 1) // BS, nbs), 0)
-
-        def issue(j, slot, op, r=r):
-            phys = tbl_ref[r * nbs + j]
-            ina = phys < pool_base
-
-            @pl.when(ina)
-            def _arena():
-                arow = phys // nbs
-                aoff = (phys % nbs) * BS
-                getattr(
-                    pltpu.make_async_copy(
-                        pay_hbm.at[li, arow, pl.ds(0, 2 * Hkv), pl.ds(aoff, BS), :],
-                        pay_buf.at[slot],
-                        sems.at[slot, 0],
-                    ),
-                    op,
-                )()
-
-            @pl.when(jnp.logical_not(ina))
-            def _pool():
-                prow = phys - pool_base
-                getattr(
-                    pltpu.make_async_copy(
-                        pool_pay_hbm.at[li, prow, pl.ds(0, 2 * Hkv)],
-                        pay_buf.at[slot],
-                        sems.at[slot, 0],
-                    ),
-                    op,
-                )()
-
-        @pl.when(nblk > 0)
-        def _warm(issue=issue):
-            issue(0, 0, "start")
-
-        sel_q = (t_idx >= lo) & (t_idx < hi)
-
-        def body(j, carry, issue=issue, sel_q=sel_q, w=w, nblk=nblk, r=r):
-            acc, m, l = carry
-            slot = jax.lax.rem(j, 2)
-
-            @pl.when(j + 1 < nblk)
-            def _pf():
-                issue(j + 1, 1 - slot, "start")
-
-            issue(j, slot, "wait")
-            buf = pay_buf[slot]  # [2*Hkv, BS, hd] int8
-            k = buf[:Hkv].astype(jnp.float32)
-            v = buf[Hkv:].astype(jnp.float32)
-            ss = srow_ref[r, :, pl.ds(j * BS, BS)].astype(jnp.float32)  # [2Hkv,BS]
-            kss, vss = ss[:Hkv], ss[Hkv:]
-            s = (
-                jax.lax.dot_general(
-                    q, k, (((3,), (2,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32,
-                )
-                * kss[:, None, None, :]
-                * scale
-            )
-            k_pos = j * BS + jax.lax.broadcasted_iota(jnp.int32, (1, BS), 1)
-            mask = (sel_q & (k_pos < w))[None, :, None, :]
-            s = jnp.where(mask, s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-            alpha = jnp.exp(m - m_new)
-            l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc = acc * alpha + jax.lax.dot_general(
-                p * vss[:, None, None, :], v, (((3,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )
-            return acc, m_new, l
-
-        acc, m, l = jax.lax.fori_loop(0, nblk, body, (acc, m, l))
-
-    seg_q = _seg_of(offs_ref, t_idx, n_rows)
-
-    def sbody(tb, carry):
-        acc, m, l = carry
-        k = ks_ref[:, pl.ds(tb * BQ, BQ), :].astype(jnp.float32)
-        v = vs_ref[:, pl.ds(tb * BQ, BQ), :].astype(jnp.float32)
-        s = (
-            jax.lax.dot_general(
-                q, k, (((3,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )
-        u_idx = tb * BQ + jax.lax.broadcasted_iota(jnp.int32, (1, BQ), 1)
-        seg_k = _seg_of(offs_ref, u_idx, n_rows)
-        mask = ((seg_q == seg_k) & (u_idx <= t_idx))[None, :, None, :]
-        s = jnp.where(mask, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p, v, (((3,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
-        return acc, m_new, l
-
-    acc, m, l = jax.lax.fori_loop(0, qi + 1, sbody, (acc, m, l))
-    out = jnp.where(l > 0, acc / jnp.where(l > 0, l, 1.0), 0.0)
-    o_ref[...] = out.astype(o_ref.dtype)
+    """Shared pallas_call plumbing of the two GQA ragged dispatchers: tile
+    the packed queries group-major, run `_ragged_prefill_gqa_kernel`, undo
+    the tiling. `extra_in`/`extra_specs` are the layout's cache operands,
+    `scratch` its stream buffers + semaphores (`stream_bytes` of VMEM)."""
+    T, Hkv, G, hd = q.shape
+    BQ = ragged_q_block(T, G, block_q)
+    assert T % BQ == 0, (T, BQ)
+    nQ, RQ = T // BQ, G * BQ
+    q_t = (
+        q.reshape(nQ, BQ, Hkv, G, hd).transpose(0, 2, 3, 1, 4).reshape(nQ, Hkv, RQ, hd)
+    )
+    kernel = functools.partial(_ragged_prefill_gqa_kernel, block_q=BQ, **kernel_kw)
+    scratch = list(scratch) + [
+        pltpu.VMEM((Hkv, RQ, hd), jnp.float32),  # acc
+        pltpu.VMEM((Hkv, RQ, 1), jnp.float32),  # m
+        pltpu.VMEM((Hkv, RQ, 1), jnp.float32),  # l
+    ]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,  # li [1], offsets [R+1], starts [R], tbl [R*nbs]
+        grid=(nQ,),
+        in_specs=[
+            pl.BlockSpec((1, Hkv, RQ, hd), lambda qi, li, of, st, tb: (qi, 0, 0, 0)),
+            pl.BlockSpec((Hkv, T, hd), lambda qi, li, of, st, tb: (0, 0, 0)),
+            pl.BlockSpec((Hkv, T, hd), lambda qi, li, of, st, tb: (0, 0, 0)),
+            *extra_specs,
+        ],
+        out_specs=pl.BlockSpec(
+            (1, Hkv, RQ, hd), lambda qi, li, of, st, tb: (qi, 0, 0, 0)
+        ),
+        scratch_shapes=scratch,
+    )
+    # VMEM the kernel asks the compiler for, from its own shapes: the
+    # pipeline double-buffers every blocked operand (the resident self K/V
+    # included), scratch is single. The default scoped limit (16 MB) is
+    # below what the largest packed length needs; a v5e core has 128 MiB.
+    blocked = 2 * _vmem_nbytes((Hkv, RQ, hd), q.dtype)  # q, out
+    blocked += 2 * _vmem_nbytes((Hkv, T, hd), q.dtype)  # self K, V
+    blocked += sum(
+        _vmem_nbytes(x.shape, x.dtype)
+        for x, sp in zip(extra_in, extra_specs) if sp.memory_space is None
+    )
+    state = _vmem_nbytes((Hkv, RQ, hd), jnp.float32) + 2 * _vmem_nbytes(
+        (Hkv, RQ, 1), jnp.float32
+    )  # acc, m, l
+    vmem = 2 * blocked + state + stream_bytes + (8 << 20)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((nQ, Hkv, RQ, hd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=int(vmem)
+        ),
+        interpret=interp,
+    )(
+        jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),
+        jnp.asarray(offsets, jnp.int32),
+        starts,
+        tbl.reshape(-1).astype(jnp.int32),
+        q_t,
+        k_self.transpose(1, 0, 2),
+        v_self.transpose(1, 0, 2),
+        *extra_in,
+    )
+    return (
+        out.reshape(nQ, Hkv, G, BQ, hd).transpose(0, 3, 1, 2, 4).reshape(T, Hkv, G, hd)
+    )
 
 
 def _ragged_prefill_mla_kernel(
@@ -3063,161 +3101,154 @@ def _ragged_prefill_mla_kernel(
     offs_ref,  # [R+1] int32 (scalar prefetch)
     starts_ref,  # [R] int32 (scalar prefetch)
     tbl_ref,  # [R * nbs] int32 (scalar prefetch)
-    qt_ref,  # [BQ, H, Rl] VMEM — absorbed latent queries (q_nope @ W_uk)
-    qr_ref,  # [BQ, H, dr] VMEM — post-rope rope queries
-    cs_ref,  # [T, Rl] VMEM — the chunk's own latents, exact bf16
+    qt_ref,  # [1, H*BQ, Rl] VMEM — absorbed latent queries (q_nope @ W_uk),
+    #          row h*BQ + t (head-major, as the GQA kernel tiles its groups)
+    qr_ref,  # [1, H*BQ, dr] VMEM — post-rope rope queries, same rows
+    cs_ref,  # [T, Rl] VMEM — the chunk's own latents, exact
     krs_ref,  # [T, dr] VMEM — the chunk's own post-rope rope keys
     rop_ref,  # [R, S, dr] VMEM — pre-gathered cached rope rows (native dtype)
-    ls_ref,  # [R, 1, S] VMEM — latent dequant scales (ones when bf16)
-    rs_ref,  # [R, 1, S] VMEM — rope dequant scales (ones when bf16)
+    ls_ref,  # [R, nbs, 1, BS] f32 VMEM — latent dequant scales (ones when bf16)
+    rs_ref,  # [R, nbs, 1, BS] f32 VMEM — rope dequant scales (ones when bf16)
     lat_hbm,  # [L, B, 1, S, Rl] ANY — latent arena (int8 or bf16)
     pool_lat,  # [L, PXB, 1, bt, Rl] ANY — latent prefix pool
-    o_ref,  # [BQ, H, Rl] VMEM out — attended latent context
+    o_ref,  # [1, H*BQ, Rl] VMEM out — attended latent context
     lbuf,  # VMEM scratch [2, BS, Rl] (double buffer)
     sems,  # DMA semaphores [2, 1]
+    acc_ref,  # VMEM scratch [H*BQ, Rl] f32
+    m_ref,  # [H*BQ, 1] f32
+    l_ref,  # [H*BQ, 1] f32
     *,
     scale: float,
     block_s: int,
     seq_len: int,
     n_rows: int,
+    block_q: int,
 ):
     """Ragged flash prefill over the MLA latent cache, absorbed form: scores
     land directly on cached latents (q_nope pre-folded through W_uk), the
-    value side re-expands outside the kernel. One static `quantized`-free
-    body covers bf16 AND int8 latents: blocks stream in the cache's native
-    dtype and dequant scales (ones for bf16 — exact multiply) fold post-dot.
-    Rope rows + scales arrive PRE-GATHERED whole-S (`paged_gather`): the
+    value side re-expands outside the kernel. One body covers bf16 AND int8
+    latents: blocks stream in the cache's native dtype and dequant scales
+    (ones for bf16 — exact multiply) fold post-dot. Every head attends the
+    SAME latent rows, so one tile is one [H*BQ, Rl] x [Rl, BS] matmul.
+    Rope rows + scales arrive PRE-GATHERED in VMEM (`paged_gather`): the
     per-block [BS, dr] rope slices are exactly the narrow DMAs Mosaic
     rejects in the MLA decode kernels, so only the [BS, Rl] latent payload
-    streams block-indirect."""
+    streams block-indirect; the scales are laid out one leading index per
+    block (a lane slice at a 64-token offset is refused). Structure as
+    `_ragged_prefill_gqa_kernel`: softmax state in VMEM scratch, `fori_loop`
+    over descriptor rows and blocks."""
     qi = pl.program_id(0)
     li = li_ref[0]
     BS = block_s
-    BQ, H, Rl = qt_ref.shape
+    BQ = block_q
+    RQ, Rl = qt_ref.shape[1], qt_ref.shape[2]
+    H = RQ // BQ
     nbs = seq_len // BS
     pool_base = lat_hbm.shape[1] * nbs
     t0 = qi * BQ
+    cdt = qt_ref.dtype
 
-    qt = qt_ref[...].astype(jnp.float32)  # [BQ, H, Rl]
-    qr = qr_ref[...].astype(jnp.float32)  # [BQ, H, dr]
-    t_idx = t0 + jax.lax.broadcasted_iota(jnp.int32, (BQ, 1), 0)
+    t_idx = _tile_token_index(RQ, BQ, t0)  # [RQ, 1] packed index of each query row
 
-    acc = jnp.zeros((BQ, H, Rl), jnp.float32)
-    m = jnp.full((BQ, H, 1), NEG_INF, jnp.float32)
-    l = jnp.zeros((BQ, H, 1), jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
 
-    for r in range(n_rows):
+    def nt(a, b):  # [M, K] x [N, K] -> [M, N] f32
+        return jax.lax.dot_general(
+            a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        )
+
+    def fold(s, mask, v, vscale):
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        if vscale is not None:
+            p = p * vscale
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(cdt), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        m_ref[...] = m_new
+
+    def issue(r, j, slot, op):
+        phys = tbl_ref[r * nbs + j]
+        ina = phys < pool_base
+
+        @pl.when(ina)
+        def _arena():
+            getattr(
+                pltpu.make_async_copy(
+                    lat_hbm.at[li, phys // nbs, 0, pl.ds((phys % nbs) * BS, BS), :],
+                    lbuf.at[slot],
+                    sems.at[slot, 0],
+                ),
+                op,
+            )()
+
+        @pl.when(jnp.logical_not(ina))
+        def _pool():
+            getattr(
+                pltpu.make_async_copy(
+                    pool_lat.at[li, phys - pool_base, 0], lbuf.at[slot], sems.at[slot, 0]
+                ),
+                op,
+            )()
+
+    def past_row(r, carry):
         w = starts_ref[r]
         lo = offs_ref[r]
         hi = offs_ref[r + 1]
         use = (hi > lo) & (lo < t0 + BQ) & (hi > t0) & (w > 0)
         nblk = jnp.where(use, jnp.minimum((w + BS - 1) // BS, nbs), 0)
-
-        def issue(j, slot, op, r=r):
-            phys = tbl_ref[r * nbs + j]
-            ina = phys < pool_base
-
-            @pl.when(ina)
-            def _arena():
-                arow = phys // nbs
-                aoff = (phys % nbs) * BS
-                getattr(
-                    pltpu.make_async_copy(
-                        lat_hbm.at[li, arow, 0, pl.ds(aoff, BS), :],
-                        lbuf.at[slot],
-                        sems.at[slot, 0],
-                    ),
-                    op,
-                )()
-
-            @pl.when(jnp.logical_not(ina))
-            def _pool():
-                prow = phys - pool_base
-                getattr(
-                    pltpu.make_async_copy(
-                        pool_lat.at[li, prow, 0], lbuf.at[slot], sems.at[slot, 0]
-                    ),
-                    op,
-                )()
+        sel_q = (t_idx >= lo) & (t_idx < hi)  # [RQ, 1]
 
         @pl.when(nblk > 0)
-        def _warm(issue=issue):
-            issue(0, 0, "start")
+        def _warm():
+            issue(r, 0, 0, "start")
 
-        sel_q = (t_idx >= lo) & (t_idx < hi)  # [BQ, 1]
-
-        def body(j, carry, issue=issue, sel_q=sel_q, w=w, nblk=nblk, r=r):
-            acc, m, l = carry
+        def block(j, carry):
             slot = jax.lax.rem(j, 2)
 
             @pl.when(j + 1 < nblk)
-            def _pf():
-                issue(j + 1, 1 - slot, "start")
+            def _prefetch():
+                issue(r, j + 1, 1 - slot, "start")
 
-            issue(j, slot, "wait")
-            lat = lbuf[slot].astype(jnp.float32)  # [BS, Rl]
-            rop = rop_ref[r, pl.ds(j * BS, BS), :].astype(jnp.float32)  # [BS,dr]
-            lsb = ls_ref[r, :, pl.ds(j * BS, BS)].astype(jnp.float32)  # [1, BS]
-            rsb = rs_ref[r, :, pl.ds(j * BS, BS)].astype(jnp.float32)
-            s = (
-                jax.lax.dot_general(
-                    qt, lat, (((2,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                * lsb[:, None, :]
-                + jax.lax.dot_general(
-                    qr, rop, (((2,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                )
-                * rsb[:, None, :]
-            ) * scale  # [BQ, H, BS]
+            issue(r, j, slot, "wait")
+            lat = lbuf[slot].astype(jnp.float32).astype(cdt)  # [BS, Rl]
+            rop = rop_ref[
+                r, pl.ds(pl.multiple_of(j * BS, BS), BS), :
+            ].astype(jnp.float32).astype(cdt)  # [BS, dr]
+            lsb = ls_ref[r, j]  # [1, BS] f32
+            rsb = rs_ref[r, j]
+            s = (nt(qt_ref[0], lat) * lsb + nt(qr_ref[0], rop) * rsb) * scale
             k_pos = j * BS + jax.lax.broadcasted_iota(jnp.int32, (1, BS), 1)
-            mask = (sel_q & (k_pos < w))[:, None, :]
-            s = jnp.where(mask, s, NEG_INF)
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-            alpha = jnp.exp(m - m_new)
-            l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc = acc * alpha + jax.lax.dot_general(
-                p * lsb[:, None, :], lat, (((2,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            return acc, m_new, l
+            fold(s, sel_q & (k_pos < w), lat, lsb)
+            return carry
 
-        acc, m, l = jax.lax.fori_loop(0, nblk, body, (acc, m, l))
+        return jax.lax.fori_loop(0, nblk, block, carry)
 
-    seg_q = _seg_of(offs_ref, t_idx, n_rows)
+    jax.lax.fori_loop(0, n_rows, past_row, 0)
 
-    def sbody(tb, carry):
-        acc, m, l = carry
-        c = cs_ref[pl.ds(tb * BQ, BQ), :].astype(jnp.float32)  # [BQk, Rl]
-        kr = krs_ref[pl.ds(tb * BQ, BQ), :].astype(jnp.float32)  # [BQk, dr]
-        s = (
-            jax.lax.dot_general(
-                qt, c, (((2,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            + jax.lax.dot_general(
-                qr, kr, (((2,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-        ) * scale  # [BQ, H, BQk]
-        u_idx = tb * BQ + jax.lax.broadcasted_iota(jnp.int32, (1, BQ), 1)
+    seg_q = _seg_of(offs_ref, t_idx, n_rows)  # [RQ, 1]
+
+    def self_tile(tb, carry):
+        u0 = pl.multiple_of(tb * BQ, BQ)
+        c = cs_ref[pl.ds(u0, BQ), :].astype(cdt)  # [BQ, Rl]
+        kr = krs_ref[pl.ds(u0, BQ), :].astype(cdt)  # [BQ, dr]
+        s = (nt(qt_ref[0], c) + nt(qr_ref[0], kr)) * scale
+        u_idx = u0 + jax.lax.broadcasted_iota(jnp.int32, (1, BQ), 1)
         seg_k = _seg_of(offs_ref, u_idx, n_rows)
-        mask = ((seg_q == seg_k) & (u_idx <= t_idx))[:, None, :]
-        s = jnp.where(mask, s, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * alpha + jax.lax.dot_general(
-            p, c, (((2,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        return acc, m_new, l
+        fold(s, (seg_q == seg_k) & (u_idx <= t_idx), c, None)
+        return carry
 
-    acc, m, l = jax.lax.fori_loop(0, qi + 1, sbody, (acc, m, l))
-    out = jnp.where(l > 0, acc / jnp.where(l > 0, l, 1.0), 0.0)
-    o_ref[...] = out.astype(o_ref.dtype)
+    jax.lax.fori_loop(0, qi + 1, self_tile, 0)
+    l = l_ref[...]
+    out = jnp.where(l > 0, acc_ref[...] / jnp.where(l > 0, l, 1.0), 0.0)
+    o_ref[0] = out.astype(o_ref.dtype)
 
 
 def _ragged_attend_gqa_fallback(
@@ -3359,7 +3390,7 @@ def ragged_prefill_attend_bf16(
     R = slots.shape[0]
     sc = scale or hd**-0.5
     starts = jnp.asarray(starts, jnp.int32)
-    use_kernel = (impl or resolve_ragged_impl()) == "kernel" and _HAS_PLTPU
+    use_kernel = _ragged_kernel_asked(impl)
 
     if not use_kernel:
         Sk = min(skey, S) if skey else S
@@ -3391,51 +3422,19 @@ def ragged_prefill_attend_bf16(
     else:
         pk = jnp.zeros((L, 1, Hkv, BS, hd), cache_k.dtype)
         pv = jnp.zeros((L, 1, Hkv, BS, hd), cache_v.dtype)
-    BQ = min(block_q, T)
-    assert T % BQ == 0, (T, BQ)
-    kernel = functools.partial(
-        _ragged_prefill_bf16_kernel, scale=sc, block_s=BS, seq_len=S, n_rows=R
-    )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,  # li [1], offsets [R+1], starts [R], tbl [R*nbs]
-        grid=(T // BQ,),
-        in_specs=[
-            pl.BlockSpec((Hkv, BQ, G, hd), lambda qi, li, of, st, tb: (0, qi, 0, 0)),
-            pl.BlockSpec((Hkv, T, hd), lambda qi, li, of, st, tb: (0, 0, 0)),
-            pl.BlockSpec((Hkv, T, hd), lambda qi, li, of, st, tb: (0, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),  # arena K
-            pl.BlockSpec(memory_space=pl.ANY),  # arena V
-            pl.BlockSpec(memory_space=pl.ANY),  # pool K
-            pl.BlockSpec(memory_space=pl.ANY),  # pool V
-        ],
-        out_specs=pl.BlockSpec(
-            (Hkv, BQ, G, hd), lambda qi, li, of, st, tb: (0, qi, 0, 0)
-        ),
-        scratch_shapes=[
+    return _ragged_gqa_call(
+        dict(scale=sc, block_s=BS, seq_len=S, n_rows=R, quantized=False),
+        q, k_self, v_self, layer, offsets, starts, tbl,
+        (cache_k, cache_v, pk, pv),
+        [pl.BlockSpec(memory_space=pl.ANY)] * 4,  # arena K, V; pool K, V
+        [
             pltpu.VMEM((2, Hkv, BS, hd), cache_k.dtype),
             pltpu.VMEM((2, Hkv, BS, hd), cache_v.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
+        4 * Hkv * BS * hd * cache_k.dtype.itemsize,
+        interp, block_q,
     )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Hkv, T, G, hd), q.dtype),
-        interpret=interp,
-    )(
-        jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),
-        jnp.asarray(offsets, jnp.int32),
-        starts,
-        tbl.reshape(-1).astype(jnp.int32),
-        q.transpose(1, 0, 2, 3),
-        k_self.transpose(1, 0, 2),
-        v_self.transpose(1, 0, 2),
-        cache_k,
-        cache_v,
-        pk,
-        pv,
-    )
-    return out.transpose(1, 0, 2, 3)
 
 
 def ragged_prefill_attend_q8(
@@ -3465,7 +3464,7 @@ def ragged_prefill_attend_q8(
     sc = scale or hd**-0.5
     starts = jnp.asarray(starts, jnp.int32)
     slots_i = jnp.asarray(slots, jnp.int32)
-    use_kernel = (impl or resolve_ragged_impl()) == "kernel" and _HAS_PLTPU
+    use_kernel = _ragged_kernel_asked(impl)
 
     if not use_kernel:
         Sk = min(skey, S) if skey else S
@@ -3511,50 +3510,28 @@ def ragged_prefill_attend_q8(
     else:
         srows = jnp.take(ss_l, slots_i, axis=0)
         pp = jnp.zeros((L, 1, cache_k["q"].shape[2], BS, hd), jnp.int8)
-    BQ = min(block_q, T)
-    assert T % BQ == 0, (T, BQ)
-    kernel = functools.partial(
-        _ragged_prefill_q8_kernel, scale=sc, block_s=BS, seq_len=S, n_rows=R
+    # [R, 2*Hkv, S] -> [R, nbs, 2*Hkv, BS] f32: block j is a leading index
+    srows = (
+        srows.astype(jnp.float32).reshape(R, 2 * Hkv, nbs, BS).transpose(0, 2, 1, 3)
     )
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(T // BQ,),
-        in_specs=[
-            pl.BlockSpec((Hkv, BQ, G, hd), lambda qi, li, of, st, tb: (0, qi, 0, 0)),
-            pl.BlockSpec((Hkv, T, hd), lambda qi, li, of, st, tb: (0, 0, 0)),
-            pl.BlockSpec((Hkv, T, hd), lambda qi, li, of, st, tb: (0, 0, 0)),
+    return _ragged_gqa_call(
+        dict(scale=sc, block_s=BS, seq_len=S, n_rows=R, quantized=True),
+        q, k_self, v_self, layer, offsets, starts, tbl,
+        (srows, cache_k["q"], pp),
+        [
             pl.BlockSpec(
-                (R, 2 * Hkv, S), lambda qi, li, of, st, tb: (0, 0, 0)
+                (R, nbs, 2 * Hkv, BS), lambda qi, li, of, st, tb: (0, 0, 0, 0)
             ),  # scales
             pl.BlockSpec(memory_space=pl.ANY),  # fused arena payload
             pl.BlockSpec(memory_space=pl.ANY),  # fused pool payload
         ],
-        out_specs=pl.BlockSpec(
-            (Hkv, BQ, G, hd), lambda qi, li, of, st, tb: (0, qi, 0, 0)
-        ),
-        scratch_shapes=[
+        [
             pltpu.VMEM((2, 2 * Hkv, BS, hd), jnp.int8),
             pltpu.SemaphoreType.DMA((2, 1)),
         ],
+        4 * Hkv * BS * hd,
+        interp, block_q,
     )
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Hkv, T, G, hd), q.dtype),
-        interpret=interp,
-    )(
-        jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),
-        jnp.asarray(offsets, jnp.int32),
-        starts,
-        tbl.reshape(-1).astype(jnp.int32),
-        q.transpose(1, 0, 2, 3),
-        k_self.transpose(1, 0, 2),
-        v_self.transpose(1, 0, 2),
-        srows,
-        cache_k["q"],
-        pp,
-    )
-    return out.transpose(1, 0, 2, 3)
 
 
 def ragged_prefill_attend_mla(
@@ -3591,7 +3568,7 @@ def ragged_prefill_attend_mla(
     R = slots.shape[0]
     starts = jnp.asarray(starts, jnp.int32)
     slots_i = jnp.asarray(slots, jnp.int32)
-    use_kernel = (impl or resolve_ragged_impl()) == "kernel" and _HAS_PLTPU
+    use_kernel = _ragged_kernel_asked(impl)
 
     def rows_of(cache_full, pool_full, bound):
         """Layer-select + per-row gather of a cache plane, bounded to the
@@ -3633,55 +3610,85 @@ def ragged_prefill_attend_mla(
     # slices are the narrow DMAs Mosaic rejects); latent payload streams
     rop_g = rows_of(rop_all, pool_r["q"] if (paged_ and quantized) else pool_r, S)
     if quantized:
-        ls_g = rows_of(cache_c["s"], pool_c and pool_c["s"], S)[:, None, :]
-        rs_g = rows_of(cache_r["s"], pool_r and pool_r["s"], S)[:, None, :]
+        ls_g = rows_of(cache_c["s"], pool_c and pool_c["s"], S).astype(jnp.float32)
+        rs_g = rows_of(cache_r["s"], pool_r and pool_r["s"], S).astype(jnp.float32)
     else:
-        ls_g = jnp.ones((R, 1, S), jnp.float32)
-        rs_g = jnp.ones((R, 1, S), jnp.float32)
+        ls_g = rs_g = jnp.ones((R, S), jnp.float32)
+    # [R, S] -> [R, nbs, 1, BS]: block j is a leading index in the kernel
+    ls_g = ls_g.reshape(R, nbs, 1, BS)
+    rs_g = rs_g.reshape(R, nbs, 1, BS)
     pl_pool = (
         (pool_c["q"] if quantized else pool_c)
         if paged_
         else jnp.zeros((L, 1, 1, BS, Rl), lat_all.dtype)
     )
-    BQ = min(block_q, T)
+    H = qt.shape[1]
+    BQ = ragged_q_block(T, H, block_q)
     assert T % BQ == 0, (T, BQ)
+    nQ, RQ = T // BQ, H * BQ
+
+    def tile(x):  # [T, H, d] -> [nQ, H*BQ, d], row h*BQ + t
+        return x.reshape(nQ, BQ, H, -1).transpose(0, 2, 1, 3).reshape(nQ, RQ, -1)
+
     kernel = functools.partial(
-        _ragged_prefill_mla_kernel, scale=scale, block_s=BS, seq_len=S, n_rows=R
+        _ragged_prefill_mla_kernel, scale=scale, block_s=BS, seq_len=S,
+        n_rows=R, block_q=BQ,
     )
+    const = lambda *dims: (lambda qi, li, of, st, tb: (0,) * len(dims))  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(T // BQ,),
+        grid=(nQ,),
         in_specs=[
-            pl.BlockSpec((BQ, qt.shape[1], Rl), lambda qi, li, of, st, tb: (qi, 0, 0)),
-            pl.BlockSpec((BQ, qt.shape[1], dr), lambda qi, li, of, st, tb: (qi, 0, 0)),
-            pl.BlockSpec((T, Rl), lambda qi, li, of, st, tb: (0, 0)),
-            pl.BlockSpec((T, dr), lambda qi, li, of, st, tb: (0, 0)),
-            pl.BlockSpec((R, S, dr), lambda qi, li, of, st, tb: (0, 0, 0)),
-            pl.BlockSpec((R, 1, S), lambda qi, li, of, st, tb: (0, 0, 0)),
-            pl.BlockSpec((R, 1, S), lambda qi, li, of, st, tb: (0, 0, 0)),
+            pl.BlockSpec((1, RQ, Rl), lambda qi, li, of, st, tb: (qi, 0, 0)),
+            pl.BlockSpec((1, RQ, dr), lambda qi, li, of, st, tb: (qi, 0, 0)),
+            pl.BlockSpec((T, Rl), const(T, Rl)),
+            pl.BlockSpec((T, dr), const(T, dr)),
+            pl.BlockSpec((R, S, dr), const(R, S, dr)),
+            pl.BlockSpec((R, nbs, 1, BS), const(R, nbs, 1, BS)),
+            pl.BlockSpec((R, nbs, 1, BS), const(R, nbs, 1, BS)),
             pl.BlockSpec(memory_space=pl.ANY),  # latent arena
             pl.BlockSpec(memory_space=pl.ANY),  # latent pool
         ],
-        out_specs=pl.BlockSpec(
-            (BQ, qt.shape[1], Rl), lambda qi, li, of, st, tb: (qi, 0, 0)
-        ),
+        out_specs=pl.BlockSpec((1, RQ, Rl), lambda qi, li, of, st, tb: (qi, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, BS, Rl), lat_all.dtype),
             pltpu.SemaphoreType.DMA((2, 1)),
+            pltpu.VMEM((RQ, Rl), jnp.float32),
+            pltpu.VMEM((RQ, 1), jnp.float32),
+            pltpu.VMEM((RQ, 1), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    # VMEM from the kernel's own shapes (blocked operands double-buffered),
+    # as `_ragged_gqa_call` does
+    blocked = sum(
+        _vmem_nbytes(shape, dt)
+        for shape, dt in (
+            ((RQ, Rl), qt.dtype), ((RQ, Rl), qt.dtype), ((RQ, dr), qt.dtype),
+            ((T, Rl), c_self.dtype), ((T, dr), kr_self.dtype),
+            (rop_g.shape, rop_g.dtype), (ls_g.shape, ls_g.dtype),
+            (rs_g.shape, rs_g.dtype),
+        )
+    )
+    state = (
+        _vmem_nbytes((RQ, Rl), jnp.float32) + 2 * _vmem_nbytes((RQ, 1), jnp.float32)
+        + _vmem_nbytes((2, BS, Rl), lat_all.dtype)
+    )
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((T, qt.shape[1], Rl), qt.dtype),
+        out_shape=jax.ShapeDtypeStruct((nQ, RQ, Rl), qt.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=int(2 * blocked + state + (8 << 20)),
+        ),
         interpret=interp,
     )(
         jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)),
         jnp.asarray(offsets, jnp.int32),
         starts,
         tbl.reshape(-1).astype(jnp.int32),
-        qt,
-        qr,
+        tile(qt),
+        tile(qr),
         c_self,
         kr_self,
         rop_g,
@@ -3690,3 +3697,4 @@ def ragged_prefill_attend_mla(
         lat_all,
         pl_pool,
     )
+    return out.reshape(nQ, H, BQ, Rl).transpose(0, 2, 1, 3).reshape(T, H, Rl)

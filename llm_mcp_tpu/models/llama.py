@@ -908,6 +908,67 @@ def llama_prefill_chunk(
     )
 
 
+def ragged_write_rows(
+    cache: jnp.ndarray,  # [L, B, Hx, S, *rest] — one plane of the KV cache
+    new: jnp.ndarray,  # [L, Hx, T, *rest] — the packed chunk's rows, head-major
+    slots: jnp.ndarray,  # [R] int32 — cache row per descriptor row
+    starts: jnp.ndarray,  # [R] int32 — first position each row writes
+    offsets: jnp.ndarray,  # [R+1] int32 — packed row boundaries
+) -> jnp.ndarray:
+    """Land a packed ragged chunk in the cache, every layer, IN PLACE.
+
+    Descriptor row r's tokens are packed contiguously ([offsets[r],
+    offsets[r+1])) and go to contiguous positions of one slot, so each
+    (row, layer) is ONE window: read the [Hx, W, ..] window of the slot that
+    covers the positions, select the new rows into it, write it back with a
+    dynamic_update_slice. R and L are static, so the chain unrolls. Pads
+    and empty rows select nothing and write back what they read.
+
+    Why this shape and no other. Each alternative was compiled for a
+    described v5e at 8B int8, where one extra copy of the cache is 4.25 GB
+    and the step program no longer fits the chip:
+      - a scatter (`cache.at[:, slot, :, pos].set`), and any loop that
+        carries the cache through single-row updates, get a full second
+        copy of the cache in temp space;
+      - a straight-line chain of window updates compiles to temp ~0 only
+        while the update has the cache's own physical layout. An update
+        that carries the layer axis (the scan stacks its outputs with L
+        next to the lanes), token-major rows swapped into place, or a
+        gather's output each made the compiler re-lay the WHOLE cache out
+        to suit the small operand instead.
+    Hence: head-major rows (as `fuse_prompt_kv` makes them), one layer per
+    update, contiguous slices and no gather."""
+    L, B, Hx, S = cache.shape[:4]
+    T = new.shape[2]
+    R = slots.shape[0]
+    W = min(S, T)  # a row holds at most T tokens
+    ntail = cache.ndim - 4
+    win = jnp.arange(W, dtype=jnp.int32)
+    # the packed index landing at window offset i is t0 + i: contiguous, so
+    # a dynamic_slice of the rows doubled along T (t0 may be negative when
+    # the window was clamped back from the end of the slot; what wraps
+    # around is never selected) — a gather would do, but its output layout
+    # is the compiler's, and the cache gets re-laid-out to match it
+    twice = jnp.concatenate([new, new], axis=2)
+    for r in range(R):
+        n = offsets[r + 1] - offsets[r]
+        a = jnp.clip(starts[r], 0, S - W)  # window start (dynamic_slice's clamp)
+        pos = a + win  # [W] cache positions the window covers
+        t0 = jnp.mod(offsets[r] + a - starts[r], T)
+        hit = (pos >= starts[r]) & (pos < starts[r] + n)
+        keep = hit.reshape((1, 1, 1, W) + (1,) * ntail)
+        for l in range(L):
+            rows = jax.lax.dynamic_slice(
+                twice, (l, 0, t0) + (0,) * ntail, (1, Hx, W) + cache.shape[4:]
+            )[None]  # [1, 1, Hx, W, *rest]
+            at = (l, slots[r], 0, a) + (0,) * ntail
+            cur = jax.lax.dynamic_slice(cache, at, (1, 1, Hx, W) + cache.shape[4:])
+            cache = jax.lax.dynamic_update_slice(
+                cache, jnp.where(keep, rows.astype(cache.dtype), cur), at
+            )
+    return cache
+
+
 def llama_prefill_chunk_ragged(
     cfg: ModelConfig,
     params: Params,
@@ -925,6 +986,9 @@ def llama_prefill_chunk_ragged(
     skey: int = 0,  # STATIC past bound for the XLA arm (kernel arm ignores
     #   it — past trips are data-dependent, so 0 keeps ONE executable)
     paged: dict | None = None,  # {"tbl","k","v"} physical paging operand
+    impl: str | None = None,  # "kernel" | "xla": the ENGINE's resolved choice
+    #   (a sharded engine must say "xla" — a Mosaic kernel cannot be
+    #   partitioned by GSPMD); None asks kernels/attention.py's resolver
 ) -> tuple[jnp.ndarray, Any, Any]:
     """Ragged chunked prefill: the packed-descriptor twin of
     `llama_prefill_chunk_batch`. Instead of [A, C] bucket-padded rows, up to
@@ -950,7 +1014,7 @@ def llama_prefill_chunk_ragged(
 
         return mla_prefill_chunk_ragged(
             cfg, params, cache_k, cache_v, tokens, rowids, positions,
-            slots, starts, last_idx, skey=skey, paged=paged,
+            slots, starts, last_idx, skey=skey, paged=paged, impl=impl,
         )
     if cfg.sliding_window or cfg.attn_softcap:
         raise NotImplementedError(
@@ -980,15 +1044,25 @@ def llama_prefill_chunk_ragged(
             ),
         ]
     )  # [R+1]
-    wslot = slots[jnp.clip(rowids, 0, R - 1)]  # [T] write slot per token
     moe_valid = rowids < R  # [T]
     btbl = paged["tbl"] if paged is not None else None
 
     h = _embed_in(cfg, params, tokens)  # [T, D]
     cos, sin = rope_tables(cfg, hd, positions)  # [T, hd/2]
 
+    # The cache is a scan-INVARIANT operand (the decode steps' structure,
+    # `_decode_step_q8`): every layer reads it pre-append, the new rows stack
+    # out as scan ys, and ONE scatter lands them after the scan. Reads never
+    # see this chunk's writes either way — a row's past is positions
+    # < starts[r], its writes are positions >= starts[r], and shared blocks
+    # are full prefix blocks nobody writes — so hoisting the writes is
+    # value-identical. What it buys: with the cache as a scan CARRY that a
+    # Pallas call reads and a scatter then updates, XLA keeps a second copy
+    # of the whole cache (the 8B int8 engine's ragged programs asked for
+    # 18.05 GB of a 15.75 GB chip); read-only inside the loop, updated once
+    # outside it, the donated buffer is rewritten in place.
     def layer(carry, lp):
-        h, ck_all, cv_all, li = carry
+        h, li = carry
         x = _norm(cfg, h, lp["attn_norm"])
         q, k, v = _qkv(cfg, lp, x)
         q = apply_rope(q.reshape(T, H, hd), cos, sin)
@@ -996,52 +1070,54 @@ def llama_prefill_chunk_ragged(
         v = v.reshape(T, Hkv, hd)
         qg = q.reshape(T, Hkv, G, hd)
 
-        # ---- reads first: ragged attention over [cached past | packed self]
+        # ---- ragged attention over [cached past | packed self]
         if quantized:
             ctx = ragged_prefill_attend_q8(
-                qg, k, v, ck_all, li, rowids, offsets, slots, starts,
+                qg, k, v, cache_k, li, rowids, offsets, slots, starts,
                 scale=cfg.attn_scale, skey=skey, block_tables=btbl,
-                pool=paged["k"] if paged is not None else None,
+                pool=paged["k"] if paged is not None else None, impl=impl,
             )
         else:
             ctx = ragged_prefill_attend_bf16(
-                qg, k, v, ck_all, cv_all, li, rowids, offsets, slots, starts,
+                qg, k, v, cache_k, cache_v, li, rowids, offsets, slots, starts,
                 scale=cfg.attn_scale, skey=skey, block_tables=btbl,
                 pool_k=paged["k"] if paged is not None else None,
-                pool_v=paged["v"] if paged is not None else None,
+                pool_v=paged["v"] if paged is not None else None, impl=impl,
             )
         ctx = ctx.reshape(T, H * hd)
         h = _attn_residual(cfg, lp, ctx, h)
         h = _ffn_residual(cfg, lp, h, moe_valid=moe_valid)
 
-        # ---- writes last: positional scatter, pads (position S) DROP ----
-        # (paging keeps writes at identity arena homes — COW re-homing is
-        # host-side ledger machinery, so the scatter needs no tables)
+        # ---- this layer's rows, in the cache's own form and axis order
         if quantized:
             fused = fuse_prompt_kv(
                 k.transpose(1, 0, 2), v.transpose(1, 0, 2),
-                scale_dtype=ck_all["s"].dtype,
+                scale_dtype=cache_k["s"].dtype,
             )  # {"q": [2*Hkv+p, T, hd], "s": [2*Hkv, T]}
-            ck_all = {
-                "q": ck_all["q"].at[li, wslot, :, positions].set(
-                    fused["q"].transpose(1, 0, 2), mode="drop"
-                ),
-                "s": ck_all["s"].at[li, wslot, :, positions].set(
-                    fused["s"].T, mode="drop"
-                ),
-            }
+            new = (fused["q"], fused["s"])
         else:
-            ck_all = ck_all.at[li, wslot, :, positions].set(
-                k.astype(ck_all.dtype), mode="drop"
+            new = (
+                k.transpose(1, 0, 2).astype(cache_k.dtype),
+                v.transpose(1, 0, 2).astype(cache_v.dtype),
             )
-            cv_all = cv_all.at[li, wslot, :, positions].set(
-                v.astype(cv_all.dtype), mode="drop"
-            )
-        return (h, ck_all, cv_all, li + 1), None
+        return (h, li + 1), new
 
-    (h, new_k, new_v, _), _ = jax.lax.scan(
-        layer, (h, cache_k, cache_v, jnp.int32(0)), params["layers"]
-    )
+    (h, _), (new_a, new_b) = jax.lax.scan(
+        layer, (h, jnp.int32(0)), params["layers"]
+    )  # new_*: [L, heads, T, ...]
+
+    # ---- writes last, all layers at once (`ragged_write_rows`). Paging keeps
+    # writes at identity arena homes — COW re-homing is host-side ledger
+    # machinery, so the writes need no tables.
+    if quantized:
+        new_k = {
+            "q": ragged_write_rows(cache_k["q"], new_a, slots, starts, offsets),
+            "s": ragged_write_rows(cache_k["s"], new_b, slots, starts, offsets),
+        }
+        new_v = cache_v
+    else:
+        new_k = ragged_write_rows(cache_k, new_a, slots, starts, offsets)
+        new_v = ragged_write_rows(cache_v, new_b, slots, starts, offsets)
     last = jnp.take(h, jnp.clip(last_idx, 0, T - 1), axis=0)  # [R, D]
     return _logits(cfg, params, last), new_k, new_v
 

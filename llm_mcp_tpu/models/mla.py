@@ -30,7 +30,7 @@ only through Ollama (`discovery.go:510` infers metadata from the name);
 this module is what "serving a deepseek-class architecture in-process"
 means TPU-side. Rope here is the repo's split-half convention; loading
 published DeepSeek checkpoints additionally needs their yarn-scaled rope
-and shared-expert MoE (tracked in NOTES_r03.md), so the in-repo configs
+and shared-expert MoE, so the in-repo configs
 are the `tiny-mla` test config and an `mla-8b` long-context serving
 config with llama-8B-scale proportions.
 """
@@ -56,6 +56,7 @@ from .llama import (
     _logits,
     _norm,
     quantize_kv,
+    ragged_write_rows,
 )
 
 Params = Any
@@ -503,6 +504,7 @@ def mla_prefill_chunk_ragged(
     last_idx: jnp.ndarray,  # [Rn] int32 packed index of each row's last token
     skey: int = 0,  # STATIC past bound for the XLA arm (kernel arm ignores)
     paged: dict | None = None,  # {"tbl","k","v"} physical paging operand
+    impl: str | None = None,  # the engine's resolved "kernel" | "xla"
 ) -> tuple[jnp.ndarray, Any, Any]:
     """Ragged chunked prefill for MLA — the packed-descriptor twin of
     `mla_prefill_chunk_batch` (see `llama_prefill_chunk_ragged` for the
@@ -534,7 +536,6 @@ def mla_prefill_chunk_ragged(
             ),
         ]
     )  # [Rn+1]
-    wslot = slots[jnp.clip(rowids, 0, Rn - 1)]  # [T]
     moe_valid = rowids < Rn
     btbl = paged["tbl"] if paged is not None else None
     pool_c = paged["k"] if paged is not None else None
@@ -543,8 +544,13 @@ def mla_prefill_chunk_ragged(
     h = _embed_in(cfg, params, tokens)  # [T, D]
     cos, sin = rope_tables(cfg, dr, positions)  # [T, dr/2]
 
+    # the cache is scan-INVARIANT: every layer reads it pre-append, the new
+    # rows stack out as scan ys and land once after the scans
+    # (models/llama.py:ragged_write_rows says why — a cache carried through
+    # a scan that a Pallas call reads and a scatter updates costs a second
+    # copy of the whole cache in HBM)
     def layer(carry, lp):
-        h, cc_all, cr_all, li = carry
+        h, li = carry
         x = _norm(cfg, h, lp["attn_norm"])
         qn, qr = _queries(cfg, lp, x)  # [T, H, dn/dr]
         qr = apply_rope(qr, cos, sin)
@@ -553,49 +559,43 @@ def mla_prefill_chunk_ragged(
         w_uk, w_uv = _absorbed_w(lp, h.dtype, R, H, dn, dv)
         qt = jnp.einsum("thd,rhd->thr", qn, w_uk)  # [T, H, R]
 
-        # ---- reads first: ragged attention over [cached past | packed self]
+        # ---- ragged attention over [cached past | packed self]
         ctx_lat = ragged_prefill_attend_mla(
-            qt, qr, c, kr, cc_all, cr_all, li, rowids, offsets, slots, starts,
+            qt, qr, c, kr, cache_c, cache_r, li, rowids, offsets, slots, starts,
             scale=scale, skey=skey, block_tables=btbl,
-            pool_c=pool_c, pool_r=pool_r,
+            pool_c=pool_c, pool_r=pool_r, impl=impl,
         )  # [T, H, R]
         ctx = jnp.einsum("thr,rhd->thd", ctx_lat, w_uv).reshape(T, H * dv)
         h = h + qdot(ctx, lp["wo_mla"])
         h = _ffn_residual(cfg, lp, h, moe_valid=moe_valid)
 
-        # ---- writes last: positional scatter, pads (position S) DROP ----
+        # ---- this layer's rows in the cache's own form, [1 (head), T, ..]
         if quantized:
-            cq = quantize_kv(c, scale_dtype=cc_all["s"].dtype)
-            rq = quantize_kv(kr, scale_dtype=cr_all["s"].dtype)
-            cc_all = {
-                "q": cc_all["q"].at[li, wslot, 0, positions].set(
-                    cq["q"], mode="drop"
-                ),
-                "s": cc_all["s"].at[li, wslot, 0, positions].set(
-                    cq["s"], mode="drop"
-                ),
-            }
-            cr_all = {
-                "q": cr_all["q"].at[li, wslot, 0, positions].set(
-                    rq["q"], mode="drop"
-                ),
-                "s": cr_all["s"].at[li, wslot, 0, positions].set(
-                    rq["s"], mode="drop"
-                ),
-            }
+            cq = quantize_kv(c, scale_dtype=cache_c["s"].dtype)
+            rq = quantize_kv(kr, scale_dtype=cache_r["s"].dtype)
+            new = (cq["q"][None], cq["s"][None], rq["q"][None], rq["s"][None])
         else:
-            cc_all = cc_all.at[li, wslot, 0, positions].set(
-                c.astype(cc_all.dtype), mode="drop"
-            )
-            cr_all = cr_all.at[li, wslot, 0, positions].set(
-                kr.astype(cr_all.dtype), mode="drop"
-            )
-        return (h, cc_all, cr_all, li + 1), None
+            new = (c.astype(cache_c.dtype)[None], kr.astype(cache_r.dtype)[None])
+        return (h, li + 1), new
 
-    carry = (h, cache_c, cache_r, jnp.int32(0))
+    carry = (h, jnp.int32(0))
+    stacks = []
     if "dense_layers" in params:
-        carry, _ = jax.lax.scan(layer, carry, params["dense_layers"])
-    (h, new_c, new_r, _), _ = jax.lax.scan(layer, carry, params["layers"])
+        carry, ys = jax.lax.scan(layer, carry, params["dense_layers"])
+        stacks.append(ys)
+    (h, _), ys = jax.lax.scan(layer, carry, params["layers"])
+    stacks.append(ys)
+    new = [jnp.concatenate(parts, axis=0) for parts in zip(*stacks)]  # [L, 1, T, ..]
+
+    # ---- writes last, all layers at once, in place
+    def land(cache, rows):
+        return ragged_write_rows(cache, rows, slots, starts, offsets)
+
+    if quantized:
+        new_c = {"q": land(cache_c["q"], new[0]), "s": land(cache_c["s"], new[1])}
+        new_r = {"q": land(cache_r["q"], new[2]), "s": land(cache_r["s"], new[3])}
+    else:
+        new_c, new_r = land(cache_c, new[0]), land(cache_r, new[1])
     last = jnp.take(h, jnp.clip(last_idx, 0, T - 1), axis=0)  # [Rn, D]
     return _logits(cfg, params, last), new_c, new_r
 

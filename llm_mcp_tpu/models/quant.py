@@ -353,8 +353,7 @@ def fuse_layer_weights(params: Params) -> Params:
     projections one `w13` dot. The layer `lax.scan` then issues 2 big
     matmuls instead of 5 small ones per block half, which raises achieved
     HBM bandwidth on the w8a8 pass (fewer kernel launches + activation
-    re-reads per weight byte; NOTES_r05 measured the unfused pass at
-    ~570 GB/s of the 819 GB/s roofline).
+    re-reads per weight byte; no chip number on record for either form).
 
     Single-chip only: the fused output axis interleaves q|k|v head groups,
     which the `tp` axis of `llama_param_specs` cannot shard — the engine
@@ -388,12 +387,11 @@ def scan_unroll() -> int:
     A modest unroll (default 4 on TPU) amortizes the per-iteration scan
     overhead (dynamic-slice of the stacked weights + loop bookkeeping)
     without the 32x program bloat of full unrolling — the middle ground
-    NOTES_r05 asked for between scan-per-layer and `unroll=n_layers`.
+    asked for between scan-per-layer and `unroll=n_layers`.
     CPU/interpret runs keep 1: unrolling only slows compilation there."""
-    import jax as _jax
+    from ..utils.platform import on_tpu
 
-    on_tpu = any(d.platform == "tpu" for d in _jax.devices())
-    return int(os.environ.get("LLM_MCP_TPU_SCAN_UNROLL", "4" if on_tpu else "1"))
+    return int(os.environ.get("LLM_MCP_TPU_SCAN_UNROLL", "4" if on_tpu() else "1"))
 
 
 def scale_pack_width(n_kv_heads: int, head_dim: int, scale_dtype) -> int:
@@ -401,8 +399,16 @@ def scale_pack_width(n_kv_heads: int, head_dim: int, scale_dtype) -> int:
     the int8 KV payload block: 1 when the 2*Hkv k+v scale bytes for one
     position fit a single head_dim lane row, else 0 (packing disabled —
     the blocked kernel falls back to a second scale DMA per cell)."""
-    it = jnp.dtype(scale_dtype).itemsize
-    return 1 if 2 * n_kv_heads * it <= head_dim else 0
+    dt = jnp.dtype(scale_dtype)
+    if dt not in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32)):
+        # the in-kernel unpack rebuilds an f32 bit pattern from the bytes
+        # (kernels/attention.py:_unpack_scale_lanes): exact for bf16 and
+        # f32 scales only
+        return 0
+    return 1 if 2 * n_kv_heads * dt.itemsize <= head_dim else 0
+
+
+_UINT_OF_WIDTH = {2: jnp.uint16, 4: jnp.uint32}
 
 
 def pack_scales(s: jnp.ndarray, head_dim: int) -> jnp.ndarray:
@@ -410,13 +416,19 @@ def pack_scales(s: jnp.ndarray, head_dim: int) -> jnp.ndarray:
     row [..., 1, T, head_dim] so the blocked attention kernel's single
     payload DMA carries the dequant scales with the int8 K/V rows.
 
-    Layout per position (lane axis): Hs scales of `s.dtype`, byte-exact via
-    bitcast, then zero padding to head_dim lanes. The kernel inverts this
-    with `unpack_scales` after the block lands in VMEM."""
+    Layout per position (lane axis): Hs scales of `s.dtype`, each as its
+    bytes from the least significant up, then zero padding to head_dim
+    lanes. The byte order is spelled out with shifts (a width-changing
+    bitcast would leave it to the backend); the kernel inverts it the same
+    way after the block lands in VMEM (`_unpack_scale_lanes`)."""
     Hs, T = s.shape[-2], s.shape[-1]
     it = jnp.dtype(s.dtype).itemsize
     sw = jnp.swapaxes(s, -1, -2)  # [..., T, Hs]
-    raw = jax.lax.bitcast_convert_type(sw, jnp.int8)  # [..., T, Hs, it]
+    bits = jax.lax.bitcast_convert_type(sw, _UINT_OF_WIDTH[it])
+    raw = jnp.stack(
+        [(bits >> (8 * b)) & 0xFF for b in range(it)], axis=-1
+    ).astype(jnp.uint8)  # [..., T, Hs, it]
+    raw = jax.lax.bitcast_convert_type(raw, jnp.int8)
     raw = raw.reshape(*sw.shape[:-1], Hs * it)
     pad = [(0, 0)] * (raw.ndim - 1) + [(0, head_dim - Hs * it)]
     return jnp.pad(raw, pad)[..., None, :, :]  # [..., 1, T, head_dim]
@@ -424,12 +436,16 @@ def pack_scales(s: jnp.ndarray, head_dim: int) -> jnp.ndarray:
 
 def unpack_scales(row: jnp.ndarray, n_heads: int, scale_dtype) -> jnp.ndarray:
     """Invert `pack_scales` for one landed block: [..., T, head_dim] int8
-    -> [..., n_heads, T] scales. Runs inside the kernel (VMEM-resident
-    bitcast on a [T, Hs*itemsize] tile) and in tests."""
+    -> [..., n_heads, T] scales (the plain-JAX twin of the kernels'
+    `_unpack_scale_lanes`; tests hold the two to each other)."""
     it = jnp.dtype(scale_dtype).itemsize
-    raw = row[..., : n_heads * it]
-    raw = raw.reshape(*row.shape[:-1], n_heads, it)
-    s = jax.lax.bitcast_convert_type(raw, scale_dtype)  # [..., T, n_heads]
+    uint = _UINT_OF_WIDTH[it]
+    raw = row[..., : n_heads * it].reshape(*row.shape[:-1], n_heads, it)
+    raw = jax.lax.bitcast_convert_type(raw, jnp.uint8).astype(uint)
+    bits = jnp.zeros(raw.shape[:-1], uint)
+    for b in range(it):
+        bits = bits | (raw[..., b] << (8 * b))
+    s = jax.lax.bitcast_convert_type(bits, scale_dtype)  # [..., T, n_heads]
     return jnp.swapaxes(s, -1, -2)
 
 

@@ -48,17 +48,9 @@ def _shard_map(f, mesh, in_specs, out_specs):
     """jax.shard_map with the replication check off (ppermute/cond carries
     confuse varying-manual-axes inference; correctness is asserted by tests
     against the single-device reference)."""
-    smap = getattr(jax, "shard_map", None)
-    if smap is None:  # pre-0.5 jax: only the experimental spelling exists
-        from jax.experimental.shard_map import shard_map as smap
-    try:
-        return smap(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-        )
-    except TypeError:  # older spelling of the replication-check kwarg
-        return smap(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-        )
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -380,13 +380,13 @@ class Metrics:
         )
         self.decode_mfu = Gauge(
             "llmtpu_decode_mfu",
-            "Model FLOPs utilization of sampled decode rounds vs TPU_PEAK_TFLOPS",
+            "Model FLOPs utilization of sampled decode rounds vs the chip's published peak (perf.CHIP_PEAKS)",
             ["engine"],
             registry=r,
         )
         self.decode_mbu = Gauge(
             "llmtpu_decode_mbu",
-            "HBM bandwidth utilization of sampled decode rounds vs TPU_PEAK_HBM_GBPS",
+            "HBM bandwidth utilization of sampled decode rounds vs the chip's published peak (perf.CHIP_PEAKS)",
             ["engine"],
             registry=r,
         )

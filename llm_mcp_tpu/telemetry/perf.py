@@ -27,7 +27,8 @@ parts:
    layout (bf16/int8 × GQA/MLA, including the fused int8 layout's scale
    pseudo-head rows and the paged path's block-table gathers) turn the
    sampled decode device time into MFU/MBU gauges against the chip peaks
-   (`TPU_PEAK_TFLOPS` / `TPU_PEAK_HBM_GBPS`, default TPU v5e). The live
+   (`CHIP_PEAKS`, keyed by the device kind the engine reports; a kind
+   that is not in the table gets no utilization, only counts). The live
    `decode_mbu` number is ROADMAP item 5's "layers_gbps toward 650"
    microbench, continuously measured on the serve path. All four layouts
    are evaluated against the same measured token rate — the non-active
@@ -93,9 +94,28 @@ CACHE_LAYOUTS = ("gqa_bf16", "gqa_int8", "mla_bf16", "mla_int8")
 
 DEFAULT_PERF_SAMPLE = 32
 DEFAULT_TARGET_ITL_MS = 0.0  # no ITL SLO unless configured
-# TPU v5e chip peaks; override for other generations via env.
-DEFAULT_PEAK_TFLOPS = 197.0
-DEFAULT_PEAK_HBM_GBPS = 819.0
+# Published per-chip peaks keyed by JAX `device_kind`: (bf16 TFLOP/s, HBM
+# GB/s). Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+# 819 GB/s). A device that is not in the table has no utilization — never a
+# default: an MBU against another chip's bandwidth is a wrong number.
+CHIP_PEAKS: dict[str, tuple[float, float]] = {
+    "TPU v5 lite": (197.0, 819.0),
+}
+
+
+def chip_peaks(device_kind: str) -> tuple[float, float]:
+    """(peak bf16 TFLOP/s, peak HBM GB/s) of one chip of `device_kind`.
+    Raises for a kind the table does not hold."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r} "
+            f"(known: {sorted(CHIP_PEAKS)}): add it to "
+            "telemetry/perf.py:CHIP_PEAKS with its source"
+        ) from None
+
+
 _SCALE_BYTES = 4  # per-(head, token) quantization scale, f32
 
 
@@ -304,8 +324,12 @@ class PerfObservatory:
         target_ttft_ms: float | None = None,
         target_itl_ms: float | None = None,
         itl_window: int = 4096,
+        device_kind: str = "",
     ):
         self.shape = shape
+        # what the engine's device calls itself (jax device_kind): keys the
+        # roofline's peaks. "" (a bare observatory) and the CPU have none.
+        self.device_kind = device_kind
         self.active_layout = active_layout
         self.paged = paged
         self.block_tokens = max(1, int(block_tokens))
@@ -584,23 +608,25 @@ class PerfObservatory:
         return tok / dev if dev > 0 else 0.0
 
     def roofline(self) -> dict[str, Any]:
-        """MFU/MBU for every cache layout at the live decode shape. The
-        measured token rate comes from the sampled device walls; the four
-        layouts share it so the non-active rows read as what-ifs."""
-        peak_flops = _env_float("TPU_PEAK_TFLOPS", DEFAULT_PEAK_TFLOPS) * 1e12
-        peak_bw = _env_float("TPU_PEAK_HBM_GBPS", DEFAULT_PEAK_HBM_GBPS) * 1e9
+        """FLOPs and HBM bytes per token for every cache layout at the live
+        decode shape and, where `device_kind` has published peaks, MFU/MBU
+        against them. The measured token rate comes from the sampled device
+        walls; the four layouts share it so the non-active rows read as
+        what-ifs."""
+        peaks = CHIP_PEAKS.get(self.device_kind)
         tok_s = self._decode_device_tok_per_s()
         ctx = self._ctx_ema or 1.0
         rows = self._rows_ema or 1.0
         out: dict[str, Any] = {
-            "peak_tflops": peak_flops / 1e12,
-            "peak_hbm_gbps": peak_bw / 1e9,
+            "device_kind": self.device_kind,
             "device_tok_per_s": round(tok_s, 1),
             "ctx_mean": round(ctx, 1),
             "rows_mean": round(rows, 2),
             "active_layout": self.active_layout,
             "layouts": {},
         }
+        if peaks is not None:
+            out["peak_tflops"], out["peak_hbm_gbps"] = peaks
         if self.shape is None:
             return out
         for layout in CACHE_LAYOUTS:
@@ -617,17 +643,22 @@ class PerfObservatory:
                     weight_bytes_per_param=wb,
                 ),
             )
-            out["layouts"][layout] = {
+            row = {
                 "flops_per_token": flops,
                 "hbm_bytes_per_token": byts,
                 "arith_intensity": flops / byts if byts else 0.0,
-                "mfu": (flops * tok_s / peak_flops) if peak_flops else 0.0,
-                "mbu": (byts * tok_s / peak_bw) if peak_bw else 0.0,
                 "active": layout == self.active_layout,
             }
-        act = out["layouts"][self.active_layout]
-        out["decode_mfu"] = round(act["mfu"], 4)
-        out["decode_mbu"] = round(act["mbu"], 4)
+            if peaks is not None:
+                row["mfu"] = flops * tok_s / (peaks[0] * 1e12)
+                row["mbu"] = byts * tok_s / (peaks[1] * 1e9)
+            out["layouts"][layout] = row
+        if peaks is not None:
+            # utilization only against the peaks of the chip that ran it;
+            # counts (flops, bytes) above are from shapes and hold anywhere
+            act = out["layouts"][self.active_layout]
+            out["decode_mfu"] = round(act["mfu"], 4)
+            out["decode_mbu"] = round(act["mbu"], 4)
         return out
 
     # -- the /v1/debug/perf document --------------------------------------

@@ -24,7 +24,7 @@ In-process, the *current* span is tracked on a module-level thread-local
 stack so nested `span()` blocks parent implicitly and helpers like
 `current_traceparent()` work from anywhere on the request thread.
 
-Engine span attribute taxonomy (the executor stamps these; consumers like
+Engine span attribute vocabulary (the executor stamps these; consumers like
 `scripts/trace_dump.py` and the stage histograms key on them):
 
   engine.admit    request_id

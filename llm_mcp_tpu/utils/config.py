@@ -215,58 +215,61 @@ class Config:
 
 # enable_compile_cache outcomes, counted not raised: a bad cache dir must
 # never take a serving boot down (the engine runs fine, just cold), but the
-# failure has to be visible somewhere — warmup_stats()/bench read these.
+# failure has to be visible somewhere — warmup_stats()/bench read these and
+# chip_smoke.py prints them.
 compile_cache_failures = 0
 compile_cache_dir: str | None = None
 
+# <checkout>/.jax_cache — where the accelerator entry points keep compiled
+# executables when nobody placed the cache from outside. A FIXED path: the
+# directory is part of the cache key's world (an entry written under one
+# path is only found again under the same one), so never a temp name, pid
+# or time.
+DEFAULT_COMPILE_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
+
 
 def compile_cache_path() -> str:
-    """Resolve the ONE compile-cache knob. `TPU_COMPILE_CACHE` wins: a path
-    enables the cache there; `0`/`off`/`false` force-disables (even when
-    JAX_COMPILATION_CACHE_DIR is set — conftest vs production isolation);
-    unset falls through to the legacy `JAX_COMPILATION_CACHE_DIR`. Empty
-    return = disabled."""
-    knob = getenv("TPU_COMPILE_CACHE", "").strip()
-    if knob.lower() in ("0", "off", "false", "no"):
-        return ""
-    if knob:
-        return knob
-    return getenv("JAX_COMPILATION_CACHE_DIR", "")
+    """The one rule: where `JAX_COMPILATION_CACHE_DIR` is set, that
+    directory; where it is not, `<checkout>/.jax_cache`."""
+    return getenv("JAX_COMPILATION_CACHE_DIR", "").strip() or DEFAULT_COMPILE_CACHE
 
 
-def enable_compile_cache(
-    path: str | None = None, min_compile_s: float = 1.0
-) -> str | None:
-    """Persistent XLA compile cache (serving entrypoints, bench, AND
-    tests/conftest.py — the one knobbed path): first 8B compiles cost 1-2
-    min each on a remote chip, and engine restarts would otherwise re-pay
+def enable_compile_cache(min_compile_s: float = 1.0) -> str | None:
+    """Persistent XLA compile cache for every process that compiles for the
+    device (`python -m llm_mcp_tpu.api`, the worker with engines,
+    chip_smoke.py, bench.py, tests/conftest.py): first 8B compiles cost
+    tens of seconds to minutes each, and a restart would otherwise re-pay
     the whole executable zoo (prompt buckets, compact buckets, admit
     shapes). The warmup planner's background AOT compiles land here too,
     which is what makes them stick for the next boot (warmup_pack.py).
 
-    STRICTLY OPT-IN via TPU_COMPILE_CACHE (fallback:
-    JAX_COMPILATION_CACHE_DIR): measured on the CPU backend, cached AOT
-    executables can carry target-machine features the loader host lacks
-    (+prefer-no-scatter et al.) — XLA loads them anyway with SIGILL
-    warnings and a large slowdown. Only enable where you've verified the
-    backend round-trips its own cache.
+    Where `JAX_COMPILATION_CACHE_DIR` is set JAX reads it itself and this
+    function gives `jax.config` no other directory; where it is not, the
+    fixed `DEFAULT_COMPILE_CACHE` is configured. A cache written on one
+    machine is for that machine: CPU entries can carry target features
+    another host lacks, and a compile for a *described* chip cannot be
+    loaded without one (tests/test_tpu_compile.py turns the cache off
+    around its compiles).
 
     Failures COUNT (module counter `compile_cache_failures`), never raise:
     an unwritable cache dir degrades to a cold boot, not a dead one.
-    Returns the active cache dir, or None when disabled/failed."""
+    Returns the active cache dir, or None when it could not be used."""
     import logging as _logging
 
     global compile_cache_failures, compile_cache_dir
-    cache_dir = path if path is not None else compile_cache_path()
-    if not cache_dir:
-        return None
-    # jax imports only on the enabled path — proxy-only workers deliberately
-    # never import jax (worker/__main__.py lazy-imports inside its engines
-    # branch), and this must stay a no-op for them
+    from_env = bool(getenv("JAX_COMPILATION_CACHE_DIR", "").strip())
+    cache_dir = compile_cache_path()
     import jax
+
     try:
         os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        if not os.access(cache_dir, os.W_OK | os.X_OK):
+            raise PermissionError(f"{cache_dir} is not writable")
+        if not from_env:
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
         jax.config.update(
             "jax_persistent_cache_min_compile_time_secs", float(min_compile_s)
         )

@@ -25,12 +25,14 @@ def main() -> None:
     from .worker import Worker
 
     cfg = Config()
-    enable_compile_cache()
     core_url = os.environ.get("CORE_URL", "http://localhost:8080")
 
     gen_engines: dict = {}
     embed_engines: dict = {}
     if os.environ.get("WORKER_LOAD_ENGINES", "") in ("1", "true"):
+        # only a worker with engines compiles anything; a proxy-only worker
+        # never imports jax
+        enable_compile_cache()
         import jax.numpy as jnp
 
         from ..executor import EmbeddingEngine, GenerationEngine

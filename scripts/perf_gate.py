@@ -2,7 +2,7 @@
 """Performance gate: compare a bench record against a baseline and exit
 nonzero on regression.
 
-    python scripts/perf_gate.py BENCH_r05.json BASELINE.json
+    python scripts/perf_gate.py tests/fixtures/gate_record_r05.json BASELINE.json
 
 The r05 regression (serve 2428 → 464.7 tok/s, p95 TTFT 3.4 s → 15.7 s)
 shipped silently because the numbers lived in a JSON blob nobody diffed.
@@ -252,7 +252,7 @@ ABS_MIN = {
     # finished tokens meeting the TTFT+ITL SLO means the headline tok/s is
     # mostly SLO-violating traffic — DistServe's "raw throughput lied"
     # case. decode_mbu: sampled decode rounds moving under 30% of
-    # TPU_PEAK_HBM_GBPS on the 8B int8 headline is a bandwidth collapse
+    # the chip's published HBM peak on the 8B int8 headline is a bandwidth collapse
     # (lost fused layout / silent fallback); healthy rounds measured well
     # above it (layers_gbps ~570/819 ≈ 0.70 on the weight stream alone)
     "goodput_ratio": 0.5,
@@ -291,7 +291,7 @@ ABS_MAX = {
     "waterfall_stall_p95_ms": 2500.0,
     "waterfall_total_p95_ms": 30000.0,
     # cold start (ISSUE 18 acceptance): boot-to-first-token in a fresh
-    # process. With a warm shipped compile cache (TPU_COMPILE_CACHE) the
+    # process. With a warm shipped compile cache the
     # critical-prefix warmup deserializes executables instead of compiling
     # them — over 10 s means the cache keyed wrong (recompiling) or the
     # critical prefix grew past "one admit bucket + one prefill + one

@@ -3,8 +3,8 @@
 
 A cold process pays 1-2 minutes of XLA compiles before its first token.
 Two artifacts make that cost portable (doc/performance.md "Cold start &
-warmup"): the persistent XLA compile cache (TPU_COMPILE_CACHE — the
-executables themselves) and the compile ledger's per-shape aggregates
+warmup"): the persistent XLA compile cache (the executables
+themselves) and the compile ledger's per-shape aggregates
 (which shapes a real serve window actually dispatched, and what each
 cost). This tool bundles both into a directory you can rsync/objstore to
 a joining host, so its warmup planner (executor/warmup.py) deserializes
@@ -22,9 +22,9 @@ files, safe to merge), PACK_DIR/warmup_plan.json (compile-ledger table
 rows), PACK_DIR/manifest.json. Import copies cache entries into the
 resolved cache dir and drops warmup_plan.json beside them, where
 CoreServer.boot_warmup auto-loads it as plan priors. Both directions
-resolve the cache dir through the one knobbed path
-(utils/config.compile_cache_path: TPU_COMPILE_CACHE, falling back to
-JAX_COMPILATION_CACHE_DIR) unless --cache-dir overrides it.
+resolve the cache dir through the one rule
+(utils/config.compile_cache_path: JAX_COMPILATION_CACHE_DIR where set,
+else <checkout>/.jax_cache) unless --cache-dir overrides it.
 
 Export plan sources, first available wins: --plan FILE (a saved
 /v1/debug/compiles response or bare table list), --core URL (live fetch).
@@ -48,13 +48,7 @@ from llm_mcp_tpu.utils.config import compile_cache_path  # noqa: E402
 
 
 def _resolve_cache_dir(arg: str | None) -> str:
-    d = arg or compile_cache_path()
-    if not d:
-        sys.exit(
-            "no compile cache dir: pass --cache-dir or set TPU_COMPILE_CACHE "
-            "(or JAX_COMPILATION_CACHE_DIR)"
-        )
-    return d
+    return arg or compile_cache_path()
 
 
 def _plan_rows(doc: object) -> list[dict]:

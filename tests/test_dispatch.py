@@ -315,7 +315,6 @@ def test_two_process_dispatch_demo_boots():
     procs = []
     for pid in range(2):
         env = dict(os.environ)
-        env.pop("_GRAFT_VMESH_CHILD", None)
         # children size their own 2-device CPU platform
         env["XLA_FLAGS"] = _HOST_COUNT_RE.sub("", env.get("XLA_FLAGS", "")).strip()
         env["JAX_COORDINATOR_ADDRESS"] = f"127.0.0.1:{coord_port}"

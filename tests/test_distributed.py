@@ -104,7 +104,6 @@ def test_two_process_jax_distributed_decode():
     procs = []
     for pid in range(2):
         env = dict(os.environ)
-        env.pop("_GRAFT_VMESH_CHILD", None)
         env["JAX_COORDINATOR_ADDRESS"] = f"127.0.0.1:{port}"
         env["JAX_NUM_PROCESSES"] = "2"
         env["JAX_PROCESS_ID"] = str(pid)

@@ -668,28 +668,82 @@ def test_engine_serves_under_virtual_mesh(mesh_shape, model):
         eng.shutdown()
 
 
-def test_soak_churn_parity():
+# Teacher-forced regret a greedy token may carry and still count as the
+# reference's choice. With the int8 cache a chunk reads its PAST quantized and
+# its own rows exact, so where the token-budget scheduler cuts the chunks
+# moves the logits by the quantization noise: measured 0.007 and 0.015 on the
+# two knife-edge cases of this soak (26, 46: top-2 margins 0.003 and 0.002),
+# 0.0 on every other token. Another request's logits miss by the spread of the
+# logits themselves (> 0.5 here, asserted below).
+_SOAK_TIE = 0.05
+
+
+@pytest.mark.parametrize("kv_quant", ["", "int8"])
+def test_soak_churn_parity(kv_quant):
     """Soak: 60 requests with mixed prompt families (shared prefixes, long
     chunked prompts, unique shorts), staggered lengths, high concurrency —
     through an engine running ALL round-3 SCHEDULING machinery at once
     (pipelined loop, fast finish-scan, slot compaction, prefix cache,
-    batched chunked prefill). Every output must match a one-slot
-    sequential engine with that machinery off — int8 KV stays ON in both
-    (identical numerics isolate the scheduling; int8-vs-f32 accuracy is
-    test_quant's job): any cross-request cache corruption, slot-reuse
-    race, or stale-emission bug under churn shows up as a text diff."""
+    batched chunked prefill), against a one-slot sequential engine with that
+    machinery off and the same cache dtype: any cross-request cache
+    corruption, slot-reuse race, or stale-emission bug under churn shows up
+    as a text diff.
+
+    With the float cache the numerics do not depend on where the scheduler
+    cuts a prompt into chunks, so every text must match EXACTLY. With the
+    int8 cache they do (see _SOAK_TIE), and the cuts follow the scheduler's
+    measured costs — under compile stalls on an empty cache they move — so a
+    text that differs must still be the reference model's greedy choice at
+    every token to within _SOAK_TIE, teacher-forced through the plain float
+    forward. int8-vs-float accuracy itself is test_quant's job."""
+    import jax
+
+    from llm_mcp_tpu.models.llama import llama_prefill
+
+    S = 192
     # max_slots=16 with the pow2 floor of 8 keeps the compact bucket
     # strictly below B at partial occupancy, so compaction really engages
     full = GenerationEngine(
-        "tiny-llm", max_slots=16, max_seq_len=192, dtype=jnp.float32,
-        decode_chunk=4, kv_quant="int8", prefill_chunk=32,
+        "tiny-llm", max_slots=16, max_seq_len=S, dtype=jnp.float32,
+        decode_chunk=4, kv_quant=kv_quant, prefill_chunk=32,
         prompt_cache_mb=64, decode_compact="on", admit_batch=4, seed=11,
-    ).start()
+    )
     plain = GenerationEngine(
-        "tiny-llm", max_slots=1, max_seq_len=192, dtype=jnp.float32,
-        decode_chunk=4, kv_quant="int8", prefill_chunk=0,
+        "tiny-llm", max_slots=1, max_seq_len=S, dtype=jnp.float32,
+        decode_chunk=4, kv_quant=kv_quant, prefill_chunk=0,
         prompt_cache_mb=0, decode_compact="off", seed=11,
     ).start()
+    # the token ids behind each text (the byte tokenizer's text is lossy)
+    emitted: dict[tuple, list[int]] = {}
+    process_token = full._process_token
+
+    def tap(s, tok, pos):
+        emitted.setdefault(tuple(s.req.prompt_ids), []).append(tok)
+        return process_token(s, tok, pos)
+
+    full._process_token = tap
+    full.start()
+
+    allowed = np.asarray(full._allowed_mask)
+    forward = jax.jit(
+        lambda params, tokens, lengths: llama_prefill(
+            full.cfg, params, tokens, lengths
+        )[0]
+    )
+
+    def regrets(ids, toks):
+        """How far each of `toks` sits under the reference's best allowed
+        logit when `ids + toks` is teacher-forced through the plain forward."""
+        seq = list(ids) + list(toks)
+        tokens = np.zeros((16, S), np.int32)  # max_tokens <= 9 here
+        lengths = np.ones((16,), np.int32)
+        for k in range(len(toks)):
+            tokens[k, : len(ids) + k] = seq[: len(ids) + k]
+            lengths[k] = len(ids) + k
+        logits = np.asarray(forward(full.params, tokens, lengths))
+        logits = np.where(allowed[None], logits, -np.inf)
+        return [float(logits[k].max() - logits[k, t]) for k, t in enumerate(toks)]
+
     try:
         shared_a = "system preamble alpha for the soak test run. " * 2
         shared_b = "different preamble bravo with its own words here. "
@@ -712,9 +766,18 @@ def test_soak_churn_parity():
 
         with cf.ThreadPoolExecutor(max_workers=len(cases)) as ex:
             results = list(ex.map(run_one, range(len(cases))))
+        ids = [tuple(full.tokenizer.encode(p)) for p, _ in cases]
         for i, (p, n) in enumerate(cases):
             want = plain.generate(p, max_tokens=n, temperature=0.0)["text"]
-            assert results[i] == want, (i, p[:40], results[i], want)
+            if results[i] == want:
+                continue
+            assert kv_quant, (i, p[:40], results[i], want)
+            worst = max(regrets(ids[i], emitted[ids[i]]))
+            assert worst <= _SOAK_TIE, (i, p[:40], results[i], want, worst)
+        # the adjudication has teeth: another family's tokens are far from
+        # this prompt's greedy path
+        assert max(regrets(ids[2], emitted[ids[3]])) > 10 * _SOAK_TIE
+        assert max(regrets(ids[3], emitted[ids[0]])) > 10 * _SOAK_TIE
         assert full.prefix_cache_hits >= 10  # the cache really engaged
         assert full.total_errors == 0
     finally:

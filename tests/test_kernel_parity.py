@@ -405,9 +405,9 @@ def test_append_q8_kernel_parity(monkeypatch):
     out_k, out_v = A.append_kv_q8(
         ck, cv, nk, nv, lens, slot_ids=ids, interpret=True
     )
-    monkeypatch.setattr(A, "_HAS_PLTPU", False)
-    A.append_kv_q8.clear_cache()  # the gate is read at trace time
-    ref_k, ref_v = A.append_kv_q8(ck, cv, nk, nv, lens, slot_ids=ids)
+    # jitted like the kernel wrapper: eager quantization rounds a value or
+    # two differently than the fused program does
+    ref_k, ref_v = jax.jit(A.append_kv_q8_reference)(ck, cv, nk, nv, lens, slot_ids=ids)
     np.testing.assert_array_equal(np.asarray(out_k["q"]), np.asarray(ref_k["q"]))
     np.testing.assert_array_equal(np.asarray(out_k["s"]), np.asarray(ref_k["s"]))
     assert out_v == ref_v == {}
@@ -423,9 +423,7 @@ def test_append_bf16_kernel_parity(monkeypatch):
     lens = jnp.asarray([15, S, 16], jnp.int32)  # tile boundary + parked row
     ids = jnp.asarray([1, 2, 0], jnp.int32)
     out_k, out_v = A.append_kv_bf16(ck, cv, nk, nv, lens, slot_ids=ids, interpret=True)
-    monkeypatch.setattr(A, "_HAS_PLTPU", False)
-    A.append_kv_bf16.clear_cache()
-    ref_k, ref_v = A.append_kv_bf16(ck, cv, nk, nv, lens, slot_ids=ids)
+    ref_k, ref_v = A.append_kv_bf16_reference(ck, cv, nk, nv, lens, slot_ids=ids)
     np.testing.assert_array_equal(np.asarray(out_k), np.asarray(ref_k))
     np.testing.assert_array_equal(np.asarray(out_v), np.asarray(ref_v))
 
@@ -616,8 +614,7 @@ KERNEL_PARITY = {
     "_attend_q8_paged_kernel": ("tests/test_kernel_parity.py", "test_q8_gqa_paged_parity"),
     "_attend_bf16_paged_kernel": ("tests/test_kernel_parity.py", "test_bf16_gqa_paged_parity"),
     "_attend_q8_mla_paged_kernel": ("tests/test_kernel_parity.py", "test_mla_paged_parity"),
-    "_ragged_prefill_bf16_kernel": ("tests/test_kernel_parity.py", "test_ragged_prefill_bf16_parity"),
-    "_ragged_prefill_q8_kernel": ("tests/test_kernel_parity.py", "test_ragged_prefill_q8_parity"),
+    "_ragged_prefill_gqa_kernel": ("tests/test_kernel_parity.py", "test_ragged_prefill_q8_parity"),
     "_ragged_prefill_mla_kernel": ("tests/test_kernel_parity.py", "test_ragged_prefill_mla_parity"),
 }
 

@@ -280,12 +280,13 @@ def test_roofline_without_shape_returns_no_layouts():
     assert r["layouts"] == {} and "decode_mbu" not in r
 
 
-def test_roofline_four_layouts_against_one_measured_rate(monkeypatch):
-    monkeypatch.delenv("TPU_PEAK_TFLOPS", raising=False)
-    monkeypatch.delenv("TPU_PEAK_HBM_GBPS", raising=False)
+V5E = "TPU v5 lite"  # what jax.devices()[0].device_kind says on a v5e
+
+
+def test_roofline_four_layouts_against_one_measured_rate():
     obs = PerfObservatory(
         SHAPE, active_layout="gqa_int8", paged=True, block_tokens=16,
-        weight_bytes_per_param=1.0,
+        weight_bytes_per_param=1.0, device_kind=V5E,
     )
     # 100 sampled decode tokens over 10ms of device wall -> 10k tok/s
     obs.observe_phase("decode", 0.001, 0.010, tokens=100, rows=4,
@@ -313,16 +314,24 @@ def test_roofline_four_layouts_against_one_measured_rate(monkeypatch):
     assert r["decode_mbu"] == pytest.approx(
         r["layouts"]["gqa_int8"]["mbu"], abs=1e-4
     )
-    assert r["peak_tflops"] == perf.DEFAULT_PEAK_TFLOPS
-    assert r["peak_hbm_gbps"] == perf.DEFAULT_PEAK_HBM_GBPS
+    assert (r["peak_tflops"], r["peak_hbm_gbps"]) == perf.chip_peaks(V5E)
+    assert r["device_kind"] == V5E
 
 
-def test_roofline_peaks_read_env_dynamically(monkeypatch):
-    obs = PerfObservatory(SHAPE)
-    obs.observe_phase("decode", 0.0, 0.010, tokens=100, rows=1, ctx_mean=64.0)
-    base = obs.roofline()["decode_mbu"]
-    monkeypatch.setenv("TPU_PEAK_HBM_GBPS", "409.5")  # half the bandwidth...
-    assert obs.roofline()["decode_mbu"] == pytest.approx(2 * base, rel=1e-3)
+def test_roofline_peaks_are_keyed_by_device_kind():
+    """No default chip: a kind without published peaks (the CPU, a bare
+    observatory) gets the counts from shapes and NO utilization, and asking
+    for its peaks outright is an error."""
+    for kind in ("", "cpu", "TPU v9"):
+        obs = PerfObservatory(SHAPE, device_kind=kind)
+        obs.observe_phase("decode", 0.0, 0.010, tokens=100, rows=1, ctx_mean=64.0)
+        r = obs.roofline()
+        assert "decode_mbu" not in r and "peak_hbm_gbps" not in r
+        for v in r["layouts"].values():
+            assert v["hbm_bytes_per_token"] > 0 and "mbu" not in v
+        with pytest.raises(KeyError, match="no published peaks"):
+            perf.chip_peaks(kind)
+    assert perf.chip_peaks(V5E) == (197.0, 819.0)
 
 
 def test_stats_document_shape():
@@ -566,7 +575,8 @@ def test_debug_perf_endpoint_full_document(base):
     assert set(rf["layouts"]) == set(CACHE_LAYOUTS)
     assert rf["active_layout"] in CACHE_LAYOUTS
     assert rf["device_tok_per_s"] > 0
-    assert rf["decode_mfu"] >= 0 and rf["decode_mbu"] >= 0
+    # a CPU run counts; it has no utilization of a chip to report
+    assert rf["device_kind"] == "cpu" and "decode_mfu" not in rf
     assert doc["sample_every"] == 1.0
     assert doc["itl"]["samples"] > 0 and doc["itl"]["p50_ms"] >= 0
     assert doc["goodput"]["finished_requests"] >= 1

@@ -482,10 +482,16 @@ def _bench(name):
     return os.path.join(REPO, name)
 
 
+# the r04 (healthy) and r05 (regressed) captures the gate was written
+# against: harness-capture shape, the record line only (tests/fixtures/)
+R04 = os.path.join("tests", "fixtures", "gate_record_r04.json")
+R05 = os.path.join("tests", "fixtures", "gate_record_r05.json")
+
+
 def test_extract_record_from_harness_capture():
     import json
 
-    with open(_bench("BENCH_r05.json")) as f:
+    with open(_bench(R05)) as f:
         rec = gate.extract_record(json.load(f))
     assert rec["value"] == pytest.approx(464.7)
     assert rec["p95_ttft_ms"] == pytest.approx(15664.7)
@@ -497,7 +503,7 @@ def test_extract_record_from_harness_capture():
 def test_gate_catches_r05_against_baseline(capsys):
     """The acceptance criterion: the regressed r05 record must fail even
     against the metric-less BASELINE.json (absolute floors)."""
-    rc = gate.main([_bench("BENCH_r05.json"), _bench("BASELINE.json")])
+    rc = gate.main([_bench(R05), _bench("BASELINE.json")])
     assert rc == 1
     out = capsys.readouterr().out
     assert "[FAIL] serve_efficiency" in out
@@ -505,11 +511,11 @@ def test_gate_catches_r05_against_baseline(capsys):
 
 
 def test_gate_passes_healthy_r04_against_baseline():
-    assert gate.main([_bench("BENCH_r04.json"), _bench("BASELINE.json")]) == 0
+    assert gate.main([_bench(R04), _bench("BASELINE.json")]) == 0
 
 
 def test_gate_catches_r05_against_r04():
-    assert gate.main([_bench("BENCH_r05.json"), _bench("BENCH_r04.json")]) == 1
+    assert gate.main([_bench(R05), _bench(R04)]) == 1
 
 
 def test_gate_relative_tolerances(tmp_path):

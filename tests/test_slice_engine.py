@@ -324,7 +324,6 @@ def test_two_process_slice_serves_sse_through_core():
     procs = []
     for pid in range(2):
         env = dict(os.environ)
-        env.pop("_GRAFT_VMESH_CHILD", None)
         env["JAX_COORDINATOR_ADDRESS"] = f"127.0.0.1:{coord_port}"
         env["JAX_NUM_PROCESSES"] = "2"
         env["JAX_PROCESS_ID"] = str(pid)

@@ -1,0 +1,252 @@
+"""The main-path Pallas kernels meet the TPU's compiler, without a chip.
+
+Interpret mode enforces none of Mosaic's rules: a kernel that passes every
+interpret-mode parity test can still be refused on the chip (a width-changing
+bitcast, a block whose last two dims are no legal tile, a lane slice at a
+64-token offset, more VMEM than a kernel may use) or sit in the compiler for
+minutes. libtpu is installed here and compiles for a chip that is *described*,
+not attached, so each kernel is lowered with `interpret=False` at the widths
+of Llama-3.1-8B (Hkv=8, G=4, hd=128, L=32, 32 slots, S=2048, 64-token blocks)
+and compiled for one chip of a `v5e:2x2`. Nothing runs: this says the compiler
+accepts the kernel, nothing about results (tests/test_kernel_parity.py) or
+times (a chip run).
+
+Rules this file keeps (guide `on-chip-measurement` §2): the topology is
+described inside a module-scoped fixture, never at import; everything built
+from it is built in a fixture or a test; all of it lives in this ONE file (a
+process that loaded libtpu keeps its lock until it exits); the persistent
+compile cache is off around the compiles, so they neither read the CPU tests'
+entries nor leave entries no chip can load.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from llm_mcp_tpu.kernels import attention as A
+
+L, B, HKV, G, HD, S, BT = 32, 32, 8, 4, 128, 2048, 64
+BF, I8, I32 = jnp.bfloat16, jnp.int8, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no libtpu here, or its lock is taken
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def sd(one_chip):
+    """ShapeDtypeStruct on the described chip."""
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+def compile_for_chip(fn, *args, **jit_kw) -> str:
+    """Lower and compile for the described chip; the compiled module's text.
+    The dispatchers read their mode knobs at trace time and are themselves
+    jitted, so a stale trace from another mode must not answer."""
+    jax.clear_caches()
+    falls = dict(A.reference_falls)
+    text = jax.jit(fn, **jit_kw).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the compiled module"
+    # a shape gate that fails with interpret=False answers with the XLA
+    # reference math and counts it: THIS compile must not have
+    assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
+    return text
+
+
+def q8_cache(sd, n=B):
+    return {"q": sd((L, n, 2 * HKV + 1, S, HD), I8), "s": sd((L, n, 2 * HKV, S), BF)}
+
+
+def q8_pool(sd, rows=64):
+    return {"q": sd((L, rows, 2 * HKV + 1, BT, HD), I8), "s": sd((L, rows, 2 * HKV, BT), BF)}
+
+
+def decode_operands(sd, ba):
+    return (sd((ba, HKV, G, HD), BF), sd((ba, HKV, HD), BF), sd((ba, HKV, HD), BF))
+
+
+def test_flash_prefill_attention(sd):
+    compile_for_chip(
+        lambda q, k, v, n: A.flash_prefill_attention(q, k, v, n, interpret=False),
+        sd((4, HKV * G, 512, HD), BF), sd((4, HKV, 512, HD), BF),
+        sd((4, HKV, 512, HD), BF), sd((4,), I32),
+    )
+
+
+@pytest.mark.parametrize("mode", ["whole", "blocked", "auto"])
+def test_decode_attend_q8(sd, monkeypatch, mode):
+    """`auto` is the default and builds BOTH arms under one lax.cond: an arm
+    the compiler refuses takes the default int8 decode step down with it (the
+    packed-scale unpack's int8->bf16 bitcast did exactly that)."""
+    monkeypatch.setenv("LLM_MCP_TPU_Q8_DECODE", mode)
+    ba = 8  # the compact ladder's first rung
+    compile_for_chip(
+        lambda q, nk, nv, ck, li, n, ids: A.decode_attend_q8(
+            q, nk, nv, ck, {}, li, n, slot_ids=ids, interpret=False),
+        *decode_operands(sd, ba), q8_cache(sd), sd((), I32), sd((ba,), I32), sd((ba,), I32),
+    )
+
+
+def test_decode_attend_q8_paged(sd, monkeypatch):
+    """The engine's default: physical paging on, so the decode step holds the
+    contiguous hybrid AND the block-indirect arm under the identity cond."""
+    monkeypatch.delenv("LLM_MCP_TPU_Q8_DECODE", raising=False)
+    ba = 32
+    compile_for_chip(
+        lambda q, nk, nv, ck, li, n, ids, tbl, pool: A.decode_attend_q8(
+            q, nk, nv, ck, {}, li, n, slot_ids=ids, block_tables=tbl, pool_k=pool,
+            interpret=False),
+        *decode_operands(sd, ba), q8_cache(sd), sd((), I32), sd((ba,), I32), sd((ba,), I32),
+        sd((B, S // BT), I32), q8_pool(sd),
+    )
+
+
+def test_append_kv_q8(sd):
+    ba = 8
+    compile_for_chip(
+        lambda ck, nk, nv, n, ids: A.append_kv_q8(ck, {}, nk, nv, n, slot_ids=ids, interpret=False),
+        q8_cache(sd), sd((L, ba, HKV, HD), BF), sd((L, ba, HKV, HD), BF),
+        sd((ba,), I32), sd((ba,), I32), donate_argnums=(0,),
+    )
+
+
+@pytest.mark.parametrize("T", [32, 2048])
+def test_ragged_prefill_attend_q8(sd, T):
+    """Both ends of the packed-length ladder, paged (64-token blocks), R = the
+    default admit batch. Compile time must not grow with T or R: the first
+    form of this kernel took 18 s at T=32 and never finished at T=512."""
+    R = 4
+    compile_for_chip(
+        lambda q, ks, vs, ck, li, rid, off, sl, st, tbl, pool: A.ragged_prefill_attend_q8(
+            q, ks, vs, ck, li, rid, off, sl, st, block_tables=tbl, pool=pool,
+            impl="kernel", interpret=False),
+        sd((T, HKV, G, HD), BF), sd((T, HKV, HD), BF), sd((T, HKV, HD), BF), q8_cache(sd),
+        sd((), I32), sd((T,), I32), sd((R + 1,), I32), sd((R,), I32), sd((R,), I32),
+        sd((B, S // BT), I32), q8_pool(sd),
+    )
+
+
+def test_ragged_prefill_attend_bf16(sd):
+    T, R, n = 512, 4, 8
+    kv = sd((L, n, HKV, S, HD), BF)
+    pool = sd((L, 16, HKV, BT, HD), BF)
+    compile_for_chip(
+        lambda q, ks, vs, ck, cv, li, rid, off, sl, st, tbl, pk, pv: A.ragged_prefill_attend_bf16(
+            q, ks, vs, ck, cv, li, rid, off, sl, st, block_tables=tbl, pool_k=pk,
+            pool_v=pv, impl="kernel", interpret=False),
+        sd((T, HKV, G, HD), BF), sd((T, HKV, HD), BF), sd((T, HKV, HD), BF), kv, kv,
+        sd((), I32), sd((T,), I32), sd((R + 1,), I32), sd((R,), I32), sd((R,), I32),
+        sd((n, S // BT), I32), pool, pool,
+    )
+
+
+def test_decode_attend_bf16(sd, monkeypatch):
+    monkeypatch.delenv("LLM_MCP_TPU_Q8_DECODE", raising=False)
+    ba, n = 8, 8  # a bf16 cache at these widths holds fewer slots
+    kv = sd((L, n, HKV, S, HD), BF)
+    compile_for_chip(
+        lambda q, nk, nv, ck, cv, li, m, ids: A.decode_attend_bf16(
+            q, nk, nv, ck, cv, li, m, slot_ids=ids, interpret=False),
+        *decode_operands(sd, ba), kv, kv, sd((), I32), sd((ba,), I32), sd((ba,), I32),
+    )
+
+
+def test_append_kv_bf16(sd):
+    ba, n = 8, 8
+    kv = sd((L, n, HKV, S, HD), BF)
+    compile_for_chip(
+        lambda ck, cv, nk, nv, m, ids: A.append_kv_bf16(ck, cv, nk, nv, m, slot_ids=ids, interpret=False),
+        kv, kv, sd((L, ba, HKV, HD), BF), sd((L, ba, HKV, HD), BF),
+        sd((ba,), I32), sd((ba,), I32), donate_argnums=(0, 1),
+    )
+
+
+def test_decode_attend_q8_mla(sd, monkeypatch):
+    """Latent attention at DeepSeek-V2-Lite's widths (kv_lora_rank 512, rope
+    64, 16 heads), default mode."""
+    monkeypatch.delenv("LLM_MCP_TPU_Q8_DECODE", raising=False)
+    ba, n, H, R, dr, Lm = 8, 32, 16, 512, 64, 27
+    cc = {"q": sd((Lm, n, 1, S, R), I8), "s": sd((Lm, n, 1, S), BF)}
+    cr = {"q": sd((Lm, n, 1, S, dr), I8), "s": sd((Lm, n, 1, S), BF)}
+    compile_for_chip(
+        lambda qt, qr, nc, nr, cc, cr, li, m, ids: A.decode_attend_q8_mla(
+            qt, qr, nc, nr, cc, cr, li, m, slot_ids=ids, scale=(128 + dr) ** -0.5,
+            interpret=False),
+        sd((ba, H, R), BF), sd((ba, H, dr), BF), sd((ba, R), BF), sd((ba, dr), BF),
+        cc, cr, sd((), I32), sd((ba,), I32), sd((ba,), I32),
+    )
+
+
+def test_ragged_prefill_attend_mla(sd):
+    """Ragged latent-attention prefill, int8 latents, paged, V2-Lite widths."""
+    T, Rn, n, H, R, dr, Lm = 512, 4, 32, 16, 512, 64, 27
+    cc = {"q": sd((Lm, n, 1, S, R), I8), "s": sd((Lm, n, 1, S), BF)}
+    cr = {"q": sd((Lm, n, 1, S, dr), I8), "s": sd((Lm, n, 1, S), BF)}
+    pc = {"q": sd((Lm, 16, 1, BT, R), I8), "s": sd((Lm, 16, 1, BT), BF)}
+    pr = {"q": sd((Lm, 16, 1, BT, dr), I8), "s": sd((Lm, 16, 1, BT), BF)}
+    compile_for_chip(
+        lambda qt, qr, c, kr, cc, cr, li, rid, off, sl, st, tbl, pc, pr:
+        A.ragged_prefill_attend_mla(
+            qt, qr, c, kr, cc, cr, li, rid, off, sl, st, scale=(128 + dr) ** -0.5,
+            block_tables=tbl, pool_c=pc, pool_r=pr, impl="kernel", interpret=False),
+        sd((T, H, R), BF), sd((T, H, dr), BF), sd((T, R), BF), sd((T, dr), BF), cc, cr,
+        sd((), I32), sd((T,), I32), sd((Rn + 1,), I32), sd((Rn,), I32), sd((Rn,), I32),
+        sd((n, S // BT), I32), pc, pr,
+    )
+
+
+def test_a_fall_to_the_reference_is_counted():
+    """What `compile_for_chip` and chip_smoke.py's zero-fall check stand on: a
+    shape gate that fails with interpret=False is counted and lands in the
+    flight recorder; interpret mode, which takes the exact math by design, is
+    not. hd=64 is not lane-aligned, so append_kv_q8 takes its scatter."""
+    from llm_mcp_tpu.telemetry.recorder import get_recorder
+
+    n, hd = 4, 64
+    ck = {"q": jax.ShapeDtypeStruct((2, n, 2 * HKV + 1, 128, hd), I8),
+          "s": jax.ShapeDtypeStruct((2, n, 2 * HKV, 128), BF)}
+    new = jax.ShapeDtypeStruct((2, n, HKV, hd), BF)
+    lens = jax.ShapeDtypeStruct((n,), I32)
+
+    def trace(interpret):
+        jax.eval_shape(
+            lambda ck, nk, nv, n: A.append_kv_q8(ck, {}, nk, nv, n, interpret=interpret),
+            ck, new, new, lens)
+
+    falls = dict(A.reference_falls)
+    events = len(get_recorder().snapshot(etype="kernel_fall"))
+    try:
+        trace(True)
+        assert A.reference_falls == falls
+        trace(False)
+        assert A.reference_falls == {**falls, "append_kv_q8": falls.get("append_kv_q8", 0) + 1}
+        assert len(get_recorder().snapshot(etype="kernel_fall")) == events + 1
+    finally:  # the table is the process's: leave it as the other tests expect it
+        A.reference_falls.clear()
+        A.reference_falls.update(falls)

@@ -496,3 +496,43 @@ def test_join_mid_window_real_engines_serve_from_fetched_blocks(monkeypatch):
         a.shutdown()
         if b is not None:
             b.shutdown()
+
+
+def test_warmup_compiles_every_plannable_phase_under_a_mesh():
+    """A sharded engine's AOT warmup compiles must lower: the small per-slot
+    arrays live on the default device, uncommitted, and a ShapeDtypeStruct
+    that pinned them to device 0 beside mesh-sharded weights made EVERY
+    warmup compile of the tp=4 engine fail on the four-chip host
+    ("incompatible devices"), so the serve path paid all of them cold. Also
+    pins that weights and cache are born sharded (no whole tree on one
+    device) and that the engine's `xla` ragged choice reaches the model."""
+    import dataclasses
+
+    from jax.sharding import NamedSharding
+
+    from llm_mcp_tpu.executor import GenerationEngine
+    from llm_mcp_tpu.models.configs import resolve_config
+    from llm_mcp_tpu.parallel.mesh import make_mesh
+
+    import jax
+
+    cfg = dataclasses.replace(
+        resolve_config("tiny-llm", ""), name="tiny-tp4", n_heads=8, n_kv_heads=4)
+    mesh = make_mesh("tp=4", devices=jax.devices()[:4])  # 4 of the 8 virtual devices
+    eng = GenerationEngine(cfg, mesh=mesh, max_slots=4, max_seq_len=128,
+                           dtype=jnp.float32, decode_chunk=4)
+    try:
+        for leaf in jax.tree.leaves((eng.params, eng._ck, eng._cv)):
+            assert isinstance(leaf.sharding, NamedSharding)
+            assert len(leaf.sharding.device_set) == 4
+        assert eng._ragged_impl == "xla"
+        phases = {ph for ph, _ in eng.warmup_shape_zoo()}
+        assert {"admit", "decode", "pf_rag"} <= phases
+        done = set()
+        for ph, key in eng.warmup_shape_zoo():
+            if ph in done:
+                continue
+            assert eng.warmup_compile(ph, key) is not None, (ph, key)
+            done.add(ph)
+    finally:
+        eng.shutdown()
