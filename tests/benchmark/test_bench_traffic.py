@@ -1,0 +1,231 @@
+"""The traffic generator, the load generator's child and the reduction from
+its records to the end-to-end metrics."""
+
+import http.server
+import json
+import os
+import sys
+import threading
+import time
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+sys.path.insert(0, ROOT)
+
+from benchmark import loadgen, reduce, trafficgen  # noqa: E402
+
+TRAFFIC = os.path.join(ROOT, "benchmark", "traffic")
+
+
+def traffic(name):
+    if name == "chat_open":
+        return dict(CHAT_OPEN)
+    with open(os.path.join(TRAFFIC, name + ".json")) as f:
+        return json.load(f)
+
+
+# the open-loop chat mix ISSUE.md specifies; no cell carries it yet (PERF.md
+# section 7), the generator and the child must handle it all the same
+CHAT_OPEN = {
+    "endpoint": "chat", "loop": "open", "rate_per_s": 3.0,
+    "prompt_tokens": {"dist": "lognormal", "median": 256, "sigma": 0.9, "lo": 32, "hi": 1536},
+    "max_tokens": {"dist": "lognormal", "median": 96, "sigma": 0.7, "lo": 16, "hi": 384},
+    "temperature": 0.7, "preroll_s": 8, "warmup_s": 12,
+}
+MIXES = sorted(f[:-5] for f in os.listdir(TRAFFIC) if f.endswith(".json")) + ["chat_open"]
+
+
+@pytest.mark.parametrize("name", MIXES)
+def test_plan_is_a_function_of_the_seed(name):
+    t = traffic(name)
+    a = trafficgen.make_plan(t, 3_000_000_001, 20, model="m")
+    b = trafficgen.make_plan(t, 3_000_000_001, 20, model="m")
+    c = trafficgen.make_plan(t, 3_000_000_002, 20, model="m")
+    assert json.dumps(a) == json.dumps(b)
+    assert json.dumps(a) != json.dumps(c)
+    texts = [trafficgen.text(*r["prompt"][0], f"r{r['i']}") for r in a["requests"][:50]]
+    assert texts == [trafficgen.text(*r["prompt"][0], f"r{r['i']}") for r in b["requests"][:50]]
+    assert all(len(x.encode()) == r["prompt"][0][0] for x, r in zip(texts, a["requests"]))
+    assert len({x.split()[0] for x in texts}) == len(texts)  # no shared first word
+    # the words after the first follow from the size's rank alone, whatever the seed
+    assert trafficgen.text(64, 7, "r1").split()[1:6] == trafficgen.text(64, 7, "w0x9").split()[1:6]
+
+
+@pytest.mark.parametrize("name", MIXES)
+def test_every_seed_offers_the_same_work_in_another_order(name):
+    t = traffic(name)
+    a = trafficgen.make_plan(t, 11, 20, model="m")
+    b = trafficgen.make_plan(t, 2**31 + 5, 20, model="m")
+    assert len(a["requests"]) == len(b["requests"])
+
+    def flat(p, key):  # prompts as (size, rank): the same texts under every seed
+        return sum(([tuple(x) for x in r[key]] if key == "prompt" else [r[key]] for r in p["requests"]), [])
+
+    # an open loop deals one deck to the pre-roll and one to the window; a
+    # closed loop deals whole decks of DECK sizes, the first requests'
+    # completion lengths staggered
+    D = trafficgen.DECK
+    cut = (slice(None), slice(None)) if a["loop"] == "open" else (slice(0, D), slice(D, 2 * D))
+    assert sorted(flat(a, "prompt")[cut[0]]) == sorted(flat(b, "prompt")[cut[0]])
+    assert sorted(flat(a, "max_tokens")[cut[1]]) == sorted(flat(b, "max_tokens")[cut[1]])
+    assert flat(a, "prompt")[cut[0]] != flat(b, "prompt")[cut[0]]
+
+
+def test_open_loop_due_times_are_fixed_before_any_request_is_sent():
+    t = traffic("chat_open")
+    p = trafficgen.make_plan(t, 5, 30, model="m")
+    pre, rate = p["preroll_s"], t["rate_per_s"]
+    due = [r["due"] for r in p["requests"]]
+    assert due == sorted(due) and due[0] >= 0 and due[-1] < pre + 30
+    assert sum(1 for d in due if d >= pre) == round(rate * 30)  # the same count under every seed
+    assert sum(1 for d in due if d < pre) == round(rate * pre)
+
+
+def test_distributions_keep_their_limits():
+    d = {"dist": "lognormal", "median": 256, "sigma": 0.9, "lo": 32, "hi": 1536}
+    import random
+    sizes = trafficgen.size_deck(d, 200, random.Random(0))
+    assert min(sizes) >= 32 and max(sizes) <= 1536
+    assert 230 <= sorted(sizes)[100] <= 280
+
+
+class _Stub(http.server.BaseHTTPRequestHandler):
+    """An OpenAI-shaped server that streams three deltas 20 ms apart, slowly
+    enough to first byte that a closed loop and an open loop tell apart."""
+    protocol_version = "HTTP/1.1"
+    delay_s = 0.15
+
+    def log_message(self, *a):
+        pass
+
+    def do_POST(self):
+        body = json.loads(self.rfile.read(int(self.headers["Content-Length"])))
+        if self.path == "/v1/embeddings":
+            dims = 8  # whatever was asked for
+            vec = [1.0] + [0.0] * (dims - 1)
+            raw = json.dumps({"data": [{"embedding": vec} for _ in body["input"]]}).encode()
+            self.send_response(200)
+            self.send_header("Content-Length", str(len(raw)))
+            self.end_headers()
+            self.wfile.write(raw)
+            return
+        self.send_response(200)
+        self.send_header("Content-Type", "text/event-stream")
+        self.send_header("Connection", "close")
+        self.end_headers()
+        time.sleep(self.delay_s)
+
+        def send(obj):
+            self.wfile.write(b"data: " + (obj if isinstance(obj, bytes) else json.dumps(obj).encode()) + b"\n\n")
+            self.wfile.flush()
+
+        send({"choices": [{"delta": {"role": "assistant"}, "finish_reason": None}]})
+        for _ in range(3):
+            send({"choices": [{"delta": {"content": "ab"}, "finish_reason": None}]})
+            time.sleep(0.02)
+        send({"choices": [{"delta": {}, "finish_reason": "length"}],
+              "usage": {"completion_tokens": 6, "prompt_tokens": 40}})
+        send(b"[DONE]")
+        self.close_connection = True
+
+
+@pytest.fixture()
+def stub():
+    srv = http.server.ThreadingHTTPServer(("127.0.0.1", 0), _Stub)
+    th = threading.Thread(target=srv.serve_forever, daemon=True)
+    th.start()
+    yield srv.server_address[1]
+    srv.shutdown()
+    th.join(5)
+    srv.server_close()
+
+
+def test_open_loop_sends_on_schedule_whatever_the_server_does(stub):
+    t = dict(traffic("chat_open"), rate_per_s=20.0, preroll_s=0.0)
+    plan = trafficgen.make_plan(t, 3, 1.0, model="m")
+    plan.update(port=stub, t_start=time.monotonic() + 0.1, stop_s=1.0, timeout_s=10.0)
+    recs = loadgen.run(plan)
+    assert len(recs) == 20 and all(reduce.ok(r) for r in recs)
+    late = reduce.late_ms(recs, (0.0, 1.0))
+    assert len(late) == 20 and max(late) < 100.0  # due times held though each reply takes 210 ms
+    ttft = reduce.ttfts_ms(recs, (0.0, 1.0), 1e6)
+    assert all(140.0 < v < 400.0 for v in ttft)
+    assert all(r["trace"] == loadgen.trace_id(3, r["i"]) and len(r["trace"]) == 32 for r in recs)
+    assert all(r["completion_tokens"] == 6 and len(r["events"]) == 3 for r in recs)
+
+
+def test_closed_loop_waits_for_each_reply(stub):
+    t = dict(traffic("decode_closed"), clients=2, preroll_s=0.0)
+    plan = trafficgen.make_plan(t, 3, 1.0, model="m")
+    plan.update(port=stub, t_start=time.monotonic() + 0.05, stop_s=1.0, timeout_s=10.0)
+    recs = loadgen.run(plan)
+    assert 6 <= len(recs) <= 10  # 2 clients, about 0.22 s a reply, one second
+    assert [r["i"] for r in recs] == list(range(len(recs)))
+    assert all(r["sent"] < 1.0 for r in recs)
+
+
+def test_embedding_replies_are_checked_by_the_child(stub):
+    t = dict(traffic("embed_batch"), clients=1, inputs_per_request=3, dimensions=8, preroll_s=0.0)
+    plan = trafficgen.make_plan(t, 3, 0.3, model="m")
+    plan.update(port=stub, t_start=time.monotonic() + 0.05, stop_s=0.3, timeout_s=10.0)
+    recs = loadgen.run(plan)
+    assert recs and all(reduce.ok(r) and r["inputs"] == 3 for r in recs)
+    plan["dimensions"] = 16  # the server answers 8 wide: every vector is bad
+    plan.update(t_start=time.monotonic() + 0.05)
+    assert all(r["bad"] == 3 and not reduce.ok(r) for r in loadgen.run(plan))
+
+
+def _chat(i, due, events, tokens, status=200):
+    return {"i": i, "due": due, "sent": due + 0.001, "status": status, "events": events,
+            "done": (events[-1] + 0.01) if events else None, "finish": "length" if events else None,
+            "completion_tokens": tokens, "prompt_tokens": 10, "error": "", "trace": str(i)}
+
+
+def test_reduce_arithmetic():
+    w = (10.0, 20.0)
+    recs = [
+        _chat(0, 9.0, [9.5, 10.5, 11.5], 30),        # due before the window: no TTFT, half its tokens
+        _chat(1, 12.0, [12.25, 12.5, 12.75, 13.0], 8),
+        _chat(2, 19.0, [19.5, 20.5], 10),            # straddles the end: half its tokens
+        _chat(3, 15.0, [], 0, status=429),           # shed: counts as a miss
+    ]
+    assert reduce.ttfts_ms(recs, w, 99_000.0) == pytest.approx([250.0, 500.0, 99_000.0])
+    gaps = reduce.gaps_ms(recs, w)
+    assert sorted(gaps) == pytest.approx([250.0, 250.0, 250.0, 1000.0, 1000.0])
+    assert reduce.out_tokens_per_s(recs, w) == pytest.approx((30 * 0.75 + 8 + 5) / 10.0)
+    assert reduce.percentile([1, 2, 3, 4, 5], 0.5) == 3 and reduce.percentile([0, 10], 0.95) == 9.5
+    # a stream whose first sampled token was EOS: no content, an honest stop
+    empty = dict(_chat(4, 16.0, [], 0), done=16.4, finish="stop")
+    assert reduce.ok(empty) and not reduce.ok(dict(empty, finish=None))
+    assert reduce.ttfts_ms([empty], w, 99_000.0) == pytest.approx([400.0])
+    assert reduce.out_tokens_per_s(recs + [empty], w) == reduce.out_tokens_per_s(recs, w)
+    emb = [{"i": 0, "sent": 9.0, "done": 11.0, "status": 200, "inputs": 32, "bad": 0, "error": ""},
+           {"i": 1, "sent": 11.0, "done": 13.0, "status": 200, "inputs": 32, "bad": 1, "error": ""}]
+    assert reduce.embeddings_per_s(emb, w) == pytest.approx(16 / 10.0)  # the bad one does not count
+
+
+def test_readers_of_the_open_loop_cell_to_come():
+    """The readers that only an open-loop chat cell uses are in the tree for
+    the PR that brings the cell: each reads a synthetic run as it should."""
+    from benchmark import run as bench_run
+
+    w = (10.0, 20.0)
+    recs = [dict(_chat(i, 10.0 + i, [10.3 + i, 10.5 + i], 4), sent=10.002 + i) for i in range(8)]
+    recs.append(_chat(8, 18.5, [], 0, status=429))
+    rows = {str(i): {"admit_wait_ms": 10.0 * i, "prefill_queue_ms": 50.0, "prefill_compute_ms": 200.0}
+            for i in range(8)}
+    run = {"records": recs, "window": w, "miss_ms": 120e3, "waterfall_rows": rows,
+           "start": {"waterfall": {"stage_s": {"prefill_compute": 1.0}}, "scheduler": {"prefill_true_tokens": 1000.0}},
+           "end": {"waterfall": {"stage_s": {"prefill_compute": 3.0}}, "scheduler": {"prefill_true_tokens": 21000.0}}}
+
+    def read(kind, name):
+        return bench_run.load_reader(kind, name).read(run)
+
+    assert read("end_to_end", "ttft_p95_ms") == pytest.approx(reduce.percentile([300.0] * 8 + [120e3], 0.95))
+    assert read("layer_metrics", "generator_late_p95_ms") == pytest.approx(2.0)
+    assert read("layer_metrics", "shed_429") == 1.0
+    assert read("layer_metrics", "queue_wait_p95_ms") == pytest.approx(50.0 + 10.0 * 6.65)
+    # sent-to-first-delta 298 ms less the waterfall's 250 + 10 i: the median over i = 0..7
+    assert read("layer_metrics", "http_overhead_ms") == pytest.approx(298.0 - 250.0 - 35.0)
+    assert read("layer_metrics", "prefill_ms_per_ktok") == pytest.approx(100.0)
