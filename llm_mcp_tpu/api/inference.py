@@ -565,6 +565,8 @@ class InferenceAPI:
                     if not resp.sse_data(chunk):
                         sp.set_attr("client_disconnected", True)
                         return  # client went away; engine keeps finishing the slot
+                    if "t" in evt:  # the engine's stamp of its put
+                        engine.observe_stream_write(evt["t"])
                 elif evt["type"] == "done":
                     usage = evt.get("usage", {})
                     finish = evt.get("finish_reason", "stop")

@@ -872,7 +872,7 @@ class CoreServer:
             self.metrics.compile_seconds.labels(
                 engine=self.device_id,
                 phase=entry["phase"],
-                hit="hit" if entry["hit"] else "miss",
+                hit={True: "hit", False: "miss"}.get(entry["hit"], "unknown"),
             ).observe(float(entry["wall_s"]))
         if self.migration is not None:
             cst = self.migration.stats()
@@ -888,6 +888,9 @@ class CoreServer:
                 "kind": "embed",
                 "total_inputs": e.total_inputs,
                 "total_tokens": e.total_tokens,
+                # forwards, rows and tokens true and padded, seconds waiting
+                # for, inside and holding the lock around a forward
+                **e.stats(recent=False),
             }
         return info
 

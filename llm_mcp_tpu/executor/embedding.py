@@ -10,11 +10,14 @@ client-side truncation fallback (`handlers.go:2063-2078`).
 from __future__ import annotations
 
 import threading
+import time
+from collections import deque
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..models.configs import ModelConfig, resolve_config
 from ..models.embedder import init_embedder_params, embed_forward
@@ -152,6 +155,21 @@ class EmbeddingEngine:
         self._lock = threading.Lock()
         self.total_inputs = 0
         self.total_tokens = 0
+        # counters behind stats(), written with the lock held: forwards
+        # run, rows asked for and rows after batch padding, tokens asked for
+        # and tokens after padding to (batch bucket x length bucket), and
+        # seconds waiting for the lock, inside a forward (call to fetched)
+        # and holding the lock outside one (staging, slicing, normalising,
+        # tolist: host work during which the chip waits)
+        self._stats: dict[str, float] = {
+            "forwards": 0, "rows": 0, "rows_padded": 0, "true_tokens": 0,
+            "padded_tokens": 0, "lock_wait_s": 0.0, "forward_s": 0.0,
+            "host_locked_s": 0.0,
+        }
+        # (time.monotonic() at the forward's start, forward_s, host_locked_s)
+        # of each forward, so that a reader can cut by its own window
+        self._recent: deque = deque(maxlen=4096)
+        self._recent_lock = threading.Lock()  # stats() copies while embed() appends
 
     def _bucket(self, n: int) -> int:
         return pow2_bucket(n, self.max_seq_len)
@@ -181,29 +199,65 @@ class EmbeddingEngine:
         total_tokens = sum(len(i) for i in all_ids)
         vectors: list[list[float]] = []
 
-        with self._lock:
+        t_ask = time.monotonic()
+        with TraceAnnotation("embed.lock_wait"):
+            self._lock.acquire()
+        try:
+            t_prev = time.monotonic()  # host time under the lock counts from here
+            st = self._stats
+            st["lock_wait_s"] += t_prev - t_ask
             for i in range(0, len(all_ids), self.max_batch):
                 chunk = all_ids[i : i + self.max_batch]
                 B = len(chunk)
-                # batch axis pads to a pow2 bucket too: without it every
-                # distinct final-chunk size compiles a fresh executable
-                # (VERDICT r2 weak #7 — B=7 vs B=8 were separate compiles);
-                # pad rows hold 1 dummy token and their vectors are dropped
-                Bb = pow2_bucket(B, self.max_batch, floor=1)
-                bucket = self._bucket(max(len(c) for c in chunk))
-                tokens = np.zeros((Bb, bucket), dtype=np.int32)
-                lengths = np.ones(Bb, dtype=np.int32)
-                for j, ids in enumerate(chunk):
-                    tokens[j, : len(ids)] = ids
-                    lengths[j] = len(ids)
-                out = np.asarray(
-                    self._fwd(self.params, tokens, lengths), dtype=np.float32
-                )[:B]
-                if dimensions and 0 < dimensions < out.shape[1]:
-                    out = out[:, :dimensions]
-                    norms = np.maximum(np.linalg.norm(out, axis=1, keepdims=True), 1e-9)
-                    out = out / norms
-                vectors.extend(out.tolist())
+                with TraceAnnotation("embed.stage"):
+                    # batch axis pads to a pow2 bucket too: without it every
+                    # distinct final-chunk size compiles a fresh executable
+                    # (VERDICT r2 weak #7 — B=7 vs B=8 were separate
+                    # compiles); pad rows hold 1 dummy token and their
+                    # vectors are dropped
+                    Bb = pow2_bucket(B, self.max_batch, floor=1)
+                    bucket = self._bucket(max(len(c) for c in chunk))
+                    tokens = np.zeros((Bb, bucket), dtype=np.int32)
+                    lengths = np.ones(Bb, dtype=np.int32)
+                    for j, ids in enumerate(chunk):
+                        tokens[j, : len(ids)] = ids
+                        lengths[j] = len(ids)
+                t_fwd = time.monotonic()
+                with TraceAnnotation("embed.forward"):
+                    out = np.asarray(
+                        self._fwd(self.params, tokens, lengths), dtype=np.float32
+                    )
+                t_done = time.monotonic()
+                with TraceAnnotation("embed.post"):
+                    out = out[:B]
+                    if dimensions and 0 < dimensions < out.shape[1]:
+                        out = out[:, :dimensions]
+                        norms = np.maximum(np.linalg.norm(out, axis=1, keepdims=True), 1e-9)
+                        out = out / norms
+                    vectors.extend(out.tolist())
+                t_post = time.monotonic()
+                host_s = (t_fwd - t_prev) + (t_post - t_done)
+                t_prev = t_post
+                st["forwards"] += 1
+                st["rows"] += B
+                st["rows_padded"] += Bb
+                st["true_tokens"] += int(lengths[:B].sum())
+                st["padded_tokens"] += Bb * bucket
+                st["forward_s"] += t_done - t_fwd
+                st["host_locked_s"] += host_s
+                with self._recent_lock:
+                    self._recent.append((t_fwd, t_done - t_fwd, host_s))
             self.total_inputs += len(texts)
             self.total_tokens += total_tokens
+        finally:
+            self._lock.release()
         return vectors, total_tokens
+
+    def stats(self, recent: bool = True) -> dict[str, Any]:
+        """Counters of the forwards run so far (see __init__), which
+        engines_info shows at /v1/debug/health and /v1/dashboard, and with
+        `recent` one (time.monotonic(), forward_s, host_locked_s) a forward."""
+        if not recent:
+            return dict(self._stats)
+        with self._recent_lock:
+            return {**self._stats, "recent": list(self._recent)}

@@ -46,6 +46,7 @@ from typing import Any, Iterator
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..kernels.attention import (
     pallas_supported,
@@ -78,6 +79,7 @@ from ..telemetry import perf
 from ..telemetry import recorder as flight
 from ..telemetry import tracing
 from ..telemetry import workload
+from . import compile_watch
 from .common import fine_bucket, pow2_bucket
 from .dispatch import DispatchBackend, GSPMDBackend, LocalArraysBackend
 from .drafter import NGramDrafter
@@ -303,6 +305,7 @@ class _Slot:
     # ITL accumulation, folded into the decode span and goodput ledger
     # at finish
     perf_last_emit: float = 0.0
+    last_text_t: float = 0.0  # time.monotonic() of the previous text event
     itl_s_total: float = 0.0
     itl_samples: int = 0
     # latency waterfall (telemetry/workload.py): synchronous prefill
@@ -329,6 +332,12 @@ class _DispatchedRound:
     rid: int = 0  # monotonic round id (slot-reuse cooling fence)
     prefill_tokens: int = 0  # fused chunk-group tokens (scheduler cost attribution)
     prefill_padded: int = 0  # dispatched token shape incl. pads (pad-waste EMA)
+    # for the device seconds told at the fetch (_observe_round_device):
+    phase: str = "decode"  # decode / fused / fused_rag
+    dx: int = 0  # engine._dx_n after this dispatch: rid-1's + 1 = back to back
+    t_disp: float = 0.0  # time.perf_counter() when the jit call returned
+    # (host_s, wait_s) where the perf observatory sampled this dispatch
+    sample: tuple | None = None
 
 
 @dataclass
@@ -338,6 +347,7 @@ class _PendingRound:
     out: Any  # np [K, Ba]
     entries: list  # [(b, _Slot, col)]
     base: Any
+    rid: int = 0
 
 
 @dataclass
@@ -997,7 +1007,8 @@ class GenerationEngine:
                     (ck, cv),
                 )
 
-            ck, cv = jax.lax.fori_loop(0, Ab, body, (ck, cv))
+            with jax.named_scope("kv_append"):
+                ck, cv = jax.lax.fori_loop(0, Ab, body, (ck, cv))
             # sampling params live ON DEVICE between rounds (decode gathers
             # them by slot id — never re-transferred per round). Pad rows
             # scatter to row B: out of bounds, dropped (the same invariant
@@ -1006,22 +1017,25 @@ class GenerationEngine:
             d_temp = d_temp.at[row].set(temps)
             d_topk = d_topk.at[row].set(topks)
             d_topp = d_topp.at[row].set(topps)
-            if mask_ is not None:
-                logits = jnp.where(mask_, logits, -jnp.inf)
-            # constrained admission: automaton masks + logit_bias for the
-            # FIRST sampled token. cn rides at the END defaulting to None
-            # (the paged=None pattern) so unconstrained admissions keep the
-            # exact executable traced before this subsystem existed.
-            if cn is not None:
-                logits = apply_token_mask(logits, cn[0], cn[1], cn[2])
-            key = jax.random.fold_in(base_key_, counter)
-            # pad rows duplicate garbage prompts/params — keep them out of
-            # the sampler's homogeneity reductions (fast-path selection)
-            toks0 = sample_tokens(
-                logits, key, temps, topks, topps,
-                active=jnp.arange(Ab) < live_n,
-                exact=cn is not None,
-            )
+            with jax.named_scope("sample"):
+                if mask_ is not None:
+                    logits = jnp.where(mask_, logits, -jnp.inf)
+                # constrained admission: automaton masks + logit_bias for
+                # the FIRST sampled token. cn rides at the END defaulting to
+                # None (the paged=None pattern) so unconstrained admissions
+                # keep the exact executable traced before this subsystem
+                # existed.
+                if cn is not None:
+                    logits = apply_token_mask(logits, cn[0], cn[1], cn[2])
+                key = jax.random.fold_in(base_key_, counter)
+                # pad rows duplicate garbage prompts/params — keep them out
+                # of the sampler's homogeneity reductions (fast-path
+                # selection)
+                toks0 = sample_tokens(
+                    logits, key, temps, topks, topps,
+                    active=jnp.arange(Ab) < live_n,
+                    exact=cn is not None,
+                )
             d_last = d_last.at[row].set(toks0)
             return ck, cv, d_temp, d_topk, d_topp, d_last, toks0
 
@@ -1480,6 +1494,14 @@ class GenerationEngine:
         # wall of the previous round completion: the sampled "wait" bucket
         # (scheduler/host gap between consecutive device rounds)
         self._perf_mark = time.perf_counter()
+        # the previous round's fetch, for the sampled device seconds: (rid,
+        # dx, time.perf_counter() when its read returned, and whether the
+        # read blocked: the round had not ended when the host asked)
+        self._prev_fetch: tuple[int, int, float, bool] = (0, 0, 0.0, False)
+        self._dx_n = 0  # device dispatches through _dx, all ops
+        # when the last first dispatch on the engine's thread ended: rounds
+        # whose life holds a compile teach the scheduler's cost model nothing
+        self._first_end = 0.0
         # watchdog/compile-grace state transition counts (satellite of the
         # shed-while-compiling post-mortem gap): bridged to
         # llmtpu_watchdog_transitions_total{state=...} by engines_info
@@ -1660,6 +1682,7 @@ class GenerationEngine:
         so followers executed (or wedged on) the same op and no local
         recovery can put every process back in the same state."""
         self._backend.emit(op, args)
+        self._dx_n += 1
         try:
             return self._ops[op](*args)
         except Exception as e:
@@ -2006,15 +2029,16 @@ class GenerationEngine:
                     cfg, params, ck, cv, toks, lens, attn_impl=impl,
                     slot_ids=slot_ids, paged=paged,
                 )
-                if mask is not None:
-                    logits = jnp.where(mask, logits, -jnp.inf)
-                rng, sub = jax.random.split(rng)
-                # parked rows (lens >= S) carry stale params from a prior
-                # occupant — exclude them from fast-path selection
-                S_cache = (ck["q"] if isinstance(ck, dict) else ck).shape[3]
-                new = sample_tokens(
-                    logits, sub, temp, topk, topp, active=lens < S_cache
-                )
+                with jax.named_scope("sample"):
+                    if mask is not None:
+                        logits = jnp.where(mask, logits, -jnp.inf)
+                    rng, sub = jax.random.split(rng)
+                    # parked rows (lens >= S) carry stale params from a prior
+                    # occupant — exclude them from fast-path selection
+                    S_cache = (ck["q"] if isinstance(ck, dict) else ck).shape[3]
+                    new = sample_tokens(
+                        logits, sub, temp, topk, topp, active=lens < S_cache
+                    )
                 return (ck, cv, new, lens + 1, rng), new
 
             (ck, cv, last, _, _), out = jax.lax.scan(
@@ -2447,16 +2471,19 @@ class GenerationEngine:
         the warmup planner's compile hook. This populates the persistent
         XLA compile cache (utils/config.enable_compile_cache), NOT jit's
         dispatch cache: the first real dispatch of the shape still traces,
-        then deserializes the cached executable in well under
-        TPU_COMPILE_HIT_S instead of paying the 1-2 min XLA compile.
+        then loads the cached executable instead of paying the 1-2 min XLA
+        compile (the ledger's `trace_s`/`lower_s`/`backend_s` say how long
+        each part of that first dispatch still takes).
         Returns the compile wall, or None for phases whose argument shapes
         cannot be synthesized from the key alone (fused/verify/restore —
         they compile on first real dispatch, exactly as before warmup)."""
         if phase not in ("admit", "chunk", "decode", "pf_rag"):
             return None
         t0 = time.perf_counter()
+        compile_watch.begin()  # the zoo's thread; closed by _compile_obs
         lowered = self.warmup_lower(phase, key)
         if lowered is None:
+            compile_watch.end()
             return None
         lowered.compile()
         wall = time.perf_counter() - t0
@@ -3025,6 +3052,9 @@ class GenerationEngine:
         if key in self._seen_exec_shapes:
             return False
         self._seen_exec_shapes.add(key)
+        # what JAX reports on this thread until _compile_obs goes to the
+        # ledger entry
+        compile_watch.begin()
         now = time.time()
         in_grace = now < self._compile_grace_until
         self._compile_grace_until = max(
@@ -3056,10 +3086,17 @@ class GenerationEngine:
         planner's AOT compiles — /v1/debug/compiles shows whether the
         serve path ever ate a cold compile warmup should have absorbed."""
         ks = ":".join(str(p) for p in key)
-        e = self._ledger.observe(phase, ks, wall_s, src=src)
+        e = self._ledger.observe(
+            phase, ks, wall_s, src=src, parts=compile_watch.end()
+        )
+        if src == "serve":
+            self._first_end = time.perf_counter()
         self._flight.event(
             "compile", phase=phase, key=ks,
             wall_ms=round(wall_s * 1e3, 1), hit=e["hit"],
+            trace_ms=round(e["trace_s"] * 1e3, 1),
+            lower_ms=round(e["lower_s"] * 1e3, 1),
+            backend_ms=round(e["backend_s"] * 1e3, 1),
         )
 
     def _paging_event(self, ops: list[tuple]) -> None:
@@ -3229,6 +3266,12 @@ class GenerationEngine:
 
     def anomaly_history(self, limit: int = 20) -> list[dict[str, Any]]:
         return self._anomaly.history(limit)
+
+    def observe_stream_write(self, t_put: float) -> None:
+        """The HTTP handler has written the SSE frame of the text event this
+        engine put at time.monotonic() `t_put` (the event's `t`): one
+        `stream_lag` sample, the handler's share of a reader's gap."""
+        self._perf.observe_sample("stream_lag", time.monotonic() - t_put)
 
     def perf_stats(self) -> dict[str, Any]:
         """Perf-observatory block (/v1/debug/perf + engines_info + bench):
@@ -4053,6 +4096,7 @@ class GenerationEngine:
              advances host mirrors (emission itself is deferred to the next
              iteration's step 3)
         """
+        tracing.name_os_thread("gen-engine")  # its line in a profiler trace
         pending: _PendingRound | None = None
         inflight: deque[_DispatchedRound] = deque()
         K = self.decode_chunk
@@ -4061,11 +4105,21 @@ class GenerationEngine:
         # where an engine-loop second actually goes — the published answer
         # to "why is serve below raw decode"
         phase = self._phase_s
+        # the same vocabulary on the profiler's host plane, beside the
+        # device's lines: a device gap is named after the phase that covers
+        # it. With no profiler session an annotation is a flag test.
+        span = {k: f"engine.{k}" for k in phase}
 
-        def timed(key, fn, *a, **kw):
+        def timed(key, fn, *a, rid=0):
             t0 = time.perf_counter()
+            # fetch and emit are handed their round; dispatch says which
+            # one it is about to make
+            rid = rid or (getattr(a[0], "rid", 0) if a else 0)
+            ann = (TraceAnnotation(span[key], rid=rid) if rid
+                   else TraceAnnotation(span[key]))
             try:
-                return fn(*a, **kw)
+                with ann:
+                    return fn(*a)
             finally:
                 phase[key] += time.perf_counter() - t0
 
@@ -4216,7 +4270,8 @@ class GenerationEngine:
                     # optimistically — this dispatch does NOT wait for any
                     # earlier round's fetch (decode_chunk_fn docstring)
                     inflight.append(
-                        timed("dispatch", self._dispatch_decode, active, group)
+                        timed("dispatch", self._dispatch_decode, active, group,
+                              rid=self._rid_dispatched + 1)
                     )
                 except Exception as e:  # a poisoned dispatch must not kill the loop
                     if pending is not None:
@@ -4261,7 +4316,8 @@ class GenerationEngine:
             elif not (active or cn_active or admitted or group is not None
                       or inflight):
                 t_idle = time.perf_counter()
-                self._wake.wait(timeout=0.05)
+                with TraceAnnotation(span["idle"]):
+                    self._wake.wait(timeout=0.05)
                 self._wake.clear()
                 phase["idle"] += time.perf_counter() - t_idle
         if pending is not None:
@@ -5038,7 +5094,8 @@ class GenerationEngine:
         t0c = time.perf_counter()
         toks0 = self._dx("admit", tokens, ipack, fpack, cn_payload)
         t_call = time.perf_counter()  # jit returned; device running
-        toks0 = np.asarray(toks0)  # host sync: first-call wall ≈ compile time
+        with TraceAnnotation("engine.admit.sync"):
+            toks0 = np.asarray(toks0)  # host sync: first-call wall ≈ compile time
         if first:
             self._compile_obs("admit", (Ab, bucket), time.perf_counter() - t0c)
         else:
@@ -5381,7 +5438,8 @@ class GenerationEngine:
                     group.last_idx_arr, group.skey, self._paged_payload(),
                 )
                 t_call = time.perf_counter()  # jit returned; device running
-                jax.block_until_ready(self._ck)
+                with TraceAnnotation("engine.prefill.sync"):
+                    jax.block_until_ready(self._ck)
                 wall = time.perf_counter() - t0
                 if first:
                     self._compile_obs(
@@ -5394,8 +5452,10 @@ class GenerationEngine:
                         "pf_rag", t0, t_call, group.n_tokens,
                         len(group.metas),
                     )
+                # a compile wall is not a prefill cost: count, teach nothing
                 self._sched.observe_prefill(
-                    group.n_tokens, wall, padded_tokens=group.bucket
+                    group.n_tokens, 0.0 if first else wall,
+                    padded_tokens=group.bucket,
                 )
                 self._credit_prefill_wall(group, wall)
                 self._flight.event(
@@ -5416,7 +5476,8 @@ class GenerationEngine:
                 self._paged_payload(),
             )
             t_call = time.perf_counter()  # jit returned; device running
-            jax.block_until_ready(self._ck)
+            with TraceAnnotation("engine.prefill.sync"):
+                jax.block_until_ready(self._ck)
             wall = time.perf_counter() - t0
             if first:
                 self._compile_obs(
@@ -5429,7 +5490,7 @@ class GenerationEngine:
                     "chunk", t0, t_call, group.n_tokens, len(group.metas),
                 )
             self._sched.observe_prefill(
-                group.n_tokens, wall,
+                group.n_tokens, 0.0 if first else wall,
                 padded_tokens=group.tokens.shape[0] * group.bucket,
             )
             self._credit_prefill_wall(group, wall)
@@ -5482,7 +5543,8 @@ class GenerationEngine:
                 self._next_counter(), cn_payload,
             )
             if fin:
-                toks0 = np.asarray(toks0)
+                with TraceAnnotation("engine.prefill.sync"):
+                    toks0 = np.asarray(toks0)
                 for k, (_, slot, st) in enumerate(fin):
                     self._prefill_q.remove(slot)
                     # _prefills entry is dropped only AFTER activation
@@ -5659,7 +5721,11 @@ class GenerationEngine:
                 wait_ms=round(wait_s * 1e3, 3),
                 rows=n,
             )
-        self._sched.observe_verify(total, time.perf_counter() - t0)
+        # a first dispatch counts its round and tokens and teaches the cost
+        # EMA nothing (a wall of 0, as observe_prefill reads it)
+        self._sched.observe_verify(
+            total, 0.0 if first else time.perf_counter() - t0
+        )
         before = self.total_tokens
         drafted_round = 0
         accepted_round = 0
@@ -5694,7 +5760,7 @@ class GenerationEngine:
             self.spec_emitted += emitted
             self._observe_itl(s, s.generated - gen_before)
             if parts:
-                s.req.out.put({"type": "token", "text": "".join(parts)})
+                self._put_text(s, "".join(parts))
             if finish is not None:
                 self._finish_slot(b, s, finish)
             else:
@@ -5827,7 +5893,7 @@ class GenerationEngine:
             emit, finish = self._process_token(s, int(toks[i]), pos)
             self._observe_itl(s, s.generated - gen_before)
             if emit:
-                s.req.out.put({"type": "token", "text": emit})
+                self._put_text(s, emit)
             if finish is not None:
                 self._finish_slot(b, s, finish)
             else:
@@ -6002,37 +6068,31 @@ class GenerationEngine:
             phase_name,
             rid=self._rid_dispatched, rows=len(active),
             prefill_tokens=group.n_tokens if group is not None else 0,
-            prefill_padded=padded,
+            prefill_padded=padded, t=time.monotonic(),
         )
         # Sampled steady-state attribution (every Nth dispatch of this
         # phase; first dispatches belong to the CompileLedger): host = the
-        # staging+dispatch wall up to the async jit return, device = one
-        # block_until_ready on the round (the sample's cost — it serializes
-        # the pipeline for this round only), wait = the host-side gap since
-        # the previous round's fetch landed.
-        if not first and self._perf.should_sample(phase_name):
-            t1 = time.perf_counter()
-            jax.block_until_ready(out)
-            t2 = time.perf_counter()
-            wait_s = max(0.0, round_t0 - self._perf_mark)
-            ctx_mean = float(base[active].mean()) if nact else 0.0
+        # staging+dispatch wall up to the async jit return, wait = the
+        # host-side gap since the previous round's fetch landed. The sample
+        # is counted HERE, with this round's rows (decode_occupancy reads
+        # them): rounds whose device time can be told are the full ones, a
+        # free slot means an admission. Device seconds are taken at the
+        # fetch (_observe_round_device): nothing here blocks the pipeline.
+        t_disp = time.perf_counter()
+        sample = None
+        if self._perf.should_sample(phase_name) and not first:
+            sample = (t_disp - round_t0, max(0.0, round_t0 - self._perf_mark))
             self._perf.observe_phase(
-                phase_name, t1 - round_t0, t2 - t1, wait_s,
+                phase_name, sample[0], 0.0, sample[1],
                 tokens=nact * self.decode_chunk, rows=nact,
-                ctx_mean=ctx_mean,
-            )
-            self._flight.event(
-                "perf", phase=phase_name,
-                host_ms=round((t1 - round_t0) * 1e3, 3),
-                device_ms=round((t2 - t1) * 1e3, 3),
-                wait_ms=round(wait_s * 1e3, 3),
-                rows=nact,
+                ctx_mean=float(base[active].mean()) if nact else 0.0,
             )
         return _DispatchedRound(
             out=out, entries=entries, base=base, t0=round_t0,
             rid=self._rid_dispatched,
             prefill_tokens=group.n_tokens if group is not None else 0,
-            prefill_padded=padded,
+            prefill_padded=padded, phase=phase_name, dx=self._dx_n,
+            t_disp=t_disp, sample=sample,
         )
 
     def _complete_round(self, disp: _DispatchedRound) -> _PendingRound:
@@ -6046,14 +6106,31 @@ class GenerationEngine:
         rules (which add stop sequences), so a fast-scan finish always
         implies an emission finish on the same tokens; emission stays
         authoritative for events, usage, and text."""
-        out = np.asarray(disp.out)  # [K, Ba] — the only host sync per round
+        # whether this read will block: a round that has already ended
+        # comes back in about a millisecond, and says nothing about when
+        blocked = not disp.out.is_ready()
+        t_wait = time.perf_counter()
+        with TraceAnnotation("engine.fetch.sync"):
+            out = np.asarray(disp.out)  # [K, Ba] — the only host sync per round
+        now = time.perf_counter()
         self._last_round_ts = time.time()  # decode-cadence stall signal
-        self._perf_mark = time.perf_counter()  # sampled wait-gap anchor
+        self._perf_mark = now  # sampled wait-gap anchor
+        wait_s = now - t_wait
+        self._flight.event(
+            "fetch", rid=disp.rid, wait_ms=round(wait_s * 1e3, 3),
+            t=time.monotonic(),
+        )
+        prev, self._prev_fetch = self._prev_fetch, (disp.rid, disp.dx, now, blocked)
+        self._observe_round_device(disp, now, blocked, prev)
         # feed the token-budget scheduler's cost model: prefill-free rounds
         # teach the decode-round EMA; fused rounds attribute their time over
-        # that EMA to the chunk group's prompt tokens
-        dt = time.perf_counter() - disp.t0
-        if disp.prefill_tokens:
+        # that EMA to the chunk group's prompt tokens. A round whose life
+        # holds a first dispatch (its own, or one the loop made before this
+        # fetch) carries a compile wall and is left out.
+        dt = now - disp.t0
+        if disp.t0 < self._first_end:
+            pass
+        elif disp.prefill_tokens:
             self._sched.observe_fused(
                 dt, disp.prefill_tokens, padded_tokens=disp.prefill_padded
             )
@@ -6104,7 +6181,41 @@ class GenerationEngine:
                 # only the recovery mirror updates here
                 self._last_tok[b] = out[-1, col]
         self._rid_fetched = max(self._rid_fetched, disp.rid)
-        return _PendingRound(out=out, entries=disp.entries, base=disp.base)
+        return _PendingRound(
+            out=out, entries=disp.entries, base=disp.base, rid=disp.rid
+        )
+
+    def _observe_round_device(
+        self, disp: _DispatchedRound, now: float, blocked: bool, prev: tuple
+    ) -> None:
+        """A round's device seconds, told at its fetch where they can be
+        (nothing blocks the pipeline for them): the device began this round
+        when the round before it ended (that round's fetch, if this one was
+        queued behind it: dispatched back to back with nothing between, the
+        host waiting at that fetch) or when this one was dispatched (if that
+        fetch had already returned), and ended it `now`, if the host was
+        waiting here. Every round that can tell gives the perf observatory
+        its seconds and its tokens together; one that cannot (an admission's
+        read sat between, or a fetch found its round long ended) gives
+        neither, and a sampled one then journals no device time."""
+        p_rid, p_dx, p_t, p_blocked = prev
+        device_s = None
+        if (blocked and p_rid == disp.rid - 1
+                and (disp.t_disp >= p_t or (p_blocked and p_dx == disp.dx - 1))):
+            device_s = now - max(disp.t_disp, p_t)
+            rows = len(disp.entries)
+            self._perf.observe_device(
+                disp.phase, device_s, rows, rows * self.decode_chunk,
+                sampled=disp.sample is not None,
+            )
+        if disp.sample is not None:
+            self._flight.event(
+                "perf", phase=disp.phase,
+                host_ms=round(disp.sample[0] * 1e3, 3),
+                device_ms=None if device_s is None else round(device_s * 1e3, 3),
+                wait_ms=round(disp.sample[1] * 1e3, 3),
+                rows=len(disp.entries),
+            )
 
     def _free_now(self, b: int) -> None:
         """Park a slot and fence its reuse until every round currently in
@@ -6124,7 +6235,9 @@ class GenerationEngine:
         """Phase 3 (deferred, overlapped with the next round's device time):
         decode token text, deliver events, finalize usage/finishes."""
         K = p.out.shape[0]
+        t_emit = time.perf_counter()
         before = self.total_tokens  # _process_token counts delivered tokens
+        texts = held = 0
         for b, s, col in p.entries:
             if s.done or s.aborted:
                 continue  # terminal event already delivered
@@ -6144,15 +6257,37 @@ class GenerationEngine:
                 # were all learned at the same fetch, so splitting them into
                 # K queue events (and K SSE frames) adds overhead with zero
                 # client-visible timing difference
-                s.req.out.put({"type": "token", "text": "".join(parts)})
+                self._put_text(s, "".join(parts))
+                texts += 1
                 if self._pool is not None:
                     # the "idle" preemption policy's victim signal; guarded
                     # so the pool-off hot path writes nothing
                     s.last_emit = time.time()
+            elif s.generated > gen_before:
+                held += 1  # tokens and no text: bytes the decoder holds back
             if finish is not None:
                 self._finish_slot(b, s, finish)
+        delivered = self.total_tokens - before
+        self._flight.event(
+            "emit", rid=p.rid, rows=len(p.entries), delivered=delivered,
+            texts=texts, held=held,
+            dur_ms=round((time.perf_counter() - t_emit) * 1e3, 3),
+            t=time.monotonic(),
+        )
         with self.stats_lock:
-            self._window.append((time.time(), self.total_tokens - before))
+            self._window.append((time.time(), delivered))
+
+    def _put_text(self, s: _Slot, text: str) -> None:
+        """One text event onto a stream's queue, stamped with the
+        time.monotonic() of its put (the HTTP handler observes `stream_lag`
+        against it after the socket write), and the gap since the stream's
+        previous text event: what a reader of the stream would see if the
+        handler added nothing, whole, not spread over the round's tokens."""
+        now = time.monotonic()
+        if s.last_text_t:
+            self._perf.observe_sample("event_gap", now - s.last_text_t)
+        s.last_text_t = now
+        s.req.out.put({"type": "token", "text": text, "t": now})
 
     def _sample_prefill_phase(
         self, phase: str, t0: float, t_call: float, tokens: int, rows: int
@@ -6216,7 +6351,7 @@ class GenerationEngine:
         identity-guarded (_finish_slot)."""
         emit, finish = self._process_token(s, tok, pos)
         if emit:
-            s.req.out.put({"type": "token", "text": emit})
+            self._put_text(s, emit)
         if finish is not None:
             self._finish_slot(slot_idx, s, finish)
             return False

@@ -136,16 +136,19 @@ class TokenBudgetScheduler:
         `seconds`. `padded_tokens` is the dispatched token shape (≥ tokens;
         0 ⇒ unknown, treated as un-padded): the cost EMA divides by it —
         the device computed every pad — while the waste ratio records how
-        much of the dispatch was pads."""
-        if tokens <= 0 or seconds <= 0:
+        much of the dispatch was pads. `seconds` of 0 (a first dispatch: its
+        wall is a compile's) counts the tokens and the pads and teaches the
+        cost EMA nothing."""
+        if tokens <= 0:
             return
         comp = max(int(tokens), int(padded_tokens))
-        per = min(1.0, max(1e-8, seconds / comp))
-        self.prefill_tok_s = _EMA * self.prefill_tok_s + (1 - _EMA) * per
         self.prefill_true_tokens += int(tokens)
         self.prefill_padded_tokens += comp
         waste = 1.0 - tokens / comp
         self.pad_waste = _EMA * self.pad_waste + (1 - _EMA) * waste
+        if seconds > 0:
+            per = min(1.0, max(1e-8, seconds / comp))
+            self.prefill_tok_s = _EMA * self.prefill_tok_s + (1 - _EMA) * per
 
     def observe_fused(
         self, round_s: float, prefill_tokens: int, padded_tokens: int = 0

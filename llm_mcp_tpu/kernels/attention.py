@@ -279,6 +279,7 @@ def flash_prefill_attention(
     win = jnp.reshape(jnp.asarray(window, dtype=jnp.int32), (1,))
     return pl.pallas_call(
         kernel,
+        name="flash_prefill_attn",
         grid=(B, H, S // bq),
         in_specs=[
             _smem_spec(),  # lengths [B]
@@ -968,7 +969,8 @@ def decode_attend_q8(
             ),
         )
         return pl.pallas_call(
-            kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp
+            kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp,
+            name="decode_attn_q8_whole",
         )(*args)
 
     def run_blocked():
@@ -1006,7 +1008,8 @@ def decode_attend_q8(
             ],
         )
         return pl.pallas_call(
-            kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp
+            kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp,
+            name="decode_attn_q8_blocked",
         )(*args)
 
     def run_paged():
@@ -1046,7 +1049,8 @@ def decode_attend_q8(
             ],
         )
         return pl.pallas_call(
-            kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp
+            kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp,
+            name="decode_attn_q8_paged",
         )(
             jnp.reshape(layer, (1,)).astype(jnp.int32),
             lengths.astype(jnp.int32),
@@ -1577,7 +1581,8 @@ def decode_attend_bf16(
             ),
         )
         return pl.pallas_call(
-            kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp
+            kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp,
+            name="decode_attn_bf16_whole",
         )(*args)
 
     def run_blocked():
@@ -1604,7 +1609,8 @@ def decode_attend_bf16(
             ],
         )
         return pl.pallas_call(
-            kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp
+            kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp,
+            name="decode_attn_bf16_blocked",
         )(*args)
 
     def run_paged():
@@ -1637,7 +1643,8 @@ def decode_attend_bf16(
             ],
         )
         return pl.pallas_call(
-            kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp
+            kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp,
+            name="decode_attn_bf16_paged",
         )(
             jnp.reshape(layer, (1,)).astype(jnp.int32),
             lengths.astype(jnp.int32),
@@ -2216,7 +2223,8 @@ def decode_attend_q8_mla(
             out_specs=pl.BlockSpec((1, H, R), lambda b, li, ids, lens: (b, 0, 0)),
         )
         return pl.pallas_call(
-            kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp
+            kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp,
+            name="decode_attn_mla_q8_whole",
         )(*args)
 
     def run_blocked():
@@ -2251,7 +2259,8 @@ def decode_attend_q8_mla(
             ],
         )
         return pl.pallas_call(
-            kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp
+            kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp,
+            name="decode_attn_mla_q8_blocked",
         )(*args)
 
     def run_paged():
@@ -2294,7 +2303,8 @@ def decode_attend_q8_mla(
             ],
         )
         return pl.pallas_call(
-            kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp
+            kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp,
+            name="decode_attn_mla_q8_paged",
         )(
             jnp.reshape(layer, (1,)).astype(jnp.int32),
             lengths.astype(jnp.int32),
@@ -2520,6 +2530,7 @@ def append_kv_q8(
     )
     oq, os_ = pl.pallas_call(
         kernel,
+        name="append_kv_q8",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(cache_k["q"].shape, cache_k["q"].dtype),
@@ -2646,6 +2657,7 @@ def append_kv_bf16(
     )
     ok, ov = pl.pallas_call(
         kernel,
+        name="append_kv_bf16",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(cache_k.shape, cache_k.dtype),
@@ -2689,6 +2701,7 @@ def decode_attention(
     kernel = functools.partial(_decode_attn_kernel, scale=hd**-0.5)
     return pl.pallas_call(
         kernel,
+        name="decode_attn_dense",
         grid=(B, Hkv),
         in_specs=[
             _smem_spec(),  # lengths [B]
@@ -3075,6 +3088,7 @@ def _ragged_gqa_call(
     vmem = 2 * blocked + state + stream_bytes + (8 << 20)
     out = pl.pallas_call(
         kernel,
+        name=f"ragged_prefill_attn_{'q8' if kernel_kw['quantized'] else 'bf16'}_gqa",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nQ, Hkv, RQ, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -3675,6 +3689,7 @@ def ragged_prefill_attend_mla(
     )
     out = pl.pallas_call(
         kernel,
+        name="ragged_prefill_attn_mla",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nQ, RQ, Rl), qt.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -293,6 +293,7 @@ def _attn_residual(cfg: ModelConfig, lp: Params, ctx: jnp.ndarray, h: jnp.ndarra
     return h + out
 
 
+@jax.named_scope("ffn")
 def _ffn_residual(
     cfg: ModelConfig,
     lp: Params,
@@ -353,6 +354,7 @@ def _embed_in(cfg: ModelConfig, params: Params, tokens: jnp.ndarray) -> jnp.ndar
     return h
 
 
+@jax.named_scope("head")
 def _logits(cfg: ModelConfig, params: Params, h: jnp.ndarray) -> jnp.ndarray:
     h = _norm(cfg, h, params["final_norm"])
     src = params["embed"] if cfg.tie_embeddings else params["lm_head"]
@@ -392,43 +394,44 @@ def prefill_layer(
     neg = jnp.float32(-1e30)
     window = jnp.asarray(window, dtype=jnp.int32)
 
-    x = _norm(cfg, h, lp["attn_norm"])
-    q, k, v = _qkv(cfg, lp, x)
-    q = q.reshape(B, S, H, hd)
-    k = k.reshape(B, S, Hkv, hd)
-    v = v.reshape(B, S, Hkv, hd)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    with jax.named_scope("attn"):
+        x = _norm(cfg, h, lp["attn_norm"])
+        q, k, v = _qkv(cfg, lp, x)
+        q = q.reshape(B, S, H, hd)
+        k = k.reshape(B, S, Hkv, hd)
+        v = v.reshape(B, S, Hkv, hd)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
-    # Cache layout: heads before sequence (see module docstring).
-    kh = k.transpose(0, 2, 1, 3)  # [B, Hkv, S, hd]
-    vh = v.transpose(0, 2, 1, 3)
+        # Cache layout: heads before sequence (see module docstring).
+        kh = k.transpose(0, 2, 1, 3)  # [B, Hkv, S, hd]
+        vh = v.transpose(0, 2, 1, 3)
 
-    if attn_impl == "pallas":
-        qh = q.transpose(0, 2, 1, 3)  # [B, H, S, hd]
-        ctx = flash_prefill_attention(
-            qh,
-            kh,
-            vh,
-            lengths,
-            window=window,
-            softcap=cfg.attn_softcap,
-            scale=cfg.attn_scale,
-        )
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
-    else:
-        qg = q.reshape(B, S, Hkv, G, hd)
-        scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k).astype(jnp.float32)
-        scores = _softcap(scores * cfg.attn_scale, cfg.attn_softcap)
-        m = mask
-        if cfg.sliding_window:
-            # q_pos - k_pos < window; window == 0 disables (global layer)
-            diff = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]  # [S, S]
-            m = m & ((window == 0) | (diff < window))[None]
-        scores = jnp.where(m[:, None, None, :, :], scores, neg)
-        probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
-        ctx = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v).reshape(B, S, H * hd)
-    h = _attn_residual(cfg, lp, ctx, h)
+        if attn_impl == "pallas":
+            qh = q.transpose(0, 2, 1, 3)  # [B, H, S, hd]
+            ctx = flash_prefill_attention(
+                qh,
+                kh,
+                vh,
+                lengths,
+                window=window,
+                softcap=cfg.attn_softcap,
+                scale=cfg.attn_scale,
+            )
+            ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
+        else:
+            qg = q.reshape(B, S, Hkv, G, hd)
+            scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k).astype(jnp.float32)
+            scores = _softcap(scores * cfg.attn_scale, cfg.attn_softcap)
+            m = mask
+            if cfg.sliding_window:
+                # q_pos - k_pos < window; window == 0 disables (global layer)
+                diff = jnp.arange(S)[:, None] - jnp.arange(S)[None, :]  # [S, S]
+                m = m & ((window == 0) | (diff < window))[None]
+            scores = jnp.where(m[:, None, None, :, :], scores, neg)
+            probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
+            ctx = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v).reshape(B, S, H * hd)
+        h = _attn_residual(cfg, lp, ctx, h)
     h = _ffn_residual(
         cfg, lp, h,
         moe_valid=jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None],
@@ -555,21 +558,22 @@ def _decode_step_q8(
     def layer(carry, xs):
         lp, win = xs
         h, li = carry
-        x = _norm(cfg, h, lp["attn_norm"])
-        q, k, v = _qkv(cfg, lp, x)
-        q = q.reshape(Ba, H, hd)
-        k = k.reshape(Ba, Hkv, hd)
-        v = v.reshape(Ba, Hkv, hd)
-        q = apply_rope(q[:, None], cos[:, None], sin[:, None])[:, 0]
-        k = apply_rope(k[:, None], cos[:, None], sin[:, None])[:, 0]
-        qg = q.reshape(Ba, Hkv, H // Hkv, hd)
-        ctx = decode_attend_q8(
-            qg, k, v, cache_k, cache_v, li, lengths,
-            slot_ids=slot_ids, scale=cfg.attn_scale,
-            block_tables=None if paged is None else paged["tbl"],
-            pool_k=None if paged is None else paged["k"],
-        ).reshape(Ba, H * hd)
-        h = _attn_residual(cfg, lp, ctx, h)
+        with jax.named_scope("attn"):
+            x = _norm(cfg, h, lp["attn_norm"])
+            q, k, v = _qkv(cfg, lp, x)
+            q = q.reshape(Ba, H, hd)
+            k = k.reshape(Ba, Hkv, hd)
+            v = v.reshape(Ba, Hkv, hd)
+            q = apply_rope(q[:, None], cos[:, None], sin[:, None])[:, 0]
+            k = apply_rope(k[:, None], cos[:, None], sin[:, None])[:, 0]
+            qg = q.reshape(Ba, Hkv, H // Hkv, hd)
+            ctx = decode_attend_q8(
+                qg, k, v, cache_k, cache_v, li, lengths,
+                slot_ids=slot_ids, scale=cfg.attn_scale,
+                block_tables=None if paged is None else paged["tbl"],
+                pool_k=None if paged is None else paged["k"],
+            ).reshape(Ba, H * hd)
+            h = _attn_residual(cfg, lp, ctx, h)
         h = _ffn_residual(cfg, lp, h, moe_capacity=Ba)
         return (h, li + 1), (k, v)
 
@@ -579,7 +583,8 @@ def _decode_step_q8(
         (params["layers"], layer_windows(cfg)),
         unroll=scan_unroll(),
     )
-    new_k, new_v = append_kv_q8(cache_k, cache_v, knew, vnew, lengths, slot_ids=slot_ids)
+    with jax.named_scope("kv_append"):
+        new_k, new_v = append_kv_q8(cache_k, cache_v, knew, vnew, lengths, slot_ids=slot_ids)
     return _logits(cfg, params, h), new_k, new_v
 
 
@@ -611,22 +616,23 @@ def _decode_step_bf16(
     def layer(carry, xs):
         lp, win = xs
         h, li = carry
-        x = _norm(cfg, h, lp["attn_norm"])
-        q, k, v = _qkv(cfg, lp, x)
-        q = q.reshape(Ba, H, hd)
-        k = k.reshape(Ba, Hkv, hd)
-        v = v.reshape(Ba, Hkv, hd)
-        q = apply_rope(q[:, None], cos[:, None], sin[:, None])[:, 0]
-        k = apply_rope(k[:, None], cos[:, None], sin[:, None])[:, 0]
-        qg = q.reshape(Ba, Hkv, H // Hkv, hd)
-        ctx = decode_attend_bf16(
-            qg, k, v, cache_k, cache_v, li, lengths,
-            slot_ids=slot_ids, scale=cfg.attn_scale,
-            block_tables=None if paged is None else paged["tbl"],
-            pool_k=None if paged is None else paged["k"],
-            pool_v=None if paged is None else paged["v"],
-        ).reshape(Ba, H * hd)
-        h = _attn_residual(cfg, lp, ctx, h)
+        with jax.named_scope("attn"):
+            x = _norm(cfg, h, lp["attn_norm"])
+            q, k, v = _qkv(cfg, lp, x)
+            q = q.reshape(Ba, H, hd)
+            k = k.reshape(Ba, Hkv, hd)
+            v = v.reshape(Ba, Hkv, hd)
+            q = apply_rope(q[:, None], cos[:, None], sin[:, None])[:, 0]
+            k = apply_rope(k[:, None], cos[:, None], sin[:, None])[:, 0]
+            qg = q.reshape(Ba, Hkv, H // Hkv, hd)
+            ctx = decode_attend_bf16(
+                qg, k, v, cache_k, cache_v, li, lengths,
+                slot_ids=slot_ids, scale=cfg.attn_scale,
+                block_tables=None if paged is None else paged["tbl"],
+                pool_k=None if paged is None else paged["k"],
+                pool_v=None if paged is None else paged["v"],
+            ).reshape(Ba, H * hd)
+            h = _attn_residual(cfg, lp, ctx, h)
         h = _ffn_residual(cfg, lp, h, moe_capacity=Ba)
         return (h, li + 1), (k, v)
 
@@ -636,9 +642,10 @@ def _decode_step_bf16(
         (params["layers"], layer_windows(cfg)),
         unroll=scan_unroll(),
     )
-    new_k, new_v = append_kv_bf16(
-        cache_k, cache_v, knew, vnew, lengths, slot_ids=slot_ids
-    )
+    with jax.named_scope("kv_append"):
+        new_k, new_v = append_kv_bf16(
+            cache_k, cache_v, knew, vnew, lengths, slot_ids=slot_ids
+        )
     return _logits(cfg, params, h), new_k, new_v
 
 
@@ -733,139 +740,141 @@ def llama_prefill_chunk_batch(
     def layer(carry, xs):
         lp, win = xs
         h, ck_all, cv_all, li = carry
-        x = _norm(cfg, h, lp["attn_norm"])
-        q, k, v = _qkv(cfg, lp, x)
-        q = apply_rope(q.reshape(A, C, H, hd), cos, sin)
-        k = apply_rope(k.reshape(A, C, Hkv, hd), cos, sin)
-        v = v.reshape(A, C, Hkv, hd)
-        kh = k.transpose(0, 2, 1, 3)  # [A, Hkv, C, hd]
-        vh = v.transpose(0, 2, 1, 3)
-        qg = q.reshape(A, C, Hkv, G, hd)
+        with jax.named_scope("attn"):
+            x = _norm(cfg, h, lp["attn_norm"])
+            q, k, v = _qkv(cfg, lp, x)
+            q = apply_rope(q.reshape(A, C, H, hd), cos, sin)
+            k = apply_rope(k.reshape(A, C, Hkv, hd), cos, sin)
+            v = v.reshape(A, C, Hkv, hd)
+            kh = k.transpose(0, 2, 1, 3)  # [A, Hkv, C, hd]
+            vh = v.transpose(0, 2, 1, 3)
+            qg = q.reshape(A, C, Hkv, G, hd)
 
-        # ---- reads first: the past rows from the PRE-write cache ----
-        if quantized:
-            # FUSED layout: K heads [0,Hkv) and V heads [Hkv,2Hkv) share one
-            # payload — one slice per slot covers both (the packed-scale
-            # pseudo-head past 2*Hkv is never read here; the plain "s" rows
-            # carry the arithmetic scales)
-            if ptbl is not None:
-                pays = paged_gather(
-                    jax.lax.dynamic_index_in_dim(ck_all["q"], li, 0, keepdims=False),
-                    jax.lax.dynamic_index_in_dim(paged["k"]["q"], li, 0, keepdims=False),
+            # ---- reads first: the past rows from the PRE-write cache ----
+            if quantized:
+                # FUSED layout: K heads [0,Hkv) and V heads [Hkv,2Hkv) share one
+                # payload — one slice per slot covers both (the packed-scale
+                # pseudo-head past 2*Hkv is never read here; the plain "s" rows
+                # carry the arithmetic scales)
+                if ptbl is not None:
+                    pays = paged_gather(
+                        jax.lax.dynamic_index_in_dim(ck_all["q"], li, 0, keepdims=False),
+                        jax.lax.dynamic_index_in_dim(paged["k"]["q"], li, 0, keepdims=False),
+                        ptbl, nbs=nbs_full,
+                    )[:, : 2 * Hkv, :Sk]  # [A, 2*Hkv, Sk, hd] int8
+                    srows = paged_gather(
+                        jax.lax.dynamic_index_in_dim(ck_all["s"], li, 0, keepdims=False),
+                        jax.lax.dynamic_index_in_dim(paged["k"]["s"], li, 0, keepdims=False),
+                        ptbl, nbs=nbs_full,
+                    )[:, : 2 * Hkv, :Sk]  # [A, 2*Hkv, Sk]
+                else:
+                    pays = jnp.stack(
+                        [
+                            jax.lax.dynamic_slice(
+                                ck_all["q"], (li, slots[a], 0, 0, 0), (1, 1, 2 * Hkv, Sk, hd)
+                            )[0, 0]
+                            for a in range(A)
+                        ]
+                    )  # [A, 2*Hkv, Sk, hd] int8
+                    srows = jnp.stack(
+                        [
+                            jax.lax.dynamic_slice(
+                                ck_all["s"], (li, slots[a], 0, 0), (1, 1, 2 * Hkv, Sk)
+                            )[0, 0]
+                            for a in range(A)
+                        ]
+                    )  # [A, 2*Hkv, Sk]
+                krows, vrows = pays[:, :Hkv], pays[:, Hkv:]
+                ksr, vsr = srows[:, :Hkv], srows[:, Hkv:]
+            elif ptbl is not None:
+                krows = paged_gather(
+                    jax.lax.dynamic_index_in_dim(ck_all, li, 0, keepdims=False),
+                    jax.lax.dynamic_index_in_dim(paged["k"], li, 0, keepdims=False),
                     ptbl, nbs=nbs_full,
-                )[:, : 2 * Hkv, :Sk]  # [A, 2*Hkv, Sk, hd] int8
-                srows = paged_gather(
-                    jax.lax.dynamic_index_in_dim(ck_all["s"], li, 0, keepdims=False),
-                    jax.lax.dynamic_index_in_dim(paged["k"]["s"], li, 0, keepdims=False),
+                )[:, :, :Sk]
+                vrows = paged_gather(
+                    jax.lax.dynamic_index_in_dim(cv_all, li, 0, keepdims=False),
+                    jax.lax.dynamic_index_in_dim(paged["v"], li, 0, keepdims=False),
                     ptbl, nbs=nbs_full,
-                )[:, : 2 * Hkv, :Sk]  # [A, 2*Hkv, Sk]
+                )[:, :, :Sk]
             else:
-                pays = jnp.stack(
+                krows = jnp.stack(
                     [
                         jax.lax.dynamic_slice(
-                            ck_all["q"], (li, slots[a], 0, 0, 0), (1, 1, 2 * Hkv, Sk, hd)
+                            ck_all, (li, slots[a], 0, 0, 0), (1, 1, Hkv, Sk, hd)
                         )[0, 0]
                         for a in range(A)
                     ]
-                )  # [A, 2*Hkv, Sk, hd] int8
-                srows = jnp.stack(
+                )  # [A, Hkv, Sk, hd]
+                vrows = jnp.stack(
                     [
                         jax.lax.dynamic_slice(
-                            ck_all["s"], (li, slots[a], 0, 0), (1, 1, 2 * Hkv, Sk)
+                            cv_all, (li, slots[a], 0, 0, 0), (1, 1, Hkv, Sk, hd)
                         )[0, 0]
                         for a in range(A)
                     ]
-                )  # [A, 2*Hkv, Sk]
-            krows, vrows = pays[:, :Hkv], pays[:, Hkv:]
-            ksr, vsr = srows[:, :Hkv], srows[:, Hkv:]
-        elif ptbl is not None:
-            krows = paged_gather(
-                jax.lax.dynamic_index_in_dim(ck_all, li, 0, keepdims=False),
-                jax.lax.dynamic_index_in_dim(paged["k"], li, 0, keepdims=False),
-                ptbl, nbs=nbs_full,
-            )[:, :, :Sk]
-            vrows = paged_gather(
-                jax.lax.dynamic_index_in_dim(cv_all, li, 0, keepdims=False),
-                jax.lax.dynamic_index_in_dim(paged["v"], li, 0, keepdims=False),
-                ptbl, nbs=nbs_full,
-            )[:, :, :Sk]
-        else:
-            krows = jnp.stack(
-                [
-                    jax.lax.dynamic_slice(
-                        ck_all, (li, slots[a], 0, 0, 0), (1, 1, Hkv, Sk, hd)
-                    )[0, 0]
-                    for a in range(A)
-                ]
-            )  # [A, Hkv, Sk, hd]
-            vrows = jnp.stack(
-                [
-                    jax.lax.dynamic_slice(
-                        cv_all, (li, slots[a], 0, 0, 0), (1, 1, Hkv, Sk, hd)
-                    )[0, 0]
-                    for a in range(A)
-                ]
-            )
+                )
 
-        # past scores (dequant post-dot when the cache is int8)
-        s_past = jnp.einsum(
-            "achgd,ahsd->ahgcs", qg, krows.astype(h.dtype)
-        ).astype(jnp.float32)
-        if quantized:
-            s_past = s_past * ksr.astype(jnp.float32)[:, :, None, None, :]
-        # self scores: exact, from in-register bf16 K
-        s_self = jnp.einsum("achgd,ahtd->ahgct", qg, kh).astype(jnp.float32)
-        s_past = _softcap(s_past * cfg.attn_scale, cfg.attn_softcap)
-        s_self = _softcap(s_self * cfg.attn_scale, cfg.attn_softcap)
+            # past scores (dequant post-dot when the cache is int8)
+            s_past = jnp.einsum(
+                "achgd,ahsd->ahgcs", qg, krows.astype(h.dtype)
+            ).astype(jnp.float32)
+            if quantized:
+                s_past = s_past * ksr.astype(jnp.float32)[:, :, None, None, :]
+            # self scores: exact, from in-register bf16 K
+            s_self = jnp.einsum("achgd,ahtd->ahgct", qg, kh).astype(jnp.float32)
+            s_past = _softcap(s_past * cfg.attn_scale, cfg.attn_softcap)
+            s_self = _softcap(s_self * cfg.attn_scale, cfg.attn_softcap)
 
-        pm, sm = past_mask, self_mask
-        if cfg.sliding_window:
-            pm = pm & (
-                (win == 0)
-                | (q_pos[:, :, None] - key_pos[None, None, :] < win)
-            )
-            sm = sm & ((win == 0) | (c_idx[None, :] - c_idx[:, None] > -win))
-        s_past = jnp.where(pm[:, None, None, :, :], s_past, neg)
-        s_self = jnp.where(sm[:, None, None, :, :], s_self, neg)
+            pm, sm = past_mask, self_mask
+            if cfg.sliding_window:
+                pm = pm & (
+                    (win == 0)
+                    | (q_pos[:, :, None] - key_pos[None, None, :] < win)
+                )
+                sm = sm & ((win == 0) | (c_idx[None, :] - c_idx[:, None] > -win))
+            s_past = jnp.where(pm[:, None, None, :, :], s_past, neg)
+            s_self = jnp.where(sm[:, None, None, :, :], s_self, neg)
 
-        # joint softmax over [past | self]
-        s = jnp.concatenate([s_past, s_self], axis=-1)  # [A, Hkv, G, C, Sk+C]
-        probs = jax.nn.softmax(s, axis=-1)
-        p_past, p_self = probs[..., :Sk], probs[..., Sk:]
-        if quantized:
-            p_past = p_past * vsr.astype(jnp.float32)[:, :, None, None, :]
-        ctx = jnp.einsum(
-            "ahgcs,ahsd->achgd", p_past.astype(h.dtype), vrows.astype(h.dtype)
-        ) + jnp.einsum("ahgct,ahtd->achgd", p_self.astype(h.dtype), vh)
-        ctx = ctx.reshape(A, C, H * hd)
-        h = _attn_residual(cfg, lp, ctx, h)
+            # joint softmax over [past | self]
+            s = jnp.concatenate([s_past, s_self], axis=-1)  # [A, Hkv, G, C, Sk+C]
+            probs = jax.nn.softmax(s, axis=-1)
+            p_past, p_self = probs[..., :Sk], probs[..., Sk:]
+            if quantized:
+                p_past = p_past * vsr.astype(jnp.float32)[:, :, None, None, :]
+            ctx = jnp.einsum(
+                "ahgcs,ahsd->achgd", p_past.astype(h.dtype), vrows.astype(h.dtype)
+            ) + jnp.einsum("ahgct,ahtd->achgd", p_self.astype(h.dtype), vh)
+            ctx = ctx.reshape(A, C, H * hd)
+            h = _attn_residual(cfg, lp, ctx, h)
         h = _ffn_residual(
             cfg, lp, h, moe_valid=c_idx[None, :] < nvalid[:, None]
         )
 
         # ---- writes last: in-place (write-after-read) ----
-        if quantized:
-            # write the chunk's rows in cache layout: fused payload
-            # (K|V|packed scales) + plain scales, so later readers — decode
-            # kernels included — see a consistent fused entry
-            fused = fuse_prompt_kv(kh, vh, scale_dtype=ck_all["s"].dtype)
-            for a in range(A):
-                ck_all = {
-                    "q": jax.lax.dynamic_update_slice(
-                        ck_all["q"], fused["q"][a][None, None], (li, slots[a], 0, starts[a], 0)
-                    ),
-                    "s": jax.lax.dynamic_update_slice(
-                        ck_all["s"], fused["s"][a][None, None], (li, slots[a], 0, starts[a])
-                    ),
-                }
-        else:
-            for a in range(A):
-                ck_all = jax.lax.dynamic_update_slice(
-                    ck_all, kh[a][None, None].astype(ck_all.dtype), (li, slots[a], 0, starts[a], 0)
-                )
-                cv_all = jax.lax.dynamic_update_slice(
-                    cv_all, vh[a][None, None].astype(cv_all.dtype), (li, slots[a], 0, starts[a], 0)
-                )
+        with jax.named_scope("kv_append"):
+            if quantized:
+                # write the chunk's rows in cache layout: fused payload
+                # (K|V|packed scales) + plain scales, so later readers — decode
+                # kernels included — see a consistent fused entry
+                fused = fuse_prompt_kv(kh, vh, scale_dtype=ck_all["s"].dtype)
+                for a in range(A):
+                    ck_all = {
+                        "q": jax.lax.dynamic_update_slice(
+                            ck_all["q"], fused["q"][a][None, None], (li, slots[a], 0, starts[a], 0)
+                        ),
+                        "s": jax.lax.dynamic_update_slice(
+                            ck_all["s"], fused["s"][a][None, None], (li, slots[a], 0, starts[a])
+                        ),
+                    }
+            else:
+                for a in range(A):
+                    ck_all = jax.lax.dynamic_update_slice(
+                        ck_all, kh[a][None, None].astype(ck_all.dtype), (li, slots[a], 0, starts[a], 0)
+                    )
+                    cv_all = jax.lax.dynamic_update_slice(
+                        cv_all, vh[a][None, None].astype(cv_all.dtype), (li, slots[a], 0, starts[a], 0)
+                    )
         return (h, ck_all, cv_all, li + 1), None
 
     (h, new_k, new_v, _), _ = jax.lax.scan(
@@ -1063,43 +1072,45 @@ def llama_prefill_chunk_ragged(
     # outside it, the donated buffer is rewritten in place.
     def layer(carry, lp):
         h, li = carry
-        x = _norm(cfg, h, lp["attn_norm"])
-        q, k, v = _qkv(cfg, lp, x)
-        q = apply_rope(q.reshape(T, H, hd), cos, sin)
-        k = apply_rope(k.reshape(T, Hkv, hd), cos, sin)
-        v = v.reshape(T, Hkv, hd)
-        qg = q.reshape(T, Hkv, G, hd)
+        with jax.named_scope("attn"):
+            x = _norm(cfg, h, lp["attn_norm"])
+            q, k, v = _qkv(cfg, lp, x)
+            q = apply_rope(q.reshape(T, H, hd), cos, sin)
+            k = apply_rope(k.reshape(T, Hkv, hd), cos, sin)
+            v = v.reshape(T, Hkv, hd)
+            qg = q.reshape(T, Hkv, G, hd)
 
-        # ---- ragged attention over [cached past | packed self]
-        if quantized:
-            ctx = ragged_prefill_attend_q8(
-                qg, k, v, cache_k, li, rowids, offsets, slots, starts,
-                scale=cfg.attn_scale, skey=skey, block_tables=btbl,
-                pool=paged["k"] if paged is not None else None, impl=impl,
-            )
-        else:
-            ctx = ragged_prefill_attend_bf16(
-                qg, k, v, cache_k, cache_v, li, rowids, offsets, slots, starts,
-                scale=cfg.attn_scale, skey=skey, block_tables=btbl,
-                pool_k=paged["k"] if paged is not None else None,
-                pool_v=paged["v"] if paged is not None else None, impl=impl,
-            )
-        ctx = ctx.reshape(T, H * hd)
-        h = _attn_residual(cfg, lp, ctx, h)
+            # ---- ragged attention over [cached past | packed self]
+            if quantized:
+                ctx = ragged_prefill_attend_q8(
+                    qg, k, v, cache_k, li, rowids, offsets, slots, starts,
+                    scale=cfg.attn_scale, skey=skey, block_tables=btbl,
+                    pool=paged["k"] if paged is not None else None, impl=impl,
+                )
+            else:
+                ctx = ragged_prefill_attend_bf16(
+                    qg, k, v, cache_k, cache_v, li, rowids, offsets, slots, starts,
+                    scale=cfg.attn_scale, skey=skey, block_tables=btbl,
+                    pool_k=paged["k"] if paged is not None else None,
+                    pool_v=paged["v"] if paged is not None else None, impl=impl,
+                )
+            ctx = ctx.reshape(T, H * hd)
+            h = _attn_residual(cfg, lp, ctx, h)
         h = _ffn_residual(cfg, lp, h, moe_valid=moe_valid)
 
         # ---- this layer's rows, in the cache's own form and axis order
-        if quantized:
-            fused = fuse_prompt_kv(
-                k.transpose(1, 0, 2), v.transpose(1, 0, 2),
-                scale_dtype=cache_k["s"].dtype,
-            )  # {"q": [2*Hkv+p, T, hd], "s": [2*Hkv, T]}
-            new = (fused["q"], fused["s"])
-        else:
-            new = (
-                k.transpose(1, 0, 2).astype(cache_k.dtype),
-                v.transpose(1, 0, 2).astype(cache_v.dtype),
-            )
+        with jax.named_scope("kv_append"):
+            if quantized:
+                fused = fuse_prompt_kv(
+                    k.transpose(1, 0, 2), v.transpose(1, 0, 2),
+                    scale_dtype=cache_k["s"].dtype,
+                )  # {"q": [2*Hkv+p, T, hd], "s": [2*Hkv, T]}
+                new = (fused["q"], fused["s"])
+            else:
+                new = (
+                    k.transpose(1, 0, 2).astype(cache_k.dtype),
+                    v.transpose(1, 0, 2).astype(cache_v.dtype),
+                )
         return (h, li + 1), new
 
     (h, _), (new_a, new_b) = jax.lax.scan(
@@ -1109,15 +1120,16 @@ def llama_prefill_chunk_ragged(
     # ---- writes last, all layers at once (`ragged_write_rows`). Paging keeps
     # writes at identity arena homes — COW re-homing is host-side ledger
     # machinery, so the writes need no tables.
-    if quantized:
-        new_k = {
-            "q": ragged_write_rows(cache_k["q"], new_a, slots, starts, offsets),
-            "s": ragged_write_rows(cache_k["s"], new_b, slots, starts, offsets),
-        }
-        new_v = cache_v
-    else:
-        new_k = ragged_write_rows(cache_k, new_a, slots, starts, offsets)
-        new_v = ragged_write_rows(cache_v, new_b, slots, starts, offsets)
+    with jax.named_scope("kv_append"):
+        if quantized:
+            new_k = {
+                "q": ragged_write_rows(cache_k["q"], new_a, slots, starts, offsets),
+                "s": ragged_write_rows(cache_k["s"], new_b, slots, starts, offsets),
+            }
+            new_v = cache_v
+        else:
+            new_k = ragged_write_rows(cache_k, new_a, slots, starts, offsets)
+            new_v = ragged_write_rows(cache_v, new_b, slots, starts, offsets)
     last = jnp.take(h, jnp.clip(last_idx, 0, T - 1), axis=0)  # [R, D]
     return _logits(cfg, params, last), new_k, new_v
 
@@ -1237,73 +1249,74 @@ def llama_decode_step(
     def layer(carry, xs):
         lp, win = xs
         h, ck_all, cv_all, li = carry
-        x = _norm(cfg, h, lp["attn_norm"])
-        q, k, v = _qkv(cfg, lp, x)
-        q = q.reshape(Ba, H, hd)
-        k = k.reshape(Ba, Hkv, hd)
-        v = v.reshape(Ba, Hkv, hd)
-        q = apply_rope(q[:, None], cos[:, None], sin[:, None])[:, 0]  # [Ba, H, hd]
-        k = apply_rope(k[:, None], cos[:, None], sin[:, None])[:, 0]
+        with jax.named_scope("attn"):
+            x = _norm(cfg, h, lp["attn_norm"])
+            q, k, v = _qkv(cfg, lp, x)
+            q = q.reshape(Ba, H, hd)
+            k = k.reshape(Ba, Hkv, hd)
+            v = v.reshape(Ba, Hkv, hd)
+            q = apply_rope(q[:, None], cos[:, None], sin[:, None])[:, 0]  # [Ba, H, hd]
+            k = apply_rope(k[:, None], cos[:, None], sin[:, None])[:, 0]
 
-        qg = q.reshape(Ba, Hkv, G, hd)
-        # Append this step's K/V row to the carry, quantizing into the FUSED
-        # layout when the cache is int8. The scatter happens BEFORE the
-        # attention read: write-after-read on the carried buffer would cost
-        # XLA a full-cache defensive copy (~10 ms at 8B B=64).
-        if quantized:
-            kq = quantize_kv(k, scale_dtype=ck_all["s"].dtype)
-            vq = quantize_kv(v, scale_dtype=ck_all["s"].dtype)
-            s_new = jnp.concatenate([kq["s"], vq["s"]], axis=1)  # [Ba, 2*Hkv]
-            pay = jnp.concatenate([kq["q"], vq["q"]], axis=1)  # [Ba, 2*Hkv, hd]
-            if ck_all["q"].shape[2] > 2 * Hkv:
-                # keep the packed pseudo-head consistent too: snapshots /
-                # path switches must see one coherent fused entry
-                pay = jnp.concatenate(
-                    [pay, pack_scales(s_new[..., None], hd)[..., 0, :]], axis=1
+            qg = q.reshape(Ba, Hkv, G, hd)
+            # Append this step's K/V row to the carry, quantizing into the FUSED
+            # layout when the cache is int8. The scatter happens BEFORE the
+            # attention read: write-after-read on the carried buffer would cost
+            # XLA a full-cache defensive copy (~10 ms at 8B B=64).
+            if quantized:
+                kq = quantize_kv(k, scale_dtype=ck_all["s"].dtype)
+                vq = quantize_kv(v, scale_dtype=ck_all["s"].dtype)
+                s_new = jnp.concatenate([kq["s"], vq["s"]], axis=1)  # [Ba, 2*Hkv]
+                pay = jnp.concatenate([kq["q"], vq["q"]], axis=1)  # [Ba, 2*Hkv, hd]
+                if ck_all["q"].shape[2] > 2 * Hkv:
+                    # keep the packed pseudo-head consistent too: snapshots /
+                    # path switches must see one coherent fused entry
+                    pay = jnp.concatenate(
+                        [pay, pack_scales(s_new[..., None], hd)[..., 0, :]], axis=1
+                    )
+                hf_idx = jnp.arange(pay.shape[1])[None, :]
+                hs_idx = jnp.arange(2 * Hkv)[None, :]
+                ck_all = {
+                    "q": ck_all["q"].at[li, b_idx, hf_idx, w_idx].set(pay),
+                    "s": ck_all["s"].at[li, b_idx, hs_idx, w_idx].set(s_new),
+                }
+            else:
+                ck_all = ck_all.at[li, b_idx, h_idx, w_idx].set(k.astype(ck_all.dtype))
+                cv_all = cv_all.at[li, b_idx, h_idx, w_idx].set(v.astype(cv_all.dtype))
+
+            if quantized:
+                payl = csel(ck_all["q"], li, None if paged is None else paged["k"]["q"])
+                ssl = csel(ck_all["s"], li, None if paged is None else paged["k"]["s"])
+                ck, cv = payl[:, :Hkv], payl[:, Hkv : 2 * Hkv]
+                ks, vs = ssl[:, :Hkv], ssl[:, Hkv:]
+                # int8 K dot in compute dtype; per-key-token dequant scales the
+                # SCORES (cheap [Ba,Hkv,G,S] multiply), not the K payload
+                scores = jnp.einsum("bhgd,bhsd->bhgs", qg, ck.astype(h.dtype)).astype(
+                    jnp.float32
+                ) * ks.astype(jnp.float32)[:, :, None, :]
+                scores = _softcap(scores * cfg.attn_scale, cfg.attn_softcap)
+                m = attn_mask
+                if cfg.sliding_window:
+                    m = m & ((win == 0) | (key_pos > (lengths[:, None] - win)))
+                scores = jnp.where(m[:, None, None, :], scores, neg)
+                probs = jax.nn.softmax(scores, axis=-1)
+                # v's dequant folds into the probs before the PV dot
+                probs = (probs * vs.astype(jnp.float32)[:, :, None, :]).astype(h.dtype)
+                ctx = jnp.einsum("bhgs,bhsd->bhgd", probs, cv.astype(h.dtype)).reshape(
+                    Ba, H * hd
                 )
-            hf_idx = jnp.arange(pay.shape[1])[None, :]
-            hs_idx = jnp.arange(2 * Hkv)[None, :]
-            ck_all = {
-                "q": ck_all["q"].at[li, b_idx, hf_idx, w_idx].set(pay),
-                "s": ck_all["s"].at[li, b_idx, hs_idx, w_idx].set(s_new),
-            }
-        else:
-            ck_all = ck_all.at[li, b_idx, h_idx, w_idx].set(k.astype(ck_all.dtype))
-            cv_all = cv_all.at[li, b_idx, h_idx, w_idx].set(v.astype(cv_all.dtype))
-
-        if quantized:
-            payl = csel(ck_all["q"], li, None if paged is None else paged["k"]["q"])
-            ssl = csel(ck_all["s"], li, None if paged is None else paged["k"]["s"])
-            ck, cv = payl[:, :Hkv], payl[:, Hkv : 2 * Hkv]
-            ks, vs = ssl[:, :Hkv], ssl[:, Hkv:]
-            # int8 K dot in compute dtype; per-key-token dequant scales the
-            # SCORES (cheap [Ba,Hkv,G,S] multiply), not the K payload
-            scores = jnp.einsum("bhgd,bhsd->bhgs", qg, ck.astype(h.dtype)).astype(
-                jnp.float32
-            ) * ks.astype(jnp.float32)[:, :, None, :]
-            scores = _softcap(scores * cfg.attn_scale, cfg.attn_softcap)
-            m = attn_mask
-            if cfg.sliding_window:
-                m = m & ((win == 0) | (key_pos > (lengths[:, None] - win)))
-            scores = jnp.where(m[:, None, None, :], scores, neg)
-            probs = jax.nn.softmax(scores, axis=-1)
-            # v's dequant folds into the probs before the PV dot
-            probs = (probs * vs.astype(jnp.float32)[:, :, None, :]).astype(h.dtype)
-            ctx = jnp.einsum("bhgs,bhsd->bhgd", probs, cv.astype(h.dtype)).reshape(
-                Ba, H * hd
-            )
-        else:
-            ck = csel(ck_all, li, None if paged is None else paged["k"])
-            cv = csel(cv_all, li, None if paged is None else paged["v"])
-            scores = jnp.einsum("bhgd,bhsd->bhgs", qg, ck).astype(jnp.float32)
-            scores = _softcap(scores * cfg.attn_scale, cfg.attn_softcap)
-            m = attn_mask
-            if cfg.sliding_window:
-                m = m & ((win == 0) | (key_pos > (lengths[:, None] - win)))
-            scores = jnp.where(m[:, None, None, :], scores, neg)
-            probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
-            ctx = jnp.einsum("bhgs,bhsd->bhgd", probs, cv).reshape(Ba, H * hd)
-        h = _attn_residual(cfg, lp, ctx, h)
+            else:
+                ck = csel(ck_all, li, None if paged is None else paged["k"])
+                cv = csel(cv_all, li, None if paged is None else paged["v"])
+                scores = jnp.einsum("bhgd,bhsd->bhgs", qg, ck).astype(jnp.float32)
+                scores = _softcap(scores * cfg.attn_scale, cfg.attn_softcap)
+                m = attn_mask
+                if cfg.sliding_window:
+                    m = m & ((win == 0) | (key_pos > (lengths[:, None] - win)))
+                scores = jnp.where(m[:, None, None, :], scores, neg)
+                probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
+                ctx = jnp.einsum("bhgs,bhsd->bhgd", probs, cv).reshape(Ba, H * hd)
+            h = _attn_residual(cfg, lp, ctx, h)
         h = _ffn_residual(cfg, lp, h, moe_capacity=Ba)  # dropless at decode
         return (h, ck_all, cv_all, li + 1), None
 

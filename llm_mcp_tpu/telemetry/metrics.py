@@ -322,8 +322,9 @@ class Metrics:
             registry=r,
         )
         # Fed from CompileLedger.drain_fresh() at engines_info refresh:
-        # one observation per jit/bucket compile on the serve path. hit is
-        # the persistent-cache heuristic (wall < TPU_COMPILE_HIT_S).
+        # one observation per first dispatch of an executable shape. hit is
+        # JAX's own answer for that dispatch (hit / miss; unknown where JAX
+        # made no compile request to the persistent cache).
         self.compile_seconds = Histogram(
             "llmtpu_compile_seconds",
             "Wall time of serve-path executable compiles, per phase and cache outcome",

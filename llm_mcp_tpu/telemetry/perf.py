@@ -16,12 +16,20 @@ parts:
    raw tok/s the dashboard has always shown.
 
 2. **Phase attribution** — every Nth dispatch (`TPU_PERF_SAMPLE`, dynamic;
-   0 disables) the engine brackets one round with a device sync and
-   reports {host staging, device compute, scheduler wait} walls per
-   dispatch phase. The CompileLedger times only *first* dispatches; this
-   is the steady-state complement, and it is sampled precisely so the
-   pipelined loop only pays a serializing block_until_ready once per N
-   rounds.
+   0 disables) the engine reports {host staging, device compute, scheduler
+   wait} walls per dispatch phase. The CompileLedger times only *first*
+   dispatches; this is the steady-state complement. A decode round's
+   sample is counted at its dispatch. Nothing blocks for its device
+   seconds (with two rounds in flight a `block_until_ready` at dispatch
+   waits for the round before as well, and serialises the pipeline): EVERY
+   round whose device time can be told at its fetch, as the interval since
+   the previous round's fetch when the two were dispatched back to back
+   and the host waited for both, gives its seconds, rows and tokens
+   together (`observe_device`), and the roofline's token rate and the rows
+   it is evaluated at are those. A
+   sampled round that can be told adds its seconds to the phase's
+   `device_s` too. The synchronous prefill-family dispatches time their
+   own sync.
 
 3. **Rooflines** — analytical FLOPs and HBM-byte cost models per cache
    layout (bf16/int8 × GQA/MLA, including the fused int8 layout's scale
@@ -54,6 +62,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass
+from itertools import islice
 from typing import Any
 
 __all__ = [
@@ -93,6 +102,15 @@ WARMUP_PHASES = ("admit", "chunk", "decode", "pf_rag")
 CACHE_LAYOUTS = ("gqa_bf16", "gqa_int8", "mla_bf16", "mla_int8")
 
 DEFAULT_PERF_SAMPLE = 32
+# Timestamped sample windows (observe_sample / samples), values in seconds:
+# event_gap — between a stream's successive text events as the engine puts
+#   them on its queue, whole (a round's tokens arrive as ONE event, so this
+#   is what a reader of the stream sees, where the ITL window spreads the
+#   gap over the round's tokens);
+# stream_lag — from the engine's put of a text event to the moment the HTTP
+#   handler has written its SSE frame to the socket.
+SAMPLE_KINDS = ("event_gap", "stream_lag")
+SAMPLE_WINDOW = 32768  # about two minutes of 32 streams at 7 rounds a second
 DEFAULT_TARGET_ITL_MS = 0.0  # no ITL SLO unless configured
 # Published per-chip peaks keyed by JAX `device_kind`: (bf16 TFLOP/s, HBM
 # GB/s). Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
@@ -349,6 +367,9 @@ class PerfObservatory:
         self._itl_fresh = deque(maxlen=8192)
         self._itl_count = 0
         self._itl_sum_s = 0.0
+        # one generic timestamped window: kind -> (time.monotonic(), value),
+        # so that a reader can cut by its own window (SAMPLE_KINDS)
+        self._samples = {k: deque(maxlen=SAMPLE_WINDOW) for k in SAMPLE_KINDS}
         # goodput ledger: lifetime counters + a rolling (ts, tokens, good)
         # window for the live tok/s split
         self.finished_requests = 0
@@ -371,6 +392,10 @@ class PerfObservatory:
             for p in DISPATCH_PHASES
         }
         self._dispatches = {p: 0 for p in DISPATCH_PHASES}
+        # decode-family rounds whose device time could be told at their
+        # fetch, sampled or not: seconds and tokens of the SAME rounds, the
+        # roofline's measured token rate (observe_device)
+        self._told = {"rounds": 0, "device_s": 0.0, "tokens": 0, "rows": 0}
         # live decode-shape EMAs feeding the roofline (mean context, rows)
         self._ctx_ema = 0.0
         self._rows_ema = 0.0
@@ -433,6 +458,36 @@ class PerfObservatory:
             vals = list(self._itl_fresh)
             self._itl_fresh.clear()
         return vals
+
+    # -- timestamped samples -----------------------------------------------
+
+    def observe_sample(self, kind: str, value_s: float) -> None:
+        """One sample of a SAMPLE_KINDS window, stamped time.monotonic().
+        Written by the engine thread and the HTTP handlers' threads."""
+        win = self._samples.get(kind)
+        if win is not None:
+            with self._lock:
+                win.append((time.monotonic(), max(0.0, value_s)))
+
+    def samples(self, kind: str) -> list[tuple[float, float]]:
+        """(time.monotonic(), seconds) of every sample the window holds."""
+        with self._lock:
+            return list(self._samples.get(kind, ()))
+
+    def sample_percentiles(self, kind: str) -> dict[str, float]:
+        """Over the newest samples only (the ITL window's size), and only
+        those are copied under the lock, which the engine thread takes on
+        every text event: the dashboard asks often, a reader with a window
+        of its own cuts `samples()` itself."""
+        with self._lock:
+            win = self._samples[kind]
+            vals = [v for _t, v in islice(reversed(win), self._itl.maxlen)]
+        vals.sort()
+        return {
+            "p50_ms": _pctl(vals, 0.50) * 1e3,
+            "p95_ms": _pctl(vals, 0.95) * 1e3,
+            "samples": float(len(vals)),
+        }
 
     # -- goodput accounting ------------------------------------------------
 
@@ -578,6 +633,25 @@ class PerfObservatory:
                     else 0.8 * self._ctx_ema + 0.2 * ctx_mean
                 )
 
+    def observe_device(
+        self, phase: str, device_s: float, rows: int, tokens: int, sampled: bool
+    ) -> None:
+        """A decode round whose device seconds could be told at its fetch,
+        with the rows and tokens of that same round. `sampled`:
+        `observe_phase` counted this round at its dispatch, with no device
+        seconds yet."""
+        rec = self._phases.get(phase)
+        if rec is None:
+            return
+        device_s = max(0.0, device_s)
+        with self._lock:
+            self._told["rounds"] += 1
+            self._told["device_s"] += device_s
+            self._told["tokens"] += max(0, tokens)
+            self._told["rows"] += max(0, rows)
+            if sampled:
+                rec["device_s"] += device_s
+
     def phase_attribution(self) -> dict[str, dict[str, float]]:
         with self._lock:
             return {
@@ -593,20 +667,6 @@ class PerfObservatory:
 
     # -- roofline ----------------------------------------------------------
 
-    def _decode_device_tok_per_s(self) -> float:
-        """Sampled decode-family token rate while the device was actually
-        computing — the roofline's measured input."""
-        with self._lock:
-            dev = sum(
-                self._phases[p]["device_s"]
-                for p in ("decode", "fused", "fused_rag")
-            )
-            tok = sum(
-                self._phases[p]["tokens"]
-                for p in ("decode", "fused", "fused_rag")
-            )
-        return tok / dev if dev > 0 else 0.0
-
     def roofline(self) -> dict[str, Any]:
         """FLOPs and HBM bytes per token for every cache layout at the live
         decode shape and, where `device_kind` has published peaks, MFU/MBU
@@ -614,12 +674,25 @@ class PerfObservatory:
         walls; the four layouts share it so the non-active rows read as
         what-ifs."""
         peaks = CHIP_PEAKS.get(self.device_kind)
-        tok_s = self._decode_device_tok_per_s()
+        with self._lock:
+            told = dict(self._told)
+        # the measured rate, and the rows it was measured at, are those of
+        # the rounds whose device time could be told (the sampled rows EMA
+        # stands in until one has been)
+        tok_s = told["tokens"] / told["device_s"] if told["device_s"] > 0 else 0.0
         ctx = self._ctx_ema or 1.0
-        rows = self._rows_ema or 1.0
+        rows = (
+            told["rows"] / told["rounds"] if told["rows"]
+            else self._rows_ema or 1.0
+        )
         out: dict[str, Any] = {
             "device_kind": self.device_kind,
             "device_tok_per_s": round(tok_s, 1),
+            # lifetime sums behind the rate: a reader with a window of its
+            # own takes end minus start
+            "device_rounds": float(told["rounds"]),
+            "device_s": round(told["device_s"], 6),
+            "device_tokens": float(told["tokens"]),
             "ctx_mean": round(ctx, 1),
             "rows_mean": round(rows, 2),
             "active_layout": self.active_layout,
@@ -667,6 +740,7 @@ class PerfObservatory:
         return {
             "sample_every": float(self.sample_every),
             "itl": self.itl_percentiles(),
+            **{k: self.sample_percentiles(k) for k in SAMPLE_KINDS},
             "itl_mean_ms": (
                 self._itl_sum_s / self._itl_count * 1e3
                 if self._itl_count else 0.0

@@ -24,7 +24,12 @@ An *event* is a tuple ``(seq, ts, etype, trace_id, fields)``:
   ts        wall-clock seconds
   etype     short event kind: admit / budget / chunk / pf_rag (packed
             ragged prefill, with true/padded token fields) / verify /
-            decode / fused / fused_rag (ragged fused step) / preempt /
+            decode / fused / fused_rag (ragged fused step; one of the
+            three at a round's DISPATCH, with rid and rows) / fetch (the
+            round's blocking read returned: rid, wait_ms) / emit (the
+            round's tokens went to their streams: rid, rows dispatched,
+            tokens delivered, text events put, rows held = tokens and no
+            text, dur_ms; these five carry t = time.monotonic()) / preempt /
             offload / restore / cow / pin / unpin / snap (paged ledger
             snapshot for preempt/offload) / pg_tbl (device
             block-table reset/rebuild, with the shared-row count) /
@@ -70,8 +75,7 @@ true no-op — no ring writes, no dumps, no detector state).
 Knobs: `TPU_FLIGHT` (default 1), `TPU_FLIGHT_RING` (ring capacity,
 default 8192), `TPU_FLIGHT_DIR` (journal directory), and
 `TPU_FLIGHT_DUMP_INTERVAL_S` (min seconds between anomaly dumps,
-default 10).  `TPU_COMPILE_HIT_S` tunes the compile ledger's
-cache-hit heuristic.  `TPU_FLIGHT_PROFILE_STEPS` is read by the engine
+default 10).  `TPU_FLIGHT_PROFILE_STEPS` is read by the engine
 (the jax.profiler hook lives there, not here).
 """
 
@@ -105,9 +109,12 @@ __all__ = [
 
 DEFAULT_RING = 8192
 DEFAULT_DUMP_INTERVAL_S = 10.0
-# Persistent-compilation-cache hits deserialize in well under this; real
-# XLA compiles of serve-path executables take multiples of it.
-DEFAULT_HIT_THRESHOLD_S = 0.25
+# What JAX itself reports inside a first dispatch (executor/compile_watch.py
+# hands them in as plain numbers): seconds tracing, lowering, in the backend
+# (compile, or load from the persistent cache), reading the cache, and the
+# number of executables JAX asked the cache for.
+COMPILE_PARTS = ("trace_s", "lower_s", "backend_s", "cache_load_s",
+                 "compile_requests")
 
 EVENT_KEYS = ("seq", "ts", "etype", "trace_id", "fields")
 
@@ -575,44 +582,58 @@ class AnomalyMonitor:
 
 
 class CompileLedger:
-    """Every jit/bucket compile on the serve path, as (phase, bucket key,
-    wall seconds, cache hit/miss). Entries land in a bounded deque; per-key
-    aggregates build the queryable table /v1/debug/compiles serves; the
-    metrics layer drains new entries into `llmtpu_compile_seconds`.
+    """Every first dispatch of an executable shape, as (phase, bucket key,
+    wall seconds, source) and what JAX reported inside it: seconds tracing,
+    lowering and in the backend, seconds reading the persistent cache, the
+    number of compile requests, and whether they were cache hits. Entries
+    land in a bounded deque; per-key aggregates build the queryable table
+    /v1/debug/compiles serves; the metrics layer drains new entries into
+    `llmtpu_compile_seconds`.
 
-    Hit/miss is a wall-time heuristic: jax's persistent compilation cache
-    deserializes in well under `hit_threshold_s` while a real XLA compile
-    of a serve executable takes multiples of it (`TPU_COMPILE_HIT_S`
-    tunes the split; an explicit hit= wins when the caller knows)."""
+    `hit` is JAX's own answer (`parts["hit"]`: every compile request of the
+    dispatch was served from the persistent cache), or an explicit `hit=`
+    from a caller that knows; None where JAX made no compile request (the
+    dispatch found its executable in memory, or the cache is off)."""
 
-    def __init__(self, max_entries: int = 512,
-                 hit_threshold_s: float | None = None):
-        self.hit_threshold_s = (
-            hit_threshold_s if hit_threshold_s is not None
-            else _env_float("TPU_COMPILE_HIT_S", DEFAULT_HIT_THRESHOLD_S)
-        )
+    def __init__(self, max_entries: int = 512):
         self._lock = threading.Lock()
         self._entries: deque = deque(maxlen=max(16, max_entries))
         self._by_key: dict[str, dict[str, Any]] = {}
         self._fresh: deque = deque(maxlen=max(16, max_entries))
         self._total_s = 0.0
+        self._parts: dict[str, dict[str, float]] = {}  # src -> sums
+
+    @staticmethod
+    def _add_parts(sums: dict[str, dict[str, float]], entry: dict) -> None:
+        row = sums.setdefault(entry["src"], {
+            "entries": 0, "wall_s": 0.0, **{k: 0.0 for k in COMPILE_PARTS}})
+        row["entries"] += 1
+        row["wall_s"] = round(row["wall_s"] + entry["wall_s"], 6)
+        for k in COMPILE_PARTS:
+            row[k] = round(row[k] + entry[k], 6)
 
     def observe(self, phase: str, key: str, wall_s: float,
-                hit: bool | None = None, src: str = "serve") -> dict[str, Any]:
+                hit: bool | None = None, src: str = "serve",
+                parts: dict[str, Any] | None = None) -> dict[str, Any]:
         """`src` is provenance: which path paid (or skipped) this compile —
         "serve" (first real dispatch), "warmup" (AOT warmup planner), or
         "import" (a warmup-pack plan entry adopted without compiling).
         Per-entry so /v1/debug/compiles can show whether the serve path
-        ever ate a cold compile that warmup should have absorbed."""
+        ever ate a cold compile that warmup should have absorbed. `parts`
+        is what JAX reported on the dispatching thread (COMPILE_PARTS and
+        `hit`); absent where nobody listened."""
+        parts = parts or {}
         if hit is None:
-            hit = wall_s < self.hit_threshold_s
+            hit = parts.get("hit")
         entry = {
             "ts": time.time(),
+            "t": time.monotonic(),
             "phase": phase,
             "key": key,
             "wall_s": round(float(wall_s), 6),
-            "hit": bool(hit),
+            "hit": None if hit is None else bool(hit),
             "src": str(src),
+            **{k: round(float(parts.get(k, 0.0)), 6) for k in COMPILE_PARTS},
         }
         with self._lock:
             self._entries.append(entry)
@@ -623,20 +644,25 @@ class CompileLedger:
                 self._by_key[key] = agg = {
                     "key": key, "phase": phase, "count": 0,
                     "hits": 0, "misses": 0, "total_s": 0.0, "max_s": 0.0,
-                    "by_src": {},
+                    "by_src": {}, "parts": {},
                 }
             agg["count"] += 1
-            agg["hits" if hit else "misses"] += 1
+            if hit is not None:
+                agg["hits" if hit else "misses"] += 1
             agg["total_s"] = round(agg["total_s"] + wall_s, 6)
             agg["max_s"] = round(max(agg["max_s"], float(wall_s)), 6)
-            agg.setdefault("by_src", {})
             agg["by_src"][entry["src"]] = agg["by_src"].get(entry["src"], 0) + 1
+            self._add_parts(agg["parts"], entry)
+            self._add_parts(self._parts, entry)
         return entry
 
     def table(self) -> list[dict[str, Any]]:
-        """Per-bucket aggregates, costliest first."""
+        """Per-bucket aggregates, costliest first. `parts` holds, by
+        source, the walls and what JAX reported inside them."""
         with self._lock:
-            rows = [dict(v) for v in self._by_key.values()]
+            rows = [dict(v, by_src=dict(v["by_src"]),
+                         parts={s: dict(p) for s, p in v["parts"].items()})
+                    for v in self._by_key.values()]
         return sorted(rows, key=lambda r: -r["total_s"])
 
     def entries(self, limit: int = 100) -> list[dict[str, Any]]:
@@ -656,19 +682,23 @@ class CompileLedger:
         with self._lock:
             n = len(self._entries)
             hits = sum(1 for e in self._entries if e["hit"])
+            misses = sum(1 for e in self._entries if e["hit"] is False)
             shapes = len(self._by_key)
             total = self._total_s
             by_src: dict[str, int] = {}
             for e in self._entries:
                 s = e.get("src", "serve")
                 by_src[s] = by_src.get(s, 0) + 1
+            parts = {s: dict(p) for s, p in self._parts.items()}
         return {
             "entries": n,
             "hits": hits,
-            "misses": n - hits,
+            "misses": misses,
             "shapes": shapes,
             "total_s": round(total, 6),
             "by_src": by_src,
+            # lifetime sums by source: wall_s and COMPILE_PARTS
+            "parts": parts,
         }
 
 

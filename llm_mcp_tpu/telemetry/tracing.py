@@ -43,6 +43,7 @@ as one JSON line (the format `scripts/trace_dump.py` reads back).
 
 from __future__ import annotations
 
+import ctypes
 import json
 import os
 import re
@@ -77,6 +78,21 @@ DEFAULT_MAX_TRACES = 512
 NEW_TRACE = object()
 # Probe endpoints would otherwise evict every interesting trace from the ring.
 UNTRACED_PATHS = frozenset({"/health", "/metrics"})
+
+
+try:  # resolved once: handler threads name themselves on every request
+    _prctl = ctypes.CDLL(None).prctl
+except (OSError, AttributeError):  # not Linux
+    _prctl = None
+
+
+def name_os_thread(name: str) -> None:
+    """Give the calling thread an OS-level name (Linux, 15 bytes). A profiler
+    trace names a host line after its thread's OS name, and Python's own
+    thread names do not reach the OS: every Python thread's line reads
+    `python3`."""
+    if _prctl is not None:
+        _prctl(15, name.encode()[:15], 0, 0, 0)  # PR_SET_NAME
 
 
 def _new_trace_id() -> str:

@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import argparse
 import os
+import re
 import sys
 import time
 
@@ -142,9 +143,11 @@ def main() -> int:
         need = (ma.argument_size_in_bytes + ma.output_size_in_bytes
                 + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
         txt = compiled.as_text()
+        kernels = sorted({re.sub(r"(\.\d+)+$", "", m) for m in re.findall(
+            r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"", txt)})
         print(f"ok {phase} {key}: {time.time() - t1:.1f} s  "
-              f"tpu_custom_call={txt.count('tpu_custom_call')}  "
-              f"per-device bytes={need / 2**30:.2f} GiB "
+              f"tpu_custom_call={txt.count('tpu_custom_call')} {kernels}  "
+              f"per-device bytes={need} = {need / 2**30:.2f} GiB "
               f"(args {ma.argument_size_in_bytes / 2**30:.2f}, "
               f"temp {ma.temp_size_in_bytes / 2**30:.2f}, "
               f"aliased {ma.alias_size_in_bytes / 2**30:.2f})"

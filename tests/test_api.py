@@ -217,6 +217,29 @@ def test_embeddings_single_and_batch(base):
     assert all(len(d["embedding"]) == 16 for d in body["data"])
 
 
+def test_embedding_counters_reach_an_operator(base, server):
+    """EmbeddingEngine.stats() is read by engines_info: /v1/debug/health and
+    the dashboard show the forward counters, never the `recent` window."""
+    emb = server.embed_engines["tiny-embed"]
+    before = emb.stats(recent=False)
+    r = httpx.post(f"{base}/v1/embeddings", timeout=60.0,
+                   json={"model": "tiny-embed", "input": ["x" * 9, "y" * 30, "z"]})
+    assert r.status_code == 200
+    for url, path in ((f"{base}/v1/debug/health", ("checks", "engines")),
+                      (f"{base}/v1/dashboard", ("engines",))):
+        doc = httpx.get(url).json()
+        for k in path:
+            doc = doc[k]
+        blk = doc["tiny-embed"]
+        assert "recent" not in blk and blk["kind"] == "embed"
+        assert blk["forwards"] == before["forwards"] + 1
+        assert blk["rows"] == before["rows"] + 3
+        assert blk["rows_padded"] == before["rows_padded"] + 4
+        assert blk["padded_tokens"] > blk["true_tokens"] > before["true_tokens"]
+        assert blk["forward_s"] > before["forward_s"]
+        assert blk["host_locked_s"] > before["host_locked_s"] and blk["lock_wait_s"] >= 0
+
+
 def test_embeddings_validation(base):
     assert httpx.post(f"{base}/v1/embeddings", json={"input": 42}).status_code == 400
     assert httpx.post(f"{base}/v1/embeddings", json={"input": []}).status_code == 400

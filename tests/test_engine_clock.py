@@ -1,0 +1,341 @@
+"""The engine's clock inside the program (PR 24): the round's life in the
+flight ring (dispatch, fetch, emit on time.monotonic()), whole event gaps and
+stream lags in the perf observatory, first dispatches taken apart by JAX's
+own timers and kept out of the scheduler's cost model, the embedding
+engine's counters. On the CPU at a tiny size: counts and clocks' ORDER, never
+a rate."""
+
+import json
+import time
+
+import httpx
+import jax.numpy as jnp
+import pytest
+
+from llm_mcp_tpu.api.server import CoreServer
+from llm_mcp_tpu.executor import EmbeddingEngine, GenerationEngine, compile_watch
+from llm_mcp_tpu.state.db import Database
+from llm_mcp_tpu.telemetry import recorder as flight
+from llm_mcp_tpu.telemetry.perf import SAMPLE_KINDS, PerfObservatory
+from llm_mcp_tpu.telemetry.recorder import CompileLedger, FlightRecorder
+from llm_mcp_tpu.utils.config import Config
+
+K = 4  # decode_chunk
+
+
+@pytest.fixture(scope="module")
+def env(tmp_path_factory):
+    """A fresh ring and ledger, installed before the engine is built."""
+    rec = FlightRecorder(capacity=8192, dump_dir=str(tmp_path_factory.mktemp("flight")))
+    led = CompileLedger()
+    prev = flight.set_recorder(rec), flight.set_compile_ledger(led)
+    gen = GenerationEngine("tiny-llm", max_slots=4, max_seq_len=128, dtype=jnp.float32,
+                           decode_chunk=K).start()
+    srv = CoreServer(Config(), db=Database(":memory:"), gen_engines={"tiny-llm": gen}).start("127.0.0.1", 0)
+    yield gen, rec, led, f"http://127.0.0.1:{srv.api.port}"
+    srv.shutdown()
+    flight.set_recorder(prev[0])
+    flight.set_compile_ledger(prev[1])
+
+
+def ring(rec, etype):
+    return [e["fields"] for e in rec.snapshot(etype=etype)]
+
+
+def settle(gen, rec, n_emit):
+    """Wait until the loop has emitted `n_emit` rounds and gone idle."""
+    deadline = time.monotonic() + 30.0
+    while time.monotonic() < deadline:
+        if len(ring(rec, "emit")) >= n_emit and all(s is None for s in gen._slots):
+            return
+        time.sleep(0.02)
+    raise AssertionError(f"engine did not settle: {len(ring(rec, 'emit'))} emits")
+
+
+def test_a_round_is_recorded_at_dispatch_fetch_and_emit(env):
+    gen, rec, _led, _base = env
+    t0 = time.monotonic()
+    # 10 tokens: the first at admission, then 9 from rounds of 4: the third
+    # round delivers ONE token of the four it computed (the reply ends there)
+    out = gen.generate("the round's life", max_tokens=10, temperature=0.0)
+    assert out["usage"]["completion_tokens"] == 10
+    settle(gen, rec, 3)
+    t1 = time.monotonic()
+    disp, fetch, emit = ring(rec, "decode"), ring(rec, "fetch"), ring(rec, "emit")
+    rids = [d["rid"] for d in disp]
+    assert rids == sorted(rids) and len(rids) >= 3
+    assert [f["rid"] for f in fetch] == rids and [e["rid"] for e in emit] == rids
+    for d, f, e in zip(disp, fetch, emit):
+        # one clock, time.monotonic(), in the order a round lives
+        assert t0 <= d["t"] <= f["t"] <= e["t"] <= t1
+        assert f["wait_ms"] >= 0 and e["dur_ms"] >= 0
+        assert e["rows"] == d["rows"] == 1
+        assert 0 <= e["texts"] <= e["rows"] and e["held"] >= 0
+    assert [e["delivered"] for e in emit[:3]] == [4, 4, 1]
+    assert sum(e["delivered"] for e in emit) == 9
+    # rounds dispatched after the reply's end was fetched deliver nothing
+
+
+def test_decode_token_yield_counts_delivered_over_dispatched(env):
+    gen, rec, _led, _base = env
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from benchmark import run as bench_run
+
+    w0 = time.monotonic()
+    n_before = len(ring(rec, "emit"))
+    gen.generate("yield", max_tokens=6, temperature=0.0)  # 1 + 4 + 1 of 4
+    settle(gen, rec, n_before + 2)
+    run = {"sut": {"gen": gen}, "window_abs": (w0, time.monotonic())}
+    emits = [e for e in ring(rec, "emit") if e["t"] >= w0]
+    rows, delivered = sum(e["rows"] for e in emits), sum(e["delivered"] for e in emits)
+    assert delivered == 5 and rows >= 2
+    reader = bench_run.load_reader("layer_metrics", "decode_token_yield")
+    assert reader.read(run) == pytest.approx(100.0 * delivered / (rows * K))
+    assert reader.read(run) <= 100.0 * 5 / 8
+    assert reader.read(dict(run, window_abs=(0.0, 1e-9))) is None  # nothing in the window
+
+
+def test_event_gap_is_whole_and_stream_lag_survives_the_handler(env):
+    gen, rec, _led, base = env
+    before = {k: len(gen._perf.samples(k)) for k in SAMPLE_KINDS}
+    itl_before = gen.perf_stats()["itl"]["samples"]
+    events, stamps = [], []
+    orig = gen.observe_stream_write
+    gen.observe_stream_write = lambda t: (stamps.append(t), orig(t))[1]
+    t0 = time.monotonic()
+    try:
+        with httpx.stream("POST", f"{base}/v1/chat/completions", timeout=120.0, json={
+                "model": "tiny-llm", "stream": True, "max_tokens": 17, "temperature": 0,
+                "messages": [{"role": "user", "content": "gaps"}]}) as r:
+            for line in r.iter_lines():
+                if line.startswith("data: {"):
+                    delta = json.loads(line[6:])["choices"][0]["delta"]
+                    if delta.get("content"):
+                        events.append(delta["content"])
+    finally:
+        gen.observe_stream_write = orig
+    t1 = time.monotonic()
+    gaps = gen._perf.samples("event_gap")[before["event_gap"]:]
+    lags = gen._perf.samples("stream_lag")[before["stream_lag"]:]
+    # one lag a frame written, each from a stamp the engine put on the event
+    assert len(lags) == len(events) == len(stamps) >= 3
+    assert all(t0 <= s <= t1 for s in stamps) and stamps == sorted(stamps)
+    assert all(t0 <= t <= t1 and 0 <= lag < t1 - t0 for t, lag in lags)
+    # one gap between successive text events of the stream, whole
+    assert len(gaps) == len(events) - 1
+    assert [round(g, 6) for _t, g in gaps] == [round(b - a, 6) for a, b in zip(stamps, stamps[1:])]
+    # the ITL window spreads the same gaps over each round's tokens
+    itl = gen.perf_stats()["itl"]
+    assert itl["samples"] - itl_before == 16
+    assert sum(g for _t, g in gaps) > 0
+    doc = httpx.get(f"{base}/v1/debug/perf").json()["tiny-llm"]
+    assert doc["event_gap"]["samples"] >= len(gaps) and doc["stream_lag"]["samples"] >= len(lags)
+    assert doc["event_gap"]["p95_ms"] >= doc["event_gap"]["p50_ms"] > 0
+
+
+def test_observe_sample_is_a_timestamped_bounded_window():
+    obs = PerfObservatory()
+    t0 = time.monotonic()
+    obs.observe_sample("event_gap", 0.25)
+    obs.observe_sample("event_gap", -1.0)  # clamped
+    obs.observe_sample("no_such_kind", 1.0)  # dropped
+    (ta, a), (tb, b) = obs.samples("event_gap")
+    assert (a, b) == (0.25, 0.0) and t0 <= ta <= tb <= time.monotonic()
+    assert obs.samples("stream_lag") == [] and obs.samples("no_such_kind") == []
+    assert obs.sample_percentiles("event_gap") == {"p50_ms": 0.0, "p95_ms": 250.0, "samples": 2.0}
+    assert obs._samples["event_gap"].maxlen >= 8192
+
+
+def test_device_seconds_are_told_at_the_fetch_with_their_own_tokens(env, monkeypatch):
+    gen, rec, _led, _base = env
+    monkeypatch.setenv("TPU_PERF_SAMPLE", "1")
+    import inspect
+
+    assert "block_until_ready" not in inspect.getsource(GenerationEngine._dispatch_decode)
+    before = gen.perf_stats()["phases"]["decode"]
+    told0 = dict(gen._perf._told)
+    n = len(ring(rec, "emit"))
+    gen.generate("sampled", max_tokens=14, temperature=0.0)
+    settle(gen, rec, n + 3)
+    after = gen.perf_stats()["phases"]["decode"]
+    got = int(after["samples"] - before["samples"])
+    assert got >= 1 and after["device_s"] > before["device_s"]
+    assert after["tokens"] - before["tokens"] == got * K  # rows x decode_chunk a sample
+    perf = [p for p in ring(rec, "perf") if p["phase"] == "decode"][-got:]
+    assert len(perf) == got  # one event a sample, told or not
+    told = [p for p in perf if p["device_ms"] is not None]
+    fetches = {f["rid"]: f for f in ring(rec, "fetch")}
+    disp = {d["rid"]: d for d in ring(rec, "decode")}
+    # a round's device time lies inside its dispatch-to-fetch span
+    spans = [1e3 * (fetches[r]["t"] - disp[r]["t"]) for r in fetches if r in disp]
+    assert told and all(0 < p["device_ms"] <= max(spans) + 1.0 for p in told)
+    # seconds and tokens of the SAME rounds: with every round sampled, the
+    # rounds told are the perf events that carry device seconds
+    d = {k: gen._perf._told[k] - told0[k] for k in told0}
+    assert d["rounds"] == d["rows"] == len(told) and d["tokens"] == K * len(told)
+    assert d["device_s"] == pytest.approx(after["device_s"] - before["device_s"], abs=1e-5)
+    rf = gen.perf_stats()["roofline"]
+    assert (rf["device_rounds"], rf["device_tokens"]) == (gen._perf._told["rounds"], gen._perf._told["tokens"])
+    assert rf["device_tok_per_s"] == pytest.approx(rf["device_tokens"] / rf["device_s"], rel=1e-3)
+
+
+def test_first_dispatches_are_taken_apart_and_leave_the_cost_model_alone(env):
+    gen, _rec, led, _base = env
+    gen.generate("first dispatches", max_tokens=6, temperature=0.0)  # if no test before this one did
+    serve = [e for e in led.entries(512) if e["src"] == "serve"]
+    assert {"admit", "decode"} <= {e["phase"] for e in serve}
+    for e in serve:
+        assert e["trace_s"] + e["lower_s"] + e["backend_s"] <= e["wall_s"] * 1.05 + 1e-3
+        assert (e["hit"] is None) == (e["compile_requests"] == 0)
+    assert all(e["trace_s"] > 0 and e["lower_s"] > 0 for e in serve if e["phase"] == "decode")
+    parts = led.stats()["parts"]["serve"]
+    assert parts["entries"] == len(serve)
+    assert parts["trace_s"] == pytest.approx(sum(e["trace_s"] for e in serve), abs=1e-4)
+    # the decode EMA never saw a first dispatch's wall: every compile here
+    # took longer than any steady round of the tiny model
+    slowest = max(e["wall_s"] for e in serve if e["phase"] in ("decode", "admit"))
+    assert gen._sched.decode_round_s < slowest
+    assert gen._first_end > 0
+
+
+def test_a_round_that_holds_a_compile_teaches_the_scheduler_nothing(env):
+    gen, _rec, _led, _base = env
+    from llm_mcp_tpu.executor.engine import _DispatchedRound
+    import numpy as np
+
+    ema = gen._sched.decode_round_s
+    cost = gen._sched.prefill_tok_s
+
+    def fake(t0, prefill=0):
+        return _DispatchedRound(out=jnp.zeros((K, 4), jnp.int32), entries=[], base=np.zeros(4, np.int32),
+                                t0=t0, rid=gen._rid_dispatched, prefill_tokens=prefill, prefill_padded=prefill)
+
+    now = time.perf_counter()
+    gen._first_end = now  # a first dispatch ended just now
+    gen._complete_round(fake(now - 7.0))  # dispatched before it: 7 s of compile inside
+    gen._complete_round(fake(now - 7.0, prefill=64))
+    assert (gen._sched.decode_round_s, gen._sched.prefill_tok_s) == (ema, cost)
+    gen._first_end = now - 10.0
+    gen._complete_round(fake(time.perf_counter() - 0.5))  # a clean round does teach
+    assert gen._sched.decode_round_s > ema
+
+
+def test_a_round_whose_device_time_cannot_be_told_gives_neither_seconds_nor_tokens(env, monkeypatch):
+    gen, rec, _led, _base = env
+    from llm_mcp_tpu.executor.engine import _DispatchedRound
+    import numpy as np
+
+    before = gen.perf_stats()["phases"]["decode"]
+    told0 = dict(gen._perf._told)
+    n_perf = len(ring(rec, "perf"))
+    # a sampled round fetched long after it ended (the read does not block)
+    late = _DispatchedRound(out=jnp.zeros((K, 4), jnp.int32), entries=[], base=np.zeros(4, np.int32),
+                            t0=time.perf_counter() - 0.3, rid=gen._rid_dispatched,
+                            t_disp=time.perf_counter() - 0.299, sample=(0.001, 0.0))
+    late.out.block_until_ready()
+    gen._complete_round(late)
+    assert gen.perf_stats()["phases"]["decode"] == before  # no 300 ms of "device" time
+    assert gen._perf._told == told0
+    assert ring(rec, "perf")[n_perf:] == [
+        {"phase": "decode", "host_ms": 1.0, "device_ms": None, "wait_ms": 0.0, "rows": 0}]
+    # with sampling far away (every 10,000th round) rounds that can tell still
+    # feed the token rate, seconds and tokens together, and count no sample
+    monkeypatch.setenv("TPU_PERF_SAMPLE", "10000")
+    n = len(ring(rec, "emit"))
+    gen.generate("sampled", max_tokens=14, temperature=0.0)  # a reply known to run its 14 tokens
+    settle(gen, rec, n + 3)
+    after = gen.perf_stats()["phases"]["decode"]
+    assert after == before and len(ring(rec, "perf")) == n_perf + 1
+    d = {k: gen._perf._told[k] - told0[k] for k in told0}
+    assert d["rounds"] >= 1 and d["tokens"] == K * d["rounds"] and 0 < d["device_s"] < 0.25 * d["rounds"]
+
+
+class FakeMonitoring:
+    """jax.monitoring's two registration calls, kept for the test to fire."""
+
+    def __init__(self):
+        self.dur, self.evt = [], []
+
+    def register_event_duration_secs_listener(self, fn):
+        self.dur.append(fn)
+
+    def register_event_listener(self, fn):
+        self.evt.append(fn)
+
+    def duration(self, name, s, **kw):
+        for fn in self.dur:
+            fn(name, s, **kw)
+
+    def event(self, name, **kw):
+        for fn in self.evt:
+            fn(name, **kw)
+
+
+@pytest.mark.parametrize("requests,hits,want", [(2, 2, True), (2, 1, False), (1, 0, False), (0, 0, None)])
+def test_compile_watch_sums_what_jax_reports_on_the_thread(monkeypatch, requests, hits, want):
+    import threading
+
+    import jax
+
+    fake = FakeMonitoring()
+    monkeypatch.setattr(jax, "monitoring", fake)
+    monkeypatch.setattr(compile_watch, "_registered", False)
+    assert compile_watch.end() is None  # no context open
+    fake_events = lambda: [fake.event("/jax/compilation_cache/compile_requests_use_cache")  # noqa: E731
+                           for _ in range(requests)] + [fake.event("/jax/compilation_cache/cache_hits")
+                                                        for _ in range(hits)]
+    compile_watch.begin()
+    assert len(fake.dur) == len(fake.evt) == 1  # registered once, on first use
+    fake.duration("/jax/core/compile/jaxpr_trace_duration", 0.002, fun_name="inner")  # nested in ...
+    time.sleep(0.005)
+    fake.duration("/jax/core/compile/jaxpr_trace_duration", 0.02, fun_name="outer")  # ... this one
+    fake.duration("/jax/core/compile/jaxpr_trace_duration", 1e-6, fun_name="helper")  # a second program
+    fake.duration("/jax/core/compile/jaxpr_to_mlir_module_duration", 0.3)
+    fake.duration("/jax/core/compile/backend_compile_duration", 1.5)
+    fake.duration("/jax/compilation_cache/cache_retrieval_time_sec", 1.25)
+    fake.duration("/jax/some/other/event", 99.0)
+    fake_events()
+    # another thread's compile (the warm-up zoo's) is not this dispatch's
+    other = threading.Thread(target=lambda: (fake.duration("/jax/core/compile/backend_compile_duration", 40.0),
+                                             fake.event("/jax/compilation_cache/cache_hits")))
+    other.start()
+    other.join()
+    parts = compile_watch.end()
+    assert parts == {"trace_s": pytest.approx(0.020001, abs=1e-5), "lower_s": 0.3, "backend_s": 1.5,
+                     "cache_load_s": 1.25, "compile_requests": requests, "hit": want}
+    compile_watch.begin()
+    assert len(fake.dur) == 1  # not registered again
+    # with no context open nothing is kept, and nothing raises
+    assert compile_watch.end()["backend_s"] == 0.0 and compile_watch.end() is None
+    fake.duration("/jax/core/compile/backend_compile_duration", 3.0)
+    e = CompileLedger().observe("decode", "32:False:True", 2.0, parts=parts)
+    assert e["hit"] is want and e["backend_s"] == 1.5
+
+
+def test_embedding_engine_stats_against_a_hand_count():
+    emb = EmbeddingEngine("tiny-embed", max_batch=4, max_seq_len=64, dtype=jnp.float32)
+    assert emb.stats() == {"forwards": 0, "rows": 0, "rows_padded": 0, "true_tokens": 0,
+                           "padded_tokens": 0, "lock_wait_s": 0.0, "forward_s": 0.0,
+                           "host_locked_s": 0.0, "recent": []}
+    texts = ["a" * 10, "b" * 40, "c" * 5, "d" * 20, "e" * 33, "f" * 3]  # 4 + 2 rows
+    lens = [len(emb.prepare_ids(t)) for t in texts]
+    t0 = time.monotonic()
+    vecs, total = emb.embed(texts, dimensions=8)
+    t1 = time.monotonic()
+    assert len(vecs) == 6 and len(vecs[0]) == 8 and total == sum(lens)
+    st = emb.stats()
+    b1, b2 = emb._bucket(max(lens[:4])), emb._bucket(max(lens[4:]))
+    assert (st["forwards"], st["rows"], st["rows_padded"]) == (2, 6, 4 + 2)
+    assert st["true_tokens"] == sum(lens) and st["padded_tokens"] == 4 * b1 + 2 * b2
+    assert len(st["recent"]) == 2
+    for t, fwd_s, host_s in st["recent"]:
+        assert t0 <= t <= t1 and fwd_s > 0 and host_s > 0
+    assert st["forward_s"] == pytest.approx(sum(r[1] for r in st["recent"]))
+    assert st["host_locked_s"] == pytest.approx(sum(r[2] for r in st["recent"]))
+    assert st["lock_wait_s"] >= 0 and st["forward_s"] + st["host_locked_s"] <= (t1 - t0)
+    emb.embed(["one more"])
+    assert emb.stats()["forwards"] == 3 and emb.stats()["rows_padded"] == 7

@@ -288,11 +288,14 @@ def test_roofline_four_layouts_against_one_measured_rate():
         SHAPE, active_layout="gqa_int8", paged=True, block_tokens=16,
         weight_bytes_per_param=1.0, device_kind=V5E,
     )
-    # 100 sampled decode tokens over 10ms of device wall -> 10k tok/s
-    obs.observe_phase("decode", 0.001, 0.010, tokens=100, rows=4,
+    # a sampled round of 100 tokens whose 10 ms of device wall were told at
+    # its fetch -> 10k tok/s
+    obs.observe_phase("decode", 0.001, 0.0, tokens=100, rows=4,
                       ctx_mean=512.0)
+    obs.observe_device("decode", 0.010, 4, 100, sampled=True)
     r = obs.roofline()
     assert r["device_tok_per_s"] == pytest.approx(10_000.0)
+    assert r["device_rounds"] == 1.0
     assert r["ctx_mean"] == 512.0 and r["rows_mean"] == 4.0
     assert set(r["layouts"]) == set(CACHE_LAYOUTS)
     assert [l for l, v in r["layouts"].items() if v["active"]] == ["gqa_int8"]
@@ -318,6 +321,30 @@ def test_roofline_four_layouts_against_one_measured_rate():
     assert r["device_kind"] == V5E
 
 
+def test_the_device_rate_pairs_seconds_and_tokens_of_the_same_rounds():
+    """Samples whose device seconds never arrive (an admission between every
+    two fetches) count for occupancy and leave the token rate alone; rounds
+    that were not sampled and could be told feed it."""
+    obs = PerfObservatory(SHAPE, device_kind=V5E)
+    for _ in range(5):  # five due samples, none told
+        obs.observe_phase("decode", 0.001, 0.0, tokens=128, rows=32, ctx_mean=200.0)
+    assert obs.roofline()["device_tok_per_s"] == 0.0
+    assert obs.roofline()["rows_mean"] == 32.0  # the sampled EMA, until a round can tell
+    obs.observe_phase("admit", 0.001, 0.010, tokens=40, rows=1)  # not a decode shape
+    obs.observe_device("fused", 0.100, 30, 120, sampled=False)  # one told round, not a sample
+    obs.observe_device("decode", 0.140, 32, 128, sampled=True)
+    obs.observe_device("nonsense", 1.0, 1, 1, sampled=True)  # unknown phase: dropped
+    r = obs.roofline()
+    assert r["device_tok_per_s"] == pytest.approx(248 / 0.240, rel=1e-3)
+    assert (r["device_rounds"], r["device_tokens"]) == (2.0, 248.0)
+    assert r["device_s"] == pytest.approx(0.240)
+    assert r["rows_mean"] == 31.0  # of the rounds the rate was measured on
+    att = obs.phase_attribution()
+    assert att["decode"]["samples"] == 5.0 and att["decode"]["tokens"] == 640.0
+    assert att["decode"]["device_s"] == pytest.approx(0.140)  # the sampled round's only
+    assert att["fused"]["device_s"] == 0.0
+
+
 def test_roofline_peaks_are_keyed_by_device_kind():
     """No default chip: a kind without published peaks (the CPU, a bare
     observatory) gets the counts from shapes and NO utilization, and asking
@@ -338,8 +365,9 @@ def test_stats_document_shape():
     st = PerfObservatory(SHAPE).stats()
     assert set(st) == {
         "sample_every", "itl", "itl_mean_ms", "goodput", "phases", "roofline",
-        "tenants",
+        "tenants", "event_gap", "stream_lag",
     }
+    assert st["event_gap"] == {"p50_ms": 0.0, "p95_ms": 0.0, "samples": 0.0}
     assert set(st["phases"]) == set(DISPATCH_PHASES)
     assert set(st["roofline"]["layouts"]) == set(CACHE_LAYOUTS)
 

@@ -320,26 +320,60 @@ def test_append_vs_dump_soak(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-def test_compile_ledger_aggregates_and_hit_heuristic():
-    led = CompileLedger(hit_threshold_s=0.25)
-    e1 = led.observe("decode", "4:4", 1.5)
+def test_compile_ledger_aggregates_and_explicit_hit():
+    led = CompileLedger()
+    e1 = led.observe("decode", "4:4", 1.5, hit=False)
     assert e1["hit"] is False
-    e2 = led.observe("decode", "4:4", 0.01)
+    e2 = led.observe("decode", "4:4", 0.01, hit=True)
     assert e2["hit"] is True
-    led.observe("chunk", "8:256", 0.8)
-    assert led.observe("chunk", "8:256", 5.0, hit=True)["hit"] is True  # explicit wins
+    # nobody said, and no wall decides: 0.01 s is not a hit, 5 s not a miss
+    assert led.observe("chunk", "8:256", 0.8)["hit"] is None
+    assert led.observe("chunk", "8:256", 5.0, hit=True)["hit"] is True
     table = led.table()
     assert [r["key"] for r in table] == ["8:256", "4:4"]  # costliest first
     agg = table[1]
     assert agg["count"] == 2 and agg["hits"] == 1 and agg["misses"] == 1
     assert agg["total_s"] == pytest.approx(1.51)
     assert agg["max_s"] == pytest.approx(1.5)
+    assert table[0]["hits"] == 1 and table[0]["misses"] == 0  # one unknown
     st = led.stats()
+    parts = st.pop("parts")
     assert st == {
-        "entries": 4, "hits": 2, "misses": 2, "shapes": 2,
+        "entries": 4, "hits": 2, "misses": 1, "shapes": 2,
         "total_s": pytest.approx(7.31), "by_src": {"serve": 4},
     }
+    assert parts["serve"]["entries"] == 4
+    assert parts["serve"]["wall_s"] == pytest.approx(7.31)
     assert len(led.entries(limit=2)) == 2
+
+
+def test_compile_ledger_files_what_jax_reported_by_source():
+    """`parts` is what executor/compile_watch.py hands in: seconds by part,
+    the number of compile requests and JAX's own hit; the sums are kept by
+    source, in the table's rows and in stats()."""
+    led = CompileLedger()
+    hit = {"trace_s": 0.4, "lower_s": 0.3, "backend_s": 1.1, "cache_load_s": 0.9,
+           "compile_requests": 2, "hit": True}
+    miss = dict(hit, backend_s=30.0, cache_load_s=0.0, hit=False)
+    e = led.observe("decode", "32:False:True", 2.0, parts=hit)
+    assert e["hit"] is True and e["trace_s"] == 0.4 and e["compile_requests"] == 2
+    assert e["t"] <= time.monotonic() and e["src"] == "serve"
+    led.observe("decode", "32:False:True", 31.0, src="warmup", parts=miss)
+    assert led.observe("admit", "1:64", 0.002, parts={"hit": None})["hit"] is None
+    assert led.observe("admit", "1:64", 0.5, hit=False, parts=hit)["hit"] is False  # explicit wins
+    row = next(r for r in led.table() if r["key"] == "32:False:True")
+    assert row["hits"] == 1 and row["misses"] == 1 and row["by_src"] == {"serve": 1, "warmup": 1}
+    assert row["parts"]["serve"] == {
+        "entries": 1, "wall_s": 2.0, "trace_s": 0.4, "lower_s": 0.3,
+        "backend_s": 1.1, "cache_load_s": 0.9, "compile_requests": 2.0}
+    assert row["parts"]["warmup"]["backend_s"] == 30.0
+    st = led.stats()
+    assert st["hits"] == 1 and st["misses"] == 2 and st["entries"] == 4
+    assert st["parts"]["serve"]["entries"] == 3
+    assert st["parts"]["serve"]["trace_s"] == pytest.approx(0.8)
+    assert st["parts"]["warmup"]["wall_s"] == 31.0
+    row["parts"]["serve"]["wall_s"] = -1.0  # a reader's copy
+    assert led.table()[0]["parts"]["serve"]["wall_s"] >= 0
 
 
 def test_compile_ledger_drain_fresh_exactly_once():
@@ -484,9 +518,22 @@ def test_compile_ledger_reports_cold_boot_walls(base):
     assert totals == sorted(totals, reverse=True)
     for e in doc["entries"]:
         assert e["wall_s"] > 0 and e["phase"] and e["key"]
-    # the first-ever dispatch of a shape is a real XLA compile, not a cache
-    # hit — cold boot must report at least one miss
-    assert doc["stats"]["misses"] >= 1
+    # hit or miss is JAX's own answer now, and depends on what the tests'
+    # persistent cache already holds; what every first dispatch on the
+    # engine's thread has is a trace and a lowering that JAX timed inside it
+    assert doc["stats"]["hits"] + doc["stats"]["misses"] <= doc["stats"]["entries"]
+    decode = [e for e in doc["entries"] if e["phase"] == "decode"]
+    assert decode and all(e["src"] == "serve" for e in decode), decode  # TPU_WARMUP=0 here
+    assert all(e["trace_s"] > 0 and e["lower_s"] > 0 for e in decode), decode
+    for e in doc["entries"]:
+        assert e["trace_s"] + e["lower_s"] + e["backend_s"] <= e["wall_s"] * 1.05 + 1e-3
+        assert e["hit"] in (True, False, None)
+        assert (e["hit"] is None) == (e["compile_requests"] == 0)
+    parts = doc["stats"]["parts"]
+    assert parts["serve"]["entries"] == doc["stats"]["entries"]
+    assert parts["serve"]["wall_s"] == pytest.approx(doc["stats"]["total_s"], rel=1e-3)
+    assert parts["serve"]["trace_s"] == pytest.approx(
+        sum(e["trace_s"] for e in doc["entries"]), rel=1e-3)
 
 
 def test_injected_decode_stall_journals_once(base, server, flight_env):

@@ -107,6 +107,20 @@ def test_degenerate_observations_ignored():
     assert s.fair_cap() >= 1
 
 
+def test_a_first_dispatch_counts_its_tokens_and_teaches_no_cost():
+    """seconds=0 is how the engine reports a first dispatch (its wall is a
+    compile's): tokens, pads and the waste EMA move, the cost EMA does not,
+    for a prefill chunk as for a verify."""
+    s = TokenBudgetScheduler()
+    p0 = s.prefill_tok_s
+    s.observe_prefill(96, 0.0, padded_tokens=128)
+    s.observe_verify(32, 0.0)
+    assert s.prefill_tok_s == p0
+    assert (s.prefill_true_tokens, s.prefill_padded_tokens) == (128, 160)
+    assert (s.verify_rounds, s.verify_tokens) == (1, 32)
+    assert s.pad_waste > 0.0
+
+
 def test_stats_contract():
     s = TokenBudgetScheduler()
     s.decide(100, n_active=1, oldest_wait_s=0.0)
