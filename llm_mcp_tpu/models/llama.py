@@ -52,7 +52,6 @@ from .quant import (
     pack_scales,
     qdot,
     scale_pack_width,
-    scan_unroll,
 )
 
 Params = dict[str, Any]
@@ -581,7 +580,6 @@ def _decode_step_q8(
         layer,
         (h, jnp.int32(0)),
         (params["layers"], layer_windows(cfg)),
-        unroll=scan_unroll(),
     )
     with jax.named_scope("kv_append"):
         new_k, new_v = append_kv_q8(cache_k, cache_v, knew, vnew, lengths, slot_ids=slot_ids)
@@ -640,7 +638,6 @@ def _decode_step_bf16(
         layer,
         (h, jnp.int32(0)),
         (params["layers"], layer_windows(cfg)),
-        unroll=scan_unroll(),
     )
     with jax.named_scope("kv_append"):
         new_k, new_v = append_kv_bf16(
@@ -1324,6 +1321,5 @@ def llama_decode_step(
         layer,
         (h, cache_k, cache_v, jnp.int32(0)),
         (params["layers"], layer_windows(cfg)),
-        unroll=scan_unroll(),
     )
     return _logits(cfg, params, h), new_k, new_v

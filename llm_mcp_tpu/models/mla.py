@@ -46,7 +46,7 @@ from ..kernels.attention import paged_gather, ragged_prefill_attend_mla
 from ..ops.norms import rms_norm as _rms_norm
 from ..ops.rope import apply_rope, rope_tables
 from .configs import ModelConfig
-from .quant import qdot, scan_unroll
+from .quant import qdot
 
 # llama.py imports this module only lazily inside its dispatch functions, so
 # pulling the shared decoder helpers in at module level is cycle-free
@@ -765,9 +765,7 @@ def mla_decode_step(
             carry, (cs_d, krs_d) = jax.lax.scan(
                 layer_k, carry, params["dense_layers"]
             )
-        (h, _), (cs, krs) = jax.lax.scan(
-            layer_k, carry, params["layers"], unroll=scan_unroll()
-        )
+        (h, _), (cs, krs) = jax.lax.scan(layer_k, carry, params["layers"])
         if cs_d is not None:
             cs = jnp.concatenate([cs_d, cs], axis=0)
             krs = jnp.concatenate([krs_d, krs], axis=0)
@@ -795,7 +793,5 @@ def mla_decode_step(
         # dense prologue first — the carried layer index li keeps the cache
         # rows aligned with absolute layer position
         carry, _ = jax.lax.scan(layer, carry, params["dense_layers"])
-    (h, cache_c, cache_r, _), _ = jax.lax.scan(
-        layer, carry, params["layers"], unroll=scan_unroll()
-    )
+    (h, cache_c, cache_r, _), _ = jax.lax.scan(layer, carry, params["layers"])
     return _logits(cfg, params, h), cache_c, cache_r

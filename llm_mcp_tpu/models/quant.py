@@ -381,19 +381,6 @@ def fuse_layer_weights(params: Params) -> Params:
     return out
 
 
-def scan_unroll() -> int:
-    """Unroll factor for the decode layer scans (`LLM_MCP_TPU_SCAN_UNROLL`).
-
-    A modest unroll (default 4 on TPU) amortizes the per-iteration scan
-    overhead (dynamic-slice of the stacked weights + loop bookkeeping)
-    without the 32x program bloat of full unrolling — the middle ground
-    asked for between scan-per-layer and `unroll=n_layers`.
-    CPU/interpret runs keep 1: unrolling only slows compilation there."""
-    from ..utils.platform import on_tpu
-
-    return int(os.environ.get("LLM_MCP_TPU_SCAN_UNROLL", "4" if on_tpu() else "1"))
-
-
 def scale_pack_width(n_kv_heads: int, head_dim: int, scale_dtype) -> int:
     """Padded head rows needed to ride per-position dequant scales inside
     the int8 KV payload block: 1 when the 2*Hkv k+v scale bytes for one

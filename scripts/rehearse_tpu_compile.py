@@ -30,14 +30,57 @@ import re
 import sys
 import time
 
-if "TPU_LOG_DIR" not in os.environ:  # libtpu's own variable: keep its logs out of /tmp
-    os.environ["TPU_LOG_DIR"] = "disabled"
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ["TPU_WARMUP"] = "0"
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+# opcodes that hand an array on without making one
+_PASS_THROUGH = {"parameter", "get-tuple-element", "bitcast", "tuple", "while",
+                 "conditional", "call", "opt-barrier"}
+
+
+def stacked_weight_dims(layers) -> set[tuple[int, int]]:
+    """Trailing two dims of every stacked weight ([L, in, out] and deeper)
+    of a `params["layers"]` tree of arrays or shapes."""
+    import jax
+
+    return {tuple(x.shape[-2:]) for x in jax.tree.leaves(layers) if len(x.shape) >= 3}
+
+
+def stacked_weight_producers(hlo: str, dims: set[tuple[int, int]]) -> list[tuple[str, str, str]]:
+    """Instructions of a compiled module that MAKE an array in HBM with the
+    trailing two dims of a stacked layer weight: (computation or "ENTRY",
+    opcode, shape). A step program should have none: its matmuls read each
+    layer's weights where they lie in the stacked tree. `lax.scan(unroll=4)`
+    over the stacked tree gave one `dynamic-slice_bitcast_fusion s8[4, ...]`
+    per weight and layer group, every weight byte read, written and read again.
+
+    Not counted: instructions inside fused computations (a fusion's inner
+    `dynamic-slice` is the read in place), the pass-through opcodes, and
+    arrays the compiler places in on-chip memory (`S(n)` in the layout: a
+    prefetch reads its bytes from HBM once)."""
+    fused = set(re.findall(r"calls=%([\w.\-]+)", hlo))
+    found, comp = [], None
+    for line in hlo.splitlines():
+        if line.startswith(("%", "ENTRY %")) and line.endswith("{"):
+            name = line.split("%", 1)[1].split(" ", 1)[0]
+            comp = None if name in fused else "ENTRY" if line.startswith("ENTRY") else name
+        elif line.startswith("}"):
+            comp = None
+        elif comp and (m := re.match(r"\s+(?:ROOT )?%\S+ = (.*?) ([\w\-]+)\(", line)):
+            result, opcode = m.groups()
+            if opcode in _PASS_THROUGH:
+                continue
+            for dtype, shape, layout in re.findall(r"(\w+)\[([\d,]+)\](\{[^}]*\})?", result):
+                d = tuple(int(x) for x in shape.split(","))
+                if d[-2:] in dims and not re.search(r"S\([1-9]", layout):
+                    found.append((comp, opcode, f"{dtype}[{shape}]"))
+    return found
 
 
 def main() -> int:
+    if "TPU_LOG_DIR" not in os.environ:  # libtpu's own variable: keep its logs out of /tmp
+        os.environ["TPU_LOG_DIR"] = "disabled"
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ["TPU_WARMUP"] = "0"
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--model", default="llama-3.1-8b")
     ap.add_argument("--slots", type=int, default=32)
@@ -127,6 +170,7 @@ def main() -> int:
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=reshard(x.sharding))
 
     hbm = 16 * (1 << 30)
+    wdims = stacked_weight_dims(eng.params["layers"])
     failed = 0
     for phase, key in zoo:
         t1 = time.time()
@@ -145,12 +189,14 @@ def main() -> int:
         txt = compiled.as_text()
         kernels = sorted({re.sub(r"(\.\d+)+$", "", m) for m in re.findall(
             r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"", txt)})
+        copies = stacked_weight_producers(txt, wdims)
         print(f"ok {phase} {key}: {time.time() - t1:.1f} s  "
               f"tpu_custom_call={txt.count('tpu_custom_call')} {kernels}  "
               f"per-device bytes={need} = {need / 2**30:.2f} GiB "
               f"(args {ma.argument_size_in_bytes / 2**30:.2f}, "
               f"temp {ma.temp_size_in_bytes / 2**30:.2f}, "
               f"aliased {ma.alias_size_in_bytes / 2**30:.2f})"
+              f"  stacked-weight copies={len(copies)} {sorted({c[2] for c in copies})}"
               f"{'  OVER 16 GiB' if need > hbm else ''}", flush=True)
         if need > hbm:
             failed += 1

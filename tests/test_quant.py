@@ -248,6 +248,33 @@ def test_int8_kv_cache_decode_matches_bf16(impl):
         lens = lens + 1
 
 
+@pytest.mark.parametrize(
+    "impl, kv_int8", [("pallas", True), ("pallas", False), ("xla", False)],
+    ids=["q8_scan", "bf16_scan", "xla_scan"],
+)
+def test_scan_unroll_knob_is_gone(tiny, monkeypatch, impl, kv_int8):
+    """The decode layer scans take no unroll (it made the compiler copy every
+    group of layers' weights out of the stacked tree, tests/test_tpu_compile.py):
+    the variable that set it is read by nothing, in each of the three scans."""
+    from llm_mcp_tpu.models import quant
+
+    assert not hasattr(quant, "scan_unroll")
+    cfg, params = tiny
+    qp = quant.fuse_layer_weights(quantize_params(params))
+    toks = jnp.array([5, 9], dtype=jnp.int32)
+    lens = jnp.array([0, 3], jnp.int32)
+
+    def step():
+        jax.clear_caches()
+        cache = init_kv_cache(cfg, 2, 32, dtype=jnp.float32, quantized=kv_int8)
+        return llama_decode_step(cfg, qp, cache["k"], cache["v"], toks, lens, attn_impl=impl)
+
+    monkeypatch.delenv("LLM_MCP_TPU_SCAN_UNROLL", raising=False)
+    plain = step()
+    monkeypatch.setenv("LLM_MCP_TPU_SCAN_UNROLL", "4")
+    jax.tree.map(np.testing.assert_array_equal, plain, step())
+
+
 def test_quantize_kv_roundtrip():
     import jax
 

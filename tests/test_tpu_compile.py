@@ -221,6 +221,96 @@ def test_ragged_prefill_attend_mla(sd):
     )
 
 
+@pytest.fixture
+def chip_kernels(monkeypatch):
+    """The program's one platform question answered "tpu", so its dispatchers
+    take the kernels with interpret mode off. They are jitted and ask at trace
+    time: no trace may cross this fixture's edges."""
+    from llm_mcp_tpu.utils import platform
+
+    monkeypatch.setattr(platform, "device_platform", lambda: "tpu")
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize(
+    "weights, attn, n_layers, slots, relaid",
+    [("int8", "pallas", 36, 32, ()), ("bf16", "pallas", 16, 8, ("wq", "wk")),
+     ("int8", "xla", 36, 32, ())],
+    ids=["int8", "bf16", "int8_xla_attention"],
+)
+def test_decode_step_reads_stacked_weights_in_place(
+    one_chip, chip_kernels, weights, attn, n_layers, slots, relaid
+):
+    """The decode round at Qwen3-8B widths, from shapes alone: 4 steps in a
+    scan as the engine's `decode_body` has them, int8 weights + int8 KV
+    (`_decode_step_q8`, the whole model), bf16 weights + bf16 KV
+    (`_decode_step_bf16`; 16 layers and 8 slots, so that bf16 fits the chip),
+    and the XLA-attention scan of `llama_decode_step` that windows, softcaps
+    and meshes take.
+    No loop body may make a weight-sized array in HBM: with `unroll=4` on the
+    layer scans each round copied every group of four layers' weights out of
+    the stacked tree (six instructions here, 0.74 GiB of temporaries, more
+    than half of a round's device time on the chip).
+
+    What the bf16 program still does, ONCE a round and outside both loops: the
+    compiler re-lays out the whole `wq` and `wk` stacks for the slices it
+    prefetches into on-chip memory (PERF.md §7). The case holds it to that."""
+    import dataclasses
+    import importlib.util
+    from functools import partial
+
+    from llm_mcp_tpu.models import llama, quant
+    from llm_mcp_tpu.models.configs import get_config
+
+    spec = importlib.util.spec_from_file_location(
+        "rehearse_tpu_compile",
+        os.path.join(os.path.dirname(__file__), "..", "scripts", "rehearse_tpu_compile.py"))
+    rehearse = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(rehearse)
+
+    cfg = dataclasses.replace(get_config("qwen3-8b"), n_layers=n_layers)
+
+    def init():
+        if weights == "bf16":
+            return llama.init_llama_params(cfg, jax.random.PRNGKey(0), dtype=BF)
+        p = quant.init_llama_params_quantized(cfg, jax.random.PRNGKey(0), scale_dtype=BF)
+        return quant.fuse_layer_weights(quant.quantize_params(p))
+
+    def decode_round(params, ck, cv, tokens, lengths):
+        def step(carry, _):
+            ck, cv, toks, lens = carry
+            logits, ck, cv = llama.llama_decode_step(
+                cfg, params, ck, cv, toks, lens, attn_impl=attn)
+            new = jnp.argmax(logits, axis=-1).astype(I32)
+            return (ck, cv, new, lens + 1), new
+
+        (ck, cv, _, _), out = jax.lax.scan(step, (ck, cv, tokens, lengths), None, length=4)
+        return out, ck, cv
+
+    params = jax.eval_shape(init)
+    cache = jax.eval_shape(partial(
+        llama.init_kv_cache, cfg, slots, S, dtype=BF, quantized=weights == "int8"))
+    params, cache = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), (params, cache))
+    rows = jax.ShapeDtypeStruct((slots,), I32, sharding=one_chip)
+
+    compiled = jax.jit(decode_round, donate_argnums=(1, 2)).lower(
+        params, cache["k"], cache["v"], rows, rows).compile()
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (attn == "pallas")
+
+    made = rehearse.stacked_weight_producers(
+        text, rehearse.stacked_weight_dims(params["layers"]))
+    assert [m for m in made if m[0] != "ENTRY"] == [], "a loop body copies weights"
+    whole = [params["layers"][k] for k in relaid]
+    assert sorted(made) == sorted(
+        ("ENTRY", "copy", f"bf16[{','.join(map(str, w.shape))}]") for w in whole)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < (256 << 20) + sum(w.size * w.dtype.itemsize for w in whole)
+
+
 def test_a_fall_to_the_reference_is_counted():
     """What `compile_for_chip` and chip_smoke.py's zero-fall check stand on: a
     shape gate that fails with interpret=False is counted and lands in the
