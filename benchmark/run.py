@@ -103,6 +103,29 @@ def load_reader(kind: str, name: str):
     return mod
 
 
+REFERENCE_NEEDS = {"generation": ("logits", "SERVED_TOL_REL"),
+                   "embedding": ("pooled", "EMBED_TOL_COS")}
+
+
+def load_reference(config: dict):
+    """(name, module) of the plain reference the configuration's file names:
+    benchmark/references/<name>.py, or benchmark/reference.py without the key.
+    It must hold what the configuration's engine kind is compared through
+    (the contract is at the top of correctness.py)."""
+    name = config.get("reference")
+    if name is None:
+        from benchmark import reference as mod
+
+        name = "reference"
+    else:
+        mod = load_reader("references", name)
+    lacks = [a for a in ("check", *REFERENCE_NEEDS[config["program"]["engine"]])
+             if not hasattr(mod, a)]
+    if lacks:
+        raise AttributeError(f"reference {name!r} lacks {lacks}")
+    return name, mod
+
+
 # -- the device ----------------------------------------------------------------
 
 
@@ -149,20 +172,87 @@ class CompileEvents:
 
 MODEL_KEYS = {  # the published config's key -> the program's ModelConfig field
     "hidden_size": "dim", "num_hidden_layers": "n_layers", "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads", "intermediate_size": "ffn_hidden",
-    "head_dim": "resolved_head_dim", "vocab_size": "vocab_size", "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps",
+    "intermediate_size": "ffn_hidden", "head_dim": "resolved_head_dim",
+    "vocab_size": "vocab_size", "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
+    "sliding_window": "sliding_window", "attention_bias": "qkv_bias",
+    "tie_word_embeddings": "tie_embeddings",
+    # latent attention
+    "kv_lora_rank": "kv_lora_rank", "q_lora_rank": "q_lora_rank",
+    "qk_nope_head_dim": "qk_nope_head_dim", "qk_rope_head_dim": "qk_rope_head_dim",
+    "v_head_dim": "v_head_dim",
+    # routed experts
+    "n_routed_experts": "n_experts", "num_experts_per_tok": "experts_per_tok",
+    "n_shared_experts": "n_shared_experts", "moe_intermediate_size": "moe_ffn_hidden",
+    "first_k_dense_replace": "first_dense_layers",
+    "routed_scaling_factor": "routed_scaling_factor", "norm_topk_prob": "norm_topk_prob",
 }
+DERIVED_KEYS = {  # a key held to something the program's table gives in another form
+    # latent attention expands a K and a V for every head: the published configs
+    # state their head count there, the program's table the latent cache's one row
+    "num_key_value_heads": lambda c: c.n_heads if c.kv_lora_rank else c.n_kv_heads,
+    "embedding_width": lambda c: c.embed_dim or c.dim,
+}
+ONLY_VALUE = {  # a key the program has one behaviour for: any other value is refused
+    "moe_layer_freq": 1,  # every layer after the dense ones is routed
+    "n_group": 1, "topk_group": 1,  # greedy top-k over all experts, no groups
+}
+ROPE_KEYS = {  # inside the nested "rope_scaling" group (null: no scaling, factor 1)
+    "factor": "rope_factor", "original_max_position_embeddings": "rope_orig_max",
+    "beta_fast": "yarn_beta_fast", "beta_slow": "yarn_beta_slow",
+    "mscale": "yarn_mscale", "mscale_all_dim": "yarn_mscale_all_dim",
+    "low_freq_factor": "llama3_low_freq_factor", "high_freq_factor": "llama3_high_freq_factor",
+}
+# Numbers a file states that are not sizes of the model the program builds: the
+# positions the published model declares (the file's `program.env` says how many
+# are served), and the seed of the random weights.
+STATED_NOT_HELD = {"max_position_embeddings", "weights_seed"}
+
+
+def _number(value) -> bool:
+    return isinstance(value, (int, float)) and not isinstance(value, bool)
 
 
 def check_sizes(config: dict, model_cfg) -> None:
     """The configuration's file is what is run: every size it states must be
-    the size the program's own table gives the engine."""
-    for key, field in MODEL_KEYS.items():
-        if key in config and float(config[key]) != float(getattr(model_cfg, field)):
+    the size the program's own table gives the engine (null reads as 0). A
+    number that no table here knows is an error, not a default: a width nobody
+    compares could be cut and still boot."""
+    held = {k: getattr(model_cfg, f) for k, f in MODEL_KEYS.items()}
+    held.update({k: f(model_cfg) for k, f in DERIVED_KEYS.items()}, **ONLY_VALUE)
+    stated = {k: v for k, v in config.items() if k in held or _number(v)}
+    if "rope_scaling" in config:
+        group = {"factor": 1.0, **(config["rope_scaling"] or {})}
+        held.update({f"rope_scaling.{k}": getattr(model_cfg, f) for k, f in ROPE_KEYS.items()})
+        stated.update({f"rope_scaling.{k}": v for k, v in group.items() if _number(v)})
+    unknown = sorted(set(stated) - set(held) - STATED_NOT_HELD)
+    if unknown:
+        raise AssertionError(
+            f"{config['name']}: the file states {unknown}, which check_sizes compares with "
+            f"nothing of the program: a size nobody holds is an error")
+    for key in stated.keys() & held.keys():
+        if float(stated[key] or 0) != float(held[key] or 0):
             raise AssertionError(
-                f"{config['name']}: {key}={config[key]} in the file, "
-                f"{getattr(model_cfg, field)} in the program ({model_cfg.name})")
+                f"{config['name']}: {key}={stated[key]} in the file, "
+                f"{held[key]} in the program ({model_cfg.name})")
+
+
+def check_before_boot(config: dict, cfg):
+    """What can be refused before the engine is built, in seconds: a size in the
+    file that is not the program's, a configuration its reference module does
+    not cover, a reference request that does not fit. Returns the reference."""
+    from benchmark import correctness
+    from llm_mcp_tpu.models.configs import resolve_config
+
+    if config["program"]["engine"] == "generation":
+        model_cfg = resolve_config(cfg.tpu_model, cfg.tpu_weights_dir)
+        correctness.reference_request(config, cfg.tpu_max_seq_len)
+    else:
+        model_cfg = resolve_config(cfg.tpu_embed_model, cfg.tpu_embed_weights_dir)
+    check_sizes(config, model_cfg)
+    name, module = load_reference(config)
+    module.check(model_cfg)
+    say(f"reference: {name}; sizes in the file are the program's ({model_cfg.name})")
+    return name, module
 
 
 def boot(config: dict) -> dict:
@@ -178,6 +268,7 @@ def boot(config: dict) -> dict:
     from llm_mcp_tpu.utils.config import Config
 
     cfg = Config()
+    reference = check_before_boot(config, cfg)
     t0 = time.monotonic()
     gen = emb = None
     if prog["engine"] == "generation":
@@ -208,7 +299,7 @@ def boot(config: dict) -> dict:
     ).start("127.0.0.1", 0)
     say(f"boot: engine {t1 - t0:.1f} s, server and critical warm-up {time.monotonic() - t1:.1f} s")
     return {"gen": gen, "emb": emb, "engine": engine, "srv": srv, "port": srv.api.port,
-            "model": model}
+            "model": model, "reference": reference}
 
 
 def snapshot(sut: dict, compiles: CompileEvents) -> dict:
@@ -393,9 +484,9 @@ def main(argv: list[str] | None = None) -> int:
         f"({len(os.listdir(cache_dir)) if cache_dir and os.path.isdir(cache_dir) else 0} entries)")
     compiles = CompileEvents()
 
+    sut = boot(spec["config"])
     work_dir = os.path.join(ROOT, ".bench_work", f"{args.workload}.{os.getpid()}")
     os.makedirs(work_dir, exist_ok=True)
-    sut = boot(spec["config"])
     try:
         tap = EmbedTap(sut["emb"]) if args.trace and sut["emb"] is not None else None
         warm_up(sut, spec, work_dir, compiles)

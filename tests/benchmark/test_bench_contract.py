@@ -80,6 +80,43 @@ def test_configs(bench):
         assert body["program"]["engine"] in ("generation", "embedding")
 
 
+def test_every_configuration_has_a_reference_that_covers_it(bench):
+    """The module the file names (or benchmark/reference.py) is there, holds
+    `check`, the forward its engine kind is compared through and that forward's
+    tolerance with a value one can read, and `check` accepts the very
+    `ModelConfig` the program would give the engine; the sizes in the file are
+    that configuration's."""
+    from benchmark import correctness
+    from llm_mcp_tpu.models.configs import resolve_config
+
+    for c in bench["configs"]:
+        with open(os.path.join(ROOT, c["file"])) as f:
+            body = json.load(f)
+        name, module = bench_run.load_reference(body)
+        assert name == body.get("reference", "reference")
+        forward, tolerance = bench_run.REFERENCE_NEEDS[body["program"]["engine"]]
+        assert callable(module.check) and callable(getattr(module, forward))
+        assert 0.0 < float(getattr(module, tolerance)) < 0.5
+        env = body["program"]["env"]
+        model = env["TPU_MODEL" if body["program"]["engine"] == "generation" else "TPU_EMBED_MODEL"]
+        model_cfg = resolve_config(model, "")
+        module.check(model_cfg)
+        bench_run.check_sizes(body, model_cfg)
+        if body["program"]["engine"] == "generation":
+            correctness.reference_request(body, int(env["TPU_MAX_SEQ_LEN"]))
+
+
+def test_a_reference_module_that_lacks_its_tolerance_is_refused(tmp_path, monkeypatch):
+    (tmp_path / "references").mkdir()
+    (tmp_path / "references" / "half.py").write_text(
+        "def check(cfg): pass\ndef logits(cfg, params, tokens, rows, cols): pass\n")
+    monkeypatch.setattr(bench_run, "HERE", str(tmp_path))
+    with pytest.raises(AttributeError, match="SERVED_TOL_REL"):
+        bench_run.load_reference({"reference": "half", "program": {"engine": "generation"}})
+    with pytest.raises(FileNotFoundError):
+        bench_run.load_reference({"reference": "absent", "program": {"engine": "generation"}})
+
+
 def test_workloads(bench):
     names = [w["name"] for w in bench["workloads"]]
     pairs = [(w["config"], w["traffic"]) for w in bench["workloads"]]

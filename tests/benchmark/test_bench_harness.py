@@ -21,6 +21,22 @@ TINY_GEN = {
                         "TPU_QUANT": "", "TPU_KV_QUANT": ""},
                 "expect": {"attn_impl": "xla"}},
 }
+TINY_V2 = {  # another architecture, brought as files: its reference module is named here
+    "name": "tiny-v2-lat", "source": "test", "reduced": [], "reference": "deepseek_v2",
+    "reference_request": {"prompt_bytes": 150, "tokens": 6},
+    "hidden_size": 128, "num_hidden_layers": 3, "num_attention_heads": 4, "num_key_value_heads": 4,
+    "intermediate_size": 256, "kv_lora_rank": 32, "q_lora_rank": None, "qk_nope_head_dim": 32,
+    "qk_rope_head_dim": 16, "v_head_dim": 32, "n_routed_experts": 4, "num_experts_per_tok": 2,
+    "n_shared_experts": 2, "moe_intermediate_size": 64, "first_k_dense_replace": 1,
+    "moe_layer_freq": 1, "n_group": 1, "topk_group": 1, "routed_scaling_factor": 1.0,
+    "norm_topk_prob": False, "tie_word_embeddings": True, "max_position_embeddings": 512,
+    "rope_scaling": {"type": "yarn", "factor": 4, "original_max_position_embeddings": 64,
+                     "beta_fast": 32, "beta_slow": 1, "mscale": 0.707, "mscale_all_dim": 0.707},
+    "program": {"engine": "generation",
+                "env": {"TPU_MODEL": "tiny-v2", "TPU_MAX_SLOTS": 4, "TPU_MAX_SEQ_LEN": 512,
+                        "TPU_QUANT": "", "TPU_KV_QUANT": ""},
+                "expect": {"attn_impl": "xla"}},
+}
 TINY_TRAFFIC = {
     "endpoint": "chat", "loop": "closed", "clients": 3,
     "prompt_tokens": {"dist": "uniform", "lo": 16, "hi": 40},
@@ -47,15 +63,24 @@ def checkout(tmp_path):
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     (root / "benchmark" / "configs" / "tiny-gen.json").write_text(json.dumps(TINY_GEN))
+    (root / "benchmark" / "configs" / "tiny-v2-lat.json").write_text(json.dumps(TINY_V2))
+    no_key = {k: v for k, v in TINY_V2.items() if k != "reference"}
+    (root / "benchmark" / "configs" / "tiny-v2-nokey.json").write_text(
+        json.dumps(dict(no_key, name="tiny-v2-nokey")))
     (root / "benchmark" / "traffic" / "tiny_closed.json").write_text(json.dumps(TINY_TRAFFIC))
     (root / "benchmark" / "layer_metrics" / "requests_seen.py").write_text(NEW_METRIC)
     bench["configs"].append({"name": "tiny-gen", "source": "test", "reduced": [], "why": "test",
                              "file": "benchmark/configs/tiny-gen.json"})
     bench["workloads"].append({"name": "tiny.closed", "config": "tiny-gen", "traffic": "tiny_closed",
                                "chips": 1, "why": "test"})
+    for name in ("tiny-v2-lat", "tiny-v2-nokey"):
+        bench["configs"].append({"name": name, "source": "test", "reduced": [], "why": "test",
+                                 "file": f"benchmark/configs/{name}.json"})
+        bench["workloads"].append({"name": name + ".closed", "config": name, "chips": 1,
+                                   "traffic": "tiny_closed", "why": "test"})
     for m in bench["end_to_end"]:
         if m["name"] in ("itl_p95_ms", "out_tokens_per_s"):
-            m["workloads"].append("tiny.closed")
+            m["workloads"] += ["tiny.closed", "tiny-v2-lat.closed", "tiny-v2-nokey.closed"]
     # an end-to-end metric whose reader is in the tree and which no cell carries yet
     bench["end_to_end"].insert(0, {"name": "ttft_p95_ms", "unit": "ms", "better": "lower",
                                    "bound": 0.1, "source": "host_clock",
@@ -100,11 +125,7 @@ def test_sizes_in_the_file_must_be_the_programs(checkout):
 
 def test_one_whole_run_at_a_tiny_size(checkout, monkeypatch, capsys):
     root, run = checkout
-    monkeypatch.setattr(run, "require_tpu",
-                        lambda chips: {"platform": "cpu", "kind": "cpu", "count": 1})
-    monkeypatch.setenv("TPU_WARMUP", "0")
-    for key in TINY_GEN["program"]["env"]:
-        monkeypatch.setenv(key, "")  # restored after the test: boot() writes them
+    stub_device(run, monkeypatch, TINY_GEN)
     rc = run.main(["--workload", "tiny.closed", "--seed", "3000000001", "--seconds", "3", "--trace", "0"])
     assert rc == 0
     out = capsys.readouterr().out.strip().splitlines()
@@ -115,6 +136,86 @@ def test_one_whole_run_at_a_tiny_size(checkout, monkeypatch, capsys):
     assert all(m["value"] > 0 for m in result["metrics"].values())
     assert result["device"]["platform"] == "cpu"  # a CPU run never reads as a TPU's
     assert result["checks"]["served_tokens"] == 8 and result["checks"]["worst_regret_rel"] <= 0.05
+    assert result["checks"]["reference"] == "reference" and result["checks"]["tolerance"] == 0.08
     assert any(ln.startswith("itl_ms: median") for ln in out)
     assert any(ln.startswith("ttft_ms: median") for ln in out)
     assert not os.path.exists(root / ".bench_work" / f"tiny.closed.{os.getpid()}")
+
+
+# -- a configuration of another architecture brings its own reference -----------
+
+
+def stub_device(run, monkeypatch, config):
+    monkeypatch.setattr(run, "require_tpu",
+                        lambda chips: {"platform": "cpu", "kind": "cpu", "count": 1})
+    monkeypatch.setenv("TPU_WARMUP", "0")
+    for key in config["program"]["env"]:
+        monkeypatch.setenv(key, "")  # restored after the test: boot() writes them
+
+
+def test_a_configuration_that_names_its_reference_runs_to_correct(checkout, monkeypatch, capsys):
+    """tiny-v2 (latent attention, routed and shared experts, yarn) through the
+    whole path, with nothing edited but added files. The engine's prefill runs
+    at the program's default `capacity_factor`; at these lengths no expert
+    overflows (tests/benchmark/test_bench_reference.py shows where one does)."""
+    _root, run = checkout
+    stub_device(run, monkeypatch, TINY_V2)
+    rc = run.main(["--workload", "tiny-v2-lat.closed", "--seed", "3000000002", "--seconds", "3",
+                   "--trace", "0"])
+    assert rc == 0
+    out = capsys.readouterr().out.strip().splitlines()
+    result = json.loads(out[-1])
+    assert result["correct"] is True and result["failed"] == 0
+    checks = result["checks"]
+    assert checks["reference"] == "deepseek_v2" and checks["tolerance"] == 0.12
+    assert checks["served_tokens"] == 6  # the file's reference_request
+    assert checks["prompt_tokens"] > 128  # it crosses two 64-token KV blocks
+    assert checks["worst_regret_rel"] <= 0.01  # float32 weights on the CPU
+    assert any(ln.startswith("reference: deepseek_v2") for ln in out)
+
+
+def test_without_the_key_it_fails_before_the_engine_is_built(checkout, monkeypatch):
+    _root, run = checkout
+    stub_device(run, monkeypatch, TINY_V2)
+    import llm_mcp_tpu.executor as executor
+
+    def no_engine(*a, **k):
+        raise AssertionError("the engine was built before the reference was asked")
+
+    monkeypatch.setattr(executor, "GenerationEngine", no_engine)
+    with pytest.raises(NotImplementedError, match="no plain reference for 'tiny-v2'"):
+        run.main(["--workload", "tiny-v2-nokey.closed", "--seed", "3000000003", "--seconds", "3",
+                  "--trace", "0"])
+
+
+@pytest.mark.parametrize("key,value,says", [
+    ("kv_lora_rank", 256, "kv_lora_rank=256"),
+    ("moe_intermediate_size", 32, "moe_intermediate_size=32"),
+    ("n_routed_experts", 8, "n_routed_experts=8"),
+    ("num_key_value_heads", 1, "num_key_value_heads=1"),  # the published files state the heads
+    ("n_group", 2, "n_group=2"),  # group-limited routing: the program has none
+    ("q_lora_rank", 16, "q_lora_rank=16"),
+    ("rope_scaling", dict(TINY_V2["rope_scaling"], factor=8), "rope_scaling.factor=8"),
+    ("rope_scaling", None, "rope_scaling.factor=1.0"),
+    ("n_future_width", 7, "states ['n_future_width']"),  # a number nobody compares
+])
+def test_every_size_a_file_states_is_held(checkout, key, value, says):
+    _root, run = checkout
+    from llm_mcp_tpu.models.configs import resolve_config
+
+    cfg = resolve_config("tiny-v2", "")
+    run.check_sizes(TINY_V2, cfg)
+    with pytest.raises(AssertionError) as err:
+        run.check_sizes(dict(TINY_V2, **{key: value}), cfg)
+    assert says in str(err.value)
+
+
+def test_a_reference_request_must_fit_the_positions_served(checkout):
+    from benchmark import correctness
+
+    assert correctness.reference_request({}, 2048) == (72, 8)
+    assert correctness.reference_request(TINY_V2, 512) == (150, 6)
+    with pytest.raises(AssertionError, match="does not fit"):
+        correctness.reference_request(TINY_V2, 256)
+    with pytest.raises(AssertionError, match="holds"):
+        correctness.reference_request({"reference_request": {"prompt_bytes": 9, "rows": 1}}, 512)

@@ -94,3 +94,174 @@ def test_unknown_device_kind_is_an_error():
     assert peaks.peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
     with pytest.raises(KeyError):
         peaks.peaks("cpu")
+
+
+# -- the DeepSeek-V2 family's reference (benchmark/references/deepseek_v2.py) ----
+
+
+@pytest.fixture(scope="module")
+def v2():
+    """The reference a configuration file names, loaded as run.py loads it, and
+    tiny-v2 (a dense layer, two routed layers with shared experts, yarn)."""
+    from benchmark import run as bench_run
+
+    name, ref = bench_run.load_reference(
+        {"reference": "deepseek_v2", "program": {"engine": "generation"}})
+    assert name == "deepseek_v2"
+    return ref, resolve_config("tiny-v2", "")
+
+
+def v2_tree(cfg, kind, monkeypatch):
+    if kind == "float32":
+        params = init_llama_params(cfg, jax.random.PRNGKey(5), dtype=jnp.float32)
+        # the random tree's latent norm weights are ones: move them, so that a
+        # reference that skipped the norm would be caught
+        for block in ("dense_layers", "layers"):
+            shape = params[block]["kv_norm"].shape
+            params[block] = dict(params[block], kv_norm=1.0 + 0.3 * jax.random.normal(
+                jax.random.PRNGKey(len(block)), shape))
+        return params
+    from llm_mcp_tpu.models import quant
+
+    # the program's weight-only int8 path (x @ q, then the scales): its default
+    # also rounds the ACTIVATIONS to int8, which no float32 forward agrees with
+    # to rounding
+    monkeypatch.setattr(quant, "_W8A8", False)
+    return quant.init_llama_params_quantized(cfg, jax.random.PRNGKey(5), scale_dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("kind", ["float32", "int8"])
+def test_v2_reference_matches_the_programs_dropless_prefill_and_decode(v2, kind, monkeypatch):
+    """Prefill logits at the last row, then three decode steps through the
+    latent cache (absorbed projections, dropless by `moe_capacity` = rows),
+    against the reference's one full forward of the whole sequence."""
+    from llm_mcp_tpu.models import init_kv_cache
+    from llm_mcp_tpu.models.llama import llama_decode_step
+
+    ref, cfg = v2
+    params = v2_tree(cfg, kind, monkeypatch)
+    # capacity = ceil(T k / E x E / k) = T: the program's prefill drops nothing
+    dropless = dataclasses.replace(cfg, capacity_factor=cfg.n_experts / cfg.experts_per_tok)
+    rng = np.random.default_rng(2)
+    n, S, steps = 27, 32, 3
+    seq = [int(t) for t in rng.integers(1, cfg.vocab_size, n)]
+    toks = np.zeros((1, S), np.int32)
+    toks[0, :n] = seq
+    logits, latents, rope_keys = llama_prefill(dropless, params, jnp.asarray(toks),
+                                               jnp.asarray([n], jnp.int32))
+    cache = init_kv_cache(cfg, 1, 64, dtype=jnp.float32)
+    ck = cache["k"].at[:, :, :, :S].set(latents)
+    cv = cache["v"].at[:, :, :, :S].set(rope_keys)
+    served = [np.asarray(logits, np.float32)[0]]
+    for _ in range(steps):
+        seq.append(int(np.argmax(served[-1])))
+        logits, ck, cv = llama_decode_step(cfg, params, ck, cv, jnp.asarray(seq[-1:], jnp.int32),
+                                           jnp.asarray([len(seq) - 1], jnp.int32))
+        served.append(np.asarray(logits, np.float32)[0])
+    full = np.asarray(seq + [0] * (64 - len(seq)), np.int32)
+    want = ref.logits(cfg, params, full, np.arange(n - 1, n + steps), np.arange(cfg.vocab_size))
+    for k, got in enumerate(served):
+        np.testing.assert_allclose(got, want[k], atol=2e-4 * float(np.abs(want[k]).max()),
+                                   err_msg=f"row {k} (0 is the prefill's)")
+
+
+def test_v2_programs_prefill_drops_tokens_at_its_default_capacity(v2):
+    """PERF.md section 7's first debt of the `model_config` PR: at
+    `capacity_factor` 1.25 an unpadded prompt overflows an expert and the
+    program's prefill leaves the reference by a share of the logits; dropless,
+    it agrees to rounding."""
+    ref, cfg = v2
+    params = init_llama_params(cfg, jax.random.PRNGKey(3), dtype=jnp.float32)
+    toks = np.random.default_rng(101).integers(1, cfg.vocab_size, (1, 64)).astype(np.int32)
+    want = ref.logits(cfg, params, toks[0], np.asarray([63]), np.arange(cfg.vocab_size))[0]
+    off = {}
+    for factor in (1.25, cfg.n_experts / cfg.experts_per_tok):
+        got = llama_prefill(dataclasses.replace(cfg, capacity_factor=factor), params,
+                            jnp.asarray(toks), jnp.asarray([64], jnp.int32))[0]
+        off[factor] = float(np.abs(np.asarray(got, np.float32)[0] - want).max() / np.abs(want).max())
+    assert off[1.25] > 0.1 and off[2.0] < 2e-4, off
+
+
+@pytest.mark.parametrize("left_out", ["shared_experts", "yarn_scale", "shared_rope_key"])
+def test_a_v2_reference_without_a_mechanism_would_be_caught(v2, left_out, monkeypatch):
+    ref, cfg = v2
+    params = init_llama_params(cfg, jax.random.PRNGKey(5), dtype=jnp.float32)
+    toks = np.random.default_rng(3).integers(1, cfg.vocab_size, 64).astype(np.int32)
+    rows, cols = np.asarray([63]), np.arange(cfg.vocab_size)
+    whole = ref.logits(cfg, params, toks, rows, cols)
+    if left_out == "shared_experts":
+        wrong = dataclasses.replace(cfg, n_shared_experts=0)
+    elif left_out == "yarn_scale":  # the frequencies stay yarn's; both mscale terms go
+        wrong = dataclasses.replace(cfg, yarn_mscale=0.0, yarn_mscale_all_dim=0.0)
+    else:  # the position's rope key reaches the first head only
+        def own_key(k_nope, k_rope, head):
+            return jnp.concatenate([k_nope[:, head], k_rope * (head == 0)], axis=-1)
+
+        monkeypatch.setattr(ref, "_head_keys", own_key)
+        wrong = dataclasses.replace(cfg, name="tiny-v2-own-key")  # another jit key: traced anew
+    without = ref.logits(wrong, params, toks, rows, cols)
+    assert float(np.abs(whole - without).max()) > 0.05 * float(np.abs(whole).max())
+    assert float(np.abs(whole - without).max()) > 250 * 2e-4 * float(np.abs(whole).max())
+
+
+def test_v2_query_latent_branch_is_the_published_form(v2):
+    """`q_lora_rank`: down, RMSNorm, up. The program has no such path, so the
+    branch is held to the equations written out in numpy."""
+    ref, cfg = v2
+    cfg = dataclasses.replace(cfg, q_lora_rank=24)
+    rng = np.random.default_rng(4)
+    width = cfg.n_heads * (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    a, g, b = (rng.standard_normal(s).astype(np.float32)
+               for s in ((2, cfg.dim, 24), (2, 24), (2, 24, width)))
+    x = rng.standard_normal((5, cfg.dim)).astype(np.float32)
+    stack = {"wq_a": jnp.asarray(a), "q_a_norm": jnp.asarray(g), "wq_b": jnp.asarray(b)}
+    got = np.asarray(ref._queries(cfg, stack, jnp.int32(1), jnp.asarray(x)))
+    c = x.astype(np.float64) @ a[1]
+    c = c / np.sqrt((c * c).mean(-1, keepdims=True) + cfg.norm_eps) * g[1]
+    np.testing.assert_allclose(got.reshape(5, width), c @ b[1], rtol=2e-4, atol=2e-4)
+
+
+def test_what_a_router_near_tie_costs_in_logits(v2, monkeypatch):
+    """The reading behind deepseek_v2.SERVED_TOL_REL, kept small: at V2-Lite's
+    routing (64 experts, top 6, raw gates, 1 + 26 layers) on tiny-v2's widths,
+    every row whose 6th and 7th scores lie within 2**-6 of each other gets the
+    7th expert for the 6th. The logits move, and the greedy token's regret
+    stays under half the tolerance."""
+    ref, base = v2
+    base = dataclasses.replace(base, n_experts=64, experts_per_tok=6, n_layers=27)
+    params = init_llama_params(base, jax.random.PRNGKey(3), dtype=jnp.float32)
+
+    def near_ties_swapped(cfg, scores):
+        k = cfg.experts_per_tok
+        top, idx = jax.lax.top_k(scores, k + 1)
+        tie = ((top[:, k - 1] - top[:, k]) < 2.0**-6 * top[:, k - 1])[:, None]
+        idx = jnp.concatenate([idx[:, :k - 1], jnp.where(tie, idx[:, k:], idx[:, k - 1:k])], axis=1)
+        top = jnp.concatenate([top[:, :k - 1], jnp.where(tie, top[:, k:], top[:, k - 1:k])], axis=1)
+        chosen = idx[:, :, None] == jnp.arange(cfg.n_experts)[None, None, :]
+        return jnp.sum(jnp.where(chosen, top[:, :, None] * cfg.routed_scaling_factor, 0.0), axis=1)
+
+    rows, cols = np.arange(100, 128), np.arange(259)
+    shift, regret = [], []
+    for seed in range(3):
+        toks = np.random.default_rng(seed).integers(1, 259, 128).astype(np.int32)
+        want = ref.logits(base, params, toks, rows, cols)
+        with monkeypatch.context() as patch:
+            patch.setattr(ref, "_gates", near_ties_swapped)
+            # another jit key, so that the layer is traced anew with the swap
+            got = ref.logits(dataclasses.replace(base, name="swapped"), params, toks, rows, cols)
+        scale = np.abs(want).max(axis=1)
+        shift.append(float((np.abs(got - want).max(axis=1) / scale).max()))
+        served = got.argmax(axis=1)
+        regret.append(float(((want.max(axis=1) - want[np.arange(len(rows)), served]) / scale).max()))
+    assert min(shift) > 0.01, shift  # the swaps are felt
+    assert max(regret) < ref.SERVED_TOL_REL / 2, regret
+
+
+def test_each_reference_refuses_the_other_family(v2):
+    ref, cfg = v2
+    ref.check(cfg)
+    reference.check(resolve_config("tiny-qwen3", ""))
+    with pytest.raises(NotImplementedError):
+        reference.check(cfg)
+    with pytest.raises(NotImplementedError):
+        ref.check(resolve_config("tiny-qwen3", ""))
