@@ -4,10 +4,11 @@ Outside the window, what the system serves is held to a plain float32
 reference over the engine's own weights. Inside it, every request must have
 done what was asked and the kernels must have done the work.
 
-**A configuration brings its own reference.** A configuration's file may hold
-`"reference": "<name>"`, which names `benchmark/references/<name>.py`; without
-the key it is `benchmark/reference.py` (the dense GQA decoder family). A later
-PR adds a family as files: nothing here is edited. A reference module holds:
+**A configuration brings its own reference, and with it the table that holds
+its file.** A configuration's file may hold `"reference": "<name>"`, which names
+`benchmark/references/<name>.py`; without the key it is `benchmark/reference.py`
+(the dense GQA decoder family). A later PR adds a family as files: nothing here
+is edited. A reference module holds:
 
 - `check(cfg)`: raises `NotImplementedError` for a `ModelConfig` whose
   equations it does not cover. run.py calls it before the engine is built, so a
@@ -20,6 +21,31 @@ PR adds a family as files: nothing here is edited. A reference module holds:
   and plain ones); everything else comes from `cfg` and the seed's tokens.
 - `SERVED_TOL_REL` beside `logits`, `EMBED_TOL_COS` beside `pooled`: the
   tolerance, with the readings it was set from written beside it.
+- optionally three tables, keyed by the dotted path of a key in the file
+  (`linear_attn_config.head_dim`, `rope_parameters.rope_theta`; a list is one
+  value): `HELD`, path -> a function of the program's `ModelConfig` that returns
+  what the program will compute with (number, bool, string or list); `ONLY`,
+  path -> the one value for which the program's behaviour is the published one
+  (any other value in the file is refused); `STATED`, path -> one line saying
+  why the key changes nothing the program computes (an empty reason is refused;
+  run.py prints these paths in every run's log).
+
+**Every key of the file is held.** The harness's own keys are `run.HARNESS_KEYS`
+(`name`, `source`, `reference`, `reference_request`, `reduced`, `published`,
+`assumed`, `deployment`, `weights_seed`, `program`); every other key is the
+model's. `run.check_sizes` walks them at any depth, before the engine is built
+and again on the engine's own `cfg`: each number, bool, string and list must
+equal what run.py's own tables (`MODEL_KEYS`, `DERIVED_KEYS`, `ONLY_VALUE`,
+`ROPE_KEYS`, `STATED_NOT_HELD`) or the module's give for it, null reading as 0.
+A path no table knows stops the run; a path both run.py and the module hold
+stops it when the module is loaded. **A cut is stated beside what was
+published**: `published` is a group with the source's value of exactly the
+paths in `reduced` (`{"num_hidden_layers": 48, "n_routed_experts": 320}`), and
+the module's tables may hold `published.<path>` too (a router keeps the
+published width while `n_routed_experts` counts the experts held here). A file
+with `reduced: []` has no `published`. `benchmark/check_source.py` compares a
+file with its row of the `model-configs` catalog as the driver will; run.py
+never calls it, because the catalog is not on the measuring machine.
 
 A configuration may also state the request the comparison serves,
 `"reference_request": {"prompt_bytes": n, "tokens": m}`: a cell whose work is
@@ -40,7 +66,6 @@ from benchmark import reduce, trafficgen
 
 PAD_TO = 128
 REF_REQUEST = {"prompt_bytes": 72, "tokens": 8}
-NEEDS = {"generation": ("logits", "SERVED_TOL_REL"), "embedding": ("pooled", "EMBED_TOL_COS")}
 
 
 def reference_request(config: dict, max_seq_len: int) -> tuple[int, int]:

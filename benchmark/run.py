@@ -105,13 +105,16 @@ def load_reader(kind: str, name: str):
 
 REFERENCE_NEEDS = {"generation": ("logits", "SERVED_TOL_REL"),
                    "embedding": ("pooled", "EMBED_TOL_COS")}
+TABLES = ("HELD", "ONLY", "STATED")  # what a reference module may bring beside its forward
 
 
 def load_reference(config: dict):
     """(name, module) of the plain reference the configuration's file names:
     benchmark/references/<name>.py, or benchmark/reference.py without the key.
-    It must hold what the configuration's engine kind is compared through
-    (the contract is at the top of correctness.py)."""
+    It must hold what the configuration's engine kind is compared through, and
+    may hold tables for `check_sizes` (the contract is at the top of
+    correctness.py): a path that run.py's own tables hold, or two of the
+    module's, and a `STATED` path with no reason, are refused here."""
     name = config.get("reference")
     if name is None:
         from benchmark import reference as mod
@@ -123,6 +126,17 @@ def load_reference(config: dict):
              if not hasattr(mod, a)]
     if lacks:
         raise AttributeError(f"reference {name!r} lacks {lacks}")
+    seen = own_paths()
+    for table in TABLES:
+        twice = sorted(seen & set(getattr(mod, table, {})))
+        if twice:
+            raise AssertionError(
+                f"reference {name!r}: {table} holds {twice}, which another table holds already "
+                f"(run.py's, or one of the module's): a path is held once")
+        seen |= set(getattr(mod, table, {}))
+    unsaid = sorted(p for p, why in getattr(mod, "STATED", {}).items() if not str(why).strip())
+    if unsaid:
+        raise AssertionError(f"reference {name!r}: STATED gives no reason for {unsaid}")
     return name, mod
 
 
@@ -175,7 +189,7 @@ MODEL_KEYS = {  # the published config's key -> the program's ModelConfig field
     "intermediate_size": "ffn_hidden", "head_dim": "resolved_head_dim",
     "vocab_size": "vocab_size", "rope_theta": "rope_theta", "rms_norm_eps": "norm_eps",
     "sliding_window": "sliding_window", "attention_bias": "qkv_bias",
-    "tie_word_embeddings": "tie_embeddings",
+    "tie_word_embeddings": "tie_embeddings", "hidden_act": "act",
     # latent attention
     "kv_lora_rank": "kv_lora_rank", "q_lora_rank": "q_lora_rank",
     "qk_nope_head_dim": "qk_nope_head_dim", "qk_rope_head_dim": "qk_rope_head_dim",
@@ -191,6 +205,7 @@ DERIVED_KEYS = {  # a key held to something the program's table gives in another
     # state their head count there, the program's table the latent cache's one row
     "num_key_value_heads": lambda c: c.n_heads if c.kv_lora_rank else c.n_kv_heads,
     "embedding_width": lambda c: c.embed_dim or c.dim,
+    "pooling": lambda c: {"last": "last_token"}.get(c.pooling, c.pooling),
 }
 ONLY_VALUE = {  # a key the program has one behaviour for: any other value is refused
     "moe_layer_freq": 1,  # every layer after the dense ones is routed
@@ -201,45 +216,112 @@ ROPE_KEYS = {  # inside the nested "rope_scaling" group (null: no scaling, facto
     "beta_fast": "yarn_beta_fast", "beta_slow": "yarn_beta_slow",
     "mscale": "yarn_mscale", "mscale_all_dim": "yarn_mscale_all_dim",
     "low_freq_factor": "llama3_low_freq_factor", "high_freq_factor": "llama3_high_freq_factor",
+    "type": "rope_type",
 }
-# Numbers a file states that are not sizes of the model the program builds: the
+# Keys a file states that are not sizes of the model the program builds: the
 # positions the published model declares (the file's `program.env` says how many
-# are served), and the seed of the random weights.
-STATED_NOT_HELD = {"max_position_embeddings", "weights_seed"}
+# are served), the released code's class and family names, and the type of the
+# checkpoint (boot() serves bfloat16).
+STATED_NOT_HELD = {"max_position_embeddings", "architectures", "model_type", "torch_dtype"}
+# The harness's own keys of a configuration's file. Every other key is the
+# model's, and check_sizes walks it.
+HARNESS_KEYS = ("name", "source", "reference", "reference_request", "reduced", "assumed",
+                "deployment", "weights_seed", "program", "published")
 
 
-def _number(value) -> bool:
-    return isinstance(value, (int, float)) and not isinstance(value, bool)
+def own_paths() -> set[str]:
+    """Every path run.py's own tables hold; a reference module may hold none of them."""
+    return ({*MODEL_KEYS, *DERIVED_KEYS, *ONLY_VALUE, *STATED_NOT_HELD}
+            | {f"rope_scaling.{k}" for k in ROPE_KEYS})
 
 
-def check_sizes(config: dict, model_cfg) -> None:
-    """The configuration's file is what is run: every size it states must be
-    the size the program's own table gives the engine (null reads as 0). A
-    number that no table here knows is an error, not a default: a width nobody
-    compares could be cut and still boot."""
+def walk(group: dict, prefix: str = "") -> dict:
+    """{dotted path: value} of every leaf of a group: a nested group is walked,
+    a list is one value, compared whole."""
+    out: dict = {}
+    for key, value in group.items():
+        if isinstance(value, dict):
+            out.update(walk(value, f"{prefix}{key}."))
+        else:
+            out[prefix + key] = value
+    return out
+
+
+def model_paths(config: dict) -> dict:
+    """{path: value} of the model's keys of a configuration's file. A
+    `rope_scaling` that is null, or gives no factor, scales by 1."""
+    model = {k: v for k, v in config.items() if k not in HARNESS_KEYS}
+    if "rope_scaling" in model:
+        model["rope_scaling"] = {"factor": 1.0, **(model["rope_scaling"] or {})}
+    return walk(model)
+
+
+def same(stated, held) -> bool:
+    """A list or a string is equal or not; a number, a bool and null are
+    compared as numbers, null reading as 0."""
+    if isinstance(held, tuple):
+        held = list(held)
+    if any(isinstance(v, (list, str)) for v in (stated, held)):
+        return type(stated) is type(held) and stated == held
+    return float(stated or 0) == float(held or 0)
+
+
+def check_published(config: dict) -> dict:
+    """`published` states the source's value of every path in `reduced`, and of
+    nothing else; a file that cuts nothing has no such group. Returns its paths."""
+    reduced, published = config.get("reduced", []), config.get("published")
+    if not reduced and published is None:
+        return {}
+    paths = walk({"published": published or {}})
+
+    def states(q: str, p: str) -> bool:  # p itself, or a path inside the group p
+        return q == f"published.{p}" or q.startswith(f"published.{p}.")
+
+    lacks = [p for p in reduced if not any(states(q, p) for q in paths)]
+    extra = [q for q in paths if not any(states(q, p) for p in reduced)]
+    if lacks or extra:
+        raise AssertionError(
+            f"{config['name']}: `published` holds the source's value of each path in `reduced` "
+            f"and of no other: it lacks {sorted(lacks)} and holds {sorted(extra)} beside them")
+    return paths
+
+
+def check_sizes(config: dict, model_cfg, module=None) -> list[str]:
+    """The configuration's file is what is run: every model key it states, at
+    any depth (number, bool, string or list), must be what the program's own
+    table gives the engine (null reads as 0). run.py's tables come first, then
+    `HELD`, `ONLY` and `STATED` of the configuration's reference module. A path
+    that no table knows is an error, not a default: a width nobody compares
+    could be cut and still boot. Returns the paths the file states that are
+    held to nothing, each with the reason where the module gives one."""
     held = {k: getattr(model_cfg, f) for k, f in MODEL_KEYS.items()}
     held.update({k: f(model_cfg) for k, f in DERIVED_KEYS.items()}, **ONLY_VALUE)
-    stated = {k: v for k, v in config.items() if k in held or _number(v)}
-    if "rope_scaling" in config:
-        group = {"factor": 1.0, **(config["rope_scaling"] or {})}
-        held.update({f"rope_scaling.{k}": getattr(model_cfg, f) for k, f in ROPE_KEYS.items()})
-        stated.update({f"rope_scaling.{k}": v for k, v in group.items() if _number(v)})
-    unknown = sorted(set(stated) - set(held) - STATED_NOT_HELD)
+    held.update({f"rope_scaling.{k}": getattr(model_cfg, f) for k, f in ROPE_KEYS.items()})
+    held.update({k: f(model_cfg) for k, f in getattr(module, "HELD", {}).items()})
+    held.update(getattr(module, "ONLY", {}))
+    why = {**dict.fromkeys(STATED_NOT_HELD, ""), **getattr(module, "STATED", {})}
+    # a path of `published` is compared where the module holds it, and only there
+    stated = {**model_paths(config),
+              **{p: v for p, v in check_published(config).items() if p in held or p in why}}
+    unknown = sorted(set(stated) - set(held) - set(why))
     if unknown:
         raise AssertionError(
             f"{config['name']}: the file states {unknown}, which check_sizes compares with "
             f"nothing of the program: a size nobody holds is an error")
-    for key in stated.keys() & held.keys():
-        if float(stated[key] or 0) != float(held[key] or 0):
+    for key in sorted(stated.keys() & held.keys()):
+        if not same(stated[key], held[key]):
             raise AssertionError(
                 f"{config['name']}: {key}={stated[key]} in the file, "
                 f"{held[key]} in the program ({model_cfg.name})")
+    return [f"{p} ({why[p]})" if why[p] else p for p in sorted(stated.keys() & why.keys())]
 
 
 def check_before_boot(config: dict, cfg):
-    """What can be refused before the engine is built, in seconds: a size in the
-    file that is not the program's, a configuration its reference module does
-    not cover, a reference request that does not fit. Returns the reference."""
+    """What can be refused before the engine is built, in seconds: a reference
+    module that is not there or brings a table it may not, a key in the file
+    that is not the program's or that nothing holds, a configuration its
+    reference module does not cover, a reference request that does not fit.
+    Returns the reference."""
     from benchmark import correctness
     from llm_mcp_tpu.models.configs import resolve_config
 
@@ -248,10 +330,11 @@ def check_before_boot(config: dict, cfg):
         correctness.reference_request(config, cfg.tpu_max_seq_len)
     else:
         model_cfg = resolve_config(cfg.tpu_embed_model, cfg.tpu_embed_weights_dir)
-    check_sizes(config, model_cfg)
     name, module = load_reference(config)
+    unheld = check_sizes(config, model_cfg, module)
     module.check(model_cfg)
     say(f"reference: {name}; sizes in the file are the program's ({model_cfg.name})")
+    say(f"stated in the file and held to nothing: {'; '.join(unheld) or 'nothing'}")
     return name, module
 
 
@@ -290,7 +373,7 @@ def boot(config: dict) -> dict:
         engine, model = emb, cfg.tpu_embed_model
     else:
         raise ValueError(f"unknown engine kind {prog['engine']!r}")
-    check_sizes(config, engine.cfg)
+    check_sizes(config, engine.cfg, reference[1])
     t1 = time.monotonic()
     srv = CoreServer(
         cfg, db=Database(":memory:"),

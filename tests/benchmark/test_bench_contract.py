@@ -12,6 +12,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
+from benchmark import check_source  # noqa: E402
 from benchmark import run as bench_run  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -72,10 +73,13 @@ def test_configs(bench):
         assert c["name"] in used
         assert any(c["file"].startswith(p + "/") for p in bench["paths"])
         assert len(c["reduced"]) <= 16 and all(NAME.match(k) for k in c["reduced"])
-        for k in c["reduced"]:  # never a width
-            assert not re.search(r"(_dim|_rank|hidden_size|intermediate_size|head_dim)$", k)
         with open(os.path.join(ROOT, c["file"])) as f:
             body = json.load(f)
+        for k in c["reduced"]:  # never a width: a path named there, or one inside a group named there
+            assert not check_source.is_width(k), k
+        for path, value in bench_run.walk(body.get("published", {})).items():
+            if check_source.is_width(path):
+                assert check_source.same_json(check_source.lookup(body, path), value), path
         assert body["source"] == c["source"] and body["reduced"] == c["reduced"]
         assert body["program"]["engine"] in ("generation", "embedding")
 
@@ -101,7 +105,7 @@ def test_every_configuration_has_a_reference_that_covers_it(bench):
         model = env["TPU_MODEL" if body["program"]["engine"] == "generation" else "TPU_EMBED_MODEL"]
         model_cfg = resolve_config(model, "")
         module.check(model_cfg)
-        bench_run.check_sizes(body, model_cfg)
+        bench_run.check_sizes(body, model_cfg, module)
         if body["program"]["engine"] == "generation":
             correctness.reference_request(body, int(env["TPU_MAX_SEQ_LEN"]))
 

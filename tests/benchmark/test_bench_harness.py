@@ -37,11 +37,37 @@ TINY_V2 = {  # another architecture, brought as files: its reference module is n
                         "TPU_QUANT": "", "TPU_KV_QUANT": ""},
                 "expect": {"attn_impl": "xla"}},
 }
+# Four keys of a catalog row (Kimi-K2.5) that run.py's own tables do not know, and
+# the module a `model_config` PR would add to hold them: the DeepSeek-V2
+# reference as it stands, with the tables beside it.
+KIMI_KEYS = {"encoder_no_repeat_ngram_size": 0, "ep_size": 1, "num_nextn_predict_layers": 0,
+             "top_k": 50}
+KIMI_MODULE = '''"""references/deepseek_v2.py, and the tables for four more keys of the file."""
+import importlib.util
+import os
+
+_spec = importlib.util.spec_from_file_location(
+    "deepseek_v2_wrapped", os.path.join(os.path.dirname(os.path.abspath(__file__)), "deepseek_v2.py"))
+_base = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(_base)
+check, logits, SERVED_TOL_REL = _base.check, _base.logits, _base.SERVED_TOL_REL
+
+ONLY = {"encoder_no_repeat_ngram_size": 0, "ep_size": 1, "num_nextn_predict_layers": 0}
+STATED = {"top_k": "the sampler's default; a request states its own"}
+'''
+# Under the load of the other test workers a first dispatch takes seconds, and one
+# inside a 3 s window left no request due in it (no `ttft_p95_ms`) or no delta
+# at all. So nothing here is left to compile in the window: one prompt length
+# (one prefill bucket), and warm-up rounds shaped to admit 1, 2 and 4 prompts at
+# once (the admit program pads a batch to a power of two) before the mix's own
+# rounds run until one builds nothing; and the pre-roll takes the clients'
+# first, simultaneous requests.
 TINY_TRAFFIC = {
     "endpoint": "chat", "loop": "closed", "clients": 3,
-    "prompt_tokens": {"dist": "uniform", "lo": 16, "hi": 40},
+    "prompt_tokens": {"dist": "const", "value": 24},
     "max_tokens": {"dist": "const", "value": 12}, "temperature": 0.7, "stagger_first": True,
-    "preroll_s": 1, "warmup_s": 1, "warmup_rounds_max": 2, "request_timeout_s": 60,
+    "preroll_s": 2, "warmup_s": 1, "warmup_rounds": [{"clients": 1}, {"clients": 2}, {"clients": 4}],
+    "warmup_rounds_max": 6, "request_timeout_s": 60,
 }
 NEW_METRIC = '''"""A layer metric a later PR drops in as a file of its own."""
 NAME, UNIT, BETTER, SOURCE = "requests_seen", "count", "higher", "program_counter"
@@ -67,13 +93,18 @@ def checkout(tmp_path):
     no_key = {k: v for k, v in TINY_V2.items() if k != "reference"}
     (root / "benchmark" / "configs" / "tiny-v2-nokey.json").write_text(
         json.dumps(dict(no_key, name="tiny-v2-nokey")))
+    (root / "benchmark" / "configs" / "tiny-v2-keys.json").write_text(
+        json.dumps(dict(TINY_V2, **KIMI_KEYS, name="tiny-v2-keys", reference="v2_keys")))
+    (root / "benchmark" / "configs" / "tiny-v2-keys-unheld.json").write_text(
+        json.dumps(dict(TINY_V2, **KIMI_KEYS, name="tiny-v2-keys-unheld")))
+    (root / "benchmark" / "references" / "v2_keys.py").write_text(KIMI_MODULE)
     (root / "benchmark" / "traffic" / "tiny_closed.json").write_text(json.dumps(TINY_TRAFFIC))
     (root / "benchmark" / "layer_metrics" / "requests_seen.py").write_text(NEW_METRIC)
     bench["configs"].append({"name": "tiny-gen", "source": "test", "reduced": [], "why": "test",
                              "file": "benchmark/configs/tiny-gen.json"})
     bench["workloads"].append({"name": "tiny.closed", "config": "tiny-gen", "traffic": "tiny_closed",
                                "chips": 1, "why": "test"})
-    for name in ("tiny-v2-lat", "tiny-v2-nokey"):
+    for name in ("tiny-v2-lat", "tiny-v2-nokey", "tiny-v2-keys", "tiny-v2-keys-unheld"):
         bench["configs"].append({"name": name, "source": "test", "reduced": [], "why": "test",
                                  "file": f"benchmark/configs/{name}.json"})
         bench["workloads"].append({"name": name + ".closed", "config": name, "chips": 1,
@@ -126,7 +157,7 @@ def test_sizes_in_the_file_must_be_the_programs(checkout):
 def test_one_whole_run_at_a_tiny_size(checkout, monkeypatch, capsys):
     root, run = checkout
     stub_device(run, monkeypatch, TINY_GEN)
-    rc = run.main(["--workload", "tiny.closed", "--seed", "3000000001", "--seconds", "3", "--trace", "0"])
+    rc = run.main(["--workload", "tiny.closed", "--seed", "3000000001", "--seconds", "6", "--trace", "0"])
     assert rc == 0
     out = capsys.readouterr().out.strip().splitlines()
     result = json.loads(out[-1])
@@ -186,6 +217,40 @@ def test_without_the_key_it_fails_before_the_engine_is_built(checkout, monkeypat
     with pytest.raises(NotImplementedError, match="no plain reference for 'tiny-v2'"):
         run.main(["--workload", "tiny-v2-nokey.closed", "--seed", "3000000003", "--seconds", "3",
                   "--trace", "0"])
+
+
+def test_a_configuration_whose_module_brings_the_tables_runs_to_correct(checkout, monkeypatch, capsys):
+    """The `tiny-v2` file with four more keys of a catalog row, and a module
+    that wraps references/deepseek_v2.py and adds `ONLY` and `STATED` for them:
+    the whole path to `correct`, with nothing edited but added files."""
+    _root, run = checkout
+    stub_device(run, monkeypatch, TINY_V2)
+    rc = run.main(["--workload", "tiny-v2-keys.closed", "--seed", "3000000004", "--seconds", "3",
+                   "--trace", "0"])
+    assert rc == 0
+    out = capsys.readouterr().out.strip().splitlines()
+    result = json.loads(out[-1])
+    assert result["correct"] is True and result["failed"] == 0
+    assert result["checks"]["reference"] == "v2_keys" and result["checks"]["tolerance"] == 0.12
+    stated = [ln for ln in out if ln.startswith("stated in the file and held to nothing: ")]
+    assert len(stated) == 1  # once a run, where a reviewer reads it
+    assert stated[0].endswith(
+        "max_position_embeddings; top_k (the sampler's default; a request states its own)")
+
+
+def test_without_the_tables_it_stops_before_the_engine_is_built(checkout, monkeypatch):
+    _root, run = checkout
+    stub_device(run, monkeypatch, TINY_V2)
+    import llm_mcp_tpu.executor as executor
+
+    def no_engine(*a, **k):
+        raise AssertionError("the engine was built before the file's keys were held")
+
+    monkeypatch.setattr(executor, "GenerationEngine", no_engine)
+    with pytest.raises(AssertionError) as err:
+        run.main(["--workload", "tiny-v2-keys-unheld.closed", "--seed", "3000000005",
+                  "--seconds", "3", "--trace", "0"])
+    assert f"states {sorted(KIMI_KEYS)}, which check_sizes compares with nothing" in str(err.value)
 
 
 @pytest.mark.parametrize("key,value,says", [
