@@ -341,6 +341,22 @@ class _DispatchedRound:
 
 
 @dataclass
+class _DispatchedAdmit:
+    """A batched admission in flight on device: `admit_fn` dispatched, its
+    sampled first tokens not yet read. It waits in the same queue as the
+    decode rounds, in device order, and is read when it is the oldest item
+    there. `entries` pins (slot index, slot OBJECT, prompt tokens) like a
+    round's: the rows are seated and decoding from the next dispatch on, and
+    identity decides at the read whether a first token still has a taker."""
+
+    toks0: Any  # device array [Ab] (un-fetched)
+    entries: list  # [(b, _Slot, P)]
+    t0: float  # time.perf_counter() before the dispatch
+    t_call: float  # ... when the jit call returned
+    first: bool  # first dispatch of its shape: the CompileLedger's, no sample
+
+
+@dataclass
 class _PendingRound:
     """A fetched decode round awaiting (deferred) emission."""
 
@@ -1215,6 +1231,16 @@ class GenerationEngine:
         self._rid_dispatched = 0
         self._rid_fetched = 0
         self._cooling: dict[int, int] = {}
+        # everything dispatched and not yet read, in device order: decode
+        # rounds and batched admissions. The engine thread only ever blocks
+        # on the oldest item (_run).
+        self._inflight: deque[_DispatchedRound | _DispatchedAdmit] = deque()
+        # how often the queued read engages: admissions read, those whose
+        # read still had to wait for the device, and those read where they
+        # were dispatched (a batch that needs its token's value at once)
+        self.admit_reads = 0
+        self.admit_reads_blocked = 0
+        self.admit_reads_at_once = 0
 
         # Self-speculative decoding (draft-and-verify): a host-side n-gram
         # drafter (drafter.py — prompt-lookup over each slot's own history)
@@ -3276,9 +3302,17 @@ class GenerationEngine:
     def perf_stats(self) -> dict[str, Any]:
         """Perf-observatory block (/v1/debug/perf + engines_info + bench):
         ITL percentiles, goodput split, sampled per-phase host/device/wait
-        attribution, and the four-layout roofline. Read-only over the
-        observatory's own lock, so safe from any thread."""
-        return self._perf.stats()
+        attribution, and the four-layout roofline, with the engine's count
+        of admissions read from the in-flight queue (`admit_reads`; of them
+        `_blocked` still had to wait for the device, `_at_once` were read
+        where they were dispatched). Read-only over the observatory's own
+        lock, so safe from any thread."""
+        return {
+            **self._perf.stats(),
+            "admit_reads": self.admit_reads,
+            "admit_reads_blocked": self.admit_reads_blocked,
+            "admit_reads_at_once": self.admit_reads_at_once,
+        }
 
     def drain_itl_samples(self) -> list[float]:
         """ITL samples (seconds) since the last drain — engines_info feeds
@@ -3834,6 +3868,13 @@ class GenerationEngine:
         )
         return {"payload": payload, "out": req.out, "req_id": req.request_id}
 
+    def _exports_after_prefill(self, req: GenRequest) -> bool:
+        """Disaggregated mode: this engine spends the prefill, emits the
+        first token and hands the slot to a decode-role peer."""
+        return self._migrate_outbox is not None and bool(
+            req.migrate_after_prefill or self.migrate_after_prefill
+        )
+
     def _migrate_export_slot(self, b: int, s: _Slot) -> None:
         """Disaggregated-mode export, engine thread, straight after
         activation: the slot's rows [0, P) are committed (the activating
@@ -4074,31 +4115,46 @@ class GenerationEngine:
             }
 
     def _run(self) -> None:
-        """Pipelined decode loop (depth 1): the next decode round is DISPATCHED
-        before the previous round's tokens are emitted, so host-side work —
+        """Pipelined serving loop. Whatever the loop dispatches (decode
+        rounds, batched admissions) joins ONE queue in device order, and the
+        engine thread only ever blocks on the OLDEST item of it: a round is
+        fetched, an admission's first tokens are read. The host's work —
         token emission (tokenizer + queue puts, the dominant host cost at
-        8B B=80), admissions, prefill dispatches — overlaps the device
+        8B B=80), admissions, prefill staging — overlaps the device's
         compute instead of serializing with it (measured: the serialized
         loop idled the chip down to ~2.0k tok/s against a 4.8k raw decode
-        loop; the reference never faces this — Ollama owns its hot loop).
+        loop; the reference never faces this — Ollama owns its hot loop),
+        and a round that has ended is never left waiting behind a read of
+        something queued after it.
 
         Order within one iteration:
-          1. stage a prefill chunk group under the token-budget scheduler's
+          1. who needs committed history drains the queue first (`drain`):
+             a preemption, a speculative verify round (which then replaces
+             steps 2-6)
+          2. constrained slots run their synchronous masked round
+          3. stage a prefill chunk group under the token-budget scheduler's
              budget (scheduler.py — bounded so the group costs ~one decode
-             round of device time)
-          2. dispatch round N FUSED with the staged group (fused_step_fn:
-             decode never stalls behind prefill; with no active decode rows
-             the group runs standalone, back-to-back); advance chunk
-             progress and activate finished prompts
-          3. emit round N-1's tokens + admissions (overlapped with 2's
-             device time)
-          4. fetch round N; fast finish-scan frees finishing slots and
-             advances host mirrors (emission itself is deferred to the next
-             iteration's step 3)
+             round of device time), and dispatch round N FUSED with it
+             (fused_step_fn: decode never stalls behind prefill; with no
+             active decode rows the group runs standalone, back-to-back);
+             advance chunk progress and activate finished prompts
+          4. emit the round fetched last iteration (overlapped with the
+             device's time on the rounds in flight)
+          5. read the admissions that are now the oldest items in flight:
+             every round before them is fetched AND emitted, so a first
+             token goes out on time and before its slot's first round
+          6. admit: dispatch the queue's next batches (_start_batch seats
+             their rows, so round N+1 carries them) — behind everything in
+             flight, read at step 5 of a later iteration; a batch that needs
+             its tokens' value at once is read where it is dispatched
+          7. once `pipeline_depth` ROUNDS are in flight (or the batch went
+             idle), block on the oldest items up to and including the oldest
+             round: its fast finish-scan frees finishing slots and advances
+             host mirrors (emission itself is step 4 of the next iteration)
         """
         tracing.name_os_thread("gen-engine")  # its line in a profiler trace
         pending: _PendingRound | None = None
-        inflight: deque[_DispatchedRound] = deque()
+        inflight = self._inflight
         K = self.decode_chunk
         S = self.max_seq_len
         # wall-clock budget per loop phase (serve breakdown, bench.py):
@@ -4124,12 +4180,13 @@ class GenerationEngine:
                 phase[key] += time.perf_counter() - t0
 
         def drain_failed(e: Exception, also: list[int] = ()) -> None:
-            # a poisoned round invalidates every LATER in-flight round too
+            # a poisoned item invalidates every LATER in-flight item too
             # (they consumed the same donated buffer chain): fail all of
-            # their live slots — plus `also` (the active set of a dispatch
-            # that raised BEFORE entering the deque: without it those slots
-            # would stay active, re-dispatch, and re-raise forever while
-            # their consumers hang) — drop the rounds, recover the cache
+            # their live slots, a round's rows and an admission's alike —
+            # plus `also` (the active set of a dispatch that raised BEFORE
+            # entering the deque: without it those slots would stay active,
+            # re-dispatch, and re-raise forever while their consumers hang)
+            # — drop the items, recover the cache
             slots: set[int] = {b for b in also if self._slots[b] is not None}
             while inflight:
                 d = inflight.popleft()
@@ -4138,6 +4195,42 @@ class GenerationEngine:
                 )
             self._rid_fetched = self._rid_dispatched  # nothing left in flight
             self._fail_round(sorted(slots), e)
+
+        def emit_pending() -> None:
+            nonlocal pending
+            if pending is not None:
+                timed("emit", self._emit_round, pending)
+                pending = None
+
+        def retire_oldest() -> bool:
+            """Block on the oldest item in flight, which every caller has
+            made sure follows an EMITTED round: an admission's first tokens
+            are read and go out, a round is fetched into `pending`. False
+            when the item was poisoned (drain_failed has answered everyone
+            in flight)."""
+            nonlocal pending
+            item = inflight.popleft()
+            try:
+                if isinstance(item, _DispatchedAdmit):
+                    timed("admit", self._read_admit, item)
+                else:
+                    pending = timed("fetch", self._complete_round, item)
+            except Exception as e:  # poisoned execution surfaces at the read
+                inflight.appendleft(item)  # drain fails its slots too
+                drain_failed(e)
+                return False
+            return True
+
+        def drain() -> bool:
+            """Commit everything in flight, in device order: who needs
+            committed history (host mirrors exact, every first token read)
+            calls this. False when an item was poisoned."""
+            emit_pending()
+            while inflight:
+                if not retire_oldest():
+                    return False
+                emit_pending()
+            return True
 
         while not self._stop_evt.is_set():
             # watchdog stamp: idle loops iterate (the _wake wait times out),
@@ -4156,23 +4249,10 @@ class GenerationEngine:
             if self._pool is not None and self._preempt_wanted():
                 # Preemption needs committed-exact host mirrors: lengths
                 # advance optimistically at dispatch and last_tok updates at
-                # fetch, so drain the pipeline first (the spec-round drain
-                # pattern below) before snapshotting the victim's rows.
-                if pending is not None:
-                    timed("emit", self._emit_round, pending)
-                    pending = None
-                ok = True
-                while inflight:
-                    disp = inflight.popleft()
-                    try:
-                        fetched = timed("fetch", self._complete_round, disp)
-                    except Exception as e:
-                        inflight.appendleft(disp)
-                        drain_failed(e)
-                        ok = False
-                        break
-                    timed("emit", self._emit_round, fetched)
-                if ok and self._preempt_wanted():
+                # the fetch (a round's) or the read (an admission's), so
+                # drain the queue first, before snapshotting the victim's
+                # rows.
+                if drain() and self._preempt_wanted():
                     # re-check: the drain may have finished slots, making a
                     # free slot appear without any eviction
                     self._preempt_one()
@@ -4197,9 +4277,7 @@ class GenerationEngine:
                 except Exception as e:
                     # cn jits donate the cache chain like decode rounds: a
                     # poisoned dispatch invalidates in-flight rounds too
-                    if pending is not None:
-                        self._emit_round(pending)
-                        pending = None
+                    emit_pending()
                     drain_failed(e, also=cn_active)
             if self._verify_fn is not None and active:
                 if self._spec_cooldown > 0:
@@ -4208,25 +4286,11 @@ class GenerationEngine:
                     # Speculative verify round (majority of active slots have
                     # an n-gram draft). Acceptance is data-dependent, so the
                     # optimistic-length pipelining contract doesn't hold:
-                    # drain the in-flight rounds (emitting in round order —
-                    # drafts must continue the COMMITTED history) and run the
+                    # drain the queue (emitting in device order — drafts
+                    # must continue the COMMITTED history) and run the
                     # verify synchronously. Iterations without a draft
                     # majority leave the pipelined path untouched.
-                    if pending is not None:
-                        timed("emit", self._emit_round, pending)
-                        pending = None
-                    ok = True
-                    while inflight:
-                        disp = inflight.popleft()
-                        try:
-                            fetched = timed("fetch", self._complete_round, disp)
-                        except Exception as e:
-                            inflight.appendleft(disp)
-                            drain_failed(e)
-                            ok = False
-                            break
-                        timed("emit", self._emit_round, fetched)
-                    if ok:
+                    if drain():
                         # re-draft against the post-drain history (slots may
                         # have finished; tokens arrived). Constrained slots
                         # stay filtered out — they already ran their masked
@@ -4274,13 +4338,11 @@ class GenerationEngine:
                               rid=self._rid_dispatched + 1)
                     )
                 except Exception as e:  # a poisoned dispatch must not kill the loop
-                    if pending is not None:
-                        # deliver already-fetched tokens BEFORE the error
-                        # events — _fail_round marks these same slot objects
-                        # aborted, which would silently drop up to K
-                        # computed tokens per stream
-                        self._emit_round(pending)
-                        pending = None
+                    # deliver already-fetched tokens BEFORE the error events
+                    # — _fail_round marks these same slot objects aborted,
+                    # which would silently drop up to K computed tokens per
+                    # stream
+                    emit_pending()
                     if group is not None:
                         self._fail_prefill_group(group, e)
                         group = None
@@ -4296,23 +4358,26 @@ class GenerationEngine:
                 # (the stale-budget alternation this replaces paced cold
                 # bursts in arbitrary 50 ms slices)
                 timed("prefill", self._dispatch_prefill_group, group)
-            if pending is not None:
-                timed("emit", self._emit_round, pending)
-                pending = None
+            emit_pending()
+            # admissions that are now the oldest items in flight: every
+            # round dispatched before them is fetched and emitted, and they
+            # are ready or one admit program away
+            while inflight and isinstance(inflight[0], _DispatchedAdmit):
+                if not retire_oldest():
+                    break
             admitted = timed("admit", self._admit_pending)
-            # fetch the OLDEST round only once the pipeline is full (or the
-            # batch went idle): up to pipeline_depth rounds chain on device
-            # without a host sync, so the fetch and the host's work on it
-            # overlap compute instead of serializing with it
-            if inflight and (
-                len(inflight) >= self.pipeline_depth or not active
-            ):
-                disp = inflight.popleft()
-                try:
-                    pending = timed("fetch", self._complete_round, disp)
-                except Exception as e:  # poisoned execution surfaces at fetch
-                    inflight.appendleft(disp)  # drain fails its slots too
-                    drain_failed(e)
+            # block on the OLDEST round only once the pipeline is full (or
+            # the batch went idle): up to pipeline_depth rounds chain on
+            # device without a host sync, so the fetch and the host's work
+            # on it overlap compute instead of serializing with it. With
+            # nothing decoding, an admission dispatched just now is the
+            # oldest item and is read here: an idle engine's first token
+            # does not wait for an iteration.
+            rounds = sum(isinstance(d, _DispatchedRound) for d in inflight)
+            if inflight and (rounds >= self.pipeline_depth or not active):
+                while inflight and pending is None:
+                    if not retire_oldest():
+                        break
             elif not (active or cn_active or admitted or group is not None
                       or inflight):
                 t_idle = time.perf_counter()
@@ -4320,18 +4385,11 @@ class GenerationEngine:
                     self._wake.wait(timeout=0.05)
                 self._wake.clear()
                 phase["idle"] += time.perf_counter() - t_idle
-        if pending is not None:
-            # flush the deferred emission: consumers of slots the fast-scan
-            # already freed would otherwise never see their done event
-            self._emit_round(pending)
-        while inflight:
-            # fetch + emit what was still in flight at shutdown: their
-            # consumers' streams end cleanly instead of hanging mid-queue
-            try:
-                self._emit_round(self._complete_round(inflight.popleft()))
-            except Exception:  # pragma: no cover — device died at shutdown
-                log.exception("in-flight round lost at shutdown")
-                break
+        # flush the deferred emission (consumers of slots the fast-scan
+        # already freed would otherwise never see their done event), then
+        # read and emit what was still in flight at shutdown: their
+        # consumers' streams end cleanly instead of hanging mid-queue
+        drain()
         if self.dead:
             # dead-on-poison: fail live slots and everything still queued —
             # their consumers must not hang on a loop that will never
@@ -4518,7 +4576,19 @@ class GenerationEngine:
                     continue  # hit slots consumed; more queue may admit
                 break
             try:
-                self._start_batch(batch)
+                adm = self._start_batch(batch)
+                if any(
+                    req.cn is not None or self._exports_after_prefill(req)
+                    for _, req, _ in batch
+                ):
+                    # the batch needs its first tokens' VALUE before the loop
+                    # goes on: a constrained request's automaton cursor must
+                    # stand on tok0 before _cn_round masks its next token,
+                    # and a prefill-role engine hands over rows that no
+                    # decode round may have touched
+                    self._read_admit(adm, at_once=True)
+                else:
+                    self._inflight.append(adm)
             except Exception as e:  # malformed batch must not kill the loop
                 log.exception("prefill failed")
                 for slot, req, _ in batch:
@@ -5063,11 +5133,18 @@ class GenerationEngine:
                 "import_rejects_total": float(self.prefix_import_rejects_total),
             }
 
-    def _start_batch(self, batch: list[tuple[int, GenRequest, list[int]]]) -> None:
+    def _start_batch(
+        self, batch: list[tuple[int, GenRequest, list[int]]]
+    ) -> _DispatchedAdmit:
         """Admit up to admit_batch short prompts with ONE batched prefill
         dispatch. At 8B the prompt weight pass dominates admission cost;
         per-request prefill starves admissions badly enough to leave most
-        slots idle (measured 102 tok/s at B=64 — vs the decode loop's ~1.9k)."""
+        slots idle (measured 102 tok/s at B=64 — vs the decode loop's ~1.9k).
+
+        The dispatch half of an admission: nothing here reads the device.
+        The rows are seated (_seat), so the very next decode dispatch carries
+        them (admit_fn left their first tokens in the device's token ring);
+        the host learns those tokens at _read_admit."""
         A = len(batch)
         Ab = 1 << (A - 1).bit_length()  # pow2 pad: bounded executable count
         bucket = self._bucket(max(len(ids) for _, _, ids in batch))
@@ -5094,34 +5171,80 @@ class GenerationEngine:
         t0c = time.perf_counter()
         toks0 = self._dx("admit", tokens, ipack, fpack, cn_payload)
         t_call = time.perf_counter()  # jit returned; device running
-        with TraceAnnotation("engine.admit.sync"):
-            toks0 = np.asarray(toks0)  # host sync: first-call wall ≈ compile time
         if first:
-            self._compile_obs("admit", (Ab, bucket), time.perf_counter() - t0c)
-        else:
+            # jit traces and compiles inside the call: the wall up to its
+            # return is the compile's, and the ledger's context closes here,
+            # before another first dispatch can open its own
+            self._compile_obs("admit", (Ab, bucket), t_call - t0c)
+        return _DispatchedAdmit(
+            toks0=toks0,
+            entries=[
+                (slot, self._seat(slot, req, ids), len(ids))
+                for slot, req, ids in batch
+            ],
+            t0=t0c, t_call=t_call, first=first,
+        )
+
+    def _read_admit(self, adm: _DispatchedAdmit, at_once: bool = False) -> None:
+        """The read half of a batched admission: the one blocking read of its
+        first tokens, then all that needs their value (_first_token). From
+        the queue this runs when the admission is the oldest item in flight:
+        every round dispatched before it has been fetched and emitted, none
+        dispatched after it has been fetched, so a slot's first token is on
+        its stream before the text of the first round that carried it."""
+        # chaos site: a poisoned admission surfaces here, as a poisoned
+        # round does at its fetch
+        maybe_fail("engine.admit", f"slots={[b for b, _, _ in adm.entries]}")
+        blocked = not adm.toks0.is_ready()
+        t_wait = time.perf_counter()
+        with TraceAnnotation("engine.admit.sync"):
+            toks0 = np.asarray(adm.toks0)  # the admission's only host sync
+        now = time.perf_counter()
+        self.admit_reads += 1
+        self.admit_reads_blocked += blocked
+        self.admit_reads_at_once += at_once
+        self._flight.event(
+            "admit_read", rows=len(adm.entries), after_rid=self._rid_fetched,
+            wait_ms=round((now - t_wait) * 1e3, 3), blocked=blocked,
+            t=time.monotonic(),
+        )
+        tot_tok = sum(P for _, _, P in adm.entries)
+        if not adm.first:
             self._sample_prefill_phase(
-                "admit", t0c, t_call,
-                sum(len(ids) for _, _, ids in batch), A,
+                "admit", adm.t0, adm.t_call, tot_tok, len(adm.entries)
             )
-        # latency waterfall: the fused admit dispatch is synchronous wall
-        # every batched prompt sat through — attribute it by token share
-        admit_wall = time.perf_counter() - t0c
-        tot_tok = sum(len(ids) for _, _, ids in batch) or 1
-        for i, (slot, req, ids) in enumerate(batch):
-            self._activate_state(slot, req, ids, int(toks0[i]))
-            s = self._slots[slot]
-            if s is not None:
-                s.prefill_compute_s += admit_wall * (len(ids) / tot_tok)
+        # latency waterfall: dispatch to read is wall every batched prompt
+        # sat through — attribute it by token share
+        wall_a_token = (now - adm.t0) / (tot_tok or 1)
+        for i, (slot, s, P) in enumerate(adm.entries):
+            if self._slots[slot] is not s:
+                continue  # failed and freed since the dispatch
+            if s.aborted:
+                # the stall watchdog delivered this consumer's terminal
+                # error while the admission was in flight (_complete_round)
+                self._free_now(slot)
+                continue
+            s.prefill_compute_s += wall_a_token * P
+            self._first_token(slot, s, int(toks0[i]))
 
     def _activate_state(
         self, slot: int, req: GenRequest, ids: list[int], tok0: int
     ) -> None:
+        """Seat a slot and deliver its first token in one step: the chunked
+        prefill's activations, which read their sample where it is made."""
+        self._first_token(slot, self._seat(slot, req, ids), tok0)
+
+    def _seat(self, slot: int, req: GenRequest, ids: list[int]) -> _Slot:
+        """All of an activation that the next decode dispatch needs and that
+        does not need the first token's value: the slot object, its length,
+        the paging ledger, the sampling mirrors, the prefix store, the
+        drafter."""
         P = len(ids)
         # the slot's cache rows [0, P) now hold exactly this prompt's KV —
         # the moment to learn a shared prefix for future admissions
         self._maybe_store_prefix(slot, ids)
         self._recent_prompts.append(tuple(ids))
-        s = _Slot(req=req, prompt_len=P, first_token_at=time.time())
+        s = _Slot(req=req, prompt_len=P)
         # the automaton cursor moves onto the slot BEFORE tok0 is emitted:
         # _process_token advances it for every token including the first
         s.cn = req.cn
@@ -5145,10 +5268,26 @@ class GenerationEngine:
         mgr.note_admit_cost(mgr.blocks_for(want) - shared_full)
         self._slots[slot] = s
         self._lengths[slot] = P
-        self._last_tok[slot] = tok0
         self._temp[slot] = req.temperature
         self._topk[slot] = req.top_k
         self._topp[slot] = req.top_p
+        if self._verify_fn is not None:
+            # seed the n-gram drafter with the prompt: prompt-lookup drafting
+            # pays off exactly when completions quote the prompt (extraction,
+            # code edits, RAG). _process_token appends every emitted token so
+            # the index also covers generated history.
+            s.spec = NGramDrafter(self.spec_min_ngram, self.spec_max_ngram)
+            s.spec.extend(ids)
+        return s
+
+    def _first_token(self, slot: int, s: _Slot, tok0: int) -> None:
+        """The host has read a seated slot's first token: the TTFT stamp
+        and its records, the recovery mirror, the token's emission, and the
+        hand-over of a prefill-role engine."""
+        req = s.req
+        P = s.prompt_len
+        s.first_token_at = time.time()
+        self._last_tok[slot] = tok0
         ttft_ms = (s.first_token_at - req.created_at) * 1000.0
         with self.stats_lock:
             self.total_requests += 1
@@ -5174,7 +5313,7 @@ class GenerationEngine:
                 attrs={
                     "request_id": req.request_id,
                     "prompt_tokens": P,
-                    "ttft_ms": round((s.first_token_at - req.created_at) * 1000.0, 1),
+                    "ttft_ms": round(ttft_ms, 1),
                     # scheduler decision context at activation: the budget
                     # this prompt's last chunk rode in under, and whether the
                     # backlog has been outrunning the TTFT deadline
@@ -5182,21 +5321,9 @@ class GenerationEngine:
                     "sched_starved_rounds": self._sched.starved_rounds,
                 },
             )
-        if self._verify_fn is not None:
-            # seed the n-gram drafter with the prompt: prompt-lookup drafting
-            # pays off exactly when completions quote the prompt (extraction,
-            # code edits, RAG). _process_token appends every emitted token so
-            # the index also covers generated history.
-            s.spec = NGramDrafter(self.spec_min_ngram, self.spec_max_ngram)
-            s.spec.extend(ids)
         # tok0's KV will be written at position P in the first decode round.
         self._emit_token(slot, s, tok0, pos=P - 1)
-        if (
-            self._migrate_outbox is not None
-            and (req.migrate_after_prefill or self.migrate_after_prefill)
-            and not s.done
-            and not s.aborted
-        ):
+        if self._exports_after_prefill(req) and not s.done and not s.aborted:
             # disaggregated mode: this engine spent the prefill and emitted
             # the first token; the decode-role peer continues from here
             self._migrate_export_slot(slot, s)

@@ -297,7 +297,7 @@ class MigrationCoordinator:
         self.failed_total = 0
         self.last_headroom_delta = 0.0
         # prefill-role engines flag every admitted request for export the
-        # moment its prefill lands (engine.py _activate_state)
+        # moment its prefill lands (engine.py _first_token)
         for n, eng in self.engines.items():
             if self.roles[n] == "prefill" and getattr(eng, "_migrate_outbox", None) is not None:
                 eng.migrate_after_prefill = True

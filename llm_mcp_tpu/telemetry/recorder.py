@@ -29,7 +29,11 @@ An *event* is a tuple ``(seq, ts, etype, trace_id, fields)``:
             round's blocking read returned: rid, wait_ms) / emit (the
             round's tokens went to their streams: rid, rows dispatched,
             tokens delivered, text events put, rows held = tokens and no
-            text, dur_ms; these five carry t = time.monotonic()) / preempt /
+            text, dur_ms; these five carry t = time.monotonic()) /
+            admit_read (a batched admission's first tokens were read from
+            the in-flight queue: rows, after_rid = the newest round
+            fetched before it, wait_ms, blocked = the read still had to
+            wait for the device, t) / preempt /
             offload / restore / cow / pin / unpin / snap (paged ledger
             snapshot for preempt/offload) / pg_tbl (device
             block-table reset/rebuild, with the shared-row count) /

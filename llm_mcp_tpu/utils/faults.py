@@ -24,6 +24,8 @@ Sites wired in-tree:
                      (exercises lease-expiry reclaim: the job outcome is
                      computed but never reported, as if the worker died)
   engine.decode    — GenerationEngine decode loop (engine failure guards)
+  engine.admit     — the read of a batched admission's first tokens, where
+                     a poisoned admission surfaces (engine._read_admit)
   api.request      — HTTP request dispatch (client-visible 5xx)
 """
 

@@ -6,13 +6,22 @@ reference has no such in-process tests; we exceed it).
 """
 
 import concurrent.futures as cf
+import contextlib
+import functools
+import os
+import tempfile
+import time
 
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from llm_mcp_tpu.executor import GenerationEngine, EmbeddingEngine
+from llm_mcp_tpu.executor.engine import _DONE, GenRequest
 from llm_mcp_tpu.executor.tokenizer import ByteTokenizer
+from llm_mcp_tpu.telemetry import recorder as flight
+from llm_mcp_tpu.telemetry.recorder import FlightRecorder
+from llm_mcp_tpu.utils import faults
 
 
 @pytest.fixture(scope="module")
@@ -876,3 +885,372 @@ def test_pipelined_compact_cap_churn(monkeypatch):
         assert again["usage"]["completion_tokens"] >= 1
     finally:
         eng.shutdown()
+
+
+# -- admissions in the in-flight queue (PR 29) -------------------------------
+# An admission joins the same queue as the decode rounds, in device order,
+# and its first tokens are read when it is the oldest item there. On the CPU:
+# the ORDER of the loop's reads and emissions and what each stream received,
+# never a time.
+
+QKW = dict(max_slots=16, max_seq_len=96, dtype=jnp.float32, decode_chunk=4, seed=5)
+QUEUE_CASES = [(1, 1, "off"), (1, 4, "off"), (2, 1, "off"), (2, 4, "off"), (2, 4, "on"), (1, 1, "on")]
+# (round at whose dispatch the request arrives, prompt, max_tokens): the first
+# starts the engine, the others arrive while rounds are in flight, two at once
+SCRIPT = [(0, "the long stream that keeps rounds in flight", 40), (2, "second " * 3, 9),
+          (3, "a pair, one", 6), (3, "a pair, two " * 2, 14), (6, "late", 1), (8, "last one in", 11)]
+
+
+@contextlib.contextmanager
+def _queue_engine(depth, env=(), **kw):
+    """An engine with a ring of its own and the pipeline depth asked for."""
+    env = {"TPU_PIPELINE_DEPTH": str(depth), "TPU_SPEC": "0", **dict(env)}
+    old = {k: os.environ.get(k) for k in env}
+    os.environ.update(env)
+    rec = FlightRecorder(capacity=16384, dump_dir=tempfile.mkdtemp(prefix="flight"))
+    prev = flight.set_recorder(rec)
+    eng = None
+    try:
+        eng = GenerationEngine("tiny-llm", **{**QKW, **kw})
+        yield eng, rec
+    finally:
+        if eng is not None:
+            eng.shutdown()
+        flight.set_recorder(prev)
+        for k, v in old.items():
+            os.environ.pop(k, None) if v is None else os.environ.__setitem__(k, v)
+
+
+def _request(eng, prompt, max_tokens, **kw):
+    return GenRequest(prompt_ids=eng.tokenizer.encode(prompt), max_tokens=max_tokens,
+                      temperature=0.0, **kw)
+
+
+def _events(req, timeout=120.0):
+    """A stream's events up to its end, without the clocks."""
+    out = []
+    while True:
+        evt = req.out.get(timeout=timeout)
+        if evt is _DONE:
+            return out
+        out.append({k: v for k, v in evt.items() if k not in ("t", "ttft_ms")})
+        if evt["type"] == "done":
+            return out
+
+
+def _spy(eng, script=()):
+    """Hand `script`'s requests to the engine from its own thread at the
+    dispatch of the round they name (so the admission that follows is
+    dispatched behind that round), and note what the loop does: each
+    admission's dispatch (rounds dispatched and fetched by then), each read,
+    each round's rows by request, every token a request was given."""
+    seen = {"admits": [], "reads": [], "rounds": [], "tokens": {}, "log": [],
+            "due": sorted(script, key=lambda x: x[0])}  # a test may add to it while the engine is idle
+    due = seen["due"]
+    dispatch, start, read, process = (eng._dispatch_decode, eng._start_batch, eng._read_admit,
+                                      eng._process_token)
+
+    def spy_dispatch(active, group=None):
+        rid = eng._rid_dispatched + 1
+        while due and due[0][0] <= rid:
+            _, req = due.pop(0)
+            req() if callable(req) else eng.submit(req)
+        seen["rounds"].append((rid, [eng._slots[b].req.request_id for b in active]))
+        seen["log"].append(("round", rid))
+        return dispatch(active, group)
+
+    def spy_start(batch):
+        adm = start(batch)
+        seen["admits"].append({"adm": adm, "dispatched": eng._rid_dispatched, "fetched": eng._rid_fetched,
+                               "requests": [r.request_id for _, r, _ in batch],
+                               "slots": [slot for slot, _, _ in batch]})
+        seen["log"].append(("admit", id(adm)))
+        return adm
+
+    def spy_read(adm, at_once=False):
+        seen["reads"].append({"adm": adm, "fetched": eng._rid_fetched, "at_once": at_once})
+        seen["log"].append(("read", id(adm)))
+        return read(adm, at_once)
+
+    def spy_process(s, tok, pos):
+        seen["tokens"].setdefault(s.req.request_id, []).append((pos, tok))
+        return process(s, tok, pos)
+
+    eng._dispatch_decode, eng._start_batch, eng._read_admit, eng._process_token = (
+        spy_dispatch, spy_start, spy_read, spy_process)
+    return seen
+
+
+@functools.lru_cache(maxsize=None)
+def _one_at_a_time():
+    """SCRIPT's requests on a depth-1 engine, each alone: what every stream
+    must receive, and the tokens behind it."""
+    with _queue_engine(1) as (eng, _rec):
+        seen = _spy(eng)
+        eng.start()
+        want = []
+        for _, prompt, n in SCRIPT:
+            req = eng.submit(_request(eng, prompt, n))
+            want.append((_events(req), seen["tokens"][req.request_id]))
+        return want
+
+
+@functools.lru_cache(maxsize=None)
+def _scripted(depth, admit_batch, compact):
+    with _queue_engine(depth, admit_batch=admit_batch, decode_compact=compact) as (eng, rec):
+        reqs = [_request(eng, p, n) for _, p, n in SCRIPT]
+        seen = _spy(eng, [(at, r) for (at, _, _), r in zip(SCRIPT, reqs) if at])
+        eng.start()
+        eng.submit(reqs[0])
+        got = [_events(r) for r in reqs]
+        deadline = time.monotonic() + 30.0
+        while (eng._inflight or any(s is not None for s in eng._slots)) and time.monotonic() < deadline:
+            time.sleep(0.01)  # rounds dispatched before the last finish was known are still fetched
+        return {"got": got, "tokens": [seen["tokens"].get(r.request_id) for r in reqs], "seen": seen,
+                "ring": [(e["etype"], e["fields"]) for e in rec.snapshot()], "stats": eng.perf_stats(),
+                "errors": eng.total_errors, "ids": [r.request_id for r in reqs],
+                "left": len(eng._inflight)}
+
+
+@pytest.mark.parametrize("depth,admit_batch,compact", QUEUE_CASES)
+def test_queued_admissions_serve_what_one_at_a_time_serves(depth, admit_batch, compact):
+    """(a) every stream's events (text split as the rounds split it, usage,
+    finish reason) and tokens are those of the same request served alone, and
+    a stream's first token comes before its first round's."""
+    run, want = _scripted(depth, admit_batch, compact), _one_at_a_time()
+    assert run["errors"] == 0 and run["left"] == 0
+    for (_, prompt, n), got, toks, (want_events, want_toks) in zip(SCRIPT, run["got"], run["tokens"], want):
+        assert got == want_events, prompt
+        assert toks == want_toks, prompt
+        assert got[-1]["type"] == "done" and got[-1]["usage"]["completion_tokens"] <= n
+        # positions rise from the prompt's last: the first token is processed
+        # (and its text put) before any token of a round
+        assert [p for p, _ in toks] == list(range(toks[0][0], toks[0][0] + len(toks)))
+    assert sum(1 for r in run["seen"]["admits"] if r["dispatched"] > r["fetched"]) >= 2
+
+
+@pytest.mark.parametrize("depth,admit_batch,compact", QUEUE_CASES)
+def test_an_admission_is_read_when_it_is_the_oldest_item_in_flight(depth, admit_batch, compact):
+    """(b) from the flight ring: an `admit_read` comes after the fetch AND the
+    emit of every round up to its `after_rid` and before the fetch of the
+    next; no read of an admission waits while a round dispatched before it is
+    unfetched."""
+    run = _scripted(depth, admit_batch, compact)
+    ring, seen = run["ring"], run["seen"]
+    at = {kind: {} for kind in ("fetch", "emit")}
+    for i, (etype, f) in enumerate(ring):
+        if etype in at:
+            at[etype][f["rid"]] = i
+    dispatched = [f["rid"] for etype, f in ring if etype in ("decode", "fused", "fused_rag")]
+    reads = [(i, f) for i, (etype, f) in enumerate(ring) if etype == "admit_read"]
+    assert len(reads) == len(seen["admits"]) == len(seen["reads"]) == run["stats"]["admit_reads"]
+    assert run["stats"]["admit_reads_at_once"] == 0
+    assert sum(f["rows"] for _, f in reads) == len(SCRIPT)
+    for (i, f), admit, read in zip(reads, seen["admits"], seen["reads"]):
+        assert read["adm"] is admit["adm"]  # read in the order dispatched
+        r = f["after_rid"]
+        before = [rid for rid in dispatched if rid <= r]
+        assert all(at["fetch"][rid] < i and at["emit"][rid] < i for rid in before)
+        assert all(at["fetch"][rid] > i for rid in dispatched if rid > r and rid in at["fetch"])
+        # every round dispatched before the admission was fetched by its read
+        assert r == read["fetched"] >= admit["dispatched"]
+        assert f["wait_ms"] >= 0 and f["blocked"] in (True, False) and f["t"] > 0
+    if admit_batch == 4:
+        assert max(f["rows"] for _, f in reads) == 2  # the pair went in one program
+    # an admission dispatched behind a full pipeline: those rounds were
+    # emitted before its first tokens were read
+    full = [a for a in seen["admits"] if a["dispatched"] - a["fetched"] == depth]
+    assert full, [(a["dispatched"], a["fetched"]) for a in seen["admits"]]
+
+
+@pytest.mark.parametrize("depth,admit_batch,compact", [(1, 1, "off"), (2, 1, "off"), (2, 4, "on")])
+def test_a_first_token_that_ends_the_reply_frees_its_slot(depth, admit_batch, compact):
+    """(c) a first token that is EOS, and `max_tokens` 1, learned after the
+    row has ridden a round: the reply ends with the usage it has alone on an
+    idle engine (where the admission is read before any round), no token of
+    the round leaks, the slot is admitted again."""
+    long_prompt, n_long = SCRIPT[0][1], SCRIPT[0][2]
+    with _queue_engine(depth, admit_batch=admit_batch, decode_compact=compact) as (eng, _rec):
+        seen = _spy(eng)
+        eng.start()
+        alone = lambda prompt, n: _events(eng.submit(_request(eng, prompt, n)))  # noqa: E731
+        probe = eng.submit(_request(eng, "ends at once", 4))
+        _events(probe)
+        tok0 = seen["tokens"][probe.request_id][0][1]
+        assert tok0 not in {t for _, t in _one_at_a_time()[0][1]}  # the long stream never samples it
+        real_eos = eng.tokenizer.eos_id
+        eng.tokenizer.eos_id = tok0
+        try:
+            want_eos, want_one = alone("ends at once", 4), alone("late", 1)
+            assert want_eos == [{"type": "done", "finish_reason": "stop", "usage": {
+                "prompt_tokens": len(eng.tokenizer.encode("ends at once")), "completion_tokens": 0,
+                "total_tokens": len(eng.tokenizer.encode("ends at once"))}}]
+            assert want_one[-1]["finish_reason"] == "length" and want_one[-1]["usage"]["completion_tokens"] == 1
+            n_admits = len(seen["admits"])
+            long_req, eos_req, one_req = (_request(eng, long_prompt, n_long), _request(eng, "ends at once", 4),
+                                          _request(eng, "late", 1))
+            later = [_request(eng, f"after the slot is free {i}", 5) for i in range(3)]
+            r0 = eng._rid_dispatched
+            seen["due"] += [(r0 + 2, eos_req), (r0 + 4, one_req)] + [
+                (r0 + 9, r) for r in later]  # past the cooling fences
+            eng.submit(long_req)
+            got_long, got_eos, got_one = _events(long_req), _events(eos_req), _events(one_req)
+            got_later = [_events(r) for r in later]
+        finally:
+            eng.tokenizer.eos_id = real_eos
+        assert got_eos == want_eos and got_one == want_one
+        assert got_long == _one_at_a_time()[0][0]  # its neighbours' ends changed nothing for it
+        assert all(g[-1]["type"] == "done" for g in got_later) and eng.total_errors == 0
+        for req, n_tok in ((eos_req, 1), (one_req, 1)):
+            # the row rode at least one round before its first token was read ...
+            assert any(req.request_id in rows for _, rows in seen["rounds"])
+            # ... and nothing of that round was processed for it
+            assert len(seen["tokens"][req.request_id]) == n_tok
+        new = seen["admits"][n_admits:]
+        slot_of = {rid: slot for a in new for rid, slot in zip(a["requests"], a["slots"])}
+        reused = [slot_of[r.request_id] for r in later]
+        assert slot_of[eos_req.request_id] in reused and slot_of[one_req.request_id] in reused
+        assert eng.perf_stats()["admit_reads"] == len(seen["admits"])
+
+
+@pytest.mark.parametrize("depth,admit_batch", [(1, 1), (2, 1), (2, 4)])
+def test_an_admission_that_raises_at_its_read_fails_its_own_and_what_followed(depth, admit_batch):
+    """(d) a poisoned admission surfaces at its read (the `engine.admit`
+    chaos site): its requests get the error event and the end of the stream,
+    whatever was dispatched after it fails as after a poisoned round, and the
+    loop serves the next request."""
+    with _queue_engine(depth, admit_batch=admit_batch) as (eng, rec):
+        seen = _spy(eng)
+        eng.start()
+        long_req = _request(eng, SCRIPT[0][1], 40)
+        victims = [_request(eng, "poisoned one", 8), _request(eng, "poisoned, the second", 8)]
+
+        def arm():  # on the engine's thread, with the victims: every read from here on raises
+            faults.configure("engine.admit:1.0", seed=0)
+            for v in victims:
+                eng.submit(v)
+
+        seen["due"].append((2, arm))
+        try:
+            eng.submit(long_req)
+            got = [_events(r) for r in [long_req, *victims]]
+            assert faults.trip_counts() == {"engine.admit": 1}  # the first poisoned read ended them all
+        finally:
+            faults.configure("")
+        for events in got[1:]:
+            assert events == [{"type": "error", "error": "injected fault at engine.admit"}]
+        # the stream that rode the rounds behind the admission: its tokens
+        # up to the last round emitted, then the same error
+        assert got[0][-1] == {"type": "error", "error": "injected fault at engine.admit"}
+        assert [e["type"] for e in got[0][:-1]] == ["token"] * (len(got[0]) - 1) and len(got[0]) >= 2
+        assert "".join(e["text"] for e in got[0][:-1]) and _one_at_a_time()[0][0][0] == got[0][0]
+        assert eng.total_errors == 3 and not eng._inflight and all(s is None for s in eng._slots)
+        assert eng._rid_fetched == eng._rid_dispatched
+        # the victims' admissions were dispatched (one program or two) and none was read
+        assert sum(len(a["requests"]) for a in seen["admits"]) == 3
+        assert eng.perf_stats()["admit_reads"] == 1 == len(rec.snapshot(etype="admit_read"))
+        after = eng.generate("served after the failure", max_tokens=6, temperature=0.0)
+        assert after["usage"]["completion_tokens"] == 6 and eng.total_errors == 3
+        assert eng.perf_stats()["admit_reads"] == 2
+
+
+def test_a_constrained_admission_is_read_where_it_is_dispatched():
+    """(e) the automaton's cursor must stand on the first token before the
+    slot's masked round: a batch that holds a constrained request is read at
+    once, behind whatever is in flight, and counted."""
+    with _queue_engine(2, admit_batch=4) as (eng, _rec):
+        choice = _request(eng, "heads or tails?", 8, constraint={"type": "choice", "choices": ["heads", "tails"]})
+        plain = _request(eng, "an unconstrained neighbour", 6)
+        seen = _spy(eng, [(2, choice), (4, plain)])
+        eng.start()
+        long_req = eng.submit(_request(eng, SCRIPT[0][1], 40))
+        got = {k: _events(r) for k, r in (("long", long_req), ("choice", choice), ("plain", plain))}
+        assert "".join(e["text"] for e in got["choice"] if e["type"] == "token") in ("heads", "tails")
+        assert got["long"] == _one_at_a_time()[0][0] and got["plain"][-1]["type"] == "done"
+        st = eng.perf_stats()
+        assert (st["admit_reads"], st["admit_reads_at_once"]) == (3, 1) and eng.total_errors == 0
+        by_req = {a["requests"][0]: (a, r) for a, r in zip(seen["admits"], seen["reads"])}
+        a, r = by_req[choice.request_id]
+        assert r["adm"] is a["adm"] and r["at_once"] and r["fetched"] < a["dispatched"]  # rounds were unfetched
+        i = seen["log"].index(("admit", id(a["adm"])))
+        assert seen["log"][i + 1] == ("read", id(a["adm"]))
+        a, r = by_req[plain.request_id]
+        assert not r["at_once"] and r["fetched"] >= a["dispatched"]
+
+
+def test_shutdown_leaves_no_admission_unread():
+    """(e) an admission in flight when the loop stops is read by the last
+    drain: its first token is on the stream before the shutdown's error."""
+    with _queue_engine(2) as (eng, _rec):
+        late = _request(eng, "admitted as the loop stops", 30)
+
+        def stop():
+            eng.submit(late)
+            eng._stop_evt.set()
+
+        seen = _spy(eng, [(3, stop)])
+        eng.start()
+        long_req = eng.submit(_request(eng, SCRIPT[0][1], 40))
+        eng._thread.join(timeout=60)
+        assert not eng._thread.is_alive() and not eng._inflight
+        assert len(seen["admits"]) == len(seen["reads"]) == eng.perf_stats()["admit_reads"] == 2
+        assert len(seen["tokens"][late.request_id]) >= 1  # read and processed, by the drain
+        assert seen["log"][-1] == ("read", id(seen["admits"][-1]["adm"]))
+        eng.shutdown()
+        for req in (long_req, late):
+            events = _events(req)
+            assert events[-1] == {"type": "error", "error": "engine shutdown"}
+            assert [e["type"] for e in events[:-1]] == ["token"] * (len(events) - 1)
+
+
+def test_a_preemption_leaves_no_admission_unread():
+    """(e) preemption snapshots committed rows: the drain before it reads
+    the admission that was dispatched an iteration earlier."""
+    with _queue_engine(2, env={"TPU_KV_HOST_OFFLOAD": "1"}, max_slots=2, max_seq_len=128) as (eng, _rec):
+        assert eng._pool is not None
+        second = _request(eng, "second low priority stream", 40)
+        urgent = _request(eng, "urgent request", 8, priority=5)
+        seen = _spy(eng, [(2, lambda: (eng.submit(second), eng.submit(urgent)))])
+        preempt_one = eng._preempt_one
+
+        def spy_preempt():
+            seen["log"].append(("preempt", len(eng._inflight), [s.first_token_at > 0 for s in eng._slots if s]))
+            return preempt_one()
+
+        eng._preempt_one = spy_preempt
+        eng.start()
+        first = eng.submit(_request(eng, "preempt me please", 40))
+        got = [_events(r) for r in (first, second, urgent)]
+        assert all(g[-1]["type"] == "done" for g in got) and eng.total_errors == 0
+        assert eng.memory_stats()["preempted_total"] >= 1
+        adm = next(a["adm"] for a in seen["admits"] if a["requests"] == [second.request_id])
+        log = seen["log"]
+        i, j = log.index(("admit", id(adm))), log.index(("read", id(adm)))
+        k = next(n for n, e in enumerate(log) if e[0] == "preempt")
+        # dispatched, then read by the drain (no round between), then the victim was picked
+        assert j == i + 1 and k == j + 1 and log[k] == ("preempt", 0, [True, True])
+
+
+def test_a_speculative_verify_round_leaves_no_admission_unread():
+    """(e) drafts continue the committed history: whenever a verify round
+    runs, nothing is in flight and every seated slot has its first token."""
+    prompt = "repeat this exact list again and again: alpha beta gamma delta alpha beta gamma delta"
+    with _queue_engine(2, env={"TPU_SPEC": "1"}, max_seq_len=256) as (eng, _rec):
+        assert eng._verify_fn is not None
+        reqs = [_request(eng, prompt + tail, 32) for tail in ("", " alpha", " alpha beta")]
+        seen = _spy(eng, [(1, reqs[1]), (2, reqs[2])])
+        spec_round, calls = eng._spec_round, []
+
+        def spy_spec(entries):
+            calls.append((len(eng._inflight), [s.first_token_at > 0 for s in eng._slots if s]))
+            return spec_round(entries)
+
+        eng._spec_round = spy_spec
+        eng.start()
+        eng.submit(reqs[0])
+        got = [_events(r) for r in reqs]
+        assert all(g[-1]["type"] == "done" for g in got) and eng.total_errors == 0
+        assert calls and eng.speculation_stats()["verify_calls"] == len(calls)
+        assert all(n == 0 and all(stamped) for n, stamped in calls)
+        assert len(seen["reads"]) == len(seen["admits"]) == eng.perf_stats()["admit_reads"]
+        assert any(a["dispatched"] > a["fetched"] for a in seen["admits"])  # one did wait in the queue

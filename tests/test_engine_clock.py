@@ -339,3 +339,81 @@ def test_embedding_engine_stats_against_a_hand_count():
     assert st["lock_wait_s"] >= 0 and st["forward_s"] + st["host_locked_s"] <= (t1 - t0)
     emb.embed(["one more"])
     assert emb.stats()["forwards"] == 3 and emb.stats()["rows_padded"] == 7
+
+
+def test_admission_reads_are_counted_and_recorded_against_a_hand_count(env):
+    """PR 29: an admission's first tokens are read from the in-flight queue.
+    Three plain requests one after another and one constrained: four
+    `admit_read` events of one row each, the last read where it was
+    dispatched; each `after_rid` is the newest round fetched before it."""
+    gen, rec, _led, base = env
+    keys = ("admit_reads", "admit_reads_blocked", "admit_reads_at_once")
+    before = {k: gen.perf_stats()[k] for k in keys}
+    n0 = len(ring(rec, "admit_read"))
+    t0 = time.monotonic()
+    for i in range(3):
+        assert gen.generate(f"count the reads {i}", max_tokens=6, temperature=0.0)["usage"]["completion_tokens"] == 6
+    out = gen.generate("heads or tails?", max_tokens=8, temperature=0.0,
+                       constraint={"type": "choice", "choices": ["heads", "tails"]})
+    assert out["text"] in ("heads", "tails")
+    t1 = time.monotonic()
+    reads = ring(rec, "admit_read")[n0:]
+    assert len(reads) == 4
+    for r in reads:
+        assert set(r) == {"rows", "after_rid", "wait_ms", "blocked", "t"}
+        assert r["rows"] == 1 and r["wait_ms"] >= 0 and t0 <= r["t"] <= t1 and r["blocked"] in (True, False)
+    after = {k: gen.perf_stats()[k] for k in keys}
+    assert after["admit_reads"] - before["admit_reads"] == 4
+    assert after["admit_reads_at_once"] - before["admit_reads_at_once"] == 1
+    assert after["admit_reads_blocked"] - before["admit_reads_blocked"] == sum(r["blocked"] for r in reads)
+    # the ring in the order it was written: a read's after_rid is the last fetch before it
+    last_fetch, seen = 0, []
+    for e in rec.snapshot():
+        if e["etype"] == "fetch":
+            last_fetch = e["fields"]["rid"]
+        elif e["etype"] == "admit_read":
+            seen.append((e["fields"]["after_rid"], last_fetch))
+    assert len(seen) >= 4 and all(a == b for a, b in seen[-4:])
+    doc = httpx.get(f"{base}/v1/debug/perf").json()["tiny-llm"]
+    assert {k: doc[k] for k in keys} == after
+
+
+def test_the_admission_blocks_only_under_engine_admit_sync(env, monkeypatch):
+    """`engine_host_ms_per_round` subtracts the admission's blocking read by
+    the name `engine.admit.sync`, nested in `engine.admit`: one such span an
+    admission, around the one device read, and none where it is dispatched."""
+    import inspect
+
+    from llm_mcp_tpu.executor import engine as engine_mod
+
+    gen, rec, _led, _base = env
+    log, real = [], engine_mod.TraceAnnotation
+
+    class Noted:
+        def __init__(self, name, **kw):
+            self.name, self.inner = name, real(name, **kw)
+
+        def __enter__(self):
+            log.append(("in", self.name))
+            return self.inner.__enter__()
+
+        def __exit__(self, *exc):
+            log.append(("out", self.name))
+            return self.inner.__exit__(*exc)
+
+    n = len(ring(rec, "emit"))
+    monkeypatch.setattr(engine_mod, "TraceAnnotation", Noted)
+    gen.generate("one admission, one blocking read", max_tokens=6, temperature=0.0)
+    settle(gen, rec, n + 2)
+    monkeypatch.setattr(engine_mod, "TraceAnnotation", real)
+    sync = [i for i, e in enumerate(log) if e == ("in", "engine.admit.sync")]
+    assert len(sync) == 1
+    opened = [name for kind, name in log[:sync[0]] if kind == "in"]
+    closed = [name for kind, name in log[:sync[0]] if kind == "out"]
+    assert opened.count("engine.admit") - closed.count("engine.admit") == 1  # nested in the phase
+    assert log[sync[0] + 1] == ("out", "engine.admit.sync")
+    read_src = inspect.getsource(GenerationEngine._read_admit)
+    assert read_src.count("np.asarray(") == 1 and 'TraceAnnotation("engine.admit.sync")' in read_src
+    for fn in (GenerationEngine._start_batch, GenerationEngine._seat, GenerationEngine._admit_pending):
+        src = inspect.getsource(fn)
+        assert "np.asarray(toks0" not in src and "block_until_ready" not in src and ".sync" not in src

@@ -311,12 +311,15 @@ def test_decode_step_reads_stacked_weights_in_place(
     assert temp < (256 << 20) + sum(w.size * w.dtype.itemsize for w in whole)
 
 
-def test_a_fall_to_the_reference_is_counted():
+def test_a_fall_to_the_reference_is_counted(tmp_path):
     """What `compile_for_chip` and chip_smoke.py's zero-fall check stand on: a
     shape gate that fails with interpret=False is counted and lands in the
     flight recorder; interpret mode, which takes the exact math by design, is
-    not. hd=64 is not lane-aligned, so append_kv_q8 takes its scatter."""
-    from llm_mcp_tpu.telemetry.recorder import get_recorder
+    not. hd=64 is not lane-aligned, so append_kv_q8 takes its scatter. The
+    fall is made on purpose, into a recorder of the test's own: the process's
+    ring keeps no `kernel_fall` for chip_smoke's check to find when one worker
+    runs both files."""
+    from llm_mcp_tpu.telemetry import recorder as flight
 
     n, hd = 4, 64
     ck = {"q": jax.ShapeDtypeStruct((2, n, 2 * HKV + 1, 128, hd), I8),
@@ -330,13 +333,17 @@ def test_a_fall_to_the_reference_is_counted():
             ck, new, new, lens)
 
     falls = dict(A.reference_falls)
-    events = len(get_recorder().snapshot(etype="kernel_fall"))
+    own = flight.FlightRecorder(capacity=64, dump_dir=str(tmp_path))
+    before = len(flight.get_recorder().snapshot(etype="kernel_fall"))  # (makes the process's ring, if none was)
+    prev = flight.set_recorder(own)
     try:
         trace(True)
         assert A.reference_falls == falls
         trace(False)
         assert A.reference_falls == {**falls, "append_kv_q8": falls.get("append_kv_q8", 0) + 1}
-        assert len(get_recorder().snapshot(etype="kernel_fall")) == events + 1
-    finally:  # the table is the process's: leave it as the other tests expect it
+        assert [e["fields"]["kernel"] for e in own.snapshot(etype="kernel_fall")] == ["append_kv_q8"]
+    finally:  # table and ring are the process's: leave them as the other tests expect them
+        flight.set_recorder(prev)
         A.reference_falls.clear()
         A.reference_falls.update(falls)
+    assert len(flight.get_recorder().snapshot(etype="kernel_fall")) == before
