@@ -5,7 +5,7 @@ knob-registry, import-purity, registry-census — behind one runner with a
 justified-allowlist baseline. Entry points:
 
 - ``python -m llm_mcp_tpu.analysis`` (human report; ``--json`` for CI)
-- ``scripts/lint_gate.py`` (CI gate, perf_gate.py conventions)
+- ``scripts/lint_gate.py`` (CI gate)
 - ``tests/test_analysis.py`` (tier-1: zero non-baselined findings)
 
 See doc/static_analysis.md for the pass catalog and baseline workflow.

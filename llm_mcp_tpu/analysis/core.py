@@ -53,8 +53,8 @@ DEFAULT_CONFIG: dict = {
     "perf_module": "llm_mcp_tpu/telemetry/perf.py",
     "recorder_module": "llm_mcp_tpu/telemetry/recorder.py",
     # knob-registry scan: the package plus the out-of-package readers the
-    # operator doc documents (bench.py's BENCH_* rows ride along)
-    "knob_extra_roots": ["bench.py", "scripts"],
+    # operator doc documents
+    "knob_extra_roots": ["scripts"],
     "knob_prefixes": ("TPU_", "LLM_MCP_TPU_"),
     # etypes the recorder census must explicitly list even if the engine
     # stops emitting them (tests/test_perf.py pinned these; wl/wf are the

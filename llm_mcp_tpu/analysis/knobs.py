@@ -18,11 +18,10 @@ directions:
   row or restore the reader — a documented knob that does nothing is an
   operator trap (the DB_DSN lesson, utils/config.py).
 
-Scan roots are the package plus `bench.py` and `scripts/` (doc rows like
-BENCH_COLDSTART are read there); tests never count as reading sites. A
-"doc row" is a markdown table row whose FIRST cell backticks the name —
-prose mentions (e.g. "replaces the retired `TPU_PREFILL_BOOST`") do not
-document a knob.
+Scan roots are the package plus `scripts/`; tests never count as reading
+sites. A "doc row" is a markdown table row whose FIRST cell backticks the
+name — prose mentions (e.g. "replaces the retired `TPU_PREFILL_BOOST`") do
+not document a knob.
 
 The full registry rides the `--json` report so future automation (config
 dump endpoints, doc generators) can consume it without re-parsing.

@@ -206,8 +206,7 @@ class InferenceAPI:
         if self.zoo is not None and model in self.zoo.models():
             # zoo-managed model: resident engines return instantly; a
             # parked one pays its swap-in here, on the request thread —
-            # the cold model's first token INCLUDES the swap, which is
-            # exactly the latency the bench zoo_sweep measures
+            # the cold model's first token INCLUDES the swap
             try:
                 return self.zoo.get(model)
             except (KeyError, RuntimeError):

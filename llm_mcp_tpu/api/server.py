@@ -103,7 +103,7 @@ class CoreServer:
         # fleet prefix tier (routing/prefix.py): engine export/import
         # counters bridge by delta; route outcomes accumulate here for the
         # dashboard/debug surfaces. prefix_sources lets in-process peers
-        # (bench, tests) register a duck-typed `prefix_fetch(ids)` source
+        # (tests) register a duck-typed `prefix_fetch(ids)` source
         # directly; remote peers resolve lazily from their advertised
         # transfer_addr tag through a cached gRPC transfer client.
         self._prefix_tier_counts: dict[str, dict[str, float]] = {}
@@ -613,8 +613,7 @@ class CoreServer:
                 "decode_compact": e.decode_compact,
                 "stalled": e.stalled,
                 "prefix_cache": e.prefix_cache_stats(),
-                # engine-loop wall-clock by phase since boot (the serve
-                # budget breakdown bench.py windows — here cumulative, so
+                # engine-loop wall-clock by phase since boot (cumulative, so
                 # operators can diff two dashboard snapshots)
                 "phase_s": {
                     k: round(v, 1) for k, v in e.phase_budget().items()
@@ -860,7 +859,7 @@ class CoreServer:
                 info[name]["workload"] = wls()
         # Process-wide flight ring + compile ledger (telemetry/recorder.py
         # singletons shared by every engine in this process): events advance
-        # by delta, drops are a gauge (perf_gate hard-fails >0), and each
+        # by delta, drops are a gauge (nonzero: a dump lost events), and each
         # fresh ledger entry feeds the compile histogram exactly once.
         rec = flight.get_recorder()
         cur_ev = float(rec.events_total())

@@ -177,8 +177,8 @@ class EmbeddingEngine:
     def prepare_ids(self, text: str) -> list[int]:
         """Tokenize one input exactly as `embed` feeds the forward pass
         (truncation + the trailing [SEP] for encoder tokenizers). The single
-        source of truth for anything that must time or replay the REAL
-        executable (bench.py's b1 latency breakdown)."""
+        source of truth for anything that must replay the REAL executable
+        (benchmark/correctness.py feeds its reference these ids)."""
         ids = self.tokenizer.encode(text)[: self.max_seq_len]
         eos = getattr(self.tokenizer, "eos_id", -1)
         if not self.decoder_arch and eos is not None and eos >= 0:

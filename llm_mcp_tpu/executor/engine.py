@@ -247,7 +247,7 @@ class GenRequest:
     migrations: int = 0
     # latency waterfall (telemetry/workload.py): admission-shed backoff the
     # client spent before this submit landed. Stamped by the serving layer
-    # (bench clients, api handlers) — the engine only ever reads it.
+    # (api handlers) — the engine only ever reads it.
     shed_wait_s: float = 0.0
     # Tenancy (model zoo): the API-key-derived tenant id this request bills
     # against. "" (the default) is unmetered — per-tenant quotas, goodput
@@ -437,7 +437,6 @@ class GenerationEngine:
         decode_compact: str = "auto",
         prompt_cache_mb: int = 256,
         prefill_buckets: str = "fine",
-        prefill_boost: float = 2.0,
         target_ttft_ms: float = 2000.0,
         backend: DispatchBackend | None = None,
     ):
@@ -567,12 +566,7 @@ class GenerationEngine:
         # Token-budget scheduler (scheduler.py): prefill rides INSIDE decode
         # rounds under a per-round token budget self-tuned from measured
         # per-token prefill vs decode-round cost, clamped so the oldest
-        # mid-prefill prompt still activates within target_ttft_ms. Replaces
-        # the retired wall-clock alternation (last decode time ×
-        # TPU_PREFILL_BOOST) that let prefill monopolize the loop on a
-        # locally-attached chip. `prefill_boost` is accepted-and-ignored so
-        # existing construction sites keep working.
-        del prefill_boost
+        # mid-prefill prompt still activates within target_ttft_ms.
         self.target_ttft_ms = max(1.0, float(target_ttft_ms))
         self._sched = TokenBudgetScheduler(
             target_ttft_ms=self.target_ttft_ms,
@@ -1380,7 +1374,7 @@ class GenerationEngine:
                 n_slots=max_slots, seq_len=max_seq_len, block_tokens=bt_,
                 pool_rows=self._paging.prefix_partition,
             )
-            # honest HBM accounting peak (bench.py paged_hbm_bytes_ratio):
+            # honest HBM accounting peak (paging_stats() hbm_bytes_ratio_peak):
             # contiguous-equivalent bytes ÷ physically-resident bytes,
             # sampled at every shared admission (the sharing peak)
             self._phys_hbm_peak_ratio = 1.0
@@ -1576,7 +1570,7 @@ class GenerationEngine:
         self._seen_exec_shapes: set[tuple] = set()
         self._compile_grace_until = 0.0
         # Warmup planner (executor/warmup.py): built by start_warmup() at
-        # boot (serving entrypoints / bench coldstart), None on the plain
+        # boot (serving entrypoints), None on the plain
         # test path and under TPU_WARMUP=0 — readiness then reads as
         # fully_warm (an unwarmed engine is not "warming", it is simply
         # pre-warmup-era cold, and must route exactly as before).
@@ -1593,11 +1587,10 @@ class GenerationEngine:
         self.total_tokens = 0
         self.total_requests = 0
         # requests failed with an error event (poisoned rounds, failed
-        # prefills, cache loss) — bench.py refuses a serve window where this
-        # moved (a degenerate run must never become the metric of record)
+        # prefills, cache loss): the engine's block at /v1/dashboard
         self.total_errors = 0
         # cleanly finished requests + their completion tokens: the ratio is
-        # the mean completion length, bench.py's decode-actually-ran guard
+        # the mean completion length a shed's Retry-After is estimated from
         self.finished_requests = 0
         self.finished_tokens = 0
         # rolling client-observed TTFT samples (ts, ttft_ms): the planner
@@ -1608,8 +1601,7 @@ class GenerationEngine:
         self._window: list[tuple[float, int]] = []  # (ts, tokens) for tps
         # engine-loop wall-clock by phase (serve budget breakdown): decode
         # dispatch staging, round fetch-wait, admission, chunked prefill,
-        # token emission, idle. bench.py snapshots this across the serve
-        # window so the serve↔raw gap has named components.
+        # token emission, idle. /v1/dashboard shows it as `phase_s`.
         self._phase_s: dict[str, float] = {
             k: 0.0 for k in ("dispatch", "fetch", "admit", "prefill", "emit", "idle")
         }
@@ -2771,7 +2763,8 @@ class GenerationEngine:
 
     def phase_budget(self) -> dict[str, float]:
         """Accumulated engine-loop wall-clock seconds per phase. Snapshot at
-        two points and subtract to budget a window (bench.py serve output)."""
+        two points and subtract to budget a window (`phase_s` at
+        /v1/dashboard)."""
         return dict(self._phase_s)
 
     def ttft_percentiles(
@@ -2824,12 +2817,11 @@ class GenerationEngine:
         }
 
     def constrain_stats(self) -> dict[str, Any]:
-        """Constrained-decoding observability (/v1/debug/constrain + the
-        bench line of record): traffic counters, the token-level validity
-        proof (illegal_tokens must be 0 — the mask makes illegal emission
-        impossible by construction; the counter is the check), per-token
-        host mask cost, spec-composition acceptance, and the schema
-        compile-cache economics."""
+        """Constrained-decoding observability (/v1/debug/constrain): traffic
+        counters, the token-level validity proof (illegal_tokens must be 0 —
+        the mask makes illegal emission impossible by construction; the
+        counter is the check), per-token host mask cost, spec-composition
+        acceptance, and the schema compile-cache economics."""
         toks = float(self.cn_tokens)
         fin = float(self.cn_finished)
         drafted = float(self.cn_spec_drafted)
@@ -3063,9 +3055,9 @@ class GenerationEngine:
         return True
 
     def _count_error(self, n: int = 1) -> None:
-        """All total_errors bumps go through here: the counter is read as
-        deltas by bench.py's degenerate-window gate and written from both the
-        engine and watchdog threads, so it must always be under stats_lock."""
+        """All total_errors bumps go through here: the counter is written
+        from both the engine and watchdog threads, so it must always be under
+        stats_lock."""
         with self.stats_lock:
             self.total_errors += n
 
@@ -3205,8 +3197,7 @@ class GenerationEngine:
         homes + pool rows, each resident ONCE) against what the
         pre-physical contiguous engine held for the same set (every
         sharer's full row copy, plus the prefix entries' own device rows).
-        The peak ratio is bench.py's `paged_hbm_bytes_ratio` line-of-record
-        metric; perf_gate floors it."""
+        The peak ratio is `hbm_bytes_ratio_peak` of paging_stats()."""
         st = self._paging.stats()
         bb = float(self._paging.bytes_per_block)
         used = st["blocks_used"]
@@ -3300,7 +3291,7 @@ class GenerationEngine:
         self._perf.observe_sample("stream_lag", time.monotonic() - t_put)
 
     def perf_stats(self) -> dict[str, Any]:
-        """Perf-observatory block (/v1/debug/perf + engines_info + bench):
+        """Perf-observatory block (/v1/debug/perf, engines_info, benchmark/):
         ITL percentiles, goodput split, sampled per-phase host/device/wait
         attribution, and the four-layout roofline, with the engine's count
         of admissions read from the in-flight queue (`admit_reads`; of them
@@ -4157,9 +4148,8 @@ class GenerationEngine:
         inflight = self._inflight
         K = self.decode_chunk
         S = self.max_seq_len
-        # wall-clock budget per loop phase (serve breakdown, bench.py):
-        # where an engine-loop second actually goes — the published answer
-        # to "why is serve below raw decode"
+        # wall-clock budget per loop phase: where an engine-loop second
+        # actually goes (phase_budget())
         phase = self._phase_s
         # the same vocabulary on the profiler's host plane, beside the
         # device's lines: a device gap is named after the phase that covers

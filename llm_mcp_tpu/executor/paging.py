@@ -41,8 +41,8 @@ Threading: one internal OrderedLock (rank 30 — see doc/concurrency.md);
 every public method is safe from the engine loop, watchdog, and HTTP
 threads. Allocation never blocks serving: if bookkeeping ever drifts past
 the ledger total (a bug), the allocator hands out an overflow id and
-counts it — ``audit()`` and the bench's end-of-run leak counter surface
-it, the request still runs.
+counts it — ``audit()`` and ``leak_count()`` surface it, the request
+still runs.
 """
 
 from __future__ import annotations
@@ -604,8 +604,8 @@ class PagedKVManager:
     def audit(self) -> dict[str, int]:
         """Recompute refcounts from the ownership maps and diff against the
         allocator's ledger. All-zero means no leaks, no double frees, no
-        drift — asserted at quiesce by the soak tests and hard-failed by
-        perf_gate via the bench's paged_block_leaks counter."""
+        drift — asserted at quiesce by the soak tests (leak_count() sums it
+        for paging_stats()["leaks"])."""
         with self._lock:
             want: dict[int, int] = {}
             for table in self._tables.values():
@@ -631,7 +631,8 @@ class PagedKVManager:
             }
 
     def leak_count(self) -> int:
-        """Single scalar for the bench line of record / perf gate."""
+        """Single scalar of audit(): `leaks` of paging_stats() and the
+        `llmtpu_kv_block_leaks` gauge; 0 at quiesce."""
         a = self.audit()
         return (
             a["leaked_blocks"]

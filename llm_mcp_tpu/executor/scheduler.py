@@ -103,7 +103,7 @@ class TokenBudgetScheduler:
         # scales with pads, and attributing pad time to true tokens
         # inflated the EMA and shrank fair_cap under mixed fill (the
         # pre-ragged bug this fixes). The cumulative totals feed the
-        # prefill_pad_waste_pct stat bench promotes to the line of record.
+        # prefill_pad_waste_pct stat of stats() (/v1/dashboard's prefill block).
         self.prefill_true_tokens = 0
         self.prefill_padded_tokens = 0
         self.pad_waste = 0.0  # EMA of per-dispatch waste fraction

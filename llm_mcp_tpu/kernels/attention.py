@@ -1132,31 +1132,6 @@ def decode_attend_q8(
     return jax.lax.cond(ident, run_contig, run_paged)
 
 
-def blocked_dma_count(layout: str, packed: bool = True) -> int:
-    """Cache copies per (row, block) cell issued by the blocked decode arms
-    (static layout property; `scripts/kernel_bench.py` and the parity-guard
-    tests read it rather than re-deriving the copy structure).
-
-      q8_gqa   — 1 packed (K|V|scale pseudo-head in one fused int8 block) or
-                 2 unpacked (payload head-slice + plain-scales block)
-      bf16_gqa — 2 (split K and V arrays; no scales to carry)
-      q8_mla   — 1 (latent payload with inlined rope rows; per-position
-                 scales fold via the absorbed-query trick, r05 layout)
-
-    The block-indirect (paged) arms issue the SAME counts — the table adds
-    a scalar lookup and a source branch, not copies (the `*_paged`
-    layouts are accepted so callers can assert that property).
-
-    The r05 pre-fusion GQA layout issued 4 (kq/ks/vq/vs)."""
-    if layout in ("q8_gqa", "q8_gqa_paged"):
-        return 1 if packed else 2
-    if layout in ("bf16_gqa", "bf16_gqa_paged"):
-        return 2
-    if layout in ("q8_mla", "q8_mla_paged"):
-        return 1
-    raise ValueError(f"unknown blocked layout: {layout!r}")
-
-
 def _attend_bf16_kernel(
     li_ref,  # [1] int32 (scalar prefetch) — layer index
     ids_ref,  # [Ba] int32 (scalar prefetch) — cache row per batch position
@@ -1237,8 +1212,8 @@ def _attend_bf16_blocked_kernel(
     `_attend_q8_blocked_kernel`: dynamic trip count streams only the
     attended prefix [0, w], flash-style online softmax across blocks, one
     grid cell = one batch row (all KV heads). Two DMAs per cell (split K and
-    V arrays — `blocked_dma_count("bf16_gqa")`); the bf16 cache keeps its
-    bare split layout because there are no scale rows to fuse."""
+    V arrays); the bf16 cache keeps its bare split layout because there are
+    no scale rows to fuse."""
     b = pl.program_id(0)
     li = li_ref[0]
     row = ids_ref[b]

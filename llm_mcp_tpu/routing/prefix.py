@@ -57,10 +57,8 @@ def prefix_route_enabled() -> bool:
 def fetch_min_tokens() -> int:
     """Crossover length below which recomputing a prefix locally beats
     fetching its KV from a peer (``TPU_PREFIX_FETCH_MIN_TOKENS``). The
-    default is measured by bench.py's prefix-tier microbench (fetch decode
-    + device upload vs chunked prefill): on CPU-backed test engines the
-    crossover sits near one 256-token chunk, and real TPU prefill is
-    faster still — below ~256 tokens the wire round-trip always loses."""
+    default is one 256-token prefill chunk (fetch decode + device upload
+    against chunked prefill); the crossover is not measured on the chip."""
     try:
         return int(os.environ.get("TPU_PREFIX_FETCH_MIN_TOKENS", "256"))
     except ValueError:

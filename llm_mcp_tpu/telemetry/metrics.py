@@ -193,7 +193,7 @@ class Metrics:
         # gauges read straight from paging_stats(); the COW counter is
         # bridged by delta like the pool counters above. sharing_ratio is
         # logical/physical blocks — >1 means prefix sharing is multiplying
-        # capacity; leaks must stay 0 (perf_gate hard-fails on it).
+        # capacity; leaks must stay 0 (tests/test_paging.py asserts it).
         self.kv_blocks_used = Gauge(
             "llmtpu_kv_blocks_used",
             "Physical KV blocks with a live refcount",

@@ -328,7 +328,7 @@ def _pctl(vals: list[float], q: float) -> float:
 class PerfObservatory:
     """Per-process-engine perf state: ITL window, goodput ledger, sampled
     phase attribution, and the roofline evaluation. All writers are the
-    engine thread; readers (API/dashboard/bench) take the same small lock
+    engine thread; readers (API, dashboard, benchmark/) take the same small lock
     the writers do, so snapshots are internally consistent."""
 
     def __init__(

@@ -69,8 +69,8 @@ item-assignment (atomic under the GIL) and never blocks, allocates
 bounded memory, and never touches a lock.  `dump()` freezes appends just
 long enough to copy the ring (microseconds), then journals the copy as
 JSONL off to disk; events arriving while frozen are *counted as dropped*
-rather than queued — the dropped counter is the health signal bench.py
-and the perf gate watch (`recorder_dropped_events` must stay 0).
+rather than queued — the dropped counter is the health signal
+`/metrics` exports (`llmtpu_flight_dropped_events` must stay 0).
 
 Enablement follows tracing.py: on by default, `TPU_FLIGHT=0` disables
 (checked per event, so the knob works on a live process and `=0` is a

@@ -19,9 +19,8 @@ closed-loop clients.  This module closes that gap in three pieces:
    workloads (chat / embed / longctx / bursty agent tool-call loops), and
    ``prompt_text_for`` derives a deterministic prompt for a record that
    carries no raw ids — seeded from the chain head hash so prefix-sharing
-   structure survives the round trip.  bench.py's ``BENCH_TRACE`` mode and
-   scripts/replay.py both build their request streams from these, which is
-   what makes two seeded replays byte-identical.
+   structure survives the round trip.  scripts/replay.py builds its request
+   stream from these, which is what makes two seeded replays byte-identical.
 
 3. **Latency waterfall** — the per-request ledger decomposing wall time
    into stages that sum *exactly* to the measured wall by construction:
@@ -90,8 +89,8 @@ CHAIN_HEAD = 8
 # CLOSED schemas — every field is an enum or boolean, so the constraint
 # automaton's accepting state has no outgoing transitions and the mask
 # forces EOS there. A grammar-constrained replay therefore terminates
-# with valid JSON on ANY model, which is what lets bench.py's
-# schema_valid_rate gate demand exactly 1.0 (scripts/perf_gate.py).
+# with valid JSON on ANY model (tests/test_constrain.py holds every schema
+# here to that).
 AGENT_TOOL_SCHEMAS: tuple = (
     {"type": "object", "properties": {
         "tool": {"enum": ["search", "fetch", "calc"]},
@@ -409,8 +408,8 @@ def synth_trace(kind: str, n: int, seed: int = 0, start_ts: float = 0.0) -> list
               prefix chain (the conversation so far), think-time between.
               Each burst is one tool loop, so its records carry the SAME
               tool-call JSON schema under ``rec["schema"]`` (drawn from
-              AGENT_TOOL_SCHEMAS) — bench.py's constrained sweep wraps it
-              as a json_schema constraint for grammar-constrained replay
+              AGENT_TOOL_SCHEMAS), which a replay can send as a
+              json_schema constraint
     """
     rng = random.Random((seed << 8) ^ len(kind))
     ts = float(start_ts)

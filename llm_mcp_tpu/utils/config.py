@@ -115,9 +115,7 @@ class Config:
     tpu_embed_weights_dir: str = field(
         default_factory=lambda: getenv("TPU_EMBED_WEIGHTS_DIR", "")
     )
-    # 32 fits the default llama-3.1-8b KV cache alongside its weights on one
-    # chip; for 1B-class models TPU_MAX_SLOTS=64 is the measured throughput
-    # optimum (bench.py sweep — larger hits an XLA full-cache-copy cliff).
+    # 32 fits the default llama-3.1-8b KV cache alongside its weights on one chip
     tpu_max_slots: int = field(default_factory=lambda: getenv_int("TPU_MAX_SLOTS", 32))
     tpu_max_seq_len: int = field(default_factory=lambda: getenv_int("TPU_MAX_SEQ_LEN", 2048))
     tpu_mesh_shape: str = field(default_factory=lambda: getenv("TPU_MESH_SHAPE", ""))  # e.g. "dp=1,tp=8"
@@ -215,8 +213,7 @@ class Config:
 
 # enable_compile_cache outcomes, counted not raised: a bad cache dir must
 # never take a serving boot down (the engine runs fine, just cold), but the
-# failure has to be visible somewhere — warmup_stats()/bench read these and
-# chip_smoke.py prints them.
+# failure has to be visible somewhere — chip_smoke.py prints them.
 compile_cache_failures = 0
 compile_cache_dir: str | None = None
 
@@ -240,7 +237,7 @@ def compile_cache_path() -> str:
 def enable_compile_cache(min_compile_s: float = 1.0) -> str | None:
     """Persistent XLA compile cache for every process that compiles for the
     device (`python -m llm_mcp_tpu.api`, the worker with engines,
-    chip_smoke.py, bench.py, tests/conftest.py): first 8B compiles cost
+    chip_smoke.py, benchmark/run.py, tests/conftest.py): first 8B compiles cost
     tens of seconds to minutes each, and a restart would otherwise re-pay
     the whole executable zoo (prompt buckets, compact buckets, admit
     shapes). The warmup planner's background AOT compiles land here too,

@@ -5,11 +5,10 @@ NEW findings.
     python scripts/lint_gate.py            # human report
     python scripts/lint_gate.py --json     # stable machine report (v1)
 
-The CI sibling of perf_gate.py, with the same reporting conventions:
-per-check [PASS]/[FAIL]/[SKIP] lines, skips warned on stderr but never
-failed, a fail only for violations the baseline does not justify. The
-suite (llm_mcp_tpu/analysis) is AST-only — no jax, no package imports —
-so this gate runs anywhere Python runs, in seconds.
+Reporting conventions: per-check [PASS]/[FAIL]/[SKIP] lines, skips warned
+on stderr but never failed, a fail only for violations the baseline does
+not justify. The suite (llm_mcp_tpu/analysis) is AST-only — no jax, no
+package imports — so this gate runs anywhere Python runs, in seconds.
 
 Exit codes: 0 clean (baselined findings allowed), 1 new findings or a
 malformed baseline, 2 usage/environment error. Stale baseline entries

@@ -22,10 +22,6 @@ operator's front door to the capture→replay loop:
     python scripts/replay.py agent.jsonl --core http://localhost:8080 \
         --compress 16 --model tiny-llm
 
-For an engine-level replay with the latency waterfall attached, use
-bench.py's BENCH_TRACE mode instead: ``BENCH_TRACE=agent.jsonl python
-bench.py`` (BENCH_TRACE_COMPRESS / BENCH_TRACE_SEED knobs).
-
 Stdlib + the purity-pinned telemetry package only (urllib for --core), so
 it runs anywhere the core does.
 """
@@ -60,10 +56,11 @@ def load_source(src: str) -> tuple[list[dict], int]:
 
 
 def stream_digest(records: list[dict], seed: int, compress: float) -> str:
-    """Seeded 16-hex digest of the exact request stream a replay issues.
-
-    Mirrors bench.build_replay_stream: gap + prompt + sampling params per
-    record, keyed by (seed, compress) — byte-identical streams hash equal."""
+    """Seeded 16-hex digest of the exact request stream a replay issues:
+    the ONE statement of the replay plan. Gap (the capture's, divided by
+    `compress`) + prompt (the record's raw `ids` where it has them, else
+    `prompt_text_for`) + sampling params per record, keyed by (seed,
+    compress) — byte-identical streams hash equal."""
     h = hashlib.sha256(f"seed={seed} compress={compress}".encode())
     prev_ts = None
     for rec in records:

@@ -274,7 +274,7 @@ def test_dashboard_and_debug(base):
     assert dash["devices_online"] >= 1
     assert "jobs" in dash and "issues" in dash
     assert any(h["role"] for h in dash["hosts"])
-    # serve-budget breakdown per engine (cumulative; bench windows it)
+    # serve-budget breakdown per engine (cumulative)
     gen_info = next(
         v for v in dash["engines"].values() if v["kind"] == "generate"
     )

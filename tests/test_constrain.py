@@ -82,7 +82,7 @@ CLOSED_SCHEMA = {
 def test_closed_schema_accepts_exactly_enumerated_json():
     """A closed schema (every field enum/boolean) admits a FINITE
     language: the four enumerations and nothing else — the property the
-    bench agent schemas lean on so the accepting state is EOS-only."""
+    agent-trace schemas lean on so the accepting state is EOS-only."""
     auto = build_automaton({"type": "json_schema", "schema": CLOSED_SCHEMA})
     # canonical output is compact: keys in schema order, no whitespace
     for tool in ("search", "fetch"):
@@ -844,8 +844,8 @@ def test_http_debug_constrain_endpoint(cn_base):
 
 
 def test_workload_agent_schemas_are_closed():
-    """The bench line of record demands schema_valid_rate == 1.0 exactly;
-    that only holds if every agent-trace schema is CLOSED — the automaton
+    """A constrained replay of the agent trace ends with valid JSON on any
+    model only if every agent-trace schema is CLOSED — the automaton
     accepting state must have no outgoing bytes so the mask forces EOS."""
     import json
 

@@ -41,9 +41,9 @@ def test_generate_basic(engine):
 
 
 def test_phase_budget_accumulates(engine):
-    """The serve-budget breakdown bench.py publishes relies on this
-    contract: phase keys are stable, values accumulate monotonically, and
-    generation moves at least the dispatch/fetch/emit phases."""
+    """The `phase_s` block of /v1/dashboard relies on this contract: phase
+    keys are stable, values accumulate monotonically, and generation moves
+    at least the dispatch/fetch/emit phases."""
     before = engine.phase_budget()
     assert set(before) == {"dispatch", "fetch", "admit", "prefill", "emit", "idle"}
     engine.generate("phase budget probe", max_tokens=6, temperature=0.0)
@@ -52,6 +52,22 @@ def test_phase_budget_accumulates(engine):
     assert after["dispatch"] > before["dispatch"]
     assert after["fetch"] > before["fetch"]
     assert after["emit"] > before["emit"]
+
+
+def test_engine_counts_finished_and_errors():
+    """The counters `/v1/dashboard` and the shed's Retry-After estimate read
+    move with real engine lifecycles."""
+    eng = GenerationEngine(
+        "tiny-llm", max_slots=2, max_seq_len=128, dtype=jnp.float32,
+        decode_chunk=2,
+    ).start()
+    try:
+        out = eng.generate("count me", max_tokens=5, temperature=0.0)
+        assert eng.finished_requests == 1
+        assert eng.finished_tokens == out["usage"]["completion_tokens"]
+        assert eng.total_errors == 0
+    finally:
+        eng.shutdown()
 
 
 def test_generate_deterministic_greedy(engine):

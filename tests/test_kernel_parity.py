@@ -203,7 +203,7 @@ def test_mla_blocked_parity(monkeypatch, fill):
 def test_mla_block_cap_boundary(monkeypatch):
     """The blocked MLA kernel statically unrolls its DMA loop, capped at 64
     blocks: S=32768 @ BS=512 is EXACTLY 64 and must stay on the kernel
-    (the S=32k bench sweep is the cap boundary in production); S=65536
+    (S=32k is the cap boundary); S=65536
     exceeds the cap for every tileable block size and must fall back to
     the exact-f32 path, not compile a 128-way unroll."""
     assert A.mla_block_size(1024) == 512

@@ -464,7 +464,7 @@ def test_engine_phase_and_etype_registries_reconcile():
 
 
 # ---------------------------------------------------------------------------
-# scheduler prefill-economy stats contract (the dashboard/bench input)
+# scheduler prefill-economy stats contract (the dashboard's input)
 # ---------------------------------------------------------------------------
 
 

@@ -12,9 +12,6 @@ Gated: set `LLM_MCP_TPU_REAL_CKPT_DIR` to an HF checkpoint directory
 natural-vs-shuffled logprob probes; encoder (embedding) checkpoints get a
 semantic-cosine probe — the probe that would catch a swapped gate/up pair
 (silu(a)·b ≠ a·silu(b)) or any other self-consistent-but-wrong mapping.
-
-`bench.py` exposes the same harness as a bench secondary when
-`BENCH_REAL_CKPT_DIR` is set (real-checkpoint tok/s + sanity flag).
 """
 
 from __future__ import annotations

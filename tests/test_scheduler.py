@@ -1,19 +1,13 @@
-"""Token-budget scheduler tests: the decide() policy table, engine-loop
-fairness under a prefill backlog (CPU backend, tiny model), and the
-scripts/perf_gate.py regression gate against the repo's real bench records.
+"""Token-budget scheduler tests: the decide() policy table, tenant quotas,
+and engine-loop fairness under a prefill backlog (CPU backend, tiny model).
 
-The r05 regression these guard against: TPU_PREFILL_BOOST let prefill
-monopolize the engine loop (93% of window wall, serve 2428 → 464.7 tok/s)
-while p95 TTFT still blew out to 15.7 s. The scheduler bounds prefill per
-round by the fairness cap; the gate makes the bench numbers un-shippable
-when they regress anyway.
+What these guard against: prefill monopolizing the engine loop while time
+to first token still blows out. The scheduler bounds prefill per round by
+the fairness cap.
 """
 
 from __future__ import annotations
 
-import importlib.util
-import os
-import sys
 import threading
 import time
 
@@ -26,8 +20,6 @@ from llm_mcp_tpu.executor.scheduler import (
     TokenBudgetScheduler,
     parse_tenant_quotas,
 )
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 # --------------------------------------------------------- decide() policy --
@@ -348,9 +340,8 @@ def test_two_tenant_isolation_soak():
     assert sheds["alice"] > 0  # the flood actually hit the quota
     assert sheds["bob"] == 0  # unmetered tenant never sheds
     ratios = perf.tenant_goodput_ratios()
-    # bob's every token met the SLO: ratio pinned at 1.0, well inside the
-    # perf_gate tenant_isolation floor (0.5) — A's overload never reached
-    # B's ledger
+    # bob's every token met the SLO: ratio pinned at 1.0 — A's overload
+    # never reached B's ledger
     assert ratios["bob"] == 1.0
     # alice's admitted requests all violated TTFT: her debt is visible
     assert ratios["alice"] < 0.5
@@ -407,8 +398,8 @@ def test_staged_groups_respect_budget_with_active_decode():
 
 def test_deep_backlog_measures_ttft_for_every_request():
     """A burst deeper than the slot count must activate every prompt and
-    record a TTFT sample for each — the p95 the dashboard and bench gate
-    read is real, not a survivor subset."""
+    record a TTFT sample for each — the p95 the dashboard
+    reads is real, not a survivor subset."""
     import concurrent.futures as cf
 
     eng = GenerationEngine(
@@ -451,21 +442,6 @@ def test_scheduler_stats_surface():
         eng.shutdown()
 
 
-def test_prefill_boost_arg_accepted_and_ignored():
-    """Launch scripts passing the retired knob must keep working."""
-    eng = GenerationEngine(
-        "tiny-llm", max_slots=2, max_seq_len=64, dtype=jnp.float32,
-        decode_chunk=2, prefill_boost=3.0, target_ttft_ms=1500.0,
-    ).start()
-    try:
-        assert not hasattr(eng, "prefill_boost")
-        assert eng._sched.target_ttft_s == pytest.approx(1.5)
-        out = eng.generate("compat", max_tokens=4, temperature=0.0)
-        assert out["usage"]["completion_tokens"] >= 1
-    finally:
-        eng.shutdown()
-
-
 def test_config_target_ttft_knob(monkeypatch):
     from llm_mcp_tpu.utils.config import Config
 
@@ -475,186 +451,3 @@ def test_config_target_ttft_knob(monkeypatch):
     assert not hasattr(cfg, "tpu_prefill_boost")
     monkeypatch.setenv("TPU_TARGET_TTFT_MS", "750")
     assert Config().tpu_target_ttft_ms == 750.0
-
-
-# ------------------------------------------------------- scripts/perf_gate --
-
-
-def _load(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(REPO, "scripts", f"{name}.py")
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-gate = _load("perf_gate")
-
-
-def _bench(name):
-    return os.path.join(REPO, name)
-
-
-# the r04 (healthy) and r05 (regressed) captures the gate was written
-# against: harness-capture shape, the record line only (tests/fixtures/)
-R04 = os.path.join("tests", "fixtures", "gate_record_r04.json")
-R05 = os.path.join("tests", "fixtures", "gate_record_r05.json")
-
-
-def test_extract_record_from_harness_capture():
-    import json
-
-    with open(_bench(R05)) as f:
-        rec = gate.extract_record(json.load(f))
-    assert rec["value"] == pytest.approx(464.7)
-    assert rec["p95_ttft_ms"] == pytest.approx(15664.7)
-    # flat line-of-record shape passes through untouched
-    flat = {"value": 1.0, "metric": "x"}
-    assert gate.extract_record(flat) is flat
-
-
-def test_gate_catches_r05_against_baseline(capsys):
-    """The acceptance criterion: the regressed r05 record must fail even
-    against the metric-less BASELINE.json (absolute floors)."""
-    rc = gate.main([_bench(R05), _bench("BASELINE.json")])
-    assert rc == 1
-    out = capsys.readouterr().out
-    assert "[FAIL] serve_efficiency" in out
-    assert "[FAIL] p95_ttft_ms" in out
-
-
-def test_gate_passes_healthy_r04_against_baseline():
-    assert gate.main([_bench(R04), _bench("BASELINE.json")]) == 0
-
-
-def test_gate_catches_r05_against_r04():
-    assert gate.main([_bench(R05), _bench(R04)]) == 1
-
-
-def test_gate_relative_tolerances(tmp_path):
-    import json
-
-    base = {"value": 1000.0, "p95_ttft_ms": 1000.0, "window_errors": 0.0,
-            "engine_direct_tok_per_s": 1100.0}
-    ok = dict(base, value=950.0, p95_ttft_ms=1200.0)  # -5% / +20%: inside
-    bad = dict(base, value=850.0)  # -15% throughput: outside TOLERANCE
-    for n, doc in (("base", base), ("ok", ok), ("bad", bad)):
-        (tmp_path / f"{n}.json").write_text(json.dumps(doc))
-    assert gate.main([str(tmp_path / "ok.json"), str(tmp_path / "base.json")]) == 0
-    assert gate.main([str(tmp_path / "bad.json"), str(tmp_path / "base.json")]) == 1
-
-
-def test_gate_usage_and_unparseable_inputs(tmp_path):
-    assert gate.main([]) == 2
-    (tmp_path / "empty.json").write_text('{"n": 1, "tail": "no record here"}')
-    assert gate.main([str(tmp_path / "empty.json"), _bench("BASELINE.json")]) == 2
-
-
-def test_gate_missing_keys_skip_with_warning(tmp_path, capsys):
-    """A candidate that predates the spec metrics (every record before this
-    change) must gate cleanly — [SKIP] rows plus a stderr warning, never a
-    KeyError and never a failure."""
-    import json
-
-    cand = {"value": 2400.0, "window_errors": 0.0}
-    (tmp_path / "cand.json").write_text(json.dumps(cand))
-    assert gate.main([str(tmp_path / "cand.json"), _bench("BASELINE.json")]) == 0
-    captured = capsys.readouterr()
-    assert "[SKIP] spec_accept_rate: absent from candidate" in captured.out
-    assert "WARNING metrics absent from candidate" in captured.err
-    assert "spec_tok_per_call" in captured.err
-
-
-def test_gate_spec_metric_floors(tmp_path):
-    """spec_accept_rate < 0.05 or spec_tok_per_call < 1.0 means drafting is
-    pure overhead: present-and-below-floor must fail the gate."""
-    import json
-
-    good = {"value": 2400.0, "window_errors": 0.0,
-            "spec_accept_rate": 0.42, "spec_tok_per_call": 2.8}
-    bad_rate = dict(good, spec_accept_rate=0.01)
-    bad_tpc = dict(good, spec_tok_per_call=0.4)
-    for n, doc in (("good", good), ("bad_rate", bad_rate), ("bad_tpc", bad_tpc)):
-        (tmp_path / f"{n}.json").write_text(json.dumps(doc))
-    base = _bench("BASELINE.json")
-    assert gate.main([str(tmp_path / "good.json"), base]) == 0
-    assert gate.main([str(tmp_path / "bad_rate.json"), base]) == 1
-    assert gate.main([str(tmp_path / "bad_tpc.json"), base]) == 1
-
-
-def test_gate_spec_metrics_relative_regression(tmp_path):
-    """spec metrics are throughput-class: a drop past TOLERANCE vs a
-    baseline that HAS them fails even above the absolute floors."""
-    import json
-
-    base = {"value": 2400.0, "window_errors": 0.0,
-            "spec_accept_rate": 0.60, "spec_tok_per_call": 4.0}
-    regressed = dict(base, spec_accept_rate=0.30)
-    for n, doc in (("base", base), ("regressed", regressed)):
-        (tmp_path / f"{n}.json").write_text(json.dumps(doc))
-    assert gate.main(
-        [str(tmp_path / "regressed.json"), str(tmp_path / "base.json")]
-    ) == 1
-    assert gate.main(
-        [str(tmp_path / "base.json"), str(tmp_path / "base.json")]
-    ) == 0
-
-
-def test_gate_skips_unmeasured_ttft(tmp_path):
-    """bench emits -1.0 for TTFT when the window measured none; the gate
-    must treat that as absent, not as an excellent latency."""
-    import json
-
-    cand = {"value": 2400.0, "p95_ttft_ms": -1.0, "window_errors": 0.0}
-    (tmp_path / "cand.json").write_text(json.dumps(cand))
-    assert gate.main([str(tmp_path / "cand.json"), _bench("BASELINE.json")]) == 0
-
-
-def test_gate_paged_kv_floors(tmp_path):
-    """ISSUE 6 floors: admit ratio >= 3.0, cow copies <= 2.0/req, and the
-    end-of-run block-leak counter is an exact zero check (no baseline
-    leniency — a leaked block is a refcount bug whatever last round did)."""
-    import json
-
-    good = {"value": 2400.0, "window_errors": 0.0,
-            "paged_admit_ratio": 3.4, "cow_copies_per_req": 0.2,
-            "paged_block_leaks": 0.0}
-    low_ratio = dict(good, paged_admit_ratio=2.1)
-    churny = dict(good, cow_copies_per_req=5.0)
-    leaky = dict(good, paged_block_leaks=2.0)
-    for n, doc in (("good", good), ("low_ratio", low_ratio),
-                   ("churny", churny), ("leaky", leaky)):
-        (tmp_path / f"{n}.json").write_text(json.dumps(doc))
-    base = str(tmp_path / "good.json")
-    assert gate.main([base, _bench("BASELINE.json")]) == 0
-    assert gate.main([str(tmp_path / "low_ratio.json"), base]) == 1
-    assert gate.main([str(tmp_path / "churny.json"), base]) == 1
-    assert gate.main([str(tmp_path / "leaky.json"), base]) == 1
-
-
-def test_gate_zoo_tenancy_floors(tmp_path, capsys):
-    """ISSUE 19 pair: tenant_isolation >= 0.5 (floor) and zoo_swap_in_s <=
-    60 (ceiling) fail when present-and-regressed, [SKIP] when absent (old
-    records and hosts that skipped the zoo sweep)."""
-    import json
-
-    good = {"value": 2400.0, "window_errors": 0.0,
-            "tenant_isolation": 0.93, "zoo_swap_in_s": 4.2}
-    starved = dict(good, tenant_isolation=0.2)
-    slow_swap = dict(good, zoo_swap_in_s=120.0)
-    for n, doc in (("good", good), ("starved", starved),
-                   ("slow_swap", slow_swap)):
-        (tmp_path / f"{n}.json").write_text(json.dumps(doc))
-    base = _bench("BASELINE.json")
-    assert gate.main([str(tmp_path / "good.json"), base]) == 0
-    assert gate.main([str(tmp_path / "starved.json"), base]) == 1
-    assert gate.main([str(tmp_path / "slow_swap.json"), base]) == 1
-    # absent keys skip with a warning, never KeyError
-    (tmp_path / "old.json").write_text(
-        json.dumps({"value": 2400.0, "window_errors": 0.0})
-    )
-    assert gate.main([str(tmp_path / "old.json"), base]) == 0
-    captured = capsys.readouterr()
-    assert "tenant_isolation" in captured.err
-    assert "zoo_swap_in_s" in captured.err

@@ -5,7 +5,9 @@ submit→claim→execute→complete stack."""
 from __future__ import annotations
 
 import importlib.util
+import json
 import os
+import re
 import sys
 import threading
 
@@ -267,3 +269,164 @@ def test_trace_dump_core_mode(live_stack, capsys):
     assert trace_dump_mod.main(["--core", core, "--limit", "5"]) == 0
     out = capsys.readouterr().out
     assert "http GET /v1/jobs" in out
+
+
+# ----------------------------------------------------------------- replay --
+# scripts/replay.py holds the one statement of the replay plan: the gap
+# (the capture's, over `compress`), the prompt (raw ids where a record has
+# them, else the text rebuilt from its chain head) and the sampling
+# parameters of every record, under (seed, compress).
+
+from llm_mcp_tpu.telemetry.workload import (  # noqa: E402
+    load_trace,
+    prompt_text_for,
+    synth_trace,
+)
+
+replay_mod = _load("replay")
+
+
+def _trace(n=4, ids=True):
+    """A hand-made capture: arrivals 0.5 s apart, two records sharing a
+    prefix chain, raw ids on request."""
+    recs = []
+    for i in range(n):
+        rec = {
+            "v": 1, "ts": 100.0 + 0.5 * i, "rid": f"rq{i:04d}", "model": "tiny-llm",
+            "pt": 8 + i, "chain": [[8, ("a" if i < 2 else "b%d" % i) * 8]],
+            "mt": 16, "temp": 0.0, "top_k": 0, "top_p": 1.0,
+            "ot": 16, "fin": "length",
+        }
+        if ids:
+            rec["ids"] = list(range(3, 11 + i))
+        recs.append(rec)
+    return recs
+
+
+@pytest.mark.parametrize("kind", ["chat", "embed", "longctx", "agent"])
+def test_stream_digest_equal_for_equal_plan(kind):
+    a = replay_mod.stream_digest(synth_trace(kind, 12, seed=5), 3, 8.0)
+    b = replay_mod.stream_digest(synth_trace(kind, 12, seed=5), 3, 8.0)
+    assert a == b and re.fullmatch(r"[0-9a-f]{16}", a)
+
+
+@pytest.mark.parametrize("field, value", [
+    ("seed", 4), ("compress", 16.0),  # the plan's own keys
+    ("ts", 100.75), ("ids", [3, 4, 5]),  # a record's gap, its prompt
+    ("mt", 17), ("temp", 0.7), ("top_k", 40), ("top_p", 0.9),  # its sampling
+])
+def test_stream_digest_moves_with(field, value):
+    plan = {"seed": 3, "compress": 8.0}
+    base = replay_mod.stream_digest(_trace(), **plan)
+    recs = _trace()
+    (plan if field in plan else recs[1])[field] = value
+    assert replay_mod.stream_digest(recs, **plan) != base
+
+
+def test_stream_digest_ignores_what_a_replay_does_not_send():
+    base = replay_mod.stream_digest(_trace(), 3, 8.0)
+    recs = _trace()
+    recs[1].update(ot=3, fin="stop", model="another", rid="zz0001")
+    assert replay_mod.stream_digest(recs, 3, 8.0) == base
+
+
+@pytest.mark.parametrize("ids", [True, False], ids=["raw_ids", "chain_only"])
+def test_stream_digest_prompt_source(ids):
+    """With raw ids the prompt is the ids and the chain is not read; without
+    them it is `prompt_text_for`, which the chain head and the rid seed."""
+    base = replay_mod.stream_digest(_trace(ids=ids), 0, 1.0)
+    recs = _trace(ids=ids)
+    before = prompt_text_for(recs[1])
+    recs[1]["chain"] = [[8, "c" * 16]]
+    assert prompt_text_for(recs[1]) != before
+    moved = replay_mod.stream_digest(recs, 0, 1.0) != base
+    assert moved == (not ids)
+
+
+@pytest.mark.parametrize("spec, kind, n, seed", [
+    ("synth:agent:8:3", "agent", 8, 3),
+    ("synth:longctx:5", "longctx", 5, 0),
+    ("synth:chat", "chat", 64, 0),
+])
+def test_load_source_synth_spec(spec, kind, n, seed):
+    records, rejected = replay_mod.load_source(spec)
+    assert rejected == 0
+    assert records == synth_trace(kind, n, seed=seed)
+
+
+def _write_trace(path, recs, torn=False):
+    lines = [json.dumps(r, separators=(",", ":")) for r in recs]
+    if torn:
+        lines.append(lines[-1][: len(lines[-1]) // 2])  # a crash mid-line
+    path.write_text("\n".join(lines) + "\n")
+    return str(path)
+
+
+def test_load_source_file_counts_a_torn_last_line(tmp_path):
+    recs = synth_trace("chat", 6, seed=2)
+    path = _write_trace(tmp_path / "capture.jsonl", recs, torn=True)
+    records, rejected = replay_mod.load_source(path)
+    assert (records, rejected) == load_trace(path)
+    assert records == recs and rejected == 1
+
+
+def test_summarize_hand_made_trace():
+    recs = _trace(4)
+    out = replay_mod.summarize(recs, rejected=2)
+    assert out["records"] == 4 and out["rejected_lines"] == 2
+    assert out["span_s"] == 1.5
+    assert out["arrival_rps"] == round(4 / 1.5, 3)
+    assert out["prompt_tokens"] == {"p50": 10, "max": 11}
+    assert out["max_tokens"] == {"p50": 16, "max": 16}
+    assert out["with_raw_ids"] == 4
+    assert out["prefix_shared_requests"] == 2  # the two on chain head "a"
+    assert out["rid_prefixes"] == {"rq": 4}
+    one = replay_mod.summarize(recs[:1], rejected=0)
+    assert one["span_s"] == 0.0 and one["arrival_rps"] == 0.0
+
+
+def _replay_main(monkeypatch, capsys, *argv):
+    monkeypatch.setattr(sys, "argv", ["replay.py", *argv])
+    rc = replay_mod.main()
+    out = capsys.readouterr().out
+    return rc, (json.loads(out) if out.strip() else None)
+
+
+def test_replay_cli_synth_then_digest_twice(tmp_path, monkeypatch, capsys):
+    path = str(tmp_path / "agent.jsonl")
+    rc, wrote = _replay_main(monkeypatch, capsys, "--synth", "agent",
+                             "--n", "8", "--seed", "3", "--out", path)
+    assert rc == 0 and wrote["records"] == 8
+    want = replay_mod.stream_digest(synth_trace("agent", 8, seed=3), 7, 4.0)
+    for source in (path, "synth:agent:8:3"):  # the file IS the synth trace
+        rc, got = _replay_main(monkeypatch, capsys, source, "--digest",
+                               "--seed", "7", "--compress", "4")
+        assert rc == 0
+        assert got == {"stream_sha": want, "records": 8, "seed": 7,
+                       "compress": 4.0}
+
+
+def test_replay_cli_summary_and_unusable_sources(tmp_path, monkeypatch, capsys):
+    path = _write_trace(tmp_path / "t.jsonl", _trace(3), torn=True)
+    rc, out = _replay_main(monkeypatch, capsys, path)
+    assert rc == 0 and out == replay_mod.summarize(_trace(3), rejected=1)
+    garbage = tmp_path / "garbage.jsonl"
+    garbage.write_text("not json\n{\"v\": 1}\n")
+    assert _replay_main(monkeypatch, capsys, str(garbage))[0] == 2
+    assert _replay_main(monkeypatch, capsys, str(tmp_path / "absent.jsonl"))[0] == 2
+
+
+def test_replay_http_against_live_core(live_stack):
+    """The operator's use: re-issue a trace against a running core with the
+    capture's gaps compressed; every request completes."""
+    core = f"http://127.0.0.1:{live_stack.api.port}"
+    recs = synth_trace("chat", 3, seed=1)
+    for r in recs:
+        r["mt"] = 4
+    out = replay_mod.replay_http(recs, core, "tiny-llm", compress=1000.0,
+                                 timeout=120.0)
+    assert out["issued"] == 3 and out["completed"] == 3 and out["errors"] == 0
+    assert out["p95_request_ms"] >= out["p50_request_ms"] > 0
+    gone = replay_mod.replay_http(recs[:1], core, "no-such-model",
+                                  compress=1000.0, timeout=30.0)
+    assert gone["completed"] == 0 and gone["errors"] == 1
