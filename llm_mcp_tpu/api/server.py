@@ -883,13 +883,22 @@ class CoreServer:
                 self.metrics.kv_migrate_requeues.inc(cur_r - self._migration_requeues)
                 self._migration_requeues = cur_r
         for name, e in self.embed_engines.items():
+            st = e.stats(recent=False)
             info[name] = {
                 "kind": "embed",
                 "total_inputs": e.total_inputs,
                 "total_tokens": e.total_tokens,
-                # forwards, rows and tokens true and padded, seconds waiting
-                # for, inside and holding the lock around a forward
-                **e.stats(recent=False),
+                # forwards, texts, rows packed and dispatched, tokens true and
+                # padded, seconds waiting for, inside and holding the lock
+                # around a forward
+                **st,
+                # texts a row (1.0 = packing never engaged) and the share of
+                # the dispatched token positions that were padding
+                "texts_per_row": st["rows"] / st["rows_packed"] if st["rows_packed"] else 0.0,
+                "pad_waste_pct": (
+                    100.0 * (1.0 - st["true_tokens"] / st["padded_tokens"])
+                    if st["padded_tokens"] else 0.0
+                ),
             }
         return info
 

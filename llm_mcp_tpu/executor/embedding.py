@@ -1,10 +1,10 @@
 """HBM-resident embedding engine serving `/v1/embeddings`.
 
 Replaces the reference's Ollama `/api/embed` proxy path
-(`core/internal/api/handlers.go:1942-2015`): batch inputs run as one jitted
-encoder forward per length bucket, entirely on TPU. Matryoshka `dimensions`
-support is exact (truncate + renormalize) rather than the reference's
-client-side truncation fallback (`handlers.go:2063-2078`).
+(`core/internal/api/handlers.go:1942-2015`): a call's inputs are packed into
+rows and run as jitted forwards of one shape, entirely on TPU. Matryoshka
+`dimensions` support is exact (truncate + renormalize) rather than the
+reference's client-side truncation fallback (`handlers.go:2063-2078`).
 """
 
 from __future__ import annotations
@@ -28,6 +28,51 @@ from ..parallel.sharding import (
 )
 from .common import pow2_bucket
 from .tokenizer import Tokenizer, load_tokenizer
+
+# Sequence packing (PR 31). A row of a forward holds up to PACK_TEXTS texts
+# back to back where the model function computes each as if it were alone
+# (`llama_encode_packed`); it is the width of the lengths operand [R, K] and
+# of the output [R, K, D]. 16 texts of the shortest length bucket (32) fill a
+# row of PACK_ROW, so packing never dispatches more positions than one text a
+# row did.
+PACK_TEXTS = 16
+# A call whose texts need more than one row gets rows of at least this many
+# tokens (and never more than its longest text needs): an engine built for
+# 8,192-token inputs does not pack short texts into rows whose attention is
+# quadratic in 8,192.
+PACK_ROW = 512
+# Token positions of one forward of the packed encoder, 2 rows of 512. A call
+# that needs more runs EQUAL forwards of one shape, so a row count rounds up
+# to a small bucket and a deployment meets one shape. From a chip sweep
+# (PERF.md section 5, PR 31; one v5e, Qwen3-Embedding-8B int8, the cell
+# embed_batch): `jit_fwd` takes 30.5 / 25.4 / 27.9 / 28.6 / 30.8 / 32.6 ms a
+# row at 1 / 2 / 4 / 8 / 16 / 32 rows of 512, and the cell gives 65.4 / 75.5 /
+# 66.3 / 63.8 / 60.1 embeddings/s at 512 / 1,024 / 2,048 / 4,096 / 8,192.
+FORWARD_TOKENS = 2 * 512
+
+
+def pack_rows(lens: list[int], row_len: int, per_row: int) -> list[list[int]]:
+    """First fit, longest first: rows of indices into `lens`, each of at most
+    `row_len` tokens and `per_row` texts. With one text a row it is the
+    texts, longest first."""
+    rows: list[list[int]] = []
+    room: list[int] = []
+    open_rows: list[int] = []  # rows that can still take the shortest text
+    shortest = min(lens)
+    for i in sorted(range(len(lens)), key=lambda i: -lens[i]):
+        for r in open_rows:
+            if lens[i] <= room[r]:
+                break
+        else:
+            r = len(rows)
+            rows.append([])
+            room.append(row_len)
+            open_rows.append(r)
+        rows[r].append(i)
+        room[r] -= lens[i]
+        if len(rows[r]) == per_row or room[r] < shortest:
+            open_rows.remove(r)
+    return rows
 
 
 class EmbeddingEngine:
@@ -138,33 +183,46 @@ class EmbeddingEngine:
 
         cfg = self.cfg
 
+        # one calling form for both: tokens [R, S], lengths [R, K] (a row's
+        # texts in order, 0 = unused place) -> [R, K, D]. The model function
+        # says how many texts a row may hold and how many token positions a
+        # forward: the decoder computes a text in a shared row as it is
+        # alone; the bidirectional encoders take one (mean/cls pooling and a
+        # learned position table know no segments)
         if self.decoder_arch:
-            from ..models.llama import llama_encode
+            from ..models.llama import llama_encode_packed
+
+            self.texts_per_row = PACK_TEXTS
+            self.forward_tokens = FORWARD_TOKENS
 
             @jax.jit
             def fwd(params, tokens, lengths):
-                return llama_encode(cfg, params, tokens, lengths)
+                return llama_encode_packed(cfg, params, tokens, lengths)
 
         else:
+            self.texts_per_row = 1
+            # no forward was measured for these: `max_batch` rows, as before
+            self.forward_tokens = self.max_batch * self.max_seq_len
 
             @jax.jit
             def fwd(params, tokens, lengths):
-                return embed_forward(cfg, params, tokens, lengths)
+                return embed_forward(cfg, params, tokens, lengths[:, 0])[:, None]
 
         self._fwd = fwd
         self._lock = threading.Lock()
         self.total_inputs = 0
         self.total_tokens = 0
         # counters behind stats(), written with the lock held: forwards
-        # run, rows asked for and rows after batch padding, tokens asked for
-        # and tokens after padding to (batch bucket x length bucket), and
+        # run, texts asked for (`rows`), the rows they were packed into and
+        # the rows dispatched after batch padding, tokens asked for and
+        # tokens after padding to (batch bucket x row length), and
         # seconds waiting for the lock, inside a forward (call to fetched)
         # and holding the lock outside one (staging, slicing, normalising,
         # tolist: host work during which the chip waits)
         self._stats: dict[str, float] = {
-            "forwards": 0, "rows": 0, "rows_padded": 0, "true_tokens": 0,
-            "padded_tokens": 0, "lock_wait_s": 0.0, "forward_s": 0.0,
-            "host_locked_s": 0.0,
+            "forwards": 0, "rows": 0, "rows_packed": 0, "rows_padded": 0,
+            "true_tokens": 0, "padded_tokens": 0, "lock_wait_s": 0.0,
+            "forward_s": 0.0, "host_locked_s": 0.0,
         }
         # (time.monotonic() at the forward's start, forward_s, host_locked_s)
         # of each forward, so that a reader can cut by its own window
@@ -188,16 +246,38 @@ class EmbeddingEngine:
                 ids = ids[: self.max_seq_len - 1] + [eos]
         return ids
 
+    def plan(self, lens: list[int]) -> tuple[int, int, list[list[list[int]]]]:
+        """One call's forwards from its texts' token counts: (row length, rows
+        of a forward, forwards), a forward being rows of indices into `lens`.
+        Every forward of a call has ONE shape: a call that needs more rows
+        than a forward carries runs equal forwards (5 rows over a cap of 4 =
+        3 + 2, both in the 4-row bucket), never a full one and a remainder
+        of another shape."""
+        row_len = self._bucket(max(lens))
+        if self.texts_per_row > 1 and sum(lens) > row_len:
+            row_len = max(row_len, min(PACK_ROW, self._bucket(sum(lens))))
+        rows = pack_rows(lens, row_len, self.texts_per_row)
+        cap = max(1, min(self.max_batch, self.forward_tokens // row_len))
+        n_fwd = -(-len(rows) // cap)
+        per = -(-len(rows) // n_fwd)
+        return (
+            row_len,
+            pow2_bucket(per, cap, floor=1),
+            [rows[i : i + per] for i in range(0, len(rows), per)],
+        )
+
     def embed(
         self, texts: list[str], dimensions: int | None = None
     ) -> tuple[list[list[float]], int]:
-        """Encode texts → (vectors, total_tokens). Batches of up to
-        `max_batch`, padded per-batch to the longest bucket."""
+        """Encode texts → (vectors in the caller's order, total_tokens).
+        The texts are packed into rows (`plan`) and run as forwards of one
+        shape."""
         if not texts:
             return [], 0
         all_ids = [self.prepare_ids(t) for t in texts]
         total_tokens = sum(len(i) for i in all_ids)
-        vectors: list[list[float]] = []
+        vectors: list[Any] = [None] * len(texts)
+        K = self.texts_per_row
 
         t_ask = time.monotonic()
         with TraceAnnotation("embed.lock_wait"):
@@ -206,22 +286,30 @@ class EmbeddingEngine:
             t_prev = time.monotonic()  # host time under the lock counts from here
             st = self._stats
             st["lock_wait_s"] += t_prev - t_ask
-            for i in range(0, len(all_ids), self.max_batch):
-                chunk = all_ids[i : i + self.max_batch]
-                B = len(chunk)
+            # an empty text takes one position (token 0): a place of length 0
+            # is an unused one
+            lens = [max(len(ids), 1) for ids in all_ids]
+            row_len, Bb, forwards = self.plan(lens)
+            for rows in forwards:
                 with TraceAnnotation("embed.stage"):
-                    # batch axis pads to a pow2 bucket too: without it every
-                    # distinct final-chunk size compiles a fresh executable
-                    # (VERDICT r2 weak #7 — B=7 vs B=8 were separate
-                    # compiles); pad rows hold 1 dummy token and their
-                    # vectors are dropped
-                    Bb = pow2_bucket(B, self.max_batch, floor=1)
-                    bucket = self._bucket(max(len(c) for c in chunk))
-                    tokens = np.zeros((Bb, bucket), dtype=np.int32)
-                    lengths = np.ones(Bb, dtype=np.int32)
-                    for j, ids in enumerate(chunk):
-                        tokens[j, : len(ids)] = ids
-                        lengths[j] = len(ids)
+                    # the batch axis pads to a pow2 bucket too: without it
+                    # every distinct row count compiles a fresh executable;
+                    # pad rows hold 1 dummy token and their vectors are dropped
+                    tokens = np.zeros((Bb, row_len), dtype=np.int32)
+                    lengths = np.zeros((Bb, K), dtype=np.int32)
+                    lengths[len(rows) :, 0] = 1
+                    texts_at: list[int] = []  # this forward's texts,
+                    rows_at: list[int] = []  # each one's row
+                    places_at: list[int] = []  # and its place in the row
+                    for r, row in enumerate(rows):
+                        at = 0
+                        for k, i in enumerate(row):
+                            tokens[r, at : at + len(all_ids[i])] = all_ids[i]
+                            lengths[r, k] = lens[i]
+                            at += lens[i]
+                            texts_at.append(i)
+                            rows_at.append(r)
+                            places_at.append(k)
                 t_fwd = time.monotonic()
                 with TraceAnnotation("embed.forward"):
                     out = np.asarray(
@@ -229,20 +317,22 @@ class EmbeddingEngine:
                     )
                 t_done = time.monotonic()
                 with TraceAnnotation("embed.post"):
-                    out = out[:B]
+                    out = out[rows_at, places_at]  # [texts of this forward, D]
                     if dimensions and 0 < dimensions < out.shape[1]:
                         out = out[:, :dimensions]
                         norms = np.maximum(np.linalg.norm(out, axis=1, keepdims=True), 1e-9)
                         out = out / norms
-                    vectors.extend(out.tolist())
+                    for i, vec in zip(texts_at, out.tolist()):
+                        vectors[i] = vec
                 t_post = time.monotonic()
                 host_s = (t_fwd - t_prev) + (t_post - t_done)
                 t_prev = t_post
                 st["forwards"] += 1
-                st["rows"] += B
+                st["rows"] += len(texts_at)
+                st["rows_packed"] += len(rows)
                 st["rows_padded"] += Bb
-                st["true_tokens"] += int(lengths[:B].sum())
-                st["padded_tokens"] += Bb * bucket
+                st["true_tokens"] += sum(lens[i] for i in texts_at)
+                st["padded_tokens"] += Bb * row_len
                 st["forward_s"] += t_done - t_fwd
                 st["host_locked_s"] += host_s
                 with self._recent_lock:

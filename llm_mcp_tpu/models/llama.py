@@ -1323,3 +1323,50 @@ def llama_decode_step(
         (params["layers"], layer_windows(cfg)),
     )
     return _logits(cfg, params, h), new_k, new_v
+
+
+def llama_encode_packed(
+    cfg: ModelConfig,
+    params: Params,
+    tokens: jnp.ndarray,  # [R, S] int32: a row's texts back to back, then padding
+    seg_lens: jnp.ndarray,  # [R, K] int32 lengths of a row's texts, 0 = unused place
+) -> jnp.ndarray:
+    """`llama_encode` over rows that hold SEVERAL texts each (sequence
+    packing): [R, K, D] unit vectors, one per place, zeros at unused places.
+
+    A text in a shared row is computed as it is alone in a padded row:
+    positions restart at its first token, attention stays inside it (causal
+    AND same text AND valid), and everything else a layer does is per token
+    (norms, q/k norms, `qdot`'s activation scales; a masked score is exactly
+    0 after the softmax). The sliding-window term of `prefill_layer` is a
+    difference of positions in the row, which inside one text is the
+    difference of its own positions. Lives at the end of the module so that
+    no generation program's traced line moves (the persistent cache's key).
+    """
+    K = seg_lens.shape[1]
+    seg_lens = seg_lens.astype(jnp.int32)
+    ends = jnp.cumsum(seg_lens, axis=1)  # [R, K] one past each text's last token
+    row_len = ends[:, -1]  # [R] valid tokens of the row
+    pos = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]  # [1, S]
+    # the place a token belongs to = texts that end at or before it; unused
+    # places end where the row's texts end, so padding (and only it) reads K
+    seg = jnp.sum(pos[:, :, None] >= ends[:, None, :], axis=-1)  # [R, S]
+    starts = jnp.take_along_axis(ends - seg_lens, jnp.minimum(seg, K - 1), axis=1)
+    cos, sin = rope_tables(cfg, cfg.resolved_head_dim, pos - starts)  # [R, S, hd/2]
+    mask = (
+        (pos[:, :, None] >= pos[:, None, :])
+        & (seg[:, :, None] == seg[:, None, :])
+        & (pos < row_len[:, None])[:, None, :]
+    )  # [R, S, S]
+    h = _embed_in(cfg, params, tokens)  # [R, S, D]
+
+    def layer(h, xs):
+        lp, win = xs
+        h, _ = prefill_layer(cfg, lp, h, cos, sin, mask, row_len, "xla", window=win)
+        return h, None
+
+    h, _ = jax.lax.scan(layer, h, (params["layers"], layer_windows(cfg)))
+    last = jnp.take_along_axis(h, jnp.maximum(ends - 1, 0)[:, :, None], axis=1)
+    e = _norm(cfg, last, params["final_norm"]).astype(jnp.float32)  # [R, K, D]
+    e = e / jnp.maximum(jnp.linalg.norm(e, axis=-1, keepdims=True), 1e-9)
+    return jnp.where((seg_lens > 0)[:, :, None], e, 0.0)

@@ -238,6 +238,34 @@ def test_embedding_counters_reach_an_operator(base, server):
         assert blk["padded_tokens"] > blk["true_tokens"] > before["true_tokens"]
         assert blk["forward_s"] > before["forward_s"]
         assert blk["host_locked_s"] > before["host_locked_s"] and blk["lock_wait_s"] >= 0
+        # an encoder keeps one text a row: packing never engages
+        assert blk["rows_packed"] == blk["rows"] and blk["texts_per_row"] == 1.0
+        assert blk["pad_waste_pct"] == pytest.approx(
+            100.0 * (1.0 - blk["true_tokens"] / blk["padded_tokens"]))
+
+
+def test_packing_is_visible_to_an_operator(base, server):
+    """PR 31: a decoder-architecture embedder packs a request's texts into
+    rows; /v1/debug/health shows texts a row and the padded share, and the
+    answers come back in the request's order."""
+    emb = EmbeddingEngine("tiny-qwen3", max_seq_len=128, dtype=jnp.float32)
+    server.embed_engines["tiny-qwen3"] = emb
+    try:
+        texts = ["p" * 50, "q" * 9, "r" * 30, "s" * 20, "t" * 70, "u" * 40]
+        r = httpx.post(f"{base}/v1/embeddings", timeout=120.0,
+                       json={"model": "tiny-qwen3", "input": texts})
+        assert r.status_code == 200
+        served = [d["embedding"] for d in r.json()["data"]]
+        for i in (1, 4):
+            alone, _ = emb.embed([texts[i]])
+            assert max(abs(a - b) for a, b in zip(served[i], alone[0])) < 1e-4
+        blk = httpx.get(f"{base}/v1/debug/health").json()["checks"]["engines"]["tiny-qwen3"]
+        # 225 tokens in two rows of 128 (the sum's bucket is 256, the cap on a row 128), then two single texts
+        assert (blk["rows"], blk["rows_packed"], blk["rows_padded"]) == (8, 4, 4)
+        assert blk["texts_per_row"] == 2.0
+        assert 0.0 < blk["pad_waste_pct"] < 50.0
+    finally:
+        del server.embed_engines["tiny-qwen3"]
 
 
 def test_embeddings_validation(base):

@@ -310,6 +310,167 @@ def test_embedding_batch_exceeds_max_batch():
     np.testing.assert_allclose(a[0], b[0], rtol=1e-4, atol=1e-5)
 
 
+# -- sequence packing (PR 31): the planner and the engine around it --------------
+
+
+def _cell_lengths(seed=7_777_777, request=0):
+    """Token counts of one embed_batch request, as the benchmark's generator
+    deals them (32 of a deck of 64 log-normal quantiles, 64-512)."""
+    import json
+    import os
+
+    from benchmark import trafficgen
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "traffic", "embed_batch.json")) as f:
+        traffic = json.load(f)
+    plan = trafficgen.make_plan(traffic, seed, 40.0, model="m")
+    return [size for size, _rank in plan["requests"][request]["prompt"]]
+
+
+def _shape_spy(eng):
+    """Replace the forward by one that records (tokens.shape, lengths) and
+    computes nothing: the planner's tests need shapes, not vectors."""
+    calls = []
+
+    def fwd(params, tokens, lengths):
+        calls.append((tokens.shape, np.array(lengths)))
+        return np.zeros(lengths.shape + (eng.cfg.dim,), np.float32)
+
+    eng._fwd = fwd
+    return calls
+
+
+@pytest.fixture(scope="module")
+def packing_engine():
+    return EmbeddingEngine("tiny-qwen3", max_seq_len=512, dtype=jnp.float32)
+
+
+def test_pack_rows_first_fit_longest_first():
+    from llm_mcp_tpu.executor.embedding import pack_rows
+
+    lens = [100, 500, 30, 300, 212, 12, 12, 400]
+    rows = pack_rows(lens, 512, 16)
+    assert rows == [[1, 5], [7, 0, 6], [3, 4], [2]]
+    assert all(sum(lens[i] for i in row) <= 512 for row in rows)
+    # at most `per_row` texts a row, and with one a row: the texts, longest first
+    assert [len(r) for r in pack_rows([8] * 10, 512, 4)] == [4, 4, 2]
+    assert pack_rows(lens, 512, 1) == [[1], [7], [3], [4], [0], [2], [5], [6]]
+
+
+def test_embedding_packed_answers_in_callers_order(packing_engine):
+    """Shuffled lengths through the REAL forward: every text's vector equals
+    the vector of the same text sent alone, at its place in the answer."""
+    eng = packing_engine
+    rng = np.random.default_rng(3)
+    texts = ["".join(chr(97 + int(c)) for c in rng.integers(0, 26, int(n)))
+             for n in (200, 9, 130, 77, 300, 40, 41, 5, 250, 64)]
+    before = eng.stats(recent=False)
+    vecs, total = eng.embed(texts)
+    st = eng.stats()
+    assert total == sum(len(eng.prepare_ids(t)) for t in texts)
+    assert st["rows"] - before["rows"] == 10
+    assert st["rows_packed"] - before["rows_packed"] == 3  # 1,116 tokens in rows of 512
+    assert st["rows_padded"] - before["rows_padded"] == 4
+    assert st["true_tokens"] - before["true_tokens"] == total
+    assert st["padded_tokens"] - before["padded_tokens"] == 4 * 512
+    assert all(len(r) == 3 for r in st["recent"])  # (t, forward_s, host_locked_s)
+    for i in (0, 1, 4, 7, 9):
+        alone, _ = eng.embed([texts[i]])
+        np.testing.assert_allclose(vecs[i], alone[0], rtol=1e-4, atol=1e-5)
+    # Matryoshka on a packed call: truncate, renormalise, same order
+    cut, _ = eng.embed(texts, dimensions=16)
+    want = np.asarray(vecs)[:, :16]
+    want /= np.linalg.norm(want, axis=1, keepdims=True)
+    np.testing.assert_allclose(cut, want, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize(
+    "lens,shapes,rows_packed",
+    [
+        # a request of the benchmark's cell: 32 texts in 15 rows, equal forwards of one shape
+        (None, [(2, 512)] * 8, 15),
+        # over the cap: EQUAL forwards of one shape, not one of the next bucket
+        ([300] * 3, [(2, 512)] * 2, 3),
+        ([300] * 17, [(2, 512)] * 9, 17),
+        # a single short text reaches the shape it reached before packing
+        ([5], [(1, 32)], 1),
+        ([200], [(1, 256)], 1),
+        ([512], [(1, 512)], 1),
+        # texts that fit one row of their own bucket share it
+        ([10, 10, 10], [(1, 32)], 1),
+        # two texts just over a bucket: the row grows to their sum's bucket, not to 512
+        ([33, 33], [(1, 128)], 1),
+        # many short texts: 16 a row, never more positions than one text a row took
+        ([20] * 64, [(2, 512)] * 2, 4),
+    ],
+)
+def test_embedding_plan_shapes(lens, shapes, rows_packed):
+    from llm_mcp_tpu.executor.embedding import FORWARD_TOKENS
+
+    eng = EmbeddingEngine("tiny-qwen3", max_seq_len=512, dtype=jnp.float32)
+    calls = _shape_spy(eng)
+    lens = lens or _cell_lengths()
+    # the byte tokenizer: one token a byte and a BOS in front
+    texts = ["x" * (n - 1) for n in lens]
+    assert [len(eng.prepare_ids(t)) for t in texts] == lens
+    vecs, total = eng.embed(texts)
+    assert len(vecs) == len(lens) and total == sum(lens)
+    assert [c[0] for c in calls] == shapes
+    assert all(c[0][0] * c[0][1] <= FORWARD_TOKENS for c in calls)
+    st = eng.stats()
+    assert (st["forwards"], st["rows"], st["rows_packed"]) == (len(shapes), len(lens), rows_packed)
+    assert st["rows_padded"] == sum(s[0] for s in shapes)
+    assert st["true_tokens"] == sum(lens) and len(st["recent"]) == len(shapes)
+    # what the benchmark's wrapper reads: lengths.sum() is the true tokens and
+    # one place of 1 for each padding row
+    assert sum(int(c[1].sum()) for c in calls) == sum(lens) + st["rows_padded"] - rows_packed
+    # every text sits whole in one row, in its place's span
+    for _shape, lengths in calls:
+        assert (lengths.sum(axis=1) <= shapes[0][1]).all()
+
+
+def test_embedding_cell_requests_reach_one_shape():
+    """Requests of the cell under the warm-up's seed and another: whatever
+    the row count (12-18), every forward is (2, 512)."""
+    eng = EmbeddingEngine("tiny-qwen3", max_seq_len=512, dtype=jnp.float32)
+    seen = set()
+    for seed in (7_777_777, 4_100_002_913):
+        for k in range(40):
+            row_len, rows_fwd, forwards = eng.plan(_cell_lengths(seed, k))
+            rows = sum(len(f) for f in forwards)
+            assert 12 <= rows <= 18 and len(forwards) == -(-rows // 2)
+            seen.add((rows_fwd, row_len))
+    assert seen == {(2, 512)}
+
+
+def test_embedding_long_context_engine_packs_short_texts_into_short_rows():
+    """max_seq_len 8,192: short texts get rows of 512, not rows whose
+    attention is quadratic in 8,192; a long text gets the row it needs and
+    a forward carries fewer of them."""
+    eng = EmbeddingEngine("tiny-qwen3", max_seq_len=8192, dtype=jnp.float32)
+    row_len, rows_fwd, forwards = eng.plan([100] * 40)
+    assert (row_len, rows_fwd, [len(f) for f in forwards]) == (512, 2, [2, 2, 2, 2])
+    row_len, rows_fwd, forwards = eng.plan([3000, 100, 100, 2000, 2000])
+    assert (row_len, rows_fwd) == (4096, 1) and [len(f) for f in forwards] == [1, 1]
+    row_len, rows_fwd, forwards = eng.plan([8192] * 3)
+    assert (row_len, rows_fwd) == (8192, 1) and [len(f) for f in forwards] == [1, 1, 1]
+
+
+def test_embedding_encoder_arch_keeps_one_text_a_row():
+    """The bidirectional encoders know no segments: one text a row, the
+    length bucket of the longest, `max_batch` the cap on a forward's rows."""
+    eng = EmbeddingEngine("tiny-embed", max_batch=4, max_seq_len=128, dtype=jnp.float32)
+    assert eng.texts_per_row == 1
+    calls = _shape_spy(eng)
+    vecs, _ = eng.embed(["a" * n for n in (3, 60, 10, 20, 5)])
+    assert len(vecs) == 5
+    assert [c[0] for c in calls] == [(4, 64), (4, 64)]  # 3 + 2 rows, one shape
+    assert all(c[1].shape == (4, 1) for c in calls)
+    st = eng.stats(recent=False)
+    assert st["rows"] == st["rows_packed"] == 5 and st["rows_padded"] == 8
+
+
 def test_chunked_prefill_matches_single_shot():
     """A prompt prefilled chunk-by-chunk must produce the same greedy output
     as one-shot prefill (VERDICT r1 #4: no head-of-line blocking, no drift)."""

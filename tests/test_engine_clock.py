@@ -318,19 +318,19 @@ def test_compile_watch_sums_what_jax_reports_on_the_thread(monkeypatch, requests
 
 def test_embedding_engine_stats_against_a_hand_count():
     emb = EmbeddingEngine("tiny-embed", max_batch=4, max_seq_len=64, dtype=jnp.float32)
-    assert emb.stats() == {"forwards": 0, "rows": 0, "rows_padded": 0, "true_tokens": 0,
-                           "padded_tokens": 0, "lock_wait_s": 0.0, "forward_s": 0.0,
-                           "host_locked_s": 0.0, "recent": []}
-    texts = ["a" * 10, "b" * 40, "c" * 5, "d" * 20, "e" * 33, "f" * 3]  # 4 + 2 rows
+    assert emb.stats() == {"forwards": 0, "rows": 0, "rows_packed": 0, "rows_padded": 0,
+                           "true_tokens": 0, "padded_tokens": 0, "lock_wait_s": 0.0,
+                           "forward_s": 0.0, "host_locked_s": 0.0, "recent": []}
+    # 6 rows over a cap of 4: two EQUAL forwards of 3 rows, both in the 4-row bucket (PR 31)
+    texts = ["a" * 10, "b" * 40, "c" * 5, "d" * 20, "e" * 33, "f" * 3]
     lens = [len(emb.prepare_ids(t)) for t in texts]
     t0 = time.monotonic()
     vecs, total = emb.embed(texts, dimensions=8)
     t1 = time.monotonic()
     assert len(vecs) == 6 and len(vecs[0]) == 8 and total == sum(lens)
     st = emb.stats()
-    b1, b2 = emb._bucket(max(lens[:4])), emb._bucket(max(lens[4:]))
-    assert (st["forwards"], st["rows"], st["rows_padded"]) == (2, 6, 4 + 2)
-    assert st["true_tokens"] == sum(lens) and st["padded_tokens"] == 4 * b1 + 2 * b2
+    assert (st["forwards"], st["rows"], st["rows_packed"], st["rows_padded"]) == (2, 6, 6, 4 + 4)
+    assert st["true_tokens"] == sum(lens) and st["padded_tokens"] == 8 * emb._bucket(max(lens))
     assert len(st["recent"]) == 2
     for t, fwd_s, host_s in st["recent"]:
         assert t0 <= t <= t1 and fwd_s > 0 and host_s > 0
@@ -338,7 +338,7 @@ def test_embedding_engine_stats_against_a_hand_count():
     assert st["host_locked_s"] == pytest.approx(sum(r[2] for r in st["recent"]))
     assert st["lock_wait_s"] >= 0 and st["forward_s"] + st["host_locked_s"] <= (t1 - t0)
     emb.embed(["one more"])
-    assert emb.stats()["forwards"] == 3 and emb.stats()["rows_padded"] == 7
+    assert emb.stats()["forwards"] == 3 and emb.stats()["rows_padded"] == 9
 
 
 def test_admission_reads_are_counted_and_recorded_against_a_hand_count(env):

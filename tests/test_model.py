@@ -209,6 +209,96 @@ def test_llama_encode_decoder_embedding():
     assert float(np.abs(np.asarray(out3[1]) - np.asarray(out[1])).max()) > 1e-4
 
 
+def _packed_rows(cfg, lens_by_row, S, K, seed=5):
+    """Rows of random texts back to back: (tokens [R, S], seg_lens [R, K],
+    the texts as lists of ids, row by row)."""
+    key = jax.random.PRNGKey(seed)
+    tokens = np.zeros((len(lens_by_row), S), np.int32)
+    seg = np.zeros((len(lens_by_row), K), np.int32)
+    texts = []
+    for r, lens in enumerate(lens_by_row):
+        at, row = 0, []
+        for k, n in enumerate(lens):
+            key, sub = jax.random.split(key)
+            ids = np.asarray(jax.random.randint(sub, (n,), 3, cfg.vocab_size))
+            tokens[r, at : at + n] = ids
+            seg[r, k] = n
+            at += n
+            row.append(ids)
+        texts.append(row)
+    return tokens, seg, texts
+
+
+def _alone(cfg, p, ids, S):
+    from llm_mcp_tpu.models.llama import llama_encode
+
+    row = np.zeros((1, S), np.int32)
+    row[0, : len(ids)] = ids
+    return np.asarray(llama_encode(cfg, p, jnp.asarray(row), jnp.array([len(ids)], jnp.int32))[0])
+
+
+@pytest.mark.parametrize(
+    "model,lens_by_row",
+    [
+        ("tiny-qwen3", [[40, 17, 30], [96], [5, 5, 5, 5]]),
+        # window 64: the 80-token text is longer than the window, and its
+        # neighbours sit within a window's reach of it in the row
+        ("tiny-mistral", [[80, 30, 18], [20, 100], [64, 64]]),
+    ],
+)
+def test_llama_encode_packed_equals_each_text_alone(model, lens_by_row):
+    """Sequence packing (PR 31): a text's vector from a row it shares equals
+    its vector from `llama_encode` alone in a padded row; a neighbour's
+    tokens do not move it, its own last token does; unused places and a
+    padding row give zeros."""
+    from llm_mcp_tpu.models.llama import llama_encode_packed
+
+    cfg = get_config(model)
+    p = init_llama_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    S, K = 128, 4
+    tokens, seg, texts = _packed_rows(cfg, lens_by_row + [[1]], S, K)
+    out = np.asarray(llama_encode_packed(cfg, p, jnp.asarray(tokens), jnp.asarray(seg)))
+    assert out.shape == (len(lens_by_row) + 1, K, cfg.dim)
+    for r, row in enumerate(texts[:-1]):
+        for k, ids in enumerate(row):
+            np.testing.assert_allclose(out[r, k], _alone(cfg, p, ids, S), rtol=1e-4, atol=1e-5)
+        assert not out[r, len(row) :].any()  # unused places
+    np.testing.assert_allclose(np.linalg.norm(out[0, :3], axis=-1), 1.0, rtol=1e-5)
+    assert not out[-1, 1:].any()  # the padding row: one place of 1, nothing else
+    # a NEIGHBOUR changes (the text before and the text after the middle one,
+    # and the row's padding): the middle text's vector stays
+    a, b = lens_by_row[0][0], lens_by_row[0][0] + lens_by_row[0][1]
+    other = tokens.copy()
+    other[0, :a] = (other[0, :a] + 7) % cfg.vocab_size
+    other[0, b:] = (other[0, b:] + 11) % cfg.vocab_size
+    out2 = np.asarray(llama_encode_packed(cfg, p, jnp.asarray(other), jnp.asarray(seg)))
+    np.testing.assert_allclose(out2[0, 1], out[0, 1], rtol=1e-4, atol=1e-5)
+    assert np.abs(out2[0, 0] - out[0, 0]).max() > 1e-4
+    # its OWN last token changes: it moves, its neighbours do not
+    own = tokens.copy()
+    own[0, b - 1] = (own[0, b - 1] + 1) % cfg.vocab_size
+    out3 = np.asarray(llama_encode_packed(cfg, p, jnp.asarray(own), jnp.asarray(seg)))
+    assert np.abs(out3[0, 1] - out[0, 1]).max() > 1e-4
+    np.testing.assert_allclose(out3[0, 0], out[0, 0], rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(out3[0, 2], out[0, 2], rtol=1e-4, atol=1e-5)
+
+
+def test_llama_encode_packed_int8_tracks_unpacked():
+    """w8a8: activation scales are per token, so packed against unpacked on
+    the same quantized tree differ by rounding noise only."""
+    from llm_mcp_tpu.models.llama import llama_encode_packed
+    from llm_mcp_tpu.models.quant import quantize_params
+
+    cfg = get_config("tiny-qwen3")
+    q = quantize_params(init_llama_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32))
+    tokens, seg, texts = _packed_rows(cfg, [[33, 50, 12], [70, 9]], 128, 4)
+    out = np.asarray(llama_encode_packed(cfg, q, jnp.asarray(tokens), jnp.asarray(seg)))
+    for r, row in enumerate(texts):
+        for k, ids in enumerate(row):
+            cos = float(np.dot(out[r, k], _alone(cfg, q, ids, 128)))
+            assert cos > 0.9999, (r, k, cos)
+
+
 def test_embedding_engine_decoder_arch():
     """EmbeddingEngine serves decoder configs through llama_encode (incl.
     int8), with Matryoshka truncation renormalized."""
