@@ -1,0 +1,20 @@
+"""Share of the HBM roofline a decode round of the hybrid configuration
+reaches: the least bytes decode_chunk steps must move (non-expert weights once
+a step, the banks of the held experts the step's rows touched by the program's
+counter, the live rows of the state pool read and written, the live int8 KV:
+solar_bytes.py) over the chip's published bytes a second, over the round's
+device time in the trace. Bound by memory: a step at 64 rows does about 0.3
+TFLOP against 7 GB."""
+from benchmark import counters, peaks, solar_bytes
+
+NAME, UNIT, BETTER, SOURCE = "solar_round_roofline", "%", "higher", "device_trace"
+LAYER, MOVES = "step programs", "out_tokens_per_s"
+
+
+def read(run: dict):
+    mean_s, need = counters.decode_round_s(run), solar_bytes.decode_step_bytes(run)
+    if not mean_s or not need:
+        return None
+    gen = run["sut"]["gen"]
+    least_s = gen.decode_chunk * need / peaks.peaks(run["device"]["kind"])["hbm_bytes_per_s"]
+    return 100.0 * least_s / mean_s

@@ -84,8 +84,11 @@ from .common import fine_bucket, pow2_bucket
 from .dispatch import DispatchBackend, GSPMDBackend, LocalArraysBackend
 from .drafter import NGramDrafter
 from .memory import (
+    build_state_pool,
+    ExpertCounts,
     KVPool,
     KVSnapshot,
+    RECURRENT_OFF,
     RESTORE_AGING_TTFT_MULT,
     bucket_len,
     pytree_nbytes,
@@ -445,6 +448,10 @@ class GenerationEngine:
         # resolve_config — the reference's serve-any-name parity,
         # discovery.go:482-560)
         self.cfg = resolve_config(model, weights_dir)
+        if self.cfg.recurrent and mesh is not None and mesh.size > 1:
+            raise NotImplementedError(
+                f"{self.cfg.name}: recurrent layers and an expert share run on "
+                "one chip; no mesh axis shards the state pool or the share yet")
         self.mesh = mesh
         # Dispatch plane (dispatch.py): every device mutation the loop makes
         # goes through ONE funnel (_dx) that forwards the (op, payload) step
@@ -677,6 +684,19 @@ class GenerationEngine:
             )
         self._ck = cache["k"]
         self._cv = cache["v"]
+        # Recurrent layers (models/hybrid.py): the per-slot state rides the
+        # cache pair's second member through every step program, and its book
+        # (memory.StatePool) is the one place that says which features such a
+        # configuration runs without. None for attention-only configurations.
+        self._state_pool = build_state_pool(
+            self.cfg, max_slots,
+            self._cv["state"] if self.cfg.recurrent else None, log)
+        recurrent = self._state_pool is not None
+        # the expert layer's counts, where the step programs carry them (a
+        # member of the cache pair of its own, beside the state): None else
+        self._experts = (
+            ExpertCounts(self.cfg.n_layers, held=self.cfg.n_experts, router=self.cfg.router_width)
+            if isinstance(self._cv, dict) and "moe" in self._cv else None)
         if self._spmd:
             # named out_sharding kinds for _shard_out: host-read outputs come
             # back fully replicated (every process device_gets locally — the
@@ -802,6 +822,7 @@ class GenerationEngine:
             and supports_ragged_prefill(mesh)
             and not cfg_.sliding_window
             and not cfg_.attn_softcap
+            and self._runs("ragged_prefill")
         )
         # Sharded plane: the packed-buffer math is GSPMD-safe (tp shards the
         # head axis, pp the layer axis; neither touches the token packing),
@@ -917,6 +938,17 @@ class GenerationEngine:
                     )
 
         def _insert_row(ck, cv, ks, vs, i, slot):
+            if recurrent:
+                # the GQA layers' rows as for any family, and the row's
+                # recurrent state into the pool beside them
+                from ..models.hybrid import insert_state_row
+
+                ck, v = _insert_kv(ck, cv["v"], ks, vs["v"], i, slot)
+                return ck, dict(cv, v=v, state=insert_state_row(
+                    cv["state"], vs["state"], i, slot))
+            return _insert_kv(ck, cv, ks, vs, i, slot)
+
+        def _insert_kv(ck, cv, ks, vs, i, slot):
             # ks/vs: batched prompt KV [L, A, Hkv, bucket, hd] (already in
             # cache-entry form when the cache is quantized: fused
             # payload+scales for GQA, {"q","s"} per side for MLA) → write
@@ -1019,6 +1051,10 @@ class GenerationEngine:
 
             with jax.named_scope("kv_append"):
                 ck, cv = jax.lax.fori_loop(0, Ab, body, (ck, cv))
+                if isinstance(vs, dict) and "moe" in vs:  # the prefill's expert counts, once a call
+                    from ..models.hybrid import add_counts
+
+                    cv = add_counts(cv, vs)
             # sampling params live ON DEVICE between rounds (decode gathers
             # them by slot id — never re-transferred per round). Pad rows
             # scatter to row B: out of bounds, dropped (the same invariant
@@ -1168,7 +1204,7 @@ class GenerationEngine:
         # the GSPMD leader/follower plane.
         self._prefix_budget = (
             int(prompt_cache_mb) * (1 << 20)
-            if self.prefill_chunk > 0 and self.sp == 1
+            if self.prefill_chunk > 0 and self.sp == 1 and self._runs("prefix_cache")
             else 0
         )
         self._recent_prompts: deque[tuple] = deque(maxlen=16)
@@ -1258,6 +1294,7 @@ class GenerationEngine:
             os.environ.get("TPU_SPEC", "1") != "0"
             and self.spec_k > 0
             and self.sp == 1
+            and self._runs("speculation")
         )
         # verify-round throughput counters (speculation_stats; engine-thread
         # writers, lock-free like total_tokens)
@@ -1311,7 +1348,8 @@ class GenerationEngine:
         # `if self._pool is not None`, so the off state is a true no-op
         # (byte-identical scheduler decisions vs the pool-less engine).
         self._pool = None
-        if os.environ.get("TPU_KV_HOST_OFFLOAD", "0") not in ("", "0", "false", "no", "off"):
+        if self._runs("offload") and os.environ.get(
+                "TPU_KV_HOST_OFFLOAD", "0") not in ("", "0", "false", "no", "off"):
             self._pool = KVPool(
                 max_slots=max_slots,
                 max_seq_len=max_seq_len,
@@ -1332,7 +1370,8 @@ class GenerationEngine:
         # calls), so it is ALWAYS constructed — the block economy feeds
         # telemetry unconditionally, and when the pool is on, admission's
         # offered load becomes unique-block accounting (_offered_load).
-        cache_bytes = pytree_nbytes({"k": self._ck, "v": self._cv})
+        cache_bytes = pytree_nbytes(
+            {"k": self._ck, "v": self._cv["v"] if recurrent else self._cv})
         self._paging = PagedKVManager(
             max_slots=max_slots,
             max_seq_len=max_seq_len,
@@ -1466,7 +1505,8 @@ class GenerationEngine:
         self.migrated_in_total = 0
         self.migrate_out_bytes_total = 0
         self.migrate_in_bytes_total = 0
-        if os.environ.get("TPU_MIGRATE", "0") not in ("", "0", "false", "no", "off"):
+        if self._runs("migration") and os.environ.get(
+                "TPU_MIGRATE", "0") not in ("", "0", "false", "no", "off"):
             self._migrate_outbox = queue.Queue()
             self._migrate_in = queue.Queue()
             log.info("KV migration enabled (TPU_MIGRATE)")
@@ -2070,6 +2110,12 @@ class GenerationEngine:
                 d_last = d_last.at[slot_ids].set(last)
             else:
                 d_last = last
+            if isinstance(cv, dict) and "moe" in cv:
+                # the expert layer's running counts ride the round's one
+                # fetch as rows behind the K rows of tokens (_complete_round)
+                moe = cv["moe"].reshape(-1)
+                moe = jnp.pad(moe, (0, -moe.shape[0] % Ba)).reshape(-1, Ba)
+                out = jnp.concatenate([out, moe.astype(out.dtype)])
             return out, ck, cv, d_last  # out: [K, Ba]
 
         @partial(jax.jit, donate_argnums=(1, 2, 7), static_argnames=("compact",),
@@ -2384,6 +2430,27 @@ class GenerationEngine:
 
     # -- warmup (executor/warmup.py; ROADMAP item 5) -----------------------
 
+    def _runs(self, feature: str) -> bool:
+        """Whether this configuration runs `feature`. All of them without a
+        recurrent state pool; with one, all but those `memory.RECURRENT_OFF`
+        names (the one list: its reasons are logged where the pool is built,
+        and the pool counts the times each would have engaged)."""
+        return self._state_pool is None or feature not in RECURRENT_OFF
+
+    @property
+    def state_dtype(self) -> str:
+        """The recurrent state pool's precision ("" without one): a
+        configuration's file states it (`program.expect`)."""
+        return str(self._cv["state"]["S"].dtype) if self._state_pool is not None else ""
+
+    @property
+    def expert_dtype(self) -> str:
+        """The routed expert banks' precision ("" without routed experts)."""
+        bank = self.params.get("layers", {}).get("w1e") if isinstance(self.params, dict) else None
+        if bank is None:
+            return ""
+        return "int8" if isinstance(bank, dict) else str(bank.dtype)
+
     def warmup_shape_zoo(self) -> list[tuple[str, tuple]]:
         """The engine's serving-shape zoo: the (phase, shape key) pairs its
         config can dispatch, in `_note_exec_shape`'s own vocabulary — the
@@ -2409,10 +2476,14 @@ class GenerationEngine:
                 break
             n = b + 1
         ab_cap = 1 << max(0, self.admit_batch - 1).bit_length()
+        # whole prompts reach an admit program up to prefill_chunk tokens (a
+        # longer one is chunked), several of them up to _admit_tokens_max
+        tok_cap = self._admit_tokens_max()
         ab = 1
         while ab <= ab_cap:
             for bk in buckets:
-                zoo.append(("admit", (ab, bk)))
+                if not tok_cap or ab * bk <= tok_cap:
+                    zoo.append(("admit", (ab, bk)))
             ab <<= 1
         B = self.max_slots
         if self.decode_compact:
@@ -3296,14 +3367,24 @@ class GenerationEngine:
         attribution, and the four-layout roofline, with the engine's count
         of admissions read from the in-flight queue (`admit_reads`; of them
         `_blocked` still had to wait for the device, `_at_once` were read
-        where they were dispatched). Read-only over the observatory's own
+        where they were dispatched), and for a configuration with recurrent
+        layers the state pool's block (`state_pool`), for one whose step
+        programs count the expert layer's work that block (`experts`). Read-only over the observatory's own
         lock, so safe from any thread."""
-        return {
+        out = {
             **self._perf.stats(),
             "admit_reads": self.admit_reads,
             "admit_reads_blocked": self.admit_reads_blocked,
             "admit_reads_at_once": self.admit_reads_at_once,
         }
+        if self._state_pool is not None:
+            # the recurrent state pool's book: bytes, slots alive (seated or
+            # mid-prefill), features off
+            live = sum(s is not None for s in self._slots) + len(self._prefills)
+            out["state_pool"] = self._state_pool.stats(live)
+        if self._experts is not None:
+            out["experts"] = self._experts.stats()
+        return out
 
     def drain_itl_samples(self) -> list[float]:
         """ITL samples (seconds) since the last drain — engines_info feeds
@@ -3912,6 +3993,8 @@ class GenerationEngine:
         live on host (the preempt path device_get them), so no engine-loop
         coordination is needed — pool pops are atomic, and a parked slot is
         touched by nobody until whoever popped its snapshot restores it."""
+        if self._state_pool is not None:
+            self._state_pool.note_off("migration")
         if self._migrate_outbox is None or self._pool is None:
             return None
         snap = self._pool.pop_restore()
@@ -3951,6 +4034,11 @@ class GenerationEngine:
         service pumps it back over the response stream). Returns the
         reconstructed request. Raises when migration is off or the payload
         cannot run here — callers error the original consumer."""
+        if self._state_pool is not None:
+            self._state_pool.note_off("migration")
+            raise RuntimeError(
+                f"KV migration is off for {self.cfg.name}: a moved sequence "
+                "would leave its recurrent state behind")
         if self._migrate_in is None:
             raise RuntimeError("KV migration disabled (TPU_MIGRATE=0)")
         if self._stop_evt.is_set() or self.stalled:
@@ -4489,6 +4577,10 @@ class GenerationEngine:
             while len(batch) < self.admit_batch:
                 slot = self._free_slot(reserved)
                 if slot is None:
+                    if self._state_pool is not None and not self._admit.empty():
+                        # a request waits and no slot is free: where a pool
+                        # with host offload would weigh a preemption
+                        self._state_pool.note_off("offload")
                     break
                 try:
                     req = self._admit.get_nowait()
@@ -4515,6 +4607,13 @@ class GenerationEngine:
                     )
                     req.out.put(_DONE)
                     continue
+                if batch and self._over_admit_budget(batch, ids):
+                    # with this prompt the program would pad to more tokens
+                    # than may stand between two decode rounds: it leads the
+                    # next program instead (the queue's order is kept)
+                    with self._admit.mutex:
+                        self._admit.queue.appendleft(req)
+                    break
                 admitted = True
                 if not self._cn_attach(req):
                     continue  # bad constraint spec: request already errored
@@ -4597,6 +4696,29 @@ class GenerationEngine:
                 break  # admit queue drained
         return admitted
 
+    def _admit_tokens_max(self) -> int:
+        """The most tokens (rows x bucket, padding included) one admit program
+        of several whole prompts may hold: what a chunked prefill may put
+        between two decode rounds, `prefill_chunk`. Every stream waits for the
+        program, so its size is the gap's: unbounded, four prompts of the
+        longest bucket stood in one gap (admit_batch x prefill_chunk tokens).
+        0 = no bound (no chunking, or sp > 1: the sp axis bounds the work)."""
+        if self.sp != 1 or not self.prefill_chunk:
+            return 0
+        return self._bucket(self.prefill_chunk)
+
+    def _over_admit_budget(self, batch: list, ids: list[int]) -> bool:
+        """Whether the whole prompt `ids`, joining `batch` (not empty), would
+        pad the admit program past `_admit_tokens_max`. A prompt alone is
+        always admitted; one longer than `prefill_chunk` is chunked and never
+        joins a batch."""
+        cap = self._admit_tokens_max()
+        if not cap or len(ids) > self.prefill_chunk:
+            return False
+        rows = 1 << len(batch).bit_length()  # _start_batch's pow2 of len + 1
+        longest = max(len(ids), *(len(i) for _, _, i in batch))
+        return rows * self._bucket(longest) > cap
+
     # -- prompt-prefix KV cache --------------------------------------------
 
     PREFIX_MIN = 32  # shortest prefix worth caching (tokens)
@@ -4613,6 +4735,8 @@ class GenerationEngine:
         """Longest cached entry that is a STRICT prefix of `ids` (at least
         one suffix token must remain — the suffix chunk produces the
         first-sample logits)."""
+        if self._state_pool is not None:
+            self._state_pool.note_off("prefix_cache")
         if not self._prefix_budget or not self._prefix_cache:
             return None
         t = tuple(ids)
@@ -4983,6 +5107,12 @@ class GenerationEngine:
         thread): ledger registration first (evicting LRU entries to fit,
         exactly like a local store), then pool-row uploads on the physical
         path or a device-array entry on the contiguous path."""
+        if self._state_pool is not None:
+            # a peer's prefix holds KV rows and no recurrent state: never here
+            self._state_pool.note_off("prefix_cache")
+            with self.stats_lock:
+                self.prefix_import_rejects_total += 1
+            return False
         P0 = int(header.get("P") or 0)
         ids = [int(x) for x in header.get("ids") or []]
         hk = trees.get("k")
@@ -5258,6 +5388,8 @@ class GenerationEngine:
         mgr.note_admit_cost(mgr.blocks_for(want) - shared_full)
         self._slots[slot] = s
         self._lengths[slot] = P
+        if self._state_pool is not None:
+            self._state_pool.admitted_total += 1  # the slot's state row is this prompt's
         self._temp[slot] = req.temperature
         self._topk[slot] = req.top_k
         self._topp[slot] = req.top_p
@@ -5385,6 +5517,8 @@ class GenerationEngine:
             return None
         if self.ragged_prefill:
             return self._stage_ragged_group(budget)
+        if self._state_pool is not None:
+            self._state_pool.note_off("ragged_prefill")  # a bucketed chunk group instead
         group: list[int] = []
         metas: list[tuple[int, _PrefillState, int]] = []
         try:  # staging bugs must also fail over to waiters
@@ -6043,6 +6177,8 @@ class GenerationEngine:
         # chaos site: a failed round must fail active slots with error
         # events, not hang callers (the poisoned-round guard in _run)
         maybe_fail("engine.decode", f"active={len(active)}")
+        if self._state_pool is not None:
+            self._state_pool.note_off("speculation")  # a decode round, dispatched with no draft
         round_t0 = time.perf_counter()
         B = self.max_slots
         nact = len(active)
@@ -6229,6 +6365,11 @@ class GenerationEngine:
         t_wait = time.perf_counter()
         with TraceAnnotation("engine.fetch.sync"):
             out = np.asarray(disp.out)  # [K, Ba] — the only host sync per round
+        if self._experts is not None:
+            # rows past the K of tokens: the expert layer's counts [2, L, 5]
+            K, L = self.decode_chunk, self.cfg.n_layers
+            self._experts.counts = out[K:].reshape(-1)[: 10 * L].reshape(2, L, 5).tolist()
+            out = out[:K]
         now = time.perf_counter()
         self._last_round_ts = time.time()  # decode-cadence stall signal
         self._perf_mark = now  # sampled wait-gap anchor

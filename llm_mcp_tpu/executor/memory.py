@@ -7,6 +7,12 @@ starves its admission queue. This module adds the memory-manager layer in the
 style of vLLM's PagedAttention pool (Kwon et al., 2023) and Sarathi-Serve's
 SLO-aware admission, without repaginating the cache:
 
+  - **Recurrent state** (`StatePool`, at the end): what a sequence owns of a
+    linear-attention layer is not rows of the KV cache but one state of fixed
+    size, whatever its length. The pool beside the KV cache is neither paged
+    nor shared by block, so a configuration with such layers runs with the
+    features that take a sequence to be its KV blocks switched off, decided
+    once where the pool is built.
   - **Accounting**: bytes per slot are measured from the live cache pytree
     (`pytree_nbytes`), so kv8's `{q: int8, s: scale}` dict and MLA's
     asymmetric latent k/v layouts are covered without layout-specific code.
@@ -46,7 +52,8 @@ from typing import Any
 
 from ..utils.locks import OrderedLock
 
-__all__ = ["KVPool", "KVSnapshot", "pytree_nbytes", "bucket_len"]
+__all__ = ["ExpertCounts", "KVPool", "KVSnapshot", "RECURRENT_OFF", "StatePool", "build_state_pool",
+           "pytree_nbytes", "bucket_len"]
 
 POLICIES = ("priority", "idle", "tokens", "slo_debt")
 
@@ -313,3 +320,81 @@ class KVPool:
                 "offload_seconds_total": self.offload_seconds_total,
                 "restore_seconds_total": self.restore_seconds_total,
             }
+
+
+# What takes a sequence to be its KV blocks, and so cannot carry a recurrent
+# state yet: each is off for a configuration with recurrent layers, with the
+# reason the log gives once and a counter of the times it would have engaged.
+RECURRENT_OFF = {
+    "prefix_cache": "a cached prefix would need the state as it stood at the block boundary",
+    "offload": "a preempted slot's snapshot holds KV rows and no state",
+    "migration": "the wire format of a moved sequence holds KV rows and no state",
+    "speculation": "rejected drafts roll the KV cache back by arithmetic; a state cannot be",
+    "ragged_prefill": "a packed chunk holds several prompts' tokens in one row of the scan; "
+                      "the chunked recurrence carries one state a row",
+}
+
+
+class StatePool:
+    """Host-side book of the per-slot recurrent state beside the KV cache.
+
+    The device arrays ride the engine's cache pair through every step program
+    (models/hybrid.py: float32 S [Lk, slots, H, dk, dv] and the convolution
+    tails), one row a slot, allocated with the engine like the KV cache. A
+    slot's row is claimed at admission and starts from zero there: a whole
+    prompt's prefill writes the row outright, a chunked prefill's first chunk
+    (start 0) never reads it. The pool counts what a per-layer metric reads:
+    its bytes, the slots alive, and the features it keeps off (`off`)."""
+
+    def __init__(self, *, max_slots: int, nbytes: int):
+        self.max_slots = int(max_slots)
+        self.nbytes = int(nbytes)
+        self.bytes_per_slot = self.nbytes // max(1, self.max_slots)
+        self.admitted_total = 0
+        self.off = dict.fromkeys(RECURRENT_OFF, 0)
+
+    def note_off(self, feature: str) -> None:
+        self.off[feature] += 1
+
+    def stats(self, live_slots: int) -> dict[str, Any]:
+        return {
+            "bytes": self.nbytes,
+            "bytes_per_slot": self.bytes_per_slot,
+            "slots": self.max_slots,
+            "live_slots": int(live_slots),
+            "live_bytes": int(live_slots) * self.bytes_per_slot,
+            "admitted_total": self.admitted_total,
+            "off": dict(self.off),
+        }
+
+
+class ExpertCounts:
+    """Host-side book of the routed-expert layer's work, for a configuration
+    whose step programs count it (models/moe.py:moe_share_ffn; the counts ride
+    the cache pair as a member of their own and come back behind each decode
+    round's tokens). `counts` [2][L][5]: rows routed, pairs on held experts,
+    held experts touched, the fullest one's rows and calls, summed since boot
+    over decode steps [0] and over prefills [1]."""
+
+    def __init__(self, n_layers: int, *, held: int, router: int):
+        self.counts = [[[0] * 5 for _ in range(n_layers)] for _ in range(2)]
+        self.held = int(held)  # experts held here, of `router` scored
+        self.router = int(router)
+
+    def stats(self) -> dict[str, Any]:
+        # `counts` is replaced whole at a round's fetch, never edited
+        return {"counts": self.counts, "held": self.held, "router": self.router}
+
+
+def build_state_pool(cfg: Any, max_slots: int, state: Any, log: Any) -> "StatePool | None":
+    """The pool's book for a configuration with recurrent layers (`state` is
+    the device tree the engine allocated), None for any other. The one place
+    that says what such a configuration runs without."""
+    if not getattr(cfg, "recurrent", False):
+        return None
+    pool = StatePool(max_slots=max_slots, nbytes=pytree_nbytes(state))
+    log.info("recurrent state pool: %.1f MB a slot, %d slots beside the KV cache",
+             pool.bytes_per_slot / (1 << 20), max_slots)
+    for feature, why in RECURRENT_OFF.items():
+        log.info("%s is off for %s: %s", feature, cfg.name, why)
+    return pool

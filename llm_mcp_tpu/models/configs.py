@@ -95,6 +95,25 @@ class ModelConfig:
     first_dense_layers: int = 0
     norm_topk_prob: bool = True
     routed_scaling_factor: float = 1.0
+    # An expert-parallel SHARE (models/moe.py:moe_share_ffn): the router scores
+    # `n_router_experts` (the published count; 0 → n_experts, no share), this
+    # process holds experts [0, n_experts) and adds their part of the result
+    # only. `router_score` is the one routing function's score: softmax, or
+    # sigmoid with a selection bias that chooses and does not weigh.
+    n_router_experts: int = 0
+    router_score: str = "softmax"  # softmax | sigmoid
+    # Hybrid of softmax-attention and linear-attention layers
+    # (models/hybrid.py, models/kda.py): layer i is a GQA layer iff
+    # i in gqa_layers, else a gated delta-rule (KDA) layer with a per-slot
+    # recurrent state. Empty = every layer is the family's attention layer.
+    gqa_layers: tuple[int, ...] = ()
+    gqa_interval: int = 0  # linear layers between two GQA layers (published)
+    lin_heads: int = 0
+    lin_head_dim: int = 0  # key and value head size of the linear layers
+    lin_conv: int = 4  # taps of the causal depthwise convolution on q, k, v
+    lin_neg_eigval: bool = False  # beta in (0, 2): negative eigenvalues allowed
+    attn_gate: bool = False  # GQA output gate: attn * sigmoid(x W_gate)
+    use_rope: bool = True  # False: no positional encoding anywhere (NoPE)
     # serving metadata
     params_b: float = 0.0
     tie_embeddings: bool = False
@@ -102,6 +121,32 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.dim // self.n_heads
+
+    @property
+    def router_width(self) -> int:
+        """Experts the router scores: the published count under a share."""
+        return self.n_router_experts or self.n_experts
+
+    @property
+    def recurrent(self) -> bool:
+        """True when some layers carry a per-slot recurrent state: a sequence
+        is then more than its KV blocks (executor/memory.py: StatePool)."""
+        return bool(self.gqa_layers)
+
+    @property
+    def n_attn_layers(self) -> int:
+        """Layers that own rows of the KV cache."""
+        return len(self.gqa_layers) if self.gqa_layers else self.n_layers
+
+    @property
+    def layer_period(self) -> tuple[str, ...]:
+        """Kinds ("gqa" | "kda") of one period of the layer pattern; the
+        layer stack is this period repeated (models/hybrid.py scans by it)."""
+        kinds = ["gqa" if i in self.gqa_layers else "kda" for i in range(self.n_layers)]
+        for p in range(1, self.n_layers + 1):
+            if self.n_layers % p == 0 and kinds == kinds[:p] * (self.n_layers // p):
+                return tuple(kinds[:p])
+        raise AssertionError("unreachable: p = n_layers always matches")
 
     @property
     def yarn_attn_mscale(self) -> float:
@@ -129,7 +174,7 @@ class ModelConfig:
             moe_f = self.moe_ffn_hidden or self.ffn_hidden
             routed = 3 * self.dim * moe_f * self.n_experts
             shared = 3 * self.dim * moe_f * self.n_shared_experts
-            moe_layer = routed + shared + self.dim * self.n_experts  # + router
+            moe_layer = routed + shared + self.dim * self.router_width  # + router
             k = self.first_dense_layers
             ffn_total = k * ffn + (self.n_layers - k) * moe_layer
         if self.kv_lora_rank:  # MLA factorized attention
@@ -146,6 +191,14 @@ class ModelConfig:
                 + 2 * self.dim * self.n_kv_heads * hd  # wk, wv
                 + self.n_heads * hd * self.dim  # wo
             )
+        if self.gqa_layers:  # hybrid: GQA (+ gate) layers and KDA layers
+            r = self.lin_head_dim  # the two gates' low rank (models/kda.py)
+            hk = self.lin_heads * self.lin_head_dim
+            kda = (4 * self.dim * hk + 2 * (self.dim * r + r * hk)
+                   + self.dim * self.lin_heads + 3 * self.lin_conv * hk)
+            gqa = attn + (self.dim * self.n_heads * hd if self.attn_gate else 0)
+            ng = len(self.gqa_layers)
+            attn = (ng * gqa + (self.n_layers - ng) * kda) // self.n_layers
         per_layer_rest = attn + 2 * self.dim  # + norms
         embed = self.vocab_size * self.dim
         head = 0 if self.tie_embeddings or self.arch == "encoder" else self.vocab_size * self.dim
@@ -294,6 +347,71 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         v_head_dim=32,
         tie_embeddings=True,
         params_b=0.001,
+    ),
+    # Solar-Open2-250B (upstage/Solar-Open2-250B config.json) as ONE CHIP of an
+    # 8-way expert-parallel group, rank 0 of pipeline stage 0: one whole period
+    # of the layer pattern (GQA, KDA, KDA, KDA of 48 layers), experts 0-39 of
+    # the published 320 (the router keeps its 320 columns), 24,576 of 196,608
+    # vocabulary rows. Every width is the published one. The low rank of the
+    # two KDA gates (the head size), the sigmoid router with a selection bias
+    # and the element-wise GQA gate are the family's conventions, assumed:
+    # benchmark/configs/solar-open2-250b-ep8-bf16.json lists them.
+    "solar-open2-250b-ep8": ModelConfig(
+        name="solar-open2-250b-ep8",
+        vocab_size=24_576,
+        dim=4096,
+        n_layers=4,
+        n_heads=64,
+        n_kv_heads=8,
+        head_dim=128,
+        ffn_hidden=10_240,  # published intermediate_size; no layer is dense
+        rope_theta=10_000.0,
+        norm_eps=1e-5,
+        max_seq_len=1_048_576,
+        n_experts=40,
+        n_router_experts=320,
+        experts_per_tok=8,
+        n_shared_experts=1,
+        moe_ffn_hidden=1280,
+        norm_topk_prob=True,
+        routed_scaling_factor=1.0,
+        router_score="sigmoid",
+        gqa_layers=(0,),
+        gqa_interval=3,
+        lin_heads=64,
+        lin_head_dim=128,
+        lin_conv=4,
+        lin_neg_eigval=True,
+        attn_gate=True,
+        use_rope=False,
+        params_b=3.3,
+    ),
+    # the same shape at toy size: one period, 16 experts of which 4 are held
+    "tiny-solar": ModelConfig(
+        name="tiny-solar",
+        vocab_size=512,
+        dim=128,
+        n_layers=4,
+        n_heads=4,
+        n_kv_heads=2,
+        head_dim=32,
+        ffn_hidden=256,
+        rope_theta=10_000.0,
+        max_seq_len=512,
+        n_experts=4,
+        n_router_experts=16,
+        experts_per_tok=4,
+        n_shared_experts=1,
+        moe_ffn_hidden=64,
+        router_score="sigmoid",
+        gqa_layers=(0,),
+        gqa_interval=3,
+        lin_heads=4,
+        lin_head_dim=32,
+        lin_neg_eigval=True,
+        attn_gate=True,
+        use_rope=False,
+        params_b=0.002,
     ),
     # Tiny config for tests / CPU dev — same code paths, toy sizes.
     "tiny-llm": ModelConfig(

@@ -1,0 +1,330 @@
+"""A decoder whose layers are of unlike kinds: softmax GQA layers among gated
+delta-rule (KDA) linear-attention layers, every layer with a routed-expert
+feed-forward of which this process may hold a share (models/moe.py).
+
+The layer stack is one PERIOD of kinds repeated (`cfg.layer_period`, e.g.
+gqa, kda, kda, kda), so the program scans over periods and unrolls the few
+layers of one period inside the scan body: one period's XLA program compiled
+once, whatever the depth. The parameter tree:
+
+    params["embed"], ["final_norm"], ["lm_head"]
+    params["layers"]: what EVERY layer has, stacked [L, ...]: attn_norm,
+        ffn_norm, router [D, Er], router_bias [Er] (sigmoid routers), w1e, w3e
+        [E, D, F], w2e [E, F, D] (the E experts held here), w1s, w3s, w2s
+    params["gqa"]: the GQA layers', stacked [Lg, ...]: wq, wk, wv, wo, and wg
+        [D, H hd] with cfg.attn_gate
+    params["kda"]: the KDA layers', stacked [Lk, ...] (models/kda.py)
+
+What a sequence owns, beside the rows of the KV cache that its GQA layers
+write (cache layers 0..Lg-1, the dense family's layout and kernels), is the
+KDA layers' recurrent state. The engine threads both through every step
+program as the cache pair (cache_k, cache_v): `cache_v` is
+{"v": the KV cache's second member, "state": {"S", "conv"}, "moe": counts},
+built by `init_hybrid_cache`. "moe" [2, L, 5] int32 is the expert layer's own
+member, beside the state and not of it: the sums of its counts
+(moe.moe_share_ffn) over every call the process has made, decode steps under
+[0] and prefills under [1]; the engine reads it back with each decode round
+(executor/memory.py: ExpertCounts).
+
+No rope anywhere when cfg.use_rope is False; the GQA layers then attend by
+content and causality alone."""
+
+from __future__ import annotations
+
+from typing import Any
+
+import jax
+import jax.numpy as jnp
+
+from ..kernels.attention import (
+    append_kv_bf16,
+    append_kv_q8,
+    decode_attend_bf16,
+    decode_attend_q8,
+)
+from .configs import ModelConfig
+from .kda import init_kda_params, init_kda_state, kda_decode, kda_prefill
+from .moe import init_moe_layer_params, moe_share_ffn
+
+Params = dict[str, Any]
+
+
+def _layout(cfg: ModelConfig) -> tuple[tuple[str, ...], int, int, int]:
+    """(period, periods, GQA layers a period, KDA layers a period)."""
+    period = cfg.layer_period
+    return period, cfg.n_layers // len(period), period.count("gqa"), period.count("kda")
+
+
+def init_hybrid_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
+    """Seeded random weights, as ONE jitted program (an eager draw a tensor is
+    a compile a tensor on the chip). The router's selection bias is normal
+    with deviation 0.01: enough to move a choice between experts whose scores
+    lie close, far too little to override the scores. It is the same for every
+    row, so a larger one sends every row to the same few experts: at 0.1
+    (against scores that spread by 0.2) the 40 held got 0.72 of their share of
+    the pairs and the fullest 9.7 times the mean (v5e, PR 32's first run)."""
+    if not cfg.n_experts:
+        raise NotImplementedError("the hybrid decoder's feed-forward is the routed-expert layer")
+    _, P, ng, nk = _layout(cfg)
+    hd, D, H, Hkv, V = cfg.resolved_head_dim, cfg.dim, cfg.n_heads, cfg.n_kv_heads, cfg.vocab_size
+    L, Lg, Lk = cfg.n_layers, P * ng, P * nk
+
+    def build(key):
+        ks = jax.random.split(key, 12)
+
+        def w(k, shape, fan_in):
+            return (jax.random.normal(k, shape, jnp.float32) * fan_in**-0.5).astype(dtype)
+
+        layers = {"attn_norm": jnp.ones((L, D), dtype), "ffn_norm": jnp.ones((L, D), dtype)}
+        layers.update(init_moe_layer_params(cfg, ks[0], dtype))
+        if cfg.router_score == "sigmoid":
+            layers["router_bias"] = 0.01 * jax.random.normal(
+                ks[1], (L, cfg.router_width), jnp.float32)
+        gqa = {
+            "wq": w(ks[2], (Lg, D, H * hd), D),
+            "wk": w(ks[3], (Lg, D, Hkv * hd), D),
+            "wv": w(ks[4], (Lg, D, Hkv * hd), D),
+            "wo": w(ks[5], (Lg, H * hd, D), H * hd),
+        }
+        if cfg.attn_gate:
+            gqa["wg"] = w(ks[6], (Lg, D, H * hd), D)
+        params = {
+            "embed": w(ks[7], (V, D), D),
+            "layers": layers,
+            "gqa": gqa,
+            "kda": init_kda_params(cfg, ks[8], dtype, Lk),
+            "final_norm": jnp.ones((D,), dtype),
+        }
+        if not cfg.tie_embeddings:
+            params["lm_head"] = w(ks[9], (D, V), D)
+        return params
+
+    return jax.jit(build)(key)
+
+
+def init_hybrid_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype, quantized: bool) -> dict:
+    """{"k", "v"} as models/llama.py:init_kv_cache gives them for the GQA
+    layers alone, the second member wrapped with the recurrent state."""
+    from .llama import init_kv_cache
+
+    _, P, _, nk = _layout(cfg)
+    kv = init_kv_cache(
+        _gqa_view(cfg), batch, max_seq, dtype=dtype, quantized=quantized)
+    return {"k": kv["k"], "v": {"v": kv["v"], "state": init_kda_state(cfg, P * nk, batch, dtype),
+                                "moe": jnp.zeros((2, cfg.n_layers, 5), jnp.int32)}}
+
+
+def _gqa_view(cfg: ModelConfig) -> ModelConfig:
+    """The config as the dense family's cache code reads it: only the GQA
+    layers own cache rows."""
+    import dataclasses
+
+    return dataclasses.replace(cfg, n_layers=cfg.n_attn_layers, gqa_layers=())
+
+
+BANKS = ("w1e", "w3e", "w2e")  # never sliced by layer: moe.moe_share_ffn says why
+
+
+def _by_period(cfg: ModelConfig, params: Params):
+    """The three stacks reshaped [P, layers of that kind a period, ...], the
+    expert banks left out."""
+    period, P, ng, nk = _layout(cfg)
+
+    def split(tree, n):
+        return jax.tree.map(lambda a: a.reshape(P, n, *a.shape[1:]), tree)
+
+    layers = {k: v for k, v in params["layers"].items() if k not in BANKS}
+    return split(layers, len(period)), split(params["gqa"], ng), split(params["kda"], nk)
+
+
+def _at(tree, i: int):
+    return jax.tree.map(lambda a: a[i], tree)
+
+
+def _ffn(cfg: ModelConfig, lp: Params, banks: Params, li, h: jnp.ndarray, valid):
+    """Pre-norm expert layer `li` and residual add on [..., D]; (h, counts [5])."""
+    from .llama import _norm
+
+    with jax.named_scope("ffn"):
+        x = _norm(cfg, h, lp["ffn_norm"])
+        y, counts = moe_share_ffn(
+            cfg, lp, x.reshape(-1, x.shape[-1]),
+            valid=None if valid is None else valid.reshape(-1), banks=banks, layer=li)
+        return h + y.reshape(h.shape), counts
+
+
+def _period_scan(cfg: ModelConfig, params: Params, h, carry, gqa_layer, kda_layer, valid):
+    """Scan the periods. `gqa_layer(h, carry, lp, ig)` and `kda_layer(h,
+    carry, lp, ik)` run one layer's mixing half on the running `carry` (the
+    caches, as the caller shapes it), `ig` / `ik` being the layer's index
+    among its kind; the expert layer follows either. Returns (h, carry,
+    counts [L, 5]), and whatever the GQA layers stacked as ys, [P ng, ...]."""
+    period, P, ng, nk = _layout(cfg)
+    banks = {k: params["layers"][k] for k in BANKS}
+
+    def body(c, xs):
+        h, carry, p = c
+        layers, gqa, kda = xs
+        ig = ik = 0
+        ys, counts = [], []
+        for i, kind in enumerate(period):
+            lp = _at(layers, i)
+            if kind == "gqa":
+                h, carry, y = gqa_layer(h, carry, {**lp, **_at(gqa, ig)}, p * ng + ig)
+                ys.append(y)
+                ig += 1
+            else:
+                h, carry = kda_layer(h, carry, {**lp, **_at(kda, ik)}, p * nk + ik)
+                ik += 1
+            h, n = _ffn(cfg, lp, banks, p * len(period) + i, h, valid)
+            counts.append(n)
+        ys = jax.tree.map(lambda *a: jnp.stack(a), *ys) if ys and ys[0] is not None else None
+        return (h, carry, p + 1), (ys, jnp.stack(counts))
+
+    (h, carry, _), (ys, counts) = jax.lax.scan(
+        body, (h, carry, jnp.int32(0)), _by_period(cfg, params))
+    if ys is not None:
+        ys = jax.tree.map(lambda a: a.reshape(P * ng, *a.shape[2:]), ys)
+    return h, carry, counts.reshape(cfg.n_layers, 5), ys
+
+
+def hybrid_prefill(cfg, params, tokens, lengths, attn_impl="xla", quant_kv=False):
+    """Whole fresh prompts [B, S] from zero state: (last logits [B, V], ks,
+    vs) with ks the GQA layers' prompt K/V as `llama_prefill` returns them and
+    vs = {"v": their second member, "state": each row's S [Lk, B, ...] and
+    conv tails, "moe": the call's expert counts [L, 5]}; the engine inserts
+    row by row (`insert_state_row`) and adds the counts once (`add_counts`)."""
+    from .llama import _embed_in, _logits, _norm, fuse_prompt_kv, prefill_attn, prefill_masks
+
+    B, S = tokens.shape
+    H, d, taps = cfg.lin_heads, cfg.lin_head_dim, cfg.lin_conv
+    _, P, _, nk = _layout(cfg)
+    h = _embed_in(cfg, params, tokens)
+    cos, sin, mask = prefill_masks(cfg, S, lengths)
+    valid = jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None]
+    S0 = jnp.zeros((B, H, d, d), jnp.float32)
+    tail0 = jnp.zeros((B, taps - 1, 3 * H * d), h.dtype)
+
+    def gqa_layer(h, carry, lp, ig):
+        h, (kh, vh) = prefill_attn(cfg, lp, h, cos, sin, mask, lengths, attn_impl)
+        return h, carry, ((fuse_prompt_kv(kh, vh), {}) if quant_kv else (kh, vh))
+
+    def kda_layer(h, carry, lp, ik):
+        Ss, tails = carry
+        y, S_new, tail = kda_prefill(cfg, lp, _norm(cfg, h, lp["attn_norm"]), lengths, S0, tail0)
+        return h + y, (Ss.at[ik].set(S_new), tails.at[ik].set(tail))
+
+    carry = (jnp.zeros((P * nk, *S0.shape), jnp.float32),
+             jnp.zeros((P * nk, *tail0.shape), tail0.dtype))
+    h, (Ss, tails), counts, (ks, vs) = _period_scan(
+        cfg, params, h, carry, gqa_layer, kda_layer, valid)
+    last = jnp.take_along_axis(h, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+    return _logits(cfg, params, last), ks, {
+        "v": vs, "state": {"S": Ss, "conv": tails}, "moe": counts}
+
+
+def insert_state_row(state: dict, new: dict, i, slot) -> dict:
+    """Row `i` of a prefill's state into pool row `slot` (both traced)."""
+    def put(pool, rows):
+        row = jax.lax.dynamic_slice_in_dim(rows, i, 1, 1)
+        return jax.lax.dynamic_update_slice(
+            pool, row.astype(pool.dtype), (0, slot) + (0,) * (pool.ndim - 2))
+
+    return {"S": put(state["S"], new["S"]), "conv": put(state["conv"], new["conv"])}
+
+
+def add_counts(cache_v: dict, new: dict) -> dict:
+    """A prefill's expert counts [L, 5] (the call's, not a row's) onto the
+    running sums the cache pair carries."""
+    return dict(cache_v, moe=cache_v["moe"].at[1].add(new["moe"]))
+
+
+def hybrid_prefill_chunk_batch(
+    cfg, params, cache_k, cache_v, tokens, slots, starts, nvalid,
+    skey=0, all_logits=False, paged=None,
+):
+    """`llama_prefill_chunk_batch` for the hybrid stack: the GQA layers read
+    and write the KV cache as there; a KDA layer continues each slot's state
+    and convolution tail from the pool, from ZERO where the chunk is a
+    prompt's first (start 0: a reused slot's old state is never read), and
+    writes both back. Rows that duplicate row 0 (the engine's padding) write
+    what row 0 writes."""
+    from .llama import _chunk_attention, _logits, _norm
+
+    A, C = tokens.shape
+    kv_v, state = cache_v["v"], cache_v["state"]
+    h, attend, write = _chunk_attention(
+        cfg, params, cache_k, tokens, slots, starts, nvalid, skey=skey, paged=paged)
+    slots = jnp.asarray(slots, jnp.int32)
+    fresh = jnp.asarray(starts, jnp.int32) == 0
+    valid = jnp.arange(C, dtype=jnp.int32)[None, :] < nvalid[:, None]
+
+    def gqa_layer(h, carry, lp, ig):
+        ck, cv, S, conv = carry
+        h, kh, vh = attend(h, ck, cv, ig, lp, 0)
+        ck, cv = write(ck, cv, kh, vh, ig)
+        return h, (ck, cv, S, conv), None
+
+    def kda_layer(h, carry, lp, ik):
+        ck, cv, S, conv = carry
+        S0 = jnp.where(fresh[:, None, None, None], 0.0, S[ik, slots])
+        tail0 = jnp.where(fresh[:, None, None], 0, conv[ik, slots])
+        y, S_new, tail = kda_prefill(cfg, lp, _norm(cfg, h, lp["attn_norm"]), nvalid, S0, tail0)
+        for a in range(A):  # row by row: duplicates of row 0 land on row 0's values
+            S = jax.lax.dynamic_update_slice(S, S_new[a][None, None], (ik, slots[a], 0, 0, 0))
+            conv = jax.lax.dynamic_update_slice(conv, tail[a][None, None], (ik, slots[a], 0, 0))
+        return h + y, (ck, cv, S, conv)
+
+    h, (ck, cv, S, conv), counts, _ = _period_scan(
+        cfg, params, h, (cache_k, kv_v, state["S"], state["conv"]), gqa_layer, kda_layer, valid)
+    new_v = {"v": cv, "state": {"S": S, "conv": conv}, "moe": cache_v["moe"].at[1].add(counts)}
+    if all_logits:
+        return _logits(cfg, params, h), ck, new_v
+    last = jnp.take_along_axis(h, (nvalid - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+    return _logits(cfg, params, last), ck, new_v
+
+
+def hybrid_decode_step(cfg, params, cache_k, cache_v, tokens, lengths, slot_ids=None, paged=None):
+    """One token a row. The GQA layers take the dense family's decode
+    structure: the KV cache is a scan-invariant operand read by the decode
+    attention kernel, the step's K/V stack out of the scan and one append
+    kernel lands them. A KDA layer steps its rows of the state pool in place
+    (kernels/kda.py). A parked or padding row (length >= the cache's) moves
+    nothing: not its cache rows, not its state."""
+    from .llama import _attn_residual, _cache_shape, _embed_in, _logits, _norm, _qkv
+
+    if paged is not None:
+        raise NotImplementedError("a recurrent configuration's blocks are never shared")
+    quantized = isinstance(cache_k, dict)
+    kv_v, state = cache_v["v"], cache_v["state"]
+    S_cache, hd = _cache_shape(cache_k)[3], cfg.resolved_head_dim
+    Ba, H, Hkv = tokens.shape[0], cfg.n_heads, cfg.n_kv_heads
+    rows = jnp.arange(Ba, dtype=jnp.int32) if slot_ids is None else slot_ids.astype(jnp.int32)
+    live = lengths < S_cache
+    attend = decode_attend_q8 if quantized else decode_attend_bf16
+    h = _embed_in(cfg, params, tokens)
+
+    def gqa_layer(h, carry, lp, ig):
+        with jax.named_scope("attn"):
+            x = _norm(cfg, h, lp["attn_norm"])
+            q, k, v = _qkv(cfg, lp, x)
+            k, v = k.reshape(Ba, Hkv, hd), v.reshape(Ba, Hkv, hd)
+            ctx = attend(
+                q.reshape(Ba, Hkv, H // Hkv, hd), k, v, cache_k, kv_v, ig, lengths,
+                slot_ids=slot_ids, scale=cfg.attn_scale,
+            ).reshape(Ba, H * hd)
+            return _attn_residual(cfg, lp, ctx, h, x), carry, (k, v)
+
+    def kda_layer(h, carry, lp, ik):
+        with jax.named_scope("kda"):
+            y, carry = kda_decode(
+                cfg, lp, _norm(cfg, h, lp["attn_norm"]), carry, ik, rows, live)
+            return h + y, carry
+
+    h, lin, counts, (knew, vnew) = _period_scan(
+        cfg, params, h, state, gqa_layer, kda_layer, live)
+    with jax.named_scope("kv_append"):
+        append = append_kv_q8 if quantized else append_kv_bf16
+        new_k, new_kv_v = append(cache_k, kv_v, knew, vnew, lengths, slot_ids=slot_ids)
+    return _logits(cfg, params, h), new_k, {
+        "v": new_kv_v, "state": lin, "moe": cache_v["moe"].at[0].add(counts)}

@@ -69,6 +69,10 @@ def init_llama_params(
         from .mla import init_mla_params
 
         return init_mla_params(cfg, key, dtype=dtype)
+    if _dispatch and cfg.gqa_layers:  # layers of unlike kinds: models/hybrid.py
+        from .hybrid import init_hybrid_params
+
+        return init_hybrid_params(cfg, key, dtype=dtype)
     hd = cfg.resolved_head_dim
     L, D, H, Hkv, F, V = (
         cfg.n_layers,
@@ -171,6 +175,10 @@ def init_kv_cache(
         from .mla import init_mla_cache
 
         return init_mla_cache(cfg, batch, max_seq, dtype=dtype, quantized=quantized)
+    if cfg.gqa_layers:  # KV rows for the GQA layers, recurrent state for the rest
+        from .hybrid import init_hybrid_cache
+
+        return init_hybrid_cache(cfg, batch, max_seq, dtype, quantized)
     hd = cfg.resolved_head_dim
     Hkv = cfg.n_kv_heads
     shape = (cfg.n_layers, batch, Hkv, max_seq, hd)
@@ -284,8 +292,15 @@ def _qkv(cfg: ModelConfig, lp: Params, x: jnp.ndarray):
     return q, k, v
 
 
-def _attn_residual(cfg: ModelConfig, lp: Params, ctx: jnp.ndarray, h: jnp.ndarray):
-    """Output projection (+ optional post-attention norm) and residual add."""
+def _attn_residual(
+    cfg: ModelConfig, lp: Params, ctx: jnp.ndarray, h: jnp.ndarray,
+    x: jnp.ndarray | None = None,
+):
+    """Output projection (+ optional post-attention norm) and residual add.
+    A layer with an output gate (`wg`, cfg.attn_gate) multiplies the heads'
+    output by sigmoid(x W_gate), x being the layer's normed input."""
+    if "wg" in lp:
+        ctx = ctx * jax.nn.sigmoid(qdot(x, lp["wg"]).astype(jnp.float32)).astype(ctx.dtype)
     out = qdot(ctx, lp["wo"])
     if cfg.post_norms:
         out = _norm(cfg, out, lp["post_attn_norm"])
@@ -386,6 +401,22 @@ def prefill_layer(
 ) -> tuple[jnp.ndarray, tuple[jnp.ndarray, jnp.ndarray]]:
     """One decoder layer over a full prompt. Shared by the scan in
     `llama_prefill` and the stage loop in parallel/pipeline.py."""
+    S = h.shape[1]
+    h, kv = prefill_attn(cfg, lp, h, cos, sin, mask, lengths, attn_impl, window)
+    h = _ffn_residual(
+        cfg, lp, h,
+        moe_valid=jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None],
+    )
+    return h, kv
+
+
+def prefill_attn(
+    cfg: ModelConfig, lp: Params, h: jnp.ndarray, cos, sin, mask, lengths,
+    attn_impl: str = "xla", window: jnp.ndarray | int = 0,
+) -> tuple[jnp.ndarray, tuple[jnp.ndarray, jnp.ndarray]]:
+    """The attention half of `prefill_layer`: (h after the residual add,
+    (kh, vh) head-major prompt K/V). models/hybrid.py runs it for its GQA
+    layers; a family without rope (cfg.use_rope False) skips the rotation."""
     B, S, _ = h.shape
     hd = cfg.resolved_head_dim
     H, Hkv = cfg.n_heads, cfg.n_kv_heads
@@ -399,8 +430,9 @@ def prefill_layer(
         q = q.reshape(B, S, H, hd)
         k = k.reshape(B, S, Hkv, hd)
         v = v.reshape(B, S, Hkv, hd)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        if cfg.use_rope:
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
 
         # Cache layout: heads before sequence (see module docstring).
         kh = k.transpose(0, 2, 1, 3)  # [B, Hkv, S, hd]
@@ -430,11 +462,7 @@ def prefill_layer(
             scores = jnp.where(m[:, None, None, :, :], scores, neg)
             probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
             ctx = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v).reshape(B, S, H * hd)
-        h = _attn_residual(cfg, lp, ctx, h)
-    h = _ffn_residual(
-        cfg, lp, h,
-        moe_valid=jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None],
-    )
+        h = _attn_residual(cfg, lp, ctx, h, x)
     return h, (kh, vh)
 
 
@@ -464,6 +492,11 @@ def llama_prefill(
         from .mla import mla_prefill
 
         return mla_prefill(cfg, params, tokens, lengths, quant_kv=quant_kv)
+    if cfg.gqa_layers:
+        from .hybrid import hybrid_prefill
+
+        return hybrid_prefill(
+            cfg, params, tokens, lengths, attn_impl=attn_impl, quant_kv=quant_kv)
     B, S = tokens.shape
     h = _embed_in(cfg, params, tokens)  # [B, S, D]
     cos, sin, mask = prefill_masks(cfg, S, lengths)
@@ -646,58 +679,16 @@ def _decode_step_bf16(
     return _logits(cfg, params, h), new_k, new_v
 
 
-def llama_prefill_chunk_batch(
-    cfg: ModelConfig,
-    params: Params,
-    cache_k: Any,  # [L, B, Hkv, S, hd] engine cache (or int8 {"q","s"} pytree)
-    cache_v: Any,
-    tokens: jnp.ndarray,  # [A, C] int32 — right-padded chunks, one per slot
-    slots: jnp.ndarray,  # [A] int32 — engine slots (distinct, or duplicated row 0 padding)
-    starts: jnp.ndarray,  # [A] int32 — absolute position of each chunk's first token
-    nvalid: jnp.ndarray,  # [A] int32 — valid tokens per chunk
-    skey: int = 0,  # STATIC bound on the PAST key range (0 = whole S); >= max(starts)
-    all_logits: bool = False,  # STATIC: logits at every chunk position, not just the last
-    paged: dict | None = None,  # {"tbl","k","v"} physical paging operand
-) -> tuple[jnp.ndarray, Any, Any]:
-    """Batched chunked prefill: one bounded chunk for up to A slots' prompts
-    in a single dispatch, written straight into the engine cache.
-
-    Three TPU-first structural choices (each measured against the naive
-    form on a v5e chip at 8B):
-
-    - **Batched over slots**: the chunk weight pass dominates chunk cost
-      (~65 ms at 8B int8); A prompts amortize it A-fold. A serial admission
-      path starves the continuous batch — most slots sit idle waiting to
-      prefill (measured 102 tok/s vs ~1.9 k tok/s decode capacity at B=64).
-    - **Read-past-then-write**: the chunk attends the slot's PAST rows
-      [0, starts) read from the pre-write cache, and its own K/V from
-      registers (exact bf16, even when the cache is int8 — the same
-      semantics as the decode kernel's current-position override). All cache
-      writes happen after the reads: write-after-read updates in place,
-      while the read-after-write form costs XLA defensive copies.
-    - **Static buckets everywhere**: C and `skey` are compile-time buckets
-      (pow2), positions/slots are traced scalars — one executable per
-      (A, C, skey) serves every admission forever.
-
-    Padding rows past `nvalid` in a ragged final chunk are written but never
-    attended (causal mask; valid q rows never reach garbage columns) and are
-    overwritten in place by later decode steps. Engine interleaving:
-    executor/engine.py:_stage_prefill_group (token-budget scheduler,
-    executor/scheduler.py). The reference never faces any of
-    this — it proxies Ollama (`core/internal/api/handlers.go:2427-2587`).
-
-    Returns (logits [A, V] f32 at each row's last valid position — or
-    [A, C, V] at every position when `all_logits` (the speculative-decoding
-    verify path scores each drafted token against the position before it) —
-    new_cache_k, new_cache_v).
-    """
-    if cfg.kv_lora_rank:  # MLA family: absorbed chunked prefill over latents
-        from .mla import mla_prefill_chunk_batch
-
-        return mla_prefill_chunk_batch(
-            cfg, params, cache_k, cache_v, tokens, slots, starts, nvalid,
-            skey=skey, all_logits=all_logits, paged=paged,
-        )
+def _chunk_attention(
+    cfg: ModelConfig, params: Params, cache_k: Any, tokens, slots, starts, nvalid,
+    skey: int = 0, paged: dict | None = None,
+):
+    """What the layers of a bucketed chunk share: (h0 [A, C, D], attend, write).
+    `attend(h, ck_all, cv_all, li, lp, win) -> (h, kh, vh)` is the attention
+    half of a layer reading cache layer `li` (past rows from the PRE-write
+    cache, the chunk's own K/V from registers); `write(ck_all, cv_all, kh, vh,
+    li)` lands the chunk's rows. `llama_prefill_chunk_batch` scans them with the
+    dense or routed FFN between; models/hybrid.py runs them for its GQA layers."""
     quantized = isinstance(cache_k, dict)
     # fused quantized cache: axis 2 of "q" is 2*Hkv + p — take Hkv from cfg
     L, B, _, S, hd = _cache_shape(cache_k)
@@ -734,14 +725,13 @@ def llama_prefill_chunk_batch(
         (c_idx[None, :] <= c_idx[:, None])[None], (A, C, C)
     )
 
-    def layer(carry, xs):
-        lp, win = xs
-        h, ck_all, cv_all, li = carry
+    def attend(h, ck_all, cv_all, li, lp, win):
         with jax.named_scope("attn"):
             x = _norm(cfg, h, lp["attn_norm"])
             q, k, v = _qkv(cfg, lp, x)
-            q = apply_rope(q.reshape(A, C, H, hd), cos, sin)
-            k = apply_rope(k.reshape(A, C, Hkv, hd), cos, sin)
+            q, k = q.reshape(A, C, H, hd), k.reshape(A, C, Hkv, hd)
+            if cfg.use_rope:
+                q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
             v = v.reshape(A, C, Hkv, hd)
             kh = k.transpose(0, 2, 1, 3)  # [A, Hkv, C, hd]
             vh = v.transpose(0, 2, 1, 3)
@@ -843,12 +833,10 @@ def llama_prefill_chunk_batch(
                 "ahgcs,ahsd->achgd", p_past.astype(h.dtype), vrows.astype(h.dtype)
             ) + jnp.einsum("ahgct,ahtd->achgd", p_self.astype(h.dtype), vh)
             ctx = ctx.reshape(A, C, H * hd)
-            h = _attn_residual(cfg, lp, ctx, h)
-        h = _ffn_residual(
-            cfg, lp, h, moe_valid=c_idx[None, :] < nvalid[:, None]
-        )
+            h = _attn_residual(cfg, lp, ctx, h, x)
+        return h, kh, vh
 
-        # ---- writes last: in-place (write-after-read) ----
+    def write(ck_all, cv_all, kh, vh, li):
         with jax.named_scope("kv_append"):
             if quantized:
                 # write the chunk's rows in cache layout: fused payload
@@ -872,6 +860,84 @@ def llama_prefill_chunk_batch(
                     cv_all = jax.lax.dynamic_update_slice(
                         cv_all, vh[a][None, None].astype(cv_all.dtype), (li, slots[a], 0, starts[a], 0)
                     )
+        return ck_all, cv_all
+
+    return h, attend, write
+
+
+def llama_prefill_chunk_batch(
+    cfg: ModelConfig,
+    params: Params,
+    cache_k: Any,  # [L, B, Hkv, S, hd] engine cache (or int8 {"q","s"} pytree)
+    cache_v: Any,
+    tokens: jnp.ndarray,  # [A, C] int32 — right-padded chunks, one per slot
+    slots: jnp.ndarray,  # [A] int32 — engine slots (distinct, or duplicated row 0 padding)
+    starts: jnp.ndarray,  # [A] int32 — absolute position of each chunk's first token
+    nvalid: jnp.ndarray,  # [A] int32 — valid tokens per chunk
+    skey: int = 0,  # STATIC bound on the PAST key range (0 = whole S); >= max(starts)
+    all_logits: bool = False,  # STATIC: logits at every chunk position, not just the last
+    paged: dict | None = None,  # {"tbl","k","v"} physical paging operand
+) -> tuple[jnp.ndarray, Any, Any]:
+    """Batched chunked prefill: one bounded chunk for up to A slots' prompts
+    in a single dispatch, written straight into the engine cache.
+
+    Three TPU-first structural choices (each measured against the naive
+    form on a v5e chip at 8B):
+
+    - **Batched over slots**: the chunk weight pass dominates chunk cost
+      (~65 ms at 8B int8); A prompts amortize it A-fold. A serial admission
+      path starves the continuous batch — most slots sit idle waiting to
+      prefill (measured 102 tok/s vs ~1.9 k tok/s decode capacity at B=64).
+    - **Read-past-then-write**: the chunk attends the slot's PAST rows
+      [0, starts) read from the pre-write cache, and its own K/V from
+      registers (exact bf16, even when the cache is int8 — the same
+      semantics as the decode kernel's current-position override). All cache
+      writes happen after the reads: write-after-read updates in place,
+      while the read-after-write form costs XLA defensive copies.
+    - **Static buckets everywhere**: C and `skey` are compile-time buckets
+      (pow2), positions/slots are traced scalars — one executable per
+      (A, C, skey) serves every admission forever.
+
+    Padding rows past `nvalid` in a ragged final chunk are written but never
+    attended (causal mask; valid q rows never reach garbage columns) and are
+    overwritten in place by later decode steps. Engine interleaving:
+    executor/engine.py:_stage_prefill_group (token-budget scheduler,
+    executor/scheduler.py). The reference never faces any of
+    this — it proxies Ollama (`core/internal/api/handlers.go:2427-2587`).
+
+    Returns (logits [A, V] f32 at each row's last valid position — or
+    [A, C, V] at every position when `all_logits` (the speculative-decoding
+    verify path scores each drafted token against the position before it) —
+    new_cache_k, new_cache_v).
+    """
+    if cfg.kv_lora_rank:  # MLA family: absorbed chunked prefill over latents
+        from .mla import mla_prefill_chunk_batch
+
+        return mla_prefill_chunk_batch(
+            cfg, params, cache_k, cache_v, tokens, slots, starts, nvalid,
+            skey=skey, all_logits=all_logits, paged=paged,
+        )
+    if cfg.gqa_layers:
+        from .hybrid import hybrid_prefill_chunk_batch
+
+        return hybrid_prefill_chunk_batch(
+            cfg, params, cache_k, cache_v, tokens, slots, starts, nvalid,
+            skey=skey, all_logits=all_logits, paged=paged,
+        )
+    A, C = tokens.shape
+    c_idx = jnp.arange(C, dtype=jnp.int32)
+    h, attend, write = _chunk_attention(
+        cfg, params, cache_k, tokens, slots, starts, nvalid, skey=skey, paged=paged)
+
+    def layer(carry, xs):
+        lp, win = xs
+        h, ck_all, cv_all, li = carry
+        h, kh, vh = attend(h, ck_all, cv_all, li, lp, win)
+        h = _ffn_residual(
+            cfg, lp, h, moe_valid=c_idx[None, :] < nvalid[:, None]
+        )
+        # ---- writes last: in-place (write-after-read) ----
+        ck_all, cv_all = write(ck_all, cv_all, kh, vh, li)
         return (h, ck_all, cv_all, li + 1), None
 
     (h, new_k, new_v, _), _ = jax.lax.scan(
@@ -1022,7 +1088,7 @@ def llama_prefill_chunk_ragged(
             cfg, params, cache_k, cache_v, tokens, rowids, positions,
             slots, starts, last_idx, skey=skey, paged=paged, impl=impl,
         )
-    if cfg.sliding_window or cfg.attn_softcap:
+    if cfg.sliding_window or cfg.attn_softcap or cfg.recurrent:
         raise NotImplementedError(
             "ragged prefill covers global-attention, no-softcap families; "
             "the engine gates others to the bucketed path"
@@ -1169,6 +1235,13 @@ def llama_decode_step(
         return mla_decode_step(
             cfg, params, cache_k, cache_v, tokens, lengths,
             slot_ids=slot_ids, attn_impl=attn_impl, paged=paged,
+        )
+    if cfg.gqa_layers:  # one structure on either platform: the kernels' own
+        from .hybrid import hybrid_decode_step
+
+        return hybrid_decode_step(
+            cfg, params, cache_k, cache_v, tokens, lengths,
+            slot_ids=slot_ids, paged=paged,
         )
     quantized = isinstance(cache_k, dict)
     # fused quantized cache: axis 2 of "q" is 2*Hkv + p — take Hkv from cfg

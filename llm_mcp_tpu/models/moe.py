@@ -39,6 +39,119 @@ def expert_capacity(cfg: ModelConfig, n_tokens: int) -> int:
     return max(1, min(c, n_tokens))
 
 
+def route(
+    cfg: ModelConfig, router_logits: jnp.ndarray, bias: jnp.ndarray | None = None
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """The one routing function: (gates [T, k] f32, experts [T, k] int32).
+
+    Scores are a softmax over the router's columns, or with
+    `router_score == "sigmoid"` an independent sigmoid of each; the top k are
+    chosen greedily over ALL columns (by score + `bias` when the family has a
+    selection bias: it chooses and does not weigh). The gates are the chosen
+    scores, renormalised to sum to 1 with `norm_topk_prob`, else scaled by
+    `routed_scaling_factor` (DeepSeek-V2's raw softmax mass)."""
+    k = cfg.experts_per_tok
+    logits = router_logits.astype(jnp.float32)
+    if cfg.router_score == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    if bias is None:
+        top_g, top_i = jax.lax.top_k(scores, k)
+    else:
+        _, top_i = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+        top_g = jnp.take_along_axis(scores, top_i, axis=-1)
+    if cfg.norm_topk_prob and k > 1:
+        top_g = top_g / jnp.sum(top_g, axis=-1, keepdims=True)  # renormalize
+    elif cfg.routed_scaling_factor != 1.0:
+        top_g = top_g * cfg.routed_scaling_factor
+    return top_g, top_i
+
+
+def moe_share_ffn(
+    cfg: ModelConfig,
+    lp: dict[str, Any],
+    x: jnp.ndarray,  # [T, D]
+    valid: jnp.ndarray | None = None,  # [T] bool: rows that are tokens
+    banks: dict[str, Any] | None = None,  # the STACKED w1e, w3e, w2e [L, E, ..]
+    layer: jnp.ndarray | int = 0,  # which of the stack's layers, with `banks`
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Dropless expert layer of ONE member of an expert-parallel group.
+
+    The router scores all `cfg.router_width` published experts (`router`
+    [D, Er], optional `router_bias` [Er]); this member holds experts
+    [0, E) of them (`w1e`, `w3e` [E, D, F], `w2e` [E, F, D]) and returns
+    sum over a row's chosen e < E of gate_e * SwiGLU_e(x), plus the shared
+    expert. What the other members' experts would add is NOT here and nothing
+    stands in for it. With Er == E it is the whole layer.
+
+    Every (row, chosen expert) pair is kept: pairs are sorted by expert, the
+    held experts' rows go through grouped products (`jax.lax.ragged_dot`, one
+    group an expert: work and weight traffic follow the pairs that landed
+    here and the experts they touched, not E), and pairs of absent experts
+    sort behind the last group, where a ragged product yields zeros.
+
+    A caller inside a layer scan hands over the expert banks STACKED over the
+    layers (`banks`, with `layer`) and not this layer's slice: the grouped
+    product is a kernel of its own to the TPU's compiler, and a slice of a
+    stack that feeds one is copied out first (three banks a layer and step,
+    1.2 GB at the published size). The stack goes in whole, as L x E groups
+    of which only this layer's E have rows.
+
+    Returns (y [T, D], counts int32 [5]): rows routed, pairs on held experts,
+    distinct held experts touched, the fullest held expert's rows, and 1 (a
+    call) — what the engine's counters sum per layer (executor/memory.py:
+    StatePool)."""
+    T, D = x.shape
+    k = cfg.experts_per_tok
+    E = (banks or lp)["w1e"].shape[-3]
+    # The router's product in float32: of the router's 8 choices among 320
+    # scores, the 8th and the 9th lie about 0.05 apart in the logit, and a
+    # bfloat16 product rounds a logit of 2 by up to 0.008: one row in five
+    # changed an expert a layer, and a served token lay up to 0.05 of its
+    # row's max |logit| under the reference's choice (v5e, PR 32).
+    logits = jnp.dot(x.astype(jnp.float32), lp["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    gates, experts = route(cfg, logits, lp.get("router_bias"))
+    held = experts < E
+    if valid is not None:
+        held = held & valid[:, None]
+    flat_e = jnp.where(held, experts, E).reshape(-1)  # absent/padding → group E
+    order = jnp.argsort(flat_e, stable=True)  # [T k] pairs grouped by expert
+    rows = order // k
+    sizes = jnp.sum(
+        flat_e[:, None] == jnp.arange(E, dtype=flat_e.dtype)[None, :], axis=0,
+        dtype=jnp.int32,
+    )  # [E] rows of each held expert
+    xs = jnp.take(x, rows, axis=0)  # [T k, D]
+    if banks is None:
+        w1, w3, w2, groups = lp["w1e"], lp["w3e"], lp["w2e"], sizes
+    else:
+        L = banks["w1e"].shape[0]
+        w1, w3, w2 = (banks[n].reshape(L * E, *banks[n].shape[2:]) for n in ("w1e", "w3e", "w2e"))
+        groups = jax.lax.dynamic_update_slice(
+            jnp.zeros((L * E,), jnp.int32), sizes, (jnp.asarray(layer, jnp.int32) * E,))
+    gate = jax.nn.silu(jax.lax.ragged_dot(xs, w1, groups))
+    up = jax.lax.ragged_dot(xs, w3, groups)
+    ys = jax.lax.ragged_dot((gate * up).astype(x.dtype), w2, groups)
+    w = jnp.where(held, gates, 0.0).reshape(-1)[order]
+    # a pair of an absent expert weighs 0 whatever its row of `ys` holds
+    ys = jnp.where(w[:, None] > 0, ys.astype(jnp.float32) * w[:, None], 0.0)
+    y = jnp.zeros((T, D), jnp.float32).at[rows].add(ys)
+    y = y.astype(x.dtype)
+    if "w1s" in lp:
+        from .quant import qdot
+
+        sg = jax.nn.silu(qdot(x, lp["w1s"]))
+        y = y + qdot(sg * qdot(x, lp["w3s"]), lp["w2s"])
+    n_rows = T if valid is None else jnp.sum(valid, dtype=jnp.int32)
+    counts = jnp.stack([
+        jnp.asarray(n_rows, jnp.int32), jnp.sum(sizes), jnp.sum(sizes > 0, dtype=jnp.int32),
+        jnp.max(sizes), jnp.int32(1),
+    ])
+    return y, counts
+
+
 def moe_dispatch(
     cfg: ModelConfig,
     router_logits: jnp.ndarray,
@@ -58,13 +171,7 @@ def moe_dispatch(
     """
     T, E = router_logits.shape
     k = cfg.experts_per_tok
-    probs = jax.nn.softmax(router_logits.astype(jnp.float32), axis=-1)  # [T, E]
-    top_g, top_i = jax.lax.top_k(probs, k)  # [T, k]
-    if cfg.norm_topk_prob and k > 1:
-        top_g = top_g / jnp.sum(top_g, axis=-1, keepdims=True)  # renormalize
-    elif cfg.routed_scaling_factor != 1.0:
-        # DeepSeek-V2 gate convention: raw softmax mass, scaled
-        top_g = top_g * cfg.routed_scaling_factor
+    top_g, top_i = route(cfg, router_logits)  # [T, k]
 
     dispatch = jnp.zeros((T, E, capacity), dtype=jnp.float32)
     combine = jnp.zeros((T, E, capacity), dtype=jnp.float32)
@@ -143,7 +250,7 @@ def init_moe_layer_params(
         ).astype(dtype)
 
     out = {
-        "router": w(keys[0], (L, D, E), D),
+        "router": w(keys[0], (L, D, cfg.router_width), D),
         "w1e": w(keys[1], (L, E, D, F), D),
         "w3e": w(keys[2], (L, E, D, F), D),
         "w2e": w(keys[3], (L, E, F, D), F),

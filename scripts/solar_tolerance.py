@@ -1,0 +1,136 @@
+#!/usr/bin/env python3
+"""The two readings of `SERVED_TOL_REL` in benchmark/references/solar_open2.py,
+through the comparison run.py makes (`benchmark.correctness.hold_to_reference`).
+
+One engine is booted as the configuration's file says. For each seed the
+harness's reference request (`reference_request`: a prompt of that seed, greedy)
+is served, and the served tokens are held
+
+- to the reference as it is: the program's reading, which must come out correct;
+- to the reference computed in each lower precision (`LOWER` there: int8
+  weights and activations, float8, a bfloat16 state): the controls. A control
+  that still comes out correct is a precision the comparison cannot tell from
+  the stated one.
+
+    chiprun -- python3 scripts/solar_tolerance.py --seeds 96 --controls 24
+    python3 scripts/solar_tolerance.py --config tiny --seeds 2 --controls 1   # CPU smoke
+
+Prints one JSON line a seed and a summary; writes both to chiprun_out/.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import statistics
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+CONTROLS = ("int8", "fp8", "state_bf16")
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--config", default="solar-open2-250b-ep8-bf16",
+                    help="a file of benchmark/configs, or `tiny` for tiny-solar on the CPU")
+    ap.add_argument("--seeds", type=int, default=96)
+    ap.add_argument("--controls", type=int, default=24, help="seeds each control is read on")
+    ap.add_argument("--first-seed", type=int, default=3200006000)
+    args = ap.parse_args()
+
+    from benchmark import correctness, run as bench_run, trafficgen
+
+    config = json.load(open(os.path.join(ROOT, "benchmark", "configs",
+                                         "solar-open2-250b-ep8-bf16.json")))
+    env = {k: str(v) for k, v in config["program"]["env"].items()}
+    if args.config == "tiny":
+        env.update(TPU_MODEL="tiny-solar", TPU_MAX_SLOTS="4")
+    os.environ.update(env)
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from llm_mcp_tpu.executor import GenerationEngine
+    from llm_mcp_tpu.utils import config as ucfg
+
+    ucfg.enable_compile_cache()
+    name, module = bench_run.load_reference(config)
+    gen = GenerationEngine(
+        env["TPU_MODEL"], max_slots=int(env["TPU_MAX_SLOTS"]), max_seq_len=int(env["TPU_MAX_SEQ_LEN"]),
+        dtype=jnp.bfloat16, kv_quant=env["TPU_KV_QUANT"], seed=int(config.get("weights_seed", 0)),
+    ).start()
+    n_bytes, n_tokens = correctness.reference_request(config, gen.max_seq_len)
+    mask = gen._allowed_mask
+    allowed = np.arange(gen.cfg.vocab_size) if mask is None else np.flatnonzero(np.asarray(mask))
+
+    def serve(seed: int) -> tuple[list[int], list[int]]:
+        got: dict = {}
+        emit = gen._process_token
+
+        def tap(slot, tok, pos):
+            got.setdefault("ids", list(slot.req.prompt_ids))
+            got.setdefault("out", []).append(int(tok))
+            return emit(slot, tok, pos)
+
+        gen._process_token = tap
+        try:
+            gen.generate(trafficgen.text(n_bytes, seed, "ref"), max_tokens=n_tokens, temperature=0.0)
+        finally:
+            del gen._process_token
+        return got["ids"], got["out"]
+
+    def held(ids, out) -> tuple[float, str]:
+        """(worst regret, "") where the comparison passes; where it refuses,
+        its message and the worst regret by the same formula over all tokens
+        (the comparison stops at the first token over the limit)."""
+        try:
+            return correctness.hold_to_reference(module, gen, ids, out)["worst_regret_rel"], ""
+        except AssertionError as e:
+            seq = ids + out[:-1]
+            rows = np.arange(len(ids) - 1, len(seq))
+            seq = np.asarray(seq + [0] * (-len(seq) % correctness.PAD_TO), np.int32)
+            ref = module.logits(gen.cfg, gen.params, seq, rows, allowed)
+            worst = max(float((np.max(r) - r[np.flatnonzero(allowed == t)[0]]) / np.max(np.abs(r)))
+                        for r, t in zip(ref, out))
+            return worst, str(e)
+
+    os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
+    lines = []
+    served = [serve(args.first_seed + i) for i in range(args.seeds)]
+    for i, (ids, out) in enumerate(served):
+        value, why = held(ids, out)
+        lines.append({"seed": args.first_seed + i, "prompt_tokens": len(ids), "program": value,
+                      "program_refused": why})
+        print(json.dumps(lines[-1]), flush=True)
+    for lower in CONTROLS:
+        module.LOWER = lower
+        jax.clear_caches()
+        for i, (ids, out) in enumerate(served[: args.controls]):
+            value, why = held(ids, out)
+            lines[i][lower], lines[i][lower + "_refused"] = value, why
+            print(json.dumps({"seed": lines[i]["seed"], lower: value, "refused": why}), flush=True)
+    module.LOWER = None
+    jax.clear_caches()
+    gen.shutdown()
+
+    def summary(key: str) -> dict:
+        rows = [r for r in lines if key in r]
+        read = sorted(r[key] for r in rows)
+        return {"seeds": len(rows), "not_correct": sum(bool(r[key + "_refused"]) for r in rows),
+                "min": read[0], "median": statistics.median(read), "max": read[-1]}
+
+    result = {"tolerance": float(module.SERVED_TOL_REL), "reference": name,
+              "request": {"prompt_bytes": n_bytes, "tokens": n_tokens},
+              **{key: summary(key) for key in ("program", *CONTROLS)}}
+    print("SUMMARY", json.dumps(result), flush=True)
+    with open(os.path.join(ROOT, "chiprun_out", "solar_tolerance.json"), "w") as f:
+        json.dump({"summary": result, "seeds": lines}, f)
+    return 0 if result["program"]["not_correct"] == 0 else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

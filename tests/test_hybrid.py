@@ -1,0 +1,461 @@
+"""The hybrid decoder (KDA linear-attention layers with a per-slot recurrent
+state, gated NoPE GQA layers, a dropless share of the routed experts) at the
+tiny preset of the published shape, held to the plain reference
+`benchmark/references/solar_open2.py` on seeded float32 weights: logits, not
+tokens, through every path a sequence can take; and the engine's slot
+life-cycle around the state pool."""
+
+import dataclasses
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from llm_mcp_tpu.kernels.kda import kda_decode_step, kda_decode_step_reference
+from llm_mcp_tpu.models import moe
+from llm_mcp_tpu.models.configs import get_config
+from llm_mcp_tpu.models.kda import kda_chunk_scan
+from llm_mcp_tpu.models.llama import (
+    init_kv_cache,
+    init_llama_params,
+    llama_decode_step,
+    llama_prefill,
+    llama_prefill_chunk_batch,
+)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+TOL = 1e-4  # float32 against float32, of logits whose largest is about 4
+
+
+@pytest.fixture(scope="module")
+def ref():
+    spec = importlib.util.spec_from_file_location(
+        "solar_open2", os.path.join(ROOT, "benchmark", "references", "solar_open2.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def model(ref):
+    """(cfg, params, tokens [96], the reference's logits at every position)."""
+    with jax.default_matmul_precision("highest"):
+        cfg = get_config("tiny-solar")
+        params = init_llama_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+        toks = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (96,), 3, 500))
+        want = ref.logits(cfg, params, toks, np.arange(96), np.arange(cfg.vocab_size))
+    return cfg, params, toks, want
+
+
+@pytest.fixture(autouse=True)
+def _float32_products():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+def test_the_reference_shares_no_code_with_the_program():
+    src = open(os.path.join(ROOT, "benchmark", "references", "solar_open2.py")).read()
+    assert "llm_mcp_tpu" not in src.split('"""', 2)[2]  # the docstring names the files
+
+
+def test_full_prefill_of_rows_of_unlike_lengths(model):
+    cfg, params, toks, want = model
+    batch = np.zeros((4, 64), np.int32)
+    lengths = [50, 30, 64, 1]
+    for i, n in enumerate(lengths):
+        batch[i, :n] = toks[:n]
+    logits, ks, vs = llama_prefill(cfg, params, jnp.asarray(batch), jnp.asarray(lengths))
+    for i, n in enumerate(lengths):
+        assert np.max(np.abs(np.asarray(logits[i]) - want[n - 1])) < TOL, (i, n)
+    assert ks.shape[0] == cfg.n_attn_layers == 1  # one GQA layer owns cache rows
+    assert vs["state"]["S"].shape == (3, 4, 4, 32, 32)  # three KDA layers, float32
+    # a row's state is its own prompt's, whatever the bucket holds behind it
+    again, _, vs2 = llama_prefill(
+        cfg, params, jnp.asarray(batch[:1, :]), jnp.asarray(lengths[:1]))
+    assert np.allclose(vs2["state"]["S"][:, 0], vs["state"]["S"][:, 0], atol=1e-5)
+
+
+def _used_cache(cfg, slots=4, seq=128):
+    """A cache whose every slot was used: state and tails hold another
+    sequence's leftovers, which a fresh admission must never read."""
+    cache = init_kv_cache(cfg, slots, seq, dtype=jnp.float32)
+    state = cache["v"]["state"]
+    cache["v"]["state"] = dict(state, S=state["S"] + 7.0, conv=state["conv"] - 3.0)
+    return cache["k"], cache["v"]
+
+
+def test_two_chunks_then_decode_through_cache_and_state_in_a_reused_slot(model):
+    cfg, params, toks, want = model
+    ck, cv = _used_cache(cfg)
+    slot, S = 2, 128
+    for start, n in ((0, 32), (32, 18)):  # the second chunk ragged, padded to 32
+        chunk = np.zeros((1, 32), np.int32)
+        chunk[0, :n] = toks[start : start + n]
+        logits, ck, cv = llama_prefill_chunk_batch(
+            cfg, params, ck, cv, jnp.asarray(chunk), jnp.array([slot]), jnp.array([start]),
+            jnp.array([n]), skey=32)
+    assert np.max(np.abs(np.asarray(logits[0]) - want[49])) < TOL
+    lens = np.full(4, S, np.int32)  # the other slots are parked
+    lens[slot] = 50
+    before = np.asarray(cv["state"]["S"][:, 0])
+    for t in range(50, 60):  # the full batch, one live row
+        tok = np.zeros(4, np.int32)
+        tok[slot] = toks[t]
+        logits, ck, cv = llama_decode_step(cfg, params, ck, cv, jnp.asarray(tok), jnp.asarray(lens))
+        assert np.max(np.abs(np.asarray(logits[slot]) - want[t])) < TOL, t
+        lens[slot] += 1
+    for t in range(60, 70):  # a compact batch: row 0 serves the slot, row 1 is a pad
+        logits, ck, cv = llama_decode_step(
+            cfg, params, ck, cv, jnp.array([toks[t], 0]), jnp.array([lens[slot], S]),
+            slot_ids=jnp.array([slot, 0]))
+        assert np.max(np.abs(np.asarray(logits[0]) - want[t])) < TOL, t
+        lens[slot] += 1
+    assert np.array_equal(np.asarray(cv["state"]["S"][:, 0]), before)  # a parked row never moves
+    counts = np.asarray(cv["moe"])
+    assert counts[0, :, 0].tolist() == [20] * 4 and counts[0, :, 4].tolist() == [20] * 4
+    assert counts[1, :, 0].tolist() == [50] * 4  # the chunks' valid rows, no padding
+
+
+def test_chunk_rows_that_duplicate_row_0_write_what_row_0_writes(model):
+    cfg, params, toks, want = model
+    ck, cv = _used_cache(cfg)
+    chunk = np.tile(toks[None, :32], (2, 1)).astype(np.int32)
+    logits, ck, cv = llama_prefill_chunk_batch(
+        cfg, params, ck, cv, jnp.asarray(chunk), jnp.array([1, 1]), jnp.array([0, 0]),
+        jnp.array([32, 32]), skey=32)
+    assert np.max(np.abs(np.asarray(logits) - want[31])) < TOL
+
+
+def test_chunked_form_is_the_token_by_token_recurrence():
+    A, T, H, d = 2, 64, 3, 16
+    ks = jax.random.split(jax.random.PRNGKey(3), 6)
+    q, k, v = (jax.random.normal(ks[i], (A, T, H, d)) for i in range(3))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    # decays from almost none to e**-12 a step: no quotient of exponentials survives that
+    g = -jnp.exp(jax.random.uniform(ks[3], (A, T, H, d), minval=-7.0, maxval=2.5))
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (A, T, H)))
+    S = S0 = jax.random.normal(ks[5], (A, H, d, d))
+    outs = []
+    for t in range(T):
+        S = S * jnp.exp(g[:, t])[..., None]
+        u = beta[:, t][..., None] * (v[:, t] - jnp.einsum("ahk,ahkv->ahv", k[:, t], S))
+        S = S + k[:, t][..., None] * u[..., None, :]
+        outs.append(jnp.einsum("ahk,ahkv->ahv", q[:, t], S))
+    o, S_end = jax.jit(kda_chunk_scan)(q, k, v, g, beta, S0)
+    assert np.isfinite(np.asarray(o)).all()
+    assert np.max(np.abs(np.asarray(o) - np.asarray(jnp.stack(outs, 1)))) < 1e-4
+    assert np.max(np.abs(np.asarray(S_end) - np.asarray(S))) < 1e-4
+
+
+@pytest.mark.parametrize("heads", [4, 32])  # one cell of heads, and two
+def test_state_kernel_steps_live_rows_in_place_and_leaves_the_rest(heads):
+    Lk, B, Ba, d = 3, 6, 4, 32
+    ks = jax.random.split(jax.random.PRNGKey(5), 6)
+    state = jax.random.normal(ks[0], (Lk, B, heads, d, d))
+    q, k, v = (jax.random.normal(ks[i], (Ba, heads, d)) for i in (1, 2, 3))
+    alpha = jax.nn.sigmoid(jax.random.normal(ks[4], (Ba, heads, d)))
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[5], (Ba, heads)))
+    ids = jnp.array([4, 1, 5, 5])  # two pads on one free row
+    live = jnp.array([True, True, False, False])
+    o_r, s_r = kda_decode_step_reference(state, jnp.int32(1), ids, live, q, k, v, alpha, beta)
+    o_k, s_k = kda_decode_step(state, jnp.int32(1), ids, live, q, k, v, alpha, beta, interpret=True)
+    assert np.allclose(np.asarray(o_r[:2]), np.asarray(o_k[:2]), rtol=1e-5, atol=1e-5)
+    assert np.allclose(np.asarray(s_r), np.asarray(s_k), rtol=1e-5, atol=1e-5)
+    untouched = np.asarray(s_k) == np.asarray(state)
+    assert untouched[0].all() and untouched[2].all() and untouched[1, [0, 2, 3, 5]].all()
+    assert not untouched[1, 1].all() and not untouched[1, 4].all()
+
+
+def test_eight_shares_and_the_shared_expert_once_are_the_uncut_layer(ref):
+    """Guide section 4: each member of the group routes over all 16 experts and
+    adds its own 2; the eight parts and ONE shared expert are the whole layer."""
+    cfg = dataclasses.replace(get_config("tiny-solar"), n_experts=2)
+    whole = dataclasses.replace(cfg, n_experts=16, n_router_experts=0)
+    lp = {k: v[0] for k, v in moe.init_moe_layer_params(whole, jax.random.PRNGKey(7), jnp.float32, 1).items()}
+    lp["router_bias"] = 0.1 * jax.random.normal(jax.random.PRNGKey(8), (16,))
+    x = jax.random.normal(jax.random.PRNGKey(9), (24, cfg.dim))
+    want = ref._experts(whole, {k: v[None] for k, v in lp.items()}, jnp.int32(0), x)
+    total = jnp.zeros_like(x)
+    pairs = 0
+    for rank in range(8):
+        mine = slice(2 * rank, 2 * rank + 2)
+        share = {
+            # this member's experts first among the router's columns: the
+            # program holds experts [0, E) of what its router scores
+            "router": jnp.roll(lp["router"], -2 * rank, axis=1),
+            "router_bias": jnp.roll(lp["router_bias"], -2 * rank),
+            **{k: lp[k][mine] for k in ("w1e", "w3e", "w2e")},
+        }
+        if rank == 0:  # the shared expert is replicated over the group: counted once
+            share.update({k: lp[k] for k in ("w1s", "w3s", "w2s")})
+        part, counts = moe.moe_share_ffn(cfg, share, x)
+        total, pairs = total + part, pairs + int(counts[1])
+    assert pairs == 24 * cfg.experts_per_tok  # every pair landed on exactly one member
+    assert np.max(np.abs(np.asarray(total) - np.asarray(want))) < 1e-5
+
+
+@pytest.mark.parametrize("rows,padded", [(24, 0), (64, 13), (1, 0)])
+def test_dropless_layer_is_the_capacity_layer_at_capacity_t(rows, padded):
+    """DeepSeek-V2's softmax path keeps its meaning: the dropless layer with
+    every expert held equals `moe_ffn` when nothing can be dropped."""
+    cfg = get_config("tiny-v2")
+    lp = {k: v[0] for k, v in moe.init_moe_layer_params(cfg, jax.random.PRNGKey(0), jnp.float32, 1).items()}
+    x = jax.random.normal(jax.random.PRNGKey(rows), (rows, cfg.dim))
+    valid = jnp.arange(rows) < rows - padded if padded else None
+    old = moe.moe_ffn(cfg, lp, x, capacity=rows, valid=valid)
+    new, counts = moe.moe_share_ffn(cfg, lp, x, valid=valid)
+    keep = np.ones(rows, bool) if valid is None else np.asarray(valid)
+    assert np.max(np.abs(np.asarray(old) - np.asarray(new))[keep]) < 1e-5
+    assert counts.tolist()[:2] == [rows - padded, (rows - padded) * cfg.experts_per_tok]
+    # and with the banks stacked over layers, as the layer scan hands them over
+    banks = {k: jnp.stack([jnp.zeros_like(lp[k]), lp[k], jnp.ones_like(lp[k])]) for k in ("w1e", "w3e", "w2e")}
+    stacked, _ = moe.moe_share_ffn(cfg, lp, x, valid=valid, banks=banks, layer=jnp.int32(1))
+    assert np.max(np.abs(np.asarray(stacked) - np.asarray(new))[keep]) < 1e-5
+
+
+# -- the engine's slot life-cycle around the state pool --------------------------------
+
+
+@pytest.fixture(scope="module")
+def engine():
+    from llm_mcp_tpu.executor import GenerationEngine
+
+    eng = GenerationEngine("tiny-solar", max_slots=2, max_seq_len=128, dtype=jnp.float32,
+                           prefill_chunk=32, prompt_cache_mb=64).start()
+    yield eng
+    eng.shutdown()
+
+
+def _serve(eng, prompt, n=10):
+    """(prompt ids, emitted ids, slot) of one greedy request, tapped where the engine emits."""
+    got = {}
+    emit = eng._process_token
+
+    def tap(slot, tok, pos):
+        got.setdefault("ids", list(slot.req.prompt_ids))
+        got.setdefault("out", []).append(int(tok))
+        return emit(slot, tok, pos)
+
+    eng._process_token = tap
+    try:
+        eng.generate(prompt, max_tokens=n, temperature=0.0)
+    finally:
+        del eng._process_token
+    return got["ids"], got["out"]
+
+
+def test_engine_serves_the_references_choice_in_fresh_and_reused_slots(engine, ref):
+    """Whole-prompt admission (under the chunk), chunked admission (over it),
+    and again: with 2 slots the later requests land in used slots."""
+    allowed = np.flatnonzero(np.asarray(engine._allowed_mask))
+    prompts = ["amber basil", "x" * 70 + " cedar dune ember", "y" * 45, "fjord grove " * 6]
+    for prompt in prompts:
+        ids, out = _serve(engine, prompt)
+        seq = ids + out[:-1]
+        rows = np.arange(len(ids) - 1, len(seq))
+        seq = np.asarray(seq + [0] * (-len(seq) % 32), np.int32)
+        want = ref.logits(engine.cfg, engine.params, seq, rows, allowed)
+        for k, tok in enumerate(out):
+            regret = float(np.max(want[k]) - want[k, np.flatnonzero(allowed == tok)[0]])
+            assert regret < 1e-3, (prompt[:12], k, regret)
+    pool = engine.perf_stats()["state_pool"]
+    assert pool["admitted_total"] == len(prompts) > pool["slots"]
+    assert pool["live_slots"] == 0 and pool["bytes"] == pool["bytes_per_slot"] * 2
+    decode, prefill = np.asarray(engine.perf_stats()["experts"]["counts"])
+    assert (decode[:, 4] > 0).all() and (prefill[:, 4] > 0).all()
+    assert (decode[:, 1] <= decode[:, 0] * engine.cfg.experts_per_tok).all()
+
+
+def test_a_chunked_prefill_rides_decode_rounds_without_touching_their_state(engine, ref):
+    """The fused step program: one slot decodes while two long prompts prefill
+    chunk by chunk in the same dispatches, carrying their state from chunk to
+    chunk; every stream's tokens stay the reference's own choice."""
+    import threading
+    import time
+
+    served = {}
+    emit = engine._process_token
+
+    def tap(slot, tok, pos):
+        served.setdefault(slot.req.request_id, (list(slot.req.prompt_ids), []))[1].append(int(tok))
+        return emit(slot, tok, pos)
+
+    engine._process_token = tap
+    try:
+        jobs = [threading.Thread(target=engine.generate, args=(p,),
+                                 kwargs={"max_tokens": n, "temperature": 0.0})
+                for p, n in (("short one", 100), ("z" * 90 + " long, in chunks", 12))]
+        jobs[0].start()
+        while not served or len(next(iter(served.values()))[1]) < 4:
+            time.sleep(0.005)  # the short one is decoding when the long one arrives
+        jobs[1].start()
+        for j in jobs:
+            j.join()
+    finally:
+        del engine._process_token
+    assert "fused" in {r["phase"] for r in engine._ledger.table()}
+    allowed = np.flatnonzero(np.asarray(engine._allowed_mask))
+    assert len(served) == 2
+    for ids, out in served.values():
+        seq = ids + out[:-1]
+        rows = np.arange(len(ids) - 1, len(seq))
+        want = ref.logits(engine.cfg, engine.params,
+                          np.asarray(seq + [0] * (-len(seq) % 32), np.int32), rows, allowed)
+        for k, tok in enumerate(out):
+            assert float(np.max(want[k]) - want[k, np.flatnonzero(allowed == tok)[0]]) < 1e-3
+
+
+def test_a_recurrent_configuration_never_shares_drafts_or_offloads(engine, monkeypatch):
+    """Prefix cache, speculation, offload and migration are off, decided from
+    the configuration where the state pool is built, each with its counter."""
+    shared = "the same long system prompt, word for word, " * 2
+    before = dict(engine.perf_stats()["state_pool"]["off"])
+    for tail in ("one", "two", "three", "four"):  # the third sharer would pin a prefix
+        engine.generate(shared + tail, max_tokens=6, temperature=0.0)
+    off = engine.perf_stats()["state_pool"]["off"]
+    assert engine.prefix_cache_hits == 0 and not engine._prefix_cache and engine._prefix_budget == 0
+    assert off["prefix_cache"] - before["prefix_cache"] == 4
+    assert engine._verify_fn is None and engine.spec_drafted == 0
+    assert off["speculation"] > before["speculation"]
+    assert engine._pool is None and engine._phys is None and not engine.ragged_prefill
+    assert engine.memory_stats().get("preempted_total", 0) == 0
+    with pytest.raises(RuntimeError, match="recurrent state"):
+        engine.migrate_import(b"")
+    assert engine.migrate_export_one() is None
+    assert engine.perf_stats()["state_pool"]["off"]["migration"] == before["migration"] + 2
+
+
+def test_the_switches_that_turn_the_features_on_do_not_for_a_recurrent_configuration(monkeypatch):
+    from llm_mcp_tpu.executor import GenerationEngine
+
+    monkeypatch.setenv("TPU_KV_HOST_OFFLOAD", "1")
+    monkeypatch.setenv("TPU_MIGRATE", "1")
+    monkeypatch.setenv("TPU_SPEC", "1")
+    eng = GenerationEngine("tiny-solar", max_slots=2, max_seq_len=64, dtype=jnp.float32)
+    assert eng._pool is None and eng._migrate_in is None and eng._verify_fn is None
+    assert GenerationEngine("tiny-llm", max_slots=2, max_seq_len=64,
+                            dtype=jnp.float32)._state_pool is None
+
+
+def test_a_mesh_is_refused_for_a_recurrent_configuration():
+    from jax.sharding import Mesh
+
+    from llm_mcp_tpu.executor import GenerationEngine
+
+    mesh = Mesh(np.asarray(jax.devices()[:2]).reshape(2), ("tp",))
+    with pytest.raises(NotImplementedError, match="one chip"):
+        GenerationEngine("tiny-solar", mesh=mesh, max_slots=2, max_seq_len=64)
+
+
+# -- what the review of PR 32 asked to be held ------------------------------------------
+
+
+def test_the_features_a_recurrent_configuration_runs_without_come_from_one_list(engine):
+    """`memory.RECURRENT_OFF` names them, `engine._runs` answers from it, and
+    the pool keeps a counter for each; the file of the configuration holds the
+    precisions a later change might lower (`program.expect`)."""
+    from llm_mcp_tpu.executor import GenerationEngine
+    from llm_mcp_tpu.executor.memory import RECURRENT_OFF
+
+    assert set(engine.perf_stats()["state_pool"]["off"]) == set(RECURRENT_OFF)
+    assert not any(engine._runs(f) for f in RECURRENT_OFF) and engine._runs("chunked_prefill")
+    dense = GenerationEngine("tiny-llm", max_slots=2, max_seq_len=64, dtype=jnp.float32)
+    assert all(dense._runs(f) for f in RECURRENT_OFF)
+    assert (engine.state_dtype, dense.state_dtype, dense.expert_dtype) == ("float32", "", "")
+    assert engine.expert_dtype == "float32"  # this engine's weights; bfloat16 as the cell boots it
+
+
+@pytest.mark.parametrize("lengths,joins", [
+    ([20], True),  # 2 rows x 32: the budget of 64, to the token
+    ([20, 20], False),  # a third prompt pads the program to 4 rows x 32
+    ([40], False),  # 2 rows x 48
+    ([], True),  # alone: always
+])
+def test_an_admit_program_pads_to_no_more_than_a_chunk_of_prefill(lengths, joins):
+    """What stands between two decode rounds is bounded by `prefill_chunk`
+    tokens, for several whole prompts in one admit program as for a chunked
+    prefill; the warm-up plan lists no admit shape beyond it."""
+    from llm_mcp_tpu.executor import GenerationEngine
+
+    eng = GenerationEngine("tiny-solar", max_slots=4, max_seq_len=128, dtype=jnp.float32,
+                           prefill_chunk=64)
+    batch = [(i, None, [1] * n) for i, n in enumerate(lengths)]
+    assert eng._admit_tokens_max() == 64
+    assert (not batch or not eng._over_admit_budget(batch, [1] * 20)) == joins
+    assert not eng._over_admit_budget([(0, None, [1] * 60)], [1] * 100)  # chunked: joins no batch
+    admits = [key for phase, key in eng.warmup_shape_zoo() if phase == "admit"]
+    assert (1, 64) in admits and (2, 32) in admits
+    assert all(rows * bucket <= 64 for rows, bucket in admits)
+    unbounded = GenerationEngine("tiny-solar", max_slots=4, max_seq_len=128, dtype=jnp.float32,
+                                 prefill_chunk=0)
+    assert unbounded._admit_tokens_max() == 0
+    assert not unbounded._over_admit_budget([(0, None, [1] * 100)] * 3, [1] * 100)
+
+
+def test_prompts_over_the_admit_budget_wait_a_round_and_keep_their_order(ref):
+    """Four prompts arrive at once where the budget holds two rows of 32: no
+    admit program is dispatched beyond it, and each stream is still the
+    reference's own choice, token for token."""
+    import threading
+
+    from llm_mcp_tpu.executor import GenerationEngine
+
+    eng = GenerationEngine("tiny-solar", max_slots=4, max_seq_len=128, dtype=jnp.float32,
+                           prefill_chunk=64).start()
+    served = {}
+    emit = eng._process_token
+
+    def tap(slot, tok, pos):
+        served.setdefault(slot.req.request_id, (list(slot.req.prompt_ids), []))[1].append(int(tok))
+        return emit(slot, tok, pos)
+
+    eng._process_token = tap
+    try:
+        jobs = [threading.Thread(target=eng.generate, args=(f"prompt {k} " + "ab" * k,),
+                                 kwargs={"max_tokens": 6, "temperature": 0.0}) for k in range(4)]
+        for j in jobs:
+            j.start()
+        for j in jobs:
+            j.join()
+        shapes = [r["key"] for r in eng._ledger.table() if r["phase"] == "admit"]
+    finally:
+        del eng._process_token
+        eng.shutdown()
+    assert shapes and all(int(k.split(":")[0]) * int(k.split(":")[1]) <= 64 for k in shapes), shapes
+    allowed = np.flatnonzero(np.asarray(eng._allowed_mask))
+    assert len(served) == 4
+    for ids, out in served.values():
+        seq = ids + out[:-1]
+        rows = np.arange(len(ids) - 1, len(seq))
+        want = ref.logits(eng.cfg, eng.params,
+                          np.asarray(seq + [0] * (-len(seq) % 32), np.int32), rows, allowed)
+        for k, tok in enumerate(out):
+            assert float(np.max(want[k]) - want[k, np.flatnonzero(allowed == tok)[0]]) < 1e-3
+
+
+def test_the_harness_comparison_passes_the_program_and_refuses_a_float8_reference(ref):
+    """scripts/solar_tolerance.py's two readings at the tiny size, through
+    `correctness.hold_to_reference`: the served tokens are the reference's own
+    choice; held to the reference computed in float8 they are not correct."""
+    from benchmark import correctness
+    from llm_mcp_tpu.executor import GenerationEngine
+
+    eng = GenerationEngine("tiny-solar", max_slots=2, max_seq_len=256, dtype=jnp.float32).start()
+    try:
+        ids, out = _serve(eng, "hold these sixteen tokens to the plain forward, " * 2, n=16)[:2]
+        assert correctness.hold_to_reference(ref, eng, ids, out)["worst_regret_rel"] < 1e-3
+        for lower, refused in (("state_bf16", False), ("fp8", True)):
+            ref.LOWER = lower
+            jax.clear_caches()
+            if refused:
+                with pytest.raises(AssertionError, match="under the reference's choice"):
+                    correctness.hold_to_reference(ref, eng, ids, out)
+            else:
+                assert correctness.hold_to_reference(ref, eng, ids, out)["worst_regret_rel"] < 0.1
+    finally:
+        ref.LOWER = None
+        jax.clear_caches()
+        eng.shutdown()

@@ -311,6 +311,113 @@ def test_decode_step_reads_stacked_weights_in_place(
     assert temp < (256 << 20) + sum(w.size * w.dtype.itemsize for w in whole)
 
 
+# -- the hybrid decoder's step programs at the published Solar-Open2 widths ---------
+
+SOLAR_SLOTS, SOLAR_S = 64, 1024
+
+
+def test_kda_decode_step(sd):
+    """The one-step state kernel alone: 64 rows of 64 heads of [128, 128]
+    float32, three layers in the pool, rows found through the slot ids."""
+    import functools
+
+    from llm_mcp_tpu.kernels.kda import kda_decode_step
+
+    F32, rows, H, d = jnp.float32, 64, 64, 128
+    vec = sd((rows, H, d), F32)
+    compile_for_chip(
+        functools.partial(kda_decode_step, interpret=False), sd((3, SOLAR_SLOTS, H, d, d), F32), sd((), I32),
+        sd((rows,), I32), sd((rows,), jnp.bool_), vec, vec, vec, vec, sd((rows, H), F32),
+        donate_argnums=(0,))
+
+
+@pytest.fixture(scope="module")
+def solar(one_chip):
+    """(cfg, params, cache) of `solar-open2-250b-ep8` as shapes on the described
+    chip: bf16 weights, int8 KV for the one GQA layer, 64 slots x 1024, the
+    float32 state pool beside it."""
+    from functools import partial
+
+    from llm_mcp_tpu.models import llama
+    from llm_mcp_tpu.models.configs import get_config
+
+    cfg = get_config("solar-open2-250b-ep8")
+    params = jax.eval_shape(partial(llama.init_llama_params, cfg, jax.random.PRNGKey(0), dtype=BF))
+    cache = jax.eval_shape(partial(
+        llama.init_kv_cache, cfg, SOLAR_SLOTS, SOLAR_S, dtype=BF, quantized=True))
+    return (cfg, *jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), (params, cache)))
+
+
+def solar_program(which: str, cfg):
+    """The three step programs the cell dispatches, as the engine builds them
+    (`decode_body`'s scan of 4 steps; `admit_fn`'s prefill and row inserts; the
+    bucketed chunk), without the sampler."""
+    from llm_mcp_tpu.models import hybrid, llama
+
+    def decode(params, ck, cv, tokens, lengths, ids):
+        def step(carry, _):
+            ck, cv, toks, lens = carry
+            logits, ck, cv = llama.llama_decode_step(
+                cfg, params, ck, cv, toks, lens, attn_impl="pallas", slot_ids=ids)
+            return (ck, cv, jnp.argmax(logits, axis=-1).astype(I32), lens + 1), None
+
+        (ck, cv, toks, _), _ = jax.lax.scan(step, (ck, cv, tokens, lengths), None, length=4)
+        return toks, ck, cv
+
+    def admit(params, ck, cv, tokens, lengths, slots):
+        logits, ks, vs = llama.llama_prefill(
+            cfg, params, tokens, lengths, attn_impl="pallas", quant_kv=True)
+
+        def body(i, cc):
+            ck, cv = cc
+            ck = {"q": jax.lax.dynamic_update_slice(
+                      ck["q"], jax.lax.dynamic_slice_in_dim(ks["q"], i, 1, 1), (0, slots[i], 0, 0, 0)),
+                  "s": jax.lax.dynamic_update_slice(
+                      ck["s"], jax.lax.dynamic_slice_in_dim(ks["s"], i, 1, 1), (0, slots[i], 0, 0))}
+            return ck, dict(cv, state=hybrid.insert_state_row(cv["state"], vs["state"], i, slots[i]))
+
+        ck, cv = jax.lax.fori_loop(0, tokens.shape[0], body, (ck, cv))
+        return logits, ck, hybrid.add_counts(cv, vs)
+
+    def chunk(params, ck, cv, tokens, slots, starts, nvalid):
+        return llama.llama_prefill_chunk_batch(
+            cfg, params, ck, cv, tokens, slots, starts, nvalid, skey=512)
+
+    return {"decode": decode, "admit": admit, "chunk": chunk}[which]
+
+
+@pytest.mark.parametrize("which,operands", [
+    ("decode", [(64,), (64,), (64,)]),  # every slot a row
+    ("admit", [(4, 256), (4,), (4,)]),  # four prompts in the 256 bucket
+    ("chunk", [(2, 512), (2,), (2,), (2,)]),  # two prompts' second chunks of 512
+])
+def test_solar_step_programs_fit_and_keep_state_and_banks_in_place(
+    sd, solar, chip_kernels, which, operands
+):
+    """Each compiles for the described v5e with its kernels (the state kernel
+    and the attention kernels in decode; the grouped expert products are the
+    compiler's own kernels), fits the chip, and makes no second copy of the
+    state pool (0.75 GiB) nor of an expert bank (a slice of a stacked bank that
+    feeds a grouped product was copied out, 0.39 GiB a bank and layer, until the
+    banks went in whole: models/moe.py). Bytes in PERF.md section 4 as
+    "described-chip compile"."""
+    cfg, params, cache = solar
+    compiled = jax.jit(solar_program(which, cfg), donate_argnums=(1, 2)).lower(
+        params, cache["k"], cache["v"], *(sd(shape, I32) for shape in operands)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("kda_decode_step" in text) == (which == "decode")
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"solar {which}: {total / 2**30:.2f} GiB, of it temporaries "
+          f"{mem.temp_size_in_bytes / 2**30:.2f} GiB")
+    assert total < 15.75 * 2**30
+    assert mem.temp_size_in_bytes < 0.7 * 2**30
+    assert mem.alias_size_in_bytes > 0.9 * 2**30  # KV cache and state pool updated in place
+
+
 def test_a_fall_to_the_reference_is_counted(tmp_path):
     """What `compile_for_chip` and chip_smoke.py's zero-fall check stand on: a
     shape gate that fails with interpret=False is counted and lands in the
