@@ -58,6 +58,7 @@ from ..kernels.attention import (
 from ..utils.faults import maybe_fail
 from ..utils.platform import on_tpu
 from ..models.configs import ModelConfig, resolve_config
+from ..models.moe import share_form
 from ..models.weights import load_llama_checkpoint
 from ..models.llama import (
     init_llama_params,
@@ -1608,6 +1609,9 @@ class GenerationEngine:
         # shed a healthy engine mid-compile; the cost is that a real wedge
         # during that window is detected one timeout later.
         self._seen_exec_shapes: set[tuple] = set()
+        # (phase, ledger key) of the plannable shapes whose first real
+        # dispatch has RETURNED: the warm-up plan skips them (warmup.py)
+        self._served_shapes: set[tuple[str, str]] = set()
         self._compile_grace_until = 0.0
         # Warmup planner (executor/warmup.py): built by start_warmup() at
         # boot (serving entrypoints), None on the plain
@@ -2709,6 +2713,8 @@ class GenerationEngine:
             self.warmup_compile, steps,
             throttle_s=float(os.environ.get("TPU_WARMUP_THROTTLE_S", "0.05") or 0),
             event=self._flight.event,
+            served=lambda phase, key: (
+                (phase, warmup_mod.key_str(key)) in self._served_shapes),
         )
         self._warmup.run_critical()
         if warmup_mod.warmup_bg_enabled():
@@ -3155,6 +3161,13 @@ class GenerationEngine:
             self._watchdog_transition("compile_grace")
         return True
 
+    def _note_expert_form(self, phase: str, rows: int, steps: int = 1) -> None:
+        """Count the expert layer's calls of a step program about to go out by
+        the form they take: the row count the program was traced at decides
+        (models/moe.py:share_form), so the host knows it without the device."""
+        if self._experts is not None:
+            self._experts.dispatched(phase, share_form(rows), steps)
+
     def _watchdog_transition(self, state: str) -> None:
         """Count a watchdog/compile-grace state transition and journal it:
         `llmtpu_watchdog_transitions_total{state=...}` + a recorder event,
@@ -3168,18 +3181,23 @@ class GenerationEngine:
         self._flight.event("watchdog", state=state)
 
     def _compile_obs(self, phase: str, key: tuple, wall_s: float,
-                     src: str = "serve") -> None:
+                     src: str = "serve", planned: bool = True) -> None:
         """First dispatch of an executable shape → compile ledger entry +
         recorder event (the ROADMAP item-5 cold-start measurement).
         `src` is provenance: "serve" for real dispatches, "warmup" for the
         planner's AOT compiles — /v1/debug/compiles shows whether the
-        serve path ever ate a cold compile warmup should have absorbed."""
+        serve path ever ate a cold compile warmup should have absorbed.
+        `planned=False`: the program dispatched is not the one a plan step
+        of this key compiles (a constrained admission's), so the plan must
+        not take the shape for served."""
         ks = ":".join(str(p) for p in key)
         e = self._ledger.observe(
             phase, ks, wall_s, src=src, parts=compile_watch.end()
         )
         if src == "serve":
             self._first_end = time.perf_counter()
+            if planned:
+                self._served_shapes.add((phase, ks))
         self._flight.event(
             "compile", phase=phase, key=ks,
             wall_ms=round(wall_s * 1e3, 1), hit=e["hit"],
@@ -5288,6 +5306,7 @@ class GenerationEngine:
         # ONE fused dispatch: prefill + cache inserts + device sampling-param
         # rows + first-token sample (see admit_fn)
         first = self._note_exec_shape("admit", Ab, bucket, cn_payload is not None)
+        self._note_expert_form("prefill", Ab * bucket)
         t0c = time.perf_counter()
         toks0 = self._dx("admit", tokens, ipack, fpack, cn_payload)
         t_call = time.perf_counter()  # jit returned; device running
@@ -5295,7 +5314,8 @@ class GenerationEngine:
             # jit traces and compiles inside the call: the wall up to its
             # return is the compile's, and the ledger's context closes here,
             # before another first dispatch can open its own
-            self._compile_obs("admit", (Ab, bucket), t_call - t0c)
+            self._compile_obs("admit", (Ab, bucket), t_call - t0c,
+                              planned=cn_payload is None)
         return _DispatchedAdmit(
             toks0=toks0,
             entries=[
@@ -5718,6 +5738,7 @@ class GenerationEngine:
             first = self._note_exec_shape("chunk", group.tokens.shape[0],
                                           group.bucket, group.skey,
                                           self._phys is not None)
+            self._note_expert_form("prefill", group.tokens.shape[0] * group.bucket)
             t0 = time.perf_counter()
             self._gid_ctr += 1
             group.gid = self._gid_ctr
@@ -6231,6 +6252,7 @@ class GenerationEngine:
                 [self._lengths, [self._next_counter()]]
             ).astype(np.int32)
         base = self._lengths.copy()
+        self._note_expert_form("decode", Ba, self.decode_chunk)
         if group is not None:
             maybe_fail(
                 "engine.prefill", f"slots={[s for s, _, _ in group.metas]}"
@@ -6261,6 +6283,7 @@ class GenerationEngine:
                     "fused", Ba, compact, group.tokens.shape[0],
                     group.bucket, group.skey, self._phys is not None,
                 )
+                self._note_expert_form("prefill", group.tokens.shape[0] * group.bucket)
                 t0c = time.perf_counter()
                 self._gid_ctr += 1
                 group.gid = self._gid_ctr

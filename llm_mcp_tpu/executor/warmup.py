@@ -90,8 +90,9 @@ def key_str(key: tuple) -> str:
 @dataclass
 class WarmupStep:
     """One executable shape in the plan. `status` lifecycle:
-    pending -> done (compiled, wall recorded) | skip (phase unplannable
-    or planner stopped) | fail (compile_fn raised)."""
+    pending -> done (compiled, wall recorded) | skip (phase unplannable,
+    planner stopped, or the serve path dispatched the shape first) | fail
+    (compile_fn raised)."""
 
     phase: str
     key: tuple
@@ -239,7 +240,16 @@ class WarmupPlanner:
     compile wall in seconds, or None when the phase cannot be AOT-compiled
     (the step records as `skip` — it will compile on first real dispatch,
     exactly the pre-warmup behavior). Exceptions record as `fail` and
-    never propagate: warmup is an accelerant, not a gate."""
+    never propagate: warmup is an accelerant, not a gate.
+
+    `served(phase, key)` says whether the serve path has ALREADY first-
+    dispatched that shape: the step then records as `skip` too. jit's own
+    cache holds that executable and the persistent cache got it on the way,
+    so lowering it again buys nothing, and it costs seconds of Python
+    tracing under the GIL the serving threads need (v5e, PR 34: a cell's
+    warm-up traffic first-dispatched 9 of its plan's 35 shapes, 4 of them
+    before the plan's thread reached them, at 2.3-3.4 s a step). `fully_warm`
+    keeps its meaning: every shape of the plan is compiled or already served."""
 
     def __init__(
         self,
@@ -248,8 +258,10 @@ class WarmupPlanner:
         *,
         throttle_s: float = 0.0,
         event: Callable[..., Any] | None = None,
+        served: Callable[[str, tuple], bool] | None = None,
     ):
         self._compile_fn = compile_fn
+        self._served = served
         self.steps = list(steps)
         self.throttle_s = max(0.0, float(throttle_s))
         self._event = event
@@ -294,7 +306,10 @@ class WarmupPlanner:
     def _run_step(self, step: WarmupStep) -> None:
         t0 = time.perf_counter()
         try:
-            wall = self._compile_fn(step.phase, step.key)
+            if self._served is not None and self._served(step.phase, step.key):
+                wall = None
+            else:
+                wall = self._compile_fn(step.phase, step.key)
         except Exception as e:  # noqa: BLE001 — warmup never takes boot down
             step.status = "fail"
             step.wall_s = time.perf_counter() - t0

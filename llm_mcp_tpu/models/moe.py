@@ -68,6 +68,77 @@ def route(
     return top_g, top_i
 
 
+# Rows of a call at or under which `moe_share_ffn` visits each touched expert
+# once instead of grouping (row, expert) pairs. The crossing on the v5e at the
+# published widths (scripts/moe_crossing.py, ms a layer, expert-major against
+# grouped; PERF.md section 6, PR 34): 1.6 against 3.6 at 64 rows, 2.1 / 4.6 at
+# 128, 2.5 / 5.0 at 256, 4.1 / 5.7 at 512, 5.8 / 6.3 at 768, 7.6 / 7.1 at
+# 1024, 14.9 / 10.4 at 2048. Up to a few hundred rows a touched bank's 31.5 MB
+# bound the dense product and the grouped kernel spends its time on groups far
+# smaller than its tiles; from about a thousand rows the arithmetic of every
+# row against every touched expert (forty times that of the rows that chose
+# it, at an even router over 320) costs more than the grouping, and loses.
+EXPERT_MAJOR_MAX_ROWS = 768
+
+
+def share_form(n_rows: int) -> str:
+    """Which form `moe_share_ffn` takes for a call of `n_rows` rows (padding
+    included: the static shape it is traced at): "expert_major" or "grouped".
+    The shape alone decides; the engine's counter asks the same function."""
+    return "expert_major" if n_rows <= EXPERT_MAJOR_MAX_ROWS else "grouped"
+
+
+def _grouped(x, stacks, first, flat_e, sizes, w):
+    """Pairs sorted by expert, the held experts' rows through grouped products
+    (`jax.lax.ragged_dot`, one group an expert: work and weight traffic follow
+    the pairs that landed here and the experts they touched, not E); pairs of
+    absent experts sort behind the last group, where a ragged product yields
+    zeros. `stacks` are G >= E groups of which [first, first + E) have rows."""
+    T, D = x.shape
+    k = flat_e.shape[0] // T
+    E = sizes.shape[0]
+    w1, w3, w2 = stacks
+    order = jnp.argsort(flat_e, stable=True)  # [T k] pairs grouped by expert
+    rows = order // k
+    xs = jnp.take(x, rows, axis=0)  # [T k, D]
+    groups = sizes
+    if w1.shape[0] != E:
+        groups = jax.lax.dynamic_update_slice(
+            jnp.zeros((w1.shape[0],), jnp.int32), sizes, (first,))
+    gate = jax.nn.silu(jax.lax.ragged_dot(xs, w1, groups))
+    up = jax.lax.ragged_dot(xs, w3, groups)
+    ys = jax.lax.ragged_dot((gate * up).astype(x.dtype), w2, groups)
+    w = w[order]
+    # a pair of an absent expert weighs 0 whatever its row of `ys` holds
+    ys = jnp.where(w[:, None] > 0, ys.astype(jnp.float32) * w[:, None], 0.0)
+    return jnp.zeros((T, D), jnp.float32).at[rows].add(ys)
+
+
+def _expert_major(x, stacks, first, hit, sizes, w):
+    """One dense product of ALL rows against each held expert that some row
+    chose, weighted by the row's gate for it (0 for a row that did not choose
+    it), one touched expert after another in ONE traced loop body; an expert
+    nobody chose is not read. The bank is indexed in the stack, by group,
+    inside the loop: the product reads it in place."""
+    T, D = x.shape
+    E = sizes.shape[0]
+    w1, w3, w2 = stacks
+    # [E, T] a row's gate for each held expert
+    dense = jnp.sum(jnp.where(hit, w[:, None], 0.0).reshape(T, -1, E), axis=1).T
+    touched = jnp.argsort(sizes == 0, stable=True)  # the touched experts' ids first
+    f32 = jnp.float32
+
+    def one(i, y):
+        e = touched[i]
+        g = first + e
+        gate = jax.nn.silu(jnp.dot(x, w1[g], preferred_element_type=f32))
+        up = jnp.dot(x, w3[g], preferred_element_type=f32)
+        ye = jnp.dot((gate * up).astype(x.dtype), w2[g], preferred_element_type=f32)
+        return y + ye * dense[e][:, None]
+
+    return jax.lax.fori_loop(0, jnp.sum(sizes > 0, dtype=jnp.int32), one, jnp.zeros((T, D), f32))
+
+
 def moe_share_ffn(
     cfg: ModelConfig,
     lp: dict[str, Any],
@@ -85,18 +156,21 @@ def moe_share_ffn(
     expert. What the other members' experts would add is NOT here and nothing
     stands in for it. With Er == E it is the whole layer.
 
-    Every (row, chosen expert) pair is kept: pairs are sorted by expert, the
-    held experts' rows go through grouped products (`jax.lax.ragged_dot`, one
-    group an expert: work and weight traffic follow the pairs that landed
-    here and the experts they touched, not E), and pairs of absent experts
-    sort behind the last group, where a ragged product yields zeros.
+    Every (row, chosen expert) pair is kept, in one of two forms of the same
+    sum, chosen where the function is traced by the call's row count alone
+    (`share_form`): up to some hundreds of rows (a decode round, an admit
+    program) each touched expert is visited once with all rows
+    (`_expert_major`); above that (the larger chunks of a long prompt) the
+    pairs are sorted by expert and go through grouped products (`_grouped`).
+    Same routing, same float32 accumulation, same counts.
 
     A caller inside a layer scan hands over the expert banks STACKED over the
     layers (`banks`, with `layer`) and not this layer's slice: the grouped
     product is a kernel of its own to the TPU's compiler, and a slice of a
     stack that feeds one is copied out first (three banks a layer and step,
     1.2 GB at the published size). The stack goes in whole, as L x E groups
-    of which only this layer's E have rows.
+    of which only this layer's E have rows; the expert-major form indexes it
+    by (layer, expert) one bank at a time.
 
     Returns (y [T, D], counts int32 [5]): rows routed, pairs on held experts,
     distinct held experts touched, the fullest held expert's rows, and 1 (a
@@ -117,27 +191,19 @@ def moe_share_ffn(
     if valid is not None:
         held = held & valid[:, None]
     flat_e = jnp.where(held, experts, E).reshape(-1)  # absent/padding → group E
-    order = jnp.argsort(flat_e, stable=True)  # [T k] pairs grouped by expert
-    rows = order // k
-    sizes = jnp.sum(
-        flat_e[:, None] == jnp.arange(E, dtype=flat_e.dtype)[None, :], axis=0,
-        dtype=jnp.int32,
-    )  # [E] rows of each held expert
-    xs = jnp.take(x, rows, axis=0)  # [T k, D]
+    hit = flat_e[:, None] == jnp.arange(E, dtype=flat_e.dtype)[None, :]  # [T k, E]
+    sizes = jnp.sum(hit, axis=0, dtype=jnp.int32)  # [E] rows of each held expert
+    w = jnp.where(held, gates, 0.0).reshape(-1)  # [T k] a pair's weight, 0 off this member
     if banks is None:
-        w1, w3, w2, groups = lp["w1e"], lp["w3e"], lp["w2e"], sizes
+        stacks, first = (lp["w1e"], lp["w3e"], lp["w2e"]), 0
     else:
         L = banks["w1e"].shape[0]
-        w1, w3, w2 = (banks[n].reshape(L * E, *banks[n].shape[2:]) for n in ("w1e", "w3e", "w2e"))
-        groups = jax.lax.dynamic_update_slice(
-            jnp.zeros((L * E,), jnp.int32), sizes, (jnp.asarray(layer, jnp.int32) * E,))
-    gate = jax.nn.silu(jax.lax.ragged_dot(xs, w1, groups))
-    up = jax.lax.ragged_dot(xs, w3, groups)
-    ys = jax.lax.ragged_dot((gate * up).astype(x.dtype), w2, groups)
-    w = jnp.where(held, gates, 0.0).reshape(-1)[order]
-    # a pair of an absent expert weighs 0 whatever its row of `ys` holds
-    ys = jnp.where(w[:, None] > 0, ys.astype(jnp.float32) * w[:, None], 0.0)
-    y = jnp.zeros((T, D), jnp.float32).at[rows].add(ys)
+        stacks = tuple(banks[n].reshape(L * E, *banks[n].shape[2:]) for n in ("w1e", "w3e", "w2e"))
+        first = jnp.asarray(layer, jnp.int32) * E
+    if share_form(T) == "expert_major":
+        y = _expert_major(x, stacks, first, hit, sizes, w)
+    else:
+        y = _grouped(x, stacks, first, flat_e, sizes, w)
     y = y.astype(x.dtype)
     if "w1s" in lp:
         from .quant import qdot

@@ -216,6 +216,82 @@ def test_dropless_layer_is_the_capacity_layer_at_capacity_t(rows, padded):
     assert np.max(np.abs(np.asarray(stacked) - np.asarray(new))[keep]) < 1e-5
 
 
+def _share_case(case: str):
+    """(cfg, lp, x, valid, banks, layer) of one routing situation at
+    `tiny-solar` (4 experts held of 16 scored, 4 a row, sigmoid router with a
+    selection bias). A zero router leaves every score at 0.5, so the bias
+    alone chooses, for every row alike."""
+    cfg = get_config("tiny-solar")
+    rows = {"one_row": 1, "sixteen_rows": 16}.get(case, 64)
+    lp = {k: v[0] for k, v in moe.init_moe_layer_params(cfg, jax.random.PRNGKey(3), jnp.float32, 1).items()}
+    bias = 0.01 * jax.random.normal(jax.random.PRNGKey(4), (cfg.router_width,))
+    x = jax.random.normal(jax.random.PRNGKey(rows), (rows, cfg.dim))
+    valid = banks = None
+    layer = 0
+    if case == "padded_rows":
+        valid = jnp.arange(rows) < rows - 13
+    elif case == "an_expert_nobody_chose":
+        bias = bias.at[2].set(-10.0)
+    elif case == "every_row_on_one_expert":
+        lp["router"] = jnp.zeros_like(lp["router"])
+        bias = jnp.zeros_like(bias).at[jnp.asarray([0, 5, 6, 7])].set(1.0)
+    elif case == "a_row_whose_choices_are_all_absent":
+        x = x.at[0].set(0.0)  # its scores are all 0.5: the bias chooses 8..11
+        bias = bias.at[8:12].add(0.02)
+    elif case == "banks_stacked_over_layers":
+        banks = {k: jnp.stack([jnp.ones_like(lp[k]), lp[k]]) for k in ("w1e", "w3e", "w2e")}
+        layer = jnp.int32(1)
+    lp["router_bias"] = bias
+    return cfg, lp, x, valid, banks, layer
+
+
+@pytest.mark.parametrize("case", [
+    "one_row", "sixteen_rows", "sixty_four_rows", "padded_rows", "an_expert_nobody_chose",
+    "every_row_on_one_expert", "a_row_whose_choices_are_all_absent", "banks_stacked_over_layers",
+])
+def test_expert_major_form_is_the_grouped_form(case, monkeypatch):
+    """One sum, two forms (models/moe.py:share_form): each touched expert
+    visited once with all rows, or the pairs sorted into grouped products.
+    Equal to float32 rounding in `y`, exactly in the counts."""
+    cfg, lp, x, valid, banks, layer = _share_case(case)
+    got = {}
+    for form, cap in (("expert_major", 1 << 30), ("grouped", 0)):
+        monkeypatch.setattr(moe, "EXPERT_MAJOR_MAX_ROWS", cap)
+        assert moe.share_form(x.shape[0]) == form
+        y, counts = jax.jit(lambda lp, x: moe.moe_share_ffn(
+            cfg, lp, x, valid=valid, banks=banks, layer=layer))(lp, x)
+        got[form] = np.asarray(y), counts.tolist()
+    (y_major, n_major), (y_grouped, n_grouped) = got["expert_major"], got["grouped"]
+    assert n_major == n_grouped
+    keep = np.ones(x.shape[0], bool) if valid is None else np.asarray(valid)
+    assert np.max(np.abs(y_major - y_grouped)[keep]) < 1e-5 * max(1.0, np.max(np.abs(y_grouped)))
+    rows, pairs, touched, fullest, calls = n_major
+    assert (rows, calls) == (int(keep.sum()), 1)
+    if case == "an_expert_nobody_chose":
+        assert touched < cfg.n_experts
+    if case == "every_row_on_one_expert":
+        assert (pairs, touched, fullest) == (rows, 1, rows)
+    if case == "a_row_whose_choices_are_all_absent":
+        _, chosen = moe.route(cfg, x @ lp["router"], lp["router_bias"])
+        assert int(jnp.min(chosen[0])) >= cfg.n_experts > int(jnp.min(chosen[1]))
+        # nothing is routed to it here, and the shared expert of a zero row is zero
+        assert np.max(np.abs(y_major[0])) == 0.0 and np.max(np.abs(y_major[1])) > 0.0
+
+
+@pytest.mark.parametrize("rows,form", [
+    (moe.EXPERT_MAJOR_MAX_ROWS, "expert_major"), (moe.EXPERT_MAJOR_MAX_ROWS + 1, "grouped"),
+])
+def test_the_form_follows_the_row_count_alone(rows, form):
+    """Either side of the threshold, where the function is traced: the grouped
+    product is in the program or it is not; nothing else chooses."""
+    cfg, lp, _, _, _, _ = _share_case("sixteen_rows")
+    x = jnp.zeros((rows, cfg.dim), jnp.float32)
+    text = str(jax.make_jaxpr(lambda lp, x: moe.moe_share_ffn(cfg, lp, x))(lp, x))
+    assert moe.share_form(rows) == form
+    assert ("ragged_dot" in text) == (form == "grouped")
+    assert ("while" in text) == (form == "expert_major")  # one traced body for all experts
+
+
 # -- the engine's slot life-cycle around the state pool --------------------------------
 
 
@@ -368,6 +444,54 @@ def test_the_features_a_recurrent_configuration_runs_without_come_from_one_list(
     assert engine.expert_dtype == "float32"  # this engine's weights; bfloat16 as the cell boots it
 
 
+def test_the_expert_counter_says_which_form_each_phase_took(monkeypatch):
+    """`perf_stats()["experts"]["forms"]`: the layer's calls by form, from the
+    row count of each step program dispatched. With the threshold at 64 rows
+    this engine decodes 2 rows a step (expert-major); a prompt of 20 tokens is
+    admitted as one row of 32 (expert-major), one of 100 as a row of 128
+    (grouped)."""
+    from llm_mcp_tpu.executor import GenerationEngine
+
+    monkeypatch.setattr(moe, "EXPERT_MAJOR_MAX_ROWS", 64)  # before this engine traces anything
+    eng = GenerationEngine("tiny-solar", max_slots=2, max_seq_len=256, dtype=jnp.float32).start()
+    try:
+        L, K = eng.cfg.n_layers, eng.decode_chunk
+        assert eng.perf_stats()["experts"]["forms"] == {"decode": {}, "prefill": {}}
+        eng.generate("k" * 20, max_tokens=2 * K, temperature=0.0)
+        eng.generate("m" * 100, max_tokens=2, temperature=0.0)
+        forms = eng.perf_stats()["experts"]["forms"]
+    finally:
+        eng.shutdown()
+    assert forms["prefill"] == {"expert_major": L, "grouped": L}
+    assert set(forms["decode"]) == {"expert_major"}
+    assert forms["decode"]["expert_major"] % (K * L) == 0 and forms["decode"]["expert_major"] >= 2 * K * L
+
+
+def test_the_cells_warm_up_plan_holds_the_steps_it_held(monkeypatch):
+    """The engine of `solar_decode_closed` as the cell sizes it (64 slots x
+    1024, the entry point's defaults, the chip's kernels and so its ladder of
+    prompt buckets, an admit program of at most 512 padded tokens): 35 shapes
+    in the plan, as in PR 32; the two forms of the expert layer add no program."""
+    from llm_mcp_tpu.executor import GenerationEngine, warmup
+    from llm_mcp_tpu.utils.config import Config
+
+    monkeypatch.setenv("LLM_MCP_TPU_ATTN", "pallas")  # what `auto` resolves to on the chip
+    cfg = Config()  # as benchmark/run.py:boot hands them over
+    eng = GenerationEngine(
+        "tiny-solar", max_slots=64, max_seq_len=1024, dtype=jnp.float32, kv_quant="int8",
+        decode_compact=cfg.tpu_decode_compact, prefill_chunk=cfg.tpu_prefill_chunk,
+        prefill_buckets=cfg.tpu_prefill_buckets)
+    zoo = eng.warmup_shape_zoo()
+    by_phase = {ph: sum(1 for p, _ in zoo if p == ph) for ph, _ in zoo}
+    assert by_phase == {"admit": 13, "decode": 4, "chunk": 18} and len(zoo) == 35
+    assert len(warmup.plan_steps(zoo)) == 35
+    forms = {moe.share_form(key[0]) for ph, key in zoo if ph == "decode"}
+    assert forms == {"expert_major"}  # every decode round: 8, 16, 32, 64 rows
+    assert {moe.share_form(key[0] * key[1]) for ph, key in zoo if ph == "admit"} == {"expert_major"}
+    chunks = {key[0] * key[1] for ph, key in zoo if ph == "chunk"}
+    assert {t for t in chunks if moe.share_form(t) == "grouped"} == {1024, 1536, 2048}
+
+
 @pytest.mark.parametrize("lengths,joins", [
     ([20], True),  # 2 rows x 32: the budget of 64, to the token
     ([20, 20], False),  # a third prompt pads the program to 4 rows x 32
@@ -420,7 +544,11 @@ def test_prompts_over_the_admit_budget_wait_a_round_and_keep_their_order(ref):
             j.start()
         for j in jobs:
             j.join()
-        shapes = [r["key"] for r in eng._ledger.table() if r["phase"] == "admit"]
+        # this engine's own first dispatches: the compile ledger is shared by
+        # the process, and under xdist an earlier file's engine may have filed
+        # an admit shape this engine's budget forbids (the driver's run on the
+        # seed failed so; which files share a worker changes from run to run)
+        shapes = [key for phase, key in eng._served_shapes if phase == "admit"]
     finally:
         del eng._process_token
         eng.shutdown()

@@ -391,6 +391,8 @@ def solar_program(which: str, cfg):
     ("decode", [(64,), (64,), (64,)]),  # every slot a row
     ("admit", [(4, 256), (4,), (4,)]),  # four prompts in the 256 bucket
     ("chunk", [(2, 512), (2,), (2,), (2,)]),  # two prompts' second chunks of 512
+    ("admit", [(1, 64), (1,), (1,)]),  # the smallest prompts: few rows, as a decode round
+    ("admit", [(2, 256), (2,), (2,)]),  # the cell's largest admit program, 512 padded tokens
 ])
 def test_solar_step_programs_fit_and_keep_state_and_banks_in_place(
     sd, solar, chip_kernels, which, operands
@@ -400,15 +402,24 @@ def test_solar_step_programs_fit_and_keep_state_and_banks_in_place(
     compiler's own kernels), fits the chip, and makes no second copy of the
     state pool (0.75 GiB) nor of an expert bank (a slice of a stacked bank that
     feeds a grouped product was copied out, 0.39 GiB a bank and layer, until the
-    banks went in whole: models/moe.py). Bytes in PERF.md section 4 as
-    "described-chip compile"."""
+    banks went in whole: models/moe.py). A program of up to some hundreds of
+    rows visits each touched expert with a dense product that reads the bank
+    where it lies in the stack: no grouped product in it and, in the decode
+    round, temporaries within 0.1 GiB of the 0.11 GiB the grouped form had. Bytes in PERF.md
+    section 4 as "described-chip compile"."""
+    from llm_mcp_tpu.models import moe
+
     cfg, params, cache = solar
     compiled = jax.jit(solar_program(which, cfg), donate_argnums=(1, 2)).lower(
         params, cache["k"], cache["v"], *(sd(shape, I32) for shape in operands)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert ("kda_decode_step" in text) == (which == "decode")
+    rows = operands[0][0] * (operands[0][1] if len(operands[0]) > 1 else 1)
+    assert ("ragged-dot" in text) == (moe.share_form(rows) == "grouped")
     mem = compiled.memory_analysis()
+    if which == "decode":
+        assert mem.temp_size_in_bytes < 0.21 * 2**30  # no bank copied out of the stack
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     print(f"solar {which}: {total / 2**30:.2f} GiB, of it temporaries "

@@ -189,6 +189,47 @@ def test_readiness_transitions_under_slow_compiles():
     pl.stop()
 
 
+@pytest.mark.parametrize("when", ["before_the_plan", "while_the_plan_runs"])
+def test_a_shape_the_serve_path_dispatched_is_skipped_and_the_plan_ends_warm(when):
+    """The plan's thread does not lower again what a real dispatch has already
+    traced, loaded and run: a ledger row from `src` "serve" makes the step a
+    `skip`, the hook is never asked for it, and `fully_warm` still means every
+    shape is compiled or already served."""
+    ledger = _rec.CompileLedger()
+
+    def served(phase, key):
+        return any(r["phase"] == phase and r["key"] == warmup.key_str(key)
+                   and r["by_src"].get("serve") for r in ledger.table())
+
+    class Hook(_SlowCompiles):
+        def __call__(self, phase, key):
+            if when == "while_the_plan_runs" and (phase, key) == ("decode", (2, True, False)):
+                # the engine's thread first-dispatches the next shape of the
+                # plan while this one compiles
+                ledger.observe("admit", "2:64", 3.0, src="serve")
+            return super().__call__(phase, key)
+
+    ledger.observe("admit", "2:64", 1.5, src="warmup")  # an AOT compile is not a dispatch
+    if when == "before_the_plan":
+        ledger.observe("admit", "2:64", 3.0, src="serve")
+    fn = Hook()
+    events: list[tuple] = []
+    pl = warmup.WarmupPlanner(fn, _steps(), served=served,
+                              event=lambda et, **kw: events.append((et, kw)))
+    pl.run_critical()
+    pl.start_background()
+    deadline = time.time() + 10
+    while pl.state != "fully_warm" and time.time() < deadline:
+        time.sleep(0.01)
+    assert pl.state == "fully_warm"
+    assert ("admit", (2, 64)) not in fn.calls and len(fn.calls) == 4
+    by_key = {(s.phase, s.key): s.status for s in pl.steps}
+    assert by_key[("admit", (2, 64))] == "skip" and by_key[("admit", (1, 32))] == "done"
+    assert pl.stats()["by_status"] == {"done": 3, "skip": 2}  # the served one and `fused`
+    assert [kw["outcome"] for et, kw in events if et == "wu" and kw["key"] == "2:64"] == ["skip"]
+    pl.stop()
+
+
 def test_stop_mid_background_skips_remainder_monotone():
     gate = threading.Event()
     fn = _SlowCompiles(gate=gate)
@@ -296,6 +337,36 @@ def test_engine_warmup_reaches_fully_warm_and_covers_zoo(monkeypatch):
         assert st["by_status"].get("done", 0) == len(zoo)
         assert 1 <= st["critical"] <= 3
         assert st["fully_warm_s"] is not None
+    finally:
+        eng.shutdown()
+
+
+def test_engine_plan_skips_the_shapes_its_own_traffic_dispatched(monkeypatch):
+    """An engine that has served before its plan runs: the shapes of those
+    first dispatches are `skip` with no warm-up entry in the ledger, a
+    constrained admission's program is not taken for the plan's, and the rest
+    of the zoo compiles."""
+    monkeypatch.setenv("TPU_WARMUP", "1")
+    monkeypatch.setenv("TPU_WARMUP_THROTTLE_S", "0")
+    eng = _engine()
+    try:
+        eng.generate("served before the plan", max_tokens=6, temperature=0.0)
+        served = set(eng._served_shapes)
+        assert {ph for ph, _ in served} == {"admit", "decode"}
+        eng._compile_obs("admit", (2, 64), 0.1, planned=False)  # as a constrained batch files it
+        assert ("admit", "2:64") not in eng._served_shapes
+        pl = eng.start_warmup()
+        deadline = time.time() + 120
+        while pl.state != "fully_warm" and time.time() < deadline:
+            time.sleep(0.05)
+        st = eng.warmup_stats()
+        assert st["state"] == "fully_warm"
+        status = {(s["phase"], s["key"]): s["status"] for s in st["plan"]}
+        assert all(status[shape] == "skip" for shape in served)
+        assert status[("admit", "2:64")] == "done"
+        assert st["by_status"] == {"skip": len(served), "done": len(status) - len(served)}
+        rows = {(r["phase"], r["key"]): r["by_src"] for r in eng._ledger.table()}
+        assert all("warmup" not in rows[shape] for shape in served)
     finally:
         eng.shutdown()
 
