@@ -1,0 +1,20 @@
+"""Share of the HBM roofline a decode round of the dense hybrid configuration
+reaches: the least bytes decode_chunk steps must move (every weight once a
+step, the live rows of the state pool read and written, the live int8 KV:
+olmo_hybrid_bytes.py) over the chip's published bytes a second, over the
+round's device time in the trace. Bound by memory: a step at 64 rows does about
+0.6 TFLOP against 14 GB. The share of the whole step that bounds a later claim
+in this cell."""
+from benchmark import counters, olmo_hybrid_bytes, peaks
+
+NAME, UNIT, BETTER, SOURCE = "olmo_round_roofline", "%", "higher", "device_trace"
+LAYER, MOVES = "step programs", "out_tokens_per_s"
+
+
+def read(run: dict):
+    mean_s, need = counters.decode_round_s(run), olmo_hybrid_bytes.decode_step_bytes(run)
+    if not mean_s or not need:
+        return None
+    gen = run["sut"]["gen"]
+    least_s = gen.decode_chunk * need / peaks.peaks(run["device"]["kind"])["hbm_bytes_per_s"]
+    return 100.0 * least_s / mean_s

@@ -2447,13 +2447,26 @@ class GenerationEngine:
         configuration's file states it (`program.expect`)."""
         return str(self._cv["state"]["S"].dtype) if self._state_pool is not None else ""
 
+    def _layer_leaf_dtype(self, *names: str) -> str:
+        """Precision of the first of `names` among the stacked layers' leaves:
+        "int8" for a quantised one, "" where there is none."""
+        layers = self.params.get("layers", {}) if isinstance(self.params, dict) else {}
+        leaf = next((layers[k] for k in names if k in layers), None)
+        if leaf is None:
+            return ""
+        return "int8" if isinstance(leaf, dict) else str(leaf.dtype)
+
+    @property
+    def weights_dtype(self) -> str:
+        """The layers' dense feed-forward matrices' precision ("" where every
+        layer's feed-forward is routed experts: `expert_dtype`); a
+        configuration's file states it (`program.expect`)."""
+        return self._layer_leaf_dtype("w13", "w1")
+
     @property
     def expert_dtype(self) -> str:
         """The routed expert banks' precision ("" without routed experts)."""
-        bank = self.params.get("layers", {}).get("w1e") if isinstance(self.params, dict) else None
-        if bank is None:
-            return ""
-        return "int8" if isinstance(bank, dict) else str(bank.dtype)
+        return self._layer_leaf_dtype("w1e")
 
     def warmup_shape_zoo(self) -> list[tuple[str, tuple]]:
         """The engine's serving-shape zoo: the (phase, shape key) pairs its

@@ -339,16 +339,20 @@ class StatePool:
     """Host-side book of the per-slot recurrent state beside the KV cache.
 
     The device arrays ride the engine's cache pair through every step program
-    (models/hybrid.py: float32 S [Lk, slots, H, dk, dv] and the convolution
-    tails), one row a slot, allocated with the engine like the KV cache. A
+    (models/hybrid.py: float32 S [Lk, slots, H / P, dk, P dv], P heads abreast
+    so that a row is a whole number of lanes and the pool's bytes in HBM are
+    its logical bytes (kernels/kda.py), and the convolution tails), one row a
+    slot, allocated with the engine like the KV cache. A
     slot's row is claimed at admission and starts from zero there: a whole
     prompt's prefill writes the row outright, a chunked prefill's first chunk
     (start 0) never reads it. The pool counts what a per-layer metric reads:
-    its bytes, the slots alive, and the features it keeps off (`off`)."""
+    its bytes (`layout`: each member's shape, whose product times the item
+    size they are), the slots alive, and the features it keeps off (`off`)."""
 
-    def __init__(self, *, max_slots: int, nbytes: int):
+    def __init__(self, *, max_slots: int, nbytes: int, layout: dict[str, list[int]] | None = None):
         self.max_slots = int(max_slots)
         self.nbytes = int(nbytes)
+        self.layout = dict(layout or {})
         self.bytes_per_slot = self.nbytes // max(1, self.max_slots)
         self.admitted_total = 0
         self.off = dict.fromkeys(RECURRENT_OFF, 0)
@@ -360,6 +364,7 @@ class StatePool:
         return {
             "bytes": self.nbytes,
             "bytes_per_slot": self.bytes_per_slot,
+            "layout": dict(self.layout),
             "slots": self.max_slots,
             "live_slots": int(live_slots),
             "live_bytes": int(live_slots) * self.bytes_per_slot,
@@ -405,7 +410,8 @@ def build_state_pool(cfg: Any, max_slots: int, state: Any, log: Any) -> "StatePo
     that says what such a configuration runs without."""
     if not getattr(cfg, "recurrent", False):
         return None
-    pool = StatePool(max_slots=max_slots, nbytes=pytree_nbytes(state))
+    pool = StatePool(max_slots=max_slots, nbytes=pytree_nbytes(state),
+                     layout={k: list(v.shape) for k, v in state.items()})
     log.info("recurrent state pool: %.1f MB a slot, %d slots beside the KV cache",
              pool.bytes_per_slot / (1 << 20), max_slots)
     for feature, why in RECURRENT_OFF.items():

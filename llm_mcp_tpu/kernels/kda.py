@@ -1,10 +1,12 @@
-"""One decode step of the gated delta rule (KDA) on the per-slot state pool.
+"""One decode step of the gated delta rule on the per-slot state pool.
 
 A linear-attention layer keeps, for every sequence and head, a float32 state
-`S` of [keys, values] (128 x 128 at the published size: 64 KB a head, 4 MB a
-layer and slot). A decode step reads ALL of it and writes all of it back:
+`S` of [keys, values] (128 x 128 for Solar-Open2's KDA layers: 64 KB a head,
+4 MB a layer and slot; 96 x 192 for Olmo-Hybrid's Gated DeltaNet layers). A
+decode step reads ALL of it and writes all of it back:
 
-    S' = diag(alpha) S            alpha = exp(g) in (0, 1), per key channel
+    S' = diag(alpha) S            alpha = exp(g) in (0, 1): a key channel's
+                                  (KDA) or one a head (Gated DeltaNet)
     u  = beta (v - S'^T k)        the delta rule's correction, rank 1
     S  = S' + k u^T
     o  = S^T q
@@ -16,9 +18,19 @@ decode batch names its pool rows the way the attention kernels' cache rows
 are named), at the layer the caller says. A row that is not live (a parked
 slot, a compaction pad) gets its tile back unchanged.
 
+**The pool's layout** is [layers, slots, H / P, dk, P dv]: P heads lie side by
+side along the values (`heads_abreast`), P the least count for which P dv is
+a multiple of the 128 lanes, so that a float32 tile of (8, 128) pads nothing
+in HBM: P = 1 at dv = 128 (the layout is then [.., H, dk, dv] itself), P = 2
+at dv = 192 (rows of 384 = 3 x 128; [.., 96, 192] alone would pad its rows to
+256, a third more bytes in the pool and in every step). `pack_state` /
+`unpack_state` go between a head-major [.., H, dk, dv] and that layout. Inside
+a tile the P heads share every product but the broadcast of their own k, q
+and decay along their own lanes (a select a head beyond the first).
+
 `kda_decode_step_reference` is the same step in plain `jax.numpy`: what the
-kernel is held to (tests/test_kda.py), and what shapes that Mosaic cannot tile
-take on the chip (counted in `kernels.attention.reference_falls`).
+kernel is held to (tests/test_hybrid.py), and what shapes that Mosaic cannot
+tile take on the chip (counted in `kernels.attention.reference_falls`).
 """
 
 from __future__ import annotations
@@ -32,95 +44,151 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .attention import _interpret, _note_fall
 
-HEAD_BLOCK = 16  # heads a grid cell: 16 x 64 KB tiles in, as many out
+HEAD_BLOCK = 16  # tiles a grid cell at most: 16 x 64 KB in, as many out
+TILE_BYTES = 1 << 20  # and at most this much state a cell and direction
+LANES = 128
+
+
+def heads_abreast(heads: int, dv: int) -> int:
+    """P: heads side by side along the values in the pool's layout, the least
+    count that makes a row a whole number of lanes; 1 where none divides the
+    heads (such a pool pads in HBM and its step is the reference's)."""
+    return next((p for p in range(1, heads + 1)
+                 if heads % p == 0 and (p * dv) % LANES == 0), 1)
+
+
+def pack_state(S: jnp.ndarray, abreast: int) -> jnp.ndarray:
+    """[..., H, dk, dv] -> [..., H / P, dk, P dv]."""
+    if abreast == 1:
+        return S
+    *lead, H, dk, dv = S.shape
+    S = S.reshape(*lead, H // abreast, abreast, dk, dv)
+    return jnp.swapaxes(S, -3, -2).reshape(*lead, H // abreast, dk, abreast * dv)
+
+
+def unpack_state(S: jnp.ndarray, abreast: int) -> jnp.ndarray:
+    """[..., H / P, dk, P dv] -> [..., H, dk, dv]."""
+    if abreast == 1:
+        return S
+    *lead, G, dk, W = S.shape
+    S = S.reshape(*lead, G, dk, abreast, W // abreast)
+    return jnp.swapaxes(S, -3, -2).reshape(*lead, G * abreast, dk, W // abreast)
 
 
 def kda_decode_step_reference(state, layer, slot_ids, live, q, k, v, alpha, beta):
-    """(o [Ba, H, dv] f32, new state): the step above by gather and scatter.
-    Rows that are not live write back what they read."""
-    S = state[layer][slot_ids]  # [Ba, H, dk, dv]
+    """(o [Ba, H, dv] f32, new state): the step above by gather and scatter,
+    on the pool in its layout. Rows that are not live write back what they
+    read. `alpha` [Ba, H, dk], or [Ba, H] for one decay a head."""
+    P = v.shape[1] // state.shape[2]
+    if alpha.ndim == 2:
+        alpha = jnp.broadcast_to(alpha[..., None], k.shape)
+    S = unpack_state(state[layer][slot_ids], P)  # [Ba, H, dk, dv]
     Sd = S * alpha[..., :, None]
     kS = jnp.einsum("bhk,bhkv->bhv", k, Sd, precision=jax.lax.Precision.HIGHEST)
     u = beta[..., None] * (v - kS)
     Sn = Sd + k[..., :, None] * u[..., None, :]
     o = jnp.einsum("bhk,bhkv->bhv", q, Sn, precision=jax.lax.Precision.HIGHEST)
     keep = jnp.where(live[:, None, None, None], Sn, S)
-    return o, state.at[layer, slot_ids].set(keep)
+    return o, state.at[layer, slot_ids].set(pack_state(keep, P))
 
 
 def _kda_step_kernel(
     layer_ref,  # [1] int32 (scalar prefetch): the pool's layer
     ids_ref,  # [Ba] int32 (scalar prefetch): pool row of each batch row
     live_ref,  # [Ba] int32 (scalar prefetch): 0 = leave the row's state alone
-    qt_ref,  # [1, 1, dk, hb] f32: this cell's heads, keys on sublanes
+    qt_ref,  # [1, 1, dk, hb P] f32: this cell's heads, keys on sublanes
     kt_ref,
-    at_ref,  # alpha, the same layout
-    v_ref,  # [1, hb, dv] f32
-    b_ref,  # [1, hb, dv] f32: beta, broadcast along the values
-    s_ref,  # [1, 1, hb, dk, dv] f32: the state tiles
-    o_ref,  # [1, hb, dv] f32
+    a_ref,  # alpha: the same layout a channel, or a row like beta's a head
+    v_ref,  # [1, 1, hb, P dv] f32
+    b_ref,  # [1, 1, hb, P dv] f32: beta, broadcast along the values
+    s_ref,  # [1, 1, hb, dk, P dv] f32: the state tiles
+    o_ref,  # [1, 1, hb, P dv] f32
     so_ref,  # aliased to the pool
     *,
     hb: int,
+    abreast: int,
+    dv: int,
+    head_decay: bool,
 ):
     del layer_ref, ids_ref  # consumed by the index maps
     live = live_ref[pl.program_id(0)] != 0
+    P = abreast
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, P * dv), 1) if P > 1 else None
+
+    def along_values(ref, j):
+        """Tile j's heads' columns [dk, 1], each over its own head's lanes."""
+        col = ref[0, 0, :, j * P : j * P + 1]  # [dk, 1]: broadcasts along the values
+        for p in range(1, P):
+            col = jnp.where(lane >= p * dv, ref[0, 0, :, j * P + p : j * P + p + 1], col)
+        return col
+
     for j in range(hb):
-        S = s_ref[0, 0, j]  # [dk, dv]
-        k = kt_ref[0, 0, :, j : j + 1]  # [dk, 1]: broadcasts along the values
-        Sd = S * at_ref[0, 0, :, j : j + 1]
-        kS = jnp.sum(Sd * k, axis=0, keepdims=True)  # [1, dv]
-        u = b_ref[0, j : j + 1, :] * (v_ref[0, j : j + 1, :] - kS)
+        S = s_ref[0, 0, j]  # [dk, P dv]
+        k = along_values(kt_ref, j)
+        Sd = S * (a_ref[0, 0, j : j + 1, :] if head_decay else along_values(a_ref, j))
+        kS = jnp.sum(Sd * k, axis=0, keepdims=True)  # [1, P dv]
+        u = b_ref[0, 0, j : j + 1, :] * (v_ref[0, 0, j : j + 1, :] - kS)
         Sn = Sd + k * u
-        o_ref[0, j : j + 1, :] = jnp.sum(
-            Sn * qt_ref[0, 0, :, j : j + 1], axis=0, keepdims=True
-        )
+        o_ref[0, 0, j : j + 1, :] = jnp.sum(
+            Sn * along_values(qt_ref, j), axis=0, keepdims=True)
         so_ref[0, 0, j] = jnp.where(live, Sn, S)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("name", "interpret"))
 def kda_decode_step(
-    state: jnp.ndarray,  # [Lk, B, H, dk, dv] f32: the pool, updated IN PLACE
+    state: jnp.ndarray,  # [Lk, B, H / P, dk, P dv] f32: the pool, updated IN PLACE
     layer: jnp.ndarray,  # int32 scalar: which of the pool's layers
     slot_ids: jnp.ndarray,  # [Ba] int32: pool row of each batch row
     live: jnp.ndarray,  # [Ba] bool: rows whose state moves
     q: jnp.ndarray,  # [Ba, H, dk] f32, normalised and scaled
     k: jnp.ndarray,  # [Ba, H, dk] f32, normalised
     v: jnp.ndarray,  # [Ba, H, dv] f32
-    alpha: jnp.ndarray,  # [Ba, H, dk] f32 in (0, 1)
+    alpha: jnp.ndarray,  # [Ba, H, dk] f32 in (0, 1); [Ba, H] for one decay a head
     beta: jnp.ndarray,  # [Ba, H] f32
     *,
+    name: str = "kda_decode_step",  # the Mosaic call's name in a trace
     interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """(o [Ba, H, dv] f32, the pool with the batch's rows stepped)."""
-    Lk, B, H, dk, dv = state.shape
-    Ba = q.shape[0]
+    Lk, B, G, dk, W = state.shape
+    Ba, H, dv = v.shape
+    P = H // G
     interp = _interpret() if interpret is None else interpret
-    hb = min(HEAD_BLOCK, H)
-    if H % hb or (not interp and (dk % 128 or dv % 128 or hb % 8)):
-        _note_fall("kda_decode_step", f"H={H} dk={dk} dv={dv}: no legal tile", interp)
+    tile = dk * W * state.dtype.itemsize
+    hb = max(d for d in range(1, min(HEAD_BLOCK, G) + 1)
+             if G % d == 0 and (d == 1 or d * tile <= TILE_BYTES))
+    if not interp and (dk % 8 or W % LANES):
+        _note_fall(name, f"H={H} dk={dk} dv={dv}: no legal tile", interp)
         return kda_decode_step_reference(
             state, layer, slot_ids, live, q, k, v, alpha, beta)
-    G = H // hb
+    Gb = G // hb
+    head_decay = alpha.ndim == 2
 
-    def keys_on_sublanes(x):  # [Ba, H, dk] -> [Ba, G, dk, hb]
-        return x.reshape(Ba, G, hb, dk).transpose(0, 1, 3, 2)
+    def keys_on_sublanes(x):  # [Ba, H, dk] -> [Ba, Gb, dk, hb P]
+        return x.reshape(Ba, Gb, hb * P, dk).transpose(0, 1, 3, 2)
 
-    vec = pl.BlockSpec((1, 1, dk, hb), lambda b, g, li, ids, lv: (b, g, 0, 0))
-    row = pl.BlockSpec((1, hb, dv), lambda b, g, li, ids, lv: (b, g, 0))
-    tile = pl.BlockSpec(
-        (1, 1, hb, dk, dv), lambda b, g, li, ids, lv: (li[0], ids[b], g, 0, 0))
+    def rows(x):  # [Ba, H, dv] -> [Ba, Gb, hb, P dv]: a tile's heads abreast
+        return x.reshape(Ba, Gb, hb, W)
+
+    def over_values(x):  # [Ba, H] -> the same, each head's scalar along its values
+        return rows(jnp.broadcast_to(x[..., None], (Ba, H, dv)))
+
+    vec = pl.BlockSpec((1, 1, dk, hb * P), lambda b, g, li, ids, lv: (b, g, 0, 0))
+    row = pl.BlockSpec((1, 1, hb, W), lambda b, g, li, ids, lv: (b, g, 0, 0))
+    tiles = pl.BlockSpec(
+        (1, 1, hb, dk, W), lambda b, g, li, ids, lv: (li[0], ids[b], g, 0, 0))
     o, new = pl.pallas_call(
-        functools.partial(_kda_step_kernel, hb=hb),
-        name="kda_decode_step",
+        functools.partial(
+            _kda_step_kernel, hb=hb, abreast=P, dv=dv, head_decay=head_decay),
+        name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(Ba, G),
-            in_specs=[vec, vec, vec, row, row, tile],
-            out_specs=[row, tile],
+            grid=(Ba, Gb),
+            in_specs=[vec, vec, row if head_decay else vec, row, row, tiles],
+            out_specs=[row, tiles],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((Ba, H, dv), jnp.float32),
+            jax.ShapeDtypeStruct((Ba, Gb, hb, W), jnp.float32),
             jax.ShapeDtypeStruct(state.shape, state.dtype),
         ],
         # operands: layer=0, ids=1, live=2, q=3, k=4, alpha=5, v=6, beta=7, state=8
@@ -132,9 +200,9 @@ def kda_decode_step(
         live.astype(jnp.int32),
         keys_on_sublanes(q),
         keys_on_sublanes(k),
-        keys_on_sublanes(alpha),
-        v,
-        jnp.broadcast_to(beta[..., None], (Ba, H, dv)),
+        over_values(alpha) if head_decay else keys_on_sublanes(alpha),
+        rows(v),
+        over_values(beta),
         state,
     )
-    return o, new
+    return o.reshape(Ba, H, dv), new

@@ -109,11 +109,25 @@ class ModelConfig:
     gqa_layers: tuple[int, ...] = ()
     gqa_interval: int = 0  # linear layers between two GQA layers (published)
     lin_heads: int = 0
-    lin_head_dim: int = 0  # key and value head size of the linear layers
+    lin_head_dim: int = 0  # key head size of the linear layers (and the value's, unless stated)
+    lin_value_dim: int = 0  # value head size of the linear layers (0 -> lin_head_dim)
     lin_conv: int = 4  # taps of the causal depthwise convolution on q, k, v
     lin_neg_eigval: bool = False  # beta in (0, 2): negative eigenvalues allowed
+    # how a linear layer makes its decay, beta and output gate (models/kda.py):
+    # "kda": a decay a key CHANNEL through a low-rank pair, a sigmoid output
+    # gate through another (Kimi Delta Attention); "gdn": ONE decay a head,
+    # projected from the input at full rank, a SiLU output gate at full rank
+    # (Gated DeltaNet)
+    lin_gates: str = "kda"  # kda | gdn
     attn_gate: bool = False  # GQA output gate: attn * sigmoid(x W_gate)
     use_rope: bool = True  # False: no positional encoding anywhere (NoPE)
+    # Where a sub-layer's RMSNorm sits (models/hybrid.py, the hybrid decoder's
+    # three layer halves): "input": h + Mix(norm(h)) (llama); "output":
+    # h + norm(Mix(h)) (OLMo 2 / OLMo 3), the same weight leaves either way
+    norm_placement: str = "input"  # input | output
+    # qk_norm over the WHOLE projection width, one weight vector each for q
+    # and k (OLMo), instead of a head at a time over head_dim (Qwen3)
+    qk_norm_whole: bool = False
     # serving metadata
     params_b: float = 0.0
     tie_embeddings: bool = False
@@ -126,6 +140,11 @@ class ModelConfig:
     def router_width(self) -> int:
         """Experts the router scores: the published count under a share."""
         return self.n_router_experts or self.n_experts
+
+    @property
+    def lin_dv(self) -> int:
+        """Value head size of the linear layers."""
+        return self.lin_value_dim or self.lin_head_dim
 
     @property
     def recurrent(self) -> bool:
@@ -191,11 +210,17 @@ class ModelConfig:
                 + 2 * self.dim * self.n_kv_heads * hd  # wk, wv
                 + self.n_heads * hd * self.dim  # wo
             )
-        if self.gqa_layers:  # hybrid: GQA (+ gate) layers and KDA layers
-            r = self.lin_head_dim  # the two gates' low rank (models/kda.py)
-            hk = self.lin_heads * self.lin_head_dim
-            kda = (4 * self.dim * hk + 2 * (self.dim * r + r * hk)
-                   + self.dim * self.lin_heads + 3 * self.lin_conv * hk)
+        if self.gqa_layers:  # hybrid: GQA (+ gate) layers and delta-rule layers
+            hk, hv = self.lin_heads * self.lin_head_dim, self.lin_heads * self.lin_dv
+            mix = (self.dim * (2 * hk + hv)  # wq, wk, wv
+                   + hv * self.dim  # wo
+                   + self.lin_conv * (2 * hk + hv)  # the depthwise convolutions
+                   + self.dim * self.lin_heads)  # w_beta
+            if self.lin_gates == "gdn":  # decay a head and output gate, full rank
+                kda = mix + self.dim * self.lin_heads + self.dim * hv
+            else:  # the two gates' low-rank pairs, rank = head size (models/kda.py)
+                r = self.lin_head_dim
+                kda = mix + 2 * (self.dim * r + r * hk)
             gqa = attn + (self.dim * self.n_heads * hd if self.attn_gate else 0)
             ng = len(self.gqa_layers)
             attn = (ng * gqa + (self.n_layers - ng) * kda) // self.n_layers
@@ -385,6 +410,65 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         attn_gate=True,
         use_rope=False,
         params_b=3.3,
+    ),
+    # Olmo-Hybrid-7B (allenai/Olmo-Hybrid-7B config.json) cut to its first 20
+    # of 32 layers (5 whole periods of three Gated DeltaNet layers and one full
+    # attention layer, the period's last) with embedding and head: what one v5e
+    # chip holds in bfloat16 beside 64 slots of state and int8 KV. Every width is
+    # the published one. The norm placement, the whole-width q/k norm and the
+    # gates' form are the family's conventions, assumed:
+    # benchmark/configs/olmo-hybrid-7b-d20-bf16.json lists them.
+    "olmo-hybrid-7b-d20": ModelConfig(
+        name="olmo-hybrid-7b-d20",
+        vocab_size=100_352,
+        dim=3840,
+        n_layers=20,
+        n_heads=30,
+        n_kv_heads=30,
+        head_dim=128,
+        ffn_hidden=11_008,
+        rope_theta=10_000.0,  # published null; read by nothing: use_rope is False
+        norm_eps=1e-6,
+        max_seq_len=65_536,
+        gqa_layers=(3, 7, 11, 15, 19),
+        gqa_interval=3,
+        lin_heads=30,
+        lin_head_dim=96,
+        lin_value_dim=192,
+        lin_conv=4,
+        lin_neg_eigval=True,
+        lin_gates="gdn",
+        use_rope=False,
+        norm_placement="output",
+        qk_norm=True,
+        qk_norm_whole=True,
+        params_b=4.93,
+    ),
+    # the same shape at toy size: two periods, heads no multiple of 8, keys and
+    # values of unlike sizes, neither a multiple of 128
+    "tiny-olmo-hybrid": ModelConfig(
+        name="tiny-olmo-hybrid",
+        vocab_size=512,
+        dim=96,
+        n_layers=8,
+        n_heads=6,
+        n_kv_heads=6,
+        head_dim=16,
+        ffn_hidden=192,
+        norm_eps=1e-6,
+        max_seq_len=512,
+        gqa_layers=(3, 7),
+        gqa_interval=3,
+        lin_heads=6,
+        lin_head_dim=24,
+        lin_value_dim=48,
+        lin_neg_eigval=True,
+        lin_gates="gdn",
+        use_rope=False,
+        norm_placement="output",
+        qk_norm=True,
+        qk_norm_whole=True,
+        params_b=0.001,
     ),
     # the same shape at toy size: one period, 16 experts of which 4 are held
     "tiny-solar": ModelConfig(

@@ -1,30 +1,37 @@
 """A decoder whose layers are of unlike kinds: softmax GQA layers among gated
-delta-rule (KDA) linear-attention layers, every layer with a routed-expert
-feed-forward of which this process may hold a share (models/moe.py).
+delta-rule linear-attention layers (models/kda.py: KDA or Gated DeltaNet, by
+`cfg.lin_gates`), every layer with a feed-forward that is either routed
+experts, of which this process may hold a share (models/moe.py), or, without
+experts (`cfg.n_experts` 0), the dense family's gated MLP.
 
 The layer stack is one PERIOD of kinds repeated (`cfg.layer_period`, e.g.
-gqa, kda, kda, kda), so the program scans over periods and unrolls the few
-layers of one period inside the scan body: one period's XLA program compiled
-once, whatever the depth. The parameter tree:
+gqa, kda, kda, kda, or kda, kda, kda, gqa), so the program scans over periods
+and unrolls the few layers of one period inside the scan body: one period's
+XLA program compiled once, whatever the depth. The parameter tree:
 
     params["embed"], ["final_norm"], ["lm_head"]
     params["layers"]: what EVERY layer has, stacked [L, ...]: attn_norm,
-        ffn_norm, router [D, Er], router_bias [Er] (sigmoid routers), w1e, w3e
-        [E, D, F], w2e [E, F, D] (the E experts held here), w1s, w3s, w2s
-    params["gqa"]: the GQA layers', stacked [Lg, ...]: wq, wk, wv, wo, and wg
-        [D, H hd] with cfg.attn_gate
-    params["kda"]: the KDA layers', stacked [Lk, ...] (models/kda.py)
+        ffn_norm, and either router [D, Er], router_bias [Er] (sigmoid
+        routers), w1e, w3e [E, D, F], w2e [E, F, D] (the E experts held here),
+        w1s, w3s, w2s, or the dense w1, w3 [D, F], w2 [F, D]
+    params["gqa"]: the GQA layers', stacked [Lg, ...]: wq, wk, wv, wo, wg
+        [D, H hd] with cfg.attn_gate, q_norm and k_norm with cfg.qk_norm
+    params["kda"]: the delta-rule layers', stacked [Lk, ...] (models/kda.py)
+
+The one norm of a sub-layer sits on its input or on its output
+(`cfg.norm_placement`, `llama._sub_in`): the weights are the same leaves.
 
 What a sequence owns, beside the rows of the KV cache that its GQA layers
 write (cache layers 0..Lg-1, the dense family's layout and kernels), is the
-KDA layers' recurrent state. The engine threads both through every step
+delta-rule layers' recurrent state. The engine threads both through every step
 program as the cache pair (cache_k, cache_v): `cache_v` is
-{"v": the KV cache's second member, "state": {"S", "conv"}, "moe": counts},
-built by `init_hybrid_cache`. "moe" [2, L, 5] int32 is the expert layer's own
-member, beside the state and not of it: the sums of its counts
-(moe.moe_share_ffn) over every call the process has made, decode steps under
-[0] and prefills under [1]; the engine reads it back with each decode round
-(executor/memory.py: ExpertCounts).
+{"v": the KV cache's second member, "state": {"S", "conv"}} and, with routed
+experts only, "moe": counts; built by `init_hybrid_cache`. "moe" [2, L, 5]
+int32 is the expert layer's own member, beside the state and not of it: the
+sums of its counts (moe.moe_share_ffn) over every call the process has made,
+decode steps under [0] and prefills under [1]; the engine reads it back with
+each decode round (executor/memory.py: ExpertCounts). A dense feed-forward
+counts nothing and the member is absent.
 
 No rope anywhere when cfg.use_rope is False; the GQA layers then attend by
 content and causality alone."""
@@ -43,7 +50,15 @@ from ..kernels.attention import (
     decode_attend_q8,
 )
 from .configs import ModelConfig
-from .kda import init_kda_params, init_kda_state, kda_decode, kda_prefill
+from .kda import (
+    head_major,
+    init_kda_params,
+    init_kda_state,
+    kda_decode,
+    kda_prefill,
+    pool_rows,
+    zero_state,
+)
 from .moe import init_moe_layer_params, moe_share_ffn
 
 Params = dict[str, Any]
@@ -63,21 +78,25 @@ def init_hybrid_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> 
     row, so a larger one sends every row to the same few experts: at 0.1
     (against scores that spread by 0.2) the 40 held got 0.72 of their share of
     the pairs and the fullest 9.7 times the mean (v5e, PR 32's first run)."""
-    if not cfg.n_experts:
-        raise NotImplementedError("the hybrid decoder's feed-forward is the routed-expert layer")
+    from .llama import qk_norm_widths
+
     _, P, ng, nk = _layout(cfg)
     hd, D, H, Hkv, V = cfg.resolved_head_dim, cfg.dim, cfg.n_heads, cfg.n_kv_heads, cfg.vocab_size
-    L, Lg, Lk = cfg.n_layers, P * ng, P * nk
+    L, Lg, Lk, F = cfg.n_layers, P * ng, P * nk, cfg.ffn_hidden
 
     def build(key):
-        ks = jax.random.split(key, 12)
+        ks = jax.random.split(key, 14)
 
         def w(k, shape, fan_in):
             return (jax.random.normal(k, shape, jnp.float32) * fan_in**-0.5).astype(dtype)
 
         layers = {"attn_norm": jnp.ones((L, D), dtype), "ffn_norm": jnp.ones((L, D), dtype)}
-        layers.update(init_moe_layer_params(cfg, ks[0], dtype))
-        if cfg.router_score == "sigmoid":
+        if cfg.n_experts:
+            layers.update(init_moe_layer_params(cfg, ks[0], dtype))
+        else:
+            layers.update(w1=w(ks[0], (L, D, F), D), w3=w(ks[10], (L, D, F), D),
+                          w2=w(ks[11], (L, F, D), F))
+        if cfg.n_experts and cfg.router_score == "sigmoid":
             layers["router_bias"] = 0.01 * jax.random.normal(
                 ks[1], (L, cfg.router_width), jnp.float32)
         gqa = {
@@ -88,6 +107,9 @@ def init_hybrid_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> 
         }
         if cfg.attn_gate:
             gqa["wg"] = w(ks[6], (Lg, D, H * hd), D)
+        if cfg.qk_norm:
+            nq, nk_ = qk_norm_widths(cfg)
+            gqa["q_norm"], gqa["k_norm"] = jnp.ones((Lg, nq), dtype), jnp.ones((Lg, nk_), dtype)
         params = {
             "embed": w(ks[7], (V, D), D),
             "layers": layers,
@@ -110,8 +132,10 @@ def init_hybrid_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype, quantiz
     _, P, _, nk = _layout(cfg)
     kv = init_kv_cache(
         _gqa_view(cfg), batch, max_seq, dtype=dtype, quantized=quantized)
-    return {"k": kv["k"], "v": {"v": kv["v"], "state": init_kda_state(cfg, P * nk, batch, dtype),
-                                "moe": jnp.zeros((2, cfg.n_layers, 5), jnp.int32)}}
+    cache_v = {"v": kv["v"], "state": init_kda_state(cfg, P * nk, batch, dtype)}
+    if cfg.n_experts:
+        cache_v["moe"] = jnp.zeros((2, cfg.n_layers, 5), jnp.int32)
+    return {"k": kv["k"], "v": cache_v}
 
 
 def _gqa_view(cfg: ModelConfig) -> ModelConfig:
@@ -125,85 +149,87 @@ def _gqa_view(cfg: ModelConfig) -> ModelConfig:
 BANKS = ("w1e", "w3e", "w2e")  # never sliced by layer: moe.moe_share_ffn says why
 
 
-def _by_period(cfg: ModelConfig, params: Params):
-    """The three stacks reshaped [P, layers of that kind a period, ...], the
-    expert banks left out."""
-    period, P, ng, nk = _layout(cfg)
-
-    def split(tree, n):
-        return jax.tree.map(lambda a: a.reshape(P, n, *a.shape[1:]), tree)
-
-    layers = {k: v for k, v in params["layers"].items() if k not in BANKS}
-    return split(layers, len(period)), split(params["gqa"], ng), split(params["kda"], nk)
-
-
-def _at(tree, i: int):
-    return jax.tree.map(lambda a: a[i], tree)
+def _at(tree, i):
+    """Layer `i` (traced) of a stacked tree. Always of the WHOLE stack, by the
+    layer's own index: a period's slice [n, ...] taken first and indexed after
+    is copied out every period (1.4 GiB of temporaries a decode round at
+    Olmo-Hybrid's widths, seen in the described-chip compile)."""
+    return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree)
 
 
 def _ffn(cfg: ModelConfig, lp: Params, banks: Params, li, h: jnp.ndarray, valid):
-    """Pre-norm expert layer `li` and residual add on [..., D]; (h, counts [5])."""
-    from .llama import _norm
+    """Feed-forward of layer `li` and residual add on [..., D]: (h, counts [5]
+    of the expert layer, None for the dense gated MLP)."""
+    from .llama import _ffn_residual, _sub_in, _sub_out
 
+    if not cfg.n_experts:
+        return _ffn_residual(cfg, lp, h), None
     with jax.named_scope("ffn"):
-        x = _norm(cfg, h, lp["ffn_norm"])
+        x = _sub_in(cfg, h, lp["ffn_norm"])
         y, counts = moe_share_ffn(
             cfg, lp, x.reshape(-1, x.shape[-1]),
             valid=None if valid is None else valid.reshape(-1), banks=banks, layer=li)
-        return h + y.reshape(h.shape), counts
+        return h + _sub_out(cfg, y.reshape(h.shape), lp["ffn_norm"]), counts
+
+
+def _counted(cache_v: dict, phase: int, counts) -> dict:
+    """The expert counts of one call onto the running sums the cache pair
+    carries (decode steps under 0, prefills under 1); nothing without them."""
+    return {} if counts is None else {"moe": cache_v["moe"].at[phase].add(counts)}
 
 
 def _period_scan(cfg: ModelConfig, params: Params, h, carry, gqa_layer, kda_layer, valid):
     """Scan the periods. `gqa_layer(h, carry, lp, ig)` and `kda_layer(h,
     carry, lp, ik)` run one layer's mixing half on the running `carry` (the
     caches, as the caller shapes it), `ig` / `ik` being the layer's index
-    among its kind; the expert layer follows either. Returns (h, carry,
-    counts [L, 5]), and whatever the GQA layers stacked as ys, [P ng, ...]."""
+    among its kind; the feed-forward follows either. Returns (h, carry,
+    counts [L, 5] of the expert layers or None), and whatever the GQA layers
+    stacked as ys, [P ng, ...]."""
     period, P, ng, nk = _layout(cfg)
-    banks = {k: params["layers"][k] for k in BANKS}
+    banks = {k: params["layers"][k] for k in BANKS if k in params["layers"]}
+    layers = {k: v for k, v in params["layers"].items() if k not in BANKS}
 
-    def body(c, xs):
+    def body(c, _):
         h, carry, p = c
-        layers, gqa, kda = xs
         ig = ik = 0
         ys, counts = [], []
         for i, kind in enumerate(period):
-            lp = _at(layers, i)
+            li = p * len(period) + i
+            lp = _at(layers, li)
             if kind == "gqa":
-                h, carry, y = gqa_layer(h, carry, {**lp, **_at(gqa, ig)}, p * ng + ig)
+                h, carry, y = gqa_layer(h, carry, {**lp, **_at(params["gqa"], p * ng + ig)}, p * ng + ig)
                 ys.append(y)
                 ig += 1
             else:
-                h, carry = kda_layer(h, carry, {**lp, **_at(kda, ik)}, p * nk + ik)
+                h, carry = kda_layer(h, carry, {**lp, **_at(params["kda"], p * nk + ik)}, p * nk + ik)
                 ik += 1
-            h, n = _ffn(cfg, lp, banks, p * len(period) + i, h, valid)
+            h, n = _ffn(cfg, lp, banks, li, h, valid)
             counts.append(n)
         ys = jax.tree.map(lambda *a: jnp.stack(a), *ys) if ys and ys[0] is not None else None
-        return (h, carry, p + 1), (ys, jnp.stack(counts))
+        return (h, carry, p + 1), (ys, jnp.stack(counts) if cfg.n_experts else None)
 
     (h, carry, _), (ys, counts) = jax.lax.scan(
-        body, (h, carry, jnp.int32(0)), _by_period(cfg, params))
+        body, (h, carry, jnp.int32(0)), None, length=P)
     if ys is not None:
         ys = jax.tree.map(lambda a: a.reshape(P * ng, *a.shape[2:]), ys)
-    return h, carry, counts.reshape(cfg.n_layers, 5), ys
+    return h, carry, None if counts is None else counts.reshape(cfg.n_layers, 5), ys
 
 
 def hybrid_prefill(cfg, params, tokens, lengths, attn_impl="xla", quant_kv=False):
     """Whole fresh prompts [B, S] from zero state: (last logits [B, V], ks,
     vs) with ks the GQA layers' prompt K/V as `llama_prefill` returns them and
-    vs = {"v": their second member, "state": each row's S [Lk, B, ...] and
-    conv tails, "moe": the call's expert counts [L, 5]}; the engine inserts
-    row by row (`insert_state_row`) and adds the counts once (`add_counts`)."""
-    from .llama import _embed_in, _logits, _norm, fuse_prompt_kv, prefill_attn, prefill_masks
+    vs = {"v": their second member, "state": each row's S [Lk, B, ...] (in
+    the pool's layout) and conv tails, and with routed experts "moe": the
+    call's expert counts [L, 5]}; the engine inserts row by row
+    (`insert_state_row`) and adds the counts once (`add_counts`)."""
+    from .llama import _embed_in, _logits, _sub_in, _sub_out, fuse_prompt_kv, prefill_attn, prefill_masks
 
     B, S = tokens.shape
-    H, d, taps = cfg.lin_heads, cfg.lin_head_dim, cfg.lin_conv
     _, P, _, nk = _layout(cfg)
     h = _embed_in(cfg, params, tokens)
     cos, sin, mask = prefill_masks(cfg, S, lengths)
     valid = jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None]
-    S0 = jnp.zeros((B, H, d, d), jnp.float32)
-    tail0 = jnp.zeros((B, taps - 1, 3 * H * d), h.dtype)
+    S0, tail0 = zero_state(cfg, B, h.dtype)
 
     def gqa_layer(h, carry, lp, ig):
         h, (kh, vh) = prefill_attn(cfg, lp, h, cos, sin, mask, lengths, attn_impl)
@@ -211,16 +237,19 @@ def hybrid_prefill(cfg, params, tokens, lengths, attn_impl="xla", quant_kv=False
 
     def kda_layer(h, carry, lp, ik):
         Ss, tails = carry
-        y, S_new, tail = kda_prefill(cfg, lp, _norm(cfg, h, lp["attn_norm"]), lengths, S0, tail0)
-        return h + y, (Ss.at[ik].set(S_new), tails.at[ik].set(tail))
+        y, S_new, tail = kda_prefill(
+            cfg, lp, _sub_in(cfg, h, lp["attn_norm"]), lengths, S0, tail0)
+        return h + _sub_out(cfg, y, lp["attn_norm"]), (
+            Ss.at[ik].set(pool_rows(cfg, S_new)), tails.at[ik].set(tail.reshape(B, -1)))
 
-    carry = (jnp.zeros((P * nk, *S0.shape), jnp.float32),
-             jnp.zeros((P * nk, *tail0.shape), tail0.dtype))
+    carry = (jnp.zeros((P * nk, *pool_rows(cfg, S0).shape), jnp.float32),
+             jnp.zeros((P * nk, B, tail0[0].size), tail0.dtype))
     h, (Ss, tails), counts, (ks, vs) = _period_scan(
         cfg, params, h, carry, gqa_layer, kda_layer, valid)
     last = jnp.take_along_axis(h, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
     return _logits(cfg, params, last), ks, {
-        "v": vs, "state": {"S": Ss, "conv": tails}, "moe": counts}
+        "v": vs, "state": {"S": Ss, "conv": tails},
+        **({} if counts is None else {"moe": counts})}
 
 
 def insert_state_row(state: dict, new: dict, i, slot) -> dict:
@@ -236,7 +265,7 @@ def insert_state_row(state: dict, new: dict, i, slot) -> dict:
 def add_counts(cache_v: dict, new: dict) -> dict:
     """A prefill's expert counts [L, 5] (the call's, not a row's) onto the
     running sums the cache pair carries."""
-    return dict(cache_v, moe=cache_v["moe"].at[1].add(new["moe"]))
+    return dict(cache_v, **_counted(cache_v, 1, new.get("moe")))
 
 
 def hybrid_prefill_chunk_batch(
@@ -249,7 +278,7 @@ def hybrid_prefill_chunk_batch(
     prompt's first (start 0: a reused slot's old state is never read), and
     writes both back. Rows that duplicate row 0 (the engine's padding) write
     what row 0 writes."""
-    from .llama import _chunk_attention, _logits, _norm
+    from .llama import _chunk_attention, _logits, _sub_in, _sub_out
 
     A, C = tokens.shape
     kv_v, state = cache_v["v"], cache_v["state"]
@@ -267,17 +296,28 @@ def hybrid_prefill_chunk_batch(
 
     def kda_layer(h, carry, lp, ik):
         ck, cv, S, conv = carry
-        S0 = jnp.where(fresh[:, None, None, None], 0.0, S[ik, slots])
-        tail0 = jnp.where(fresh[:, None, None], 0, conv[ik, slots])
-        y, S_new, tail = kda_prefill(cfg, lp, _norm(cfg, h, lp["attn_norm"]), nvalid, S0, tail0)
+        # row by row, as they are written: a gather of rows of 384 lanes made the
+        # compiler copy the whole pool in three slabs of 128 (2 GiB at Olmo-Hybrid's
+        # size, seen in the described-chip compile)
+        def rows_of(pool):
+            return jnp.stack([jax.lax.dynamic_slice(
+                pool, (ik, slots[a]) + (0,) * (pool.ndim - 2), (1, 1, *pool.shape[2:]))[0, 0]
+                for a in range(A)])
+
+        S0 = jnp.where(fresh[:, None, None, None], 0.0, head_major(cfg, rows_of(S)))
+        tail0 = jnp.where(fresh[:, None, None], 0, rows_of(conv).reshape(A, cfg.lin_conv - 1, -1))
+        y, S_new, tail = kda_prefill(
+            cfg, lp, _sub_in(cfg, h, lp["attn_norm"]), nvalid, S0, tail0)
+        S_new = pool_rows(cfg, S_new)
         for a in range(A):  # row by row: duplicates of row 0 land on row 0's values
             S = jax.lax.dynamic_update_slice(S, S_new[a][None, None], (ik, slots[a], 0, 0, 0))
-            conv = jax.lax.dynamic_update_slice(conv, tail[a][None, None], (ik, slots[a], 0, 0))
-        return h + y, (ck, cv, S, conv)
+            conv = jax.lax.dynamic_update_slice(
+                conv, tail[a].reshape(1, 1, -1), (ik, slots[a], 0))
+        return h + _sub_out(cfg, y, lp["attn_norm"]), (ck, cv, S, conv)
 
     h, (ck, cv, S, conv), counts, _ = _period_scan(
         cfg, params, h, (cache_k, kv_v, state["S"], state["conv"]), gqa_layer, kda_layer, valid)
-    new_v = {"v": cv, "state": {"S": S, "conv": conv}, "moe": cache_v["moe"].at[1].add(counts)}
+    new_v = {"v": cv, "state": {"S": S, "conv": conv}, **_counted(cache_v, 1, counts)}
     if all_logits:
         return _logits(cfg, params, h), ck, new_v
     last = jnp.take_along_axis(h, (nvalid - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
@@ -291,7 +331,7 @@ def hybrid_decode_step(cfg, params, cache_k, cache_v, tokens, lengths, slot_ids=
     kernel lands them. A KDA layer steps its rows of the state pool in place
     (kernels/kda.py). A parked or padding row (length >= the cache's) moves
     nothing: not its cache rows, not its state."""
-    from .llama import _attn_residual, _cache_shape, _embed_in, _logits, _norm, _qkv
+    from .llama import _attn_residual, _cache_shape, _embed_in, _logits, _qkv, _sub_in, _sub_out
 
     if paged is not None:
         raise NotImplementedError("a recurrent configuration's blocks are never shared")
@@ -299,14 +339,14 @@ def hybrid_decode_step(cfg, params, cache_k, cache_v, tokens, lengths, slot_ids=
     kv_v, state = cache_v["v"], cache_v["state"]
     S_cache, hd = _cache_shape(cache_k)[3], cfg.resolved_head_dim
     Ba, H, Hkv = tokens.shape[0], cfg.n_heads, cfg.n_kv_heads
-    rows = jnp.arange(Ba, dtype=jnp.int32) if slot_ids is None else slot_ids.astype(jnp.int32)
+    rows = None if slot_ids is None else slot_ids.astype(jnp.int32)
     live = lengths < S_cache
     attend = decode_attend_q8 if quantized else decode_attend_bf16
     h = _embed_in(cfg, params, tokens)
 
     def gqa_layer(h, carry, lp, ig):
         with jax.named_scope("attn"):
-            x = _norm(cfg, h, lp["attn_norm"])
+            x = _sub_in(cfg, h, lp["attn_norm"])
             q, k, v = _qkv(cfg, lp, x)
             k, v = k.reshape(Ba, Hkv, hd), v.reshape(Ba, Hkv, hd)
             ctx = attend(
@@ -316,10 +356,10 @@ def hybrid_decode_step(cfg, params, cache_k, cache_v, tokens, lengths, slot_ids=
             return _attn_residual(cfg, lp, ctx, h, x), carry, (k, v)
 
     def kda_layer(h, carry, lp, ik):
-        with jax.named_scope("kda"):
+        with jax.named_scope(cfg.lin_gates):  # "kda" | "gdn"
             y, carry = kda_decode(
-                cfg, lp, _norm(cfg, h, lp["attn_norm"]), carry, ik, rows, live)
-            return h + y, carry
+                cfg, lp, _sub_in(cfg, h, lp["attn_norm"]), carry, ik, rows, live)
+            return h + _sub_out(cfg, y, lp["attn_norm"]), carry
 
     h, lin, counts, (knew, vnew) = _period_scan(
         cfg, params, h, state, gqa_layer, kda_layer, live)
@@ -327,4 +367,4 @@ def hybrid_decode_step(cfg, params, cache_k, cache_v, tokens, lengths, slot_ids=
         append = append_kv_q8 if quantized else append_kv_bf16
         new_k, new_kv_v = append(cache_k, kv_v, knew, vnew, lengths, slot_ids=slot_ids)
     return _logits(cfg, params, h), new_k, {
-        "v": new_kv_v, "state": lin, "moe": cache_v["moe"].at[0].add(counts)}
+        "v": new_kv_v, "state": lin, **_counted(cache_v, 0, counts)}

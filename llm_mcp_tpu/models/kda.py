@@ -1,5 +1,7 @@
-"""Kimi Delta Attention (KDA): a gated delta-rule linear-attention layer with a
-per-channel decay, as a layer of the hybrid decoder (models/hybrid.py).
+"""The gated delta rule as a linear-attention layer of the hybrid decoder
+(models/hybrid.py), with two forms of gate (`cfg.lin_gates`): Kimi Delta
+Attention ("kda": a decay a key channel) and Gated DeltaNet ("gdn": one decay
+a head; Yang, Kautz, Hatamizadeh, arXiv 2412.06464).
 
 For every sequence and head the layer keeps a float32 state S of
 [keys dk, values dv] instead of keys and values of the past:
@@ -7,12 +9,16 @@ For every sequence and head the layer keeps a float32 state S of
     q, k, v = SiLU(conv(x Wq)), SiLU(conv(x Wk)), SiLU(conv(x Wv))
               (causal depthwise convolution, `lin_conv` taps a channel)
     q, k    = q / |q| * dk**-0.5, k / |k|            (L2 over the head)
-    g_t     = -exp(A_log_h) * softplus(x Wf_down Wf_up + dt_bias)   per channel
     beta_t  = sigmoid(x W_beta) (* 2 with `lin_neg_eigval`)          per head
     S'      = diag(exp(g_t)) S_{t-1}
     S_t     = S' + beta_t k_t (v_t - S'^T k_t)^T
     o_t     = S_t^T q_t
-    y       = (RMSNorm_head(o_t) * sigmoid(x Wg_down Wg_up)) Wo
+    y       = (RMSNorm_head(o_t) * gate) Wo
+
+    kda: g_t = -exp(A_log_h) * softplus(x Wf_down Wf_up + dt_bias)  per channel
+         gate = sigmoid(x Wg_down Wg_up)          (both through a low rank)
+    gdn: g_t = -exp(A_log_h) * softplus(x W_a + dt_bias_h)          per head
+         gate = SiLU(x W_g)                        (both at full rank)
 
 What a slot owns of a layer is S and the convolution's tail: the last
 `lin_conv - 1` rows of x [Wq | Wk | Wv] (before the convolution), so that the
@@ -29,10 +35,11 @@ Two forms of one recurrence:
   exponentials, so a channel that forgets fast cannot overflow.
 - one token (`kda_decode`): the Pallas kernel of kernels/kda.py on the pool.
 
-The layer's parameters (stacked [Lk, ...] under params["kda"]; C = H dk):
-wqkv_lin [D, 3C], conv_w [taps, 3C], wfg_down [D, 2r] (decay | output gate),
-wf_up [r, C], wg_up [r, C], dt_bias [C], A_log [H], w_beta [D, H],
-o_norm [dv], wo_lin [C, D]."""
+The layer's parameters (stacked [Lk, ...] under params["kda"]; Ck = H dk,
+Cv = H dv, W = 2 Ck + Cv): wqkv_lin [D, W] (q | k | v), conv_w [taps, W],
+A_log [H], w_beta [D, H], o_norm [dv], wo_lin [Cv, D]; with "kda" gates
+wfg_down [D, 2r] (decay | output gate), wf_up [r, Ck], wg_up [r, Cv], dt_bias
+[Ck]; with "gdn" gates w_a [D, H], dt_bias [H], wg_lin [D, Cv]."""
 
 from __future__ import annotations
 
@@ -42,7 +49,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..kernels.kda import kda_decode_step
+from ..kernels.kda import heads_abreast, kda_decode_step, pack_state, unpack_state
 from ..ops.norms import rms_norm
 from .configs import ModelConfig
 from .quant import qdot
@@ -53,11 +60,24 @@ _L2_EPS = 1e-6
 
 
 def kda_sizes(cfg: ModelConfig) -> tuple[int, int, int, int]:
-    """(heads, head size, gate rank, taps). The low rank of the decay and
-    output gates is the head size (Kimi Linear's convention): no configuration
-    states another, so it is no field."""
-    d = cfg.lin_head_dim
-    return cfg.lin_heads, d, d, cfg.lin_conv
+    """(heads, key head size, value head size, taps)."""
+    return cfg.lin_heads, cfg.lin_head_dim, cfg.lin_dv, cfg.lin_conv
+
+
+def conv_width(cfg: ModelConfig) -> int:
+    """Channels of x [Wq | Wk | Wv], which the convolution runs over."""
+    H, dk, dv, _ = kda_sizes(cfg)
+    return H * (2 * dk + dv)
+
+
+def state_abreast(cfg: ModelConfig) -> int:
+    """Heads side by side in the pool's layout (kernels/kda.py)."""
+    return heads_abreast(cfg.lin_heads, cfg.lin_dv)
+
+
+def step_kernel_name(cfg: ModelConfig) -> str:
+    """The one-step state kernel's name in a trace, by the form of gate."""
+    return f"{cfg.lin_gates}_decode_step"
 
 
 def init_kda_params(cfg: ModelConfig, key: jax.Array, dtype, n_layers: int) -> dict[str, Any]:
@@ -65,62 +85,99 @@ def init_kda_params(cfg: ModelConfig, key: jax.Array, dtype, n_layers: int) -> d
     scaling like every other linear. The decay's two leaves follow the
     state-space convention so that no channel is degenerate: A = exp(A_log)
     log-uniform in [1, 16] a head, and dt_bias the inverse softplus of a step
-    log-uniform in [1e-3, 1e-1] a channel: with the low-rank projection's
-    unit-variance output on top, alpha = exp(g) spreads from about 0.1 (a
-    channel that forgets within a few tokens) to 0.999 (one that remembers
-    for a thousand)."""
-    H, d, r, taps = kda_sizes(cfg)
-    D, C, L = cfg.dim, H * d, n_layers
+    log-uniform in [1e-3, 1e-1] a channel (a head with "gdn" gates): with the
+    projection's unit-variance output on top, alpha = exp(g) spreads from
+    about 0.1 (a channel that forgets within a few tokens) to 0.999 (one that
+    remembers for a thousand). The low rank of KDA's two gates is the key
+    head size (Kimi Linear's convention): no configuration states another, so
+    it is no field."""
+    H, dk, dv, taps = kda_sizes(cfg)
+    D, Ck, Cv, W, L = cfg.dim, H * dk, H * dv, conv_width(cfg), n_layers
     ks = jax.random.split(key, 10)
 
     def w(k, shape, fan_in):
         return (jax.random.normal(k, shape, jnp.float32) * fan_in**-0.5).astype(dtype)
 
-    dt = jnp.exp(jax.random.uniform(ks[6], (L, C), jnp.float32, math.log(1e-3), math.log(1e-1)))
-    return {
-        "wqkv_lin": w(ks[0], (L, D, 3 * C), D),
-        "conv_w": w(ks[1], (L, taps, 3 * C), taps),
-        "wfg_down": w(ks[2], (L, D, 2 * r), D),
-        "wf_up": w(ks[3], (L, r, C), r),
-        "wg_up": w(ks[4], (L, r, C), r),
+    per_head = cfg.lin_gates == "gdn"
+    dt = jnp.exp(jax.random.uniform(
+        ks[6], (L, H if per_head else Ck), jnp.float32, math.log(1e-3), math.log(1e-1)))
+    params = {
+        "wqkv_lin": w(ks[0], (L, D, W), D),
+        "conv_w": w(ks[1], (L, taps, W), taps),
         "w_beta": w(ks[5], (L, D, H), D),
         "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),  # softplus^-1(dt), float32
         "A_log": jnp.log(jax.random.uniform(ks[7], (L, H), jnp.float32, 1.0, 16.0)),
-        "o_norm": jnp.ones((L, d), dtype),
-        "wo_lin": w(ks[8], (L, C, D), C),
+        "o_norm": jnp.ones((L, dv), dtype),
+        "wo_lin": w(ks[8], (L, Cv, D), Cv),
     }
+    if per_head:
+        params.update(w_a=w(ks[2], (L, D, H), D), wg_lin=w(ks[3], (L, D, Cv), D))
+    else:
+        r = dk
+        params.update(wfg_down=w(ks[2], (L, D, 2 * r), D), wf_up=w(ks[3], (L, r, Ck), r),
+                      wg_up=w(ks[4], (L, r, Cv), r))
+    return params
 
 
 def init_kda_state(cfg: ModelConfig, n_layers: int, slots: int, dtype) -> dict[str, jnp.ndarray]:
-    """The pool: {"S": f32 [Lk, slots, H, dk, dv], "conv": [Lk, slots, taps-1, 3C]}."""
-    H, d, _, taps = kda_sizes(cfg)
+    """The pool: {"S": f32 [Lk, slots, H / P, dk, P dv] (P heads abreast, so
+    that no row pads in HBM: kernels/kda.py), "conv": [Lk, slots, (taps-1) W]
+    (a slot's tail rows end to end: an axis of 3 among the last two pads in
+    HBM and, as the minor axis of a layout the compiler once chose for a chunk
+    program, made a 2.6 GiB copy of a 63 MB pool)}."""
+    H, dk, dv, taps = kda_sizes(cfg)
+    P = state_abreast(cfg)
     return {
-        "S": jnp.zeros((n_layers, slots, H, d, d), jnp.float32),
-        "conv": jnp.zeros((n_layers, slots, taps - 1, 3 * H * d), dtype),
+        "S": jnp.zeros((n_layers, slots, H // P, dk, P * dv), jnp.float32),
+        "conv": jnp.zeros((n_layers, slots, (taps - 1) * conv_width(cfg)), dtype),
     }
 
 
+def zero_state(cfg: ModelConfig, rows: int, dtype) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(S0 [rows, H, dk, dv] f32, tail0 [rows, taps-1, W]) of fresh prompts."""
+    H, dk, dv, taps = kda_sizes(cfg)
+    return (jnp.zeros((rows, H, dk, dv), jnp.float32),
+            jnp.zeros((rows, taps - 1, conv_width(cfg)), dtype))
+
+
 def _gates(cfg: ModelConfig, kp: dict, x: jnp.ndarray):
-    """x [..., D] -> (g [..., H, dk] f32 log decay <= 0, beta [..., H] f32,
-    output gate [..., C] in x's dtype)."""
-    H, d, r, _ = kda_sizes(cfg)
-    low = qdot(x, kp["wfg_down"])
-    f = qdot(low[..., :r], kp["wf_up"]).astype(jnp.float32) + kp["dt_bias"].astype(jnp.float32)
-    g = -jnp.exp(kp["A_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
-        f.reshape(*f.shape[:-1], H, d))
+    """x [..., D] -> (g f32 log decay <= 0: [..., H, dk] a channel, [..., H]
+    a head; beta [..., H] f32; output gate [..., Cv] in x's dtype)."""
+    H, dk, _, _ = kda_sizes(cfg)
+    A = -jnp.exp(kp["A_log"].astype(jnp.float32))
+    if cfg.lin_gates == "gdn":
+        f = qdot(x, kp["w_a"]).astype(jnp.float32) + kp["dt_bias"].astype(jnp.float32)
+        g = A * jax.nn.softplus(f)
+        out_gate = jax.nn.silu(qdot(x, kp["wg_lin"]).astype(jnp.float32)).astype(x.dtype)
+    else:
+        r = dk
+        low = qdot(x, kp["wfg_down"])
+        f = qdot(low[..., :r], kp["wf_up"]).astype(jnp.float32) + kp["dt_bias"].astype(jnp.float32)
+        g = A[:, None] * jax.nn.softplus(f.reshape(*f.shape[:-1], H, dk))
+        out_gate = jax.nn.sigmoid(
+            qdot(low[..., r:], kp["wg_up"]).astype(jnp.float32)).astype(x.dtype)
     beta = jax.nn.sigmoid(qdot(x, kp["w_beta"]).astype(jnp.float32))
     if cfg.lin_neg_eigval:
         beta = beta * 2.0
-    out_gate = jax.nn.sigmoid(qdot(low[..., r:], kp["wg_up"]).astype(jnp.float32)).astype(x.dtype)
     return g, beta, out_gate
 
 
 def _heads(cfg: ModelConfig, mixed: jnp.ndarray):
-    """SiLU(conv(.)) rows [..., 3C] -> q, k, v [..., H, d] float32, q and k
-    L2-normalised a head and q scaled by d**-0.5."""
-    H, d, _, _ = kda_sizes(cfg)
-    a = jax.nn.silu(mixed.astype(jnp.float32)).reshape(*mixed.shape[:-1], 3, H, d)
-    q, k, v = a[..., 0, :, :], a[..., 1, :, :], a[..., 2, :, :]
+    """SiLU(conv(.)) rows [..., W] -> q, k [..., H, dk], v [..., H, dv]
+    float32, q and k L2-normalised a head and q scaled by dk**-0.5."""
+    H, d, dv, _ = kda_sizes(cfg)
+    a = jax.nn.silu(mixed.astype(jnp.float32))
+    lead, Ck = a.shape[:-1], H * d
+    if d == dv:
+        # three parts of one size are one array [3, H, d]: cut as three slices
+        # the compiler laid Solar's out with six more copies an admit program
+        # and a decode round 0.3 ms slower (v5e, PR 35)
+        a = a.reshape(*lead, 3, H, d)
+        q, k, v = a[..., 0, :, :], a[..., 1, :, :], a[..., 2, :, :]
+    else:
+        q = a[..., :Ck].reshape(*lead, H, d)
+        k = a[..., Ck : 2 * Ck].reshape(*lead, H, d)
+        v = a[..., 2 * Ck :].reshape(*lead, H, dv)
 
     def unit(t):
         return t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + _L2_EPS)
@@ -137,20 +194,24 @@ def _out(cfg: ModelConfig, kp: dict, o: jnp.ndarray, out_gate: jnp.ndarray, dtyp
 def kda_chunk_scan(q, k, v, g, beta, S0):
     """The recurrence over a whole (padded) sequence, chunk by chunk.
 
-    q, k [A, T, H, dk], v [A, T, H, dv], g [A, T, H, dk] (log decay, 0 at a
-    padding position), beta [A, T, H] (0 at a padding position), S0
-    [A, H, dk, dv]; all float32. Returns (o [A, T, H, dv], S_T). A padding
-    position leaves the state as it was (alpha 1, beta 0) and its output is
-    never read.
+    q, k [A, T, H, dk], v [A, T, H, dv], g the log decay (0 at a padding
+    position): [A, T, H, dk] a key channel, or [A, T, H] one a head; beta
+    [A, T, H] (0 at a padding position), S0 [A, H, dk, dv]; all float32.
+    Returns (o [A, T, H, dv], S_T). A padding position leaves the state as it
+    was (alpha 1, beta 0) and its output is never read.
 
     Inside a chunk, with G the cumulative log decay (inclusive) and
     kk[t, s] = sum_d k_t k_s exp(G_t - G_s) for s < t, the corrections U solve
     (I + diag(beta) kk) U = beta (V - (K exp(G)) S0); then
     o = (Q exp(G)) S0 + qk U with qk[t, s] likewise for s <= t, and
-    S_end = exp(G_end) S0 + (K exp(G_end - G))^T U."""
+    S_end = exp(G_end) S0 + (K exp(G_end - G))^T U. With one decay a head the
+    decay between two positions is a [C, C] matrix and kk, qk are the products
+    K K^T, Q K^T masked by it; a decay a channel stands inside the sum over
+    d, a [C, C, dk] tensor a chunk."""
     A, T, H, dk = q.shape
     C = math.gcd(T, CHUNK)
     N = T // C
+    per_head = g.ndim == 3
 
     def chunks(x):  # [A, T, H, ...] -> [N, A, H, C, ...]
         x = x.reshape(A, N, C, *x.shape[2:])
@@ -158,17 +219,24 @@ def kda_chunk_scan(q, k, v, g, beta, S0):
 
     t_idx = jnp.arange(C)
     earlier = t_idx[:, None] > t_idx[None, :]
+    upto = t_idx[:, None] >= t_idx[None, :]
     eye = jnp.eye(C, dtype=jnp.float32)
 
     def step(S, xs):
-        q, k, v, g, beta = xs  # [A, H, C, .]; beta [A, H, C, 1]
+        q, k, v, g, beta = xs  # [A, H, C, .]; beta [A, H, C, 1]; g [A, H, C] a head
         G = jnp.cumsum(g, axis=2)
-        # decay from position s to position t >= s, per channel: exp(<= 0)
-        decay = jnp.exp(jnp.minimum(G[:, :, :, None, :] - G[:, :, None, :, :], 0.0))
-        kd = k[:, :, None, :, :] * decay  # [A, H, t, s, dk]
-        kk = jnp.where(earlier, jnp.sum(k[:, :, :, None, :] * kd, axis=-1), 0.0)
-        qk = jnp.where(earlier | eye.astype(bool),
-                       jnp.sum(q[:, :, :, None, :] * kd, axis=-1), 0.0)
+        # decay from position s to position t >= s: exp(<= 0)
+        if per_head:
+            decay = jnp.exp(jnp.minimum(G[:, :, :, None] - G[:, :, None, :], 0.0))  # [A, H, t, s]
+            kT = jnp.swapaxes(k, -1, -2)
+            kk = jnp.where(earlier, jnp.matmul(k, kT, precision=_HI) * decay, 0.0)
+            qk = jnp.where(upto, jnp.matmul(q, kT, precision=_HI) * decay, 0.0)
+            G = G[..., None]  # [A, H, C, 1]: broadcasts over the keys below
+        else:
+            decay = jnp.exp(jnp.minimum(G[:, :, :, None, :] - G[:, :, None, :, :], 0.0))
+            kd = k[:, :, None, :, :] * decay  # [A, H, t, s, dk]
+            kk = jnp.where(earlier, jnp.sum(k[:, :, :, None, :] * kd, axis=-1), 0.0)
+            qk = jnp.where(upto, jnp.sum(q[:, :, :, None, :] * kd, axis=-1), 0.0)
         eG = jnp.exp(G)
         rhs = beta * (v - jnp.matmul(k * eG, S, precision=_HI))
         U = jax.scipy.linalg.solve_triangular(
@@ -188,18 +256,18 @@ def kda_chunk_scan(q, k, v, g, beta, S0):
 def kda_prefill(
     cfg: ModelConfig,
     kp: dict,  # this layer's weights (un-stacked)
-    x: jnp.ndarray,  # [A, T, D] normed activations of a chunk (or a whole prompt)
+    x: jnp.ndarray,  # [A, T, D] the layer's input of a chunk (or a whole prompt)
     nvalid: jnp.ndarray,  # [A] int32: valid positions of each row
     S0: jnp.ndarray,  # [A, H, dk, dv] f32
-    tail0: jnp.ndarray,  # [A, taps-1, 3C]
+    tail0: jnp.ndarray,  # [A, taps-1, W]
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """The layer over a chunk that continues (S0, tail0): (y [A, T, D], S,
     tail), the last two as they stand after each row's `nvalid` positions."""
     A, T, _ = x.shape
     taps = cfg.lin_conv
-    with jax.named_scope("kda_prefill"):
-        proj = qdot(x, kp["wqkv_lin"])  # [A, T, 3C]
-        full = jnp.concatenate([tail0.astype(proj.dtype), proj], axis=1)  # [A, taps-1+T, 3C]
+    with jax.named_scope(f"{cfg.lin_gates}_prefill"):
+        proj = qdot(x, kp["wqkv_lin"])  # [A, T, W]
+        full = jnp.concatenate([tail0.astype(proj.dtype), proj], axis=1)  # [A, taps-1+T, W]
         mixed = sum(
             full[:, j : j + T] * kp["conv_w"][j].astype(proj.dtype) for j in range(taps))
         tail = jax.vmap(
@@ -207,9 +275,9 @@ def kda_prefill(
         )(full, nvalid)  # rows [n, n + taps - 1) of `full` are the last taps-1 projections
         q, k, v = _heads(cfg, mixed)
         g, beta, out_gate = _gates(cfg, kp, x)
-        valid = (jnp.arange(T)[None, :] < nvalid[:, None])[..., None]  # [A, T, 1]
-        g = jnp.where(valid[..., None], g, 0.0)
-        beta = jnp.where(valid, beta, 0.0)
+        valid = jnp.arange(T)[None, :] < nvalid[:, None]  # [A, T]
+        g = jnp.where(valid.reshape(A, T, *(1,) * (g.ndim - 2)), g, 0.0)
+        beta = jnp.where(valid[..., None], beta, 0.0)
         o, S = kda_chunk_scan(q, k, v, g, beta, S0)
         return _out(cfg, kp, o, out_gate, x.dtype), S, tail.astype(tail0.dtype)
 
@@ -217,20 +285,46 @@ def kda_prefill(
 def kda_decode(
     cfg: ModelConfig,
     kp: dict,
-    x: jnp.ndarray,  # [Ba, D] normed activations, one token a row
+    x: jnp.ndarray,  # [Ba, D] the layer's input, one token a row
     state: dict,  # the pool (init_kda_state)
     layer: jnp.ndarray,  # int32 scalar: the pool's layer
-    slot_ids: jnp.ndarray,  # [Ba] int32 pool rows
+    slot_ids: jnp.ndarray | None,  # [Ba] int32 pool rows; None: row b is slot b, all of them
     live: jnp.ndarray,  # [Ba] bool: a parked or padding row moves nothing
 ) -> tuple[jnp.ndarray, dict]:
     """One token through the layer on the pool: (y [Ba, D], the pool)."""
-    proj = qdot(x, kp["wqkv_lin"])  # [Ba, 3C]
-    tail = state["conv"][layer, slot_ids]  # [Ba, taps-1, 3C]
-    full = jnp.concatenate([tail.astype(proj.dtype), proj[:, None]], axis=1)  # [Ba, taps, 3C]
-    mixed = jnp.sum(full * kp["conv_w"].astype(proj.dtype)[None], axis=1)
-    new_tail = jnp.where(live[:, None, None], full[:, 1:].astype(tail.dtype), tail)
-    conv = state["conv"].at[layer, slot_ids].set(new_tail)
+    Ba, taps = x.shape[0], cfg.lin_conv
+    proj = qdot(x, kp["wqkv_lin"])  # [Ba, W]
+    W = proj.shape[-1]
+    whole = slot_ids is None  # the full batch: the layer's tails are one block, no scatter
+    tail = (jax.lax.dynamic_index_in_dim(state["conv"], layer, 0, keepdims=False) if whole
+            else state["conv"][layer, slot_ids])  # [Ba, (taps-1) W]: a slot's rows end to end
+    # the taps as slices of the flat row, W a whole number of lanes: as
+    # [Ba, taps, W] every step re-laid the rows out twice (68 us a layer and
+    # step at Solar's width, 0.8 ms a round: v5e, PR 35)
+    full = jnp.concatenate([tail.astype(proj.dtype), proj], axis=-1)  # [Ba, taps W]
+    conv_w = kp["conv_w"].astype(proj.dtype)
+    mixed = sum(full[:, j * W : (j + 1) * W] * conv_w[j] for j in range(taps))
+    new_tail = jnp.where(live[:, None], full[:, W:].astype(tail.dtype), tail)
+    if whole:
+        # a scatter of 64 rows runs row after row on the chip (a seventh of the
+        # device in Olmo-Hybrid's cell, 15 layers x 4 steps a round); the block does not
+        conv = jax.lax.dynamic_update_slice(state["conv"], new_tail[None], (layer, 0, 0))
+        slot_ids = jnp.arange(Ba, dtype=jnp.int32)
+    else:
+        conv = state["conv"].at[layer, slot_ids].set(new_tail)
     q, k, v = _heads(cfg, mixed)
     g, beta, out_gate = _gates(cfg, kp, x)
-    o, S = kda_decode_step(state["S"], layer, slot_ids, live, q, k, v, jnp.exp(g), beta)
+    o, S = kda_decode_step(
+        state["S"], layer, slot_ids, live, q, k, v, jnp.exp(g), beta,
+        name=step_kernel_name(cfg))
     return _out(cfg, kp, o, out_gate, x.dtype), {"S": S, "conv": conv}
+
+
+def pool_rows(cfg: ModelConfig, S: jnp.ndarray) -> jnp.ndarray:
+    """Head-major states [..., H, dk, dv] in the pool's layout."""
+    return pack_state(S, state_abreast(cfg))
+
+
+def head_major(cfg: ModelConfig, rows: jnp.ndarray) -> jnp.ndarray:
+    """Rows of the pool [..., H / P, dk, P dv] as head-major [..., H, dk, dv]."""
+    return unpack_state(rows, state_abreast(cfg))

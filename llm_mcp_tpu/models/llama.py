@@ -109,9 +109,11 @@ def init_llama_params(
         layers["bk"] = jnp.zeros((L, Hkv * hd), dtype=dtype)
         layers["bv"] = jnp.zeros((L, Hkv * hd), dtype=dtype)
     if cfg.qk_norm:
-        # Qwen3 per-head q/k RMSNorm: one [hd] weight vector per layer
-        layers["q_norm"] = jnp.ones((L, hd), dtype=dtype)
-        layers["k_norm"] = jnp.ones((L, hd), dtype=dtype)
+        # Qwen3 per-head q/k RMSNorm: one [hd] weight vector per layer; OLMo's
+        # runs over the whole projection width (cfg.qk_norm_whole)
+        nq, nk = qk_norm_widths(cfg)
+        layers["q_norm"] = jnp.ones((L, nq), dtype=dtype)
+        layers["k_norm"] = jnp.ones((L, nk), dtype=dtype)
     if cfg.post_norms:
         layers["post_attn_norm"] = norm_init
         layers["post_ffn_norm"] = norm_init
@@ -244,6 +246,27 @@ def _norm(cfg: ModelConfig, x: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     return _rms_norm(x, w, cfg.norm_eps)
 
 
+def _sub_in(cfg: ModelConfig, h: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """What a sub-layer (mixing or feed-forward) reads: the normed residual
+    stream under `norm_placement` "input", the stream itself under "output"
+    (the norm then sits on the sub-layer's output: `_attn_residual`,
+    `_ffn_residual`, with the same weight `w`)."""
+    return _norm(cfg, h, w) if cfg.norm_placement == "input" else h
+
+
+def _sub_out(cfg: ModelConfig, y: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """A sub-layer's output as it joins the residual stream: normed with the
+    same `w` under `norm_placement` "output", as it is under "input"."""
+    return _norm(cfg, y, w) if cfg.norm_placement == "output" else y
+
+
+def qk_norm_widths(cfg: ModelConfig) -> tuple[int, int]:
+    """Lengths of a layer's q_norm and k_norm vectors: the whole projection
+    (OLMo, `qk_norm_whole`) or one head (Qwen3)."""
+    hd = cfg.resolved_head_dim
+    return (cfg.n_heads * hd, cfg.n_kv_heads * hd) if cfg.qk_norm_whole else (hd, hd)
+
+
 def _act(cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     return jax.nn.gelu(x) if cfg.act == "gelu" else jax.nn.silu(x)
 
@@ -279,7 +302,12 @@ def _qkv(cfg: ModelConfig, lp: Params, x: jnp.ndarray):
             q = q + lp["bq"]
             k = k + lp["bk"]
             v = v + lp["bv"]
-    if cfg.qk_norm:
+    if cfg.qk_norm and cfg.qk_norm_whole:
+        # OLMo: RMSNorm over the whole projection width, one weight vector
+        # each ([H hd] and [Hkv hd]) a layer
+        q = _rms_norm(q, lp["q_norm"], cfg.norm_eps)
+        k = _rms_norm(k, lp["k_norm"], cfg.norm_eps)
+    elif cfg.qk_norm:
         # Qwen3: per-head RMSNorm over head_dim, applied pre-rope. Weights
         # are one [hd] vector per layer, shared across heads.
         hd = cfg.resolved_head_dim
@@ -298,13 +326,14 @@ def _attn_residual(
 ):
     """Output projection (+ optional post-attention norm) and residual add.
     A layer with an output gate (`wg`, cfg.attn_gate) multiplies the heads'
-    output by sigmoid(x W_gate), x being the layer's normed input."""
+    output by sigmoid(x W_gate), x being the layer's input (`_sub_in`). Under
+    `norm_placement` "output" the layer's one norm sits here."""
     if "wg" in lp:
         ctx = ctx * jax.nn.sigmoid(qdot(x, lp["wg"]).astype(jnp.float32)).astype(ctx.dtype)
     out = qdot(ctx, lp["wo"])
     if cfg.post_norms:
         out = _norm(cfg, out, lp["post_attn_norm"])
-    return h + out
+    return h + _sub_out(cfg, out, lp["attn_norm"])
 
 
 @jax.named_scope("ffn")
@@ -315,10 +344,11 @@ def _ffn_residual(
     moe_capacity: int = 0,
     moe_valid: jnp.ndarray | None = None,
 ) -> jnp.ndarray:
-    """The FFN half of a decoder layer (pre-norm, MoE or gated-MLP, optional
-    post-norm, residual add) on [..., D] activations — shared by prefill,
-    chunked prefill, and decode so layer semantics live in one place."""
-    x = _norm(cfg, h, lp["ffn_norm"])
+    """The FFN half of a decoder layer (norm by `norm_placement`, MoE or
+    gated-MLP, optional post-norm, residual add) on [..., D] activations —
+    shared by prefill, chunked prefill, and decode so layer semantics live in
+    one place."""
+    x = _sub_in(cfg, h, lp["ffn_norm"])
     # dispatch on THIS LAYER's params, not cfg: DeepSeek-style models carry
     # a dense prologue (params["dense_layers"], cfg.first_dense_layers)
     # through the same layer function as their MoE stack
@@ -345,7 +375,7 @@ def _ffn_residual(
         out = qdot(gate * up, lp["w2"])
     if cfg.post_norms:
         out = _norm(cfg, out, lp["post_ffn_norm"])
-    return h + out
+    return h + _sub_out(cfg, out, lp["ffn_norm"])
 
 
 def layer_windows(cfg: ModelConfig) -> jnp.ndarray:
@@ -425,7 +455,7 @@ def prefill_attn(
     window = jnp.asarray(window, dtype=jnp.int32)
 
     with jax.named_scope("attn"):
-        x = _norm(cfg, h, lp["attn_norm"])
+        x = _sub_in(cfg, h, lp["attn_norm"])
         q, k, v = _qkv(cfg, lp, x)
         q = q.reshape(B, S, H, hd)
         k = k.reshape(B, S, Hkv, hd)
@@ -591,7 +621,7 @@ def _decode_step_q8(
         lp, win = xs
         h, li = carry
         with jax.named_scope("attn"):
-            x = _norm(cfg, h, lp["attn_norm"])
+            x = _sub_in(cfg, h, lp["attn_norm"])
             q, k, v = _qkv(cfg, lp, x)
             q = q.reshape(Ba, H, hd)
             k = k.reshape(Ba, Hkv, hd)
@@ -648,7 +678,7 @@ def _decode_step_bf16(
         lp, win = xs
         h, li = carry
         with jax.named_scope("attn"):
-            x = _norm(cfg, h, lp["attn_norm"])
+            x = _sub_in(cfg, h, lp["attn_norm"])
             q, k, v = _qkv(cfg, lp, x)
             q = q.reshape(Ba, H, hd)
             k = k.reshape(Ba, Hkv, hd)
@@ -727,7 +757,7 @@ def _chunk_attention(
 
     def attend(h, ck_all, cv_all, li, lp, win):
         with jax.named_scope("attn"):
-            x = _norm(cfg, h, lp["attn_norm"])
+            x = _sub_in(cfg, h, lp["attn_norm"])
             q, k, v = _qkv(cfg, lp, x)
             q, k = q.reshape(A, C, H, hd), k.reshape(A, C, Hkv, hd)
             if cfg.use_rope:
@@ -1136,7 +1166,7 @@ def llama_prefill_chunk_ragged(
     def layer(carry, lp):
         h, li = carry
         with jax.named_scope("attn"):
-            x = _norm(cfg, h, lp["attn_norm"])
+            x = _sub_in(cfg, h, lp["attn_norm"])
             q, k, v = _qkv(cfg, lp, x)
             q = apply_rope(q.reshape(T, H, hd), cos, sin)
             k = apply_rope(k.reshape(T, Hkv, hd), cos, sin)
@@ -1320,7 +1350,7 @@ def llama_decode_step(
         lp, win = xs
         h, ck_all, cv_all, li = carry
         with jax.named_scope("attn"):
-            x = _norm(cfg, h, lp["attn_norm"])
+            x = _sub_in(cfg, h, lp["attn_norm"])
             q, k, v = _qkv(cfg, lp, x)
             q = q.reshape(Ba, H, hd)
             k = k.reshape(Ba, Hkv, hd)

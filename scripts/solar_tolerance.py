@@ -1,19 +1,23 @@
 #!/usr/bin/env python3
-"""The two readings of `SERVED_TOL_REL` in benchmark/references/solar_open2.py,
-through the comparison run.py makes (`benchmark.correctness.hold_to_reference`).
+"""The two readings of `SERVED_TOL_REL` in a hybrid configuration's reference
+module (benchmark/references/solar_open2.py, olmo_hybrid.py), through the
+comparison run.py makes (`benchmark.correctness.hold_to_reference`).
 
 One engine is booted as the configuration's file says. For each seed the
 harness's reference request (`reference_request`: a prompt of that seed, greedy)
 is served, and the served tokens are held
 
 - to the reference as it is: the program's reading, which must come out correct;
-- to the reference computed in each lower precision (`LOWER` there: int8
-  weights and activations, float8, a bfloat16 state): the controls. A control
-  that still comes out correct is a precision the comparison cannot tell from
-  the stated one.
+- to the reference computed under each control (`LOWER` there: int8 weights
+  and activations, float8, a bfloat16 state; the module's `CONTROLS` where it
+  names its own, as olmo_hybrid.py does for a lost state): a control that
+  still comes out correct is something the comparison cannot tell from what
+  the file states.
 
     chiprun -- python3 scripts/solar_tolerance.py --seeds 96 --controls 24
+    chiprun -- python3 scripts/solar_tolerance.py --config olmo-hybrid-7b-d20-bf16 --seeds 32 --controls 8
     python3 scripts/solar_tolerance.py --config tiny --seeds 2 --controls 1   # CPU smoke
+    python3 scripts/solar_tolerance.py --config olmo-hybrid-7b-d20-bf16 --model tiny-olmo-hybrid ...
 
 Prints one JSON line a seed and a summary; writes both to chiprun_out/.
 """
@@ -36,6 +40,8 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", default="solar-open2-250b-ep8-bf16",
                     help="a file of benchmark/configs, or `tiny` for tiny-solar on the CPU")
+    ap.add_argument("--model", default="",
+                    help="boot this TPU_MODEL with 4 slots instead of the file's (a CPU smoke)")
     ap.add_argument("--seeds", type=int, default=96)
     ap.add_argument("--controls", type=int, default=24, help="seeds each control is read on")
     ap.add_argument("--first-seed", type=int, default=3200006000)
@@ -43,11 +49,12 @@ def main() -> int:
 
     from benchmark import correctness, run as bench_run, trafficgen
 
-    config = json.load(open(os.path.join(ROOT, "benchmark", "configs",
-                                         "solar-open2-250b-ep8-bf16.json")))
-    env = {k: str(v) for k, v in config["program"]["env"].items()}
     if args.config == "tiny":
-        env.update(TPU_MODEL="tiny-solar", TPU_MAX_SLOTS="4")
+        args.config, args.model = "solar-open2-250b-ep8-bf16", "tiny-solar"
+    config = json.load(open(os.path.join(ROOT, "benchmark", "configs", args.config + ".json")))
+    env = {k: str(v) for k, v in config["program"]["env"].items()}
+    if args.model:
+        env.update(TPU_MODEL=args.model, TPU_MAX_SLOTS="4")
     os.environ.update(env)
 
     import jax
@@ -59,6 +66,7 @@ def main() -> int:
 
     ucfg.enable_compile_cache()
     name, module = bench_run.load_reference(config)
+    controls = tuple(getattr(module, "CONTROLS", CONTROLS))
     gen = GenerationEngine(
         env["TPU_MODEL"], max_slots=int(env["TPU_MAX_SLOTS"]), max_seq_len=int(env["TPU_MAX_SEQ_LEN"]),
         dtype=jnp.bfloat16, kv_quant=env["TPU_KV_QUANT"], seed=int(config.get("weights_seed", 0)),
@@ -106,7 +114,7 @@ def main() -> int:
         lines.append({"seed": args.first_seed + i, "prompt_tokens": len(ids), "program": value,
                       "program_refused": why})
         print(json.dumps(lines[-1]), flush=True)
-    for lower in CONTROLS:
+    for lower in controls:
         module.LOWER = lower
         jax.clear_caches()
         for i, (ids, out) in enumerate(served[: args.controls]):
@@ -125,9 +133,9 @@ def main() -> int:
 
     result = {"tolerance": float(module.SERVED_TOL_REL), "reference": name,
               "request": {"prompt_bytes": n_bytes, "tokens": n_tokens},
-              **{key: summary(key) for key in ("program", *CONTROLS)}}
+              **{key: summary(key) for key in ("program", *controls)}}
     print("SUMMARY", json.dumps(result), flush=True)
-    with open(os.path.join(ROOT, "chiprun_out", "solar_tolerance.json"), "w") as f:
+    with open(os.path.join(ROOT, "chiprun_out", f"{name}_tolerance.json"), "w") as f:
         json.dump({"summary": result, "seeds": lines}, f)
     return 0 if result["program"]["not_correct"] == 0 else 1
 

@@ -71,7 +71,8 @@ def test_full_prefill_of_rows_of_unlike_lengths(model):
     for i, n in enumerate(lengths):
         assert np.max(np.abs(np.asarray(logits[i]) - want[n - 1])) < TOL, (i, n)
     assert ks.shape[0] == cfg.n_attn_layers == 1  # one GQA layer owns cache rows
-    assert vs["state"]["S"].shape == (3, 4, 4, 32, 32)  # three KDA layers, float32
+    # three KDA layers, float32, in the pool's layout: the four heads of 32 values abreast
+    assert vs["state"]["S"].shape == (3, 4, 1, 32, 128)
     # a row's state is its own prompt's, whatever the bucket holds behind it
     again, _, vs2 = llama_prefill(
         cfg, params, jnp.asarray(batch[:1, :]), jnp.asarray(lengths[:1]))
@@ -585,5 +586,275 @@ def test_the_harness_comparison_passes_the_program_and_refuses_a_float8_referenc
                 assert correctness.hold_to_reference(ref, eng, ids, out)["worst_regret_rel"] < 0.1
     finally:
         ref.LOWER = None
+        jax.clear_caches()
+        eng.shutdown()
+
+
+# -- Olmo-Hybrid (PR 35): Gated DeltaNet layers, a dense feed-forward, norms on the outputs --
+
+
+@pytest.fixture(scope="module")
+def olmo_ref():
+    spec = importlib.util.spec_from_file_location(
+        "olmo_hybrid", os.path.join(ROOT, "benchmark", "references", "olmo_hybrid.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _unlike_norms(params, key=11):
+    """Norm weights away from one (the seeded tree has ones, under which a
+    norm on the wrong leaf or over the wrong width would still agree)."""
+    keys = iter(jax.random.split(jax.random.PRNGKey(key), 16))
+
+    def jitter(w):
+        return w * (1.0 + 0.3 * jax.random.normal(next(keys), w.shape, w.dtype))
+
+    params = dict(params, final_norm=jitter(params["final_norm"]))
+    params["layers"] = dict(params["layers"], attn_norm=jitter(params["layers"]["attn_norm"]),
+                            ffn_norm=jitter(params["layers"]["ffn_norm"]))
+    params["gqa"] = dict(params["gqa"], q_norm=jitter(params["gqa"]["q_norm"]),
+                         k_norm=jitter(params["gqa"]["k_norm"]))
+    params["kda"] = dict(params["kda"], o_norm=jitter(params["kda"]["o_norm"]))
+    return params
+
+
+@pytest.fixture(scope="module")
+def olmo(olmo_ref):
+    """(cfg, params, tokens [96], the reference's logits at every position) of
+    `tiny-olmo-hybrid`: two periods of three linear layers and a full one, 6
+    heads (no multiple of 8), keys of 24 and values of 48."""
+    with jax.default_matmul_precision("highest"):
+        cfg = get_config("tiny-olmo-hybrid")
+        params = _unlike_norms(init_llama_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32))
+        toks = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (96,), 3, 500))
+        want = olmo_ref.logits(cfg, params, toks, np.arange(96), np.arange(cfg.vocab_size))
+    return cfg, params, toks, want
+
+
+def test_the_olmo_reference_shares_no_code_with_the_program():
+    src = open(os.path.join(ROOT, "benchmark", "references", "olmo_hybrid.py")).read()
+    assert "llm_mcp_tpu" not in src.split('"""', 2)[2]  # the docstring names the files
+
+
+def test_olmo_full_prefill_of_rows_of_unlike_lengths(olmo):
+    cfg, params, toks, want = olmo
+    assert cfg.layer_period == ("kda", "kda", "kda", "gqa") and cfg.lin_dv == 2 * cfg.lin_head_dim
+    batch = np.zeros((4, 64), np.int32)
+    lengths = [50, 30, 64, 1]
+    for i, n in enumerate(lengths):
+        batch[i, :n] = toks[:n]
+    logits, ks, vs = llama_prefill(cfg, params, jnp.asarray(batch), jnp.asarray(lengths))
+    for i, n in enumerate(lengths):
+        assert np.max(np.abs(np.asarray(logits[i]) - want[n - 1])) < TOL, (i, n)
+    assert ks.shape[0] == cfg.n_attn_layers == 2
+    assert vs["state"]["S"].shape == (6, 4, 6, 24, 48) and "moe" not in vs  # no experts, no counts
+
+
+def test_olmo_two_chunks_then_decode_through_cache_and_pool_in_a_reused_slot(olmo):
+    cfg, params, toks, want = olmo
+    ck, cv = _used_cache(cfg)
+    assert set(cv) == {"v", "state"}  # the expert counts' member is absent, not zero
+    slot, S = 2, 128
+    for start, n in ((0, 32), (32, 18)):  # the second chunk ragged, padded to 32
+        chunk = np.zeros((1, 32), np.int32)
+        chunk[0, :n] = toks[start : start + n]
+        logits, ck, cv = llama_prefill_chunk_batch(
+            cfg, params, ck, cv, jnp.asarray(chunk), jnp.array([slot]), jnp.array([start]),
+            jnp.array([n]), skey=32)
+    assert np.max(np.abs(np.asarray(logits[0]) - want[49])) < TOL
+    lens = np.full(4, S, np.int32)  # the other slots are parked
+    lens[slot] = 50
+    before = np.asarray(cv["state"]["S"][:, 0]), np.asarray(cv["state"]["conv"][:, 0])
+    for t in range(50, 58):  # the full batch, one live row
+        tok = np.zeros(4, np.int32)
+        tok[slot] = toks[t]
+        logits, ck, cv = llama_decode_step(cfg, params, ck, cv, jnp.asarray(tok), jnp.asarray(lens))
+        assert np.max(np.abs(np.asarray(logits[slot]) - want[t])) < TOL, t
+        lens[slot] += 1
+    for t in range(58, 66):  # a compact batch: row 0 serves the slot, row 1 is a pad
+        logits, ck, cv = llama_decode_step(
+            cfg, params, ck, cv, jnp.array([toks[t], 0]), jnp.array([lens[slot], S]),
+            slot_ids=jnp.array([slot, 0]))
+        assert np.max(np.abs(np.asarray(logits[0]) - want[t])) < TOL, t
+        lens[slot] += 1
+    assert np.array_equal(np.asarray(cv["state"]["S"][:, 0]), before[0])  # a parked row never moves
+    assert np.array_equal(np.asarray(cv["state"]["conv"][:, 0]), before[1])
+    assert set(cv) == {"v", "state"}
+
+
+@pytest.mark.parametrize("placement", ["input", "output"])
+def test_both_norm_placements_run_and_only_the_stated_one_is_the_reference(olmo, placement):
+    """`ModelConfig.norm_placement` is one word in a preset: both values run
+    through prefill and decode, the same weight leaves either way, and the two
+    differ by far more than rounding."""
+    cfg, params, toks, want = olmo
+    cfg = dataclasses.replace(cfg, norm_placement=placement)
+    batch = jnp.asarray(toks[None, :32].astype(np.int32))
+    logits, _, _ = llama_prefill(cfg, params, batch, jnp.array([32]))
+    ck, cv = _used_cache(cfg)
+    chunked, ck, cv = llama_prefill_chunk_batch(
+        cfg, params, ck, cv, batch, jnp.array([1]), jnp.array([0]), jnp.array([32]), skey=32)
+    stepped, _, _ = llama_decode_step(
+        cfg, params, ck, cv, jnp.array([toks[32]]), jnp.array([32]), slot_ids=jnp.array([1]))
+    assert np.isfinite(np.asarray(stepped)).all()
+    assert np.max(np.abs(np.asarray(logits[0]) - np.asarray(chunked[0]))) < TOL
+    miss = np.max(np.abs(np.asarray(logits[0]) - want[31]))
+    assert (miss < TOL) if placement == "output" else (miss > 0.1)
+
+
+def test_the_one_decay_a_head_chunk_form_is_the_token_by_token_recurrence():
+    """Keys of 24 and values of 48; the decay between two positions of a chunk
+    is a [C, C] matrix a head, never a [C, C, dk] tensor."""
+    A, T, H, dk, dv = 2, 64, 3, 24, 48
+    ks = jax.random.split(jax.random.PRNGKey(3), 6)
+    q, k = (jax.random.normal(ks[i], (A, T, H, dk)) for i in range(2))
+    v = jax.random.normal(ks[2], (A, T, H, dv))
+    k = k / jnp.linalg.norm(k, axis=-1, keepdims=True)
+    # decays from almost none to e**-12 a step: no quotient of exponentials survives that
+    g = -jnp.exp(jax.random.uniform(ks[3], (A, T, H), minval=-7.0, maxval=2.5))
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (A, T, H)))
+    S = S0 = jax.random.normal(ks[5], (A, H, dk, dv))
+    outs = []
+    for t in range(T):
+        S = S * jnp.exp(g[:, t])[..., None, None]
+        u = beta[:, t][..., None] * (v[:, t] - jnp.einsum("ahk,ahkv->ahv", k[:, t], S))
+        S = S + k[:, t][..., None] * u[..., None, :]
+        outs.append(jnp.einsum("ahk,ahkv->ahv", q[:, t], S))
+    o, S_end = jax.jit(kda_chunk_scan)(q, k, v, g, beta, S0)
+    assert np.isfinite(np.asarray(o)).all()
+    assert np.max(np.abs(np.asarray(o) - np.asarray(jnp.stack(outs, 1)))) < 1e-4
+    assert np.max(np.abs(np.asarray(S_end) - np.asarray(S))) < 1e-4
+    text = str(jax.make_jaxpr(kda_chunk_scan)(q, k, v, g, beta, S0))
+    assert f"32,32,{dk}]" not in text  # no decay a channel was built
+    # and broadcast to every key channel it is the decay-a-channel form's own answer
+    o_c, S_c = jax.jit(kda_chunk_scan)(q, k, v, jnp.broadcast_to(g[..., None], q.shape), beta, S0)
+    assert np.max(np.abs(np.asarray(o) - np.asarray(o_c))) < 1e-4
+    assert np.max(np.abs(np.asarray(S_end) - np.asarray(S_c))) < 1e-4
+
+
+@pytest.mark.parametrize("H,dk,dv,head_decay,abreast", [
+    (30, 96, 192, True, 2),  # Olmo-Hybrid: two heads abreast, 384 = 3 x 128 lanes
+    (30, 96, 192, False, 2),  # the same pool under a decay a channel
+    (64, 128, 128, False, 1),  # Solar-Open2: the layout is [.., H, dk, dv] itself
+    (6, 24, 48, True, 1),  # the tiny preset: no count of heads makes whole lanes
+], ids=["olmo_30x96x192", "olmo_per_channel", "solar_64x128x128", "tiny_6x24x48"])
+def test_state_kernel_in_the_pools_layout_is_the_reference_step(H, dk, dv, head_decay, abreast):
+    from llm_mcp_tpu.kernels.kda import heads_abreast, pack_state, unpack_state
+
+    Lk, B, Ba = 2, 6, 4
+    assert heads_abreast(H, dv) == abreast
+    ks = jax.random.split(jax.random.PRNGKey(5), 6)
+    head_major = jax.random.normal(ks[0], (Lk, B, H, dk, dv))
+    state = pack_state(head_major, abreast)
+    assert state.shape == (Lk, B, H // abreast, dk, abreast * dv)
+    assert np.array_equal(np.asarray(unpack_state(state, abreast)), np.asarray(head_major))
+    q, k = (jax.random.normal(ks[i], (Ba, H, dk)) for i in (1, 2))
+    v = jax.random.normal(ks[3], (Ba, H, dv))
+    alpha = jax.nn.sigmoid(jax.random.normal(ks[4], (Ba, H) if head_decay else (Ba, H, dk)))
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[5], (Ba, H)))
+    ids = jnp.array([4, 1, 5, 5])  # two pads on one free row
+    live = jnp.array([True, True, False, False])
+    o_r, s_r = kda_decode_step_reference(state, jnp.int32(1), ids, live, q, k, v, alpha, beta)
+    o_k, s_k = kda_decode_step(state, jnp.int32(1), ids, live, q, k, v, alpha, beta,
+                               name="gdn_decode_step" if head_decay else "kda_decode_step",
+                               interpret=True)
+    # the step itself, head-major, by hand for row 0
+    S = head_major[1, 4] * (alpha[0][:, None, None] if head_decay else alpha[0][..., None])
+    u = beta[0][:, None] * (v[0] - jnp.einsum("hk,hkv->hv", k[0], S, precision="highest"))
+    S = S + k[0][..., None] * u[:, None, :]
+    assert np.allclose(np.asarray(unpack_state(s_r, abreast)[1, 4]), np.asarray(S), atol=1e-4)
+    assert np.allclose(np.asarray(o_r[:2]), np.asarray(o_k[:2]), rtol=1e-4, atol=1e-3)
+    assert np.allclose(np.asarray(s_r), np.asarray(s_k), rtol=1e-4, atol=1e-4)
+    untouched = np.asarray(s_k) == np.asarray(state)
+    assert untouched[0].all() and untouched[1, [0, 2, 3, 5]].all()  # a parked row unmoved
+    assert not untouched[1, 1].all() and not untouched[1, 4].all()
+
+
+def test_param_count_reckons_the_new_layer_kinds():
+    """ISSUE 35's arithmetic: a linear layer 88.7 M + MLP 126.8 M, a full layer
+    59.0 M + 126.8 M, embedding and head 770.7 M: 7.43 B whole, 4.93 B at the
+    cut; Solar's count is what it was."""
+    cut = get_config("olmo-hybrid-7b-d20")
+    whole = dataclasses.replace(cut, n_layers=32, gqa_layers=tuple(range(3, 32, 4)))
+    D, H, dk, dv, F, V = 3840, 30, 96, 192, 11_008, 100_352
+    linear = D * (2 * H * dk + 2 * H * dv) + H * dv * D + 2 * D * H + 4 * H * (2 * dk + dv)
+    full, mlp = 4 * D * D, 3 * D * F
+    assert linear // 10**5 == 887 and mlp // 10**5 == 1268  # 88.7 M and 126.8 M
+    for cfg, layers in ((cut, 20), (whole, 32)):
+        reckoned = layers // 4 * (3 * linear + full) + layers * mlp + 2 * V * D
+        assert abs(cfg.param_count() - reckoned) < 2e-4 * reckoned  # the norms' vectors
+    assert round(cut.param_count() / 1e9, 2) == 4.93 and round(whole.param_count() / 1e9, 2) == 7.43
+    tiny = get_config("tiny-olmo-hybrid")
+    held = sum(x.size for x in jax.tree.leaves(init_llama_params(tiny, jax.random.PRNGKey(0))))
+    assert abs(tiny.param_count() - held) < 1e-3 * held
+    solar = get_config("solar-open2-250b-ep8")
+    assert round(solar.param_count() / 1e9, 2) == 3.31
+
+
+@pytest.fixture(scope="module")
+def olmo_engine():
+    from llm_mcp_tpu.executor import GenerationEngine
+
+    eng = GenerationEngine("tiny-olmo-hybrid", max_slots=2, max_seq_len=128, dtype=jnp.float32,
+                           prefill_chunk=32, prompt_cache_mb=64).start()
+    yield eng
+    eng.shutdown()
+
+
+def test_olmo_engine_serves_the_references_choice_whole_and_chunked(olmo_engine, olmo_ref):
+    """Whole-prompt admission (under the engine's chunk of 32), a chunked
+    prefill carried across ENGINE chunks (over it), and again in used slots;
+    the pool's book follows the layout and no expert block exists."""
+    eng = olmo_engine
+    allowed = np.flatnonzero(np.asarray(eng._allowed_mask))
+    prompts = ["amber basil", "x" * 70 + " cedar dune ember", "y" * 45, "fjord grove " * 6]
+    for prompt in prompts:
+        ids, out = _serve(eng, prompt)
+        seq = ids + out[:-1]
+        rows = np.arange(len(ids) - 1, len(seq))
+        seq = np.asarray(seq + [0] * (-len(seq) % 32), np.int32)
+        want = olmo_ref.logits(eng.cfg, eng.params, seq, rows, allowed)
+        for k, tok in enumerate(out):
+            regret = float(np.max(want[k]) - want[k, np.flatnonzero(allowed == tok)[0]])
+            assert regret < 1e-3, (prompt[:12], k, regret)
+    assert "chunk" in {r["phase"] for r in eng._ledger.table()}
+    stats = eng.perf_stats()
+    pool = stats["state_pool"]
+    assert "experts" not in stats and eng._experts is None and eng.expert_dtype == ""
+    assert (eng.state_dtype, eng.weights_dtype) == ("float32", "float32")
+    cfg = eng.cfg
+    logical = 6 * 2 * (cfg.lin_heads * cfg.lin_head_dim * cfg.lin_dv * 4
+                       + 3 * cfg.lin_heads * (2 * cfg.lin_head_dim + cfg.lin_dv) * 4)
+    assert pool["bytes"] == logical == pool["bytes_per_slot"] * 2
+    assert pool["layout"] == {"S": [6, 2, 6, 24, 48], "conv": [6, 2, 3 * 6 * 96]}
+    assert pool["admitted_total"] == len(prompts) and pool["live_slots"] == 0
+    assert not any(eng._runs(f) for f in ("prefix_cache", "offload", "migration", "speculation",
+                                           "ragged_prefill"))
+
+
+def test_the_harness_comparison_passes_the_olmo_program_and_refuses_its_controls(olmo_ref):
+    """scripts/solar_tolerance.py's readings at the tiny size, through
+    `correctness.hold_to_reference`: the served tokens are the reference's own
+    choice (float32 against float32: the tolerance on the chip, 0.2 of a row's
+    max |logit|, is for bfloat16 weights); held to the reference computed in
+    float8, or with the first linear layer's state lost, they are not correct."""
+    from benchmark import correctness
+    from llm_mcp_tpu.executor import GenerationEngine
+
+    eng = GenerationEngine("tiny-olmo-hybrid", max_slots=2, max_seq_len=256, dtype=jnp.float32).start()
+    try:
+        ids, out = _serve(eng, "hold these sixteen tokens to the plain forward, " * 2, n=16)[:2]
+        assert correctness.hold_to_reference(olmo_ref, eng, ids, out)["worst_regret_rel"] < 1e-3
+        for lower in olmo_ref.CONTROLS:
+            olmo_ref.LOWER = lower
+            jax.clear_caches()
+            if lower in ("fp8", "lost_state"):
+                with pytest.raises(AssertionError, match="under the reference's choice"):
+                    correctness.hold_to_reference(olmo_ref, eng, ids, out)
+            else:
+                assert correctness.hold_to_reference(olmo_ref, eng, ids, out)["worst_regret_rel"] < 0.2
+    finally:
+        olmo_ref.LOWER = None
         jax.clear_caches()
         eng.shutdown()

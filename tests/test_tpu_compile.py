@@ -23,6 +23,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -316,37 +317,57 @@ def test_decode_step_reads_stacked_weights_in_place(
 SOLAR_SLOTS, SOLAR_S = 64, 1024
 
 
-def test_kda_decode_step(sd):
-    """The one-step state kernel alone: 64 rows of 64 heads of [128, 128]
-    float32, three layers in the pool, rows found through the slot ids."""
+@pytest.mark.parametrize("name,H,dk,dv,head_decay", [
+    ("kda_decode_step", 64, 128, 128, False),  # Solar-Open2: a decay a key channel
+    ("gdn_decode_step", 30, 96, 192, True),  # Olmo-Hybrid: one a head, two heads abreast
+], ids=["kda_64x128x128", "gdn_30x96x192"])
+def test_kda_decode_step(sd, name, H, dk, dv, head_decay):
+    """The one-step state kernel alone: 64 rows, three layers in the pool, rows
+    found through the slot ids; 64 heads of [128, 128] float32, and 30 heads
+    of [96, 192] in a pool of 15 x [96, 384], whose bytes as the compiler lays
+    it out are its logical bytes (a [.., 96, 192] pool would pad to 256)."""
     import functools
 
-    from llm_mcp_tpu.kernels.kda import kda_decode_step
+    from llm_mcp_tpu.kernels.kda import heads_abreast, kda_decode_step
 
-    F32, rows, H, d = jnp.float32, 64, 64, 128
-    vec = sd((rows, H, d), F32)
-    compile_for_chip(
-        functools.partial(kda_decode_step, interpret=False), sd((3, SOLAR_SLOTS, H, d, d), F32), sd((), I32),
-        sd((rows,), I32), sd((rows,), jnp.bool_), vec, vec, vec, vec, sd((rows, H), F32),
-        donate_argnums=(0,))
+    F32, rows, P = jnp.float32, 64, heads_abreast(H, dv)
+    pool = sd((3, SOLAR_SLOTS, H // P, dk, P * dv), F32)
+    keys = sd((rows, H, dk), F32)
+    text = compile_for_chip(
+        functools.partial(kda_decode_step, name=name, interpret=False), pool, sd((), I32),
+        sd((rows,), I32), sd((rows,), jnp.bool_), keys, keys, sd((rows, H, dv), F32),
+        sd((rows, H), F32) if head_decay else keys, sd((rows, H), F32), donate_argnums=(0,))
+    assert name in text
+    laid_out = jax.jit(lambda s: s + 1.0).lower(pool).compile().memory_analysis()
+    assert laid_out.argument_size_in_bytes == 3 * SOLAR_SLOTS * H * dk * dv * 4
 
 
-@pytest.fixture(scope="module")
-def solar(one_chip):
-    """(cfg, params, cache) of `solar-open2-250b-ep8` as shapes on the described
-    chip: bf16 weights, int8 KV for the one GQA layer, 64 slots x 1024, the
-    float32 state pool beside it."""
+def hybrid_shapes(name: str, one_chip, slots: int, seq: int):
+    """(cfg, params, cache) of a hybrid preset as shapes on the described chip:
+    bf16 weights, int8 KV for the GQA layers, the float32 state pool beside it."""
     from functools import partial
 
     from llm_mcp_tpu.models import llama
     from llm_mcp_tpu.models.configs import get_config
 
-    cfg = get_config("solar-open2-250b-ep8")
+    cfg = get_config(name)
     params = jax.eval_shape(partial(llama.init_llama_params, cfg, jax.random.PRNGKey(0), dtype=BF))
     cache = jax.eval_shape(partial(
-        llama.init_kv_cache, cfg, SOLAR_SLOTS, SOLAR_S, dtype=BF, quantized=True))
+        llama.init_kv_cache, cfg, slots, seq, dtype=BF, quantized=True))
     return (cfg, *jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), (params, cache)))
+
+
+@pytest.fixture(scope="module")
+def solar(one_chip):
+    """`solar-open2-250b-ep8`, 64 slots x 1024."""
+    return hybrid_shapes("solar-open2-250b-ep8", one_chip, SOLAR_SLOTS, SOLAR_S)
+
+
+@pytest.fixture(scope="module")
+def olmo(one_chip):
+    """`olmo-hybrid-7b-d20`, 64 slots x 1024, as its cell boots it."""
+    return hybrid_shapes("olmo-hybrid-7b-d20", one_chip, SOLAR_SLOTS, SOLAR_S)
 
 
 def solar_program(which: str, cfg):
@@ -427,6 +448,52 @@ def test_solar_step_programs_fit_and_keep_state_and_banks_in_place(
     assert total < 15.75 * 2**30
     assert mem.temp_size_in_bytes < 0.7 * 2**30
     assert mem.alias_size_in_bytes > 0.9 * 2**30  # KV cache and state pool updated in place
+
+
+@pytest.mark.parametrize("which,operands", [
+    ("decode", [(64,), (64,), (64,)]),  # every slot a row
+    ("admit", [(4, 128), (4,), (4,)]),  # the cell's largest admit program, 512 padded tokens
+    ("chunk", [(2, 512), (2,), (2,), (2,)]),  # two prompts' second chunks of 512
+])
+def test_olmo_hybrid_step_programs_fit_beside_64_slots(sd, olmo, chip_kernels, which, operands):
+    """The decode round, an admit and a chunk program of `olmo-hybrid-7b-d20`
+    at its cell's 64 slots x 1024 compile for the described v5e with their
+    kernels: `gdn_decode_step` and the decode attention and append kernels at
+    30 KV heads, group 1, as Mosaic calls with no fall to their reference; the
+    flash prefill kernel in the admit program. Each fits under the 15.0 GiB at which
+    ISSUE 35 would have taken 48 slots, and updates the 2.38 GiB KV cache and
+    the 2.04 GiB state pool in place. The argument bytes hold the pool at its
+    logical size. GiB in PERF.md section 4 as "described-chip compile"."""
+    from llm_mcp_tpu.models import kda
+
+    cfg, params, cache = olmo
+    falls = dict(A.reference_falls)
+    compiled = jax.jit(solar_program(which, cfg), donate_argnums=(1, 2)).lower(
+        params, cache["k"], cache["v"], *(sd(shape, I32) for shape in operands)).compile()
+    assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
+    text = compiled.as_text()
+    # the bucketed chunk's attention over [past | self] is `jax.numpy` for every
+    # configuration (llama._chunk_attention) and so is the chunked recurrence:
+    # a chunk program holds no Mosaic call (PERF.md section 7)
+    assert ("tpu_custom_call" in text) == (which != "chunk")
+    assert ("%gdn_decode_step" in text) == (which == "decode") and "%kda_decode_step" not in text
+    if which == "decode":
+        assert "decode_attn_q8" in text and "append_kv_q8" in text
+    if which == "admit":
+        assert "flash_prefill_attn" in text
+    S = cache["v"]["state"]["S"]
+    assert S.shape == (15, 64, 15, 96, 384) and kda.state_abreast(cfg) == 2
+    mem = compiled.memory_analysis()
+    leaves = jax.tree.leaves((params, cache)) + [sd(shape, I32) for shape in operands]
+    logical = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in leaves)
+    assert mem.argument_size_in_bytes < logical * 1.002  # nothing pads: the pool least of all
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"olmo {which}: {total / 2**30:.2f} GiB, of it temporaries "
+          f"{mem.temp_size_in_bytes / 2**30:.2f} GiB, arguments "
+          f"{mem.argument_size_in_bytes / 2**30:.2f} GiB")
+    assert total < 15.0 * 2**30
+    assert mem.alias_size_in_bytes > 4.3 * 2**30  # KV cache and state pool updated in place
 
 
 def test_a_fall_to_the_reference_is_counted(tmp_path):
