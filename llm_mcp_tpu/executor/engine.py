@@ -49,6 +49,7 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from ..kernels.attention import (
+    AttnStream,
     pallas_supported,
     ragged_prefill_max_tokens,
     resolve_attn_impl,
@@ -698,6 +699,12 @@ class GenerationEngine:
         self._experts = (
             ExpertCounts(self.cfg.n_layers, held=self.cfg.n_experts, router=self.cfg.router_width)
             if isinstance(self._cv, dict) and "moe" in self._cv else None)
+        # what the blocked int8 decode-attention arm streams, where decode
+        # rounds run it (int8 GQA cache read by the Pallas kernel): None else
+        self._attn_stream = (
+            AttnStream(self._ck["q"].shape)
+            if self.kv_quant == "int8" and not self.cfg.kv_lora_rank
+            and self.decode_impl == "pallas" else None)
         if self._spmd:
             # named out_sharding kinds for _shard_out: host-read outputs come
             # back fully replicated (every process device_gets locally — the
@@ -3400,7 +3407,9 @@ class GenerationEngine:
         `_blocked` still had to wait for the device, `_at_once` were read
         where they were dispatched), and for a configuration with recurrent
         layers the state pool's block (`state_pool`), for one whose step
-        programs count the expert layer's work that block (`experts`). Read-only over the observatory's own
+        programs count the expert layer's work that block (`experts`), for one
+        whose decode rounds read an int8 cache through the blocked attention
+        arm what that arm streams (`decode_attn`). Read-only over the observatory's own
         lock, so safe from any thread."""
         out = {
             **self._perf.stats(),
@@ -3415,6 +3424,8 @@ class GenerationEngine:
             out["state_pool"] = self._state_pool.stats(live)
         if self._experts is not None:
             out["experts"] = self._experts.stats()
+        if self._attn_stream is not None:
+            out["decode_attn"] = self._attn_stream.stats()
         return out
 
     def drain_itl_samples(self) -> list[float]:
@@ -6266,6 +6277,8 @@ class GenerationEngine:
             ).astype(np.int32)
         base = self._lengths.copy()
         self._note_expert_form("decode", Ba, self.decode_chunk)
+        if self._attn_stream is not None:
+            self._attn_stream.dispatched(packed[:Ba], self.decode_chunk)
         if group is not None:
             maybe_fail(
                 "engine.prefill", f"slots={[s for s, _, _ in group.metas]}"

@@ -38,6 +38,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -439,13 +440,27 @@ def _unpack_scale_lanes(srow, n_heads: int, scale_dtype):
     return jax.lax.bitcast_convert_type(bits, jnp.float32)
 
 
+def blocked_row_blocks(w, seq_len: int, block_s: int, xp=jnp):
+    """Blocks of `block_s` cache positions the blocked q8 arm streams for a
+    row at position `w`: the attended prefix [0, w] rounded up to whole
+    blocks, and ONE block for a parked or free row (w >= seq_len, the
+    engine's convention: its output is discarded, and at low occupancy such
+    rows would otherwise dominate the cache traffic). `xp` is `jnp` inside
+    the step program and `numpy` for the host's count of the same traffic
+    (`engine.perf_stats()["decode_attn"]`)."""
+    nblk = xp.clip((w + block_s) // block_s, 1, seq_len // block_s)
+    return xp.where(w >= seq_len, 1, nblk)
+
+
 def _attend_q8_blocked_kernel(
     li_ref,  # [1] int32 (scalar prefetch) — layer index
     ids_ref,  # [Ba] int32 (scalar prefetch) — cache row per batch position
     lengths_ref,  # [Ba] int32 (scalar prefetch) — this step's position per row
+    cum_ref,  # [Ba + 1] int32 (scalar prefetch) — running sum of the rows'
+    #           block counts: row b's cells are cum[b] .. cum[b + 1] - 1
     q_ref,  # [1, Hkv, G, hd] VMEM
-    nk_ref,  # [1, Hkv, 1, hd] VMEM — this step's K vectors (post-rope)
-    nv_ref,  # [1, Hkv, 1, hd] VMEM
+    nk_ref,  # [1, Hkv, hd] VMEM — this step's K vectors (post-rope)
+    nv_ref,  # [1, Hkv, hd] VMEM
     pay_hbm,  # [L, B, 2*Hkv + p, S, hd] int8 — fused K|V(|packed scales)
     #           payload, stays in HBM (ANY), DMA'd per block
     s_hbm,  # [L, B, 2*Hkv, S] — plain scales (read only when packed=False)
@@ -458,7 +473,6 @@ def _attend_q8_blocked_kernel(
     *,
     scale: float,
     block_s: int,
-    seq_len: int,
     packed: bool,
     scale_dtype,
 ):
@@ -473,9 +487,17 @@ def _attend_q8_blocked_kernel(
     flash-style online softmax accumulating across blocks. Same s8-MXU dot
     discipline and exact current-position override as `_attend_q8_kernel`.
 
-    DMA count per (row, block) cell is the r05-measured bottleneck
-    (~2.5 µs of issue latency per cell regardless of bytes): the fused
-    layout collapses the old 4 copies (kq/ks/vq/vs) to
+    **The (row, block) cells of the whole batch are ONE pipeline.** Cell c
+    (row b's block j: c = cum[b] + j) lands in buffer c % 2, and cell c + 1's
+    copy is started before cell c's is waited for, whether c + 1 is the same
+    row's next block or the NEXT ROW's first one. The grid is sequential and
+    the buffers and semaphores are scratch, so a copy started in one grid
+    step is waited for in the next: the only wait with nothing before it is
+    the call's first. A double buffer over ONE row's blocks overlaps nothing at
+    one block a row, which is every short fill (PERF.md section 6, PR 36: 59 us
+    a call of 32 rows against 34).
+
+    One copy a cell where the scales travel with the payload:
 
       packed=True  — ONE copy: K, V and a bit-packed per-position scale
         pseudo-head travel in the same [2*Hkv+1, BS, hd] int8 block; the
@@ -483,25 +505,23 @@ def _attend_q8_blocked_kernel(
       packed=False — TWO copies: the [2*Hkv, BS, hd] payload head-slice
         plus one [2*Hkv, BS] block of the plain scales array. This is the
         fallback when the scale bytes don't fit one head row
-        (2*Hkv*itemsize > hd) or LLM_MCP_TPU_Q8_SCALE_PACK=0. Unlike the
-        r05-rejected per-cache single-row [2, BS] loads, a [2*Hkv, BS]
+        (2*Hkv*itemsize > hd) or LLM_MCP_TPU_Q8_SCALE_PACK=0. A [2*Hkv, BS]
         slice of the head-major scales array is a (sublane, lane)-tileable
         copy Mosaic accepts.
     """
     b = pl.program_id(0)
     li = li_ref[0]
-    row = ids_ref[b]  # cache row for this batch position (compaction)
-    w = lengths_ref[b]
     BS = block_s
-    Hkv = q_ref.shape[1]
-    nblk_max = seq_len // BS
-    nblk = jnp.clip((w + BS) // BS, 1, nblk_max)
-    # parked/free rows (w >= S, engine convention) produce discarded output:
-    # stream one block instead of the whole row — at low occupancy most of
-    # the batch is parked and would otherwise dominate cache traffic
-    nblk = jnp.where(w >= seq_len, 1, nblk)
+    _, Hkv, G, hd = q_ref.shape
+    n_rows = ids_ref.shape[0]
+    row = ids_ref[b]  # cache row for this batch position (compaction)
+    next_row = ids_ref[jnp.minimum(b + 1, n_rows - 1)]
+    w = lengths_ref[b]
+    c0 = cum_ref[b]
+    nblk = cum_ref[b + 1] - c0
+    total = cum_ref[n_rows]
 
-    def copies(j, slot):
+    def copies(row, j, slot):
         if packed:
             # one DMA: full head axis (K | V | packed-scale pseudo-head)
             return (
@@ -524,39 +544,41 @@ def _attend_q8_blocked_kernel(
             ),
         )
 
-    def start(j, slot):
-        for c in copies(j, slot):
+    def start(row, j, slot):
+        for c in copies(row, j, slot):
             c.start()
 
-    def wait(j, slot):
-        for c in copies(j, slot):
+    def wait(row, j, slot):
+        for c in copies(row, j, slot):
             c.wait()
 
-    start(0, 0)
+    @pl.when(b == 0)
+    def _first_cell():  # the one copy nothing runs ahead of
+        start(row, 0, 0)
 
     q = q_ref[0].astype(jnp.float32)  # [Hkv, G, hd]
-    nk = nk_ref[0, :, 0].astype(jnp.float32)  # [Hkv, hd]
-    nv = nv_ref[0, :, 0].astype(jnp.float32)
+    nk = nk_ref[0].astype(jnp.float32)  # [Hkv, hd]
+    nv = nv_ref[0].astype(jnp.float32)
     qa = jnp.max(jnp.abs(q), axis=-1)
     qsc = jnp.maximum(qa / 127.0, 1e-30)
     q8 = jnp.round(q / qsc[..., None]).astype(jnp.int8)
     s_new = jnp.sum(q * nk[:, None, :], axis=-1, keepdims=True) * scale  # [Hkv,G,1]
 
-    G = q_ref.shape[2]
-    hd = q_ref.shape[3]
     acc0 = jnp.zeros((Hkv, G, hd), jnp.float32)
     m0 = jnp.full((Hkv, G, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((Hkv, G, 1), jnp.float32)
 
     def body(j, carry):
         acc, m, l = carry
-        slot = jax.lax.rem(j, 2)
+        c = c0 + j
+        slot = jax.lax.rem(c, 2)
+        last = j + 1 == nblk
 
-        @pl.when(j + 1 < nblk)
-        def _prefetch():
-            start(j + 1, 1 - slot)
+        @pl.when(c + 1 < total)
+        def _prefetch():  # the batch's next cell: this row's, or the next row's first
+            start(jnp.where(last, next_row, row), jnp.where(last, 0, j + 1), 1 - slot)
 
-        wait(j, slot)
+        wait(row, j, slot)
         buf = pay_buf[slot]  # [Hh, BS, hd] int8 — k rows, v rows(, scales)
         k = buf[:Hkv]  # [Hkv, BS, hd] int8
         if packed:
@@ -803,6 +825,67 @@ def paged_gather(arena, pool, tables, *, nbs=None):
     return jnp.swapaxes(g, 1, 2).reshape(A, Hx, nsel * bt, *rest)
 
 
+# The blocked q8 arm's largest block, in bytes of one copy ([payload heads, BS,
+# hd] int8). Measured on a v5e with the batch as one pipeline
+# (scripts/attn_block_sweep.py; PERF.md section 6, PR 36): at 17 payload heads
+# a cell costs about 0.5 us whatever it holds and 0.55 us more for 256
+# positions, so one block of 0.56 MB (17 x 256) beats two of 0.28 MB by 20%
+# (34 against 43 us a call of 32 rows, 123 against 152 of 64); at 61 heads a
+# block of 2.0 MB (61 x 256) streams at 744 GB/s, bound by its bytes, and
+# loses 9% to blocks of 1.0 MB that over-read less (341 against 309 us).
+# Between 0.56 and 1.0 MB: not measured, no cell has such a shape.
+Q8_BLOCK_BYTES_MAX = 1 << 20
+
+
+def q8_block_tokens(payload_heads: int, seq_len: int, head_dim: int) -> int:
+    """Cache positions in one block of the blocked q8 arm: the largest of 256,
+    128, 64, 32 that divides `seq_len` (a floored block count would drop the
+    row's tail, the current position with it) and whose copy of
+    `payload_heads` (2*Hkv + p) heads stays within `Q8_BLOCK_BYTES_MAX`; the
+    smallest that divides where none stays within; 0 where none divides (no
+    int8-tileable block: the dispatcher takes the whole-S arm or the reference).
+    A function of the cache's shape alone."""
+    fits = [c for c in (256, 128, 64, 32) if seq_len % c == 0]
+    return next(
+        (c for c in fits if payload_heads * c * head_dim <= Q8_BLOCK_BYTES_MAX),
+        fits[-1] if fits else 0,
+    )
+
+
+class AttnStream:
+    """Host-side book of what the blocked int8 decode-attention arm streams
+    (`decode_attend_q8`), from the positions the host packs
+    for each decode round and the block size in force for the cache's shape
+    (`q8_block_tokens`): over the steps of the rounds dispatched, for ONE layer's
+    call a step, cache positions fetched (each row's blocks x `block_tokens`, a
+    parked or padding row one block) and positions live (`w + 1` of the seated
+    rows). Live over streamed is the share of the arm's traffic that is work.
+    A count of what the arm WOULD fetch: a round whose fill takes the whole-S
+    arm under the dispatcher's `lax.cond` (0.55 of rows x length; no cell of
+    the benchmark comes near) is counted the same."""
+
+    def __init__(self, cache_q_shape: tuple[int, ...]):
+        _, _, heads, self.seq_len, head_dim = cache_q_shape
+        self.block_tokens = q8_block_tokens(heads, self.seq_len, head_dim)
+        self.steps = self.tokens_streamed = self.tokens_live = 0
+
+    def dispatched(self, lengths: np.ndarray, steps: int) -> None:
+        """A decode round of `steps` steps went out with the rows at
+        `lengths` (this step's position a row; >= the cache's length: parked)."""
+        w = lengths[:, None].astype(np.int64) + np.arange(steps)
+        w = np.where(lengths[:, None] >= self.seq_len, self.seq_len, w)  # parked stays parked
+        blocks = blocked_row_blocks(w, self.seq_len, self.block_tokens, xp=np)
+        self.steps += steps
+        self.tokens_streamed += int(blocks.sum()) * self.block_tokens
+        self.tokens_live += int(np.where(w < self.seq_len, w + 1, 0).sum())
+
+    def stats(self) -> dict:
+        return {"block_tokens": self.block_tokens, "steps": self.steps,
+                "tokens_streamed": self.tokens_streamed, "tokens_live": self.tokens_live,
+                "live_over_streamed": round(self.tokens_live / self.tokens_streamed, 4)
+                if self.tokens_streamed else None}
+
+
 def fused_q8_heads(cache_k: dict) -> tuple[int, int]:
     """(Hkv, p) of a FUSED int8 GQA cache: the payload carries 2*Hkv K|V
     heads plus p ∈ {0, 1} packed-scale pseudo-heads; the plain "s" array
@@ -859,7 +942,7 @@ def _decode_attend_q8_fallback(
     return ctx.astype(q.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "scale"))
+@functools.partial(jax.jit, static_argnames=("interpret", "scale", "block_s"))
 def decode_attend_q8(
     q: jnp.ndarray,  # [Ba, Hkv, G, hd] — COMPACT batch (active rows only)
     new_k: jnp.ndarray,  # [Ba, Hkv, hd] — post-rope K for this step
@@ -876,6 +959,8 @@ def decode_attend_q8(
     #   {"q": int8 [L,PXB,2*Hkv+p,bt,hd], "s": [L,PXB,2*Hkv,bt]}
     scale: float = 0.0,  # query scale (0 = head_dim**-0.5)
     interpret: bool | None = None,
+    block_s: int | None = None,  # the blocked arm's block; None = the rule
+    #   (`q8_block_tokens`). Given only by scripts/attn_block_sweep.py and tests
 ) -> jnp.ndarray:
     """Attention over the FUSED int8 KV cache for one layer of the decode
     step (layout: models/llama.py:init_kv_cache — K heads, V heads, and an
@@ -910,9 +995,7 @@ def decode_attend_q8(
     nk4 = new_k.reshape(B, Hkv, 1, hd)
     nv4 = new_v.reshape(B, Hkv, 1, hd)
     can_whole = S <= decode_pallas_max_seq(hd, Hkv, Hkv * G, quantized=True)
-    # BS must divide S (a floored block count would silently drop the tail —
-    # including the current position)
-    BS = next((c for c in (256, 128, 64, 32) if S % c == 0), 0)
+    BS = block_s or q8_block_tokens(2 * Hkv + p, S, hd)
     if not can_whole and BS == 0:
         # no whole-S fit and no int8-tileable block divides S: exact f32
         # math of the reference (slower, never wrong)
@@ -976,31 +1059,32 @@ def decode_attend_q8(
     def run_blocked():
         # rows stream blockwise from HBM with a dynamic trip count — no
         # VMEM cliff at any S, and only the attended prefix [0, w] is ever
-        # read. The r05 layout paid ~2.5 µs/cell of DMA-issue latency over
-        # FOUR copies (measured: ~9 ms of fixed cost at 8B B=112); the
-        # fused layout issues ONE copy per cell (packed) or two (unpacked).
+        # read. The batch's (row, block) cells are one pipeline (see the
+        # kernel): their order and count are a running sum of the rows'
+        # block counts, made here from `lengths` and prefetched as scalars.
         Hh = 2 * Hkv + 1 if packed else 2 * Hkv
+        li, _, lens = args[:3]
+        cum = jnp.concatenate(
+            [jnp.zeros((1,), jnp.int32), jnp.cumsum(blocked_row_blocks(lens, S, BS))]
+        ).astype(jnp.int32)
         kernel = functools.partial(
             _attend_q8_blocked_kernel,
             scale=sc,
             block_s=BS,
-            seq_len=S,
             packed=packed,
             scale_dtype=cache_k["s"].dtype,
         )
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,  # layer [1], slot ids [Ba], lengths [Ba]
+            num_scalar_prefetch=4,  # layer [1], slot ids, lengths, cells [Ba + 1]
             grid=(B,),
             in_specs=[
-                pl.BlockSpec((1, Hkv, G, hd), lambda b, li, ids, lens: (b, 0, 0, 0)),
-                pl.BlockSpec((1, Hkv, 1, hd), lambda b, li, ids, lens: (b, 0, 0, 0)),
-                pl.BlockSpec((1, Hkv, 1, hd), lambda b, li, ids, lens: (b, 0, 0, 0)),
+                pl.BlockSpec((1, Hkv, G, hd), lambda b, *_: (b, 0, 0, 0)),
+                pl.BlockSpec((1, Hkv, hd), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec((1, Hkv, hd), lambda b, *_: (b, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),  # fused payload
                 pl.BlockSpec(memory_space=pl.ANY),  # plain scales
             ],
-            out_specs=pl.BlockSpec(
-                (1, Hkv, G, hd), lambda b, li, ids, lens: (b, 0, 0, 0)
-            ),
+            out_specs=pl.BlockSpec((1, Hkv, G, hd), lambda b, *_: (b, 0, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((2, Hh, BS, hd), jnp.int8),
                 pltpu.VMEM((2, 2 * Hkv, BS), cache_k["s"].dtype),
@@ -1010,7 +1094,9 @@ def decode_attend_q8(
         return pl.pallas_call(
             kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp,
             name="decode_attn_q8_blocked",
-        )(*args)
+            # sequential: a cell's copy is started in the grid step before its own
+            compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        )(li, ids, lens, cum, q, new_k, new_v, cache_k["q"], cache_k["s"])
 
     def run_paged():
         # block-indirect arm: BS is pinned to the ledger's block_tokens so
@@ -1078,13 +1164,12 @@ def decode_attend_q8(
             # would emulate BOTH kernels per call in tests. Parity tests force
             # the blocked arm via LLM_MCP_TPU_Q8_DECODE=blocked instead.
             return run_whole()
-        # Runtime hybrid (both executables compile once). The r05 4-DMA layout
-        # measured the crossover at ~40% traffic ratio (8B B=112 S=1024: 20.5
-        # vs 24.4 ms/step empty, 29.2 vs 24.4 at 88% fill); the fused layout
-        # cuts the blocked arm's per-cell fixed cost ~4x, so its win region
-        # extends to higher fills — default threshold 0.55 (projected from the
-        # r05 fixed-cost split, to be re-measured on hardware; the env knob is
-        # the re-tuning surface).
+        # Runtime hybrid (both executables compile once). The threshold 0.55
+        # is NOT MEASURED on this layout: it was projected from a 4-copy layout
+        # two rewrites ago (r05: crossover at a traffic ratio of about 0.4), and
+        # no cell of the benchmark fills its cache past 0.4, so every measured
+        # round takes the blocked arm (PERF.md section 7). The env knob is the
+        # re-tuning surface until a long-context cell measures the crossing.
         # Compare the kernels' ACTUAL traffic: whole-S DMAs all B rows in full
         # (parked/pad rows included), blocked streams the attended prefix per
         # active row and ONE block per parked row — so the ratio denominator is
@@ -1647,10 +1732,10 @@ def decode_attend_bf16(
             # as decode_attend_q8); parity tests force the blocked arm via
             # LLM_MCP_TPU_BF16_DECODE=blocked.
             return run_whole()
-        # Runtime hybrid, same traffic-ratio rule as the q8 path. The bf16
-        # blocked arm pays 2 DMAs/cell (split K/V), so its fixed cost sits
-        # between the fused-q8 1-copy arm and the r05 4-copy layout — start at
-        # the same 0.55 default and re-tune on hardware via the env knob.
+        # Runtime hybrid, same traffic-ratio rule as the q8 path and the same
+        # 0.55, NOT MEASURED: no cell runs a bf16 cache. This blocked arm pays
+        # two copies a cell (split K/V) and still waits at every row's edge
+        # (the q8 arm's batch-wide pipeline, PR 36, is not ported: ROADMAP C4).
         thr = float(os.environ.get("LLM_MCP_TPU_BF16_HYBRID", "0.55"))
         w_eff = jnp.where(lengths < S, jnp.minimum(lengths + 1, S), BS)
         ratio = jnp.sum(w_eff.astype(jnp.float32)) / (B * S)

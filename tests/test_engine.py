@@ -588,6 +588,38 @@ def test_decode_compact_matches_full_batch(kv_quant):
         off.shutdown()
 
 
+@pytest.mark.parametrize("attn", ["pallas", "xla"])
+def test_perf_stats_counts_what_the_blocked_attention_arm_streams(monkeypatch, attn):
+    """`perf_stats()["decode_attn"]` exists where decode rounds read the int8
+    cache through the Pallas arm, counts every step of every round dispatched
+    from the positions the host packed, and is absent where XLA attends."""
+    from llm_mcp_tpu.kernels.attention import q8_block_tokens
+
+    monkeypatch.setenv("LLM_MCP_TPU_ATTN", attn)
+    eng = GenerationEngine(
+        "tiny-llm", max_slots=4, max_seq_len=128, dtype=jnp.float32,
+        decode_chunk=2, kv_quant="int8", prefill_chunk=8,
+    ).start()
+    try:
+        out = eng.generate("count what the arm streams", max_tokens=9, temperature=0.0)
+        got = eng.perf_stats().get("decode_attn")
+        if attn == "xla":
+            assert got is None
+            return
+        heads, seq, hd = eng._ck["q"].shape[2:]
+        assert got["block_tokens"] == q8_block_tokens(heads, seq, hd) == 128
+        # every decode round's steps are counted (a verify round of the
+        # speculation path is another program and reads no blocked arm)
+        assert out["usage"]["completion_tokens"] == 9
+        assert got["steps"] >= eng.decode_chunk and got["steps"] % eng.decode_chunk == 0
+        # every row streams one block a step (4 slots, contexts under 128)
+        assert got["tokens_streamed"] == got["steps"] * 128 * 4
+        assert 0 < got["tokens_live"] < got["tokens_streamed"]
+        assert got["live_over_streamed"] == round(got["tokens_live"] / got["tokens_streamed"], 4)
+    finally:
+        eng.shutdown()
+
+
 _RAGGED_PROMPTS = [
     "ragged prefill equivalence " * 6,
     "short",

@@ -47,32 +47,124 @@ def _lens_for(fill: float, B: int, S: int, rng) -> jnp.ndarray:
 # -- GQA int8 (fused layout) -------------------------------------------------
 
 
-@pytest.mark.parametrize("pack", ["0", "1"])
-@pytest.mark.parametrize("fill", FILLS)
-def test_q8_gqa_blocked_parity(monkeypatch, fill, pack):
+# What the batch-wide pipeline of the blocked arm adds (PR 36): a cell's copy
+# is started a cell ahead, across a row's edge too, so every way one row's
+# cells can end and the next row's begin gets a case. `w` is given in blocks
+# (a pair (a, b) is a * BS + b), S is 4 blocks, "parked" is w >= S; ids None
+# is the full batch, "perm" a permuted compaction.
+PARKED = (4, 0)
+PIPELINE_CASES = {
+    "fill_0.0": None, "fill_0.4": None, "fill_0.9": None,  # scattered fills, B=3
+    "unlike_lengths": [(1, -1), (1, 0), (2, -1), (0, 0)],
+    "unlike_lengths_reversed": [(0, 0), (2, -1), (1, 0), (1, -1)],
+    "every_row_one_block": [(0, 5), (0, 0), (1, -1), (0, 17)],
+    "every_row_whole": [(4, -1), (4, -1), (4, -1)],
+    "parked_first": [PARKED, (1, 3), (0, 9)],
+    "parked_middle": [(2, 3), PARKED, (0, 9)],
+    "parked_last": [(2, 3), (0, 9), PARKED],
+    "parked_pair_between": [(1, 0), PARKED, PARKED, (2, -1)],
+    "all_parked": [PARKED, PARKED, PARKED],
+    "one_row": [(2, 5)],
+    "one_row_one_block": [(0, 0)],
+    "one_row_parked": [PARKED],
+}
+
+
+def _pipeline_params():
+    """(case, pack, block, ids): the scattered fills at the block their shape's
+    rule gives, both scale modes; every row-edge case packed at 128 and
+    unpacked at 64 under a permuted compaction; four of them at the outer block
+    sizes, and as a full batch (ids None)."""
+    out = []
+    for case, rows in PIPELINE_CASES.items():
+        if rows is None:
+            out += [(case, pack, 0, "perm") for pack in ("0", "1")]
+            continue
+        out += [(case, "1", 128, "perm"), (case, "0", 64, "perm")]
+        if case in ("unlike_lengths", "parked_first", "parked_middle", "one_row"):
+            out += [(case, "1", 32, "perm"), (case, "1", 256, "perm"), (case, "1", 128, None)]
+    return [pytest.param(*c, id="-".join(map(str, c))) for c in out]
+
+
+@pytest.mark.parametrize("case,pack,block,ids_kind", _pipeline_params())
+def test_q8_gqa_blocked_parity(monkeypatch, case, pack, block, ids_kind):
     """Fused blocked q8 kernel (packed 1-DMA and unpacked 2-DMA modes) vs
     the exact-f32 fallback: odd batch (B=3, a remainder against every
-    block shape), scattered fills, compaction ids."""
+    block shape), scattered fills, compaction ids; and since the batch's
+    cells are one pipeline, the row edges of PIPELINE_CASES at each block
+    size `q8_block_tokens` can return."""
+    rows = PIPELINE_CASES[case]
     monkeypatch.setenv("LLM_MCP_TPU_Q8_DECODE", "blocked")
     monkeypatch.setenv("LLM_MCP_TPU_Q8_SCALE_PACK", pack)
     A.decode_attend_q8.clear_cache()  # env knobs are read at trace time
     rng = np.random.default_rng(7)
-    L, B, Hkv, S, hd, G = 2, 3, 2, 256, 64, 2
+    if rows is None:
+        L, B, Hkv, S, hd, G = 2, 3, 2, 256, 64, 2
+        lens, kw = _lens_for(float(case.split("_")[1]), B, S, rng), {}
+    else:
+        L, B, Hkv, S, hd, G = 2, len(rows), 2, 4 * block, 64, 2
+        lens = jnp.asarray([a * block + b for a, b in rows], jnp.int32)
+        kw = {"block_s": block}
     ck, cv = _fused_q8_cache(rng, L, B, Hkv, S, hd)
     q = jnp.asarray(rng.standard_normal((B, Hkv, G, hd)), jnp.float32)
     nk = jnp.asarray(rng.standard_normal((B, Hkv, hd)), jnp.float32)
     nv = jnp.asarray(rng.standard_normal((B, Hkv, hd)), jnp.float32)
-    lens = _lens_for(fill, B, S, rng)
-    ids = jnp.asarray(rng.permutation(B), jnp.int32)
+    ids = jnp.asarray(rng.permutation(B), jnp.int32) if ids_kind else None
     out = A.decode_attend_q8(
-        q, nk, nv, ck, cv, jnp.int32(1), lens, slot_ids=ids, interpret=True
+        q, nk, nv, ck, cv, jnp.int32(1), lens, slot_ids=ids, interpret=True, **kw
     )
     ref = A._decode_attend_q8_fallback(
         q, nk, nv, ck, cv, jnp.int32(1), lens, hd**-0.5, ids
     )
+    seated = (lens < S)[:, None, None, None]  # a parked row's output is discarded
     # tolerance covers the kernel's q/prob int8 requantization
-    assert float(jnp.max(jnp.abs(out - ref))) < 0.05
-    assert not bool(jnp.isnan(out).any())
+    assert float(jnp.max(jnp.abs(jnp.where(seated, out - ref, 0.0)))) < 0.05
+    assert not bool(jnp.isnan(jnp.where(seated, out, 0.0)).any())
+
+
+def test_q8_block_tokens_is_a_function_of_the_caches_shape():
+    """The three shapes the benchmark's cells run (PERF.md section 6, PR 36):
+    17 payload heads take the coarse block, 61 the finer one; a block always
+    divides the row; 0 where nothing int8-tileable does."""
+    assert A.q8_block_tokens(17, 2048, 128) == 256  # decode_closed
+    assert A.q8_block_tokens(17, 1024, 128) == 256  # solar_decode_closed
+    assert A.q8_block_tokens(61, 1024, 128) == 128  # olmo_hybrid_decode_closed
+    assert A.q8_block_tokens(61, 1024 + 64, 128) == 64
+    assert A.q8_block_tokens(400, 1024, 128) == 32  # none within the bound: the smallest
+    assert A.q8_block_tokens(17, 1000, 128) == 0
+    for heads, seq in [(17, 2048), (61, 1024), (5, 96), (33, 640)]:
+        assert seq % A.q8_block_tokens(heads, seq, 128) == 0
+
+
+def test_blocked_row_blocks_counts_what_the_arm_streams():
+    w = np.array([0, 63, 64, 127, 128, 255, 256, 1023, 1024, 5000])
+    assert A.blocked_row_blocks(w, 1024, 64, xp=np).tolist() == [1, 1, 2, 2, 3, 4, 5, 16, 1, 1]
+    assert A.blocked_row_blocks(w, 1024, 256, xp=np).tolist() == [1, 1, 1, 1, 1, 1, 2, 4, 1, 1]
+    np.testing.assert_array_equal(
+        np.asarray(A.blocked_row_blocks(jnp.asarray(w), 1024, 128)),
+        A.blocked_row_blocks(w, 1024, 128, xp=np))
+
+
+@pytest.mark.parametrize("heads,block", [(17, 256), (61, 128)])
+def test_attn_stream_counts_a_rounds_steps(heads, block):
+    """`perf_stats()["decode_attn"]`: positions fetched and positions live over
+    the steps of the rounds dispatched, at the block size the cache's shape
+    gives; a parked row one block and no live position, a row that reaches the
+    cache's end mid-round parked from there."""
+    book = A.AttnStream((4, 8, heads, 1024, 128))
+    assert book.block_tokens == block and book.stats()["live_over_streamed"] is None
+    lens = np.array([0, block - 2, 1022, 1024, 3000], np.int32)
+    book.dispatched(lens, 4)
+    # row 0: positions 0..3, one block a step; row 1 crosses into its second
+    # block at step 2; row 2 holds 1023 and 1024 positions, then is parked
+    blocks = 4 + (1 + 1 + 2 + 2) + (1024 // block) * 2 + 2 + 4 + 4
+    live = (1 + 2 + 3 + 4) + (4 * block + 2) + (1023 + 1024)
+    got = book.stats()
+    assert got["steps"] == 4 and got["block_tokens"] == block
+    assert got["tokens_streamed"] == blocks * block and got["tokens_live"] == live
+    assert got["live_over_streamed"] == round(live / (blocks * block), 4)
+    book.dispatched(lens[:1], 2)
+    assert book.stats()["steps"] == 6 and book.stats()["tokens_live"] == live + 1 + 2
 
 
 @pytest.mark.parametrize("fill", FILLS)

@@ -114,6 +114,34 @@ def test_decode_attend_q8(sd, monkeypatch, mode):
     )
 
 
+# [rows, KV heads, group], cache length, layers: the blocked arm as each
+# generation cell of BENCHMARK.json runs it (PERF.md section 4)
+CELL_SHAPES = {
+    "decode_closed": ((32, 8, 4), 2048, 36),
+    "solar_decode_closed": ((64, 8, 8), 1024, 1),
+    "olmo_hybrid_decode_closed": ((64, 30, 1), 1024, 5),
+}
+
+
+@pytest.mark.parametrize("mode", ["blocked", "auto"])
+@pytest.mark.parametrize("cell", list(CELL_SHAPES))
+def test_decode_attend_q8_blocked_at_the_cells_shapes(sd, monkeypatch, cell, mode):
+    """The batch-wide pipeline of the blocked arm at the three shapes the
+    benchmark runs it at, with the block size its rule gives there: a Mosaic
+    call under the name the trace readers look for, and no fall."""
+    monkeypatch.setenv("LLM_MCP_TPU_Q8_DECODE", mode)
+    (ba, hkv, g), seq, layers = CELL_SHAPES[cell]
+    cache = {"q": sd((layers, ba, 2 * hkv + 1, seq, HD), I8),
+             "s": sd((layers, ba, 2 * hkv, seq), BF)}
+    text = compile_for_chip(
+        lambda q, nk, nv, ck, li, n, ids: A.decode_attend_q8(
+            q, nk, nv, ck, {}, li, n, slot_ids=ids, interpret=False),
+        sd((ba, hkv, g, HD), BF), sd((ba, hkv, HD), BF), sd((ba, hkv, HD), BF),
+        cache, sd((), I32), sd((ba,), I32), sd((ba,), I32),
+    )
+    assert "decode_attn_q8_blocked" in text
+
+
 def test_decode_attend_q8_paged(sd, monkeypatch):
     """The engine's default: physical paging on, so the decode step holds the
     contiguous hybrid AND the block-indirect arm under the identity cond."""
