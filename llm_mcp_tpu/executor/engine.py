@@ -236,6 +236,9 @@ class GenRequest:
     # filled by the engine
     out: "queue.Queue[Any]" = field(default_factory=queue.Queue)
     created_at: float = field(default_factory=time.time)
+    # the same moment on the clock every span and sample is on
+    # (time.monotonic): a batch's wait, a slot's vacancy
+    arrived_t: float = field(default_factory=time.monotonic)
     # tracing: wire context captured on the submitting thread; the engine
     # loop records admit/prefill/decode child spans against it retroactively
     # (the loop thread never blocks on the tracer)
@@ -311,6 +314,9 @@ class _Slot:
     # at finish
     perf_last_emit: float = 0.0
     last_text_t: float = 0.0  # time.monotonic() of the previous text event
+    # ... and where that event stood in the device's order: the admissions
+    # dispatched before what brought it, and their padded tokens
+    adm_mark: tuple = (0, 0)
     itl_s_total: float = 0.0
     itl_samples: int = 0
     # latency waterfall (telemetry/workload.py): synchronous prefill
@@ -343,6 +349,10 @@ class _DispatchedRound:
     t_disp: float = 0.0  # time.perf_counter() when the jit call returned
     # (host_s, wait_s) where the perf observatory sampled this dispatch
     sample: tuple | None = None
+    # (admissions dispatched before this round since boot, their padded
+    # tokens): the difference of two rounds' is what the device ran between
+    # them beside the rounds (_put_text)
+    mark: tuple = (0, 0)
 
 
 @dataclass
@@ -359,6 +369,10 @@ class _DispatchedAdmit:
     t0: float  # time.perf_counter() before the dispatch
     t_call: float  # ... when the jit call returned
     first: bool  # first dispatch of its shape: the CompileLedger's, no sample
+    aid: int = 0  # the ring's `admit_prog` event of this dispatch
+    # (admissions dispatched up to and including this one, their padded
+    # tokens): where its first tokens stand in the device's order (_put_text)
+    mark: tuple = (0, 0)
 
 
 @dataclass
@@ -369,6 +383,7 @@ class _PendingRound:
     entries: list  # [(b, _Slot, col)]
     base: Any
     rid: int = 0
+    mark: tuple = (0, 0)  # the dispatched round's
 
 
 @dataclass
@@ -1273,12 +1288,12 @@ class GenerationEngine:
         # rounds and batched admissions. The engine thread only ever blocks
         # on the oldest item (_run).
         self._inflight: deque[_DispatchedRound | _DispatchedAdmit] = deque()
-        # how often the queued read engages: admissions read, those whose
-        # read still had to wait for the device, and those read where they
-        # were dispatched (a batch that needs its token's value at once)
-        self.admit_reads = 0
-        self.admit_reads_blocked = 0
-        self.admit_reads_at_once = 0
+        # admission's account (perf_stats()["admit"]): a record an admit
+        # program dispatched, how often the queued read engages, and why a
+        # slot stood empty. slot -> [time.monotonic() of its free, ... of the
+        # fetch that ended its cooling fence (None until then)]
+        self._adm = perf.AdmitAccount()
+        self._vacant: dict[int, list] = {}
 
         # Self-speculative decoding (draft-and-verify): a host-side n-gram
         # drafter (drafter.py — prompt-lookup over each slot's own history)
@@ -3402,21 +3417,21 @@ class GenerationEngine:
     def perf_stats(self) -> dict[str, Any]:
         """Perf-observatory block (/v1/debug/perf, engines_info, benchmark/):
         ITL percentiles, goodput split, sampled per-phase host/device/wait
-        attribution, and the four-layout roofline, with the engine's count
-        of admissions read from the in-flight queue (`admit_reads`; of them
-        `_blocked` still had to wait for the device, `_at_once` were read
-        where they were dispatched), and for a configuration with recurrent
+        attribution, and the four-layout roofline, with admission's account
+        (`admit`: sums over the admit programs dispatched, `programs`,
+        `prompts`, `rows_padded`, `true_tokens`, `padded_tokens`, `queued_sum`
+        = requests left in the queue behind each, `by_shape`, `held_by` = why
+        a batch closed; the admissions read from the in-flight queue, `reads`,
+        of them `reads_blocked` still had to wait for the device and
+        `reads_at_once` were read where they were dispatched; `vacancy` = a
+        slot's empty time by owner, telemetry/perf.py:AdmitAccount), and for a
+        configuration with recurrent
         layers the state pool's block (`state_pool`), for one whose step
         programs count the expert layer's work that block (`experts`), for one
         whose decode rounds read an int8 cache through the blocked attention
         arm what that arm streams (`decode_attn`). Read-only over the observatory's own
         lock, so safe from any thread."""
-        out = {
-            **self._perf.stats(),
-            "admit_reads": self.admit_reads,
-            "admit_reads_blocked": self.admit_reads_blocked,
-            "admit_reads_at_once": self.admit_reads_at_once,
-        }
+        out = {**self._perf.stats(), "admit": self._adm.stats()}
         if self._state_pool is not None:
             # the recurrent state pool's book: bytes, slots alive (seated or
             # mid-prefill), features off
@@ -3833,6 +3848,7 @@ class GenerationEngine:
         self._topk[b] = snap.top_k
         self._topp[b] = snap.top_p
         self._slots[b] = s
+        self._vacant.pop(b, None)  # a restore's vacancy is not booked (_seat)
         # ledger: re-table the parked shared pins + a fresh private tail.
         # A MIGRATED snapshot has no parked pins on this engine — when its
         # shared-prefix key matched our own cache, the blocks pin through
@@ -4616,6 +4632,7 @@ class GenerationEngine:
             # dispatch serves the whole group
             hits: dict[int, tuple[dict, list]] = {}
             reserved: set[int] = set()
+            held_by = "admit_batch"  # why the batch closed: it was full, or
             while len(batch) < self.admit_batch:
                 slot = self._free_slot(reserved)
                 if slot is None:
@@ -4623,10 +4640,12 @@ class GenerationEngine:
                         # a request waits and no slot is free: where a pool
                         # with host offload would weigh a preemption
                         self._state_pool.note_off("offload")
+                    held_by = "no_slot"
                     break
                 try:
                     req = self._admit.get_nowait()
                 except queue.Empty:
+                    held_by = "queue_empty"
                     break
                 req.admitted_at = time.time()
                 ids = req.prompt_ids
@@ -4655,6 +4674,7 @@ class GenerationEngine:
                     # next program instead (the queue's order is kept)
                     with self._admit.mutex:
                         self._admit.queue.appendleft(req)
+                    held_by = "budget"
                     break
                 admitted = True
                 if not self._cn_attach(req):
@@ -4707,7 +4727,7 @@ class GenerationEngine:
                     continue  # hit slots consumed; more queue may admit
                 break
             try:
-                adm = self._start_batch(batch)
+                adm = self._start_batch(batch, held_by)
                 if any(
                     req.cn is not None or self._exports_after_prefill(req)
                     for _, req, _ in batch
@@ -4824,6 +4844,7 @@ class GenerationEngine:
                 eid = ent["eid"] = self._eid_ctr
                 self._x_prefix[eid] = (ent["k"], ent["v"])
             self._dx("insert", eid, slots, n)
+            self._note_admit("cached", [req for _, req, _ in group], nb, 0, 0)
         for slot, req, ids in group:
             self._prefills[slot] = _PrefillState(
                 req=req, ids=list(ids), done=ent["P"],
@@ -5295,8 +5316,34 @@ class GenerationEngine:
                 "import_rejects_total": float(self.prefix_import_rejects_total),
             }
 
+    def _note_admit(
+        self, kind: str, reqs: list[GenRequest], rows_padded: int, bucket: int,
+        true_tokens: int, held_by: str = "",
+    ) -> int:
+        """One record an admission dispatched as a program of its own (the
+        ring's `admit_prog`, the sums of perf_stats()["admit"]); returns its
+        `aid`. `queued` is what the batch left behind in the queue, `held_by`
+        why it closed (a batch alone has a reason), `after_rid` its place in
+        the device's order: the newest round dispatched before it."""
+        queued = self._admit.qsize()
+        aid = self._adm.program(
+            kind, len(reqs), rows_padded, bucket, true_tokens, queued, held_by
+        )
+        now = time.monotonic()
+        self._flight.event(
+            "admit_prog", aid=aid, kind=kind, rows=len(reqs),
+            rows_padded=rows_padded, bucket=bucket, true_tokens=true_tokens,
+            padded_tokens=rows_padded * bucket, queued=queued,
+            held_by=held_by or None,
+            wait_ms_max=round(
+                max((now - r.arrived_t for r in reqs), default=0.0) * 1e3, 3
+            ),
+            after_rid=self._rid_dispatched, t=now,
+        )
+        return aid
+
     def _start_batch(
-        self, batch: list[tuple[int, GenRequest, list[int]]]
+        self, batch: list[tuple[int, GenRequest, list[int]]], held_by: str = "",
     ) -> _DispatchedAdmit:
         """Admit up to admit_batch short prompts with ONE batched prefill
         dispatch. At 8B the prompt weight pass dominates admission cost;
@@ -5332,8 +5379,15 @@ class GenerationEngine:
         first = self._note_exec_shape("admit", Ab, bucket, cn_payload is not None)
         self._note_expert_form("prefill", Ab * bucket)
         t0c = time.perf_counter()
-        toks0 = self._dx("admit", tokens, ipack, fpack, cn_payload)
+        # the dispatch by its number: in a profiler trace each run of
+        # jit_admit_fn has the annotation that caused it, inside engine.admit
+        with TraceAnnotation("engine.admit.dispatch", aid=self._adm.programs + 1):
+            toks0 = self._dx("admit", tokens, ipack, fpack, cn_payload)
         t_call = time.perf_counter()  # jit returned; device running
+        aid = self._note_admit(
+            "batch", [req for _, req, _ in batch], Ab, bucket,
+            sum(len(ids) for _, _, ids in batch), held_by,
+        )
         if first:
             # jit traces and compiles inside the call: the wall up to its
             # return is the compile's, and the ledger's context closes here,
@@ -5343,10 +5397,11 @@ class GenerationEngine:
         return _DispatchedAdmit(
             toks0=toks0,
             entries=[
-                (slot, self._seat(slot, req, ids), len(ids))
+                (slot, self._seat(slot, req, ids, batched=True), len(ids))
                 for slot, req, ids in batch
             ],
             t0=t0c, t_call=t_call, first=first,
+            aid=aid, mark=self._adm.mark(),
         )
 
     def _read_admit(self, adm: _DispatchedAdmit, at_once: bool = False) -> None:
@@ -5364,11 +5419,10 @@ class GenerationEngine:
         with TraceAnnotation("engine.admit.sync"):
             toks0 = np.asarray(adm.toks0)  # the admission's only host sync
         now = time.perf_counter()
-        self.admit_reads += 1
-        self.admit_reads_blocked += blocked
-        self.admit_reads_at_once += at_once
+        self._adm.read(blocked, at_once)
         self._flight.event(
-            "admit_read", rows=len(adm.entries), after_rid=self._rid_fetched,
+            "admit_read", aid=adm.aid, rows=len(adm.entries),
+            after_rid=self._rid_fetched,
             wait_ms=round((now - t_wait) * 1e3, 3), blocked=blocked,
             t=time.monotonic(),
         )
@@ -5389,7 +5443,7 @@ class GenerationEngine:
                 self._free_now(slot)
                 continue
             s.prefill_compute_s += wall_a_token * P
-            self._first_token(slot, s, int(toks0[i]))
+            self._first_token(slot, s, int(toks0[i]), adm.mark)
 
     def _activate_state(
         self, slot: int, req: GenRequest, ids: list[int], tok0: int
@@ -5398,12 +5452,24 @@ class GenerationEngine:
         prefill's activations, which read their sample where it is made."""
         self._first_token(slot, self._seat(slot, req, ids), tok0)
 
-    def _seat(self, slot: int, req: GenRequest, ids: list[int]) -> _Slot:
+    def _seat(
+        self, slot: int, req: GenRequest, ids: list[int], batched: bool = False
+    ) -> _Slot:
         """All of an activation that the next decode dispatch needs and that
         does not need the first token's value: the slot object, its length,
         the paging ledger, the sampling mirrors, the prefix store, the
-        drafter."""
+        drafter. It closes the slot's vacancy; a `batched` admission (a whole
+        prompt through an admit program) books it by owner, any other taker
+        (a chunked prefill, which reserved the slot long before, a prefix
+        hit) drops the stamp unbooked."""
         P = len(ids)
+        vacant = self._vacant.pop(slot, None)
+        if vacant is not None and batched:
+            now = time.monotonic()
+            t_free, t_cool = vacant
+            self._adm.vacancy(
+                t_free, now if t_cool is None else t_cool, req.arrived_t, now
+            )
         # the slot's cache rows [0, P) now hold exactly this prompt's KV —
         # the moment to learn a shared prefix for future admissions
         self._maybe_store_prefix(slot, ids)
@@ -5446,10 +5512,12 @@ class GenerationEngine:
             s.spec.extend(ids)
         return s
 
-    def _first_token(self, slot: int, s: _Slot, tok0: int) -> None:
+    def _first_token(
+        self, slot: int, s: _Slot, tok0: int, mark: tuple | None = None
+    ) -> None:
         """The host has read a seated slot's first token: the TTFT stamp
-        and its records, the recovery mirror, the token's emission, and the
-        hand-over of a prefill-role engine."""
+        and its records, the recovery mirror, the token's emission (`mark`:
+        _put_text), and the hand-over of a prefill-role engine."""
         req = s.req
         P = s.prompt_len
         s.first_token_at = time.time()
@@ -5488,7 +5556,7 @@ class GenerationEngine:
                 },
             )
         # tok0's KV will be written at position P in the first decode round.
-        self._emit_token(slot, s, tok0, pos=P - 1)
+        self._emit_token(slot, s, tok0, pos=P - 1, mark=mark)
         if self._exports_after_prefill(req) and not s.done and not s.aborted:
             # disaggregated mode: this engine spent the prefill and emitted
             # the first token; the decode-role peer continues from here
@@ -5757,6 +5825,9 @@ class GenerationEngine:
                     "pf_rag", rows=len(group.metas), tokens=group.n_tokens,
                     packed=group.bucket, wall_ms=round(wall * 1e3, 2),
                 )
+                # a packed buffer is one row of `bucket` tokens
+                self._note_admit("chunk", [st.req for _, st, _ in group.metas],
+                                 1, group.bucket, group.n_tokens)
                 self._finish_prefill_group(group)
                 return
             first = self._note_exec_shape("chunk", group.tokens.shape[0],
@@ -5794,6 +5865,8 @@ class GenerationEngine:
                 "chunk", rows=len(group.metas), tokens=group.n_tokens,
                 bucket=group.bucket, wall_ms=round(wall * 1e3, 2),
             )
+            self._note_admit("chunk", [st.req for _, st, _ in group.metas],
+                             group.tokens.shape[0], group.bucket, group.n_tokens)
         except Exception as e:
             self._fail_prefill_group(group, e)
             return
@@ -6394,7 +6467,7 @@ class GenerationEngine:
             rid=self._rid_dispatched,
             prefill_tokens=group.n_tokens if group is not None else 0,
             prefill_padded=padded, phase=phase_name, dx=self._dx_n,
-            t_disp=t_disp, sample=sample,
+            t_disp=t_disp, sample=sample, mark=self._adm.mark(),
         )
 
     def _complete_round(self, disp: _DispatchedRound) -> _PendingRound:
@@ -6488,8 +6561,18 @@ class GenerationEngine:
                 # only the recovery mirror updates here
                 self._last_tok[b] = out[-1, col]
         self._rid_fetched = max(self._rid_fetched, disp.rid)
+        if self._cooling:
+            # the fetch that ends a freed slot's fence stamps it: from here
+            # the slot is empty for want of a request or of an admit program
+            t_cool = time.monotonic()
+            for b, fence in self._cooling.items():
+                vacant = self._vacant.get(b)
+                if (vacant is not None and vacant[1] is None
+                        and fence <= self._rid_fetched):
+                    vacant[1] = t_cool
         return _PendingRound(
-            out=out, entries=disp.entries, base=disp.base, rid=disp.rid
+            out=out, entries=disp.entries, base=disp.base, rid=disp.rid,
+            mark=disp.mark,
         )
 
     def _observe_round_device(
@@ -6535,8 +6618,12 @@ class GenerationEngine:
         # physical: the device table row back to identity + pool-row sweep
         self._paging.free_slot(b)
         self._phys_reset(b)
+        t_free = time.monotonic()  # the vacancy _seat closes
         if self._rid_dispatched > self._rid_fetched:
             self._cooling[b] = self._rid_dispatched
+            self._vacant[b] = [t_free, None]
+        else:
+            self._vacant[b] = [t_free, t_free]  # nothing in flight: cool at once
 
     def _emit_round(self, p: _PendingRound) -> None:
         """Phase 3 (deferred, overlapped with the next round's device time):
@@ -6564,7 +6651,7 @@ class GenerationEngine:
                 # were all learned at the same fetch, so splitting them into
                 # K queue events (and K SSE frames) adds overhead with zero
                 # client-visible timing difference
-                self._put_text(s, "".join(parts))
+                self._put_text(s, "".join(parts), p.mark)
                 texts += 1
                 if self._pool is not None:
                     # the "idle" preemption policy's victim signal; guarded
@@ -6584,16 +6671,26 @@ class GenerationEngine:
         with self.stats_lock:
             self._window.append((time.time(), delivered))
 
-    def _put_text(self, s: _Slot, text: str) -> None:
+    def _put_text(self, s: _Slot, text: str, mark: tuple | None = None) -> None:
         """One text event onto a stream's queue, stamped with the
         time.monotonic() of its put (the HTTP handler observes `stream_lag`
         against it after the socket write), and the gap since the stream's
         previous text event: what a reader of the stream would see if the
-        handler added nothing, whole, not spread over the round's tokens."""
+        handler added nothing, whole, not spread over the round's tokens.
+        `mark` is where what brought the event stands in the device's order,
+        (admissions dispatched before it, their padded tokens): a round's,
+        an admission's own, or where nothing was in flight the engine's
+        count as it stands. The gap's sample says how many admit programs,
+        of how many padded tokens, the device ran between the two events."""
         now = time.monotonic()
+        mark = mark or self._adm.mark()
         if s.last_text_t:
-            self._perf.observe_sample("event_gap", now - s.last_text_t)
+            self._perf.observe_sample(
+                "event_gap", now - s.last_text_t,
+                mark[0] - s.adm_mark[0], mark[1] - s.adm_mark[1],
+            )
         s.last_text_t = now
+        s.adm_mark = mark
         s.req.out.put({"type": "token", "text": text, "t": now})
 
     def _sample_prefill_phase(
@@ -6645,7 +6742,10 @@ class GenerationEngine:
             s.stall_s += gap - thr
         self._anomaly.signal("itl_degradation", itl_ms=itl * 1e3)
 
-    def _emit_token(self, slot_idx: int, s: _Slot, tok: int, pos: int) -> bool:
+    def _emit_token(
+        self, slot_idx: int, s: _Slot, tok: int, pos: int,
+        mark: tuple | None = None,
+    ) -> bool:
         """Append one token to a slot; returns False when the slot finished.
 
         `pos` is the cache position this token's KV occupies (or will occupy,
@@ -6658,7 +6758,7 @@ class GenerationEngine:
         identity-guarded (_finish_slot)."""
         emit, finish = self._process_token(s, tok, pos)
         if emit:
-            self._put_text(s, emit)
+            self._put_text(s, emit, mark)
         if finish is not None:
             self._finish_slot(slot_idx, s, finish)
             return False

@@ -67,6 +67,7 @@ from typing import Any
 
 __all__ = [
     "AUX_COMPILE_PHASES",
+    "AdmitAccount",
     "CACHE_LAYOUTS",
     "DISPATCH_PHASES",
     "ModelShape",
@@ -109,8 +110,18 @@ DEFAULT_PERF_SAMPLE = 32
 #   gap over the round's tokens);
 # stream_lag — from the engine's put of a text event to the moment the HTTP
 #   handler has written its SSE frame to the socket.
+# An event_gap sample also says what the device ran between the two events:
+# the admit programs dispatched between the rounds that brought them, and
+# those programs' padded tokens (`samples(kind, whole=True)`).
 SAMPLE_KINDS = ("event_gap", "stream_lag")
-SAMPLE_WINDOW = 32768  # about two minutes of 32 streams at 7 rounds a second
+# The largest cell (64 streams, a round and its admit programs every 47 ms)
+# puts 1,300 gaps a second: 52,000 in its 40 s, 56,000 with the drain, 80,000
+# since boot when the benchmark reads them (v5e, PR 37; at 32,768 the first
+# 18 s of the 40 were gone by then). 131,072 holds window and drain with rounds
+# twice as fast; a full window of four numbers a sample is about 20 MB a kind.
+# What is pushed out is counted (`samples_evicted`), so a reader knows when
+# its window has lost its start.
+SAMPLE_WINDOW = 131072
 DEFAULT_TARGET_ITL_MS = 0.0  # no ITL SLO unless configured
 # Published per-chip peaks keyed by JAX `device_kind`: (bf16 TFLOP/s, HBM
 # GB/s). Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
@@ -325,6 +336,89 @@ def _pctl(vals: list[float], q: float) -> float:
     return vals[max(0, min(n - 1, int(n * q + 0.5) - 1))]
 
 
+class AdmitAccount:
+    """What admission cost, in sums since boot (`perf_stats()["admit"]`): a
+    reader takes the difference over its window. Written by the engine's
+    thread alone, read from any.
+
+    `program` books one admission the engine dispatched as a device program
+    of its own: `kind` "batch" (whole prompts through `admit_fn`), "cached"
+    (a prefix hit's rows copied into their slots) or "chunk" (a chunk group
+    run by itself, nothing decoding). `read` books the read of a batch's
+    first tokens. `vacancy` books one slot's empty time, free to seated, in
+    three parts on one clock: `cooling_s` (free, fenced until the rounds in
+    flight at the free were fetched), `no_request_s` (cool, and the request
+    that took the slot had not arrived), `queued_s` (cool, the request
+    waiting, no admit program dispatched yet: the engine's own part)."""
+
+    SUMS = ("programs", "prompts", "rows_padded", "true_tokens",
+            "padded_tokens", "queued_sum", "reads", "reads_blocked",
+            "reads_at_once")
+    VACANCY = ("count", "cooling_s", "no_request_s", "queued_s")
+
+    def __init__(self) -> None:
+        self._lock = threading.Lock()
+        for k in self.SUMS:
+            setattr(self, k, 0)
+        self.by_shape: dict[str, int] = {}  # "rows_padded:bucket" -> programs
+        self.held_by: dict[str, int] = {}  # why a batch closed -> programs
+        self._vacancy = {**dict.fromkeys(self.VACANCY, 0.0), "count": 0}
+
+    def program(self, kind: str, rows: int, rows_padded: int, bucket: int,
+                true_tokens: int, queued: int, held_by: str = "") -> int:
+        """Book one admission dispatched; returns its `aid`, the count of
+        admissions dispatched up to and including it."""
+        shape = f"{rows_padded}:{bucket}"
+        if kind != "batch":
+            shape = f"{kind} {shape}"
+        with self._lock:
+            self.programs += 1
+            self.prompts += rows
+            self.rows_padded += rows_padded
+            self.true_tokens += true_tokens
+            self.padded_tokens += rows_padded * bucket
+            self.queued_sum += queued
+            self.by_shape[shape] = self.by_shape.get(shape, 0) + 1
+            if held_by:
+                self.held_by[held_by] = self.held_by.get(held_by, 0) + 1
+            return self.programs
+
+    def mark(self) -> tuple[int, int]:
+        """(admissions dispatched so far, their padded tokens): a place in
+        the device's order; the difference of two is what was dispatched
+        between them."""
+        return self.programs, self.padded_tokens
+
+    def read(self, blocked: bool, at_once: bool) -> None:
+        self.reads += 1
+        self.reads_blocked += blocked
+        self.reads_at_once += at_once
+
+    def vacancy(self, t_free: float, t_cool: float, t_arrived: float,
+                t_seat: float) -> tuple[float, float, float]:
+        """One vacancy closed at `t_seat`; the three parts sum to seat less
+        free whatever the order of the four times."""
+        t_cool = min(max(t_cool, t_free), t_seat)
+        no_request = min(max(t_arrived - t_cool, 0.0), t_seat - t_cool)
+        parts = (t_cool - t_free, no_request, t_seat - t_cool - no_request)
+        v = self._vacancy
+        with self._lock:
+            v["count"] += 1
+            v["cooling_s"] += parts[0]
+            v["no_request_s"] += parts[1]
+            v["queued_s"] += parts[2]
+        return parts
+
+    def stats(self) -> dict[str, Any]:
+        with self._lock:
+            return {
+                **{k: getattr(self, k) for k in self.SUMS},
+                "by_shape": dict(self.by_shape),
+                "held_by": dict(self.held_by),
+                "vacancy": dict(self._vacancy),
+            }
+
+
 class PerfObservatory:
     """Per-process-engine perf state: ITL window, goodput ledger, sampled
     phase attribution, and the roofline evaluation. All writers are the
@@ -370,6 +464,8 @@ class PerfObservatory:
         # one generic timestamped window: kind -> (time.monotonic(), value),
         # so that a reader can cut by its own window (SAMPLE_KINDS)
         self._samples = {k: deque(maxlen=SAMPLE_WINDOW) for k in SAMPLE_KINDS}
+        # samples pushed out of a full window since boot, a kind
+        self.samples_evicted = {k: 0 for k in SAMPLE_KINDS}
         # goodput ledger: lifetime counters + a rolling (ts, tokens, good)
         # window for the live tok/s split
         self.finished_requests = 0
@@ -461,18 +557,25 @@ class PerfObservatory:
 
     # -- timestamped samples -----------------------------------------------
 
-    def observe_sample(self, kind: str, value_s: float) -> None:
-        """One sample of a SAMPLE_KINDS window, stamped time.monotonic().
-        Written by the engine thread and the HTTP handlers' threads."""
+    def observe_sample(self, kind: str, value_s: float, *also: float) -> None:
+        """One sample of a SAMPLE_KINDS window, stamped time.monotonic(),
+        with what the writer knows `also` (an event_gap's admit programs and
+        their padded tokens). Written by the engine thread and the HTTP
+        handlers' threads. A full window pushes its oldest sample out and
+        counts it."""
         win = self._samples.get(kind)
         if win is not None:
             with self._lock:
-                win.append((time.monotonic(), max(0.0, value_s)))
+                if len(win) == win.maxlen:
+                    self.samples_evicted[kind] += 1
+                win.append((time.monotonic(), max(0.0, value_s), *also))
 
-    def samples(self, kind: str) -> list[tuple[float, float]]:
-        """(time.monotonic(), seconds) of every sample the window holds."""
+    def samples(self, kind: str, whole: bool = False) -> list[tuple]:
+        """(time.monotonic(), seconds) of every sample the window holds;
+        `whole` gives each sample as it was written, (t, seconds, *also)."""
         with self._lock:
-            return list(self._samples.get(kind, ()))
+            got = list(self._samples.get(kind, ()))
+        return got if whole else [s[:2] for s in got]
 
     def sample_percentiles(self, kind: str) -> dict[str, float]:
         """Over the newest samples only (the ITL window's size), and only
@@ -481,7 +584,7 @@ class PerfObservatory:
         of its own cuts `samples()` itself."""
         with self._lock:
             win = self._samples[kind]
-            vals = [v for _t, v in islice(reversed(win), self._itl.maxlen)]
+            vals = [s[1] for s in islice(reversed(win), self._itl.maxlen)]
         vals.sort()
         return {
             "p50_ms": _pctl(vals, 0.50) * 1e3,
@@ -741,6 +844,7 @@ class PerfObservatory:
             "sample_every": float(self.sample_every),
             "itl": self.itl_percentiles(),
             **{k: self.sample_percentiles(k) for k in SAMPLE_KINDS},
+            "samples_evicted": dict(self.samples_evicted),
             "itl_mean_ms": (
                 self._itl_sum_s / self._itl_count * 1e3
                 if self._itl_count else 0.0

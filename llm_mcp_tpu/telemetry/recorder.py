@@ -30,8 +30,18 @@ An *event* is a tuple ``(seq, ts, etype, trace_id, fields)``:
             round's tokens went to their streams: rid, rows dispatched,
             tokens delivered, text events put, rows held = tokens and no
             text, dur_ms; these five carry t = time.monotonic()) /
+            admit_prog (one admission DISPATCHED as a device program of
+            its own: aid = the engine's count of them, kind = batch (whole
+            prompts, admit_fn) / cached (a prefix hit's rows) / chunk (a
+            chunk group with nothing decoding), rows, rows_padded, bucket,
+            true_tokens, padded_tokens = rows_padded x bucket, queued =
+            requests the batch left in the queue, held_by = why it closed:
+            queue_empty / no_slot / admit_batch / budget, wait_ms_max = its
+            oldest request's arrival to here, after_rid = the newest round
+            dispatched before it, t; `admit` is the per-request event at
+            the first token) /
             admit_read (a batched admission's first tokens were read from
-            the in-flight queue: rows, after_rid = the newest round
+            the in-flight queue: aid, rows, after_rid = the newest round
             fetched before it, wait_ms, blocked = the read still had to
             wait for the device, t) / preempt /
             offload / restore / cow / pin / unpin / snap (paged ledger

@@ -1152,12 +1152,16 @@ def _spy(eng, script=()):
     dispatch of the round they name (so the admission that follows is
     dispatched behind that round), and note what the loop does: each
     admission's dispatch (rounds dispatched and fetched by then), each read,
-    each round's rows by request, every token a request was given."""
-    seen = {"admits": [], "reads": [], "rounds": [], "tokens": {}, "log": [],
+    each round's rows by request, every token a request was given, and every
+    text event put with what brought it (`puts`: a round's emission by its
+    rid, an admission's read by its place in `admits`)."""
+    seen = {"admits": [], "reads": [], "rounds": [], "tokens": {}, "log": [], "puts": [],
             "due": sorted(script, key=lambda x: x[0])}  # a test may add to it while the engine is idle
     due = seen["due"]
     dispatch, start, read, process = (eng._dispatch_decode, eng._start_batch, eng._read_admit,
                                       eng._process_token)
+    emit, put = eng._emit_round, eng._put_text
+    bringing = [None]
 
     def spy_dispatch(active, group=None):
         rid = eng._rid_dispatched + 1
@@ -1168,8 +1172,8 @@ def _spy(eng, script=()):
         seen["log"].append(("round", rid))
         return dispatch(active, group)
 
-    def spy_start(batch):
-        adm = start(batch)
+    def spy_start(batch, held_by=""):
+        adm = start(batch, held_by)
         seen["admits"].append({"adm": adm, "dispatched": eng._rid_dispatched, "fetched": eng._rid_fetched,
                                "requests": [r.request_id for _, r, _ in batch],
                                "slots": [slot for slot, _, _ in batch]})
@@ -1179,7 +1183,16 @@ def _spy(eng, script=()):
     def spy_read(adm, at_once=False):
         seen["reads"].append({"adm": adm, "fetched": eng._rid_fetched, "at_once": at_once})
         seen["log"].append(("read", id(adm)))
+        bringing[0] = ("adm", next(i for i, a in enumerate(seen["admits"]) if a["adm"] is adm))
         return read(adm, at_once)
+
+    def spy_emit(p):
+        bringing[0] = ("round", p.rid)
+        return emit(p)
+
+    def spy_put(s, text, mark=None):
+        seen["puts"].append((s.req.request_id, bringing[0]))
+        return put(s, text, mark)
 
     def spy_process(s, tok, pos):
         seen["tokens"].setdefault(s.req.request_id, []).append((pos, tok))
@@ -1187,6 +1200,7 @@ def _spy(eng, script=()):
 
     eng._dispatch_decode, eng._start_batch, eng._read_admit, eng._process_token = (
         spy_dispatch, spy_start, spy_read, spy_process)
+    eng._emit_round, eng._put_text = spy_emit, spy_put
     return seen
 
 
@@ -1217,6 +1231,7 @@ def _scripted(depth, admit_batch, compact):
             time.sleep(0.01)  # rounds dispatched before the last finish was known are still fetched
         return {"got": got, "tokens": [seen["tokens"].get(r.request_id) for r in reqs], "seen": seen,
                 "ring": [(e["etype"], e["fields"]) for e in rec.snapshot()], "stats": eng.perf_stats(),
+                "gaps": eng._perf.samples("event_gap", whole=True),
                 "errors": eng.total_errors, "ids": [r.request_id for r in reqs],
                 "left": len(eng._inflight)}
 
@@ -1252,8 +1267,8 @@ def test_an_admission_is_read_when_it_is_the_oldest_item_in_flight(depth, admit_
             at[etype][f["rid"]] = i
     dispatched = [f["rid"] for etype, f in ring if etype in ("decode", "fused", "fused_rag")]
     reads = [(i, f) for i, (etype, f) in enumerate(ring) if etype == "admit_read"]
-    assert len(reads) == len(seen["admits"]) == len(seen["reads"]) == run["stats"]["admit_reads"]
-    assert run["stats"]["admit_reads_at_once"] == 0
+    assert len(reads) == len(seen["admits"]) == len(seen["reads"]) == run["stats"]["admit"]["reads"]
+    assert run["stats"]["admit"]["reads_at_once"] == 0
     assert sum(f["rows"] for _, f in reads) == len(SCRIPT)
     for (i, f), admit, read in zip(reads, seen["admits"], seen["reads"]):
         assert read["adm"] is admit["adm"]  # read in the order dispatched
@@ -1270,6 +1285,113 @@ def test_an_admission_is_read_when_it_is_the_oldest_item_in_flight(depth, admit_
     # emitted before its first tokens were read
     full = [a for a in seen["admits"] if a["dispatched"] - a["fetched"] == depth]
     assert full, [(a["dispatched"], a["fetched"]) for a in seen["admits"]]
+
+
+@pytest.mark.parametrize("depth,admit_batch,compact", QUEUE_CASES)
+def test_every_admission_dispatched_has_one_record_and_the_block_sums_them(depth, admit_batch, compact):
+    """PR 37: the ring's `admit_prog`, one a dispatched admission in the order
+    dispatched, states the program's shape and what it carried; the sums of
+    `perf_stats()["admit"]` are the ring's."""
+    run = _scripted(depth, admit_batch, compact)
+    progs = [f for etype, f in run["ring"] if etype == "admit_prog"]
+    admits, block = run["seen"]["admits"], run["stats"]["admit"]
+    lens = {rid: len(ByteTokenizer().encode(p)) for rid, (_, p, _) in zip(run["ids"], SCRIPT)}
+    assert [f["aid"] for f in progs] == list(range(1, len(admits) + 1))
+    for f, a in zip(progs, admits):
+        assert f["kind"] == "batch" and f["rows"] == len(a["requests"]) <= f["rows_padded"] <= admit_batch
+        assert f["rows_padded"] & (f["rows_padded"] - 1) == 0
+        assert f["true_tokens"] == sum(lens[r] for r in a["requests"])
+        assert f["padded_tokens"] == f["rows_padded"] * f["bucket"] >= f["true_tokens"]
+        assert f["bucket"] >= max(lens[r] for r in a["requests"])
+        assert f["after_rid"] == a["dispatched"] and f["queued"] >= 0 and f["wait_ms_max"] >= 0
+        assert f["held_by"] in ("queue_empty", "admit_batch", "no_slot", "budget")
+    for key, field in (("programs", None), ("prompts", "rows"), ("rows_padded", "rows_padded"),
+                       ("true_tokens", "true_tokens"), ("padded_tokens", "padded_tokens"),
+                       ("queued_sum", "queued")):
+        assert block[key] == sum(1 if field is None else f[field] for f in progs), key
+    assert block["prompts"] == len(SCRIPT) and block["reads"] == block["programs"]
+    assert sum(block["by_shape"].values()) == sum(block["held_by"].values()) == block["programs"]
+    assert block["by_shape"] == {k: sum(f"{f['rows_padded']}:{f['bucket']}" == k for f in progs)
+                                 for k in block["by_shape"]}
+    reads = [f for etype, f in run["ring"] if etype == "admit_read"]
+    assert [f["aid"] for f in reads] == [f["aid"] for f in progs]  # read in the order dispatched
+    if admit_batch == 1:
+        assert block["held_by"].get("admit_batch", 0) == block["programs"]  # every batch closed full
+    else:
+        assert block["held_by"].get("queue_empty", 0) >= 1
+
+
+@pytest.mark.parametrize("depth,admit_batch,compact", QUEUE_CASES)
+def test_a_gap_counts_the_admissions_dispatched_between_its_two_events(depth, admit_batch, compact):
+    """PR 37: an `event_gap` sample says how many admit programs, of how many
+    padded tokens, the device ran between the stream's two events. The oracle
+    is the loop as the spy saw it: an admission dispatched when `d` rounds had
+    been stands between round d and round d + 1; a gap from round r1's event
+    to round r2's holds those with r1 <= d < r2, and a gap from a first token
+    those dispatched after its own admission with d < r2."""
+    run = _scripted(depth, admit_batch, compact)
+    admits = run["seen"]["admits"]
+    padded = [f["padded_tokens"] for etype, f in run["ring"] if etype == "admit_prog"]
+    gaps = iter(run["gaps"])
+    last: dict[str, tuple] = {}
+    n = with_one = without = 0
+    for rid, what in run["seen"]["puts"]:
+        if rid in last:
+            kind, at = last[rid]
+            first = at + 1 if kind == "adm" else next(
+                (i for i, a in enumerate(admits) if a["dispatched"] >= at), len(admits))
+            assert what[0] == "round"  # a stream's later events all come from rounds
+            between = [i for i in range(first, len(admits)) if admits[i]["dispatched"] < what[1]]
+            _t, seconds, n_admits, n_tokens = next(gaps)
+            assert (n_admits, n_tokens) == (len(between), sum(padded[i] for i in between)), (rid, last[rid], what)
+            assert seconds >= 0
+            n += 1
+            with_one += n_admits >= 1
+            without += n_admits == 0
+        last[rid] = what
+    assert next(gaps, None) is None and n == len(run["gaps"])
+    # the long stream rode rounds with an admission between them and rounds without
+    assert with_one >= 2 and without >= 2
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_a_slots_empty_time_is_split_by_owner_on_one_clock(depth):
+    """PR 37: `_free_now` stamps a slot, the fetch that ends its fence stamps
+    it cool, `_seat` closes the vacancy: cooling + no_request + queued = seat
+    less free for every vacancy; a request queued before the slot was freed
+    reads no_request 0, one that arrives into an empty engine reads what it
+    was late by."""
+    with _queue_engine(depth, max_slots=1) as (eng, _rec):
+        booked = []
+        vacancy = eng._adm.vacancy
+
+        def spy(t_free, t_cool, t_arrived, t_seat):
+            parts = vacancy(t_free, t_cool, t_arrived, t_seat)
+            booked.append(((t_free, t_cool, t_arrived, t_seat), parts))
+            return parts
+
+        eng._adm.vacancy = spy
+        eng.start()
+        first, queued = (eng.submit(_request(eng, p, 9)) for p in ("holds the only slot", "waits in the queue"))
+        _events(first), _events(queued)
+        assert [a[2] < a[0] for a, _ in booked] == [True]  # the first seat closed no vacancy
+        time.sleep(0.2)
+        t_late = time.monotonic()
+        _events(eng.submit(_request(eng, "arrives into an empty engine", 5)))
+        assert len(booked) == 2
+        for (t_free, t_cool, _arr, t_seat), parts in booked:
+            assert t_free <= t_cool <= t_seat and min(parts) >= 0
+            assert sum(parts) == pytest.approx(t_seat - t_free, abs=1e-9)
+        (_, (cool_q, none_q, queued_q)), ((t_free, t_cool, t_arr, t_seat), (cool_l, none_l, queued_l)) = booked
+        assert none_q == 0 and queued_q > 0
+        assert t_arr >= t_late and none_l == pytest.approx(t_arr - t_cool) and none_l > 0.1  # of the 0.2 s slept
+        assert queued_l == pytest.approx(t_seat - t_arr)
+        v = eng.perf_stats()["admit"]["vacancy"]
+        assert v["count"] == 2
+        assert (v["cooling_s"], v["no_request_s"], v["queued_s"]) == pytest.approx(
+            (cool_q + cool_l, none_q + none_l, queued_q + queued_l))
+        if depth == 1:
+            assert cool_q < 0.05  # at depth 1 the fetch that frees the slot ends its fence
 
 
 @pytest.mark.parametrize("depth,admit_batch,compact", [(1, 1, "off"), (2, 1, "off"), (2, 4, "on")])
@@ -1319,7 +1441,7 @@ def test_a_first_token_that_ends_the_reply_frees_its_slot(depth, admit_batch, co
         slot_of = {rid: slot for a in new for rid, slot in zip(a["requests"], a["slots"])}
         reused = [slot_of[r.request_id] for r in later]
         assert slot_of[eos_req.request_id] in reused and slot_of[one_req.request_id] in reused
-        assert eng.perf_stats()["admit_reads"] == len(seen["admits"])
+        assert eng.perf_stats()["admit"]["reads"] == len(seen["admits"])
 
 
 @pytest.mark.parametrize("depth,admit_batch", [(1, 1), (2, 1), (2, 4)])
@@ -1357,10 +1479,10 @@ def test_an_admission_that_raises_at_its_read_fails_its_own_and_what_followed(de
         assert eng._rid_fetched == eng._rid_dispatched
         # the victims' admissions were dispatched (one program or two) and none was read
         assert sum(len(a["requests"]) for a in seen["admits"]) == 3
-        assert eng.perf_stats()["admit_reads"] == 1 == len(rec.snapshot(etype="admit_read"))
+        assert eng.perf_stats()["admit"]["reads"] == 1 == len(rec.snapshot(etype="admit_read"))
         after = eng.generate("served after the failure", max_tokens=6, temperature=0.0)
         assert after["usage"]["completion_tokens"] == 6 and eng.total_errors == 3
-        assert eng.perf_stats()["admit_reads"] == 2
+        assert eng.perf_stats()["admit"]["reads"] == 2
 
 
 def test_a_constrained_admission_is_read_where_it_is_dispatched():
@@ -1376,8 +1498,8 @@ def test_a_constrained_admission_is_read_where_it_is_dispatched():
         got = {k: _events(r) for k, r in (("long", long_req), ("choice", choice), ("plain", plain))}
         assert "".join(e["text"] for e in got["choice"] if e["type"] == "token") in ("heads", "tails")
         assert got["long"] == _one_at_a_time()[0][0] and got["plain"][-1]["type"] == "done"
-        st = eng.perf_stats()
-        assert (st["admit_reads"], st["admit_reads_at_once"]) == (3, 1) and eng.total_errors == 0
+        st = eng.perf_stats()["admit"]
+        assert (st["reads"], st["reads_at_once"]) == (3, 1) and eng.total_errors == 0
         by_req = {a["requests"][0]: (a, r) for a, r in zip(seen["admits"], seen["reads"])}
         a, r = by_req[choice.request_id]
         assert r["adm"] is a["adm"] and r["at_once"] and r["fetched"] < a["dispatched"]  # rounds were unfetched
@@ -1402,7 +1524,7 @@ def test_shutdown_leaves_no_admission_unread():
         long_req = eng.submit(_request(eng, SCRIPT[0][1], 40))
         eng._thread.join(timeout=60)
         assert not eng._thread.is_alive() and not eng._inflight
-        assert len(seen["admits"]) == len(seen["reads"]) == eng.perf_stats()["admit_reads"] == 2
+        assert len(seen["admits"]) == len(seen["reads"]) == eng.perf_stats()["admit"]["reads"] == 2
         assert len(seen["tokens"][late.request_id]) >= 1  # read and processed, by the drain
         assert seen["log"][-1] == ("read", id(seen["admits"][-1]["adm"]))
         eng.shutdown()
@@ -1461,5 +1583,5 @@ def test_a_speculative_verify_round_leaves_no_admission_unread():
         assert all(g[-1]["type"] == "done" for g in got) and eng.total_errors == 0
         assert calls and eng.speculation_stats()["verify_calls"] == len(calls)
         assert all(n == 0 and all(stamped) for n, stamped in calls)
-        assert len(seen["reads"]) == len(seen["admits"]) == eng.perf_stats()["admit_reads"]
+        assert len(seen["reads"]) == len(seen["admits"]) == eng.perf_stats()["admit"]["reads"]
         assert any(a["dispatched"] > a["fetched"] for a in seen["admits"])  # one did wait in the queue

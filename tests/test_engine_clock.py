@@ -5,6 +5,7 @@ own timers and kept out of the scheduler's cost model, the embedding
 engine's counters. On the CPU at a tiny size: counts and clocks' ORDER, never
 a rate."""
 
+import concurrent.futures as cf
 import json
 import time
 
@@ -347,8 +348,8 @@ def test_admission_reads_are_counted_and_recorded_against_a_hand_count(env):
     `admit_read` events of one row each, the last read where it was
     dispatched; each `after_rid` is the newest round fetched before it."""
     gen, rec, _led, base = env
-    keys = ("admit_reads", "admit_reads_blocked", "admit_reads_at_once")
-    before = {k: gen.perf_stats()[k] for k in keys}
+    keys = ("reads", "reads_blocked", "reads_at_once")
+    before = {k: gen.perf_stats()["admit"][k] for k in keys}
     n0 = len(ring(rec, "admit_read"))
     t0 = time.monotonic()
     for i in range(3):
@@ -360,12 +361,12 @@ def test_admission_reads_are_counted_and_recorded_against_a_hand_count(env):
     reads = ring(rec, "admit_read")[n0:]
     assert len(reads) == 4
     for r in reads:
-        assert set(r) == {"rows", "after_rid", "wait_ms", "blocked", "t"}
+        assert set(r) == {"aid", "rows", "after_rid", "wait_ms", "blocked", "t"}
         assert r["rows"] == 1 and r["wait_ms"] >= 0 and t0 <= r["t"] <= t1 and r["blocked"] in (True, False)
-    after = {k: gen.perf_stats()[k] for k in keys}
-    assert after["admit_reads"] - before["admit_reads"] == 4
-    assert after["admit_reads_at_once"] - before["admit_reads_at_once"] == 1
-    assert after["admit_reads_blocked"] - before["admit_reads_blocked"] == sum(r["blocked"] for r in reads)
+    after = {k: gen.perf_stats()["admit"][k] for k in keys}
+    assert after["reads"] - before["reads"] == 4
+    assert after["reads_at_once"] - before["reads_at_once"] == 1
+    assert after["reads_blocked"] - before["reads_blocked"] == sum(r["blocked"] for r in reads)
     # the ring in the order it was written: a read's after_rid is the last fetch before it
     last_fetch, seen = 0, []
     for e in rec.snapshot():
@@ -375,7 +376,7 @@ def test_admission_reads_are_counted_and_recorded_against_a_hand_count(env):
             seen.append((e["fields"]["after_rid"], last_fetch))
     assert len(seen) >= 4 and all(a == b for a, b in seen[-4:])
     doc = httpx.get(f"{base}/v1/debug/perf").json()["tiny-llm"]
-    assert {k: doc[k] for k in keys} == after
+    assert {k: doc["admit"][k] for k in keys} == after
 
 
 def test_the_admission_blocks_only_under_engine_admit_sync(env, monkeypatch):
@@ -417,3 +418,57 @@ def test_the_admission_blocks_only_under_engine_admit_sync(env, monkeypatch):
     for fn in (GenerationEngine._start_batch, GenerationEngine._seat, GenerationEngine._admit_pending):
         src = inspect.getsource(fn)
         assert "np.asarray(toks0" not in src and "block_until_ready" not in src and ".sync" not in src
+
+
+@pytest.mark.parametrize("prompts", [1, 3])
+def test_an_admit_program_is_recorded_where_it_is_dispatched(env, monkeypatch, prompts):
+    """PR 37: one `admit_prog` ring event an admission dispatched, on the one
+    clock, and around the dispatch itself an annotation `engine.admit.dispatch`
+    that carries the event's `aid`, nested in the `engine.admit` phase: in a
+    profiler trace every run of `jit_admit_fn` has the dispatch that caused it."""
+    from llm_mcp_tpu.executor import engine as engine_mod
+
+    gen, rec, _led, _base = env
+    log, real = [], engine_mod.TraceAnnotation
+
+    class Noted:
+        def __init__(self, name, **kw):
+            self.name, self.kw, self.inner = name, kw, real(name, **kw)
+
+        def __enter__(self):
+            log.append(("in", self.name, self.kw))
+            return self.inner.__enter__()
+
+        def __exit__(self, *exc):
+            log.append(("out", self.name, self.kw))
+            return self.inner.__exit__(*exc)
+
+    n0, before = len(ring(rec, "admit_prog")), gen.perf_stats()["admit"]
+    monkeypatch.setattr(engine_mod, "TraceAnnotation", Noted)
+    t0 = time.monotonic()
+    texts = [f"prompt number {i} " * (i + 1) for i in range(prompts)]
+    with cf.ThreadPoolExecutor(prompts) as pool:
+        outs = list(pool.map(lambda p: gen.generate(p, max_tokens=5, temperature=0.0), texts))
+    t1 = time.monotonic()
+    monkeypatch.setattr(engine_mod, "TraceAnnotation", real)
+    assert all(o["usage"]["completion_tokens"] == 5 for o in outs)
+    progs = ring(rec, "admit_prog")[n0:]
+    after = gen.perf_stats()["admit"]
+    assert 1 <= len(progs) <= prompts and sum(f["rows"] for f in progs) == prompts
+    for f in progs:
+        assert set(f) == {"aid", "kind", "rows", "rows_padded", "bucket", "true_tokens", "padded_tokens",
+                          "queued", "held_by", "wait_ms_max", "after_rid", "t"}
+        assert t0 <= f["t"] <= t1 and 0 <= f["wait_ms_max"] <= 1e3 * (t1 - t0)
+        assert f["padded_tokens"] == f["rows_padded"] * f["bucket"]
+    assert sum(f["true_tokens"] for f in progs) == sum(len(gen.tokenizer.encode(p)) for p in texts)
+    assert after["true_tokens"] - before["true_tokens"] == sum(f["true_tokens"] for f in progs)
+    assert after["programs"] - before["programs"] == len(progs)
+    # the annotation: one a program, its aid the event's, inside engine.admit, no device read in it
+    spans = [(i, kw["aid"]) for i, (kind, name, kw) in enumerate(log)
+             if kind == "in" and name == "engine.admit.dispatch"]
+    assert [aid for _i, aid in spans] == [f["aid"] for f in progs]
+    for i, _aid in spans:
+        opened = [name for kind, name, _kw in log[:i] if kind == "in"]
+        closed = [name for kind, name, _kw in log[:i] if kind == "out"]
+        assert opened.count("engine.admit") - closed.count("engine.admit") == 1
+        assert log[i + 1][:2] == ("out", "engine.admit.dispatch")

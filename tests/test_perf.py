@@ -365,8 +365,9 @@ def test_stats_document_shape():
     st = PerfObservatory(SHAPE).stats()
     assert set(st) == {
         "sample_every", "itl", "itl_mean_ms", "goodput", "phases", "roofline",
-        "tenants", "event_gap", "stream_lag",
+        "tenants", "event_gap", "stream_lag", "samples_evicted",
     }
+    assert st["samples_evicted"] == {"event_gap": 0, "stream_lag": 0}
     assert st["event_gap"] == {"p50_ms": 0.0, "p95_ms": 0.0, "samples": 0.0}
     assert set(st["phases"]) == set(DISPATCH_PHASES)
     assert set(st["roofline"]["layouts"]) == set(CACHE_LAYOUTS)
@@ -582,6 +583,79 @@ def _chat(base, max_tokens=24):
     return r
 
 
+@pytest.mark.parametrize("kind", perf.SAMPLE_KINDS)
+def test_a_full_sample_window_counts_what_it_pushes_out(kind, monkeypatch):
+    """PR 37: a reader that cuts `samples(kind)` by its own window must know
+    when the window's start is gone: every sample pushed out is counted."""
+    monkeypatch.setattr(perf, "SAMPLE_WINDOW", 4)
+    obs = PerfObservatory()
+    for k in range(4):
+        obs.observe_sample(kind, 0.001 * (k + 1))
+    assert obs.samples_evicted[kind] == 0 and obs.stats()["samples_evicted"][kind] == 0
+    for k in range(3):
+        obs.observe_sample(kind, 1.0)
+    other = next(o for o in perf.SAMPLE_KINDS if o != kind)
+    assert obs.stats()["samples_evicted"] == {kind: 3, other: 0}
+    assert [v for _t, v in obs.samples(kind)] == [0.004, 1.0, 1.0, 1.0]  # the oldest three went
+    assert obs.stats()[kind]["samples"] == 4.0
+
+
+def test_the_shipped_window_holds_the_largest_cells_run():
+    # the largest cell's 40 s and drain put 56,000 gaps (PERF.md §6, PR 37): room for rounds twice as fast
+    assert perf.SAMPLE_WINDOW >= 2 * 56_000
+    assert PerfObservatory()._samples["event_gap"].maxlen == perf.SAMPLE_WINDOW
+
+
+def test_a_sample_keeps_what_its_writer_knew_and_the_old_readers_get_pairs():
+    obs = PerfObservatory()
+    obs.observe_sample("event_gap", 0.05, 1, 64)
+    obs.observe_sample("event_gap", 0.04, 0, 0)
+    obs.observe_sample("stream_lag", 0.002)
+    whole = obs.samples("event_gap", whole=True)
+    assert [s[1:] for s in whole] == [(0.05, 1, 64), (0.04, 0, 0)]
+    assert obs.samples("event_gap") == [s[:2] for s in whole]  # (t, seconds): what spans.window_samples unpacks
+    assert [len(s) for s in obs.samples("stream_lag", whole=True)] == [2]
+    assert obs.sample_percentiles("event_gap")["p95_ms"] == 50.0
+
+
+@pytest.mark.parametrize("times,parts", [
+    # (free, cool, arrived, seat) -> (cooling, no_request, queued)
+    ((10.0, 10.05, 9.0, 10.2), (0.05, 0.0, 0.15)),    # queued before the slot was freed
+    ((10.0, 10.05, 10.02, 10.2), (0.05, 0.0, 0.15)),  # arrived while the slot cooled
+    ((10.0, 10.05, 10.15, 10.2), (0.05, 0.10, 0.05)),  # arrived into a cool, empty slot
+    ((10.0, 10.0, 10.0, 10.0), (0.0, 0.0, 0.0)),
+    ((10.0, 10.5, 10.1, 10.2), (0.2, 0.0, 0.0)),      # seated before the fence's fetch was seen
+    ((10.0, 9.0, 10.1, 10.2), (0.0, 0.1, 0.1)),       # a cool stamp older than the free
+    ((10.0, 10.05, 11.0, 10.2), (0.05, 0.15, 0.0)),   # an arrival stamped after the seat
+])
+def test_a_vacancys_parts_sum_to_seat_less_free(times, parts):
+    adm = perf.AdmitAccount()
+    got = adm.vacancy(*times)
+    assert got == pytest.approx(parts) and sum(got) == pytest.approx(times[3] - times[0])
+    adm.vacancy(*times)
+    v = adm.stats()["vacancy"]
+    assert v["count"] == 2 and isinstance(v["count"], int)
+    assert (v["cooling_s"], v["no_request_s"], v["queued_s"]) == pytest.approx([2 * p for p in parts])
+
+
+def test_admit_account_sums_programs_by_shape_kind_and_reason():
+    adm = perf.AdmitAccount()
+    assert adm.program("batch", 1, 1, 64, 48, queued=2, held_by="admit_batch") == 1
+    assert adm.program("batch", 3, 4, 64, 150, queued=0, held_by="queue_empty") == 2
+    assert adm.program("chunk", 2, 2, 512, 900, queued=1) == 3
+    adm.read(blocked=True, at_once=False)
+    adm.read(blocked=False, at_once=True)
+    st = adm.stats()
+    assert {k: st[k] for k in perf.AdmitAccount.SUMS} == {
+        "programs": 3, "prompts": 6, "rows_padded": 7, "true_tokens": 1098,
+        "padded_tokens": 64 + 256 + 1024, "queued_sum": 3,
+        "reads": 2, "reads_blocked": 1, "reads_at_once": 1}
+    assert st["by_shape"] == {"1:64": 1, "4:64": 1, "chunk 2:512": 1}
+    assert st["held_by"] == {"admit_batch": 1, "queue_empty": 1}  # a batch alone has a reason
+    assert st["vacancy"] == {"count": 0, "cooling_s": 0.0, "no_request_s": 0.0, "queued_s": 0.0}
+    json.dumps(st)  # /v1/debug/perf and the dashboard serialise it as it is
+
+
 def test_debug_perf_endpoint_full_document(base):
     _chat(base)
     deadline = time.monotonic() + 15.0
@@ -609,6 +683,12 @@ def test_debug_perf_endpoint_full_document(base):
     assert doc["itl"]["samples"] > 0 and doc["itl"]["p50_ms"] >= 0
     assert doc["goodput"]["finished_requests"] >= 1
     assert doc["goodput"]["finished_tokens"] > 0
+    # admission's account, as perf_stats() gives it (PR 37)
+    assert set(doc["admit"]) == {*perf.AdmitAccount.SUMS, "by_shape", "held_by", "vacancy"}
+    assert doc["admit"]["programs"] >= 1 and doc["admit"]["reads"] == doc["admit"]["programs"]
+    assert set(doc["admit"]["vacancy"]) == set(perf.AdmitAccount.VACANCY)
+    assert doc["samples_evicted"] == {"event_gap": 0, "stream_lag": 0}
+    assert not any(k.startswith("admit_reads") for k in doc)  # in one place only
 
 
 def test_perf_events_land_in_flight_ring(base):
