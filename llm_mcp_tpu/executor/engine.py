@@ -68,6 +68,8 @@ from ..models.llama import (
     llama_prefill_chunk_batch,
     llama_prefill_chunk_ragged,
     llama_decode_step,
+    mixed_step_q8,
+    mixed_step_supported,
     quantize_kv,
 )
 from .. import constrain
@@ -370,9 +372,27 @@ class _DispatchedAdmit:
     t_call: float  # ... when the jit call returned
     first: bool  # first dispatch of its shape: the CompileLedger's, no sample
     aid: int = 0  # the ring's `admit_prog` event of this dispatch
+    rid: int = 0  # the mixed round that carried it (no program of its own, no aid)
     # (admissions dispatched up to and including this one, their padded
     # tokens): where its first tokens stand in the device's order (_put_text)
     mark: tuple = (0, 0)
+
+
+@dataclass
+class _Ride:
+    """A batch of whole prompts staged to ride the next full-batch decode
+    round's first step (`mixed_round_fn`): popped from the queue, their slots
+    reserved, the packed buffer built; nothing dispatched or seated yet."""
+
+    batch: list  # [(slot, GenRequest, ids)]
+    rung: int  # the packed buffer's length T
+    tokens: Any  # np [T] int32
+    rowids: Any  # np [T] int32 (pads = the descriptor rows R)
+    positions: Any  # np [T] int32 (pads = max_seq_len)
+    ipack: Any  # np [3 R + 2] int32 (mixed_round_fn)
+    fpack: Any  # np [2 R] float32
+    held_by: str = ""  # why the batch closed
+    adm: Any = None  # its _DispatchedAdmit, once the round is dispatched
 
 
 @dataclass
@@ -759,9 +779,8 @@ class GenerationEngine:
                 allowed[bad] = False
         self._allowed_mask = jnp.asarray(allowed) if not allowed.all() else None
 
-        self._decode_fn, self._fused_fn, self._fused_ragged_fn = (
-            self._build_decode()
-        )
+        (self._decode_fn, self._fused_fn, self._fused_ragged_fn,
+         self._mixed_fn) = self._build_decode()
         mask = self._allowed_mask
         cfg_ = self.cfg
         skey_base = self._base_key
@@ -1294,6 +1313,14 @@ class GenerationEngine:
         # fetch that ended its cooling fence (None until then)]
         self._adm = perf.AdmitAccount()
         self._vacant: dict[int, list] = {}
+        # admissions riding a decode round (_stage_ride, mixed_round_fn): the
+        # rungs this cache holds, the descriptor rows of the packed buffer,
+        # why the configuration keeps admit_fn (_ride_off; None = not asked
+        # yet), and what stood against a ride in the loop's current iteration
+        self._ride_rungs = tuple(r for r in self.RIDE_RUNGS if r <= max_seq_len)
+        self._ride_rows = 1 << max(0, self.admit_batch - 1).bit_length()
+        self._ride_why: str | None = None
+        self._ride_state = "other"
 
         # Self-speculative decoding (draft-and-verify): a host-side n-gram
         # drafter (drafter.py — prompt-lookup over each slot's own history)
@@ -1909,6 +1936,14 @@ class GenerationEngine:
                     compact=compact, paged=paged,
                 )
                 return out
+            if kind == "mixed":
+                (out, toks0, self._ck, self._cv, self._d_temp, self._d_topk,
+                 self._d_topp, self._d_last_tok) = self._mixed_fn(
+                    self.params, self._ck, self._cv, packed, self._d_temp,
+                    self._d_topk, self._d_topp, self._d_last_tok, *p_args,
+                    paged=paged,
+                )
+                return out, toks0
             fn = self._fused_fn if kind == "fused" else self._fused_ragged_fn
             out, logits, self._ck, self._cv, self._d_last_tok = fn(
                 self.params, self._ck, self._cv, packed, self._d_temp,
@@ -2074,6 +2109,35 @@ class GenerationEngine:
         impl = self.decode_impl
         base_key = self._base_key
 
+        def sample_step(logits, ck, lens, rng, temp, topk, topp):
+            """A decode step's sample: (tokens [Ba], the key carried on)."""
+            with jax.named_scope("sample"):
+                if mask is not None:
+                    logits = jnp.where(mask, logits, -jnp.inf)
+                rng, sub = jax.random.split(rng)
+                # parked rows (lens >= S) carry stale params from a prior
+                # occupant — exclude them from fast-path selection
+                S_cache = (ck["q"] if isinstance(ck, dict) else ck).shape[3]
+                new = sample_tokens(
+                    logits, sub, temp, topk, topp, active=lens < S_cache
+                )
+            return new, rng
+
+        def plain_step(params, temp, topk, topp, slot_ids=None, paged=None):
+            """The scan body of one plain decode step over the carry (ck, cv,
+            tokens, lengths, rng): decode_body's K steps, and the K - 1 that
+            follow mixed_round_fn's first."""
+            def step(carry, _):
+                ck, cv, toks, lens, rng = carry
+                logits, ck, cv = llama_decode_step(
+                    cfg, params, ck, cv, toks, lens, attn_impl=impl,
+                    slot_ids=slot_ids, paged=paged,
+                )
+                new, rng = sample_step(logits, ck, lens, rng, temp, topk, topp)
+                return (ck, cv, new, lens + 1, rng), new
+
+            return step
+
         def decode_body(params, ck, cv, packed, d_temp, d_topk, d_topp,
                         d_last, compact, paged=None):
             """One decode round (K fused steps) — traced body shared by
@@ -2107,26 +2171,9 @@ class GenerationEngine:
                 temp, topk, topp = d_temp, d_topk, d_topp
             rng = jax.random.fold_in(base_key, packed[-1])
 
-            def step(carry, _):
-                ck, cv, toks, lens, rng = carry
-                logits, ck, cv = llama_decode_step(
-                    cfg, params, ck, cv, toks, lens, attn_impl=impl,
-                    slot_ids=slot_ids, paged=paged,
-                )
-                with jax.named_scope("sample"):
-                    if mask is not None:
-                        logits = jnp.where(mask, logits, -jnp.inf)
-                    rng, sub = jax.random.split(rng)
-                    # parked rows (lens >= S) carry stale params from a prior
-                    # occupant — exclude them from fast-path selection
-                    S_cache = (ck["q"] if isinstance(ck, dict) else ck).shape[3]
-                    new = sample_tokens(
-                        logits, sub, temp, topk, topp, active=lens < S_cache
-                    )
-                return (ck, cv, new, lens + 1, rng), new
-
             (ck, cv, last, _, _), out = jax.lax.scan(
-                step, (ck, cv, tokens, lengths, rng), None, length=K
+                plain_step(params, temp, topk, topp, slot_ids, paged),
+                (ck, cv, tokens, lengths, rng), None, length=K
             )
             # write the round's final tokens back into the ring. Compact pad
             # rows all target the same inactive row (duplicate-index set:
@@ -2206,7 +2253,61 @@ class GenerationEngine:
             )
             return out, p_logits, ck, cv, d_last
 
-        return decode_chunk_fn, fused_step_fn, fused_ragged_fn
+        @partial(jax.jit, donate_argnums=(1, 2, 4, 5, 6, 7),
+                 **self._shard_out(["repl", "repl", "k", "v", "repl", "repl",
+                                   "repl", "repl"]))
+        def mixed_round_fn(params, ck, cv, packed, d_temp, d_topk, d_topp,
+                           d_last, p_tokens, p_rowids, p_positions, ipack,
+                           fpack, paged=None):
+            """A full-batch decode round whose FIRST step carries admitted
+            prompts through its pass over the weights (`mixed_step_q8`); the
+            other K - 1 steps are `decode_chunk_fn`'s. What `admit_fn` followed
+            by a plain round gives, for one weight pass less: the prompts'
+            rows land in their slots, their sampling parameters and first
+            tokens are written where `admit_fn` writes them (the slots are
+            parked rows of this round and decode from the next one on).
+
+            `packed` is the plain round's [lengths | counter]. p_tokens,
+            p_rowids, p_positions [T]: whole prompts packed back to back (pads:
+            rowid R, position S). ipack i32 [3 R + 2]: slots, each prompt's last
+            packed index, top_k, the live prompt count, the admission's rng
+            counter; fpack f32 [2 R]: temperature, top_p."""
+            R = fpack.shape[0] // 2
+            slots, last_idx, topks = ipack[:R], ipack[R : 2 * R], ipack[2 * R : 3 * R]
+            live = jnp.arange(R) < ipack[3 * R]
+            temps, topps = fpack[:R], fpack[R:]
+            Ba = packed.shape[0] - 1
+            lengths = packed[:Ba]
+            rng = jax.random.fold_in(base_key, packed[-1])
+            logits, ck, cv = mixed_step_q8(
+                cfg, params, ck, cv, d_last, lengths, p_tokens, p_rowids,
+                p_positions, slots, last_idx, paged=paged,
+            )
+            new, rng = sample_step(logits[:Ba], ck, lengths, rng, d_temp, d_topk, d_topp)
+            with jax.named_scope("sample"):  # the prompts' first tokens, as admit_fn samples them
+                p_logits = logits[Ba:]
+                if mask is not None:
+                    p_logits = jnp.where(mask, p_logits, -jnp.inf)
+                toks0 = sample_tokens(
+                    p_logits, jax.random.fold_in(base_key, ipack[3 * R + 1]),
+                    temps, topks, topps, active=live,
+                )
+
+            out = new[None]
+            if K > 1:
+                (ck, cv, new, _, _), rest = jax.lax.scan(
+                    plain_step(params, d_temp, d_topk, d_topp, paged=paged),
+                    (ck, cv, new, lengths + 1, rng), None, length=K - 1)
+                out = jnp.concatenate([out, rest])
+            # a pad prompt scatters to row B: out of bounds, dropped
+            row = jnp.where(live, slots, Ba)
+            d_temp = d_temp.at[row].set(temps)
+            d_topk = d_topk.at[row].set(topks)
+            d_topp = d_topp.at[row].set(topps)
+            d_last = new.at[row].set(toks0)
+            return out, toks0, ck, cv, d_temp, d_topk, d_topp, d_last
+
+        return decode_chunk_fn, fused_step_fn, fused_ragged_fn, mixed_round_fn
 
     def _build_verify(self):
         """Jitted speculative verify: ONE model call over [token, draft_1..
@@ -2531,6 +2632,9 @@ class GenerationEngine:
                 zoo.append(("decode", (ba, True, phys)))
                 ba <<= 1
         zoo.append(("decode", (B, False, phys)))
+        if not self._ride_off():
+            # the full batch only, a rung an executable (_stage_ride)
+            zoo.extend(("mixed", (t, phys)) for t in self._ride_rungs)
         if self.ragged_prefill and self._ragged_cap:
             skey0 = 0 if self._ragged_impl == "kernel" else min(128, S)
             t = min(32, self._ragged_cap)
@@ -2582,6 +2686,8 @@ class GenerationEngine:
                         and self._bucket(bucket) == bucket)
             if phase == "decode":
                 return 1 <= int(key[0]) <= self.max_slots
+            if phase == "mixed":
+                return not self._ride_off() and int(key[0]) in self._ride_rungs
             if phase == "chunk":
                 rows, bucket, skey = int(key[0]), int(key[1]), int(key[2])
                 return (self.prefill_chunk > 0 and 1 <= rows <= ab_cap
@@ -2605,7 +2711,7 @@ class GenerationEngine:
         Returns the compile wall, or None for phases whose argument shapes
         cannot be synthesized from the key alone (fused/verify/restore —
         they compile on first real dispatch, exactly as before warmup)."""
-        if phase not in ("admit", "chunk", "decode", "pf_rag"):
+        if phase not in perf.WARMUP_PHASES:
             return None
         t0 = time.perf_counter()
         compile_watch.begin()  # the zoo's thread; closed by _compile_obs
@@ -2632,8 +2738,13 @@ class GenerationEngine:
         of a ledger key, or None when the key does not fit this engine.
 
         The operands are ShapeDtypeStruct mirrors of the live params/cache/
-        sampling arrays carrying their committed shardings, so the lowered
-        module (and its cache key) matches what the serve path will build.
+        sampling arrays, so the lowered module (and its cache key) matches
+        what the serve path will build: under a mesh they carry the arrays'
+        NamedShardings; off one they carry NONE, as the live call's module
+        does (an explicit single-device sharding is written into the module's
+        arguments, which makes it another module under another cache key: the
+        plan's compile then serves no first dispatch, and on a cold cache
+        every shape compiles twice).
         scripts/rehearse_tpu_compile.py re-places the same operands on
         described chips, which is how the whole step programs meet the TPU
         compiler on a machine without one."""
@@ -2652,7 +2763,7 @@ class GenerationEngine:
             # every AOT warmup compile of a sharded engine failed on a real
             # four-chip host.
             sh = getattr(x, "sharding", None)
-            if self.mesh is not None and not isinstance(sh, NamedSharding):
+            if self.mesh is None or not isinstance(sh, NamedSharding):
                 return None
             return sh
 
@@ -2689,6 +2800,15 @@ class GenerationEngine:
             return self._decode_fn, (
                 P, CK, CV, packed(ba, compact), *sampling,
             ), dict(compact=compact, paged=paged)
+        if phase == "mixed":
+            t, r = int(key[0]), self._ride_rows
+            if bool(key[1]) != phys:
+                return None
+            return self._mixed_fn, (
+                P, CK, CV, packed(self.max_slots, False), *sampling,
+                host((t,)), host((t,)), host((t,)), host((3 * r + 2,)),
+                host((2 * r,), jnp.float32),
+            ), dict(paged=paged)
         if phase == "chunk":
             rws, bucket, skey = int(key[0]), int(key[1]), int(key[2])
             if bool(key[3]) != phys:
@@ -4464,15 +4584,27 @@ class GenerationEngine:
             # cadence never stalls behind a prefill backlog, and the group's
             # device time is capped at ~one decode round by construction.
             group = timed("prefill", self._stage_prefill_group, len(active))
+            # Whole prompts ride a full-batch round's first step (a weight
+            # pass shared with the decode rows, mixed_round_fn) where the
+            # configuration allows: staged HERE, before the dispatch, so the
+            # round of this iteration carries them; what may not ride, and
+            # every admission while nothing decodes or the round is compact
+            # or carries a chunk group, takes admit_fn below as ever.
+            riding = self._round_carries(len(active), group)
+            ride = timed("admit", self._stage_ride) if riding else None
             if active:
                 try:
                     # tokens come from the device ring, lengths advance
                     # optimistically — this dispatch does NOT wait for any
                     # earlier round's fetch (decode_chunk_fn docstring)
-                    inflight.append(
-                        timed("dispatch", self._dispatch_decode, active, group,
-                              rid=self._rid_dispatched + 1)
-                    )
+                    disp = timed("dispatch", self._dispatch_decode, active, group,
+                                 *(() if ride is None else (ride,)),
+                                 rid=self._rid_dispatched + 1)
+                    if ride is not None:
+                        # its first tokens are the round's own output: read
+                        # before the round, so they go out a fetch earlier
+                        inflight.append(ride.adm)
+                    inflight.append(disp)
                 except Exception as e:  # a poisoned dispatch must not kill the loop
                     # deliver already-fetched tokens BEFORE the error events
                     # — _fail_round marks these same slot objects aborted,
@@ -4482,6 +4614,8 @@ class GenerationEngine:
                     if group is not None:
                         self._fail_prefill_group(group, e)
                         group = None
+                    if ride is not None:
+                        self._fail_ride(ride, e)
                     drain_failed(e, also=active)
                 else:
                     if group is not None:
@@ -4501,7 +4635,7 @@ class GenerationEngine:
             while inflight and isinstance(inflight[0], _DispatchedAdmit):
                 if not retire_oldest():
                     break
-            admitted = timed("admit", self._admit_pending)
+            admitted = timed("admit", self._admit_pending, riding) or ride is not None
             # block on the OLDEST round only once the pipeline is full (or
             # the batch went idle): up to pipeline_depth rounds chain on
             # device without a host sync, so the fetch and the host's work
@@ -4610,7 +4744,48 @@ class GenerationEngine:
         self.cn_mask_s += time.perf_counter() - t0
         return masks, bids, bvals
 
-    def _admit_pending(self) -> bool:
+    def _pop_request(self) -> tuple[GenRequest, list[int]] | None:
+        """The queue's next request that wants a slot, with its prompt cut to
+        what the cache holds; None when the queue is empty. A request that
+        asks for no tokens is answered here."""
+        while True:
+            try:
+                req = self._admit.get_nowait()
+            except queue.Empty:
+                return None
+            req.admitted_at = time.time()
+            ids = req.prompt_ids
+            # Leave room for at least one decode chunk after the prompt.
+            max_prompt = self.max_seq_len - self.decode_chunk
+            if len(ids) > max_prompt:  # keep the tail (left-truncation)
+                ids = ids[-max_prompt:]
+            if req.max_tokens > 0:
+                return req, list(ids)
+            req.out.put(
+                {
+                    "type": "done",
+                    "finish_reason": "length",
+                    "usage": {
+                        "prompt_tokens": len(ids),
+                        "completion_tokens": 0,
+                        "total_tokens": len(ids),
+                    },
+                    "ttft_ms": 0.0,
+                }
+            )
+            req.out.put(_DONE)
+
+    def _push_back(self, req: GenRequest) -> None:
+        """A popped request leads the queue again (its order is kept)."""
+        with self._admit.mutex:
+            self._admit.queue.appendleft(req)
+
+    def _admit_pending(self, riding: bool = False) -> bool:
+        """Admit what the queue holds, each batch of whole prompts as an admit
+        program of its own. `riding`: rows are decoding at a full batch and
+        this configuration's admissions ride the decode rounds (_stage_ride),
+        so a request that may ride stays queued for the next round's staging
+        and only what may not is admitted here."""
         admitted = False
         if self._migrate_in is not None and not self._migrate_in.empty():
             # migrated-in snapshots re-enter first: their prefill was spent
@@ -4642,38 +4817,20 @@ class GenerationEngine:
                         self._state_pool.note_off("offload")
                     held_by = "no_slot"
                     break
-                try:
-                    req = self._admit.get_nowait()
-                except queue.Empty:
+                nxt = self._pop_request()
+                if nxt is None:
                     held_by = "queue_empty"
                     break
-                req.admitted_at = time.time()
-                ids = req.prompt_ids
-                # Leave room for at least one decode chunk after the prompt.
-                max_prompt = self.max_seq_len - self.decode_chunk
-                if len(ids) > max_prompt:  # keep the tail (left-truncation)
-                    ids = ids[-max_prompt:]
-                if req.max_tokens <= 0:
-                    req.out.put(
-                        {
-                            "type": "done",
-                            "finish_reason": "length",
-                            "usage": {
-                                "prompt_tokens": len(ids),
-                                "completion_tokens": 0,
-                                "total_tokens": len(ids),
-                            },
-                            "ttft_ms": 0.0,
-                        }
-                    )
-                    req.out.put(_DONE)
-                    continue
+                req, ids = nxt
+                if riding and self._may_ride(req, ids):
+                    self._push_back(req)  # the next round carries it
+                    held_by = "rides"
+                    break
                 if batch and self._over_admit_budget(batch, ids):
                     # with this prompt the program would pad to more tokens
                     # than may stand between two decode rounds: it leads the
                     # next program instead (the queue's order is kept)
-                    with self._admit.mutex:
-                        self._admit.queue.appendleft(req)
+                    self._push_back(req)
                     held_by = "budget"
                     break
                 admitted = True
@@ -4755,8 +4912,159 @@ class GenerationEngine:
                 if self._recover_cache():
                     self._abort_all("kv cache lost in failed prefill")
             if len(batch) < self.admit_batch:
-                break  # admit queue drained
+                break  # admit queue drained (or its head rides the next round)
         return admitted
+
+    # The packed-token sizes of a mixed round's prompt buffer, one executable
+    # each: a batch takes the smallest that holds its prompts (_stage_ride: a
+    # rung's first dispatch apart), and the largest is the most prompt tokens
+    # one round carries.
+    RIDE_RUNGS = (128, 256)
+
+    def _ride_off(self) -> str:
+        """Why this configuration's admissions never ride a decode round
+        (`mixed_round_fn`), "" where they may: `recurrent` (a state pool: a
+        prompt there is a chunked recurrence, not rows of a matmul;
+        `memory.RECURRENT_OFF`), `other` (the decode step is not
+        `_decode_step_q8` on one chip: a mesh, a bf16 or latent cache, the XLA
+        path, routed experts, sliding windows)."""
+        if self._ride_why is None:
+            if not self._runs("mixed_round"):
+                self._ride_why = "recurrent"
+            elif (self.mesh is not None or self._spmd or self.sp != 1
+                  or self.kv_quant != "int8" or self.decode_impl != "pallas"
+                  or not mixed_step_supported(self.cfg) or not self._ride_rungs):
+                self._ride_why = "other"
+            else:
+                self._ride_why = ""
+        return self._ride_why
+
+    def _round_carries(self, nact: int, group: _PrefillGroup | None) -> bool:
+        """Whether the round about to be dispatched for `nact` rows may carry
+        the queue's next whole prompts; notes what stands against it for the
+        admit programs this iteration takes (_own_reason). A full batch
+        (_dispatch_decode's compaction rule) with no chunk group in it and no
+        snapshot waiting to re-enter ahead of the queue: _admit_pending knows
+        their order, and a preempted one yields to the queue's head."""
+        B = self.max_slots
+        if not nact:
+            self._ride_state = "no active rows"
+        elif self.decode_compact and pow2_bucket(nact, B, floor=min(8, B)) != B:
+            self._ride_state = "compact"
+        else:
+            self._ride_state = "other"
+            return (
+                group is None and not self._ride_off()
+                and (self._migrate_in is None or self._migrate_in.empty())
+                and not (self._pool is not None and self._pool.has_preempted())
+            )
+        return False
+
+    def _may_ride(self, req: GenRequest, ids: list[int]) -> bool:
+        """Whether this request's whole prompt may ride a decode round: its
+        first token's value is not needed at once (no constraint, no hand-over
+        after the prefill), it fits the largest rung whole, and no cached
+        prefix serves it."""
+        if self._constrain is not None and (req.constraint or req.logit_bias):
+            return False
+        if self._exports_after_prefill(req) or len(ids) > self._ride_rungs[-1]:
+            return False
+        if self.prefill_chunk and len(ids) > self.prefill_chunk:
+            return False
+        return self._match_prefix(ids, count=False) is None
+
+    def _stage_ride(self) -> _Ride | None:
+        """Stage the queue's next whole prompts to ride the full-batch round
+        about to be dispatched: up to `admit_batch` of them, as many as fit
+        the largest rung (the rest lead the next round's batch; the queue's
+        order is kept). None when nothing may ride now."""
+        cap = self._ride_rungs[-1]
+        batch: list[tuple[int, GenRequest, list[int]]] = []
+        reserved: set[int] = set()
+        total = 0
+        held_by = "admit_batch"
+        while len(batch) < self.admit_batch:
+            slot = self._free_slot(reserved)
+            if slot is None:
+                held_by = "no_slot"
+                break
+            nxt = self._pop_request()
+            if nxt is None:
+                held_by = "queue_empty"
+                break
+            req, ids = nxt
+            if not self._may_ride(req, ids):
+                self._push_back(req)  # an admit program of its own (_admit_pending)
+                held_by = "own"
+                break
+            if total + len(ids) > cap:
+                self._push_back(req)
+                held_by = "budget"
+                break
+            self.prefix_cache_misses += bool(self._prefix_budget and self._prefix_cache)
+            total += len(ids)
+            reserved.add(slot)
+            batch.append((slot, req, ids))
+        if not batch:
+            return None
+        # the smallest rung that holds the batch; but a rung never dispatched
+        # yet takes the first batch it holds, the largest first, so the first
+        # two rides first-dispatch both executables (seconds each) right away
+        # and not whenever traffic first packs more than the small rung holds
+        fits = [r for r in self._ride_rungs if r >= total]
+        phys = self._phys is not None
+        cold = [r for r in fits if ("mixed", r, phys) not in self._seen_exec_shapes]
+        T = cold[-1] if cold else fits[0]
+        R = self._ride_rows
+        tokens = np.zeros((T,), dtype=np.int32)
+        rowids = np.full((T,), R, dtype=np.int32)
+        positions = np.full((T,), self.max_seq_len, dtype=np.int32)
+        ipack = np.zeros((3 * R + 2,), dtype=np.int32)
+        fpack = np.zeros((2 * R,), dtype=np.float32)
+        fpack[R:] = 1.0  # top_p
+        at = 0
+        for i, (slot, req, ids) in enumerate(batch):
+            n = len(ids)
+            tokens[at : at + n] = ids
+            rowids[at : at + n] = i
+            positions[at : at + n] = np.arange(n)
+            at += n
+            ipack[i] = slot
+            ipack[R + i] = at - 1
+            ipack[2 * R + i] = req.top_k
+            fpack[i] = req.temperature
+            fpack[R + i] = req.top_p
+        # an unused descriptor row is empty (no token carries its id) and
+        # writes nothing; it names the first prompt's slot to stay in bounds
+        ipack[len(batch) : R] = batch[0][0]
+        ipack[3 * R] = len(batch)
+        return _Ride(batch=batch, rung=T, tokens=tokens, rowids=rowids,
+                     positions=positions, ipack=ipack, fpack=fpack,
+                     held_by=held_by)
+
+    def _fail_ride(self, ride: _Ride, e: Exception) -> None:
+        """The round that carried a staged batch failed at its dispatch: its
+        requests are answered, and a slot seated before the failure is freed."""
+        for slot, req, _ in ride.batch:
+            s = self._slots[slot]
+            if s is not None and s.req is req:
+                self._free_now(slot)
+            self._count_error()
+            req.out.put({"type": "error", "error": str(e)})
+            req.out.put(_DONE)
+
+    def _own_reason(self, batch: list) -> str:
+        """Why a batch of whole prompts takes an admit program of its own and
+        does not ride a decode round (perf_stats()["admit"]["own"])."""
+        off = self._ride_off()
+        if off:
+            return off
+        if any(req.cn is not None or self._exports_after_prefill(req)
+               for _, req, _ in batch):
+            return "reads at once"
+        if any(len(ids) > self._ride_rungs[-1] for _, _, ids in batch):
+            return "over the cap"
+        return self._ride_state
 
     def _admit_tokens_max(self) -> int:
         """The most tokens (rows x bucket, padding included) one admit program
@@ -4793,10 +5101,11 @@ class GenerationEngine:
             i += 1
         return i
 
-    def _match_prefix(self, ids: list[int]) -> dict | None:
+    def _match_prefix(self, ids: list[int], count: bool = True) -> dict | None:
         """Longest cached entry that is a STRICT prefix of `ids` (at least
         one suffix token must remain — the suffix chunk produces the
-        first-sample logits)."""
+        first-sample logits). `count` False only asks: the hit and miss
+        counters and the LRU order stay as they are (_may_ride)."""
         if self._state_pool is not None:
             self._state_pool.note_off("prefix_cache")
         if not self._prefix_budget or not self._prefix_cache:
@@ -4814,6 +5123,8 @@ class GenerationEngine:
             if e is not None:
                 best_key, best = t[:P], e
                 break
+        if not count:
+            return best
         if best is not None:
             self._prefix_cache.move_to_end(best_key)  # LRU touch
             self.prefix_cache_hits += 1
@@ -5388,6 +5699,9 @@ class GenerationEngine:
             "batch", [req for _, req, _ in batch], Ab, bucket,
             sum(len(ids) for _, _, ids in batch), held_by,
         )
+        self._adm.own(self._own_reason(batch), A)
+        if self._state_pool is not None:
+            self._state_pool.note_off("mixed_round")
         if first:
             # jit traces and compiles inside the call: the wall up to its
             # return is the compile's, and the ledger's context closes here,
@@ -5420,14 +5734,16 @@ class GenerationEngine:
             toks0 = np.asarray(adm.toks0)  # the admission's only host sync
         now = time.perf_counter()
         self._adm.read(blocked, at_once)
+        # a ride names its round and carries no `aid`: the readers place the
+        # runs of the admit PROGRAM by the aids of blocked reads
         self._flight.event(
-            "admit_read", aid=adm.aid, rows=len(adm.entries),
-            after_rid=self._rid_fetched,
+            "admit_read", **({"rid": adm.rid} if adm.rid else {"aid": adm.aid}),
+            rows=len(adm.entries), after_rid=self._rid_fetched,
             wait_ms=round((now - t_wait) * 1e3, 3), blocked=blocked,
             t=time.monotonic(),
         )
         tot_tok = sum(P for _, _, P in adm.entries)
-        if not adm.first:
+        if not adm.first and not adm.rid:
             self._sample_prefill_phase(
                 "admit", adm.t0, adm.t_call, tot_tok, len(adm.entries)
             )
@@ -6277,7 +6593,8 @@ class GenerationEngine:
             self._window.append((time.time(), self.total_tokens - before))
 
     def _dispatch_decode(
-        self, active: list[int], group: _PrefillGroup | None = None
+        self, active: list[int], group: _PrefillGroup | None = None,
+        ride: _Ride | None = None,
     ) -> _DispatchedRound:
         """Phase 1: stage host inputs and dispatch one decode round (NO
         fetch — the returned round is in flight on device). Input tokens
@@ -6291,7 +6608,13 @@ class GenerationEngine:
         fused_step_fn: the same dispatch also writes the group's prompt
         tokens (budget-bounded, slot-disjoint from the active rows) and
         parks its boundary logits un-fetched on the dispatch plane
-        (_x_logits[group.gid]) for the activation sample."""
+        (_x_logits[group.gid]) for the activation sample.
+
+        With a staged `ride` (a full batch and no group: _run) the round goes
+        through mixed_round_fn: its first step carries the batch's whole
+        prompts through its pass over the weights, and the batch is seated
+        here, after the dispatch, as _start_batch seats one (`ride.adm` is its
+        record for the in-flight queue, read by _read_admit)."""
         # chaos site: a failed round must fail active slots with error
         # events, not hang callers (the poisoned-round guard in _run)
         maybe_fail("engine.decode", f"active={len(active)}")
@@ -6402,6 +6725,34 @@ class GenerationEngine:
                          group.skey, self._phys is not None),
                         time.perf_counter() - t0c,
                     )
+        elif ride is not None:
+            ride.ipack[-1] = self._next_counter()
+            first = self._note_exec_shape("mixed", ride.rung,
+                                          self._phys is not None)
+            t0c = time.perf_counter()
+            out, toks0 = self._dx(
+                "decode", "mixed", 0, packed,
+                (ride.tokens, ride.rowids, ride.positions, ride.ipack,
+                 ride.fpack),
+                False, 0, self._paged_payload(),
+            )
+            t_call = time.perf_counter()
+            if first:
+                self._compile_obs(
+                    "mixed", (ride.rung, self._phys is not None),
+                    t_call - t0c,
+                )
+            true_tokens = sum(len(ids) for _, _, ids in ride.batch)
+            self._adm.ride(len(ride.batch), true_tokens, ride.rung)
+            ride.adm = _DispatchedAdmit(
+                toks0=toks0,
+                entries=[
+                    (slot, self._seat(slot, req, ids, batched=True), len(ids))
+                    for slot, req, ids in ride.batch
+                ],
+                t0=t0c, t_call=t_call, first=first,
+                rid=self._rid_dispatched + 1, mark=self._adm.mark(),
+            )
         else:
             first = self._note_exec_shape("decode", Ba, compact,
                                           self._phys is not None)
@@ -6435,16 +6786,28 @@ class GenerationEngine:
             )
         else:
             padded = 0
+        # a mixed round is the perf observatory's `fused`: a decode round
+        # with prompt tokens in the same dispatch, its decode rows its tokens
         phase_name = (
             ("fused_rag" if group.ragged else "fused")
-            if group is not None else "decode"
+            if group is not None else "fused" if ride is not None else "decode"
         )
-        self._flight.event(
-            phase_name,
-            rid=self._rid_dispatched, rows=len(active),
-            prefill_tokens=group.n_tokens if group is not None else 0,
-            prefill_padded=padded, t=time.monotonic(),
-        )
+        if ride is not None:
+            # a ring event of its own kind: `admit_prog` stays "a program of
+            # its own stood between two rounds"
+            self._flight.event(
+                "mixed", rid=self._rid_dispatched, rows=len(active),
+                prompts=len(ride.batch), prompt_tokens=true_tokens,
+                padded_tokens=ride.rung, queued=self._admit.qsize(),
+                held_by=ride.held_by, t=time.monotonic(),
+            )
+        else:
+            self._flight.event(
+                phase_name,
+                rid=self._rid_dispatched, rows=len(active),
+                prefill_tokens=group.n_tokens if group is not None else 0,
+                prefill_padded=padded, t=time.monotonic(),
+            )
         # Sampled steady-state attribution (every Nth dispatch of this
         # phase; first dispatches belong to the CompileLedger): host = the
         # staging+dispatch wall up to the async jit return, wait = the

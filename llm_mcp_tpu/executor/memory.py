@@ -332,6 +332,8 @@ RECURRENT_OFF = {
     "speculation": "rejected drafts roll the KV cache back by arithmetic; a state cannot be",
     "ragged_prefill": "a packed chunk holds several prompts' tokens in one row of the scan; "
                       "the chunked recurrence carries one state a row",
+    "mixed_round": "a prompt riding a decode step's weight pass is rows of a matmul; "
+                   "here it is a chunked recurrence (counts the admit programs taken)",
 }
 
 

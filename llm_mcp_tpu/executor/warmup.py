@@ -63,7 +63,14 @@ READINESS_STATES = ("cold", "first_token_ready", "fully_warm")
 # Phases an AOT compile can be synthesized for from the shape key alone
 # (mirrors telemetry/perf.py WARMUP_PHASES — duplicated as a literal so
 # this module stays importable standalone; tests pin the two in sync).
-PLANNABLE_PHASES = ("admit", "chunk", "decode", "pf_rag")
+PLANNABLE_PHASES = ("admit", "chunk", "decode", "mixed", "pf_rag")
+
+# Phases no first request dispatches. A mixed round needs a full batch already
+# decoding, and the serve path first-dispatches both of its rungs with its
+# first two rides (engine._stage_ride); the plan's step then records as `skip`.
+# Unmeasured, they rank after every other shape: the plan's thread reaches
+# them once traffic has had its chance, and compiles them only if it had none.
+LATE_PHASES = ("mixed",)
 
 
 def warmup_enabled() -> bool:
@@ -166,8 +173,9 @@ def _score(phase: str, key: tuple, priors: dict[tuple, dict]) -> float:
             continue
         if isinstance(part, (int, float)) and part > 0:
             size *= float(part)
-    # unmeasured: rank below every measured shape, smallest-first within
-    return 1.0 / (1.0 + size) * 1e-6
+    # unmeasured: rank below every measured shape, smallest-first within,
+    # the late phases after all of those
+    return 1.0 / (1.0 + size) * (1e-12 if phase in LATE_PHASES else 1e-6)
 
 
 def select_critical(

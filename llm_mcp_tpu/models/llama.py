@@ -649,6 +649,158 @@ def _decode_step_q8(
     return _logits(cfg, params, h), new_k, new_v
 
 
+def mixed_step_supported(cfg: ModelConfig) -> bool:
+    """Whether `mixed_step_q8` serves this family: the ones whose decode step
+    is `_decode_step_q8` with a dense feed-forward (global attention, no score
+    softcap, rope, no latent cache, no recurrent layers, no routed experts)."""
+    return not (
+        cfg.kv_lora_rank or cfg.gqa_layers or cfg.recurrent or cfg.n_experts
+        or cfg.sliding_window or cfg.attn_softcap or cfg.attn_gate
+        or not cfg.use_rope
+    )
+
+
+def packed_prompt_attn(
+    cfg: ModelConfig,
+    q: jnp.ndarray,  # [T, H, hd] roped
+    k: jnp.ndarray,  # [T, Hkv, hd] roped
+    v: jnp.ndarray,  # [T, Hkv, hd]
+    rowids: jnp.ndarray,  # [T] int32, sorted; pads carry the row count
+) -> jnp.ndarray:
+    """Causal self-attention of several whole prompts packed back to back:
+    `prefill_attn`'s product under a segment-and-causal mask, in the
+    precision `flash_prefill_attention` computes in (float32 scores, softmax
+    and weighted sum; the context rounded to the activations' type once), so
+    a riding prompt's K/V and first token are admit_fn's to float32 rounding.
+    A token sees the tokens of its own prompt at or before it (packed order
+    is position order inside a prompt); pads see each other, finite garbage
+    nobody reads."""
+    T, H, hd = q.shape
+    Hkv = k.shape[1]
+    qg = q.reshape(T, Hkv, H // Hkv, hd).astype(jnp.float32) * cfg.attn_scale
+    scores = jnp.einsum("qhgd,khd->hgqk", qg, k.astype(jnp.float32))
+    t = jnp.arange(T, dtype=jnp.int32)
+    mask = (rowids[:, None] == rowids[None, :]) & (t[None, :] <= t[:, None])
+    scores = jnp.where(mask[None, None], scores, jnp.float32(-1e30))
+    probs = jax.nn.softmax(scores, axis=-1)
+    ctx = jnp.einsum("hgqk,khd->qhgd", probs, v.astype(jnp.float32))
+    return ctx.astype(q.dtype).reshape(T, H * hd)
+
+
+def write_prompt_rows(
+    cache: jnp.ndarray,  # [L, B, Hx, S, *rest]: one plane of the KV cache
+    new: jnp.ndarray,  # [L, Hx, T, *rest]: whole prompts' rows packed, head-major
+    slots: jnp.ndarray,  # [R] int32: the cache row of each prompt
+    offsets: jnp.ndarray,  # [R + 1] int32: packed boundaries
+) -> jnp.ndarray:
+    """Land whole packed prompts at position 0 of their slots, IN PLACE:
+    `ragged_write_rows` for rows that all start at 0, where one window a
+    prompt covers every layer (R updates a plane where the general form
+    unrolls R x L; half the seconds to lower and compile the mixed round for
+    the described v5e, and as there no second copy of the cache). An empty
+    row (offsets equal) selects nothing and writes back what it read."""
+    L, B, Hx, S = cache.shape[:4]
+    W = min(S, new.shape[2])  # a prompt holds at most T tokens
+    ntail = cache.ndim - 4
+    win = jnp.arange(W, dtype=jnp.int32).reshape((1, 1, 1, W) + (1,) * ntail)
+    # the window may run past the packed buffer's end: doubled, never selected
+    twice = jnp.concatenate([new, new], axis=2)
+    for r in range(slots.shape[0]):
+        rows = jax.lax.dynamic_slice(
+            twice, (0, 0, offsets[r]) + (0,) * ntail, (L, Hx, W) + cache.shape[4:]
+        )[:, None]  # [L, 1, Hx, W, *rest]
+        at = (0, slots[r], 0, 0) + (0,) * ntail
+        cur = jax.lax.dynamic_slice(cache, at, (L, 1, Hx, W) + cache.shape[4:])
+        keep = win < offsets[r + 1] - offsets[r]
+        cache = jax.lax.dynamic_update_slice(
+            cache, jnp.where(keep, rows.astype(cache.dtype), cur), at
+        )
+    return cache
+
+
+def mixed_step_q8(
+    cfg: ModelConfig,
+    params: Params,
+    cache_k: dict,
+    cache_v: dict,
+    tokens: jnp.ndarray,  # [B] int32, the full batch's last tokens
+    lengths: jnp.ndarray,  # [B] int32 (a parked row carries S)
+    p_tokens: jnp.ndarray,  # [T] int32: whole prompts packed back to back
+    p_rowids: jnp.ndarray,  # [T] int32: prompt of each token, sorted; pads = R
+    p_positions: jnp.ndarray,  # [T] int32: position in its prompt; pads = S
+    p_slots: jnp.ndarray,  # [R] int32: the cache row each prompt takes
+    p_last_idx: jnp.ndarray,  # [R] int32: packed index of each prompt's last token
+    paged: dict | None = None,  # the decode rows' paging operand (`_decode_step_q8`);
+    #   a fresh prompt's rows go to its slot's own arena rows, as admit_fn's do
+) -> tuple[jnp.ndarray, dict, dict]:
+    """`_decode_step_q8` for the full batch with admitted prompts riding the
+    same pass over the weights: each layer runs ONE `_qkv`, ONE output
+    projection and ONE feed-forward over the B decode rows and the T prompt
+    tokens stacked. `qdot` scales activations a row, so a row's products do
+    not depend on what shares the matmul: the decode rows get what the plain
+    step gives them and the prompt tokens what `llama_prefill` gives them. In
+    between the decode rows attend through the cache as ever and the prompt
+    tokens attend causally over their own fresh K/V (position 0 on: they read
+    no cache, so their numerics do not depend on the quantised rows). After
+    the scan the decode rows' K/V is appended, then the prompts' rows land in
+    their slots in the cache's fused form (`fuse_prompt_kv`, the scales
+    `admit_fn`'s insert writes; `write_prompt_rows`); the prompts' slots are parked rows of the
+    decode batch, so the append writes nothing live there first.
+
+    Returns (logits [B + R, V]: the decode rows, then each prompt's last
+    token; new_k, new_v)."""
+    L, B, _, S, hd = _cache_shape(cache_k)
+    Hkv, H = cfg.n_kv_heads, cfg.n_heads
+    T = p_tokens.shape[0]
+    R = p_slots.shape[0]
+    N = B + T
+    p_rowids = jnp.asarray(p_rowids, dtype=jnp.int32)
+    h = _embed_in(cfg, params, jnp.concatenate([tokens, p_tokens]))  # [N, D]
+    cos, sin = rope_tables(cfg, hd, jnp.concatenate([lengths, p_positions]))
+
+    def layer(carry, xs):
+        lp, win = xs
+        h, li = carry
+        with jax.named_scope("attn"):
+            x = _sub_in(cfg, h, lp["attn_norm"])
+            q, k, v = _qkv(cfg, lp, x)
+            q = apply_rope(q.reshape(N, 1, H, hd), cos[:, None], sin[:, None])[:, 0]
+            k = apply_rope(k.reshape(N, 1, Hkv, hd), cos[:, None], sin[:, None])[:, 0]
+            v = v.reshape(N, Hkv, hd)
+            ctx_d = decode_attend_q8(
+                q[:B].reshape(B, Hkv, H // Hkv, hd), k[:B], v[:B],
+                cache_k, cache_v, li, lengths, scale=cfg.attn_scale,
+                block_tables=None if paged is None else paged["tbl"],
+                pool_k=None if paged is None else paged["k"],
+            ).reshape(B, H * hd)
+            ctx_p = packed_prompt_attn(cfg, q[B:], k[B:], v[B:], p_rowids)
+            h = _attn_residual(cfg, lp, jnp.concatenate([ctx_d, ctx_p]), h)
+        h = _ffn_residual(cfg, lp, h)
+        with jax.named_scope("kv_append"):
+            fused = fuse_prompt_kv(
+                k[B:].transpose(1, 0, 2), v[B:].transpose(1, 0, 2),
+                scale_dtype=cache_k["s"].dtype,
+            )  # {"q": [2 Hkv + p, T, hd], "s": [2 Hkv, T]}
+        return (h, li + 1), (k[:B], v[:B], fused["q"], fused["s"])
+
+    (h, _), (knew, vnew, pq, ps) = jax.lax.scan(
+        layer, (h, jnp.int32(0)), (params["layers"], layer_windows(cfg)),
+    )
+    with jax.named_scope("kv_append"):
+        new_k, new_v = append_kv_q8(cache_k, cache_v, knew, vnew, lengths)
+        offsets = jnp.concatenate([
+            jnp.zeros((1,), jnp.int32),
+            jnp.sum(p_rowids[None, :] < jnp.arange(1, R + 1, dtype=jnp.int32)[:, None],
+                    axis=1, dtype=jnp.int32),
+        ])  # [R + 1] packed boundaries; an unused row is empty and writes nothing
+        new_k = {
+            "q": write_prompt_rows(new_k["q"], pq, p_slots, offsets),
+            "s": write_prompt_rows(new_k["s"], ps, p_slots, offsets),
+        }
+    last = jnp.take(h[B:], jnp.clip(p_last_idx, 0, T - 1), axis=0)  # [R, D]
+    return _logits(cfg, params, jnp.concatenate([h[:B], last])), new_k, new_v
+
+
 def _decode_step_bf16(
     cfg: ModelConfig,
     params: Params,

@@ -84,8 +84,8 @@ __all__ = [
 # Steady-state dispatch phases: every one has a CompileLedger phase string,
 # a flight-recorder etype, and a cost model in PHASE_COSTS (lint-enforced).
 DISPATCH_PHASES = (
-    "admit", "chunk", "cnstep", "decode", "fused", "fused_rag", "pf_rag",
-    "verify",
+    "admit", "chunk", "cnstep", "decode", "fused", "fused_rag", "mixed",
+    "pf_rag", "verify",
 )
 # Compile-ledger-only phases: rare, data-dependent dispatches (COW block
 # copies, pool offload staging, host-payload pool puts on the fleet
@@ -98,7 +98,7 @@ AUX_COMPILE_PHASES = ("cow", "pool_put", "pool_put_host", "restore")
 # alone, without live traffic). fused/fused_rag/verify depend on the live
 # fill mix and speculation state — the planner lists their ledger-observed
 # keys but marks them unplannable (they compile on first real dispatch).
-WARMUP_PHASES = ("admit", "chunk", "decode", "pf_rag")
+WARMUP_PHASES = ("admit", "chunk", "decode", "mixed", "pf_rag")
 
 CACHE_LAYOUTS = ("gqa_bf16", "gqa_int8", "mla_bf16", "mla_int8")
 
@@ -310,6 +310,7 @@ PHASE_COSTS = {
     "decode": _decode_cost,
     "fused": _decode_cost,
     "fused_rag": _decode_cost,
+    "mixed": _decode_cost,  # a decode round whose first step carries prompts
     "verify": _decode_cost,
 }
 
@@ -349,12 +350,22 @@ class AdmitAccount:
     three parts on one clock: `cooling_s` (free, fenced until the rounds in
     flight at the free were fetched), `no_request_s` (cool, and the request
     that took the slot had not arrived), `queued_s` (cool, the request
-    waiting, no admit program dispatched yet: the engine's own part)."""
+    waiting, no admit program dispatched yet: the engine's own part).
+
+    `ride` books a batch of whole prompts that rode a decode round's first
+    step (the engine's `mixed_round_fn`) and took no program: `rides`
+    {rounds, prompts, true_tokens, padded_tokens (the round's rung)}. `own`
+    books, for every batch that DID take an admit program, why it did not
+    ride: `own` {reason: programs} and `own_prompts` {reason: prompts}, the
+    reasons "no active rows", "compact", "reads at once", "over the cap",
+    "recurrent", "other". `programs`, `prompts` and the mark stay programs of
+    their own alone."""
 
     SUMS = ("programs", "prompts", "rows_padded", "true_tokens",
             "padded_tokens", "queued_sum", "reads", "reads_blocked",
             "reads_at_once")
     VACANCY = ("count", "cooling_s", "no_request_s", "queued_s")
+    RIDES = ("rounds", "prompts", "true_tokens", "padded_tokens")
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
@@ -363,6 +374,24 @@ class AdmitAccount:
         self.by_shape: dict[str, int] = {}  # "rows_padded:bucket" -> programs
         self.held_by: dict[str, int] = {}  # why a batch closed -> programs
         self._vacancy = {**dict.fromkeys(self.VACANCY, 0.0), "count": 0}
+        self._rides = dict.fromkeys(self.RIDES, 0)
+        self._own: dict[str, int] = {}  # reason -> programs
+        self._own_prompts: dict[str, int] = {}  # reason -> prompts
+
+    def ride(self, prompts: int, true_tokens: int, padded_tokens: int) -> None:
+        """Book one batch that rode a decode round."""
+        r = self._rides
+        with self._lock:
+            r["rounds"] += 1
+            r["prompts"] += prompts
+            r["true_tokens"] += true_tokens
+            r["padded_tokens"] += padded_tokens
+
+    def own(self, reason: str, prompts: int) -> None:
+        """Book why a batch took an admit program of its own."""
+        with self._lock:
+            self._own[reason] = self._own.get(reason, 0) + 1
+            self._own_prompts[reason] = self._own_prompts.get(reason, 0) + prompts
 
     def program(self, kind: str, rows: int, rows_padded: int, bucket: int,
                 true_tokens: int, queued: int, held_by: str = "") -> int:
@@ -416,6 +445,9 @@ class AdmitAccount:
                 "by_shape": dict(self.by_shape),
                 "held_by": dict(self.held_by),
                 "vacancy": dict(self._vacancy),
+                "rides": dict(self._rides),
+                "own": dict(self._own),
+                "own_prompts": dict(self._own_prompts),
             }
 
 

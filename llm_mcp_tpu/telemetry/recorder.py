@@ -41,9 +41,16 @@ An *event* is a tuple ``(seq, ts, etype, trace_id, fields)``:
             dispatched before it, t; `admit` is the per-request event at
             the first token) /
             admit_read (a batched admission's first tokens were read from
-            the in-flight queue: aid, rows, after_rid = the newest round
-            fetched before it, wait_ms, blocked = the read still had to
-            wait for the device, t) / preempt /
+            the in-flight queue: aid, or rid = the mixed round that carried
+            it where it took no program of its own, rows, after_rid = the
+            newest round fetched before it, wait_ms, blocked = the read
+            still had to wait for the device, t) /
+            mixed (a full-batch decode round DISPATCHED whose first step
+            carries whole prompts through its pass over the weights,
+            mixed_round_fn, in place of decode: rid, rows, prompts,
+            prompt_tokens, padded_tokens = the round's rung, queued,
+            held_by = why the batch closed: queue_empty / no_slot /
+            admit_batch / budget / own, t) / preempt /
             offload / restore / cow / pin / unpin / snap (paged ledger
             snapshot for preempt/offload) / pg_tbl (device
             block-table reset/rebuild, with the shared-row count) /

@@ -11,9 +11,21 @@ line is `benchmark/run.py`'s with the seven added, and with the traced run's own
 `itl_p95_ms` and `out_tokens_per_s` (beside an untraced run's they are what the
 tracing costs); the line `admission:` before it holds what no metric reads:
 the window's difference of `perf_stats()["admit"]` whole (`by_shape`, `held_by`,
-the vacancy's three parts), `samples_evicted` and how many `event_gap` samples
-the window and the drain put, and the ring's admit programs inside the
-profiler's slice beside the runs of `jit_admit_fn` in it.
+the vacancy's three parts; since PR 38 `rides`, the batches that rode a decode
+round, and `own` / `own_prompts`, those that took a program of their own by
+reason, with `engaged_share` = riding prompts over all), `samples_evicted` and
+how many `event_gap` samples the window and the drain put, the ring's admit
+programs inside the profiler's slice beside the runs of `jit_admit_fn` in it,
+the slice's rounds by program name (`rounds`: runs and mean device ms of the
+plain `jit_decode_chunk_fn` and of the mixed `jit_mixed_round_fn`, the mixed
+ones by rung from the ring's `mixed` events in the slice, and the host's
+milliseconds a round over rounds of BOTH names, which
+`engine_host_ms_per_round` divides by the plain ones alone), the rows of EVERY
+round of the window over the slots (`occupancy_ring`, from the ring's `emit`
+events: `decode_occupancy` reads one dispatch in 32 of a phase, about two
+dozen a window), the window's gaps of over 200 ms between two rounds'
+emissions with the ring's events inside each (`stalls`), and the warm-up
+plan's seconds by phase (`plan`).
 """
 
 from __future__ import annotations
@@ -22,6 +34,7 @@ import argparse
 import json
 import os
 import sys
+import time
 import types
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -43,6 +56,84 @@ def diff(a, b):
     return b - (a or 0)
 
 
+MIXED_PROGRAM = "jit_mixed_round_fn"  # a decode round whose first step carries prompts
+
+
+def wall_shift() -> float:
+    """What a wall-clock stamp (the ring's `ts`, `_ttft_window`) is ahead of
+    the monotonic clock the window's edges are on."""
+    return time.time() - time.monotonic()
+
+
+def first_token_ms(gen, w0: float, w1: float) -> dict | None:
+    """The engine's own first-token samples of the window (arrival to the
+    first token's emission, `_ttft_window`: wall-clock stamps, so the window's
+    edges are moved onto that clock)."""
+    shift = wall_shift()
+    got = sorted(ms for t, ms in list(gen._ttft_window) if w0 <= t - shift < w1)
+    if not got:
+        return None
+    return {"n": len(got), "p50": round(got[len(got) // 2], 2),
+            "p95": round(got[min(len(got) - 1, int(len(got) * 0.95))], 2),
+            "mean": round(sum(got) / len(got), 2)}
+
+
+def stalls(gen, w0: float, w1: float, over_ms: float = 200.0) -> list:
+    """The window's gaps between two rounds' emissions that are longer than
+    `over_ms` (a round is 50-90 ms in every cell): [seconds into the window,
+    the gap's ms, the ring's events inside it by kind]. A stall of seconds is
+    invisible to a p95 and is most of what a low run of tokens lost."""
+    shift = wall_shift()
+    rows = gen._flight.snapshot()
+    emits = [r["ts"] - shift for r in rows if r["etype"] == "emit"]
+    emits = [t for t in emits if w0 <= t < w1]
+    got = []
+    for a, b in zip(emits, emits[1:]):
+        if (b - a) * 1e3 > over_ms:
+            kinds: dict[str, int] = {}
+            for r in rows:
+                if a < r["ts"] - shift < b:
+                    kinds[r["etype"]] = kinds.get(r["etype"], 0) + 1
+            got.append([round(a - w0, 3), round((b - a) * 1e3, 1), kinds])
+    return got
+
+
+def rounds_by_program(run: dict, tr: dict) -> dict | None:
+    """Runs and mean device ms of the plain and the mixed round in the slice,
+    the mixed rounds of the slice by rung (the ring says which rung a round
+    took, the trace what a run cost: matched in order where they agree in
+    number), and the host's ms a round over rounds of both names."""
+    from benchmark import spans
+    from benchmark.layer_metrics import engine_host_ms_per_round as host_reader
+
+    got = spans.planes(run)
+    if got is None:
+        return None
+    chips, host = got
+    plain = spans.program_runs(chips, "jit_decode_chunk_fn")
+    mixed = spans.program_runs(chips, MIXED_PROGRAM)
+
+    def ms(runs):
+        return round(sum(b - a for a, b in runs) / len(runs) / 1e6, 3) if runs else None
+
+    out = {"plain": [len(plain), ms(plain)], "mixed": [len(mixed), ms(mixed)]}
+    ring = [e["fields"] for e in run["sut"]["gen"]._flight.snapshot(etype="mixed")]
+    if "start" in tr:
+        ring = [f for f in ring if tr["start"] <= f["t"] < tr["stop"]]
+        out["mixed_ring_in_slice"] = len(ring)
+        if mixed and abs(len(ring) - len(mixed)) <= 2:
+            by_rung: dict[int, list[float]] = {}
+            # the slice's edges may hold a run whose dispatch fell outside: match from the end
+            for f, (a, b) in zip(reversed(ring), reversed(mixed)):
+                by_rung.setdefault(f["padded_tokens"], []).append((b - a) / 1e6)
+            out["mixed_by_rung"] = {k: [len(v), round(sum(v) / len(v), 3)] for k, v in sorted(by_rung.items())}
+    by_reader = host_reader.read(run)
+    if by_reader is not None and plain:
+        out["host_ms_per_round"] = {"reader_plain_only": round(by_reader, 3),
+                                    "both_names": round(by_reader * len(plain) / (len(plain) + len(mixed)), 3)}
+    return out
+
+
 def extras(run: dict):
     """Print what the block holds beyond the metrics; a reader that gives no
     metric (None)."""
@@ -60,7 +151,33 @@ def extras(run: dict):
                               "after_window": sum(s[0] >= w1 for s in held),
                               "oldest_before_window_s": round(w0 - held[0][0], 3) if held else None},
     }
+    win = out["window"]
+    rode = (win.get("rides") or {}).get("prompts", 0)
+    own = sum((win.get("own_prompts") or {}).values())
+    out["engaged_share"] = round(rode / (rode + own), 4) if rode + own else None
+    out["first_token_ms"] = first_token_ms(gen, w0, w1)
+    # every round of the window, where `decode_occupancy` reads one dispatch in 32
+    emits = [f for f in (e["fields"] or {} for e in gen._flight.snapshot(etype="emit"))
+             if "t" in f and w0 <= f["t"] < w1]
+    out["occupancy_ring"] = {
+        "rounds": len(emits),
+        "pct": round(100.0 * sum(f["rows"] for f in emits) / (len(emits) * gen.max_slots), 3),
+    } if emits else None
+    out["stalls"] = stalls(gen, w0, w1)
+    plan = (gen.warmup_stats().get("plan") or [])
+    out["plan"] = {
+        "steps": len(plan),
+        "wall_s_by_phase": {
+            ph: round(sum(st.get("wall_s") or 0.0 for st in plan if st["phase"] == ph), 2)
+            for ph in sorted({st["phase"] for st in plan})},
+        "mixed": [{k: st.get(k) for k in ("key", "status", "wall_s")}
+                  for st in plan if st["phase"] == "mixed"],
+    }
     tr = run.get("trace") or {}
+    try:
+        out["rounds"] = rounds_by_program(run, tr)
+    except Exception as e:  # noqa: BLE001: a builder's print must not lose the run's line
+        out["rounds"] = f"{type(e).__name__}: {e}"
     if "start" in tr:
         progs = admit_spans.ring(run, "admit_prog").values()
         got = spans.planes(run)

@@ -148,7 +148,7 @@ def main() -> int:
     phys = eng._phys is not None
     B = eng.max_slots
     zoo: list[tuple[str, tuple]] = [
-        (ph, key) for ph, key in eng.warmup_shape_zoo() if ph == "decode"
+        (ph, key) for ph, key in eng.warmup_shape_zoo() if ph in ("decode", "mixed")
     ]
     if eng._ragged_cap:
         skey = 0 if eng._ragged_impl == "kernel" else min(128, eng.max_seq_len)

@@ -684,7 +684,8 @@ def test_debug_perf_endpoint_full_document(base):
     assert doc["goodput"]["finished_requests"] >= 1
     assert doc["goodput"]["finished_tokens"] > 0
     # admission's account, as perf_stats() gives it (PR 37)
-    assert set(doc["admit"]) == {*perf.AdmitAccount.SUMS, "by_shape", "held_by", "vacancy"}
+    assert set(doc["admit"]) == {*perf.AdmitAccount.SUMS, "by_shape", "held_by", "vacancy",
+                                 "rides", "own", "own_prompts"}
     assert doc["admit"]["programs"] >= 1 and doc["admit"]["reads"] == doc["admit"]["programs"]
     assert set(doc["admit"]["vacancy"]) == set(perf.AdmitAccount.VACANCY)
     assert doc["samples_evicted"] == {"event_gap": 0, "stream_lag": 0}

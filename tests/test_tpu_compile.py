@@ -340,6 +340,74 @@ def test_decode_step_reads_stacked_weights_in_place(
     assert temp < (256 << 20) + sum(w.size * w.dtype.itemsize for w in whole)
 
 
+@pytest.mark.parametrize("rung", [128, 256])
+def test_mixed_round_fits_beside_decode_closed(one_chip, chip_kernels, rung):
+    """The mixed round at `decode_closed`'s shapes, from shapes alone as the
+    engine's `mixed_round_fn` has it: Qwen3-8B int8, 32 rows, the int8 cache at
+    2,048, the packed prompt buffer of one rung, 4 prompt rows, then 3 plain
+    steps in a scan. The cache is updated in place (the prompts' rows go in
+    through `write_prompt_rows` after the decode rows' append), no loop body
+    copies weights, and the program stays inside what the cell has left: its
+    peak is 13.77 GB of the chip's 15.75 GiB (PERF.md section 4), the plain round
+    compiles to 12.74 GiB and the ragged chunk programs to 13.34."""
+    import importlib.util
+    from functools import partial
+
+    from llm_mcp_tpu.models import llama, quant
+    from llm_mcp_tpu.models.configs import get_config
+
+    spec = importlib.util.spec_from_file_location(
+        "rehearse_tpu_compile",
+        os.path.join(os.path.dirname(__file__), "..", "scripts", "rehearse_tpu_compile.py"))
+    rehearse = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(rehearse)
+    cfg = get_config("qwen3-8b")
+    slots, R = 32, 4
+
+    def init():
+        p = quant.init_llama_params_quantized(cfg, jax.random.PRNGKey(0), scale_dtype=BF)
+        return quant.fuse_layer_weights(quant.quantize_params(p))
+
+    def mixed_round(params, ck, cv, tokens, lengths, p_tokens, p_rowids, p_positions,
+                    p_slots, p_last):
+        logits, ck, cv = llama.mixed_step_q8(
+            cfg, params, ck, cv, tokens, lengths, p_tokens, p_rowids, p_positions,
+            p_slots, p_last)
+        new = jnp.argmax(logits, axis=-1).astype(I32)
+
+        def step(carry, _):
+            ck, cv, toks, lens = carry
+            logits, ck, cv = llama.llama_decode_step(
+                cfg, params, ck, cv, toks, lens, attn_impl="pallas")
+            new = jnp.argmax(logits, axis=-1).astype(I32)
+            return (ck, cv, new, lens + 1), new
+
+        (ck, cv, _, _), out = jax.lax.scan(
+            step, (ck, cv, new[:slots], lengths + 1), None, length=3)
+        return jnp.concatenate([new[None, :slots], out]), new[slots:], ck, cv
+
+    params = jax.eval_shape(init)
+    cache = jax.eval_shape(partial(llama.init_kv_cache, cfg, slots, S, dtype=BF, quantized=True))
+    params, cache = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), (params, cache))
+    vec = lambda n: jax.ShapeDtypeStruct((n,), I32, sharding=one_chip)  # noqa: E731
+
+    compiled = jax.jit(mixed_round, donate_argnums=(1, 2)).lower(
+        params, cache["k"], cache["v"], vec(slots), vec(slots), vec(rung), vec(rung),
+        vec(rung), vec(R), vec(R)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    made = rehearse.stacked_weight_producers(
+        text, rehearse.stacked_weight_dims(params["layers"]))
+    assert made == [], "the mixed round copies weights"
+    mem = compiled.memory_analysis()
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    # the cache and nothing weight-sized beside it in temporaries; the whole
+    # under the 13.34 GiB of the ragged programs the cell already holds
+    assert mem.temp_size_in_bytes < (512 << 20), mem.temp_size_in_bytes
+    assert total < int(13.34 * (1 << 30)), total
+
+
 # -- the hybrid decoder's step programs at the published Solar-Open2 widths ---------
 
 SOLAR_SLOTS, SOLAR_S = 64, 1024

@@ -117,6 +117,27 @@ def test_critical_split_cold_picks_smallest_shapes():
     assert len(steps) == len(zoo)
 
 
+def test_the_mixed_rungs_come_last_in_an_unmeasured_plan():
+    # no first request dispatches a mixed round (it needs a full batch
+    # decoding), and the serve path's first two rides first-dispatch both
+    # rungs: the plan reaches them last and mostly records them as skip.
+    # Measured, a rung ranks by its cost x hits like any other shape.
+    zoo = [
+        ("mixed", (128, True)), ("mixed", (256, True)),
+        ("admit", (4, 512)), ("admit", (1, 32)),
+        ("pf_rag", (2048, 0, True)), ("pf_rag", (32, 0, True)),
+        ("decode", (32, False, True)), ("decode", (8, True, True)),
+    ]
+    steps = warmup.plan_steps(zoo, {})
+    assert [(s.phase, s.key) for s in steps[-2:]] == [
+        ("mixed", (128, True)), ("mixed", (256, True))]
+    assert not any(s.critical for s in steps[-2:])
+    priors = warmup.priors_from_table(_table([("mixed", "256:True", 3, 18.0)]))
+    rest = [s for s in warmup.plan_steps(zoo, priors) if not s.critical]
+    assert (rest[0].phase, rest[0].key) == ("mixed", (256, True))
+    assert (rest[-1].phase, rest[-1].key) == ("mixed", (128, True))
+
+
 def test_priors_from_table_drops_malformed_rows():
     priors = warmup.priors_from_table(
         _table([("admit", "1:32", 3, 6.0)])
