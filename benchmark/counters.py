@@ -50,11 +50,18 @@ def mean_live_tokens(run: dict, samples: int = 64) -> float:
     return total / samples
 
 
-DECODE_PROGRAM = "jit_decode_chunk_fn"  # the engine's decode step program, as the trace names it
+DECODE_PROGRAM = "jit_decode_chunk_fn"  # the engine's PLAIN decode step program, as the trace names it
+# Every program that is one round of the engine's loop: the plain round, and the
+# round whose first step carries queued prompts through its weight pass
+# (`engine.mixed_round_fn`). A reader that counts the rounds the host served
+# takes all of them; one that means a plain round's bytes or kernels keeps
+# DECODE_PROGRAM.
+ROUND_PROGRAMS = (DECODE_PROGRAM, "jit_mixed_round_fn")
 
 
 def decode_round_s(run: dict) -> float | None:
-    """Mean device seconds of one run of the decode step program in the trace."""
+    """Mean device seconds of one run of the plain decode step program in the
+    trace; a mixed round is another program and is left out."""
     tr = run.get("trace_reduced")
     runs = tr["module_runs"].get(DECODE_PROGRAM) if tr else None
     return runs[1] if runs else None

@@ -2,7 +2,9 @@
 steps must read (the int8 weights once a step, and the live int8 KV rows with
 their scales at the window's mean fill, from shapes: peaks.py) over the
 chip's published bytes a second, over the round's device time in the trace.
-Bound by memory: a step at 32 rows does 0.5 TOP against 8 GB."""
+Bound by memory: a step at 32 rows does 0.5 TOP against 8 GB. Plain rounds
+alone: a mixed round (`jit_mixed_round_fn`) also moves its prompts' rows and
+writes their KV, bytes this count does not hold, so it is left out."""
 from benchmark import counters, peaks
 
 NAME, UNIT, BETTER, SOURCE = "decode_round_roofline", "%", "higher", "device_trace"

@@ -4,7 +4,9 @@ step, the live rows of the state pool read and written, the live int8 KV:
 olmo_hybrid_bytes.py) over the chip's published bytes a second, over the
 round's device time in the trace. Bound by memory: a step at 64 rows does about
 0.6 TFLOP against 14 GB. The share of the whole step that bounds a later claim
-in this cell."""
+in this cell. Plain rounds alone (`counters.DECODE_PROGRAM`): the bytes are a
+plain round's, and this configuration runs no mixed round
+(`memory.RECURRENT_OFF["mixed_round"]`); one would be left out."""
 from benchmark import counters, olmo_hybrid_bytes, peaks
 
 NAME, UNIT, BETTER, SOURCE = "olmo_round_roofline", "%", "higher", "device_trace"

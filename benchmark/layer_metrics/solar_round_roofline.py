@@ -4,7 +4,9 @@ a step, the banks of the held experts the step's rows touched by the program's
 counter, the live rows of the state pool read and written, the live int8 KV:
 solar_bytes.py) over the chip's published bytes a second, over the round's
 device time in the trace. Bound by memory: a step at 64 rows does about 0.3
-TFLOP against 7 GB."""
+TFLOP against 7 GB. Plain rounds alone (`counters.DECODE_PROGRAM`): the bytes
+are a plain round's, and this configuration runs no mixed round
+(`memory.RECURRENT_OFF["mixed_round"]`); one would be left out."""
 from benchmark import counters, peaks, solar_bytes
 
 NAME, UNIT, BETTER, SOURCE = "solar_round_roofline", "%", "higher", "device_trace"

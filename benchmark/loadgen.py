@@ -14,7 +14,7 @@ record per request it sent, every time in seconds from `t_start`:
     embeddings: i, sent, status, done, inputs, bad (vectors not finite, not of
                 unit norm or not `dimensions` wide), error
 
-Idea copied from bench.py's client_proc; the code is the benchmark's own.
+One process, a thread for each closed-loop client or open-loop worker; the code is the benchmark's own.
 """
 
 from __future__ import annotations

@@ -24,7 +24,10 @@ or {"dist": "lognormal", "median", "sigma", "lo", "hi"} (clipped).
 Every seed gets the SAME multiset of sizes, texts and arrival gaps, in another
 order: sizes are the distribution's stratified quantiles, a text's words are
 drawn from its size's rank among them, and the seed only shuffles. So the work
-a window offers does not move with the seed, only its order does. (With random
+a window offers does not move with the seed, only its order does. A closed
+loop's request of several texts (embeddings) is a hand dealt in turn from the
+deck, the same hand under every seed (`_deal_hands`), because what such a
+request costs follows from the sizes it holds together. (With random
 weights a reply ends where EOS is sampled, and how soon depends on the prompt:
 texts drawn from the seed made one seed's window hold 25% more requests than
 another's; v5e, PR 23.)
@@ -109,6 +112,29 @@ def _deal(dealer, dist: dict, n: int, rng: random.Random) -> list:
     return out[:n]
 
 
+def _deal_hands(dist: dict, n: int, per_req: int, rng: random.Random) -> list[list[list[int]]]:
+    """n requests of per_req texts each, dealt in turn from whole decks: a
+    deck is the stratified quantiles, longest first, DECK of them or the next
+    multiple of per_req, and its card i goes to the deck's request i mod k (k
+    requests a deck), as cards are dealt round a table. So the j-th request of
+    every deck holds the same sizes and texts under every seed, an even sample
+    of the whole distribution, and the seed shuffles them within the request
+    alone. (A deck shuffled and then cut into requests gave each request a
+    random half of it: the server packs a request's texts into rows of 512
+    and runs a forward for every two rows, a half packed into 12 to 17 rows,
+    and a window's forwards moved with the seed by half a percent; v5e, PR 40.)"""
+    k = -(-DECK // per_req)
+    size = k * per_req
+    cards = [[max(1, round(quantile(dist, (i + 0.5) / size))), i] for i in reversed(range(size))]
+    out: list[list[list[int]]] = []
+    while len(out) < n:
+        for j in range(k):
+            hand = [list(c) for c in cards[j::k]]
+            rng.shuffle(hand)
+            out.append(hand)
+    return out[:n]
+
+
 def make_plan(traffic: dict, seed: int, seconds: float, *, model: str,
               preroll_s: float | None = None, salt: str = "r") -> dict:
     """The plan one run of the load generator executes: a list of requests in
@@ -142,7 +168,11 @@ def make_plan(traffic: dict, seed: int, seconds: float, *, model: str,
                 t += gaps[j]
     elif loop == "closed":
         n = CLOSED_CAP
-        plens = _deal(deck, traffic["prompt_tokens"], n * per_req, rng)
+        if per_req > 1:
+            hands = _deal_hands(traffic["prompt_tokens"], n, per_req, rng)
+            plens = [card for hand in hands for card in hand]
+        else:
+            plens = _deal(deck, traffic["prompt_tokens"], n * per_req, rng)
         mtoks = _deal(size_deck, traffic["max_tokens"], n, rng) if endpoint == "chat" else [0] * n
         clients = int(traffic["clients"])
         for j in range(n):

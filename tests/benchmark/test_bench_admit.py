@@ -270,6 +270,67 @@ def test_the_share_is_read_only_where_runs_and_dispatches_agree(traced, tmp_path
     assert admit_spans.admit_runs(run) is None and reader("admit_program_share").read(run) is None
 
 
+# -- since PR 38: rounds of two names, and a window whose admissions all ride ---------------
+
+MIXED = [("jit_mixed_round_fn(33)", 199, 252), ("jit_mixed_round_fn(34)", 252, 312)]  # the two rungs, an executable each
+
+
+def slice_of(tmp_path, monkeypatch, runs, dispatches):
+    monkeypatch.setattr(sys.modules[__name__], "RUNS", runs)
+    monkeypatch.setattr(sys.modules[__name__], "DISPATCHES", dispatches)
+    path = xspace(tmp_path / "rounds.xspace.txt")
+    return {"trace_path": path, "trace_reduced": tr.reduce_trace(path)}
+
+
+def test_engine_host_ms_per_round_divides_by_the_rounds_of_both_names(traced, tmp_path, monkeypatch):
+    """The slice's host seconds are four `engine.admit` phases of 1.5 ms; the
+    host served a round that carried prompts as it served a plain one."""
+    from benchmark import counters
+
+    host = reader("engine_host_ms_per_round")
+    assert counters.ROUND_PROGRAMS == (counters.DECODE_PROGRAM, "jit_mixed_round_fn")
+    assert host.read(traced) == pytest.approx(6.0 / 3)  # three plain rounds and no other
+    recorded = list(RUNS)
+    both = slice_of(tmp_path, monkeypatch, recorded + MIXED, DISPATCHES)
+    assert host.read(both) == pytest.approx(6.0 / 5)  # not 6.0 / 3: what the reader gave until PR 40
+    assert reader("decode_round_ms").read(both) == pytest.approx(50.0)  # a PLAIN round's time, as before
+    mixed_only = slice_of(tmp_path, monkeypatch, [r for r in recorded if "decode" not in r[0]] + MIXED, DISPATCHES)
+    assert host.read(mixed_only) == pytest.approx(6.0 / 2) and reader("decode_round_ms").read(mixed_only) is None
+
+
+@pytest.fixture()
+def riding(tmp_path, monkeypatch):
+    """A window in which every admission rode a decode round (decode_closed
+    since PR 38): the `admit` block's programs stand still while its rides and
+    vacancies grow, no gap holds an admit program, the slice holds rounds of
+    both names and no run of `jit_admit_fn`."""
+    def block(n):
+        return {"programs": 3, "prompts": 5, "true_tokens": 200, "padded_tokens": 320,
+                "rides": {"rounds": n, "prompts": 2 * n},
+                "vacancy": {"count": n, "cooling_s": 0.050 * n, "no_request_s": 0.0, "queued_s": 0.002 * n}}
+
+    gaps = [(100.0 + k, 0.052, 0, 0) for k in range(40)]
+    perf = types.SimpleNamespace(samples=lambda kind, whole=False: list(gaps) if whole else [g[:2] for g in gaps],
+                                 samples_evicted={"event_gap": 0, "stream_lag": 0})
+    edge = lambda n: {"perf": {"admit": block(n), "samples_evicted": dict(perf.samples_evicted)}}  # noqa: E731
+    run = slice_of(tmp_path, monkeypatch, [r for r in RUNS if "admit" not in r[0]] + MIXED, [])
+    return {**run, "sut": {"gen": types.SimpleNamespace(_perf=perf, _flight=ring_of([]))},
+            "start": edge(0), "end": edge(40), "window_abs": (100.0, 140.0)}
+
+
+@pytest.mark.parametrize("name", ["admit_program_share", "admit_rows_mean", "admit_pad_waste_pct",
+                                  "event_gap_admit_ms"])
+def test_a_reader_of_the_admit_program_gives_none_where_none_ran(name, riding):
+    assert riding["trace_reduced"]["module_runs"].get(admit_spans.ADMIT_PROGRAM) is None
+    assert reader(name).read(riding) is None
+
+
+def test_the_readers_of_gaps_and_vacancies_still_read_where_every_admission_rides(riding):
+    assert reader("event_gap_admit_share").read(riding) == 0.0  # a reading: what the ride bought
+    assert reader("slot_vacant_ms").read(riding) == pytest.approx(52.0)
+    assert reader("slot_vacant_queued_ms").read(riding) == pytest.approx(2.0)
+
+
 # -- the builder's script ----------------------------------------------------------------
 
 

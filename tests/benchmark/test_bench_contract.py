@@ -1,5 +1,8 @@
 """BENCHMARK.json against the contract it is written to, and against the files
-it names: what the driver refuses before a single run must fail here first."""
+it names: what the driver refuses before a single run must fail here first.
+Every test takes the file through the `bench` fixture (conftest.py) and finds
+an entry by its name, never by its place: test_bench_rehearsal.py runs them
+again on a copy with a stand-in configuration, cell and metric appended."""
 
 import json
 import os
@@ -19,12 +22,6 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 PATH = re.compile(r"^[A-Za-z0-9_.\-/]{1,200}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
-
-
-@pytest.fixture(scope="module")
-def bench():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
 
 
 def line_ok(s):
@@ -134,8 +131,8 @@ def test_workloads(bench):
         assert os.path.isfile(traffic), traffic
     four = sum(1 for w in bench["workloads"] if w["chips"] == 4)
     assert four <= max(1, len(names) // 4)
-    # the order ISSUE.md asks for, less the cell that could not be proved
-    assert names[:2] == ["decode_closed", "embed_batch"]
+    # the two cells ISSUE 23 asked for first (its third could not be proved); where they stand is free
+    assert {"decode_closed", "embed_batch"} <= set(names)
 
 
 def test_metrics(bench):
@@ -184,8 +181,9 @@ def test_layer_names_are_perf_md_layers(bench):
 
 def test_run_on_the_cpu_exits_non_zero_and_prints_no_result(bench):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
+    cell = next(w["name"] for w in bench["workloads"] if w["chips"] == 1)  # any: the refusal names the chips
     out = subprocess.run(
-        [sys.executable, *bench["command"][1:], "--workload", bench["workloads"][0]["name"],
+        [sys.executable, *bench["command"][1:], "--workload", cell,
          "--seed", "3000000001", "--seconds", "1", "--trace", "0"],
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=120)
     assert out.returncode != 0

@@ -220,30 +220,34 @@ def test_a_program_in_a_lower_precision_than_the_file_states_is_not_correct(monk
         correctness.check_generation(run)
 
 
-def test_the_cell_is_solars_traffic_number_for_number_and_is_appended_where_the_issue_says():
+ON_CELL = {*NEW, "decode_occupancy", "decode_token_yield", "engine_host_ms_per_round",
+           "decode_round_ms", "engine_itl_p95_ms", "engine_event_gap_p95_ms",
+           "stream_write_lag_p95_ms", "window_compiles.serve", "pallas_busy_share",
+           "decode_attn_ms", "setup_first_dispatch_s.serve",
+           "setup_first_dispatch_s.trace_lower", "setup_first_dispatch_s.backend",
+           "state_pool_share"}  # the seventeen PR 35 put on the cell; a later metric may list it too
+
+
+def test_the_cell_is_solars_traffic_number_for_number_and_its_entries_are_found_by_name(bench):
+    """The cell, its configuration and its three metrics by NAME, wherever they
+    stand in their lists: nothing here is said of another configuration's
+    entries, of what comes last, or of metrics added to the cell since."""
     traffic = os.path.join(ROOT, "benchmark", "traffic")
     assert json.load(open(os.path.join(traffic, CELL + ".json"))) == json.load(
         open(os.path.join(traffic, "solar_decode_closed.json")))
-    bench = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    cell = bench["workloads"][-1]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
-        CELL, "olmo-hybrid-7b-d20-bf16", CELL, 1)
-    assert bench["configs"][-1]["reduced"] == ["layer_types", "num_hidden_layers"]
+    cell, = [w for w in bench["workloads"] if w["name"] == CELL]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == ("olmo-hybrid-7b-d20-bf16", CELL, 1)
+    config, = [c for c in bench["configs"] if c["name"] == cell["config"]]
+    assert config["reduced"] == ["layer_types", "num_hidden_layers"]
     reports = {m["name"] for m in bench["end_to_end"] if CELL in m.get("workloads", [CELL])}
     assert reports == {"itl_p95_ms", "out_tokens_per_s", "setup_s"}
     layer = {m["name"]: m for m in bench["per_layer"]}
-    on_cell = {n for n, m in layer.items() if CELL in m["workloads"]}
-    assert on_cell == {*NEW, "decode_occupancy", "decode_token_yield", "engine_host_ms_per_round",
-                       "decode_round_ms", "engine_itl_p95_ms", "engine_event_gap_p95_ms",
-                       "stream_write_lag_p95_ms", "window_compiles.serve", "pallas_busy_share",
-                       "decode_attn_ms", "setup_first_dispatch_s.serve",
-                       "setup_first_dispatch_s.trace_lower", "setup_first_dispatch_s.backend",
-                       "state_pool_share"}
-    for name in NEW:  # the last three entries, each on this cell alone
+    on_cell = {n for n, m in layer.items() if CELL in m.get("workloads", [CELL])}
+    assert on_cell >= ON_CELL, ON_CELL - on_cell
+    for name in NEW:  # its three own entries, each on this cell alone
         assert layer[name]["workloads"] == [CELL] and layer[name]["moves"] == "out_tokens_per_s"
         mod = reader(name)
         assert (mod.NAME, mod.UNIT, mod.SOURCE) == (name, layer[name]["unit"], "device_trace")
-    assert [m["name"] for m in bench["per_layer"][-3:]] == NEW
 
 
 def test_the_references_controls_move_the_logits_and_the_tables_name_every_key_run_py_does_not_hold():

@@ -72,6 +72,31 @@ def test_every_seed_offers_the_same_work_in_another_order(name):
     assert flat(a, "prompt")[cut[0]] != flat(b, "prompt")[cut[0]]
 
 
+HANDS = [n for n in MIXES if traffic(n)["endpoint"] == "embeddings" and traffic(n).get("inputs_per_request", 1) > 1]
+
+
+@pytest.mark.parametrize("name", HANDS)
+def test_a_request_of_several_texts_holds_the_same_texts_under_every_seed(name):
+    """What the server does for a request follows from the sizes it holds
+    together (it packs them into rows), so a seed may shuffle a request's
+    texts and nothing else: request j is the same hand under every seed, and
+    the hands of one deck are the whole deck, each an even sample of it."""
+    t = traffic(name)
+    per = t["inputs_per_request"]
+    a = trafficgen.make_plan(t, 11, 20, model="m")["requests"]
+    b = trafficgen.make_plan(t, 2**31 + 5, 20, model="m")["requests"]
+    assert all(len(r["prompt"]) == per for r in a[:64])
+    hands = [sorted(map(tuple, r["prompt"])) for r in a]
+    assert hands == [sorted(map(tuple, r["prompt"])) for r in b]
+    assert [r["prompt"] for r in a[:8]] != [r["prompt"] for r in b[:8]]  # in another order
+    k = -(-trafficgen.DECK // per)
+    assert hands[:k] == hands[k:2 * k] and len(hands) == trafficgen.CLOSED_CAP
+    ranks = sorted(rank for hand in hands[:k] for _size, rank in hand)
+    assert ranks == list(range(k * per))
+    totals = [sum(size for size, _rank in hand) for hand in hands[:k]]
+    assert max(totals) - min(totals) < 0.05 * min(totals)  # no hand is the long half
+
+
 def test_open_loop_due_times_are_fixed_before_any_request_is_sent():
     t = traffic("chat_open")
     p = trafficgen.make_plan(t, 5, 30, model="m")
