@@ -837,6 +837,16 @@ def paged_gather(arena, pool, tables, *, nbs=None):
 Q8_BLOCK_BYTES_MAX = 1 << 20
 
 
+def blocked_arm_fits(head_dim: int, interp: bool) -> bool:
+    """Whether the blocked q8 arm can run: its copies cut blocks of whole
+    128-lane rows out of HBM, where a narrower head lies padded to 128 (Mosaic:
+    "slice shape along dimension 4 must be aligned to tiling (128), but is 64",
+    seen in the described-chip compile). On the chip such a cache takes the
+    whole-S arm, whose tiles the pipeline copies; interpret mode has no tiling
+    and keeps the blocked arm for the parity tests."""
+    return interp or head_dim % 128 == 0
+
+
 def q8_block_tokens(payload_heads: int, seq_len: int, head_dim: int) -> int:
     """Cache positions in one block of the blocked q8 arm: the largest of 256,
     128, 64, 32 that divides `seq_len` (a floored block count would drop the
@@ -866,7 +876,9 @@ class AttnStream:
 
     def __init__(self, cache_q_shape: tuple[int, ...]):
         _, _, heads, self.seq_len, head_dim = cache_q_shape
-        self.block_tokens = q8_block_tokens(heads, self.seq_len, head_dim)
+        # 0: the whole-S arm alone runs here, and streams every row in full
+        self.block_tokens = (q8_block_tokens(heads, self.seq_len, head_dim)
+                             if blocked_arm_fits(head_dim, _interpret()) else 0)
         self.steps = self.tokens_streamed = self.tokens_live = 0
 
     def dispatched(self, lengths: np.ndarray, steps: int) -> None:
@@ -874,9 +886,12 @@ class AttnStream:
         `lengths` (this step's position a row; >= the cache's length: parked)."""
         w = lengths[:, None].astype(np.int64) + np.arange(steps)
         w = np.where(lengths[:, None] >= self.seq_len, self.seq_len, w)  # parked stays parked
-        blocks = blocked_row_blocks(w, self.seq_len, self.block_tokens, xp=np)
         self.steps += steps
-        self.tokens_streamed += int(blocks.sum()) * self.block_tokens
+        if self.block_tokens:
+            blocks = blocked_row_blocks(w, self.seq_len, self.block_tokens, xp=np)
+            self.tokens_streamed += int(blocks.sum()) * self.block_tokens
+        else:
+            self.tokens_streamed += w.size * self.seq_len
         self.tokens_live += int(np.where(w < self.seq_len, w + 1, 0).sum())
 
     def stats(self) -> dict:
@@ -996,6 +1011,8 @@ def decode_attend_q8(
     nv4 = new_v.reshape(B, Hkv, 1, hd)
     can_whole = S <= decode_pallas_max_seq(hd, Hkv, Hkv * G, quantized=True)
     BS = block_s or q8_block_tokens(2 * Hkv + p, S, hd)
+    if not blocked_arm_fits(hd, interp):
+        BS = 0
     if not can_whole and BS == 0:
         # no whole-S fit and no int8-tileable block divides S: exact f32
         # math of the reference (slower, never wrong)
@@ -2438,7 +2455,9 @@ def _append_q8_kernel(
     #          cache tiles are selected at row ids[b], the body never reads it)
     pay_ref,  # [L, 1, Hf, hd] int8 — this step's FUSED row: quantized K
     #           heads, V heads, packed-scale bytes (built by append_kv_q8
-    #           in plain JAX — the kernel only selects, never quantizes)
+    #           in plain JAX — the kernel only selects, never quantizes);
+    #           [L, 1, Hf, BSQ, hd], the row repeated down the tile, where a
+    #           head is narrower than the 128 lanes (`_down_the_tile`)
     s_ref,  # [L, 1, 2*Hkv, BSS] — this step's plain dequant scales, already
     #         broadcast along the lane tile (a [L, 1, 2*Hkv] block has a
     #         second-to-last dim of 1 over Ba: no legal TPU tile)
@@ -2459,10 +2478,22 @@ def _append_q8_kernel(
 
     rows = jax.lax.broadcasted_iota(jnp.int32, (1, 1, block_q, 1), 2)  # [1,1,BSQ,1]
     hit = live & (rows == wq)
-    oq_ref[:, 0] = jnp.where(hit, pay_ref[:, 0][:, :, None, :], cq_ref[:, 0])
+    pay = pay_ref[:, 0]
+    oq_ref[:, 0] = jnp.where(hit, pay if pay.ndim == 4 else pay[:, :, None, :], cq_ref[:, 0])
     lanes = jax.lax.broadcasted_iota(jnp.int32, (1, 1, block_s), 2)  # [1,1,BSS]
     hit_s = live & (lanes == ws)
     os_ref[:, 0] = jnp.where(hit_s, s_ref[:, 0].astype(os_ref.dtype), cs_ref[:, 0])
+
+
+def _down_the_tile(x: jnp.ndarray, block: int) -> jnp.ndarray:
+    """A step's rows [L, Ba, heads, hd] repeated down a cache tile's `block`
+    positions, [L, Ba, heads, block, hd]: what the append kernels select from
+    where a head is narrower than the 128 lanes. Mosaic has no form of the
+    in-kernel broadcast [heads, hd] -> [heads, 1, hd] at 64 lanes ("unsupported
+    shape cast", seen in the described-chip compile), so the repeat is made
+    outside, a few MB a step at head size 64, and the kernel's select is
+    between two tiles of one shape."""
+    return jnp.broadcast_to(x[:, :, :, None, :], (*x.shape[:3], block, x.shape[-1]))
 
 
 def _q8_step_rows(cache_k: dict, new_k, new_v):
@@ -2547,10 +2578,11 @@ def append_kv_q8(
     )
     pay, s_new = _q8_step_rows(cache_k, new_k, new_v)
 
-    # mosaic int8 stores want full 128-lane rows; small-head test configs
-    # (hd 32/64) take the scatter. Interpret mode keeps the kernel path at
-    # lane-aligned shapes so parity tests cover the real tile-rewrite body.
-    if hd % 128 != 0 or S % 128 != 0:
+    # mosaic int8 stores want rows of 128 lanes, or of 64 (a head of 64 is
+    # padded to 128 in HBM and stored under a mask); smaller-head test configs
+    # (hd 32) take the scatter. Interpret mode keeps the kernel path at those
+    # shapes so parity tests cover the real tile-rewrite body.
+    if hd % 64 != 0 or S % 128 != 0:
         _note_fall("append_kv_q8", f"hd={hd} S={S} not lane-aligned", interp)
         return _append_q8_scatter(cache_k, pay, s_new, rows, lengths), cache_v
 
@@ -2558,6 +2590,9 @@ def append_kv_q8(
     BSS = 128  # lane width: smallest in-place scales rewrite
     assert S % BSQ == 0 and S % BSS == 0, (S, BSQ, BSS)
     kernel = functools.partial(_append_q8_kernel, block_q=BSQ, block_s=BSS, seq_len=S)
+    narrow = hd % 128 != 0  # the step's row comes repeated down the tile
+    pay_spec = (pl.BlockSpec((L, 1, Hf, BSQ, hd), lambda b, lens, ids: (0, b, 0, 0, 0)) if narrow
+                else pl.BlockSpec((L, 1, Hf, hd), lambda b, lens, ids: (0, b, 0, 0)))
 
     def blkq(lens, b):
         # payload tile holding this row's write position (clamped if parked)
@@ -2570,7 +2605,7 @@ def append_kv_q8(
         num_scalar_prefetch=2,  # lengths [Ba], cache row ids [Ba]
         grid=(Ba,),
         in_specs=[
-            pl.BlockSpec((L, 1, Hf, hd), lambda b, lens, ids: (0, b, 0, 0)),
+            pay_spec,
             pl.BlockSpec((L, 1, Hs, BSS), lambda b, lens, ids: (0, b, 0, 0)),
             pl.BlockSpec(
                 (L, 1, Hf, BSQ, hd), lambda b, lens, ids: (0, ids[b], 0, blkq(lens, b), 0)
@@ -2603,7 +2638,7 @@ def append_kv_q8(
     )(
         lengths.astype(jnp.int32),
         rows,
-        pay,
+        _down_the_tile(pay, BSQ) if narrow else pay,
         jnp.broadcast_to(s_new[..., None], (L, Ba, Hs, BSS)),
         cache_k["q"],
         cache_k["s"],
@@ -2614,8 +2649,8 @@ def append_kv_q8(
 def _append_bf16_kernel(
     lengths_ref,  # [Ba] int32 (scalar prefetch) — this step's position per row
     ids_ref,  # [Ba] int32 (scalar prefetch) — cache row per batch position
-    nk_ref,  # [L, 1, Hkv, hd] — this step's K vectors (post-rope)
-    nv_ref,  # [L, 1, Hkv, hd]
+    nk_ref,  # [L, 1, Hkv, hd] — this step's K vectors (post-rope);
+    nv_ref,  # [L, 1, Hkv, BQ, hd] where a head is narrower than the 128 lanes
     ck_ref,  # [L, 1, Hkv, BQ, hd] — K tile containing position w
     cv_ref,  # [L, 1, Hkv, BQ, hd]
     ok_ref,  # outputs — aliased to the cache operands
@@ -2631,8 +2666,13 @@ def _append_bf16_kernel(
 
     rows = jax.lax.broadcasted_iota(jnp.int32, (1, 1, block_q, 1), 2)  # [1,1,BQ,1]
     hit = live & (rows == wq)
-    ok_ref[:, 0] = jnp.where(hit, nk_ref[:, 0][:, :, None, :].astype(ok_ref.dtype), ck_ref[:, 0])
-    ov_ref[:, 0] = jnp.where(hit, nv_ref[:, 0][:, :, None, :].astype(ov_ref.dtype), cv_ref[:, 0])
+
+    def tile(ref):
+        x = ref[:, 0]
+        return (x if x.ndim == 4 else x[:, :, None, :]).astype(ok_ref.dtype)
+
+    ok_ref[:, 0] = jnp.where(hit, tile(nk_ref), ck_ref[:, 0])
+    ov_ref[:, 0] = jnp.where(hit, tile(nv_ref), cv_ref[:, 0])
 
 
 def append_kv_bf16_reference(cache_k, cache_v, new_k, new_v, lengths, slot_ids=None):
@@ -2678,16 +2718,22 @@ def append_kv_bf16(
     )
 
     BQ = 16  # bf16 sublane tile height (f32 needs 8 — 16 covers both)
-    # mosaic stores want full 128-lane rows; small-head test configs take
-    # the scatter fallback. Interpret mode keeps the kernel path at lane-
-    # aligned shapes so parity tests cover the real tile-rewrite body.
-    if hd % 128 != 0 or S % BQ != 0:
+    # mosaic stores want rows of 128 lanes, or of 64 (`append_kv_q8` says
+    # how); smaller-head test configs take the scatter fallback. Interpret
+    # mode keeps the kernel path at those shapes so parity tests cover the
+    # real tile-rewrite body.
+    if hd % 64 != 0 or S % BQ != 0:
         _note_fall("append_kv_bf16", f"hd={hd} S={S} not tile-aligned", interp)
         return append_kv_bf16_reference(
             cache_k, cache_v, new_k, new_v, lengths, slot_ids=rows
         )
 
     kernel = functools.partial(_append_bf16_kernel, block_q=BQ, seq_len=S)
+    narrow = hd % 128 != 0  # the step's rows come repeated down the tile
+    new_spec = (pl.BlockSpec((L, 1, Hkv, BQ, hd), lambda b, lens, ids: (0, b, 0, 0, 0)) if narrow
+                else pl.BlockSpec((L, 1, Hkv, hd), lambda b, lens, ids: (0, b, 0, 0)))
+    if narrow:
+        new_k, new_v = _down_the_tile(new_k, BQ), _down_the_tile(new_v, BQ)
 
     def blkq(lens, b):
         # tile holding this row's write position (clamped if parked)
@@ -2697,8 +2743,8 @@ def append_kv_bf16(
         num_scalar_prefetch=2,  # lengths [Ba], cache row ids [Ba]
         grid=(Ba,),
         in_specs=[
-            pl.BlockSpec((L, 1, Hkv, hd), lambda b, lens, ids: (0, b, 0, 0)),
-            pl.BlockSpec((L, 1, Hkv, hd), lambda b, lens, ids: (0, b, 0, 0)),
+            new_spec,
+            new_spec,
             pl.BlockSpec(
                 (L, 1, Hkv, BQ, hd), lambda b, lens, ids: (0, ids[b], 0, blkq(lens, b), 0)
             ),

@@ -50,6 +50,16 @@ class ModelConfig:
     act: str = "silu"  # FFN activation: silu (llama/qwen/mistral) | gelu (gemma)
     norm_weight_offset: float = 0.0  # Gemma: RMSNorm computes x * (1 + w)
     embed_scale: bool = False  # Gemma: hidden = embed * sqrt(dim)
+    # Granite's four multipliers, each neutral by default (models/llama.py:
+    # `_embed_in`, `_residual`, `_logits`; `attn_scale` below): the embedding
+    # is multiplied by `embed_multiplier`, each sub-layer's output by
+    # `residual_multiplier` before it joins the stream, the logits are divided
+    # by `logits_divisor`, and the attention scores are multiplied by
+    # `attn_multiplier` INSTEAD of head_dim**-0.5 (0 = the usual scale)
+    embed_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_divisor: float = 1.0
+    attn_multiplier: float = 0.0
     logit_softcap: float = 0.0  # Gemma2: logits = cap * tanh(logits / cap)
     attn_softcap: float = 0.0  # Gemma2: same cap on attention scores
     sliding_window: int = 0  # Mistral/Gemma2: local-attention window (0 = off)
@@ -102,10 +112,11 @@ class ModelConfig:
     # sigmoid with a selection bias that chooses and does not weigh.
     n_router_experts: int = 0
     router_score: str = "softmax"  # softmax | sigmoid
-    # Hybrid of softmax-attention and linear-attention layers
-    # (models/hybrid.py, models/kda.py): layer i is a GQA layer iff
-    # i in gqa_layers, else a gated delta-rule (KDA) layer with a per-slot
-    # recurrent state. Empty = every layer is the family's attention layer.
+    # Hybrid of softmax-attention and recurrent layers (models/hybrid.py):
+    # layer i is a GQA layer iff i in gqa_layers, else a layer with a per-slot
+    # recurrent state: a Mamba-2 state-space layer (models/ssm.py) where
+    # `ssm_heads` is set, else a gated delta-rule layer (models/kda.py: KDA or
+    # Gated DeltaNet). Empty = every layer is the family's attention layer.
     gqa_layers: tuple[int, ...] = ()
     gqa_interval: int = 0  # linear layers between two GQA layers (published)
     lin_heads: int = 0
@@ -119,6 +130,14 @@ class ModelConfig:
     # projected from the input at full rank, a SiLU output gate at full rank
     # (Gated DeltaNet)
     lin_gates: str = "kda"  # kda | gdn
+    # A Mamba-2 state-space layer (models/ssm.py): `ssm_heads` heads of
+    # `ssm_head_dim` values each (the inner width is their product), a state of
+    # `ssm_state` keys a head, B and C ONE group shared by every head, a causal
+    # depthwise convolution of `ssm_conv` taps WITH bias over x | B | C
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 4
     attn_gate: bool = False  # GQA output gate: attn * sigmoid(x W_gate)
     use_rope: bool = True  # False: no positional encoding anywhere (NoPE)
     # Where a sub-layer's RMSNorm sits (models/hybrid.py, the hybrid decoder's
@@ -158,10 +177,17 @@ class ModelConfig:
         return len(self.gqa_layers) if self.gqa_layers else self.n_layers
 
     @property
+    def recurrent_kind(self) -> str:
+        """The kind of the layers that are not GQA layers, which is also their
+        key in the parameter tree: "ssm" (Mamba-2) or "kda" (the delta rule)."""
+        return "ssm" if self.ssm_heads else "kda"
+
+    @property
     def layer_period(self) -> tuple[str, ...]:
-        """Kinds ("gqa" | "kda") of one period of the layer pattern; the
+        """Kinds ("gqa" | "kda" | "ssm") of one period of the layer pattern; the
         layer stack is this period repeated (models/hybrid.py scans by it)."""
-        kinds = ["gqa" if i in self.gqa_layers else "kda" for i in range(self.n_layers)]
+        rec = self.recurrent_kind
+        kinds = ["gqa" if i in self.gqa_layers else rec for i in range(self.n_layers)]
         for p in range(1, self.n_layers + 1):
             if self.n_layers % p == 0 and kinds == kinds[:p] * (self.n_layers // p):
                 return tuple(kinds[:p])
@@ -180,6 +206,8 @@ class ModelConfig:
 
     @property
     def attn_scale(self) -> float:
+        if self.attn_multiplier:
+            return self.attn_multiplier
         return (
             self.query_pre_attn_scalar or self.resolved_head_dim
         ) ** -0.5 * self.yarn_attn_mscale
@@ -210,7 +238,18 @@ class ModelConfig:
                 + 2 * self.dim * self.n_kv_heads * hd  # wk, wv
                 + self.n_heads * hd * self.dim  # wo
             )
-        if self.gqa_layers:  # hybrid: GQA (+ gate) layers and delta-rule layers
+        if self.gqa_layers and self.ssm_heads:  # hybrid: GQA and Mamba-2 layers
+            inner = self.ssm_heads * self.ssm_head_dim
+            conv = inner + 2 * self.ssm_state  # x | B | C
+            ssm = (self.dim * (inner + conv + self.ssm_heads)  # W_in: gate | x B C | dt
+                   + (self.ssm_conv + 1) * conv  # the convolution and its bias
+                   + 3 * self.ssm_heads  # dt_bias, A_log, D
+                   + inner  # the gated norm
+                   + inner * self.dim)  # W_out
+            ng = len(self.gqa_layers)
+            ffn_total += ng * attn + (self.n_layers - ng) * ssm  # exact: no mean a layer
+            attn = 0
+        elif self.gqa_layers:  # hybrid: GQA (+ gate) layers and delta-rule layers
             hk, hv = self.lin_heads * self.lin_head_dim, self.lin_heads * self.lin_dv
             mix = (self.dim * (2 * hk + hv)  # wq, wk, wv
                    + hv * self.dim  # wo
@@ -468,6 +507,66 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         norm_placement="output",
         qk_norm=True,
         qk_norm_whole=True,
+        params_b=0.001,
+    ),
+    # Granite-4.0-H-Micro (ibm-granite/granite-4.0-h-micro config.json), whole:
+    # 36 Mamba-2 state-space layers and 4 attention layers (the sixth of every
+    # ten) without positional encoding, a dense gated MLP in every layer, the
+    # embedding table tied to the head, and Granite's four multipliers. What no
+    # key of the source states is listed as `assumed` in
+    # benchmark/configs/granite-4.0-h-micro-bf16.json.
+    "granite-4.0-h-micro": ModelConfig(
+        name="granite-4.0-h-micro",
+        vocab_size=100_352,
+        dim=2048,
+        n_layers=40,
+        n_heads=32,
+        n_kv_heads=8,
+        ffn_hidden=8192,
+        rope_theta=10_000.0,  # published; read by nothing: use_rope is False
+        norm_eps=1e-5,
+        max_seq_len=131_072,
+        experts_per_tok=0,
+        gqa_layers=(5, 15, 25, 35),
+        ssm_heads=64,
+        ssm_head_dim=64,
+        ssm_state=128,
+        ssm_conv=4,
+        use_rope=False,
+        tie_embeddings=True,
+        embed_multiplier=12.0,
+        residual_multiplier=0.22,
+        logits_divisor=8.0,
+        attn_multiplier=0.015625,
+        params_b=3.19,
+    ),
+    # the same shape at toy size: two periods of six (four state-space layers,
+    # which the program scans as a run, the attention layer, one more), state-space
+    # heads no multiple of 8 and two abreast in the pool (values of 64),
+    # attention heads of 16
+    "tiny-granite-hybrid": ModelConfig(
+        name="tiny-granite-hybrid",
+        vocab_size=512,
+        dim=96,
+        n_layers=12,
+        n_heads=4,
+        n_kv_heads=2,
+        head_dim=16,
+        ffn_hidden=192,
+        norm_eps=1e-5,
+        max_seq_len=512,
+        experts_per_tok=0,
+        gqa_layers=(4, 10),
+        ssm_heads=6,
+        ssm_head_dim=64,
+        ssm_state=32,
+        ssm_conv=4,
+        use_rope=False,
+        tie_embeddings=True,
+        embed_multiplier=12.0,
+        residual_multiplier=0.22,
+        logits_divisor=8.0,
+        attn_multiplier=0.0625,
         params_b=0.001,
     ),
     # the same shape at toy size: one period, 16 experts of which 4 are held

@@ -1,13 +1,15 @@
-"""A decoder whose layers are of unlike kinds: softmax GQA layers among gated
-delta-rule linear-attention layers (models/kda.py: KDA or Gated DeltaNet, by
-`cfg.lin_gates`), every layer with a feed-forward that is either routed
-experts, of which this process may hold a share (models/moe.py), or, without
-experts (`cfg.n_experts` 0), the dense family's gated MLP.
+"""A decoder whose layers are of unlike kinds: softmax GQA layers among
+recurrent layers of ONE kind (`cfg.recurrent_kind`): gated delta-rule
+linear-attention layers ("kda", models/kda.py: KDA or Gated DeltaNet, by
+`cfg.lin_gates`) or Mamba-2 state-space layers ("ssm", models/ssm.py); every
+layer with a feed-forward that is either routed experts, of which this process
+may hold a share (models/moe.py), or, without experts (`cfg.n_experts` 0), the
+dense family's gated MLP.
 
 The layer stack is one PERIOD of kinds repeated (`cfg.layer_period`, e.g.
-gqa, kda, kda, kda, or kda, kda, kda, gqa), so the program scans over periods
-and unrolls the few layers of one period inside the scan body: one period's
-XLA program compiled once, whatever the depth. The parameter tree:
+gqa, kda, kda, kda, or kda, kda, kda, gqa, or five ssm, gqa, four ssm), so the
+program scans over periods and unrolls one period inside the scan body: one
+period's XLA program compiled once, whatever the depth. The parameter tree:
 
     params["embed"], ["final_norm"], ["lm_head"]
     params["layers"]: what EVERY layer has, stacked [L, ...]: attn_norm,
@@ -16,14 +18,15 @@ XLA program compiled once, whatever the depth. The parameter tree:
         w1s, w3s, w2s, or the dense w1, w3 [D, F], w2 [F, D]
     params["gqa"]: the GQA layers', stacked [Lg, ...]: wq, wk, wv, wo, wg
         [D, H hd] with cfg.attn_gate, q_norm and k_norm with cfg.qk_norm
-    params["kda"]: the delta-rule layers', stacked [Lk, ...] (models/kda.py)
+    params["kda"] or params["ssm"]: the recurrent layers', stacked [Lk, ...]
+        (models/kda.py, models/ssm.py)
 
 The one norm of a sub-layer sits on its input or on its output
 (`cfg.norm_placement`, `llama._sub_in`): the weights are the same leaves.
 
 What a sequence owns, beside the rows of the KV cache that its GQA layers
 write (cache layers 0..Lg-1, the dense family's layout and kernels), is the
-delta-rule layers' recurrent state. The engine threads both through every step
+recurrent layers' state. The engine threads both through every step
 program as the cache pair (cache_k, cache_v): `cache_v` is
 {"v": the KV cache's second member, "state": {"S", "conv"}} and, with routed
 experts only, "moe": counts; built by `init_hybrid_cache`. "moe" [2, L, 5]
@@ -38,6 +41,7 @@ content and causality alone."""
 
 from __future__ import annotations
 
+from types import SimpleNamespace
 from typing import Any
 
 import jax
@@ -49,25 +53,38 @@ from ..kernels.attention import (
     decode_attend_bf16,
     decode_attend_q8,
 )
+from . import kda, ssm
 from .configs import ModelConfig
-from .kda import (
-    head_major,
-    init_kda_params,
-    init_kda_state,
-    kda_decode,
-    kda_prefill,
-    pool_rows,
-    zero_state,
-)
 from .moe import init_moe_layer_params, moe_share_ffn
 
 Params = dict[str, Any]
 
+# A recurrent layer kind's functions, under one set of names: its key in the
+# parameter tree is the kind's name. `scope` names the decode layer's
+# `jax.named_scope` (the chunk form opens `<scope>_prefill` itself).
+_RECURRENT = {
+    "kda": SimpleNamespace(
+        init_params=kda.init_kda_params, init_state=kda.init_kda_state,
+        prefill=kda.kda_prefill, decode=kda.kda_decode, zero_state=kda.zero_state,
+        pool_rows=kda.pool_rows, head_major=kda.head_major,
+        taps=lambda cfg: cfg.lin_conv, scope=lambda cfg: cfg.lin_gates),  # "kda" | "gdn"
+    "ssm": SimpleNamespace(
+        init_params=ssm.init_ssm_params, init_state=ssm.init_ssm_state,
+        prefill=ssm.ssm_prefill, decode=ssm.ssm_decode, zero_state=ssm.zero_state,
+        pool_rows=ssm.pool_rows, head_major=ssm.head_major,
+        taps=lambda cfg: cfg.ssm_conv, scope=lambda cfg: "ssd"),
+}
+
+
+def _rec(cfg: ModelConfig) -> SimpleNamespace:
+    return _RECURRENT[cfg.recurrent_kind]
+
 
 def _layout(cfg: ModelConfig) -> tuple[tuple[str, ...], int, int, int]:
-    """(period, periods, GQA layers a period, KDA layers a period)."""
+    """(period, periods, GQA layers a period, recurrent layers a period)."""
     period = cfg.layer_period
-    return period, cfg.n_layers // len(period), period.count("gqa"), period.count("kda")
+    ng = period.count("gqa")
+    return period, cfg.n_layers // len(period), ng, len(period) - ng
 
 
 def init_hybrid_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> Params:
@@ -111,10 +128,14 @@ def init_hybrid_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.bfloat16) -> 
             nq, nk_ = qk_norm_widths(cfg)
             gqa["q_norm"], gqa["k_norm"] = jnp.ones((Lg, nq), dtype), jnp.ones((Lg, nk_), dtype)
         params = {
-            "embed": w(ks[7], (V, D), D),
+            # a table that is multiplied on the way in (Granite's 12) is drawn that
+            # much smaller: the stream then starts at the scale it has in every
+            # other configuration, and a TIED head does not read the input token
+            # back as every row's largest logit, whatever the layers compute
+            "embed": w(ks[7], (V, D), D * cfg.embed_multiplier**2),
             "layers": layers,
             "gqa": gqa,
-            "kda": init_kda_params(cfg, ks[8], dtype, Lk),
+            cfg.recurrent_kind: _rec(cfg).init_params(cfg, ks[8], dtype, Lk),
             "final_norm": jnp.ones((D,), dtype),
         }
         if not cfg.tie_embeddings:
@@ -132,7 +153,7 @@ def init_hybrid_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype, quantiz
     _, P, _, nk = _layout(cfg)
     kv = init_kv_cache(
         _gqa_view(cfg), batch, max_seq, dtype=dtype, quantized=quantized)
-    cache_v = {"v": kv["v"], "state": init_kda_state(cfg, P * nk, batch, dtype)}
+    cache_v = {"v": kv["v"], "state": _rec(cfg).init_state(cfg, P * nk, batch, dtype)}
     if cfg.n_experts:
         cache_v["moe"] = jnp.zeros((2, cfg.n_layers, 5), jnp.int32)
     return {"k": kv["k"], "v": cache_v}
@@ -160,7 +181,7 @@ def _at(tree, i):
 def _ffn(cfg: ModelConfig, lp: Params, banks: Params, li, h: jnp.ndarray, valid):
     """Feed-forward of layer `li` and residual add on [..., D]: (h, counts [5]
     of the expert layer, None for the dense gated MLP)."""
-    from .llama import _ffn_residual, _sub_in, _sub_out
+    from .llama import _ffn_residual, _residual, _sub_in, _sub_out
 
     if not cfg.n_experts:
         return _ffn_residual(cfg, lp, h), None
@@ -169,7 +190,7 @@ def _ffn(cfg: ModelConfig, lp: Params, banks: Params, li, h: jnp.ndarray, valid)
         y, counts = moe_share_ffn(
             cfg, lp, x.reshape(-1, x.shape[-1]),
             valid=None if valid is None else valid.reshape(-1), banks=banks, layer=li)
-        return h + _sub_out(cfg, y.reshape(h.shape), lp["ffn_norm"]), counts
+        return _residual(cfg, h, _sub_out(cfg, y.reshape(h.shape), lp["ffn_norm"])), counts
 
 
 def _counted(cache_v: dict, phase: int, counts) -> dict:
@@ -178,14 +199,15 @@ def _counted(cache_v: dict, phase: int, counts) -> dict:
     return {} if counts is None else {"moe": cache_v["moe"].at[phase].add(counts)}
 
 
-def _period_scan(cfg: ModelConfig, params: Params, h, carry, gqa_layer, kda_layer, valid):
-    """Scan the periods. `gqa_layer(h, carry, lp, ig)` and `kda_layer(h,
+def _period_scan(cfg: ModelConfig, params: Params, h, carry, gqa_layer, rec_layer, valid):
+    """Scan the periods. `gqa_layer(h, carry, lp, ig)` and `rec_layer(h,
     carry, lp, ik)` run one layer's mixing half on the running `carry` (the
     caches, as the caller shapes it), `ig` / `ik` being the layer's index
     among its kind; the feed-forward follows either. Returns (h, carry,
     counts [L, 5] of the expert layers or None), and whatever the GQA layers
     stacked as ys, [P ng, ...]."""
     period, P, ng, nk = _layout(cfg)
+    rec_params = params[cfg.recurrent_kind]
     banks = {k: params["layers"][k] for k in BANKS if k in params["layers"]}
     layers = {k: v for k, v in params["layers"].items() if k not in BANKS}
 
@@ -201,7 +223,7 @@ def _period_scan(cfg: ModelConfig, params: Params, h, carry, gqa_layer, kda_laye
                 ys.append(y)
                 ig += 1
             else:
-                h, carry = kda_layer(h, carry, {**lp, **_at(params["kda"], p * nk + ik)}, p * nk + ik)
+                h, carry = rec_layer(h, carry, {**lp, **_at(rec_params, p * nk + ik)}, p * nk + ik)
                 ik += 1
             h, n = _ffn(cfg, lp, banks, li, h, valid)
             counts.append(n)
@@ -222,30 +244,33 @@ def hybrid_prefill(cfg, params, tokens, lengths, attn_impl="xla", quant_kv=False
     the pool's layout) and conv tails, and with routed experts "moe": the
     call's expert counts [L, 5]}; the engine inserts row by row
     (`insert_state_row`) and adds the counts once (`add_counts`)."""
-    from .llama import _embed_in, _logits, _sub_in, _sub_out, fuse_prompt_kv, prefill_attn, prefill_masks
+    from .llama import (
+        _embed_in, _logits, _residual, _sub_in, _sub_out, fuse_prompt_kv, prefill_attn,
+        prefill_masks)
 
     B, S = tokens.shape
     _, P, _, nk = _layout(cfg)
+    rec = _rec(cfg)
     h = _embed_in(cfg, params, tokens)
     cos, sin, mask = prefill_masks(cfg, S, lengths)
     valid = jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None]
-    S0, tail0 = zero_state(cfg, B, h.dtype)
+    S0, tail0 = rec.zero_state(cfg, B, h.dtype)
 
     def gqa_layer(h, carry, lp, ig):
         h, (kh, vh) = prefill_attn(cfg, lp, h, cos, sin, mask, lengths, attn_impl)
         return h, carry, ((fuse_prompt_kv(kh, vh), {}) if quant_kv else (kh, vh))
 
-    def kda_layer(h, carry, lp, ik):
+    def rec_layer(h, carry, lp, ik):
         Ss, tails = carry
-        y, S_new, tail = kda_prefill(
+        y, S_new, tail = rec.prefill(
             cfg, lp, _sub_in(cfg, h, lp["attn_norm"]), lengths, S0, tail0)
-        return h + _sub_out(cfg, y, lp["attn_norm"]), (
-            Ss.at[ik].set(pool_rows(cfg, S_new)), tails.at[ik].set(tail.reshape(B, -1)))
+        return _residual(cfg, h, _sub_out(cfg, y, lp["attn_norm"])), (
+            Ss.at[ik].set(rec.pool_rows(cfg, S_new)), tails.at[ik].set(tail.reshape(B, -1)))
 
-    carry = (jnp.zeros((P * nk, *pool_rows(cfg, S0).shape), jnp.float32),
+    carry = (jnp.zeros((P * nk, *rec.pool_rows(cfg, S0).shape), jnp.float32),
              jnp.zeros((P * nk, B, tail0[0].size), tail0.dtype))
     h, (Ss, tails), counts, (ks, vs) = _period_scan(
-        cfg, params, h, carry, gqa_layer, kda_layer, valid)
+        cfg, params, h, carry, gqa_layer, rec_layer, valid)
     last = jnp.take_along_axis(h, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
     return _logits(cfg, params, last), ks, {
         "v": vs, "state": {"S": Ss, "conv": tails},
@@ -273,14 +298,15 @@ def hybrid_prefill_chunk_batch(
     skey=0, all_logits=False, paged=None,
 ):
     """`llama_prefill_chunk_batch` for the hybrid stack: the GQA layers read
-    and write the KV cache as there; a KDA layer continues each slot's state
+    and write the KV cache as there; a recurrent layer continues each slot's state
     and convolution tail from the pool, from ZERO where the chunk is a
     prompt's first (start 0: a reused slot's old state is never read), and
     writes both back. Rows that duplicate row 0 (the engine's padding) write
     what row 0 writes."""
-    from .llama import _chunk_attention, _logits, _sub_in, _sub_out
+    from .llama import _chunk_attention, _logits, _residual, _sub_in, _sub_out
 
     A, C = tokens.shape
+    rec = _rec(cfg)
     kv_v, state = cache_v["v"], cache_v["state"]
     h, attend, write = _chunk_attention(
         cfg, params, cache_k, tokens, slots, starts, nvalid, skey=skey, paged=paged)
@@ -289,34 +315,40 @@ def hybrid_prefill_chunk_batch(
     valid = jnp.arange(C, dtype=jnp.int32)[None, :] < nvalid[:, None]
 
     def gqa_layer(h, carry, lp, ig):
-        ck, cv, S, conv = carry
+        ck, cv, Ss, tails = carry
         h, kh, vh = attend(h, ck, cv, ig, lp, 0)
         ck, cv = write(ck, cv, kh, vh, ig)
-        return h, (ck, cv, S, conv), None
+        return h, (ck, cv, Ss, tails), None
 
-    def kda_layer(h, carry, lp, ik):
-        ck, cv, S, conv = carry
-        # row by row, as they are written: a gather of rows of 384 lanes made the
-        # compiler copy the whole pool in three slabs of 128 (2 GiB at Olmo-Hybrid's
-        # size, seen in the described-chip compile)
-        def rows_of(pool):
-            return jnp.stack([jax.lax.dynamic_slice(
-                pool, (ik, slots[a]) + (0,) * (pool.ndim - 2), (1, 1, *pool.shape[2:]))[0, 0]
-                for a in range(A)])
+    # Each row's state and tail of EVERY recurrent layer come out of the pool
+    # before the layer scan and go back after it, row by row (a gather of rows of
+    # 384 lanes made the compiler copy the whole pool in three slabs of 128: 2 GiB
+    # at Olmo-Hybrid's size). The pool itself stays out of the scan: carried
+    # through it, a pool of square [128, 128] tiles was re-laid out whole on the
+    # way in and out (two copies of 4.5 GiB at Granite-4.0-H's size, seen in the
+    # described-chip compile), the layout being the chunk form's to choose where
+    # no kernel holds it.
+    def rows_of(pool):  # [Lk, A, ...]
+        return jnp.concatenate([jax.lax.dynamic_slice(
+            pool, (0, slots[a]) + (0,) * (pool.ndim - 2), (pool.shape[0], 1, *pool.shape[2:]))
+            for a in range(A)], axis=1)
 
-        S0 = jnp.where(fresh[:, None, None, None], 0.0, head_major(cfg, rows_of(S)))
-        tail0 = jnp.where(fresh[:, None, None], 0, rows_of(conv).reshape(A, cfg.lin_conv - 1, -1))
-        y, S_new, tail = kda_prefill(
+    def rec_layer(h, carry, lp, ik):
+        ck, cv, Ss, tails = carry
+        S0 = jnp.where(fresh[:, None, None, None], 0.0, rec.head_major(cfg, Ss[ik]))
+        tail0 = jnp.where(fresh[:, None, None], 0, tails[ik].reshape(A, rec.taps(cfg) - 1, -1))
+        y, S_new, tail = rec.prefill(
             cfg, lp, _sub_in(cfg, h, lp["attn_norm"]), nvalid, S0, tail0)
-        S_new = pool_rows(cfg, S_new)
-        for a in range(A):  # row by row: duplicates of row 0 land on row 0's values
-            S = jax.lax.dynamic_update_slice(S, S_new[a][None, None], (ik, slots[a], 0, 0, 0))
-            conv = jax.lax.dynamic_update_slice(
-                conv, tail[a].reshape(1, 1, -1), (ik, slots[a], 0))
-        return h + _sub_out(cfg, y, lp["attn_norm"]), (ck, cv, S, conv)
+        return _residual(cfg, h, _sub_out(cfg, y, lp["attn_norm"])), (
+            ck, cv, Ss.at[ik].set(rec.pool_rows(cfg, S_new)), tails.at[ik].set(tail.reshape(A, -1)))
 
-    h, (ck, cv, S, conv), counts, _ = _period_scan(
-        cfg, params, h, (cache_k, kv_v, state["S"], state["conv"]), gqa_layer, kda_layer, valid)
+    h, (ck, cv, Ss, tails), counts, _ = _period_scan(
+        cfg, params, h, (cache_k, kv_v, rows_of(state["S"]), rows_of(state["conv"])),
+        gqa_layer, rec_layer, valid)
+    S, conv = state["S"], state["conv"]
+    for a in range(A):  # row by row: duplicates of row 0 land on row 0's values
+        S = jax.lax.dynamic_update_slice(S, Ss[:, a : a + 1], (0, slots[a], 0, 0, 0))
+        conv = jax.lax.dynamic_update_slice(conv, tails[:, a : a + 1], (0, slots[a], 0))
     new_v = {"v": cv, "state": {"S": S, "conv": conv}, **_counted(cache_v, 1, counts)}
     if all_logits:
         return _logits(cfg, params, h), ck, new_v
@@ -328,10 +360,11 @@ def hybrid_decode_step(cfg, params, cache_k, cache_v, tokens, lengths, slot_ids=
     """One token a row. The GQA layers take the dense family's decode
     structure: the KV cache is a scan-invariant operand read by the decode
     attention kernel, the step's K/V stack out of the scan and one append
-    kernel lands them. A KDA layer steps its rows of the state pool in place
-    (kernels/kda.py). A parked or padding row (length >= the cache's) moves
+    kernel lands them. A recurrent layer steps its rows of the state pool in
+    place (kernels/kda.py). A parked or padding row (length >= the cache's) moves
     nothing: not its cache rows, not its state."""
-    from .llama import _attn_residual, _cache_shape, _embed_in, _logits, _qkv, _sub_in, _sub_out
+    from .llama import (
+        _attn_residual, _cache_shape, _embed_in, _logits, _qkv, _residual, _sub_in, _sub_out)
 
     if paged is not None:
         raise NotImplementedError("a recurrent configuration's blocks are never shared")
@@ -342,6 +375,7 @@ def hybrid_decode_step(cfg, params, cache_k, cache_v, tokens, lengths, slot_ids=
     rows = None if slot_ids is None else slot_ids.astype(jnp.int32)
     live = lengths < S_cache
     attend = decode_attend_q8 if quantized else decode_attend_bf16
+    rec = _rec(cfg)
     h = _embed_in(cfg, params, tokens)
 
     def gqa_layer(h, carry, lp, ig):
@@ -355,14 +389,14 @@ def hybrid_decode_step(cfg, params, cache_k, cache_v, tokens, lengths, slot_ids=
             ).reshape(Ba, H * hd)
             return _attn_residual(cfg, lp, ctx, h, x), carry, (k, v)
 
-    def kda_layer(h, carry, lp, ik):
-        with jax.named_scope(cfg.lin_gates):  # "kda" | "gdn"
-            y, carry = kda_decode(
+    def rec_layer(h, carry, lp, ik):
+        with jax.named_scope(rec.scope(cfg)):  # "kda" | "gdn" | "ssd"
+            y, carry = rec.decode(
                 cfg, lp, _sub_in(cfg, h, lp["attn_norm"]), carry, ik, rows, live)
-            return h + _sub_out(cfg, y, lp["attn_norm"]), carry
+            return _residual(cfg, h, _sub_out(cfg, y, lp["attn_norm"])), carry
 
     h, lin, counts, (knew, vnew) = _period_scan(
-        cfg, params, h, state, gqa_layer, kda_layer, live)
+        cfg, params, h, state, gqa_layer, rec_layer, live)
     with jax.named_scope("kv_append"):
         append = append_kv_q8 if quantized else append_kv_bf16
         new_k, new_kv_v = append(cache_k, kv_v, knew, vnew, lengths, slot_ids=slot_ids)

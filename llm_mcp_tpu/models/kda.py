@@ -200,6 +200,11 @@ def kda_chunk_scan(q, k, v, g, beta, S0):
     Returns (o [A, T, H, dv], S_T). A padding position leaves the state as it
     was (alpha 1, beta 0) and its output is never read.
 
+    Without the delta rule (`beta` None: a Mamba-2 layer, models/ssm.py) the
+    state takes the input as it is, U = V, and there is no system to solve; v
+    is then 0 at a padding position. q and k may be ONE group for every head,
+    [A, T, 1, dk]: every product below broadcasts them.
+
     Inside a chunk, with G the cumulative log decay (inclusive) and
     kk[t, s] = sum_d k_t k_s exp(G_t - G_s) for s < t, the corrections U solve
     (I + diag(beta) kk) U = beta (V - (K exp(G)) S0); then
@@ -208,10 +213,10 @@ def kda_chunk_scan(q, k, v, g, beta, S0):
     decay between two positions is a [C, C] matrix and kk, qk are the products
     K K^T, Q K^T masked by it; a decay a channel stands inside the sum over
     d, a [C, C, dk] tensor a chunk."""
-    A, T, H, dk = q.shape
+    A, T, H = v.shape[:3]
     C = math.gcd(T, CHUNK)
     N = T // C
-    per_head = g.ndim == 3
+    per_head, delta = g.ndim == 3, beta is not None
 
     def chunks(x):  # [A, T, H, ...] -> [N, A, H, C, ...]
         x = x.reshape(A, N, C, *x.shape[2:])
@@ -223,13 +228,14 @@ def kda_chunk_scan(q, k, v, g, beta, S0):
     eye = jnp.eye(C, dtype=jnp.float32)
 
     def step(S, xs):
-        q, k, v, g, beta = xs  # [A, H, C, .]; beta [A, H, C, 1]; g [A, H, C] a head
+        q, k, v, g, *beta = xs  # [A, H, C, .]; beta [A, H, C, 1]; g [A, H, C] a head
         G = jnp.cumsum(g, axis=2)
         # decay from position s to position t >= s: exp(<= 0)
         if per_head:
             decay = jnp.exp(jnp.minimum(G[:, :, :, None] - G[:, :, None, :], 0.0))  # [A, H, t, s]
             kT = jnp.swapaxes(k, -1, -2)
-            kk = jnp.where(earlier, jnp.matmul(k, kT, precision=_HI) * decay, 0.0)
+            if delta:
+                kk = jnp.where(earlier, jnp.matmul(k, kT, precision=_HI) * decay, 0.0)
             qk = jnp.where(upto, jnp.matmul(q, kT, precision=_HI) * decay, 0.0)
             G = G[..., None]  # [A, H, C, 1]: broadcasts over the keys below
         else:
@@ -238,9 +244,13 @@ def kda_chunk_scan(q, k, v, g, beta, S0):
             kk = jnp.where(earlier, jnp.sum(k[:, :, :, None, :] * kd, axis=-1), 0.0)
             qk = jnp.where(upto, jnp.sum(q[:, :, :, None, :] * kd, axis=-1), 0.0)
         eG = jnp.exp(G)
-        rhs = beta * (v - jnp.matmul(k * eG, S, precision=_HI))
-        U = jax.scipy.linalg.solve_triangular(
-            eye + beta * kk, rhs, lower=True, unit_diagonal=True)  # [A, H, C, dv]
+        if delta:
+            (beta,) = beta
+            rhs = beta * (v - jnp.matmul(k * eG, S, precision=_HI))
+            U = jax.scipy.linalg.solve_triangular(
+                eye + beta * kk, rhs, lower=True, unit_diagonal=True)  # [A, H, C, dv]
+        else:
+            U = v
         o = jnp.matmul(q * eG, S, precision=_HI) + jnp.matmul(qk, U, precision=_HI)
         k_out = k * jnp.exp(G[:, :, -1:, :] - G)  # carries a correction to the chunk's end
         S = eG[:, :, -1, :, None] * S + jnp.einsum(
@@ -248,9 +258,24 @@ def kda_chunk_scan(q, k, v, g, beta, S0):
         return S, o
 
     S, o = jax.lax.scan(
-        step, S0, (chunks(q), chunks(k), chunks(v), chunks(g), chunks(beta[..., None])))
+        step, S0, (chunks(q), chunks(k), chunks(v), chunks(g),
+                   *([chunks(beta[..., None])] if delta else [])))
     o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1)  # [A, N, C, H, dv]
     return o.reshape(A, T, H, v.shape[-1]), S
+
+
+def conv_chunk(tail0, nvalid, proj, conv_w):
+    """The causal depthwise convolution over a chunk `proj` [A, T, W] that
+    continues `tail0` [A, taps-1, W]: (its output before bias and activation,
+    the tail as it stands after each row's `nvalid` positions)."""
+    T, taps = proj.shape[1], conv_w.shape[0]
+    full = jnp.concatenate([tail0.astype(proj.dtype), proj], axis=1)  # [A, taps-1+T, W]
+    mixed = sum(
+        full[:, j : j + T] * conv_w[j].astype(proj.dtype) for j in range(taps))
+    tail = jax.vmap(
+        lambda rows, n: jax.lax.dynamic_slice_in_dim(rows, n, taps - 1, axis=0)
+    )(full, nvalid)  # rows [n, n + taps - 1) of `full` are the last taps-1 projections
+    return mixed, tail
 
 
 def kda_prefill(
@@ -264,15 +289,9 @@ def kda_prefill(
     """The layer over a chunk that continues (S0, tail0): (y [A, T, D], S,
     tail), the last two as they stand after each row's `nvalid` positions."""
     A, T, _ = x.shape
-    taps = cfg.lin_conv
     with jax.named_scope(f"{cfg.lin_gates}_prefill"):
         proj = qdot(x, kp["wqkv_lin"])  # [A, T, W]
-        full = jnp.concatenate([tail0.astype(proj.dtype), proj], axis=1)  # [A, taps-1+T, W]
-        mixed = sum(
-            full[:, j : j + T] * kp["conv_w"][j].astype(proj.dtype) for j in range(taps))
-        tail = jax.vmap(
-            lambda rows, n: jax.lax.dynamic_slice_in_dim(rows, n, taps - 1, axis=0)
-        )(full, nvalid)  # rows [n, n + taps - 1) of `full` are the last taps-1 projections
+        mixed, tail = conv_chunk(tail0, nvalid, proj, kp["conv_w"])
         q, k, v = _heads(cfg, mixed)
         g, beta, out_gate = _gates(cfg, kp, x)
         valid = jnp.arange(T)[None, :] < nvalid[:, None]  # [A, T]
@@ -292,32 +311,41 @@ def kda_decode(
     live: jnp.ndarray,  # [Ba] bool: a parked or padding row moves nothing
 ) -> tuple[jnp.ndarray, dict]:
     """One token through the layer on the pool: (y [Ba, D], the pool)."""
-    Ba, taps = x.shape[0], cfg.lin_conv
     proj = qdot(x, kp["wqkv_lin"])  # [Ba, W]
-    W = proj.shape[-1]
-    whole = slot_ids is None  # the full batch: the layer's tails are one block, no scatter
-    tail = (jax.lax.dynamic_index_in_dim(state["conv"], layer, 0, keepdims=False) if whole
-            else state["conv"][layer, slot_ids])  # [Ba, (taps-1) W]: a slot's rows end to end
-    # the taps as slices of the flat row, W a whole number of lanes: as
-    # [Ba, taps, W] every step re-laid the rows out twice (68 us a layer and
-    # step at Solar's width, 0.8 ms a round: v5e, PR 35)
-    full = jnp.concatenate([tail.astype(proj.dtype), proj], axis=-1)  # [Ba, taps W]
-    conv_w = kp["conv_w"].astype(proj.dtype)
-    mixed = sum(full[:, j * W : (j + 1) * W] * conv_w[j] for j in range(taps))
-    new_tail = jnp.where(live[:, None], full[:, W:].astype(tail.dtype), tail)
-    if whole:
-        # a scatter of 64 rows runs row after row on the chip (a seventh of the
-        # device in Olmo-Hybrid's cell, 15 layers x 4 steps a round); the block does not
-        conv = jax.lax.dynamic_update_slice(state["conv"], new_tail[None], (layer, 0, 0))
-        slot_ids = jnp.arange(Ba, dtype=jnp.int32)
-    else:
-        conv = state["conv"].at[layer, slot_ids].set(new_tail)
+    mixed, conv, slot_ids = conv_step(state["conv"], layer, slot_ids, live, proj, kp["conv_w"])
     q, k, v = _heads(cfg, mixed)
     g, beta, out_gate = _gates(cfg, kp, x)
     o, S = kda_decode_step(
         state["S"], layer, slot_ids, live, q, k, v, jnp.exp(g), beta,
         name=step_kernel_name(cfg))
     return _out(cfg, kp, o, out_gate, x.dtype), {"S": S, "conv": conv}
+
+
+def conv_step(tails, layer, slot_ids, live, proj, conv_w):
+    """One token of the causal depthwise convolution on the pool's tails
+    [Lk, slots, (taps-1) W]: (the convolution's output [Ba, W] before bias and
+    activation, the tails with `proj` [Ba, W] shifted in on the live rows, the
+    rows' slot ids). `slot_ids` None: row b is slot b, all of them."""
+    Ba, W = proj.shape
+    taps = conv_w.shape[0]
+    whole = slot_ids is None  # the full batch: the layer's tails are one block, no scatter
+    tail = (jax.lax.dynamic_index_in_dim(tails, layer, 0, keepdims=False) if whole
+            else tails[layer, slot_ids])  # [Ba, (taps-1) W]: a slot's rows end to end
+    # the taps as slices of the flat row, W a whole number of lanes: as
+    # [Ba, taps, W] every step re-laid the rows out twice (68 us a layer and
+    # step at Solar's width, 0.8 ms a round: v5e, PR 35)
+    full = jnp.concatenate([tail.astype(proj.dtype), proj], axis=-1)  # [Ba, taps W]
+    conv_w = conv_w.astype(proj.dtype)
+    mixed = sum(full[:, j * W : (j + 1) * W] * conv_w[j] for j in range(taps))
+    new_tail = jnp.where(live[:, None], full[:, W:].astype(tail.dtype), tail)
+    if whole:
+        # a scatter of 64 rows runs row after row on the chip (a seventh of the
+        # device in Olmo-Hybrid's cell, 15 layers x 4 steps a round); the block does not
+        conv = jax.lax.dynamic_update_slice(tails, new_tail[None], (layer, 0, 0))
+        slot_ids = jnp.arange(Ba, dtype=jnp.int32)
+    else:
+        conv = tails.at[layer, slot_ids].set(new_tail)
+    return mixed, conv, slot_ids
 
 
 def pool_rows(cfg: ModelConfig, S: jnp.ndarray) -> jnp.ndarray:

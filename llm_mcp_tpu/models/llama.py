@@ -260,6 +260,14 @@ def _sub_out(cfg: ModelConfig, y: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     return _norm(cfg, y, w) if cfg.norm_placement == "output" else y
 
 
+def _residual(cfg: ModelConfig, h: jnp.ndarray, out: jnp.ndarray) -> jnp.ndarray:
+    """A sub-layer's output onto the residual stream, times
+    `residual_multiplier` where the family has one (Granite)."""
+    if cfg.residual_multiplier != 1.0:
+        out = out * jnp.asarray(cfg.residual_multiplier, dtype=out.dtype)
+    return h + out
+
+
 def qk_norm_widths(cfg: ModelConfig) -> tuple[int, int]:
     """Lengths of a layer's q_norm and k_norm vectors: the whole projection
     (OLMo, `qk_norm_whole`) or one head (Qwen3)."""
@@ -333,7 +341,7 @@ def _attn_residual(
     out = qdot(ctx, lp["wo"])
     if cfg.post_norms:
         out = _norm(cfg, out, lp["post_attn_norm"])
-    return h + _sub_out(cfg, out, lp["attn_norm"])
+    return _residual(cfg, h, _sub_out(cfg, out, lp["attn_norm"]))
 
 
 @jax.named_scope("ffn")
@@ -375,7 +383,7 @@ def _ffn_residual(
         out = qdot(gate * up, lp["w2"])
     if cfg.post_norms:
         out = _norm(cfg, out, lp["post_ffn_norm"])
-    return h + _sub_out(cfg, out, lp["ffn_norm"])
+    return _residual(cfg, h, _sub_out(cfg, out, lp["ffn_norm"]))
 
 
 def layer_windows(cfg: ModelConfig) -> jnp.ndarray:
@@ -395,6 +403,8 @@ def _embed_in(cfg: ModelConfig, params: Params, tokens: jnp.ndarray) -> jnp.ndar
     h = embed_lookup(params["embed"], tokens)
     if cfg.embed_scale:
         h = h * jnp.asarray(cfg.dim**0.5, dtype=h.dtype)
+    if cfg.embed_multiplier != 1.0:
+        h = h * jnp.asarray(cfg.embed_multiplier, dtype=h.dtype)
     return h
 
 
@@ -402,7 +412,10 @@ def _embed_in(cfg: ModelConfig, params: Params, tokens: jnp.ndarray) -> jnp.ndar
 def _logits(cfg: ModelConfig, params: Params, h: jnp.ndarray) -> jnp.ndarray:
     h = _norm(cfg, h, params["final_norm"])
     src = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-    return _softcap(logits_head(src, h, tied=cfg.tie_embeddings), cfg.logit_softcap)
+    logits = logits_head(src, h, tied=cfg.tie_embeddings)
+    if cfg.logits_divisor != 1.0:
+        logits = logits / jnp.asarray(cfg.logits_divisor, dtype=logits.dtype)
+    return _softcap(logits, cfg.logit_softcap)
 
 
 def prefill_masks(

@@ -19,8 +19,8 @@ programs inside the profiler's slice beside the runs of `jit_admit_fn` in it,
 the slice's rounds by program name (`rounds`: runs and mean device ms of the
 plain `jit_decode_chunk_fn` and of the mixed `jit_mixed_round_fn`, the mixed
 ones by rung from the ring's `mixed` events in the slice, and the host's
-milliseconds a round over rounds of BOTH names, which
-`engine_host_ms_per_round` divides by the plain ones alone), the rows of EVERY
+milliseconds a round as `engine_host_ms_per_round` reads them, over rounds of
+BOTH names since PR 40), the rows of EVERY
 round of the window over the slots (`occupancy_ring`, from the ring's `emit`
 events: `decode_occupancy` reads one dispatch in 32 of a phase, about two
 dozen a window), the window's gaps of over 200 ms between two rounds'
@@ -128,9 +128,8 @@ def rounds_by_program(run: dict, tr: dict) -> dict | None:
                 by_rung.setdefault(f["padded_tokens"], []).append((b - a) / 1e6)
             out["mixed_by_rung"] = {k: [len(v), round(sum(v) / len(v), 3)] for k, v in sorted(by_rung.items())}
     by_reader = host_reader.read(run)
-    if by_reader is not None and plain:
-        out["host_ms_per_round"] = {"reader_plain_only": round(by_reader, 3),
-                                    "both_names": round(by_reader * len(plain) / (len(plain) + len(mixed)), 3)}
+    if by_reader is not None:  # since PR 40 the reader divides by the rounds of both names itself
+        out["host_ms_per_round"] = round(by_reader, 3)
     return out
 
 

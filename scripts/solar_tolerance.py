@@ -19,6 +19,11 @@ is served, and the served tokens are held
     python3 scripts/solar_tolerance.py --config tiny --seeds 2 --controls 1   # CPU smoke
     python3 scripts/solar_tolerance.py --config olmo-hybrid-7b-d20-bf16 --model tiny-olmo-hybrid ...
 
+`--prompt-bytes` above the engine's chunk (512 tokens) sends the prompt through
+the bucketed CHUNK program (`hybrid_prefill_chunk_batch`: the state carried from
+chunk to chunk through the pool), which the harness's own request, an admit
+program's, never reaches; the summary's `admit_by_shape` says which programs ran.
+
 Prints one JSON line a seed and a summary; writes both to chiprun_out/.
 """
 
@@ -45,6 +50,8 @@ def main() -> int:
     ap.add_argument("--seeds", type=int, default=96)
     ap.add_argument("--controls", type=int, default=24, help="seeds each control is read on")
     ap.add_argument("--first-seed", type=int, default=3200006000)
+    ap.add_argument("--prompt-bytes", type=int, default=0,
+                    help="the prompt's bytes instead of the configuration's reference_request")
     args = ap.parse_args()
 
     from benchmark import correctness, run as bench_run, trafficgen
@@ -71,6 +78,9 @@ def main() -> int:
         env["TPU_MODEL"], max_slots=int(env["TPU_MAX_SLOTS"]), max_seq_len=int(env["TPU_MAX_SEQ_LEN"]),
         dtype=jnp.bfloat16, kv_quant=env["TPU_KV_QUANT"], seed=int(config.get("weights_seed", 0)),
     ).start()
+    if args.prompt_bytes:
+        config = dict(config, reference_request=dict(
+            config.get("reference_request", {}), prompt_bytes=args.prompt_bytes))
     n_bytes, n_tokens = correctness.reference_request(config, gen.max_seq_len)
     mask = gen._allowed_mask
     allowed = np.arange(gen.cfg.vocab_size) if mask is None else np.flatnonzero(np.asarray(mask))
@@ -123,6 +133,7 @@ def main() -> int:
             print(json.dumps({"seed": lines[i]["seed"], lower: value, "refused": why}), flush=True)
     module.LOWER = None
     jax.clear_caches()
+    by_shape = dict(gen.perf_stats()["admit"]["by_shape"])
     gen.shutdown()
 
     def summary(key: str) -> dict:
@@ -133,6 +144,7 @@ def main() -> int:
 
     result = {"tolerance": float(module.SERVED_TOL_REL), "reference": name,
               "request": {"prompt_bytes": n_bytes, "tokens": n_tokens},
+              "admit_by_shape": by_shape,
               **{key: summary(key) for key in ("program", *controls)}}
     print("SUMMARY", json.dumps(result), flush=True)
     with open(os.path.join(ROOT, "chiprun_out", f"{name}_tolerance.json"), "w") as f:

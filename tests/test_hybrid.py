@@ -771,10 +771,26 @@ def test_state_kernel_in_the_pools_layout_is_the_reference_step(H, dk, dv, head_
     assert not untouched[1, 1].all() and not untouched[1, 4].all()
 
 
-def test_param_count_reckons_the_new_layer_kinds():
+@pytest.mark.parametrize("family", ["delta_rule", "state_space"])
+def test_param_count_reckons_the_new_layer_kinds(family):
     """ISSUE 35's arithmetic: a linear layer 88.7 M + MLP 126.8 M, a full layer
     59.0 M + 126.8 M, embedding and head 770.7 M: 7.43 B whole, 4.93 B at the
-    cut; Solar's count is what it was."""
+    cut; Solar's count is what it was. ISSUE 41's: a state-space layer
+    76,182,976 with its MLP and norms, an attention layer 60,821,504, the tied
+    table once: 3,191,396,096, to the parameter."""
+    if family == "state_space":
+        cfg = get_config("granite-4.0-h-micro")
+        mixer = 2048 * 8512 + (4 * 4352 + 4352) + 3 * 64 + 4096 + 4096 * 2048
+        mlp, norms = 3 * 2048 * 8192, 2 * 2048
+        assert mixer == 25_847_232 and mixer + mlp + norms == 76_182_976
+        attention = 2 * 2048 * 2048 + 2 * 2048 * 512
+        assert attention + mlp + norms == 60_821_504
+        assert cfg.param_count() == 3_191_396_096 == (
+            36 * 76_182_976 + 4 * 60_821_504 + 100_352 * 2048 + 2048)
+        tiny = get_config("tiny-granite-hybrid")
+        held = sum(x.size for x in jax.tree.leaves(init_llama_params(tiny, jax.random.PRNGKey(0))))
+        assert tiny.param_count() == held  # every leaf, the tied table once
+        return
     cut = get_config("olmo-hybrid-7b-d20")
     whole = dataclasses.replace(cut, n_layers=32, gqa_layers=tuple(range(3, 32, 4)))
     D, H, dk, dv, F, V = 3840, 30, 96, 192, 11_008, 100_352
@@ -856,5 +872,319 @@ def test_the_harness_comparison_passes_the_olmo_program_and_refuses_its_controls
                 assert correctness.hold_to_reference(olmo_ref, eng, ids, out)["worst_regret_rel"] < 0.2
     finally:
         olmo_ref.LOWER = None
+        jax.clear_caches()
+        eng.shutdown()
+
+
+# -- Granite-4.0-H: Mamba-2 state-space layers, four multipliers, heads of 64 ----------
+
+
+@pytest.fixture(scope="module")
+def granite_ref():
+    spec = importlib.util.spec_from_file_location(
+        "granite_hybrid", os.path.join(ROOT, "benchmark", "references", "granite_hybrid.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _unlike_ones(params, key=13):
+    """Norm weights and the state-space layers' skip away from one (the seeded
+    tree has ones, under which a norm over the wrong width, or a skip on the
+    wrong head, would still agree)."""
+    keys = iter(jax.random.split(jax.random.PRNGKey(key), 16))
+
+    def jitter(w):
+        return w * (1.0 + 0.3 * jax.random.normal(next(keys), w.shape, w.dtype))
+
+    params = dict(params, final_norm=jitter(params["final_norm"]))
+    params["layers"] = dict(params["layers"], attn_norm=jitter(params["layers"]["attn_norm"]),
+                            ffn_norm=jitter(params["layers"]["ffn_norm"]))
+    params["ssm"] = dict(params["ssm"], norm=jitter(params["ssm"]["norm"]),
+                         D=jitter(params["ssm"]["D"]))
+    return params
+
+
+@pytest.fixture(scope="module")
+def granite(granite_ref):
+    """(cfg, params, tokens [96], the reference's logits at every position) of
+    `tiny-granite-hybrid`: two periods of four state-space layers (a run the
+    program scans), an attention layer and one more state-space layer; 6 heads
+    (no multiple of 8) of 64 values, two abreast in the pool; one group."""
+    with jax.default_matmul_precision("highest"):
+        cfg = get_config("tiny-granite-hybrid")
+        params = _unlike_ones(init_llama_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32))
+        toks = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (96,), 3, 500))
+        want = granite_ref.logits(cfg, params, toks, np.arange(96), np.arange(cfg.vocab_size))
+    return cfg, params, toks, want
+
+
+# float32 against float32, of logits whose largest is about 0.05 (a table drawn
+# 12 times smaller, tied, and logits divided by 8): 1e-4 of that. The chunk form's
+# products and the reference's token-by-token sums differ by rounding alone.
+GRANITE_TOL = 5e-6
+
+
+def test_the_granite_reference_shares_no_code_with_the_program():
+    src = open(os.path.join(ROOT, "benchmark", "references", "granite_hybrid.py")).read()
+    assert "llm_mcp_tpu" not in src.split('"""', 2)[2]  # the docstring names the files
+
+
+def test_granite_full_prefill_of_rows_of_unlike_lengths(granite):
+    cfg, params, toks, want = granite
+    assert cfg.layer_period == ("ssm", "ssm", "ssm", "ssm", "gqa", "ssm")
+    # the period's attention layer is not at an end
+    assert 0.03 < np.max(np.abs(want)) < 0.08
+    batch = np.zeros((4, 64), np.int32)
+    lengths = [50, 30, 64, 1]
+    for i, n in enumerate(lengths):
+        batch[i, :n] = toks[:n]
+    logits, ks, vs = llama_prefill(cfg, params, jnp.asarray(batch), jnp.asarray(lengths))
+    for i, n in enumerate(lengths):
+        assert np.max(np.abs(np.asarray(logits[i]) - want[n - 1])) < GRANITE_TOL, (i, n)
+    assert ks.shape[0] == cfg.n_attn_layers == 2
+    # ten layers' states, 6 heads of [32 keys, 64 values] two abreast, and no expert counts
+    assert vs["state"]["S"].shape == (10, 4, 3, 32, 128) and "moe" not in vs
+    assert vs["state"]["conv"].shape == (10, 4, 3 * (6 * 64 + 2 * 32))
+
+
+@pytest.mark.parametrize("quantized,tol", [
+    (False, GRANITE_TOL),
+    # the attention layers' keys and values rounded to 8 bits with one scale a
+    # head and token: 1/254 of a head's largest value a number, which two
+    # attention layers of twelve bring to the logits as 2e-5 to 3e-5, 6e-4 of
+    # the largest (read here)
+    (True, 1e-4),
+], ids=["float32_cache", "int8_cache"])
+def test_granite_two_chunks_then_decode_through_cache_and_pool_in_a_reused_slot(
+        granite, granite_ref, quantized, tol):
+    cfg, params, toks, want = granite
+    cache = init_kv_cache(cfg, 4, 128, dtype=jnp.float32, quantized=quantized)
+    state = cache["v"]["state"]  # every slot was used: another sequence's leftovers
+    ck, cv = cache["k"], dict(cache["v"], state=dict(state, S=state["S"] + 7.0, conv=state["conv"] - 3.0))
+    assert set(cv) == {"v", "state"}
+    slot, S = 2, 128
+    for start, n in ((0, 32), (32, 18)):  # across a chunk's edge; the second ragged, padded to 32
+        chunk = np.zeros((1, 32), np.int32)
+        chunk[0, :n] = toks[start : start + n]
+        logits, ck, cv = llama_prefill_chunk_batch(
+            cfg, params, ck, cv, jnp.asarray(chunk), jnp.array([slot]), jnp.array([start]),
+            jnp.array([n]), skey=32)
+    assert np.max(np.abs(np.asarray(logits[0]) - want[49])) < tol
+    lens = np.full(4, S, np.int32)  # the other slots are parked
+    lens[slot] = 50
+    before = np.asarray(cv["state"]["S"][:, 0]), np.asarray(cv["state"]["conv"][:, 0])
+    got = []
+    for t in range(50, 58):  # the full batch, one live row
+        tok = np.zeros(4, np.int32)
+        tok[slot] = toks[t]
+        logits, ck, cv = llama_decode_step(cfg, params, ck, cv, jnp.asarray(tok), jnp.asarray(lens))
+        got.append(np.asarray(logits[slot]))
+        lens[slot] += 1
+    for t in range(58, 66):  # a compact batch: row 0 serves the slot, row 1 is a pad
+        logits, ck, cv = llama_decode_step(
+            cfg, params, ck, cv, jnp.array([toks[t], 0]), jnp.array([lens[slot], S]),
+            slot_ids=jnp.array([slot, 0]))
+        got.append(np.asarray(logits[0]))
+        lens[slot] += 1
+    miss = np.max(np.abs(np.stack(got) - want[50:66]), axis=-1)
+    assert miss.max() < tol, miss
+    assert np.array_equal(np.asarray(cv["state"]["S"][:, 0]), before[0])  # a parked row never moves
+    assert np.array_equal(np.asarray(cv["state"]["conv"][:, 0]), before[1])
+    if not quantized:
+        # tight enough that a state rounded to bfloat16 after every token fails it
+        granite_ref.LOWER = "state_bf16"
+        jax.clear_caches()
+        try:
+            lower = granite_ref.logits(cfg, params, toks, np.arange(50, 66), np.arange(cfg.vocab_size))
+        finally:
+            granite_ref.LOWER = None
+            jax.clear_caches()
+        assert np.max(np.abs(np.stack(got) - lower)) > 4 * tol
+
+
+@pytest.mark.parametrize("field,neutral", [
+    ("embed_multiplier", 1.0), ("residual_multiplier", 1.0), ("logits_divisor", 1.0),
+    ("attn_multiplier", 0.0),  # 0: the scores are scaled by head_dim**-0.5, as elsewhere
+])
+def test_each_of_the_four_multipliers_matters(granite, field, neutral):
+    """With one multiplier at its neutral value, prefill and a decode step
+    through cache and pool still agree with each other and miss the reference
+    by far more than rounding."""
+    cfg, params, toks, want = granite
+    assert getattr(cfg, field) != neutral
+    cfg = dataclasses.replace(cfg, **{field: neutral})
+    batch = jnp.asarray(toks[None, :33].astype(np.int32))
+    logits, _, _ = llama_prefill(cfg, params, batch, jnp.array([33]))
+    cache = init_kv_cache(cfg, 2, 128, dtype=jnp.float32)
+    _, ck, cv = llama_prefill_chunk_batch(
+        cfg, params, cache["k"], cache["v"], batch[:, :32], jnp.array([1]), jnp.array([0]),
+        jnp.array([32]), skey=32)
+    stepped, _, _ = llama_decode_step(
+        cfg, params, ck, cv, jnp.array([toks[32]]), jnp.array([32]), slot_ids=jnp.array([1]))
+    assert np.max(np.abs(np.asarray(logits[0]) - np.asarray(stepped[0]))) < 10 * GRANITE_TOL
+    assert np.max(np.abs(np.asarray(stepped[0]) - want[32])) > 100 * GRANITE_TOL
+
+
+def test_the_chunk_form_without_the_delta_rule_is_the_token_by_token_recurrence():
+    """Mamba-2's chunk form: `kda_chunk_scan` with no beta (U = V, no system
+    solved) and keys and queries ONE group for every head, across a chunk's edge
+    (64 positions in chunks of 32) and with padding behind row 1's 41 positions."""
+    A, T, H, N, P = 2, 64, 3, 16, 24
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    C, B = (jax.random.normal(ks[i], (A, T, N)) for i in range(2))
+    nvalid = jnp.array([64, 41])
+    valid = jnp.arange(T)[None, :] < nvalid[:, None]
+    v = jnp.where(valid[..., None, None], jax.random.normal(ks[2], (A, T, H, P)), 0.0)
+    # decays from almost none to e**-12 a step, none at a padding position
+    g = jnp.where(valid[..., None], -jnp.exp(jax.random.uniform(ks[3], (A, T, H), minval=-7.0, maxval=2.5)), 0.0)
+    S = S0 = jax.random.normal(ks[4], (A, H, N, P))
+    outs, ends = [], {}
+    for t in range(T):
+        S = S * jnp.exp(g[:, t])[..., None, None] + B[:, t][:, None, :, None] * v[:, t][:, :, None, :]
+        outs.append(jnp.einsum("ak,ahkv->ahv", C[:, t], S))
+        ends[t + 1] = S
+    scan = jax.jit(lambda C, B, v, g, S0: kda_chunk_scan(C[:, :, None], B[:, :, None], v, g, None, S0))
+    o, S_end = scan(C, B, v, g, S0)
+    want = np.asarray(jnp.stack(outs, 1))
+    assert np.isfinite(np.asarray(o)).all()
+    assert np.max(np.abs(np.where(valid[..., None, None], np.asarray(o) - want, 0.0))) < 1e-4
+    assert np.max(np.abs(np.asarray(S_end[0]) - np.asarray(ends[64][0]))) < 1e-4
+    assert np.max(np.abs(np.asarray(S_end[1]) - np.asarray(ends[41][1]))) < 1e-4  # padding moved nothing
+    text = str(jax.make_jaxpr(scan)(C, B, v, g, S0))
+    assert "triangular_solve" not in text and f"{A},{T},{H},{N}]" not in text  # no solve, no copy a head
+
+
+@pytest.mark.parametrize("H,N,P,abreast", [
+    (64, 128, 64, 2),  # Granite-4.0-H: the pool's tile [.., 32, 128, 128]
+    (6, 32, 64, 2),  # the tiny preset: heads off 8, three tiles a row
+    (5, 16, 24, 1),  # no count of heads makes whole lanes: one head a tile
+], ids=["granite_64x128x64", "tiny_6x32x64", "odd_5x16x24"])
+def test_state_kernel_without_the_delta_rule_is_the_plain_step(H, N, P, abreast):
+    """`ssd_decode_step`: the pool's kernel body with the correction left out
+    and B, C one row a batch row, in interpret mode against the step by hand;
+    live rows stepped in place, parked and padding rows untouched."""
+    from llm_mcp_tpu.kernels.kda import heads_abreast, pack_state, unpack_state
+
+    Lk, slots, Ba = 2, 6, 4
+    assert heads_abreast(H, P) == abreast
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    head_major = jax.random.normal(ks[0], (Lk, slots, H, N, P))
+    state = pack_state(head_major, abreast)
+    assert state.shape == (Lk, slots, H // abreast, N, abreast * P)
+    C, B = (jax.random.normal(ks[i], (Ba, N)) for i in (1, 2))
+    v = jax.random.normal(ks[3], (Ba, H, P))
+    alpha = jax.nn.sigmoid(jax.random.normal(ks[4], (Ba, H)))
+    ids = jnp.array([4, 1, 5, 5])  # two pads on one free row
+    live = jnp.array([True, True, False, False])
+    o_k, s_k = kda_decode_step(state, jnp.int32(1), ids, live, C, B, v, alpha,
+                               name="ssd_decode_step", interpret=True)
+    o_r, s_r = kda_decode_step_reference(state, jnp.int32(1), ids, live, C, B, v, alpha)
+    for row in (0, 1):  # the step itself, head-major, by hand
+        S = (head_major[1, ids[row]] * alpha[row][:, None, None]
+             + B[row][None, :, None] * v[row][:, None, :])
+        assert np.allclose(np.asarray(unpack_state(s_k, abreast)[1, ids[row]]), np.asarray(S), atol=1e-5)
+        o = jnp.einsum("k,hkv->hv", C[row], S, precision="highest")
+        assert np.allclose(np.asarray(o_k[row]), np.asarray(o), rtol=1e-4, atol=1e-4)
+    assert np.allclose(np.asarray(o_r[:2]), np.asarray(o_k[:2]), rtol=1e-4, atol=1e-4)
+    assert np.allclose(np.asarray(s_r), np.asarray(s_k), rtol=1e-5, atol=1e-5)
+    untouched = np.asarray(s_k) == np.asarray(state)
+    assert untouched[0].all() and untouched[1, [0, 2, 3, 5]].all()  # a parked row unmoved
+    assert not untouched[1, 1].all() and not untouched[1, 4].all()
+
+
+@pytest.mark.parametrize("quantized", [True, False], ids=["int8", "bf16"])
+def test_the_append_kernel_takes_heads_of_64_with_no_fall(quantized):
+    """Head size 64 (Granite-4.0-H's attention): the append is the tile-rewrite
+    kernel, its step rows repeated down the tile, and not the scatter it fell
+    to; parked rows write nothing. (Mosaic's acceptance of the shape is
+    tests/test_tpu_compile.py -k granite.)"""
+    from llm_mcp_tpu.kernels import attention as A
+
+    L, slots, Hkv, S, hd, Ba = 2, 5, 2, 128, 64, 3
+    cfg = dataclasses.replace(get_config("tiny-llm"), n_layers=L, n_kv_heads=Hkv, head_dim=hd)
+    cache = init_kv_cache(cfg, slots, S, dtype=jnp.float32, quantized=quantized)
+    ks = jax.random.split(jax.random.PRNGKey(9), 2)
+    new_k, new_v = (jax.random.normal(k, (L, Ba, Hkv, hd)) for k in ks)
+    lengths, ids = jnp.array([5, 127, S]), jnp.array([3, 0, 1])  # the third row is parked
+    append = A.append_kv_q8 if quantized else A.append_kv_bf16
+    reference = A.append_kv_q8_reference if quantized else A.append_kv_bf16_reference
+
+    def call(ck, cv):
+        return append(ck, cv, new_k, new_v, lengths, slot_ids=ids, interpret=True)
+
+    assert "pallas_call" in str(jax.make_jaxpr(call)(cache["k"], cache["v"]))
+    got = call(cache["k"], cache["v"])
+    want = reference(cache["k"], cache["v"], new_k, new_v, lengths, slot_ids=ids)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
+    first = jax.tree.leaves(got)[0]
+    assert np.asarray(first[:, 3, :, 5]).any() and not np.asarray(first[:, 1]).any()
+
+
+@pytest.fixture(scope="module")
+def granite_engine():
+    from llm_mcp_tpu.executor import GenerationEngine
+
+    eng = GenerationEngine("tiny-granite-hybrid", max_slots=2, max_seq_len=128, dtype=jnp.float32,
+                           prefill_chunk=32, prompt_cache_mb=64, kv_quant="int8").start()
+    yield eng
+    eng.shutdown()
+
+
+def test_granite_engine_serves_the_references_choice_whole_and_chunked(granite_engine, granite_ref):
+    """Whole-prompt admission (under the engine's chunk of 32), a chunked
+    prefill carried across ENGINE chunks (over it), and again in used slots,
+    through the int8 cache; the pool's book follows the new state's layout."""
+    eng = granite_engine
+    allowed = np.flatnonzero(np.asarray(eng._allowed_mask))
+    prompts = ["amber basil", "x" * 70 + " cedar dune ember", "y" * 45, "fjord grove " * 6]
+    for prompt in prompts:
+        ids, out = _serve(eng, prompt)
+        seq = ids + out[:-1]
+        rows = np.arange(len(ids) - 1, len(seq))
+        seq = np.asarray(seq + [0] * (-len(seq) % 32), np.int32)
+        want = granite_ref.logits(eng.cfg, eng.params, seq, rows, allowed)
+        for k, tok in enumerate(out):
+            regret = float(np.max(want[k]) - want[k, np.flatnonzero(allowed == tok)[0]])
+            assert regret < 1e-3, (prompt[:12], k, regret)
+    assert "chunk" in {r["phase"] for r in eng._ledger.table()}
+    stats = eng.perf_stats()
+    pool = stats["state_pool"]
+    assert "experts" not in stats and eng._experts is None
+    assert (eng.state_dtype, eng.weights_dtype) == ("float32", "float32")
+    cfg = eng.cfg
+    width = cfg.ssm_heads * cfg.ssm_head_dim + 2 * cfg.ssm_state
+    logical = 10 * 2 * (cfg.ssm_heads * cfg.ssm_state * cfg.ssm_head_dim * 4 + 3 * width * 4)
+    assert pool["bytes"] == logical == pool["bytes_per_slot"] * 2
+    assert pool["layout"] == {"S": [10, 2, 3, 32, 128], "conv": [10, 2, 3 * width]}
+    assert pool["admitted_total"] == len(prompts) and pool["live_slots"] == 0
+    assert not any(eng._runs(f) for f in ("prefix_cache", "offload", "migration", "speculation",
+                                           "ragged_prefill"))
+    assert pool["off"]["mixed_round"] > 0  # every admission took a program of its own
+
+
+def test_the_harness_comparison_passes_the_granite_program_and_refuses_its_controls(granite_ref):
+    """scripts/solar_tolerance.py's readings at the tiny size, through
+    `correctness.hold_to_reference`: the served tokens are the reference's own
+    choice; held to the reference computed in float8, or with the first
+    state-space layer's state lost, they are not correct."""
+    from benchmark import correctness
+    from llm_mcp_tpu.executor import GenerationEngine
+
+    eng = GenerationEngine("tiny-granite-hybrid", max_slots=2, max_seq_len=256, dtype=jnp.float32).start()
+    try:
+        ids, out = _serve(eng, "hold these sixteen tokens to the plain forward, " * 2, n=16)[:2]
+        assert correctness.hold_to_reference(granite_ref, eng, ids, out)["worst_regret_rel"] < 1e-3
+        for lower in granite_ref.CONTROLS:
+            granite_ref.LOWER = lower
+            jax.clear_caches()
+            if lower in ("fp8", "lost_state"):
+                with pytest.raises(AssertionError, match="under the reference's choice"):
+                    correctness.hold_to_reference(granite_ref, eng, ids, out)
+            else:
+                assert correctness.hold_to_reference(granite_ref, eng, ids, out)["worst_regret_rel"] < 0.2
+    finally:
+        granite_ref.LOWER = None
         jax.clear_caches()
         eng.shutdown()

@@ -592,17 +592,71 @@ def test_olmo_hybrid_step_programs_fit_beside_64_slots(sd, olmo, chip_kernels, w
     assert mem.alias_size_in_bytes > 4.3 * 2**30  # KV cache and state pool updated in place
 
 
+@pytest.fixture(scope="module")
+def granite(one_chip):
+    """`granite-4.0-h-micro`, whole, 64 slots x 1024, as its cell boots it."""
+    return hybrid_shapes("granite-4.0-h-micro", one_chip, SOLAR_SLOTS, SOLAR_S)
+
+
+@pytest.mark.parametrize("which,operands", [
+    ("decode", [(64,), (64,), (64,)]),  # every slot a row
+    ("admit", [(4, 128), (4,), (4,)]),  # the cell's largest admit program, 512 padded tokens
+    ("admit", [(1, 64), (1,), (1,)]),  # and its smallest
+    ("chunk", [(2, 512), (2,), (2,), (2,)]),  # two prompts' second chunks of 512
+])
+def test_granite_step_programs_fit_beside_64_slots(sd, granite, chip_kernels, which, operands):
+    """The decode round, two admit programs and a chunk program of
+    `granite-4.0-h-micro` at its cell's 64 slots x 1024 compile for the
+    described v5e with their kernels: `ssd_decode_step` on the pool's
+    [36, 64, 32, 128, 128] (two heads of 64 values abreast), the decode attention
+    (the whole-S arm: a head of 64 lies padded to 128 lanes in HBM, which the
+    blocked arm's copies cannot cut) and the append kernel at head size 64, as
+    Mosaic calls with no fall to their reference; the flash prefill kernel in the
+    admit programs. Each fits under 15.0 GiB and updates the KV cache and the
+    4.5 GiB state pool in place; the pool's bytes as the compiler lays it out are
+    its logical bytes. GiB in PERF.md section 4 as "described-chip compile"."""
+    from llm_mcp_tpu.models import ssm
+
+    cfg, params, cache = granite
+    falls = dict(A.reference_falls)
+    compiled = jax.jit(solar_program(which, cfg), donate_argnums=(1, 2)).lower(
+        params, cache["k"], cache["v"], *(sd(shape, I32) for shape in operands)).compile()
+    assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (which != "chunk")  # as Olmo-Hybrid's: PERF.md section 7
+    assert ("%ssd_decode_step" in text) == (which == "decode")
+    assert "%kda_decode_step" not in text and "%gdn_decode_step" not in text
+    if which == "decode":
+        assert "decode_attn_q8_whole" in text and "append_kv_q8" in text
+        assert "decode_attn_q8_blocked" not in text
+    if which == "admit":
+        assert "flash_prefill_attn" in text
+    S = cache["v"]["state"]["S"]
+    assert S.shape == (36, 64, 32, 128, 128) and ssm.state_abreast(cfg) == 2
+    assert cache["k"]["q"].shape == (4, 64, 17, 1024, 64)
+    pool = jax.jit(lambda s: s + 1.0).lower(S).compile().memory_analysis()
+    assert pool.argument_size_in_bytes == 36 * 64 * 64 * 128 * 64 * 4
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"granite {which} {operands[0]}: {total / 2**30:.2f} GiB, of it temporaries "
+          f"{mem.temp_size_in_bytes / 2**30:.2f} GiB, arguments "
+          f"{mem.argument_size_in_bytes / 2**30:.2f} GiB")
+    assert total < 15.0 * 2**30
+    assert mem.alias_size_in_bytes > 4.5 * 2**30  # KV cache and state pool updated in place
+
+
 def test_a_fall_to_the_reference_is_counted(tmp_path):
     """What `compile_for_chip` and chip_smoke.py's zero-fall check stand on: a
     shape gate that fails with interpret=False is counted and lands in the
     flight recorder; interpret mode, which takes the exact math by design, is
-    not. hd=64 is not lane-aligned, so append_kv_q8 takes its scatter. The
+    not. hd=32 is no row the append kernel can store, so append_kv_q8 takes its scatter. The
     fall is made on purpose, into a recorder of the test's own: the process's
     ring keeps no `kernel_fall` for chip_smoke's check to find when one worker
     runs both files."""
     from llm_mcp_tpu.telemetry import recorder as flight
 
-    n, hd = 4, 64
+    n, hd = 4, 32
     ck = {"q": jax.ShapeDtypeStruct((2, n, 2 * HKV + 1, 128, hd), I8),
           "s": jax.ShapeDtypeStruct((2, n, 2 * HKV, 128), BF)}
     new = jax.ShapeDtypeStruct((2, n, HKV, hd), BF)
