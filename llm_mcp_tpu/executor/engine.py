@@ -59,6 +59,7 @@ from ..kernels.attention import (
 from ..utils.faults import maybe_fail
 from ..utils.platform import on_tpu
 from ..models.configs import ModelConfig, resolve_config
+from ..models.kda import CHUNK as RECURRENCE_CHUNK
 from ..models.moe import share_form
 from ..models.weights import load_llama_checkpoint
 from ..models.llama import (
@@ -1318,7 +1319,18 @@ class GenerationEngine:
         # why the configuration keeps admit_fn (_ride_off; None = not asked
         # yet), and what stood against a ride in the loop's current iteration
         self._ride_rungs = tuple(r for r in self.RIDE_RUNGS if r <= max_seq_len)
+        if self.cfg.recurrent:
+            # the largest alone: `hybrid_mixed_step` runs the recurrence over the
+            # chunks that hold tokens whatever the rung, so a smaller rung saves
+            # the padding rows' share of the stacked products and no more, and
+            # costs a second executable of a period of unlike layers to trace
+            # and lower at its first ride while every stream waits (both
+            # measured: PERF.md section 6, PR 42)
+            self._ride_rungs = self._ride_rungs[-1:]
         self._ride_rows = 1 << max(0, self.admit_batch - 1).bit_length()
+        # a riding prompt starts at a multiple of this in the packed buffer: the
+        # recurrence's chunk where there is one, which resets at a chunk's start
+        self._ride_align = RECURRENCE_CHUNK if self.cfg.recurrent else 1
         self._ride_why: str | None = None
         self._ride_state = "other"
 
@@ -2138,6 +2150,16 @@ class GenerationEngine:
 
             return step
 
+        def with_counts(out, cv):
+            """The expert layer's running counts ride the round's one fetch
+            as rows behind the K rows of tokens [K, Ba] (_complete_round)."""
+            if not (isinstance(cv, dict) and "moe" in cv):
+                return out
+            Ba = out.shape[1]
+            moe = cv["moe"].reshape(-1)
+            moe = jnp.pad(moe, (0, -moe.shape[0] % Ba)).reshape(-1, Ba)
+            return jnp.concatenate([out, moe.astype(out.dtype)])
+
         def decode_body(params, ck, cv, packed, d_temp, d_topk, d_topp,
                         d_last, compact, paged=None):
             """One decode round (K fused steps) — traced body shared by
@@ -2183,13 +2205,7 @@ class GenerationEngine:
                 d_last = d_last.at[slot_ids].set(last)
             else:
                 d_last = last
-            if isinstance(cv, dict) and "moe" in cv:
-                # the expert layer's running counts ride the round's one
-                # fetch as rows behind the K rows of tokens (_complete_round)
-                moe = cv["moe"].reshape(-1)
-                moe = jnp.pad(moe, (0, -moe.shape[0] % Ba)).reshape(-1, Ba)
-                out = jnp.concatenate([out, moe.astype(out.dtype)])
-            return out, ck, cv, d_last  # out: [K, Ba]
+            return with_counts(out, cv), ck, cv, d_last  # out: [K, Ba]
 
         @partial(jax.jit, donate_argnums=(1, 2, 7), static_argnames=("compact",),
                  **self._shard_out(["repl", "k", "v", "repl"]))
@@ -2253,6 +2269,11 @@ class GenerationEngine:
             )
             return out, p_logits, ck, cv, d_last
 
+        if cfg.recurrent:
+            from ..models.hybrid import hybrid_mixed_step as mixed_step
+        else:
+            mixed_step = mixed_step_q8
+
         @partial(jax.jit, donate_argnums=(1, 2, 4, 5, 6, 7),
                  **self._shard_out(["repl", "repl", "k", "v", "repl", "repl",
                                    "repl", "repl"]))
@@ -2260,8 +2281,10 @@ class GenerationEngine:
                            d_last, p_tokens, p_rowids, p_positions, ipack,
                            fpack, paged=None):
             """A full-batch decode round whose FIRST step carries admitted
-            prompts through its pass over the weights (`mixed_step_q8`); the
-            other K - 1 steps are `decode_chunk_fn`'s. What `admit_fn` followed
+            prompts through its pass over the weights (`mixed_step_q8`, or
+            `hybrid_mixed_step` for a stack with recurrent layers: the
+            configuration decides, where this is traced); the other K - 1
+            steps are `decode_chunk_fn`'s. What `admit_fn` followed
             by a plain round gives, for one weight pass less: the prompts'
             rows land in their slots, their sampling parameters and first
             tokens are written where `admit_fn` writes them (the slots are
@@ -2279,7 +2302,7 @@ class GenerationEngine:
             Ba = packed.shape[0] - 1
             lengths = packed[:Ba]
             rng = jax.random.fold_in(base_key, packed[-1])
-            logits, ck, cv = mixed_step_q8(
+            logits, ck, cv = mixed_step(
                 cfg, params, ck, cv, d_last, lengths, p_tokens, p_rowids,
                 p_positions, slots, last_idx, paged=paged,
             )
@@ -2305,7 +2328,7 @@ class GenerationEngine:
             d_topk = d_topk.at[row].set(topks)
             d_topp = d_topp.at[row].set(topps)
             d_last = new.at[row].set(toks0)
-            return out, toks0, ck, cv, d_temp, d_topk, d_topp, d_last
+            return with_counts(out, cv), toks0, ck, cv, d_temp, d_topk, d_topp, d_last
 
         return decode_chunk_fn, fused_step_fn, fused_ragged_fn, mixed_round_fn
 
@@ -2561,7 +2584,9 @@ class GenerationEngine:
         """Whether this configuration runs `feature`. All of them without a
         recurrent state pool; with one, all but those `memory.RECURRENT_OFF`
         names (the one list: its reasons are logged where the pool is built,
-        and the pool counts the times each would have engaged)."""
+        and the pool counts the times each would have engaged). A mixed round
+        is not among them: a recurrent configuration's admissions ride too
+        (`hybrid_mixed_step`)."""
         return self._state_pool is None or feature not in RECURRENT_OFF
 
     @property
@@ -4923,17 +4948,14 @@ class GenerationEngine:
 
     def _ride_off(self) -> str:
         """Why this configuration's admissions never ride a decode round
-        (`mixed_round_fn`), "" where they may: `recurrent` (a state pool: a
-        prompt there is a chunked recurrence, not rows of a matmul;
-        `memory.RECURRENT_OFF`), `other` (the decode step is not
-        `_decode_step_q8` on one chip: a mesh, a bf16 or latent cache, the XLA
-        path, routed experts, sliding windows)."""
+        (`mixed_round_fn`), "" where they may: `other` (the decode step is
+        neither `_decode_step_q8` nor `hybrid_decode_step` on one chip with the
+        int8 cache: a mesh, a bf16 or latent cache, the XLA path, routed experts
+        or sliding windows in the dense family, a cache shorter than a rung)."""
         if self._ride_why is None:
-            if not self._runs("mixed_round"):
-                self._ride_why = "recurrent"
-            elif (self.mesh is not None or self._spmd or self.sp != 1
-                  or self.kv_quant != "int8" or self.decode_impl != "pallas"
-                  or not mixed_step_supported(self.cfg) or not self._ride_rungs):
+            if (self.mesh is not None or self._spmd or self.sp != 1
+                    or self.kv_quant != "int8" or self.decode_impl != "pallas"
+                    or not mixed_step_supported(self.cfg) or not self._ride_rungs):
                 self._ride_why = "other"
             else:
                 self._ride_why = ""
@@ -4967,17 +4989,23 @@ class GenerationEngine:
         prefix serves it."""
         if self._constrain is not None and (req.constraint or req.logit_bias):
             return False
-        if self._exports_after_prefill(req) or len(ids) > self._ride_rungs[-1]:
+        if self._exports_after_prefill(req) or self._ride_len(ids) > self._ride_rungs[-1]:
             return False
         if self.prefill_chunk and len(ids) > self.prefill_chunk:
             return False
         return self._match_prefix(ids, count=False) is None
 
+    def _ride_len(self, ids: list[int]) -> int:
+        """The positions a riding prompt takes of a rung: its tokens, up to
+        the next multiple of `_ride_align`."""
+        return -(-len(ids) // self._ride_align) * self._ride_align
+
     def _stage_ride(self) -> _Ride | None:
         """Stage the queue's next whole prompts to ride the full-batch round
         about to be dispatched: up to `admit_batch` of them, as many as fit
         the largest rung (the rest lead the next round's batch; the queue's
-        order is kept). None when nothing may ride now."""
+        order is kept), each from a multiple of `_ride_align` on, the
+        positions between them padding. None when nothing may ride now."""
         cap = self._ride_rungs[-1]
         batch: list[tuple[int, GenRequest, list[int]]] = []
         reserved: set[int] = set()
@@ -4997,12 +5025,12 @@ class GenerationEngine:
                 self._push_back(req)  # an admit program of its own (_admit_pending)
                 held_by = "own"
                 break
-            if total + len(ids) > cap:
+            if total + self._ride_len(ids) > cap:
                 self._push_back(req)
                 held_by = "budget"
                 break
             self.prefix_cache_misses += bool(self._prefix_budget and self._prefix_cache)
-            total += len(ids)
+            total += self._ride_len(ids)
             reserved.add(slot)
             batch.append((slot, req, ids))
         if not batch:
@@ -5028,9 +5056,9 @@ class GenerationEngine:
             tokens[at : at + n] = ids
             rowids[at : at + n] = i
             positions[at : at + n] = np.arange(n)
-            at += n
             ipack[i] = slot
-            ipack[R + i] = at - 1
+            ipack[R + i] = at + n - 1
+            at += self._ride_len(ids)
             ipack[2 * R + i] = req.top_k
             fpack[i] = req.temperature
             fpack[R + i] = req.top_p
@@ -5062,7 +5090,7 @@ class GenerationEngine:
         if any(req.cn is not None or self._exports_after_prefill(req)
                for _, req, _ in batch):
             return "reads at once"
-        if any(len(ids) > self._ride_rungs[-1] for _, _, ids in batch):
+        if any(self._ride_len(ids) > self._ride_rungs[-1] for _, _, ids in batch):
             return "over the cap"
         return self._ride_state
 
@@ -6672,7 +6700,14 @@ class GenerationEngine:
                 [self._lengths, [self._next_counter()]]
             ).astype(np.int32)
         base = self._lengths.copy()
-        self._note_expert_form("decode", Ba, self.decode_chunk)
+        if ride is None:
+            self._note_expert_form("decode", Ba, self.decode_chunk)
+        else:
+            # the first step's expert layers are traced at the decode rows and
+            # the rung stacked, and are one call of each phase
+            self._note_expert_form("decode", Ba + ride.rung)
+            self._note_expert_form("prefill", Ba + ride.rung)
+            self._note_expert_form("decode", Ba, self.decode_chunk - 1)
         if self._attn_stream is not None:
             self._attn_stream.dispatched(packed[:Ba], self.decode_chunk)
         if group is not None:

@@ -332,9 +332,14 @@ RECURRENT_OFF = {
     "speculation": "rejected drafts roll the KV cache back by arithmetic; a state cannot be",
     "ragged_prefill": "a packed chunk holds several prompts' tokens in one row of the scan; "
                       "the chunked recurrence carries one state a row",
-    "mixed_round": "a prompt riding a decode step's weight pass is rows of a matmul; "
-                   "here it is a chunked recurrence (counts the admit programs taken)",
 }
+# What the pool counts beside them, in the same block (`off`), that is NOT off:
+# whole prompts ride a decode round's weight pass in a recurrent configuration
+# too (models/hybrid.py: hybrid_mixed_step), and "mixed_round" counts the admit
+# programs such a configuration still takes of its own (engine._own_reason says
+# why each: no active rows, a compact round, a first token read at once, a
+# prompt over the largest rung).
+POOL_COUNTS = ("mixed_round",)
 
 
 class StatePool:
@@ -349,7 +354,8 @@ class StatePool:
     prompt's prefill writes the row outright, a chunked prefill's first chunk
     (start 0) never reads it. The pool counts what a per-layer metric reads:
     its bytes (`layout`: each member's shape, whose product times the item
-    size they are), the slots alive, and the features it keeps off (`off`)."""
+    size they are), the slots alive, and the features it keeps off (`off`,
+    with `POOL_COUNTS` beside them)."""
 
     def __init__(self, *, max_slots: int, nbytes: int, layout: dict[str, list[int]] | None = None):
         self.max_slots = int(max_slots)
@@ -357,7 +363,7 @@ class StatePool:
         self.layout = dict(layout or {})
         self.bytes_per_slot = self.nbytes // max(1, self.max_slots)
         self.admitted_total = 0
-        self.off = dict.fromkeys(RECURRENT_OFF, 0)
+        self.off = dict.fromkeys((*RECURRENT_OFF, *POOL_COUNTS), 0)
 
     def note_off(self, feature: str) -> None:
         self.off[feature] += 1

@@ -61,17 +61,24 @@ Params = dict[str, Any]
 
 # A recurrent layer kind's functions, under one set of names: its key in the
 # parameter tree is the kind's name. `scope` names the decode layer's
-# `jax.named_scope` (the chunk form opens `<scope>_prefill` itself).
+# `jax.named_scope` (the chunk form opens `<scope>_prefill` itself). `prefill`
+# and `decode` are the whole layer for rows of one sort; a mixed step, whose rows
+# are of both, composes it from the parts they are made of (models/kda.py says
+# which part is a product over rows and which is a row's own).
 _RECURRENT = {
     "kda": SimpleNamespace(
         init_params=kda.init_kda_params, init_state=kda.init_kda_state,
         prefill=kda.kda_prefill, decode=kda.kda_decode, zero_state=kda.zero_state,
         pool_rows=kda.pool_rows, head_major=kda.head_major,
+        project=kda.project, operands=kda.operands, step_rows=kda.step_rows,
+        scan_packed=kda.scan_packed, output=kda.output,
         taps=lambda cfg: cfg.lin_conv, scope=lambda cfg: cfg.lin_gates),  # "kda" | "gdn"
     "ssm": SimpleNamespace(
         init_params=ssm.init_ssm_params, init_state=ssm.init_ssm_state,
         prefill=ssm.ssm_prefill, decode=ssm.ssm_decode, zero_state=ssm.zero_state,
         pool_rows=ssm.pool_rows, head_major=ssm.head_major,
+        project=ssm.project, operands=ssm.operands, step_rows=ssm.step_rows,
+        scan_packed=ssm.scan_packed, output=ssm.output,
         taps=lambda cfg: cfg.ssm_conv, scope=lambda cfg: "ssd"),
 }
 
@@ -178,9 +185,10 @@ def _at(tree, i):
     return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), tree)
 
 
-def _ffn(cfg: ModelConfig, lp: Params, banks: Params, li, h: jnp.ndarray, valid):
+def _ffn(cfg: ModelConfig, lp: Params, banks: Params, li, h: jnp.ndarray, valid, prompt=None):
     """Feed-forward of layer `li` and residual add on [..., D]: (h, counts [5]
-    of the expert layer, None for the dense gated MLP)."""
+    of the expert layer, None for the dense gated MLP); with `prompt` [N] (a
+    mixed step's rows: which are a prompt's) the counts of each phase, [2, 5]."""
     from .llama import _ffn_residual, _residual, _sub_in, _sub_out
 
     if not cfg.n_experts:
@@ -189,7 +197,8 @@ def _ffn(cfg: ModelConfig, lp: Params, banks: Params, li, h: jnp.ndarray, valid)
         x = _sub_in(cfg, h, lp["ffn_norm"])
         y, counts = moe_share_ffn(
             cfg, lp, x.reshape(-1, x.shape[-1]),
-            valid=None if valid is None else valid.reshape(-1), banks=banks, layer=li)
+            valid=None if valid is None else valid.reshape(-1), banks=banks, layer=li,
+            prompt=prompt)
         return _residual(cfg, h, _sub_out(cfg, y.reshape(h.shape), lp["ffn_norm"])), counts
 
 
@@ -199,13 +208,14 @@ def _counted(cache_v: dict, phase: int, counts) -> dict:
     return {} if counts is None else {"moe": cache_v["moe"].at[phase].add(counts)}
 
 
-def _period_scan(cfg: ModelConfig, params: Params, h, carry, gqa_layer, rec_layer, valid):
+def _period_scan(cfg: ModelConfig, params: Params, h, carry, gqa_layer, rec_layer, valid,
+                 prompt=None):
     """Scan the periods. `gqa_layer(h, carry, lp, ig)` and `rec_layer(h,
     carry, lp, ik)` run one layer's mixing half on the running `carry` (the
     caches, as the caller shapes it), `ig` / `ik` being the layer's index
     among its kind; the feed-forward follows either. Returns (h, carry,
-    counts [L, 5] of the expert layers or None), and whatever the GQA layers
-    stacked as ys, [P ng, ...]."""
+    counts [L, 5] of the expert layers ([L, 2, 5] with `prompt`: `_ffn`) or
+    None), and whatever the GQA layers stacked as ys, [P ng, ...]."""
     period, P, ng, nk = _layout(cfg)
     rec_params = params[cfg.recurrent_kind]
     banks = {k: params["layers"][k] for k in BANKS if k in params["layers"]}
@@ -225,7 +235,7 @@ def _period_scan(cfg: ModelConfig, params: Params, h, carry, gqa_layer, rec_laye
             else:
                 h, carry = rec_layer(h, carry, {**lp, **_at(rec_params, p * nk + ik)}, p * nk + ik)
                 ik += 1
-            h, n = _ffn(cfg, lp, banks, li, h, valid)
+            h, n = _ffn(cfg, lp, banks, li, h, valid, prompt)
             counts.append(n)
         ys = jax.tree.map(lambda *a: jnp.stack(a), *ys) if ys and ys[0] is not None else None
         return (h, carry, p + 1), (ys, jnp.stack(counts) if cfg.n_experts else None)
@@ -234,7 +244,8 @@ def _period_scan(cfg: ModelConfig, params: Params, h, carry, gqa_layer, rec_laye
         body, (h, carry, jnp.int32(0)), None, length=P)
     if ys is not None:
         ys = jax.tree.map(lambda a: a.reshape(P * ng, *a.shape[2:]), ys)
-    return h, carry, None if counts is None else counts.reshape(cfg.n_layers, 5), ys
+    return h, carry, None if counts is None else counts.reshape(
+        cfg.n_layers, *counts.shape[2:]), ys
 
 
 def hybrid_prefill(cfg, params, tokens, lengths, attn_impl="xla", quant_kv=False):
@@ -402,3 +413,132 @@ def hybrid_decode_step(cfg, params, cache_k, cache_v, tokens, lengths, slot_ids=
         new_k, new_kv_v = append(cache_k, kv_v, knew, vnew, lengths, slot_ids=slot_ids)
     return _logits(cfg, params, h), new_k, {
         "v": new_kv_v, "state": lin, **_counted(cache_v, 0, counts)}
+
+
+def hybrid_mixed_step(
+    cfg, params, cache_k, cache_v, tokens, lengths, p_tokens, p_rowids, p_positions,
+    p_slots, p_last_idx, paged=None,
+):
+    """`hybrid_decode_step` for the full batch with admitted prompts riding the
+    same pass over the weights: `llama.mixed_step_q8` (whose operands and
+    returns these are) for a stack with recurrent layers. Every product that is
+    rows of a matmul runs ONCE over the B decode rows and the T prompt tokens
+    stacked: a recurrent layer's `project` and `output` (and, row by row,
+    `operands`), a GQA layer's `_qkv`, gate and output projection, the
+    feed-forward. Between them each sort of row takes its own part: the decode
+    rows the convolution step and the state kernel on the pool, or the decode
+    attention through the cache, as `hybrid_decode_step` has them; the prompt
+    tokens the causal convolution and the chunked recurrence from ZERO state (a
+    riding prompt is always fresh), or causal attention over their own K/V, in
+    the precision `hybrid_prefill` computes them in.
+
+    The prompts stay packed in ONE row of T positions, and the recurrence runs
+    over the chunks of it that hold tokens and no more (a rung is an
+    executable's size, not its work). Each prompt starts at a multiple of
+    `kda.CHUNK` (the engine stages them so: `_stage_ride`; the positions
+    between are padding, row id R), so a prompt's first chunk is the scan's
+    chunk whose first position is 0: there the state is zeroed, and a prompt's
+    own state is the scan's after its last chunk. Padding inside a prompt's
+    last chunk leaves the state alone.
+
+    After the scan the decode rows' K/V is appended, the prompts' K/V lands in
+    their slots, and each prompt's state and convolution tail land in its row
+    of the pool, row by row, outside the scan (`hybrid_prefill_chunk_batch`
+    says why). The prompts' slots are parked rows of the decode batch: the
+    append, the state kernel and the convolution step move nothing there."""
+    from .llama import (
+        _attn_residual, _cache_shape, _embed_in, _logits, _qkv, _residual, _sub_in, _sub_out,
+        fuse_prompt_kv, packed_prompt_attn, write_prompt_rows)
+
+    if paged is not None:
+        raise NotImplementedError("a recurrent configuration's blocks are never shared")
+    kv_v, state = cache_v["v"], cache_v["state"]
+    S_cache, hd = _cache_shape(cache_k)[3], cfg.resolved_head_dim
+    B, T, R = tokens.shape[0], p_tokens.shape[0], p_slots.shape[0]
+    H, Hkv = cfg.n_heads, cfg.n_kv_heads
+    _, P, _, nk = _layout(cfg)
+    rec = _rec(cfg)
+    assert T % kda.CHUNK == 0, (T, kda.CHUNK)
+    p_rowids = jnp.asarray(p_rowids, jnp.int32)
+    live = lengths < S_cache
+    token = p_rowids < R  # [T]: a prompt's token, not padding
+    prompt = jnp.arange(B + T) >= B  # [N]: which rows are the prompts'
+    fresh = p_positions.reshape(-1, kda.CHUNK)[:, 0] == 0  # [T / C]: a prompt's first chunk
+    # the chunks up to the last staged token's: the scan's trip count
+    staged = jnp.max(jnp.where(token, jnp.arange(T) // kda.CHUNK + 1, 0))
+    ends = jnp.clip(p_last_idx, 0, T - 1)
+    h = _embed_in(cfg, params, jnp.concatenate([tokens, p_tokens]))  # [N, D]
+
+    def gqa_layer(h, carry, lp, ig):
+        with jax.named_scope("attn"):
+            x = _sub_in(cfg, h, lp["attn_norm"])
+            q, k, v = _qkv(cfg, lp, x)
+            q = q.reshape(B + T, H, hd)
+            k, v = k.reshape(B + T, Hkv, hd), v.reshape(B + T, Hkv, hd)
+            ctx_d = decode_attend_q8(
+                q[:B].reshape(B, Hkv, H // Hkv, hd), k[:B], v[:B], cache_k, kv_v, ig, lengths,
+                scale=cfg.attn_scale).reshape(B, H * hd)
+            ctx_p = packed_prompt_attn(cfg, q[B:], k[B:], v[B:], p_rowids)
+            h = _attn_residual(cfg, lp, jnp.concatenate([ctx_d, ctx_p]), h, x)
+        with jax.named_scope("kv_append"):
+            fused = fuse_prompt_kv(
+                k[B:].transpose(1, 0, 2), v[B:].transpose(1, 0, 2),
+                scale_dtype=cache_k["s"].dtype)
+        return h, carry, (k[:B], v[:B], fused["q"], fused["s"])
+
+    def rec_layer(h, carry, lp, ik):
+        pool, Ss, tails = carry
+        with jax.named_scope(rec.scope(cfg)):  # "kda" | "gdn" | "ssd"
+            x = _sub_in(cfg, h, lp["attn_norm"])
+            conv_in, side = rec.project(cfg, lp, x)  # [N, W]
+            mixed_d, conv, rows = kda.conv_step(
+                pool["conv"], ik, None, live, conv_in[:B], lp["conv_w"])
+            mixed_p, tail = kda.conv_packed(conv_in[B:], p_positions, ends, lp["conv_w"])
+            ops, side = rec.operands(cfg, lp, jnp.concatenate([mixed_d, mixed_p]), side)
+            o_d, S = rec.step_rows(
+                cfg, pool["S"], ik, rows, live, jax.tree.map(lambda a: a[:B], ops))
+            o_p, after = rec.scan_packed(
+                jax.tree.map(lambda a: a[B:][None], ops), token[None], fresh, staged)
+            y = rec.output(cfg, lp, jnp.concatenate([o_d, o_p[0]]), side, x.dtype)
+            own = jnp.take(after[:, 0], ends // kda.CHUNK, axis=0)  # [R, H, dk, dv]
+            return _residual(cfg, h, _sub_out(cfg, y, lp["attn_norm"])), (
+                {"S": S, "conv": conv},
+                Ss.at[ik].set(rec.pool_rows(cfg, own).reshape(Ss.shape[1:])),
+                tails.at[ik].set(tail.reshape(R, -1).astype(tails.dtype)))
+
+    # the prompts' states ride the scan as [Lk, R, (H / P) dk, P dv]: with the
+    # pool's own last two axes, both 128 at Granite-4.0-H's size, the compiler
+    # carried them transposed and then re-laid the WHOLE pool out to take them
+    # (4.5 GiB of temporaries, seen in the described-chip compile)
+    G, dk, W = state["S"].shape[2:]
+    carry = (state, jnp.zeros((P * nk, R, G * dk, W), jnp.float32),
+             jnp.zeros((P * nk, R, state["conv"].shape[2]), state["conv"].dtype))
+    h, (pool, Ss, tails), counts, (knew, vnew, pq, ps) = _period_scan(
+        cfg, params, h, carry, gqa_layer, rec_layer, jnp.concatenate([live, token]), prompt)
+    count = jnp.sum(p_rowids[None, :] == jnp.arange(R, dtype=jnp.int32)[:, None],
+                    axis=1, dtype=jnp.int32)  # [R] tokens of each prompt; 0: an unused row
+    starts = ends + 1 - count
+    with jax.named_scope("kv_append"):
+        new_k, new_kv_v = append_kv_q8(cache_k, kv_v, knew, vnew, lengths)
+        new_k = {
+            "q": write_prompt_rows(new_k["q"], pq, p_slots, starts, count),
+            "s": write_prompt_rows(new_k["s"], ps, p_slots, starts, count),
+        }
+        S, conv = pool["S"], pool["conv"]
+        Ss = Ss.reshape(P * nk, R, G, dk, W)
+        for r in range(R):  # an unused row writes back what it read
+            S = _put_row(S, Ss[:, r : r + 1], p_slots[r], count[r] > 0)
+            conv = _put_row(conv, tails[:, r : r + 1], p_slots[r], count[r] > 0)
+    new_v = {"v": new_kv_v, "state": {"S": S, "conv": conv}}
+    if counts is not None:
+        new_v["moe"] = cache_v["moe"] + jnp.moveaxis(counts, 1, 0)
+    last = jnp.take(h[B:], ends, axis=0)  # [R, D]
+    return _logits(cfg, params, jnp.concatenate([h[:B], last])), new_k, new_v
+
+
+def _put_row(pool, row, slot, live):
+    """`row` [Lk, 1, ...] into row `slot` of the pool, in place; not `live`:
+    the pool's own row back."""
+    at = (0, slot) + (0,) * (pool.ndim - 2)
+    cur = jax.lax.dynamic_slice(pool, at, row.shape)
+    return jax.lax.dynamic_update_slice(pool, jnp.where(live, row.astype(pool.dtype), cur), at)

@@ -185,50 +185,35 @@ def _heads(cfg: ModelConfig, mixed: jnp.ndarray):
     return unit(q) * d**-0.5, unit(k), v
 
 
-def _out(cfg: ModelConfig, kp: dict, o: jnp.ndarray, out_gate: jnp.ndarray, dtype) -> jnp.ndarray:
+def output(cfg: ModelConfig, kp: dict, o: jnp.ndarray, out_gate: jnp.ndarray, dtype) -> jnp.ndarray:
     """o [..., H, dv] f32 -> the layer's output [..., D]."""
     o = rms_norm(o, kp["o_norm"], cfg.norm_eps).astype(dtype)
     return qdot(o.reshape(*o.shape[:-2], -1) * out_gate, kp["wo_lin"])
 
 
-def kda_chunk_scan(q, k, v, g, beta, S0):
-    """The recurrence over a whole (padded) sequence, chunk by chunk.
+def _chunked(x, N: int):
+    """[A, N C, H, ...] -> [N, A, H, C, ...]: a chunk an entry."""
+    x = x.reshape(x.shape[0], N, x.shape[1] // N, *x.shape[2:])
+    return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 3, 2)
 
-    q, k [A, T, H, dk], v [A, T, H, dv], g the log decay (0 at a padding
-    position): [A, T, H, dk] a key channel, or [A, T, H] one a head; beta
-    [A, T, H] (0 at a padding position), S0 [A, H, dk, dv]; all float32.
-    Returns (o [A, T, H, dv], S_T). A padding position leaves the state as it
-    was (alpha 1, beta 0) and its output is never read.
 
-    Without the delta rule (`beta` None: a Mamba-2 layer, models/ssm.py) the
-    state takes the input as it is, U = V, and there is no system to solve; v
-    is then 0 at a padding position. q and k may be ONE group for every head,
-    [A, T, 1, dk]: every product below broadcasts them.
+def _unchunked(o):
+    """[N, A, H, C, dv] -> [A, N C, H, dv]."""
+    o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1)  # [A, N, C, H, dv]
+    return o.reshape(o.shape[0], -1, *o.shape[3:])
 
-    Inside a chunk, with G the cumulative log decay (inclusive) and
-    kk[t, s] = sum_d k_t k_s exp(G_t - G_s) for s < t, the corrections U solve
-    (I + diag(beta) kk) U = beta (V - (K exp(G)) S0); then
-    o = (Q exp(G)) S0 + qk U with qk[t, s] likewise for s <= t, and
-    S_end = exp(G_end) S0 + (K exp(G_end - G))^T U. With one decay a head the
-    decay between two positions is a [C, C] matrix and kk, qk are the products
-    K K^T, Q K^T masked by it; a decay a channel stands inside the sum over
-    d, a [C, C, dk] tensor a chunk."""
-    A, T, H = v.shape[:3]
-    C = math.gcd(T, CHUNK)
-    N = T // C
-    per_head, delta = g.ndim == 3, beta is not None
 
-    def chunks(x):  # [A, T, H, ...] -> [N, A, H, C, ...]
-        x = x.reshape(A, N, C, *x.shape[2:])
-        return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 3, 2)
-
+def _chunk_step(C: int, per_head: bool, delta: bool):
+    """One chunk of `kda_chunk_scan`'s recurrence as a scan's body: (S, (q, k,
+    v, g[, beta])) -> (S after the chunk, o), a chunk's operands [A, H, C, .],
+    beta [A, H, C, 1], g [A, H, C] where the decay is one a head."""
     t_idx = jnp.arange(C)
     earlier = t_idx[:, None] > t_idx[None, :]
     upto = t_idx[:, None] >= t_idx[None, :]
     eye = jnp.eye(C, dtype=jnp.float32)
 
     def step(S, xs):
-        q, k, v, g, *beta = xs  # [A, H, C, .]; beta [A, H, C, 1]; g [A, H, C] a head
+        q, k, v, g, *beta = xs
         G = jnp.cumsum(g, axis=2)
         # decay from position s to position t >= s: exp(<= 0)
         if per_head:
@@ -257,11 +242,69 @@ def kda_chunk_scan(q, k, v, g, beta, S0):
             "ahck,ahcv->ahkv", k_out, U, precision=_HI)
         return S, o
 
+    return step
+
+
+def kda_chunk_scan(q, k, v, g, beta, S0):
+    """The recurrence over a whole (padded) sequence, chunk by chunk.
+
+    q, k [A, T, H, dk], v [A, T, H, dv], g the log decay (0 at a padding
+    position): [A, T, H, dk] a key channel, or [A, T, H] one a head; beta
+    [A, T, H] (0 at a padding position), S0 [A, H, dk, dv]; all float32.
+    Returns (o [A, T, H, dv], S_T). A padding position leaves the state as it
+    was (alpha 1, beta 0) and its output is never read.
+
+    Without the delta rule (`beta` None: a Mamba-2 layer, models/ssm.py) the
+    state takes the input as it is, U = V, and there is no system to solve; v
+    is then 0 at a padding position. q and k may be ONE group for every head,
+    [A, T, 1, dk]: every product below broadcasts them.
+
+    Inside a chunk, with G the cumulative log decay (inclusive) and
+    kk[t, s] = sum_d k_t k_s exp(G_t - G_s) for s < t, the corrections U solve
+    (I + diag(beta) kk) U = beta (V - (K exp(G)) S0); then
+    o = (Q exp(G)) S0 + qk U with qk[t, s] likewise for s <= t, and
+    S_end = exp(G_end) S0 + (K exp(G_end - G))^T U. With one decay a head the
+    decay between two positions is a [C, C] matrix and kk, qk are the products
+    K K^T, Q K^T masked by it; a decay a channel stands inside the sum over
+    d, a [C, C, dk] tensor a chunk."""
+    T = v.shape[1]
+    C = math.gcd(T, CHUNK)
+    N = T // C
+    delta = beta is not None
+    step = _chunk_step(C, g.ndim == 3, delta)
     S, o = jax.lax.scan(
-        step, S0, (chunks(q), chunks(k), chunks(v), chunks(g),
-                   *([chunks(beta[..., None])] if delta else [])))
-    o = jnp.moveaxis(jnp.moveaxis(o, 2, 3), 0, 1)  # [A, N, C, H, dv]
-    return o.reshape(A, T, H, v.shape[-1]), S
+        step, S0, (_chunked(q, N), _chunked(k, N), _chunked(v, N), _chunked(g, N),
+                   *([_chunked(beta[..., None], N)] if delta else [])))
+    return _unchunked(o), S
+
+
+def kda_packed_scan(q, k, v, g, beta, fresh, staged):
+    """`kda_chunk_scan` over SEVERAL fresh sequences back to back in one row,
+    each from a chunk boundary on (a mixed step's packed prompts,
+    models/hybrid.py): a chunk marked in `fresh` [T / C] bool starts from zero
+    state and not from its predecessor's. Only the first `staged` chunks (a
+    traced count: those that hold tokens) are run; the positions behind them
+    read o = 0. Returns (o [A, T, H, dv], the state after EVERY chunk run
+    [T / C, A, H, dk, dv]: a sequence's own is the one after its last chunk)."""
+    A, T, H = v.shape[:3]
+    assert T % CHUNK == 0, (T, CHUNK)
+    N = T // CHUNK
+    delta = beta is not None
+    step = _chunk_step(CHUNK, g.ndim == 3, delta)
+    xs = (_chunked(q, N), _chunked(k, N), _chunked(v, N), _chunked(g, N),
+          *([_chunked(beta[..., None], N)] if delta else []))
+
+    def chunk(i, carry):
+        S, o, after = carry
+        S, o_i = step(jnp.where(fresh[i], 0.0, S), jax.tree.map(lambda x: x[i], xs))
+        return S, o.at[i].set(o_i), after.at[i].set(S)
+
+    S0 = jnp.zeros((A, H, k.shape[-1], v.shape[-1]), jnp.float32)
+    _, o, after = jax.lax.fori_loop(
+        0, staged, chunk,
+        (S0, jnp.zeros((N, A, H, CHUNK, v.shape[-1]), jnp.float32),
+         jnp.zeros((N, *S0.shape), jnp.float32)))
+    return _unchunked(o), after
 
 
 def conv_chunk(tail0, nvalid, proj, conv_w):
@@ -278,6 +321,76 @@ def conv_chunk(tail0, nvalid, proj, conv_w):
     return mixed, tail
 
 
+def conv_packed(proj, positions, last_idx, conv_w):
+    """`conv_chunk` for whole FRESH prompts packed back to back in one row,
+    `proj` [T, W], a token's place in its own prompt in `positions` [T]: (the
+    convolution's output before bias and activation [T, W], each prompt's tail
+    [R, taps-1, W] as it stands after its token at `last_idx` [R]). A prompt
+    starts from a zero tail: a tap that reaches back past position 0 reads 0
+    and not its neighbour's last rows."""
+    T, taps = proj.shape[0], conv_w.shape[0]
+    full = jnp.concatenate([jnp.zeros((taps - 1, proj.shape[1]), proj.dtype), proj])
+    mixed = sum(
+        jnp.where((positions >= taps - 1 - j)[:, None], full[j : j + T], 0)
+        * conv_w[j].astype(proj.dtype) for j in range(taps))
+
+    def tail_of(e):  # rows (e - taps + 1, e] of `proj`, those of this prompt
+        rows = jax.lax.dynamic_slice_in_dim(full, e + 1, taps - 1, axis=0)
+        own = positions[e] + 2 - taps + jnp.arange(taps - 1) >= 0
+        return jnp.where(own[:, None], rows, 0)
+
+    return mixed, jax.vmap(tail_of)(last_idx)
+
+
+# The layer in the parts a step program composes it from (models/hybrid.py,
+# `_RECURRENT`): `project` and `output` are products over rows, `operands` is
+# row by row, and between them stand the convolution and the recurrence, which
+# one token a row takes on the pool (`conv_step`, `step_rows`) and a prompt in
+# chunks (`conv_chunk` and `scan_rows`, or packed in one row `conv_packed` and
+# `scan_packed`). A mixed step runs the first kind ONCE over decode rows and
+# prompt tokens stacked.
+
+
+def project(cfg: ModelConfig, kp: dict, x: jnp.ndarray):
+    """x [..., D] -> (the convolution's input x [Wq | Wk | Wv] [..., W], what
+    `operands` needs beside the convolution's output: x, for the gates)."""
+    return qdot(x, kp["wqkv_lin"]), x
+
+
+def operands(cfg: ModelConfig, kp: dict, mixed: jnp.ndarray, x: jnp.ndarray):
+    """The convolution's output [..., W] and the layer's input -> (the
+    recurrence's (q, k, v, g, beta), float32; the output gate for `output`)."""
+    q, k, v = _heads(cfg, mixed)
+    g, beta, out_gate = _gates(cfg, kp, x)
+    return (q, k, v, g, beta), out_gate
+
+
+def step_rows(cfg: ModelConfig, S, layer, slot_ids, live, ops):
+    """One token a row on the pool's states: (o [Ba, H, dv], the states)."""
+    q, k, v, g, beta = ops
+    return kda_decode_step(
+        S, layer, slot_ids, live, q, k, v, jnp.exp(g), beta, name=step_kernel_name(cfg))
+
+
+def _masked(ops, valid):
+    """The recurrence's operands with `valid` [A, T] (a row's tokens) applied:
+    a padding position leaves the state alone."""
+    q, k, v, g, beta = ops
+    g = jnp.where(valid.reshape(*valid.shape, *(1,) * (g.ndim - 2)), g, 0.0)
+    return q, k, v, g, jnp.where(valid[..., None], beta, 0.0)
+
+
+def scan_rows(ops, valid, S0):
+    """Prompts [A, T, ...] in chunks from S0, `valid` [A, T] their tokens:
+    `kda_chunk_scan`'s returns."""
+    return kda_chunk_scan(*_masked(ops, valid), S0)
+
+
+def scan_packed(ops, valid, fresh, staged):
+    """Fresh prompts packed in one row [1, T, ...]: `kda_packed_scan`'s returns."""
+    return kda_packed_scan(*_masked(ops, valid), fresh, staged)
+
+
 def kda_prefill(
     cfg: ModelConfig,
     kp: dict,  # this layer's weights (un-stacked)
@@ -288,17 +401,13 @@ def kda_prefill(
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """The layer over a chunk that continues (S0, tail0): (y [A, T, D], S,
     tail), the last two as they stand after each row's `nvalid` positions."""
-    A, T, _ = x.shape
+    T = x.shape[1]
     with jax.named_scope(f"{cfg.lin_gates}_prefill"):
-        proj = qdot(x, kp["wqkv_lin"])  # [A, T, W]
+        proj, side = project(cfg, kp, x)  # [A, T, W]
         mixed, tail = conv_chunk(tail0, nvalid, proj, kp["conv_w"])
-        q, k, v = _heads(cfg, mixed)
-        g, beta, out_gate = _gates(cfg, kp, x)
-        valid = jnp.arange(T)[None, :] < nvalid[:, None]  # [A, T]
-        g = jnp.where(valid.reshape(A, T, *(1,) * (g.ndim - 2)), g, 0.0)
-        beta = jnp.where(valid[..., None], beta, 0.0)
-        o, S = kda_chunk_scan(q, k, v, g, beta, S0)
-        return _out(cfg, kp, o, out_gate, x.dtype), S, tail.astype(tail0.dtype)
+        ops, out_gate = operands(cfg, kp, mixed, side)
+        o, S = scan_rows(ops, jnp.arange(T)[None, :] < nvalid[:, None], S0)
+        return output(cfg, kp, o, out_gate, x.dtype), S, tail.astype(tail0.dtype)
 
 
 def kda_decode(
@@ -311,14 +420,11 @@ def kda_decode(
     live: jnp.ndarray,  # [Ba] bool: a parked or padding row moves nothing
 ) -> tuple[jnp.ndarray, dict]:
     """One token through the layer on the pool: (y [Ba, D], the pool)."""
-    proj = qdot(x, kp["wqkv_lin"])  # [Ba, W]
+    proj, side = project(cfg, kp, x)  # [Ba, W]
     mixed, conv, slot_ids = conv_step(state["conv"], layer, slot_ids, live, proj, kp["conv_w"])
-    q, k, v = _heads(cfg, mixed)
-    g, beta, out_gate = _gates(cfg, kp, x)
-    o, S = kda_decode_step(
-        state["S"], layer, slot_ids, live, q, k, v, jnp.exp(g), beta,
-        name=step_kernel_name(cfg))
-    return _out(cfg, kp, o, out_gate, x.dtype), {"S": S, "conv": conv}
+    ops, out_gate = operands(cfg, kp, mixed, side)
+    o, S = step_rows(cfg, state["S"], layer, slot_ids, live, ops)
+    return output(cfg, kp, o, out_gate, x.dtype), {"S": S, "conv": conv}
 
 
 def conv_step(tails, layer, slot_ids, live, proj, conv_w):

@@ -663,14 +663,16 @@ def _decode_step_q8(
 
 
 def mixed_step_supported(cfg: ModelConfig) -> bool:
-    """Whether `mixed_step_q8` serves this family: the ones whose decode step
-    is `_decode_step_q8` with a dense feed-forward (global attention, no score
-    softcap, rope, no latent cache, no recurrent layers, no routed experts)."""
-    return not (
-        cfg.kv_lora_rank or cfg.gqa_layers or cfg.recurrent or cfg.n_experts
-        or cfg.sliding_window or cfg.attn_softcap or cfg.attn_gate
-        or not cfg.use_rope
-    )
+    """Whether a mixed step serves this family. `mixed_step_q8`: the ones whose
+    decode step is `_decode_step_q8` with a dense feed-forward (global
+    attention, no score softcap, rope, no latent cache, no routed experts).
+    `hybrid.hybrid_mixed_step`: a stack with recurrent layers, which has no
+    rope (`hybrid_decode_step` applies none), whatever its feed-forward."""
+    if cfg.kv_lora_rank or cfg.sliding_window or cfg.attn_softcap:
+        return False
+    if cfg.recurrent:
+        return not cfg.use_rope
+    return not (cfg.n_experts or cfg.attn_gate or not cfg.use_rope)
 
 
 def packed_prompt_attn(
@@ -705,6 +707,8 @@ def write_prompt_rows(
     new: jnp.ndarray,  # [L, Hx, T, *rest]: whole prompts' rows packed, head-major
     slots: jnp.ndarray,  # [R] int32: the cache row of each prompt
     offsets: jnp.ndarray,  # [R + 1] int32: packed boundaries
+    counts: jnp.ndarray | None = None,  # [R] int32: with padding BETWEEN the prompts,
+    #   each one's tokens; `offsets` [R] is then where each starts
 ) -> jnp.ndarray:
     """Land whole packed prompts at position 0 of their slots, IN PLACE:
     `ragged_write_rows` for rows that all start at 0, where one window a
@@ -724,7 +728,7 @@ def write_prompt_rows(
         )[:, None]  # [L, 1, Hx, W, *rest]
         at = (0, slots[r], 0, 0) + (0,) * ntail
         cur = jax.lax.dynamic_slice(cache, at, (L, 1, Hx, W) + cache.shape[4:])
-        keep = win < offsets[r + 1] - offsets[r]
+        keep = win < (offsets[r + 1] - offsets[r] if counts is None else counts[r])
         cache = jax.lax.dynamic_update_slice(
             cache, jnp.where(keep, rows.astype(cache.dtype), cur), at
         )

@@ -146,6 +146,7 @@ def moe_share_ffn(
     valid: jnp.ndarray | None = None,  # [T] bool: rows that are tokens
     banks: dict[str, Any] | None = None,  # the STACKED w1e, w3e, w2e [L, E, ..]
     layer: jnp.ndarray | int = 0,  # which of the stack's layers, with `banks`
+    prompt: jnp.ndarray | None = None,  # [T] bool: rows that are a prompt's (a mixed step)
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Dropless expert layer of ONE member of an expert-parallel group.
 
@@ -175,7 +176,10 @@ def moe_share_ffn(
     Returns (y [T, D], counts int32 [5]): rows routed, pairs on held experts,
     distinct held experts touched, the fullest held expert's rows, and 1 (a
     call) — what the engine's counters sum per layer (executor/memory.py:
-    StatePool)."""
+    StatePool). A call whose rows are of both phases (a mixed step: decode rows
+    and prompt tokens through ONE pass over the banks) says which are which
+    with `prompt` and gets the counts of each, [2, 5]: the decode rows' under
+    [0], the prompt tokens' under [1], by masks on the router's choice."""
     T, D = x.shape
     k = cfg.experts_per_tok
     E = (banks or lp)["w1e"].shape[-3]
@@ -210,12 +214,19 @@ def moe_share_ffn(
 
         sg = jax.nn.silu(qdot(x, lp["w1s"]))
         y = y + qdot(sg * qdot(x, lp["w3s"]), lp["w2s"])
-    n_rows = T if valid is None else jnp.sum(valid, dtype=jnp.int32)
-    counts = jnp.stack([
-        jnp.asarray(n_rows, jnp.int32), jnp.sum(sizes), jnp.sum(sizes > 0, dtype=jnp.int32),
-        jnp.max(sizes), jnp.int32(1),
-    ])
-    return y, counts
+    def counted(rows, sizes):
+        return jnp.stack([
+            jnp.asarray(rows, jnp.int32), jnp.sum(sizes), jnp.sum(sizes > 0, dtype=jnp.int32),
+            jnp.max(sizes), jnp.int32(1),
+        ])
+
+    if prompt is None:
+        return y, counted(T if valid is None else jnp.sum(valid, dtype=jnp.int32), sizes)
+    live = jnp.ones((T,), bool) if valid is None else valid
+    of_prompts = jnp.sum(hit & jnp.repeat(prompt, k)[:, None], axis=0, dtype=jnp.int32)
+    return y, jnp.stack([
+        counted(jnp.sum(live & ~prompt, dtype=jnp.int32), sizes - of_prompts),
+        counted(jnp.sum(live & prompt, dtype=jnp.int32), of_prompts)])
 
 
 def moe_dispatch(

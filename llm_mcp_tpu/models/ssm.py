@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from ..kernels.kda import heads_abreast, kda_decode_step, pack_state, unpack_state
 from ..ops.norms import rms_norm
 from .configs import ModelConfig
-from .kda import conv_chunk, conv_step, kda_chunk_scan
+from .kda import conv_chunk, conv_step, kda_chunk_scan, kda_packed_scan
 from .quant import qdot
 
 STEP_KERNEL = "ssd_decode_step"  # the one-step state kernel's name in a trace
@@ -136,6 +136,58 @@ def _out(cfg: ModelConfig, kp: dict, o, x, z, dtype) -> jnp.ndarray:
     return qdot(rms_norm(y, kp["norm"], cfg.norm_eps).astype(dtype), kp["w_out"])
 
 
+# The layer in the parts a step program composes it from, under the names the
+# delta-rule layer gives them (models/kda.py says which is which).
+
+
+def project(cfg: ModelConfig, kp: dict, x: jnp.ndarray):
+    """x [..., D] -> (the convolution's input xBC [..., W], (z, dt))."""
+    z, xBC, dt = _project(cfg, kp, x)
+    return xBC, (z, dt)
+
+
+def operands(cfg: ModelConfig, kp: dict, mixed: jnp.ndarray, side):
+    """The convolution's output [..., W] and `project`'s (z, dt) -> (the
+    recurrence's (x, B, C, dt, g), float32; (x, z) for `output`)."""
+    z, dt = side
+    ops = _inputs(cfg, kp, mixed, dt)
+    return ops, (ops[0], z)
+
+
+def step_rows(cfg: ModelConfig, S, layer, slot_ids, live, ops):
+    """One token a row on the pool's states: (o [Ba, H, P], the states)."""
+    xh, B, C, dt, g = ops
+    return kda_decode_step(
+        S, layer, slot_ids, live, C, B, dt[..., None] * xh, jnp.exp(g), name=STEP_KERNEL)
+
+
+def _masked(ops, valid):
+    """The recurrence's operands (q, k, v, g, no beta) with `valid` [A, T] (a
+    row's tokens) applied: a padding position leaves the state as it was, no
+    decay and no input."""
+    xh, B, C, dt, g = ops
+    valid = valid[..., None]  # [A, T, 1]
+    g = jnp.where(valid, g, 0.0)
+    v = jnp.where(valid[..., None], dt[..., None] * xh, 0.0)
+    return C[:, :, None, :], B[:, :, None, :], v, g, None
+
+
+def scan_rows(ops, valid, S0):
+    """Prompts [A, T, ...] in chunks from S0, `valid` [A, T] their tokens:
+    `kda_chunk_scan`'s returns."""
+    return kda_chunk_scan(*_masked(ops, valid), S0)
+
+
+def scan_packed(ops, valid, fresh, staged):
+    """Fresh prompts packed in one row [1, T, ...]: `kda_packed_scan`'s returns."""
+    return kda_packed_scan(*_masked(ops, valid), fresh, staged)
+
+
+def output(cfg: ModelConfig, kp: dict, o, side, dtype) -> jnp.ndarray:
+    """The state's output o [..., H, P] and `operands`' (x, z) -> y [..., D]."""
+    return _out(cfg, kp, o, *side, dtype)
+
+
 def ssm_prefill(
     cfg: ModelConfig,
     kp: dict,  # this layer's weights (un-stacked)
@@ -145,18 +197,14 @@ def ssm_prefill(
     tail0: jnp.ndarray,  # [A, taps-1, W]
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """The layer over a chunk that continues (S0, tail0): (y [A, T, D], S,
-    tail), the last two as they stand after each row's `nvalid` positions. A
-    padding position leaves the state as it was: no decay, no input."""
+    tail), the last two as they stand after each row's `nvalid` positions."""
     T = x.shape[1]
     with jax.named_scope("ssd_prefill"):
-        z, xBC, dt = _project(cfg, kp, x)
+        xBC, side = project(cfg, kp, x)
         mixed, tail = conv_chunk(tail0, nvalid, xBC, kp["conv_w"])
-        xh, B, C, dt, g = _inputs(cfg, kp, mixed, dt)
-        valid = (jnp.arange(T)[None, :] < nvalid[:, None])[..., None]  # [A, T, 1]
-        g = jnp.where(valid, g, 0.0)
-        v = jnp.where(valid[..., None], dt[..., None] * xh, 0.0)
-        o, S = kda_chunk_scan(C[:, :, None, :], B[:, :, None, :], v, g, None, S0)
-        return _out(cfg, kp, o, xh, z, x.dtype), S, tail.astype(tail0.dtype)
+        ops, side = operands(cfg, kp, mixed, side)
+        o, S = scan_rows(ops, jnp.arange(T)[None, :] < nvalid[:, None], S0)
+        return output(cfg, kp, o, side, x.dtype), S, tail.astype(tail0.dtype)
 
 
 def ssm_decode(
@@ -169,13 +217,11 @@ def ssm_decode(
     live: jnp.ndarray,  # [Ba] bool: a parked or padding row moves nothing
 ) -> tuple[jnp.ndarray, dict]:
     """One token through the layer on the pool: (y [Ba, D], the pool)."""
-    z, xBC, dt = _project(cfg, kp, x)
+    xBC, side = project(cfg, kp, x)
     mixed, conv, slot_ids = conv_step(state["conv"], layer, slot_ids, live, xBC, kp["conv_w"])
-    xh, B, C, dt, g = _inputs(cfg, kp, mixed, dt)
-    o, S = kda_decode_step(
-        state["S"], layer, slot_ids, live, C, B, dt[..., None] * xh, jnp.exp(g),
-        name=STEP_KERNEL)
-    return _out(cfg, kp, o, xh, z, x.dtype), {"S": S, "conv": conv}
+    ops, side = operands(cfg, kp, mixed, side)
+    o, S = step_rows(cfg, state["S"], layer, slot_ids, live, ops)
+    return output(cfg, kp, o, side, x.dtype), {"S": S, "conv": conv}
 
 
 def pool_rows(cfg: ModelConfig, S: jnp.ndarray) -> jnp.ndarray:

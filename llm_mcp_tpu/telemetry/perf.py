@@ -358,8 +358,9 @@ class AdmitAccount:
     books, for every batch that DID take an admit program, why it did not
     ride: `own` {reason: programs} and `own_prompts` {reason: prompts}, the
     reasons "no active rows", "compact", "reads at once", "over the cap",
-    "recurrent", "other". `programs`, `prompts` and the mark stay programs of
-    their own alone."""
+    "other" (and, in records from before a recurrent configuration's
+    admissions rode, "recurrent"). `programs`, `prompts` and the mark stay
+    programs of their own alone."""
 
     SUMS = ("programs", "prompts", "rows_padded", "true_tokens",
             "padded_tokens", "queued_sum", "reads", "reads_blocked",

@@ -24,6 +24,12 @@ the bucketed CHUNK program (`hybrid_prefill_chunk_batch`: the state carried from
 chunk to chunk through the pool), which the harness's own request, an admit
 program's, never reaches; the summary's `admit_by_shape` says which programs ran.
 
+`--ride` serves each request to an engine with most of its rows decoding
+(`keep_rows_busy`), so that the prompt RIDES a full decode round's first step
+(`hybrid_mixed_step`) and takes no admit program: the benchmark's own `correct`
+request meets an idle engine and never does. The summary's `admit` block says how
+many prompts rode and how many took a program of their own.
+
 Prints one JSON line a seed and a summary; writes both to chiprun_out/.
 """
 
@@ -41,6 +47,36 @@ sys.path.insert(0, ROOT)
 CONTROLS = ("int8", "fp8", "state_bf16")
 
 
+def keep_rows_busy(gen, rows: int, stop) -> dict:
+    """Keep `rows` greedy requests of short prompts decoding until `stop` is
+    set, a thread each: three quarters of the slots are more than half, so
+    every round is the full batch, and one that finds a request queued and a
+    slot free carries the prompt. Returns the requests by id (the tap's filter)."""
+    import threading
+
+    from benchmark import trafficgen
+    from llm_mcp_tpu.executor.engine import GenRequest
+
+    mine: dict[int, GenRequest] = {}
+
+    def loop(i: int) -> None:
+        n = 0
+        while not stop.is_set():
+            req = GenRequest(
+                prompt_ids=gen.tokenizer.encode(trafficgen.text(64, 77000 + i, f"busy{n}")),
+                max_tokens=gen.max_seq_len - 160, temperature=0.0)
+            mine[id(req)] = req
+            gen.submit(req)
+            while isinstance(req.out.get(), dict):  # to the stream's end marker
+                if stop.is_set():
+                    req.cancelled = True
+            n += 1
+
+    for i in range(rows):
+        threading.Thread(target=loop, args=(i,), daemon=True).start()
+    return mine
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", default="solar-open2-250b-ep8-bf16",
@@ -52,6 +88,8 @@ def main() -> int:
     ap.add_argument("--first-seed", type=int, default=3200006000)
     ap.add_argument("--prompt-bytes", type=int, default=0,
                     help="the prompt's bytes instead of the configuration's reference_request")
+    ap.add_argument("--ride", action="store_true",
+                    help="serve beside decoding rows, so that the prompt rides a decode round")
     args = ap.parse_args()
 
     from benchmark import correctness, run as bench_run, trafficgen
@@ -85,13 +123,25 @@ def main() -> int:
     mask = gen._allowed_mask
     allowed = np.arange(gen.cfg.vocab_size) if mask is None else np.flatnonzero(np.asarray(mask))
 
+    busy: dict = {}
+    if args.ride:
+        import threading
+        import time
+
+        stop = threading.Event()
+        rows = gen.max_slots * 3 // 4
+        busy = keep_rows_busy(gen, rows, stop)
+        while sum(s is not None for s in gen._slots) < rows:
+            time.sleep(0.05)
+
     def serve(seed: int) -> tuple[list[int], list[int]]:
         got: dict = {}
         emit = gen._process_token
 
         def tap(slot, tok, pos):
-            got.setdefault("ids", list(slot.req.prompt_ids))
-            got.setdefault("out", []).append(int(tok))
+            if id(slot.req) not in busy:
+                got.setdefault("ids", list(slot.req.prompt_ids))
+                got.setdefault("out", []).append(int(tok))
             return emit(slot, tok, pos)
 
         gen._process_token = tap
@@ -119,6 +169,9 @@ def main() -> int:
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     lines = []
     served = [serve(args.first_seed + i) for i in range(args.seeds)]
+    admit = gen.perf_stats()["admit"]
+    if args.ride:
+        stop.set()
     for i, (ids, out) in enumerate(served):
         value, why = held(ids, out)
         lines.append({"seed": args.first_seed + i, "prompt_tokens": len(ids), "program": value,
@@ -133,7 +186,6 @@ def main() -> int:
             print(json.dumps({"seed": lines[i]["seed"], lower: value, "refused": why}), flush=True)
     module.LOWER = None
     jax.clear_caches()
-    by_shape = dict(gen.perf_stats()["admit"]["by_shape"])
     gen.shutdown()
 
     def summary(key: str) -> dict:
@@ -144,7 +196,8 @@ def main() -> int:
 
     result = {"tolerance": float(module.SERVED_TOL_REL), "reference": name,
               "request": {"prompt_bytes": n_bytes, "tokens": n_tokens},
-              "admit_by_shape": by_shape,
+              "admit_by_shape": dict(admit["by_shape"]),
+              "admit": {"rides": admit["rides"], "own_prompts": admit["own_prompts"]},
               **{key: summary(key) for key in ("program", *controls)}}
     print("SUMMARY", json.dumps(result), flush=True)
     with open(os.path.join(ROOT, "chiprun_out", f"{name}_tolerance.json"), "w") as f:

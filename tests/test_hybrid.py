@@ -217,6 +217,27 @@ def test_dropless_layer_is_the_capacity_layer_at_capacity_t(rows, padded):
     assert np.max(np.abs(np.asarray(stacked) - np.asarray(new))[keep]) < 1e-5
 
 
+@pytest.mark.parametrize("rows", [24, 900])  # the expert-major form, and the grouped one
+def test_a_call_of_both_phases_counts_each_under_its_own(rows):
+    """`moe_share_ffn(prompt=...)`, a mixed step's call: the same output as the
+    call without it, and the counts of the decode rows and of the prompt tokens
+    apart, each what a call of those rows alone counts (the output of a row
+    does not depend on what shares the call)."""
+    cfg = get_config("tiny-solar")
+    lp = {k: v[0] for k, v in moe.init_moe_layer_params(cfg, jax.random.PRNGKey(0), jnp.float32, 1).items()}
+    x = jax.random.normal(jax.random.PRNGKey(rows), (rows, cfg.dim))
+    n = rows // 3
+    valid = (jnp.arange(rows) % 7) != 3
+    prompt = jnp.arange(rows) >= n
+    plain, whole = moe.moe_share_ffn(cfg, lp, x, valid=valid)
+    y, counts = moe.moe_share_ffn(cfg, lp, x, valid=valid, prompt=prompt)
+    assert np.array_equal(np.asarray(y), np.asarray(plain)) and counts.shape == (2, 5)
+    _, decode = moe.moe_share_ffn(cfg, lp, x[:n], valid=valid[:n])
+    _, prefill = moe.moe_share_ffn(cfg, lp, x[n:], valid=valid[n:])
+    assert counts.tolist() == [decode.tolist(), prefill.tolist()]
+    assert (counts[0, :2] + counts[1, :2]).tolist() == whole[:2].tolist() and whole[1] > 0
+
+
 def _share_case(case: str):
     """(cfg, lp, x, valid, banks, layer) of one routing situation at
     `tiny-solar` (4 experts held of 16 scored, 4 a row, sigmoid router with a
@@ -432,13 +453,18 @@ def test_a_mesh_is_refused_for_a_recurrent_configuration():
 
 def test_the_features_a_recurrent_configuration_runs_without_come_from_one_list(engine):
     """`memory.RECURRENT_OFF` names them, `engine._runs` answers from it, and
-    the pool keeps a counter for each; the file of the configuration holds the
-    precisions a later change might lower (`program.expect`)."""
+    the pool keeps a counter for each, and one more for what is NOT off: the
+    admit programs such a configuration still takes of its own, under
+    `mixed_round` (its admissions ride a decode round since PR 42); the file of
+    the configuration holds the precisions a later change might lower
+    (`program.expect`)."""
     from llm_mcp_tpu.executor import GenerationEngine
-    from llm_mcp_tpu.executor.memory import RECURRENT_OFF
+    from llm_mcp_tpu.executor.memory import POOL_COUNTS, RECURRENT_OFF
 
-    assert set(engine.perf_stats()["state_pool"]["off"]) == set(RECURRENT_OFF)
+    assert POOL_COUNTS == ("mixed_round",) and "mixed_round" not in RECURRENT_OFF
+    assert set(engine.perf_stats()["state_pool"]["off"]) == set(RECURRENT_OFF) | set(POOL_COUNTS)
     assert not any(engine._runs(f) for f in RECURRENT_OFF) and engine._runs("chunked_prefill")
+    assert engine._runs("mixed_round")
     dense = GenerationEngine("tiny-llm", max_slots=2, max_seq_len=64, dtype=jnp.float32)
     assert all(dense._runs(f) for f in RECURRENT_OFF)
     assert (engine.state_dtype, dense.state_dtype, dense.expert_dtype) == ("float32", "", "")
@@ -471,8 +497,10 @@ def test_the_expert_counter_says_which_form_each_phase_took(monkeypatch):
 def test_the_cells_warm_up_plan_holds_the_steps_it_held(monkeypatch):
     """The engine of `solar_decode_closed` as the cell sizes it (64 slots x
     1024, the entry point's defaults, the chip's kernels and so its ladder of
-    prompt buckets, an admit program of at most 512 padded tokens): 35 shapes
-    in the plan, as in PR 32; the two forms of the expert layer add no program."""
+    prompt buckets, an admit program of at most 512 padded tokens): the 35
+    shapes of PR 32 in the plan and, since PR 42, the mixed round at ONE rung,
+    the largest (a configuration with recurrent layers: `engine._ride_rungs`);
+    the two forms of the expert layer add no program."""
     from llm_mcp_tpu.executor import GenerationEngine, warmup
     from llm_mcp_tpu.utils.config import Config
 
@@ -484,8 +512,9 @@ def test_the_cells_warm_up_plan_holds_the_steps_it_held(monkeypatch):
         prefill_buckets=cfg.tpu_prefill_buckets)
     zoo = eng.warmup_shape_zoo()
     by_phase = {ph: sum(1 for p, _ in zoo if p == ph) for ph, _ in zoo}
-    assert by_phase == {"admit": 13, "decode": 4, "chunk": 18} and len(zoo) == 35
-    assert len(warmup.plan_steps(zoo)) == 35
+    assert by_phase == {"admit": 13, "decode": 4, "chunk": 18, "mixed": 1} and len(zoo) == 36
+    assert [key for ph, key in zoo if ph == "mixed"] == [(256, False)] and eng._ride_rungs == (256,)
+    assert len(warmup.plan_steps(zoo)) == 36
     forms = {moe.share_form(key[0]) for ph, key in zoo if ph == "decode"}
     assert forms == {"expert_major"}  # every decode round: 8, 16, 32, 64 rows
     assert {moe.share_form(key[0] * key[1]) for ph, key in zoo if ph == "admit"} == {"expert_major"}
@@ -1161,7 +1190,11 @@ def test_granite_engine_serves_the_references_choice_whole_and_chunked(granite_e
     assert pool["admitted_total"] == len(prompts) and pool["live_slots"] == 0
     assert not any(eng._runs(f) for f in ("prefix_cache", "offload", "migration", "speculation",
                                            "ragged_prefill"))
-    assert pool["off"]["mixed_round"] > 0  # every admission took a program of its own
+    # a recurrent configuration's admissions may ride a decode round (not on this
+    # engine: the XLA decode path, `other`); the pool counts the admit programs of
+    # whole prompts it still takes of its own, here every one
+    assert eng._runs("mixed_round") and eng._ride_off() == "other" and eng._ride_align == 32
+    assert pool["off"]["mixed_round"] == sum(stats["admit"]["own"].values()) > 0
 
 
 def test_the_harness_comparison_passes_the_granite_program_and_refuses_its_controls(granite_ref):
@@ -1188,3 +1221,76 @@ def test_the_harness_comparison_passes_the_granite_program_and_refuses_its_contr
         granite_ref.LOWER = None
         jax.clear_caches()
         eng.shutdown()
+
+
+# -- the parts a mixed step composes a recurrent layer from (PR 42) -------------------
+
+
+@pytest.mark.parametrize("form", ["a decay a channel", "a decay a head", "no delta rule"])
+def test_a_packed_chunk_scan_starts_from_zero_at_a_fresh_chunk(form):
+    """`kda_packed_scan` over two sequences packed in ONE row, each from a chunk
+    boundary on, with `fresh` marking their first chunks: every position's
+    output and each sequence's state after its last chunk are what
+    `kda_chunk_scan` gives the sequence alone from zero state, in all three
+    forms of the recurrence; padding inside a sequence's last chunk and a whole
+    chunk of it leave the state alone, and a chunk past the `staged` ones is
+    not run at all: its outputs and its state read 0."""
+    from llm_mcp_tpu.models.kda import CHUNK, kda_chunk_scan, kda_packed_scan
+
+    H, dk, dv = 3, 8, 16
+    rng = np.random.default_rng(0)
+    lens, T = (40, 32), 4 * CHUNK  # 40 pads to 64, 32 is a chunk to the token, a chunk of padding
+
+    def draw(n):
+        valid = (np.arange(-(-n // CHUNK) * CHUNK) < n)[None, :, None]
+        t = valid.shape[1]
+        q, k = (rng.normal(size=(1, t, H, dk)).astype(np.float32) * 0.3 for _ in range(2))
+        v = rng.normal(size=(1, t, H, dv)).astype(np.float32) * valid[..., None]
+        g = -rng.uniform(0.01, 0.5, size=(1, t, H, dk) if form == "a decay a channel" else (1, t, H))
+        g = (g * (valid[..., None] if g.ndim == 4 else valid)).astype(np.float32)
+        beta = None if form == "no delta rule" else (
+            rng.uniform(0.1, 1.9, size=(1, t, H)) * valid).astype(np.float32)
+        return q, k, v, g, beta
+
+    S0 = jnp.zeros((1, H, dk, dv), jnp.float32)
+    alone = [draw(n) for n in lens]
+    pad = [np.zeros((1, CHUNK, *a.shape[2:]), np.float32) if a is not None else None
+           for a in alone[0]]
+    packed = [None if parts[0] is None else np.concatenate(parts, axis=1)
+              for parts in zip(*alone, pad)]
+    assert packed[0].shape[1] == T
+    fresh = jnp.asarray([True, False, True, False])
+    o, after = kda_packed_scan(*packed, fresh, 4)
+    assert after.shape == (4, 1, H, dk, dv)
+    at = 0
+    for ops, n, last in zip(alone, lens, (1, 2)):
+        want_o, want_S = kda_chunk_scan(*ops, S0)
+        np.testing.assert_allclose(o[:, at : at + n], want_o[:, :n], rtol=1e-6, atol=1e-7)
+        np.testing.assert_allclose(after[last], want_S, rtol=1e-6, atol=1e-7)
+        assert float(jnp.abs(want_S).max()) > 1e-2
+        at += want_o.shape[1]
+    assert np.array_equal(after[3], after[2])  # a chunk of padding moves nothing
+    o3, after3 = jax.jit(kda_packed_scan)(*packed, fresh, jnp.int32(3))  # the chunks that hold tokens
+    assert np.array_equal(o3[:, : 3 * CHUNK], o[:, : 3 * CHUNK]) and not o3[:, 3 * CHUNK :].any()
+    assert np.array_equal(after3[:3], after[:3]) and not after3[3].any()
+
+
+def test_a_packed_convolution_reads_nothing_before_a_prompts_first_token():
+    """`conv_packed` against `conv_chunk` of each prompt alone from a zero tail:
+    outputs and tails, a prompt shorter than the taps' reach among them."""
+    from llm_mcp_tpu.models.kda import conv_chunk, conv_packed
+
+    taps, W = 4, 6
+    rng = np.random.default_rng(1)
+    conv_w = jnp.asarray(rng.normal(size=(taps, W)), jnp.float32)
+    lens, starts, T = (5, 2, 7), (0, 8, 16), 24
+    proj = jnp.asarray(rng.normal(size=(T, W)), jnp.float32)
+    positions = np.full(T, 99, np.int32)
+    for n, at in zip(lens, starts):
+        positions[at : at + n] = np.arange(n)
+    last = jnp.asarray([at + n - 1 for n, at in zip(lens, starts)], jnp.int32)
+    mixed, tails = conv_packed(proj, jnp.asarray(positions), last, conv_w)
+    for r, (n, at) in enumerate(zip(lens, starts)):
+        want, tail = conv_chunk(jnp.zeros((1, taps - 1, W)), jnp.asarray([n]), proj[None, at : at + 8], conv_w)
+        assert np.array_equal(mixed[at : at + n], want[0, :n])
+        assert np.array_equal(tails[r], tail[0])

@@ -52,6 +52,8 @@ def _restore(eng, state):
 
 
 def _ride_arrays(eng, prompts, slots, counter, rung):
+    """The packed buffer as `_stage_ride` lays it out: a prompt from a multiple
+    of the engine's `_ride_align` on (1, or the recurrence's chunk)."""
     R = eng._ride_rows
     tokens = np.zeros(rung, np.int32)
     rowids = np.full(rung, R, np.int32)
@@ -64,8 +66,9 @@ def _ride_arrays(eng, prompts, slots, counter, rung):
         tokens[at:at + len(p)] = p
         rowids[at:at + len(p)] = i
         positions[at:at + len(p)] = np.arange(len(p))
-        at += len(p)
-        ipack[i], ipack[R + i] = slot, at - 1
+        ipack[i], ipack[R + i] = slot, at + len(p) - 1
+        at += eng._ride_len(p)
+    assert at <= rung
     ipack[len(prompts):R] = slots[0]
     ipack[3 * R], ipack[3 * R + 1] = len(prompts), counter
     return tokens, rowids, positions, ipack, fpack
@@ -234,9 +237,15 @@ def test_a_queued_request_rides_a_round_beside_active_rows(monkeypatch):
     """With a full-batch round decoding, a queued request rides it: the ride
     counter moves and `admit_prog` does not, its first token is emitted before
     its first decode token, its text equals an idle engine's (admit_fn), and
-    the occupancy bookkeeping sees the mixed round as a `fused` sample."""
+    the occupancy bookkeeping sees the mixed round as a `fused` sample. (With
+    recurrent layers of each kind: tests/test_mixed_round_hybrid.py.)"""
+    rides_beside_active_rows(monkeypatch, "tiny-llm")
+
+
+def rides_beside_active_rows(monkeypatch, model):
     monkeypatch.setenv("TPU_PERF_SAMPLE", "1")
-    eng = _engine(monkeypatch, max_slots=4, decode_chunk=2).start()
+    kw = {} if model == "tiny-llm" else {"model": model, "quant": ""}
+    eng = _engine(monkeypatch, max_slots=4, decode_chunk=2, **kw).start()
     try:
         alone = eng.generate("the quick brown fox rides along", max_tokens=10, temperature=0.0)
         # three of four rows decoding: a full-batch round (pow2 of 3 = 4)
@@ -279,9 +288,13 @@ def test_a_queued_request_rides_a_round_beside_active_rows(monkeypatch):
 @pytest.mark.parametrize("why", ["no active rows", "compact", "reads at once", "recurrent",
                                  "over the cap", "other"])
 def test_what_may_not_ride_takes_admit_fn_and_says_why(monkeypatch, why):
-    kw = {}
+    """`recurrent` is no reason any more (a configuration with a state pool
+    rides: above): its case is such a configuration's request that meets an idle
+    engine, which takes a program of its own for the reason any configuration's
+    does, and the pool's `mixed_round` counter counts that program."""
+    kw, reason = {}, why
     if why == "recurrent":
-        kw = dict(model="tiny-olmo-hybrid", quant="", max_slots=4)
+        kw, reason = dict(model="tiny-olmo-hybrid", quant="", max_slots=4), "no active rows"
     elif why == "other":
         kw = dict(attn="xla", max_slots=4)
     elif why == "compact":
@@ -293,7 +306,7 @@ def test_what_may_not_ride_takes_admit_fn_and_says_why(monkeypatch, why):
     eng = _engine(monkeypatch, **kw).start()
     try:
         busy = []
-        if why != "no active rows":
+        if reason != "no active rows":
             n = 2 if why == "compact" else 3  # 2 of 16: a compact round
             busy = [_submit(eng, f"row {i} keeps decoding for a while", max_tokens=100)
                     for i in range(n)]
@@ -308,10 +321,11 @@ def test_what_may_not_ride_takes_admit_fn_and_says_why(monkeypatch, why):
         after = eng.perf_stats()["admit"]
         assert after["rides"] == before["rides"]
         assert after["programs"] == before["programs"] + 1
-        assert after["own"].get(why, 0) == before["own"].get(why, 0) + 1
-        assert after["own_prompts"].get(why, 0) == before["own_prompts"].get(why, 0) + 1
+        assert after["own"].get(reason, 0) == before["own"].get(reason, 0) + 1
+        assert after["own_prompts"].get(reason, 0) == before["own_prompts"].get(reason, 0) + 1
         if why == "recurrent":
-            assert eng.perf_stats()["state_pool"]["off"]["mixed_round"] >= 1
+            assert eng._ride_off() == "" and eng._runs("mixed_round") and "recurrent" not in after["own"]
+            assert eng.perf_stats()["state_pool"]["off"]["mixed_round"] == after["programs"]
         for r in busy:
             r.cancelled = True
     finally:
@@ -326,12 +340,20 @@ def test_every_mixed_shape_the_engine_dispatches_is_in_the_zoo(monkeypatch):
     zoo lists a `mixed` step a rung (the full batch only), `_stage_ride` picks
     no other size, the plan can lower each, and a key of another
     configuration's (a rung it lacks, the other paging flag, an engine that
-    keeps admit_fn) is refused."""
-    eng = _engine(monkeypatch, max_seq_len=256)
+    keeps admit_fn) is refused. (With recurrent layers:
+    tests/test_mixed_round_hybrid.py.)"""
+    every_mixed_shape_is_in_the_zoo(monkeypatch, "tiny-llm")
+
+
+def every_mixed_shape_is_in_the_zoo(monkeypatch, model):
+    base = {} if model == "tiny-llm" else {"model": model, "quant": ""}
+    eng = _engine(monkeypatch, max_seq_len=256, **base)
     phys = eng._phys is not None
     zoo = eng.warmup_shape_zoo()
-    assert [k for ph, k in zoo if ph == "mixed"] == [(128, phys), (256, phys)]
-    assert eng._ride_rungs == (128, 256) == eng.RIDE_RUNGS
+    # a configuration with recurrent layers rides at the largest rung alone
+    rungs = (256,) if eng.cfg.recurrent else (128, 256)
+    assert [k for ph, k in zoo if ph == "mixed"] == [(r, phys) for r in rungs]
+    assert eng._ride_rungs == rungs and eng.RIDE_RUNGS == (128, 256)
     for rung in eng._ride_rungs:
         assert eng._warmup_key_fits("mixed", (rung, phys))
         fn, args, kw = eng.warmup_operands("mixed", (rung, phys))
@@ -340,12 +362,13 @@ def test_every_mixed_shape_the_engine_dispatches_is_in_the_zoo(monkeypatch):
         assert eng.warmup_operands("mixed", (rung, not phys)) is None
     assert not eng._warmup_key_fits("mixed", (512, phys))
     assert not eng._warmup_key_fits("mixed", (64, phys))
-    assert eng.warmup_lower("mixed", (128, phys)) is not None
+    assert eng._warmup_key_fits("mixed", (128, phys)) == (not eng.cfg.recurrent)
+    assert eng.warmup_lower("mixed", (rungs[0], phys)) is not None
     # a cache too short for a rung lists none of it; an engine that keeps
     # admit_fn lists no mixed step and refuses a prior that carries one
-    short = _engine(monkeypatch, max_seq_len=128)
+    short = _engine(monkeypatch, max_seq_len=128, **base)
     assert [k[0] for ph, k in short.warmup_shape_zoo() if ph == "mixed"] == [128]
-    xla = _engine(monkeypatch, attn="xla")
+    xla = _engine(monkeypatch, attn="xla", **base)
     assert xla._ride_off() == "other"
     assert not [ph for ph, _ in xla.warmup_shape_zoo() if ph == "mixed"]
     assert not xla._warmup_key_fits("mixed", (128, False))
@@ -353,13 +376,13 @@ def test_every_mixed_shape_the_engine_dispatches_is_in_the_zoo(monkeypatch):
 
 
 @pytest.mark.parametrize("phase", ["mixed", "decode", "admit"])
-def test_the_plans_module_is_the_one_the_live_call_lowers(monkeypatch, phase):
+def test_the_plans_module_is_the_one_the_live_call_lowers(monkeypatch, phase, model="tiny-llm"):
     """The warm-up plan's compile serves a shape's first real dispatch only if
     both lower to the SAME module (the persistent cache's key is made of it).
     Off a mesh the live call's module carries no argument shardings, so the
     plan's operands carry none: with a single-device sharding on each the
     plan compiled every shape under a key no dispatch ever asked for."""
-    eng = _engine(monkeypatch)
+    eng = _engine(monkeypatch, **({} if model == "tiny-llm" else {"model": model, "quant": ""}))
     phys = eng._phys is not None
     R = eng._ride_rows
     state = (eng._d_temp, eng._d_topk, eng._d_topp, eng._d_last_tok)

@@ -472,14 +472,14 @@ def solar_program(which: str, cfg):
     bucketed chunk), without the sampler."""
     from llm_mcp_tpu.models import hybrid, llama
 
-    def decode(params, ck, cv, tokens, lengths, ids):
+    def decode(params, ck, cv, tokens, lengths, ids, steps=4):
         def step(carry, _):
             ck, cv, toks, lens = carry
             logits, ck, cv = llama.llama_decode_step(
                 cfg, params, ck, cv, toks, lens, attn_impl="pallas", slot_ids=ids)
             return (ck, cv, jnp.argmax(logits, axis=-1).astype(I32), lens + 1), None
 
-        (ck, cv, toks, _), _ = jax.lax.scan(step, (ck, cv, tokens, lengths), None, length=4)
+        (ck, cv, toks, _), _ = jax.lax.scan(step, (ck, cv, tokens, lengths), None, length=steps)
         return toks, ck, cv
 
     def admit(params, ck, cv, tokens, lengths, slots):
@@ -501,7 +501,16 @@ def solar_program(which: str, cfg):
         return llama.llama_prefill_chunk_batch(
             cfg, params, ck, cv, tokens, slots, starts, nvalid, skey=512)
 
-    return {"decode": decode, "admit": admit, "chunk": chunk}[which]
+    def mixed(params, ck, cv, tokens, lengths, p_tokens, p_rowids, p_positions, p_slots, p_last):
+        # `mixed_round_fn`: the first step carries the packed prompts, three plain ones follow
+        logits, ck, cv = hybrid.hybrid_mixed_step(
+            cfg, params, ck, cv, tokens, lengths, p_tokens, p_rowids, p_positions, p_slots, p_last)
+        new = jnp.argmax(logits, axis=-1).astype(I32)
+        n = tokens.shape[0]
+        toks, ck, cv = decode(params, ck, cv, new[:n], lengths + 1, None, steps=3)
+        return toks, new[n:], ck, cv
+
+    return {"decode": decode, "admit": admit, "chunk": chunk, "mixed": mixed}[which]
 
 
 @pytest.mark.parametrize("which,operands", [
@@ -644,6 +653,49 @@ def test_granite_step_programs_fit_beside_64_slots(sd, granite, chip_kernels, wh
           f"{mem.argument_size_in_bytes / 2**30:.2f} GiB")
     assert total < 15.0 * 2**30
     assert mem.alias_size_in_bytes > 4.5 * 2**30  # KV cache and state pool updated in place
+
+
+@pytest.mark.parametrize("rung", [128, 256])
+@pytest.mark.parametrize("name,kernel,limit,temps", [
+    ("solar", "%kda_decode_step", 15.75, 0.25), ("olmo", "%gdn_decode_step", 15.0, 0.5),
+    # its cache of 0.27 GiB is re-laid for the kernels and back, as in its decode
+    # round (heads of 64: PERF.md section 7), and the prompts' states ride the scan
+    ("granite", "%ssd_decode_step", 15.0, 1.5)])
+def test_hybrid_mixed_round_fits_beside_its_decode_round(
+    sd, request, chip_kernels, name, kernel, limit, temps, rung
+):
+    """The mixed round of the three benchmark configurations with recurrent
+    layers (`hybrid_mixed_step`, then three plain steps) at their cells' 64
+    slots x 1024 and both rungs of the packed prompt buffer, four prompt rows,
+    compiles for the described v5e: the decode rows' state kernel, decode
+    attention and append kernel as Mosaic calls with no fall to their reference,
+    inside the limit its decode round is held to, the KV cache and the state
+    pool updated in place, and among the temporaries no copy of the pool (0.78,
+    2.04 and 4.56 GiB: each is larger than all of them together; carried with
+    the pool's own last two axes, Granite's prompt states made the compiler
+    re-lay the whole pool out) nor of Olmo-Hybrid's cache (2.42 GiB). GiB in
+    PERF.md section 4 as "described-chip compile"."""
+    cfg, params, cache = request.getfixturevalue(name)
+    falls = dict(A.reference_falls)
+    vec = lambda n: sd((n,), I32)  # noqa: E731
+    compiled = jax.jit(solar_program("mixed", cfg), donate_argnums=(1, 2)).lower(
+        params, cache["k"], cache["v"], vec(64), vec(64), vec(rung), vec(rung), vec(rung),
+        vec(4), vec(4)).compile()
+    assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
+    text = compiled.as_text()
+    assert kernel in text and "decode_attn_q8" in text and "append_kv_q8" in text
+    nbytes = lambda tree: sum(  # noqa: E731
+        int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(tree))
+    pool, kv = nbytes(cache["v"]["state"]), nbytes(cache["k"])
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"{name} mixed {rung}: {total / 2**30:.2f} GiB, of it temporaries "
+          f"{mem.temp_size_in_bytes / 2**30:.2f} GiB (pool {pool / 2**30:.2f}, "
+          f"KV cache {kv / 2**30:.2f})")
+    assert total < limit * 2**30
+    assert mem.temp_size_in_bytes < temps * 2**30 < pool
+    assert mem.alias_size_in_bytes > 0.99 * (pool + kv)
 
 
 def test_a_fall_to_the_reference_is_counted(tmp_path):
