@@ -59,6 +59,7 @@ from ..kernels.attention import (
 from ..utils.faults import maybe_fail
 from ..utils.platform import on_tpu
 from ..models.configs import ModelConfig, resolve_config
+from ..models.hybrid import SLOT_MEMBERS
 from ..models.kda import CHUNK as RECURRENCE_CHUNK
 from ..models.moe import share_form
 from ..models.weights import load_llama_checkpoint
@@ -722,18 +723,22 @@ class GenerationEngine:
             )
         self._ck = cache["k"]
         self._cv = cache["v"]
-        # Recurrent layers (models/hybrid.py): the per-slot state rides the
-        # cache pair's second member through every step program, and its book
+        # Layers with a per-slot state of fixed size (models/hybrid.py: a
+        # recurrent state, or a window layer's ring): it rides the cache pair's
+        # second member through every step program, and its book
         # (memory.StatePool) is the one place that says which features such a
-        # configuration runs without. None for attention-only configurations.
+        # configuration runs without. None for a configuration whose every
+        # layer keeps full-length KV rows.
         self._state_pool = build_state_pool(
             self.cfg, max_slots,
-            self._cv["state"] if self.cfg.recurrent else None, log)
+            next(self._cv[m] for m in SLOT_MEMBERS if m in self._cv)
+            if self.cfg.recurrent else None, log)
         recurrent = self._state_pool is not None
         # the expert layer's counts, where the step programs carry them (a
         # member of the cache pair of its own, beside the state): None else
         self._experts = (
-            ExpertCounts(self.cfg.n_layers, held=self.cfg.n_experts, router=self.cfg.router_width)
+            ExpertCounts(int(self._cv["moe"].shape[1]), held=self.cfg.n_experts,
+                         router=self.cfg.router_width)
             if isinstance(self._cv, dict) and "moe" in self._cv else None)
         # what the blocked int8 decode-attention arm streams, where decode
         # rounds run it (int8 GQA cache read by the Pallas kernel): None else
@@ -741,6 +746,11 @@ class GenerationEngine:
             AttnStream(self._ck["q"].shape)
             if self.kv_quant == "int8" and not self.cfg.kv_lora_rank
             and self.decode_impl == "pallas" else None)
+        # and what the window arm streams of the window layers' rings
+        self._win_stream = (
+            AttnStream(self._cv["win"]["k"]["q"].shape, window=self.cfg.sliding_window,
+                       max_seq_len=max_seq_len)
+            if self._attn_stream is not None and "win" in self._cv else None)
         if self._spmd:
             # named out_sharding kinds for _shard_out: host-read outputs come
             # back fully replicated (every process device_gets locally — the
@@ -983,12 +993,11 @@ class GenerationEngine:
         def _insert_row(ck, cv, ks, vs, i, slot):
             if recurrent:
                 # the GQA layers' rows as for any family, and the row's
-                # recurrent state into the pool beside them
+                # recurrent state (or its ring) into the pool beside them
                 from ..models.hybrid import insert_state_row
 
                 ck, v = _insert_kv(ck, cv["v"], ks, vs["v"], i, slot)
-                return ck, dict(cv, v=v, state=insert_state_row(
-                    cv["state"], vs["state"], i, slot))
+                return ck, dict(cv, v=v, **insert_state_row(cv, vs, i, slot))
             return _insert_kv(ck, cv, ks, vs, i, slot)
 
         def _insert_kv(ck, cv, ks, vs, i, slot):
@@ -2593,12 +2602,15 @@ class GenerationEngine:
     def state_dtype(self) -> str:
         """The recurrent state pool's precision ("" without one): a
         configuration's file states it (`program.expect`)."""
-        return str(self._cv["state"]["S"].dtype) if self._state_pool is not None else ""
+        return str(self._cv["state"]["S"].dtype) if (
+            self._state_pool is not None and "state" in self._cv) else ""
 
     def _layer_leaf_dtype(self, *names: str) -> str:
-        """Precision of the first of `names` among the stacked layers' leaves:
+        """Precision of the first of `names` among the layers' leaves (the
+        stacked ones', or a leading dense layer's own: `params["first"]`):
         "int8" for a quantised one, "" where there is none."""
-        layers = self.params.get("layers", {}) if isinstance(self.params, dict) else {}
+        params = self.params if isinstance(self.params, dict) else {}
+        layers = {**next(iter(params.get("first", ())), {}), **params.get("layers", {})}
         leaf = next((layers[k] for k in names if k in layers), None)
         if leaf is None:
             return ""
@@ -2607,7 +2619,8 @@ class GenerationEngine:
     @property
     def weights_dtype(self) -> str:
         """The layers' dense feed-forward matrices' precision ("" where every
-        layer's feed-forward is routed experts: `expert_dtype`); a
+        layer's feed-forward is routed experts: `expert_dtype`; a leading
+        dense layer's where the rest are); a
         configuration's file states it (`program.expect`)."""
         return self._layer_leaf_dtype("w13", "w1")
 
@@ -3586,6 +3599,30 @@ class GenerationEngine:
             out["experts"] = self._experts.stats()
         if self._attn_stream is not None:
             out["decode_attn"] = self._attn_stream.stats()
+            if self._win_stream is not None:  # the window layers' arm, by its own book
+                out["decode_attn"]["window"] = self._win_stream.stats()
+        out["kv_kinds"] = self._kv_kinds()
+        return out
+
+    def _kv_kinds(self) -> dict[str, dict[str, int]]:
+        """The KV cache by kind of layer: `full` (every position of
+        `max_seq_len` a slot) and, where window layers keep rings, `window`
+        (`ring_len` positions a slot): layers, bytes, positions held a layer
+        and positions live a layer (the seated rows' lengths; a window layer's
+        row holds at most its window)."""
+        lens = self._lengths[self._lengths < self.max_seq_len].astype(np.int64)
+        recurrent = self._state_pool is not None
+        out = {"full": {
+            "layers": self.cfg.n_attn_layers,
+            "bytes": pytree_nbytes({"k": self._ck, "v": self._cv["v"] if recurrent else self._cv}),
+            "positions": self.max_slots * self.max_seq_len,
+            "live_positions": int(lens.sum())}}
+        if recurrent and "win" in self._cv:
+            out["window"] = {
+                "layers": self.cfg.n_layers - self.cfg.n_attn_layers,
+                "bytes": pytree_nbytes(self._cv["win"]),
+                "positions": self.max_slots * self.cfg.ring_len,
+                "live_positions": int(np.minimum(lens, self.cfg.sliding_window).sum())}
         return out
 
     def drain_itl_samples(self) -> list[float]:
@@ -6710,6 +6747,8 @@ class GenerationEngine:
             self._note_expert_form("decode", Ba, self.decode_chunk - 1)
         if self._attn_stream is not None:
             self._attn_stream.dispatched(packed[:Ba], self.decode_chunk)
+        if self._win_stream is not None:
+            self._win_stream.dispatched(packed[:Ba], self.decode_chunk)
         if group is not None:
             maybe_fail(
                 "engine.prefill", f"slots={[s for s, _, _ in group.metas]}"
@@ -6887,7 +6926,7 @@ class GenerationEngine:
             out = np.asarray(disp.out)  # [K, Ba] — the only host sync per round
         if self._experts is not None:
             # rows past the K of tokens: the expert layer's counts [2, L, 5]
-            K, L = self.decode_chunk, self.cfg.n_layers
+            K, L = self.decode_chunk, self._experts.n_layers
             self._experts.counts = out[K:].reshape(-1)[: 10 * L].reshape(2, L, 5).tolist()
             out = out[:K]
         now = time.perf_counter()

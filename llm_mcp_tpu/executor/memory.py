@@ -7,12 +7,14 @@ starves its admission queue. This module adds the memory-manager layer in the
 style of vLLM's PagedAttention pool (Kwon et al., 2023) and Sarathi-Serve's
 SLO-aware admission, without repaginating the cache:
 
-  - **Recurrent state** (`StatePool`, at the end): what a sequence owns of a
-    linear-attention layer is not rows of the KV cache but one state of fixed
-    size, whatever its length. The pool beside the KV cache is neither paged
-    nor shared by block, so a configuration with such layers runs with the
-    features that take a sequence to be its KV blocks switched off, decided
-    once where the pool is built.
+  - **Per-slot state of fixed size** (`StatePool`, at the end): what a
+    sequence owns of a linear-attention or state-space layer is not rows of
+    the KV cache but one state of fixed size, whatever its length; of a
+    WINDOW attention layer it is a ring of its last positions, a second kind
+    of KV member that does not grow either. The pool beside the KV cache is
+    neither paged nor shared by block, so a configuration with such layers
+    runs with the features that take a sequence to be its KV blocks switched
+    off, decided once where the pool is built.
   - **Accounting**: bytes per slot are measured from the live cache pytree
     (`pytree_nbytes`), so kv8's `{q: int8, s: scale}` dict and MLA's
     asymmetric latent k/v layouts are covered without layout-specific code.
@@ -52,7 +54,8 @@ from typing import Any
 
 from ..utils.locks import OrderedLock
 
-__all__ = ["ExpertCounts", "KVPool", "KVSnapshot", "RECURRENT_OFF", "StatePool", "build_state_pool",
+__all__ = ["ExpertCounts", "KVPool", "KVSnapshot", "RECURRENT_OFF", "StatePool",
+           "build_state_pool",
            "pytree_nbytes", "bucket_len"]
 
 POLICIES = ("priority", "idle", "tokens", "slo_debt")
@@ -322,16 +325,20 @@ class KVPool:
             }
 
 
-# What takes a sequence to be its KV blocks, and so cannot carry a recurrent
-# state yet: each is off for a configuration with recurrent layers, with the
-# reason the log gives once and a counter of the times it would have engaged.
+# What takes a sequence to be its KV blocks, and so cannot carry a per-slot
+# state of fixed size yet, a recurrent state or a window layer's ring: each is
+# off for a configuration with either, with the reason the log gives once and a
+# counter of the times it would have engaged.
 RECURRENT_OFF = {
-    "prefix_cache": "a cached prefix would need the state as it stood at the block boundary",
-    "offload": "a preempted slot's snapshot holds KV rows and no state",
-    "migration": "the wire format of a moved sequence holds KV rows and no state",
-    "speculation": "rejected drafts roll the KV cache back by arithmetic; a state cannot be",
-    "ragged_prefill": "a packed chunk holds several prompts' tokens in one row of the scan; "
-                      "the chunked recurrence carries one state a row",
+    "prefix_cache": "a cached prefix would need the state (of a ring, its last window of "
+                    "positions) as it stood at the block boundary; a block holds full-length rows alone",
+    "offload": "a preempted slot's snapshot holds full-length KV rows, no state and no ring",
+    "migration": "the wire format of a moved sequence holds full-length KV rows, no state and no ring",
+    "speculation": "rejected drafts roll the KV cache back by arithmetic; a state cannot be, and a "
+                   "ring has already lost the positions the drafts replaced",
+    "ragged_prefill": "a packed chunk holds several prompts' tokens in one row: the chunked "
+                      "recurrence carries one state a row, and the packed kernel masks no window "
+                      "and writes by position, not by position modulo a ring",
 }
 # What the pool counts beside them, in the same block (`off`), that is NOT off:
 # whole prompts ride a decode round's weight pass in a recurrent configuration
@@ -412,15 +419,26 @@ class ExpertCounts:
                 "forms": {phase: dict(by_form) for phase, by_form in self.forms.items()}}
 
 
+def _shapes(tree: Any, prefix: str = "") -> dict[str, list[int]]:
+    """{dotted path: shape} of every array leaf of a nested dict."""
+    if isinstance(tree, dict):
+        return {k: v for name, sub in tree.items()
+                for k, v in _shapes(sub, f"{prefix}{name}.").items()}
+    return {prefix[:-1]: list(tree.shape)}
+
+
 def build_state_pool(cfg: Any, max_slots: int, state: Any, log: Any) -> "StatePool | None":
-    """The pool's book for a configuration with recurrent layers (`state` is
-    the device tree the engine allocated), None for any other. The one place
-    that says what such a configuration runs without."""
+    """The pool's book for a configuration whose layers keep a per-slot state
+    of fixed size beside the full-length KV cache, recurrent layers or window
+    layers on rings (`state` is the device tree the engine allocated for it),
+    None for any other. The one place that says what such a configuration runs
+    without."""
     if not getattr(cfg, "recurrent", False):
         return None
-    pool = StatePool(max_slots=max_slots, nbytes=pytree_nbytes(state),
-                     layout={k: list(v.shape) for k, v in state.items()})
-    log.info("recurrent state pool: %.1f MB a slot, %d slots beside the KV cache",
+    ring = cfg.recurrent_kind == "win"
+    pool = StatePool(max_slots=max_slots, nbytes=pytree_nbytes(state), layout=_shapes(state))
+    log.info("%s: %.1f MB a slot, %d slots beside the KV cache",
+             "window layers' rings" if ring else "recurrent state pool",
              pool.bytes_per_slot / (1 << 20), max_slots)
     for feature, why in RECURRENT_OFF.items():
         log.info("%s is off for %s: %s", feature, cfg.name, why)

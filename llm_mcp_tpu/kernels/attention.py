@@ -344,8 +344,16 @@ def _attend_q8_kernel(
     o_ref,  # [1, Hkv, G, hd] — attention output
     *,
     scale: float,
+    window: int = 0,
 ):
     """One grid cell = one batch row, all KV heads.
+
+    With `window` the tile is a window layer's RING of S positions (a power of
+    two, at least the window): position p lies at index p mod S, so index j
+    holds the position `(w - j) mod S` back from this step's, seen while that
+    is inside the window and not before the sequence's start; the index this
+    step's position wraps onto holds the position S back, out of the window,
+    and takes the new vectors as any cache's position w does.
 
     The cache rides the FUSED layout (models/llama.py:init_kv_cache): K
     heads [0, Hkv), V heads [Hkv, 2*Hkv) of one int8 payload array, so the
@@ -387,20 +395,21 @@ def _attend_q8_kernel(
     s = s_i.astype(jnp.float32) * (scale * qsc)[..., None] * kss[:, None, :]
 
     pos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, S), 2)
+    at_w, seen = _ring_masks(pos, w, S, window)
     # the tile holds the PRE-append cache — position w's score/value come
     # from the unquantized new vectors instead (exact; the quantized row
     # scatters into the cache outside the kernel)
     s_new = jnp.sum(q * nk[:, None, :], axis=-1, keepdims=True) * scale  # [Hkv, G, 1]
-    s = jnp.where(pos == w, s_new, s)
-    s = jnp.where(pos <= w, s, NEG_INF)
+    s = jnp.where(at_w(), s_new, s)
+    s = jnp.where(seen(), s, NEG_INF)
 
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    p_w = jnp.sum(jnp.where(pos == w, p, 0.0), axis=-1, keepdims=True)  # [Hkv, G, 1]
+    p_w = jnp.sum(jnp.where(at_w(), p, 0.0), axis=-1, keepdims=True)  # [Hkv, G, 1]
     # fold v's dequant scales into the probs, then quantize the prob rows so
     # the PV dot also runs s8 x s8 on the MXU
-    pv = jnp.where(pos == w, 0.0, p * vss[:, None, :])  # [Hkv, G, S]
+    pv = jnp.where(at_w(), 0.0, p * vss[:, None, :])  # [Hkv, G, S]
     pa = jnp.max(pv, axis=-1)  # [Hkv, G]
     psc = jnp.maximum(pa / 127.0, 1e-30)
     p8 = jnp.round(pv / psc[..., None]).astype(jnp.int8)
@@ -412,6 +421,18 @@ def _attend_q8_kernel(
     )  # [Hkv, G, hd]
     ctx = ctx_i.astype(jnp.float32) * psc[..., None] + p_w * nv[:, None, :]
     o_ref[0] = (ctx / l).astype(o_ref.dtype)
+
+
+def _ring_masks(pos, w, S: int, window: int):
+    """(at_w, seen): which of a tile's indices `pos` holds this step's position
+    `w`, and which hold positions the step attends, each as a function that
+    makes the mask where it is used. A full-length tile holds position p at
+    index p. A window layer's ring of S positions (`window` > 0) holds it at
+    p mod S, so index j's position lies `(w - j) mod S` behind this step's."""
+    if window:
+        back = (w - pos) & (S - 1)
+        return (lambda: back == 0), (lambda: (back < window) & (back <= w))
+    return (lambda: pos == w), (lambda: pos <= w)
 
 
 def _unpack_scale_lanes(srow, n_heads: int, scale_dtype):
@@ -863,7 +884,7 @@ def q8_block_tokens(payload_heads: int, seq_len: int, head_dim: int) -> int:
 
 
 class AttnStream:
-    """Host-side book of what the blocked int8 decode-attention arm streams
+    """Host-side book of what the int8 decode-attention arms stream
     (`decode_attend_q8`), from the positions the host packs
     for each decode round and the block size in force for the cache's shape
     (`q8_block_tokens`): over the steps of the rounds dispatched, for ONE layer's
@@ -872,33 +893,41 @@ class AttnStream:
     rows). Live over streamed is the share of the arm's traffic that is work.
     A count of what the arm WOULD fetch: a round whose fill takes the whole-S
     arm under the dispatcher's `lax.cond` (0.55 of rows x length; no cell of
-    the benchmark comes near) is counted the same."""
+    the benchmark comes near) is counted the same. With `window` the cache is a
+    window layer's ring and the arm the whole-tile one alone: every row of a
+    round streams the ring in full, and a seated row's live positions are its
+    last `window` (`max_seq_len`: the length at which a row is parked, the
+    full-length cache's)."""
 
-    def __init__(self, cache_q_shape: tuple[int, ...]):
+    def __init__(self, cache_q_shape: tuple[int, ...], window: int = 0, max_seq_len: int = 0):
         _, _, heads, self.seq_len, head_dim = cache_q_shape
+        self.window = window
+        self.parked_at = max_seq_len or self.seq_len
         # 0: the whole-S arm alone runs here, and streams every row in full
         self.block_tokens = (q8_block_tokens(heads, self.seq_len, head_dim)
-                             if blocked_arm_fits(head_dim, _interpret()) else 0)
+                             if not window and blocked_arm_fits(head_dim, _interpret()) else 0)
         self.steps = self.tokens_streamed = self.tokens_live = 0
 
     def dispatched(self, lengths: np.ndarray, steps: int) -> None:
         """A decode round of `steps` steps went out with the rows at
         `lengths` (this step's position a row; >= the cache's length: parked)."""
         w = lengths[:, None].astype(np.int64) + np.arange(steps)
-        w = np.where(lengths[:, None] >= self.seq_len, self.seq_len, w)  # parked stays parked
+        w = np.where(lengths[:, None] >= self.parked_at, self.parked_at, w)  # parked stays parked
         self.steps += steps
         if self.block_tokens:
             blocks = blocked_row_blocks(w, self.seq_len, self.block_tokens, xp=np)
             self.tokens_streamed += int(blocks.sum()) * self.block_tokens
         else:
             self.tokens_streamed += w.size * self.seq_len
-        self.tokens_live += int(np.where(w < self.seq_len, w + 1, 0).sum())
+        live = np.minimum(w + 1, self.window) if self.window else w + 1
+        self.tokens_live += int(np.where(w < self.parked_at, live, 0).sum())
 
     def stats(self) -> dict:
         return {"block_tokens": self.block_tokens, "steps": self.steps,
                 "tokens_streamed": self.tokens_streamed, "tokens_live": self.tokens_live,
                 "live_over_streamed": round(self.tokens_live / self.tokens_streamed, 4)
-                if self.tokens_streamed else None}
+                if self.tokens_streamed else None,
+                **({"window": self.window, "ring_tokens": self.seq_len} if self.window else {})}
 
 
 def fused_q8_heads(cache_k: dict) -> tuple[int, int]:
@@ -957,7 +986,7 @@ def _decode_attend_q8_fallback(
     return ctx.astype(q.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "scale", "block_s"))
+@functools.partial(jax.jit, static_argnames=("interpret", "scale", "block_s", "window"))
 def decode_attend_q8(
     q: jnp.ndarray,  # [Ba, Hkv, G, hd] — COMPACT batch (active rows only)
     new_k: jnp.ndarray,  # [Ba, Hkv, hd] — post-rope K for this step
@@ -976,6 +1005,8 @@ def decode_attend_q8(
     interpret: bool | None = None,
     block_s: int | None = None,  # the blocked arm's block; None = the rule
     #   (`q8_block_tokens`). Given only by scripts/attn_block_sweep.py and tests
+    window: int = 0,  # > 0: `cache_k` is a window layer's RING of S positions,
+    #   position p at index p mod S, and a row sees its last `window` positions
 ) -> jnp.ndarray:
     """Attention over the FUSED int8 KV cache for one layer of the decode
     step (layout: models/llama.py:init_kv_cache — K heads, V heads, and an
@@ -993,6 +1024,10 @@ def decode_attend_q8(
     The int8 payload streams from HBM straight into s8 x s8 -> s32 MXU dots
     (XLA's einsum path materializes a dequantized bf16 copy and runs ~2x
     slower than the bf16 cache); per-token dequant scales fold in post-dot.
+    A window layer's ring (`window`) is short by construction and takes the
+    whole-tile arm alone, under a name of its own (`decode_attn_win_q8`): it
+    streams the ring and nothing else, and wraps by a mask.
+
     The caller owns the cache append (single-row write-back blocks would
     violate TPU (8, 128) block alignment): whether the row at `lengths[b]`
     has been scattered yet or not, the kernel overrides that position's
@@ -1013,6 +1048,8 @@ def decode_attend_q8(
     BS = block_s or q8_block_tokens(2 * Hkv + p, S, hd)
     if not blocked_arm_fits(hd, interp):
         BS = 0
+    if window and (not can_whole or S & (S - 1) or S < window or block_tables is not None):
+        raise NotImplementedError(f"a ring of {S} positions for a window of {window}")
     if not can_whole and BS == 0:
         # no whole-S fit and no int8-tileable block divides S: exact f32
         # math of the reference (slower, never wrong)
@@ -1045,7 +1082,7 @@ def decode_attend_q8(
         # pipelined across grid cells — the cheaper shape once rows are
         # mostly full. The payload block stops at head 2*Hkv: the packed
         # scale pseudo-head is blocked-arm fuel and never enters VMEM here.
-        kernel = functools.partial(_attend_q8_kernel, scale=sc)
+        kernel = functools.partial(_attend_q8_kernel, scale=sc, window=window)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,  # layer [1], slot ids [Ba], lengths [Ba]
             grid=(B,),
@@ -1070,7 +1107,7 @@ def decode_attend_q8(
         )
         return pl.pallas_call(
             kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp,
-            name="decode_attn_q8_whole",
+            name="decode_attn_win_q8" if window else "decode_attn_q8_whole",
         )(*args)
 
     def run_blocked():
@@ -1170,7 +1207,7 @@ def decode_attend_q8(
     mode = os.environ.get("LLM_MCP_TPU_Q8_DECODE", "auto")
 
     def run_contig():
-        if mode == "whole" and can_whole:
+        if window or (mode == "whole" and can_whole):
             return run_whole()
         if mode == "blocked" and BS:
             return run_blocked()
@@ -1246,13 +1283,15 @@ def _attend_bf16_kernel(
     o_ref,  # [1, 1, G, hd]
     *,
     scale: float,
+    window: int = 0,
 ):
     """Whole-S bf16 decode attention, one grid cell = one (batch row, KV
     head) — the bf16 sibling of `_attend_q8_kernel`, with the same
     compaction indirection (slot ids), traced layer index, and exact
     current-position override. A per-(row, head) cell keeps the VMEM
     per-position cost at ~2·hd·2 bytes so the whole-S arm reaches the same
-    ~12K-position cap as the q8 arm (`decode_pallas_max_seq`)."""
+    ~12K-position cap as the q8 arm (`decode_pallas_max_seq`). `window`: the
+    tile is a window layer's ring, as in `_attend_q8_kernel`."""
     b = pl.program_id(0)
     w = lengths_ref[b]
     S = k_ref.shape[3]
@@ -1271,19 +1310,20 @@ def _attend_bf16_kernel(
         * scale
     )  # [G, S]
     pos = jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
+    at_w, seen = _ring_masks(pos, w, S, window)
     # the tile holds the PRE-append cache — position w's score/value come
     # from the exact new vectors (append happens outside the kernel)
     s_new = (
         jnp.sum(q.astype(jnp.float32) * nk[None, :], axis=-1, keepdims=True) * scale
     )  # [G, 1]
-    s = jnp.where(pos == w, s_new, s)
-    s = jnp.where(pos <= w, s, NEG_INF)
+    s = jnp.where(at_w(), s_new, s)
+    s = jnp.where(seen(), s, NEG_INF)
 
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    p_w = jnp.sum(jnp.where(pos == w, p, 0.0), axis=-1, keepdims=True)  # [G, 1]
-    pv = jnp.where(pos == w, 0.0, p)
+    p_w = jnp.sum(jnp.where(at_w(), p, 0.0), axis=-1, keepdims=True)  # [G, 1]
+    pv = jnp.where(at_w(), 0.0, p)
     ctx = jax.lax.dot_general(
         pv.astype(v.dtype), v, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
@@ -1575,7 +1615,7 @@ def _decode_attend_bf16_fallback(
     return ctx.astype(q.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "scale"))
+@functools.partial(jax.jit, static_argnames=("interpret", "scale", "window"))
 def decode_attend_bf16(
     q: jnp.ndarray,  # [Ba, Hkv, G, hd] — COMPACT batch (active rows only)
     new_k: jnp.ndarray,  # [Ba, Hkv, hd] — post-rope K for this step
@@ -1592,6 +1632,7 @@ def decode_attend_bf16(
     pool_v: jnp.ndarray | None = None,
     scale: float = 0.0,  # query scale (0 = head_dim**-0.5)
     interpret: bool | None = None,
+    window: int = 0,  # > 0: the caches are a window layer's RING (`decode_attend_q8`)
 ) -> jnp.ndarray:
     """Attention over the bf16 (or f32) split KV cache for one layer of the
     decode step — the bf16 twin of `decode_attend_q8`: same scan-invariant
@@ -1612,6 +1653,8 @@ def decode_attend_bf16(
     can_whole = S <= decode_pallas_max_seq(hd, Hkv, Hkv * G, quantized=False)
     # BS must divide S (a floored block count would silently drop the tail)
     BS = next((c for c in (256, 128, 64, 32) if S % c == 0), 0)
+    if window and (not can_whole or S & (S - 1) or S < window or block_tables is not None):
+        raise NotImplementedError(f"a ring of {S} positions for a window of {window}")
     if not can_whole and BS == 0:
         _note_fall("decode_attend_bf16", f"S={S}: no whole-S fit, no block size", interp)
         return _decode_attend_bf16_fallback(
@@ -1636,7 +1679,7 @@ def decode_attend_bf16(
     out_shape = jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype)
 
     def run_whole():
-        kernel = functools.partial(_attend_bf16_kernel, scale=sc)
+        kernel = functools.partial(_attend_bf16_kernel, scale=sc, window=window)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,  # layer [1], slot ids [Ba], lengths [Ba]
             grid=(B, Hkv),
@@ -1659,7 +1702,7 @@ def decode_attend_bf16(
         )
         return pl.pallas_call(
             kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp,
-            name="decode_attn_bf16_whole",
+            name="decode_attn_win_bf16" if window else "decode_attn_bf16_whole",
         )(*args)
 
     def run_blocked():
@@ -1738,7 +1781,7 @@ def decode_attend_bf16(
     mode = os.environ.get("LLM_MCP_TPU_BF16_DECODE", "auto")
 
     def run_contig():
-        if mode == "whole" and can_whole:
+        if window or (mode == "whole" and can_whole):
             return run_whole()
         if mode == "blocked" and BS:
             return run_blocked()

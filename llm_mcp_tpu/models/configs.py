@@ -62,13 +62,18 @@ class ModelConfig:
     attn_multiplier: float = 0.0
     logit_softcap: float = 0.0  # Gemma2: logits = cap * tanh(logits / cap)
     attn_softcap: float = 0.0  # Gemma2: same cap on attention scores
-    sliding_window: int = 0  # Mistral/Gemma2: local-attention window (0 = off)
+    sliding_window: int = 0  # the window layers' size (0 = no layer slides)
+    # the window of EACH layer as the family publishes it, 0 = a global layer
+    # (Mistral: the size on every layer; Gemma2: size, 0 alternating;
+    # K-EXAONE: 128, 128, 128, 0 repeated). Empty = every layer is global.
+    # `periodic_windows` writes a fixed period out. In the dense decoder
+    # (models/llama.py) every layer keeps a full-length cache and the window is
+    # a mask; in the decoder of unlike layers (models/hybrid.py, chosen by
+    # `gqa_layers`) a window layer keeps a RING of `ring_len` positions a slot
+    sliding_windows: tuple[int, ...] = ()
     # Gemma2 query_pre_attn_scalar: scores scale by this**-0.5 instead of
     # head_dim**-0.5 (9B: dim/n_heads = 224 while head_dim = 256). 0 → head_dim.
     query_pre_attn_scalar: float = 0.0
-    # every `sliding_pattern`-th layer is GLOBAL, the rest sliding
-    # (1 = all layers sliding, Mistral; 2 = alternating, Gemma2)
-    sliding_pattern: int = 1
     post_norms: bool = False  # Gemma2: extra RMSNorm after attn and after FFN
     # MLA (DeepSeek-V2/V3 multi-head latent attention, arch="mla"): q/kv
     # project through low-rank latents; the KV cache stores ONE latent
@@ -112,11 +117,13 @@ class ModelConfig:
     # sigmoid with a selection bias that chooses and does not weigh.
     n_router_experts: int = 0
     router_score: str = "softmax"  # softmax | sigmoid
-    # Hybrid of softmax-attention and recurrent layers (models/hybrid.py):
-    # layer i is a GQA layer iff i in gqa_layers, else a layer with a per-slot
-    # recurrent state: a Mamba-2 state-space layer (models/ssm.py) where
-    # `ssm_heads` is set, else a gated delta-rule layer (models/kda.py: KDA or
-    # Gated DeltaNet). Empty = every layer is the family's attention layer.
+    # A decoder of unlike layers (models/hybrid.py): layer i is a GQA layer
+    # with rows of the full-length KV cache iff i in gqa_layers, else a layer
+    # whose per-slot state has a fixed size (`recurrent_kind`): a Mamba-2
+    # state-space layer (models/ssm.py) where `ssm_heads` is set, a gated
+    # delta-rule layer (models/kda.py: KDA or Gated DeltaNet) where `lin_heads`
+    # is, else a WINDOW attention layer (`sliding_windows`) on a ring of its
+    # last positions. Empty = every layer is the family's attention layer.
     gqa_layers: tuple[int, ...] = ()
     gqa_interval: int = 0  # linear layers between two GQA layers (published)
     lin_heads: int = 0
@@ -140,6 +147,13 @@ class ModelConfig:
     ssm_conv: int = 4
     attn_gate: bool = False  # GQA output gate: attn * sigmoid(x W_gate)
     use_rope: bool = True  # False: no positional encoding anywhere (NoPE)
+    # False: where window and global layers are mixed, only the window layers
+    # rotate and a global layer attends by content alone (EXAONE 4.0)
+    global_rope: bool = True
+    # Multi-token prediction (DeepSeek-V3's module; models/hybrid.py:
+    # `init_mtp_params`, `mtp_logits`): how many such modules the family
+    # publishes. The engine holds none: no step program runs one yet
+    mtp_layers: int = 0
     # Where a sub-layer's RMSNorm sits (models/hybrid.py, the hybrid decoder's
     # three layer halves): "input": h + Mix(norm(h)) (llama); "output":
     # h + norm(Mix(h)) (OLMo 2 / OLMo 3), the same weight leaves either way
@@ -167,8 +181,10 @@ class ModelConfig:
 
     @property
     def recurrent(self) -> bool:
-        """True when some layers carry a per-slot recurrent state: a sequence
-        is then more than its KV blocks (executor/memory.py: StatePool)."""
+        """True when some layers keep a per-slot state of fixed size beside the
+        full-length KV cache (a recurrent state, or a window layer's ring): a
+        sequence is then more than its KV blocks (executor/memory.py:
+        StatePool)."""
         return bool(self.gqa_layers)
 
     @property
@@ -179,19 +195,33 @@ class ModelConfig:
     @property
     def recurrent_kind(self) -> str:
         """The kind of the layers that are not GQA layers, which is also their
-        key in the parameter tree: "ssm" (Mamba-2) or "kda" (the delta rule)."""
-        return "ssm" if self.ssm_heads else "kda"
+        key in the parameter tree: "ssm" (Mamba-2), "kda" (the delta rule) or
+        "win" (window attention on a ring)."""
+        return "ssm" if self.ssm_heads else "kda" if self.lin_heads else "win"
+
+    @property
+    def ring_len(self) -> int:
+        """Positions a window layer's ring holds a slot: the window, rounded up
+        to a power of two of at least 128 (a lane tile of the scales; the
+        kernels wrap by a mask). A step reads the ring BEFORE it writes, so the
+        position a write replaces, `ring_len` back, is already out of the
+        window and the ring needs no room beyond it."""
+        return max(128, 1 << (self.sliding_window - 1).bit_length()) if self.sliding_window else 0
 
     @property
     def layer_period(self) -> tuple[str, ...]:
-        """Kinds ("gqa" | "kda" | "ssm") of one period of the layer pattern; the
-        layer stack is this period repeated (models/hybrid.py scans by it)."""
+        """Kinds ("gqa" | "kda" | "ssm" | "win") of one period of the layer
+        pattern AFTER the leading dense layers (`first_dense_layers`, which the
+        decoder unrolls before its scan where the feed-forward has experts);
+        the rest of the stack is this period repeated (models/hybrid.py scans
+        by it)."""
         rec = self.recurrent_kind
-        kinds = ["gqa" if i in self.gqa_layers else rec for i in range(self.n_layers)]
-        for p in range(1, self.n_layers + 1):
-            if self.n_layers % p == 0 and kinds == kinds[:p] * (self.n_layers // p):
+        k = self.first_dense_layers if self.n_experts else 0
+        kinds = ["gqa" if i in self.gqa_layers else rec for i in range(k, self.n_layers)]
+        for p in range(1, len(kinds) + 1):
+            if len(kinds) % p == 0 and kinds == kinds[:p] * (len(kinds) // p):
                 return tuple(kinds[:p])
-        raise AssertionError("unreachable: p = n_layers always matches")
+        raise AssertionError("unreachable: p = the layers' number always matches")
 
     @property
     def yarn_attn_mscale(self) -> float:
@@ -249,7 +279,7 @@ class ModelConfig:
             ng = len(self.gqa_layers)
             ffn_total += ng * attn + (self.n_layers - ng) * ssm  # exact: no mean a layer
             attn = 0
-        elif self.gqa_layers:  # hybrid: GQA (+ gate) layers and delta-rule layers
+        elif self.gqa_layers and self.lin_heads:  # hybrid: GQA (+ gate) layers and delta-rule layers
             hk, hv = self.lin_heads * self.lin_head_dim, self.lin_heads * self.lin_dv
             mix = (self.dim * (2 * hk + hv)  # wq, wk, wv
                    + hv * self.dim  # wo
@@ -267,6 +297,16 @@ class ModelConfig:
         embed = self.vocab_size * self.dim
         head = 0 if self.tie_embeddings or self.arch == "encoder" else self.vocab_size * self.dim
         return embed + self.n_layers * per_layer_rest + ffn_total + head + self.dim
+
+
+def periodic_windows(window: int, period: int, n_layers: int) -> tuple[int, ...]:
+    """`sliding_windows` of a family that states a size and a fixed period:
+    every `period`-th layer global, the rest sliding (1: every layer slides,
+    Mistral; 2: alternating, Gemma2). Empty where nothing slides."""
+    if not window:
+        return ()
+    return tuple(
+        window if period == 1 or li % period != period - 1 else 0 for li in range(n_layers))
 
 
 # Canonical architectures. Llama-3.1-8B per the published architecture
@@ -569,6 +609,79 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         attn_multiplier=0.0625,
         params_b=0.001,
     ),
+    # K-EXAONE-236B-A23B (LGAI-EXAONE/K-EXAONE-236B-A23B config.json) as ONE
+    # CHIP of an 8-way expert-parallel group, rank 0 of pipeline stage 0: the
+    # model's first 5 of 48 layers (the leading dense layer, then one whole
+    # period of the expert layers: window, window, global, window), experts
+    # 0-15 of the published 128 (the router keeps its 128 columns), 19,200 of
+    # 153,600 vocabulary rows. Every width is the published one. Norms on the
+    # sub-layers' outputs, a q/k norm a head, no rotation on a global layer and
+    # the router's selection bias are EXAONE 4.0's and DeepSeek-V3's
+    # conventions, assumed: benchmark/configs/k-exaone-236b-ep8-bf16.json lists
+    # them. The multi-token-prediction module (`mtp_layers`) is built where a
+    # caller asks (models/hybrid.py), never by the engine.
+    "k-exaone-236b-ep8": ModelConfig(
+        name="k-exaone-236b-ep8",
+        vocab_size=19_200,
+        dim=6144,
+        n_layers=5,
+        n_heads=64,
+        n_kv_heads=8,
+        head_dim=128,
+        ffn_hidden=18_432,  # the leading dense layer's
+        rope_theta=1_000_000.0,
+        norm_eps=1e-5,
+        max_seq_len=262_144,
+        n_experts=16,
+        n_router_experts=128,
+        experts_per_tok=8,
+        n_shared_experts=1,
+        moe_ffn_hidden=2048,
+        first_dense_layers=1,
+        norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        router_score="sigmoid",
+        gqa_layers=(3,),
+        sliding_window=128,
+        sliding_windows=(128, 128, 128, 0, 128),
+        qk_norm=True,
+        norm_placement="output",
+        global_rope=False,
+        mtp_layers=1,
+        params_b=3.7,
+    ),
+    # the same shape at toy size: a dense window layer, then window, window,
+    # global, window with 16 experts of which 4 are held; a ring of 128
+    # positions for a window of 32
+    "tiny-kexaone": ModelConfig(
+        name="tiny-kexaone",
+        vocab_size=512,
+        dim=128,
+        n_layers=5,
+        n_heads=4,
+        n_kv_heads=2,
+        head_dim=32,
+        ffn_hidden=256,
+        rope_theta=10_000.0,
+        max_seq_len=512,
+        n_experts=4,
+        n_router_experts=16,
+        experts_per_tok=4,
+        n_shared_experts=1,
+        moe_ffn_hidden=64,
+        first_dense_layers=1,
+        norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        router_score="sigmoid",
+        gqa_layers=(3,),
+        sliding_window=32,
+        sliding_windows=(32, 32, 32, 0, 32),
+        qk_norm=True,
+        norm_placement="output",
+        global_rope=False,
+        mtp_layers=1,
+        params_b=0.002,
+    ),
     # the same shape at toy size: one period, 16 experts of which 4 are held
     "tiny-solar": ModelConfig(
         name="tiny-solar",
@@ -740,7 +853,7 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         rope_theta=10_000.0,
         max_seq_len=32_768,
         sliding_window=4096,
-        sliding_pattern=1,
+        sliding_windows=periodic_windows(4096, 1, 32),
         params_b=7.2,
     ),
     # Gemma-2-9B: gelu FFN, (1+w) RMSNorm with post-norms, sqrt(dim) embed
@@ -764,7 +877,7 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         logit_softcap=30.0,
         attn_softcap=50.0,
         sliding_window=4096,
-        sliding_pattern=2,
+        sliding_windows=periodic_windows(4096, 2, 42),
         query_pre_attn_scalar=224.0,  # dim / n_heads, NOT head_dim
         post_norms=True,
         tie_embeddings=True,
@@ -811,7 +924,7 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         rope_theta=10_000.0,
         max_seq_len=512,
         sliding_window=64,
-        sliding_pattern=1,
+        sliding_windows=periodic_windows(64, 1, 2),
         tie_embeddings=True,
         params_b=0.001,
     ),
@@ -831,7 +944,7 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         logit_softcap=30.0,
         attn_softcap=50.0,
         sliding_window=64,
-        sliding_pattern=2,
+        sliding_windows=periodic_windows(64, 2, 2),
         query_pre_attn_scalar=24.0,  # ≠ head_dim (32) so tests exercise it
         post_norms=True,
         tie_embeddings=True,
@@ -1058,7 +1171,7 @@ def config_from_hf(doc: dict, name: str = "") -> ModelConfig:
         kw["qk_norm"] = True
     elif mt == "mistral":
         kw["sliding_window"] = int(doc.get("sliding_window") or 0)
-        kw["sliding_pattern"] = 1
+        kw["sliding_windows"] = periodic_windows(kw["sliding_window"], 1, kw["n_layers"])
     elif mt == "mixtral":
         kw["n_experts"] = int(doc["num_local_experts"])
         kw["experts_per_tok"] = int(doc.get("num_experts_per_tok") or 2)
@@ -1070,7 +1183,8 @@ def config_from_hf(doc: dict, name: str = "") -> ModelConfig:
             logit_softcap=float(doc.get("final_logit_softcapping") or 0.0),
             attn_softcap=float(doc.get("attn_logit_softcapping") or 0.0),
             sliding_window=int(doc.get("sliding_window") or 0),
-            sliding_pattern=2,
+            sliding_windows=periodic_windows(
+                int(doc.get("sliding_window") or 0), 2, kw["n_layers"]),
             query_pre_attn_scalar=float(doc.get("query_pre_attn_scalar") or 0.0),
             post_norms=True,
             tie_embeddings=True,

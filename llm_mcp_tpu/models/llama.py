@@ -387,15 +387,11 @@ def _ffn_residual(
 
 
 def layer_windows(cfg: ModelConfig) -> jnp.ndarray:
-    """Per-layer attention window sizes, [L] int32 (0 = global attention).
-
-    `sliding_pattern=1` → every layer sliding (Mistral); `=p` → every p-th
-    layer global, the rest sliding (Gemma2 alternation with p=2)."""
-    p = max(cfg.sliding_pattern, 1)
-    wins = [
-        cfg.sliding_window if cfg.sliding_window and (p == 1 or li % p != p - 1) else 0
-        for li in range(cfg.n_layers)
-    ]
+    """Per-layer attention window sizes, [L] int32 (0 = global attention): the
+    family's published list (`cfg.sliding_windows`; a fixed period is written
+    out by `configs.periodic_windows`), zeros where nothing slides."""
+    wins = cfg.sliding_windows or (0,) * cfg.n_layers
+    assert len(wins) == cfg.n_layers, (cfg.name, len(wins), cfg.n_layers)
     return jnp.asarray(wins, dtype=jnp.int32)
 
 
@@ -455,11 +451,12 @@ def prefill_layer(
 
 def prefill_attn(
     cfg: ModelConfig, lp: Params, h: jnp.ndarray, cos, sin, mask, lengths,
-    attn_impl: str = "xla", window: jnp.ndarray | int = 0,
+    attn_impl: str = "xla", window: jnp.ndarray | int = 0, rope: bool | None = None,
 ) -> tuple[jnp.ndarray, tuple[jnp.ndarray, jnp.ndarray]]:
     """The attention half of `prefill_layer`: (h after the residual add,
-    (kh, vh) head-major prompt K/V). models/hybrid.py runs it for its GQA
-    layers; a family without rope (cfg.use_rope False) skips the rotation."""
+    (kh, vh) head-major prompt K/V). models/hybrid.py runs it for its GQA and
+    window layers; a family without rope (cfg.use_rope False), or a layer that
+    does not rotate (`rope` False), skips the rotation."""
     B, S, _ = h.shape
     hd = cfg.resolved_head_dim
     H, Hkv = cfg.n_heads, cfg.n_kv_heads
@@ -473,7 +470,7 @@ def prefill_attn(
         q = q.reshape(B, S, H, hd)
         k = k.reshape(B, S, Hkv, hd)
         v = v.reshape(B, S, Hkv, hd)
-        if cfg.use_rope:
+        if cfg.use_rope if rope is None else rope:
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
 
@@ -667,7 +664,8 @@ def mixed_step_supported(cfg: ModelConfig) -> bool:
     decode step is `_decode_step_q8` with a dense feed-forward (global
     attention, no score softcap, rope, no latent cache, no routed experts).
     `hybrid.hybrid_mixed_step`: a stack with recurrent layers, which has no
-    rope (`hybrid_decode_step` applies none), whatever its feed-forward."""
+    rope, whatever its feed-forward; a stack of window and global layers rotates
+    (and keeps rings, which the mixed step does not write) and takes `admit_fn`."""
     if cfg.kv_lora_rank or cfg.sliding_window or cfg.attn_softcap:
         return False
     if cfg.recurrent:
@@ -880,14 +878,20 @@ def _decode_step_bf16(
 
 def _chunk_attention(
     cfg: ModelConfig, params: Params, cache_k: Any, tokens, slots, starts, nvalid,
-    skey: int = 0, paged: dict | None = None,
+    skey: int = 0, paged: dict | None = None, ring: int = 0,
 ):
     """What the layers of a bucketed chunk share: (h0 [A, C, D], attend, write).
-    `attend(h, ck_all, cv_all, li, lp, win) -> (h, kh, vh)` is the attention
-    half of a layer reading cache layer `li` (past rows from the PRE-write
-    cache, the chunk's own K/V from registers); `write(ck_all, cv_all, kh, vh,
-    li)` lands the chunk's rows. `llama_prefill_chunk_batch` scans them with the
-    dense or routed FFN between; models/hybrid.py runs them for its GQA layers."""
+    `attend(h, ck_all, cv_all, li, lp, win, rope) -> (h, kh, vh)` is the
+    attention half of a layer reading cache layer `li` (past rows from the
+    PRE-write cache, the chunk's own K/V from registers; `rope` False: a layer
+    that does not rotate); `write(ck_all, cv_all, kh, vh, li)` lands the chunk's
+    rows. `llama_prefill_chunk_batch` scans them with the dense or routed FFN
+    between; models/hybrid.py runs them for its GQA layers, and once more for
+    its window layers with `ring` their window: `cache_k` is then the RING
+    [Lw, B, .., R, hd], position p at index p mod R. The past segment is
+    the whole ring under a mask of each row's own positions, the window is in
+    both masks, and `write` lands each row's last R VALID positions at their
+    wrapped indices (a padding row of a ragged chunk would replace a live one)."""
     quantized = isinstance(cache_k, dict)
     # fused quantized cache: axis 2 of "q" is 2*Hkv + p — take Hkv from cfg
     L, B, _, S, hd = _cache_shape(cache_k)
@@ -895,7 +899,8 @@ def _chunk_attention(
     H = cfg.n_heads
     G = H // Hkv
     A, C = tokens.shape
-    Sk = min(skey, S) if skey else S
+    Sk = S if ring else min(skey, S) if skey else S
+    assert not ring or paged is None, "a ring is never paged"
     neg = jnp.float32(-1e30)
     slots = jnp.asarray(slots, dtype=jnp.int32)
     starts = jnp.asarray(starts, dtype=jnp.int32)
@@ -923,13 +928,19 @@ def _chunk_attention(
     self_mask = jnp.broadcast_to(
         (c_idx[None, :] <= c_idx[:, None])[None], (A, C, C)
     )
+    if ring:
+        # index j holds the last position before the chunk that wraps onto it
+        # (negative: never written by this sequence), seen inside the window
+        ring_pos = _ring_positions(starts, S)[:, None, :]  # [A, 1, R]
+        past_mask = (ring_pos >= 0) & (q_pos[:, :, None] - ring_pos < ring)  # [A, C, R]
+        self_mask = self_mask & (c_idx[:, None] - c_idx[None, :] < ring)[None]
 
-    def attend(h, ck_all, cv_all, li, lp, win):
+    def attend(h, ck_all, cv_all, li, lp, win, rope=None):
         with jax.named_scope("attn"):
             x = _sub_in(cfg, h, lp["attn_norm"])
             q, k, v = _qkv(cfg, lp, x)
             q, k = q.reshape(A, C, H, hd), k.reshape(A, C, Hkv, hd)
-            if cfg.use_rope:
+            if cfg.use_rope if rope is None else rope:
                 q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
             v = v.reshape(A, C, Hkv, hd)
             kh = k.transpose(0, 2, 1, 3)  # [A, Hkv, C, hd]
@@ -1013,7 +1024,7 @@ def _chunk_attention(
             s_self = _softcap(s_self * cfg.attn_scale, cfg.attn_softcap)
 
             pm, sm = past_mask, self_mask
-            if cfg.sliding_window:
+            if cfg.sliding_window and not ring:  # a ring's masks hold its window
                 pm = pm & (
                     (win == 0)
                     | (q_pos[:, :, None] - key_pos[None, None, :] < win)
@@ -1037,7 +1048,28 @@ def _chunk_attention(
 
     def write(ck_all, cv_all, kh, vh, li):
         with jax.named_scope("kv_append"):
-            if quantized:
+            if ring:
+                # the chunk's row that index j holds after it; negative: an
+                # older position, which the ring keeps
+                src = _ring_positions(starts + nvalid, S) - starts[:, None]  # [A, R]
+                take = jnp.clip(src, 0, C - 1)
+
+                def turn(plane, rows):  # plane [Lw, B, Hx, R, ..], rows [A, Hx, C, ..]
+                    for a in range(A):
+                        at = (li, slots[a]) + (0,) * (plane.ndim - 2)
+                        cur = jax.lax.dynamic_slice(plane, at, (1, 1, *plane.shape[2:]))[0, 0]
+                        new = jnp.take(rows[a], take[a], axis=1).astype(plane.dtype)
+                        kept = (src[a] >= 0).reshape((1, S) + (1,) * (plane.ndim - 4))
+                        plane = jax.lax.dynamic_update_slice(
+                            plane, jnp.where(kept, new, cur)[None, None], at)
+                    return plane
+
+                if quantized:
+                    fused = fuse_prompt_kv(kh, vh, scale_dtype=ck_all["s"].dtype)
+                    ck_all = {"q": turn(ck_all["q"], fused["q"]), "s": turn(ck_all["s"], fused["s"])}
+                else:
+                    ck_all, cv_all = turn(ck_all, kh), turn(cv_all, vh)
+            elif quantized:
                 # write the chunk's rows in cache layout: fused payload
                 # (K|V|packed scales) + plain scales, so later readers — decode
                 # kernels included — see a consistent fused entry
@@ -1062,6 +1094,14 @@ def _chunk_attention(
         return ck_all, cv_all
 
     return h, attend, write
+
+
+def _ring_positions(ends: jnp.ndarray, ring_len: int) -> jnp.ndarray:
+    """[A, R]: the position that index j of a ring of R holds once a sequence
+    has written positions [0, end): the last one below `end` that is j modulo
+    R; negative where the sequence has not reached j yet."""
+    last = jnp.asarray(ends, jnp.int32)[:, None] - 1
+    return last - (last - jnp.arange(ring_len, dtype=jnp.int32)[None, :]) % ring_len
 
 
 def llama_prefill_chunk_batch(

@@ -48,8 +48,9 @@ def route(
     `router_score == "sigmoid"` an independent sigmoid of each; the top k are
     chosen greedily over ALL columns (by score + `bias` when the family has a
     selection bias: it chooses and does not weigh). The gates are the chosen
-    scores, renormalised to sum to 1 with `norm_topk_prob`, else scaled by
-    `routed_scaling_factor` (DeepSeek-V2's raw softmax mass)."""
+    scores, renormalised to sum to 1 with `norm_topk_prob`, then scaled by
+    `routed_scaling_factor` (DeepSeek-V2 scales its raw softmax mass and does
+    not renormalise; K-EXAONE states both: renormalised, then times 2.5)."""
     k = cfg.experts_per_tok
     logits = router_logits.astype(jnp.float32)
     if cfg.router_score == "sigmoid":
@@ -63,7 +64,7 @@ def route(
         top_g = jnp.take_along_axis(scores, top_i, axis=-1)
     if cfg.norm_topk_prob and k > 1:
         top_g = top_g / jnp.sum(top_g, axis=-1, keepdims=True)  # renormalize
-    elif cfg.routed_scaling_factor != 1.0:
+    if cfg.routed_scaling_factor != 1.0:
         top_g = top_g * cfg.routed_scaling_factor
     return top_g, top_i
 

@@ -1,0 +1,80 @@
+#!/usr/bin/env python3
+"""Digests of the step programs of the tiny hybrid presets, to hold two trees
+equal before a chip call: for each preset the decode step, the mixed step, a
+bucketed chunk and a whole-prompt prefill are lowered on the CPU (kernels in
+interpret mode, so their bodies are in the text), the StableHLO is run through
+`cse, canonicalize, cse` (a duplicated index computation or a reshape in two
+steps is no other program) and hashed. Two trees whose digests agree give the
+compiler the same programs at these presets; a change to shared code of
+models/hybrid.py, llama.py, kda.py, ssm.py, moe.py or the kernels that is
+meant to leave a configuration alone shows here in seconds.
+
+    JAX_PLATFORMS=cpu python scripts/hybrid_hlo_digest.py [--tree _clean] [--out DIR]
+
+`--tree` is a checkout of another commit (`git archive <commit> | tar -x -C
+_clean`); `--out` keeps the texts, to diff where a digest differs. PR 43 read
+all twelve equal between 40d3bf3 and its own tree.
+"""
+
+from __future__ import annotations
+
+import argparse
+import hashlib
+import os
+import sys
+from functools import partial
+
+PRESETS = ("tiny-solar", "tiny-olmo-hybrid", "tiny-granite-hybrid")
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--tree", default=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    ap.add_argument("--out", default="")
+    args = ap.parse_args()
+    sys.path.insert(0, os.path.abspath(args.tree))
+
+    import jax
+    import jax.numpy as jnp
+    from jax._src.lib.mlir import passmanager
+
+    from llm_mcp_tpu.models import hybrid, llama
+    from llm_mcp_tpu.models.configs import get_config
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    B, T, R = 4, 128, 4
+    for name in PRESETS:
+        cfg = get_config(name)
+        params = jax.eval_shape(
+            partial(llama.init_llama_params, cfg, jax.random.PRNGKey(0), dtype=jnp.float32))
+        cache = jax.eval_shape(
+            partial(llama.init_kv_cache, cfg, B, 128, dtype=jnp.float32, quantized=True))
+        programs = {
+            "decode": (lambda p, ck, cv, *a: llama.llama_decode_step(
+                cfg, p, ck, cv, *a, attn_impl="pallas"), (i32(B), i32(B))),
+            "mixed": (lambda p, ck, cv, *a: hybrid.hybrid_mixed_step(cfg, p, ck, cv, *a),
+                      (i32(B), i32(B), i32(T), i32(T), i32(T), i32(R), i32(R))),
+            "chunk": (lambda p, ck, cv, *a: llama.llama_prefill_chunk_batch(
+                cfg, p, ck, cv, *a, skey=64), (i32(2, 32), i32(2), i32(2), i32(2))),
+            "prefill": (lambda p, ck, cv, *a: llama.llama_prefill(cfg, p, *a, quant_kv=True),
+                        (i32(2, 64), i32(2))),
+        }
+        for tag, (fn, operands) in programs.items():
+            module = jax.jit(fn).lower(params, cache["k"], cache["v"], *operands).compiler_ir(
+                "stablehlo")
+            with module.context:
+                passmanager.PassManager.parse("builtin.module(cse,canonicalize,cse)").run(
+                    module.operation)
+            text = str(module)
+            if args.out:
+                os.makedirs(args.out, exist_ok=True)
+                with open(os.path.join(args.out, f"{name}.{tag}.mlir"), "w") as f:
+                    f.write(text)
+            print(name, tag, hashlib.sha1(text.encode()).hexdigest()[:12], flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -115,6 +115,7 @@ def main() -> int:
     gen = GenerationEngine(
         env["TPU_MODEL"], max_slots=int(env["TPU_MAX_SLOTS"]), max_seq_len=int(env["TPU_MAX_SEQ_LEN"]),
         dtype=jnp.bfloat16, kv_quant=env["TPU_KV_QUANT"], seed=int(config.get("weights_seed", 0)),
+        **({"prefill_chunk": int(env["TPU_PREFILL_CHUNK"])} if "TPU_PREFILL_CHUNK" in env else {}),
     ).start()
     if args.prompt_bytes:
         config = dict(config, reference_request=dict(

@@ -492,14 +492,14 @@ def solar_program(which: str, cfg):
                       ck["q"], jax.lax.dynamic_slice_in_dim(ks["q"], i, 1, 1), (0, slots[i], 0, 0, 0)),
                   "s": jax.lax.dynamic_update_slice(
                       ck["s"], jax.lax.dynamic_slice_in_dim(ks["s"], i, 1, 1), (0, slots[i], 0, 0))}
-            return ck, dict(cv, state=hybrid.insert_state_row(cv["state"], vs["state"], i, slots[i]))
+            return ck, dict(cv, **hybrid.insert_state_row(cv, vs, i, slots[i]))
 
         ck, cv = jax.lax.fori_loop(0, tokens.shape[0], body, (ck, cv))
         return logits, ck, hybrid.add_counts(cv, vs)
 
     def chunk(params, ck, cv, tokens, slots, starts, nvalid):
         return llama.llama_prefill_chunk_batch(
-            cfg, params, ck, cv, tokens, slots, starts, nvalid, skey=512)
+            cfg, params, ck, cv, tokens, slots, starts, nvalid, skey=min(512, tokens.shape[1]))
 
     def mixed(params, ck, cv, tokens, lengths, p_tokens, p_rowids, p_positions, p_slots, p_last):
         # `mixed_round_fn`: the first step carries the packed prompts, three plain ones follow
@@ -653,6 +653,60 @@ def test_granite_step_programs_fit_beside_64_slots(sd, granite, chip_kernels, wh
           f"{mem.argument_size_in_bytes / 2**30:.2f} GiB")
     assert total < 15.0 * 2**30
     assert mem.alias_size_in_bytes > 4.5 * 2**30  # KV cache and state pool updated in place
+
+
+@pytest.fixture(scope="module")
+def kexaone(one_chip):
+    """`k-exaone-236b-ep8`, 64 slots x 4096, as its cell boots it."""
+    return hybrid_shapes("k-exaone-236b-ep8", one_chip, SOLAR_SLOTS, 4096)
+
+
+@pytest.mark.parametrize("which,operands", [
+    ("decode", [(64,), (64,), (64,)]),  # every slot a row
+    ("admit", [(1, 1024), (1,), (1,)]),  # the cell's one admit shape: a prompt of 640-1024 tokens
+    ("admit", [(2, 512), (2,), (2,)]),  # two shorter prompts: 1024 rows through the expert layer too
+    ("chunk", [(1, 1024), (1,), (1,), (1,)]),  # a prompt over 1024 tokens: its second chunk
+])
+def test_kexaone_step_programs_fit_and_keep_both_kinds_of_cache_in_place(
+        sd, kexaone, chip_kernels, which, operands):
+    """The decode round, the admit programs and a chunk program of
+    `k-exaone-236b-ep8` at its cell's 64 slots x 4096 compile for the described
+    v5e with their kernels: the decode attention in BOTH arms (the blocked or
+    whole-S arm over the global layer's cache, the window arm over the rings),
+    the append kernel twice (the cache, the rings), the flash prefill kernel in
+    the admit programs, as Mosaic calls with no fall to their reference. Window
+    layers hold a ring of 128 positions a slot and not 4096: the arguments are
+    the weights, 0.58 GB of the global layer's cache and 0.07 GB of rings. Each
+    program fits under 12 GiB with temporaries under 1.5 and updates both kinds
+    in place: no copy of a whole cache member among the temporaries. GiB in
+    PERF.md section 4 as "described-chip compile"."""
+    cfg, params, cache = kexaone
+    ring, full = cache["v"]["win"]["k"], cache["k"]
+    assert full["q"].shape == (1, 64, 17, 4096, 128) and ring["q"].shape == (4, 64, 17, 128, 128)
+    assert ring["s"].shape == (4, 64, 16, 128) and cache["v"]["win"]["v"] == {}
+    assert cache["v"]["moe"].shape == (2, 4, 5) and "state" not in cache["v"]
+    falls = dict(A.reference_falls)
+    compiled = jax.jit(solar_program(which, cfg), donate_argnums=(1, 2)).lower(
+        params, cache["k"], cache["v"], *(sd(shape, I32) for shape in operands)).compile()
+    assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
+    text = compiled.as_text()
+    if which == "decode":
+        assert "decode_attn_win_q8" in text and "decode_attn_q8_blocked" in text
+        assert text.count("append_kv_q8") >= 2
+    if which == "admit":
+        assert "flash_prefill_attn" in text and "decode_attn" not in text
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"kexaone {which} {operands[0]}: {total / 2**30:.2f} GiB, of it temporaries "
+          f"{mem.temp_size_in_bytes / 2**30:.2f} GiB, arguments "
+          f"{mem.argument_size_in_bytes / 2**30:.2f} GiB")
+    caches = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
+    assert round(weights / 1e9, 2) == 7.42 and 0.64e9 < caches < 0.66e9
+    assert mem.argument_size_in_bytes < weights + caches + 2**20  # rings, not 4 x 4096 positions
+    assert total < 12.0 * 2**30 and mem.temp_size_in_bytes < 1.5 * 2**30
+    assert mem.alias_size_in_bytes > 0.99 * caches  # the cache and the rings updated in place
 
 
 @pytest.mark.parametrize("rung", [128, 256])
