@@ -395,8 +395,8 @@ class ExpertCounts:
     round's tokens). `counts` [2][L][5]: rows routed, pairs on held experts,
     held experts touched, the fullest one's rows and calls, summed since boot
     over decode steps [0] and over prefills [1]. `forms`: the layer's calls
-    since boot by the form they took (moe.share_form: "expert_major" or
-    "grouped"), under "decode" and "prefill"; the host's own count, from the
+    since boot by the form they took (moe.share_form: "grouped", the one
+    there is), under "decode" and "prefill"; the host's own count, from the
     shape of each step program it dispatched, so it runs ahead of `counts` by
     what is in flight."""
 

@@ -16,8 +16,7 @@ each other), the runs, the mean ms a run, and the leaf operations inside a run
 by kind and output shape, in ms a run. Leaf operations only: a `while` spans
 its body's. The groups on top are by the patterns of `GROUPS` on an operation's
 whole HLO text (its type and its operands' types), first match wins, and each
-line says which took it. The defaults know the kernels by name, a product that
-reads an expert bank out of K-EXAONE's stack ([64, 6144, 2048]) and the
+line says which took it. The defaults know the kernels by name and the
 compiler's matmul fusions; what the expert layer does beside its products (the
 gather, the weighing, the sum of a row's pairs) has shapes that other
 operations share (8,192 pairs a prompt and 8,192 = 64 heads x 128 at
@@ -41,7 +40,7 @@ from cut_xplane import load  # noqa: E402  (scripts/: this file's own directory)
 # group -> pattern on the operation's HLO text; first match wins
 GROUPS = {
     "attention": r"flash_prefill|decode_attn|append_kv",
-    "expert layer": r"ragged-dot|%grouped_|%sort|\[64,(6144,2048|2048,6144)\]",
+    "expert layer": r"%grouped_|%sort",
     "dense products": r"convolution|kind=kOutput",
 }
 

@@ -218,7 +218,7 @@ def test_dropless_layer_is_the_capacity_layer_at_capacity_t(rows, padded):
     assert np.max(np.abs(np.asarray(stacked) - np.asarray(new))[keep]) < 1e-5
 
 
-@pytest.mark.parametrize("rows", [24, 900])  # the expert-major form, and the grouped one
+@pytest.mark.parametrize("rows", [24, 900])  # one window of one row tile, and one of nine
 def test_a_call_of_both_phases_counts_each_under_its_own(rows):
     """`moe_share_ffn(prompt=...)`, a mixed step's call: the same output as the
     call without it, and the counts of the decode rows and of the prompt tokens
@@ -239,21 +239,29 @@ def test_a_call_of_both_phases_counts_each_under_its_own(rows):
     assert (counts[0, :2] + counts[1, :2]).tolist() == whole[:2].tolist() and whole[1] > 0
 
 
-PROMPT_ROWS = 1024  # a prompt's pass: eight row tiles, over `EXPERT_MAJOR_MAX_ROWS`
+PROMPT_ROWS = 1024  # a prompt's pass: eight row tiles
 
 
 def _share_case(case: str):
     """(cfg, lp, x, valid, banks, layer, prompt) of one routing situation at
     `tiny-solar` (4 experts held of 16 scored, 4 a row, sigmoid router with a
     selection bias). A zero router leaves every score at 0.5, so the bias
-    alone chooses, for every row alike. The cases from `a_prompts_rows` on are
-    prompt-sized (1,024 rows, a window of 1,280 pairs of the 4,096)."""
+    alone chooses, for every row alike. The cases from `a_prompts_rows` to
+    `a_mixed_calls_rows` are prompt-sized (1,024 rows, a window of 1,280 pairs
+    of the 4,096); those behind them are a decode step's and a mixed step's
+    calls: few rows, padding among them, the decode rows in front and the
+    packed prompt tokens behind (`hybrid_mixed_step`), one row tile a window
+    up to 64 rows and four at 64 + 256."""
     cfg = get_config("tiny-solar")
     prompt_sized = case in (
         "a_prompts_rows", "one_expert_over_many_tiles", "no_pair_held_here", "every_pair_held_here",
         "the_whole_layer_held", "a_prompts_padded_rows", "a_prompt_on_stacked_banks",
         "a_mixed_calls_rows")
-    rows = PROMPT_ROWS if prompt_sized else {"one_row": 1, "sixteen_rows": 16}.get(case, 64)
+    decode_rows = {"one_row_of_two_phases": 1, "two_rows_of_two_phases": 1, "a_steps_rows_of_two_phases": 16,
+                   "a_mixed_steps_rows": 64, "a_step_that_holds_no_pair": 64, "a_step_over_one_window": 64}
+    rows = PROMPT_ROWS if prompt_sized else {
+        "one_row": 1, "one_row_of_two_phases": 1, "two_rows_of_two_phases": 2, "sixteen_rows": 16,
+        "a_mixed_steps_rows": 64 + 256}.get(case, 64)
     lp = {k: v[0] for k, v in moe.init_moe_layer_params(cfg, jax.random.PRNGKey(3), jnp.float32, 1).items()}
     bias = 0.01 * jax.random.normal(jax.random.PRNGKey(4), (cfg.router_width,))
     x = jax.random.normal(jax.random.PRNGKey(rows), (rows, cfg.dim))
@@ -282,6 +290,13 @@ def _share_case(case: str):
     elif case == "a_mixed_calls_rows":
         valid = (jnp.arange(rows) % 7) != 3
         prompt = jnp.arange(rows) >= 64
+    if case in decode_rows:
+        valid = jnp.arange(rows) < 2 if rows <= 2 else (jnp.arange(rows) % 7) != 3
+        prompt = jnp.arange(rows) >= decode_rows[case]
+        if case == "a_step_that_holds_no_pair":
+            bias = bias.at[8:12].add(10.0)
+        elif case == "a_step_over_one_window":
+            bias = bias.at[:4].add(10.0)  # all 220 pairs land here: a second turn of the window's loop
     lp["router_bias"] = bias
     return cfg, lp, x, valid, banks, layer, prompt
 
@@ -311,37 +326,48 @@ def _every_pair_by_itself(cfg, lp, x, valid, banks, layer):
     "every_row_on_one_expert", "a_row_whose_choices_are_all_absent", "banks_stacked_over_layers",
     "a_prompts_rows", "one_expert_over_many_tiles", "no_pair_held_here", "every_pair_held_here",
     "the_whole_layer_held", "a_prompts_padded_rows", "a_prompt_on_stacked_banks", "a_mixed_calls_rows",
+    "one_row_of_two_phases", "two_rows_of_two_phases", "a_steps_rows_of_two_phases", "a_mixed_steps_rows",
+    "a_step_that_holds_no_pair", "a_step_over_one_window",
 ])
-def test_expert_major_form_is_the_grouped_form(case, monkeypatch):
-    """One sum, two forms (models/moe.py:share_form) and the sum with no form
-    (`_every_pair_by_itself`): each touched expert visited once with all rows,
-    or the pairs held here sorted in front and they alone gathered, multiplied
-    by the grouped kernel and summed, a window at a time. Equal to float32
-    rounding in `y`, exactly in the counts; whatever the router does (nothing
-    here, everything here: four windows, an expert over eight row tiles)."""
+def test_the_grouped_form_is_every_pair_by_itself(case):
+    """One sum, in the one form it is served in (models/moe.py:_grouped: the
+    pairs held here sorted in front and they alone gathered, multiplied by the
+    grouped kernels and summed, a window at a time) and with no form at all
+    (`_every_pair_by_itself`). Equal to float32 rounding in `y`, the counts
+    exactly what the router's choices say, of the decode rows and of the prompt
+    tokens apart where the call says which are which; whatever the router does
+    (nothing here: no window runs; everything here: four windows of a prompt's
+    rows, two of a decode step's; an expert over eight row tiles)."""
     cfg, lp, x, valid, banks, layer, prompt = _share_case(case)
-    got = {}
-    for form, cap in (("expert_major", 1 << 30), ("grouped", 0)):
-        monkeypatch.setattr(moe, "EXPERT_MAJOR_MAX_ROWS", cap)
-        assert moe.share_form(x.shape[0]) == form
-        y, counts = jax.jit(lambda lp, x: moe.moe_share_ffn(
-            cfg, lp, x, valid=valid, banks=banks, layer=layer, prompt=prompt))(lp, x)
-        got[form] = np.asarray(y), counts.tolist()
-    (y_major, n_major), (y_grouped, n_grouped) = got["expert_major"], got["grouped"]
-    assert n_major == n_grouped
+    y, counts = jax.jit(lambda lp, x: moe.moe_share_ffn(
+        cfg, lp, x, valid=valid, banks=banks, layer=layer, prompt=prompt))(lp, x)
+    y_grouped, n_grouped = np.asarray(y), counts.tolist()
     keep = np.ones(x.shape[0], bool) if valid is None else np.asarray(valid)
     plain = _every_pair_by_itself(cfg, lp, x, valid, banks, layer)
     scale = max(1.0, np.max(np.abs(plain)))
-    assert np.max(np.abs(y_major - y_grouped)[keep]) < 1e-5 * scale
     assert np.max(np.abs(y_grouped - plain)[keep]) < 1e-5 * scale
-    assert np.max(np.abs(y_major - plain)[keep]) < 1e-5 * scale
+    k, E = cfg.experts_per_tok, cfg.n_experts
+    window = moe.window_rows(x.shape[0], k, E, cfg.router_width)
+    _, chosen = moe.route(cfg, x @ lp["router"], lp["router_bias"])
+
+    def counted(phase):
+        sizes = np.bincount(np.asarray(chosen)[phase].reshape(-1), minlength=cfg.router_width)[:E]
+        return [int(phase.sum()), int(sizes.sum()), int((sizes > 0).sum()), int(sizes.max()), 1]
+
     if prompt is not None:  # the decode rows' counts and the prompt tokens', apart
-        assert np.asarray(n_major).shape == (2, 5) and n_major[0][0] + n_major[1][0] == int(keep.sum())
-        assert n_major[0][1] > 0 and n_major[1][1] > moe.window_rows(x.shape[0], 4, 4, 16) // 2
+        assert n_grouped == [counted(keep & ~np.asarray(prompt)), counted(keep & np.asarray(prompt))]
+        held = n_grouped[0][1] + n_grouped[1][1]
+        if case == "a_mixed_calls_rows":
+            assert n_grouped[0][1] > 0 and n_grouped[1][1] > window // 2
+        elif case == "a_step_that_holds_no_pair":
+            assert held == 0
+        elif case == "a_step_over_one_window":
+            assert held == int(keep.sum()) * k > window == ROW_TILE
+        else:
+            assert 0 < held <= window == (4 * ROW_TILE if case == "a_mixed_steps_rows" else ROW_TILE)
         return
-    rows, pairs, touched, fullest, calls = n_major
-    assert (rows, calls) == (int(keep.sum()), 1)
-    k, window = cfg.experts_per_tok, moe.window_rows(x.shape[0], cfg.experts_per_tok, cfg.n_experts, cfg.router_width)
+    assert n_grouped == counted(keep)
+    rows, pairs, touched, fullest, calls = n_grouped
     if case == "an_expert_nobody_chose":
         assert touched < cfg.n_experts
     if case in ("every_row_on_one_expert", "one_expert_over_many_tiles"):
@@ -349,10 +375,9 @@ def test_expert_major_form_is_the_grouped_form(case, monkeypatch):
     if case == "one_expert_over_many_tiles":
         assert fullest == 8 * ROW_TILE <= window
     if case == "a_row_whose_choices_are_all_absent":
-        _, chosen = moe.route(cfg, x @ lp["router"], lp["router_bias"])
         assert int(jnp.min(chosen[0])) >= cfg.n_experts > int(jnp.min(chosen[1]))
         # nothing is routed to it here, and the shared expert of a zero row is zero
-        assert np.max(np.abs(y_major[0])) == 0.0 and np.max(np.abs(y_major[1])) > 0.0
+        assert np.max(np.abs(y_grouped[0])) == 0.0 and np.max(np.abs(y_grouped[1])) > 0.0
     if case == "a_prompts_rows":
         assert 0 < pairs <= window == 1280  # one window holds them
     if case == "no_pair_held_here":
@@ -364,24 +389,21 @@ def test_expert_major_form_is_the_grouped_form(case, monkeypatch):
 
 
 @pytest.mark.parametrize("preset", ["tiny-solar", "tiny-kexaone"])
-@pytest.mark.parametrize("rows,form", [
-    (64, "expert_major"), (moe.EXPERT_MAJOR_MAX_ROWS, "expert_major"),
-    (moe.EXPERT_MAJOR_MAX_ROWS + 1, "grouped"), (768, "grouped"), (1024, "grouped"),
-])
-def test_the_form_follows_the_row_count_alone(preset, rows, form):
-    """Either side of the threshold, where the function is traced, at both
-    expert shares' presets: the grouped kernel is in the program or it is not;
-    nothing else chooses (PERF.md section 6, PR 44: the crossing is a row count,
-    the same at Solar's widths and at K-EXAONE's). Every decode and mixed round
-    of the two expert cells (64 and 64 + 256 rows) is on the loop's side, every
-    admit program of `kexaone_reason_closed` (768 and 1,024 rows) on the other."""
+@pytest.mark.parametrize("rows", [64, 320, 321, 768, 1024])
+def test_the_form_follows_the_row_count_alone(preset, rows):
+    """One form at every row count since PR 45, at both expert shares' presets:
+    a decode round's 64 rows, a mixed round's 64 + 256, the first row count the
+    old threshold sent to the kernels, and `kexaone_reason_closed`'s two admit
+    programs all hold the grouped kernels inside one traced loop over the
+    windows, and no product over all the pairs (PERF.md section 6, PR 45: the
+    traced rounds of both expert cells are faster so, Solar's by 5-9%)."""
     cfg = get_config(preset)
     lp = {k: v[0] for k, v in moe.init_moe_layer_params(cfg, jax.random.PRNGKey(3), jnp.float32, 1).items()}
     x = jnp.zeros((rows, cfg.dim), jnp.float32)
     text = str(jax.make_jaxpr(lambda lp, x: moe.moe_share_ffn(cfg, lp, x))(lp, x))
-    assert moe.share_form(rows) == form
-    assert ("pallas_call" in text) == (form == "grouped") and "ragged_dot" not in text
-    assert "while" in text  # one traced body for all experts, or for all windows
+    assert moe.share_form(rows) == "grouped"
+    assert "grouped_swiglu" in text and "grouped_down" in text and "ragged_dot" not in text
+    assert text.count("while[") == 1  # one traced body for all windows; no loop over the experts
 
 
 @pytest.mark.parametrize("sizes,first", [
@@ -582,15 +604,15 @@ def test_the_features_a_recurrent_configuration_runs_without_come_from_one_list(
     assert engine.expert_dtype == "float32"  # this engine's weights; bfloat16 as the cell boots it
 
 
-def test_the_expert_counter_says_which_form_each_phase_took(monkeypatch):
+def test_the_expert_counter_says_which_form_each_phase_took():
     """`perf_stats()["experts"]["forms"]`: the layer's calls by form, from the
-    row count of each step program dispatched. With the threshold at 64 rows
-    this engine decodes 2 rows a step (expert-major); a prompt of 20 tokens is
-    admitted as one row of 32 (expert-major), one of 100 as a row of 128
-    (grouped)."""
+    row count of each step program dispatched. This engine decodes 2 rows a
+    step; a prompt of 20 tokens is admitted as one row of 32, one of 100 as a
+    row of 128: all grouped since PR 45 (a mixed step's 64 + 256 rows too:
+    tests/test_mixed_round.py:rides_beside_active_rows reads the counter after
+    a ride)."""
     from llm_mcp_tpu.executor import GenerationEngine
 
-    monkeypatch.setattr(moe, "EXPERT_MAJOR_MAX_ROWS", 64)  # before this engine traces anything
     eng = GenerationEngine("tiny-solar", max_slots=2, max_seq_len=256, dtype=jnp.float32).start()
     try:
         L, K = eng.cfg.n_layers, eng.decode_chunk
@@ -600,9 +622,9 @@ def test_the_expert_counter_says_which_form_each_phase_took(monkeypatch):
         forms = eng.perf_stats()["experts"]["forms"]
     finally:
         eng.shutdown()
-    assert forms["prefill"] == {"expert_major": L, "grouped": L}
-    assert set(forms["decode"]) == {"expert_major"}
-    assert forms["decode"]["expert_major"] % (K * L) == 0 and forms["decode"]["expert_major"] >= 2 * K * L
+    assert forms["prefill"] == {"grouped": 2 * L}
+    assert set(forms["decode"]) == {"grouped"}
+    assert forms["decode"]["grouped"] % (K * L) == 0 and forms["decode"]["grouped"] >= 2 * K * L
 
 
 def test_the_cells_warm_up_plan_holds_the_steps_it_held(monkeypatch):
@@ -610,8 +632,7 @@ def test_the_cells_warm_up_plan_holds_the_steps_it_held(monkeypatch):
     1024, the entry point's defaults, the chip's kernels and so its ladder of
     prompt buckets, an admit program of at most 512 padded tokens): the 35
     shapes of PR 32 in the plan and, since PR 42, the mixed round at ONE rung,
-    the largest (a configuration with recurrent layers: `engine._ride_rungs`);
-    the two forms of the expert layer add no program."""
+    the largest (a configuration with recurrent layers: `engine._ride_rungs`)."""
     from llm_mcp_tpu.executor import GenerationEngine, warmup
     from llm_mcp_tpu.utils.config import Config
 
@@ -626,16 +647,8 @@ def test_the_cells_warm_up_plan_holds_the_steps_it_held(monkeypatch):
     assert by_phase == {"admit": 13, "decode": 4, "chunk": 18, "mixed": 1} and len(zoo) == 36
     assert [key for ph, key in zoo if ph == "mixed"] == [(256, False)] and eng._ride_rungs == (256,)
     assert len(warmup.plan_steps(zoo)) == 36
-    forms = {moe.share_form(key[0]) for ph, key in zoo if ph == "decode"}
-    assert forms == {"expert_major"}  # every decode round: 8, 16, 32, 64 rows
-    # the four admit programs of 384 and 512 padded tokens and the chunk
-    # programs from 384 rows take the grouped form since PR 44: the cell's set-up
-    # compiles them, its window (prompts of 64-128 tokens, riding) never runs one
-    admits = {key for ph, key in zoo if ph == "admit"}
-    assert {key for key in admits if moe.share_form(key[0] * key[1]) == "grouped"} == {
-        (1, 384), (1, 512), (2, 256), (4, 128)}
-    chunks = {key[0] * key[1] for ph, key in zoo if ph == "chunk"}
-    assert {t for t in chunks if moe.share_form(t) == "grouped"} == {384, 512, 768, 1024, 1536, 2048}
+    # one form of the expert layer in all 36 since PR 45: the grouped kernels
+    assert {moe.share_form(key[0]) for _, key in zoo} == {"grouped"}
 
 
 @pytest.mark.parametrize("lengths,joins", [

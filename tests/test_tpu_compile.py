@@ -530,28 +530,22 @@ def test_solar_step_programs_fit_and_keep_state_and_banks_in_place(
     sd, solar, chip_kernels, which, operands
 ):
     """Each compiles for the described v5e with its kernels (the state kernel
-    and the attention kernels in decode; the grouped expert products are the
-    compiler's own kernels), fits the chip, and makes no second copy of the
-    state pool (0.75 GiB) nor of an expert bank (a slice of a stacked bank that
-    feeds a grouped product was copied out, 0.39 GiB a bank and layer, until the
-    banks went in whole: models/moe.py). A program of up to 320 rows visits
-    each touched expert with a dense product that reads the bank where it lies
-    in the stack: no grouped product in it and, in the decode round,
-    temporaries within 0.1 GiB of the 0.11 GiB the grouped form had. A program
-    of more rows (the 512 and 1,024 padded tokens here: `moe.share_form`) holds
-    the two grouped kernels over a window of the pairs held here. Bytes in PERF.md
-    section 4 as "described-chip compile"."""
-    from llm_mcp_tpu.models import moe
-
+    and the attention kernels in decode, the two grouped expert kernels in every
+    program since PR 45: the decode round's 64 rows through a window of one row
+    tile, with no fall to their reference), fits the chip, and makes no second
+    copy of the state pool (0.75 GiB) nor of an expert bank (a slice of a
+    stacked bank that feeds a grouped product was copied out, 0.39 GiB a bank
+    and layer, until the banks went in whole: models/moe.py): the decode
+    round's temporaries are 0.09 GiB. Bytes in PERF.md section 4 as
+    "described-chip compile"."""
     cfg, params, cache = solar
+    falls = dict(A.reference_falls)
     compiled = jax.jit(solar_program(which, cfg), donate_argnums=(1, 2)).lower(
         params, cache["k"], cache["v"], *(sd(shape, I32) for shape in operands)).compile()
+    assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
     assert ("kda_decode_step" in text) == (which == "decode")
-    rows = operands[0][0] * (operands[0][1] if len(operands[0]) > 1 else 1)
-    # the loop's products are the compiler's own fusions
-    assert grouped_kernels_in(text) == (moe.share_form(rows) == "grouped")
+    assert grouped_kernels_in(text)
     mem = compiled.memory_analysis()
     if which == "decode":
         assert mem.temp_size_in_bytes < 0.21 * 2**30  # no bank copied out of the stack
@@ -688,9 +682,10 @@ def test_kexaone_step_programs_fit_and_keep_both_kinds_of_cache_in_place(
     layers hold a ring of 128 positions a slot and not 4096: the arguments are
     the weights, 0.58 GB of the global layer's cache and 0.07 GB of rings. Each
     program fits under 12 GiB and updates both kinds in place: no copy of a
-    whole cache member among the temporaries. The admit programs' expert layers
-    are the grouped kernels over a window of the pairs held here (1,280
-    rows of the 8,192 a 1,024-row prompt has: `moe.window_rows`), and their
+    whole cache member among the temporaries. Every program's expert layers are
+    the grouped kernels over a window of the pairs held here (one row tile for
+    the decode round's 64 rows since PR 45; 1,280 rows of the 8,192 a 1,024-row
+    prompt has: `moe.window_rows`), and the admit programs'
     temporaries stay under 0.55 GiB: the sorted copies of all 8,192 pairs' rows
     stood at 0.72 and 0.64 (PR 43's tree, 1 x 1024 and 2 x 512; 0.41 and 0.31
     now). The chunk's 0.99 GiB are its attention over [past | self], the same
@@ -710,7 +705,7 @@ def test_kexaone_step_programs_fit_and_keep_both_kinds_of_cache_in_place(
         assert text.count("append_kv_q8") >= 2
     if which == "admit":
         assert "flash_prefill_attn" in text and "decode_attn" not in text
-    assert grouped_kernels_in(text) == (which != "decode")  # every program of more than 320 rows
+    assert grouped_kernels_in(text)
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
@@ -755,6 +750,7 @@ def test_hybrid_mixed_round_fits_beside_its_decode_round(
     assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
     text = compiled.as_text()
     assert kernel in text and "decode_attn_q8" in text and "append_kv_q8" in text
+    assert grouped_kernels_in(text) == (name == "solar")  # its 64 + rung rows through the expert kernels
     nbytes = lambda tree: sum(  # noqa: E731
         int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(tree))
     pool, kv = nbytes(cache["v"]["state"]), nbytes(cache["k"])
