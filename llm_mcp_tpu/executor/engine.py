@@ -59,25 +59,21 @@ from ..kernels.attention import (
 from ..utils.faults import maybe_fail
 from ..utils.platform import on_tpu
 from ..models.configs import ModelConfig, resolve_config
-from ..models.hybrid import SLOT_MEMBERS
 from ..models.kda import CHUNK as RECURRENCE_CHUNK
-from ..models.moe import share_form
 from ..models.weights import load_llama_checkpoint
 from ..models.llama import (
     init_llama_params,
-    init_kv_cache,
     llama_prefill,
     llama_prefill_chunk_batch,
     llama_prefill_chunk_ragged,
     llama_decode_step,
     mixed_step_q8,
     mixed_step_supported,
-    quantize_kv,
 )
 from .. import constrain
 from ..ops.sampling import apply_token_mask, sample_tokens, spec_verify
 from ..parallel.sharding import (
-    llama_param_specs, kv_cache_specs, kv_pool_specs, shard_pytree,
+    llama_param_specs, named_shardings, shard_pytree,
     supports_ragged_prefill,
 )
 from ..routing import prefix as prefix_fp
@@ -94,14 +90,14 @@ from .memory import (
     ExpertCounts,
     KVPool,
     KVSnapshot,
-    RECURRENT_OFF,
     RESTORE_AGING_TTFT_MULT,
     bucket_len,
     pytree_nbytes,
 )
 from . import migration
 from .paging import PagedKVManager
-from .physical import PhysicalPool, pool_like
+from .layout import CacheLayout
+from .physical import PhysicalPool
 from .scheduler import TokenBudgetScheduler, parse_tenant_quotas
 from .tokenizer import ByteTokenizer, Tokenizer, load_tokenizer
 from ..utils.locks import OrderedLock
@@ -574,9 +570,13 @@ class GenerationEngine:
                     "latents; s8-MXU decode kernel (whole-S at serving "
                     "lengths, blocked streaming at long context)"
                 )
+        # which cache this engine holds and what that rules out: decided here,
+        # once, before anything is allocated (executor/layout.py)
+        layout = self._layout = CacheLayout(
+            self.cfg, max_slots, max_seq_len, dtype, self.kv_quant == "int8", mesh)
         self.decode_impl = resolve_decode_impl(
             mesh,
-            quantized=self.kv_quant == "int8",
+            quantized=layout.int8,
             seq_len=max_seq_len,
             head_dim=hd,
             n_kv_heads=self.cfg.n_kv_heads,
@@ -598,7 +598,7 @@ class GenerationEngine:
             dc = "auto"
         single_chip = mesh is None or mesh.size == 1
         self.decode_compact = dc == "on" or (
-            dc == "auto" and self.kv_quant == "int8" and single_chip
+            dc == "auto" and layout.int8 and single_chip
         )
         # chunked prefill: bound the per-iteration prefill work so admissions
         # interleave with decode rounds (0 disables; sp prefill is whole-prompt
@@ -628,8 +628,6 @@ class GenerationEngine:
             from ..models.quant import quantized_specs
 
             pspecs = quantized_specs(pspecs)
-        cspecs = kv_cache_specs(quantized=self.kv_quant == "int8",
-                                latent=bool(self.cfg.kv_lora_rank))
         def _init_born_sharded():
             # init runs as ONE GSPMD program with explicit out_shardings: no
             # device (and, multi-controller, no process) ever materializes
@@ -708,19 +706,7 @@ class GenerationEngine:
                 params = shard_pytree(params, pspecs, mesh)  # no-op when placed
             self.params = params
 
-        if mesh is not None:
-            # the cache is born sharded for the same reason as the weights
-            with mesh:
-                cache = jax.jit(
-                    partial(init_kv_cache, self.cfg, max_slots, max_seq_len,
-                            dtype=dtype, quantized=self.kv_quant == "int8"),
-                    out_shardings=self._ns(cspecs),
-                )()
-        else:
-            cache = init_kv_cache(
-                self.cfg, max_slots, max_seq_len, dtype=dtype,
-                quantized=self.kv_quant == "int8",
-            )
+        cache = layout.allocate()
         self._ck = cache["k"]
         self._cv = cache["v"]
         # Layers with a per-slot state of fixed size (models/hybrid.py: a
@@ -731,9 +717,7 @@ class GenerationEngine:
         # layer keeps full-length KV rows.
         self._state_pool = build_state_pool(
             self.cfg, max_slots,
-            next(self._cv[m] for m in SLOT_MEMBERS if m in self._cv)
-            if self.cfg.recurrent else None, log)
-        recurrent = self._state_pool is not None
+            self._cv[layout.slot_member] if layout.slot_member else None, log)
         # the expert layer's counts, where the step programs carry them (a
         # member of the cache pair of its own, beside the state): None else
         self._experts = (
@@ -744,21 +728,22 @@ class GenerationEngine:
         # rounds run it (int8 GQA cache read by the Pallas kernel): None else
         self._attn_stream = (
             AttnStream(self._ck["q"].shape)
-            if self.kv_quant == "int8" and not self.cfg.kv_lora_rank
-            and self.decode_impl == "pallas" else None)
+            if layout.fused and self.decode_impl == "pallas" else None)
         # and what the window arm streams of the window layers' rings
         self._win_stream = (
             AttnStream(self._cv["win"]["k"]["q"].shape, window=self.cfg.sliding_window,
                        max_seq_len=max_seq_len)
-            if self._attn_stream is not None and "win" in self._cv else None)
+            if self._attn_stream is not None and layout.slot_member == "win" else None)
         if self._spmd:
             # named out_sharding kinds for _shard_out: host-read outputs come
             # back fully replicated (every process device_gets locally — the
-            # slice decode_fn convention), cache outputs keep their specs
+            # slice decode_fn convention), cache and pool outputs keep their specs
+            pool_sh = self._ns(layout.pool_specs())
             self._out_kinds = {
                 "repl": self._repl_sharding,
-                "k": self._ns(cspecs["k"]),
-                "v": self._ns(cspecs["v"]),
+                **self._ns(layout.specs()),
+                "pk": pool_sh["k"],
+                "pv": pool_sh["v"],
             }
 
         # Host-side mirrors of per-slot device state. Invariant: only ACTIVE
@@ -908,29 +893,6 @@ class GenerationEngine:
         else:
             self._ragged_cap = 0
 
-        kv_q = self.kv_quant == "int8"
-        # quantized GQA caches use the FUSED single-payload layout
-        # (models/llama.py:init_kv_cache): cache["v"] is the empty dict and
-        # V rides cache["k"]'s head axis. MLA int8 keeps its two-dict latent
-        # layout; bf16 keeps bare arrays.
-        fused_kv = kv_q and not self.cfg.kv_lora_rank
-        dtype_ = dtype
-
-        def _maybe_quant_kv(ks, vs):
-            # quantize prompt KV INSIDE the prefill jit: the bf16 KV of a
-            # batched admission (A × bucket rows × L layers) never
-            # materializes in HBM outside the fused program
-            if fused_kv:
-                from ..models.llama import fuse_prompt_kv
-
-                return fuse_prompt_kv(ks, vs, scale_dtype=dtype_), {}
-            if kv_q:
-                return (
-                    quantize_kv(ks, scale_dtype=dtype_),
-                    quantize_kv(vs, scale_dtype=dtype_),
-                )
-            return ks, vs
-
         self.pp_prefill = 1  # >1 when whole-prompt prefill rides the stage scan
         if self.sp > 1:
             from ..parallel.ring import llama_prefill_sp
@@ -939,8 +901,7 @@ class GenerationEngine:
 
             def _prefill_body(params, tokens, lengths):
                 logits, ks, vs = llama_prefill_sp(cfg_, params, tokens, lengths, mesh)
-                ks, vs = _maybe_quant_kv(ks, vs)
-                return logits, ks, vs
+                return logits, *layout.entries(ks, vs)
 
         else:
             # Pipeline-parallel prefill (parallel/pipeline.py): with a pp
@@ -975,8 +936,7 @@ class GenerationEngine:
                         cfg_, params, tokens, lengths, mesh,
                         n_microbatches=m, attn_impl=impl,
                     )
-                    ks, vs = _maybe_quant_kv(ks, vs)
-                    return logits, ks, vs
+                    return logits, *layout.entries(ks, vs)
 
             else:
 
@@ -987,11 +947,11 @@ class GenerationEngine:
                 # admission never materializes (llama_prefill docstring).
                 def _prefill_body(params, tokens, lengths):
                     return llama_prefill(
-                        cfg_, params, tokens, lengths, attn_impl=impl, quant_kv=kv_q
+                        cfg_, params, tokens, lengths, attn_impl=impl, quant_kv=layout.int8
                     )
 
         def _insert_row(ck, cv, ks, vs, i, slot):
-            if recurrent:
+            if layout.slot_member:
                 # the GQA layers' rows as for any family, and the row's
                 # recurrent state (or its ring) into the pool beside them
                 from ..models.hybrid import insert_state_row
@@ -1001,55 +961,18 @@ class GenerationEngine:
             return _insert_kv(ck, cv, ks, vs, i, slot)
 
         def _insert_kv(ck, cv, ks, vs, i, slot):
-            # ks/vs: batched prompt KV [L, A, Hkv, bucket, hd] (already in
-            # cache-entry form when the cache is quantized: fused
-            # payload+scales for GQA, {"q","s"} per side for MLA) → write
-            # row `i` at [:, slot, :, :bucket]. `i`/`slot` are traced
-            # scalars; the dynamic_update_slice form updates the donated
-            # cache in place (an advanced-index scatter would copy the full
-            # cache payload).
-            if fused_kv:
-                ck = {
-                    "q": jax.lax.dynamic_update_slice(
-                        ck["q"], jax.lax.dynamic_slice_in_dim(ks["q"], i, 1, 1),
-                        (0, slot, 0, 0, 0),
-                    ),
-                    "s": jax.lax.dynamic_update_slice(
-                        ck["s"],
-                        jax.lax.dynamic_slice_in_dim(ks["s"], i, 1, 1).astype(ck["s"].dtype),
-                        (0, slot, 0, 0),
-                    ),
-                }
-                return ck, cv
-            if kv_q:
-                ck = {
-                    "q": jax.lax.dynamic_update_slice(
-                        ck["q"], jax.lax.dynamic_slice_in_dim(ks["q"], i, 1, 1),
-                        (0, slot, 0, 0, 0),
-                    ),
-                    "s": jax.lax.dynamic_update_slice(
-                        ck["s"],
-                        jax.lax.dynamic_slice_in_dim(ks["s"], i, 1, 1).astype(ck["s"].dtype),
-                        (0, slot, 0, 0),
-                    ),
-                }
-                cv = {
-                    "q": jax.lax.dynamic_update_slice(
-                        cv["q"], jax.lax.dynamic_slice_in_dim(vs["q"], i, 1, 1),
-                        (0, slot, 0, 0, 0),
-                    ),
-                    "s": jax.lax.dynamic_update_slice(
-                        cv["s"],
-                        jax.lax.dynamic_slice_in_dim(vs["s"], i, 1, 1).astype(cv["s"].dtype),
-                        (0, slot, 0, 0),
-                    ),
-                }
-                return ck, cv
-            kr = jax.lax.dynamic_slice_in_dim(ks, i, 1, 1)
-            vr = jax.lax.dynamic_slice_in_dim(vs, i, 1, 1)
-            ck = jax.lax.dynamic_update_slice(ck, kr.astype(ck.dtype), (0, slot, 0, 0, 0))
-            cv = jax.lax.dynamic_update_slice(cv, vr.astype(cv.dtype), (0, slot, 0, 0, 0))
-            return ck, cv
+            # ks/vs: batched prompt KV [L, A, Hkv, bucket, hd] in the cache's
+            # own form (layout.entries) → write row `i` at [:, slot, :, :bucket],
+            # leaf by leaf, whatever the layout's tree. `i`/`slot` are traced
+            # scalars; the dynamic_update_slice form updates the donated cache
+            # in place (an advanced-index scatter would copy the full cache
+            # payload).
+            def put(c, rows):
+                row = jax.lax.dynamic_slice_in_dim(rows, i, 1, 1)
+                return jax.lax.dynamic_update_slice(
+                    c, row.astype(c.dtype), (0, slot) + (0,) * (c.ndim - 2))
+
+            return jax.tree.map(put, ck, ks), jax.tree.map(put, cv, vs)
 
         mask_ = self._allowed_mask
         base_key_ = self._base_key
@@ -1167,41 +1090,11 @@ class GenerationEngine:
             the start index backwards and overwrite the shared prefix rows
             just re-inserted below it. Restore guarantees start+R = bucket
             <= S, so the traced start is never clamped."""
-            if fused_kv:
-                ck = {
-                    "q": jax.lax.dynamic_update_slice(
-                        ck["q"], pk["q"], (0, slot, 0, start, 0)
-                    ),
-                    "s": jax.lax.dynamic_update_slice(
-                        ck["s"], pk["s"].astype(ck["s"].dtype), (0, slot, 0, start)
-                    ),
-                }
-                return ck, cv
-            if kv_q:
-                ck = {
-                    "q": jax.lax.dynamic_update_slice(
-                        ck["q"], pk["q"], (0, slot, 0, start, 0)
-                    ),
-                    "s": jax.lax.dynamic_update_slice(
-                        ck["s"], pk["s"].astype(ck["s"].dtype), (0, slot, 0, start)
-                    ),
-                }
-                cv = {
-                    "q": jax.lax.dynamic_update_slice(
-                        cv["q"], pv["q"], (0, slot, 0, start, 0)
-                    ),
-                    "s": jax.lax.dynamic_update_slice(
-                        cv["s"], pv["s"].astype(cv["s"].dtype), (0, slot, 0, start)
-                    ),
-                }
-                return ck, cv
-            ck = jax.lax.dynamic_update_slice(
-                ck, pk.astype(ck.dtype), (0, slot, 0, start, 0)
-            )
-            cv = jax.lax.dynamic_update_slice(
-                cv, pv.astype(cv.dtype), (0, slot, 0, start, 0)
-            )
-            return ck, cv
+            def put(c, rows):
+                return jax.lax.dynamic_update_slice(
+                    c, rows.astype(c.dtype), (0, slot, 0, start) + (0,) * (c.ndim - 4))
+
+            return jax.tree.map(put, ck, pk), jax.tree.map(put, cv, pv)
 
         @partial(jax.jit, donate_argnums=(1, 2), static_argnames=("skey",),
                  **self._shard_out(["repl", "k", "v"]))
@@ -1325,8 +1218,8 @@ class GenerationEngine:
         self._vacant: dict[int, list] = {}
         # admissions riding a decode round (_stage_ride, mixed_round_fn): the
         # rungs this cache holds, the descriptor rows of the packed buffer,
-        # why the configuration keeps admit_fn (_ride_off; None = not asked
-        # yet), and what stood against a ride in the loop's current iteration
+        # why the configuration keeps admit_fn (_ride_off), and what stood
+        # against a ride in the loop's current iteration
         self._ride_rungs = tuple(r for r in self.RIDE_RUNGS if r <= max_seq_len)
         if self.cfg.recurrent:
             # the largest alone: `hybrid_mixed_step` runs the recurrence over the
@@ -1340,7 +1233,10 @@ class GenerationEngine:
         # a riding prompt starts at a multiple of this in the packed buffer: the
         # recurrence's chunk where there is one, which resets at a chunk's start
         self._ride_align = RECURRENCE_CHUNK if self.cfg.recurrent else 1
-        self._ride_why: str | None = None
+        self._ride_why = "" if (
+            mesh is None and not self._spmd and self.sp == 1 and layout.int8
+            and self.decode_impl == "pallas" and mixed_step_supported(self.cfg)
+            and self._ride_rungs) else "other"
         self._ride_state = "other"
 
         # Self-speculative decoding (draft-and-verify): a host-side n-gram
@@ -1441,8 +1337,7 @@ class GenerationEngine:
         # calls), so it is ALWAYS constructed — the block economy feeds
         # telemetry unconditionally, and when the pool is on, admission's
         # offered load becomes unique-block accounting (_offered_load).
-        cache_bytes = pytree_nbytes(
-            {"k": self._ck, "v": self._cv["v"] if recurrent else self._cv})
+        cache_bytes = pytree_nbytes(layout.kv_rows(self._ck, self._cv))
         self._paging = PagedKVManager(
             max_slots=max_slots,
             max_seq_len=max_seq_len,
@@ -1489,41 +1384,9 @@ class GenerationEngine:
             # sampled at every shared admission (the sharing peak)
             self._phys_hbm_peak_ratio = 1.0
             self._phys_hbm_peak = (0.0, 0.0)
+            pools = layout.allocate_pools(self._paging.prefix_partition, bt_)
+            self._pool_k, self._pool_v = pools["k"], pools["v"]
             if self._spmd:
-                # born sharded (the multi-controller placement rule): build
-                # the pool shapes host-side, then allocate as one GSPMD
-                # program — pool_like's eager zeros would be process-local
-                specs = kv_pool_specs(
-                    quantized=self.kv_quant == "int8",
-                    latent=bool(self.cfg.kv_lora_rank),
-                )
-                rows_ = self._paging.prefix_partition
-
-                def _pool_shapes(cache):
-                    return jax.tree.map(
-                        lambda c: jax.ShapeDtypeStruct(
-                            (c.shape[0], rows_, c.shape[2], bt_) + c.shape[4:],
-                            c.dtype,
-                        ),
-                        cache,
-                    )
-
-                def _alloc(shapes):
-                    return jax.tree.map(
-                        lambda s: jnp.zeros(s.shape, s.dtype), shapes
-                    )
-
-                with self.mesh:
-                    self._pool_k = jax.jit(
-                        partial(_alloc, _pool_shapes(self._ck)),
-                        out_shardings=self._ns(specs["k"]),
-                    )()
-                    self._pool_v = jax.jit(
-                        partial(_alloc, _pool_shapes(self._cv)),
-                        out_shardings=self._ns(specs["v"]),
-                    )()
-                self._out_kinds["pk"] = self._ns(specs["k"])
-                self._out_kinds["pv"] = self._ns(specs["v"])
                 self._cow_fn = jax.jit(
                     _cow_block_raw, donate_argnums=(0, 1),
                     **self._shard_out(["k", "v"]),
@@ -1540,19 +1403,6 @@ class GenerationEngine:
                     _pool_put_host_raw, donate_argnums=(0, 1),
                     **self._shard_out(["pk", "pv"]),
                 )
-            else:
-                self._pool_k = pool_like(self._ck, self._paging.prefix_partition, bt_)
-                self._pool_v = pool_like(self._cv, self._paging.prefix_partition, bt_)
-                if self.mesh is not None:
-                    # size-1 meshes pass the gate; keep the pool's placement
-                    # commitment consistent with the arena's (pool-row axis
-                    # replicates — rows are a global resource, not dp-sliced)
-                    specs = kv_pool_specs(
-                        quantized=self.kv_quant == "int8",
-                        latent=bool(self.cfg.kv_lora_rank),
-                    )
-                    self._pool_k = shard_pytree(self._pool_k, specs["k"], self.mesh)
-                    self._pool_v = shard_pytree(self._pool_v, specs["v"], self.mesh)
             log.info(
                 "physical paged KV: [%d, %d] block table + %d-row prefix pool"
                 " (%.1f MB)",
@@ -1604,9 +1454,7 @@ class GenerationEngine:
         # module, so the engine hands it plain scalars only.
         self._perf = perf.PerfObservatory(
             shape=perf.ModelShape.from_config(self.cfg),
-            active_layout=perf.layout_name(
-                bool(self.cfg.kv_lora_rank), self.kv_quant == "int8"
-            ),
+            active_layout=layout.name,
             paged=self._phys is not None,
             block_tokens=self._paging.block_tokens,
             weight_bytes_per_param=(
@@ -1735,12 +1583,7 @@ class GenerationEngine:
 
     def _ns(self, specs):
         """PartitionSpec tree → NamedSharding tree on this engine's mesh."""
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        return jax.tree.map(
-            lambda s: NamedSharding(self.mesh, s), specs,
-            is_leaf=lambda x: isinstance(x, PartitionSpec),
-        )
+        return named_shardings(self.mesh, specs)
 
     def _shard_out(self, kinds: list[str]) -> dict:
         """out_shardings kwargs for a jit definition: empty on the local
@@ -2590,20 +2433,19 @@ class GenerationEngine:
     # -- warmup (executor/warmup.py; ROADMAP item 5) -----------------------
 
     def _runs(self, feature: str) -> bool:
-        """Whether this configuration runs `feature`. All of them without a
-        recurrent state pool; with one, all but those `memory.RECURRENT_OFF`
-        names (the one list: its reasons are logged where the pool is built,
-        and the pool counts the times each would have engaged). A mixed round
-        is not among them: a recurrent configuration's admissions ride too
-        (`hybrid_mixed_step`)."""
-        return self._state_pool is None or feature not in RECURRENT_OFF
+        """Whether this configuration runs `feature`: all but what its cache's
+        layout rules out (`CacheLayout.without`, which is `memory.RECURRENT_OFF`,
+        the one list, where a slot member rides the pair: its reasons are
+        logged where the pool is built, and the pool counts the times each
+        would have engaged). A mixed round is not among them: a recurrent
+        configuration's admissions ride too (`hybrid_mixed_step`)."""
+        return feature not in self._layout.without
 
     @property
     def state_dtype(self) -> str:
         """The recurrent state pool's precision ("" without one): a
         configuration's file states it (`program.expect`)."""
-        return str(self._cv["state"]["S"].dtype) if (
-            self._state_pool is not None and "state" in self._cv) else ""
+        return str(self._cv["state"]["S"].dtype) if self._layout.slot_member == "state" else ""
 
     def _layer_leaf_dtype(self, *names: str) -> str:
         """Precision of the first of `names` among the layers' leaves (the
@@ -3291,15 +3133,7 @@ class GenerationEngine:
         self._d_topp = jnp.asarray(self._topp)
         self._d_last_tok = jnp.asarray(self._last_tok)
         log.warning("KV cache buffers were donated into a failed dispatch; re-allocating")
-        cache = init_kv_cache(
-            self.cfg, self.max_slots, self.max_seq_len, dtype=self.dtype,
-            quantized=self.kv_quant == "int8",
-        )
-        if self.mesh is not None:
-            cache = shard_pytree(
-                cache, kv_cache_specs(quantized=self.kv_quant == "int8",
-                               latent=bool(self.cfg.kv_lora_rank)), self.mesh
-            )
+        cache = self._layout.allocate()
         self._ck = cache["k"]
         self._cv = cache["v"]
         if self._phys is not None:
@@ -3308,17 +3142,9 @@ class GenerationEngine:
             # prefix entry's pool bytes are now suspect, so drop them all.
             # _abort_all follows every _recover_cache()=True return and
             # resets the per-slot tables + sweeps the id map.
-            self._pool_k = pool_like(self._ck, self._paging.prefix_partition,
-                                     self._paging.block_tokens)
-            self._pool_v = pool_like(self._cv, self._paging.prefix_partition,
-                                     self._paging.block_tokens)
-            if self.mesh is not None:
-                pspecs = kv_pool_specs(
-                    quantized=self.kv_quant == "int8",
-                    latent=bool(self.cfg.kv_lora_rank),
-                )
-                self._pool_k = shard_pytree(self._pool_k, pspecs["k"], self.mesh)
-                self._pool_v = shard_pytree(self._pool_v, pspecs["v"], self.mesh)
+            pools = self._layout.allocate_pools(
+                self._paging.prefix_partition, self._paging.block_tokens)
+            self._pool_k, self._pool_v = pools["k"], pools["v"]
             while self._prefix_cache:
                 self._evict_lru_prefix()
             self._phys.reset_all()
@@ -3353,13 +3179,6 @@ class GenerationEngine:
             # first sightings extend the same open window
             self._watchdog_transition("compile_grace")
         return True
-
-    def _note_expert_form(self, phase: str, rows: int, steps: int = 1) -> None:
-        """Count the expert layer's calls of a step program about to go out by
-        the form they take: the row count the program was traced at decides
-        (models/moe.py:share_form), so the host knows it without the device."""
-        if self._experts is not None:
-            self._experts.dispatched(phase, share_form(rows), steps)
 
     def _watchdog_transition(self, state: str) -> None:
         """Count a watchdog/compile-grace state transition and journal it:
@@ -3611,13 +3430,12 @@ class GenerationEngine:
         and positions live a layer (the seated rows' lengths; a window layer's
         row holds at most its window)."""
         lens = self._lengths[self._lengths < self.max_seq_len].astype(np.int64)
-        recurrent = self._state_pool is not None
         out = {"full": {
             "layers": self.cfg.n_attn_layers,
-            "bytes": pytree_nbytes({"k": self._ck, "v": self._cv["v"] if recurrent else self._cv}),
+            "bytes": pytree_nbytes(self._layout.kv_rows(self._ck, self._cv)),
             "positions": self.max_slots * self.max_seq_len,
             "live_positions": int(lens.sum())}}
-        if recurrent and "win" in self._cv:
+        if self._layout.slot_member == "win":
             out["window"] = {
                 "layers": self.cfg.n_layers - self.cfg.n_attn_layers,
                 "bytes": pytree_nbytes(self._cv["win"]),
@@ -4989,13 +4807,6 @@ class GenerationEngine:
         neither `_decode_step_q8` nor `hybrid_decode_step` on one chip with the
         int8 cache: a mesh, a bf16 or latent cache, the XLA path, routed experts
         or sliding windows in the dense family, a cache shorter than a rung)."""
-        if self._ride_why is None:
-            if (self.mesh is not None or self._spmd or self.sp != 1
-                    or self.kv_quant != "int8" or self.decode_impl != "pallas"
-                    or not mixed_step_supported(self.cfg) or not self._ride_rungs):
-                self._ride_why = "other"
-            else:
-                self._ride_why = ""
         return self._ride_why
 
     def _round_carries(self, nact: int, group: _PrefillGroup | None) -> bool:
@@ -5753,7 +5564,6 @@ class GenerationEngine:
         # ONE fused dispatch: prefill + cache inserts + device sampling-param
         # rows + first-token sample (see admit_fn)
         first = self._note_exec_shape("admit", Ab, bucket, cn_payload is not None)
-        self._note_expert_form("prefill", Ab * bucket)
         t0c = time.perf_counter()
         # the dispatch by its number: in a profiler trace each run of
         # jit_admit_fn has the annotation that caused it, inside engine.admit
@@ -6214,7 +6024,6 @@ class GenerationEngine:
             first = self._note_exec_shape("chunk", group.tokens.shape[0],
                                           group.bucket, group.skey,
                                           self._phys is not None)
-            self._note_expert_form("prefill", group.tokens.shape[0] * group.bucket)
             t0 = time.perf_counter()
             self._gid_ctr += 1
             group.gid = self._gid_ctr
@@ -6737,14 +6546,6 @@ class GenerationEngine:
                 [self._lengths, [self._next_counter()]]
             ).astype(np.int32)
         base = self._lengths.copy()
-        if ride is None:
-            self._note_expert_form("decode", Ba, self.decode_chunk)
-        else:
-            # the first step's expert layers are traced at the decode rows and
-            # the rung stacked, and are one call of each phase
-            self._note_expert_form("decode", Ba + ride.rung)
-            self._note_expert_form("prefill", Ba + ride.rung)
-            self._note_expert_form("decode", Ba, self.decode_chunk - 1)
         if self._attn_stream is not None:
             self._attn_stream.dispatched(packed[:Ba], self.decode_chunk)
         if self._win_stream is not None:
@@ -6779,7 +6580,6 @@ class GenerationEngine:
                     "fused", Ba, compact, group.tokens.shape[0],
                     group.bucket, group.skey, self._phys is not None,
                 )
-                self._note_expert_form("prefill", group.tokens.shape[0] * group.bucket)
                 t0c = time.perf_counter()
                 self._gid_ctr += 1
                 group.gid = self._gid_ctr

@@ -394,29 +394,17 @@ class ExpertCounts:
     the cache pair as a member of their own and come back behind each decode
     round's tokens). `counts` [2][L][5]: rows routed, pairs on held experts,
     held experts touched, the fullest one's rows and calls, summed since boot
-    over decode steps [0] and over prefills [1]. `forms`: the layer's calls
-    since boot by the form they took (moe.share_form: "grouped", the one
-    there is), under "decode" and "prefill"; the host's own count, from the
-    shape of each step program it dispatched, so it runs ahead of `counts` by
-    what is in flight."""
+    over decode steps [0] and over prefills [1]."""
 
     def __init__(self, n_layers: int, *, held: int, router: int):
         self.counts = [[[0] * 5 for _ in range(n_layers)] for _ in range(2)]
         self.held = int(held)  # experts held here, of `router` scored
         self.router = int(router)
         self.n_layers = int(n_layers)
-        self.forms: dict[str, dict[str, int]] = {"decode": {}, "prefill": {}}
-
-    def dispatched(self, phase: str, form: str, steps: int = 1) -> None:
-        """A step program of `steps` passes over the layers went out, its
-        expert layers traced in `form`."""
-        by_form = self.forms[phase]
-        by_form[form] = by_form.get(form, 0) + steps * self.n_layers
 
     def stats(self) -> dict[str, Any]:
         # `counts` is replaced whole at a round's fetch, never edited
-        return {"counts": self.counts, "held": self.held, "router": self.router,
-                "forms": {phase: dict(by_form) for phase, by_form in self.forms.items()}}
+        return {"counts": self.counts, "held": self.held, "router": self.router}
 
 
 def _shapes(tree: Any, prefix: str = "") -> dict[str, list[int]]:

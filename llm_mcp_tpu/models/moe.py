@@ -83,13 +83,6 @@ def route(
 WINDOW_OVER_MEAN = 1.25
 
 
-def share_form(n_rows: int) -> str:
-    """Which form `moe_share_ffn` takes for a call of `n_rows` rows: "grouped",
-    whatever the rows (PERF.md section 6, PR 45, has the readings of the loop
-    over the touched experts it replaced). The engine's counter asks here."""
-    return "grouped"
-
-
 def window_rows(n_rows: int, k: int, held: int, router: int) -> int:
     """Rows of one window of the grouped form: what a call of `n_rows` rows,
     `k` choices a row, lands on `held` of `router` experts in expectation,

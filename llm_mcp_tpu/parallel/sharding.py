@@ -255,6 +255,13 @@ def supports_ragged_prefill(mesh: Mesh | None) -> bool:
     return all(shape.get(ax, 1) == 1 for ax in ("dp", "sp", "ep"))
 
 
+def named_shardings(mesh: Mesh, specs: Any) -> Any:
+    """PartitionSpec tree → NamedSharding tree on `mesh`."""
+    return jax.tree.map(
+        lambda s: NamedSharding(mesh, s), specs, is_leaf=lambda x: isinstance(x, P)
+    )
+
+
 def shard_pytree(tree: Any, specs: Any, mesh: Mesh) -> Any:
     """Place a pytree on the mesh according to matching PartitionSpecs."""
     return jax.tree.map(

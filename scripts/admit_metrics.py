@@ -24,8 +24,7 @@ BOTH names since PR 40), the rows of EVERY
 round of the window over the slots (`occupancy_ring`, from the ring's `emit`
 events: `decode_occupancy` reads one dispatch in 32 of a phase, about two
 dozen a window), the window's gaps of over 200 ms between two rounds'
-emissions with the ring's events inside each (`stalls`), the expert layer's
-calls of the window by form and phase (`expert_forms`), and the warm-up plan's
+emissions with the ring's events inside each (`stalls`), and the warm-up plan's
 seconds by phase (`plan`).
 """
 
@@ -164,9 +163,6 @@ def extras(run: dict):
         "pct": round(100.0 * sum(f["rows"] for f in emits) / (len(emits) * gen.max_slots), 3),
     } if emits else None
     out["stalls"] = stalls(gen, w0, w1)
-    # the expert layer's calls by form over the window (`moe.share_form`), where there is one
-    forms = [(p.get("experts") or {}).get("forms") for p in (start, end)]
-    out["expert_forms"] = diff(*forms) if forms[1] else None
     plan = (gen.warmup_stats().get("plan") or [])
     out["plan"] = {
         "steps": len(plan),

@@ -401,7 +401,6 @@ def test_the_form_follows_the_row_count_alone(preset, rows):
     lp = {k: v[0] for k, v in moe.init_moe_layer_params(cfg, jax.random.PRNGKey(3), jnp.float32, 1).items()}
     x = jnp.zeros((rows, cfg.dim), jnp.float32)
     text = str(jax.make_jaxpr(lambda lp, x: moe.moe_share_ffn(cfg, lp, x))(lp, x))
-    assert moe.share_form(rows) == "grouped"
     assert "grouped_swiglu" in text and "grouped_down" in text and "ragged_dot" not in text
     assert text.count("while[") == 1  # one traced body for all windows; no loop over the experts
 
@@ -604,29 +603,6 @@ def test_the_features_a_recurrent_configuration_runs_without_come_from_one_list(
     assert engine.expert_dtype == "float32"  # this engine's weights; bfloat16 as the cell boots it
 
 
-def test_the_expert_counter_says_which_form_each_phase_took():
-    """`perf_stats()["experts"]["forms"]`: the layer's calls by form, from the
-    row count of each step program dispatched. This engine decodes 2 rows a
-    step; a prompt of 20 tokens is admitted as one row of 32, one of 100 as a
-    row of 128: all grouped since PR 45 (a mixed step's 64 + 256 rows too:
-    tests/test_mixed_round.py:rides_beside_active_rows reads the counter after
-    a ride)."""
-    from llm_mcp_tpu.executor import GenerationEngine
-
-    eng = GenerationEngine("tiny-solar", max_slots=2, max_seq_len=256, dtype=jnp.float32).start()
-    try:
-        L, K = eng.cfg.n_layers, eng.decode_chunk
-        assert eng.perf_stats()["experts"]["forms"] == {"decode": {}, "prefill": {}}
-        eng.generate("k" * 20, max_tokens=2 * K, temperature=0.0)
-        eng.generate("m" * 100, max_tokens=2, temperature=0.0)
-        forms = eng.perf_stats()["experts"]["forms"]
-    finally:
-        eng.shutdown()
-    assert forms["prefill"] == {"grouped": 2 * L}
-    assert set(forms["decode"]) == {"grouped"}
-    assert forms["decode"]["grouped"] % (K * L) == 0 and forms["decode"]["grouped"] >= 2 * K * L
-
-
 def test_the_cells_warm_up_plan_holds_the_steps_it_held(monkeypatch):
     """The engine of `solar_decode_closed` as the cell sizes it (64 slots x
     1024, the entry point's defaults, the chip's kernels and so its ladder of
@@ -647,8 +623,6 @@ def test_the_cells_warm_up_plan_holds_the_steps_it_held(monkeypatch):
     assert by_phase == {"admit": 13, "decode": 4, "chunk": 18, "mixed": 1} and len(zoo) == 36
     assert [key for ph, key in zoo if ph == "mixed"] == [(256, False)] and eng._ride_rungs == (256,)
     assert len(warmup.plan_steps(zoo)) == 36
-    # one form of the expert layer in all 36 since PR 45: the grouped kernels
-    assert {moe.share_form(key[0]) for _, key in zoo} == {"grouped"}
 
 
 @pytest.mark.parametrize("lengths,joins", [
