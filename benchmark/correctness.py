@@ -38,7 +38,14 @@ and again on the engine's own `cfg`: each number, bool, string and list must
 equal what run.py's own tables (`MODEL_KEYS`, `DERIVED_KEYS`, `ONLY_VALUE`,
 `ROPE_KEYS`, `STATED_NOT_HELD`) or the module's give for it, null reading as 0.
 A path no table knows stops the run; a path both run.py and the module hold
-stops it when the module is loaded. **A cut is stated beside what was
+stops it when the module is loaded, with one exception: a path of
+`ONLY_VALUE` (`moe_layer_freq`, `n_group`, `topk_group`: one behaviour, any
+other value refused) may be named in a module's `HELD`, and only there. A
+family that brings the behaviour holds the key to what its program computes
+with, and the file is then compared with that holder and not with
+`ONLY_VALUE`; in `ONLY` or `STATED` the path is refused as any other overlap
+is, and a configuration whose module does not hold it is compared with
+`ONLY_VALUE` as before. **A cut is stated beside what was
 published**: `published` is a group with the source's value of exactly the
 paths in `reduced` (`{"num_hidden_layers": 48, "n_routed_experts": 320}`), and
 the module's tables may hold `published.<path>` too (a router keeps the

@@ -1,5 +1,7 @@
 """Shared arithmetic of the per-layer readers: counters as the difference
-between the window's edges, waterfall rows of the window's requests."""
+between the window's edges, waterfall rows of the window's requests, and for a
+reader that sets a count beside the trace's device times the same run cut to
+the traced slice (`slice_of`)."""
 
 from __future__ import annotations
 
@@ -14,6 +16,31 @@ def delta(run: dict, *path: str) -> float | None:
             return None
         a, b = a[key], b[key]
     return float(b) - float(a)
+
+
+def slice_of(run: dict) -> dict | None:
+    """The run as the traced slice saw it: `start` and `end` are the counters
+    read at the slice's two edges and `window` is the slice, on the records'
+    clock. A `device_trace` reader takes the rows and the live tokens it sets
+    beside the slice's device times from THIS run, so that both sides are the
+    same rounds: rows averaged over the whole window against a time averaged
+    over the slice read 105.5% of a roofline where the slice's rounds were
+    unlike the window's (PERF.md section 6, PR 47). A `program_counter` reader
+    keeps the window. None for a run without a slice: nothing to set beside a
+    device time."""
+    cut = run.get("slice")
+    return dict(run, **cut) if cut else None
+
+
+def plain_rows(cut: dict) -> float | None:
+    """Mean decode rows of the PLAIN rounds the engine dispatched in the traced
+    slice (`run.ring_rounds`: the flight ring's `decode` events, every round;
+    the perf observatory samples one dispatch in 32, which in a slice is one or
+    none). They are the rounds whose device time `decode_round_s` and
+    `spans.kernel_seconds` read, and a plain round is the fuller one: no prompt
+    waits while every slot is taken."""
+    rows = [n for kind, n, _t in cut.get("rounds", ()) if kind == "decode"]
+    return sum(rows) / len(rows) if rows else None
 
 
 def window_rows(run: dict) -> list[tuple[dict, dict]]:
@@ -35,8 +62,9 @@ def window_compiles(run: dict) -> float:
 
 def mean_live_tokens(run: dict, samples: int = 64) -> float:
     """Cached tokens summed over the sequences in flight, averaged over the
-    window: a request's context grows from its prompt to prompt + completion
-    between its first and last delta."""
+    run's window (the traced slice, of a run `slice_of` cut to it): a request's
+    context grows from its prompt to prompt + completion between its first and
+    last delta."""
     w0, w1 = run["window"]
     total = 0.0
     for k in range(samples):
@@ -60,8 +88,9 @@ ROUND_PROGRAMS = (DECODE_PROGRAM, "jit_mixed_round_fn")
 
 
 def decode_round_s(run: dict) -> float | None:
-    """Mean device seconds of one run of the plain decode step program in the
-    trace; a mixed round is another program and is left out."""
+    """Mean device seconds of one WHOLE run of the plain decode step program in
+    the trace (`trace_reduce.whole_runs`: a run the slice's edge cut is no
+    round's time); a mixed round is another program and is left out."""
     tr = run.get("trace_reduced")
-    runs = tr["module_runs"].get(DECODE_PROGRAM) if tr else None
+    runs = tr["whole_runs"].get(DECODE_PROGRAM) if tr else None
     return runs[1] if runs else None

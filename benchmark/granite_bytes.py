@@ -46,8 +46,9 @@ def state_step_bytes(cfg, rows: float) -> float:
 def decode_step_bytes(run: dict) -> float | None:
     """The least one decode step reads and writes: every weight once (the tied
     table once, as the head: peaks.decode_weight_bytes), the state pool's live
-    rows read and written with their tails, the live KV rows at the window's
-    mean fill."""
+    rows read and written with their tails, the live KV rows at the mean fill
+    of the run's window (a roofline reader hands the run over cut to the traced
+    slice)."""
     rows = live_rows(run)
     gen = run["sut"]["gen"]
     if not rows or not getattr(gen.cfg, "ssm_heads", 0):

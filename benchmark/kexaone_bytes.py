@@ -78,8 +78,9 @@ def decode_step_bytes(run: dict) -> float | None:
     once (the head's slice with them; the embedding table left out, as peaks.py
     does), the banks of the held experts the step's rows touched (by the
     program's counter, expert layer by expert layer), the global layers' live
-    rows at the window's mean fill, and of a window layer min(fill, window)
-    rows a sequence."""
+    rows at the mean fill of the run's window (a roofline reader hands the run
+    over cut to the traced slice), and of a window layer min(fill, window) rows
+    a sequence."""
     got, win, full = solar_bytes.decode_counts(run), win_step_bytes(run), full_step_bytes(run)
     if not got or win is None or full is None:
         return None

@@ -6,14 +6,15 @@ device time a round of the kernels named `decode_attn*` that are not the window
 arm (`decode_attn_q8_blocked` in `kexaone_reason_closed`). Bound by memory.
 `decode_attn_roofline` is not this: `peaks.kv_row_bytes` counts every layer of
 the configuration, and four of this one's five read a ring."""
-from benchmark import kexaone_bytes, peaks
+from benchmark import counters, kexaone_bytes, peaks
 
 NAME, UNIT, BETTER, SOURCE = "full_attn_roofline", "%", "higher", "device_trace"
 LAYER, MOVES = "Pallas kernels", "out_tokens_per_s"
 
 
 def read(run: dict):
-    s, need = kexaone_bytes.full_round_s(run), kexaone_bytes.full_step_bytes(run)
+    cut = counters.slice_of(run)  # the fill of the slice's own rounds, beside the slice's time
+    s, need = kexaone_bytes.full_round_s(run), kexaone_bytes.full_step_bytes(cut) if cut else None
     if not s or not need:
         return None
     gen = run["sut"]["gen"]

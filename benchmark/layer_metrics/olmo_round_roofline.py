@@ -4,9 +4,11 @@ step, the live rows of the state pool read and written, the live int8 KV:
 olmo_hybrid_bytes.py) over the chip's published bytes a second, over the
 round's device time in the trace. Bound by memory: a step at 64 rows does about
 0.6 TFLOP against 14 GB. The share of the whole step that bounds a later claim
-in this cell. Plain rounds alone (`counters.DECODE_PROGRAM`): the bytes are a
-plain round's, and this configuration runs no mixed round
-(`memory.RECURRENT_OFF["mixed_round"]`); one would be left out."""
+in this cell. WHOLE plain rounds alone (`counters.DECODE_PROGRAM`,
+`trace_reduce.whole_runs`): the bytes are a plain round's, with the rows of the
+slice's own plain rounds and the slice's fill (`counters.slice_of`). Since PR
+42 most of this cell's rounds carry a prompt (`jit_mixed_round_fn`, 58-67 of 80
+in a slice): they are left out, their prompts' bytes are not counted here."""
 from benchmark import counters, olmo_hybrid_bytes, peaks
 
 NAME, UNIT, BETTER, SOURCE = "olmo_round_roofline", "%", "higher", "device_trace"
@@ -14,7 +16,8 @@ LAYER, MOVES = "step programs", "out_tokens_per_s"
 
 
 def read(run: dict):
-    mean_s, need = counters.decode_round_s(run), olmo_hybrid_bytes.decode_step_bytes(run)
+    cut = counters.slice_of(run)  # rows, touched experts and fill of the slice's own rounds
+    mean_s, need = counters.decode_round_s(run), olmo_hybrid_bytes.decode_step_bytes(cut) if cut else None
     if not mean_s or not need:
         return None
     gen = run["sut"]["gen"]

@@ -17,8 +17,12 @@ PHASES = ("decode", "fused", "fused_rag")  # the perf observatory's decode round
 
 
 def live_rows(run: dict) -> float | None:
-    """Sequences a decode step carries, averaged over the window's sampled
-    rounds: tokens over decode_chunk over samples."""
+    """Sequences a decode step carries. Of a run cut to the traced slice
+    (`counters.slice_of`): the rows of the plain rounds dispatched in it
+    (`counters.plain_rows`). Of a whole window: averaged over its sampled
+    rounds, tokens over decode_chunk over samples."""
+    if "rounds" in run:
+        return counters.plain_rows(run)
     tokens = sum(counters.delta(run, "perf", "phases", p, "tokens") or 0.0 for p in PHASES)
     samples = sum(counters.delta(run, "perf", "phases", p, "samples") or 0.0 for p in PHASES)
     if not samples or not tokens:
@@ -55,7 +59,8 @@ def state_step_bytes(cfg, rows: float) -> float:
 def decode_step_bytes(run: dict) -> float | None:
     """The least one decode step reads and writes: every weight once (the
     embedding table left out, as peaks.py does), the state pool's live rows
-    read and written with their tails, the live KV rows at the window's mean fill."""
+    read and written with their tails, the live KV rows at the mean fill of the
+    run's window (a roofline reader hands the run over cut to the traced slice)."""
     rows = live_rows(run)
     if not rows:
         return None

@@ -1,11 +1,15 @@
 """Plain float32 reference forward for the DeepSeek-V2 family: multi-head
 latent attention over a compressed key/value latent with one rope key shared
-by all heads, yarn-scaled rope, and feed-forward layers of routed experts plus
-always-on shared experts after a dense prologue.
+by all heads (the query dense, or through a latent of its own), yarn-scaled
+rope, and feed-forward layers of routed experts plus always-on shared experts
+after a dense prologue; the router greedy over all experts or limited to the
+best groups of them (the paper's device-limited routing), and the chip under
+test may hold a SHARE of the routed experts.
 
 Written from the published description (DeepSeek-V2, arXiv 2405.04434, section
 2.1 and appendix C; the released `modeling_deepseek.py` for what the paper
-leaves to the code: yarn's two mscale terms, greedy top-k, the gate scaling).
+leaves to the code: yarn's two mscale terms, `MoEGate`'s greedy and
+group-limited greedy top-k, the gate scaling).
 The same weights go through those equations one layer at a time in float32
 `jax.numpy` at `Precision.HIGHEST`: every position expands its own per-head
 keys and values from the latent (no cache, no absorbed projections), every
@@ -20,8 +24,25 @@ models/moe.py but the names of the parameter tree:
     in both: attn_norm, ffn_norm, wq_mla [D, H (dn + dr)], w_dkv [D, R + dr],
         kv_norm [R], w_ukv [R, H (dn + dv)], wo_mla [H dv, D]
     dense FFN: w1, w3 [D, F], w2 [F, D], or w13 = [w1 | w3] side by side
-    routed FFN: router [D, E], w1e, w3e [E, D, Fm], w2e [E, Fm, D],
-        shared experts as one gated MLP w1s, w3s [D, n_shared Fm], w2s
+    routed FFN: router [D, Er] (Er = `cfg.router_width`, the published count of
+        experts), w1e, w3e [E, D, Fm], w2e [E, Fm, D] (the E = `cfg.n_experts`
+        experts held here, the first E of the router's order; E = Er without a
+        share), shared experts as one gated MLP w1s, w3s [D, n_shared Fm], w2s
+
+**Routing** (`_gates`; `MoEGate` of the released code): s = softmax(x W_r) in
+float32 over all Er experts; with `n_group` > 1 the experts are `n_group` groups
+of Er / n_group consecutive ones, a group's score is its best expert's, the
+`topk_group` best groups keep their scores and every other score is 0; the top
+k of what is left are the row's experts with their s as gates, renormalised
+where `norm_topk_prob` is set and k > 1, else times `routed_scaling_factor`.
+`n_group` and `topk_group` are read from the program's configuration where it
+has the fields (`getattr(cfg, "n_group", 1)`: the names are for the
+`model_config` PR that brings the path to meet); at 1 and 1 the forward is the
+greedy one, bit for bit. **A share** (`references/exaone_moe.py`'s way): the
+router scores the published width and groups and the top k are chosen over all
+of it; only the experts [0, E) are applied, what the absent ones would have
+added is left out, and that partial result goes on; the shared experts are
+applied whole.
 
 A linear is a plain array or the int8 form {"q", "s"} with one scale an output
 channel; both are multiplied out to float32, one layer (and one expert) at a
@@ -46,9 +67,8 @@ change of the mathematics:
   what follows a row does not move it). Every expert is applied to every row
   and weighted by the row's gate for it, 0 where it was not chosen: dropless by
   construction, E/k times the arithmetic, no gather or scatter to get wrong.
-- Group-limited routing (`n_group` > 1, DeepSeek-V2 full size) and the sigmoid
-  scores of V3 are not here: run.py's `check_sizes` refuses a file that states
-  them, because the program's `ModelConfig` has no field for either.
+- Sigmoid scores and the selection bias of V3 are not here, nor a share that
+  cuts a routing group: `check` refuses all three.
 """
 
 from __future__ import annotations
@@ -90,6 +110,29 @@ FFN_BLOCKS = 4
 SERVED_TOL_REL = 0.12
 
 
+# -- what a configuration's file states beyond run.py's own tables ----------------
+
+
+def _groups(cfg) -> tuple[int, int]:
+    """(n_group, topk_group) the program computes with: 1 and 1, greedy over all
+    experts, for a configuration without the fields."""
+    return int(getattr(cfg, "n_group", 1) or 1), int(getattr(cfg, "topk_group", 1) or 1)
+
+
+# `n_group` and `topk_group` are paths of run.py's `ONLY_VALUE`: this family
+# brings the behaviour, so it holds them to what its program computes with
+# (`run.load_reference` lets `HELD`, and only `HELD`, take them over).
+HELD = {
+    "n_group": lambda c: _groups(c)[0],
+    "topk_group": lambda c: _groups(c)[1],
+    "scoring_func": lambda c: c.router_score,
+    "topk_method": lambda c: "group_limited_greedy" if _groups(c)[0] > 1 else "greedy",
+    # the router keeps the published width while n_routed_experts counts the held
+    "published.n_routed_experts": lambda c: c.router_width,
+}
+STATED = {"seq_aux": "a switch of the training loss (the auxiliary loss a sequence): nothing in a forward"}
+
+
 def check(cfg) -> None:
     """Raises for a configuration these equations do not cover."""
     if not cfg.kv_lora_rank:
@@ -100,6 +143,25 @@ def check(cfg) -> None:
         raise NotImplementedError(f"no plain DeepSeek-V2 reference for {cfg.name!r}")
     if cfg.rope_factor > 1.0 and cfg.rope_type != "yarn":
         raise NotImplementedError(f"reference rope type {cfg.rope_type!r}")
+    if not cfg.n_experts:
+        return
+    if cfg.router_score != "softmax":
+        raise NotImplementedError(f"reference router score {cfg.router_score!r}: softmax alone, "
+                                  f"no sigmoid scores and no selection bias")
+    n_group, topk_group = _groups(cfg)
+    width, held = cfg.router_width, cfg.n_experts
+    if width % n_group or not 1 <= topk_group <= n_group or held > width:
+        raise NotImplementedError(
+            f"{cfg.name!r}: {n_group} groups, {topk_group} a token, over {width} experts of which "
+            f"{held} are held")
+    if cfg.experts_per_tok > topk_group * (width // n_group):
+        raise NotImplementedError(
+            f"{cfg.name!r}: {cfg.experts_per_tok} experts a token out of {topk_group} groups of "
+            f"{width // n_group}")
+    if held < width and held % (width // n_group):
+        raise NotImplementedError(
+            f"{cfg.name!r}: a share of {held} experts is not whole groups of {width // n_group} "
+            f"in the router's order")
 
 
 # -- yarn ------------------------------------------------------------------------
@@ -232,23 +294,37 @@ def _dense_ffn(cfg, stack, li, x):
 
 
 def _gates(cfg, scores):
-    """scores [T, E] float32 softmax -> the weight of every expert for every
-    row, 0 for all but the row's top k (greedy, over all experts)."""
+    """scores [T, Er] float32 softmax over every expert the router scores ->
+    the weight of every one of them for every row, 0 for all but the row's top
+    k: greedy over all experts, or with `n_group` > 1 over the experts of the
+    `topk_group` groups whose best score is largest (every score outside them
+    is 0 before the top k are taken)."""
+    n_group, topk_group = _groups(cfg)
+    width = scores.shape[-1]
+    if n_group > 1:
+        best = jnp.max(scores.reshape(-1, n_group, width // n_group), axis=-1)  # [T, G]
+        _, keep = jax.lax.top_k(best, topk_group)
+        kept = jnp.any(keep[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)  # [T, G]
+        scores = jnp.where(jnp.repeat(kept, width // n_group, axis=1), scores, 0.0)
     top, idx = jax.lax.top_k(scores, cfg.experts_per_tok)
     if cfg.norm_topk_prob and cfg.experts_per_tok > 1:
         top = top / jnp.sum(top, axis=-1, keepdims=True)
     else:
         top = top * cfg.routed_scaling_factor
-    chosen = idx[:, :, None] == jnp.arange(cfg.n_experts)[None, None, :]
-    return jnp.sum(jnp.where(chosen, top[:, :, None], 0.0), axis=1)  # [T, E]
+    chosen = idx[:, :, None] == jnp.arange(width)[None, None, :]
+    return jnp.sum(jnp.where(chosen, top[:, :, None], 0.0), axis=1)  # [T, Er]
 
 
 def _routed_ffn(cfg, stack, li, x):
-    logits = _mm(x, _pick(stack["router"], (li,)).astype(jnp.float32))
+    """This chip's part of the routed experts' sum (all of it without a share),
+    and the shared experts whole."""
+    if "router_bias" in stack:
+        raise NotImplementedError(f"{cfg.name!r}: a selection bias on the router is not this family's")
+    logits = _mm(x, _pick(stack["router"], (li,)).astype(jnp.float32))  # [T, Er]: every published expert
     scores = jnp.exp(logits - jnp.max(logits, axis=-1, keepdims=True))
     gates = _gates(cfg, scores / jnp.sum(scores, axis=-1, keepdims=True))
 
-    def expert(e, out):
+    def expert(e, out):  # e < the experts held here
         y = _swiglu(x, *(_linear(stack[n], (li, e)) for n in ("w1e", "w3e", "w2e")))
         return out + y * jax.lax.dynamic_index_in_dim(gates, e, 1, keepdims=True)
 

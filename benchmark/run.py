@@ -14,7 +14,9 @@ traceback, a non-zero exit code and no result line.
 
 With `--trace 0` the line holds the cell's end-to-end metrics; with `--trace 1`
 its per-layer metrics, taken from counters read at the window's edges and from
-a profiler trace of a few seconds in the middle of the window.
+a profiler trace of a few seconds in the middle of the window; a reader that
+sets a count beside the trace's device times takes the count between the
+slice's own edges (`counters.slice_of`).
 
 Everything that belongs to one cell is data: BENCHMARK.json names the cell's
 configuration (`benchmark/configs/<config>.json`) and traffic mix
@@ -25,6 +27,7 @@ configuration (`benchmark/configs/<config>.json`) and traffic mix
 from __future__ import annotations
 
 import argparse
+import collections
 import importlib.util
 import json
 import os
@@ -43,7 +46,13 @@ if ROOT not in sys.path:
 from benchmark import trafficgen  # noqa: E402  (pure standard library)
 
 WARM_SEED = 7_777_777  # warm-up traffic never shares a seed with a window
-TRACE_SLICE_S = 3.0
+# Seconds of the window a traced run profiles. 8 and not 3 (PR 47): where most
+# rounds carry a prompt (the recurrent cells since PR 42) one round in ten to
+# fifteen is the PLAIN program the round readers time, and a slice of 3 s held
+# 21-29 rounds of 100-135 ms: two plain ones, or none, and then no reading.
+TRACE_SLICE_S = 8.0
+ROUND_EVENTS = ("decode", "fused", "fused_rag", "mixed")  # the flight ring's one event a round
+ROUND_LEAD_S = 0.3  # a round runs on the device up to two rounds after the host dispatched it
 
 
 def say(msg: str) -> None:
@@ -114,7 +123,11 @@ def load_reference(config: dict):
     It must hold what the configuration's engine kind is compared through, and
     may hold tables for `check_sizes` (the contract is at the top of
     correctness.py): a path that run.py's own tables hold, or two of the
-    module's, and a `STATED` path with no reason, are refused here."""
+    module's, and a `STATED` path with no reason, are refused here. One overlap
+    stands: a path of `ONLY_VALUE` in the module's `HELD`, and only there. A
+    family that brings the behaviour (routing in groups, a layer pattern) holds
+    the key to what ITS program computes with, and `check_sizes` then compares
+    the file with that holder."""
     name = config.get("reference")
     if name is None:
         from benchmark import reference as mod
@@ -128,12 +141,14 @@ def load_reference(config: dict):
         raise AttributeError(f"reference {name!r} lacks {lacks}")
     seen = own_paths()
     for table in TABLES:
-        twice = sorted(seen & set(getattr(mod, table, {})))
+        paths = set(getattr(mod, table, {}))
+        twice = sorted(seen & (paths - set(ONLY_VALUE) if table == "HELD" else paths))
         if twice:
             raise AssertionError(
                 f"reference {name!r}: {table} holds {twice}, which another table holds already "
-                f"(run.py's, or one of the module's): a path is held once")
-        seen |= set(getattr(mod, table, {}))
+                f"(run.py's, or one of the module's): a path is held once, and only `HELD` may "
+                f"take over a path of run.py's `ONLY_VALUE`")
+        seen |= paths
     unsaid = sorted(p for p, why in getattr(mod, "STATED", {}).items() if not str(why).strip())
     if unsaid:
         raise AssertionError(f"reference {name!r}: STATED gives no reason for {unsaid}")
@@ -207,7 +222,10 @@ DERIVED_KEYS = {  # a key held to something the program's table gives in another
     "embedding_width": lambda c: c.embed_dim or c.dim,
     "pooling": lambda c: {"last": "last_token"}.get(c.pooling, c.pooling),
 }
-ONLY_VALUE = {  # a key the program has one behaviour for: any other value is refused
+# A key the program has one behaviour for: any other value is refused, unless the
+# configuration's reference module holds the key itself (`HELD`), as a family
+# that brings the behaviour does.
+ONLY_VALUE = {
     "moe_layer_freq": 1,  # every layer after the dense ones is routed
     "n_group": 1, "topk_group": 1,  # greedy top-k over all experts, no groups
 }
@@ -230,7 +248,8 @@ HARNESS_KEYS = ("name", "source", "reference", "reference_request", "reduced", "
 
 
 def own_paths() -> set[str]:
-    """Every path run.py's own tables hold; a reference module may hold none of them."""
+    """Every path run.py's own tables hold. A reference module may hold none of
+    them again, but for a path of `ONLY_VALUE` in its `HELD` (`load_reference`)."""
     return ({*MODEL_KEYS, *DERIVED_KEYS, *ONLY_VALUE, *STATED_NOT_HELD}
             | {f"rope_scaling.{k}" for k in ROPE_KEYS})
 
@@ -290,10 +309,13 @@ def check_sizes(config: dict, model_cfg, module=None) -> list[str]:
     """The configuration's file is what is run: every model key it states, at
     any depth (number, bool, string or list), must be what the program's own
     table gives the engine (null reads as 0). run.py's tables come first, then
-    `HELD`, `ONLY` and `STATED` of the configuration's reference module. A path
-    that no table knows is an error, not a default: a width nobody compares
-    could be cut and still boot. Returns the paths the file states that are
-    held to nothing, each with the reason where the module gives one."""
+    `HELD`, `ONLY` and `STATED` of the configuration's reference module. Where
+    the module's `HELD` names a path of `ONLY_VALUE`, the file is compared with
+    the module's holder, a list element by element; where it does not,
+    `ONLY_VALUE` stands. A path that no table knows is an error, not a default:
+    a width nobody compares could be cut and still boot. Returns the paths the
+    file states that are held to nothing, each with the reason where the module
+    gives one."""
     held = {k: getattr(model_cfg, f) for k, f in MODEL_KEYS.items()}
     held.update({k: f(model_cfg) for k, f in DERIVED_KEYS.items()}, **ONLY_VALUE)
     held.update({f"rope_scaling.{k}": getattr(model_cfg, f) for k, f in ROPE_KEYS.items()})
@@ -488,9 +510,33 @@ def warm_up(sut: dict, spec: dict, work_dir: str, compiles: CompileEvents) -> No
         say(f"warm-up zoo fully_warm after {time.monotonic() - t0:.1f} s more")
 
 
-def trace_slice(trace_dir: str, at: float, out: dict) -> None:
-    """Profile TRACE_SLICE_S seconds starting at monotonic time `at`. No
-    Python tracer: it slows the host it shares with the server."""
+def slice_edge(sut: dict) -> dict:
+    """The counters a `device_trace` reader sets beside the slice's device
+    times (`counters.slice_of`), at one moment: the perf observatory's phases
+    and the expert layer's counts, both in `perf_stats()`."""
+    gen = sut["gen"]
+    return {"t": time.monotonic(), **({"perf": gen.perf_stats()} if gen is not None else {})}
+
+
+def ring_rounds(sut: dict, a: float, b: float) -> list[tuple[str, int, float]]:
+    """(kind, decode rows, t) of every round the engine dispatched in [a, b),
+    from the flight ring's one event a round: EVERY round, where the perf
+    observatory samples one dispatch in 32, which in a slice is one or none."""
+    gen = sut["gen"]
+    out = []
+    for ev in gen._flight.snapshot() if gen is not None else ():
+        f = ev["fields"] or {}
+        if ev["etype"] in ROUND_EVENTS and a <= f.get("t", -1.0) < b:
+            out.append((ev["etype"], int(f["rows"]), float(f["t"])))
+    return out
+
+
+def trace_slice(trace_dir: str, at: float, out: dict, sut: dict) -> None:
+    """Profile TRACE_SLICE_S seconds starting at monotonic time `at`, and read
+    the counters at the slice's two edges and the rounds the engine dispatched
+    between them (`out["cut"]`: start, end, rounds), so that what a reader
+    divides by the slice's device time is counted over the slice's own rounds.
+    No Python tracer: it slows the host it shares with the server."""
     import jax
 
     time.sleep(max(0.0, at - time.monotonic()))
@@ -500,7 +546,11 @@ def trace_slice(trace_dir: str, at: float, out: dict) -> None:
     out["start"] = time.monotonic()
     jax.profiler.start_trace(trace_dir, profiler_options=opts)
     try:
-        time.sleep(TRACE_SLICE_S)
+        first = slice_edge(sut)
+        time.sleep(max(0.0, out["start"] + TRACE_SLICE_S - time.monotonic()))
+        last = slice_edge(sut)
+        out["cut"] = {"start": first, "end": last,
+                      "rounds": ring_rounds(sut, first["t"] - ROUND_LEAD_S, last["t"])}
     finally:
         out["stop"] = time.monotonic()
         jax.profiler.stop_trace()
@@ -522,7 +572,7 @@ def measure(sut: dict, spec: dict, args, work_dir: str, compiles: CompileEvents)
     if args.trace:
         trace["dir"] = os.path.join(work_dir, "trace")
         tracer = threading.Thread(
-            target=trace_slice, args=(trace["dir"], (w0 + w1) / 2 - TRACE_SLICE_S / 2, trace))
+            target=trace_slice, args=(trace["dir"], (w0 + w1) / 2 - TRACE_SLICE_S / 2, trace, sut))
         tracer.start()
     time.sleep(max(0.0, w0 - time.monotonic()))
     start = snapshot(sut, compiles)
@@ -540,10 +590,15 @@ def measure(sut: dict, spec: dict, args, work_dir: str, compiles: CompileEvents)
     if gen is not None and args.trace:
         for row in gen.waterfall_recent(128):
             rows[row["trace"] or row["rid"]] = row
-    return {"records": records, "window": (pre, pre + seconds), "window_abs": (w0, w1),
-            "t_start": t_start, "start": start, "end": end, "waterfall_rows": rows,
-            "trace": trace, "plan": plan,
-            "window_compiles": compiles.between(w0, w1)}
+    run = {"records": records, "window": (pre, pre + seconds), "window_abs": (w0, w1),
+           "t_start": t_start, "start": start, "end": end, "waterfall_rows": rows,
+           "trace": trace, "plan": plan,
+           "window_compiles": compiles.between(w0, w1)}
+    cut = trace.pop("cut", None)
+    if cut:  # the traced slice as a window of its own: counters.slice_of
+        a, b = cut["start"]["t"], cut["end"]["t"]
+        run["slice"] = dict(cut, window_abs=(a, b), window=(a - t_start, b - t_start))
+    return run
 
 
 def main(argv: list[str] | None = None) -> int:
@@ -577,7 +632,7 @@ def main(argv: list[str] | None = None) -> int:
         run.update(sut=sut, spec=spec, device=device, args=args, embed_tap=tap,
                    setup_s=run["window_abs"][0] - t_proc,
                    miss_ms=float(run["plan"]["timeout_s"]) * 1e3)
-        from benchmark import correctness, reduce, trace_reduce
+        from benchmark import correctness, counters, reduce, trace_reduce
 
         bad = [r for r in run["records"] if not reduce.ok(r)]
         checks = correctness.check(run)
@@ -589,9 +644,16 @@ def main(argv: list[str] | None = None) -> int:
         reduced = None
         if args.trace:
             path = trace_reduce.find_xplane(run["trace"]["dir"])
+            t0 = time.monotonic()
             reduced = trace_reduce.reduce_trace(path) if path else None
             if reduced is None:
                 raise RuntimeError("traced run: the trace holds no device operation")
+            cut = run.get("slice", {})
+            kinds = collections.Counter(kind for kind, _rows, _t in cut.get("rounds", ()))
+            say(f"traced slice: {reduced['window_s']:.3f} s on the device, read in "
+                f"{time.monotonic() - t0:.1f} s; rounds dispatched in it by kind: {dict(kinds)}, rows of "
+                f"the plain ones {counters.plain_rows(cut)}; whole runs [count, mean s]: "
+                f"{json.dumps(reduced['whole_runs'])}")
             keep = os.environ.get("BENCH_KEEP_TRACE", "")
             if keep:  # the builder's own look at a raw trace; the driver never sets it
                 os.makedirs(keep, exist_ok=True)

@@ -36,7 +36,12 @@ def decode_counts(run: dict) -> list[list[float]] | None:
 
 
 def live_rows(run: dict) -> float | None:
-    """Sequences a decode step carries, averaged over the window's steps."""
+    """Sequences a decode step carries: of a run cut to the traced slice
+    (`counters.slice_of`) the rows of the plain rounds dispatched in it
+    (`counters.plain_rows`); of a whole window, averaged over its decode steps
+    by the expert layer's counts."""
+    if "rounds" in run:
+        return counters.plain_rows(run)
     got = decode_counts(run)
     return sum(r[ROWS] / r[CALLS] for r in got) / len(got) if got else None
 
@@ -75,7 +80,8 @@ def decode_step_bytes(run: dict) -> float | None:
     """The least one decode step reads and writes: every weight outside the
     expert banks once (the embedding table left out, as peaks.py does), the
     banks of the held experts the step's rows touched (by the counter, layer
-    by layer), the state pool's live rows, the live KV rows."""
+    by layer), the state pool's live rows, the live KV rows; all over the run's
+    window (a roofline reader hands the run over cut to the traced slice)."""
     got = decode_counts(run)
     if not got:
         return None
@@ -85,6 +91,8 @@ def decode_step_bytes(run: dict) -> float | None:
     one_expert = banks / (cfg.n_layers * cfg.n_experts)
     touched = sum(r[TOUCHED] / r[CALLS] for r in got)  # experts a step, summed over layers
     rows = live_rows(run)
+    if not rows:
+        return None
     return (peaks.decode_weight_bytes(gen.params) - banks + touched * one_expert
             + state_step_bytes(cfg, rows)
             + kv_row_bytes(cfg, gen.kv_quant) * counters.mean_live_tokens(run))

@@ -35,17 +35,22 @@ def host_seconds(host: dict, names: set[str]) -> dict[str, float]:
     return out
 
 
-def program_runs(chips, program: str) -> list[tuple[float, float]]:
-    """[start, end) of every run of one step program, over all chips."""
-    return sorted((a, b) for _i, _ops, mods in chips for name, a, b in mods
+def program_runs(chips, program: str, whole: bool = False) -> list[tuple[float, float]]:
+    """[start, end) of every run of one step program, over all chips; with
+    `whole`, less the runs the slice's edges cut (`trace_reduce.whole_runs`)."""
+    return sorted((a, b) for _i, _ops, mods in chips
+                  for name, a, b in (trace_reduce.whole_runs(mods) if whole else mods)
                   if trace_reduce.program_name(name) == program)
 
 
-def kernel_seconds(chips, program: str, prefix: str) -> tuple[float, int, set[str]]:
+def kernel_seconds(chips, program: str, prefix: str,
+                   whole: bool = True) -> tuple[float, int, set[str]]:
     """Device seconds of the leaf operations whose HLO name starts with
-    `prefix`, inside runs of `program`; the number of those runs; and the
-    names found."""
-    runs = program_runs(chips, program)
+    `prefix`, inside WHOLE runs of `program` (a run the slice's edge cut holds
+    part of its kernels and would count as one; `whole=False` takes those too,
+    for a look at a recorded cut); the number of those runs; and the names
+    found."""
+    runs = program_runs(chips, program, whole)
     starts = [a for a, _ in runs]
     total, found = 0.0, set()
     for _i, ops, _mods in chips:

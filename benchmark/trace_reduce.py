@@ -101,6 +101,18 @@ def program_name(module: str) -> str:
     return re.sub(r"\(\d+\)$", "", module)
 
 
+def whole_runs(mods: list[tuple[str, float, float]]) -> list[tuple[str, float, float]]:
+    """One chip's `XLA Modules` events less the first and the last by time: the
+    two the traced slice's edges cut. The profiler clips a program that was
+    running when the trace began, or still is when it ends, to the part it saw
+    (a round of 105 ms read 83 and 89 at the two ends of one slice; my chip
+    run, PR 47), and a device that serves is never idle at an edge. A mean A
+    RUN counts whole runs alone: where a slice holds two runs of a program, one
+    cut run in the mean reads four fifths of a round and 106% of a roofline.
+    A run that happened to lie whole at an edge is one sample lost."""
+    return sorted(mods, key=lambda m: m[1])[1:-1]
+
+
 def read_planes(path: str):
     """[(chip index, [(name, start_ns, end_ns)] ops, [..] modules)] and
     the host plane's lines {thread: [(name, start_ns, end_ns)]}."""
@@ -152,6 +164,7 @@ def reduce_trace(path: str, top: int = 10) -> dict | None:
     by_name: dict[str, float] = {}
     by_module: dict[str, float] = {}
     module_calls: dict[str, int] = {}
+    whole: dict[str, list[float]] = {}
     gap_s: dict[str, float] = {}
     for _idx, ops, mods in chips:
         spans = [(a, b) for _n, a, b in ops]
@@ -175,6 +188,8 @@ def reduce_trace(path: str, top: int = 10) -> dict | None:
             key = program_name(name)
             by_module[key] = by_module.get(key, 0.0) + (b - a) / 1e9
             module_calls[key] = module_calls.get(key, 0) + 1
+        for name, a, b in whole_runs(mods):
+            whole.setdefault(program_name(name), []).append((b - a) / 1e9)
         mods_sorted = sorted(mods, key=lambda m: m[1])
         for a, b in sorted(gaps(spans, w0, w1), key=lambda g: g[0] - g[1])[:200]:
             before = [m for m in mods_sorted if m[1] <= a]  # a program may end a little after its last op
@@ -196,7 +211,10 @@ def reduce_trace(path: str, top: int = 10) -> dict | None:
         "mosaic_s": sum(mosaic) / n,
         "device_ops": ranked(by_name),
         "modules": ranked(by_module),
-        # step programs: {name: [times run, mean seconds a run]} over all chips
+        # step programs: {name: [times run, mean seconds a run]} over all chips,
+        # the runs the slice's edges cut among them: for sums and counts
         "module_runs": {k: [module_calls[k], by_module[k] / module_calls[k]] for k in by_module},
+        # the same over whole runs alone (`whole_runs`): for a time A RUN
+        "whole_runs": {k: [len(v), sum(v) / len(v)] for k, v in whole.items()},
         "idle_gaps": ranked(gap_s),
     }

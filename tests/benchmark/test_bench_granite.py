@@ -18,7 +18,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from benchmark import granite_bytes, olmo_hybrid_bytes, solar_bytes  # noqa: E402
+from benchmark import counters, granite_bytes, olmo_hybrid_bytes, solar_bytes  # noqa: E402
 from benchmark import run as bench_run  # noqa: E402
 from benchmark import trace_reduce  # noqa: E402
 from llm_mcp_tpu.models.configs import get_config  # noqa: E402
@@ -42,7 +42,10 @@ def phases(rounds: int, rows: int) -> dict:
 def granite_run(kernel: str = "ssd_decode_step") -> dict:
     """Counters at both edges (100 sampled rounds of 60 rows), a trace with 10
     runs of the decode program of 100 ms, each holding 144 calls of the state
-    kernel of 0.4 ms, and no request in flight (no KV to count)."""
+    kernel of 0.4 ms (the slice's edges cut the first and the last: eight whole
+    runs), no request in flight (no KV to count), and the traced slice as
+    `run.measure` records it: no sample of the observatory inside it, and of
+    the rounds dispatched in it the plain ones carry 30 rows, the window's 60."""
     params = {"embed": np.zeros((64, 8), np.int8), "final_norm": np.zeros((8,), np.int8),
               "layers": {"w1": np.zeros((40, 8, 16), np.int8)},
               "gqa": {"wq": np.zeros((4, 8, 8), np.int8)},
@@ -60,7 +63,11 @@ def granite_run(kernel: str = "ssd_decode_step") -> dict:
     return {"sut": {"gen": gen}, "device": {"kind": "TPU v5 lite"},
             "start": {"perf": phases(0, 60)}, "end": {"perf": phases(100, 60)},
             "records": [], "window": (10.0, 50.0),
-            "trace_reduced": {"module_runs": {"jit_decode_chunk_fn": [10, 0.100]}},
+            "slice": {"start": {"perf": phases(50, 60)}, "end": {"perf": phases(50, 60)},
+                      "window": (26.0, 34.0), "window_abs": (126.0, 134.0),
+                      "rounds": [("decode", 30, 126.5), ("mixed", 64, 128.0), ("decode", 30, 130.0)]},
+            "trace_reduced": {"module_runs": {"jit_decode_chunk_fn": [10, 0.100]},
+                              "whole_runs": {"jit_decode_chunk_fn": [8, 0.100]}},
             "_planes": ([(0, ops, mods)], {})}
 
 
@@ -105,14 +112,16 @@ def test_each_new_reader_gives_its_number_on_a_run_with_the_kernel_and_the_phase
     run = granite_run()
     got = {name: reader(name).read(run) for name in NEW}
     assert all(v is not None for v in got.values()), got
-    assert granite_bytes.live_rows(run) == pytest.approx(60.0)
+    cut = counters.slice_of(run)
+    assert granite_bytes.live_rows(run) == pytest.approx(60.0)  # the window's sampled rounds
+    assert granite_bytes.live_rows(cut) == pytest.approx(30.0)  # the slice's plain rounds, every one
     assert got["ssd_decode_ms"] == pytest.approx(144 * 0.4)  # the stray call outside a run is not read
-    need = 4 * granite_bytes.kernel_step_bytes(CFG, 60)
+    need = 4 * granite_bytes.kernel_step_bytes(CFG, 30)  # the slice's rows beside the slice's time
     assert got["ssd_decode_roofline"] == pytest.approx(100 * need / 819e9 / 57.6e-3)
     assert 0 < got["ssd_decode_roofline"] < 100
     weights = 64 * 8 + 8 + 40 * 8 * 16 + 4 * 8 * 8 + 36 * 8 * 24  # the tied table ONCE, as the head
-    step = granite_bytes.decode_step_bytes(run)
-    assert step == pytest.approx(weights + granite_bytes.state_step_bytes(CFG, 60))  # no KV yet
+    step = granite_bytes.decode_step_bytes(cut)
+    assert step == pytest.approx(weights + granite_bytes.state_step_bytes(CFG, 30))  # no KV yet
     assert got["granite_round_roofline"] == pytest.approx(100 * 4 * step / 819e9 / 0.100)
     assert 0 < got["granite_round_roofline"] < 100
 
@@ -155,14 +164,16 @@ def test_each_new_reader_gives_nothing_where_the_program_lacks_what_it_reads(nam
     assert reader(name).read(bare) is None
     idle = granite_run()
     idle["end"] = idle["start"]  # a window without a decode round
+    idle["slice"]["rounds"] = []
     if name != "ssd_decode_ms":
         assert reader(name).read(idle) is None
     recorded = granite_run()
     path = os.path.join(ROOT, "benchmark", "fixtures", "v5e_decode_slice.xspace.txt")
     recorded["_planes"] = trace_reduce.read_planes(path)
     recorded["trace_reduced"] = trace_reduce.reduce_trace(path)
-    if name != "granite_round_roofline":  # the round's time is there; the kernel's is not
-        assert reader(name).read(recorded) is None
+    # the kernel is not there, and its one run of the decode program is cut by the slice's edge:
+    # no whole run, so no round's time either
+    assert reader(name).read(recorded) is None
 
 
 def test_the_other_hybrid_cells_readers_find_nothing_on_this_cell_and_keep_their_own():
@@ -262,9 +273,12 @@ ON_CELL = {*NEW, "decode_copy_ms", "decode_occupancy", "decode_round_ms", "engin
            "pallas_busy_share", "decode_token_yield", "engine_host_ms_per_round",
            "engine_event_gap_p95_ms", "stream_write_lag_p95_ms", "decode_attn_ms",
            "setup_first_dispatch_s.serve", "setup_first_dispatch_s.trace_lower",
-           "setup_first_dispatch_s.backend", "state_pool_share", "admit_program_share",
-           "admit_rows_mean", "admit_pad_waste_pct", "event_gap_admit_share", "event_gap_admit_ms",
+           "setup_first_dispatch_s.backend", "state_pool_share", "event_gap_admit_share",
            "slot_vacant_ms", "slot_vacant_queued_ms"}  # what PR 41 put on the cell; a later metric may list it too
+# PR 41 listed four more, which read an admit program of the window; since PR 42 this cell's
+# prompts ride a decode round and none runs, every traced run read None, and PR 47 took the cell
+# off their lists (they read in `kexaone_reason_closed`, whose rings rule the ride out)
+NO_ADMIT_PROGRAM = {"admit_program_share", "admit_rows_mean", "admit_pad_waste_pct", "event_gap_admit_ms"}
 
 
 def test_the_cell_is_the_hybrid_cells_traffic_number_for_number_and_its_entries_are_found_by_name(bench):
@@ -282,6 +296,7 @@ def test_the_cell_is_the_hybrid_cells_traffic_number_for_number_and_its_entries_
     layer = {m["name"]: m for m in bench["per_layer"]}
     on_cell = {n for n, m in layer.items() if CELL in m.get("workloads", [CELL])}
     assert on_cell >= ON_CELL, ON_CELL - on_cell
+    assert not on_cell & NO_ADMIT_PROGRAM
     for name in (*NEW, "decode_copy_ms"):  # its own entries, each on this cell alone
         assert layer[name]["workloads"] == [CELL] and layer[name]["moves"] == "out_tokens_per_s"
         mod = reader(name)

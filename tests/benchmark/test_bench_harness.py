@@ -2,6 +2,7 @@
 dropped in as new files are found without an edit; and one whole run at a tiny
 size on the CPU, with the device check stubbed here in the test."""
 
+import dataclasses
 import importlib.util
 import json
 import os
@@ -284,3 +285,208 @@ def test_a_reference_request_must_fit_the_positions_served(checkout):
         correctness.reference_request(TINY_V2, 256)
     with pytest.raises(AssertionError, match="holds"):
         correctness.reference_request({"reference_request": {"prompt_bytes": 9, "rows": 1}}, 512)
+
+
+# -- the traced slice brings its own counters (PR 47) ------------------------------
+
+
+def test_the_traced_slice_reads_the_counters_at_its_own_edges_and_its_own_rounds(checkout, monkeypatch,
+                                                                                   tmp_path):
+    """`trace_slice` on the CPU with a stand-in engine (a traced run cannot be
+    rehearsed whole here: its trace holds no device plane): the counters are
+    read inside the slice at both edges, the ring's rounds are the slice's and
+    the lead's, and `measure`'s `slice` is what `counters.slice_of` lays over
+    the run."""
+    import time
+    from types import SimpleNamespace
+
+    from benchmark import counters
+
+    _root, run = checkout
+    monkeypatch.setattr(run, "TRACE_SLICE_S", 0.4)
+    calls = []
+
+    def perf_stats():
+        calls.append(time.monotonic())
+        return {"phases": {"decode": {"samples": len(calls), "tokens": 256 * len(calls)}}}
+
+    t0 = time.monotonic()
+    ring = [{"etype": "decode", "fields": {"rid": 1, "rows": 64, "t": t0 - 5.0}},  # long before: not the slice's
+            {"etype": "mixed", "fields": {"rid": 2, "rows": 61, "t": t0 + 0.02}},  # within the lead
+            {"etype": "emit", "fields": {"rid": 2, "rows": 61, "delivered": 240, "t": t0 + 0.2}},
+            {"etype": "decode", "fields": {"rid": 3, "rows": 32, "t": t0 + 0.3}},
+            {"etype": "admit", "fields": None},
+            {"etype": "decode", "fields": {"rid": 9, "rows": 7, "t": t0 + 60.0}}]  # after it
+    gen = SimpleNamespace(perf_stats=perf_stats, _flight=SimpleNamespace(snapshot=lambda: ring))
+    out = {}
+    run.trace_slice(str(tmp_path / "trace"), t0 + 0.1, out, {"gen": gen})
+    first, last, rounds = (out["cut"][k] for k in ("start", "end", "rounds"))
+    assert out["start"] <= first["t"] < last["t"] <= out["stop"] <= out["written"]
+    assert last["t"] - out["start"] == pytest.approx(0.4, abs=0.15)
+    assert [e["perf"]["phases"]["decode"]["samples"] for e in (first, last)] == [1, 2]
+    assert rounds == [("mixed", 61, t0 + 0.02), ("decode", 32, t0 + 0.3)]
+    # an embedding cell has no engine loop: edges without counters, no rounds
+    bare = {}
+    run.trace_slice(str(tmp_path / "trace2"), time.monotonic(), bare, {"gen": None})
+    assert bare["cut"]["rounds"] == [] and set(bare["cut"]["start"]) == set(bare["cut"]["end"]) == {"t"}
+    # what a reader gets: the run with the slice laid over it, the window's own counters kept
+    whole = {"start": {"perf": "w0"}, "end": {"perf": "w1"}, "window": (10.0, 50.0), "records": [],
+             "slice": dict(out["cut"], window=(28.0, 28.4))}
+    cut = counters.slice_of(whole)
+    assert (cut["start"], cut["end"], cut["window"]) == (first, last, (28.0, 28.4))
+    assert counters.delta(cut, "perf", "phases", "decode", "tokens") == 256.0
+    assert counters.plain_rows(cut) == 32.0  # the plain round's, not the mixed round's 61
+    assert whole["start"] == {"perf": "w0"} and counters.slice_of({"records": []}) is None
+
+
+# -- a family brings a behaviour run.py has one value for (PR 47) ------------------
+
+TABLE_MODULE = '''"""A reference module whose tables a case of the test below states."""
+SERVED_TOL_REL = 0.1
+HELD = {held}
+ONLY = {only!r}
+STATED = {stated!r}
+
+
+def check(cfg):
+    pass
+
+
+def logits(cfg, params, tokens, rows, cols):
+    raise NotImplementedError
+'''
+
+
+@pytest.mark.parametrize("held,only,stated,refused", [
+    # a path of ONLY_VALUE in HELD: the family brings the behaviour and holds the key itself
+    ('{"n_group": lambda c: 8, "topk_group": lambda c: 3}', {}, {}, None),
+    ('{"moe_layer_freq": lambda c: [0, 1, 1]}', {}, {}, None),
+    # the same path in ONLY or STATED is refused as any overlap with run.py's own tables is
+    ("{}", {"n_group": 8}, {}, "ONLY holds ['n_group']"),
+    ("{}", {}, {"topk_group": "nothing reads it"}, "STATED holds ['topk_group']"),
+    ('{"n_group": lambda c: 8}', {"n_group": 8}, {}, "ONLY holds ['n_group']"),  # nor twice in the module
+    # every other path of run.py's tables, in any table, as before this PR
+    ('{"hidden_size": lambda c: c.dim}', {}, {}, "HELD holds ['hidden_size']"),
+    ("{}", {"hidden_size": 128}, {}, "ONLY holds ['hidden_size']"),
+    ("{}", {}, {"hidden_size": "a reason"}, "STATED holds ['hidden_size']"),
+    ('{"rope_scaling.factor": lambda c: 1.0}', {}, {}, "HELD holds ['rope_scaling.factor']"),
+    ('{"max_position_embeddings": lambda c: 512}', {}, {}, "HELD holds ['max_position_embeddings']"),
+])
+def test_a_module_may_hold_a_one_behaviour_key_in_held_and_only_there(checkout, held, only, stated, refused):
+    root, run = checkout
+    (root / "benchmark" / "references" / "case_tables.py").write_text(
+        TABLE_MODULE.format(held=held, only=only, stated=stated))
+    config = {"reference": "case_tables", "program": {"engine": "generation"}}
+    if refused is None:
+        name, mod = run.load_reference(config)
+        assert name == "case_tables" and set(mod.HELD) <= set(run.ONLY_VALUE) <= run.own_paths()
+    else:
+        with pytest.raises(AssertionError) as err:
+            run.load_reference(config)
+        assert refused in str(err.value) and "a path is held once" in str(err.value)
+
+
+V2_ROW = os.path.join(os.path.dirname(os.path.abspath(__file__)), "fixtures", "deepseek_v2_catalog_row.jsonl")
+
+
+def v2_share_file() -> tuple[dict, dict]:
+    """(the catalog's DeepSeek-V2 row, a configuration's file built from it): one
+    chip of an 8-way expert group, cut as ISSUE 47 reckons it: a dense and seven
+    expert layers, one routing group of 20 experts held, an eighth of the
+    vocabulary; no width changed, the routing keys as published."""
+    with open(V2_ROW) as f:
+        row = json.loads(f.readline())
+    cut = {"num_hidden_layers": 8, "n_routed_experts": 20, "vocab_size": 12_800}
+    config = {"name": "deepseek-v2-ep8-bf16", "source": row["source_url"], "reference": "deepseek_v2",
+              **json.loads(json.dumps(row["config"])), **cut,
+              "reduced": sorted(cut), "published": {k: row["config"][k] for k in cut},
+              "assumed": ["seeded random weights"], "deployment": "a test", "weights_seed": 0,
+              "program": {"engine": "generation", "env": {"TPU_MODEL": "deepseek-v2-ep8"}}}
+    return row, config
+
+
+def v2_program(**more):
+    """A `ModelConfig` at the file's sizes. `more` adds the fields a
+    `model_config` PR would bring for routing in groups; without it, it is
+    today's table, which has one behaviour."""
+    from llm_mcp_tpu.models.configs import ModelConfig
+
+    sizes = dict(
+        name="deepseek-v2-ep8", arch="mla", vocab_size=12_800, dim=5120, n_layers=8, n_heads=128,
+        n_kv_heads=1, ffn_hidden=12_288, norm_eps=1e-6, rope_theta=10_000.0, kv_lora_rank=512,
+        q_lora_rank=1536, qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128, n_experts=20,
+        n_router_experts=160, experts_per_tok=6, n_shared_experts=2, moe_ffn_hidden=1536,
+        first_dense_layers=1, norm_topk_prob=False, routed_scaling_factor=16.0, rope_factor=40.0,
+        rope_orig_max=4096, rope_type="yarn", yarn_beta_fast=32, yarn_beta_slow=1, yarn_mscale=0.707,
+        yarn_mscale_all_dim=0.707)
+    if not more:
+        return ModelConfig(**sizes)
+    grouped = dataclasses.make_dataclass(
+        "GroupedConfig", [(k, type(v), v) for k, v in more.items()], bases=(ModelConfig,), frozen=True)
+    return grouped(**sizes)
+
+
+def test_the_catalogs_deepseek_v2_row_is_held_as_files_with_no_edit(checkout):
+    """What stood between the row and a cell was run.py's `ONLY_VALUE`
+    (`n_group` and `topk_group` at 1): the file must state 8 and 3 for the
+    driver's comparison, and no module could take the paths over. Now the
+    family's own reference holds them, and the file is refused by VALUE against
+    a program with one behaviour, not by table."""
+    from benchmark import check_source
+
+    _root, run = checkout
+    row, config = v2_share_file()
+    assert check_source.differs(config, row) == []
+    assert (config["n_group"], config["topk_group"], config["topk_method"], config["scoring_func"],
+            config["seq_aux"], config["n_routed_experts"], config["published"]["n_routed_experts"]) == (
+        8, 3, "group_limited_greedy", "softmax", True, 20, 160)
+    name, module = run.load_reference(config)
+    assert name == "deepseek_v2" and {"n_group", "topk_group"} <= set(module.HELD) & set(run.ONLY_VALUE)
+    program = v2_program(n_group=8, topk_group=3)
+    unheld = run.check_sizes(config, program, module)
+    assert unheld == ["max_position_embeddings", "model_type", "seq_aux (" + module.STATED["seq_aux"] + ")"]
+    module.check(program)
+    # today's table has no field for the groups: one behaviour, and the value says so
+    with pytest.raises(AssertionError, match="n_group=8 in the file, 1 in the program"):
+        run.check_sizes(config, v2_program(), module)
+    # a program that routes in other groups than the file states
+    with pytest.raises(AssertionError, match="topk_group=3 in the file, 4 in the program"):
+        run.check_sizes(config, v2_program(n_group=8, topk_group=4), module)
+    # a router cut to the held experts is no share of the published one
+    with pytest.raises(AssertionError, match="published.n_routed_experts=160 in the file, 20 in"):
+        run.check_sizes(config, dataclasses.replace(program, n_router_experts=0), module)
+    # under a module that holds the other keys and not the groups the path is run.py's again:
+    # ONLY_VALUE stands, whatever fields the program has
+    from types import SimpleNamespace
+
+    others = SimpleNamespace(HELD={k: v for k, v in module.HELD.items() if k not in run.ONLY_VALUE},
+                             STATED=module.STATED)
+    with pytest.raises(AssertionError, match="n_group=8 in the file, 1 in the program"):
+        run.check_sizes(config, program, others)
+    # and a share that cuts a routing group is not what the reference computes
+    with pytest.raises(NotImplementedError, match="not whole groups of 20"):
+        module.check(dataclasses.replace(program, n_experts=30))
+    with pytest.raises(NotImplementedError, match="softmax alone"):
+        module.check(dataclasses.replace(program, router_score="sigmoid"))
+
+
+def test_a_list_valued_layer_pattern_is_compared_element_by_element(checkout):
+    """MiniMax-M3 states `moe_layer_freq` as a list, a layer each: held by a
+    module's `HELD`, it is compared with what the program's layers are, element
+    by element; with no holder it is refused against run.py's 1."""
+    from types import SimpleNamespace
+
+    from llm_mcp_tpu.models.configs import resolve_config
+
+    _root, run = checkout
+    cfg = resolve_config("tiny-v2", "")  # a dense layer, then two routed ones
+    module = SimpleNamespace(HELD={"moe_layer_freq": lambda c: [0] * c.first_dense_layers
+                                   + [1] * (c.n_layers - c.first_dense_layers)})
+    listed = dict(TINY_V2, moe_layer_freq=[0, 1, 1])
+    run.check_sizes(listed, cfg, module)
+    run.check_sizes(dict(TINY_V2, moe_layer_freq=[0.0, 1, True]), cfg, module)  # JSON numbers, by value
+    for wrong in ([0, 0, 1], [0, 1], [0, 1, 1, 1], 1):
+        with pytest.raises(AssertionError, match="moe_layer_freq="):
+            run.check_sizes(dict(TINY_V2, moe_layer_freq=wrong), cfg, module)
+    with pytest.raises(AssertionError, match=r"moe_layer_freq=\[0, 1, 1\] in the file, 1 in the program"):
+        run.check_sizes(listed, cfg)

@@ -40,11 +40,11 @@ def reader(name):
     return bench_run.load_reader("layer_metrics", name)
 
 
-def edge(rounds: int) -> dict:
+def edge(rounds: int, touched: int = 11) -> dict:
     """Counters at one edge: `rounds` x 4 decode steps of 60 rows since boot in
     each of the four expert layers, 11 held experts touched a step."""
     steps = 4 * rounds
-    counts = [[60 * steps, 220 * steps, 11 * steps, 30 * steps, steps] for _ in range(4)]
+    counts = [[60 * steps, 220 * steps, touched * steps, 30 * steps, steps] for _ in range(4)]
     return {"perf": {
         "experts": {"counts": [counts, [[0] * 5] * 4], "held": 16, "router": 128},
         "kv_kinds": {"full": {"layers": 1, "bytes": FULL, "positions": 64 * 4096, "live_positions": 0},
@@ -56,7 +56,10 @@ def kexaone_run(kernel: str = "decode_attn_win_q8") -> dict:
     """Counters at both edges, 60 requests in flight through the whole window
     (prompts of 800 tokens growing by 1000), a trace with 10 runs of the decode
     program of 50 ms, each holding 16 calls of the window arm of 0.05 ms and 4 of
-    the blocked arm of 0.4 ms."""
+    the blocked arm of 0.4 ms (the slice's edges cut the first and the last:
+    eight whole runs), and the traced slice as `run.measure` records it: late in
+    the window, where the sequences are 140 tokens longer than at its middle,
+    and its steps touch 7 held experts where the window's touch 11."""
     params = {"embed": np.zeros((64, 8), np.int8), "final_norm": np.zeros((8,), np.int8),
               "lm_head": np.zeros((8, 64), np.int8),
               "layers": {"attn_norm": np.zeros((4, 8), np.int8),
@@ -81,7 +84,10 @@ def kexaone_run(kernel: str = "decode_attn_win_q8") -> dict:
                for _ in range(60)]
     return {"sut": {"gen": gen}, "device": {"kind": "TPU v5 lite"},
             "start": edge(0), "end": edge(100), "records": records, "window": (10.0, 50.0),
-            "trace_reduced": {"module_runs": {"jit_decode_chunk_fn": [10, 0.050]}},
+            "slice": {"start": edge(0, touched=7), "end": edge(3, touched=7), "window": (40.0, 48.0),
+                      "window_abs": (140.0, 148.0), "rounds": [("decode", 60, 141.0)]},
+            "trace_reduced": {"module_runs": {"jit_decode_chunk_fn": [10, 0.050]},
+                              "whole_runs": {"jit_decode_chunk_fn": [8, 0.050]}},
             "_planes": ([(0, ops, mods)], {})}
 
 
@@ -124,15 +130,19 @@ def test_each_new_reader_gives_its_number_on_a_run_with_the_arm_and_the_counters
     assert got["win_attn_roofline"] == pytest.approx(100 * need / 819e9 / 0.8e-3)
     assert 0 < got["win_attn_roofline"] < 100
     # the global layer's arm: the decode attention kernels' time less the window arm's
+    # the fill of the SLICE's middle (44 of 100 s: 1240 tokens a sequence), whose time this is;
+    # the window's middle reads 1100
     assert got["full_attn_roofline"] == pytest.approx(
-        100 * 4 * POSITION * 60 * 1100 / 819e9 / (4 * 0.4e-3), rel=0.01)
+        100 * 4 * POSITION * 60 * 1240 / 819e9 / (4 * 0.4e-3), rel=0.01)
     assert 0 < got["full_attn_roofline"] < 100
     assert got["kv_window_bytes_share"] == pytest.approx(100 * RING / (RING + FULL))
     weights = 8 + 8 * 64 + 5 * 8 + 1 * 8 * 32 + 1 * 8 * 8 + 4 * 8 * 8  # all but the table and the banks
     one_expert = 3 * 8 * 4
-    step = kexaone_bytes.decode_step_bytes(run)
-    assert step == pytest.approx(weights + 4 * 11 * one_expert + POSITION * 60 * 1100
+    step = kexaone_bytes.decode_step_bytes(counters.slice_of(run))  # and the slice's 7 touched experts
+    assert step == pytest.approx(weights + 4 * 7 * one_expert + POSITION * 60 * 1240
                                  + 4 * POSITION * 60 * 128, rel=0.01)
+    assert kexaone_bytes.decode_step_bytes(run) == pytest.approx(  # the window's, no reader's
+        weights + 4 * 11 * one_expert + POSITION * 60 * 1100 + 4 * POSITION * 60 * 128, rel=0.01)
     assert got["kexaone_round_roofline"] == pytest.approx(100 * 4 * step / 819e9 / 0.050)
     assert 0 < got["kexaone_round_roofline"] < 100
     assert solar_bytes.live_rows(run) == pytest.approx(60.0)
@@ -146,13 +156,13 @@ def test_each_new_reader_gives_nothing_where_the_program_lacks_what_it_reads(nam
     Solar's cell (experts, no rings), decode_closed, a bare run, an untraced
     run, and the recorded v5e trace of decode_closed."""
     parent = kexaone_run(kernel="decode_attn_q8_whole")
-    for e in ("start", "end"):
-        del parent[e]["perf"]["kv_kinds"]
+    for e in (parent["start"], parent["end"], parent["slice"]["start"], parent["slice"]["end"]):
+        del e["perf"]["kv_kinds"]
     assert reader(name).read(parent) is None
     solar = kexaone_run(kernel="kda_decode_step")
     solar["sut"]["gen"].cfg = get_config("solar-open2-250b-ep8")
-    for e in ("start", "end"):
-        del solar[e]["perf"]["kv_kinds"]["window"]  # every layer with rows keeps full-length ones
+    for e in (solar["start"], solar["end"], solar["slice"]["start"], solar["slice"]["end"]):
+        del e["perf"]["kv_kinds"]["window"]  # every layer with rows keeps full-length ones
     assert reader(name).read(solar) is None
     bare = {"sut": {"gen": kexaone_run()["sut"]["gen"]}, "start": {}, "end": {}, "records": [],
             "window": (0.0, 1.0), "device": {"kind": "TPU v5 lite"}}
@@ -165,8 +175,9 @@ def test_each_new_reader_gives_nothing_where_the_program_lacks_what_it_reads(nam
         path = os.path.join(ROOT, "benchmark", "fixtures", "v5e_decode_slice.xspace.txt")
         recorded["_planes"] = trace_reduce.read_planes(path)
         recorded["trace_reduced"] = trace_reduce.reduce_trace(path)
-        if name != "kexaone_round_roofline":  # the round's time is there; the arm's is not
-            assert reader(name).read(recorded) is None
+        # the arm is not there, and its one run of the decode program is cut by the slice's
+        # edge: no whole run, so no round's time either
+        assert reader(name).read(recorded) is None
 
 
 def test_the_other_cells_kernel_readers_find_nothing_on_this_cell():

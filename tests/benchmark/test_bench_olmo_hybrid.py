@@ -17,7 +17,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from benchmark import olmo_hybrid_bytes, solar_bytes  # noqa: E402
+from benchmark import counters, olmo_hybrid_bytes, solar_bytes  # noqa: E402
 from benchmark import run as bench_run  # noqa: E402
 from llm_mcp_tpu.models.configs import get_config  # noqa: E402
 
@@ -40,7 +40,11 @@ def phases(rounds: int, rows: int) -> dict:
 def olmo_run(kernel: str = "gdn_decode_step") -> dict:
     """Counters at both edges (100 sampled rounds of 60 rows), a trace with 10
     runs of the decode program of 100 ms, each holding 60 calls of the state
-    kernel of 0.45 ms, and one request mid-stream for the whole window."""
+    kernel of 0.45 ms (the slice's edges cut the first and the last: eight
+    whole runs), one request mid-stream for the whole window, and the traced
+    slice as `run.measure` records it: the observatory took no sample inside
+    it, as on the chip, and of the rounds the engine dispatched in it the plain
+    ones carry 30 rows where the window's carry 60."""
     params = {"embed": np.zeros((64, 8), np.int8), "lm_head": np.zeros((8, 64), np.int8),
               "final_norm": np.zeros((8,), np.int8),
               "layers": {"w1": np.zeros((20, 8, 16), np.int8)},
@@ -61,7 +65,11 @@ def olmo_run(kernel: str = "gdn_decode_step") -> dict:
     return {"sut": {"gen": gen}, "device": {"kind": "TPU v5 lite"},
             "start": {"perf": phases(0, 60)}, "end": {"perf": phases(100, 60)},
             "records": [], "window": (10.0, 50.0), "_record": record,
-            "trace_reduced": {"module_runs": {"jit_decode_chunk_fn": [10, 0.100]}},
+            "slice": {"start": {"perf": phases(50, 60)}, "end": {"perf": phases(50, 60)},
+                      "window": (26.0, 34.0), "window_abs": (126.0, 134.0),
+                      "rounds": [("decode", 30, 126.5), ("mixed", 64, 128.0), ("decode", 30, 130.0)]},
+            "trace_reduced": {"module_runs": {"jit_decode_chunk_fn": [10, 0.100]},
+                              "whole_runs": {"jit_decode_chunk_fn": [8, 0.100]}},
             "_planes": ([(0, ops, mods)], {})}
 
 
@@ -98,15 +106,20 @@ def test_each_new_reader_gives_its_number_on_a_run_with_the_kernel_and_the_phase
     run = olmo_run()
     got = {name: reader(name).read(run) for name in NEW}
     assert all(v is not None for v in got.values()), got
-    assert olmo_hybrid_bytes.live_rows(run) == pytest.approx(60.0)
+    cut = counters.slice_of(run)
+    assert olmo_hybrid_bytes.live_rows(run) == pytest.approx(60.0)  # the window's sampled rounds
+    assert olmo_hybrid_bytes.live_rows(cut) == pytest.approx(30.0)  # the slice's plain rounds, every one
     assert got["gdn_decode_ms"] == pytest.approx(60 * 0.45)  # the stray call outside a run is not read
-    need = 4 * olmo_hybrid_bytes.kernel_step_bytes(CFG, 60)
+    need = 4 * olmo_hybrid_bytes.kernel_step_bytes(CFG, 30)  # the slice's rows beside the slice's time
     assert got["gdn_decode_roofline"] == pytest.approx(100 * need / 819e9 / 27e-3)
     assert 0 < got["gdn_decode_roofline"] < 100
     weights = 8 * 64 + 8 + 20 * 8 * 16 + 5 * 8 * 8 + 15 * 8 * 24  # head, norm, w1, wq, wqkv_lin
-    step = olmo_hybrid_bytes.decode_step_bytes(run)
-    assert step == pytest.approx(weights + olmo_hybrid_bytes.state_step_bytes(CFG, 60))  # no KV yet
+    step = olmo_hybrid_bytes.decode_step_bytes(cut)
+    assert step == pytest.approx(weights + olmo_hybrid_bytes.state_step_bytes(CFG, 30))  # no KV yet
     assert got["olmo_round_roofline"] == pytest.approx(100 * 4 * step / 819e9 / 0.100)
+    assert 0 < got["olmo_round_roofline"] < 100
+    del run["slice"]  # an untraced run: no device time to set a count beside
+    assert reader("gdn_decode_roofline").read(run) is None and reader("olmo_round_roofline").read(run) is None
 
 
 @pytest.mark.parametrize("name", NEW)
@@ -124,6 +137,7 @@ def test_each_new_reader_gives_nothing_where_the_program_lacks_what_it_reads(nam
     assert reader(name).read(bare) is None
     idle = olmo_run()
     idle["end"] = idle["start"]  # a window without a decode round
+    idle["slice"]["rounds"] = []
     if name != "gdn_decode_ms":
         assert reader(name).read(idle) is None
 

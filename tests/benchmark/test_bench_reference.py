@@ -206,7 +206,8 @@ def test_a_v2_reference_without_a_mechanism_would_be_caught(v2, left_out, monkey
 
 def test_v2_query_latent_branch_is_the_published_form(v2):
     """`q_lora_rank`: down, RMSNorm, up. The program has no such path, so the
-    branch is held to the equations written out in numpy."""
+    branch is held to the equations written out in numpy, as the routing in
+    groups and the share are (below)."""
     ref, cfg = v2
     cfg = dataclasses.replace(cfg, q_lora_rank=24)
     rng = np.random.default_rng(4)
@@ -255,6 +256,151 @@ def test_what_a_router_near_tie_costs_in_logits(v2, monkeypatch):
         regret.append(float(((want.max(axis=1) - want[np.arange(len(rows)), served]) / scale).max()))
     assert min(shift) > 0.01, shift  # the swaps are felt
     assert max(regret) < ref.SERVED_TOL_REL / 2, regret
+
+
+# -- routing in groups and an expert share (PR 47: the yardstick before the program's path) --
+
+# sha256 of the parent commit's logits (04b7733, before routing in groups) of tiny-v2 under
+# PRNGKey(5), 64 tokens of default_rng(3), every row and column; and of a small float32 product
+# at HIGHEST through exp on the machine that stored it: where this machine computes the canary
+# bit for bit, it has to compute the logits so too
+PARENT_LOGITS_SHA = "8bcc7e670c3f56799f5e9187621edca38324facfdfabd38ef0c5b51d6ebc7c81"
+CANARY_SHA = "2bfc83b6757401ab44a6e09af9fe60618af36f064c024c6d7a3a4346f137086d"
+
+
+def _sha(a) -> str:
+    import hashlib
+
+    return hashlib.sha256(np.ascontiguousarray(np.asarray(a, np.float32)).tobytes()).hexdigest()
+
+
+def _parent_gates(cfg, scores):
+    """`_gates` as the parent commit has it, letter for letter: greedy over all experts."""
+    top, idx = jax.lax.top_k(scores, cfg.experts_per_tok)
+    if cfg.norm_topk_prob and cfg.experts_per_tok > 1:
+        top = top / jnp.sum(top, axis=-1, keepdims=True)
+    else:
+        top = top * cfg.routed_scaling_factor
+    chosen = idx[:, :, None] == jnp.arange(cfg.n_experts)[None, None, :]
+    return jnp.sum(jnp.where(chosen, top[:, :, None], 0.0), axis=1)  # [T, E]
+
+
+def grouped(cfg, **fields):
+    """The configuration with the fields a `model_config` PR would bring
+    (`n_group`, `topk_group`): the reference reads them by `getattr`."""
+    values = {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)}
+    added = [(k, int, v) for k, v in fields.items() if k not in values]
+    made = dataclasses.make_dataclass("Grouped", added, bases=(type(cfg),), frozen=True) if added else type(cfg)
+    return made(**{**values, **fields})
+
+
+def test_v2_at_one_group_is_bit_for_bit_the_forward_before_routing_in_groups(v2, monkeypatch):
+    ref, cfg = v2
+    params = init_llama_params(cfg, jax.random.PRNGKey(5), dtype=jnp.float32)
+    toks = np.random.default_rng(3).integers(1, cfg.vocab_size, 64).astype(np.int32)
+    rows, cols = np.arange(64), np.arange(cfg.vocab_size)
+    got = ref.logits(cfg, params, toks, rows, cols)
+    # the fields at 1 and 1 are the configuration without them
+    one = ref.logits(grouped(cfg, n_group=1, topk_group=1), params, toks, rows, cols)
+    assert np.array_equal(got, one)
+    # against the parent's own selection on this machine, traced anew under another jit key
+    monkeypatch.setattr(ref, "_gates", _parent_gates)
+    parent = ref.logits(dataclasses.replace(cfg, name="tiny-v2-parent-gates"), params, toks, rows, cols)
+    assert np.array_equal(got, parent)
+    # and against the digest stored from the parent commit, where this machine rounds as that one did
+    a = np.random.default_rng(0).standard_normal((64, 128)).astype(np.float32)
+    b = np.random.default_rng(1).standard_normal((128, 96)).astype(np.float32)
+    canary = jnp.exp(jnp.matmul(jnp.asarray(a), jnp.asarray(b), precision=jax.lax.Precision.HIGHEST) * 0.05)
+    if _sha(canary) == CANARY_SHA:
+        assert _sha(got) == PARENT_LOGITS_SHA
+    assert ref.HELD["topk_method"](cfg) == "greedy" and ref.HELD["n_group"](cfg) == 1
+
+
+def _numpy_gates(scores, n_group, topk_group, k, norm, scale):
+    """ISSUE 47's equations in plain numpy, a row at a time: the best score of
+    each group of consecutive experts, the `topk_group` best groups keep their
+    scores and every other score is 0, the top k of what is left with their
+    scores as gates, renormalised or scaled."""
+    out = np.zeros_like(scores)
+    per = scores.shape[1] // n_group
+    for t, s in enumerate(scores):
+        best = s.reshape(n_group, per).max(axis=1)
+        kept = np.argsort(-best, kind="stable")[:topk_group]
+        left = np.where(np.isin(np.arange(len(s)) // per, kept), s, 0.0)
+        top = np.argsort(-left, kind="stable")[:k]
+        out[t, top] = s[top] / s[top].sum() if norm and k > 1 else s[top] * scale
+    return out
+
+
+@pytest.mark.parametrize("norm", [False, True])
+def test_v2_group_limited_routing_is_the_published_equations(v2, norm):
+    """8 experts in 4 groups, 2 groups a token, 3 experts a token: the chosen
+    experts and their gates are the numpy's, and no row's experts lie in more
+    than `topk_group` groups, though a third of the rows' greedy top 3 do."""
+    ref, base = v2
+    cfg = grouped(dataclasses.replace(base, n_experts=8, experts_per_tok=3, norm_topk_prob=norm,
+                                      routed_scaling_factor=16.0), n_group=4, topk_group=2)
+    ref.check(cfg)
+    rng = np.random.default_rng(11)
+    logits = rng.standard_normal((256, 8)).astype(np.float32) * 2.0
+    scores = np.exp(logits - logits.max(axis=1, keepdims=True))
+    scores = (scores / scores.sum(axis=1, keepdims=True)).astype(np.float32)
+    got = np.asarray(ref._gates(cfg, jnp.asarray(scores)))
+    want = _numpy_gates(scores, 4, 2, 3, norm, 16.0)
+    assert np.array_equal(got != 0, want != 0)  # the same experts, row for row
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=0)
+    groups = [set(np.flatnonzero(row) // 2) for row in got]
+    assert all((row != 0).sum() == 3 for row in got) and max(map(len, groups)) == 2
+    greedy = np.asarray(ref._gates(dataclasses.replace(cfg, name="greedy-8"), jnp.asarray(scores)))
+    assert np.array_equal(greedy, got)  # the name alone changes nothing
+    free = np.asarray(ref._gates(grouped(cfg, n_group=1, topk_group=1), jnp.asarray(scores)))
+    spread = [len(set(np.flatnonzero(row) // 2)) for row in free]
+    assert 0.2 < np.mean(np.asarray(spread) == 3) < 0.6  # what the limit forbids does occur without it
+    assert ref.HELD["topk_method"](cfg) == "group_limited_greedy"
+    assert (ref.HELD["n_group"](cfg), ref.HELD["topk_group"](cfg)) == (4, 2)
+
+
+def _ffn_stack(cfg, n_router, rng):
+    D, Fm, E = cfg.dim, cfg.moe_ffn_hidden, cfg.n_experts
+    w = lambda *shape: jnp.asarray(rng.standard_normal(shape).astype(np.float32) * shape[-2] ** -0.5)  # noqa: E731
+    return {"router": w(1, D, n_router), "w1e": w(1, E, D, Fm), "w3e": w(1, E, D, Fm), "w2e": w(1, E, Fm, D),
+            "w1s": w(1, D, 2 * Fm), "w3s": w(1, D, 2 * Fm), "w2s": w(1, 2 * Fm, D)}
+
+
+def test_v2_four_shares_of_two_experts_add_up_to_the_uncut_layer(v2):
+    """The guide's one test of a share: the router scores all 8 published
+    experts and chooses groups and the top 3 over all of them in every share; a
+    share applies its own two (one whole routing group, the first of ITS order)
+    and leaves out what the absent would add; the shared experts are applied
+    whole, so they are counted once. The four parts add up to the uncut layer."""
+    ref, base = v2
+    whole = grouped(dataclasses.replace(base, n_experts=8, experts_per_tok=3, n_shared_experts=2,
+                                        routed_scaling_factor=16.0), n_group=4, topk_group=2)
+    rng = np.random.default_rng(12)
+    stack = _ffn_stack(whole, 8, rng)
+    x = jnp.asarray(rng.standard_normal((48, whole.dim)).astype(np.float32))
+    li = jnp.int32(0)
+    uncut = np.asarray(ref._routed_ffn(whole, stack, li, x))
+    parts = []
+    for s in range(4):
+        # this chip's order of the published experts: its own group first, the others after it
+        order = np.roll(np.arange(8), -2 * s)
+        share = grouped(dataclasses.replace(whole, n_experts=2, n_router_experts=8,
+                                            n_shared_experts=2 if s == 0 else 0), n_group=4, topk_group=2)
+        ref.check(share)
+        assert ref.HELD["published.n_routed_experts"](share) == 8
+        held = dict(stack, router=stack["router"][:, :, order],
+                    **{k: stack[k][:, order[:2]] for k in ("w1e", "w3e", "w2e")})
+        parts.append(np.asarray(ref._routed_ffn(share, held, li, x)))
+    scale = float(np.abs(uncut).max())
+    np.testing.assert_allclose(sum(parts), uncut, atol=2e-6 * scale, rtol=0)
+    assert all(float(np.abs(p).max()) > 0.01 * scale for p in parts)  # every share adds its part
+    assert float(np.abs(parts[0] - uncut).max()) > 0.05 * scale  # and one share alone is not the layer
+    # a router cut to the held experts is another layer: every row would get its 3 experts from these 2
+    with pytest.raises(NotImplementedError):
+        ref.check(grouped(dataclasses.replace(whole, n_experts=3, n_router_experts=8), n_group=4, topk_group=2))
+    with pytest.raises(NotImplementedError, match="selection bias"):
+        ref._routed_ffn(whole, dict(stack, router_bias=jnp.zeros((1, 8))), li, x)
 
 
 def test_each_reference_refuses_the_other_family(v2):

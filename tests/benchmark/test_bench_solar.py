@@ -14,7 +14,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from benchmark import run as bench_run, solar_bytes  # noqa: E402
+from benchmark import counters, run as bench_run, solar_bytes  # noqa: E402
 from llm_mcp_tpu.models.configs import get_config  # noqa: E402
 
 NEW = ["solar_round_roofline", "kda_decode_ms", "kda_decode_roofline",
@@ -42,7 +42,11 @@ def experts_block(steps: int) -> dict:
 
 def solar_run() -> dict:
     """Counters at both edges, a trace with 10 runs of the decode program of
-    40 ms, each holding 12 calls of the state kernel of 0.8 ms."""
+    40 ms, each holding 12 calls of the state kernel of 0.8 ms (the slice's
+    edges cut the first and the last: eight whole runs), and the traced slice
+    as `run.measure` records it: the counters at ITS edges and the rounds the
+    engine dispatched in it. The window's steps carry 60 rows; the slice's
+    plain rounds carry 30, and a mixed round between them its own 64."""
     bank = np.zeros((4, 40, 8, 8), np.int8)  # shapes stand in: bytes are what is read
     params = {"embed": np.zeros((64, 8), np.int8), "lm_head": np.zeros((8, 64), np.int8),
               "final_norm": np.zeros((8,), np.int8),
@@ -62,7 +66,12 @@ def solar_run() -> dict:
             "start": {"perf": {"state_pool": pool_block(62), "experts": experts_block(0)}},
             "end": {"perf": {"state_pool": pool_block(64), "experts": experts_block(STEPS)}},
             "records": [], "window": (10.0, 50.0),
-            "trace_reduced": {"module_runs": {"jit_decode_chunk_fn": [10, 0.040]}},
+            "slice": {"start": {"perf": {"experts": experts_block(STEPS // 2)}},
+                      "end": {"perf": {"experts": experts_block(STEPS // 2 + 12)}},
+                      "window": (26.0, 34.0), "window_abs": (126.0, 134.0),
+                      "rounds": [("decode", 30, 126.5), ("mixed", 64, 128.0), ("decode", 30, 130.0)]},
+            "trace_reduced": {"module_runs": {"jit_decode_chunk_fn": [10, 0.040]},
+                              "whole_runs": {"jit_decode_chunk_fn": [8, 0.040]}},
             "_planes": ([(0, ops, mods)], {})}
 
 
@@ -74,15 +83,23 @@ def test_each_new_reader_gives_its_number_on_a_run_with_the_counters():
     assert got["moe_load_max_over_mean"] == pytest.approx(5 / (61 / 40))
     assert got["state_pool_share"] == pytest.approx(100 * (62 + 64) / 2 / 64)
     assert got["kda_decode_ms"] == pytest.approx(12 * 0.8)  # the stray call outside a run is not read
-    state = 3 * 60 * (2 * 64 * 128 * 128 * 4 + 6 * 64 * 128 * 4)  # a step: three layers, 60 live rows
+    # a step: three layers, and the 30 rows of the SLICE's plain rounds, whose time this is; not
+    # the window's 60, nor the mixed round's 64
+    state = 3 * 30 * (2 * 64 * 128 * 128 * 4 + 6 * 64 * 128 * 4)
     assert got["kda_decode_roofline"] == pytest.approx(100 * 4 * state / 819e9 / 9.6e-3)
     assert 0 < got["kda_decode_roofline"] < 100
     # the round: weights outside the banks once a step, 31 of 40 experts a layer, state, no KV yet
-    step = solar_bytes.decode_step_bytes(run)
+    cut = counters.slice_of(run)
+    step = solar_bytes.decode_step_bytes(cut)
     one_expert = 3 * 8 * 8
     weights = 64 * 8 + 8 + 4 * 8 * 320 + 8 * 8 + 3 * 8 * 24  # head, norm, router, wq, wqkv_lin
-    assert step == pytest.approx(weights + 4 * 31 * one_expert + solar_bytes.state_step_bytes(CFG, 60))
+    assert step == pytest.approx(weights + 4 * 31 * one_expert + solar_bytes.state_step_bytes(CFG, 30))
+    assert solar_bytes.decode_step_bytes(run) == pytest.approx(
+        weights + 4 * 31 * one_expert + solar_bytes.state_step_bytes(CFG, 60))  # the window's, no reader's
     assert got["solar_round_roofline"] == pytest.approx(100 * 4 * step / 819e9 / 0.040)
+    assert 0 < got["solar_round_roofline"] < 100
+    del run["slice"]  # an untraced run: no device time to set a count beside
+    assert reader("kda_decode_roofline").read(run) is None and reader("solar_round_roofline").read(run) is None
 
 
 @pytest.mark.parametrize("name", NEW)
@@ -91,6 +108,7 @@ def test_each_new_reader_gives_nothing_on_a_cell_without_the_counters(name):
     `state_pool` block and the trace no such kernel."""
     run = solar_run()
     run["start"], run["end"] = {"perf": {"phases": {}}}, {"perf": {"phases": {}}}
+    run["slice"].update(start=run["start"], end=run["end"])
     run["_planes"] = ([(0, [("%decode_attn_q8_blocked.1 = custom-call(...)", 1e6, 2e6)],
                         [("jit_decode_chunk_fn(77)", 0.0, 40e6)])], {})
     assert reader(name).read(run) is None
@@ -101,6 +119,7 @@ def test_each_new_reader_gives_nothing_on_a_cell_without_the_counters(name):
 def test_a_window_without_a_decode_step_gives_nothing():
     run = solar_run()
     run["end"]["perf"].update(state_pool=pool_block(64), experts=experts_block(0))
+    run["slice"].update(start=run["start"], end=run["end"], rounds=[])
     for name in ("solar_round_roofline", "kda_decode_roofline", "moe_local_pairs_per_row",
                  "moe_load_max_over_mean"):
         assert reader(name).read(run) is None

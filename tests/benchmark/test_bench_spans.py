@@ -13,7 +13,7 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from benchmark import run as bench_run, spans  # noqa: E402
+from benchmark import counters, run as bench_run, spans  # noqa: E402
 
 W = (100.0, 140.0)
 
@@ -98,14 +98,17 @@ APPEND = KERNEL.replace("decode_attn_q8_blocked.5", "append_kv_q8.9")
 
 
 def planes_by_hand(named=True, annotated=True):
-    """Two runs of the decode program of 130 ms and one admit; attention
-    kernels of 2 + 1 ms in the first run, 3 ms in the second, 5 ms of them in
-    the admit program (not counted); the engine thread's phases around them."""
+    """Two runs of the decode program of 130 ms between an admit the slice's
+    start cut and one its end cut (a per-run mean leaves both out:
+    `trace_reduce.whole_runs`); attention kernels of 2 + 1 ms in the first run,
+    3 ms in the second, 5 ms of them in the admit program (not counted); the
+    engine thread's phases around them."""
     k1, k2 = (KERNEL, OTHER_ARM) if named else (KERNEL.replace("decode_attn_q8_blocked", "branch_1_fun"),) * 2
     ops = [(k1, 10 * MS, 12 * MS), (k2, 20 * MS, 21 * MS), (APPEND, 30 * MS, 31 * MS),
            ("%fusion.1 = bf16[32,4096]{1,0} fusion(bf16[32,4096]{1,0} %decode_attn_q8_blocked.5)", 40 * MS, 90 * MS),
            (k1, 150 * MS, 153 * MS), (k1, 290 * MS, 295 * MS)]
-    mods = [("jit_decode_chunk_fn(1)", 0, 130 * MS), ("jit_decode_chunk_fn(1)", 140 * MS, 270 * MS),
+    mods = [("jit_admit_fn(2)", -9 * MS, -1 * MS),
+            ("jit_decode_chunk_fn(1)", 0, 130 * MS), ("jit_decode_chunk_fn(1)", 140 * MS, 270 * MS),
             ("jit_admit_fn(2)", 280 * MS, 300 * MS)]
     host = {"python3": [("np.asarray(jax.Array)", 0, 125 * MS)]}
     if annotated:
@@ -146,9 +149,16 @@ def test_decode_attn_roofline_is_live_kv_bytes_over_the_peak_over_the_kernels_ti
     rec = {"status": 200, "done": 45.0, "finish": "length", "events": [5.0, 45.0], "prompt_tokens": 6000,
            "completion_tokens": 0}
     run = {"_planes": planes_by_hand(), "sut": {"gen": gen}, "device": {"kind": "TPU v5 lite"},
-           "records": [rec], "window": (10.0, 40.0)}
+           "records": [rec], "window": (10.0, 40.0), "slice": {"window": (21.0, 29.0)}}
     row = peaks.kv_row_bytes(cfg, "int8")
     assert row == 36 * 8 * 2 * 130
     least_s = 4 * row * 6000 / 819e9
     assert reader("decode_attn_roofline").read(run) == pytest.approx(100.0 * least_s / 0.003)
     assert 0 < reader("decode_attn_roofline").read(run) < 100
+    # the live tokens are the SLICE's, whose kernels these are: a second stream that ends before
+    # the slice is in the window's mean and not in the reading
+    run["records"].append(dict(rec, events=[5.0, 20.0], done=20.0))
+    assert counters.mean_live_tokens(run) > 1.3 * 6000
+    assert reader("decode_attn_roofline").read(run) == pytest.approx(100.0 * least_s / 0.003)
+    del run["slice"]  # an untraced run's records have no device time beside them
+    assert reader("decode_attn_roofline").read(run) is None

@@ -53,14 +53,19 @@ def test_time_by_name_counts_leaves_and_knows_kernels(reduced):
     assert dict(map(tuple, reduced["modules"])).keys() == {"jit_admit_fn", "jit_decode_chunk_fn"}
     calls, mean_s = reduced["module_runs"]["jit_decode_chunk_fn"]
     assert calls == 1 and mean_s == pytest.approx(0.001382, rel=0.01)  # clipped at the cut
+    assert reduced["whole_runs"] == {}  # so it is no round's time: both runs touch the cut's edges
 
 
 def test_decode_round_readers_take_the_program_from_the_trace(reduced):
     from benchmark import counters, run as bench_run
 
     run = {"trace_reduced": reduced}
-    assert counters.decode_round_s(run) == reduced["module_runs"][counters.DECODE_PROGRAM][1]
-    assert bench_run.load_reader("layer_metrics", "decode_round_ms").read(run) == pytest.approx(1.382, rel=0.01)
+    # the recorded cut holds 1.382 ms of a decode round: a run the edge clipped, no round's time
+    assert counters.decode_round_s(run) is None
+    assert bench_run.load_reader("layer_metrics", "decode_round_ms").read(run) is None
+    whole = {"trace_reduced": dict(reduced, whole_runs={counters.DECODE_PROGRAM: [3, 0.0512]})}
+    assert counters.decode_round_s(whole) == 0.0512
+    assert bench_run.load_reader("layer_metrics", "decode_round_ms").read(whole) == pytest.approx(51.2)
     assert bench_run.load_reader("layer_metrics", "decode_round_ms").read({"trace_reduced": None}) is None
     share = bench_run.load_reader("layer_metrics", "pallas_busy_share").read(run)
     assert share == pytest.approx(100.0 * reduced["mosaic_s"] / reduced["busy_s"])
