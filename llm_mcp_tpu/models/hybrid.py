@@ -90,14 +90,12 @@ _RECURRENT = {
     "kda": SimpleNamespace(
         init_params=kda.init_kda_params, init_state=kda.init_kda_state,
         prefill=kda.kda_prefill, decode=kda.kda_decode, zero_state=kda.zero_state,
-        pool_rows=kda.pool_rows, head_major=kda.head_major,
         project=kda.project, operands=kda.operands, step_rows=kda.step_rows,
         scan_packed=kda.scan_packed, output=kda.output,
         taps=lambda cfg: cfg.lin_conv, scope=lambda cfg: cfg.lin_gates),  # "kda" | "gdn"
     "ssm": SimpleNamespace(
         init_params=ssm.init_ssm_params, init_state=ssm.init_ssm_state,
         prefill=ssm.ssm_prefill, decode=ssm.ssm_decode, zero_state=ssm.zero_state,
-        pool_rows=ssm.pool_rows, head_major=ssm.head_major,
         project=ssm.project, operands=ssm.operands, step_rows=ssm.step_rows,
         scan_packed=ssm.scan_packed, output=ssm.output,
         taps=lambda cfg: cfg.ssm_conv, scope=lambda cfg: "ssd"),
@@ -398,10 +396,9 @@ def hybrid_prefill(cfg, params, tokens, lengths, attn_impl="xla", quant_kv=False
             y, S_new, tail = rec.prefill(
                 cfg, lp, _sub_in(cfg, h, lp["attn_norm"]), lengths, S0, tail0)
             return _residual(cfg, h, _sub_out(cfg, y, lp["attn_norm"])), (
-                Ss.at[ik].set(rec.pool_rows(cfg, S_new)),
-                tails.at[ik].set(tail.reshape(B, -1))), None
+                Ss.at[ik].set(S_new), tails.at[ik].set(tail.reshape(B, -1))), None
 
-        carry = (jnp.zeros((Lk, *rec.pool_rows(cfg, S0).shape), jnp.float32),
+        carry = (jnp.zeros((Lk, *S0.shape), jnp.float32),
                  jnp.zeros((Lk, B, tail0[0].size), tail0.dtype))
     h, carry, counts, ys = _period_scan(cfg, params, h, carry, gqa_layer, rec_layer, valid)
     ks, vs = ys["gqa"]
@@ -491,12 +488,12 @@ def hybrid_prefill_chunk_batch(
 
         def rec_layer(h, carry, lp, ik):
             ck, cv, Ss, tails = carry
-            S0 = jnp.where(fresh[:, None, None, None], 0.0, rec.head_major(cfg, Ss[ik]))
+            S0 = jnp.where(fresh[:, None, None, None], 0.0, Ss[ik])
             tail0 = jnp.where(fresh[:, None, None], 0, tails[ik].reshape(A, rec.taps(cfg) - 1, -1))
             y, S_new, tail = rec.prefill(
                 cfg, lp, _sub_in(cfg, h, lp["attn_norm"]), nvalid, S0, tail0)
             return _residual(cfg, h, _sub_out(cfg, y, lp["attn_norm"])), (
-                ck, cv, Ss.at[ik].set(rec.pool_rows(cfg, S_new)),
+                ck, cv, Ss.at[ik].set(S_new),
                 tails.at[ik].set(tail.reshape(A, -1))), None
 
         def back(Ss, tails):
@@ -618,7 +615,9 @@ def hybrid_mixed_step(
 
     The prompts stay packed in ONE row of T positions, and the recurrence runs
     over the chunks of it that hold tokens and no more (a rung is an
-    executable's size, not its work). Each prompt starts at a multiple of
+    executable's size, not its work): with one decay a head as ONE Pallas call
+    a layer (kernels/kda.py:chunk_scan), which hands each prompt's state over
+    in the pool's layout. Each prompt starts at a multiple of
     `kda.CHUNK` (the engine stages them so: `_stage_ride`; the positions
     between are padding, row id R), so a prompt's first chunk is the scan's
     chunk whose first position is 0: there the state is zeroed, and a prompt's
@@ -686,10 +685,10 @@ def hybrid_mixed_step(
             o_p, after = rec.scan_packed(
                 jax.tree.map(lambda a: a[B:][None], ops), token[None], fresh, staged)
             y = rec.output(cfg, lp, jnp.concatenate([o_d, o_p[0]]), side, x.dtype)
-            own = jnp.take(after[:, 0], ends // kda.CHUNK, axis=0)  # [R, H, dk, dv]
+            own = jnp.take(after[:, 0], ends // kda.CHUNK, axis=0)  # [R, H / P, dk, P dv]
             return _residual(cfg, h, _sub_out(cfg, y, lp["attn_norm"])), (
                 {"S": S, "conv": conv},
-                Ss.at[ik].set(rec.pool_rows(cfg, own).reshape(Ss.shape[1:])),
+                Ss.at[ik].set(own.reshape(Ss.shape[1:])),
                 tails.at[ik].set(tail.reshape(R, -1).astype(tails.dtype))), None
 
     # the prompts' states ride the scan as [Lk, R, (H / P) dk, P dv]: with the

@@ -26,13 +26,16 @@ next token, or the next chunk of a prompt, continues where this one stopped.
 
 Two forms of one recurrence:
 
-- `kda_chunk_scan`, for prompts: the sequence in chunks of `CHUNK` tokens, a
-  `lax.scan` that carries S from chunk to chunk. Inside a chunk the rank-1
+- `kda_chunk_scan`, for prompts: the sequence in chunks of `CHUNK` tokens, S
+  carried from chunk to chunk. Inside a chunk the rank-1
   corrections depend on each other only through a unit lower-triangular
   system, solved once a chunk; the rest is products of [C, d] blocks. Every
   decay enters as exp of a difference of cumulative log decays that is <= 0
   (a later position against an earlier one), never as a quotient of two
-  exponentials, so a channel that forgets fast cannot overflow.
+  exponentials, so a channel that forgets fast cannot overflow. With one
+  decay a head ("gdn", and Mamba-2's form) the chunks are ONE Pallas call a
+  layer (kernels/kda.py:chunk_scan); with a decay a channel ("kda") a
+  `lax.scan` of `_chunk_step`, which is also the kernel's reference.
 - one token (`kda_decode`): the Pallas kernel of kernels/kda.py on the pool.
 
 The layer's parameters (stacked [Lk, ...] under params["kda"]; Ck = H dk,
@@ -49,7 +52,9 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..kernels.kda import heads_abreast, kda_decode_step, pack_state, unpack_state
+from ..kernels.attention import _interpret, _note_fall
+from ..kernels.kda import (
+    chunk_scan, chunk_scan_tiles, heads_abreast, kda_decode_step, pack_state, unpack_state)
 from ..ops.norms import rms_norm
 from .configs import ModelConfig
 from .quant import qdot
@@ -134,9 +139,11 @@ def init_kda_state(cfg: ModelConfig, n_layers: int, slots: int, dtype) -> dict[s
 
 
 def zero_state(cfg: ModelConfig, rows: int, dtype) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """(S0 [rows, H, dk, dv] f32, tail0 [rows, taps-1, W]) of fresh prompts."""
+    """(S0 [rows, H / P, dk, P dv] f32: the pool's layout, tail0 [rows, taps-1, W])
+    of fresh prompts."""
     H, dk, dv, taps = kda_sizes(cfg)
-    return (jnp.zeros((rows, H, dk, dv), jnp.float32),
+    P = state_abreast(cfg)
+    return (jnp.zeros((rows, H // P, dk, P * dv), jnp.float32),
             jnp.zeros((rows, taps - 1, conv_width(cfg)), dtype))
 
 
@@ -245,14 +252,34 @@ def _chunk_step(C: int, per_head: bool, delta: bool):
     return step
 
 
+def _kernel_name(g, k, v, beta, C: int) -> str:
+    """The chunk kernel's name in a trace where these operands take it
+    (kernels/kda.py:chunk_scan: one decay a head; `ssd_chunk_scan` without the
+    delta rule, `gdn_chunk_scan` with it), "" where they take `_chunk_step`: a
+    decay a key channel, which is another algorithm inside the chunk, and
+    shapes Mosaic cannot tile (counted in `kernels.attention.reference_falls`)."""
+    if g.ndim != 3:
+        return ""
+    name = "ssd_chunk_scan" if beta is None else "gdn_chunk_scan"
+    H, dv = v.shape[2:]
+    dk, W = k.shape[-1], heads_abreast(H, dv) * dv
+    if not _interpret() and not chunk_scan_tiles(C, dk, W):
+        _note_fall(name, f"C={C} H={H} dk={dk} dv={dv}: no legal tile", False)
+        return ""
+    return name
+
+
 def kda_chunk_scan(q, k, v, g, beta, S0):
     """The recurrence over a whole (padded) sequence, chunk by chunk.
 
     q, k [A, T, H, dk], v [A, T, H, dv], g the log decay (0 at a padding
     position): [A, T, H, dk] a key channel, or [A, T, H] one a head; beta
-    [A, T, H] (0 at a padding position), S0 [A, H, dk, dv]; all float32.
-    Returns (o [A, T, H, dv], S_T). A padding position leaves the state as it
-    was (alpha 1, beta 0) and its output is never read.
+    [A, T, H] (0 at a padding position); all float32. S0 and the state
+    returned are IN THE POOL'S LAYOUT, [A, H / P, dk, P dv] with P =
+    `heads_abreast(H, dv)` (kernels/kda.py; [A, H, dk, dv] itself where P is
+    1), which is how the layer's callers hold them and how the kernel takes and
+    leaves them. Returns (o [A, T, H, dv], S_T). A padding position leaves the
+    state as it was (alpha 1, beta 0) and its output is never read.
 
     Without the delta rule (`beta` None: a Mamba-2 layer, models/ssm.py) the
     state takes the input as it is, U = V, and there is no system to solve; v
@@ -265,17 +292,35 @@ def kda_chunk_scan(q, k, v, g, beta, S0):
     o = (Q exp(G)) S0 + qk U with qk[t, s] likewise for s <= t, and
     S_end = exp(G_end) S0 + (K exp(G_end - G))^T U. With one decay a head the
     decay between two positions is a [C, C] matrix and kk, qk are the products
-    K K^T, Q K^T masked by it; a decay a channel stands inside the sum over
-    d, a [C, C, dk] tensor a chunk."""
-    T = v.shape[1]
+    K K^T, Q K^T masked by it: that form is ONE Pallas call over the chunks
+    (kernels/kda.py:chunk_scan), the state in VMEM from chunk to chunk. A decay
+    a channel stands inside the sum over d, a [C, C, dk] tensor a chunk, and
+    is a `lax.scan` of `_chunk_step`, which is also what the kernel is held to
+    (tests/test_hybrid.py)."""
+    A, T = v.shape[:2]
     C = math.gcd(T, CHUNK)
     N = T // C
+    name = _kernel_name(g, k, v, beta, C)
+    if not name:
+        return _loop_chunk_scan(q, k, v, g, beta, S0)
+    return chunk_scan(
+        q, k, v, g, beta, S0, jnp.zeros((N,), bool), N,
+        jnp.broadcast_to(jnp.arange(A)[:, None], (A, N)), chunk=C, rows=A, name=name)
+
+
+def _loop_chunk_scan(q, k, v, g, beta, S0):
+    """`kda_chunk_scan` as a `lax.scan` of `_chunk_step` (head-major inside)."""
+    T, H, dv = v.shape[1:]
+    C = math.gcd(T, CHUNK)
+    N = T // C
+    P = heads_abreast(H, dv)
     delta = beta is not None
     step = _chunk_step(C, g.ndim == 3, delta)
     S, o = jax.lax.scan(
-        step, S0, (_chunked(q, N), _chunked(k, N), _chunked(v, N), _chunked(g, N),
-                   *([_chunked(beta[..., None], N)] if delta else [])))
-    return _unchunked(o), S
+        step, unpack_state(S0, P),
+        (_chunked(q, N), _chunked(k, N), _chunked(v, N), _chunked(g, N),
+         *([_chunked(beta[..., None], N)] if delta else [])))
+    return _unchunked(o), pack_state(S, P)
 
 
 def kda_packed_scan(q, k, v, g, beta, fresh, staged):
@@ -284,10 +329,31 @@ def kda_packed_scan(q, k, v, g, beta, fresh, staged):
     models/hybrid.py): a chunk marked in `fresh` [T / C] bool starts from zero
     state and not from its predecessor's. Only the first `staged` chunks (a
     traced count: those that hold tokens) are run; the positions behind them
-    read o = 0. Returns (o [A, T, H, dv], the state after EVERY chunk run
-    [T / C, A, H, dk, dv]: a sequence's own is the one after its last chunk)."""
-    A, T, H = v.shape[:3]
+    read o = 0. Returns (o [A, T, H, dv], the states by chunk in the pool's
+    layout, [T / C, A, H / P, dk, P dv] as `kda_chunk_scan` has it: a
+    sequence's own is the one after its last chunk RUN, and only that one is
+    there to read; the kernel writes no other, and with nothing staged chunk
+    0's reads zero)."""
+    A, T = v.shape[:2]
     assert T % CHUNK == 0, (T, CHUNK)
+    N = T // CHUNK
+    name = _kernel_name(g, k, v, beta, CHUNK)
+    if not name:
+        return _loop_packed_scan(q, k, v, g, beta, fresh, staged)
+    # a chunk's state goes to the slot of its sequence's last chunk run: the one
+    # before the next fresh chunk, or before the first chunk not staged
+    idx = jnp.arange(N)
+    ahead = jnp.where(fresh[None, :] & (idx[None, :] > idx[:, None]), idx[None, :], N)
+    last = jnp.maximum(jnp.minimum(jnp.min(ahead, axis=1), staged) - 1, 0)  # [N]; nothing staged: 0
+    o, after = chunk_scan(
+        q, k, v, g, beta, None, fresh, staged,
+        jnp.arange(A)[:, None] * N + last[None, :], chunk=CHUNK, rows=A * N, name=name)
+    return o, jnp.swapaxes(after.reshape(A, N, *after.shape[1:]), 0, 1)
+
+
+def _loop_packed_scan(q, k, v, g, beta, fresh, staged):
+    """`kda_packed_scan` as a `fori_loop` of `_chunk_step` to the last staged chunk."""
+    A, T, H = v.shape[:3]
     N = T // CHUNK
     delta = beta is not None
     step = _chunk_step(CHUNK, g.ndim == 3, delta)
@@ -304,7 +370,7 @@ def kda_packed_scan(q, k, v, g, beta, fresh, staged):
         0, staged, chunk,
         (S0, jnp.zeros((N, A, H, CHUNK, v.shape[-1]), jnp.float32),
          jnp.zeros((N, *S0.shape), jnp.float32)))
-    return _unchunked(o), after
+    return _unchunked(o), pack_state(after, heads_abreast(H, v.shape[-1]))
 
 
 def conv_chunk(tail0, nvalid, proj, conv_w):
@@ -396,7 +462,7 @@ def kda_prefill(
     kp: dict,  # this layer's weights (un-stacked)
     x: jnp.ndarray,  # [A, T, D] the layer's input of a chunk (or a whole prompt)
     nvalid: jnp.ndarray,  # [A] int32: valid positions of each row
-    S0: jnp.ndarray,  # [A, H, dk, dv] f32
+    S0: jnp.ndarray,  # [A, H / P, dk, P dv] f32: the pool's layout
     tail0: jnp.ndarray,  # [A, taps-1, W]
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """The layer over a chunk that continues (S0, tail0): (y [A, T, D], S,
@@ -454,11 +520,3 @@ def conv_step(tails, layer, slot_ids, live, proj, conv_w):
     return mixed, conv, slot_ids
 
 
-def pool_rows(cfg: ModelConfig, S: jnp.ndarray) -> jnp.ndarray:
-    """Head-major states [..., H, dk, dv] in the pool's layout."""
-    return pack_state(S, state_abreast(cfg))
-
-
-def head_major(cfg: ModelConfig, rows: jnp.ndarray) -> jnp.ndarray:
-    """Rows of the pool [..., H / P, dk, P dv] as head-major [..., H, dk, dv]."""
-    return unpack_state(rows, state_abreast(cfg))

@@ -14,9 +14,11 @@ B and C ONE group shared by every head.
 This is the delta-rule layer's recurrence (models/kda.py) with keys B, queries
 C, values dt x, one decay a head and NO correction: the state takes its input
 as it is. So the two forms are that layer's own: prompts go through
-`kda_chunk_scan` without its triangular solve, one token through the state
-pool's kernel without the delta rule (kernels/kda.py, under the name
-`ssd_decode_step`), B and C handed over as one row a batch row.
+`kda_chunk_scan` without its triangular solve (the chunk kernel of
+kernels/kda.py under the name `ssd_chunk_scan`, B and C one group that no copy
+a head is made of), one token through the state pool's kernel without the
+delta rule (under the name `ssd_decode_step`), B and C handed over as one row
+a batch row.
 
 What a slot owns of a layer is S (float32, in the pool's layout: P' heads
 abreast so that a row is a whole number of lanes) and the convolution's tail:
@@ -38,7 +40,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..kernels.kda import heads_abreast, kda_decode_step, pack_state, unpack_state
+from ..kernels.kda import heads_abreast, kda_decode_step
 from ..ops.norms import rms_norm
 from .configs import ModelConfig
 from .kda import conv_chunk, conv_step, kda_chunk_scan, kda_packed_scan
@@ -104,9 +106,11 @@ def init_ssm_state(cfg: ModelConfig, n_layers: int, slots: int, dtype) -> dict[s
 
 
 def zero_state(cfg: ModelConfig, rows: int, dtype) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """(S0 [rows, H, N, P] f32, tail0 [rows, taps-1, W]) of fresh prompts."""
+    """(S0 [rows, H / P', N, P' P] f32: the pool's layout, tail0 [rows, taps-1, W])
+    of fresh prompts."""
     H, P, N, taps = ssm_sizes(cfg)
-    return (jnp.zeros((rows, H, N, P), jnp.float32),
+    abreast = state_abreast(cfg)
+    return (jnp.zeros((rows, H // abreast, N, abreast * P), jnp.float32),
             jnp.zeros((rows, taps - 1, conv_width(cfg)), dtype))
 
 
@@ -193,7 +197,7 @@ def ssm_prefill(
     kp: dict,  # this layer's weights (un-stacked)
     x: jnp.ndarray,  # [A, T, D] the layer's input of a chunk (or a whole prompt)
     nvalid: jnp.ndarray,  # [A] int32: valid positions of each row
-    S0: jnp.ndarray,  # [A, H, N, P] f32
+    S0: jnp.ndarray,  # [A, H / P', N, P' P] f32: the pool's layout
     tail0: jnp.ndarray,  # [A, taps-1, W]
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """The layer over a chunk that continues (S0, tail0): (y [A, T, D], S,
@@ -224,11 +228,3 @@ def ssm_decode(
     return output(cfg, kp, o, side, x.dtype), {"S": S, "conv": conv}
 
 
-def pool_rows(cfg: ModelConfig, S: jnp.ndarray) -> jnp.ndarray:
-    """Head-major states [..., H, N, P] in the pool's layout."""
-    return pack_state(S, state_abreast(cfg))
-
-
-def head_major(cfg: ModelConfig, rows: jnp.ndarray) -> jnp.ndarray:
-    """Rows of the pool [..., H / P', N, P' P] as head-major [..., H, N, P]."""
-    return unpack_state(rows, state_abreast(cfg))

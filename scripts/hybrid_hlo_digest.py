@@ -10,15 +10,21 @@ models/hybrid.py, llama.py, kda.py, ssm.py, moe.py or the kernels that is
 meant to leave a configuration alone shows here in seconds.
 
     JAX_PLATFORMS=cpu python scripts/hybrid_hlo_digest.py [--tree _clean] [--out DIR]
+        [--lin-value-dim 128]
 
 `--tree` is a checkout of another commit (`git archive <commit> | tar -x -C
 _clean`); `--out` keeps the texts, to diff where a digest differs. PR 43 read
-all twelve equal between 40d3bf3 and its own tree.
+all twelve equal between 40d3bf3 and its own tree. `--lin-value-dim 128` gives
+the delta-rule presets Solar-Open2's own value heads, ONE head a tile of the
+pool (`kernels/kda.py:heads_abreast`; `tiny-solar`'s 32 lie four abreast, which
+Solar's never do): a change to where states are packed into the pool's layout
+moves `tiny-solar`'s text and leaves Solar's alone, and shows so here (PR 50).
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import hashlib
 import os
 import sys
@@ -31,6 +37,7 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--tree", default=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     ap.add_argument("--out", default="")
+    ap.add_argument("--lin-value-dim", type=int, default=0)
     args = ap.parse_args()
     sys.path.insert(0, os.path.abspath(args.tree))
 
@@ -47,6 +54,8 @@ def main() -> int:
     B, T, R = 4, 128, 4
     for name in PRESETS:
         cfg = get_config(name)
+        if args.lin_value_dim and cfg.lin_heads:
+            cfg = dataclasses.replace(cfg, lin_value_dim=args.lin_value_dim)
         params = jax.eval_shape(
             partial(llama.init_llama_params, cfg, jax.random.PRNGKey(0), dtype=jnp.float32))
         cache = jax.eval_shape(

@@ -1337,7 +1337,9 @@ def test_a_packed_chunk_scan_starts_from_zero_at_a_fresh_chunk(form):
     `kda_chunk_scan` gives the sequence alone from zero state, in all three
     forms of the recurrence; padding inside a sequence's last chunk and a whole
     chunk of it leave the state alone, and a chunk past the `staged` ones is
-    not run at all: its outputs and its state read 0."""
+    not run at all: its outputs read 0. The states come by chunk in the pool's
+    layout, a sequence's own at its last chunk RUN (the kernel form, one decay
+    a head, writes no other)."""
     from llm_mcp_tpu.models.kda import CHUNK, kda_chunk_scan, kda_packed_scan
 
     H, dk, dv = 3, 8, 16
@@ -1364,18 +1366,219 @@ def test_a_packed_chunk_scan_starts_from_zero_at_a_fresh_chunk(form):
     assert packed[0].shape[1] == T
     fresh = jnp.asarray([True, False, True, False])
     o, after = kda_packed_scan(*packed, fresh, 4)
-    assert after.shape == (4, 1, H, dk, dv)
+    assert after.shape == (4, 1, H, dk, dv)  # no count of 3 heads of 16 makes whole lanes
+    o3, after3 = jax.jit(kda_packed_scan)(*packed, fresh, jnp.int32(3))  # the chunks that hold tokens
     at = 0
-    for ops, n, last in zip(alone, lens, (1, 2)):
+    for ops, n, last, last3 in zip(alone, lens, (1, 3), (1, 2)):
         want_o, want_S = kda_chunk_scan(*ops, S0)
         np.testing.assert_allclose(o[:, at : at + n], want_o[:, :n], rtol=1e-6, atol=1e-7)
+        # a chunk of padding moves nothing: the second sequence's state is the same behind it
         np.testing.assert_allclose(after[last], want_S, rtol=1e-6, atol=1e-7)
+        np.testing.assert_allclose(after3[last3], want_S, rtol=1e-6, atol=1e-7)
         assert float(jnp.abs(want_S).max()) > 1e-2
         at += want_o.shape[1]
-    assert np.array_equal(after[3], after[2])  # a chunk of padding moves nothing
-    o3, after3 = jax.jit(kda_packed_scan)(*packed, fresh, jnp.int32(3))  # the chunks that hold tokens
     assert np.array_equal(o3[:, : 3 * CHUNK], o[:, : 3 * CHUNK]) and not o3[:, 3 * CHUNK :].any()
-    assert np.array_equal(after3[:3], after[:3]) and not after3[3].any()
+    if form == "a decay a channel":  # the loop form holds the state after EVERY chunk run
+        assert np.array_equal(after[3], after[2])
+        assert np.array_equal(after3[:3], after[:3]) and not after3[3].any()
+
+
+CHUNK_KERNEL_LAYOUTS = {  # H, dk, dv: the pool's tile [dk, P dv] of P heads abreast
+    "olmo_two_abreast": (4, 24, 192),  # dk 96 / dv 192 in miniature: rows of 384 = 3 x 128 lanes
+    "granite_two_abreast": (4, 32, 64),  # dk 128 / dv 64 in miniature: square tiles of 128
+    "one_head_a_tile": (3, 16, 128),
+    "no_whole_lanes": (3, 8, 16),  # the tiny presets' kind: interpreted here, the loop form on the chip
+}
+
+
+def _chunk_kernel_operands(rng, layout, group, arm, lens):
+    """Sequences of `lens` tokens, each padded to whole chunks: the recurrence's
+    operands one decay a head, as the layer masks them (no decay, no beta and,
+    without the delta rule, no input at a padding position)."""
+    from llm_mcp_tpu.models.kda import CHUNK
+
+    H, dk, dv = CHUNK_KERNEL_LAYOUTS[layout]
+    Hq = 1 if group == "one_group" else H
+    seqs = []
+    for n in lens:
+        t = -(-n // CHUNK) * CHUNK
+        valid = (np.arange(t) < n)[None, :, None]
+        q = rng.normal(size=(1, t, Hq, dk)).astype(np.float32)
+        k = rng.normal(size=(1, t, Hq, dk)).astype(np.float32)
+        q, k = q / np.linalg.norm(q, axis=-1, keepdims=True) * dk**-0.5, k / np.linalg.norm(k, axis=-1, keepdims=True)
+        v = rng.normal(size=(1, t, H, dv)).astype(np.float32)
+        g = (-np.exp(rng.uniform(-6.0, 0.5, size=(1, t, H))) * valid).astype(np.float32)
+        beta = (rng.uniform(0.1, 1.9, size=(1, t, H)) * valid).astype(np.float32)
+        seqs.append((q, k, v * valid[..., None] if arm == "no_delta" else v, g,
+                     None if arm == "no_delta" else beta))
+    return seqs
+
+
+def _by_token(q, k, v, g, beta, S, n):
+    """The recurrence token by token over the first n positions of ONE row, float64."""
+    q, k, v, g, S = (np.asarray(x, np.float64) for x in (q, k, v, g, S))
+    H = v.shape[2]
+    outs = []
+    for t in range(n):
+        kt, qt = np.broadcast_to(k[0, t], (H, k.shape[-1])), np.broadcast_to(q[0, t], (H, q.shape[-1]))
+        S = S * np.exp(g[0, t])[:, None, None]
+        u = v[0, t] if beta is None else np.asarray(beta, np.float64)[0, t][:, None] * (
+            v[0, t] - np.einsum("hk,hkv->hv", kt, S))
+        S = S + kt[:, :, None] * u[:, None, :]
+        outs.append(np.einsum("hk,hkv->hv", qt, S))
+    return np.stack(outs), S
+
+
+@pytest.mark.parametrize("arm", ["delta", "no_delta"])
+@pytest.mark.parametrize("group", ["one_group", "a_head_each"])
+@pytest.mark.parametrize("layout", list(CHUNK_KERNEL_LAYOUTS))
+def test_the_chunk_kernel_is_the_loop_form_from_a_carried_state(layout, group, arm):
+    """`kda_chunk_scan` with one decay a head is the Pallas kernel
+    (`kernels/kda.py:chunk_scan`, interpreted here): against the `lax.scan` of
+    `_chunk_step` it replaced and against the recurrence token by token, from a
+    NONZERO state, two rows of three chunks, the second with padding inside its
+    last chunk (70 of 96 positions), which leaves the state where the 70th
+    token put it; both arms, Q and K one group and a head each, the pool's
+    layouts of one head a tile and of two abreast."""
+    from llm_mcp_tpu.kernels.kda import heads_abreast, pack_state, unpack_state
+    from llm_mcp_tpu.models import kda
+
+    rng = np.random.default_rng(50)
+    lens = (96, 70)
+    rows = _chunk_kernel_operands(rng, layout, group, arm, lens)
+    ops = [None if rows[0][i] is None else jnp.asarray(np.concatenate([r[i] for r in rows]))
+           for i in range(5)]
+    H, dk, dv = CHUNK_KERNEL_LAYOUTS[layout]
+    P = heads_abreast(H, dv)
+    head_major = jnp.asarray(rng.normal(size=(2, H, dk, dv)), jnp.float32)
+    S0 = pack_state(head_major, P)  # both forms take and leave the pool's layout
+    assert kda._kernel_name(ops[3], ops[1], ops[2], ops[4], kda.CHUNK) == (
+        "ssd_chunk_scan" if arm == "no_delta" else "gdn_chunk_scan")
+    text = str(jax.make_jaxpr(kda.kda_chunk_scan)(*ops, S0))
+    assert "pallas_call" in text and "while" not in text and "bf16" not in text  # one call, float32
+    o, S = jax.jit(kda.kda_chunk_scan)(*ops, S0)
+    o_loop, S_loop = jax.jit(kda._loop_chunk_scan)(*ops, S0)
+    assert S.shape == S_loop.shape == (2, H // P, dk, P * dv)
+    np.testing.assert_allclose(o, o_loop, rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(S, S_loop, rtol=2e-5, atol=2e-6)
+    for a, n in enumerate(lens):
+        want_o, want_S = _by_token(*rows[a], head_major[a], n)
+        np.testing.assert_allclose(o[a, :n], want_o, rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(unpack_state(S, P)[a], want_S, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("arm", ["delta", "no_delta"])
+@pytest.mark.parametrize("group", ["one_group", "a_head_each"])
+@pytest.mark.parametrize("layout", list(CHUNK_KERNEL_LAYOUTS))
+def test_the_packed_chunk_kernel_is_each_prompt_alone_from_zero(layout, group, arm):
+    """`kda_packed_scan` with one decay a head is the same kernel over SEVERAL
+    fresh prompts in one row of eight chunks: one of exactly a chunk, one a
+    token over it, one of 70 tokens (padding inside its last chunk) and two
+    chunks nothing was staged in. Every prompt's outputs and its state after
+    its last chunk run are the loop form's of the prompt ALONE from zero state;
+    the outputs behind the staged chunks read 0; with `staged` below the row's
+    chunks (4 of 6) the cut prompt's state is the one after its first chunk,
+    where the 32nd token put it. The states come in the pool's layout."""
+    from llm_mcp_tpu.kernels.kda import heads_abreast, unpack_state
+    from llm_mcp_tpu.models import kda
+
+    C = kda.CHUNK
+    rng = np.random.default_rng(51)
+    lens = (32, 33, 70)
+    alone = _chunk_kernel_operands(rng, layout, group, arm, lens)
+    T = 8 * C
+    packed = []
+    for i in range(5):
+        if alone[0][i] is None:
+            packed.append(None)
+            continue
+        parts = [a[i] for a in alone]
+        parts.append(np.zeros((1, T - sum(p.shape[1] for p in parts), *parts[0].shape[2:]), np.float32))
+        packed.append(jnp.asarray(np.concatenate(parts, axis=1)))
+    H, dk, dv = CHUNK_KERNEL_LAYOUTS[layout]
+    P = heads_abreast(H, dv)
+    fresh = jnp.asarray([True, True, False, True, False, False, False, False])
+    scan = jax.jit(kda.kda_packed_scan)
+    o, after = scan(*packed, fresh, jnp.int32(6))
+    assert after.shape == (8, 1, H // P, dk, P * dv)
+    S0 = jnp.zeros((1, H // P, dk, P * dv), jnp.float32)
+    at = 0
+    for ops, n, last in zip(alone, lens, (0, 2, 5)):
+        want_o, want_S = kda._loop_chunk_scan(*(None if x is None else jnp.asarray(x) for x in ops), S0)
+        np.testing.assert_allclose(o[:, at : at + n], want_o[:, :n], rtol=2e-5, atol=2e-6)
+        np.testing.assert_allclose(after[last], want_S, rtol=2e-5, atol=2e-6)
+        assert float(jnp.abs(want_S).max()) > 1e-2
+        at += want_o.shape[1]
+    assert not np.asarray(o[:, 6 * C :]).any()  # nothing was run there
+    o4, after4 = scan(*packed, fresh, jnp.int32(4))  # the third prompt cut behind its first chunk
+    assert np.array_equal(o4[:, : 4 * C], o[:, : 4 * C]) and not np.asarray(o4[:, 4 * C :]).any()
+    assert np.array_equal(after4[0], after[0]) and np.array_equal(after4[2], after[2])
+    _, want_S = _by_token(*alone[2], np.zeros((H, dk, dv)), C)
+    np.testing.assert_allclose(unpack_state(after4[3], P)[0], want_S, rtol=1e-4, atol=1e-5)
+    # the loop form under the same contract: the same states in the same layout
+    o_loop, after_loop = jax.jit(kda._loop_packed_scan)(*packed, fresh, jnp.int32(6))
+    assert after_loop.shape == after.shape
+    np.testing.assert_allclose(o, o_loop, rtol=2e-5, atol=2e-6)
+    for last in (0, 2, 5):
+        np.testing.assert_allclose(after[last], after_loop[last], rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("arm", ["delta", "no_delta"])
+@pytest.mark.parametrize("group", ["one_group", "a_head_each"])
+@pytest.mark.parametrize("layout", ["granite_two_abreast", "no_whole_lanes"])
+def test_a_packed_chunk_kernel_with_nothing_staged_runs_nothing(layout, group, arm):
+    """`staged` 0: a mixed step computes the count from its row ids and nothing
+    in the program rules a row with no token out. No chunk is run, every output
+    reads 0, and the row of the states that the cells name (chunk 0's, never a
+    row before the buffer) reads 0 as the loop form's does."""
+    from llm_mcp_tpu.models import kda
+
+    rng = np.random.default_rng(52)
+    (ops,) = _chunk_kernel_operands(rng, layout, group, arm, (4 * kda.CHUNK,))
+    ops = [None if x is None else jnp.asarray(x) for x in ops]
+    fresh = jnp.asarray([True, False, True, False])
+    o, after = jax.jit(kda.kda_packed_scan)(*ops, fresh, jnp.int32(0))
+    o_loop, after_loop = jax.jit(kda._loop_packed_scan)(*ops, fresh, jnp.int32(0))
+    assert after.shape == after_loop.shape and not np.asarray(after_loop).any()
+    assert not np.asarray(o).any() and not np.asarray(o_loop).any()
+    assert not np.asarray(after[0]).any()
+
+
+def test_a_chunk_scan_no_tile_fits_falls_to_the_loop_form_and_is_counted(monkeypatch, tmp_path):
+    """On the chip a shape Mosaic cannot tile (a state row that is no whole
+    number of lanes: the tiny presets') takes `_chunk_step` and counts in
+    `kernels.attention.reference_falls` under the chunk kernel's name; a decay
+    a key channel is another algorithm and no fall; interpret mode takes the
+    kernel at any shape. The fall lands in a recorder of the test's own
+    (tests/test_tpu_compile.py says why)."""
+    from llm_mcp_tpu.kernels import attention as A
+    from llm_mcp_tpu.models import kda
+    from llm_mcp_tpu.telemetry import recorder as flight
+    from llm_mcp_tpu.utils import platform
+
+    sd = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    H, dk, dv = CHUNK_KERNEL_LAYOUTS["no_whole_lanes"]
+    ops = (sd(1, 64, H, dk), sd(1, 64, H, dk), sd(1, 64, H, dv), sd(1, 64, H), sd(1, 64, H), sd(1, H, dk, dv))
+    falls = dict(A.reference_falls)
+    assert "pallas_call" in str(jax.make_jaxpr(kda.kda_chunk_scan)(*ops))
+    monkeypatch.setattr(platform, "device_platform", lambda: "tpu")
+    jax.clear_caches()
+    own = flight.FlightRecorder(capacity=64, dump_dir=str(tmp_path))
+    flight.get_recorder()  # (makes the process's ring, if none was)
+    prev = flight.set_recorder(own)
+    try:
+        assert "pallas_call" not in str(jax.make_jaxpr(kda.kda_chunk_scan)(*ops))
+        assert A.reference_falls == {**falls, "gdn_chunk_scan": falls.get("gdn_chunk_scan", 0) + 1}
+        assert [e["fields"]["kernel"] for e in own.snapshot(etype="kernel_fall")] == ["gdn_chunk_scan"]
+        a_channel = (*ops[:3], sd(1, 64, H, dk), *ops[4:])
+        assert "pallas_call" not in str(jax.make_jaxpr(kda.kda_chunk_scan)(*a_channel))
+        assert A.reference_falls == {**falls, "gdn_chunk_scan": falls.get("gdn_chunk_scan", 0) + 1}
+    finally:  # table and ring are the process's: leave them as the other tests expect them
+        flight.set_recorder(prev)
+        A.reference_falls.clear()
+        A.reference_falls.update(falls)
+        monkeypatch.undo()
+        jax.clear_caches()
 
 
 def test_a_packed_convolution_reads_nothing_before_a_prompts_first_token():

@@ -438,6 +438,36 @@ def test_kda_decode_step(sd, name, H, dk, dv, head_decay):
     assert laid_out.argument_size_in_bytes == 3 * SOLAR_SLOTS * H * dk * dv * 4
 
 
+@pytest.mark.parametrize("rows,T,packed", [(1, 256, True), (4, 128, False)],
+                         ids=["a_mixed_rounds_row", "an_admit_programs_rows"])
+@pytest.mark.parametrize("name,H,dk,dv,one_group", [
+    ("ssd_chunk_scan", 64, 128, 64, True),  # Granite-4.0-H: no delta rule, B and C one group
+    ("gdn_chunk_scan", 30, 96, 192, False),  # Olmo-Hybrid: the delta rule, q and k a head each
+], ids=["ssd_64x128x64", "gdn_30x96x192"])
+def test_chunk_scan(sd, name, H, dk, dv, one_group, rows, T, packed):
+    """The chunk kernel alone at the two configurations' widths: a mixed
+    round's one row of 256 packed positions from zero state, a state by chunk
+    out, and an admit program's four rows of 128 from their states before; as
+    a Mosaic call with no fall, and for Granite with no [.., 64, 128] copy of
+    the one group's keys a head."""
+    import functools
+
+    from llm_mcp_tpu.kernels.kda import chunk_scan, heads_abreast
+
+    F32, N, P = jnp.float32, T // 32, heads_abreast(H, dv)
+    qk = sd((rows, T, 1 if one_group else H, dk), F32)
+    text = compile_for_chip(
+        functools.partial(chunk_scan, chunk=32, rows=rows * N if packed else rows, name=name,
+                          interpret=False),
+        qk, qk, sd((rows, T, H, dv), F32), sd((rows, T, H), F32),
+        None if name.startswith("ssd") else sd((rows, T, H), F32),
+        None if packed else sd((rows, H // P, dk, P * dv), F32),
+        sd((N,), jnp.bool_), sd((), I32), sd((rows, N), I32))
+    assert name in text
+    if one_group:
+        assert f"f32[{rows},{T},{H},{dk}]" not in text and f",{H},32,{dk}]" not in text
+
+
 def grouped_kernels_in(text: str) -> bool:
     """Both of `kernels/grouped.py`'s calls, and no product over all the pairs."""
     assert "ragged-dot" not in text
@@ -581,9 +611,9 @@ def test_olmo_hybrid_step_programs_fit_beside_64_slots(sd, olmo, chip_kernels, w
     assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
     text = compiled.as_text()
     # the bucketed chunk's attention over [past | self] is `jax.numpy` for every
-    # configuration (llama._chunk_attention) and so is the chunked recurrence:
-    # a chunk program holds no Mosaic call (PERF.md section 7)
-    assert ("tpu_custom_call" in text) == (which != "chunk")
+    # configuration (llama._chunk_attention: PERF.md section 7); the chunked
+    # recurrence of a prompt is the chunk kernel, one Mosaic call a layer
+    assert ("%gdn_chunk_scan" in text) == (which != "decode") and "%ssd_chunk_scan" not in text
     assert ("%gdn_decode_step" in text) == (which == "decode") and "%kda_decode_step" not in text
     if which == "decode":
         assert "decode_attn_q8" in text and "append_kv_q8" in text
@@ -635,7 +665,7 @@ def test_granite_step_programs_fit_beside_64_slots(sd, granite, chip_kernels, wh
         params, cache["k"], cache["v"], *(sd(shape, I32) for shape in operands)).compile()
     assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
     text = compiled.as_text()
-    assert ("tpu_custom_call" in text) == (which != "chunk")  # as Olmo-Hybrid's: PERF.md section 7
+    assert ("%ssd_chunk_scan" in text) == (which != "decode") and "%gdn_chunk_scan" not in text
     assert ("%ssd_decode_step" in text) == (which == "decode")
     assert "%kda_decode_step" not in text and "%gdn_decode_step" not in text
     if which == "decode":
@@ -723,10 +753,10 @@ def test_kexaone_step_programs_fit_and_keep_both_kinds_of_cache_in_place(
 
 @pytest.mark.parametrize("rung", [128, 256])
 @pytest.mark.parametrize("name,kernel,limit,temps", [
-    ("solar", "%kda_decode_step", 15.75, 0.25), ("olmo", "%gdn_decode_step", 15.0, 0.5),
+    ("solar", "%kda_decode_step", 15.75, 0.25), ("olmo", "%gdn_decode_step", 15.0, 0.35),
     # its cache of 0.27 GiB is re-laid for the kernels and back, as in its decode
     # round (heads of 64: PERF.md section 7), and the prompts' states ride the scan
-    ("granite", "%ssd_decode_step", 15.0, 1.5)])
+    ("granite", "%ssd_decode_step", 15.0, 1.25)])
 def test_hybrid_mixed_round_fits_beside_its_decode_round(
     sd, request, chip_kernels, name, kernel, limit, temps, rung
 ):
@@ -750,6 +780,10 @@ def test_hybrid_mixed_round_fits_beside_its_decode_round(
     assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
     text = compiled.as_text()
     assert kernel in text and "decode_attn_q8" in text and "append_kv_q8" in text
+    # the prompts' recurrence with one decay a head is the chunk kernel; Solar's, a
+    # decay a key channel, stays the loop of `jax.numpy` (models/kda.py)
+    scan = kernel.replace("decode_step", "chunk_scan")
+    assert (scan in text) == (name != "solar") and ("chunk_scan" in text) == (name != "solar")
     assert grouped_kernels_in(text) == (name == "solar")  # its 64 + rung rows through the expert kernels
     nbytes = lambda tree: sum(  # noqa: E731
         int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(tree))
