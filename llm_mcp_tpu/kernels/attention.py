@@ -6,9 +6,14 @@ line scanner. Here the hot loop is attention over the KV cache, so it gets
 hand-written TPU kernels:
 
   - `flash_prefill_attention` — causal flash attention for prompt prefill.
-    Online-softmax over key blocks: scores never materialize in HBM, VMEM
-    holds one [BQ, BK] tile at a time, the two matmuls hit the MXU at
-    [128, 128] granularity.
+    Online-softmax over key blocks: scores never materialize in HBM. A grid
+    cell is one query block of the G query heads of a KV group, [G*BQ, hd]
+    against each K/V block; it runs the key blocks its mask leaves (from the
+    window's lower edge to the diagonal, cut at the prompt's length; none for
+    a query block past the length), masks the edge blocks alone, holds the
+    scores transposed ([BK, G*BQ]) so that the softmax's row statistics lie
+    along the lanes, and feeds both products to the MXU in the operands' own
+    dtype with a float32 accumulator.
   - `decode_attention` — single-position GQA attention over the cache for
     the continuous batch. Bandwidth-bound: the win is streaming K/V through
     VMEM exactly once per step in their native [S, hd] tiling and fusing
@@ -182,67 +187,136 @@ def _interpret() -> bool:
 def _flash_prefill_kernel(
     lengths_ref,  # [B] int32 (SMEM)
     window_ref,  # [1] int32 (SMEM) — sliding window, 0 = global
-    q_ref,  # [1, 1, BQ, hd]
+    q_ref,  # [1, G, BQ, hd] — one query block of a KV group's G heads
     k_ref,  # [1, 1, S, hd]
     v_ref,  # [1, 1, S, hd]
-    o_ref,  # [1, 1, BQ, hd]
+    o_ref,  # [1, G, BQ, hd]
     *,
     scale: float,
     block_k: int,
-    seq_len: int,
     softcap: float,
 ):
+    """One grid cell = one query block of the G query heads that share a KV
+    head: their rows are ONE [G*BQ, hd] operand against each K and V block
+    (row r is head r // BQ at position qi*BQ + r % BQ), so a K/V block is read
+    from VMEM once for the group.
+
+    The scores of a step are held TRANSPOSED, [BK, G*BQ]: keys down the
+    sublanes, the cell's rows along the lanes. A row's max and sum over the
+    keys are then element-wise work between vregs and one fold of eight
+    sublanes, and m, l and alpha are [1, G*BQ], a vreg for every 128 rows;
+    with the rows down the sublanes each of the two reductions was a lane
+    reduction a vreg of scores and each of m, l, alpha a vreg for every EIGHT
+    rows, which cost the step more than its exponentials (PERF.md section 6,
+    PR 51). The accumulator is [hd, G*BQ] and is turned once, at the cell's end.
+
+    The cell runs the key blocks its mask leaves and no other: from the block
+    that holds the window's lower edge of the first query row (block 0 on a
+    global layer) to the diagonal's, cut at the block that holds position
+    valid_len - 1. A query block whose first row lies at or past valid_len
+    runs no step and writes zeros. Of those blocks the ones that lie wholly
+    under the diagonal, inside the window of every row and under the length
+    take the unmasked body; the edges (the window's lower edge, the diagonal,
+    the length's block) take the masked one, in a loop of their own after the
+    others: the online softmax does not care in which order blocks arrive.
+
+    Both products run on the operands' own dtype with a float32 accumulator
+    (bfloat16 x bfloat16 on the chip: the MXU's full rate, and the same
+    products a float32 cast gave). The scale and the soft cap are applied to
+    the float32 scores; m, l, acc and the exponentials are float32; p is
+    rounded to v's dtype for the second product, as the XLA arm of
+    `models/llama.py:prefill_attn` rounds its probabilities (float32 inputs
+    stay float32 throughout)."""
     b = pl.program_id(0)
     qi = pl.program_id(2)
-    bq = q_ref.shape[2]
-    hd = q_ref.shape[3]
+    _, G, bq, hd = q_ref.shape
+    rows = G * bq
+    bk = block_k
     valid_len = lengths_ref[b]
     window = window_ref[0]
+    q_lo = qi * bq  # the block's first and last query positions
+    q_hi = q_lo + bq - 1
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale  # [BQ, hd]
-    q_pos = qi * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)  # [BQ, 1]
+    q = q_ref[0].reshape(rows, hd)
+    q_pos = q_lo + (jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1) & (bq - 1))
 
-    acc = jnp.zeros((bq, hd), dtype=jnp.float32)
-    m = jnp.full((bq, 1), NEG_INF, dtype=jnp.float32)
-    l = jnp.zeros((bq, 1), dtype=jnp.float32)
+    # Key blocks [lo, hi) hold every key some row of the cell attends; of
+    # them [full_lo, full_hi) need no mask.
+    windowed = window > 0
+    lo = jnp.where(windowed, jax.lax.div(jnp.maximum(q_lo - window + 1, 0), bk), 0)
+    hi = jnp.minimum(jax.lax.div(q_hi, bk) + 1, jax.lax.div(valid_len + bk - 1, bk))
+    hi = jnp.where(q_lo < valid_len, hi, lo)
+    full_lo = jnp.where(
+        windowed, jax.lax.div(jnp.maximum(q_hi - window + 1, 0) + bk - 1, bk), 0)
+    full_lo = jnp.clip(full_lo, lo, hi)
+    full_hi = jnp.clip(
+        jnp.minimum(jax.lax.div(q_lo + 1, bk), jax.lax.div(valid_len, bk)), full_lo, hi)
+    n_low = full_lo - lo  # edge blocks under the unmasked ones; the rest lie over
 
-    # Causal: key block kb only matters while kb*BK <= last q position.
-    n_kb = jnp.minimum((qi * bq + bq + block_k - 1) // block_k, seq_len // block_k)
-
-    def body(kb, carry):
-        acc, m, l = carry
-        k = k_ref[0, 0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, 0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
+    def step(kb, carry, masked: bool):
+        acc, m, l = carry  # [hd, rows], [1, rows], [1, rows]
+        at = pl.multiple_of(kb * bk, bk)
+        k = k_ref[0, 0, pl.ds(at, bk), :]
+        v = v_ref[0, 0, pl.ds(at, bk), :]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [BQ, BK]
+            k, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # [BK, rows]
         if softcap:
             s = jnp.tanh(s / softcap) * softcap
-        k_pos = kb * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1
-        )  # [1, BK]
-        mask = (k_pos <= q_pos) & (k_pos < valid_len)
-        mask &= (window == 0) | (q_pos - k_pos < window)
-        s = jnp.where(mask, s, NEG_INF)
-
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        # Mask p explicitly: a fully-masked row keeps m_new == NEG_INF, where
-        # exp(s - m_new) == 1 would silently average V; masked p keeps l == 0
-        # so the guard below emits 0 for such rows.
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        if masked:
+            k_pos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+            mask = (k_pos <= q_pos) & (k_pos < valid_len)
+            mask &= jnp.logical_not(windowed) | (q_pos - k_pos < window)
+            s = jnp.where(mask, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+        p = jnp.exp(s - m_new)
+        if masked:
+            # Mask p explicitly: a fully-masked row keeps m_new == NEG_INF,
+            # where exp(s - m_new) == 1 would silently average V; masked p
+            # keeps l == 0 so the guard below emits 0 for such rows.
+            p = jnp.where(mask, p, 0.0)
         alpha = jnp.exp(m - m_new)
-        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
         acc = acc * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
+            v, p.astype(v.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [hd, rows]
         return acc, m_new, l
 
-    acc, m, l = jax.lax.fori_loop(0, n_kb, body, (acc, m, l))
-    # l == 0 when a row saw no unmasked key (valid_len == 0) — emit 0
-    # instead of 0/0 NaN. Padding rows with valid_len > 0 still attend the
+    carry = (
+        jnp.zeros((hd, rows), dtype=jnp.float32),
+        jnp.full((1, rows), NEG_INF, dtype=jnp.float32),
+        jnp.zeros((1, rows), dtype=jnp.float32),
+    )
+    carry = jax.lax.fori_loop(
+        full_lo, full_hi, functools.partial(step, masked=False), carry)
+
+    def edge(e, carry):
+        kb = jnp.where(e < n_low, lo + e, full_hi + e - n_low)
+        return step(kb, carry, masked=True)
+
+    acc, m, l = jax.lax.fori_loop(0, n_low + hi - full_hi, edge, carry)
+    # l == 0 when a row saw no unmasked key (valid_len == 0, or a query block
+    # wholly past valid_len, which ran no step) — emit 0 instead of 0/0 NaN.
+    # Padding rows inside a block that holds valid rows still attend the
     # valid prefix and produce garbage the caller never reads.
     out = jnp.where(l > 0, acc / jnp.where(l > 0, l, 1.0), 0.0)
-    o_ref[0, 0] = out.astype(o_ref.dtype)
+    o_ref[0] = out.T.reshape(G, bq, hd).astype(o_ref.dtype)
+
+
+def prefill_block(group: int, seq_len: int) -> int:
+    """Query and key block of `flash_prefill_attention` (one size for both: a
+    square diagonal block wastes least under the causal mask) for a KV group
+    of `group` query heads at a bucket of `seq_len` positions: the largest
+    power of two that divides `seq_len` and keeps a cell at 1,024 rows or
+    under, between 128 and 512 (a bucket under 128 is one block). Measured on a
+    v5e (scripts/flash_prefill_sweep.py; PERF.md section 6, PR 51): a group
+    of 8 at 128 (a window layer 167 us a call of 1 x 1024 against 190 at key
+    blocks of 256), of 4 at 256 (141 against 154 at 128), of 1 at 512 (168
+    against 377 at 128: a cell of 128 rows pays its fixed costs eight times as
+    often). A function of the shapes alone."""
+    cap = min(512, max(128, 1024 // group))
+    return next(b for b in (512, 256, 128, seq_len) if b <= cap and seq_len % b == 0)
 
 
 @functools.partial(
@@ -257,39 +331,39 @@ def flash_prefill_attention(
     window: jnp.ndarray | int = 0,  # sliding window (0 = global); may be traced
     softcap: float = 0.0,  # Gemma2-style score soft-capping (0 = off)
     scale: float = 0.0,  # query scale override (0 = head_dim**-0.5)
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: int = 0,  # 0 = the rule (`prefill_block`); given only by
+    block_k: int = 0,  #   scripts/flash_prefill_sweep.py and tests
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Causal + length-masked GQA flash attention. Returns [B, H, S, hd]."""
+    """Causal + length-masked GQA flash attention. Returns [B, H, S, hd]; rows
+    at or past a prompt's length hold nothing a caller may read."""
     B, H, S, hd = q.shape
     Hkv = k.shape[1]
     G = H // Hkv
-    bq = min(block_q, S)
-    bk = min(block_k, S)
-    assert S % bq == 0 and S % bk == 0, (S, bq, bk)
+    rule = prefill_block(G, S)
+    bq, bk = min(block_q or rule, S), min(block_k or rule, S)
+    assert S % bq == 0 and S % bk == 0 and bq & (bq - 1) == 0, (S, bq, bk)
     interp = _interpret() if interpret is None else interpret
 
     kernel = functools.partial(
         _flash_prefill_kernel,
         scale=scale or hd**-0.5,
         block_k=bk,
-        seq_len=S,
         softcap=softcap,
     )
     win = jnp.reshape(jnp.asarray(window, dtype=jnp.int32), (1,))
     return pl.pallas_call(
         kernel,
         name="flash_prefill_attn",
-        grid=(B, H, S // bq),
+        grid=(B, Hkv, S // bq),
         in_specs=[
             _smem_spec(),  # lengths [B]
             _smem_spec(),  # window [1]
-            pl.BlockSpec((1, 1, bq, hd), lambda b, h, qi: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, S, hd), lambda b, h, qi: (b, h // G, 0, 0)),
-            pl.BlockSpec((1, 1, S, hd), lambda b, h, qi: (b, h // G, 0, 0)),
+            pl.BlockSpec((1, G, bq, hd), lambda b, h, qi: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, S, hd), lambda b, h, qi: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, S, hd), lambda b, h, qi: (b, h, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, hd), lambda b, h, qi: (b, h, qi, 0)),
+        out_specs=pl.BlockSpec((1, G, bq, hd), lambda b, h, qi: (b, h, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, hd), q.dtype),
         interpret=interp,
     )(lengths.astype(jnp.int32), win, q, k, v)

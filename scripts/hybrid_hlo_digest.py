@@ -19,6 +19,9 @@ the delta-rule presets Solar-Open2's own value heads, ONE head a tile of the
 pool (`kernels/kda.py:heads_abreast`; `tiny-solar`'s 32 lie four abreast, which
 Solar's never do): a change to where states are packed into the pool's layout
 moves `tiny-solar`'s text and leaves Solar's alone, and shows so here (PR 50).
+The whole-prompt prefill is lowered on the Pallas arm since PR 51, as the cells
+run it (before, on the XLA arm, a change to `flash_prefill_attention` moved no
+digest: PR 51 read the three prefills alone differ from dd59802's).
 """
 
 from __future__ import annotations
@@ -67,8 +70,8 @@ def main() -> int:
                       (i32(B), i32(B), i32(T), i32(T), i32(T), i32(R), i32(R))),
             "chunk": (lambda p, ck, cv, *a: llama.llama_prefill_chunk_batch(
                 cfg, p, ck, cv, *a, skey=64), (i32(2, 32), i32(2), i32(2), i32(2))),
-            "prefill": (lambda p, ck, cv, *a: llama.llama_prefill(cfg, p, *a, quant_kv=True),
-                        (i32(2, 64), i32(2))),
+            "prefill": (lambda p, ck, cv, *a: llama.llama_prefill(
+                cfg, p, *a, attn_impl="pallas", quant_kv=True), (i32(2, 64), i32(2))),
         }
         for tag, (fn, operands) in programs.items():
             module = jax.jit(fn).lower(params, cache["k"], cache["v"], *operands).compiler_ir(

@@ -27,18 +27,23 @@ def params():
     return init_llama_params(CFG, jax.random.PRNGKey(0), dtype=jnp.float32)
 
 
-def _ref_attention(q, k, v, lengths, causal):
-    """[B, H, S, hd] x [B, Hkv, S, hd] dense-masked reference in f64-ish f32."""
+def _ref_attention(q, k, v, lengths, causal, window=0, softcap=0.0):
+    """[B, H, S, hd] x [B, Hkv, S, hd] dense-masked reference in f64-ish f32.
+    `window` > 0: a row sees its last `window` positions, itself among them."""
     B, H, S, hd = q.shape
     Hkv = k.shape[1]
     G = H // Hkv
     qg = q.reshape(B, Hkv, G, S, hd).astype(jnp.float32)
     s = jnp.einsum("bhgqd,bhkd->bhgqk", qg, k.astype(jnp.float32)) * (hd**-0.5)
+    if softcap:
+        s = jnp.tanh(s / softcap) * softcap
     kpos = jnp.arange(S)[None, None, None, None, :]
     mask = kpos < lengths[:, None, None, None, None]
     if causal:
         qpos = jnp.arange(S)[None, None, None, :, None]
         mask = mask & (kpos <= qpos)
+        if window:
+            mask = mask & (qpos - kpos < window)
     s = jnp.where(mask, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     # fully-masked rows → zero output (matches kernel's l==0 guard)
@@ -48,18 +53,88 @@ def _ref_attention(q, k, v, lengths, causal):
     return out.reshape(B, H, S, hd)
 
 
-def test_flash_prefill_matches_reference():
-    key = jax.random.PRNGKey(1)
-    B, H, Hkv, S, hd = 2, 4, 2, 64, 32
-    kq, kk, kv = jax.random.split(key, 3)
-    q = jax.random.normal(kq, (B, H, S, hd), dtype=jnp.float32)
-    k = jax.random.normal(kk, (B, Hkv, S, hd), dtype=jnp.float32)
-    v = jax.random.normal(kv, (B, Hkv, S, hd), dtype=jnp.float32)
-    lengths = jnp.array([64, 37], dtype=jnp.int32)
+def _qkv(seed, B, Hkv, G, S, hd, dtype=jnp.float32):
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(kq, (B, Hkv * G, S, hd), dtype=dtype),
+            jax.random.normal(kk, (B, Hkv, S, hd), dtype=dtype),
+            jax.random.normal(kv, (B, Hkv, S, hd), dtype=dtype))
 
-    out = flash_prefill_attention(q, k, v, lengths, block_q=32, block_k=32)
-    ref = _ref_attention(q, k, v, lengths, causal=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-4, atol=1e-4)
+
+def _assert_close_under_length(out, ref, lengths, tol=1e-4):
+    """Rows under each prompt's length alone: the kernel runs no step for a
+    query block wholly past the length, the dense reference lets such a row
+    see the valid prefix, and no caller reads either."""
+    live = (jnp.arange(out.shape[2])[None, :] < lengths[:, None])[:, None, :, None]
+    np.testing.assert_allclose(np.asarray(jnp.where(live, out.astype(jnp.float32), 0.0)),
+                               np.asarray(jnp.where(live, ref, 0.0)), rtol=tol, atol=tol)
+
+
+# (G, hd, S, block): every group size and head size of the cells, S of one,
+# four and six blocks of 64 (32 at the smallest), a block of 128 once
+FLASH_SHAPES = [(1, 32, 64, 32), (4, 64, 256, 64), (8, 128, 384, 64), (8, 32, 256, 128),
+                (4, 128, 64, 32), (1, 64, 384, 64)]
+# window: none, under a block, a block, a block and a half, over S
+FLASH_WINDOWS = [0, 24, 64, 96, 1000]
+
+
+@pytest.mark.parametrize("window", FLASH_WINDOWS)
+@pytest.mark.parametrize("G,hd,S,block", FLASH_SHAPES)
+def test_flash_prefill_matches_reference(G, hd, S, block, window):
+    """The grouped cell, its loop bounds and its edge masks against the dense
+    mask: lengths 0, one position, mid-block, a block's end and S."""
+    lengths = jnp.array([0, 1, S // 2 + 5, block, S], dtype=jnp.int32)
+    q, k, v = _qkv(1, len(lengths), 2, G, S, hd)
+    out = flash_prefill_attention(q, k, v, lengths, window=window, block_q=block, block_k=block)
+    ref = _ref_attention(q, k, v, lengths, causal=True, window=window)
+    _assert_close_under_length(out, ref, lengths)
+    assert not np.asarray(jnp.isnan(out)).any()
+    # a query block wholly past the length runs no step and writes zeros
+    first_dead = -(-np.asarray(lengths) // block) * block
+    for b, at in enumerate(first_dead):
+        assert not np.asarray(out[b, :, at:]).any()
+
+
+@pytest.mark.parametrize("block_q,block_k", [(32, 64), (64, 32), (128, 32), (32, 128)])
+@pytest.mark.parametrize("window", [0, 40, 64])
+def test_flash_prefill_unequal_blocks(block_q, block_k, window):
+    """Query and key blocks of unlike sizes: the diagonal then spans several
+    key blocks, or a key block several query blocks."""
+    S = 256
+    lengths = jnp.array([S, 131, 64, 0], dtype=jnp.int32)
+    q, k, v = _qkv(5, len(lengths), 1, 4, S, 32)
+    out = flash_prefill_attention(q, k, v, lengths, window=window, block_q=block_q, block_k=block_k)
+    ref = _ref_attention(q, k, v, lengths, causal=True, window=window)
+    _assert_close_under_length(out, ref, lengths)
+
+
+@pytest.mark.parametrize("softcap", [0.0, 5.0])
+def test_flash_prefill_traced_window_and_soft_cap(softcap):
+    """`llama_prefill` scans layers with a per-layer window: ONE traced kernel
+    answers for every window, the global layer's 0 among them."""
+    S = 256
+    lengths = jnp.array([S, 150], dtype=jnp.int32)
+    q, k, v = _qkv(6, 2, 2, 4, S, 64)
+    fn = jax.jit(lambda w: flash_prefill_attention(
+        q, k, v, lengths, window=w, softcap=softcap, block_q=64, block_k=64))
+    for window in (0, 17, 64, 200):
+        out = fn(jnp.int32(window))
+        ref = _ref_attention(q, k, v, lengths, causal=True, window=window, softcap=softcap)
+        _assert_close_under_length(out, ref, lengths)
+    assert fn._cache_size() == 1
+
+
+@pytest.mark.parametrize("window", [0, 128])
+def test_flash_prefill_bfloat16_inputs(window):
+    """bfloat16 operands (the cells' dtype) at the rule's own blocks: products
+    on bfloat16 with a float32 accumulator, p rounded to bfloat16 for the
+    second product, against the float32 reference on the same rounded inputs."""
+    S = 384
+    lengths = jnp.array([S, 200], dtype=jnp.int32)
+    q, k, v = _qkv(7, 2, 1, 8, S, 128, dtype=jnp.bfloat16)
+    out = flash_prefill_attention(q, k, v, lengths, window=window)
+    assert out.dtype == jnp.bfloat16
+    ref = _ref_attention(q, k, v, lengths, causal=True, window=window)
+    _assert_close_under_length(out, ref, lengths, tol=2e-2)
 
 
 def test_decode_attention_matches_reference():

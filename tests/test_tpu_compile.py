@@ -92,12 +92,38 @@ def decode_operands(sd, ba):
     return (sd((ba, HKV, G, HD), BF), sd((ba, HKV, HD), BF), sd((ba, HKV, HD), BF))
 
 
-def test_flash_prefill_attention(sd):
-    compile_for_chip(
-        lambda q, k, v, n: A.flash_prefill_attention(q, k, v, n, interpret=False),
-        sd((4, HKV * G, 512, HD), BF), sd((4, HKV, 512, HD), BF),
-        sd((4, HKV, 512, HD), BF), sd((4,), I32),
-    )
+# [B, H, S, hd], KV heads, window (None: a traced scalar, as a scanned layer
+# stack hands it over): the prompt attention as the admit programs of the
+# benchmark's cells hold it (PERF.md section 4), and the smallest bucket
+FLASH_PREFILL_SHAPES = {
+    "llama_4x512": ((4, HKV * G, 512, HD), HKV, 0),
+    "kexaone_1024_win": ((1, 64, 1024, 128), 8, 128),
+    "kexaone_1024_global": ((1, 64, 1024, 128), 8, 0),
+    "kexaone_768_win": ((1, 64, 768, 128), 8, 128),
+    "kexaone_768_global": ((1, 64, 768, 128), 8, 0),
+    "kexaone_1024_traced": ((1, 64, 1024, 128), 8, None),
+    "granite_1024": ((1, 32, 1024, 64), 8, 0),
+    "granite_768": ((1, 32, 768, 64), 8, 0),
+    "olmo_hybrid_1024": ((1, 30, 1024, 128), 30, 0),
+    "olmo_hybrid_768": ((1, 30, 768, 128), 30, 0),
+    "olmo_hybrid_32": ((1, 30, 32, 128), 30, 0),
+    "qwen3_64_traced": ((1, 32, 64, 128), 8, None),
+}
+
+
+@pytest.mark.parametrize("case", list(FLASH_PREFILL_SHAPES))
+def test_flash_prefill_attention(sd, case):
+    """The grouped, transposed-score cell at every group size, head size and
+    bucket the cells give it, with the block its rule gives there: a Mosaic
+    call under the name the trace readers look for, and no fall."""
+    (b, h, s, hd), hkv, window = FLASH_PREFILL_SHAPES[case]
+    traced = [sd((), I32)] if window is None else []
+    text = compile_for_chip(
+        lambda q, k, v, n, *w: A.flash_prefill_attention(
+            q, k, v, n, window=w[0] if w else window, interpret=False),
+        sd((b, h, s, hd), BF), sd((b, hkv, s, hd), BF), sd((b, hkv, s, hd), BF), sd((b,), I32),
+        *traced)
+    assert "flash_prefill_attn" in text
 
 
 @pytest.mark.parametrize("mode", ["whole", "blocked", "auto"])
