@@ -2443,9 +2443,13 @@ class GenerationEngine:
 
     @property
     def state_dtype(self) -> str:
-        """The recurrent state pool's precision ("" without one): a
-        configuration's file states it (`program.expect`)."""
-        return str(self._cv["state"]["S"].dtype) if self._layout.slot_member == "state" else ""
+        """The recurrent state pool's precision ("" without one): the matrix
+        state's, or the convolution tails' for a kind whose only state they are;
+        a configuration's file states it (`program.expect`)."""
+        if self._layout.slot_member != "state":
+            return ""
+        state = self._cv["state"]
+        return str(state.get("S", state["conv"]).dtype)
 
     def _layer_leaf_dtype(self, *names: str) -> str:
         """Precision of the first of `names` among the layers' leaves (the

@@ -14,11 +14,14 @@ any of that again:
     tiny-olmo-hybrid "int8"      False  True   True  "state"     gqa_int8
     tiny-granite-hybrid "int8"   False  True   True  "state"     gqa_int8
     tiny-kexaone "int8"          False  True   True  "win"       gqa_int8
+    tiny-lfm2 "int8"             False  True   True  "state"     gqa_int8
 
 `latent`: MLA's two asymmetric members (models/mla.py). `fused`: int8 GQA, V
 rides `cache["k"]`'s head axis and `cache["v"]` is the empty dict
 (models/llama.py:init_kv_cache). `slot_member`: the member of `cache["v"]` that
-holds one row a slot beside the full-length rows (`hybrid.SLOT_MEMBERS`); the
+holds one row a slot beside the full-length rows (`hybrid.SLOT_MEMBERS`; a
+"state" is a matrix state and a convolution's tail a layer, or for a kind
+without a matrix state, `tiny-lfm2`'s, the tail alone); the
 full-length rows' second member is then `cache["v"]["v"]`. `without`: what
 such a configuration runs without, feature to reason (`memory.RECURRENT_OFF`,
 the one list), empty where every layer keeps full-length rows.

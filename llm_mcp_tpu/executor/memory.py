@@ -355,7 +355,9 @@ class StatePool:
     The device arrays ride the engine's cache pair through every step program
     (models/hybrid.py: float32 S [Lk, slots, H / P, dk, P dv], P heads abreast
     so that a row is a whole number of lanes and the pool's bytes in HBM are
-    its logical bytes (kernels/kda.py), and the convolution tails), one row a
+    its logical bytes (kernels/kda.py), and the convolution tails; a kind whose
+    only state is its tail, models/shortconv.py, has no S and `layout` no such
+    entry: kilobytes a slot where the others hold megabytes), one row a
     slot, allocated with the engine like the KV cache. A
     slot's row is claimed at admission and starts from zero there: a whole
     prompt's prefill writes the row outright, a chunked prefill's first chunk
@@ -425,8 +427,9 @@ def build_state_pool(cfg: Any, max_slots: int, state: Any, log: Any) -> "StatePo
         return None
     ring = cfg.recurrent_kind == "win"
     pool = StatePool(max_slots=max_slots, nbytes=pytree_nbytes(state), layout=_shapes(state))
-    log.info("%s: %.1f MB a slot, %d slots beside the KV cache",
-             "window layers' rings" if ring else "recurrent state pool",
+    log.info("%s: %.2f MB a slot, %d slots beside the KV cache",
+             "window layers' rings" if ring else "recurrent state pool" if "S" in state
+             else "convolution tails (no matrix state)",
              pool.bytes_per_slot / (1 << 20), max_slots)
     for feature, why in RECURRENT_OFF.items():
         log.info("%s is off for %s: %s", feature, cfg.name, why)

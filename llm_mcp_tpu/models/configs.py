@@ -122,8 +122,9 @@ class ModelConfig:
     # whose per-slot state has a fixed size (`recurrent_kind`): a Mamba-2
     # state-space layer (models/ssm.py) where `ssm_heads` is set, a gated
     # delta-rule layer (models/kda.py: KDA or Gated DeltaNet) where `lin_heads`
-    # is, else a WINDOW attention layer (`sliding_windows`) on a ring of its
-    # last positions. Empty = every layer is the family's attention layer.
+    # is, a gated short convolution (models/shortconv.py) where `conv_taps` is,
+    # else a WINDOW attention layer (`sliding_windows`) on a ring of its last
+    # positions. Empty = every layer is the family's attention layer.
     gqa_layers: tuple[int, ...] = ()
     gqa_interval: int = 0  # linear layers between two GQA layers (published)
     lin_heads: int = 0
@@ -145,6 +146,11 @@ class ModelConfig:
     ssm_head_dim: int = 0
     ssm_state: int = 0
     ssm_conv: int = 4
+    # A gated short convolution layer (models/shortconv.py; LFM2's `conv`):
+    # taps of its causal depthwise convolution over the product B * x at the
+    # model's width, no bias, no activation. Its per-slot state is the
+    # convolution's tail alone: no matrix state
+    conv_taps: int = 0
     attn_gate: bool = False  # GQA output gate: attn * sigmoid(x W_gate)
     use_rope: bool = True  # False: no positional encoding anywhere (NoPE)
     # False: where window and global layers are mixed, only the window layers
@@ -195,9 +201,13 @@ class ModelConfig:
     @property
     def recurrent_kind(self) -> str:
         """The kind of the layers that are not GQA layers, which is also their
-        key in the parameter tree: "ssm" (Mamba-2), "kda" (the delta rule) or
-        "win" (window attention on a ring)."""
-        return "ssm" if self.ssm_heads else "kda" if self.lin_heads else "win"
+        key in the parameter tree: "ssm" (Mamba-2), "kda" (the delta rule),
+        "conv" (a gated short convolution) or "win" (window attention on a
+        ring)."""
+        for kind, sized in (("ssm", self.ssm_heads), ("kda", self.lin_heads), ("conv", self.conv_taps)):
+            if sized:
+                return kind
+        return "win"
 
     @property
     def ring_len(self) -> int:
@@ -210,7 +220,7 @@ class ModelConfig:
 
     @property
     def layer_period(self) -> tuple[str, ...]:
-        """Kinds ("gqa" | "kda" | "ssm" | "win") of one period of the layer
+        """Kinds ("gqa" | "kda" | "ssm" | "conv" | "win") of one period of the layer
         pattern AFTER the leading dense layers (`first_dense_layers`, which the
         decoder unrolls before its scan where the feed-forward has experts);
         the rest of the stack is this period repeated (models/hybrid.py scans
@@ -279,6 +289,15 @@ class ModelConfig:
             ng = len(self.gqa_layers)
             ffn_total += ng * attn + (self.n_layers - ng) * ssm  # exact: no mean a layer
             attn = 0
+        elif self.gqa_layers and self.conv_taps:  # hybrid: GQA and gated short convolution layers
+            conv = (self.dim * 3 * self.dim  # W_in: B | C | x
+                    + self.conv_taps * self.dim  # the depthwise taps
+                    + self.dim * self.dim)  # W_out
+            ng = len(self.gqa_layers)
+            ffn_total += ng * (attn + (2 * hd if self.qk_norm else 0)) + (self.n_layers - ng) * conv
+            if self.n_experts and self.router_score == "sigmoid":  # the selection bias
+                ffn_total += (self.n_layers - self.first_dense_layers) * self.router_width
+            attn = 0  # exact, to the parameter: no mean a layer
         elif self.gqa_layers and self.lin_heads:  # hybrid: GQA (+ gate) layers and delta-rule layers
             hk, hv = self.lin_heads * self.lin_head_dim, self.lin_heads * self.lin_dv
             mix = (self.dim * (2 * hk + hv)  # wq, wk, wv
@@ -680,6 +699,64 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         norm_placement="output",
         global_rope=False,
         mtp_layers=1,
+        params_b=0.002,
+    ),
+    # LFM2-8B-A1B (LiquidAI/LFM2-8B-A1B config.json) cut to its first 14 of 24
+    # layers: the two leading dense layers, both gated short convolutions, then
+    # three whole periods of (full attention, conv, conv, conv) of expert
+    # layers; all 32 experts of every layer, the whole vocabulary, every width
+    # the published one: stage 0 of a two-stage pipeline. One table for
+    # embedding and head, heads of 64, the q/k norm a head before rope and the
+    # selection bias are the released code's, assumed:
+    # benchmark/configs/lfm2-8b-a1b-d14-bf16.json lists them.
+    "lfm2-8b-a1b-d14": ModelConfig(
+        name="lfm2-8b-a1b-d14",
+        vocab_size=65_536,
+        dim=2048,
+        n_layers=14,
+        n_heads=32,
+        n_kv_heads=8,
+        ffn_hidden=7168,  # the two leading dense layers'
+        rope_theta=1_000_000.0,
+        norm_eps=1e-5,
+        max_seq_len=128_000,
+        n_experts=32,
+        experts_per_tok=4,
+        moe_ffn_hidden=1792,
+        first_dense_layers=2,
+        norm_topk_prob=True,
+        routed_scaling_factor=1.0,
+        router_score="sigmoid",
+        gqa_layers=(2, 6, 10),
+        conv_taps=3,
+        qk_norm=True,
+        tie_embeddings=True,
+        params_b=4.67,
+    ),
+    # the same shape at toy size: two leading dense conv layers, then two
+    # periods of attention + three conv, 8 experts of which a row takes 4, all
+    # held; attention heads of 64 kept
+    "tiny-lfm2": ModelConfig(
+        name="tiny-lfm2",
+        vocab_size=512,
+        dim=128,
+        n_layers=10,
+        n_heads=4,
+        n_kv_heads=2,
+        head_dim=64,
+        ffn_hidden=256,
+        rope_theta=1_000_000.0,
+        norm_eps=1e-5,
+        max_seq_len=512,
+        n_experts=8,
+        experts_per_tok=4,
+        moe_ffn_hidden=64,
+        first_dense_layers=2,
+        router_score="sigmoid",
+        gqa_layers=(2, 6),
+        conv_taps=3,
+        qk_norm=True,
+        tie_embeddings=True,
         params_b=0.002,
     ),
     # the same shape at toy size: one period, 16 experts of which 4 are held

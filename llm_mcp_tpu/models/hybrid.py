@@ -2,7 +2,8 @@
 of the full-length KV cache among layers of ONE other kind
 (`cfg.recurrent_kind`) whose per-slot state has a fixed size: gated delta-rule
 linear-attention layers ("kda", models/kda.py: KDA or Gated DeltaNet, by
-`cfg.lin_gates`), Mamba-2 state-space layers ("ssm", models/ssm.py), or WINDOW
+`cfg.lin_gates`), Mamba-2 state-space layers ("ssm", models/ssm.py), gated
+short convolutions ("conv", models/shortconv.py), or WINDOW
 attention layers ("win": the same attention over the layer's last
 `cfg.sliding_window` positions, kept in a ring of `cfg.ring_len` a slot); every
 layer with a feed-forward that is either routed experts, of which this process
@@ -23,9 +24,10 @@ period's XLA program compiled once, whatever the depth. The parameter tree:
         here), w1s, w3s, w2s, or the dense w1, w3 [D, F], w2 [F, D]
     params["gqa"]: the scanned GQA layers', stacked [Lg, ...]: wq, wk, wv, wo,
         wg [D, H hd] with cfg.attn_gate, q_norm and k_norm with cfg.qk_norm
-    params["kda"] or params["ssm"]: the scanned recurrent layers', stacked
-        [Lk, ...] (models/kda.py, models/ssm.py); params["win"]: the scanned
-        window layers', the leaves of params["gqa"]
+    params["kda"], params["ssm"] or params["conv"]: the scanned recurrent
+        layers', stacked [Lk, ...] (models/kda.py, models/ssm.py,
+        models/shortconv.py); params["win"]: the scanned window layers', the
+        leaves of params["gqa"]
     params["first"]: the k leading dense layers, a list of whole layers, each
         its own leaves UNSTACKED (attn_norm, ffn_norm, the mixing half's, w1,
         w3, w2): a leaf of a stack taken at a fixed index before the scan is
@@ -39,7 +41,8 @@ What a sequence owns, beside the rows of the KV cache that its GQA layers
 write (cache layers 0..Lg-1, the dense family's layout and kernels), is the
 other layers' state. The engine threads both through every step
 program as the cache pair (cache_k, cache_v): `cache_v` is
-{"v": the KV cache's second member, "state": {"S", "conv"}}, or for window
+{"v": the KV cache's second member, "state": {"S", "conv"}} (a kind whose
+only state is its convolution's tail has no "S": `_pool`), or for window
 layers "win": their ring, a KV cache pair {"k", "v"} of its own in the KV
 cache's form over [Lw, slots, .., R, hd] (int8: {"q": [Lw, slots, 2 Hkv + p, R,
 hd], "s": [Lw, slots, 2 Hkv, R]} and {}) with position p at index p mod R
@@ -74,7 +77,7 @@ from ..kernels.attention import (
     decode_attend_bf16,
     decode_attend_q8,
 )
-from . import kda, ssm
+from . import kda, shortconv, ssm
 from .configs import ModelConfig
 from .moe import init_moe_layer_params, moe_share_ffn
 
@@ -85,7 +88,9 @@ Params = dict[str, Any]
 # `jax.named_scope` (the chunk form opens `<scope>_prefill` itself). `prefill`
 # and `decode` are the whole layer for rows of one sort; a mixed step, whose rows
 # are of both, composes it from the parts they are made of (models/kda.py says
-# which part is a product over rows and which is a row's own).
+# which part is a product over rows and which is a row's own). A kind's state is
+# a matrix state S and its convolution's tail; a kind without a matrix state
+# ("conv") hands None over wherever the others hand S, and its pool has no "S".
 _RECURRENT = {
     "kda": SimpleNamespace(
         init_params=kda.init_kda_params, init_state=kda.init_kda_state,
@@ -99,11 +104,28 @@ _RECURRENT = {
         project=ssm.project, operands=ssm.operands, step_rows=ssm.step_rows,
         scan_packed=ssm.scan_packed, output=ssm.output,
         taps=lambda cfg: cfg.ssm_conv, scope=lambda cfg: "ssd"),
+    "conv": SimpleNamespace(
+        init_params=shortconv.init_conv_params, init_state=shortconv.init_conv_state,
+        prefill=shortconv.conv_prefill, decode=shortconv.conv_decode,
+        zero_state=shortconv.zero_state, project=shortconv.project,
+        operands=shortconv.operands, step_rows=shortconv.step_rows,
+        scan_packed=shortconv.scan_packed, output=shortconv.output,
+        taps=lambda cfg: cfg.conv_taps, scope=lambda cfg: "conv"),
 }
 
 
 def _rec(cfg: ModelConfig) -> SimpleNamespace:
     return _RECURRENT[cfg.recurrent_kind]
+
+
+def _pool(S, conv) -> dict:
+    """The recurrent state's tree: a kind without a matrix state has no "S"."""
+    return {"conv": conv} if S is None else {"S": S, "conv": conv}
+
+
+def _layer_set(stack, i, new):
+    """`new` as layer `i` of `stack` [Lk, ...]; None (no matrix state) stays None."""
+    return None if stack is None else stack.at[i].set(new.reshape(stack.shape[1:]))
 
 
 # The members of `cache_v` that hold one row a slot: what a whole prompt's
@@ -349,7 +371,7 @@ def hybrid_prefill(cfg, params, tokens, lengths, attn_impl="xla", quant_kv=False
     `mtp_logits` reads; ks, vs) with ks the GQA layers' prompt K/V as
     `llama_prefill` returns them and
     vs = {"v": their second member, "state": each row's S [Lk, B, ...] (in
-    the pool's layout) and conv tails, or "win": each row's ring, the pair
+    the pool's layout; none for a kind without one) and conv tails, or "win": each row's ring, the pair
     {"k", "v"} over [Lw, B, .., R, ..] holding its prompt's last R positions at
     their wrapped indices, and with routed experts "moe": the call's expert
     counts [Le, 5]}; the engine inserts row by row (`insert_state_row`) and adds the counts once
@@ -396,16 +418,16 @@ def hybrid_prefill(cfg, params, tokens, lengths, attn_impl="xla", quant_kv=False
             y, S_new, tail = rec.prefill(
                 cfg, lp, _sub_in(cfg, h, lp["attn_norm"]), lengths, S0, tail0)
             return _residual(cfg, h, _sub_out(cfg, y, lp["attn_norm"])), (
-                Ss.at[ik].set(S_new), tails.at[ik].set(tail.reshape(B, -1))), None
+                _layer_set(Ss, ik, S_new), tails.at[ik].set(tail.reshape(B, -1))), None
 
-        carry = (jnp.zeros((Lk, *S0.shape), jnp.float32),
+        carry = (None if S0 is None else jnp.zeros((Lk, *S0.shape), jnp.float32),
                  jnp.zeros((Lk, B, tail0[0].size), tail0.dtype))
     h, carry, counts, ys = _period_scan(cfg, params, h, carry, gqa_layer, rec_layer, valid)
     ks, vs = ys["gqa"]
     last = jnp.take_along_axis(h, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1)[:, 0]
     return h if hidden else _logits(cfg, params, last), ks, {
         "v": vs,
-        **({"win": ys["win"]} if carry is None else {"state": {"S": carry[0], "conv": carry[1]}}),
+        **({"win": ys["win"]} if carry is None else {"state": _pool(*carry)}),
         **({} if counts is None else {"moe": counts})}
 
 
@@ -481,29 +503,30 @@ def hybrid_prefill_chunk_batch(
         # way in and out (two copies of 4.5 GiB at Granite-4.0-H's size, seen in the
         # described-chip compile), the layout being the chunk form's to choose where
         # no kernel holds it.
-        def rows_of(pool):  # [Lk, A, ...]
-            return jnp.concatenate([jax.lax.dynamic_slice(
+        def rows_of(pool):  # [Lk, A, ...]; no matrix state: None
+            return None if pool is None else jnp.concatenate([jax.lax.dynamic_slice(
                 pool, (0, slots[a]) + (0,) * (pool.ndim - 2), (pool.shape[0], 1, *pool.shape[2:]))
                 for a in range(A)], axis=1)
 
         def rec_layer(h, carry, lp, ik):
             ck, cv, Ss, tails = carry
-            S0 = jnp.where(fresh[:, None, None, None], 0.0, Ss[ik])
+            S0 = None if Ss is None else jnp.where(fresh[:, None, None, None], 0.0, Ss[ik])
             tail0 = jnp.where(fresh[:, None, None], 0, tails[ik].reshape(A, rec.taps(cfg) - 1, -1))
             y, S_new, tail = rec.prefill(
                 cfg, lp, _sub_in(cfg, h, lp["attn_norm"]), nvalid, S0, tail0)
             return _residual(cfg, h, _sub_out(cfg, y, lp["attn_norm"])), (
-                ck, cv, Ss.at[ik].set(S_new),
+                ck, cv, _layer_set(Ss, ik, S_new),
                 tails.at[ik].set(tail.reshape(A, -1))), None
 
         def back(Ss, tails):
-            S, conv = state["S"], state["conv"]
+            S, conv = state.get("S"), state["conv"]
             for a in range(A):  # row by row: duplicates of row 0 land on row 0's values
-                S = jax.lax.dynamic_update_slice(S, Ss[:, a : a + 1], (0, slots[a], 0, 0, 0))
+                if S is not None:
+                    S = jax.lax.dynamic_update_slice(S, Ss[:, a : a + 1], (0, slots[a], 0, 0, 0))
                 conv = jax.lax.dynamic_update_slice(conv, tails[:, a : a + 1], (0, slots[a], 0))
-            return {"state": {"S": S, "conv": conv}}
+            return {"state": _pool(S, conv)}
 
-        own = (rows_of(state["S"]), rows_of(state["conv"]))
+        own = (rows_of(state.get("S")), rows_of(state["conv"]))
     h, (ck, cv, *own), counts, _ = _period_scan(
         cfg, params, h, (cache_k, kv_v, *own), gqa_layer, rec_layer, valid)
     new_v = {"v": cv, **back(*own), **_counted(cache_v, 1, counts)}
@@ -605,8 +628,9 @@ def hybrid_mixed_step(
     returns these are) for a stack with recurrent layers. Every product that is
     rows of a matmul runs ONCE over the B decode rows and the T prompt tokens
     stacked: a recurrent layer's `project` and `output` (and, row by row,
-    `operands`), a GQA layer's `_qkv`, gate and output projection, the
-    feed-forward. Between them each sort of row takes its own part: the decode
+    `operands`), a GQA layer's `_qkv` (rotated where the attention layers
+    rotate: a decode row at its length, a prompt's token at its own position),
+    gate and output projection, the feed-forward. Between them each sort of row takes its own part: the decode
     rows the convolution step and the state kernel on the pool, or the decode
     attention through the cache, as `hybrid_decode_step` has them; the prompt
     tokens the causal convolution and the chunked recurrence from ZERO state (a
@@ -625,10 +649,12 @@ def hybrid_mixed_step(
     last chunk leaves the state alone.
 
     After the scan the decode rows' K/V is appended, the prompts' K/V lands in
-    their slots, and each prompt's state and convolution tail land in its row
-    of the pool, row by row, outside the scan (`hybrid_prefill_chunk_batch`
+    their slots, and each prompt's state and convolution tail (the tail alone
+    for a kind without a matrix state) land in its row of the pool, row by row,
+    outside the scan (`hybrid_prefill_chunk_batch`
     says why). The prompts' slots are parked rows of the decode batch: the
     append, the state kernel and the convolution step move nothing there."""
+    from ..ops.rope import apply_rope, rope_tables
     from .llama import (
         _attn_residual, _cache_shape, _embed_in, _logits, _qkv, _residual, _sub_in, _sub_out,
         fuse_prompt_kv, packed_prompt_attn, write_prompt_rows)
@@ -641,7 +667,6 @@ def hybrid_mixed_step(
     S_cache, hd = _cache_shape(cache_k)[3], cfg.resolved_head_dim
     B, T, R = tokens.shape[0], p_tokens.shape[0], p_slots.shape[0]
     H, Hkv = cfg.n_heads, cfg.n_kv_heads
-    _, P, _, nk = _layout(cfg)
     rec = _rec(cfg)
     assert T % kda.CHUNK == 0, (T, kda.CHUNK)
     p_rowids = jnp.asarray(p_rowids, jnp.int32)
@@ -653,6 +678,8 @@ def hybrid_mixed_step(
     staged = jnp.max(jnp.where(token, jnp.arange(T) // kda.CHUNK + 1, 0))
     ends = jnp.clip(p_last_idx, 0, T - 1)
     h = _embed_in(cfg, params, jnp.concatenate([tokens, p_tokens]))  # [N, D]
+    if _rotates(cfg, "gqa"):  # a decode row at its length, a prompt's token at its own position
+        cos, sin = rope_tables(cfg, hd, jnp.concatenate([lengths, p_positions]))
 
     def gqa_layer(h, carry, lp, ig):
         with jax.named_scope("attn"):
@@ -660,6 +687,9 @@ def hybrid_mixed_step(
             q, k, v = _qkv(cfg, lp, x)
             q = q.reshape(B + T, H, hd)
             k, v = k.reshape(B + T, Hkv, hd), v.reshape(B + T, Hkv, hd)
+            if _rotates(cfg, "gqa"):
+                q = apply_rope(q[:, None], cos[:, None], sin[:, None])[:, 0]
+                k = apply_rope(k[:, None], cos[:, None], sin[:, None])[:, 0]
             ctx_d = decode_attend_q8(
                 q[:B].reshape(B, Hkv, H // Hkv, hd), k[:B], v[:B], cache_k, kv_v, ig, lengths,
                 scale=cfg.attn_scale).reshape(B, H * hd)
@@ -673,7 +703,7 @@ def hybrid_mixed_step(
 
     def rec_layer(h, carry, lp, ik):
         pool, Ss, tails = carry
-        with jax.named_scope(rec.scope(cfg)):  # "kda" | "gdn" | "ssd"
+        with jax.named_scope(rec.scope(cfg)):  # "kda" | "gdn" | "ssd" | "conv"
             x = _sub_in(cfg, h, lp["attn_norm"])
             conv_in, side = rec.project(cfg, lp, x)  # [N, W]
             mixed_d, conv, rows = kda.conv_step(
@@ -681,23 +711,26 @@ def hybrid_mixed_step(
             mixed_p, tail = kda.conv_packed(conv_in[B:], p_positions, ends, lp["conv_w"])
             ops, side = rec.operands(cfg, lp, jnp.concatenate([mixed_d, mixed_p]), side)
             o_d, S = rec.step_rows(
-                cfg, pool["S"], ik, rows, live, jax.tree.map(lambda a: a[:B], ops))
+                cfg, pool.get("S"), ik, rows, live, jax.tree.map(lambda a: a[:B], ops))
             o_p, after = rec.scan_packed(
                 jax.tree.map(lambda a: a[B:][None], ops), token[None], fresh, staged)
             y = rec.output(cfg, lp, jnp.concatenate([o_d, o_p[0]]), side, x.dtype)
-            own = jnp.take(after[:, 0], ends // kda.CHUNK, axis=0)  # [R, H / P, dk, P dv]
+            # each prompt's own state [R, H / P, dk, P dv]; none without a matrix state
+            own = None if after is None else jnp.take(after[:, 0], ends // kda.CHUNK, axis=0)
             return _residual(cfg, h, _sub_out(cfg, y, lp["attn_norm"])), (
-                {"S": S, "conv": conv},
-                Ss.at[ik].set(own.reshape(Ss.shape[1:])),
+                _pool(S, conv), _layer_set(Ss, ik, own),
                 tails.at[ik].set(tail.reshape(R, -1).astype(tails.dtype))), None
 
     # the prompts' states ride the scan as [Lk, R, (H / P) dk, P dv]: with the
     # pool's own last two axes, both 128 at Granite-4.0-H's size, the compiler
     # carried them transposed and then re-laid the WHOLE pool out to take them
     # (4.5 GiB of temporaries, seen in the described-chip compile)
-    G, dk, W = state["S"].shape[2:]
-    carry = (state, jnp.zeros((P * nk, R, G * dk, W), jnp.float32),
-             jnp.zeros((P * nk, R, state["conv"].shape[2]), state["conv"].dtype))
+    # (the pool's layers: a leading dense layer of the recurrent kind has a row of its own)
+    S_pool, Lk = state.get("S"), state["conv"].shape[0]
+    carry = (state,
+             None if S_pool is None else jnp.zeros(
+                 (Lk, R, S_pool.shape[2] * S_pool.shape[3], S_pool.shape[4]), jnp.float32),
+             jnp.zeros((Lk, R, state["conv"].shape[2]), state["conv"].dtype))
     h, (pool, Ss, tails), counts, ys = _period_scan(
         cfg, params, h, carry, gqa_layer, rec_layer, jnp.concatenate([live, token]), prompt)
     knew, vnew, pq, ps = ys["gqa"]
@@ -710,12 +743,14 @@ def hybrid_mixed_step(
             "q": write_prompt_rows(new_k["q"], pq, p_slots, starts, count),
             "s": write_prompt_rows(new_k["s"], ps, p_slots, starts, count),
         }
-        S, conv = pool["S"], pool["conv"]
-        Ss = Ss.reshape(P * nk, R, G, dk, W)
+        S, conv = pool.get("S"), pool["conv"]
+        if S is not None:
+            Ss = Ss.reshape(Lk, R, *S.shape[2:])
         for r in range(R):  # an unused row writes back what it read
-            S = _put_row(S, Ss[:, r : r + 1], p_slots[r], count[r] > 0)
+            if S is not None:
+                S = _put_row(S, Ss[:, r : r + 1], p_slots[r], count[r] > 0)
             conv = _put_row(conv, tails[:, r : r + 1], p_slots[r], count[r] > 0)
-    new_v = {"v": new_kv_v, "state": {"S": S, "conv": conv}}
+    new_v = {"v": new_kv_v, "state": _pool(S, conv)}
     if counts is not None:
         new_v["moe"] = cache_v["moe"] + jnp.moveaxis(counts, 1, 0)
     last = jnp.take(h[B:], ends, axis=0)  # [R, D]

@@ -663,13 +663,14 @@ def mixed_step_supported(cfg: ModelConfig) -> bool:
     """Whether a mixed step serves this family. `mixed_step_q8`: the ones whose
     decode step is `_decode_step_q8` with a dense feed-forward (global
     attention, no score softcap, rope, no latent cache, no routed experts).
-    `hybrid.hybrid_mixed_step`: a stack with recurrent layers, which has no
-    rope, whatever its feed-forward; a stack of window and global layers rotates
-    (and keeps rings, which the mixed step does not write) and takes `admit_fn`."""
+    `hybrid.hybrid_mixed_step`: a stack with recurrent layers, with rope on its
+    attention layers or without, whatever its feed-forward; a stack of window and
+    global layers keeps rings, which the mixed step does not write, and takes
+    `admit_fn`."""
     if cfg.kv_lora_rank or cfg.sliding_window or cfg.attn_softcap:
         return False
     if cfg.recurrent:
-        return not cfg.use_rope
+        return True
     return not (cfg.n_experts or cfg.attn_gate or not cfg.use_rope)
 
 

@@ -14,7 +14,8 @@ meant to leave a configuration alone shows here in seconds.
 
 `--tree` is a checkout of another commit (`git archive <commit> | tar -x -C
 _clean`); `--out` keeps the texts, to diff where a digest differs. PR 43 read
-all twelve equal between 40d3bf3 and its own tree. `--lin-value-dim 128` gives
+all twelve equal between 40d3bf3 and its own tree; PR 52 added `tiny-kexaone`
+(no mixed step: rings) and `tiny-lfm2`, and a preset the tree lacks is skipped. `--lin-value-dim 128` gives
 the delta-rule presets Solar-Open2's own value heads, ONE head a tile of the
 pool (`kernels/kda.py:heads_abreast`; `tiny-solar`'s 32 lie four abreast, which
 Solar's never do): a change to where states are packed into the pool's layout
@@ -33,7 +34,7 @@ import os
 import sys
 from functools import partial
 
-PRESETS = ("tiny-solar", "tiny-olmo-hybrid", "tiny-granite-hybrid")
+PRESETS = ("tiny-solar", "tiny-olmo-hybrid", "tiny-granite-hybrid", "tiny-kexaone", "tiny-lfm2")
 
 
 def main() -> int:
@@ -49,13 +50,15 @@ def main() -> int:
     from jax._src.lib.mlir import passmanager
 
     from llm_mcp_tpu.models import hybrid, llama
-    from llm_mcp_tpu.models.configs import get_config
+    from llm_mcp_tpu.models.configs import MODEL_CONFIGS, get_config
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32)
 
     B, T, R = 4, 128, 4
     for name in PRESETS:
+        if name not in MODEL_CONFIGS:  # a tree from before the preset
+            continue
         cfg = get_config(name)
         if args.lin_value_dim and cfg.lin_heads:
             cfg = dataclasses.replace(cfg, lin_value_dim=args.lin_value_dim)
@@ -73,6 +76,8 @@ def main() -> int:
             "prefill": (lambda p, ck, cv, *a: llama.llama_prefill(
                 cfg, p, *a, attn_impl="pallas", quant_kv=True), (i32(2, 64), i32(2))),
         }
+        if not llama.mixed_step_supported(cfg):  # a stack with rings takes admit programs alone
+            del programs["mixed"]
         for tag, (fn, operands) in programs.items():
             module = jax.jit(fn).lower(params, cache["k"], cache["v"], *operands).compiler_ir(
                 "stablehlo")

@@ -25,6 +25,7 @@ TABLE = [
     ("tiny-olmo-hybrid", "int8", (False, True, True, "state", "gqa_int8")),
     ("tiny-granite-hybrid", "int8", (False, True, True, "state", "gqa_int8")),
     ("tiny-kexaone", "int8", (False, True, True, "win", "gqa_int8")),
+    ("tiny-lfm2", "int8", (False, True, True, "state", "gqa_int8")),  # a state of tails alone
 ]
 
 

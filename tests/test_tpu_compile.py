@@ -777,6 +777,74 @@ def test_kexaone_step_programs_fit_and_keep_both_kinds_of_cache_in_place(
     assert mem.alias_size_in_bytes > 0.99 * caches  # the cache and the rings updated in place
 
 
+@pytest.fixture(scope="module")
+def lfm2(one_chip):
+    """`lfm2-8b-a1b-d14`, 64 slots x 1024, as its cell boots it."""
+    return hybrid_shapes("lfm2-8b-a1b-d14", one_chip, SOLAR_SLOTS, SOLAR_S)
+
+
+@pytest.mark.parametrize("which,operands", [
+    ("decode", [(64,), (64,), (64,)]),  # every slot a row
+    ("admit", [(4, 128), (4,), (4,)]),  # the cell's largest admit program, 512 padded tokens
+    ("admit", [(1, 64), (1,), (1,)]),  # and its smallest
+    ("chunk", [(2, 512), (2,), (2,), (2,)]),  # two prompts' second chunks of 512
+    ("mixed", 128), ("mixed", 256),  # the round that carries prompts, at both rungs
+])
+def test_lfm2_step_programs_fit_with_the_banks_whole_and_the_tails_in_place(
+    sd, lfm2, chip_kernels, which, operands
+):
+    """The decode round of 64 rows, the admit programs the traffic meets, a
+    chunk program and the mixed round at both rungs of `lfm2-8b-a1b-d14` (the
+    published widths, 14 layers, all 32 experts of 2048 x 1792 a layer) at its
+    cell's 64 slots x 1024 compile for the described v5e: the two grouped expert
+    kernels at banks of [2048, 1792] (one column block of two banks, 14.7 MB) and
+    [1792, 2048], the decode attention's whole-S arm and the append kernel at
+    heads of 64 WITH rotation, the flash prefill kernel in the admit programs,
+    every one a Mosaic call with no fall to its reference. Each fits the chip;
+    the temporaries hold no copy of a layer's banks (0.66 GiB a layer; the
+    stack goes in whole) nor of a leading layer's feed-forward (84 MB, unstacked:
+    a slice of a stack at a fixed index was copied out every step), and the KV
+    cache and the tails (5.5 MiB: a pool with no matrix state) are updated in
+    place. GiB in PERF.md section 4 as "described-chip compile"."""
+    cfg, params, cache = lfm2
+    falls = dict(A.reference_falls)
+    vec = lambda n: sd((n,), I32)  # noqa: E731
+    args = ((vec(64), vec(64), vec(operands), vec(operands), vec(operands), vec(4), vec(4))
+            if which == "mixed" else tuple(sd(shape, I32) for shape in operands))
+    compiled = jax.jit(solar_program(which, cfg), donate_argnums=(1, 2)).lower(
+        params, cache["k"], cache["v"], *args).compile()
+    assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
+    text = compiled.as_text()
+    assert grouped_kernels_in(text)
+    # no state kernel: no matrix state
+    assert not any(f"%{k}_{form}" in text for k in ("kda", "gdn", "ssd")
+                   for form in ("decode_step", "chunk_scan"))
+    if which in ("decode", "mixed"):
+        assert "decode_attn_q8_whole" in text and "append_kv_q8" in text
+        assert "decode_attn_q8_blocked" not in text
+    if which == "admit":
+        assert "flash_prefill_attn" in text
+    state = cache["v"]["state"]
+    assert set(state) == {"conv"} and state["conv"].shape == (11, 64, 2 * 2048)
+    assert cache["k"]["q"].shape == (3, 64, 17, 1024, 64)
+    assert params["layers"]["w1e"].shape == (12, 32, 2048, 1792) and len(params["first"]) == 2
+    nbytes = lambda tree: sum(  # noqa: E731
+        int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(tree))
+    weights, pool, kv = nbytes(params), nbytes(state), nbytes(cache["k"])
+    # bfloat16 but for the twelve selection biases [32], float32
+    assert weights == 2 * cfg.param_count() + 2 * 12 * 32 == 9_334_155_520 and pool == 64 * 90_112
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"lfm2 {which} {operands}: {total / 2**30:.2f} GiB, of it temporaries "
+          f"{mem.temp_size_in_bytes / 2**30:.3f} GiB, arguments "
+          f"{mem.argument_size_in_bytes / 2**30:.2f} GiB (weights {weights / 2**30:.2f}, "
+          f"KV cache {kv / 2**30:.2f}, tails {pool / 2**20:.1f} MiB)")
+    assert total < 12.0 * 2**30
+    assert mem.temp_size_in_bytes < 0.6 * 2**30  # under one layer's banks
+    assert mem.alias_size_in_bytes > 0.99 * (pool + kv)  # KV cache and tails updated in place
+
+
 @pytest.mark.parametrize("rung", [128, 256])
 @pytest.mark.parametrize("name,kernel,limit,temps", [
     ("solar", "%kda_decode_step", 15.75, 0.25), ("olmo", "%gdn_decode_step", 15.0, 0.35),
