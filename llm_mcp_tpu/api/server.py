@@ -888,9 +888,10 @@ class CoreServer:
                 "kind": "embed",
                 "total_inputs": e.total_inputs,
                 "total_tokens": e.total_tokens,
-                # forwards, texts, rows packed and dispatched, tokens true and
-                # padded, seconds waiting for, inside and holding the lock
-                # around a forward
+                # forwards (of them `ahead`: dispatched behind one not yet
+                # ready), texts, rows packed and dispatched, tokens true and
+                # padded, seconds waiting for the lock, holding it (staging
+                # and dispatch) and blocked in a fetch, `inflight_max`
                 **st,
                 # texts a row (1.0 = packing never engaged) and the share of
                 # the dispatched token positions that were padding

@@ -5,6 +5,13 @@ Replaces the reference's Ollama `/api/embed` proxy path
 rows and run as jitted forwards of one shape, entirely on TPU. Matryoshka
 `dimensions` support is exact (truncate + renormalize) rather than the
 reference's client-side truncation fallback (`handlers.go:2063-2078`).
+
+A call dispatches AHEAD of its fetch (PR 53): under the engine's lock it
+plans, stages and dispatches all its forwards and reads none of them; it
+fetches and post-processes them with the lock free. JAX's dispatch returns
+once a forward is enqueued, so the next caller's forwards queue on the device
+behind this call's, in lock order (first come, first served), and the device
+holds its next forward when one ends.
 """
 
 from __future__ import annotations
@@ -48,6 +55,9 @@ PACK_ROW = 512
 # embed_batch): `jit_fwd` takes 30.5 / 25.4 / 27.9 / 28.6 / 30.8 / 32.6 ms a
 # row at 1 / 2 / 4 / 8 / 16 / 32 rows of 512, and the cell gives 65.4 / 75.5 /
 # 66.3 / 63.8 / 60.1 embeddings/s at 512 / 1,024 / 2,048 / 4,096 / 8,192.
+# Those embeddings/s were read WITH the hand-over between requests in them
+# (each forward fetched under the lock, until PR 53); the ms a row are the
+# device's own.
 FORWARD_TOKENS = 2 * 512
 
 
@@ -209,25 +219,41 @@ class EmbeddingEngine:
                 return embed_forward(cfg, params, tokens, lengths[:, 0])[:, None]
 
         self._fwd = fwd
+        # held over a call's planning, staging and dispatch ALONE: the order
+        # callers take it in is the order their forwards run on the device
         self._lock = threading.Lock()
         self.total_inputs = 0
         self.total_tokens = 0
-        # counters behind stats(), written with the lock held: forwards
-        # run, texts asked for (`rows`), the rows they were packed into and
-        # the rows dispatched after batch padding, tokens asked for and
-        # tokens after padding to (batch bucket x row length), and
-        # seconds waiting for the lock, inside a forward (call to fetched)
-        # and holding the lock outside one (staging, slicing, normalising,
-        # tolist: host work during which the chip waits)
+        # counters behind stats(). Written at dispatch, with `_lock` held:
+        # forwards dispatched, of them those dispatched while the forward
+        # before was not ready yet (`ahead`: the device had its next forward
+        # before it ended the last), texts asked for (`rows`), the rows they
+        # were packed into and the rows dispatched after batch padding,
+        # tokens asked for and tokens after padding to (batch bucket x row
+        # length), and seconds waiting for the lock. Written where a
+        # forward's fetch ends, with `_recent_lock` held (never `_lock`
+        # inside it): `host_locked_s`, the host seconds of that forward with
+        # `_lock` held (staging and the dispatching call: what a waiting
+        # caller still waits for), and `forward_s`, the seconds its caller
+        # stood blocked in its fetch (for one caller the two cannot pass the
+        # wall clock; over callers `forward_s` counts a device second once
+        # for each caller that waited through it). `inflight_max` is the
+        # most forwards dispatched and not yet fetched at one time, read
+        # where a call has dispatched its last
         self._stats: dict[str, float] = {
-            "forwards": 0, "rows": 0, "rows_packed": 0, "rows_padded": 0,
+            "forwards": 0, "ahead": 0, "rows": 0, "rows_packed": 0, "rows_padded": 0,
             "true_tokens": 0, "padded_tokens": 0, "lock_wait_s": 0.0,
-            "forward_s": 0.0, "host_locked_s": 0.0,
+            "forward_s": 0.0, "host_locked_s": 0.0, "inflight_max": 0,
         }
-        # (time.monotonic() at the forward's start, forward_s, host_locked_s)
-        # of each forward, so that a reader can cut by its own window
+        self._last_out: Any = None  # the newest forward dispatched, for `ahead`
+        self._inflight = 0  # dispatched and not yet fetched; under `_recent_lock`
+        # (time.monotonic() at the forward's dispatch, forward_s,
+        # host_locked_s) of each forward, so that a reader can cut by its own
+        # window
         self._recent: deque = deque(maxlen=4096)
-        self._recent_lock = threading.Lock()  # stats() copies while embed() appends
+        # stats() copies while embed() appends; taken inside `_lock`, never
+        # around it
+        self._recent_lock = threading.Lock()
 
     def _bucket(self, n: int) -> int:
         return pow2_bucket(n, self.max_seq_len)
@@ -271,13 +297,18 @@ class EmbeddingEngine:
     ) -> tuple[list[list[float]], int]:
         """Encode texts → (vectors in the caller's order, total_tokens).
         The texts are packed into rows (`plan`) and run as forwards of one
-        shape."""
+        shape: all dispatched under the lock, then fetched and post-processed
+        in order with the lock free."""
         if not texts:
             return [], 0
         all_ids = [self.prepare_ids(t) for t in texts]
         total_tokens = sum(len(i) for i in all_ids)
         vectors: list[Any] = [None] * len(texts)
         K = self.texts_per_row
+        # this call's forwards dispatched and not yet fetched: (output,
+        # time of dispatch, host seconds under the lock, its texts, each
+        # one's row and its place in the row)
+        pending: deque = deque()
 
         t_ask = time.monotonic()
         with TraceAnnotation("embed.lock_wait"):
@@ -311,11 +342,44 @@ class EmbeddingEngine:
                             rows_at.append(r)
                             places_at.append(k)
                 t_fwd = time.monotonic()
+                ahead = self._last_out is not None and not self._last_out.is_ready()
                 with TraceAnnotation("embed.forward"):
-                    out = np.asarray(
-                        self._fwd(self.params, tokens, lengths), dtype=np.float32
-                    )
+                    # enqueued, not awaited: the call returns once the device
+                    # has the forward in its queue
+                    out = self._fwd(self.params, tokens, lengths)
+                    out.copy_to_host_async()
                 t_done = time.monotonic()
+                self._last_out = out
+                pending.append((out, t_fwd, t_done - t_prev, texts_at, rows_at, places_at))
+                t_prev = t_done
+                st["forwards"] += 1
+                st["ahead"] += ahead
+                st["rows"] += len(texts_at)
+                st["rows_packed"] += len(rows)
+                st["rows_padded"] += Bb
+                st["true_tokens"] += sum(lens[i] for i in texts_at)
+                st["padded_tokens"] += Bb * row_len
+            self.total_inputs += len(texts)
+            self.total_tokens += total_tokens
+            with self._recent_lock:  # counted where the call has dispatched its last
+                self._inflight += len(pending)
+                st["inflight_max"] = max(st["inflight_max"], self._inflight)
+        finally:
+            self._lock.release()
+
+        try:
+            while pending:
+                out, t_fwd, host_s, texts_at, rows_at, places_at = pending[0]
+                t_wait = time.monotonic()
+                with TraceAnnotation("embed.fetch"):
+                    out = np.asarray(out, dtype=np.float32)
+                fwd_s = time.monotonic() - t_wait
+                pending.popleft()
+                with self._recent_lock:
+                    self._inflight -= 1
+                    st["forward_s"] += fwd_s
+                    st["host_locked_s"] += host_s
+                    self._recent.append((t_fwd, fwd_s, host_s))
                 with TraceAnnotation("embed.post"):
                     out = out[rows_at, places_at]  # [texts of this forward, D]
                     if dimensions and 0 < dimensions < out.shape[1]:
@@ -324,30 +388,24 @@ class EmbeddingEngine:
                         out = out / norms
                     for i, vec in zip(texts_at, out.tolist()):
                         vectors[i] = vec
-                t_post = time.monotonic()
-                host_s = (t_fwd - t_prev) + (t_post - t_done)
-                t_prev = t_post
-                st["forwards"] += 1
-                st["rows"] += len(texts_at)
-                st["rows_packed"] += len(rows)
-                st["rows_padded"] += Bb
-                st["true_tokens"] += sum(lens[i] for i in texts_at)
-                st["padded_tokens"] += Bb * row_len
-                st["forward_s"] += t_done - t_fwd
-                st["host_locked_s"] += host_s
-                with self._recent_lock:
-                    self._recent.append((t_fwd, t_done - t_fwd, host_s))
-            self.total_inputs += len(texts)
-            self.total_tokens += total_tokens
         finally:
-            self._lock.release()
+            # a fetch that raised leaves forwards behind that nobody will
+            # read: they leave the count with their call
+            if pending:
+                with self._recent_lock:
+                    self._inflight -= len(pending)
         return vectors, total_tokens
 
     def stats(self, recent: bool = True) -> dict[str, Any]:
-        """Counters of the forwards run so far (see __init__), which
-        engines_info shows at /v1/debug/health and /v1/dashboard, and with
-        `recent` one (time.monotonic(), forward_s, host_locked_s) a forward."""
-        if not recent:
-            return dict(self._stats)
+        """Counters of the forwards dispatched so far (see __init__), which
+        engines_info shows at /v1/debug/health and /v1/dashboard: beside
+        `forwards`, `ahead` of them were dispatched while the forward before
+        was not ready yet, and `inflight_max` were at most dispatched and not
+        yet fetched. With `recent`, one (time.monotonic() at its dispatch,
+        forward_s, host_locked_s) a FETCHED forward: `host_locked_s` its host
+        seconds with the lock held (staging and the dispatching call),
+        `forward_s` the seconds its caller stood blocked in its fetch."""
         with self._recent_lock:
+            if not recent:
+                return dict(self._stats)
             return {**self._stats, "recent": list(self._recent)}

@@ -236,8 +236,11 @@ def test_embedding_counters_reach_an_operator(base, server):
         assert blk["rows"] == before["rows"] + 3
         assert blk["rows_padded"] == before["rows_padded"] + 4
         assert blk["padded_tokens"] > blk["true_tokens"] > before["true_tokens"]
+        # PR 53: seconds blocked in the fetch, seconds under the lock (staging and
+        # dispatch), and how often a forward was dispatched behind an unready one
         assert blk["forward_s"] > before["forward_s"]
         assert blk["host_locked_s"] > before["host_locked_s"] and blk["lock_wait_s"] >= 0
+        assert before["ahead"] <= blk["ahead"] <= blk["forwards"] - 1 and blk["inflight_max"] >= 1
         # an encoder keeps one text a row: packing never engages
         assert blk["rows_packed"] == blk["rows"] and blk["texts_per_row"] == 1.0
         assert blk["pad_waste_pct"] == pytest.approx(

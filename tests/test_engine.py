@@ -9,7 +9,9 @@ import concurrent.futures as cf
 import contextlib
 import functools
 import os
+import sys
 import tempfile
+import threading
 import time
 
 import jax.numpy as jnp
@@ -335,7 +337,7 @@ def _shape_spy(eng):
 
     def fwd(params, tokens, lengths):
         calls.append((tokens.shape, np.array(lengths)))
-        return np.zeros(lengths.shape + (eng.cfg.dim,), np.float32)
+        return jnp.zeros(lengths.shape + (eng.cfg.dim,), jnp.float32)  # a jax.Array, as `_fwd` gives
 
     eng._fwd = fwd
     return calls
@@ -469,6 +471,190 @@ def test_embedding_encoder_arch_keeps_one_text_a_row():
     assert all(c[1].shape == (4, 1) for c in calls)
     st = eng.stats(recent=False)
     assert st["rows"] == st["rows_packed"] == 5 and st["rows_padded"] == 8
+
+
+class _GatedOut:
+    """What a forward returns, ready when the test opens its gate: the three
+    calls `embed` makes on a `jax.Array` (is it ready, start the copy, read)."""
+
+    def __init__(self, value, by, ready, fail):
+        self.value, self.by, self.fail = value, by, fail
+        self.gate, self.fetching = threading.Event(), threading.Event()
+        if ready:
+            self.gate.set()
+
+    def is_ready(self):
+        return self.gate.is_set()
+
+    def copy_to_host_async(self):
+        pass
+
+    def __array__(self, dtype=None, copy=None):
+        self.fetching.set()
+        assert self.gate.wait(60.0), "the test never opened this forward's gate"
+        if self.fail:
+            raise RuntimeError("forward lost")
+        return self.value.astype(dtype)
+
+
+class _GatedForwards:
+    """Stands in for `eng._fwd`: computes at once (the real program, read back
+    here) and hands out a `_GatedOut`, kept in `outs` in dispatch order.
+    `born_ready`: the gates of new forwards start open; `fail_next`: the next
+    forward's fetch raises."""
+
+    def __init__(self, eng):
+        self.real, self.outs, self.born_ready, self.fail_next = eng._fwd, [], False, False
+        eng._fwd = self
+
+    def __call__(self, params, tokens, lengths):
+        value = np.asarray(self.real(params, tokens, lengths))
+        out = _GatedOut(value, threading.current_thread().name, self.born_ready, self.fail_next)
+        self.fail_next = False
+        self.outs.append(out)
+        return out
+
+    def wait(self, n, fetching=None, timeout=60.0):
+        """Until `n` forwards are dispatched (and `outs[fetching]` is being read)."""
+        t_end = time.monotonic() + timeout
+        while len(self.outs) < n or (fetching is not None and not self.outs[fetching].fetching.is_set()):
+            assert time.monotonic() < t_end, f"{len(self.outs)} forwards dispatched, waiting for {n}"
+            time.sleep(0.005)
+
+    def open(self, order=iter):
+        for out in order(self.outs):
+            out.gate.set()
+
+
+def _gated_engine():
+    """tiny-embed with two rows a forward (three texts are two forwards), two
+    callers' texts and the vectors each gets alone; then the gates go in."""
+    eng = EmbeddingEngine("tiny-embed", max_batch=2, max_seq_len=64, dtype=jnp.float32)
+    texts = {"A": ["alpha", "a much longer second text", "a3"], "B": ["bravo one", "b2", "the third of B"]}
+    alone = {k: eng.embed(v)[0] for k, v in texts.items()}
+    return eng, texts, alone, _GatedForwards(eng)
+
+
+def test_embedding_lock_is_free_during_a_fetch():
+    """PR 53: a call dispatches all its forwards under the lock and fetches
+    them with the lock free, so a second caller's first forward is dispatched
+    BEFORE the first caller's first fetch returns; each gets its own vectors
+    in its own order."""
+    eng, texts, alone, fwd = _gated_engine()
+    with cf.ThreadPoolExecutor(2) as pool:
+        fa = pool.submit(eng.embed, texts["A"])
+        fwd.wait(2, fetching=0)
+        assert not eng._lock.locked()
+        fb = pool.submit(eng.embed, texts["B"])
+        fwd.wait(4)  # B's forwards dispatched while A stands in its first fetch
+        assert not fa.done() and not fb.done() and not fwd.outs[1].fetching.is_set()
+        by = [out.by for out in fwd.outs]
+        assert by[0] == by[1] != by[2] == by[3]  # lock order, a call whole
+        fwd.open(reversed)  # ready in any order: a call reads its own in its own order
+        got = {"A": fa.result(60.0)[0], "B": fb.result(60.0)[0]}
+    assert got == alone
+    assert eng._inflight == 0 and not eng._lock.locked()
+
+
+def test_embedding_ahead_and_inflight_against_a_hand_count():
+    """`ahead` counts a forward dispatched while the one dispatched before it
+    was not ready; `inflight_max` is the most dispatched and not fetched."""
+    eng, texts, alone, fwd = _gated_engine()
+    base = eng.stats(recent=False)
+    assert (base["forwards"], base["inflight_max"]) == (4, 2)  # `alone`: two calls of two
+
+    def since():
+        st = eng.stats(recent=False)
+        return st["forwards"] - base["forwards"], st["ahead"] - base["ahead"], st["inflight_max"]
+
+    # each forward ready before the next is dispatched: none is ahead
+    fwd.born_ready = True
+    assert eng.embed(texts["A"])[0] == alone["A"]
+    assert since() == (2, 0, 2)
+    # two callers behind closed gates: B's first follows a READY forward, the other three are ahead
+    fwd.born_ready = False
+    with cf.ThreadPoolExecutor(2) as pool:
+        fb = pool.submit(eng.embed, texts["B"])
+        fwd.wait(4, fetching=2)
+        fa = pool.submit(eng.embed, texts["A"])
+        fwd.wait(6)
+        assert since() == (6, 3, 4) and eng._inflight == 4
+        assert len(eng.stats()["recent"]) == 4 + 2  # a forward is `recent` once fetched
+        fwd.open()
+        assert fb.result(60.0)[0] == alone["B"] and fa.result(60.0)[0] == alone["A"]
+    st = eng.stats()
+    assert eng._inflight == 0 and st["inflight_max"] == 4 and len(st["recent"]) == st["forwards"] == 10
+    assert st["forward_s"] == pytest.approx(sum(r[1] for r in st["recent"]))
+    assert st["host_locked_s"] == pytest.approx(sum(r[2] for r in st["recent"]))
+
+
+def test_embedding_a_lost_fetch_reaches_its_caller_alone():
+    """An exception raised by a forward's fetch reaches its caller, leaves the
+    lock free, nothing counted in flight, another caller's forwards untouched
+    and a later call sound."""
+    eng, texts, alone, fwd = _gated_engine()
+    fwd.fail_next = True
+    with cf.ThreadPoolExecutor(2) as pool:
+        fa = pool.submit(eng.embed, texts["A"])
+        fwd.wait(2, fetching=0)
+        fb = pool.submit(eng.embed, texts["B"])
+        fwd.wait(4)
+        fwd.open()
+        with pytest.raises(RuntimeError, match="forward lost"):
+            fa.result(60.0)
+        assert fb.result(60.0)[0] == alone["B"]
+    assert not eng._lock.locked() and eng._inflight == 0
+    st = eng.stats()
+    assert st["forwards"] == 4 + 4 and len(st["recent"]) == 4 + 2  # A's two were dispatched, never read
+    fwd.born_ready = True
+    assert eng.embed(texts["A"])[0] == alone["A"]
+    assert not eng._lock.locked() and eng._inflight == 0
+
+
+def test_embedding_a_lost_dispatch_leaves_the_lock_free():
+    """A dispatch that raises under the lock releases it; the forward the call
+    had already dispatched is read by nobody and was never counted in flight."""
+    eng, texts, alone, fwd = _gated_engine()
+    fwd.born_ready = True
+
+    def second_lost(params, tokens, lengths):
+        if len(fwd.outs) % 2:
+            raise RuntimeError("dispatch lost")
+        return fwd(params, tokens, lengths)
+
+    eng._fwd = second_lost
+    with pytest.raises(RuntimeError, match="dispatch lost"):
+        eng.embed(texts["A"])
+    assert len(fwd.outs) == 1 and not eng._lock.locked() and eng._inflight == 0
+    eng._fwd = fwd
+    assert eng.embed(texts["B"])[0] == alone["B"] and eng._inflight == 0
+
+
+@pytest.mark.parametrize("model,dimensions", [("tiny-embed", None), ("tiny-embed", 8),
+                                              ("tiny-qwen3", None), ("tiny-qwen3", 16)])
+def test_embedding_callers_side_by_side_get_what_they_get_alone(model, dimensions):
+    """Four threads, each with texts of its own, several forwards a call: every
+    caller gets exactly the vectors its texts give alone (same program, same
+    operands, same order of a call's forwards), both encoders, `dimensions` cut."""
+    eng = EmbeddingEngine(model, max_batch=2, max_seq_len=128, dtype=jnp.float32)
+    texts = [[f"{who}{n} " * (1 + (3 * n + k) % 9) for n in range(5)] for k, who in enumerate("wxyz")]
+    alone = [eng.embed(t, dimensions=dimensions)[0] for t in texts]
+    assert all(len(v[0]) == (dimensions or eng.cfg.dim) for v in alone)
+    a_round = eng.stats(recent=False)["forwards"]  # of all four calls
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)  # more hand-overs between the threads than the default gives
+    try:
+        with cf.ThreadPoolExecutor(4) as pool:
+            futs = [pool.submit(lambda t=t: [eng.embed(t, dimensions=dimensions)[0] for _ in range(6)])
+                    for t in texts]
+            got = [f.result(120.0) for f in futs]
+    finally:
+        sys.setswitchinterval(was)
+    for mine, rounds in zip(alone, got):
+        assert all(r == mine for r in rounds)
+    st = eng.stats()
+    assert st["forwards"] == 7 * a_round == len(st["recent"])
+    assert eng._inflight == 0 and not eng._lock.locked() and 1 <= st["inflight_max"] <= a_round
 
 
 def test_chunked_prefill_matches_single_shot():

@@ -319,9 +319,9 @@ def test_compile_watch_sums_what_jax_reports_on_the_thread(monkeypatch, requests
 
 def test_embedding_engine_stats_against_a_hand_count():
     emb = EmbeddingEngine("tiny-embed", max_batch=4, max_seq_len=64, dtype=jnp.float32)
-    assert emb.stats() == {"forwards": 0, "rows": 0, "rows_packed": 0, "rows_padded": 0,
+    assert emb.stats() == {"forwards": 0, "ahead": 0, "rows": 0, "rows_packed": 0, "rows_padded": 0,
                            "true_tokens": 0, "padded_tokens": 0, "lock_wait_s": 0.0,
-                           "forward_s": 0.0, "host_locked_s": 0.0, "recent": []}
+                           "forward_s": 0.0, "host_locked_s": 0.0, "inflight_max": 0, "recent": []}
     # 6 rows over a cap of 4: two EQUAL forwards of 3 rows, both in the 4-row bucket (PR 31)
     texts = ["a" * 10, "b" * 40, "c" * 5, "d" * 20, "e" * 33, "f" * 3]
     lens = [len(emb.prepare_ids(t)) for t in texts]
@@ -340,6 +340,35 @@ def test_embedding_engine_stats_against_a_hand_count():
     assert st["lock_wait_s"] >= 0 and st["forward_s"] + st["host_locked_s"] <= (t1 - t0)
     emb.embed(["one more"])
     assert emb.stats()["forwards"] == 3 and emb.stats()["rows_padded"] == 9
+
+
+def test_embedding_engine_under_the_benchmarks_tap_still_feeds_every_reader():
+    """A traced run wraps `_fwd` in `benchmark/run.py:EmbedTap`, which blocks
+    until the forward is ready and reads `tokens.size` and `lengths.sum()`: every
+    dispatch then waits as the serial loop did (PR 53: the traced run does not
+    show the queue). The tap still sees every forward, `recent` is still
+    triples, and the cell's three embedding readers read a number."""
+    from benchmark import run as bench_run
+    from benchmark.layer_metrics import embed_forward_ms, embed_host_locked_ms, embed_pad_waste_pct
+
+    emb = EmbeddingEngine("tiny-qwen3", max_seq_len=512, dtype=jnp.float32)
+    tap = bench_run.EmbedTap(emb)
+    texts = [[f"{who} text {n} " * (1 + 5 * n) for n in range(12)] for who in ("one", "two")]
+    t0 = time.monotonic()
+    with cf.ThreadPoolExecutor(2) as pool:
+        got = [f.result(120.0) for f in [pool.submit(emb.embed, t, 16) for t in texts]]
+    t1 = time.monotonic()
+    assert [len(vecs) for vecs, _ in got] == [12, 12]
+    st = emb.stats()
+    assert len(tap.calls) == st["forwards"] == len(st["recent"]) >= 2
+    assert sum(p for _a, _b, p, _t in tap.calls) == st["padded_tokens"]
+    assert sum(t for _a, _b, _p, t in tap.calls) == st["true_tokens"] == sum(n for _, n in got)
+    assert st["ahead"] == 0  # the tap hands a forward back when it is ready: none is ahead
+    for t, fwd_s, host_s in st["recent"]:
+        assert t0 <= t <= t1 and fwd_s >= 0 and host_s > 0
+    run = {"sut": {"emb": emb}, "embed_tap": tap, "window_abs": (t0, t1)}
+    assert embed_host_locked_ms.read(run) == pytest.approx(1e3 * st["host_locked_s"] / st["forwards"])
+    assert embed_forward_ms.read(run) > 0 and 0 < embed_pad_waste_pct.read(run) < 100
 
 
 def test_admission_reads_are_counted_and_recorded_against_a_hand_count(env):
