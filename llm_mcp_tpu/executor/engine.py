@@ -343,10 +343,15 @@ class _DispatchedRound:
     rid: int = 0  # monotonic round id (slot-reuse cooling fence)
     prefill_tokens: int = 0  # fused chunk-group tokens (scheduler cost attribution)
     prefill_padded: int = 0  # dispatched token shape incl. pads (pad-waste EMA)
-    # for the device seconds told at the fetch (_observe_round_device):
+    # for the device seconds told where the round ends (_round_ended):
     phase: str = "decode"  # decode / fused / fused_rag
     dx: int = 0  # engine._dx_n after this dispatch: rid-1's + 1 = back to back
     t_disp: float = 0.0  # time.perf_counter() when the jit call returned
+    # the account of rounds (telemetry/perf.py:RoundAccount): the step
+    # program's key, and `ended` once the first read that waited for the
+    # program has returned (a ride's read, else the fetch)
+    prog: str = "plain"  # plain / mixed_<rung> / fused / fused_rag
+    ended: bool = False
     # (host_s, wait_s) where the perf observatory sampled this dispatch
     sample: tuple | None = None
     # (admissions dispatched before this round since boot, their padded
@@ -371,6 +376,7 @@ class _DispatchedAdmit:
     first: bool  # first dispatch of its shape: the CompileLedger's, no sample
     aid: int = 0  # the ring's `admit_prog` event of this dispatch
     rid: int = 0  # the mixed round that carried it (no program of its own, no aid)
+    round: Any = None  # that round's _DispatchedRound: this read ends it
     # (admissions dispatched up to and including this one, their padded
     # tokens): where its first tokens stand in the device's order (_put_text)
     mark: tuple = (0, 0)
@@ -402,6 +408,7 @@ class _PendingRound:
     base: Any
     rid: int = 0
     mark: tuple = (0, 0)  # the dispatched round's
+    prog: str = "plain"  # the dispatched round's
 
 
 @dataclass
@@ -1473,10 +1480,17 @@ class GenerationEngine:
         # wall of the previous round completion: the sampled "wait" bucket
         # (scheduler/host gap between consecutive device rounds)
         self._perf_mark = time.perf_counter()
-        # the previous round's fetch, for the sampled device seconds: (rid,
-        # dx, time.perf_counter() when its read returned, and whether the
-        # read blocked: the round had not ended when the host asked)
-        self._prev_fetch: tuple[int, int, float, bool] = (0, 0, 0.0, False)
+        # the previous round's end, for the device seconds told: (rid, dx,
+        # time.perf_counter() when the first read that waited for its program
+        # returned, and whether that read blocked: the round had not ended
+        # when the host asked)
+        self._prev_end: tuple[int, int, float, bool] = (0, 0, 0.0, False)
+        # shapes dispatched for the first time, so far: a stall whose interval
+        # holds one is named after it (_retired)
+        self._firsts = 0
+        # the key _note_exec_shape has just opened, until _dx dispatches it
+        # inside the annotation `engine.first_dispatch`
+        self._first_key: tuple | None = None
         self._dx_n = 0  # device dispatches through _dx, all ops
         # when the last first dispatch on the engine's thread ended: rounds
         # whose life holds a compile teach the scheduler's cost model nothing
@@ -1658,8 +1672,17 @@ class GenerationEngine:
         recovery can put every process back in the same state."""
         self._backend.emit(op, args)
         self._dx_n += 1
+        first, self._first_key = self._first_key, None
         try:
-            return self._ops[op](*args)
+            if first is None:
+                return self._ops[op](*args)
+            # the call that puts a shape on the device for the first time
+            # (_note_exec_shape opened its key): jit traces, lowers and
+            # compiles or loads inside it, and on the profiler's host plane
+            # that time carries the ledger's key
+            with TraceAnnotation("engine.first_dispatch",
+                                 key=":".join(str(k) for k in first)):
+                return self._ops[op](*args)
         except Exception as e:
             if self._spmd:
                 self._mark_dead(f"dispatch {op!r} failed: {e}")
@@ -3170,6 +3193,8 @@ class GenerationEngine:
         if key in self._seen_exec_shapes:
             return False
         self._seen_exec_shapes.add(key)
+        self._firsts += 1
+        self._first_key = key
         # what JAX reports on this thread until _compile_obs goes to the
         # ledger entry
         compile_watch.begin()
@@ -3398,7 +3423,10 @@ class GenerationEngine:
     def perf_stats(self) -> dict[str, Any]:
         """Perf-observatory block (/v1/debug/perf, engines_info, benchmark/):
         ITL percentiles, goodput split, sampled per-phase host/device/wait
-        attribution, and the four-layout roofline, with admission's account
+        attribution, the four-layout roofline and the account of rounds
+        (`rounds`: every round by step program, the device seconds of those
+        that could tell them, the stalls of the in-flight queue by the loop's
+        phase, telemetry/perf.py:RoundAccount), with admission's account
         (`admit`: sums over the admit programs dispatched, `programs`,
         `prompts`, `rows_padded`, `true_tokens`, `padded_tokens`, `queued_sum`
         = requests left in the queue behind each, `by_shape`, `held_by` = why
@@ -4306,15 +4334,15 @@ class GenerationEngine:
         # it. With no profiler session an annotation is a flag test.
         span = {k: f"engine.{k}" for k in phase}
 
-        def timed(key, fn, *a, rid=0):
+        def timed(key, fn, *a, rid=0, prog=""):
             t0 = time.perf_counter()
             # fetch and emit are handed their round; dispatch says which
-            # one it is about to make
+            # one it is about to make, and of which step program (the
+            # account's key: perf_stats()["rounds"]["by_program"])
             rid = rid or (getattr(a[0], "rid", 0) if a else 0)
-            ann = (TraceAnnotation(span[key], rid=rid) if rid
-                   else TraceAnnotation(span[key]))
+            args = {"rid": rid, "prog": prog} if prog else {"rid": rid} if rid else {}
             try:
-                with ann:
+                with TraceAnnotation(span[key], **args):
                     return fn(*a)
             finally:
                 phase[key] += time.perf_counter() - t0
@@ -4483,7 +4511,8 @@ class GenerationEngine:
                     # earlier round's fetch (decode_chunk_fn docstring)
                     disp = timed("dispatch", self._dispatch_decode, active, group,
                                  *(() if ride is None else (ride,)),
-                                 rid=self._rid_dispatched + 1)
+                                 rid=self._rid_dispatched + 1,
+                                 prog=self._round_prog(group, ride))
                     if ride is not None:
                         # its first tokens are the round's own output: read
                         # before the round, so they go out a fetch earlier
@@ -4535,6 +4564,7 @@ class GenerationEngine:
             elif not (active or cn_active or admitted or group is not None
                       or inflight):
                 t_idle = time.perf_counter()
+                self._perf.rounds.unchain()  # nothing in flight, nothing to dispatch
                 with TraceAnnotation(span["idle"]):
                     self._wake.wait(timeout=0.05)
                 self._wake.clear()
@@ -5612,6 +5642,12 @@ class GenerationEngine:
         with TraceAnnotation("engine.admit.sync"):
             toks0 = np.asarray(adm.toks0)  # the admission's only host sync
         now = time.perf_counter()
+        if adm.round is not None:
+            self._round_ended(adm.round, now, t_wait, blocked, "admit")
+        elif not at_once:  # read from the queue: a retirement of its own
+            self._retired("admit", 0, now, t_wait, "admit")
+        else:  # dispatched and read in one place: the device was busy, no stall
+            self._perf.rounds.unchain()
         self._adm.read(blocked, at_once)
         # a ride names its round and carries no `aid`: the readers place the
         # runs of the admit PROGRAM by the aids of blocked reads
@@ -5977,6 +6013,7 @@ class GenerationEngine:
         """Standalone chunk dispatch for a pure-prefill window (no decode
         rows active — nothing to fuse with). Synchronous: the measured wall
         feeds the scheduler's per-token prefill cost EMA."""
+        self._perf.rounds.unchain()  # device seconds that are no retirement's
         try:
             maybe_fail(
                 "engine.prefill", f"slots={[s for s, _, _ in group.metas]}"
@@ -6198,6 +6235,7 @@ class GenerationEngine:
         start per row, decode attends < length, later writes land in place),
         so nothing is erased."""
         maybe_fail("engine.verify", f"slots={[b for b, _ in entries]}")
+        self._perf.rounds.unchain()  # device seconds that are no retirement's
         t0 = time.perf_counter()
         B = self.max_slots
         Kd = self.spec_k
@@ -6385,6 +6423,7 @@ class GenerationEngine:
         commit the sampled token through _process_token (which advances
         the automaton for the NEXT round's masks)."""
         maybe_fail("engine.cnstep", f"slots={cn_active}")
+        self._perf.rounds.unchain()  # device seconds that are no retirement's
         t0 = time.perf_counter()
         B = self.max_slots
         S = self.max_seq_len
@@ -6692,8 +6731,8 @@ class GenerationEngine:
         # host-side gap since the previous round's fetch landed. The sample
         # is counted HERE, with this round's rows (decode_occupancy reads
         # them): rounds whose device time can be told are the full ones, a
-        # free slot means an admission. Device seconds are taken at the
-        # fetch (_observe_round_device): nothing here blocks the pipeline.
+        # free slot means an admission. Device seconds are taken where the
+        # round ends (_round_ended): nothing here blocks the pipeline.
         t_disp = time.perf_counter()
         sample = None
         if self._perf.should_sample(phase_name) and not first:
@@ -6703,13 +6742,27 @@ class GenerationEngine:
                 tokens=nact * self.decode_chunk, rows=nact,
                 ctx_mean=float(base[active].mean()) if nact else 0.0,
             )
-        return _DispatchedRound(
+        disp = _DispatchedRound(
             out=out, entries=entries, base=base, t0=round_t0,
             rid=self._rid_dispatched,
             prefill_tokens=group.n_tokens if group is not None else 0,
             prefill_padded=padded, phase=phase_name, dx=self._dx_n,
             t_disp=t_disp, sample=sample, mark=self._adm.mark(),
+            prog=self._round_prog(group, ride),
         )
+        if ride is not None:
+            ride.adm.round = disp  # the read of its first tokens ends the round
+        return disp
+
+    @staticmethod
+    def _round_prog(group: _PrefillGroup | None, ride: _Ride | None) -> str:
+        """A round's step program as the account of rounds keys it
+        (telemetry/perf.py:RoundAccount), known before the dispatch: the
+        plain round, the mixed round by its rung, the round fused with a
+        chunk group by its phase."""
+        if group is not None:
+            return "fused_rag" if group.ragged else "fused"
+        return "plain" if ride is None else f"mixed_{ride.rung}"
 
     def _complete_round(self, disp: _DispatchedRound) -> _PendingRound:
         """Phase 2 (the per-round sync point): fetch the round, fast-scan
@@ -6741,8 +6794,10 @@ class GenerationEngine:
             "fetch", rid=disp.rid, wait_ms=round(wait_s * 1e3, 3),
             t=time.monotonic(),
         )
-        prev, self._prev_fetch = self._prev_fetch, (disp.rid, disp.dx, now, blocked)
-        self._observe_round_device(disp, now, blocked, prev)
+        if not disp.ended:  # a round that carried prompts ended at their read
+            self._round_ended(disp, now, t_wait, blocked, "fetch")
+        rows = len(disp.entries)
+        self._perf.rounds.fetched(disp.prog, rows, rows * self.decode_chunk)
         # feed the token-budget scheduler's cost model: prefill-free rounds
         # teach the decode-round EMA; fused rounds attribute their time over
         # that EMA to the chunk group's prompt tokens. A round whose life
@@ -6813,22 +6868,57 @@ class GenerationEngine:
                     vacant[1] = t_cool
         return _PendingRound(
             out=out, entries=disp.entries, base=disp.base, rid=disp.rid,
-            mark=disp.mark,
+            mark=disp.mark, prog=disp.prog,
         )
+
+    def _round_ended(
+        self, disp: _DispatchedRound, now: float, t_wait: float, blocked: bool,
+        kind: str,
+    ) -> None:
+        """The first read that waited for a round's program has returned at
+        `now`, having started at `t_wait` inside the loop phase `kind`: its
+        fetch ("fetch"), or for a round that carried prompts the read of
+        their first tokens ("admit": the same program's output, queued before
+        the round, so the host waits for a mixed round THERE and its fetch
+        then finds it ended). That read's time and whether it blocked are the
+        round's end, which the next round's device seconds start from;
+        and a round whose interval since the retirement before was a stall
+        tells nothing: those seconds are the stall's."""
+        disp.ended = True
+        prev, self._prev_end = self._prev_end, (disp.rid, disp.dx, now, blocked)
+        stalled = self._retired(disp.prog, disp.rid, now, t_wait, kind)
+        self._observe_round_device(disp, now, blocked and not stalled, prev)
+
+    def _retired(
+        self, prog: str, rid: int, now: float, t_wait: float, kind: str
+    ) -> bool:
+        """One retirement of the in-flight queue (a round's end, the read of
+        an admit program of its own), for the account's stalls: with the
+        loop's seconds by phase up to `now`, the blocked read in progress
+        counted into its phase `kind` (`timed` adds it when the call
+        returns). A stall is one `stall` event in the flight ring."""
+        phase_s = dict(self._phase_s)
+        phase_s[kind] += now - t_wait
+        stall = self._perf.rounds.retired(
+            prog, rid, now, now - t_wait, phase_s, self._firsts)
+        if stall is not None:
+            self._flight.event("stall", **stall)
+        return stall is not None
 
     def _observe_round_device(
         self, disp: _DispatchedRound, now: float, blocked: bool, prev: tuple
     ) -> None:
-        """A round's device seconds, told at its fetch where they can be
-        (nothing blocks the pipeline for them): the device began this round
-        when the round before it ended (that round's fetch, if this one was
-        queued behind it: dispatched back to back with nothing between, the
-        host waiting at that fetch) or when this one was dispatched (if that
-        fetch had already returned), and ended it `now`, if the host was
-        waiting here. Every round that can tell gives the perf observatory
-        its seconds and its tokens together; one that cannot (an admission's
-        read sat between, or a fetch found its round long ended) gives
-        neither, and a sampled one then journals no device time."""
+        """A round's device seconds, told where it ends (_round_ended) where
+        they can be (nothing blocks the pipeline for them): the device began
+        this round when the round before it ended (that round's end, if this
+        one was queued behind it: dispatched back to back with nothing
+        between, the host waiting at that end) or when this one was
+        dispatched (if that read had already returned), and ended it `now`,
+        if the host was waiting here. Every round that can tell gives the
+        perf observatory its seconds and its tokens together, under its step
+        program's key; one that cannot (an admit program of its own sat
+        between, or the read found its round long ended) gives neither, and a
+        sampled one then journals no device time."""
         p_rid, p_dx, p_t, p_blocked = prev
         device_s = None
         if (blocked and p_rid == disp.rid - 1
@@ -6836,7 +6926,7 @@ class GenerationEngine:
             device_s = now - max(disp.t_disp, p_t)
             rows = len(disp.entries)
             self._perf.observe_device(
-                disp.phase, device_s, rows, rows * self.decode_chunk,
+                disp.phase, disp.prog, device_s, rows, rows * self.decode_chunk,
                 sampled=disp.sample is not None,
             )
         if disp.sample is not None:
@@ -6903,6 +6993,7 @@ class GenerationEngine:
             if finish is not None:
                 self._finish_slot(b, s, finish)
         delivered = self.total_tokens - before
+        self._perf.rounds.delivered(p.prog, delivered)
         self._flight.event(
             "emit", rid=p.rid, rows=len(p.entries), delivered=delivered,
             texts=texts, held=held,

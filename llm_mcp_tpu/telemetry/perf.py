@@ -22,11 +22,14 @@ parts:
    sample is counted at its dispatch. Nothing blocks for its device
    seconds (with two rounds in flight a `block_until_ready` at dispatch
    waits for the round before as well, and serialises the pipeline): EVERY
-   round whose device time can be told at its fetch, as the interval since
-   the previous round's fetch when the two were dispatched back to back
-   and the host waited for both, gives its seconds, rows and tokens
-   together (`observe_device`), and the roofline's token rate and the rows
-   it is evaluated at are those. A
+   round whose device time can be told where it ends (the first read that
+   waited for its program: its fetch, or for a round that carried prompts
+   the read of their first tokens), as the interval since the previous
+   round's end when the two were dispatched back to back and the host
+   waited for both, gives its seconds, rows and tokens together
+   (`observe_device`) to the row of its step program in `RoundAccount`, the
+   one book of rounds, and the roofline's token rate and the rows it is
+   evaluated at are that book's totals. A
    sampled round that can be told adds its seconds to the phase's
    `device_s` too. The synchronous prefill-family dispatches time their
    own sync.
@@ -57,6 +60,7 @@ cadence to sample).
 
 from __future__ import annotations
 
+import gc
 import os
 import threading
 import time
@@ -72,6 +76,7 @@ __all__ = [
     "DISPATCH_PHASES",
     "ModelShape",
     "PerfObservatory",
+    "RoundAccount",
     "WARMUP_PHASES",
     "decode_flops_per_token",
     "decode_hbm_bytes_per_token",
@@ -452,6 +457,172 @@ class AdmitAccount:
             }
 
 
+# An interval between two retirements of the engine's in-flight queue that is
+# longer than this (and than twice the retiring program's mean told device
+# seconds) is a stall: a round is 36-122 ms in every cell, and 200 ms is the
+# threshold builders' scripts have walked the ring with since PR 38.
+STALL_S = 0.2
+STALL_ROWS = 16  # the newest stalls kept whole
+
+# Seconds this process has spent inside Python's collector, on one pair of
+# stamps: (when the collection in progress began or 0.0, the seconds of those
+# that have ended), swapped whole. A collection holds the GIL whichever thread
+# set it off, so it is the engine thread's time too; but it lets go of it in
+# destructors that release it (a device buffer's), and the loop can then come
+# by before the collection's end is stamped: the reader counts the part of a
+# collection in progress, or a stall would give its seconds to the next
+# interval (PR 54: stalls of 0.33-0.40 s with `gc_s` 0.000 in windows whose
+# collections summed to 0.39-0.48 s).
+_gc = {"v": (0.0, 0.0)}
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    t0, s = _gc["v"]
+    now = time.perf_counter()
+    _gc["v"] = (now, s) if phase == "start" else (0.0, s + (now - t0 if t0 else 0.0))
+
+
+def gc_seconds() -> float:
+    """Seconds inside `gc` collections since the first `RoundAccount` of the
+    process was made, the one in progress up to now."""
+    t0, s = _gc["v"]
+    return s + (time.perf_counter() - t0 if t0 else 0.0)
+
+
+class RoundAccount:
+    """Every round of the engine's loop, in sums since boot
+    (`perf_stats()["rounds"]`): a reader takes the difference over its window.
+    Written by the engine's thread alone, read from any.
+
+    `by_program` has one row a step program the loop dispatches as a round,
+    under the key the engine knows it by at dispatch: `plain`, `mixed_<rung>`
+    (a round whose first step carried prompts, by its packed length), `fused`,
+    `fused_rag`. `fetched` books a round at its fetch: `rounds`, `rows`,
+    `row_steps` (rows x decode_chunk); the prompts a mixed round carried are
+    `AdmitAccount`'s `rides`. `told` books the device seconds of a round that
+    could tell them (`told`, `device_s`, and the rows and tokens of those
+    same rounds: `told_rows`, `told_tokens`). `delivered` books the tokens
+    its emission handed on.
+
+    `retired` is handed every retirement of the in-flight queue (a round's
+    end, the read of an admit program) and books a STALL where the interval
+    since the retirement before it, the chain unbroken between (`unchain`), is
+    longer than `STALL_S` and than twice the retiring program's mean told
+    device seconds: `count`, `seconds`, `excess_s` (the interval less that
+    mean), `by_phase` {phase: [count, excess_s]} under the loop phase that
+    held most of the interval's host seconds, or `first_dispatch` where a
+    shape was dispatched for the first time inside it, and `gc_s`, the part of
+    the stalls' seconds inside Python's collector; the newest `STALL_ROWS`
+    whole in `recent` (`t` on time.monotonic(); `wait_s` the blocked read that
+    closed it). The account's own `gc_s` sums the collector's seconds over
+    every interval, stall or not."""
+
+    ROW = ("rounds", "rows", "row_steps", "delivered", "told", "told_rows",
+           "told_tokens", "device_s")
+    TOLD = ("told", "told_rows", "told_tokens", "device_s")
+
+    def __init__(self, lock: threading.Lock | None = None) -> None:
+        self._lock = lock or threading.Lock()
+        self._rows: dict[str, dict[str, float]] = {}
+        self._stalls = {"count": 0, "seconds": 0.0, "excess_s": 0.0,
+                        "longest_s": 0.0, "gc_s": 0.0}
+        self._by_phase: dict[str, list] = {}
+        self._recent: deque[dict[str, Any]] = deque(maxlen=STALL_ROWS)
+        self.gc_s = 0.0
+        # the retirement before: (its time, the loop's seconds by phase then,
+        # first dispatches so far, the collector's seconds so far); None
+        # where the chain has broken since (unchain)
+        self._prev: tuple | None = None
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+
+    def _row(self, prog: str) -> dict[str, float]:
+        row = self._rows.get(prog)
+        if row is None:
+            row = self._rows[prog] = {
+                k: 0.0 if k.endswith("_s") else 0 for k in self.ROW}
+        return row
+
+    def fetched(self, prog: str, rows: int, row_steps: int) -> None:
+        with self._lock:
+            row = self._row(prog)
+            row["rounds"] += 1
+            row["rows"] += rows
+            row["row_steps"] += row_steps
+
+    def told(self, prog: str, device_s: float, rows: int, tokens: int) -> None:
+        with self._lock:
+            row = self._row(prog)
+            row["told"] += 1
+            row["told_rows"] += max(0, rows)
+            row["told_tokens"] += max(0, tokens)
+            row["device_s"] += max(0.0, device_s)
+
+    def delivered(self, prog: str, tokens: int) -> None:
+        with self._lock:
+            self._row(prog)["delivered"] += tokens
+
+    def unchain(self) -> None:
+        """The next retirement closes no interval: the loop found nothing in
+        flight and nothing to dispatch (a wait for requests is no stall), or
+        ran a device program to its end that is no retirement (a standalone
+        chunk group, a constrained or a speculative round, an admission read
+        at once: the device was busy, and those seconds are no stall either)."""
+        self._prev = None
+
+    def retired(self, prog: str, rid: int, now: float, wait_s: float,
+                phase_s: dict[str, float], firsts: int) -> dict[str, Any] | None:
+        """One retirement at `now` (the engine's time.perf_counter()), its
+        blocked read `wait_s` long, with the loop's seconds by phase up to
+        `now` and the count of first dispatches so far. Returns the stall's
+        row where the interval it closes was one."""
+        gc_now = gc_seconds()
+        prev, self._prev = self._prev, (now, phase_s, firsts, gc_now)
+        if prev is None:
+            return None
+        p_t, p_phase, p_firsts, p_gc = prev
+        seconds, gc_s = now - p_t, gc_now - p_gc
+        with self._lock:
+            self.gc_s += gc_s
+            row = self._rows.get(prog)
+            mean = row["device_s"] / row["told"] if row and row["told"] else 0.0
+            if seconds <= max(STALL_S, 2.0 * mean):
+                return None
+            host = {k: v - p_phase.get(k, 0.0) for k, v in phase_s.items()}
+            phase = ("first_dispatch" if firsts != p_firsts
+                     else max(host, key=host.get) if host else "")
+            st = self._stalls
+            st["count"] += 1
+            st["seconds"] += seconds
+            st["excess_s"] += seconds - mean
+            st["longest_s"] = max(st["longest_s"], seconds)
+            st["gc_s"] += gc_s
+            by = self._by_phase.setdefault(phase, [0, 0.0])
+            by[0] += 1
+            by[1] += seconds - mean
+            stall = {"t": time.monotonic(), "seconds": round(seconds, 6),
+                     "excess_s": round(seconds - mean, 6), "phase": phase,
+                     "program": prog, "rid": rid, "gc_s": round(gc_s, 6),
+                     "wait_s": round(wait_s, 6)}
+            self._recent.append(stall)
+        return stall
+
+    def totals(self) -> dict[str, float]:
+        """The told rounds of every program together: the roofline's rate."""
+        with self._lock:
+            return {k: sum(r[k] for r in self._rows.values()) for k in self.TOLD}
+
+    def stats(self) -> dict[str, Any]:
+        with self._lock:
+            return {
+                "by_program": {k: dict(r) for k, r in self._rows.items()},
+                "stalls": {**self._stalls,
+                           "by_phase": {k: list(v) for k, v in self._by_phase.items()},
+                           "recent": [dict(r) for r in self._recent]},
+                "gc_s": self.gc_s,
+            }
+
+
 class PerfObservatory:
     """Per-process-engine perf state: ITL window, goodput ledger, sampled
     phase attribution, and the roofline evaluation. All writers are the
@@ -521,10 +692,11 @@ class PerfObservatory:
             for p in DISPATCH_PHASES
         }
         self._dispatches = {p: 0 for p in DISPATCH_PHASES}
-        # decode-family rounds whose device time could be told at their
-        # fetch, sampled or not: seconds and tokens of the SAME rounds, the
-        # roofline's measured token rate (observe_device)
-        self._told = {"rounds": 0, "device_s": 0.0, "tokens": 0, "rows": 0}
+        # the one book of rounds, by step program: every round's counts, and
+        # of those whose device time could be told, sampled or not, seconds
+        # and tokens of the SAME rounds, the roofline's measured token rate
+        # (observe_device)
+        self.rounds = RoundAccount(self._lock)
         # live decode-shape EMAs feeding the roofline (mean context, rows)
         self._ctx_ema = 0.0
         self._rows_ema = 0.0
@@ -770,23 +942,20 @@ class PerfObservatory:
                 )
 
     def observe_device(
-        self, phase: str, device_s: float, rows: int, tokens: int, sampled: bool
+        self, phase: str, prog: str, device_s: float, rows: int, tokens: int,
+        sampled: bool,
     ) -> None:
-        """A decode round whose device seconds could be told at its fetch,
-        with the rows and tokens of that same round. `sampled`:
-        `observe_phase` counted this round at its dispatch, with no device
-        seconds yet."""
+        """A decode round of step program `prog` (`RoundAccount`'s key) whose
+        device seconds could be told where it ended, with the rows and tokens
+        of that same round. `sampled`: `observe_phase` counted this round at
+        its dispatch, with no device seconds yet."""
         rec = self._phases.get(phase)
         if rec is None:
             return
-        device_s = max(0.0, device_s)
-        with self._lock:
-            self._told["rounds"] += 1
-            self._told["device_s"] += device_s
-            self._told["tokens"] += max(0, tokens)
-            self._told["rows"] += max(0, rows)
-            if sampled:
-                rec["device_s"] += device_s
+        self.rounds.told(prog, device_s, rows, tokens)
+        if sampled:
+            with self._lock:
+                rec["device_s"] += max(0.0, device_s)
 
     def phase_attribution(self) -> dict[str, dict[str, float]]:
         with self._lock:
@@ -810,15 +979,16 @@ class PerfObservatory:
         walls; the four layouts share it so the non-active rows read as
         what-ifs."""
         peaks = CHIP_PEAKS.get(self.device_kind)
-        with self._lock:
-            told = dict(self._told)
+        told = self.rounds.totals()
         # the measured rate, and the rows it was measured at, are those of
-        # the rounds whose device time could be told (the sampled rows EMA
-        # stands in until one has been)
-        tok_s = told["tokens"] / told["device_s"] if told["device_s"] > 0 else 0.0
+        # the rounds whose device time could be told, of every step program
+        # (the sampled rows EMA stands in until one has been): a round that
+        # carried prompts counts its decode rows' tokens and its whole
+        # seconds, and the account's rows by program say what each kind took
+        tok_s = told["told_tokens"] / told["device_s"] if told["device_s"] > 0 else 0.0
         ctx = self._ctx_ema or 1.0
         rows = (
-            told["rows"] / told["rounds"] if told["rows"]
+            told["told_rows"] / told["told"] if told["told_rows"]
             else self._rows_ema or 1.0
         )
         out: dict[str, Any] = {
@@ -826,9 +996,9 @@ class PerfObservatory:
             "device_tok_per_s": round(tok_s, 1),
             # lifetime sums behind the rate: a reader with a window of its
             # own takes end minus start
-            "device_rounds": float(told["rounds"]),
+            "device_rounds": float(told["told"]),
             "device_s": round(told["device_s"], 6),
-            "device_tokens": float(told["tokens"]),
+            "device_tokens": float(told["told_tokens"]),
             "ctx_mean": round(ctx, 1),
             "rows_mean": round(rows, 2),
             "active_layout": self.active_layout,
@@ -886,4 +1056,5 @@ class PerfObservatory:
             "tenants": self.tenant_goodput(),
             "phases": self.phase_attribution(),
             "roofline": self.roofline(),
+            "rounds": self.rounds.stats(),
         }

@@ -2,7 +2,7 @@
 """One traced run of a generation cell with the seven admission readers beside
 the cell's own per-layer metrics, through the harness itself.
 
-    chiprun -- python3 scripts/admit_metrics.py --workload olmo_hybrid_decode_closed --seed <n>
+    chiprun -- python3 scripts/admit_metrics.py --workload olmo_hybrid_decode_closed --seed <n> [--trace 0]
 
 The readers (`benchmark/layer_metrics/admit_*.py`, `event_gap_admit_*.py`,
 `slot_vacant*.py`) take any generation cell; until `BENCHMARK.json` lists them
@@ -16,16 +16,14 @@ round, and `own` / `own_prompts`, those that took a program of their own by
 reason, with `engaged_share` = riding prompts over all), `samples_evicted` and
 how many `event_gap` samples the window and the drain put, the ring's admit
 programs inside the profiler's slice beside the runs of `jit_admit_fn` in it,
-the slice's rounds by program name (`rounds`: runs and mean device ms of the
-plain `jit_decode_chunk_fn` and of the mixed `jit_mixed_round_fn`, the mixed
-ones by rung from the ring's `mixed` events in the slice, and the host's
-milliseconds a round as `engine_host_ms_per_round` reads them, over rounds of
-BOTH names since PR 40), the rows of EVERY
+the window's difference of the engine's account of rounds (`rounds`, since
+PR 54: `perf_stats()["rounds"]`, every round of the WINDOW by step program with
+its rounds, told rounds and device ms, the mixed round by its rung, and the
+stalls of the in-flight queue by the loop's phase; nothing from a program
+without the account), the rows of EVERY
 round of the window over the slots (`occupancy_ring`, from the ring's `emit`
 events: `decode_occupancy` reads one dispatch in 32 of a phase, about two
-dozen a window), the window's gaps of over 200 ms between two rounds'
-emissions with the ring's events inside each (`stalls`), and the warm-up plan's
-seconds by phase (`plan`).
+dozen a window) and the warm-up plan's seconds by phase (`plan`).
 """
 
 from __future__ import annotations
@@ -56,9 +54,6 @@ def diff(a, b):
     return b - (a or 0)
 
 
-MIXED_PROGRAM = "jit_mixed_round_fn"  # a decode round whose first step carries prompts
-
-
 def wall_shift() -> float:
     """What a wall-clock stamp (the ring's `ts`, `_ttft_window`) is ahead of
     the monotonic clock the window's edges are on."""
@@ -78,58 +73,23 @@ def first_token_ms(gen, w0: float, w1: float) -> dict | None:
             "mean": round(sum(got) / len(got), 2)}
 
 
-def stalls(gen, w0: float, w1: float, over_ms: float = 200.0) -> list:
-    """The window's gaps between two rounds' emissions that are longer than
-    `over_ms` (a round is 50-90 ms in every cell): [seconds into the window,
-    the gap's ms, the ring's events inside it by kind]. A stall of seconds is
-    invisible to a p95 and is most of what a low run of tokens lost."""
-    shift = wall_shift()
-    rows = gen._flight.snapshot()
-    emits = [r["ts"] - shift for r in rows if r["etype"] == "emit"]
-    emits = [t for t in emits if w0 <= t < w1]
-    got = []
-    for a, b in zip(emits, emits[1:]):
-        if (b - a) * 1e3 > over_ms:
-            kinds: dict[str, int] = {}
-            for r in rows:
-                if a < r["ts"] - shift < b:
-                    kinds[r["etype"]] = kinds.get(r["etype"], 0) + 1
-            got.append([round(a - w0, 3), round((b - a) * 1e3, 1), kinds])
-    return got
+def rounds(run: dict, apart_s: float = 0.015) -> dict | None:
+    """The window's difference of `perf_stats()["rounds"]`: rounds, told rounds
+    and device ms by program, the stalls by phase, and beside them the rounds
+    of the window by the CLIENTS' records (`client_rounds`: the bursts of
+    content deltas over all streams; a round's emission reaches its streams
+    within milliseconds and rounds are 36 ms and more apart). None from a
+    program without the account."""
+    from benchmark import round_account
 
-
-def rounds_by_program(run: dict, tr: dict) -> dict | None:
-    """Runs and mean device ms of the plain and the mixed round in the slice,
-    the mixed rounds of the slice by rung (the ring says which rung a round
-    took, the trace what a run cost: matched in order where they agree in
-    number), and the host's ms a round over rounds of both names."""
-    from benchmark import spans
-    from benchmark.layer_metrics import engine_host_ms_per_round as host_reader
-
-    got = spans.planes(run)
-    if got is None:
+    rows, st = round_account.by_program(run), round_account.stalls(run)
+    if rows is None or st is None:
         return None
-    chips, host = got
-    plain = spans.program_runs(chips, "jit_decode_chunk_fn")
-    mixed = spans.program_runs(chips, MIXED_PROGRAM)
-
-    def ms(runs):
-        return round(sum(b - a for a, b in runs) / len(runs) / 1e6, 3) if runs else None
-
-    out = {"plain": [len(plain), ms(plain)], "mixed": [len(mixed), ms(mixed)]}
-    ring = [e["fields"] for e in run["sut"]["gen"]._flight.snapshot(etype="mixed")]
-    if "start" in tr:
-        ring = [f for f in ring if tr["start"] <= f["t"] < tr["stop"]]
-        out["mixed_ring_in_slice"] = len(ring)
-        if mixed and abs(len(ring) - len(mixed)) <= 2:
-            by_rung: dict[int, list[float]] = {}
-            # the slice's edges may hold a run whose dispatch fell outside: match from the end
-            for f, (a, b) in zip(reversed(ring), reversed(mixed)):
-                by_rung.setdefault(f["padded_tokens"], []).append((b - a) / 1e6)
-            out["mixed_by_rung"] = {k: [len(v), round(sum(v) / len(v), 3)] for k, v in sorted(by_rung.items())}
-    by_reader = host_reader.read(run)
-    if by_reader is not None:  # since PR 40 the reader divides by the rounds of both names itself
-        out["host_ms_per_round"] = round(by_reader, 3)
+    out = {"by_program": {prog: {**r, "ms": round_account.ms(r)} for prog, r in sorted(rows.items())}, "stalls": st}
+    if "records" in run:
+        w0, w1 = run["window"]
+        ts = sorted(t for r in run["records"] for t in (r.get("events") or []) if w0 <= t < w1)
+        out["client_rounds"] = sum(b - a > apart_s for a, b in zip(ts, ts[1:])) + bool(ts)
     return out
 
 
@@ -162,7 +122,6 @@ def extras(run: dict):
         "rounds": len(emits),
         "pct": round(100.0 * sum(f["rows"] for f in emits) / (len(emits) * gen.max_slots), 3),
     } if emits else None
-    out["stalls"] = stalls(gen, w0, w1)
     plan = (gen.warmup_stats().get("plan") or [])
     out["plan"] = {
         "steps": len(plan),
@@ -172,11 +131,10 @@ def extras(run: dict):
         "mixed": [{k: st.get(k) for k in ("key", "status", "wall_s")}
                   for st in plan if st["phase"] == "mixed"],
     }
+    got = rounds(run)
+    if got is not None:
+        out["rounds"] = got
     tr = run.get("trace") or {}
-    try:
-        out["rounds"] = rounds_by_program(run, tr)
-    except Exception as e:  # noqa: BLE001: a builder's print must not lose the run's line
-        out["rounds"] = f"{type(e).__name__}: {e}"
     if "start" in tr:
         progs = admit_spans.ring(run, "admit_prog").values()
         got = spans.planes(run)
@@ -194,6 +152,8 @@ def main() -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, default=40.0)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=1,
+                    help="0: an UNTRACED run (the end-to-end metrics) with the `admission:` line before it")
     args = ap.parse_args()
 
     load_cell, load_reader = bench_run.load_cell, bench_run.load_reader
@@ -206,6 +166,7 @@ def main() -> int:
                 unit = EXTRAS if name == EXTRAS else load_reader("layer_metrics", name).UNIT
                 spec["per_layer"].append({"name": name, "unit": unit})
         spec["per_layer"] += [m for m in spec["end_to_end"] if m["name"] in END_TO_END]
+        spec["end_to_end"].append({"name": EXTRAS, "unit": EXTRAS})  # what an untraced run reads
         return spec
 
     def reader(kind: str, name: str):
@@ -215,7 +176,7 @@ def main() -> int:
 
     bench_run.load_cell, bench_run.load_reader = cell_with_admission, reader
     return bench_run.main(["--workload", args.workload, "--seed", str(args.seed),
-                           "--seconds", str(args.seconds), "--trace", "1"])
+                           "--seconds", str(args.seconds), "--trace", str(args.trace)])
 
 
 if __name__ == "__main__":

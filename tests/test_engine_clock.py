@@ -158,7 +158,7 @@ def test_device_seconds_are_told_at_the_fetch_with_their_own_tokens(env, monkeyp
 
     assert "block_until_ready" not in inspect.getsource(GenerationEngine._dispatch_decode)
     before = gen.perf_stats()["phases"]["decode"]
-    told0 = dict(gen._perf._told)
+    told0 = gen._perf.rounds.totals()
     n = len(ring(rec, "emit"))
     gen.generate("sampled", max_tokens=14, temperature=0.0)
     settle(gen, rec, n + 3)
@@ -176,11 +176,12 @@ def test_device_seconds_are_told_at_the_fetch_with_their_own_tokens(env, monkeyp
     assert told and all(0 < p["device_ms"] <= max(spans) + 1.0 for p in told)
     # seconds and tokens of the SAME rounds: with every round sampled, the
     # rounds told are the perf events that carry device seconds
-    d = {k: gen._perf._told[k] - told0[k] for k in told0}
-    assert d["rounds"] == d["rows"] == len(told) and d["tokens"] == K * len(told)
+    now = gen._perf.rounds.totals()
+    d = {k: now[k] - told0[k] for k in told0}
+    assert d["told"] == d["told_rows"] == len(told) and d["told_tokens"] == K * len(told)
     assert d["device_s"] == pytest.approx(after["device_s"] - before["device_s"], abs=1e-5)
     rf = gen.perf_stats()["roofline"]
-    assert (rf["device_rounds"], rf["device_tokens"]) == (gen._perf._told["rounds"], gen._perf._told["tokens"])
+    assert (rf["device_rounds"], rf["device_tokens"]) == (now["told"], now["told_tokens"])
     assert rf["device_tok_per_s"] == pytest.approx(rf["device_tokens"] / rf["device_s"], rel=1e-3)
 
 
@@ -231,7 +232,7 @@ def test_a_round_whose_device_time_cannot_be_told_gives_neither_seconds_nor_toke
     import numpy as np
 
     before = gen.perf_stats()["phases"]["decode"]
-    told0 = dict(gen._perf._told)
+    told0 = gen._perf.rounds.totals()
     n_perf = len(ring(rec, "perf"))
     # a sampled round fetched long after it ended (the read does not block)
     late = _DispatchedRound(out=jnp.zeros((K, 4), jnp.int32), entries=[], base=np.zeros(4, np.int32),
@@ -240,7 +241,7 @@ def test_a_round_whose_device_time_cannot_be_told_gives_neither_seconds_nor_toke
     late.out.block_until_ready()
     gen._complete_round(late)
     assert gen.perf_stats()["phases"]["decode"] == before  # no 300 ms of "device" time
-    assert gen._perf._told == told0
+    assert gen._perf.rounds.totals() == told0
     assert ring(rec, "perf")[n_perf:] == [
         {"phase": "decode", "host_ms": 1.0, "device_ms": None, "wait_ms": 0.0, "rows": 0}]
     # with sampling far away (every 10,000th round) rounds that can tell still
@@ -251,8 +252,9 @@ def test_a_round_whose_device_time_cannot_be_told_gives_neither_seconds_nor_toke
     settle(gen, rec, n + 3)
     after = gen.perf_stats()["phases"]["decode"]
     assert after == before and len(ring(rec, "perf")) == n_perf + 1
-    d = {k: gen._perf._told[k] - told0[k] for k in told0}
-    assert d["rounds"] >= 1 and d["tokens"] == K * d["rounds"] and 0 < d["device_s"] < 0.25 * d["rounds"]
+    now = gen._perf.rounds.totals()
+    d = {k: now[k] - told0[k] for k in told0}
+    assert d["told"] >= 1 and d["told_tokens"] == K * d["told"] and 0 < d["device_s"] < 0.25 * d["told"]
 
 
 class FakeMonitoring:
@@ -500,4 +502,6 @@ def test_an_admit_program_is_recorded_where_it_is_dispatched(env, monkeypatch, p
         opened = [name for kind, name, _kw in log[:i] if kind == "in"]
         closed = [name for kind, name, _kw in log[:i] if kind == "out"]
         assert opened.count("engine.admit") - closed.count("engine.admit") == 1
-        assert log[i + 1][:2] == ("out", "engine.admit.dispatch")
+        # (a shape's first dispatch names itself inside it since PR 54: `_dx` opens `engine.first_dispatch`)
+        inside = [e for e in log[i + 1:] if e[1] != "engine.first_dispatch"]
+        assert inside[0][:2] == ("out", "engine.admit.dispatch")

@@ -17,11 +17,29 @@ from llm_mcp_tpu.executor.engine import GenRequest
 S, B, K = 128, 8, 2
 
 
+_built: list = []  # the engines of the test that is running (_engines_end)
+
+
 def _engine(monkeypatch, model="tiny-llm", attn="pallas", **kw):
     monkeypatch.setenv("LLM_MCP_TPU_ATTN", attn)
     kw = {"max_slots": B, "max_seq_len": S, "dtype": jnp.float32, "decode_chunk": K,
           "quant": "int8", "kv_quant": "int8", "prefill_chunk": 64, **kw}
-    return GenerationEngine(model, **kw)
+    _built.append(GenerationEngine(model, **kw))
+    return _built[-1]
+
+
+@pytest.fixture(autouse=True)
+def _engines_end():
+    """Every engine a test built ends with it (a file that imports `_engine`
+    imports this too). An engine that is built and never started keeps its
+    watchdog's thread, the thread keeps the engine, and the engine its
+    executables: 3,000-4,500 memory maps a hybrid test that never came back,
+    65,000 of 65,530 by the end of tests/test_mixed_round_hybrid.py in one
+    process, and past the limit the next compile or load segfaulted (PR 54;
+    the driver's `--dist load` deals every file's tests to every worker)."""
+    yield
+    while _built:  # a started engine's test shuts it down itself; this ends a built one's watchdog
+        _built.pop()._stop_evt.set()
 
 
 def _prompt(rng, n):
@@ -224,7 +242,7 @@ def _submit(eng, text, **kw):
     return eng.submit(req)
 
 
-def _wait_active(eng, n, timeout=30.0):
+def _wait_active(eng, n, timeout=120.0):  # an admit program's first dispatch compiles: 30 s and more a hybrid's, cold, beside five workers
     end = time.time() + timeout
     while time.time() < end:
         if sum(s is not None for s in eng._slots) >= n:

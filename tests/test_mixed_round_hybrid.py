@@ -8,8 +8,8 @@ dense family's cases are tests/test_mixed_round.py's."""
 import numpy as np
 import pytest
 
-from test_mixed_round import (
-    B, K, S, _admit_arrays, _engine, _prompt, _restore, _ride_arrays, _seed_rows, _state,
+from test_mixed_round import (  # noqa: F401 (_engines_end: an autouse fixture)
+    B, K, S, _admit_arrays, _engine, _engines_end, _prompt, _restore, _ride_arrays, _seed_rows, _state,
     every_mixed_shape_is_in_the_zoo, rides_beside_active_rows,
     test_the_plans_module_is_the_one_the_live_call_lowers as plans_module_is_the_live_calls)
 

@@ -7,6 +7,7 @@ a process of its own (that file says why)."""
 import pytest
 
 import test_mixed_round_hybrid as rounds
+from test_mixed_round import _engines_end  # noqa: F401 (an autouse fixture: the engines a test built end with it)
 
 
 @pytest.mark.parametrize("case", list(rounds.HYBRID_CASES))

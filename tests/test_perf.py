@@ -292,7 +292,7 @@ def test_roofline_four_layouts_against_one_measured_rate():
     # its fetch -> 10k tok/s
     obs.observe_phase("decode", 0.001, 0.0, tokens=100, rows=4,
                       ctx_mean=512.0)
-    obs.observe_device("decode", 0.010, 4, 100, sampled=True)
+    obs.observe_device("decode", "plain", 0.010, 4, 100, sampled=True)
     r = obs.roofline()
     assert r["device_tok_per_s"] == pytest.approx(10_000.0)
     assert r["device_rounds"] == 1.0
@@ -331,14 +331,20 @@ def test_the_device_rate_pairs_seconds_and_tokens_of_the_same_rounds():
     assert obs.roofline()["device_tok_per_s"] == 0.0
     assert obs.roofline()["rows_mean"] == 32.0  # the sampled EMA, until a round can tell
     obs.observe_phase("admit", 0.001, 0.010, tokens=40, rows=1)  # not a decode shape
-    obs.observe_device("fused", 0.100, 30, 120, sampled=False)  # one told round, not a sample
-    obs.observe_device("decode", 0.140, 32, 128, sampled=True)
-    obs.observe_device("nonsense", 1.0, 1, 1, sampled=True)  # unknown phase: dropped
+    obs.observe_device("fused", "mixed_128", 0.100, 30, 120, sampled=False)  # one told round, not a sample
+    obs.observe_device("decode", "plain", 0.140, 32, 128, sampled=True)
+    obs.observe_device("nonsense", "plain", 1.0, 1, 1, sampled=True)  # unknown phase: dropped
     r = obs.roofline()
     assert r["device_tok_per_s"] == pytest.approx(248 / 0.240, rel=1e-3)
     assert (r["device_rounds"], r["device_tokens"]) == (2.0, 248.0)
     assert r["device_s"] == pytest.approx(0.240)
     assert r["rows_mean"] == 31.0  # of the rounds the rate was measured on
+    # the account of rounds is the one book: the totals over its rows by program
+    by = obs.rounds.stats()["by_program"]
+    assert {k: (v["told"], v["told_rows"], v["told_tokens"]) for k, v in by.items()} == {
+        "mixed_128": (1, 30, 120), "plain": (1, 32, 128)}
+    assert sum(v["device_s"] for v in by.values()) == pytest.approx(r["device_s"])
+    assert not hasattr(obs, "_told")
     att = obs.phase_attribution()
     assert att["decode"]["samples"] == 5.0 and att["decode"]["tokens"] == 640.0
     assert att["decode"]["device_s"] == pytest.approx(0.140)  # the sampled round's only
@@ -365,7 +371,7 @@ def test_stats_document_shape():
     st = PerfObservatory(SHAPE).stats()
     assert set(st) == {
         "sample_every", "itl", "itl_mean_ms", "goodput", "phases", "roofline",
-        "tenants", "event_gap", "stream_lag", "samples_evicted",
+        "tenants", "event_gap", "stream_lag", "samples_evicted", "rounds",
     }
     assert st["samples_evicted"] == {"event_gap": 0, "stream_lag": 0}
     assert st["event_gap"] == {"p50_ms": 0.0, "p95_ms": 0.0, "samples": 0.0}

@@ -357,11 +357,19 @@ def test_grpc_metadata_propagation(server, base):
         db.close()
 
 
-def test_stage_histogram_observes_every_stage(base):
+def test_stage_histogram_observes_every_stage(base, server):
     """After the flows above, llmtpu_stage_duration_seconds has counted
     every stage: queue_wait, route, rpc, prefill, decode."""
+    stages = ("queue_wait", "route", "rpc", "prefill", "decode")
     text = httpx.get(f"{base}/metrics").text
-    for stage in ("queue_wait", "route", "rpc", "prefill", "decode"):
+    if not all(f'llmtpu_stage_duration_seconds_count{{stage="{st}"}}' in text for st in stages):
+        # `--dist load` deals a file's tests to any worker: the flows above
+        # may have run against another process's server, so run them here
+        test_chat_completion_trace_e2e(base)
+        test_job_trace_has_queue_wait_span(base)
+        test_grpc_metadata_propagation(server, base)
+        text = httpx.get(f"{base}/metrics").text
+    for stage in stages:
         m = re.search(
             rf'llmtpu_stage_duration_seconds_count{{stage="{stage}"}} (\d+\.?\d*)', text
         )
