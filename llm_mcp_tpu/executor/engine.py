@@ -734,12 +734,12 @@ class GenerationEngine:
         # what the blocked int8 decode-attention arm streams, where decode
         # rounds run it (int8 GQA cache read by the Pallas kernel): None else
         self._attn_stream = (
-            AttnStream(self._ck["q"].shape)
+            AttnStream(self._ck["q"].shape, kv_heads=self.cfg.n_kv_heads)
             if layout.fused and self.decode_impl == "pallas" else None)
         # and what the window arm streams of the window layers' rings
         self._win_stream = (
             AttnStream(self._cv["win"]["k"]["q"].shape, window=self.cfg.sliding_window,
-                       max_seq_len=max_seq_len)
+                       max_seq_len=max_seq_len, kv_heads=self.cfg.n_kv_heads)
             if self._attn_stream is not None and layout.slot_member == "win" else None)
         if self._spmd:
             # named out_sharding kinds for _shard_out: host-read outputs come

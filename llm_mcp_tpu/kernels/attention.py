@@ -51,6 +51,7 @@ from ..telemetry.recorder import get_recorder
 from ..utils.platform import on_tpu as _on_tpu
 
 NEG_INF = float(-1e30)
+LANES = 128  # a row of the chip's tiles
 
 # Kernel-to-reference falls: a dispatcher that was asked for a compiled
 # (non-interpret) kernel and answered with the XLA reference math instead,
@@ -410,17 +411,24 @@ def _attend_q8_kernel(
     li_ref,  # [1] int32 (scalar prefetch) — layer index
     ids_ref,  # [Ba] int32 (scalar prefetch) — cache row per batch position
     lengths_ref,  # [Ba] int32 (scalar prefetch) — this step's position per row
-    q_ref,  # [1, Hkv, G, hd]
-    nk_ref,  # [1, Hkv, 1, hd] — this step's K vectors (post-rope)
-    nv_ref,  # [1, Hkv, 1, hd]
-    kv_ref,  # [1, 1, 2*Hkv, S, hd] int8 — fused K|V payload, all heads
+    q_ref,  # [1, R, P*G, W] — R = Hkv / P cache rows of P heads abreast,
+    #         W = P*hd; a query group in its head's lanes (`q_abreast`)
+    nk_ref,  # [1, R, 1, W] — this step's K vectors (post-rope), abreast
+    nv_ref,  # [1, R, 1, W]
+    kv_ref,  # [1, 1, 2*R, S, W] int8 — fused K|V payload, all heads
     s_ref,  # [1, 1, 2*Hkv, S] — fused K|V dequant scales
-    o_ref,  # [1, Hkv, G, hd] — attention output
+    o_ref,  # [1, R, P*G, W] — attention output (`ctx_apart` takes each
+    #         group's own head's lanes)
     *,
     scale: float,
     window: int = 0,
 ):
     """One grid cell = one batch row, all KV heads.
+
+    Where P heads lie abreast in a cache row (`kv_heads_abreast`) a row's
+    product is over all W lanes: a query group's row is zero outside its own
+    head's lanes, so the int8 scores are that head's exactly, and of the
+    second product's W output lanes the group's own head's are its context.
 
     With `window` the tile is a window layer's RING of S positions (a power of
     two, at least the window): position p lies at index p mod S, so index j
@@ -445,12 +453,14 @@ def _attend_q8_kernel(
     b = pl.program_id(0)
     w = lengths_ref[b]  # this step's position; attend to 0..w inclusive
     S = kv_ref.shape[3]
-    Hkv = q_ref.shape[1]
-    G = q_ref.shape[2]
+    R = q_ref.shape[1]
+    Hkv = s_ref.shape[2] // 2
+    P = Hkv // R
+    G = q_ref.shape[2] // P
 
-    nk = nk_ref[0, :, 0].astype(jnp.float32)  # [Hkv, hd]
+    nk = nk_ref[0, :, 0].astype(jnp.float32)  # [R, W]
     nv = nv_ref[0, :, 0].astype(jnp.float32)
-    q = q_ref[0].astype(jnp.float32)  # [Hkv, G, hd]
+    q = q_ref[0].astype(jnp.float32)  # [R, P*G, W]
     ss = s_ref[0, 0].astype(jnp.float32)  # [2*Hkv, S]
     kss, vss = ss[:Hkv], ss[Hkv:]
 
@@ -459,42 +469,57 @@ def _attend_q8_kernel(
     qsc = jnp.maximum(qa / 127.0, 1e-30)
     q8 = jnp.round(q / qsc[..., None]).astype(jnp.int8)
 
-    kvq = kv_ref[0, 0]  # [2*Hkv, S, hd] int8 — k rows then v rows
+    kvq = kv_ref[0, 0]  # [2*R, S, W] int8 — k rows then v rows
     s_i = jax.lax.dot_general(
         q8,
-        kvq[:Hkv],
+        kvq[:R],
         (((2,), (2,)), ((0,), (0,))),
         preferred_element_type=jnp.int32,
-    )  # [Hkv, G, S]
-    s = s_i.astype(jnp.float32) * (scale * qsc)[..., None] * kss[:, None, :]
+    )  # [R, P*G, S]
+    s = s_i.astype(jnp.float32) * (scale * qsc)[..., None] * _scales_by_row(kss, P, G)
 
     pos = jax.lax.broadcasted_iota(jnp.int32, (1, 1, S), 2)
     at_w, seen = _ring_masks(pos, w, S, window)
     # the tile holds the PRE-append cache — position w's score/value come
     # from the unquantized new vectors instead (exact; the quantized row
     # scatters into the cache outside the kernel)
-    s_new = jnp.sum(q * nk[:, None, :], axis=-1, keepdims=True) * scale  # [Hkv, G, 1]
+    s_new = jnp.sum(q * nk[:, None, :], axis=-1, keepdims=True) * scale  # [R, P*G, 1]
     s = jnp.where(at_w(), s_new, s)
     s = jnp.where(seen(), s, NEG_INF)
 
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    p_w = jnp.sum(jnp.where(at_w(), p, 0.0), axis=-1, keepdims=True)  # [Hkv, G, 1]
+    p_w = jnp.sum(jnp.where(at_w(), p, 0.0), axis=-1, keepdims=True)  # [R, P*G, 1]
     # fold v's dequant scales into the probs, then quantize the prob rows so
     # the PV dot also runs s8 x s8 on the MXU
-    pv = jnp.where(at_w(), 0.0, p * vss[:, None, :])  # [Hkv, G, S]
-    pa = jnp.max(pv, axis=-1)  # [Hkv, G]
+    pv = jnp.where(at_w(), 0.0, p * _scales_by_row(vss, P, G))  # [R, P*G, S]
+    pa = jnp.max(pv, axis=-1)  # [R, P*G]
     psc = jnp.maximum(pa / 127.0, 1e-30)
     p8 = jnp.round(pv / psc[..., None]).astype(jnp.int8)
     ctx_i = jax.lax.dot_general(
         p8,
-        kvq[Hkv:],
+        kvq[R:],
         (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.int32,
-    )  # [Hkv, G, hd]
+    )  # [R, P*G, W]
     ctx = ctx_i.astype(jnp.float32) * psc[..., None] + p_w * nv[:, None, :]
     o_ref[0] = (ctx / l).astype(o_ref.dtype)
+
+
+def _scales_by_row(ss, abreast: int, group: int):
+    """Dequant scales [Hkv, N] of the heads, as they multiply the scores
+    [Hkv / P, P*G, N] of P heads abreast: head p*R + r lies in cache row r
+    (`kv_abreast`) and its G query rows are rows [p*G, (p+1)*G) there. P = 1:
+    [Hkv, 1, N], a head a row."""
+    if abreast == 1:
+        return ss[:, None, :]
+    R = ss.shape[0] // abreast
+    row = jax.lax.broadcasted_iota(jnp.int32, (1, abreast * group, 1), 1)
+    out = ss[(abreast - 1) * R :][:, None, :]
+    for p in range(abreast - 2, -1, -1):
+        out = jnp.where(row < (p + 1) * group, ss[p * R : (p + 1) * R][:, None, :], out)
+    return out
 
 
 def _ring_masks(pos, w, S: int, window: int):
@@ -553,15 +578,16 @@ def _attend_q8_blocked_kernel(
     lengths_ref,  # [Ba] int32 (scalar prefetch) — this step's position per row
     cum_ref,  # [Ba + 1] int32 (scalar prefetch) — running sum of the rows'
     #           block counts: row b's cells are cum[b] .. cum[b + 1] - 1
-    q_ref,  # [1, Hkv, G, hd] VMEM
-    nk_ref,  # [1, Hkv, hd] VMEM — this step's K vectors (post-rope)
-    nv_ref,  # [1, Hkv, hd] VMEM
-    pay_hbm,  # [L, B, 2*Hkv + p, S, hd] int8 — fused K|V(|packed scales)
+    q_ref,  # [1, R, P*G, W] VMEM — R = Hkv / P cache rows of P heads abreast,
+    #         W = P*hd (`_attend_q8_kernel` says how the products stay exact)
+    nk_ref,  # [1, R, W] VMEM — this step's K vectors (post-rope)
+    nv_ref,  # [1, R, W] VMEM
+    pay_hbm,  # [L, B, 2*R + p, S, W] int8 — fused K|V(|packed scales)
     #           payload, stays in HBM (ANY), DMA'd per block
     s_hbm,  # [L, B, 2*Hkv, S] — plain scales (read only when packed=False)
-    o_ref,  # [1, Hkv, G, hd] VMEM out
-    pay_buf,  # VMEM scratch [2, Hh, BS, hd] int8 (double buffer);
-    #           Hh = 2*Hkv + 1 when packed else 2*Hkv
+    o_ref,  # [1, R, P*G, W] VMEM out
+    pay_buf,  # VMEM scratch [2, Hh, BS, W] int8 (double buffer);
+    #           Hh = 2*R + 1 when packed else 2*R
     s_buf,  # [2, 2*Hkv, BS] (unused when packed — tiny, kept so both modes
     #        share one scratch list)
     sems,  # DMA semaphores [2, 2]
@@ -595,19 +621,22 @@ def _attend_q8_blocked_kernel(
     One copy a cell where the scales travel with the payload:
 
       packed=True  — ONE copy: K, V and a bit-packed per-position scale
-        pseudo-head travel in the same [2*Hkv+1, BS, hd] int8 block; the
+        pseudo-head travel in the same [2*R+1, BS, W] int8 block; the
         scales are unpacked in VMEM (`_unpack_scale_lanes`).
-      packed=False — TWO copies: the [2*Hkv, BS, hd] payload head-slice
+      packed=False — TWO copies: the [2*R, BS, W] payload head-slice
         plus one [2*Hkv, BS] block of the plain scales array. This is the
-        fallback when the scale bytes don't fit one head row
-        (2*Hkv*itemsize > hd) or LLM_MCP_TPU_Q8_SCALE_PACK=0. A [2*Hkv, BS]
+        fallback when the scale bytes don't fit one cache row
+        (2*Hkv*itemsize > W) or LLM_MCP_TPU_Q8_SCALE_PACK=0. A [2*Hkv, BS]
         slice of the head-major scales array is a (sublane, lane)-tileable
         copy Mosaic accepts.
     """
     b = pl.program_id(0)
     li = li_ref[0]
     BS = block_s
-    _, Hkv, G, hd = q_ref.shape
+    _, R, PG, W = q_ref.shape
+    Hkv = s_buf.shape[1] // 2
+    P = Hkv // R
+    G = PG // P
     n_rows = ids_ref.shape[0]
     row = ids_ref[b]  # cache row for this batch position (compaction)
     next_row = ids_ref[jnp.minimum(b + 1, n_rows - 1)]
@@ -628,7 +657,7 @@ def _attend_q8_blocked_kernel(
             )
         return (
             pltpu.make_async_copy(
-                pay_hbm.at[li, row, pl.ds(0, 2 * Hkv), pl.ds(j * BS, BS), :],
+                pay_hbm.at[li, row, pl.ds(0, 2 * R), pl.ds(j * BS, BS), :],
                 pay_buf.at[slot],
                 sems.at[slot, 0],
             ),
@@ -651,17 +680,17 @@ def _attend_q8_blocked_kernel(
     def _first_cell():  # the one copy nothing runs ahead of
         start(row, 0, 0)
 
-    q = q_ref[0].astype(jnp.float32)  # [Hkv, G, hd]
-    nk = nk_ref[0].astype(jnp.float32)  # [Hkv, hd]
+    q = q_ref[0].astype(jnp.float32)  # [R, P*G, W]
+    nk = nk_ref[0].astype(jnp.float32)  # [R, W]
     nv = nv_ref[0].astype(jnp.float32)
     qa = jnp.max(jnp.abs(q), axis=-1)
     qsc = jnp.maximum(qa / 127.0, 1e-30)
     q8 = jnp.round(q / qsc[..., None]).astype(jnp.int8)
-    s_new = jnp.sum(q * nk[:, None, :], axis=-1, keepdims=True) * scale  # [Hkv,G,1]
+    s_new = jnp.sum(q * nk[:, None, :], axis=-1, keepdims=True) * scale  # [R,P*G,1]
 
-    acc0 = jnp.zeros((Hkv, G, hd), jnp.float32)
-    m0 = jnp.full((Hkv, G, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((Hkv, G, 1), jnp.float32)
+    acc0 = jnp.zeros((R, PG, W), jnp.float32)
+    m0 = jnp.full((R, PG, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((R, PG, 1), jnp.float32)
 
     def body(j, carry):
         acc, m, l = carry
@@ -674,18 +703,18 @@ def _attend_q8_blocked_kernel(
             start(jnp.where(last, next_row, row), jnp.where(last, 0, j + 1), 1 - slot)
 
         wait(row, j, slot)
-        buf = pay_buf[slot]  # [Hh, BS, hd] int8 — k rows, v rows(, scales)
-        k = buf[:Hkv]  # [Hkv, BS, hd] int8
+        buf = pay_buf[slot]  # [Hh, BS, W] int8 — k rows, v rows(, scales)
+        k = buf[:R]  # [R, BS, W] int8
         if packed:
-            ss = _unpack_scale_lanes(buf[2 * Hkv], 2 * Hkv, scale_dtype)
+            ss = _unpack_scale_lanes(buf[2 * R], 2 * Hkv, scale_dtype)
         else:
             ss = s_buf[slot].astype(jnp.float32)
         # ss: [2*Hkv, BS] f32
         kss, vss = ss[:Hkv], ss[Hkv:]
         s_i = jax.lax.dot_general(
             q8, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.int32
-        )  # [Hkv, G, BS]
-        s = s_i.astype(jnp.float32) * (scale * qsc)[..., None] * kss[:, None, :]
+        )  # [R, P*G, BS]
+        s = s_i.astype(jnp.float32) * (scale * qsc)[..., None] * _scales_by_row(kss, P, G)
         pos = j * BS + jax.lax.broadcasted_iota(jnp.int32, (1, 1, BS), 2)
         s = jnp.where(pos == w, s_new, s)
         s = jnp.where(pos <= w, s, NEG_INF)
@@ -695,16 +724,16 @@ def _attend_q8_blocked_kernel(
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
         p_w = jnp.sum(jnp.where(pos == w, p, 0.0), axis=-1, keepdims=True)
-        pv = jnp.where(pos == w, 0.0, p * vss[:, None, :])
+        pv = jnp.where(pos == w, 0.0, p * _scales_by_row(vss, P, G))
         pa = jnp.max(pv, axis=-1)
         psc = jnp.maximum(pa / 127.0, 1e-30)
         p8 = jnp.round(pv / psc[..., None]).astype(jnp.int8)
         ctx_i = jax.lax.dot_general(
             p8,
-            buf[Hkv : 2 * Hkv],
+            buf[R : 2 * R],
             (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.int32,
-        )  # [Hkv, G, hd]
+        )  # [R, P*G, W]
         acc_new = (
             acc * alpha + ctx_i.astype(jnp.float32) * psc[..., None] + p_w * nv[:, None, :]
         )
@@ -720,15 +749,15 @@ def _attend_q8_paged_kernel(
     tbl_ref,  # [Ba * nbs] int32 (scalar prefetch) — flattened per-row block
     #          tables: physical block id per logical block (already gathered
     #          to the compact batch; arena homes < pool_base, pool rows >=)
-    q_ref,  # [1, Hkv, G, hd] VMEM
-    nk_ref,  # [1, Hkv, 1, hd] VMEM
-    nv_ref,  # [1, Hkv, 1, hd] VMEM
-    pay_hbm,  # [L, B, 2*Hkv + p, S, hd] int8 — slot arena (identity homes)
+    q_ref,  # [1, R, P*G, W] VMEM — R = Hkv / P cache rows of P heads abreast
+    nk_ref,  # [1, R, 1, W] VMEM
+    nv_ref,  # [1, R, 1, W] VMEM
+    pay_hbm,  # [L, B, 2*R + p, S, W] int8 — slot arena (identity homes)
     s_hbm,  # [L, B, 2*Hkv, S] — arena plain scales (packed=False only)
-    pool_pay_hbm,  # [L, PXB, 2*Hkv + p, bt, hd] int8 — prefix block pool
+    pool_pay_hbm,  # [L, PXB, 2*R + p, bt, W] int8 — prefix block pool
     pool_s_hbm,  # [L, PXB, 2*Hkv, bt] — pool plain scales
-    o_ref,  # [1, Hkv, G, hd] VMEM out
-    pay_buf,  # VMEM scratch [2, Hh, BS, hd] int8 (double buffer)
+    o_ref,  # [1, R, P*G, W] VMEM out
+    pay_buf,  # VMEM scratch [2, Hh, BS, W] int8 (double buffer)
     s_buf,  # [2, 2*Hkv, BS]
     sems,  # DMA semaphores [2, 2]
     *,
@@ -754,7 +783,10 @@ def _attend_q8_paged_kernel(
     li = li_ref[0]
     w = lengths_ref[b]
     BS = block_s
-    Hkv = q_ref.shape[1]
+    _, R, PG, W = q_ref.shape
+    Hkv = s_buf.shape[1] // 2
+    P = Hkv // R
+    G = PG // P
     nbs = seq_len // BS
     pool_base = pay_hbm.shape[1] * nbs
     nblk = jnp.clip((w + BS) // BS, 1, nbs)
@@ -775,7 +807,7 @@ def _attend_q8_paged_kernel(
             )
         return (
             pltpu.make_async_copy(
-                pay_hbm.at[li, arow, pl.ds(0, 2 * Hkv), pl.ds(aoff, BS), :],
+                pay_hbm.at[li, arow, pl.ds(0, 2 * R), pl.ds(aoff, BS), :],
                 pay_buf.at[slot],
                 sems.at[slot, 0],
             ),
@@ -796,7 +828,7 @@ def _attend_q8_paged_kernel(
             )
         return (
             pltpu.make_async_copy(
-                pool_pay_hbm.at[li, prow, pl.ds(0, 2 * Hkv)],
+                pool_pay_hbm.at[li, prow, pl.ds(0, 2 * R)],
                 pay_buf.at[slot],
                 sems.at[slot, 0],
             ),
@@ -821,19 +853,17 @@ def _attend_q8_paged_kernel(
 
     issue(0, 0, "start")
 
-    q = q_ref[0].astype(jnp.float32)  # [Hkv, G, hd]
-    nk = nk_ref[0, :, 0].astype(jnp.float32)  # [Hkv, hd]
+    q = q_ref[0].astype(jnp.float32)  # [R, P*G, W]
+    nk = nk_ref[0, :, 0].astype(jnp.float32)  # [R, W]
     nv = nv_ref[0, :, 0].astype(jnp.float32)
     qa = jnp.max(jnp.abs(q), axis=-1)
     qsc = jnp.maximum(qa / 127.0, 1e-30)
     q8 = jnp.round(q / qsc[..., None]).astype(jnp.int8)
-    s_new = jnp.sum(q * nk[:, None, :], axis=-1, keepdims=True) * scale  # [Hkv,G,1]
+    s_new = jnp.sum(q * nk[:, None, :], axis=-1, keepdims=True) * scale  # [R,P*G,1]
 
-    G = q_ref.shape[2]
-    hd = q_ref.shape[3]
-    acc0 = jnp.zeros((Hkv, G, hd), jnp.float32)
-    m0 = jnp.full((Hkv, G, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((Hkv, G, 1), jnp.float32)
+    acc0 = jnp.zeros((R, PG, W), jnp.float32)
+    m0 = jnp.full((R, PG, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((R, PG, 1), jnp.float32)
 
     def body(j, carry):
         acc, m, l = carry
@@ -844,18 +874,18 @@ def _attend_q8_paged_kernel(
             issue(j + 1, 1 - slot, "start")
 
         issue(j, slot, "wait")
-        buf = pay_buf[slot]  # [Hh, BS, hd] int8 — k rows, v rows(, scales)
-        k = buf[:Hkv]  # [Hkv, BS, hd] int8
+        buf = pay_buf[slot]  # [Hh, BS, W] int8 — k rows, v rows(, scales)
+        k = buf[:R]  # [R, BS, W] int8
         if packed:
-            ss = _unpack_scale_lanes(buf[2 * Hkv], 2 * Hkv, scale_dtype)
+            ss = _unpack_scale_lanes(buf[2 * R], 2 * Hkv, scale_dtype)
         else:
             ss = s_buf[slot].astype(jnp.float32)
         # ss: [2*Hkv, BS] f32
         kss, vss = ss[:Hkv], ss[Hkv:]
         s_i = jax.lax.dot_general(
             q8, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.int32
-        )  # [Hkv, G, BS]
-        s = s_i.astype(jnp.float32) * (scale * qsc)[..., None] * kss[:, None, :]
+        )  # [R, P*G, BS]
+        s = s_i.astype(jnp.float32) * (scale * qsc)[..., None] * _scales_by_row(kss, P, G)
         pos = j * BS + jax.lax.broadcasted_iota(jnp.int32, (1, 1, BS), 2)
         s = jnp.where(pos == w, s_new, s)
         s = jnp.where(pos <= w, s, NEG_INF)
@@ -865,16 +895,16 @@ def _attend_q8_paged_kernel(
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
         p_w = jnp.sum(jnp.where(pos == w, p, 0.0), axis=-1, keepdims=True)
-        pv = jnp.where(pos == w, 0.0, p * vss[:, None, :])
+        pv = jnp.where(pos == w, 0.0, p * _scales_by_row(vss, P, G))
         pa = jnp.max(pv, axis=-1)
         psc = jnp.maximum(pa / 127.0, 1e-30)
         p8 = jnp.round(pv / psc[..., None]).astype(jnp.int8)
         ctx_i = jax.lax.dot_general(
             p8,
-            buf[Hkv : 2 * Hkv],
+            buf[R : 2 * R],
             (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.int32,
-        )  # [Hkv, G, hd]
+        )  # [R, P*G, W]
         acc_new = (
             acc * alpha + ctx_i.astype(jnp.float32) * psc[..., None] + p_w * nv[:, None, :]
         )
@@ -932,14 +962,14 @@ def paged_gather(arena, pool, tables, *, nbs=None):
 Q8_BLOCK_BYTES_MAX = 1 << 20
 
 
-def blocked_arm_fits(head_dim: int, interp: bool) -> bool:
-    """Whether the blocked q8 arm can run: its copies cut blocks of whole
-    128-lane rows out of HBM, where a narrower head lies padded to 128 (Mosaic:
-    "slice shape along dimension 4 must be aligned to tiling (128), but is 64",
-    seen in the described-chip compile). On the chip such a cache takes the
-    whole-S arm, whose tiles the pipeline copies; interpret mode has no tiling
-    and keeps the blocked arm for the parity tests."""
-    return interp or head_dim % 128 == 0
+def blocked_arm_fits(row_lanes: int, interp: bool) -> bool:
+    """Whether the blocked q8 arm can run on a cache whose rows are `row_lanes`
+    wide (P*hd): its copies cut blocks of whole 128-lane rows out of HBM.
+    Every cache `init_kv_cache` makes of heads that divide the lanes has such
+    rows (`kv_heads_abreast`); one that has not (a head of 96, an odd count of
+    heads of 64) lies padded in HBM and takes the whole-S arm, whose tiles the
+    pipeline copies. Interpret mode has no tiling."""
+    return interp or row_lanes % LANES == 0
 
 
 def q8_block_tokens(payload_heads: int, seq_len: int, head_dim: int) -> int:
@@ -973,13 +1003,17 @@ class AttnStream:
     last `window` (`max_seq_len`: the length at which a row is parked, the
     full-length cache's)."""
 
-    def __init__(self, cache_q_shape: tuple[int, ...], window: int = 0, max_seq_len: int = 0):
-        _, _, heads, self.seq_len, head_dim = cache_q_shape
+    def __init__(self, cache_q_shape: tuple[int, ...], window: int = 0, max_seq_len: int = 0,
+                 kv_heads: int = 0):
+        _, _, rows, self.seq_len, row_lanes = cache_q_shape
         self.window = window
         self.parked_at = max_seq_len or self.seq_len
+        # P: heads abreast in a payload row (`fused_q8_heads`' rule, from the
+        # configuration's KV heads; without them, a head a row)
+        self.heads_abreast = _payload_rows(rows, 2 * kv_heads)[1] if kv_heads else 1
         # 0: the whole-S arm alone runs here, and streams every row in full
-        self.block_tokens = (q8_block_tokens(heads, self.seq_len, head_dim)
-                             if not window and blocked_arm_fits(head_dim, _interpret()) else 0)
+        self.block_tokens = (q8_block_tokens(rows, self.seq_len, row_lanes)
+                             if not window and blocked_arm_fits(row_lanes, _interpret()) else 0)
         self.steps = self.tokens_streamed = self.tokens_live = 0
 
     def dispatched(self, lengths: np.ndarray, steps: int) -> None:
@@ -997,19 +1031,98 @@ class AttnStream:
         self.tokens_live += int(np.where(w < self.parked_at, live, 0).sum())
 
     def stats(self) -> dict:
-        return {"block_tokens": self.block_tokens, "steps": self.steps,
+        return {"block_tokens": self.block_tokens, "heads_abreast": self.heads_abreast,
+                "steps": self.steps,
                 "tokens_streamed": self.tokens_streamed, "tokens_live": self.tokens_live,
                 "live_over_streamed": round(self.tokens_live / self.tokens_streamed, 4)
                 if self.tokens_streamed else None,
                 **({"window": self.window, "ring_tokens": self.seq_len} if self.window else {})}
 
 
-def fused_q8_heads(cache_k: dict) -> tuple[int, int]:
-    """(Hkv, p) of a FUSED int8 GQA cache: the payload carries 2*Hkv K|V
-    heads plus p ∈ {0, 1} packed-scale pseudo-heads; the plain "s" array
-    always has exactly 2*Hkv."""
+def kv_heads_abreast(n_kv_heads: int, head_dim: int) -> int:
+    """P: KV heads that lie side by side in one row of the fused int8 cache.
+    Where a head is narrower than the 128 lanes and divides them, as many as
+    fill them, so that the cache's minor dimension is whole lanes and the
+    chip's layout of it is the kernels' (a minor dimension of 64 is laid out
+    with positions minor, and every step program copied the whole cache to the
+    kernels' layout and back: PERF.md section 6, PR 55); 1 where the heads are
+    128 wide or more, or P does not divide them. A function of the shape
+    alone, as `kernels/kda.py:heads_abreast` is of the state pool's."""
+    P = LANES // head_dim if head_dim < LANES and LANES % head_dim == 0 else 1
+    return P if n_kv_heads % P == 0 else 1
+
+
+def kv_abreast(x: jnp.ndarray, abreast: int) -> jnp.ndarray:
+    """[..., H, S, hd] -> [..., H / P, S, P*hd]: head p*R + r (R = H / P) in
+    lanes [p*hd, (p+1)*hd) of row r, so that the heads a row holds are R
+    apart and a contiguous run of R heads (their scales with them) is one
+    lane group of every row."""
+    if abreast == 1:
+        return x
+    *lead, H, S, hd = x.shape
+    x = x.reshape(*lead, abreast, H // abreast, S, hd)
+    return jnp.moveaxis(x, -4, -2).reshape(*lead, H // abreast, S, abreast * hd)
+
+
+def kv_apart(x: jnp.ndarray, abreast: int) -> jnp.ndarray:
+    """[..., H / P, S, P*hd] -> [..., H, S, hd]: `kv_abreast`'s inverse."""
+    if abreast == 1:
+        return x
+    *lead, R, S, W = x.shape
+    x = x.reshape(*lead, R, S, abreast, W // abreast)
+    return jnp.moveaxis(x, -2, -4).reshape(*lead, R * abreast, S, W // abreast)
+
+
+def fused_kv(pay: jnp.ndarray, n_kv_heads: int, abreast: int):
+    """(K, V) int8 [..., Hkv, S, hd] out of payload rows [..., 2*Hkv/P (+ p),
+    S, P*hd] of the fused cache: what every reader outside the kernels takes."""
+    R = n_kv_heads // abreast
+    return kv_apart(pay[..., :R, :, :], abreast), kv_apart(pay[..., R : 2 * R, :, :], abreast)
+
+
+def q_abreast(q: jnp.ndarray, abreast: int) -> jnp.ndarray:
+    """Queries [..., Hkv, G, hd] -> [..., Hkv / P, P*G, P*hd] for K/V rows of
+    P heads abreast: head p*R + r's G rows are rows [p*G, (p+1)*G) of row r,
+    in that head's lanes, zeros in the others'. A product with a row of P
+    heads over all its lanes is then each head's own, exactly (the zeros add
+    nothing), and no reader has to pull the heads of a row apart."""
+    if abreast == 1:
+        return q
+    *lead, Hkv, G, hd = q.shape
+    R, n = Hkv // abreast, len(lead)
+    rows = jnp.moveaxis(q.reshape(*lead, abreast, R, G, hd), n, n + 1)  # [..., R, P, G, hd]
+    own = jnp.eye(abreast, dtype=bool)[:, None, :, None]  # [P, 1, P, 1]
+    return jnp.where(own, rows[..., None, :], 0).reshape(*lead, R, abreast * G, abreast * hd)
+
+
+def ctx_apart(ctx: jnp.ndarray, abreast: int) -> jnp.ndarray:
+    """Contexts [..., Hkv / P, P*G, P*hd] of `q_abreast`'s rows against V rows
+    of P heads abreast -> [..., Hkv, G, hd]: of a query group's P*hd output
+    lanes, its own head's."""
+    if abreast == 1:
+        return ctx
+    *lead, R, PG, W = ctx.shape
+    G, hd, n = PG // abreast, W // abreast, len(lead)
+    ctx = ctx.reshape(*lead, R, abreast, G, abreast, hd)
+    own = jnp.stack([ctx[..., p, :, p, :] for p in range(abreast)], axis=n)  # [..., P, R, G, hd]
+    return own.reshape(*lead, abreast * R, G, hd)
+
+
+def fused_q8_heads(cache_k: dict) -> tuple[int, int, int]:
+    """(Hkv, p, P) of a FUSED int8 GQA cache, from its two members' shapes: the
+    plain "s" array always has exactly 2*Hkv rows; the payload carries
+    2*Hkv / P rows of P heads abreast (`kv_heads_abreast`: P divides Hkv, so
+    their count is even) plus p in {0, 1} packed-scale pseudo-heads."""
     Hs = cache_k["s"].shape[2]
-    return Hs // 2, cache_k["q"].shape[2] - Hs
+    return (Hs // 2, *_payload_rows(cache_k["q"].shape[2], Hs))
+
+
+def _payload_rows(rows: int, scale_rows: int) -> tuple[int, int]:
+    """(p, P) of a payload of `rows` rows beside 2*Hkv = `scale_rows` plain
+    scale rows: the K and V rows are an even count, so an odd one holds the
+    pseudo-head, and P is how many heads each of the others holds."""
+    p = rows % 2
+    return p, scale_rows // (rows - p)
 
 
 def _decode_attend_q8_fallback(
@@ -1025,7 +1138,7 @@ def _decode_attend_q8_fallback(
     kernels and the CPU serve path under physical paging."""
     del cache_v
     S = cache_k["q"].shape[3]
-    Hkv, _ = fused_q8_heads(cache_k)
+    Hkv, _, P = fused_q8_heads(cache_k)
     pay = jax.lax.dynamic_index_in_dim(cache_k["q"], layer, 0, keepdims=False)
     ss = jax.lax.dynamic_index_in_dim(cache_k["s"], layer, 0, keepdims=False)
     if block_tables is not None:
@@ -1041,7 +1154,7 @@ def _decode_attend_q8_fallback(
     elif slot_ids is not None:
         pay = jnp.take(pay, slot_ids, 0)
         ss = jnp.take(ss, slot_ids, 0)
-    kf, vf = pay[:, :Hkv], pay[:, Hkv : 2 * Hkv]
+    kf, vf = fused_kv(pay, Hkv, P)
     kss, vss = ss[:, :Hkv], ss[:, Hkv:]
     qf = q.astype(jnp.float32) * sc
     s = jnp.einsum("bhgd,bhsd->bhgs", qf, kf.astype(jnp.float32)) * kss.astype(
@@ -1065,7 +1178,7 @@ def decode_attend_q8(
     q: jnp.ndarray,  # [Ba, Hkv, G, hd] — COMPACT batch (active rows only)
     new_k: jnp.ndarray,  # [Ba, Hkv, hd] — post-rope K for this step
     new_v: jnp.ndarray,  # [Ba, Hkv, hd]
-    cache_k: dict,  # FUSED: {"q": int8 [L,B,2*Hkv+p,S,hd], "s": [L,B,2*Hkv,S]}
+    cache_k: dict,  # FUSED: {"q": int8 [L,B,2*Hkv/P+p,S,P*hd], "s": [L,B,2*Hkv,S]}
     cache_v: dict,  # {} — V rides cache_k's head axis (layout invariant)
     layer: jnp.ndarray,  # scalar int32
     lengths: jnp.ndarray,  # [Ba] int32 — this step's position per row
@@ -1074,7 +1187,7 @@ def decode_attend_q8(
     block_tables: jnp.ndarray | None = None,  # [n_slots, nbs] int32 physical
     #   block tables (executor/physical.py); None = contiguous layout
     pool_k: dict | None = None,  # prefix pool mirroring cache_k's structure:
-    #   {"q": int8 [L,PXB,2*Hkv+p,bt,hd], "s": [L,PXB,2*Hkv,bt]}
+    #   {"q": int8 [L,PXB,2*Hkv/P+p,bt,P*hd], "s": [L,PXB,2*Hkv,bt]}
     scale: float = 0.0,  # query scale (0 = head_dim**-0.5)
     interpret: bool | None = None,
     block_s: int | None = None,  # the blocked arm's block; None = the rule
@@ -1114,13 +1227,16 @@ def decode_attend_q8(
     S = cache_k["q"].shape[3]
     interp = _interpret() if interpret is None else interpret
     sc = scale or hd**-0.5
-    _, p = fused_q8_heads(cache_k)
+    _, p, P = fused_q8_heads(cache_k)
+    # P heads abreast in a cache row of W lanes: R rows of K, R of V
+    R, PG, W = Hkv // P, P * G, cache_k["q"].shape[4]
+    assert W == P * hd, (cache_k["q"].shape, hd)
 
-    nk4 = new_k.reshape(B, Hkv, 1, hd)
-    nv4 = new_v.reshape(B, Hkv, 1, hd)
+    nk4 = kv_abreast(new_k.reshape(B, Hkv, 1, hd), P)  # [B, R, 1, W]
+    nv4 = kv_abreast(new_v.reshape(B, Hkv, 1, hd), P)
     can_whole = S <= decode_pallas_max_seq(hd, Hkv, Hkv * G, quantized=True)
-    BS = block_s or q8_block_tokens(2 * Hkv + p, S, hd)
-    if not blocked_arm_fits(hd, interp):
+    BS = block_s or q8_block_tokens(2 * R + p, S, W)
+    if not blocked_arm_fits(W, interp):
         BS = 0
     if window and (not can_whole or S & (S - 1) or S < window or block_tables is not None):
         raise NotImplementedError(f"a ring of {S} positions for a window of {window}")
@@ -1143,13 +1259,14 @@ def decode_attend_q8(
         jnp.reshape(layer, (1,)).astype(jnp.int32),
         ids,
         lengths.astype(jnp.int32),
-        q,
+        q_abreast(q, P),
         nk4,
         nv4,
         cache_k["q"],
         cache_k["s"],
     )
-    out_shape = jax.ShapeDtypeStruct((B, Hkv, G, hd), q.dtype)
+    qw = args[3]  # [B, R, P*G, W]
+    out_shape = jax.ShapeDtypeStruct((B, R, PG, W), q.dtype)
 
     def run_whole():
         # whole-S tiles fit VMEM: one payload + one scales DMA per cell,
@@ -1161,14 +1278,14 @@ def decode_attend_q8(
             num_scalar_prefetch=3,  # layer [1], slot ids [Ba], lengths [Ba]
             grid=(B,),
             in_specs=[
-                pl.BlockSpec((1, Hkv, G, hd), lambda b, li, ids, lens: (b, 0, 0, 0)),
-                pl.BlockSpec((1, Hkv, 1, hd), lambda b, li, ids, lens: (b, 0, 0, 0)),
-                pl.BlockSpec((1, Hkv, 1, hd), lambda b, li, ids, lens: (b, 0, 0, 0)),
+                pl.BlockSpec((1, R, PG, W), lambda b, li, ids, lens: (b, 0, 0, 0)),
+                pl.BlockSpec((1, R, 1, W), lambda b, li, ids, lens: (b, 0, 0, 0)),
+                pl.BlockSpec((1, R, 1, W), lambda b, li, ids, lens: (b, 0, 0, 0)),
                 # cache tiles follow the compaction indirection: batch cell b
                 # reads cache row ids[b]. Head-block index 0 of the ragged
-                # (2*Hkv + p) head axis covers exactly the 2*Hkv payload rows.
+                # (2*R + p) head axis covers exactly the 2*R payload rows.
                 pl.BlockSpec(
-                    (1, 1, 2 * Hkv, S, hd),
+                    (1, 1, 2 * R, S, W),
                     lambda b, li, ids, lens: (li[0], ids[b], 0, 0, 0),
                 ),
                 pl.BlockSpec(
@@ -1176,13 +1293,13 @@ def decode_attend_q8(
                 ),
             ],
             out_specs=pl.BlockSpec(
-                (1, Hkv, G, hd), lambda b, li, ids, lens: (b, 0, 0, 0)
+                (1, R, PG, W), lambda b, li, ids, lens: (b, 0, 0, 0)
             ),
         )
-        return pl.pallas_call(
+        return ctx_apart(pl.pallas_call(
             kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp,
             name="decode_attn_win_q8" if window else "decode_attn_q8_whole",
-        )(*args)
+        )(*args), P)
 
     def run_blocked():
         # rows stream blockwise from HBM with a dynamic trip count — no
@@ -1190,8 +1307,11 @@ def decode_attend_q8(
         # read. The batch's (row, block) cells are one pipeline (see the
         # kernel): their order and count are a running sum of the rows'
         # block counts, made here from `lengths` and prefetched as scalars.
-        Hh = 2 * Hkv + 1 if packed else 2 * Hkv
+        Hh = 2 * R + 1 if packed else 2 * R
         li, _, lens = args[:3]
+        # [B, R, W]; at P = 1 the operands as they came, so that the program is
+        # the one it was (a reshape there and back is another text)
+        nk3, nv3 = (new_k, new_v) if P == 1 else (nk4[:, :, 0], nv4[:, :, 0])
         cum = jnp.concatenate(
             [jnp.zeros((1,), jnp.int32), jnp.cumsum(blocked_row_blocks(lens, S, BS))]
         ).astype(jnp.int32)
@@ -1206,32 +1326,32 @@ def decode_attend_q8(
             num_scalar_prefetch=4,  # layer [1], slot ids, lengths, cells [Ba + 1]
             grid=(B,),
             in_specs=[
-                pl.BlockSpec((1, Hkv, G, hd), lambda b, *_: (b, 0, 0, 0)),
-                pl.BlockSpec((1, Hkv, hd), lambda b, *_: (b, 0, 0)),
-                pl.BlockSpec((1, Hkv, hd), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec((1, R, PG, W), lambda b, *_: (b, 0, 0, 0)),
+                pl.BlockSpec((1, R, W), lambda b, *_: (b, 0, 0)),
+                pl.BlockSpec((1, R, W), lambda b, *_: (b, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),  # fused payload
                 pl.BlockSpec(memory_space=pl.ANY),  # plain scales
             ],
-            out_specs=pl.BlockSpec((1, Hkv, G, hd), lambda b, *_: (b, 0, 0, 0)),
+            out_specs=pl.BlockSpec((1, R, PG, W), lambda b, *_: (b, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, Hh, BS, hd), jnp.int8),
+                pltpu.VMEM((2, Hh, BS, W), jnp.int8),
                 pltpu.VMEM((2, 2 * Hkv, BS), cache_k["s"].dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
             ],
         )
-        return pl.pallas_call(
+        return ctx_apart(pl.pallas_call(
             kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp,
             name="decode_attn_q8_blocked",
             # sequential: a cell's copy is started in the grid step before its own
             compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
-        )(li, ids, lens, cum, q, new_k, new_v, cache_k["q"], cache_k["s"])
+        )(li, ids, lens, cum, qw, nk3, nv3, cache_k["q"], cache_k["s"]), P)
 
     def run_paged():
         # block-indirect arm: BS is pinned to the ledger's block_tokens so
         # table entry j covers exactly the kernel's block j
         nbs = block_tables.shape[1]
         bt = S // nbs
-        Hh = 2 * Hkv + 1 if packed else 2 * Hkv
+        Hh = 2 * R + 1 if packed else 2 * R
         tblf = jnp.take(block_tables, ids, 0).reshape(-1).astype(jnp.int32)
         kernel = functools.partial(
             _attend_q8_paged_kernel,
@@ -1245,38 +1365,38 @@ def decode_attend_q8(
             num_scalar_prefetch=3,  # layer [1], lengths [Ba], tables [Ba*nbs]
             grid=(B,),
             in_specs=[
-                pl.BlockSpec((1, Hkv, G, hd), lambda b, li, lens, tbl: (b, 0, 0, 0)),
-                pl.BlockSpec((1, Hkv, 1, hd), lambda b, li, lens, tbl: (b, 0, 0, 0)),
-                pl.BlockSpec((1, Hkv, 1, hd), lambda b, li, lens, tbl: (b, 0, 0, 0)),
+                pl.BlockSpec((1, R, PG, W), lambda b, li, lens, tbl: (b, 0, 0, 0)),
+                pl.BlockSpec((1, R, 1, W), lambda b, li, lens, tbl: (b, 0, 0, 0)),
+                pl.BlockSpec((1, R, 1, W), lambda b, li, lens, tbl: (b, 0, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),  # fused payload (arena)
                 pl.BlockSpec(memory_space=pl.ANY),  # plain scales (arena)
                 pl.BlockSpec(memory_space=pl.ANY),  # fused payload (pool)
                 pl.BlockSpec(memory_space=pl.ANY),  # plain scales (pool)
             ],
             out_specs=pl.BlockSpec(
-                (1, Hkv, G, hd), lambda b, li, lens, tbl: (b, 0, 0, 0)
+                (1, R, PG, W), lambda b, li, lens, tbl: (b, 0, 0, 0)
             ),
             scratch_shapes=[
-                pltpu.VMEM((2, Hh, bt, hd), jnp.int8),
+                pltpu.VMEM((2, Hh, bt, W), jnp.int8),
                 pltpu.VMEM((2, 2 * Hkv, bt), cache_k["s"].dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
             ],
         )
-        return pl.pallas_call(
+        return ctx_apart(pl.pallas_call(
             kernel, grid_spec=grid_spec, out_shape=out_shape, interpret=interp,
             name="decode_attn_q8_paged",
         )(
             jnp.reshape(layer, (1,)).astype(jnp.int32),
             lengths.astype(jnp.int32),
             tblf,
-            q,
+            qw,
             nk4,
             nv4,
             cache_k["q"],
             cache_k["s"],
             pool_k["q"],
             pool_k["s"],
-        )
+        ), P)
 
     mode = os.environ.get("LLM_MCP_TPU_Q8_DECODE", "auto")
 
@@ -2570,15 +2690,14 @@ def _append_q8_kernel(
     ids_ref,  # [Ba] int32 (scalar prefetch) — cache row per batch position
     #          (consumed by the BlockSpec index maps only: grid cell b's
     #          cache tiles are selected at row ids[b], the body never reads it)
-    pay_ref,  # [L, 1, Hf, hd] int8 — this step's FUSED row: quantized K
-    #           heads, V heads, packed-scale bytes (built by append_kv_q8
-    #           in plain JAX — the kernel only selects, never quantizes);
-    #           [L, 1, Hf, BSQ, hd], the row repeated down the tile, where a
-    #           head is narrower than the 128 lanes (`_down_the_tile`)
+    pay_ref,  # [L, 1, Hf, W] int8 — this step's FUSED row in the cache's own
+    #           form (`_q8_step_rows`): quantized K heads, V heads, P abreast
+    #           in rows of W lanes, packed-scale bytes (built by append_kv_q8
+    #           in plain JAX — the kernel only selects, never quantizes)
     s_ref,  # [L, 1, 2*Hkv, BSS] — this step's plain dequant scales, already
     #         broadcast along the lane tile (a [L, 1, 2*Hkv] block has a
     #         second-to-last dim of 1 over Ba: no legal TPU tile)
-    cq_ref,  # [L, 1, Hf, BSQ, hd] int8 — payload tile containing position w
+    cq_ref,  # [L, 1, Hf, BSQ, W] int8 — payload tile containing position w
     cs_ref,  # [L, 1, 2*Hkv, BSS] — scales tile containing position w
     oq_ref,  # outputs — aliased to the cache operands
     os_ref,
@@ -2595,8 +2714,7 @@ def _append_q8_kernel(
 
     rows = jax.lax.broadcasted_iota(jnp.int32, (1, 1, block_q, 1), 2)  # [1,1,BSQ,1]
     hit = live & (rows == wq)
-    pay = pay_ref[:, 0]
-    oq_ref[:, 0] = jnp.where(hit, pay if pay.ndim == 4 else pay[:, :, None, :], cq_ref[:, 0])
+    oq_ref[:, 0] = jnp.where(hit, pay_ref[:, 0][:, :, None, :], cq_ref[:, 0])
     lanes = jax.lax.broadcasted_iota(jnp.int32, (1, 1, block_s), 2)  # [1,1,BSS]
     hit_s = live & (lanes == ws)
     os_ref[:, 0] = jnp.where(hit_s, s_ref[:, 0].astype(os_ref.dtype), cs_ref[:, 0])
@@ -2604,31 +2722,38 @@ def _append_q8_kernel(
 
 def _down_the_tile(x: jnp.ndarray, block: int) -> jnp.ndarray:
     """A step's rows [L, Ba, heads, hd] repeated down a cache tile's `block`
-    positions, [L, Ba, heads, block, hd]: what the append kernels select from
+    positions, [L, Ba, heads, block, hd]: what `append_kv_bf16` selects from
     where a head is narrower than the 128 lanes. Mosaic has no form of the
     in-kernel broadcast [heads, hd] -> [heads, 1, hd] at 64 lanes ("unsupported
     shape cast", seen in the described-chip compile), so the repeat is made
     outside, a few MB a step at head size 64, and the kernel's select is
-    between two tiles of one shape."""
+    between two tiles of one shape. (The int8 cache has no such rows: its heads
+    lie abreast, `kv_heads_abreast`.)"""
     return jnp.broadcast_to(x[:, :, :, None, :], (*x.shape[:3], block, x.shape[-1]))
 
 
 def _q8_step_rows(cache_k: dict, new_k, new_v):
-    """One decode step's K/V [L, Ba, Hkv, hd] in the FUSED cache's own form:
-    (payload [L, Ba, Hf, hd] int8 — K heads | V heads | packed-scale bytes,
-    plain scales [L, Ba, 2*Hkv])."""
+    """One decode step's K/V [..., Ba, Hkv, hd] in the FUSED cache's own form:
+    (payload [..., Ba, Hf, W] int8 — K heads | V heads, P abreast as the cache
+    holds them, | packed-scale bytes; plain scales [..., Ba, 2*Hkv]). The same
+    bytes `fuse_prompt_kv` makes of a prompt's rows."""
     from ..models.llama import quantize_kv  # local import: avoid cycle
     from ..models.quant import pack_scales
 
-    hd = cache_k["q"].shape[-1]
+    W = cache_k["q"].shape[-1]
+    _, p, P = fused_q8_heads(cache_k)
     sdt = cache_k["s"].dtype
     kq = quantize_kv(new_k, scale_dtype=sdt)
     vq = quantize_kv(new_v, scale_dtype=sdt)
-    s_new = jnp.concatenate([kq["s"], vq["s"]], axis=2)
-    pay = jnp.concatenate([kq["q"], vq["q"]], axis=2)
-    if cache_k["q"].shape[2] > cache_k["s"].shape[2]:
-        # the packed pseudo-head row for this position: [L, Ba, 1, hd]
-        pay = jnp.concatenate([pay, pack_scales(s_new[..., None], hd)[..., 0, :]], 2)
+    s_new = jnp.concatenate([kq["s"], vq["s"]], axis=-1)
+
+    def rows(x):  # [..., Hkv, hd] -> [..., Hkv / P, W]
+        return x if P == 1 else kv_abreast(x[..., None, :], P)[..., 0, :]
+
+    pay = jnp.concatenate([rows(kq["q"]), rows(vq["q"])], axis=-2)
+    if p:
+        # the packed pseudo-head row for this position: [..., Ba, 1, W]
+        pay = jnp.concatenate([pay, pack_scales(s_new[..., None], W)[..., 0, :]], -2)
     return pay, s_new
 
 
@@ -2658,7 +2783,7 @@ def append_kv_q8_reference(cache_k, cache_v, new_k, new_v, lengths, slot_ids=Non
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def append_kv_q8(
-    cache_k: dict,  # FUSED: {"q": int8 [L,B,2*Hkv+p,S,hd], "s": [L,B,2*Hkv,S]}
+    cache_k: dict,  # FUSED: {"q": int8 [L,B,2*Hkv/P+p,S,P*hd], "s": [L,B,2*Hkv,S]}
     cache_v: dict,  # {} — passed through untouched
     new_k: jnp.ndarray,  # [L, Ba, Hkv, hd] — post-rope K for this step, all layers
     new_v: jnp.ndarray,
@@ -2681,10 +2806,10 @@ def append_kv_q8(
     Quantization AND scale-packing happen outside the kernel in plain JAX
     on the tiny [L, Ba, Hkv, hd] step tensors (the bitcast lane-packing of
     `pack_scales` has no proven in-kernel store form; the kernel body only
-    selects rows), producing one fused [L, Ba, Hf, hd] row per slot whose
+    selects rows), producing one fused [L, Ba, Hf, W] row per slot whose
     bytes are written in a single aliased tile pass.
     """
-    L, B, Hf, S, hd = cache_k["q"].shape
+    L, B, Hf, S, W = cache_k["q"].shape
     Hs = cache_k["s"].shape[2]
     Ba = new_k.shape[1]
     interp = _interpret() if interpret is None else interpret
@@ -2695,21 +2820,17 @@ def append_kv_q8(
     )
     pay, s_new = _q8_step_rows(cache_k, new_k, new_v)
 
-    # mosaic int8 stores want rows of 128 lanes, or of 64 (a head of 64 is
-    # padded to 128 in HBM and stored under a mask); smaller-head test configs
-    # (hd 32) take the scatter. Interpret mode keeps the kernel path at those
-    # shapes so parity tests cover the real tile-rewrite body.
-    if hd % 64 != 0 or S % 128 != 0:
-        _note_fall("append_kv_q8", f"hd={hd} S={S} not lane-aligned", interp)
+    # mosaic int8 stores want rows of whole 128-lane tiles, which every cache
+    # of heads that divide the lanes has (`kv_heads_abreast`); narrower test
+    # configs (hd 32 with two KV heads) take the scatter.
+    if W % LANES != 0 or S % 128 != 0:
+        _note_fall("append_kv_q8", f"row={W} S={S} not lane-aligned", interp)
         return _append_q8_scatter(cache_k, pay, s_new, rows, lengths), cache_v
 
     BSQ = 32  # int8 sublane tile height: smallest in-place payload rewrite
     BSS = 128  # lane width: smallest in-place scales rewrite
     assert S % BSQ == 0 and S % BSS == 0, (S, BSQ, BSS)
     kernel = functools.partial(_append_q8_kernel, block_q=BSQ, block_s=BSS, seq_len=S)
-    narrow = hd % 128 != 0  # the step's row comes repeated down the tile
-    pay_spec = (pl.BlockSpec((L, 1, Hf, BSQ, hd), lambda b, lens, ids: (0, b, 0, 0, 0)) if narrow
-                else pl.BlockSpec((L, 1, Hf, hd), lambda b, lens, ids: (0, b, 0, 0)))
 
     def blkq(lens, b):
         # payload tile holding this row's write position (clamped if parked)
@@ -2722,10 +2843,10 @@ def append_kv_q8(
         num_scalar_prefetch=2,  # lengths [Ba], cache row ids [Ba]
         grid=(Ba,),
         in_specs=[
-            pay_spec,
+            pl.BlockSpec((L, 1, Hf, W), lambda b, lens, ids: (0, b, 0, 0)),
             pl.BlockSpec((L, 1, Hs, BSS), lambda b, lens, ids: (0, b, 0, 0)),
             pl.BlockSpec(
-                (L, 1, Hf, BSQ, hd), lambda b, lens, ids: (0, ids[b], 0, blkq(lens, b), 0)
+                (L, 1, Hf, BSQ, W), lambda b, lens, ids: (0, ids[b], 0, blkq(lens, b), 0)
             ),
             pl.BlockSpec(
                 (L, 1, Hs, BSS), lambda b, lens, ids: (0, ids[b], 0, blks(lens, b))
@@ -2733,7 +2854,7 @@ def append_kv_q8(
         ],
         out_specs=[
             pl.BlockSpec(
-                (L, 1, Hf, BSQ, hd), lambda b, lens, ids: (0, ids[b], 0, blkq(lens, b), 0)
+                (L, 1, Hf, BSQ, W), lambda b, lens, ids: (0, ids[b], 0, blkq(lens, b), 0)
             ),
             pl.BlockSpec(
                 (L, 1, Hs, BSS), lambda b, lens, ids: (0, ids[b], 0, blks(lens, b))
@@ -2755,7 +2876,7 @@ def append_kv_q8(
     )(
         lengths.astype(jnp.int32),
         rows,
-        _down_the_tile(pay, BSQ) if narrow else pay,
+        pay,
         jnp.broadcast_to(s_new[..., None], (L, Ba, Hs, BSS)),
         cache_k["q"],
         cache_k["s"],
@@ -3678,7 +3799,7 @@ def ragged_prefill_attend_q8(
     q: jnp.ndarray,  # [T, Hkv, G, hd] post-rope queries (packed)
     k_self: jnp.ndarray,  # [T, Hkv, hd] exact bf16 self keys
     v_self: jnp.ndarray,
-    cache_k: dict,  # FUSED int8 cache {"q": [L,B,2Hkv+p,S,hd], "s": [L,B,2Hkv,S]}
+    cache_k: dict,  # FUSED int8 cache {"q": [L,B,2Hkv/P+p,S,P*hd], "s": [L,B,2Hkv,S]}
     layer,
     rowids: jnp.ndarray,
     offsets: jnp.ndarray,
@@ -3702,6 +3823,12 @@ def ragged_prefill_attend_q8(
     starts = jnp.asarray(starts, jnp.int32)
     slots_i = jnp.asarray(slots, jnp.int32)
     use_kernel = _ragged_kernel_asked(impl)
+    _, _, P = fused_q8_heads(cache_k)
+    if use_kernel and P > 1:
+        # the ragged kernel walks the heads one by one under a `fori_loop` and
+        # has no form of a head's lanes of a row at a traced index
+        _note_fall("ragged_prefill_attend_q8", f"{P} heads abreast", _interpret())
+        use_kernel = False
 
     if not use_kernel:
         Sk = min(skey, S) if skey else S
@@ -3714,17 +3841,16 @@ def ragged_prefill_attend_q8(
             tbl = jnp.take(block_tables, slots_i, axis=0)[:, :nsel]
             pp_l = jax.lax.dynamic_index_in_dim(pool["q"], layer, 0, keepdims=False)
             ps_l = jax.lax.dynamic_index_in_dim(pool["s"], layer, 0, keepdims=False)
-            pays = paged_gather(pay_l, pp_l, tbl, nbs=nbs_full)[:, : 2 * Hkv, :Sk]
+            pays = paged_gather(pay_l, pp_l, tbl, nbs=nbs_full)[:, : 2 * Hkv // P, :Sk]
             srows = paged_gather(ss_l, ps_l, tbl, nbs=nbs_full)[:, : 2 * Hkv, :Sk]
         else:
-            pays = jnp.take(pay_l, slots_i, axis=0)[:, : 2 * Hkv, :Sk]
+            pays = jnp.take(pay_l, slots_i, axis=0)[:, : 2 * Hkv // P, :Sk]
             srows = jnp.take(ss_l, slots_i, axis=0)[:, : 2 * Hkv, :Sk]
         return _ragged_attend_gqa_fallback(
             q,
             k_self,
             v_self,
-            pays[:, :Hkv],
-            pays[:, Hkv:],
+            *fused_kv(pays, Hkv, P),
             srows[:, :Hkv],
             srows[:, Hkv:],
             rowids,

@@ -44,8 +44,8 @@ program as the cache pair (cache_k, cache_v): `cache_v` is
 {"v": the KV cache's second member, "state": {"S", "conv"}} (a kind whose
 only state is its convolution's tail has no "S": `_pool`), or for window
 layers "win": their ring, a KV cache pair {"k", "v"} of its own in the KV
-cache's form over [Lw, slots, .., R, hd] (int8: {"q": [Lw, slots, 2 Hkv + p, R,
-hd], "s": [Lw, slots, 2 Hkv, R]} and {}) with position p at index p mod R
+cache's form over [Lw, slots, .., R, hd] (int8: {"q": [Lw, slots, 2 Hkv / P + p,
+R, P hd], "s": [Lw, slots, 2 Hkv, R]} and {}) with position p at index p mod R
 (`SLOT_MEMBERS`), and, with routed
 experts only, "moe": counts; built by `init_hybrid_cache`. "moe" [2, Le, 5]
 int32 (Le the expert layers) is the expert layer's own member, beside the state

@@ -33,12 +33,19 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.attention import (
+    _q8_step_rows,
     append_kv_bf16,
     append_kv_q8,
+    ctx_apart,
     decode_attend_bf16,
     decode_attend_q8,
     flash_prefill_attention,
+    fused_kv,
+    fused_q8_heads,
+    kv_abreast,
+    kv_heads_abreast,
     paged_gather,
+    q_abreast,
     ragged_prefill_attend_bf16,
     ragged_prefill_attend_q8,
 )
@@ -151,14 +158,24 @@ def init_kv_cache(
 
     Quantized GQA entries use the FUSED single-payload layout:
 
-        cache["k"] = {"q": int8 [L, B, 2*Hkv + p, S, hd],
+        cache["k"] = {"q": int8 [L, B, 2*Hkv/P + p, S, P*hd],
                       "s": dtype [L, B, 2*Hkv, S]}
         cache["v"] = {}   (V rides cache["k"]'s head axis)
 
-    Payload head rows [0, Hkv) are K, [Hkv, 2*Hkv) are V, and — when the
-    scale bytes fit one head row (p = 1, `models/quant.py:scale_pack_width`)
-    — head 2*Hkv carries the per-position dequant scales BIT-PACKED into
-    int8 lanes. The fusion is what lets the blocked decode kernel issue ONE
+    A payload row holds P heads ABREAST (`kernels/attention.py:
+    kv_heads_abreast`): 1 at heads of 128 or wider, and where a head is
+    narrower than the 128 lanes and divides them as many as fill them (two
+    heads of 64; head p*R + r in lanes [p*hd, (p+1)*hd) of row r, R = Hkv/P),
+    so that the minor dimension is whole lanes and the chip lays the array
+    out as the kernels read it. At a minor dimension of 64 it laid positions
+    minor instead, and every step program copied the whole cache to the
+    kernels' layout and back (PERF.md section 6, PR 55). P is a function of the
+    shape alone, and every reader takes it off the two members' shapes
+    (`fused_q8_heads`). Payload rows [0, R) are K, [R, 2*R) are V, and — when
+    the scale bytes fit one row (p = 1, `models/quant.py:scale_pack_width`)
+    — row 2*R carries the per-position dequant scales BIT-PACKED into
+    int8 lanes. One scale a (position, head) either way: the plain "s" array
+    does not know of P. The fusion is what lets the blocked decode kernel issue ONE
     DMA per (row, block) cell instead of the r05 layout's four (kq/ks/vq/vs
     as separate arrays — kernels/attention.py:_attend_q8_blocked_kernel);
     the plain "s" array is dual-written for every consumer that wants
@@ -185,11 +202,12 @@ def init_kv_cache(
     Hkv = cfg.n_kv_heads
     shape = (cfg.n_layers, batch, Hkv, max_seq, hd)
     if quantized:
-        p = scale_pack_width(Hkv, hd, dtype)
+        P = kv_heads_abreast(Hkv, hd)
+        p = scale_pack_width(Hkv, P * hd, dtype)
         return {
             "k": {
                 "q": jnp.zeros(
-                    (cfg.n_layers, batch, 2 * Hkv + p, max_seq, hd), dtype=jnp.int8
+                    (cfg.n_layers, batch, 2 * Hkv // P + p, max_seq, P * hd), dtype=jnp.int8
                 ),
                 "s": jnp.zeros(
                     (cfg.n_layers, batch, 2 * Hkv, max_seq), dtype=dtype
@@ -219,18 +237,21 @@ def fuse_prompt_kv(
     scale_dtype=None,
 ) -> dict[str, jnp.ndarray]:
     """Quantize a prompt's K/V rows into the FUSED cache entry
-    (`init_kv_cache`): one int8 payload carrying K heads | V heads | the
-    optional bit-packed scale pseudo-head, plus the plain "s" scales. The
-    engine's cache "v" member is the empty dict — callers pair the returned
-    dict with `{}`."""
+    (`init_kv_cache`): one int8 payload carrying K heads | V heads, P abreast
+    in a row as the cache of this shape holds them, | the optional bit-packed
+    scale pseudo-head, plus the plain "s" scales. The engine's cache "v"
+    member is the empty dict — callers pair the returned dict with `{}`."""
     hd = kh.shape[-1]
     Hkv = kh.shape[-3]
+    P = kv_heads_abreast(Hkv, hd)
     kq = quantize_kv(kh, scale_dtype=scale_dtype)
     vq = quantize_kv(vh, scale_dtype=scale_dtype)
     s = jnp.concatenate([kq["s"], vq["s"]], axis=-2)  # [..., 2*Hkv, S]
-    pay = jnp.concatenate([kq["q"], vq["q"]], axis=-3)  # [..., 2*Hkv, S, hd]
-    if scale_pack_width(Hkv, hd, s.dtype):
-        pay = jnp.concatenate([pay, pack_scales(s, hd)], axis=-3)
+    pay = jnp.concatenate(
+        [kv_abreast(kq["q"], P), kv_abreast(vq["q"], P)], axis=-3
+    )  # [..., 2*Hkv/P, S, P*hd]
+    if scale_pack_width(Hkv, P * hd, s.dtype):
+        pay = jnp.concatenate([pay, pack_scales(s, P * hd)], axis=-3)
     return {"q": pay, "s": s}
 
 
@@ -619,9 +640,10 @@ def _decode_step_q8(
     for every parked slot; the kernels follow the indirection via scalar
     prefetch, so cache traffic also shrinks on the blocked path).
     """
-    # fused cache: axis 2 of "q" is 2*Hkv + p, not Hkv — take Hkv from cfg
-    L, B, _, S, hd = _cache_shape(cache_k)
-    Hkv = cfg.n_kv_heads
+    # fused cache: axis 2 of "q" is 2*Hkv/P + p and its rows P*hd wide — take
+    # Hkv and hd from cfg
+    L, B, _, S, _ = _cache_shape(cache_k)
+    Hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     Ba = tokens.shape[0]
     H = cfg.n_heads
     h = _embed_in(cfg, params, tokens)  # [Ba, D]
@@ -765,8 +787,8 @@ def mixed_step_q8(
 
     Returns (logits [B + R, V]: the decode rows, then each prompt's last
     token; new_k, new_v)."""
-    L, B, _, S, hd = _cache_shape(cache_k)
-    Hkv, H = cfg.n_kv_heads, cfg.n_heads
+    L, B, _, S, _ = _cache_shape(cache_k)
+    Hkv, H, hd = cfg.n_kv_heads, cfg.n_heads, cfg.resolved_head_dim
     T = p_tokens.shape[0]
     R = p_slots.shape[0]
     N = B + T
@@ -796,7 +818,7 @@ def mixed_step_q8(
             fused = fuse_prompt_kv(
                 k[B:].transpose(1, 0, 2), v[B:].transpose(1, 0, 2),
                 scale_dtype=cache_k["s"].dtype,
-            )  # {"q": [2 Hkv + p, T, hd], "s": [2 Hkv, T]}
+            )  # {"q": [2 Hkv / P + p, T, P hd], "s": [2 Hkv, T]}
         return (h, li + 1), (k[:B], v[:B], fused["q"], fused["s"])
 
     (h, _), (knew, vnew, pq, ps) = jax.lax.scan(
@@ -894,9 +916,11 @@ def _chunk_attention(
     both masks, and `write` lands each row's last R VALID positions at their
     wrapped indices (a padding row of a ragged chunk would replace a live one)."""
     quantized = isinstance(cache_k, dict)
-    # fused quantized cache: axis 2 of "q" is 2*Hkv + p — take Hkv from cfg
-    L, B, _, S, hd = _cache_shape(cache_k)
-    Hkv = cfg.n_kv_heads
+    # fused quantized cache: axis 2 of "q" is 2*Hkv/P + p and its rows P*hd
+    # wide — take Hkv and hd from cfg, P from the cache
+    L, B, _, S, _ = _cache_shape(cache_k)
+    Hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+    P = fused_q8_heads(cache_k)[2] if quantized else 1
     H = cfg.n_heads
     G = H // Hkv
     A, C = tokens.shape
@@ -946,7 +970,15 @@ def _chunk_attention(
             v = v.reshape(A, C, Hkv, hd)
             kh = k.transpose(0, 2, 1, 3)  # [A, Hkv, C, hd]
             vh = v.transpose(0, 2, 1, 3)
-            qg = q.reshape(A, C, Hkv, G, hd)
+            # everything below is in the cache's own arrangement, P heads
+            # abreast in a row (P = 1: a head a row): the past rows are read as
+            # they lie, [A, R, Sk, W], and a query group's rows hold zeros
+            # outside its head's lanes (`q_abreast`). Pulled apart to heads of
+            # 64, the past rows wanted positions minor, and the compiler re-laid
+            # the whole cache for them at the loop's edge (described-chip
+            # compile, PR 55)
+            qg = q_abreast(q.reshape(A, C, Hkv, G, hd), P)  # [A, C, R, P*G, W]
+            kw, vw = kv_abreast(kh, P), kv_abreast(vh, P)  # [A, R, C, W]
 
             # ---- reads first: the past rows from the PRE-write cache ----
             if quantized:
@@ -959,7 +991,7 @@ def _chunk_attention(
                         jax.lax.dynamic_index_in_dim(ck_all["q"], li, 0, keepdims=False),
                         jax.lax.dynamic_index_in_dim(paged["k"]["q"], li, 0, keepdims=False),
                         ptbl, nbs=nbs_full,
-                    )[:, : 2 * Hkv, :Sk]  # [A, 2*Hkv, Sk, hd] int8
+                    )[:, : 2 * Hkv // P, :Sk]  # [A, 2*Hkv/P, Sk, P*hd] int8
                     srows = paged_gather(
                         jax.lax.dynamic_index_in_dim(ck_all["s"], li, 0, keepdims=False),
                         jax.lax.dynamic_index_in_dim(paged["k"]["s"], li, 0, keepdims=False),
@@ -969,11 +1001,12 @@ def _chunk_attention(
                     pays = jnp.stack(
                         [
                             jax.lax.dynamic_slice(
-                                ck_all["q"], (li, slots[a], 0, 0, 0), (1, 1, 2 * Hkv, Sk, hd)
+                                ck_all["q"], (li, slots[a], 0, 0, 0),
+                                (1, 1, 2 * Hkv // P, Sk, P * hd),
                             )[0, 0]
                             for a in range(A)
                         ]
-                    )  # [A, 2*Hkv, Sk, hd] int8
+                    )  # [A, 2*Hkv/P, Sk, P*hd] int8
                     srows = jnp.stack(
                         [
                             jax.lax.dynamic_slice(
@@ -982,7 +1015,7 @@ def _chunk_attention(
                             for a in range(A)
                         ]
                     )  # [A, 2*Hkv, Sk]
-                krows, vrows = pays[:, :Hkv], pays[:, Hkv:]
+                krows, vrows = pays[:, : Hkv // P], pays[:, Hkv // P :]  # [A, R, Sk, W]
                 ksr, vsr = srows[:, :Hkv], srows[:, Hkv:]
             elif ptbl is not None:
                 krows = paged_gather(
@@ -1018,9 +1051,9 @@ def _chunk_attention(
                 "achgd,ahsd->ahgcs", qg, krows.astype(h.dtype)
             ).astype(jnp.float32)
             if quantized:
-                s_past = s_past * ksr.astype(jnp.float32)[:, :, None, None, :]
+                s_past = s_past * _scales_by_row(ksr.astype(jnp.float32), P, G)
             # self scores: exact, from in-register bf16 K
-            s_self = jnp.einsum("achgd,ahtd->ahgct", qg, kh).astype(jnp.float32)
+            s_self = jnp.einsum("achgd,ahtd->ahgct", qg, kw).astype(jnp.float32)
             s_past = _softcap(s_past * cfg.attn_scale, cfg.attn_softcap)
             s_self = _softcap(s_self * cfg.attn_scale, cfg.attn_softcap)
 
@@ -1039,11 +1072,11 @@ def _chunk_attention(
             probs = jax.nn.softmax(s, axis=-1)
             p_past, p_self = probs[..., :Sk], probs[..., Sk:]
             if quantized:
-                p_past = p_past * vsr.astype(jnp.float32)[:, :, None, None, :]
+                p_past = p_past * _scales_by_row(vsr.astype(jnp.float32), P, G)
             ctx = jnp.einsum(
                 "ahgcs,ahsd->achgd", p_past.astype(h.dtype), vrows.astype(h.dtype)
-            ) + jnp.einsum("ahgct,ahtd->achgd", p_self.astype(h.dtype), vh)
-            ctx = ctx.reshape(A, C, H * hd)
+            ) + jnp.einsum("ahgct,ahtd->achgd", p_self.astype(h.dtype), vw)
+            ctx = ctx_apart(ctx, P).reshape(A, C, H * hd)
             h = _attn_residual(cfg, lp, ctx, h, x)
         return h, kh, vh
 
@@ -1095,6 +1128,19 @@ def _chunk_attention(
         return ck_all, cv_all
 
     return h, attend, write
+
+
+def _scales_by_row(ss: jnp.ndarray, abreast: int, group: int) -> jnp.ndarray:
+    """Dequant scales [A, Hkv, Sk] as they multiply a chunk's scores
+    [A, Hkv / P, P*G, C, Sk] of P heads abreast (`q_abreast`'s rows: head
+    p*R + r's G rows are rows [p*G, (p+1)*G) of row r). The kernels' twin
+    (`kernels/attention.py:_scales_by_row`) spreads them with a select, having
+    no reshape of a tile's sublanes; here a reshape does."""
+    if abreast == 1:
+        return ss[:, :, None, None, :]
+    A, Hkv, Sk = ss.shape
+    rows = ss.reshape(A, abreast, Hkv // abreast, Sk).transpose(0, 2, 1, 3)  # [A, R, P, Sk]
+    return jnp.repeat(rows, group, axis=2)[:, :, :, None, :]
 
 
 def _ring_positions(ends: jnp.ndarray, ring_len: int) -> jnp.ndarray:
@@ -1334,8 +1380,8 @@ def llama_prefill_chunk_ragged(
             "the engine gates others to the bucketed path"
         )
     quantized = isinstance(cache_k, dict)
-    L, B, _, S, hd = _cache_shape(cache_k)
-    Hkv = cfg.n_kv_heads
+    L, B, _, S, _ = _cache_shape(cache_k)
+    Hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     H = cfg.n_heads
     G = H // Hkv
     T = tokens.shape[0]
@@ -1407,7 +1453,7 @@ def llama_prefill_chunk_ragged(
                 fused = fuse_prompt_kv(
                     k.transpose(1, 0, 2), v.transpose(1, 0, 2),
                     scale_dtype=cache_k["s"].dtype,
-                )  # {"q": [2*Hkv+p, T, hd], "s": [2*Hkv, T]}
+                )  # {"q": [2*Hkv/P+p, T, P*hd], "s": [2*Hkv, T]}
                 new = (fused["q"], fused["s"])
             else:
                 new = (
@@ -1484,9 +1530,11 @@ def llama_decode_step(
             slot_ids=slot_ids, paged=paged,
         )
     quantized = isinstance(cache_k, dict)
-    # fused quantized cache: axis 2 of "q" is 2*Hkv + p — take Hkv from cfg
-    L, B, _, S, hd = _cache_shape(cache_k)
-    Hkv = cfg.n_kv_heads
+    # fused quantized cache: axis 2 of "q" is 2*Hkv/P + p and its rows P*hd
+    # wide — take Hkv and hd from cfg, P from the cache
+    L, B, _, S, _ = _cache_shape(cache_k)
+    Hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+    P = fused_q8_heads(cache_k)[2] if quantized else 1
     Ba = tokens.shape[0]
     H = cfg.n_heads
     G = H // Hkv
@@ -1574,16 +1622,9 @@ def llama_decode_step(
             # attention read: write-after-read on the carried buffer would cost
             # XLA a full-cache defensive copy (~10 ms at 8B B=64).
             if quantized:
-                kq = quantize_kv(k, scale_dtype=ck_all["s"].dtype)
-                vq = quantize_kv(v, scale_dtype=ck_all["s"].dtype)
-                s_new = jnp.concatenate([kq["s"], vq["s"]], axis=1)  # [Ba, 2*Hkv]
-                pay = jnp.concatenate([kq["q"], vq["q"]], axis=1)  # [Ba, 2*Hkv, hd]
-                if ck_all["q"].shape[2] > 2 * Hkv:
-                    # keep the packed pseudo-head consistent too: snapshots /
-                    # path switches must see one coherent fused entry
-                    pay = jnp.concatenate(
-                        [pay, pack_scales(s_new[..., None], hd)[..., 0, :]], axis=1
-                    )
+                # the packed pseudo-head is kept consistent too: snapshots /
+                # path switches must see one coherent fused entry
+                pay, s_new = _q8_step_rows(ck_all, k, v)  # [Ba, Hf, P*hd], [Ba, 2*Hkv]
                 hf_idx = jnp.arange(pay.shape[1])[None, :]
                 hs_idx = jnp.arange(2 * Hkv)[None, :]
                 ck_all = {
@@ -1597,7 +1638,7 @@ def llama_decode_step(
             if quantized:
                 payl = csel(ck_all["q"], li, None if paged is None else paged["k"]["q"])
                 ssl = csel(ck_all["s"], li, None if paged is None else paged["k"]["s"])
-                ck, cv = payl[:, :Hkv], payl[:, Hkv : 2 * Hkv]
+                ck, cv = fused_kv(payl, Hkv, P)
                 ks, vs = ssl[:, :Hkv], ssl[:, Hkv:]
                 # int8 K dot in compute dtype; per-key-token dequant scales the
                 # SCORES (cheap [Ba,Hkv,G,S] multiply), not the K payload

@@ -207,12 +207,18 @@ class ModelShape:
 
 def _fused_scale_bytes(n_kv_heads: int, head_dim: int) -> int:
     """Per-token bytes of the fused int8 layout's packed scales: one f32
-    scale per (k|v, kv-head, token), packed into pseudo-head rows of
-    head_dim int8 lanes riding in the payload tensor — storage rounds up
-    to whole rows, so the cost is the padded row width, not the scalars."""
+    scale per (k|v, kv-head, token), packed into pseudo-head rows of the
+    payload's row width in int8 lanes — storage rounds up to whole rows, so
+    the cost is the padded row width, not the scalars. A row is a head wide,
+    or the 128 lanes where narrower heads lie abreast in it
+    (kernels/attention.py:kv_heads_abreast, restated: this module imports
+    nothing of jax; tests/test_perf.py holds the two to each other)."""
     raw = 2 * n_kv_heads * _SCALE_BYTES
-    rows = -(-raw // max(1, head_dim))
-    return rows * head_dim
+    hd = max(1, head_dim)
+    abreast = 128 // hd if hd < 128 and 128 % hd == 0 else 1
+    width = hd * (abreast if n_kv_heads % abreast == 0 else 1)
+    rows = -(-raw // width)
+    return rows * width
 
 
 def kv_bytes_per_token(shape: ModelShape, layout: str) -> float:

@@ -23,7 +23,10 @@ stalls of the in-flight queue by the loop's phase; nothing from a program
 without the account), the rows of EVERY
 round of the window over the slots (`occupancy_ring`, from the ring's `emit`
 events: `decode_occupancy` reads one dispatch in 32 of a phase, about two
-dozen a window) and the warm-up plan's seconds by phase (`plan`).
+dozen a window), the warm-up plan's seconds by phase (`plan`) and what the
+int8 decode-attention arm streamed (`decode_attn`, since PR 55: the window's
+difference of `perf_stats()["decode_attn"]` with `block_tokens` and
+`heads_abreast` beside it).
 """
 
 from __future__ import annotations
@@ -134,6 +137,16 @@ def extras(run: dict):
     got = rounds(run)
     if got is not None:
         out["rounds"] = got
+    # what the int8 decode-attention arm streamed over the window (`AttnStream`):
+    # the block in force, the heads a row of the cache holds (PR 55; None from a
+    # program without the key), positions live over positions fetched
+    a0, a1 = start.get("decode_attn") or {}, end.get("decode_attn")
+    if a1:
+        d = {k: a1[k] - a0.get(k, 0) for k in ("steps", "tokens_streamed", "tokens_live")}
+        out["decode_attn"] = {
+            "block_tokens": a1["block_tokens"], "heads_abreast": a1.get("heads_abreast"), **d,
+            "live_over_streamed": round(d["tokens_live"] / d["tokens_streamed"], 4)
+            if d["tokens_streamed"] else None}
     tr = run.get("trace") or {}
     if "start" in tr:
         progs = admit_spans.ring(run, "admit_prog").values()

@@ -3,10 +3,14 @@
 
     chiprun -- python3 scripts/attn_block_sweep.py [--blocks 0 64 128 256] [--tree _clean]
 
-The arm at the three shapes the benchmark's generation cells run it at
+The arm at the shapes the benchmark's generation cells run it at
 (`[rows, KV heads, group]` and the cache's length: decode_closed `[32, 8, 4]`
 S 2048, solar_decode_closed `[64, 8, 8]` S 1024, olmo_hybrid_decode_closed
-`[64, 30, 1]` S 1024; head size 128, packed bf16 scales), each row's position
+`[64, 30, 1]` S 1024, head size 128; granite_decode_closed `[64, 8, 4]` S 1024,
+head size 64, which is lfm2_decode_closed's shape too: since PR 55 two heads
+abreast in rows of 128 lanes, and in a tree from before that, which `--tree`
+may name, a head a row, where the dispatcher answers with the whole-S arm;
+packed bf16 scales), each row's position
 drawn uniformly between the cell's shortest and longest context so that the
 mean is the cell's (`PERF.md` section 5), one row in sixteen parked. For each
 block size one jitted function (a function of its own a form: `jax.jit` caches
@@ -29,12 +33,13 @@ import os
 import sys
 import time
 
-SHAPES = {  # cell: rows, KV heads, group, cache length, shortest and longest context
-    "decode_closed": (32, 8, 4, 2048, 60, 240),
-    "solar_decode_closed": (64, 8, 8, 1024, 100, 680),
-    "olmo_hybrid_decode_closed": (64, 30, 1, 1024, 100, 680),
+SHAPES = {  # cell: rows, KV heads, group, cache length, shortest and longest context, head size
+    "decode_closed": (32, 8, 4, 2048, 60, 240, 128),
+    "solar_decode_closed": (64, 8, 8, 1024, 100, 680, 128),
+    "olmo_hybrid_decode_closed": (64, 30, 1, 1024, 100, 680, 128),
+    "granite_decode_closed": (64, 8, 4, 1024, 100, 680, 64),
 }
-HD, LAYERS = 128, 4
+LAYERS = 4
 
 
 def main() -> int:
@@ -64,12 +69,15 @@ def main() -> int:
     os.environ["LLM_MCP_TPU_Q8_DECODE"] = "blocked"  # the arm alone, not the cond on the fill
 
     for cell in args.cells:
-        B, Hkv, G, S, lo, hi = SHAPES[cell]
+        B, Hkv, G, S, lo, hi, HD = SHAPES[cell]
         rng = np.random.default_rng(args.seed)
         key = jax.random.PRNGKey(args.seed)
-        pay = jax.random.randint(key, (LAYERS, B, 2 * Hkv, S, HD), -127, 128, jnp.int8)
+        # P heads abreast in a row, as the tree's `init_kv_cache` lays this shape
+        P = A.kv_heads_abreast(Hkv, HD) if hasattr(A, "kv_heads_abreast") else 1
+        rows, W = 2 * Hkv // P, P * HD
+        pay = jax.random.randint(key, (LAYERS, B, rows, S, W), -127, 128, jnp.int8)
         s = (jax.random.uniform(key, (LAYERS, B, 2 * Hkv, S)) * 0.02).astype(jnp.bfloat16)
-        ck = {"q": jnp.concatenate([pay, pack_scales(s, HD)], axis=2), "s": s}
+        ck = {"q": jnp.concatenate([pay, pack_scales(s, W)], axis=2), "s": s}
         del pay
         q = jax.random.normal(key, (B, Hkv, G, HD), jnp.bfloat16)
         nk = jax.random.normal(jax.random.fold_in(key, 1), (B, Hkv, HD), jnp.bfloat16)
@@ -100,15 +108,17 @@ def main() -> int:
             t0 = time.perf_counter()
             jax.block_until_ready([fn(q, nk, nv, ck, lens) for _ in range(args.reps)])
             us = (time.perf_counter() - t0) * 1e6 / (args.reps * args.calls)
-            line = {"cell": cell, "shape": [B, Hkv, G], "S": S, "device": dev.device_kind,
-                    "block": bs or "rule",
+            line = {"cell": cell, "shape": [B, Hkv, G], "S": S, "head": HD, "heads_abreast": P,
+                    "device": dev.device_kind, "block": bs or "rule",
                     "max_err_to_f32": round(err, 4), "us_a_call": round(us, 2), "tokens_live": live}
             if has_block:
-                eff = bs or A.q8_block_tokens(2 * Hkv + 1, S, HD)
+                eff = bs or A.q8_block_tokens(rows + 1, S, W)
+                if W % 128:  # no blocked arm on such rows: the whole-S arm answered
+                    eff = S
                 streamed = int(np.sum(A.blocked_row_blocks(w, S, eff, xp=np))) * eff
                 line.update(block_tokens=eff, tokens_streamed=streamed,
                             live_over_streamed=round(live / streamed, 3),
-                            streamed_gb_s=round(streamed * (2 * Hkv + 1) * HD / us / 1e3, 1))
+                            streamed_gb_s=round(streamed * (rows + 1) * W / us / 1e3, 1))
             print(json.dumps(line), flush=True)
     return 0
 

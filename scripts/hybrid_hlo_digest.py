@@ -10,7 +10,7 @@ models/hybrid.py, llama.py, kda.py, ssm.py, moe.py or the kernels that is
 meant to leave a configuration alone shows here in seconds.
 
     JAX_PLATFORMS=cpu python scripts/hybrid_hlo_digest.py [--tree _clean] [--out DIR]
-        [--lin-value-dim 128]
+        [--lin-value-dim 128] [--chip]
 
 `--tree` is a checkout of another commit (`git archive <commit> | tar -x -C
 _clean`); `--out` keeps the texts, to diff where a digest differs. PR 43 read
@@ -23,6 +23,18 @@ moves `tiny-solar`'s text and leaves Solar's alone, and shows so here (PR 50).
 The whole-prompt prefill is lowered on the Pallas arm since PR 51, as the cells
 run it (before, on the XLA arm, a change to `flash_prefill_attention` moved no
 digest: PR 51 read the three prefills alone differ from dd59802's).
+
+`--chip` (PR 55) lowers the CELLS' configurations instead, at their published
+widths, 64 slots x 1024 in bfloat16, FOR the TPU (`lowering_platforms`, no chip
+and no libtpu; the platform question answered "tpu", so the dispatchers take
+the kernels with interpret mode off and the arms a chip would compile): a
+Mosaic kernel is then a `tpu_custom_call` whose body is serialised bytecode
+WITH the source lines of the kernel's Python, which move with every edit above
+them, so each body is read back and printed without locations before the text
+is hashed. PR 55 read the fifteen programs of the four cells of heads of 128
+equal between a600a99 and its own tree (the tiny presets cannot show that: all
+but `tiny-lfm2` have heads no row of 128 lanes holds whole), and Granite's and
+LFM2's, heads of 64, all differ.
 """
 
 from __future__ import annotations
@@ -35,6 +47,29 @@ import sys
 from functools import partial
 
 PRESETS = ("tiny-solar", "tiny-olmo-hybrid", "tiny-granite-hybrid", "tiny-kexaone", "tiny-lfm2")
+CELLS = ("qwen3-8b", "solar-open2-250b-ep8", "olmo-hybrid-7b-d20", "granite-4.0-h-micro",
+         "k-exaone-236b-ep8", "lfm2-8b-a1b-d14")
+
+
+def without_locations(text: str) -> str:
+    """`text` with every Mosaic kernel's serialised body replaced by the body
+    printed without its source locations."""
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    ctx = mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True  # the serialised dialect, `stable_mosaic`
+
+    def body(m):
+        with ctx:
+            asm = ir.Module.parse(base64.b64decode(m.group(1))).operation.get_asm(
+                enable_debug_info=False)
+        return f'body\\22: \\22<{hashlib.sha1(asm.encode()).hexdigest()}>\\22'
+
+    return re.sub(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22', body, text)
 
 
 def main() -> int:
@@ -42,6 +77,7 @@ def main() -> int:
     ap.add_argument("--tree", default=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     ap.add_argument("--out", default="")
     ap.add_argument("--lin-value-dim", type=int, default=0)
+    ap.add_argument("--chip", action="store_true")
     args = ap.parse_args()
     sys.path.insert(0, os.path.abspath(args.tree))
 
@@ -51,21 +87,24 @@ def main() -> int:
 
     from llm_mcp_tpu.models import hybrid, llama
     from llm_mcp_tpu.models.configs import MODEL_CONFIGS, get_config
+    from llm_mcp_tpu.utils import platform
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32)
 
-    B, T, R = 4, 128, 4
-    for name in PRESETS:
+    B, S, T, R, dtype = (64, 1024, 256, 4, jnp.bfloat16) if args.chip else (4, 128, 128, 4, jnp.float32)
+    if args.chip:
+        platform.device_platform = lambda: "tpu"
+    for name in CELLS if args.chip else PRESETS:
         if name not in MODEL_CONFIGS:  # a tree from before the preset
             continue
         cfg = get_config(name)
         if args.lin_value_dim and cfg.lin_heads:
             cfg = dataclasses.replace(cfg, lin_value_dim=args.lin_value_dim)
         params = jax.eval_shape(
-            partial(llama.init_llama_params, cfg, jax.random.PRNGKey(0), dtype=jnp.float32))
+            partial(llama.init_llama_params, cfg, jax.random.PRNGKey(0), dtype=dtype))
         cache = jax.eval_shape(
-            partial(llama.init_kv_cache, cfg, B, 128, dtype=jnp.float32, quantized=True))
+            partial(llama.init_kv_cache, cfg, B, S, dtype=dtype, quantized=True))
         programs = {
             "decode": (lambda p, ck, cv, *a: llama.llama_decode_step(
                 cfg, p, ck, cv, *a, attn_impl="pallas"), (i32(B), i32(B))),
@@ -78,13 +117,16 @@ def main() -> int:
         }
         if not llama.mixed_step_supported(cfg):  # a stack with rings takes admit programs alone
             del programs["mixed"]
+        elif not cfg.gqa_layers:  # the dense family's mixed step is `llama.mixed_step_q8`
+            programs["mixed"] = (lambda p, ck, cv, *a: llama.mixed_step_q8(cfg, p, ck, cv, *a),
+                                 programs["mixed"][1])
         for tag, (fn, operands) in programs.items():
-            module = jax.jit(fn).lower(params, cache["k"], cache["v"], *operands).compiler_ir(
-                "stablehlo")
+            module = jax.jit(fn).trace(params, cache["k"], cache["v"], *operands).lower(
+                lowering_platforms=("tpu" if args.chip else "cpu",)).compiler_ir("stablehlo")
             with module.context:
                 passmanager.PassManager.parse("builtin.module(cse,canonicalize,cse)").run(
                     module.operation)
-            text = str(module)
+            text = without_locations(str(module)) if args.chip else str(module)
             if args.out:
                 os.makedirs(args.out, exist_ok=True)
                 with open(os.path.join(args.out, f"{name}.{tag}.mlir"), "w") as f:

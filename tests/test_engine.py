@@ -774,16 +774,20 @@ def test_decode_compact_matches_full_batch(kv_quant):
         off.shutdown()
 
 
-@pytest.mark.parametrize("attn", ["pallas", "xla"])
-def test_perf_stats_counts_what_the_blocked_attention_arm_streams(monkeypatch, attn):
+@pytest.mark.parametrize("attn,model,abreast", [
+    ("pallas", "tiny-llm", 1), ("xla", "tiny-llm", 1), ("pallas", "tiny-qwen3", 2)])
+def test_perf_stats_counts_what_the_blocked_attention_arm_streams(monkeypatch, attn, model, abreast):
     """`perf_stats()["decode_attn"]` exists where decode rounds read the int8
     cache through the Pallas arm, counts every step of every round dispatched
-    from the positions the host packed, and is absent where XLA attends."""
+    from the positions the host packed, and is absent where XLA attends;
+    `heads_abreast` says how many KV heads a row of the cache holds (two of
+    `tiny-qwen3`'s 64 wide, one of `tiny-llm`'s 32 wide: two KV heads cannot
+    fill 128 lanes four abreast)."""
     from llm_mcp_tpu.kernels.attention import q8_block_tokens
 
     monkeypatch.setenv("LLM_MCP_TPU_ATTN", attn)
     eng = GenerationEngine(
-        "tiny-llm", max_slots=4, max_seq_len=128, dtype=jnp.float32,
+        model, max_slots=4, max_seq_len=128, dtype=jnp.float32,
         decode_chunk=2, kv_quant="int8", prefill_chunk=8,
     ).start()
     try:
@@ -794,6 +798,8 @@ def test_perf_stats_counts_what_the_blocked_attention_arm_streams(monkeypatch, a
             return
         heads, seq, hd = eng._ck["q"].shape[2:]
         assert got["block_tokens"] == q8_block_tokens(heads, seq, hd) == 128
+        assert got["heads_abreast"] == abreast and hd == abreast * eng.cfg.resolved_head_dim
+        assert heads == 2 * eng.cfg.n_kv_heads // abreast + 1
         # every decode round's steps are counted (a verify round of the
         # speculation path is another program and reads no blocked arm)
         assert out["usage"]["completion_tokens"] == 9
@@ -821,6 +827,9 @@ _RAGGED_SHARED = "you are a helpful assistant. answer briefly. " * 3
     [
         ("tiny-llm", ""),
         pytest.param("tiny-llm", "int8", marks=pytest.mark.slow),
+        # two KV heads of 64 ABREAST in a row of 128 lanes: the chunk program
+        # multiplies the rows as they lie, the ragged one pulls the heads apart
+        ("tiny-qwen3", "int8"),
         pytest.param("tiny-mla", "", marks=pytest.mark.slow),
         pytest.param("tiny-mla", "int8", marks=pytest.mark.slow),
     ],

@@ -26,14 +26,32 @@ FILLS = (0.0, 0.4, 0.9)
 RAGGED_FILLS = (0.0, pytest.param(0.4, marks=pytest.mark.slow), 0.9)
 
 
-def _fused_q8_cache(rng, L, B, Hkv, S, hd, dtype=jnp.float32):
+def _fused_q8_cache(rng, L, B, Hkv, S, hd, dtype=jnp.float32, abreast=False):
+    """A random fused cache: a head a row (the form of heads of 128, which the
+    kernels read at any head size), or with `abreast` in the form
+    `init_kv_cache` gives this shape, P = `kv_heads_abreast` heads side by side
+    in rows of P*hd lanes."""
+    P = A.kv_heads_abreast(Hkv, hd) if abreast else 1
     pay = jnp.asarray(rng.integers(-127, 128, (L, B, 2 * Hkv, S, hd), dtype="int8"))
     s = jnp.asarray(rng.random((L, B, 2 * Hkv, S), dtype="float32") * 0.02).astype(
         dtype
     )
-    if scale_pack_width(Hkv, hd, dtype):
-        pay = jnp.concatenate([pay, pack_scales(s, hd)], axis=2)
+    pay = jnp.concatenate(
+        [A.kv_abreast(pay[:, :, :Hkv], P), A.kv_abreast(pay[:, :, Hkv:], P)], axis=2)
+    if scale_pack_width(Hkv, P * hd, dtype):
+        pay = jnp.concatenate([pay, pack_scales(s, P * hd)], axis=2)
     return {"q": pay, "s": s}, {}
+
+
+def _heads_apart(ck):
+    """The same bytes with a head a row: what the cache of this shape was
+    before its heads lay abreast (the kernels read either off the shapes)."""
+    Hkv, p, P = A.fused_q8_heads(ck)
+    k, v = A.fused_kv(ck["q"], Hkv, P)
+    pay = jnp.concatenate([k, v], axis=2)
+    if p:
+        pay = jnp.concatenate([pay, pack_scales(ck["s"], k.shape[-1])], axis=2)
+    return {"q": pay, "s": ck["s"]}
 
 
 def _lens_for(fill: float, B: int, S: int, rng) -> jnp.ndarray:
@@ -78,34 +96,40 @@ def _pipeline_params():
     out = []
     for case, rows in PIPELINE_CASES.items():
         if rows is None:
-            out += [(case, pack, 0, "perm") for pack in ("0", "1")]
+            out += [(case, pack, 0, "perm", form) for pack in ("0", "1")
+                    for form in ("apart", "abreast")]
             continue
-        out += [(case, "1", 128, "perm"), (case, "0", 64, "perm")]
+        out += [(case, "1", 128, "perm", "apart"), (case, "0", 64, "perm", "apart")]
         if case in ("unlike_lengths", "parked_first", "parked_middle", "one_row"):
-            out += [(case, "1", 32, "perm"), (case, "1", 256, "perm"), (case, "1", 128, None)]
+            out += [(case, "1", 32, "perm", "apart"), (case, "1", 256, "perm", "apart"),
+                    (case, "1", 128, None, "apart"), (case, "1", 128, "perm", "abreast")]
     return [pytest.param(*c, id="-".join(map(str, c))) for c in out]
 
 
-@pytest.mark.parametrize("case,pack,block,ids_kind", _pipeline_params())
-def test_q8_gqa_blocked_parity(monkeypatch, case, pack, block, ids_kind):
+@pytest.mark.parametrize("case,pack,block,ids_kind,form", _pipeline_params())
+def test_q8_gqa_blocked_parity(monkeypatch, case, pack, block, ids_kind, form):
     """Fused blocked q8 kernel (packed 1-DMA and unpacked 2-DMA modes) vs
     the exact-f32 fallback: odd batch (B=3, a remainder against every
     block shape), scattered fills, compaction ids; and since the batch's
     cells are one pipeline, the row edges of PIPELINE_CASES at each block
-    size `q8_block_tokens` can return."""
+    size `q8_block_tokens` can return. `abreast`: four KV heads of 64 two to a
+    row of 128 lanes, the form `init_kv_cache` gives that shape, against the
+    fallback on the same cache and the kernel on the same bytes a head a row."""
     rows = PIPELINE_CASES[case]
     monkeypatch.setenv("LLM_MCP_TPU_Q8_DECODE", "blocked")
     monkeypatch.setenv("LLM_MCP_TPU_Q8_SCALE_PACK", pack)
     A.decode_attend_q8.clear_cache()  # env knobs are read at trace time
     rng = np.random.default_rng(7)
+    abreast = form == "abreast"
     if rows is None:
-        L, B, Hkv, S, hd, G = 2, 3, 2, 256, 64, 2
+        L, B, Hkv, S, hd, G = 2, 3, 4 if abreast else 2, 256, 64, 2
         lens, kw = _lens_for(float(case.split("_")[1]), B, S, rng), {}
     else:
-        L, B, Hkv, S, hd, G = 2, len(rows), 2, 4 * block, 64, 2
+        L, B, Hkv, S, hd, G = 2, len(rows), 4 if abreast else 2, 4 * block, 64, 2
         lens = jnp.asarray([a * block + b for a, b in rows], jnp.int32)
         kw = {"block_s": block}
-    ck, cv = _fused_q8_cache(rng, L, B, Hkv, S, hd)
+    ck, cv = _fused_q8_cache(rng, L, B, Hkv, S, hd, abreast=abreast)
+    assert A.fused_q8_heads(ck)[2] == (2 if abreast else 1)
     q = jnp.asarray(rng.standard_normal((B, Hkv, G, hd)), jnp.float32)
     nk = jnp.asarray(rng.standard_normal((B, Hkv, hd)), jnp.float32)
     nv = jnp.asarray(rng.standard_normal((B, Hkv, hd)), jnp.float32)
@@ -120,6 +144,13 @@ def test_q8_gqa_blocked_parity(monkeypatch, case, pack, block, ids_kind):
     # tolerance covers the kernel's q/prob int8 requantization
     assert float(jnp.max(jnp.abs(jnp.where(seated, out - ref, 0.0)))) < 0.05
     assert not bool(jnp.isnan(jnp.where(seated, out, 0.0)).any())
+    if abreast:  # a row of two heads gives each head what its own row gave it
+        apart = A.decode_attend_q8(
+            q, nk, nv, _heads_apart(ck), cv, jnp.int32(1), lens, slot_ids=ids,
+            interpret=True, **kw)
+        np.testing.assert_allclose(
+            np.asarray(jnp.where(seated, out, 0.0)), np.asarray(jnp.where(seated, apart, 0.0)),
+            rtol=0, atol=1e-6)
 
 
 def test_q8_block_tokens_is_a_function_of_the_caches_shape():
@@ -128,6 +159,7 @@ def test_q8_block_tokens_is_a_function_of_the_caches_shape():
     divides the row; 0 where nothing int8-tileable does."""
     assert A.q8_block_tokens(17, 2048, 128) == 256  # decode_closed
     assert A.q8_block_tokens(17, 1024, 128) == 256  # solar_decode_closed
+    assert A.q8_block_tokens(9, 1024, 128) == 256  # granite_ / lfm2_decode_closed: heads of 64 abreast
     assert A.q8_block_tokens(61, 1024, 128) == 128  # olmo_hybrid_decode_closed
     assert A.q8_block_tokens(61, 1024 + 64, 128) == 64
     assert A.q8_block_tokens(400, 1024, 128) == 32  # none within the bound: the smallest
@@ -167,15 +199,40 @@ def test_attn_stream_counts_a_rounds_steps(heads, block):
     assert book.stats()["steps"] == 6 and book.stats()["tokens_live"] == live + 1 + 2
 
 
+@pytest.mark.parametrize("shape,kv_heads,block,abreast", [
+    ((4, 64, 9, 1024, 128), 8, 256, 2),  # Granite-4.0-H: 8 KV heads of 64, two abreast
+    ((36, 32, 17, 2048, 128), 8, 256, 1),  # Qwen3-8B: 8 KV heads of 128
+    ((5, 64, 61, 1024, 128), 30, 128, 1),  # Olmo-Hybrid
+    ((4, 64, 9, 1024, 128), 0, 256, 1),  # without the configuration's heads: a head a row
+], ids=["granite", "qwen3_8b", "olmo_hybrid", "heads_not_given"])
+def test_attn_stream_says_how_many_heads_lie_abreast(shape, kv_heads, block, abreast):
+    """`perf_stats()["decode_attn"]["heads_abreast"]`: P of the cache the arm
+    streams, by `fused_q8_heads`' rule from the payload's rows and the
+    configuration's KV heads, beside the block of that shape."""
+    book = A.AttnStream(shape, kv_heads=kv_heads)
+    assert book.block_tokens == block and book.heads_abreast == abreast
+    got = book.stats()
+    assert got["heads_abreast"] == abreast and got["block_tokens"] == block
+    if kv_heads:
+        ck = {"q": jax.ShapeDtypeStruct(shape, jnp.int8),
+              "s": jax.ShapeDtypeStruct((*shape[:2], 2 * kv_heads, shape[3]), jnp.bfloat16)}
+        assert A.fused_q8_heads(ck) == (kv_heads, 1, abreast)
+
+
+@pytest.mark.parametrize("Hkv,hd", [(2, 32), (4, 64), (2, 128)], ids=["hd32", "hd64_abreast", "hd128"])
 @pytest.mark.parametrize("fill", FILLS)
-def test_q8_gqa_whole_parity(monkeypatch, fill):
+def test_q8_gqa_whole_parity(monkeypatch, fill, Hkv, hd):
     """Fused whole-S q8 kernel (payload head-block + plain-scales DMA) vs
-    the exact-f32 fallback at the same fills."""
+    the exact-f32 fallback at the same fills; a head of 64 beside one of 128:
+    four KV heads of 64 lie two to a row, and since the int8 products of a row
+    of two heads are each head's own exactly, the output is the same bytes' a
+    head a row BIT FOR BIT."""
     monkeypatch.setenv("LLM_MCP_TPU_Q8_DECODE", "whole")
     A.decode_attend_q8.clear_cache()
     rng = np.random.default_rng(8)
-    L, B, Hkv, S, hd, G = 2, 3, 2, 64, 32, 2
-    ck, cv = _fused_q8_cache(rng, L, B, Hkv, S, hd)
+    L, B, S, G = 2, 3, 64, 2
+    ck, cv = _fused_q8_cache(rng, L, B, Hkv, S, hd, abreast=True)
+    assert A.fused_q8_heads(ck)[2] == (2 if hd == 64 else 1)
     q = jnp.asarray(rng.standard_normal((B, Hkv, G, hd)), jnp.float32)
     nk = jnp.asarray(rng.standard_normal((B, Hkv, hd)), jnp.float32)
     nv = jnp.asarray(rng.standard_normal((B, Hkv, hd)), jnp.float32)
@@ -185,6 +242,13 @@ def test_q8_gqa_whole_parity(monkeypatch, fill):
         q, nk, nv, ck, cv, jnp.int32(0), lens, hd**-0.5, None
     )
     assert float(jnp.max(jnp.abs(out - ref))) < 0.05
+    apart = _heads_apart(ck)
+    np.testing.assert_array_equal(
+        np.asarray(ref), np.asarray(A._decode_attend_q8_fallback(
+            q, nk, nv, apart, cv, jnp.int32(0), lens, hd**-0.5, None)))
+    np.testing.assert_array_equal(
+        np.asarray(out),
+        np.asarray(A.decode_attend_q8(q, nk, nv, apart, cv, jnp.int32(0), lens, interpret=True)))
 
 
 # -- GQA bf16 (split arrays) -------------------------------------------------
@@ -366,20 +430,23 @@ def _paged_tables(B, nbs, nshared):
 # configuration (packed scales; and for bf16/MLA the mid-fill case that
 # exercises both shared and private blocks) runs in tier-1, the rest of
 # the fill x pack grid is slow-marked and covered by `-m slow` runs.
+@pytest.mark.parametrize("form", ["apart", "abreast"])
 @pytest.mark.parametrize(
     "pack", [pytest.param("0", marks=pytest.mark.slow), "1"])
 @pytest.mark.parametrize("fill", FILLS)
-def test_q8_gqa_paged_parity(monkeypatch, fill, pack):
+def test_q8_gqa_paged_parity(monkeypatch, fill, pack, form):
     """Block-indirect fused-q8 kernel (packed and unpacked) vs the plain
-    contiguous fallback on the pre-split reference cache."""
+    contiguous fallback on the pre-split reference cache; `abreast`: arena and
+    pool with four KV heads of 64 two to a row."""
     monkeypatch.setenv("LLM_MCP_TPU_Q8_DECODE", "paged")
     monkeypatch.setenv("LLM_MCP_TPU_Q8_SCALE_PACK", pack)
     A.decode_attend_q8.clear_cache()
     rng = np.random.default_rng(21)
-    L, B, Hkv, S, hd, G, bt = 2, 3, 2, 256, 64, 2, 64
+    L, B, Hkv, S, hd, G, bt = 2, 3, 4 if form == "abreast" else 2, 256, 64, 2, 64
     nbs = S // bt
     nshared = min(nbs, round(fill * nbs))
-    ck, cv = _fused_q8_cache(rng, L, B, Hkv, S, hd)
+    ck, cv = _fused_q8_cache(rng, L, B, Hkv, S, hd, abreast=form == "abreast")
+    assert ck["q"].shape == (L, B, 5, S, 128 if form == "abreast" else 64)
     ref, arena, pool = _paged_split(ck, bt, nshared, nbs, rng)
     tbl = _paged_tables(B, nbs, nshared)
     q = jnp.asarray(rng.standard_normal((B, Hkv, G, hd)), jnp.float32)
@@ -483,13 +550,19 @@ def test_paged_fallback_gather_matches_contiguous():
 # -- append kernels ----------------------------------------------------------
 
 
-def test_append_q8_kernel_parity(monkeypatch):
+@pytest.mark.parametrize("Hkv,hd", [(2, 128), (4, 64), (4, 32)],
+                         ids=["hd128", "hd64_two_abreast", "hd32_four_abreast"])
+def test_append_q8_kernel_parity(monkeypatch, Hkv, hd):
     """The aliased tile-rewrite append vs the XLA scatter at a lane-aligned
-    shape (hd=128, S=128 — the kernel path): identical bytes, including
-    the packed pseudo-head, with parked rows and compaction ids."""
+    shape (rows of 128 lanes, S=128 — the kernel path): identical bytes,
+    including the packed pseudo-head, with parked rows and compaction ids;
+    heads of 128, and heads of 64 and of 32 abreast in rows of 128 lanes (the
+    kernel selects a full-lane row whatever lies in it)."""
     rng = np.random.default_rng(14)
-    L, B, Hkv, S, hd = 2, 3, 2, 128, 128
-    ck, cv = _fused_q8_cache(rng, L, B, Hkv, S, hd)
+    L, B, S = 2, 3, 128
+    ck, cv = _fused_q8_cache(rng, L, B, Hkv, S, hd, abreast=True)
+    assert ck["q"].shape[-1] == 128 and A.fused_q8_heads(ck) == (Hkv, 1, 128 // hd)
+    falls = dict(A.reference_falls)
     nk = jnp.asarray(rng.standard_normal((L, B, Hkv, hd)), jnp.float32)
     nv = jnp.asarray(rng.standard_normal((L, B, Hkv, hd)), jnp.float32)
     lens = jnp.asarray([0, S, 100], jnp.int32)  # row 1 parked: writes nothing
@@ -503,6 +576,87 @@ def test_append_q8_kernel_parity(monkeypatch):
     np.testing.assert_array_equal(np.asarray(out_k["q"]), np.asarray(ref_k["q"]))
     np.testing.assert_array_equal(np.asarray(out_k["s"]), np.asarray(ref_k["s"]))
     assert out_v == ref_v == {}
+    # the kernel ran: with interpret off this shape would not have fallen either
+    jax.eval_shape(lambda *a: A.append_kv_q8(*a, slot_ids=ids, interpret=False), ck, cv, nk, nv, lens)
+    assert A.reference_falls == falls
+    # and what it wrote is the step's K/V: the row read back a head at a time
+    k_rows, v_rows = A.fused_kv(out_k["q"], Hkv, 128 // hd)
+    kq = jax.jit(lambda x: A._q8_step_rows(_heads_apart(ck), x, x)[0])(nk)[:, :, :Hkv]
+    np.testing.assert_array_equal(np.asarray(k_rows[:, 2, :, 0]), np.asarray(kq[:, 0]))
+
+
+@pytest.mark.parametrize("Hkv,hd,P", [(4, 64, 2), (8, 64, 2), (4, 32, 4), (2, 128, 1), (2, 32, 1), (3, 64, 1)])
+def test_kv_heads_abreast_round_trip(Hkv, hd, P):
+    """`kv_heads_abreast`'s rule and the two directions of the form: heads
+    narrower than the 128 lanes that divide them lie P = 128 // hd to a row
+    where P divides the heads; head p*R + r in lanes [p*hd, (p+1)*hd) of row r;
+    `kv_apart` undoes `kv_abreast`, `ctx_apart` undoes `q_abreast`, and a
+    query's row holds zeros outside its own head's lanes."""
+    assert A.kv_heads_abreast(Hkv, hd) == P
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.integers(-127, 128, (2, 3, Hkv, 8, hd), dtype="int8"))
+    rows = A.kv_abreast(x, P)
+    R = Hkv // P
+    assert rows.shape == (2, 3, R, 8, P * hd)
+    for h in range(Hkv):
+        p, r = divmod(h, R)
+        np.testing.assert_array_equal(
+            np.asarray(rows[:, :, r, :, p * hd:(p + 1) * hd]), np.asarray(x[:, :, h]))
+    np.testing.assert_array_equal(np.asarray(A.kv_apart(rows, P)), np.asarray(x))
+    k, v = A.fused_kv(jnp.concatenate([rows, rows + 0, rows[:, :, :1]], axis=2), Hkv, P)
+    np.testing.assert_array_equal(np.asarray(k), np.asarray(x))
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(x))
+    G = 3
+    q = jnp.asarray(rng.integers(-9, 10, (5, Hkv, G, hd)), jnp.float32)  # whole numbers: exact sums
+    qw = A.q_abreast(q, P)
+    assert qw.shape == (5, R, P * G, P * hd)
+    np.testing.assert_array_equal(np.asarray(A.ctx_apart(qw, P)), np.asarray(q))
+    assert int(jnp.sum(qw != 0)) == int(jnp.sum(q != 0))  # zeros everywhere else
+    # a product over all of a row's lanes is each head's own
+    kf = x[0, 0].astype(jnp.float32)  # [Hkv, 8, hd]
+    want = jnp.einsum("bhgd,hsd->bhgs", q, kf)
+    got = jnp.einsum("brgw,rsw->brgs", qw, rows[0, 0].astype(jnp.float32))  # [5, R, P*G, 8]
+    got = got.reshape(5, R, P, G, 8).transpose(0, 2, 1, 3, 4).reshape(5, Hkv, G, 8)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("Hkv,hd", [(4, 64), (8, 64), (2, 128), (4, 32)])
+def test_fuse_prompt_kv_and_step_rows_pack_the_same_bytes(Hkv, hd):
+    """A prompt's rows (`fuse_prompt_kv`: admit, chunk and mixed programs) and
+    a decode step's row (`_q8_step_rows`: the append) land in one form, the
+    cache's own (`init_kv_cache`): position t of the prompt's entry is the
+    step's row of the same vectors, byte for byte, pseudo-head included, and
+    the packed scales read back (`unpack_scales`) are the plain ones."""
+    from llm_mcp_tpu.models.configs import ModelConfig
+    from llm_mcp_tpu.models.llama import fuse_prompt_kv, init_kv_cache
+    from llm_mcp_tpu.models.quant import unpack_scales
+
+    rng = np.random.default_rng(5)
+    L, B, S = 2, 3, 16
+    k = jnp.asarray(rng.standard_normal((L, B, Hkv, S, hd)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((L, B, Hkv, S, hd)), jnp.float32)
+    entry = jax.jit(lambda k, v: fuse_prompt_kv(k, v, scale_dtype=jnp.float32))(k, v)
+    P = A.kv_heads_abreast(Hkv, hd)
+    cfg = ModelConfig(name="t", vocab_size=8, dim=Hkv * hd, n_layers=L, n_heads=Hkv,
+                      n_kv_heads=Hkv, ffn_hidden=8, head_dim=hd)
+    made = jax.eval_shape(lambda: init_kv_cache(cfg, B, S, dtype=jnp.float32, quantized=True))
+    assert made["k"]["q"].shape == entry["q"].shape == (L, B, 2 * Hkv // P + 1, S, P * hd)
+    assert made["k"]["s"].shape == entry["s"].shape == (L, B, 2 * Hkv, S)
+    assert A.fused_q8_heads(entry) == (Hkv, 1, P)
+    for t in (0, 7, S - 1):
+        pay, s_new = jax.jit(lambda k, v: A._q8_step_rows(entry, k, v))(
+            k[:, :, :, t], v[:, :, :, t])
+        np.testing.assert_array_equal(np.asarray(pay), np.asarray(entry["q"][:, :, :, t]))
+        np.testing.assert_array_equal(np.asarray(s_new), np.asarray(entry["s"][:, :, :, t]))
+    np.testing.assert_array_equal(
+        np.asarray(unpack_scales(entry["q"][:, :, -1], 2 * Hkv, jnp.float32)),
+        np.asarray(entry["s"]))
+    # and the heads read back a row at a time are the quantised K and V
+    kq, vq = A.fused_kv(entry["q"], Hkv, P)
+    deq = kq.astype(jnp.float32) * entry["s"][:, :, :Hkv, :, None]
+    assert float(jnp.max(jnp.abs(deq - k))) < float(jnp.max(jnp.abs(k))) / 127 * 0.51
+    deq = vq.astype(jnp.float32) * entry["s"][:, :, Hkv:, :, None]
+    assert float(jnp.max(jnp.abs(deq - v))) < float(jnp.max(jnp.abs(v))) / 127 * 0.51
 
 
 def test_append_bf16_kernel_parity(monkeypatch):
@@ -631,6 +785,35 @@ def test_ragged_prefill_q8_parity(fill, paged):
     out = A.ragged_prefill_attend_q8(*args, impl="kernel", interpret=True, **kw)
     assert float(jnp.max(jnp.abs(out[:total] - ref[:total]))) < 1e-4
     assert not bool(jnp.isnan(out).any())
+
+
+@pytest.mark.parametrize("paged", [False, True])
+def test_ragged_prefill_q8_reads_heads_abreast(paged):
+    """Over a cache of four KV heads of 64 two to a row the ragged prefill's
+    past rows are read through `fused_kv` by the XLA arm, whichever arm is asked
+    for (the kernel walks the heads under a `fori_loop` and has no form of a
+    head's lanes at a traced index): what it returns is what the same bytes a
+    head a row give."""
+    rng = np.random.default_rng(33)
+    L, Hkv, G, hd, S, bt, B = 2, 4, 2, 64, 128, 32, 6
+    R, T, total, rowids, offsets, slots, starts, tbl, nbs, pxb = _ragged_case(0.4, S, bt, B)
+    ck, _ = _fused_q8_cache(rng, L, B, Hkv, S, hd, abreast=True)
+    pool, _ = _fused_q8_cache(rng, L, pxb, Hkv, bt, hd, abreast=True)
+    assert ck["q"].shape == (L, B, 5, S, 128) and pool["q"].shape == (L, pxb, 5, bt, 128)
+    q = jnp.asarray(rng.standard_normal((T, Hkv, G, hd)), jnp.float32)
+    ks = jnp.asarray(rng.standard_normal((T, Hkv, hd)), jnp.float32)
+    vs = jnp.asarray(rng.standard_normal((T, Hkv, hd)), jnp.float32)
+
+    def run(ck, pool, impl):
+        return A.ragged_prefill_attend_q8(
+            q, ks, vs, ck, 1, rowids, offsets, slots, starts, scale=hd**-0.5, skey=0,
+            block_q=16, block_tables=jnp.asarray(tbl) if paged else None,
+            pool=pool if paged else None, impl=impl, interpret=True)
+
+    want = run(_heads_apart(ck), _heads_apart(pool), "xla")
+    for impl in ("xla", "kernel"):
+        got = run(ck, pool, impl)
+        np.testing.assert_allclose(np.asarray(got[:total]), np.asarray(want[:total]), atol=1e-5)
 
 
 @pytest.mark.parametrize(

@@ -13,6 +13,7 @@ import pytest
 
 from llm_mcp_tpu.executor import GenerationEngine
 from llm_mcp_tpu.executor.engine import GenRequest
+from llm_mcp_tpu.kernels.attention import fused_q8_heads
 
 S, B, K = 128, 8, 2
 
@@ -115,7 +116,9 @@ def _rows_match(eng, ck, ck_ref, slot, n):
     layers pass through the prompt's attention, which admit_fn runs as the
     flash kernel and the mixed step as one masked product: float32 rounding
     apart, so scales to 1e-5 and a payload step of 1 on a few entries."""
-    hk = 2 * eng.cfg.n_kv_heads
+    heads, _, abreast = fused_q8_heads(ck)  # the K and V rows; the packed scales' row follows them
+    hk = 2 * heads // abreast
+    assert heads == eng.cfg.n_kv_heads
     q, q_ref = ck["q"][:, slot, :hk, :n].astype(int), ck_ref["q"][:, slot, :hk, :n].astype(int)
     s, s_ref = ck["s"][:, slot, :, :n], ck_ref["s"][:, slot, :, :n]
     assert np.array_equal(ck["q"][0, slot, :, :n], ck_ref["q"][0, slot, :, :n])

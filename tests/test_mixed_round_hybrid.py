@@ -8,6 +8,8 @@ dense family's cases are tests/test_mixed_round.py's."""
 import numpy as np
 import pytest
 
+from llm_mcp_tpu.kernels.attention import fused_q8_heads
+
 from test_mixed_round import (  # noqa: F401 (_engines_end: an autouse fixture)
     B, K, S, _admit_arrays, _engine, _engines_end, _prompt, _restore, _ride_arrays, _seed_rows, _state,
     every_mixed_shape_is_in_the_zoo, rides_beside_active_rows,
@@ -40,7 +42,9 @@ def _kv_close(eng, ck, ck_ref, slot, n):
     flash kernel in admit_fn and one masked product in the mixed step, and the
     recurrence before it the same chunks in a scan of another length, so
     float32 rounding apart: scales to 1e-4, a payload step of 1 on a few entries."""
-    hk = 2 * eng.cfg.n_kv_heads
+    heads, _, abreast = fused_q8_heads(ck)  # the K and V rows; the packed scales' row follows them
+    hk = 2 * heads // abreast
+    assert heads == eng.cfg.n_kv_heads
     q, q_ref = ck["q"][:, slot, :hk, :n].astype(int), ck_ref["q"][:, slot, :hk, :n].astype(int)
     assert np.abs(q - q_ref).max() <= 1 and (q != q_ref).mean() < 2e-3
     np.testing.assert_allclose(ck["s"][:, slot, :, :n], ck_ref["s"][:, slot, :, :n], rtol=1e-4)

@@ -207,6 +207,28 @@ def test_layout_name_matrix():
     }
 
 
+def test_fused_scale_row_is_as_wide_as_the_caches_rows():
+    """The packed scales ride a pseudo-head as wide as the payload's rows: a
+    head, or the 128 lanes where narrower heads lie abreast. The rule is
+    `kernels/attention.py:kv_heads_abreast`, restated in this module (it
+    imports nothing of jax): held to it here, and to what `init_kv_cache`
+    allocates at Granite-4.0-H's and Qwen3-8B's heads."""
+    from llm_mcp_tpu.kernels.attention import kv_heads_abreast
+    from llm_mcp_tpu.telemetry.perf import _fused_scale_bytes
+
+    for hkv in (1, 2, 3, 4, 8, 12, 30):
+        for hd in (16, 32, 64, 96, 128, 256):
+            width = hd * kv_heads_abreast(hkv, hd)
+            assert _fused_scale_bytes(hkv, hd) == -(-2 * hkv * 4 // width) * width, (hkv, hd)
+    # 8 KV heads of 64: 9 rows of 128 lanes a position (17 x 64 padded to as much in VMEM)
+    granite = ModelShape(dim=2048, n_layers=4, n_heads=32, n_kv_heads=8, head_dim=64,
+                         param_count=1)
+    assert kv_bytes_per_token(granite, "gqa_int8") == 4 * 9 * 128
+    qwen = ModelShape(dim=4096, n_layers=36, n_heads=32, n_kv_heads=8, head_dim=128,
+                      param_count=1)
+    assert kv_bytes_per_token(qwen, "gqa_int8") == 36 * 17 * 128
+
+
 def test_kv_bytes_per_token_orderings():
     # bf16 GQA: L * 2 (k+v) * Hkv * hd * 2 bytes
     assert kv_bytes_per_token(SHAPE, "gqa_bf16") == 16 * 2 * 4 * 128 * 2

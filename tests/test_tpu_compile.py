@@ -140,29 +140,37 @@ def test_decode_attend_q8(sd, monkeypatch, mode):
     )
 
 
-# [rows, KV heads, group], cache length, layers: the blocked arm as each
-# generation cell of BENCHMARK.json runs it (PERF.md section 4)
+# [rows, KV heads, group, head size], cache length, layers: the blocked arm as
+# each generation cell of BENCHMARK.json runs it (PERF.md section 4); the heads
+# of 64 lie two abreast in rows of 128 lanes (`kv_heads_abreast`)
 CELL_SHAPES = {
-    "decode_closed": ((32, 8, 4), 2048, 36),
-    "solar_decode_closed": ((64, 8, 8), 1024, 1),
-    "olmo_hybrid_decode_closed": ((64, 30, 1), 1024, 5),
+    "decode_closed": ((32, 8, 4, HD), 2048, 36),
+    "solar_decode_closed": ((64, 8, 8, HD), 1024, 1),
+    "olmo_hybrid_decode_closed": ((64, 30, 1, HD), 1024, 5),
+    "granite_decode_closed": ((64, 8, 4, 64), 1024, 4),
+    "lfm2_decode_closed": ((64, 8, 4, 64), 1024, 3),
 }
 
 
 @pytest.mark.parametrize("mode", ["blocked", "auto"])
 @pytest.mark.parametrize("cell", list(CELL_SHAPES))
 def test_decode_attend_q8_blocked_at_the_cells_shapes(sd, monkeypatch, cell, mode):
-    """The batch-wide pipeline of the blocked arm at the three shapes the
-    benchmark runs it at, with the block size its rule gives there: a Mosaic
-    call under the name the trace readers look for, and no fall."""
+    """The batch-wide pipeline of the blocked arm at the shapes the benchmark
+    runs it at, with the block size its rule gives there: a Mosaic call under
+    the name the trace readers look for, and no fall. At heads of 64 the rows
+    are two heads wide, P*hd = 128, and the arm's copies cut whole rows."""
     monkeypatch.setenv("LLM_MCP_TPU_Q8_DECODE", mode)
-    (ba, hkv, g), seq, layers = CELL_SHAPES[cell]
-    cache = {"q": sd((layers, ba, 2 * hkv + 1, seq, HD), I8),
+    (ba, hkv, g, hd), seq, layers = CELL_SHAPES[cell]
+    P = A.kv_heads_abreast(hkv, hd)
+    assert P * hd == 128
+    cache = {"q": sd((layers, ba, 2 * hkv // P + 1, seq, P * hd), I8),
              "s": sd((layers, ba, 2 * hkv, seq), BF)}
+    assert A.fused_q8_heads(cache) == (hkv, 1, P)
+    assert A.q8_block_tokens(cache["q"].shape[2], seq, P * hd) == (128 if hkv == 30 else 256)
     text = compile_for_chip(
         lambda q, nk, nv, ck, li, n, ids: A.decode_attend_q8(
             q, nk, nv, ck, {}, li, n, slot_ids=ids, interpret=False),
-        sd((ba, hkv, g, HD), BF), sd((ba, hkv, HD), BF), sd((ba, hkv, HD), BF),
+        sd((ba, hkv, g, hd), BF), sd((ba, hkv, hd), BF), sd((ba, hkv, hd), BF),
         cache, sd((), I32), sd((ba,), I32), sd((ba,), I32),
     )
     assert "decode_attn_q8_blocked" in text
@@ -494,6 +502,20 @@ def test_chunk_scan(sd, name, H, dk, dv, one_group, rows, T, packed):
         assert f"f32[{rows},{T},{H},{dk}]" not in text and f",{H},32,{dk}]" not in text
 
 
+def cache_relayouts(text: str, cache_q_shape) -> list[str]:
+    """The compiled module's lines that COPY an int8 array of the KV cache's
+    size: a `copy` to another layout, or the `remat_compressed` /
+    `_uncompressed` pair the compiler makes of one to save memory. (An update
+    in place, `dynamic-update-slice` or a fusion of one, has the cache's shape
+    too and is no copy.) A cache whose minor dimension was a head of 64 was
+    laid out with positions minor and copied to the kernels' layout and back in
+    every step program (PERF.md section 6, PR 55)."""
+    import re
+
+    made = re.compile(r"= s8\[" + ",".join(map(str, cache_q_shape)) + r"\]\{[^}]*\} copy\(")
+    return [line.strip()[:160] for line in text.splitlines() if made.search(line)]
+
+
 def grouped_kernels_in(text: str) -> bool:
     """Both of `kernels/grouped.py`'s calls, and no product over all the pairs."""
     assert "ragged-dot" not in text
@@ -677,12 +699,15 @@ def test_granite_step_programs_fit_beside_64_slots(sd, granite, chip_kernels, wh
     `granite-4.0-h-micro` at its cell's 64 slots x 1024 compile for the
     described v5e with their kernels: `ssd_decode_step` on the pool's
     [36, 64, 32, 128, 128] (two heads of 64 values abreast), the decode attention
-    (the whole-S arm: a head of 64 lies padded to 128 lanes in HBM, which the
-    blocked arm's copies cannot cut) and the append kernel at head size 64, as
-    Mosaic calls with no fall to their reference; the flash prefill kernel in the
-    admit programs. Each fits under 15.0 GiB and updates the KV cache and the
-    4.5 GiB state pool in place; the pool's bytes as the compiler lays it out are
-    its logical bytes. GiB in PERF.md section 4 as "described-chip compile"."""
+    (both arms under the dispatcher's `cond`: the cache's heads of 64 lie two
+    abreast in rows of 128 lanes, which the blocked arm's copies cut) and the
+    append kernel on those rows, as Mosaic calls with no fall to their
+    reference; the flash prefill kernel in the admit programs. No program copies
+    the cache to another layout (at [4, 64, 17, 1024, 64] every one did, 0.27
+    GiB there and 0.27 back: the decode round's temporaries were 0.56 GiB). Each
+    fits under 15.0 GiB and updates the KV cache and the 4.5 GiB state pool in
+    place; the pool's bytes as the compiler lays it out are its logical bytes.
+    GiB in PERF.md section 4 as "described-chip compile"."""
     from llm_mcp_tpu.models import ssm
 
     cfg, params, cache = granite
@@ -696,12 +721,13 @@ def test_granite_step_programs_fit_beside_64_slots(sd, granite, chip_kernels, wh
     assert "%kda_decode_step" not in text and "%gdn_decode_step" not in text
     if which == "decode":
         assert "decode_attn_q8_whole" in text and "append_kv_q8" in text
-        assert "decode_attn_q8_blocked" not in text
+        assert "decode_attn_q8_blocked" in text
     if which == "admit":
         assert "flash_prefill_attn" in text
     S = cache["v"]["state"]["S"]
     assert S.shape == (36, 64, 32, 128, 128) and ssm.state_abreast(cfg) == 2
-    assert cache["k"]["q"].shape == (4, 64, 17, 1024, 64)
+    assert cache["k"]["q"].shape == (4, 64, 9, 1024, 128)
+    assert cache_relayouts(text, cache["k"]["q"].shape) == []
     pool = jax.jit(lambda s: s + 1.0).lower(S).compile().memory_analysis()
     assert pool.argument_size_in_bytes == 36 * 64 * 64 * 128 * 64 * 4
     mem = compiled.memory_analysis()
@@ -712,6 +738,8 @@ def test_granite_step_programs_fit_beside_64_slots(sd, granite, chip_kernels, wh
           f"{mem.argument_size_in_bytes / 2**30:.2f} GiB")
     assert total < 15.0 * 2**30
     assert mem.alias_size_in_bytes > 4.5 * 2**30  # KV cache and state pool updated in place
+    if which == "decode":  # 0.05 GiB; 0.56 with the cache re-laid and back
+        assert mem.temp_size_in_bytes < 0.15 * 2**30
 
 
 @pytest.fixture(scope="module")
@@ -798,9 +826,11 @@ def test_lfm2_step_programs_fit_with_the_banks_whole_and_the_tails_in_place(
     published widths, 14 layers, all 32 experts of 2048 x 1792 a layer) at its
     cell's 64 slots x 1024 compile for the described v5e: the two grouped expert
     kernels at banks of [2048, 1792] (one column block of two banks, 14.7 MB) and
-    [1792, 2048], the decode attention's whole-S arm and the append kernel at
-    heads of 64 WITH rotation, the flash prefill kernel in the admit programs,
-    every one a Mosaic call with no fall to its reference. Each fits the chip;
+    [1792, 2048], the decode attention (both arms: heads of 64 WITH rotation,
+    two abreast in rows of 128 lanes) and the append kernel on those rows, the
+    flash prefill kernel in the admit programs, every one a Mosaic call with no
+    fall to its reference. No program copies the cache to another layout. Each
+    fits the chip;
     the temporaries hold no copy of a layer's banks (0.66 GiB a layer; the
     stack goes in whole) nor of a leading layer's feed-forward (84 MB, unstacked:
     a slice of a stack at a fixed index was copied out every step), and the KV
@@ -821,12 +851,13 @@ def test_lfm2_step_programs_fit_with_the_banks_whole_and_the_tails_in_place(
                    for form in ("decode_step", "chunk_scan"))
     if which in ("decode", "mixed"):
         assert "decode_attn_q8_whole" in text and "append_kv_q8" in text
-        assert "decode_attn_q8_blocked" not in text
+        assert "decode_attn_q8_blocked" in text
     if which == "admit":
         assert "flash_prefill_attn" in text
     state = cache["v"]["state"]
     assert set(state) == {"conv"} and state["conv"].shape == (11, 64, 2 * 2048)
-    assert cache["k"]["q"].shape == (3, 64, 17, 1024, 64)
+    assert cache["k"]["q"].shape == (3, 64, 9, 1024, 128)
+    assert cache_relayouts(text, cache["k"]["q"].shape) == []
     assert params["layers"]["w1e"].shape == (12, 32, 2048, 1792) and len(params["first"]) == 2
     nbytes = lambda tree: sum(  # noqa: E731
         int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(tree))
@@ -841,16 +872,18 @@ def test_lfm2_step_programs_fit_with_the_banks_whole_and_the_tails_in_place(
           f"{mem.argument_size_in_bytes / 2**30:.2f} GiB (weights {weights / 2**30:.2f}, "
           f"KV cache {kv / 2**30:.2f}, tails {pool / 2**20:.1f} MiB)")
     assert total < 12.0 * 2**30
-    assert mem.temp_size_in_bytes < 0.6 * 2**30  # under one layer's banks
+    # under one layer's banks; a decode or mixed round's are 0.02 and 0.05 GiB
+    # (0.42 and 0.45 with the cache of 0.20 GiB re-laid and back)
+    assert mem.temp_size_in_bytes < (0.15 if which in ("decode", "mixed") else 0.6) * 2**30
     assert mem.alias_size_in_bytes > 0.99 * (pool + kv)  # KV cache and tails updated in place
 
 
 @pytest.mark.parametrize("rung", [128, 256])
 @pytest.mark.parametrize("name,kernel,limit,temps", [
     ("solar", "%kda_decode_step", 15.75, 0.25), ("olmo", "%gdn_decode_step", 15.0, 0.35),
-    # its cache of 0.27 GiB is re-laid for the kernels and back, as in its decode
-    # round (heads of 64: PERF.md section 7), and the prompts' states ride the scan
-    ("granite", "%ssd_decode_step", 15.0, 1.25)])
+    # the prompts' states ride the scan (its cache of 0.27 GiB was re-laid for the
+    # kernels and back besides, 1.06 GiB in all, while its heads of 64 lay a row each)
+    ("granite", "%ssd_decode_step", 15.0, 0.75)])
 def test_hybrid_mixed_round_fits_beside_its_decode_round(
     sd, request, chip_kernels, name, kernel, limit, temps, rung
 ):
@@ -891,6 +924,7 @@ def test_hybrid_mixed_round_fits_beside_its_decode_round(
     assert total < limit * 2**30
     assert mem.temp_size_in_bytes < temps * 2**30 < pool
     assert mem.alias_size_in_bytes > 0.99 * (pool + kv)
+    assert cache_relayouts(text, cache["k"]["q"].shape) == []
 
 
 def test_a_fall_to_the_reference_is_counted(tmp_path):
