@@ -50,6 +50,7 @@ from jax.profiler import TraceAnnotation
 
 from ..kernels.attention import (
     AttnStream,
+    mla_stream_block,
     pallas_supported,
     ragged_prefill_max_tokens,
     resolve_attn_impl,
@@ -560,17 +561,25 @@ class GenerationEngine:
                         self.kv_quant, jnp.dtype(dtype).name)
             self.kv_quant = ""
         if self.cfg.kv_lora_rank:
-            # MLA (models/mla.py): chunked prefill runs the absorbed form
-            # against the latent cache (models/mla.py:
-            # mla_prefill_chunk_batch) — long prompts interleave with decode
-            # rounds and the prompt-prefix KV cache applies, exactly as for
-            # the GQA families. int8 latents (kv_quant=int8): ~7x fewer
-            # cache bytes than bf16 GQA K/V; decode runs the s8-MXU kernel
+            # MLA (models/mla.py): whole prompts prefill expanded and
+            # query-blocked (mla_prefill), chunked prefill runs the absorbed
+            # form against the latent cache (mla_prefill_chunk_batch, or
+            # packed, mla_prefill_chunk_ragged) — long prompts interleave with
+            # decode rounds and the prompt-prefix KV cache applies, exactly
+            # as for the GQA families, unless the pair's second member also
+            # carries the expert layer's counts (a share of DeepSeek-V3-style
+            # experts, `CacheLayout.counted`: memory.COUNTED_OFF lists what
+            # such a configuration runs without). int8 latents
+            # (kv_quant=int8): ~7x fewer cache bytes than bf16 GQA K/V;
+            # decode runs the s8-MXU kernel
             # (kernels/attention.py:decode_attend_q8_mla) — whole-S tiles
-            # at serving context lengths, blocked HBM streaming with a
-            # dynamic trip count past its VMEM budget (S=32k included);
-            # the XLA dequant-then-dot path remains only for cache lengths
-            # no 128-multiple block divides.
+            # at serving context lengths (every row's S positions a step,
+            # whatever its fill: perf_stats()["decode_attn"] counts both),
+            # blocked HBM streaming with a dynamic trip count past its VMEM
+            # budget (S=32k included); the XLA dequant-then-dot path remains
+            # only for cache lengths no 128-multiple block divides. An
+            # admission never rides a decode round on a latent cache
+            # (`_ride_off`).
             if self.kv_quant:
                 log.info(
                     "MLA int8 latents: ~2x context capacity vs bf16 "
@@ -733,9 +742,13 @@ class GenerationEngine:
             if isinstance(self._cv, dict) and "moe" in self._cv else None)
         # what the blocked int8 decode-attention arm streams, where decode
         # rounds run it (int8 GQA cache read by the Pallas kernel): None else
-        self._attn_stream = (
-            AttnStream(self._ck["q"].shape, kv_heads=self.cfg.n_kv_heads)
-            if layout.fused and self.decode_impl == "pallas" else None)
+        self._attn_stream = None
+        if layout.fused and self.decode_impl == "pallas":
+            self._attn_stream = AttnStream(self._ck["q"].shape, kv_heads=self.cfg.n_kv_heads)
+        elif layout.latent and layout.int8 and self.decode_impl == "pallas":
+            # the latent arms: whole-S where it fits, else blocks of the prefix
+            self._attn_stream = AttnStream(self._ck["q"].shape, block_tokens=mla_stream_block(
+                max_seq_len, self.cfg.kv_lora_rank, self.cfg.qk_rope_head_dim, self.cfg.n_heads))
         # and what the window arm streams of the window layers' rings
         self._win_stream = (
             AttnStream(self._cv["win"]["k"]["q"].shape, window=self.cfg.sliding_window,
@@ -958,9 +971,10 @@ class GenerationEngine:
                     )
 
         def _insert_row(ck, cv, ks, vs, i, slot):
-            if layout.slot_member:
-                # the GQA layers' rows as for any family, and the row's
+            if layout.wrapped:
+                # the full-length rows as for any family, and the row's
                 # recurrent state (or its ring) into the pool beside them
+                # (a counted latent pair has none: the counts land once a call)
                 from ..models.hybrid import insert_state_row
 
                 ck, v = _insert_kv(ck, cv["v"], ks, vs["v"], i, slot)
@@ -2460,7 +2474,8 @@ class GenerationEngine:
         layout rules out (`CacheLayout.without`, which is `memory.RECURRENT_OFF`,
         the one list, where a slot member rides the pair: its reasons are
         logged where the pool is built, and the pool counts the times each
-        would have engaged). A mixed round is not among them: a recurrent
+        would have engaged; `memory.COUNTED_OFF` where a latent pair's second
+        member carries the expert counts). A mixed round is not among them: a recurrent
         configuration's admissions ride too (`hybrid_mixed_step`)."""
         return feature not in self._layout.without
 
@@ -2476,10 +2491,12 @@ class GenerationEngine:
 
     def _layer_leaf_dtype(self, *names: str) -> str:
         """Precision of the first of `names` among the layers' leaves (the
-        stacked ones', or a leading dense layer's own: `params["first"]`):
-        "int8" for a quantised one, "" where there is none."""
+        stacked ones', or a leading dense layer's own: `params["first"]`, or
+        the latent family's `params["dense_layers"]`): "int8" for a quantised
+        one, "" where there is none."""
         params = self.params if isinstance(self.params, dict) else {}
-        layers = {**next(iter(params.get("first", ())), {}), **params.get("layers", {})}
+        layers = {**next(iter(params.get("first", ())), {}), **params.get("dense_layers", {}),
+                  **params.get("layers", {})}
         leaf = next((layers[k] for k in names if k in layers), None)
         if leaf is None:
             return ""
@@ -5391,9 +5408,11 @@ class GenerationEngine:
         thread): ledger registration first (evicting LRU entries to fit,
         exactly like a local store), then pool-row uploads on the physical
         path or a device-array entry on the contiguous path."""
-        if self._state_pool is not None:
-            # a peer's prefix holds KV rows and no recurrent state: never here
-            self._state_pool.note_off("prefix_cache")
+        if not self._runs("prefix_cache"):
+            # a peer's prefix holds KV rows and no recurrent state (or, for a
+            # counted latent pair, bare rows of both members): never here
+            if self._state_pool is not None:
+                self._state_pool.note_off("prefix_cache")
             with self.stats_lock:
                 self.prefix_import_rejects_total += 1
             return False

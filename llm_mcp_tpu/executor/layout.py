@@ -15,8 +15,14 @@ any of that again:
     tiny-granite-hybrid "int8"   False  True   True  "state"     gqa_int8
     tiny-kexaone "int8"          False  True   True  "win"       gqa_int8
     tiny-lfm2 "int8"             False  True   True  "state"     gqa_int8
+    tiny-joyai "int8"            True   True   False ""          mla_int8   (counted)
 
-`latent`: MLA's two asymmetric members (models/mla.py). `fused`: int8 GQA, V
+`latent`: MLA's two asymmetric members (models/mla.py). `counted`: a latent pair
+whose expert layer counts its work (`moe.share_form`): the counts [2, Le, 5] ride
+the second member beside the rope keys, {"v": the rope keys, "moe": the counts},
+as they ride a hybrid pair's; `without` is then `memory.COUNTED_OFF`, what takes
+the second member for bare rows. `wrapped`: the second member is such a dict
+(a slot member, or the counts), and the full-length rows are its "v". `fused`: int8 GQA, V
 rides `cache["k"]`'s head axis and `cache["v"]` is the empty dict
 (models/llama.py:init_kv_cache). `slot_member`: the member of `cache["v"]` that
 holds one row a slot beside the full-length rows (`hybrid.SLOT_MEMBERS`; a
@@ -38,9 +44,10 @@ from jax.sharding import Mesh, PartitionSpec
 
 from ..models.configs import ModelConfig
 from ..models.llama import fuse_prompt_kv, init_kv_cache, quantize_kv
+from ..models.moe import share_form
 from ..parallel.sharding import kv_cache_specs, kv_pool_specs, named_shardings
 from ..telemetry.perf import layout_name
-from .memory import RECURRENT_OFF
+from .memory import COUNTED_OFF, RECURRENT_OFF
 from .physical import pool_like
 
 
@@ -68,12 +75,20 @@ class CacheLayout:
         return "win" if self.cfg.recurrent_kind == "win" else "state"
 
     @property
+    def counted(self) -> bool:
+        return self.latent and share_form(self.cfg)
+
+    @property
+    def wrapped(self) -> bool:
+        return bool(self.slot_member) or self.counted
+
+    @property
     def name(self) -> str:
         return layout_name(self.latent, self.int8)
 
     @property
     def without(self) -> Mapping[str, str]:
-        return RECURRENT_OFF if self.slot_member else {}
+        return RECURRENT_OFF if self.slot_member else COUNTED_OFF if self.counted else {}
 
     # -- how it is made ------------------------------------------------------
 
@@ -86,7 +101,7 @@ class CacheLayout:
         members beside the full-length rows (a slot member, the expert counts)
         replicate: such a configuration runs on one chip."""
         specs = kv_cache_specs(quantized=self.int8, latent=self.latent)
-        if self.slot_member:
+        if self.wrapped:
             beside = jax.eval_shape(self._init)["v"]
             specs["v"] = {m: specs["v"] if m == "v" else jax.tree.map(lambda _: PartitionSpec(), sub)
                           for m, sub in beside.items()}
@@ -130,4 +145,4 @@ class CacheLayout:
 
     def kv_rows(self, ck: Any, cv: Any) -> dict[str, Any]:
         """The members of a pair that hold full-length KV rows."""
-        return {"k": ck, "v": cv["v"] if self.slot_member else cv}
+        return {"k": ck, "v": cv["v"] if self.wrapped else cv}

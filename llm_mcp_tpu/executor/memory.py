@@ -54,7 +54,7 @@ from typing import Any
 
 from ..utils.locks import OrderedLock
 
-__all__ = ["ExpertCounts", "KVPool", "KVSnapshot", "RECURRENT_OFF", "StatePool",
+__all__ = ["COUNTED_OFF", "ExpertCounts", "KVPool", "KVSnapshot", "RECURRENT_OFF", "StatePool",
            "build_state_pool",
            "pytree_nbytes", "bucket_len"]
 
@@ -339,6 +339,16 @@ RECURRENT_OFF = {
     "ragged_prefill": "a packed chunk holds several prompts' tokens in one row: the chunked "
                       "recurrence carries one state a row, and the packed kernel masks no window "
                       "and writes by position, not by position modulo a ring",
+}
+# What a latent pair runs without where the expert counts ride its second member
+# beside the rope keys (`CacheLayout.counted`): the three features that take the
+# pair's members apart row by row outside the step programs. Ragged prefill and
+# speculation hand the pair to the step programs whole and stay on.
+COUNTED_OFF = {
+    "prefix_cache": "a stored prefix is a slice of the pair's two members as bare rows; the second "
+                    "member here is the rope keys AND the expert counts",
+    "offload": "a preempted slot's snapshot cuts bare rows out of both members",
+    "migration": "the wire format of a moved sequence holds bare rows of both members",
 }
 # What the pool counts beside them, in the same block (`off`), that is NOT off:
 # whole prompts ride a decode round's weight pass in a recurrent configuration

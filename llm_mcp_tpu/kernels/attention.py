@@ -1001,10 +1001,13 @@ class AttnStream:
     window layer's ring and the arm the whole-tile one alone: every row of a
     round streams the ring in full, and a seated row's live positions are its
     last `window` (`max_seq_len`: the length at which a row is parked, the
-    full-length cache's)."""
+    full-length cache's). With `block_tokens` the cache is the int8 LATENT one
+    (`decode_attend_q8_mla`, whose arm is chosen from the shape alone:
+    `mla_stream_block`) and `cache_q_shape` its latents': `tokens_live` is then
+    the latent positions the steps read, rows x their lengths."""
 
     def __init__(self, cache_q_shape: tuple[int, ...], window: int = 0, max_seq_len: int = 0,
-                 kv_heads: int = 0):
+                 kv_heads: int = 0, block_tokens: int | None = None):
         _, _, rows, self.seq_len, row_lanes = cache_q_shape
         self.window = window
         self.parked_at = max_seq_len or self.seq_len
@@ -1012,8 +1015,9 @@ class AttnStream:
         # configuration's KV heads; without them, a head a row)
         self.heads_abreast = _payload_rows(rows, 2 * kv_heads)[1] if kv_heads else 1
         # 0: the whole-S arm alone runs here, and streams every row in full
-        self.block_tokens = (q8_block_tokens(rows, self.seq_len, row_lanes)
-                             if not window and blocked_arm_fits(row_lanes, _interpret()) else 0)
+        self.block_tokens = block_tokens if block_tokens is not None else (
+            q8_block_tokens(rows, self.seq_len, row_lanes)
+            if not window and blocked_arm_fits(row_lanes, _interpret()) else 0)
         self.steps = self.tokens_streamed = self.tokens_live = 0
 
     def dispatched(self, lengths: np.ndarray, steps: int) -> None:
@@ -2388,6 +2392,14 @@ def mla_block_size(seq_len: int) -> int:
     if bs and seq_len // bs > 64:
         return 0
     return bs
+
+
+def mla_stream_block(seq_len: int, R: int, dr: int, H: int) -> int:
+    """Cache positions a block of the latent decode arm streams for a cache of
+    this shape, as `decode_attend_q8_mla` chooses its arm: 0 where the whole-S
+    arm fits (every row's `seq_len` positions a step, whatever its fill), else
+    the blocked arm's block (the attended prefix in whole blocks)."""
+    return 0 if mla_whole_s_fits(seq_len, R, dr, H) else mla_block_size(seq_len)
 
 
 def _decode_attend_q8_mla_fallback(

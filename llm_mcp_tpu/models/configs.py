@@ -156,9 +156,9 @@ class ModelConfig:
     # False: where window and global layers are mixed, only the window layers
     # rotate and a global layer attends by content alone (EXAONE 4.0)
     global_rope: bool = True
-    # Multi-token prediction (DeepSeek-V3's module; models/hybrid.py:
-    # `init_mtp_params`, `mtp_logits`): how many such modules the family
-    # publishes. The engine holds none: no step program runs one yet
+    # Multi-token prediction (DeepSeek-V3's module; models/hybrid.py and
+    # models/mla.py: `init_mtp_params`, `mtp_logits`): how many such modules
+    # the family publishes. The engine holds none: no step program runs one yet
     mtp_layers: int = 0
     # Where a sub-layer's RMSNorm sits (models/hybrid.py, the hybrid decoder's
     # three layer halves): "input": h + Mix(norm(h)) (llama); "output":
@@ -266,12 +266,19 @@ class ModelConfig:
             ffn_total = k * ffn + (self.n_layers - k) * moe_layer
         if self.kv_lora_rank:  # MLA factorized attention
             dn, dr, dv = self.qk_nope_head_dim, self.qk_rope_head_dim, self.v_head_dim
+            rq = self.q_lora_rank
             attn = (
-                self.dim * self.n_heads * (dn + dr)  # q proj (dense-q)
+                # q proj: dense, or down, its norm and up through the query latent
+                (self.dim * rq + rq + rq * self.n_heads * (dn + dr) if rq
+                 else self.dim * self.n_heads * (dn + dr))
                 + self.dim * (self.kv_lora_rank + dr)  # kv down + rope key
                 + self.kv_lora_rank * self.n_heads * (dn + dv)  # kv up
                 + self.n_heads * dv * self.dim  # o proj
             )
+            if rq:  # exact, to the parameter: the latent's norm and the selection bias
+                attn += self.kv_lora_rank
+                if self.n_experts and self.router_score == "sigmoid":
+                    ffn_total += (self.n_layers - self.first_dense_layers) * self.router_width
         else:
             attn = (
                 self.dim * self.n_heads * hd  # wq
@@ -470,6 +477,74 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         v_head_dim=32,
         tie_embeddings=True,
         params_b=0.001,
+    ),
+    # JoyAI-LLM-Flash (jdopensource/JoyAI-LLM-Flash config.json, 48B-A2.7B) as
+    # ONE CHIP of a 16-way expert-parallel group, whole depth: latent attention
+    # with a low-rank query in every layer, one leading dense layer, then 39
+    # layers of 256 routed experts of which this chip holds experts 0-15 (the
+    # router keeps its 256 columns and its 8 a token: sigmoid scores, a
+    # selection bias, renormalised gates times 2.5) beside one shared expert;
+    # every width, all 40 layers and all 129,280 vocabulary rows the published
+    # ones. benchmark/configs/joyai-llm-flash-ep16-bf16.json lists what is assumed.
+    "joyai-llm-flash-ep16": ModelConfig(
+        name="joyai-llm-flash-ep16",
+        arch="mla",
+        vocab_size=129_280,
+        dim=2048,
+        n_layers=40,
+        n_heads=32,
+        n_kv_heads=1,  # latent cache: one shared row per token
+        ffn_hidden=7168,  # the leading dense layer's
+        norm_eps=1e-6,
+        rope_theta=32_000_000.0,
+        max_seq_len=131_072,
+        q_lora_rank=1536,
+        kv_lora_rank=512,
+        qk_rope_head_dim=64,
+        qk_nope_head_dim=128,
+        v_head_dim=128,
+        n_experts=16,
+        n_router_experts=256,
+        experts_per_tok=8,
+        n_shared_experts=1,
+        moe_ffn_hidden=768,
+        first_dense_layers=1,
+        norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        router_score="sigmoid",
+        mtp_layers=1,
+        params_b=4.8,
+    ),
+    # the same structure at toy size: a dense layer, then three expert layers
+    # of 16 routed experts of which 4 are held, 2 a token, one shared expert
+    "tiny-joyai": ModelConfig(
+        name="tiny-joyai",
+        arch="mla",
+        vocab_size=512,
+        dim=64,
+        n_layers=4,
+        n_heads=4,
+        n_kv_heads=1,
+        ffn_hidden=128,
+        norm_eps=1e-6,
+        rope_theta=10_000.0,
+        max_seq_len=512,
+        q_lora_rank=24,
+        kv_lora_rank=32,
+        qk_rope_head_dim=16,
+        qk_nope_head_dim=32,
+        v_head_dim=32,
+        n_experts=4,
+        n_router_experts=16,
+        experts_per_tok=2,
+        n_shared_experts=1,
+        moe_ffn_hidden=32,
+        first_dense_layers=1,
+        norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        router_score="sigmoid",
+        mtp_layers=1,
+        params_b=0.0003,
     ),
     # Solar-Open2-250B (upstage/Solar-Open2-250B config.json) as ONE CHIP of an
     # 8-way expert-parallel group, rank 0 of pipeline stage 0: one whole period

@@ -28,11 +28,26 @@ TPU-first choices:
 Reference parity note: the reference serves deepseek-architecture models
 only through Ollama (`discovery.go:510` infers metadata from the name);
 this module is what "serving a deepseek-class architecture in-process"
-means TPU-side. Rope here is the repo's split-half convention; loading
-published DeepSeek checkpoints additionally needs their yarn-scaled rope
-and shared-expert MoE, so the in-repo configs
-are the `tiny-mla` test config and an `mla-8b` long-context serving
-config with llama-8B-scale proportions.
+means TPU-side. Rope here is the repo's split-half convention (the loader
+de-interleaves a checkpoint's rope columns once, models/weights.py).
+
+What the module runs: the query dense (`wq_mla`, DeepSeek-V2-Lite) or through a
+latent of its own (`q_lora_rank`: `w_dq`, `q_a_norm`, `w_uq`, DeepSeek-V2/V3),
+yarn-scaled rope, and after `first_dense_layers` dense layers
+(`params["dense_layers"]`, a stack of its own) a feed-forward of routed experts
+in one of two forms, by what the preset states (`moe.share_form`): `moe.moe_ffn`'s
+capacity dispatch with a softmax router (`tiny-v2`, `deepseek-v2-lite`), or
+`moe.moe_share_ffn` (`joyai-llm-flash-ep16`, `tiny-joyai`): DeepSeek-V3's router
+(sigmoid scores, a selection bias, renormalised gates times a factor), dropless
+in every program, this process holding a share of the published experts, the
+banks handed over STACKED and never sliced by a layer scan, and its counts
+riding the cache pair's second member as {"v": the rope keys, "moe": [2, Le, 5]}
+(`executor/layout.py:CacheLayout.counted`; models/hybrid.py says what the
+counts are). The multi-token-prediction module (`cfg.mtp_layers`) is a tree and
+a forward of its own, `init_mtp_params` and `mtp_logits`, built and run where a
+caller asks: no step program holds it. Every product of the attention half
+carries a `jax.named_scope` under the prefix `mla.` (`q_down`, `q_up` or `q`,
+`kv_down`, `kv_up` or `absorb`, `attend`, `out`), which a trace's operations keep.
 """
 
 from __future__ import annotations
@@ -46,6 +61,8 @@ from ..kernels.attention import paged_gather, ragged_prefill_attend_mla
 from ..ops.norms import rms_norm as _rms_norm
 from ..ops.rope import apply_rope, rope_tables
 from .configs import ModelConfig
+from .hybrid import BANKS, _ffn as _share_ffn
+from .moe import share_form
 from .quant import qdot
 
 # llama.py imports this module only lazily inside its dispatch functions, so
@@ -78,9 +95,10 @@ def mla_scale(cfg: ModelConfig) -> float:
 def _mla_attn_weights(
     cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype, L: int
 ) -> Params:
-    """Stacked [L, ...] MLA attention weights (dense-q factorization)."""
+    """Stacked [L, ...] MLA attention weights: the query dense (`wq_mla`) or,
+    with `q_lora_rank`, through its own latent (`w_dq`, `q_a_norm`, `w_uq`)."""
     H, dn, dr, dv = _dims(cfg)
-    D, R = cfg.dim, cfg.kv_lora_rank
+    D, R, Rq = cfg.dim, cfg.kv_lora_rank, cfg.q_lora_rank
 
     def w(k, shape, fan_in):
         return (
@@ -88,8 +106,14 @@ def _mla_attn_weights(
         ).astype(dtype)
 
     kq = jax.random.split(key, 4)
+    if Rq:
+        k_dq, k_uq = jax.random.split(kq[0])
+        query = {"w_dq": w(k_dq, (L, D, Rq), D), "q_a_norm": jnp.ones((L, Rq), dtype=dtype),
+                 "w_uq": w(k_uq, (L, Rq, H * (dn + dr)), Rq)}
+    else:
+        query = {"wq_mla": w(kq[0], (L, D, H * (dn + dr)), D)}
     return {
-        "wq_mla": w(kq[0], (L, D, H * (dn + dr)), D),
+        **query,
         # one matmul produces (latent c_kv | shared rope key), HF
         # kv_a_proj_with_mqa layout
         "w_dkv": w(kq[1], (L, D, R + dr), D),
@@ -103,22 +127,26 @@ def _mla_attn_weights(
 def init_mla_params(
     cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype = jnp.bfloat16
 ) -> Params:
-    """Random-init MLA decoder weights (dense-q variant: q_lora_rank == 0
-    projects queries directly, as DeepSeek-V2-Lite does).
+    """Random-init MLA decoder weights.
 
     With cfg.first_dense_layers > 0 (DeepSeek-V2 MoE), the layer stack
     splits into params["dense_layers"] (layers 0..k-1, dense FFN at
     ffn_hidden) and params["layers"] (the MoE stack) — two uniform scans
-    instead of one, since the FFN weight shapes differ."""
+    instead of one, since the FFN weight shapes differ. A configuration in the
+    share form (`moe.share_form`) is drawn as ONE jitted program (an eager draw
+    a tensor is a compile a tensor on the chip) and a sigmoid router gets its
+    selection bias, normal with deviation 0.01 (models/hybrid.py:
+    init_hybrid_params says why no larger)."""
+    if share_form(cfg):
+        return jax.jit(lambda k: _init(cfg, k, dtype))(key)
+    return _init(cfg, key, dtype)
+
+
+def _init(cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype) -> Params:
     import dataclasses
 
     from .llama import init_llama_params  # local: dispatch entry point
 
-    if cfg.q_lora_rank:
-        raise ValueError(
-            "q_lora_rank > 0 (low-rank query path) is not implemented; use "
-            "the dense-q MLA variant (q_lora_rank=0, V2-Lite style)"
-        )
     k_dense = cfg.first_dense_layers if cfg.n_experts else 0
     L_main = cfg.n_layers - k_dense
     # the base init skips wq/wk/wv/wo for MLA configs (they would be
@@ -132,6 +160,9 @@ def init_mla_params(
     for k in ("wq", "wk", "wv", "wo", "bq", "bk", "bv"):
         layers.pop(k, None)
     layers.update(_mla_attn_weights(cfg, jax.random.fold_in(key, 7), dtype, L_main))
+    if cfg.n_experts and cfg.router_score == "sigmoid":
+        layers["router_bias"] = 0.01 * jax.random.normal(
+            jax.random.fold_in(key, 17), (L_main, cfg.router_width), jnp.float32)
     if k_dense:
         cfg_dense = dataclasses.replace(cfg, n_layers=k_dense, n_experts=0)
         dense = init_llama_params(
@@ -162,10 +193,11 @@ def init_mla_cache(
     `quantized=True` stores int8 payloads with per-token scales (the same
     post-dot scale-folding scheme as the GQA int8 cache): MLA's latent is
     already ~3.6x smaller than GQA K/V by VALUE COUNT; int8 makes it
-    ~7x smaller by BYTES — double the context per HBM byte again."""
+    ~7x smaller by BYTES — double the context per HBM byte again. In the share
+    form the second member is {"v": the rope keys, "moe": the expert counts}."""
     L, R, dr = cfg.n_layers, cfg.kv_lora_rank, cfg.qk_rope_head_dim
     if quantized:
-        return {
+        pair = {
             "k": {
                 "q": jnp.zeros((L, batch, 1, max_seq, R), dtype=jnp.int8),
                 "s": jnp.zeros((L, batch, 1, max_seq), dtype=dtype),
@@ -175,25 +207,136 @@ def init_mla_cache(
                 "s": jnp.zeros((L, batch, 1, max_seq), dtype=dtype),
             },
         }
-    return {
-        "k": jnp.zeros((L, batch, 1, max_seq, R), dtype=dtype),
-        "v": jnp.zeros((L, batch, 1, max_seq, dr), dtype=dtype),
-    }
+    else:
+        pair = {
+            "k": jnp.zeros((L, batch, 1, max_seq, R), dtype=dtype),
+            "v": jnp.zeros((L, batch, 1, max_seq, dr), dtype=dtype),
+        }
+    if share_form(cfg):
+        # the expert layer's counts ride the pair's second member, beside the
+        # rope keys (models/hybrid.py: what they are; `_rows`, `_second`)
+        Le = L - cfg.first_dense_layers
+        pair["v"] = {"v": pair["v"], "moe": jnp.zeros((2, Le, 5), jnp.int32)}
+    return pair
 
 
 def _latents(cfg: ModelConfig, lp: Params, x: jnp.ndarray):
     """x [..., D] → (c_kv [..., R] normed, k_rope [..., dr] pre-rope)."""
     R = cfg.kv_lora_rank
-    ckr = qdot(x, lp["w_dkv"])  # [..., R + dr]
-    c = _rms_norm(ckr[..., :R], lp["kv_norm"], cfg.norm_eps)
+    with jax.named_scope("mla.kv_down"):
+        ckr = qdot(x, lp["w_dkv"])  # [..., R + dr]
+        c = _rms_norm(ckr[..., :R], lp["kv_norm"], cfg.norm_eps)
     return c, ckr[..., R:]
 
 
 def _queries(cfg: ModelConfig, lp: Params, x: jnp.ndarray):
-    """x [..., D] → (q_nope [..., H, dn], q_rope [..., H, dr])."""
+    """x [..., D] → (q_nope [..., H, dn], q_rope [..., H, dr]): one dense
+    product, or with `q_lora_rank` through the query's own latent, c_q =
+    RMSNorm(x W_dq; q_a_norm), q = c_q W_uq. The one query of all four programs."""
     H, dn, dr, _ = _dims(cfg)
-    q = qdot(x, lp["wq_mla"]).reshape(*x.shape[:-1], H, dn + dr)
+    if "w_dq" in lp:
+        with jax.named_scope("mla.q_down"):
+            cq = _rms_norm(qdot(x, lp["w_dq"]), lp["q_a_norm"], cfg.norm_eps)
+        with jax.named_scope("mla.q_up"):
+            q = qdot(cq, lp["w_uq"])
+        # `w_uq`'s columns are every head's content part, then every head's rope
+        # part, [H dn | H dr]: both cuts fall on whole lanes. With a head's two
+        # parts side by side (192 a head, as `wq_mla` has them) the compiler
+        # re-laid the whole stack out every round to cut inside a head (0.69 GiB
+        # at the published size, seen in the described-chip compile).
+        return (q[..., : H * dn].reshape(*x.shape[:-1], H, dn),
+                q[..., H * dn :].reshape(*x.shape[:-1], H, dr))
+    with jax.named_scope("mla.q"):
+        q = qdot(x, lp["wq_mla"])
+    q = q.reshape(*x.shape[:-1], H, dn + dr)
     return q[..., :dn], q[..., dn:]
+
+
+def _expert_stack(cfg: ModelConfig, layers: Params) -> tuple[Params | None, Params]:
+    """(banks, rest) of the expert layers' stacked tree. In the share form the
+    banks go to `moe_share_ffn` whole with the layer's index and the layer scan
+    slices the rest alone (a slice of a stack that feeds a grouped kernel is
+    copied out every step: moe.moe_share_ffn); otherwise (None, everything)."""
+    if not share_form(cfg):
+        return None, layers
+    return ({n: layers[n] for n in BANKS}, {n: v for n, v in layers.items() if n not in BANKS})
+
+
+def _n_dense(params: Params) -> int:
+    return params["dense_layers"]["attn_norm"].shape[0] if "dense_layers" in params else 0
+
+
+def _ffn(cfg: ModelConfig, lp: Params, banks: Params | None, le, h, valid=None, capacity: int = 0):
+    """Feed-forward half of one layer and residual add: (h, counts [5] of an
+    expert layer in the share form (`banks` and its index `le` among the expert
+    layers), None for every other)."""
+    if banks is not None and "router" in lp:
+        return _share_ffn(cfg, lp, banks, le, h, valid)
+    return _ffn_residual(cfg, lp, h, moe_capacity=capacity, moe_valid=valid), None
+
+
+def _rows(cache_r: Any) -> Any:
+    """The rope keys of the cache pair's second member: the member itself, or
+    its "v" where the expert counts ride beside them."""
+    return cache_r["v"] if isinstance(cache_r, dict) and "v" in cache_r else cache_r
+
+
+def _second(cache_r: Any, new_r: Any, phase: int, counts) -> Any:
+    """The pair's second member after a call: the new rope keys, and where the
+    member carries the expert counts, this call's [Le, 5] added onto the running
+    sums (decode steps under 0, prefills under 1)."""
+    if counts is None or not (isinstance(cache_r, dict) and "moe" in cache_r):
+        return new_r
+    return {"v": new_r, "moe": cache_r["moe"].at[phase].add(counts)}
+
+
+def _prefill_attn(cfg: ModelConfig, lp: Params, h, cos, sin, valid_k):
+    """The attention half of one layer over whole prompts h [B, S, D], expanded
+    and query-blocked (`mla_prefill`): (h after the residual add, the layer's
+    latents [B, S, R], its rope keys [B, S, dr] post-rope)."""
+    H, dn, dr, dv = _dims(cfg)
+    B, S, _ = h.shape
+    scale = mla_scale(cfg)
+    key_pos = jnp.arange(S, dtype=jnp.int32)
+    neg = jnp.float32(-1e30)
+    QB = next((c for c in (256, 128, 64, 32, 16, 8, 4, 2, 1) if S % c == 0))
+    nb = S // QB
+    x = _norm(cfg, h, lp["attn_norm"])
+    qn, qr = _queries(cfg, lp, x)  # [B, S, H, dn/dr]
+    qr = apply_rope(qr, cos, sin)
+    c, kr = _latents(cfg, lp, x)  # [B, S, R], [B, S, dr]
+    kr = apply_rope(kr[..., None, :], cos, sin)[..., 0, :]  # shared key
+    with jax.named_scope("mla.kv_up"):
+        kv = qdot(c, lp["w_ukv"]).reshape(B, S, H, dn + dv)
+    kn, v = kv[..., :dn], kv[..., dn:]
+
+    # query blocks ride a scan: [nb, B, QB, H, d] xs against the full
+    # (linear-size) keys closed over — one block's [B, H, QB, S] scores
+    # live at a time
+    qn_b = qn.reshape(B, nb, QB, H, dn).transpose(1, 0, 2, 3, 4)
+    qr_b = qr.reshape(B, nb, QB, H, dr).transpose(1, 0, 2, 3, 4)
+    pos_b = jnp.arange(S, dtype=jnp.int32).reshape(nb, QB)
+
+    def qblock(_, xs):
+        qnj, qrj, posj = xs  # [B, QB, H, ·], [QB]
+        scores = (
+            jnp.einsum("bqhd,bkhd->bhqk", qnj, kn)
+            + jnp.einsum("bqhd,bkd->bhqk", qrj, kr)
+        ).astype(jnp.float32) * scale
+        mask = (key_pos[None, :] <= posj[:, None])[None, None] & valid_k[
+            :, None, None, :
+        ]  # [B, 1|QB, S] → [B, 1, QB, S]
+        scores = jnp.where(mask, scores, neg)
+        probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
+        ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)  # [B, QB, H, dv]
+        return None, ctx
+
+    with jax.named_scope("mla.attend"):
+        _, ctx_b = jax.lax.scan(qblock, None, (qn_b, qr_b, pos_b))
+    ctx = ctx_b.transpose(1, 0, 2, 3, 4).reshape(B, S, H * dv)
+    with jax.named_scope("mla.out"):
+        h = h + qdot(ctx, lp["wo_mla"])
+    return h, c, kr
 
 
 def mla_prefill(
@@ -202,6 +345,7 @@ def mla_prefill(
     tokens: jnp.ndarray,  # [B, S] int32 right-padded prompts
     lengths: jnp.ndarray,  # [B] int32 true lengths
     quant_kv: bool = False,  # int8 latents (per-token scales) inside the scan
+    hidden: bool = False,  # the residual stream after the last layer [B, S, D] for the logits
 ) -> tuple[jnp.ndarray, Any, Any]:
     """Causal prefill with QUERY-BLOCKED expanded attention: per-head K/V
     re-materialize once (O(S) memory), but scores/probs only ever exist for
@@ -212,75 +356,39 @@ def mla_prefill(
 
     Returns (last_logits [B, V] f32, latents [L, B, 1, S, R], rope_keys
     [L, B, 1, S, dr]) — the cache rows to insert at the request's slot
-    (post-rope, decode-ready)."""
-    H, dn, dr, dv = _dims(cfg)
+    (post-rope, decode-ready). In the share form the third is {"v": the rope
+    keys, "moe": the call's expert counts [Le, 5]}: the engine inserts the rows
+    and adds the counts once (`hybrid.add_counts`)."""
+    dr = cfg.qk_rope_head_dim
     B, S = tokens.shape
-    scale = mla_scale(cfg)
     h = _embed_in(cfg, params, tokens)  # [B, S, D]
     positions = jnp.arange(S, dtype=jnp.int32)[None, :]
     cos, sin = rope_tables(cfg, dr, positions)  # [1, S, dr/2]
-    key_pos = jnp.arange(S, dtype=jnp.int32)
-    valid_k = key_pos[None, :] < lengths[:, None]  # [B, S]
-    neg = jnp.float32(-1e30)
-    QB = next((c for c in (256, 128, 64, 32, 16, 8, 4, 2, 1) if S % c == 0))
-    nb = S // QB
+    valid_k = jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None]  # [B, S]
+    banks, stack = _expert_stack(cfg, params["layers"])
 
-    def layer(h, lp):
-        x = _norm(cfg, h, lp["attn_norm"])
-        qn, qr = _queries(cfg, lp, x)  # [B, S, H, dn/dr]
-        qr = apply_rope(qr, cos, sin)
-        c, kr = _latents(cfg, lp, x)  # [B, S, R], [B, S, dr]
-        kr = apply_rope(kr[..., None, :], cos, sin)[..., 0, :]  # shared key
-        kv = qdot(c, lp["w_ukv"]).reshape(B, S, H, dn + dv)
-        kn, v = kv[..., :dn], kv[..., dn:]
-
-        # query blocks ride a scan: [nb, B, QB, H, d] xs against the full
-        # (linear-size) keys closed over — one block's [B, H, QB, S] scores
-        # live at a time
-        qn_b = qn.reshape(B, nb, QB, H, dn).transpose(1, 0, 2, 3, 4)
-        qr_b = qr.reshape(B, nb, QB, H, dr).transpose(1, 0, 2, 3, 4)
-        pos_b = jnp.arange(S, dtype=jnp.int32).reshape(nb, QB)
-
-        def qblock(_, xs):
-            qnj, qrj, posj = xs  # [B, QB, H, ·], [QB]
-            scores = (
-                jnp.einsum("bqhd,bkhd->bhqk", qnj, kn)
-                + jnp.einsum("bqhd,bkd->bhqk", qrj, kr)
-            ).astype(jnp.float32) * scale
-            mask = (key_pos[None, :] <= posj[:, None])[None, None] & valid_k[
-                :, None, None, :
-            ]  # [B, 1|QB, S] → [B, 1, QB, S]
-            scores = jnp.where(mask, scores, neg)
-            probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
-            ctx = jnp.einsum("bhqk,bkhd->bqhd", probs, v)  # [B, QB, H, dv]
-            return None, ctx
-
-        _, ctx_b = jax.lax.scan(qblock, None, (qn_b, qr_b, pos_b))
-        ctx = ctx_b.transpose(1, 0, 2, 3, 4).reshape(B, S, H * dv)
-        h = h + qdot(ctx, lp["wo_mla"])
-        h = _ffn_residual(cfg, lp, h, moe_valid=valid_k)
+    def scan_layer(carry, lp):
+        h, le = carry
+        h, c, kr = _prefill_attn(cfg, lp, h, cos, sin, valid_k)
+        h, counts = _ffn(cfg, lp, banks, le, h, valid=valid_k)
         if quant_kv:
             # quantize INSIDE the scan: the stacked bf16 latents of a long
             # admission never materialize (llama_prefill's same trick)
-            return h, (quantize_kv(c), quantize_kv(kr))
-        return h, (c, kr)
-
-    def scan_layer(carry, lp):
-        h = carry
-        h, (c, kr) = layer(h, lp)
-        return h, (c, kr)
+            return (h, le + 1), (quantize_kv(c), quantize_kv(kr), counts)
+        return (h, le + 1), (c, kr, counts)
 
     if "dense_layers" in params:
         # DeepSeek first-dense prologue (layers 0..k-1): same layer fn, the
         # FFN shape difference lives in the params (see _ffn_residual)
-        h, (cs_d, krs_d) = jax.lax.scan(scan_layer, h, params["dense_layers"])
-    h, (cs, krs) = jax.lax.scan(scan_layer, h, params["layers"])
+        (h, _), (cs_d, krs_d, _) = jax.lax.scan(
+            scan_layer, (h, jnp.int32(0)), params["dense_layers"])
+    (h, _), (cs, krs, counts) = jax.lax.scan(scan_layer, (h, jnp.int32(0)), stack)
     if "dense_layers" in params:
         cs = jax.tree.map(lambda a, b: jnp.concatenate([a, b], axis=0), cs_d, cs)
         krs = jax.tree.map(lambda a, b: jnp.concatenate([a, b], axis=0), krs_d, krs)
     last = jnp.clip(lengths - 1, 0, S - 1)
     h_last = jnp.take_along_axis(h, last[:, None, None], axis=1)[:, 0]
-    logits = _logits(cfg, params, h_last)
+    logits = h if hidden else _logits(cfg, params, h_last)
 
     def to_engine_layout(x):
         # [L, B, S, ·] → engine layout [L, B, 1, S, ·]
@@ -288,7 +396,10 @@ def mla_prefill(
             return {"q": x["q"][:, :, None], "s": x["s"][:, :, None]}
         return x[:, :, None]
 
-    return logits, to_engine_layout(cs), to_engine_layout(krs)
+    vs = to_engine_layout(krs)
+    if counts is not None:  # the call's expert counts [Le, 5], as hybrid_prefill hands them over
+        vs = {"v": vs, "moe": counts}
+    return logits, to_engine_layout(cs), vs
 
 
 def mla_prefill_chunk_batch(
@@ -321,9 +432,12 @@ def mla_prefill_chunk_batch(
     rides this path with start = P0.
     """
     H, dn, dr, dv = _dims(cfg)
+    pair_r, cache_r = cache_r, _rows(cache_r)
     quantized = isinstance(cache_c, dict)
     L, B, _, S, R = (cache_c["q"] if quantized else cache_c).shape
     A, C = tokens.shape
+    banks, stack = _expert_stack(cfg, params["layers"])
+    k_dense = _n_dense(params)
     Sk = min(skey, S) if skey else S
     scale = mla_scale(cfg)
     neg = jnp.float32(-1e30)
@@ -362,8 +476,9 @@ def mla_prefill_chunk_batch(
         qr = apply_rope(qr, cos, sin)
         c, kr = _latents(cfg, lp, x)  # [A, C, R], [A, C, dr]
         kr = apply_rope(kr[..., None, :], cos, sin)[..., 0, :]
-        w_uk, w_uv = _absorbed_w(lp, h.dtype, R, H, dn, dv)
-        qt = jnp.einsum("achd,rhd->achr", qn, w_uk)  # [A, C, H, R]
+        with jax.named_scope("mla.absorb"):
+            w_uk, w_uv = _absorbed_w(lp, h.dtype, R, H, dn, dv)
+            qt = jnp.einsum("achd,rhd->achr", qn, w_uk)  # [A, C, H, R]
 
         # ---- reads first: past latents/rope keys from the PRE-write cache
         def past_rows(cache, d, pool=None):
@@ -394,50 +509,53 @@ def mla_prefill_chunk_batch(
                 ]
             ).astype(jnp.float32)  # [A, Sk]
 
-        pk = None if paged is None else paged["k"]
-        pv = None if paged is None else paged["v"]
-        if quantized:
-            lat = past_rows(cc_all["q"], R, pk and pk["q"])
-            rop = past_rows(cr_all["q"], dr, pv and pv["q"])
-            ls = past_scales(cc_all["s"], pk and pk["s"])
-            rs = past_scales(cr_all["s"], pv and pv["s"])
-            # per-token dequant scales fold POST-DOT (decode path's trick)
-            s_past = (
-                jnp.einsum("achr,asr->ahcs", qt, lat.astype(qt.dtype)).astype(
-                    jnp.float32
-                )
-                * ls[:, None, None, :]
-                + jnp.einsum("achd,asd->ahcs", qr, rop.astype(qr.dtype)).astype(
-                    jnp.float32
-                )
-                * rs[:, None, None, :]
-            ) * scale
-        else:
-            lat = past_rows(cc_all, R, pk)
-            rop = past_rows(cr_all, dr, pv)
-            s_past = (
-                jnp.einsum("achr,asr->ahcs", qt, lat.astype(qt.dtype))
-                + jnp.einsum("achd,asd->ahcs", qr, rop.astype(qr.dtype))
+        with jax.named_scope("mla.attend"):
+            pk = None if paged is None else paged["k"]
+            pv = None if paged is None else paged["v"]
+            if quantized:
+                lat = past_rows(cc_all["q"], R, pk and pk["q"])
+                rop = past_rows(cr_all["q"], dr, pv and pv["q"])
+                ls = past_scales(cc_all["s"], pk and pk["s"])
+                rs = past_scales(cr_all["s"], pv and pv["s"])
+                # per-token dequant scales fold POST-DOT (decode path's trick)
+                s_past = (
+                    jnp.einsum("achr,asr->ahcs", qt, lat.astype(qt.dtype)).astype(
+                        jnp.float32
+                    )
+                    * ls[:, None, None, :]
+                    + jnp.einsum("achd,asd->ahcs", qr, rop.astype(qr.dtype)).astype(
+                        jnp.float32
+                    )
+                    * rs[:, None, None, :]
+                ) * scale
+            else:
+                lat = past_rows(cc_all, R, pk)
+                rop = past_rows(cr_all, dr, pv)
+                s_past = (
+                    jnp.einsum("achr,asr->ahcs", qt, lat.astype(qt.dtype))
+                    + jnp.einsum("achd,asd->ahcs", qr, rop.astype(qr.dtype))
+                ).astype(jnp.float32) * scale
+            s_self = (
+                jnp.einsum("achr,atr->ahct", qt, c)
+                + jnp.einsum("achd,atd->ahct", qr, kr)
             ).astype(jnp.float32) * scale
-        s_self = (
-            jnp.einsum("achr,atr->ahct", qt, c)
-            + jnp.einsum("achd,atd->ahct", qr, kr)
-        ).astype(jnp.float32) * scale
-        s_past = jnp.where(past_mask[:, None], s_past, neg)
-        s_self = jnp.where(self_mask[:, None], s_self, neg)
+            s_past = jnp.where(past_mask[:, None], s_past, neg)
+            s_self = jnp.where(self_mask[:, None], s_self, neg)
 
-        # joint softmax over [past | self]
-        s = jnp.concatenate([s_past, s_self], axis=-1)  # [A, H, C, Sk+C]
-        probs = jax.nn.softmax(s, axis=-1)
-        p_past, p_self = probs[..., :Sk], probs[..., Sk:]
-        if quantized:
-            p_past = p_past * ls[:, None, None, :]  # value-side dequant
-        ctx_lat = jnp.einsum(
-            "ahcs,asr->achr", p_past.astype(h.dtype), lat.astype(h.dtype)
-        ) + jnp.einsum("ahct,atr->achr", p_self.astype(h.dtype), c)
-        ctx = jnp.einsum("achr,rhd->achd", ctx_lat, w_uv).reshape(A, C, H * dv)
-        h = h + qdot(ctx, lp["wo_mla"])
-        h = _ffn_residual(cfg, lp, h, moe_valid=c_idx[None, :] < nvalid[:, None])
+            # joint softmax over [past | self]
+            s = jnp.concatenate([s_past, s_self], axis=-1)  # [A, H, C, Sk+C]
+            probs = jax.nn.softmax(s, axis=-1)
+            p_past, p_self = probs[..., :Sk], probs[..., Sk:]
+            if quantized:
+                p_past = p_past * ls[:, None, None, :]  # value-side dequant
+            ctx_lat = jnp.einsum(
+                "ahcs,asr->achr", p_past.astype(h.dtype), lat.astype(h.dtype)
+            ) + jnp.einsum("ahct,atr->achr", p_self.astype(h.dtype), c)
+        with jax.named_scope("mla.absorb"):
+            ctx = jnp.einsum("achr,rhd->achd", ctx_lat, w_uv).reshape(A, C, H * dv)
+        with jax.named_scope("mla.out"):
+            h = h + qdot(ctx, lp["wo_mla"])
+        h, counts = _ffn(cfg, lp, banks, li - k_dense, h, valid=c_idx[None, :] < nvalid[:, None])
 
         # ---- writes last: in place (write-after-read)
         if quantized:
@@ -474,14 +592,15 @@ def mla_prefill_chunk_batch(
                     cr_all, kr[a][None, None, None].astype(cr_all.dtype),
                     (li, slots[a], 0, starts[a], 0),
                 )
-        return (h, cc_all, cr_all, li + 1), None
+        return (h, cc_all, cr_all, li + 1), counts
 
     carry = (h, cache_c, cache_r, jnp.int32(0))
     if "dense_layers" in params:
         # DeepSeek first-dense prologue; carried li keeps cache rows aligned
         # with absolute layer position
         carry, _ = jax.lax.scan(layer, carry, params["dense_layers"])
-    (h, new_c, new_r, _), _ = jax.lax.scan(layer, carry, params["layers"])
+    (h, new_c, new_r, _), counts = jax.lax.scan(layer, carry, stack)
+    new_r = _second(pair_r, new_r, 1, counts)
     if all_logits:
         return _logits(cfg, params, h), new_c, new_r  # [A, C, V]
     last = jnp.take_along_axis(
@@ -517,9 +636,12 @@ def mla_prefill_chunk_ragged(
     Returns (logits [Rn, V] f32 at each row's `last_idx` token, new_c, new_r).
     """
     H, dn, dr, dv = _dims(cfg)
+    pair_r, cache_r = cache_r, _rows(cache_r)
     quantized = isinstance(cache_c, dict)
     L, B, _, S, R = (cache_c["q"] if quantized else cache_c).shape
     T = tokens.shape[0]
+    banks, stack = _expert_stack(cfg, params["layers"])
+    k_dense = _n_dense(params)
     Rn = slots.shape[0]
     scale = mla_scale(cfg)
     slots = jnp.asarray(slots, dtype=jnp.int32)
@@ -556,18 +678,22 @@ def mla_prefill_chunk_ragged(
         qr = apply_rope(qr, cos, sin)
         c, kr = _latents(cfg, lp, x)  # [T, R], [T, dr]
         kr = apply_rope(kr[..., None, :], cos, sin)[..., 0, :]
-        w_uk, w_uv = _absorbed_w(lp, h.dtype, R, H, dn, dv)
-        qt = jnp.einsum("thd,rhd->thr", qn, w_uk)  # [T, H, R]
+        with jax.named_scope("mla.absorb"):
+            w_uk, w_uv = _absorbed_w(lp, h.dtype, R, H, dn, dv)
+            qt = jnp.einsum("thd,rhd->thr", qn, w_uk)  # [T, H, R]
 
         # ---- ragged attention over [cached past | packed self]
-        ctx_lat = ragged_prefill_attend_mla(
-            qt, qr, c, kr, cache_c, cache_r, li, rowids, offsets, slots, starts,
-            scale=scale, skey=skey, block_tables=btbl,
-            pool_c=pool_c, pool_r=pool_r, impl=impl,
-        )  # [T, H, R]
-        ctx = jnp.einsum("thr,rhd->thd", ctx_lat, w_uv).reshape(T, H * dv)
-        h = h + qdot(ctx, lp["wo_mla"])
-        h = _ffn_residual(cfg, lp, h, moe_valid=moe_valid)
+        with jax.named_scope("mla.attend"):
+            ctx_lat = ragged_prefill_attend_mla(
+                qt, qr, c, kr, cache_c, cache_r, li, rowids, offsets, slots, starts,
+                scale=scale, skey=skey, block_tables=btbl,
+                pool_c=pool_c, pool_r=pool_r, impl=impl,
+            )  # [T, H, R]
+        with jax.named_scope("mla.absorb"):
+            ctx = jnp.einsum("thr,rhd->thd", ctx_lat, w_uv).reshape(T, H * dv)
+        with jax.named_scope("mla.out"):
+            h = h + qdot(ctx, lp["wo_mla"])
+        h, counts = _ffn(cfg, lp, banks, li - k_dense, h, valid=moe_valid)
 
         # ---- this layer's rows in the cache's own form, [1 (head), T, ..]
         if quantized:
@@ -576,14 +702,14 @@ def mla_prefill_chunk_ragged(
             new = (cq["q"][None], cq["s"][None], rq["q"][None], rq["s"][None])
         else:
             new = (c.astype(cache_c.dtype)[None], kr.astype(cache_r.dtype)[None])
-        return (h, li + 1), new
+        return (h, li + 1), (new, counts)
 
     carry = (h, jnp.int32(0))
     stacks = []
     if "dense_layers" in params:
-        carry, ys = jax.lax.scan(layer, carry, params["dense_layers"])
+        carry, (ys, _) = jax.lax.scan(layer, carry, params["dense_layers"])
         stacks.append(ys)
-    (h, _), ys = jax.lax.scan(layer, carry, params["layers"])
+    (h, _), (ys, counts) = jax.lax.scan(layer, carry, stack)
     stacks.append(ys)
     new = [jnp.concatenate(parts, axis=0) for parts in zip(*stacks)]  # [L, 1, T, ..]
 
@@ -597,7 +723,7 @@ def mla_prefill_chunk_ragged(
     else:
         new_c, new_r = land(cache_c, new[0]), land(cache_r, new[1])
     last = jnp.take(h, jnp.clip(last_idx, 0, T - 1), axis=0)  # [Rn, D]
-    return _logits(cfg, params, last), new_c, new_r
+    return _logits(cfg, params, last), new_c, _second(pair_r, new_r, 1, counts)
 
 
 def _absorbed_w(lp, h_dtype, R, H, dn, dv):
@@ -636,9 +762,14 @@ def mla_decode_step(
     the layer scan — instead of L per-layer scatters, each of which XLA
     turns into a full-cache copy."""
     H, dn, dr, dv = _dims(cfg)
+    pair_r, cache_r = cache_r, _rows(cache_r)
     quantized = isinstance(cache_c, dict)
     L, B, _, S, R = (cache_c["q"] if quantized else cache_c).shape
     Ba = tokens.shape[0]
+    banks, stack = _expert_stack(cfg, params["layers"])
+    k_dense = _n_dense(params)
+    # in the share form a parked or padding row routes nothing and counts nothing
+    live = lengths < S if banks is not None else None
     scale = mla_scale(cfg)
     h = _embed_in(cfg, params, tokens)  # [Ba, D]
     cos, sin = rope_tables(cfg, dr, lengths)  # [Ba, dr/2]
@@ -689,8 +820,9 @@ def mla_decode_step(
                 kr[:, None].astype(cr_all.dtype)
             )
         # absorbed queries: q̃[h] = q_nope[h] @ W_uk[:, h]  → [Ba, H, R]
-        w_uk, w_uv = _absorbed_w(lp, h.dtype, R, H, dn, dv)
-        qt = jnp.einsum("bhd,rhd->bhr", qn, w_uk)
+        with jax.named_scope("mla.absorb"):
+            w_uk, w_uv = _absorbed_w(lp, h.dtype, R, H, dn, dv)
+            qt = jnp.einsum("bhd,rhd->bhr", qn, w_uk)
 
         def sel(x, pool=None):
             xl = jax.lax.dynamic_index_in_dim(x, li, 0, keepdims=False)
@@ -730,10 +862,12 @@ def mla_decode_step(
             scores = jnp.where(attn_mask[:, None, :], scores, neg)
             probs = jax.nn.softmax(scores, axis=-1).astype(h.dtype)
             ctx_lat = jnp.einsum("bhs,bsr->bhr", probs, lat.astype(probs.dtype))
-        ctx = jnp.einsum("bhr,rhd->bhd", ctx_lat, w_uv).reshape(Ba, H * dv)
-        h = h + qdot(ctx, lp["wo_mla"])
-        h = _ffn_residual(cfg, lp, h, moe_capacity=Ba)  # dropless at decode
-        return (h, cc_all, cr_all, li + 1), None
+        with jax.named_scope("mla.absorb"):
+            ctx = jnp.einsum("bhr,rhd->bhd", ctx_lat, w_uv).reshape(Ba, H * dv)
+        with jax.named_scope("mla.out"):
+            h = h + qdot(ctx, lp["wo_mla"])
+        h, counts = _ffn(cfg, lp, banks, li - k_dense, h, valid=live, capacity=Ba)  # dropless at decode
+        return (h, cc_all, cr_all, li + 1), counts
 
     if quantized and attn_impl == "pallas":
         from ..kernels.attention import decode_attend_q8_mla
@@ -745,27 +879,31 @@ def mla_decode_step(
             qr = apply_rope(qr, cos, sin)
             c, kr = _latents(cfg, lp, x)
             kr = apply_rope(kr[:, None], cos, sin)[:, 0]
-            w_uk, w_uv = _absorbed_w(lp, h.dtype, R, H, dn, dv)
-            qt = jnp.einsum("bhd,rhd->bhr", qn, w_uk)
-            ctx_lat = decode_attend_q8_mla(
-                qt, qr, c, kr, cache_c, cache_r, li, lengths,
-                slot_ids=slot_ids, scale=scale,
-                block_tables=None if paged is None else paged["tbl"],
-                pool_c=None if paged is None else paged["k"],
-                pool_r=None if paged is None else paged["v"],
-            )
-            ctx = jnp.einsum("bhr,rhd->bhd", ctx_lat.astype(h.dtype), w_uv)
-            h = h + qdot(ctx.reshape(Ba, H * dv), lp["wo_mla"])
-            h = _ffn_residual(cfg, lp, h, moe_capacity=Ba)
-            return (h, li + 1), (c, kr)
+            with jax.named_scope("mla.absorb"):
+                w_uk, w_uv = _absorbed_w(lp, h.dtype, R, H, dn, dv)
+                qt = jnp.einsum("bhd,rhd->bhr", qn, w_uk)
+            with jax.named_scope("mla.attend"):
+                ctx_lat = decode_attend_q8_mla(
+                    qt, qr, c, kr, cache_c, cache_r, li, lengths,
+                    slot_ids=slot_ids, scale=scale,
+                    block_tables=None if paged is None else paged["tbl"],
+                    pool_c=None if paged is None else paged["k"],
+                    pool_r=None if paged is None else paged["v"],
+                )
+            with jax.named_scope("mla.absorb"):
+                ctx = jnp.einsum("bhr,rhd->bhd", ctx_lat.astype(h.dtype), w_uv)
+            with jax.named_scope("mla.out"):
+                h = h + qdot(ctx.reshape(Ba, H * dv), lp["wo_mla"])
+            h, counts = _ffn(cfg, lp, banks, li - k_dense, h, valid=live, capacity=Ba)
+            return (h, li + 1), (c, kr, counts)
 
         carry = (h, jnp.int32(0))
         cs_d = krs_d = None
         if "dense_layers" in params:
-            carry, (cs_d, krs_d) = jax.lax.scan(
+            carry, (cs_d, krs_d, _) = jax.lax.scan(
                 layer_k, carry, params["dense_layers"]
             )
-        (h, _), (cs, krs) = jax.lax.scan(layer_k, carry, params["layers"])
+        (h, _), (cs, krs, counts) = jax.lax.scan(layer_k, carry, stack)
         if cs_d is not None:
             cs = jnp.concatenate([cs_d, cs], axis=0)
             krs = jnp.concatenate([krs_d, krs], axis=0)
@@ -786,12 +924,58 @@ def mla_decode_step(
                 rq["s"].astype(cache_r["s"].dtype)
             ),
         }
-        return _logits(cfg, params, h), cache_c, cache_r
+        return _logits(cfg, params, h), cache_c, _second(pair_r, cache_r, 0, counts)
 
     carry = (h, cache_c, cache_r, jnp.int32(0))
     if "dense_layers" in params:
         # dense prologue first — the carried layer index li keeps the cache
         # rows aligned with absolute layer position
         carry, _ = jax.lax.scan(layer, carry, params["dense_layers"])
-    (h, cache_c, cache_r, _), _ = jax.lax.scan(layer, carry, params["layers"])
-    return _logits(cfg, params, h), cache_c, cache_r
+    (h, cache_c, cache_r, _), counts = jax.lax.scan(layer, carry, stack)
+    return _logits(cfg, params, h), cache_c, _second(pair_r, cache_r, 0, counts)
+
+
+def init_mtp_params(cfg: ModelConfig, key: jax.Array, dtype: jnp.dtype = jnp.bfloat16) -> Params:
+    """ONE multi-token-prediction module's own weights, seeded (DeepSeek-V3's
+    form): `hnorm`, `enorm` [D] and `eh_proj` [2 D, D] that join the main
+    model's hidden state with the next token's embedding, one latent-attention
+    expert layer (`layers`: the main expert stack's leaves, stacked [1, ...])
+    and `final_norm` [D], the main final norm's twin. The embedding and the
+    head are the main model's, shared. Built where a caller asks, never by the
+    engine's boot: no step program runs the module (models/hybrid.py has the
+    GQA families' twin)."""
+    import dataclasses
+
+    assert cfg.mtp_layers and share_form(cfg), cfg.name
+    D = cfg.dim
+
+    def build(key):
+        ks = jax.random.split(key, 2)
+        one = dataclasses.replace(cfg, n_layers=1, first_dense_layers=0)
+        return {"hnorm": jnp.ones((D,), dtype), "enorm": jnp.ones((D,), dtype),
+                "eh_proj": (jax.random.normal(ks[0], (2 * D, D), jnp.float32)
+                            * (2 * D) ** -0.5).astype(dtype),
+                "layers": _init(one, ks[1], dtype)["layers"],
+                "final_norm": jnp.ones((D,), dtype)}
+
+    return jax.jit(build)(key)
+
+
+def mtp_logits(cfg, params, mtp, h, next_tokens, lengths):
+    """The module's forward over whole sequences: `h` [B, S, D] the main
+    model's residual stream after its last layer (`mla_prefill(hidden=True)`),
+    `next_tokens` [B, S] the token AFTER each position; logits [B, S, V] for the
+    token after that. h' = eh_proj [norm(h) ; norm(embed(next))], one
+    latent-attention expert layer as `mla_prefill` runs it, the module's final
+    norm and the main model's head."""
+    S = next_tokens.shape[1]
+    cos, sin = rope_tables(cfg, cfg.qk_rope_head_dim, jnp.arange(S, dtype=jnp.int32)[None, :])
+    valid = jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None]
+    x = qdot(jnp.concatenate([
+        _norm(cfg, h, mtp["hnorm"]), _norm(cfg, _embed_in(cfg, params, next_tokens), mtp["enorm"]),
+    ], axis=-1), mtp["eh_proj"])
+    banks, stack = _expert_stack(cfg, mtp["layers"])
+    lp = jax.tree.map(lambda a: a[0], stack)
+    x, _, _ = _prefill_attn(cfg, lp, x, cos, sin, valid)
+    x, _ = _ffn(cfg, lp, banks, 0, x, valid=valid)
+    return _logits(cfg, {**params, "final_norm": mtp["final_norm"]}, x)

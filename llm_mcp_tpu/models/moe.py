@@ -34,6 +34,16 @@ from ..kernels.grouped import ROW_TILE, grouped_ffn
 from .configs import ModelConfig
 
 
+def share_form(cfg: ModelConfig) -> bool:
+    """Whether the dense and latent-attention families run a configuration's
+    expert layer as `moe_share_ffn` (dropless, counted, the banks handed over
+    stacked) and not as `moe_ffn`'s capacity dispatch: where it states a share
+    of the published experts (`n_router_experts`) or a sigmoid router, neither
+    of which `moe_ffn` computes. From what the preset states, no switch. (The
+    decoder of unlike layers, models/hybrid.py, has no other form.)"""
+    return bool(cfg.n_experts and (cfg.n_router_experts or cfg.router_score == "sigmoid"))
+
+
 def expert_capacity(cfg: ModelConfig, n_tokens: int) -> int:
     """Static per-expert token capacity for a T-token step."""
     c = math.ceil(n_tokens * cfg.experts_per_tok / cfg.n_experts * cfg.capacity_factor)

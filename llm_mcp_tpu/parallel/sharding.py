@@ -45,6 +45,11 @@ def llama_param_specs(cfg: ModelConfig) -> dict[str, Any]:
             "wo_mla": P("pp", "tp", None),
             "ffn_norm": P("pp", None),
         }
+        if cfg.q_lora_rank:  # the query through a latent of its own: its down-
+            # projection and norm replicate as the key/value latent's do
+            del attn["wq_mla"]
+            attn.update(w_dq=P("pp", None, None), q_a_norm=P("pp", None),
+                        w_uq=P("pp", None, "tp"))
         dense_ffn = {
             "w1": P("pp", None, "tp"),
             "w3": P("pp", None, "tp"),
@@ -65,6 +70,8 @@ def llama_param_specs(cfg: ModelConfig) -> dict[str, Any]:
                         "w2s": P("pp", "tp", None),
                     }
                 )
+            if cfg.router_score == "sigmoid":
+                ffn["router_bias"] = P("pp", None)
         else:
             ffn = dense_ffn
         specs: dict[str, Any] = {

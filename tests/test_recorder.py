@@ -611,7 +611,14 @@ def test_watchdog_transitions_and_metrics_bridge(base, server):
     assert "llmtpu_flight_dropped_events" in text
 
 
-def test_dashboard_carries_anomaly_and_compile_blocks(base):
+def test_dashboard_carries_anomaly_and_compile_blocks(base, server):
+    eng = server.gen_engines["tiny-llm"]
+    if not eng._anomaly.stats()["by_detector"].get("decode_stall"):
+        # under `--dist load` the stall test above may have run in another
+        # worker, with a server of its own: one episode of this worker's own
+        eng._anomaly.signal("decode_stall", gap_s=120.0, ema_s=0.01, busy=2)
+    if not eng.flight_stats()["compile"]["entries"]:  # and a first dispatch of its own
+        _chat(base, max_tokens=4)
     doc = httpx.get(f"{base}/v1/dashboard").json()
     assert "anomalies" in doc and "compiles" in doc
     eng = doc["anomalies"]["tiny-llm"]

@@ -963,3 +963,104 @@ def test_a_fall_to_the_reference_is_counted(tmp_path):
         A.reference_falls.clear()
         A.reference_falls.update(falls)
     assert len(flight.get_recorder().snapshot(etype="kernel_fall")) == before
+
+
+@pytest.fixture(scope="module")
+def joyai(one_chip):
+    """`joyai-llm-flash-ep16`, whole depth, 64 slots x 1024, as its cell boots it."""
+    return hybrid_shapes("joyai-llm-flash-ep16", one_chip, SOLAR_SLOTS, SOLAR_S)
+
+
+def joyai_program(which: str, cfg):
+    """The latent family's step programs as the engine builds them: the decode
+    round and the bucketed chunk are `solar_program`'s (the same dispatch through
+    models/llama.py); the admit program inserts rows of BOTH members of the
+    latent pair, as `engine._insert_row` does for a counted pair; the packed
+    chunk is the ragged program."""
+    from llm_mcp_tpu.models import hybrid, llama
+
+    def admit(params, ck, cv, tokens, lengths, slots):
+        logits, ks, vs = llama.llama_prefill(
+            cfg, params, tokens, lengths, attn_impl="pallas", quant_kv=True)
+
+        def put(c, rows, i, slot):
+            return jax.lax.dynamic_update_slice(
+                c, jax.lax.dynamic_slice_in_dim(rows, i, 1, 1), (0, slot) + (0,) * (c.ndim - 2))
+
+        def body(i, cc):
+            ck, cv = cc
+            ck = jax.tree.map(lambda c, r: put(c, r, i, slots[i]), ck, ks)
+            return ck, dict(cv, v=jax.tree.map(lambda c, r: put(c, r, i, slots[i]), cv["v"], vs["v"]))
+
+        ck, cv = jax.lax.fori_loop(0, tokens.shape[0], body, (ck, cv))
+        return logits, ck, hybrid.add_counts(cv, vs)
+
+    def ragged(params, ck, cv, tokens, rowids, positions, slots, starts, last_idx):
+        return llama.llama_prefill_chunk_ragged(
+            cfg, params, ck, cv, tokens, rowids, positions, slots, starts, last_idx, impl="kernel")
+
+    return {"admit": admit, "ragged": ragged}.get(which) or solar_program(which, cfg)
+
+
+@pytest.mark.parametrize("which,operands", [
+    ("decode", [(64,), (64,), (64,)]),  # every slot a row
+    ("admit", [(4, 128), (4,), (4,)]),  # the cell's largest admit program, 512 padded tokens
+    ("admit", [(1, 64), (1,), (1,)]),  # and its smallest
+    ("chunk", [(2, 512), (2,), (2,), (2,)]),  # two prompts' second chunks of 512
+    ("ragged", [(512,), (512,), (512,), (4,), (4,), (4,)]),  # a packed buffer of 512 tokens, four rows
+])
+def test_joyai_step_programs_fit_with_the_banks_whole_beside_the_latent_cache(
+    sd, joyai, chip_kernels, which, operands
+):
+    """The decode round of 64 rows, the admit programs the traffic meets, a
+    bucketed chunk and a packed chunk of `joyai-llm-flash-ep16` (the published
+    widths, all 40 layers, 16 of 256 experts of 2048 x 768 a layer) at its cell's
+    64 slots x 1024 compile for the described v5e: the MLA step programs' first
+    compile for the chip (ROADMAP B2, debt (d)). The two grouped expert kernels in
+    every program, the latent decode attention as the whole-S arm
+    (`decode_attn_mla_q8_whole`: 1024 positions fit its VMEM budget) in the decode
+    round, `ragged_prefill_attn_mla` in the packed chunk, every one a Mosaic call
+    with no fall to its reference. Each fits the chip beside 9.55 GB of weights
+    and the 1.52 GB latent cache; the temporaries hold no copy of a layer's banks
+    (151 MB a layer: the stack goes in whole) nor of the leading dense layer's
+    feed-forward (a stack of ONE layer scanned once: sliced in place), and the
+    latent pair is updated in place. GiB in PERF.md section 4 as "described-chip
+    compile"."""
+    cfg, params, cache = joyai
+    falls = dict(A.reference_falls)
+    compiled = jax.jit(joyai_program(which, cfg), donate_argnums=(1, 2)).lower(
+        params, cache["k"], cache["v"], *(sd(shape, I32) for shape in operands)).compile()
+    assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
+    text = compiled.as_text()
+    assert grouped_kernels_in(text)
+    assert ("decode_attn_mla_q8_whole" in text) == (which == "decode")
+    assert "decode_attn_mla_q8_blocked" not in text and "decode_attn_mla_q8_paged" not in text
+    assert ("ragged_prefill_attn_mla" in text) == (which == "ragged")
+    assert cache["k"]["q"].shape == (40, 64, 1, 1024, 512) and cache["v"]["v"]["q"].shape == (40, 64, 1, 1024, 64)
+    assert cache["v"]["moe"].shape == (2, 39, 5)
+    assert cache_relayouts(text, cache["k"]["q"].shape) == []
+    assert params["layers"]["w1e"].shape == (39, 16, 2048, 768) and params["dense_layers"]["w1"].shape == (1, 2048, 7168)
+    nbytes = lambda tree: sum(  # noqa: E731
+        int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(tree))
+    weights, latent = nbytes(params), nbytes(cache) - cache["v"]["moe"].size * 4
+    # bfloat16 but for the 39 selection biases [256], float32
+    assert weights == 2 * cfg.param_count() + 2 * 39 * 256 == 9_553_062_912
+    assert latent == 40 * 64 * 1024 * (512 + 64 + 4) == 1_520_435_200  # 580 bytes a position and layer
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"joyai {which} {operands}: {total / 2**30:.2f} GiB, of it temporaries "
+          f"{mem.temp_size_in_bytes / 2**30:.3f} GiB, arguments "
+          f"{mem.argument_size_in_bytes / 2**30:.2f} GiB (weights {weights / 2**30:.2f}, "
+          f"latent cache {latent / 2**30:.2f} logical)")
+    assert total < 14.5 * 2**30
+    # No copy of a layer's banks (0.14 GiB a layer and step would be 5.5 GiB a
+    # round) nor of `w_uq` (0.69 GiB until its columns were `[H dn | H dr]`). What
+    # the decode round's 1.06 GiB still holds, every ROUND: `w_ukv` transposed whole
+    # for the absorbed products (0.30), `w_dkv` (0.09), and the rope keys' int8
+    # cache, whose rows are 64 lanes, re-laid four times (0.16 each): ROADMAP B2.
+    assert mem.temp_size_in_bytes < (1.1 if which == "decode" else 1.0) * 2**30
+    if which == "decode":
+        assert "bf16[39,1536,6144]" not in "".join(
+            line for line in text.splitlines() if " copy(" in line)  # `w_uq` read in place
+    assert mem.alias_size_in_bytes > 0.99 * latent  # the latent pair updated in place
