@@ -56,6 +56,8 @@ from ..kernels.attention import (
     resolve_attn_impl,
     resolve_decode_impl,
     resolve_ragged_impl,
+    rope_apart,
+    rope_put,
 )
 from ..utils.faults import maybe_fail
 from ..utils.platform import on_tpu
@@ -118,6 +120,25 @@ def _tree2(fn, a, b):
     return fn(a, b)
 
 
+def _put_rows(c, rows, slot, start):
+    """Rows [L, 1, H, n, *rest] cut out of a cache (positions on axis 3) into
+    positions [start, start + n) of cache row `slot`, in place (a
+    dynamic_update_slice: an advanced-index scatter would copy the whole
+    payload). The latent pair's int8 rope keys lie P positions abreast in the
+    cache and apart wherever they are cut out of it (a prompt's rows, a prefix
+    entry, a pool's block, a host copy), so theirs is `rope_put`."""
+    if c.ndim == 5 and c.shape[4] != rows.shape[4]:
+        return rope_put(c, rows, (0, slot), start)
+    return jax.lax.dynamic_update_slice(
+        c, rows.astype(c.dtype), (0, slot, 0, start) + (0,) * (c.ndim - 4))
+
+
+def _slot_rows(c, slot, abreast: int = 1):
+    """One cache row [L, 1, H, S, *rest] with its positions in order on axis 3:
+    the leaf's own, or of the rope keys that lie `abreast`, pulled apart."""
+    return rope_apart(jax.lax.dynamic_slice_in_dim(c, slot, 1, 1), abreast)
+
+
 def _cow_block_raw(ck, cv, pk, pv, slot, blk, prow):
     """Physical copy-on-write: copy ONE prefix-pool block (pool row `prow`)
     into a slot's arena at block index `blk` — the boundary block of an
@@ -132,9 +153,7 @@ def _cow_block_raw(ck, cv, pk, pv, slot, blk, prow):
             pool, (0, prow, 0, 0) + z,
             (pool.shape[0], 1, pool.shape[2], bt) + pool.shape[4:],
         )
-        return jax.lax.dynamic_update_slice(
-            arena, seg.astype(arena.dtype), (0, slot, 0, blk * bt) + z
-        )
+        return _put_rows(arena, seg, slot, blk * bt)
 
     return _tree2(one, ck, pk), _tree2(one, cv, pv)
 
@@ -149,8 +168,11 @@ def _pool_put_arena_raw(pk, pv, ck, cv, row, off, prow):
     def one(pool, arena):
         z = (0,) * (arena.ndim - 4)
         bt = pool.shape[3]
+        src = row
+        if arena.ndim == 5 and arena.shape[4] != pool.shape[4]:  # rope keys abreast
+            arena, src = _slot_rows(arena, row, arena.shape[4] // pool.shape[4]), 0
         seg = jax.lax.dynamic_slice(
-            arena, (0, row, 0, off) + z,
+            arena, (0, src, 0, off) + z,
             (arena.shape[0], 1, arena.shape[2], bt) + arena.shape[4:],
         )
         return jax.lax.dynamic_update_slice(
@@ -747,8 +769,10 @@ class GenerationEngine:
             self._attn_stream = AttnStream(self._ck["q"].shape, kv_heads=self.cfg.n_kv_heads)
         elif layout.latent and layout.int8 and self.decode_impl == "pallas":
             # the latent arms: whole-S where it fits, else blocks of the prefix
-            self._attn_stream = AttnStream(self._ck["q"].shape, block_tokens=mla_stream_block(
-                max_seq_len, self.cfg.kv_lora_rank, self.cfg.qk_rope_head_dim, self.cfg.n_heads))
+            self._attn_stream = AttnStream(
+                self._ck["q"].shape, block_tokens=mla_stream_block(
+                    max_seq_len, self.cfg.kv_lora_rank, self.cfg.qk_rope_head_dim, self.cfg.n_heads),
+                positions_abreast=max_seq_len // layout.kv_rows(self._ck, self._cv)["v"]["q"].shape[3])
         # and what the window arm streams of the window layers' rings
         self._win_stream = (
             AttnStream(self._cv["win"]["k"]["q"].shape, window=self.cfg.sliding_window,
@@ -989,9 +1013,7 @@ class GenerationEngine:
             # in place (an advanced-index scatter would copy the full cache
             # payload).
             def put(c, rows):
-                row = jax.lax.dynamic_slice_in_dim(rows, i, 1, 1)
-                return jax.lax.dynamic_update_slice(
-                    c, row.astype(c.dtype), (0, slot) + (0,) * (c.ndim - 2))
+                return _put_rows(c, jax.lax.dynamic_slice_in_dim(rows, i, 1, 1), slot, 0)
 
             return jax.tree.map(put, ck, ks), jax.tree.map(put, cv, vs)
 
@@ -1112,8 +1134,7 @@ class GenerationEngine:
             just re-inserted below it. Restore guarantees start+R = bucket
             <= S, so the traced start is never clamped."""
             def put(c, rows):
-                return jax.lax.dynamic_update_slice(
-                    c, rows.astype(c.dtype), (0, slot, 0, start) + (0,) * (c.ndim - 4))
+                return _put_rows(c, rows, slot, start)
 
             return jax.tree.map(put, ck, pk), jax.tree.map(put, cv, pv)
 
@@ -1908,10 +1929,11 @@ class GenerationEngine:
                         k: cut(arr[k], None if pool is None else pool[k])
                         for k in arr
                     }
+                P = self.max_seq_len // arr.shape[3]  # the rope keys' positions abreast
                 if srcs is None:
-                    return self._fetch(arr[:, b : b + 1, :, start:Lb])
+                    return self._fetch(_slot_rows(arr, b, P)[:, :, :, start:Lb])
                 parts = [
-                    arr[:, row : row + 1, :, off : off + bt]
+                    _slot_rows(arr, row, P)[:, :, :, off : off + bt]
                     if in_arena
                     else pool[:, row : row + 1]
                     for in_arena, row, off in srcs
@@ -1925,8 +1947,10 @@ class GenerationEngine:
 
         def op_pfxput(eid, slot, p0):
             # park a slot's prefix rows [0, p0) as a device prefix entry
-            pk = _tree2(lambda c, _: c[:, slot : slot + 1, :, :p0], self._ck, self._ck)
-            pv = _tree2(lambda c, _: c[:, slot : slot + 1, :, :p0], self._cv, self._cv)
+            def cut(c, _):
+                return _slot_rows(c, slot, self.max_seq_len // c.shape[3])[:, :, :, :p0]
+
+            pk, pv = _tree2(cut, self._ck, self._ck), _tree2(cut, self._cv, self._cv)
             self._x_prefix[eid] = (pk, pv)
             return pk, pv
 
@@ -5151,7 +5175,7 @@ class GenerationEngine:
                 self._phys.sweep(self._paging.alive)
                 return
             nbytes = sum(
-                (x.size // (x.shape[1] * x.shape[3])) * p0 * x.dtype.itemsize
+                (x.size // (x.shape[1] * self.max_seq_len)) * p0 * x.dtype.itemsize
                 for x in jax.tree.leaves((self._ck, self._cv))
             )
             ent = {"P": p0, "bytes": nbytes, "key": key}
@@ -5453,7 +5477,7 @@ class GenerationEngine:
                     self.prefix_import_rejects_total += 1
                 return False
             nbytes = sum(
-                (x.size // (x.shape[1] * x.shape[3])) * P0 * x.dtype.itemsize
+                (x.size // (x.shape[1] * self.max_seq_len)) * P0 * x.dtype.itemsize
                 for x in jax.tree.leaves((self._ck, self._cv))
             )
             ent = {"P": P0, "bytes": nbytes, "key": key}

@@ -129,7 +129,8 @@ class CacheLayout:
         """The physical prefix pools {"k", "v"} (executor/physical.py): the
         pair's leaves with the slot axis `rows` long and S `block_tokens`."""
         return self._born(
-            partial(pool_like, jax.eval_shape(self._init), rows, block_tokens), self.pool_specs())
+            partial(pool_like, jax.eval_shape(self._init), rows, block_tokens, self.max_seq_len),
+            self.pool_specs())
 
     def entries(self, ks, vs) -> tuple[Any, Any]:
         """A prompt's float K/V rows in the form the pair stores them; inside

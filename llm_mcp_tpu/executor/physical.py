@@ -48,7 +48,7 @@ import numpy as np
 log = logging.getLogger("llm_mcp_tpu.physical")
 
 
-def pool_like(cache: Any, pool_rows: int, block_tokens: int) -> Any:
+def pool_like(cache: Any, pool_rows: int, block_tokens: int, seq_len: int = 0) -> Any:
     """Allocate a prefix pool pytree mirroring a KV cache pytree.
 
     Every cache leaf is ``[L, B, heads, S, *rest]`` (rest may be empty —
@@ -56,14 +56,18 @@ def pool_like(cache: Any, pool_rows: int, block_tokens: int) -> Any:
     slot axis for ``pool_rows`` and the S axis for ``block_tokens``:
     ``[L, pool_rows, heads, block_tokens, *rest]``. One pool row holds
     one block's tokens across *all* layers, matching the ledger's
-    bytes-per-block accounting.
+    bytes-per-block accounting. A leaf whose rows hold P = ``seq_len`` / S
+    positions abreast (the latent pair's int8 rope keys,
+    kernels/attention.py:rope_abreast) is pooled APART, a position a row,
+    as everything cut out of the cache lies: ``rest`` is then a P-th as wide.
     """
     import jax
     import jax.numpy as jnp
 
     def leaf(c):
-        shape = (c.shape[0], pool_rows, c.shape[2], block_tokens) + c.shape[4:]
-        return jnp.zeros(shape, dtype=c.dtype)
+        P = max(1, seq_len // c.shape[3])
+        rest = c.shape[4:] if P == 1 else (c.shape[4] // P,) + c.shape[5:]
+        return jnp.zeros((c.shape[0], pool_rows, c.shape[2], block_tokens) + rest, dtype=c.dtype)
 
     return jax.tree.map(leaf, cache)
 

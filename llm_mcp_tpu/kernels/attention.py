@@ -1004,12 +1004,16 @@ class AttnStream:
     full-length cache's). With `block_tokens` the cache is the int8 LATENT one
     (`decode_attend_q8_mla`, whose arm is chosen from the shape alone:
     `mla_stream_block`) and `cache_q_shape` its latents': `tokens_live` is then
-    the latent positions the steps read, rows x their lengths."""
+    the latent positions the steps read, rows x their lengths, and
+    `positions_abreast` says how many positions share a row of its rope keys."""
 
     def __init__(self, cache_q_shape: tuple[int, ...], window: int = 0, max_seq_len: int = 0,
-                 kv_heads: int = 0, block_tokens: int | None = None):
+                 kv_heads: int = 0, block_tokens: int | None = None, positions_abreast: int = 1):
         _, _, rows, self.seq_len, row_lanes = cache_q_shape
         self.window = window
+        # P of the latent pair's rope keys (`positions_abreast`, read off the
+        # pair's shapes by the engine); 1 for every other cache
+        self.positions_abreast = positions_abreast
         self.parked_at = max_seq_len or self.seq_len
         # P: heads abreast in a payload row (`fused_q8_heads`' rule, from the
         # configuration's KV heads; without them, a head a row)
@@ -1036,7 +1040,7 @@ class AttnStream:
 
     def stats(self) -> dict:
         return {"block_tokens": self.block_tokens, "heads_abreast": self.heads_abreast,
-                "steps": self.steps,
+                "positions_abreast": self.positions_abreast, "steps": self.steps,
                 "tokens_streamed": self.tokens_streamed, "tokens_live": self.tokens_live,
                 "live_over_streamed": round(self.tokens_live / self.tokens_streamed, 4)
                 if self.tokens_streamed else None,
@@ -1110,6 +1114,120 @@ def ctx_apart(ctx: jnp.ndarray, abreast: int) -> jnp.ndarray:
     ctx = ctx.reshape(*lead, R, abreast, G, abreast, hd)
     own = jnp.stack([ctx[..., p, :, p, :] for p in range(abreast)], axis=n)  # [..., P, R, G, hd]
     return own.reshape(*lead, abreast * R, G, hd)
+
+
+def positions_abreast(seq_len: int, width: int) -> int:
+    """P: cache POSITIONS that lie side by side in one row of the latent pair's
+    int8 rope keys. The pair has one "head", `width` (qk_rope_head_dim) wide;
+    where that is narrower than the 128 lanes and divides them, as many
+    positions as fill them share a row, so that the member's minor dimension
+    is whole lanes and the chip's layout of it is the kernels' (a minor
+    dimension of 64 was laid out with positions minor, and every step program
+    re-laid the whole member for its Mosaic call and for its append: PERF.md
+    section 6, PR 58); 1 where the width is 128 or more, or P does not divide
+    the positions. A function of the shape alone, as `kv_heads_abreast` is."""
+    P = LANES // width if width < LANES and LANES % width == 0 else 1
+    return P if seq_len % P == 0 else 1
+
+
+def rope_abreast(x: jnp.ndarray, abreast: int) -> jnp.ndarray:
+    """[..., S, d] -> [..., S / P, P*d]: position s in row s mod S/P, lanes
+    [(s div S/P) d, +d). The P lane groups of the rows, laid end to end, are
+    the positions in order: a product over whole rows with the queries in one
+    group's lanes (`q_abreast`) gives that group's scores, and the groups'
+    scores side by side are the scores in the latents' order, no interleave.
+    The P runs of S/P positions, side by side along the lanes: written as that
+    concatenation and not as a transpose, which made the compiler lay a step's
+    few rows out with positions minor and re-lay the WHOLE cache to suit them
+    (described-chip compile of the bucketed chunk, PR 58)."""
+    if abreast == 1:
+        return x
+    half = x.shape[-2] // abreast
+    return jnp.concatenate(
+        [x[..., g * half:(g + 1) * half, :] for g in range(abreast)], axis=-1)
+
+
+def rope_apart(x: jnp.ndarray, abreast: int) -> jnp.ndarray:
+    """[..., S / P, P*d] -> [..., S, d]: `rope_abreast`'s inverse, the lane
+    groups end to end."""
+    if abreast == 1:
+        return x
+    d = x.shape[-1] // abreast
+    return jnp.concatenate([x[..., g * d:(g + 1) * d] for g in range(abreast)], axis=-2)
+
+
+def rope_queries(qr: jnp.ndarray, abreast: int) -> jnp.ndarray:
+    """Rope queries [..., H, d] -> [..., P*H, P*d] for rope keys P positions
+    abreast: rows [p*H, (p+1)*H) hold the queries in lane group p, zeros
+    elsewhere (`q_abreast` with the P runs of positions as the heads)."""
+    if abreast == 1:
+        return qr
+    *lead, H, d = qr.shape
+    q = jnp.broadcast_to(qr[..., None, :, :], (*lead, abreast, H, d))
+    return q_abreast(q, abreast)[..., 0, :, :]
+
+
+def rope_put(cache, rows, at, start, keep=None):
+    """Rows of rope keys [n_l, 1, 1, n, d], apart, into positions [start,
+    start + n) of cache row `at[1]`, layers [at[0], at[0] + n_l), of `cache`
+    [L, B, 1, S / P, P*d], in place; `keep` [n] bool: which of them land (all).
+    At P = 1 the one dynamic_update_slice every family's insert is. Else the
+    row's S/P x 128 lanes are read, the new positions selected into their
+    lanes and the row written back whole: a write that starts or ends inside
+    a row leaves its neighbours' bytes, and the update has the cache's own
+    layout (models/llama.py:ragged_write_rows says what any other costs).
+    `start` is clamped as dynamic_update_slice clamps it."""
+    n_l, n, d = rows.shape[0], rows.shape[3], rows.shape[4]
+    half, W = cache.shape[3:]
+    P = W // d
+    rows = rows.astype(cache.dtype)
+    if P == 1 and keep is None:
+        return jax.lax.dynamic_update_slice(cache, rows, (at[0], at[1], 0, start, 0))
+    S = half * P
+    start = jnp.clip(start, 0, S - n)
+    whole = jax.lax.dynamic_update_slice(
+        jnp.zeros((n_l, 1, 1, S, d), cache.dtype), rows, (0, 0, 0, start, 0))
+    pos = (jnp.arange(W, dtype=jnp.int32) // d)[None, :] * half + jnp.arange(half, dtype=jnp.int32)[:, None]
+    if keep is None:
+        lands = (pos >= start) & (pos < start + n)
+    else:
+        hit = jax.lax.dynamic_update_slice(jnp.zeros((S,), bool), keep, (start,))
+        lands = rope_abreast(jnp.broadcast_to(hit[:, None], (S, d)), P)
+    origin = (at[0], at[1], 0, 0, 0)
+    cur = jax.lax.dynamic_slice(cache, origin, (n_l, 1, 1, half, W))
+    return jax.lax.dynamic_update_slice(
+        cache, jnp.where(lands, rope_abreast(whole, P), cur), origin)
+
+
+def rope_append(cache, new, layers, rows, w):
+    """One position a cache row: `new` [..., Ba, d] lands at position `w` [Ba]
+    of rows `rows` [Ba], layers `layers` (a scalar, or [L, 1] against [1, Ba]
+    indices), of `cache` [L, B, 1, S / P, P*d]; a parked row (w >= S) is
+    dropped. At P = 1 the scatter of one position it always was. Else the
+    position's row of 128 lanes is gathered, its lane group selected and the
+    row scattered back whole: the neighbours' bytes stay."""
+    half, W = cache.shape[3:]
+    d = new.shape[-1]
+    P = W // d
+    new = new.astype(cache.dtype)
+    if P == 1:
+        return cache.at[layers, rows, 0, w].set(new)
+    r = jnp.where(w < half * P, w % half, half)  # out of range: dropped
+    cur = cache.at[layers, rows, 0, r].get(mode="clip")  # [..., Ba, W]
+    own = (jnp.arange(W, dtype=jnp.int32) // d) == (w // half)[..., None]
+    return cache.at[layers, rows, 0, r].set(jnp.where(own, jnp.tile(new, P), cur))
+
+
+def rope_rows(plane, abreast: int, slots=None, tables=None, pool=None, nbs=None):
+    """Rope keys of one layer's plane [B, 1, S / P, P*d], PULLED APART, for the
+    rows a call reads, the positions in order [A, S', d]: rows `slots` (None:
+    every row), or block-indirect through `tables` and `pool` (a pool's rows,
+    as every row cut out of the cache, lie apart). What the readers OUTSIDE the
+    kernels' main path take: the references, the XLA decode path, the packed
+    chunk's few gathered rows."""
+    if tables is not None:
+        return paged_gather(rope_apart(plane, abreast), pool, tables, nbs=nbs)[:, 0]
+    return rope_apart((plane if slots is None else jnp.take(plane, slots, axis=0))[:, 0], abreast)
 
 
 def fused_q8_heads(cache_k: dict) -> tuple[int, int, int]:
@@ -2025,17 +2143,36 @@ def decode_attend_bf16(
     return jax.lax.cond(ident, run_contig, run_paged)
 
 
+def _rope_scores(qr, rop_rows, lo: int, n: int, half: int, H: int):
+    """Scores [H, n] f32, before the scales, of positions [lo, lo + n) (static)
+    of a row whose int8 rope keys lie P abreast (`rope_abreast`): `qr` [P*H,
+    P*dr] f32 are `rope_queries`' rows, `rop_rows(r0, m)` the row's rows [r0,
+    r0 + m) whole, [m, P*dr] int8. A run of positions inside one lane group is
+    ONE product over whole rows with that group's query rows (zeros in the
+    other groups' lanes add nothing), and the runs laid side by side are the
+    positions in order."""
+    parts, s = [], lo
+    while s < lo + n:
+        g, r0 = divmod(s, half)
+        m = min(lo + n - s, half - r0)
+        parts.append(jax.lax.dot_general(
+            qr[g * H:(g + 1) * H], rop_rows(r0, m).astype(jnp.float32),
+            (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32))
+        s += m
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
 def _attend_q8_mla_kernel(
     li_ref,  # [1] int32 (scalar prefetch) — layer index
     ids_ref,  # [Ba] int32 (scalar prefetch) — cache row per batch position
     lengths_ref,  # [Ba] int32 (scalar prefetch) — this step's position per row
     qt_ref,  # [1, H, R] — absorbed queries (latent space)
-    qr_ref,  # [1, H, dr] — rope queries
+    qr_ref,  # [1, P*H, P*dr] — rope queries, a lane group a row group (`rope_queries`)
     nc_ref,  # [1, 1, R] — this step's exact latent
-    nr_ref,  # [1, 1, dr] — this step's exact rope key
+    nr_ref,  # [1, 1, P*dr] — this step's exact rope key in lane group 0, zeros beside
     lat_ref,  # [1, 1, 1, S, R] int8 — latent payload (cache row ids[b])
     lats_ref,  # [1, 1, 1, S] — latent scales
-    rop_ref,  # [1, 1, 1, S, dr] int8 — rope-key payload
+    rop_ref,  # [1, 1, 1, S/P, P*dr] int8 — rope-key payload, P positions abreast
     rops_ref,  # [1, 1, 1, S] — rope-key scales
     o_ref,  # [1, H, R] — context in latent space
     *,
@@ -2049,8 +2186,9 @@ def _attend_q8_mla_kernel(
     structural difference: scores take a SECOND additive term from the
     shared rope keys. The latent side (R = 512 at DeepSeek shapes — the
     bulk of the HBM traffic) runs s8 x s8 -> s32 on the MXU with post-dot
-    scale folding; the rope side (dr = 64, ~1/9 of the bytes and below the
-    128-lane int8 tile width) dequantizes on the VPU and dots in f32.
+    scale folding; the rope side (dr = 64, ~1/9 of the bytes, P = 2 positions
+    abreast in rows of the 128 lanes: `rope_abreast`) converts on the VPU and
+    dots in f32 over whole rows, its scales folded post-dot too (`_rope_scores`).
     Position w's score and value come from the exact unquantized vectors,
     so the current token is attended at full precision whether or not the
     quantized row has been scattered yet.
@@ -2060,9 +2198,10 @@ def _attend_q8_mla_kernel(
     S = lat_ref.shape[3]
 
     qt = qt_ref[0].astype(jnp.float32)  # [H, R]
-    qr = qr_ref[0].astype(jnp.float32)  # [H, dr]
+    qr = qr_ref[0].astype(jnp.float32)  # [P*H, P*dr]
+    H = qt.shape[0]
     nc = nc_ref[0, 0].astype(jnp.float32)  # [R]
-    nr = nr_ref[0, 0].astype(jnp.float32)  # [dr]
+    nr = nr_ref[0, 0].astype(jnp.float32)  # [P*dr]
     lats = lats_ref[0, 0, 0].astype(jnp.float32)  # [S]
     rops = rops_ref[0, 0, 0].astype(jnp.float32)  # [S]
 
@@ -2078,15 +2217,14 @@ def _attend_q8_mla_kernel(
     )  # [H, S]
     s = s_lat_i.astype(jnp.float32) * (scale * qsc)[:, None] * lats[None, :]
 
-    # rope scores: S x dr is tiny — dequant on the VPU, f32 dot
-    rop = rop_ref[0, 0, 0].astype(jnp.float32) * rops[:, None]  # [S, dr]
-    s = s + jax.lax.dot_general(
-        qr, rop, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ) * scale
+    # rope scores: S x dr is tiny — f32 dot over the rows as they lie
+    s = s + _rope_scores(
+        qr, lambda r0, m: rop_ref[0, 0, 0, r0:r0 + m, :], 0, S, rop_ref.shape[3], H
+    ) * scale * rops[None, :]
 
     pos = jax.lax.broadcasted_iota(jnp.int32, (1, S), 1)
     s_new = (
-        jnp.sum(qt * nc[None, :], axis=-1) + jnp.sum(qr * nr[None, :], axis=-1)
+        jnp.sum(qt * nc[None, :], axis=-1) + jnp.sum(qr[:H] * nr[None, :], axis=-1)
     ) * scale  # [H]
     s = jnp.where(pos == w, s_new[:, None], s)
     s = jnp.where(pos <= w, s, NEG_INF)
@@ -2126,15 +2264,15 @@ def _attend_q8_mla_blocked_kernel(
     ids_ref,  # [Ba] int32 (scalar prefetch) — cache row per batch position
     lengths_ref,  # [Ba] int32 (scalar prefetch) — this step's position per row
     qt_ref,  # [1, H, R] VMEM — absorbed queries (latent space)
-    qr_ref,  # [1, H, dr] VMEM — rope queries
+    qr_ref,  # [1, P*H, P*dr] VMEM — rope queries (`rope_queries`)
     nc_ref,  # [1, 1, R] VMEM — this step's exact latent
-    nr_ref,  # [1, 1, dr] VMEM — this step's exact rope key
+    nr_ref,  # [1, 1, P*dr] VMEM — this step's exact rope key, lane group 0
     lat_hbm,  # [L, B, 1, S, R] int8 — latent payload, stays in HBM (ANY)
     lats_ref,  # [1, 1, 1, S] VMEM — latent scales (whole row via BlockSpec)
-    rop_ref,  # [1, 1, 1, S, dr] VMEM — rope payload (whole row: dr < the
-    #           128-lane tile, so a manual DMA of a [BS, dr] slice of its
-    #           lane-padded HBM layout is rejected; the BlockSpec pipeline
-    #           is layout-aware. Rope+scales are ≤1/8 of the latent bytes
+    rop_ref,  # [1, 1, 1, S/P, P*dr] VMEM — rope payload, P positions abreast
+    #           (whole row: a block of positions is a run of rows in ONE lane
+    #           group, which no manual DMA cuts out; the BlockSpec pipeline
+    #           brings the row. Rope+scales are ≤1/8 of the latent bytes
     #           and the caller caps S//BS at 64, so whole-row VMEM is ≤3 MB)
     rops_ref,  # [1, 1, 1, S] VMEM — rope scales
     o_ref,  # [1, H, R] VMEM out — context in latent space
@@ -2191,17 +2329,17 @@ def _attend_q8_mla_blocked_kernel(
     start(0, 0)
 
     qt = qt_ref[0].astype(jnp.float32)  # [H, R]
-    qr = qr_ref[0].astype(jnp.float32)  # [H, dr]
+    qr = qr_ref[0].astype(jnp.float32)  # [P*H, P*dr]
+    H, R = qt.shape
     nc = nc_ref[0, 0].astype(jnp.float32)  # [R]
-    nr = nr_ref[0, 0].astype(jnp.float32)  # [dr]
+    nr = nr_ref[0, 0].astype(jnp.float32)  # [P*dr]
     qa = jnp.max(jnp.abs(qt), axis=-1)
     qsc = jnp.maximum(qa / 127.0, 1e-30)
     qt8 = jnp.round(qt / qsc[:, None]).astype(jnp.int8)
     s_new = (
-        jnp.sum(qt * nc[None, :], axis=-1) + jnp.sum(qr * nr[None, :], axis=-1)
+        jnp.sum(qt * nc[None, :], axis=-1) + jnp.sum(qr[:H] * nr[None, :], axis=-1)
     )[:, None] * scale  # [H, 1]
 
-    H, R = qt.shape
     acc = jnp.zeros((H, R), jnp.float32)
     m = jnp.full((H, 1), NEG_INF, jnp.float32)
     l = jnp.zeros((H, 1), jnp.float32)
@@ -2220,12 +2358,11 @@ def _attend_q8_mla_blocked_kernel(
             qt8, lat, (((1,), (1,)), ((), ())), preferred_element_type=jnp.int32
         )  # [H, BS]
         s = s_i.astype(jnp.float32) * (scale * qsc)[:, None] * lats[None, :]
-        # rope scores: BS x dr is tiny — dequant on the VPU, f32 dot
+        # rope scores: BS x dr is tiny — f32 dot over the rows as they lie
         rops = rops_ref[0, 0, 0, j * BS:(j + 1) * BS].astype(jnp.float32)
-        rop = rop_ref[0, 0, 0, j * BS:(j + 1) * BS, :].astype(jnp.float32) * rops[:, None]
-        s = s + jax.lax.dot_general(
-            qr, rop, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
+        s = s + _rope_scores(
+            qr, lambda r0, m: rop_ref[0, 0, 0, r0:r0 + m, :], j * BS, BS, rop_ref.shape[3], H
+        ) * scale * rops[None, :]
         pos = j * BS + jax.lax.broadcasted_iota(jnp.int32, (1, BS), 1)
         # skipped blocks (j >= nblk) hold STALE buffer bytes — every mask
         # must also gate on the block being live, or a parked row (w >= S,
@@ -2261,13 +2398,13 @@ def _attend_q8_mla_paged_kernel(
     lengths_ref,  # [Ba] int32 (scalar prefetch) — this step's position per row
     tbl_ref,  # [Ba * nbs] int32 (scalar prefetch) — flattened block tables
     qt_ref,  # [1, H, R] VMEM — absorbed queries (latent space)
-    qr_ref,  # [1, H, dr] VMEM — rope queries
+    qr_ref,  # [1, P*H, P*dr] VMEM — rope queries (`rope_queries`)
     nc_ref,  # [1, 1, R] VMEM — this step's exact latent
-    nr_ref,  # [1, 1, dr] VMEM — this step's exact rope key
+    nr_ref,  # [1, 1, P*dr] VMEM — this step's exact rope key, lane group 0
     lat_hbm,  # [L, B, 1, S, R] int8 — latent arena (identity homes), HBM
     pool_lat_hbm,  # [L, PXB, 1, bt, R] int8 — latent prefix pool, HBM
     lats_ref,  # [1, S] VMEM — latent scales, PRE-GATHERED through the table
-    rop_ref,  # [1, S, dr] VMEM — rope payload, PRE-GATHERED
+    rop_ref,  # [1, S/P, P*dr] VMEM — rope payload, PRE-GATHERED, laid abreast again
     rops_ref,  # [1, S] VMEM — rope scales, PRE-GATHERED
     o_ref,  # [1, H, R] VMEM out — context in latent space
     lat_buf,  # VMEM scratch [2, BS, R] int8 (double buffer)
@@ -2322,17 +2459,17 @@ def _attend_q8_mla_paged_kernel(
     issue(0, 0, "start")
 
     qt = qt_ref[0].astype(jnp.float32)  # [H, R]
-    qr = qr_ref[0].astype(jnp.float32)  # [H, dr]
+    qr = qr_ref[0].astype(jnp.float32)  # [P*H, P*dr]
+    H, R = qt.shape
     nc = nc_ref[0, 0].astype(jnp.float32)  # [R]
-    nr = nr_ref[0, 0].astype(jnp.float32)  # [dr]
+    nr = nr_ref[0, 0].astype(jnp.float32)  # [P*dr]
     qa = jnp.max(jnp.abs(qt), axis=-1)
     qsc = jnp.maximum(qa / 127.0, 1e-30)
     qt8 = jnp.round(qt / qsc[:, None]).astype(jnp.int8)
     s_new = (
-        jnp.sum(qt * nc[None, :], axis=-1) + jnp.sum(qr * nr[None, :], axis=-1)
+        jnp.sum(qt * nc[None, :], axis=-1) + jnp.sum(qr[:H] * nr[None, :], axis=-1)
     )[:, None] * scale  # [H, 1]
 
-    H, R = qt.shape
     acc = jnp.zeros((H, R), jnp.float32)
     m = jnp.full((H, 1), NEG_INF, jnp.float32)
     l = jnp.zeros((H, 1), jnp.float32)
@@ -2349,10 +2486,9 @@ def _attend_q8_mla_paged_kernel(
         )  # [H, BS]
         s = s_i.astype(jnp.float32) * (scale * qsc)[:, None] * lats[None, :]
         rops = rops_ref[0, j * BS:(j + 1) * BS].astype(jnp.float32)
-        rop = rop_ref[0, j * BS:(j + 1) * BS, :].astype(jnp.float32) * rops[:, None]
-        s = s + jax.lax.dot_general(
-            qr, rop, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale
+        s = s + _rope_scores(
+            qr, lambda r0, m: rop_ref[0, r0:r0 + m, :], j * BS, BS, rop_ref.shape[1], H
+        ) * scale * rops[None, :]
         pos = j * BS + jax.lax.broadcasted_iota(jnp.int32, (1, BS), 1)
         # skipped blocks (j >= nblk) hold STALE buffer bytes — gate every
         # mask on liveness (same invariant as the blocked kernel)
@@ -2429,7 +2565,15 @@ def _decode_attend_q8_mla_fallback(
         return paged_gather(a, p, tbl)[:, 0]
 
     lat = sel(cache_c["q"], pool_c and pool_c["q"]).astype(jnp.float32)  # [Ba,S,R]
-    rop = sel(cache_r["q"], pool_r and pool_r["q"]).astype(jnp.float32)  # [Ba,S,dr]
+    # the rope keys' bytes, pulled apart: the positions in order [Ba, S, dr]
+    P = cache_c["q"].shape[3] // cache_r["q"].shape[3]
+    rop_l = jax.lax.dynamic_index_in_dim(cache_r["q"], layer, 0, keepdims=False)
+    if block_tables is None:
+        rop = rope_rows(rop_l, P, slot_ids)
+    else:
+        pool_l = jax.lax.dynamic_index_in_dim(pool_r["q"], layer, 0, keepdims=False)
+        rop = rope_rows(rop_l, P, tables=tbl, pool=pool_l)
+    rop = rop.astype(jnp.float32)
     ls = sel(cache_c["s"], pool_c and pool_c["s"]).astype(jnp.float32)  # [Ba, S]
     rs = sel(cache_r["s"], pool_r and pool_r["s"]).astype(jnp.float32)
     S = lat.shape[1]
@@ -2462,7 +2606,7 @@ def decode_attend_q8_mla(
     new_c: jnp.ndarray,  # [Ba, R] — this step's exact latent
     new_r: jnp.ndarray,  # [Ba, dr] — this step's exact rope key
     cache_c: dict,  # {"q": int8 [L,B,1,S,R], "s": [L,B,1,S]}
-    cache_r: dict,  # {"q": int8 [L,B,1,S,dr], "s": [L,B,1,S]}
+    cache_r: dict,  # {"q": int8 [L,B,1,S/P,P*dr] (`rope_abreast`), "s": [L,B,1,S]}
     layer: jnp.ndarray,  # scalar int32
     lengths: jnp.ndarray,  # [Ba] int32 — this step's position per row
     *,
@@ -2470,7 +2614,7 @@ def decode_attend_q8_mla(
     block_tables: jnp.ndarray | None = None,  # [n_slots, nbs] int32 physical
     #   block tables (executor/physical.py); None = contiguous layout
     pool_c: dict | None = None,  # latent prefix pool mirroring cache_c
-    pool_r: dict | None = None,  # rope prefix pool mirroring cache_r
+    pool_r: dict | None = None,  # rope prefix pool, its rows apart [L,PXB,1,bt,dr]
     scale: float,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
@@ -2490,6 +2634,9 @@ def decode_attend_q8_mla(
     Ba, H, R = qt.shape
     dr = qr.shape[-1]
     S = cache_c["q"].shape[3]
+    # P positions abreast in a row of the rope keys, read off the pair's shapes
+    Sr, W = cache_r["q"].shape[3:]
+    P = S // Sr
     interp = _interpret() if interpret is None else interpret
     fits = mla_whole_s_fits(S, R, dr, H)
     BS = mla_block_size(S)
@@ -2508,14 +2655,18 @@ def decode_attend_q8_mla(
         if slot_ids is None
         else slot_ids.astype(jnp.int32)
     )
+    # the rope queries a lane group a row group, and this step's exact rope key
+    # in lane group 0 (the first H query rows' own): `_rope_scores`
+    qr_ab = rope_queries(qr, P)  # [Ba, P*H, W]
+    nr_ab = jnp.pad(new_r, ((0, 0), (0, W - dr))).reshape(Ba, 1, W)
     args = (
         jnp.reshape(layer, (1,)).astype(jnp.int32),
         ids,
         lengths.astype(jnp.int32),
         qt,
-        qr,
+        qr_ab,
         new_c.reshape(Ba, 1, R),
-        new_r.reshape(Ba, 1, dr),
+        nr_ab,
         cache_c["q"],
         cache_c["s"],
         cache_r["q"],
@@ -2530,9 +2681,9 @@ def decode_attend_q8_mla(
             grid=(Ba,),
             in_specs=[
                 pl.BlockSpec((1, H, R), lambda b, li, ids, lens: (b, 0, 0)),
-                pl.BlockSpec((1, H, dr), lambda b, li, ids, lens: (b, 0, 0)),
+                pl.BlockSpec((1, P * H, W), lambda b, li, ids, lens: (b, 0, 0)),
                 pl.BlockSpec((1, 1, R), lambda b, li, ids, lens: (b, 0, 0)),
-                pl.BlockSpec((1, 1, dr), lambda b, li, ids, lens: (b, 0, 0)),
+                pl.BlockSpec((1, 1, W), lambda b, li, ids, lens: (b, 0, 0)),
                 pl.BlockSpec(
                     (1, 1, 1, S, R), lambda b, li, ids, lens: (li[0], ids[b], 0, 0, 0)
                 ),
@@ -2540,7 +2691,7 @@ def decode_attend_q8_mla(
                     (1, 1, 1, S), lambda b, li, ids, lens: (li[0], ids[b], 0, 0)
                 ),
                 pl.BlockSpec(
-                    (1, 1, 1, S, dr), lambda b, li, ids, lens: (li[0], ids[b], 0, 0, 0)
+                    (1, 1, 1, Sr, W), lambda b, li, ids, lens: (li[0], ids[b], 0, 0, 0)
                 ),
                 pl.BlockSpec(
                     (1, 1, 1, S), lambda b, li, ids, lens: (li[0], ids[b], 0, 0)
@@ -2562,17 +2713,17 @@ def decode_attend_q8_mla(
             grid=(Ba,),
             in_specs=[
                 pl.BlockSpec((1, H, R), lambda b, li, ids, lens: (b, 0, 0)),
-                pl.BlockSpec((1, H, dr), lambda b, li, ids, lens: (b, 0, 0)),
+                pl.BlockSpec((1, P * H, W), lambda b, li, ids, lens: (b, 0, 0)),
                 pl.BlockSpec((1, 1, R), lambda b, li, ids, lens: (b, 0, 0)),
-                pl.BlockSpec((1, 1, dr), lambda b, li, ids, lens: (b, 0, 0)),
+                pl.BlockSpec((1, 1, W), lambda b, li, ids, lens: (b, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),  # latent payload (DMA'd)
-                # scales + the (small, lane-padded) rope row ride the
-                # layout-aware BlockSpec pipeline — see kernel docstring
+                # scales + the (small) rope row ride the BlockSpec
+                # pipeline — see kernel docstring
                 pl.BlockSpec(
                     (1, 1, 1, S), lambda b, li, ids, lens: (li[0], ids[b], 0, 0)
                 ),
                 pl.BlockSpec(
-                    (1, 1, 1, S, dr), lambda b, li, ids, lens: (li[0], ids[b], 0, 0, 0)
+                    (1, 1, 1, Sr, W), lambda b, li, ids, lens: (li[0], ids[b], 0, 0, 0)
                 ),
                 pl.BlockSpec(
                     (1, 1, 1, S), lambda b, li, ids, lens: (li[0], ids[b], 0, 0)
@@ -2601,7 +2752,8 @@ def decode_attend_q8_mla(
         lats_g = paged_gather(lat_a, lat_p, tblc)[:, 0]  # [Ba, S]
         rop_a = jax.lax.dynamic_index_in_dim(cache_r["q"], layer, 0, keepdims=False)
         rop_p = jax.lax.dynamic_index_in_dim(pool_r["q"], layer, 0, keepdims=False)
-        rop_g = paged_gather(rop_a, rop_p, tblc)[:, 0]  # [Ba, S, dr]
+        # gathered apart (a pool's rows are), laid abreast again for the kernel
+        rop_g = rope_abreast(rope_rows(rop_a, P, tables=tblc, pool=rop_p), P)  # [Ba, S/P, W]
         rops_a = jax.lax.dynamic_index_in_dim(cache_r["s"], layer, 0, keepdims=False)
         rops_p = jax.lax.dynamic_index_in_dim(pool_r["s"], layer, 0, keepdims=False)
         rops_g = paged_gather(rops_a, rops_p, tblc)[:, 0]  # [Ba, S]
@@ -2613,13 +2765,13 @@ def decode_attend_q8_mla(
             grid=(Ba,),
             in_specs=[
                 pl.BlockSpec((1, H, R), lambda b, li, lens, tbl: (b, 0, 0)),
-                pl.BlockSpec((1, H, dr), lambda b, li, lens, tbl: (b, 0, 0)),
+                pl.BlockSpec((1, P * H, W), lambda b, li, lens, tbl: (b, 0, 0)),
                 pl.BlockSpec((1, 1, R), lambda b, li, lens, tbl: (b, 0, 0)),
-                pl.BlockSpec((1, 1, dr), lambda b, li, lens, tbl: (b, 0, 0)),
+                pl.BlockSpec((1, 1, W), lambda b, li, lens, tbl: (b, 0, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),  # latent arena (DMA'd)
                 pl.BlockSpec(memory_space=pl.ANY),  # latent pool (DMA'd)
                 pl.BlockSpec((1, S), lambda b, li, lens, tbl: (b, 0)),
-                pl.BlockSpec((1, S, dr), lambda b, li, lens, tbl: (b, 0, 0)),
+                pl.BlockSpec((1, Sr, W), lambda b, li, lens, tbl: (b, 0, 0)),
                 pl.BlockSpec((1, S), lambda b, li, lens, tbl: (b, 0)),
             ],
             out_specs=pl.BlockSpec((1, H, R), lambda b, li, lens, tbl: (b, 0, 0)),
@@ -2636,9 +2788,9 @@ def decode_attend_q8_mla(
             lengths.astype(jnp.int32),
             tblc.reshape(-1),
             qt,
-            qr,
+            qr_ab,
             new_c.reshape(Ba, 1, R),
-            new_r.reshape(Ba, 1, dr),
+            nr_ab,
             cache_c["q"],
             pool_c["q"],
             lats_g,
@@ -3938,17 +4090,24 @@ def ragged_prefill_attend_mla(
     lat_all = cache_c["q"] if quantized else cache_c
     rop_all = cache_r["q"] if quantized else cache_r
     L, B, _, S, Rl = lat_all.shape
-    dr = rop_all.shape[-1]
+    dr = qr.shape[-1]
+    P = S // rop_all.shape[3]  # positions abreast in a row of the rope keys
     T = qt.shape[0]
     R = slots.shape[0]
     starts = jnp.asarray(starts, jnp.int32)
     slots_i = jnp.asarray(slots, jnp.int32)
     use_kernel = _ragged_kernel_asked(impl)
 
-    def rows_of(cache_full, pool_full, bound):
+    def rows_of(cache_full, pool_full, bound, abreast=1):
         """Layer-select + per-row gather of a cache plane, bounded to the
-        first `bound` positions (block-rounded under paging)."""
+        first `bound` positions (block-rounded under paging). The rope keys
+        that lie `abreast` are pulled apart once gathered ([R, S, dr], a quarter
+        of a MB a layer: the cache itself is read as it lies)."""
         plane = jax.lax.dynamic_index_in_dim(cache_full, layer, 0, keepdims=False)
+        if abreast > 1:
+            tbl = None if block_tables is None else jnp.take(block_tables, slots_i, axis=0)
+            return rope_rows(plane, abreast, slots_i, tbl, None if tbl is None else jax.lax.dynamic_index_in_dim(
+                pool_full, layer, 0, keepdims=False))[:, :bound]
         if block_tables is not None:
             nbs_full = block_tables.shape[1]
             bt = S // nbs_full
@@ -3966,7 +4125,7 @@ def ragged_prefill_attend_mla(
         Sk = min(skey, S) if skey else S
         if quantized:
             lat = rows_of(cache_c["q"], pool_c and pool_c["q"], Sk)
-            rop = rows_of(cache_r["q"], pool_r and pool_r["q"], Sk)
+            rop = rows_of(cache_r["q"], pool_r and pool_r["q"], Sk, P)
             ls = rows_of(cache_c["s"], pool_c and pool_c["s"], Sk).astype(jnp.float32)
             rs = rows_of(cache_r["s"], pool_r and pool_r["s"], Sk).astype(jnp.float32)
         else:
@@ -3983,7 +4142,7 @@ def ragged_prefill_attend_mla(
     tbl, nbs, paged_ = _ragged_tables(slots, S, BS, block_tables)
     # rope rows + dequant scales pre-gathered whole-S (per-block rope/scale
     # slices are the narrow DMAs Mosaic rejects); latent payload streams
-    rop_g = rows_of(rop_all, pool_r["q"] if (paged_ and quantized) else pool_r, S)
+    rop_g = rows_of(rop_all, pool_r["q"] if (paged_ and quantized) else pool_r, S, P)
     if quantized:
         ls_g = rows_of(cache_c["s"], pool_c and pool_c["s"], S).astype(jnp.float32)
         rs_g = rows_of(cache_r["s"], pool_r and pool_r["s"], S).astype(jnp.float32)

@@ -48,6 +48,7 @@ from ..kernels.attention import (
     q_abreast,
     ragged_prefill_attend_bf16,
     ragged_prefill_attend_q8,
+    rope_put,
 )
 from ..ops.norms import rms_norm as _rms_norm
 from ..ops.rope import rope_tables, apply_rope
@@ -1299,6 +1300,15 @@ def ragged_write_rows(
     L, B, Hx, S = cache.shape[:4]
     T = new.shape[2]
     R = slots.shape[0]
+    # the latent pair's int8 rope keys lie P positions abreast in a row of
+    # whole lanes (`rope_abreast`) and the packed rows apart: `rope_put`
+    # selects a window's rows into the slot's row as the loop below does, ONE
+    # update a descriptor row for all layers (its update is a select's output
+    # in the cache's own layout, so the layer axis costs nothing there, and a
+    # row's mask is made once and not L times: 160 of them in a program of
+    # four rows cost every packed shape 3-5 s more to lower, PR 58)
+    P = cache.shape[4] // new.shape[3] if cache.ndim == 5 else 1
+    S *= P
     W = min(S, T)  # a row holds at most T tokens
     ntail = cache.ndim - 4
     win = jnp.arange(W, dtype=jnp.int32)
@@ -1314,6 +1324,10 @@ def ragged_write_rows(
         pos = a + win  # [W] cache positions the window covers
         t0 = jnp.mod(offsets[r] + a - starts[r], T)
         hit = (pos >= starts[r]) & (pos < starts[r] + n)
+        if P > 1:
+            rows = jax.lax.dynamic_slice(twice, (0, 0, t0, 0), (L, Hx, W) + new.shape[3:])
+            cache = rope_put(cache, rows[:, None], (0, slots[r]), a, keep=hit)
+            continue
         keep = hit.reshape((1, 1, 1, W) + (1,) * ntail)
         for l in range(L):
             rows = jax.lax.dynamic_slice(

@@ -24,6 +24,16 @@ TPU-first choices:
     entire slot machinery (bucketed inserts, chunk writes, compaction
     scatter, donation, recovery) works unchanged. `llama_prefill` /
     `llama_decode_step` dispatch here when cfg.kv_lora_rank > 0.
+  - **The int8 rope keys lie in rows of whole lanes**: P = 128 //
+    qk_rope_head_dim positions abreast, s8[L, B, 1, S / P, P * dr], position
+    s in row s mod S/P, lanes [(s div S/P) dr, +dr)
+    (`kernels/attention.py:positions_abreast`, `rope_abreast` / `rope_apart`;
+    P = 2 at the published 64). A minor dimension of 64 the chip lays out
+    with positions minor, and every step program re-laid the whole member
+    for its Mosaic call and again for its append. Every reader and writer
+    here goes through that module's functions (`rope_put`, `rope_append`,
+    `rope_rows`, `rope_queries`); what is CUT OUT of the cache (a prompt's
+    rows, a prefix entry, a pool's block, a host copy) lies apart, [.., n, dr].
 
 Reference parity note: the reference serves deepseek-architecture models
 only through Ollama (`discovery.go:510` infers metadata from the name);
@@ -57,7 +67,15 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from ..kernels.attention import paged_gather, ragged_prefill_attend_mla
+from ..kernels.attention import (
+    paged_gather,
+    positions_abreast,
+    ragged_prefill_attend_mla,
+    rope_append,
+    rope_put,
+    rope_queries,
+    rope_rows,
+)
 from ..ops.norms import rms_norm as _rms_norm
 from ..ops.rope import apply_rope, rope_tables
 from .configs import ModelConfig
@@ -186,9 +204,11 @@ def init_mla_cache(
 ) -> dict[str, Any]:
     """Latent cache in the engine's (k, v) pair convention:
     k := latents [L, B, 1, S, kv_lora_rank], v := rope keys
-    [L, B, 1, S, qk_rope_head_dim]. The fake one-head axis keeps every
-    slot-machinery code path (inserts, chunked writes, compaction)
-    byte-compatible with the llama cache layout.
+    [L, B, 1, S, qk_rope_head_dim], the int8 ones P positions abreast in rows
+    of whole lanes, [L, B, 1, S / P, P * dr] (`positions_abreast`: a function
+    of S and dr alone; the scales stay one a position, [L, B, 1, S]). The
+    fake one-head axis keeps every slot-machinery code path (inserts, chunked
+    writes, compaction) byte-compatible with the llama cache layout.
 
     `quantized=True` stores int8 payloads with per-token scales (the same
     post-dot scale-folding scheme as the GQA int8 cache): MLA's latent is
@@ -197,13 +217,14 @@ def init_mla_cache(
     form the second member is {"v": the rope keys, "moe": the expert counts}."""
     L, R, dr = cfg.n_layers, cfg.kv_lora_rank, cfg.qk_rope_head_dim
     if quantized:
+        P = positions_abreast(max_seq, dr)
         pair = {
             "k": {
                 "q": jnp.zeros((L, batch, 1, max_seq, R), dtype=jnp.int8),
                 "s": jnp.zeros((L, batch, 1, max_seq), dtype=dtype),
             },
             "v": {
-                "q": jnp.zeros((L, batch, 1, max_seq, dr), dtype=jnp.int8),
+                "q": jnp.zeros((L, batch, 1, max_seq // P, P * dr), dtype=jnp.int8),
                 "s": jnp.zeros((L, batch, 1, max_seq), dtype=dtype),
             },
         }
@@ -406,7 +427,7 @@ def mla_prefill_chunk_batch(
     cfg: ModelConfig,
     params: Params,
     cache_c: Any,  # [L, B, 1, S, R] latents (or int8 {"q","s"} pytree)
-    cache_r: Any,  # [L, B, 1, S, dr] rope keys
+    cache_r: Any,  # [L, B, 1, S, dr] rope keys (int8: [L, B, 1, S / P, P * dr])
     tokens: jnp.ndarray,  # [A, C] int32 — right-padded chunks, one per slot
     slots: jnp.ndarray,  # [A] int32 engine slots
     starts: jnp.ndarray,  # [A] int32 absolute position of each chunk's start
@@ -435,6 +456,7 @@ def mla_prefill_chunk_batch(
     pair_r, cache_r = cache_r, _rows(cache_r)
     quantized = isinstance(cache_c, dict)
     L, B, _, S, R = (cache_c["q"] if quantized else cache_c).shape
+    Pr = S // cache_r["q"].shape[3] if quantized else 1  # positions abreast
     A, C = tokens.shape
     banks, stack = _expert_stack(cfg, params["layers"])
     k_dense = _n_dense(params)
@@ -497,6 +519,31 @@ def mla_prefill_chunk_batch(
                 ]
             )  # [A, Sk, d]
 
+        def past_rope_scores(qr, cache, pool=None):
+            """[A, H, C, Sk] f32 of the rope queries against the past int8 rope
+            keys. They are multiplied AS THEY LIE, P positions abreast in rows
+            of whole lanes: the queries go to one lane group of an otherwise
+            zero operand (`rope_queries`), a product over whole rows is then
+            the group's own, and the groups side by side are the positions in
+            order. Pulled apart to rows of 64 at the scan's edge the compiler
+            re-lays the whole member (models/llama.py:_chunk_attention)."""
+            if ptbl is not None:  # block-indirect: gathered apart, as a pool lies
+                rop = rope_rows(
+                    jax.lax.dynamic_index_in_dim(cache, li, 0, keepdims=False), Pr, tables=ptbl,
+                    pool=jax.lax.dynamic_index_in_dim(pool, li, 0, keepdims=False), nbs=nbs_full,
+                )[:, :Sk]
+                return jnp.einsum("achd,asd->ahcs", qr, rop.astype(qr.dtype)).astype(jnp.float32)
+            n = min(Sk, S // Pr)  # rows that hold a past position
+            G = -(-Sk // n)  # and the lane groups that do
+            rows = jnp.stack([
+                jax.lax.dynamic_slice(
+                    cache, (li, slots[a], 0, 0, 0), (1, 1, 1, n, Pr * dr))[0, 0, 0]
+                for a in range(A)
+            ])  # [A, n, P*dr]
+            qg = rope_queries(qr, Pr).reshape(A, C, Pr, H, Pr * dr)[:, :, :G]
+            s = jnp.einsum("acphw,arw->ahcpr", qg, rows.astype(qr.dtype))
+            return s.astype(jnp.float32).reshape(A, H, C, G * n)[..., :Sk]
+
         def past_scales(cache_s, pool_s=None):
             if ptbl is not None:
                 return past_rows(cache_s, 0, pool_s).astype(jnp.float32)
@@ -514,7 +561,6 @@ def mla_prefill_chunk_batch(
             pv = None if paged is None else paged["v"]
             if quantized:
                 lat = past_rows(cc_all["q"], R, pk and pk["q"])
-                rop = past_rows(cr_all["q"], dr, pv and pv["q"])
                 ls = past_scales(cc_all["s"], pk and pk["s"])
                 rs = past_scales(cr_all["s"], pv and pv["s"])
                 # per-token dequant scales fold POST-DOT (decode path's trick)
@@ -523,9 +569,7 @@ def mla_prefill_chunk_batch(
                         jnp.float32
                     )
                     * ls[:, None, None, :]
-                    + jnp.einsum("achd,asd->ahcs", qr, rop.astype(qr.dtype)).astype(
-                        jnp.float32
-                    )
+                    + past_rope_scores(qr, cr_all["q"], pv and pv["q"])
                     * rs[:, None, None, :]
                 ) * scale
             else:
@@ -573,9 +617,8 @@ def mla_prefill_chunk_batch(
                     ),
                 }
                 cr_all = {
-                    "q": jax.lax.dynamic_update_slice(
-                        cr_all["q"], rq["q"][a][None, None, None],
-                        (li, slots[a], 0, starts[a], 0),
+                    "q": rope_put(
+                        cr_all["q"], rq["q"][a][None, None, None], (li, slots[a]), starts[a]
                     ),
                     "s": jax.lax.dynamic_update_slice(
                         cr_all["s"], rq["s"][a][None, None, None],
@@ -613,7 +656,7 @@ def mla_prefill_chunk_ragged(
     cfg: ModelConfig,
     params: Params,
     cache_c: Any,  # [L, B, 1, S, R] latents (or int8 {"q","s"} pytree)
-    cache_r: Any,  # [L, B, 1, S, dr] rope keys
+    cache_r: Any,  # [L, B, 1, S, dr] rope keys (int8: [L, B, 1, S / P, P * dr])
     tokens: jnp.ndarray,  # [T] int32 — PACKED chunks, rows back-to-back
     rowids: jnp.ndarray,  # [T] int32 — descriptor row per token, sorted
     #   ascending; pads carry rowid == Rn
@@ -740,7 +783,7 @@ def mla_decode_step(
     cfg: ModelConfig,
     params: Params,
     cache_c: jnp.ndarray,  # [L, B, 1, S, R] latents (engine "k")
-    cache_r: jnp.ndarray,  # [L, B, 1, S, dr] rope keys (engine "v")
+    cache_r: jnp.ndarray,  # [L, B, 1, S, dr] rope keys (engine "v"; int8: P abreast)
     tokens: jnp.ndarray,  # [Ba] int32
     lengths: jnp.ndarray,  # [Ba] int32 — write position per row
     slot_ids: jnp.ndarray | None = None,  # [Ba] compaction indirection
@@ -807,7 +850,7 @@ def mla_decode_step(
                 ),
             }
             cr_all = {
-                "q": cr_all["q"].at[li, b_idx, zero, w_idx].set(krq["q"][:, None]),
+                "q": rope_append(cr_all["q"], krq["q"], li, rows, lengths),
                 "s": cr_all["s"].at[li, b_idx, zero, w_idx].set(
                     krq["s"][:, None].astype(cr_all["s"].dtype)
                 ),
@@ -835,7 +878,12 @@ def mla_decode_step(
         pv = None if paged is None else paged["v"]
         if quantized:
             lat = sel(cc_all["q"], pk and pk["q"])  # [Ba, S, R] int8 payload
-            rop = sel(cr_all["q"], pv and pv["q"])  # [Ba, S, dr] int8
+            # the rope keys' bytes pulled apart, [Ba, S, dr] int8 (this path is
+            # the one no chip runs: the kernels multiply the rows as they lie)
+            Pr = S // cr_all["q"].shape[3]
+            rop_l = jax.lax.dynamic_index_in_dim(cr_all["q"], li, 0, keepdims=False)
+            rop = rope_rows(rop_l, Pr, slot_ids) if ptbl is None else rope_rows(
+                rop_l, Pr, tables=ptbl, pool=jax.lax.dynamic_index_in_dim(pv["q"], li, 0, keepdims=False))
             ls = sel(cc_all["s"], pk and pk["s"]).astype(jnp.float32)  # [Ba, S]
             rs = sel(cr_all["s"], pv and pv["s"]).astype(jnp.float32)
             # per-token dequant scales fold POST-DOT (the GQA int8 cache's
@@ -919,7 +967,7 @@ def mla_decode_step(
             ),
         }
         cache_r = {
-            "q": cache_r["q"].at[l_idx, bb, 0, ww].set(rq["q"]),
+            "q": rope_append(cache_r["q"], rq["q"], l_idx, bb, ww),
             "s": cache_r["s"].at[l_idx, bb, 0, ww].set(
                 rq["s"].astype(cache_r["s"].dtype)
             ),

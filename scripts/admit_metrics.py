@@ -25,8 +25,9 @@ round of the window over the slots (`occupancy_ring`, from the ring's `emit`
 events: `decode_occupancy` reads one dispatch in 32 of a phase, about two
 dozen a window), the warm-up plan's seconds by phase (`plan`) and what the
 int8 decode-attention arm streamed (`decode_attn`, since PR 55: the window's
-difference of `perf_stats()["decode_attn"]` with `block_tokens` and
-`heads_abreast` beside it).
+difference of `perf_stats()["decode_attn"]` with `block_tokens`,
+`heads_abreast` and, since PR 58, `positions_abreast` beside it: how many
+positions share a row of the latent pair's int8 rope keys, 1 for any other cache).
 """
 
 from __future__ import annotations
@@ -138,13 +139,15 @@ def extras(run: dict):
     if got is not None:
         out["rounds"] = got
     # what the int8 decode-attention arm streamed over the window (`AttnStream`):
-    # the block in force, the heads a row of the cache holds (PR 55; None from a
+    # the block in force, the heads a row of the cache holds (PR 55) and the
+    # positions a row of the latent pair's rope keys holds (PR 58; None from a
     # program without the key), positions live over positions fetched
     a0, a1 = start.get("decode_attn") or {}, end.get("decode_attn")
     if a1:
         d = {k: a1[k] - a0.get(k, 0) for k in ("steps", "tokens_streamed", "tokens_live")}
         out["decode_attn"] = {
-            "block_tokens": a1["block_tokens"], "heads_abreast": a1.get("heads_abreast"), **d,
+            "block_tokens": a1["block_tokens"], "heads_abreast": a1.get("heads_abreast"),
+            "positions_abreast": a1.get("positions_abreast"), **d,
             "live_over_streamed": round(d["tokens_live"] / d["tokens_streamed"], 4)
             if d["tokens_streamed"] else None}
     tr = run.get("trace") or {}

@@ -34,7 +34,10 @@ them, so each body is read back and printed without locations before the text
 is hashed. PR 55 read the fifteen programs of the four cells of heads of 128
 equal between a600a99 and its own tree (the tiny presets cannot show that: all
 but `tiny-lfm2` have heads no row of 128 lanes holds whole), and Granite's and
-LFM2's, heads of 64, all differ.
+LFM2's, heads of 64, all differ. PR 58 added `joyai-llm-flash-ep16`, the latent
+family's cell, with its four programs (decode, bucketed chunk, whole-prompt
+prefill, and `ragged`, the packed chunk on the kernel arm), and read the other
+six cells' 23 equal between 934ceba and its own tree.
 """
 
 from __future__ import annotations
@@ -48,7 +51,7 @@ from functools import partial
 
 PRESETS = ("tiny-solar", "tiny-olmo-hybrid", "tiny-granite-hybrid", "tiny-kexaone", "tiny-lfm2")
 CELLS = ("qwen3-8b", "solar-open2-250b-ep8", "olmo-hybrid-7b-d20", "granite-4.0-h-micro",
-         "k-exaone-236b-ep8", "lfm2-8b-a1b-d14")
+         "k-exaone-236b-ep8", "lfm2-8b-a1b-d14", "joyai-llm-flash-ep16")
 
 
 def without_locations(text: str) -> str:
@@ -117,6 +120,10 @@ def main() -> int:
         }
         if not llama.mixed_step_supported(cfg):  # a stack with rings takes admit programs alone
             del programs["mixed"]
+        if cfg.kv_lora_rank:  # the latent family packs its chunks (ragged prefill stays on)
+            programs["ragged"] = (lambda p, ck, cv, *a: llama.llama_prefill_chunk_ragged(
+                cfg, p, ck, cv, *a, impl="kernel"),
+                (i32(T), i32(T), i32(T), i32(R), i32(R), i32(R)))
         elif not cfg.gqa_layers:  # the dense family's mixed step is `llama.mixed_step_q8`
             programs["mixed"] = (lambda p, ck, cv, *a: llama.mixed_step_q8(cfg, p, ck, cv, *a),
                                  programs["mixed"][1])

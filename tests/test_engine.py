@@ -775,14 +775,17 @@ def test_decode_compact_matches_full_batch(kv_quant):
 
 
 @pytest.mark.parametrize("attn,model,abreast", [
-    ("pallas", "tiny-llm", 1), ("xla", "tiny-llm", 1), ("pallas", "tiny-qwen3", 2)])
+    ("pallas", "tiny-llm", 1), ("xla", "tiny-llm", 1), ("pallas", "tiny-qwen3", 2),
+    ("pallas", "tiny-mla", 8), ("pallas", "tiny-joyai", 8)])
 def test_perf_stats_counts_what_the_blocked_attention_arm_streams(monkeypatch, attn, model, abreast):
     """`perf_stats()["decode_attn"]` exists where decode rounds read the int8
     cache through the Pallas arm, counts every step of every round dispatched
     from the positions the host packed, and is absent where XLA attends;
     `heads_abreast` says how many KV heads a row of the cache holds (two of
     `tiny-qwen3`'s 64 wide, one of `tiny-llm`'s 32 wide: two KV heads cannot
-    fill 128 lanes four abreast)."""
+    fill 128 lanes four abreast), `positions_abreast` how many POSITIONS a row
+    of a latent pair's int8 rope keys holds (eight of the tiny twins' 16 lanes;
+    1 for every other cache)."""
     from llm_mcp_tpu.kernels.attention import q8_block_tokens
 
     monkeypatch.setenv("LLM_MCP_TPU_ATTN", attn)
@@ -796,6 +799,15 @@ def test_perf_stats_counts_what_the_blocked_attention_arm_streams(monkeypatch, a
         if attn == "xla":
             assert got is None
             return
+        if eng._layout.latent:  # the whole-S arm: every row's 128 positions a step
+            rope = eng._layout.kv_rows(eng._ck, eng._cv)["v"]
+            assert got["positions_abreast"] == abreast and got["heads_abreast"] == 1
+            assert rope["q"].shape[3:] == (128 // abreast, abreast * eng.cfg.qk_rope_head_dim) == (16, 128)
+            assert rope["s"].shape[3] == eng._ck["q"].shape[3] == 128  # a scale a position, the latents untouched
+            assert got["block_tokens"] == 0 and got["tokens_streamed"] == got["steps"] * 128 * 4
+            assert out["usage"]["completion_tokens"] == 9 and 0 < got["tokens_live"] < got["tokens_streamed"]
+            return
+        assert got["positions_abreast"] == 1
         heads, seq, hd = eng._ck["q"].shape[2:]
         assert got["block_tokens"] == q8_block_tokens(heads, seq, hd) == 128
         assert got["heads_abreast"] == abreast and hd == abreast * eng.cfg.resolved_head_dim

@@ -302,6 +302,24 @@ def test_bf16_gqa_blocked_parked_rows(monkeypatch):
 # -- MLA int8 latents --------------------------------------------------------
 
 
+# The rope keys' width and how the int8 cache holds them: (dr, P). P positions
+# lie abreast in a row of whole lanes (`A.positions_abreast`: 2 at the
+# published 64, 8 at the tiny twins' 16); at 32 the rows are laid APART, a
+# position a row, the form every cache had and a shape the rule leaves alone
+# where P does not divide the positions.
+MLA_FORMS = [(32, 1), (64, 2), (16, 8)]
+MLA_FORM_IDS = ["dr32_apart", "dr64_two_abreast", "dr16_eight_abreast"]
+
+
+def _mla_pair(rng, L, B, S, R, dr, H, P):
+    """`_mla_args` with the rope keys' int8 payload P positions abreast, and
+    the SAME bytes laid apart for the reference."""
+    cc, cr, qt, qr, nc, nr = _mla_args(rng, L, B, S, R, dr, H)
+    if P > 1:
+        assert A.positions_abreast(S, dr) == P
+    return cc, {"q": A.rope_abreast(cr["q"], P), "s": cr["s"]}, cr, qt, qr, nc, nr
+
+
 def _mla_args(rng, L, B, S, R, dr, H):
     cc = {
         "q": jnp.asarray(rng.integers(-127, 128, (L, B, 1, S, R), dtype="int8")),
@@ -318,31 +336,46 @@ def _mla_args(rng, L, B, S, R, dr, H):
     return cc, cr, qt, qr, nc, nr
 
 
+def _same_as_apart(out, apart):
+    """The kernel over rows laid abreast against ITSELF over the same bytes laid
+    apart: the int8 products are the same numbers and the rope product only
+    adds zeros, so the contexts agree to the last bits of a float32 sum."""
+    np.testing.assert_allclose(np.asarray(out), np.asarray(apart), atol=2e-6, rtol=0)
+
+
+@pytest.mark.parametrize("dr,P", MLA_FORMS, ids=MLA_FORM_IDS)
 @pytest.mark.parametrize("fill", FILLS)
-def test_mla_whole_s_parity(fill):
+def test_mla_whole_s_parity(fill, dr, P):
     rng = np.random.default_rng(11)
-    L, B, S, R, dr, H = 2, 3, 128, 64, 32, 4
-    cc, cr, qt, qr, nc, nr = _mla_args(rng, L, B, S, R, dr, H)
+    L, B, S, R, H = 2, 3, 128, 64, 4
+    cc, cr, apart, qt, qr, nc, nr = _mla_pair(rng, L, B, S, R, dr, H, P)
     lens = _lens_for(fill, B, S, rng)
     sc = (R + dr) ** -0.5
     out = A.decode_attend_q8_mla(
         qt, qr, nc, nr, cc, cr, jnp.int32(1), lens, scale=sc, interpret=True
     )
     ref = A._decode_attend_q8_mla_fallback(
-        qt, qr, nc, nr, cc, cr, jnp.int32(1), lens, sc, None
+        qt, qr, nc, nr, cc, apart, jnp.int32(1), lens, sc, None
     )
     assert float(jnp.max(jnp.abs(out - ref))) < 0.05
+    # the fallback pulls the rows apart itself: bit for bit what it reads apart
+    np.testing.assert_array_equal(np.asarray(ref), np.asarray(A._decode_attend_q8_mla_fallback(
+        qt, qr, nc, nr, cc, cr, jnp.int32(1), lens, sc, None)))
+    _same_as_apart(out, A.decode_attend_q8_mla(
+        qt, qr, nc, nr, cc, apart, jnp.int32(1), lens, scale=sc, interpret=True))
 
 
+@pytest.mark.parametrize("dr,P", MLA_FORMS, ids=MLA_FORM_IDS)
 @pytest.mark.parametrize("fill", FILLS)
-def test_mla_blocked_parity(monkeypatch, fill):
+def test_mla_blocked_parity(monkeypatch, fill, dr, P):
     """The blocked MLA kernel (whole-S arm disabled via the VMEM-fit
     probe): S=1024 runs 2 blocks of 512 — the same static-unroll dispatch
-    the S=32k sweep uses at the 64-block cap."""
+    the S=32k sweep uses at the 64-block cap. Two positions abreast a block is
+    one lane group of every row; eight abreast it is four groups of 128 rows."""
     monkeypatch.setattr(A, "mla_whole_s_fits", lambda *a, **k: False)
     rng = np.random.default_rng(12)
-    L, B, S, R, dr, H = 1, 3, 1024, 64, 32, 4
-    cc, cr, qt, qr, nc, nr = _mla_args(rng, L, B, S, R, dr, H)
+    L, B, S, R, H = 1, 3, 1024, 64, 4
+    cc, cr, apart, qt, qr, nc, nr = _mla_pair(rng, L, B, S, R, dr, H, P)
     lens = _lens_for(fill, B, S, rng)
     ids = jnp.asarray(rng.permutation(B), jnp.int32)
     sc = (R + dr) ** -0.5
@@ -351,9 +384,11 @@ def test_mla_blocked_parity(monkeypatch, fill):
         slot_ids=ids, scale=sc, interpret=True,
     )
     ref = A._decode_attend_q8_mla_fallback(
-        qt, qr, nc, nr, cc, cr, jnp.int32(0), lens, sc, ids
+        qt, qr, nc, nr, cc, apart, jnp.int32(0), lens, sc, ids
     )
     assert float(jnp.max(jnp.abs(out - ref))) < 0.05
+    _same_as_apart(out, A.decode_attend_q8_mla(
+        qt, qr, nc, nr, cc, apart, jnp.int32(0), lens, slot_ids=ids, scale=sc, interpret=True))
 
 
 def test_mla_block_cap_boundary(monkeypatch):
@@ -497,20 +532,23 @@ def test_bf16_gqa_paged_parity(monkeypatch, fill):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
+@pytest.mark.parametrize("dr,P", MLA_FORMS, ids=MLA_FORM_IDS)
 @pytest.mark.parametrize(
     "fill", [pytest.param(0.0, marks=pytest.mark.slow), 0.4,
              pytest.param(0.9, marks=pytest.mark.slow)])
-def test_mla_paged_parity(monkeypatch, fill):
+def test_mla_paged_parity(monkeypatch, fill, dr, P):
     """Block-indirect MLA latent kernel vs the contiguous fallback: one
-    table drives BOTH the latent and rope pools."""
+    table drives BOTH the latent and rope pools. The rope keys' ARENA lies P
+    positions abreast; its pool, as every row cut out of the cache, apart."""
     monkeypatch.setenv("LLM_MCP_TPU_Q8_DECODE", "paged")
     rng = np.random.default_rng(23)
-    L, B, S, R, dr, H, bt = 2, 3, 256, 64, 32, 4, 64
+    L, B, S, R, H, bt = 2, 3, 256, 64, 4, 64
     nbs = S // bt
     nshared = min(nbs, round(fill * nbs))
     cc, cr, qt, qr, nc, nr = _mla_args(rng, L, B, S, R, dr, H)
     ref_c, arena_c, pool_c = _paged_split(cc, bt, nshared, nbs, rng)
     ref_r, arena_r, pool_r = _paged_split(cr, bt, nshared, nbs, rng)
+    arena_r = {"q": A.rope_abreast(arena_r["q"], P), "s": arena_r["s"]}
     tbl = _paged_tables(B, nbs, nshared)
     lens = _lens_for(fill, B, S, rng)
     ids = jnp.asarray(rng.permutation(B), jnp.int32)
@@ -618,6 +656,92 @@ def test_kv_heads_abreast_round_trip(Hkv, hd, P):
     got = jnp.einsum("brgw,rsw->brgs", qw, rows[0, 0].astype(jnp.float32))  # [5, R, P*G, 8]
     got = got.reshape(5, R, P, G, 8).transpose(0, 2, 1, 3, 4).reshape(5, Hkv, G, 8)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("S,dr,P", [(64, 64, 2), (64, 16, 8), (48, 32, 4), (64, 128, 1), (64, 96, 1), (63, 64, 1)])
+def test_positions_abreast_round_trip(S, dr, P):
+    """`positions_abreast`'s rule and the two directions of the latent pair's
+    form: position s of the rope keys lies in row s mod S/P, lanes [(s div S/P)
+    dr, +dr), `rope_apart` undoes `rope_abreast`, and a product of
+    `rope_queries`' rows over whole rows is each position's own score, the lane
+    groups' scores side by side the positions in order."""
+    assert A.positions_abreast(S, dr) == P
+    rng = np.random.default_rng(58)
+    x = jnp.asarray(rng.integers(-127, 128, (2, 3, 1, S, dr)), jnp.int8)
+    rows = A.rope_abreast(x, P)
+    half = S // P
+    assert rows.shape == (2, 3, 1, half, P * dr)
+    for s_ in range(S):
+        g, r = divmod(s_, half)
+        np.testing.assert_array_equal(
+            np.asarray(rows[:, :, 0, r, g * dr:(g + 1) * dr]), np.asarray(x[:, :, 0, s_]))
+    np.testing.assert_array_equal(np.asarray(A.rope_apart(rows, P)), np.asarray(x))
+    H = 3
+    q = jnp.asarray(rng.integers(-9, 10, (5, H, dr)), jnp.float32)  # whole numbers: exact sums
+    qw = A.rope_queries(q, P)
+    assert qw.shape == (5, P * H, P * dr) and int(jnp.sum(qw != 0)) == P * int(jnp.sum(q != 0))
+    want = jnp.einsum("bhd,sd->bhs", q, x[0, 0, 0].astype(jnp.float32))
+    got = jnp.einsum("bgw,rw->bgr", qw, rows[0, 0, 0].astype(jnp.float32))  # [5, P*H, half]
+    got = got.reshape(5, P, H, half).transpose(0, 2, 1, 3).reshape(5, H, S)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("dr,P", [(64, 2), (16, 8), (32, 1)], ids=["two_abreast", "eight_abreast", "apart"])
+@pytest.mark.parametrize("w", [7, 8, 31, 32, 33, 63])
+def test_rope_append_leaves_the_neighbours_bytes(dr, P, w):
+    """A decode step's append at an odd and at an even position, inside and at
+    the ends of a lane group (S / P = 32 rows at two abreast): the one position
+    changes and every other byte of the cache stays, and a parked row (w >= S)
+    writes nothing. Batched over the layers (the Pallas path's one scatter) and
+    a layer at a time (the XLA path's)."""
+    rng = np.random.default_rng(w)
+    L, B, S = 2, 3, 64
+    P = P if dr != 32 else 1
+    apart = jnp.asarray(rng.integers(-127, 128, (L, B, 1, S, dr)), jnp.int8)
+    cache = A.rope_abreast(apart, P)
+    new = jnp.asarray(rng.integers(-127, 128, (L, 2, dr)), jnp.int8)
+    rows, ws = jnp.asarray([2, 0], jnp.int32), jnp.asarray([w, S], jnp.int32)  # row 0 parked
+    want = np.array(apart)
+    want[:, 2, 0, w] = np.asarray(new[:, 0])
+    got = A.rope_append(cache, new, jnp.arange(L)[:, None], rows[None, :], ws[None, :])
+    np.testing.assert_array_equal(np.asarray(A.rope_apart(got, P)), want)
+    one = cache
+    for l in range(L):
+        one = A.rope_append(one, new[l], jnp.int32(l), rows, ws)
+    np.testing.assert_array_equal(np.asarray(one), np.asarray(got))
+
+
+@pytest.mark.parametrize("dr,P", [(64, 2), (16, 8), (32, 1)], ids=["two_abreast", "eight_abreast", "apart"])
+@pytest.mark.parametrize("start,n", [(0, 16), (0, 24), (0, 40), (0, 64), (5, 16), (27, 10), (60, 16)])
+def test_rope_put_leaves_the_neighbours_bytes(dr, P, start, n):
+    """A prompt's rows into a cache row: a bucket that ends inside a row's lane
+    group (16 and 24 of 32), one that runs over into the next group (40), the
+    whole row, a chunk that starts inside a group and one that crosses from one
+    group into the next (27..36), and a start that dynamic_update_slice clamps
+    back (60 + 16 > 64): the positions written are the rows, every other byte of
+    the cache as it was, for all layers at once (admit, a prefix entry, a pool's
+    block) and a layer at a time (the bucketed chunk)."""
+    rng = np.random.default_rng(n + start)
+    L, B, S = 2, 3, 64
+    P = P if dr != 32 else 1
+    apart = jnp.asarray(rng.integers(-127, 128, (L, B, 1, S, dr)), jnp.int8)
+    cache = A.rope_abreast(apart, P)
+    rows = jnp.asarray(rng.integers(-127, 128, (L, 1, 1, n, dr)), jnp.int8)
+    want = jax.lax.dynamic_update_slice(apart, rows, (0, 1, 0, start, 0))
+    got = jax.jit(lambda c, r, b, s_: A.rope_put(c, r, (0, b), s_))(cache, rows, jnp.int32(1), jnp.int32(start))
+    assert got.shape == cache.shape and got.dtype == cache.dtype
+    np.testing.assert_array_equal(np.asarray(A.rope_apart(got, P)), np.asarray(want))
+    one = cache
+    for l in range(L):
+        one = A.rope_put(one, rows[l:l + 1], (jnp.int32(l), jnp.int32(1)), jnp.int32(start))
+    np.testing.assert_array_equal(np.asarray(one), np.asarray(got))
+    # `keep`: the packed chunk's window, of which only some rows land
+    keep = jnp.asarray(rng.integers(0, 2, (n,)), bool)
+    kept = A.rope_put(cache, rows, (0, 1), jnp.int32(start), keep=keep)
+    at = np.clip(start, 0, S - n)
+    want_k = np.array(apart)
+    want_k[:, 1, 0, at:at + n] = np.where(np.asarray(keep)[:, None], np.asarray(rows[:, 0, 0]), want_k[:, 1, 0, at:at + n])
+    np.testing.assert_array_equal(np.asarray(A.rope_apart(kept, P)), want_k)
 
 
 @pytest.mark.parametrize("Hkv,hd", [(4, 64), (8, 64), (2, 128), (4, 32)])
@@ -817,14 +941,16 @@ def test_ragged_prefill_q8_reads_heads_abreast(paged):
 
 
 @pytest.mark.parametrize(
-    "quant", [pytest.param(False, marks=pytest.mark.slow), True])
+    "quant", [pytest.param(False, marks=pytest.mark.slow), True, "eight_abreast"])
 @pytest.mark.parametrize(
     "paged", [pytest.param(False, marks=pytest.mark.slow), True])
 @pytest.mark.parametrize("fill", RAGGED_FILLS)
 def test_ragged_prefill_mla_parity(fill, paged, quant):
     """One ragged MLA body covers bf16 and int8 latents (ones-scales when
     bf16); rope and per-token scales ride pre-gathered VMEM operands while
-    the latent payload streams block-indirect."""
+    the latent payload streams block-indirect. `eight_abreast`: the int8 rope
+    keys as `init_mla_cache` lays them, eight positions of 16 lanes a row."""
+    abreast = quant == "eight_abreast"
     rng = np.random.default_rng(33)
     L, S, bt, B, Rl, dr, H = 2, 128, 32, 6, 32, 16, 4
     R, T, total, rowids, offsets, slots, starts, tbl, nbs, pxb = _ragged_case(
@@ -863,6 +989,12 @@ def test_ragged_prefill_mla_parity(fill, paged, quant):
     )
     args = (qt, qr, cs, krs, cc, cr, 1, rowids, offsets, slots, starts)
     ref = A.ragged_prefill_attend_mla(*args, impl="xla", **kw)
+    if abreast:  # the same bytes, eight positions a row: what both arms read of them is the same
+        cr = {"q": A.rope_abreast(cr["q"], A.positions_abreast(S, dr)), "s": cr["s"]}
+        assert cr["q"].shape == (L, B, 1, S // 8, 128)
+        args = (qt, qr, cs, krs, cc, cr, 1, rowids, offsets, slots, starts)
+        np.testing.assert_array_equal(
+            np.asarray(ref), np.asarray(A.ragged_prefill_attend_mla(*args, impl="xla", **kw)))
     out = A.ragged_prefill_attend_mla(
         *args, impl="kernel", interpret=True, **kw
     )

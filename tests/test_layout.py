@@ -53,11 +53,17 @@ def test_the_layout_is_what_its_table_says(preset, kv_quant, row):
         # the prefix pools mirror the pair: pool rows for slots, a block for S
         pools = layout.allocate_pools(3, 32)
         assert jax.tree.structure(pools) == jax.tree.structure(layout.pool_specs(), is_leaf=is_spec)
-        assert jax.tree.map(lambda p, c: p.shape == (c.shape[0], 3, c.shape[2], 32) + c.shape[4:]
-                            and p.dtype == c.dtype, pools, cache) == jax.tree.map(lambda _: True, cache)
-    # every full-length leaf is [layers, slots, heads, S, ...]
-    assert all(x.shape[1] == slots and x.shape[3] == seq
-               for x in jax.tree.leaves(layout.kv_rows(cache["k"], cache["v"])))
+        # (a leaf of P positions abreast is pooled apart, a P-th as wide)
+        assert jax.tree.map(
+            lambda p, c: p.shape == (c.shape[0], 3, c.shape[2], 32) + tuple(
+                n * c.shape[3] // seq for n in c.shape[4:]) and p.dtype == c.dtype,
+            pools, cache) == jax.tree.map(lambda _: True, cache)
+    # every full-length leaf is [layers, slots, heads, S, ...], but the latent pair's
+    # int8 rope keys: P = 128 // 16 positions abreast in rows of whole lanes
+    rows = layout.kv_rows(cache["k"], cache["v"])
+    abreast = rows["v"].pop("q") if layout.latent and layout.int8 else None
+    assert all(x.shape[1] == slots and x.shape[3] == seq for x in jax.tree.leaves(rows))
+    assert abreast is None or abreast.shape == (2, slots, 1, seq // 8, 128)
 
 
 @pytest.mark.parametrize("meshed", [False, True], ids=["no-mesh", "one-device-mesh"])

@@ -243,9 +243,14 @@ def test_mla_s8_kernel_matches_xla_path(setup):
     # appended rows agree after dequant (±1 LSB payload differences are
     # expected: the two attention impls round differently, so downstream
     # layers' latents differ at f32 epsilon before quantization)
+    from llm_mcp_tpu.kernels.attention import rope_apart
+
+    def dequant(x):  # the rope keys lie P positions abreast: pulled apart, a scale a row
+        q = rope_apart(x["q"], x["s"].shape[3] // x["q"].shape[3])
+        return np.asarray(q, np.float32) * np.asarray(x["s"])[..., None]
+
     for a, b in ((ckx, ckp), (cvx, cvp)):
-        da = np.asarray(a["q"], np.float32) * np.asarray(a["s"])[..., None]
-        db = np.asarray(b["q"], np.float32) * np.asarray(b["s"])[..., None]
+        da, db = dequant(a), dequant(b)
         denom = max(np.abs(da).max(), 1e-9)
         assert np.abs(da - db).max() / denom < 0.02
     # parked row (w >= S) writes nothing on either path

@@ -977,15 +977,15 @@ def joyai_program(which: str, cfg):
     models/llama.py); the admit program inserts rows of BOTH members of the
     latent pair, as `engine._insert_row` does for a counted pair; the packed
     chunk is the ragged program."""
+    from llm_mcp_tpu.executor.engine import _put_rows
     from llm_mcp_tpu.models import hybrid, llama
 
     def admit(params, ck, cv, tokens, lengths, slots):
         logits, ks, vs = llama.llama_prefill(
             cfg, params, tokens, lengths, attn_impl="pallas", quant_kv=True)
 
-        def put(c, rows, i, slot):
-            return jax.lax.dynamic_update_slice(
-                c, jax.lax.dynamic_slice_in_dim(rows, i, 1, 1), (0, slot) + (0,) * (c.ndim - 2))
+        def put(c, rows, i, slot):  # `engine._insert_kv`'s: a prompt's rope keys lie apart
+            return _put_rows(c, jax.lax.dynamic_slice_in_dim(rows, i, 1, 1), slot, 0)
 
         def body(i, cc):
             ck, cv = cc
@@ -1024,7 +1024,9 @@ def test_joyai_step_programs_fit_with_the_banks_whole_beside_the_latent_cache(
     and the 1.52 GB latent cache; the temporaries hold no copy of a layer's banks
     (151 MB a layer: the stack goes in whole) nor of the leading dense layer's
     feed-forward (a stack of ONE layer scanned once: sliced in place), and the
-    latent pair is updated in place. GiB in PERF.md section 4 as "described-chip
+    latent pair is updated in place, neither member copied or re-laid (the int8
+    rope keys lie two positions abreast in rows of whole lanes: until PR 58 their
+    rows of 64 lanes were re-laid four times a decode round). GiB in PERF.md section 4 as "described-chip
     compile"."""
     cfg, params, cache = joyai
     falls = dict(A.reference_falls)
@@ -1036,9 +1038,12 @@ def test_joyai_step_programs_fit_with_the_banks_whole_beside_the_latent_cache(
     assert ("decode_attn_mla_q8_whole" in text) == (which == "decode")
     assert "decode_attn_mla_q8_blocked" not in text and "decode_attn_mla_q8_paged" not in text
     assert ("ragged_prefill_attn_mla" in text) == (which == "ragged")
-    assert cache["k"]["q"].shape == (40, 64, 1, 1024, 512) and cache["v"]["v"]["q"].shape == (40, 64, 1, 1024, 64)
+    # the rope keys two positions abreast in rows of the 128 lanes (`positions_abreast`)
+    assert cache["k"]["q"].shape == (40, 64, 1, 1024, 512) and cache["v"]["v"]["q"].shape == (40, 64, 1, 512, 128)
     assert cache["v"]["moe"].shape == (2, 39, 5)
+    # no copy of either member of the latent pair, to another layout or otherwise
     assert cache_relayouts(text, cache["k"]["q"].shape) == []
+    assert cache_relayouts(text, cache["v"]["v"]["q"].shape) == []
     assert params["layers"]["w1e"].shape == (39, 16, 2048, 768) and params["dense_layers"]["w1"].shape == (1, 2048, 7168)
     nbytes = lambda tree: sum(  # noqa: E731
         int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(tree))
@@ -1056,10 +1061,9 @@ def test_joyai_step_programs_fit_with_the_banks_whole_beside_the_latent_cache(
     assert total < 14.5 * 2**30
     # No copy of a layer's banks (0.14 GiB a layer and step would be 5.5 GiB a
     # round) nor of `w_uq` (0.69 GiB until its columns were `[H dn | H dr]`). What
-    # the decode round's 1.06 GiB still holds, every ROUND: `w_ukv` transposed whole
-    # for the absorbed products (0.30), `w_dkv` (0.09), and the rope keys' int8
-    # cache, whose rows are 64 lanes, re-laid four times (0.16 each): ROADMAP B2.
-    assert mem.temp_size_in_bytes < (1.1 if which == "decode" else 1.0) * 2**30
+    # the decode round still holds, every ROUND: `w_ukv` transposed whole for the
+    # absorbed products (0.30) and `w_dkv` (0.09): ROADMAP B2.
+    assert mem.temp_size_in_bytes < (0.5 if which == "decode" else 1.0) * 2**30
     if which == "decode":
         assert "bf16[39,1536,6144]" not in "".join(
             line for line in text.splitlines() if " copy(" in line)  # `w_uq` read in place
