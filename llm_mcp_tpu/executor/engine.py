@@ -63,6 +63,7 @@ from ..utils.faults import maybe_fail
 from ..utils.platform import on_tpu
 from ..models.configs import ModelConfig, resolve_config
 from ..models.kda import CHUNK as RECURRENCE_CHUNK
+from ..models.moe import share_form
 from ..models.weights import load_llama_checkpoint
 from ..models.llama import (
     init_llama_params,
@@ -70,6 +71,8 @@ from ..models.llama import (
     llama_prefill_chunk_batch,
     llama_prefill_chunk_ragged,
     llama_decode_step,
+    block_denoise,
+    block_pass,
     mixed_step_q8,
     mixed_step_supported,
 )
@@ -453,6 +456,9 @@ class _PrefillState:
     # prompt was mid-chunk (token-share of each group dispatch), copied
     # onto the activated _Slot's prefill_compute_s
     prefill_s: float = 0.0
+    # a block configuration's prompt past its last whole block (P mod L
+    # tokens): never prefilled, they start the slot's first block (_seat)
+    tail: list = field(default_factory=list)
 
 
 @dataclass
@@ -517,6 +523,30 @@ class GenerationEngine:
             raise NotImplementedError(
                 f"{self.cfg.name}: recurrent layers and an expert share run on "
                 "one chip; no mesh axis shards the state pool or the share yet")
+        # Generation by diffusion over blocks (`cfg.block_len`): a round is a
+        # BLOCK (`_build_decode`: block_round_fn in the decode round's place),
+        # the tokens a round gives a row, `decode_chunk`, ARE the block, and
+        # everything below follows from the configuration: no switch
+        self._block = int(self.cfg.block_len)
+        if self._block:
+            if decode_chunk != self._block:
+                raise ValueError(
+                    f"{self.cfg.name}: a round is a block of {self._block} tokens; "
+                    f"decode_chunk={decode_chunk} (TPU_DECODE_CHUNK) must be {self._block}")
+            if mesh is not None and mesh.size > 1:
+                raise NotImplementedError(
+                    f"{self.cfg.name}: the block round and the expert share run on one chip")
+            if (self.cfg.unmask_rule not in ("low_confidence_dynamic", "low_confidence_static")
+                    or self.cfg.denoise_steps < 1 or self._block % self.cfg.denoise_steps
+                    or not 0 <= self.cfg.mask_token_id < self.cfg.vocab_size):
+                raise ValueError(
+                    f"{self.cfg.name}: unmask_rule {self.cfg.unmask_rule!r} with "
+                    f"{self.cfg.denoise_steps} steps over blocks of {self._block}, "
+                    f"mask id {self.cfg.mask_token_id} of {self.cfg.vocab_size}")
+        elif share_form(self.cfg) and not (self.cfg.kv_lora_rank or self.cfg.recurrent):
+            raise NotImplementedError(
+                f"{self.cfg.name}: the dense family's decode step has no expert share "
+                "(models/llama.py: prefill, chunk and block passes alone take moe_share_ffn)")
         self.mesh = mesh
         # Dispatch plane (dispatch.py): every device mutation the loop makes
         # goes through ONE funnel (_dx) that forwards the (op, payload) step
@@ -764,8 +794,10 @@ class GenerationEngine:
             if isinstance(self._cv, dict) and "moe" in self._cv else None)
         # what the blocked int8 decode-attention arm streams, where decode
         # rounds run it (int8 GQA cache read by the Pallas kernel): None else
+        # (and never for a block configuration: a block pass's attention is the
+        # bucketed chunk's, no decode arm runs)
         self._attn_stream = None
-        if layout.fused and self.decode_impl == "pallas":
+        if layout.fused and self.decode_impl == "pallas" and not self._block:
             self._attn_stream = AttnStream(self._ck["q"].shape, kv_heads=self.cfg.n_kv_heads)
         elif layout.latent and layout.int8 and self.decode_impl == "pallas":
             # the latent arms: whole-S where it fits, else blocks of the prefix
@@ -798,7 +830,10 @@ class GenerationEngine:
         # this, decode rounds would write garbage rows inside a slot that is
         # mid-chunked-prefill (stale length 0) and corrupt its prompt KV.
         self._lengths = np.full(max_slots, max_seq_len, dtype=np.int32)
-        self._last_tok = np.zeros(max_slots, dtype=np.int32)
+        # (a block configuration's is a slot's next block as it starts, [B, L]:
+        # masks, or a prompt's last P mod L tokens and masks)
+        self._last_tok = np.zeros(
+            (max_slots, self._block) if self._block else max_slots, dtype=np.int32)
         self._temp = np.zeros(max_slots, dtype=np.float32)
         self._topk = np.zeros(max_slots, dtype=np.int32)
         self._topp = np.ones(max_slots, dtype=np.float32)
@@ -817,6 +852,11 @@ class GenerationEngine:
         for bad in (self.tokenizer.pad_id, self.tokenizer.bos_id):
             if bad != self.tokenizer.eos_id and 0 <= bad < self.cfg.vocab_size:
                 allowed[bad] = False
+        if self._block:
+            # a block is done when no position holds the mask: a sampler that
+            # could emit the mask's id would leave a block that never is,
+            # whatever ids the tokenizer covers
+            allowed[self.cfg.mask_token_id] = False
         self._allowed_mask = jnp.asarray(allowed) if not allowed.all() else None
 
         (self._decode_fn, self._fused_fn, self._fused_ragged_fn,
@@ -1044,6 +1084,13 @@ class GenerationEngine:
 
             ipack i32 [3*Ab+2]: slots, prompt lengths, top_k, A (live row
             count), rng counter. fpack f32 [2*Ab]: temperature, top_p.
+
+            A configuration that generates by diffusion over blocks
+            (`cfg.block_len` L) samples NOTHING here: `tokens` are each
+            prompt's first L * (P // L) tokens, `lengths` that many, and
+            ipack carries Ab * L more ints, each slot's first block as it
+            starts (the prompt's last P mod L tokens, then masks), which land
+            in `d_last` [B, L]; `toks0` is zeros, read for the program's end.
             """
             Ab = tokens.shape[0]
             slots = ipack[:Ab]
@@ -1081,6 +1128,10 @@ class GenerationEngine:
             d_temp = d_temp.at[row].set(temps)
             d_topk = d_topk.at[row].set(topks)
             d_topp = d_topp.at[row].set(topps)
+            if cfg_.block_len:
+                first = ipack[3 * Ab + 2 :].reshape(Ab, cfg_.block_len)
+                return (ck, cv, d_temp, d_topk, d_topp, d_last.at[row].set(first),
+                        jnp.zeros((Ab,), jnp.int32))
             with jax.named_scope("sample"):
                 if mask_ is not None:
                     logits = jnp.where(mask_, logits, -jnp.inf)
@@ -1325,7 +1376,7 @@ class GenerationEngine:
         # constructed, no request ever carries `cn`, every jitted path
         # keeps its cn=None trailing operand — zero new executables traced
         # and token-identical greedy output.
-        self.constrain_enabled = constrain.constrain_enabled()
+        self.constrain_enabled = constrain.constrain_enabled() and self._runs("constrain")
         self.cn_bias_max = max(
             1, int(os.environ.get("LLM_MCP_TPU_CN_BIAS_MAX", "") or 64)
         )
@@ -1505,6 +1556,9 @@ class GenerationEngine:
             target_ttft_ms=self.target_ttft_ms,
             device_kind=jax.devices()[0].device_kind,
         )
+        # the block rounds' book and the counters of what such a configuration
+        # runs without (perf_stats()["blocks"]): None for every other
+        self._block_book = self._perf.count_blocks(layout.without) if self._block else None
         # Workload capture + latency waterfall (telemetry/workload.py).
         # The capture ring is process-shared (like the flight recorder) so
         # a fleet of engines streams one trace; the waterfall is per-engine
@@ -1823,11 +1877,19 @@ class GenerationEngine:
         ops["ragged"] = op_ragged
 
         def op_bsample(gid, rows, slots_fin, temps, topks, topps, counter,
-                       cn=None):
+                       cn=None, first=None):
             # activation sample off a parked chunk group's boundary logits +
             # the sampling-param/token-ring writes for the finishing slots
+            # (`first`: a block configuration's, whose ring holds each slot's
+            # first block as it starts and which samples nothing here)
             logits = self._x_logits.pop(gid, None)
             if logits is None or len(rows) == 0:
+                return None
+            if first is not None:
+                self._d_temp = self._d_temp.at[slots_fin].set(temps)
+                self._d_topk = self._d_topk.at[slots_fin].set(topks)
+                self._d_topp = self._d_topp.at[slots_fin].set(topps)
+                self._d_last_tok = self._d_last_tok.at[slots_fin].set(first)
                 return None
             if cn is not None:
                 # constrained activation (chunked-prefill and prefix-hit
@@ -2243,7 +2305,85 @@ class GenerationEngine:
             d_last = new.at[row].set(toks0)
             return with_counts(out, cv), toks0, ck, cv, d_temp, d_topk, d_topp, d_last
 
-        return decode_chunk_fn, fused_step_fn, fused_ragged_fn, mixed_round_fn
+        if not cfg.block_len:
+            return decode_chunk_fn, fused_step_fn, fused_ragged_fn, mixed_round_fn
+
+        L, MASK = cfg.block_len, cfg.mask_token_id
+
+        def block_round_fn(params, ck, cv, packed, d_temp, d_topk, d_topp,
+                           d_last, compact, paged=None):
+            """One BLOCK round, in the decode round's place for a configuration
+            that generates by diffusion over blocks: ONE program that fills
+            each row's block of L positions and commits it.
+
+            `packed` is the decode round's ([lengths | slot_ids | counter] or
+            [lengths | counter]); a row's length IS its block's first position,
+            a multiple of L. The blocks as they start come from `d_last`
+            [B, L], device-resident like the decode round's token ring: masks,
+            or behind an admission the prompt's last P mod L tokens and masks
+            (admit_fn). Denoising passes (`llama.block_denoise`: the L
+            positions against the cache of every earlier block and, whole,
+            against each other, NO cache write, then the unmask rule on device)
+            repeat while a live row holds a mask, `denoise_steps` of them at
+            most: the host never learns the count before the fetch and does
+            not need to. The commit pass
+            (`llama.block_pass(commit=True)`) runs the final tokens once more
+            and writes their keys and values. Out: the tokens [L, Ba] in
+            position order (a first block's leading P mod L rows are the
+            prompt's own), one row of each row's denoising passes, then the
+            expert counts as a decode round appends them; every live row's
+            `d_last` is L masks again, so the next round is dispatched before
+            this one is fetched."""
+            assert paged is None, "a block configuration runs without the prefix pool"
+            if compact:
+                Ba = (packed.shape[0] - 1) // 2
+                starts, slot_ids = packed[:Ba], packed[Ba : 2 * Ba]
+                first = d_last[slot_ids]
+                temp, topk, topp = d_temp[slot_ids], d_topk[slot_ids], d_topp[slot_ids]
+            else:
+                Ba = packed.shape[0] - 1
+                starts, slot_ids, first = packed[:Ba], None, d_last
+                temp, topk, topp = d_temp, d_topk, d_topp
+            live = starts < (ck["q"] if isinstance(ck, dict) else ck).shape[3]
+            counted = isinstance(cv, dict) and "moe" in cv
+
+            def masks_left(carry):
+                # a pass fills at least L / denoise_steps of a row's masks and
+                # the sampler cannot emit the mask (`_allowed_mask`), so no
+                # block outlives `denoise_steps` passes: the bound only keeps a
+                # fault from spinning on the chip
+                return jnp.any((carry[0] == MASK) & live[:, None]) & (carry[4] < cfg.denoise_steps)
+
+            def denoise(carry):
+                tokens, passes, rng, moe, n = carry
+                rng, sub = jax.random.split(rng)
+                new, cv_p, _ = block_denoise(
+                    cfg, params, ck, dict(cv, moe=moe) if counted else cv, tokens,
+                    slot_ids, starts, live, sub, temp, topk, topp, allowed=mask)
+                passes = passes + (jnp.any(tokens == MASK, axis=1) & live)
+                return new, passes, rng, cv_p["moe"] if counted else moe, n + 1
+
+            tokens, passes, _, moe, _ = jax.lax.while_loop(
+                masks_left, denoise,
+                (first, jnp.zeros((Ba,), jnp.int32), jax.random.fold_in(base_key, packed[-1]),
+                 cv["moe"] if counted else jnp.zeros((), jnp.int32), jnp.int32(0)))
+            with jax.named_scope("block.commit"):
+                _, ck, cv = block_pass(
+                    cfg, params, ck, dict(cv, moe=moe) if counted else cv, tokens,
+                    slot_ids, starts, live, commit=True)
+            fresh = jnp.where(live[:, None], MASK, first)
+            d_last = fresh if slot_ids is None else d_last.at[slot_ids].set(fresh)
+            out = jnp.concatenate([tokens.T, passes[None]])  # [L + 1, Ba]
+            return with_counts(out, cv), ck, cv, d_last
+
+        # in a trace the block round is THE plain round, as the decode round is
+        # for every other configuration (perf.PLAIN_ROUND_TRACE_NAME says why)
+        assert decode_chunk_fn.__name__ == perf.PLAIN_ROUND_TRACE_NAME
+        block_round_fn.__name__ = block_round_fn.__qualname__ = perf.PLAIN_ROUND_TRACE_NAME
+        block_round_fn = jax.jit(
+            block_round_fn, donate_argnums=(1, 2, 7), static_argnames=("compact",),
+            **self._shard_out(["repl", "k", "v", "repl"]))
+        return block_round_fn, fused_step_fn, fused_ragged_fn, mixed_round_fn
 
     def _build_verify(self):
         """Jitted speculative verify: ONE model call over [token, draft_1..
@@ -2503,6 +2643,14 @@ class GenerationEngine:
         configuration's admissions ride too (`hybrid_mixed_step`)."""
         return feature not in self._layout.without
 
+    def _note_off(self, feature: str) -> None:
+        """A feature this configuration runs without would have engaged: the
+        book that keeps it off counts it (the state pool's, or the block
+        rounds'); a configuration with neither counts nothing."""
+        book = self._block_book if self._state_pool is None else self._state_pool
+        if book is not None:
+            book.note_off(feature)
+
     @property
     def state_dtype(self) -> str:
         """The recurrent state pool's precision ("" without one): the matrix
@@ -2738,7 +2886,7 @@ class GenerationEngine:
             ab, bucket = int(key[0]), int(key[1])
             return self._admit_fn, (
                 P, CK, CV, *sampling,
-                host((ab, bucket)), host((3 * ab + 2,)),
+                host((ab, bucket)), host((3 * ab + 2 + ab * self._block,)),
                 host((2 * ab,), jnp.float32),
             ), {}
         if phase == "decode":
@@ -4124,8 +4272,7 @@ class GenerationEngine:
         live on host (the preempt path device_get them), so no engine-loop
         coordination is needed — pool pops are atomic, and a parked slot is
         touched by nobody until whoever popped its snapshot restores it."""
-        if self._state_pool is not None:
-            self._state_pool.note_off("migration")
+        self._note_off("migration")
         if self._migrate_outbox is None or self._pool is None:
             return None
         snap = self._pool.pop_restore()
@@ -4165,11 +4312,12 @@ class GenerationEngine:
         service pumps it back over the response stream). Returns the
         reconstructed request. Raises when migration is off or the payload
         cannot run here — callers error the original consumer."""
-        if self._state_pool is not None:
-            self._state_pool.note_off("migration")
+        if not self._runs("migration"):
+            self._note_off("migration")
             raise RuntimeError(
-                f"KV migration is off for {self.cfg.name}: a moved sequence "
-                "would leave its recurrent state behind")
+                f"KV migration is off for {self.cfg.name}: " + (
+                    "a moved sequence would leave its recurrent state behind"
+                    if self._state_pool is not None else self._layout.without["migration"]))
         if self._migrate_in is None:
             raise RuntimeError("KV migration disabled (TPU_MIGRATE=0)")
         if self._stop_evt.is_set() or self.stalled:
@@ -4537,6 +4685,13 @@ class GenerationEngine:
             # cadence never stalls behind a prefill backlog, and the group's
             # device time is capped at ~one decode round by construction.
             group = timed("prefill", self._stage_prefill_group, len(active))
+            if group is not None and active and self._block:
+                # no round of a block configuration carries a chunk group
+                # (memory.BLOCK_OFF): it runs as a program of its own, here,
+                # between two block rounds
+                self._note_off("fused_round")
+                timed("prefill", self._dispatch_prefill_group, group)
+                group = None
             # Whole prompts ride a full-batch round's first step (a weight
             # pass shared with the decode rows, mixed_round_fn) where the
             # configuration allows: staged HERE, before the dispatch, so the
@@ -4653,6 +4808,16 @@ class GenerationEngine:
         and LRU-cached by schema hash; a bad spec errors the request here
         (the API already 400s well-formed-but-unsupported specs, this is
         the engine-side backstop). Returns False when the request died."""
+        if self._block_book is not None and (req.constraint or req.logit_bias):
+            # a block's positions unmask in any order: no automaton masks them
+            # (memory.BLOCK_OFF); the request is refused, not served unmasked
+            self._note_off("constrain")
+            self._count_error()
+            req.out.put({"type": "error", "error":
+                         f"constraint: {self.cfg.name} generates by diffusion over "
+                         "blocks; constrained decoding is off for it"})
+            req.out.put(_DONE)
+            return False
         if self._constrain is None or not (req.constraint or req.logit_bias):
             return True
         before = self._constrain.stats_d["misses"]
@@ -4766,10 +4931,10 @@ class GenerationEngine:
             while len(batch) < self.admit_batch:
                 slot = self._free_slot(reserved)
                 if slot is None:
-                    if self._state_pool is not None and not self._admit.empty():
+                    if not self._admit.empty():
                         # a request waits and no slot is free: where a pool
                         # with host offload would weigh a preemption
-                        self._state_pool.note_off("offload")
+                        self._note_off("offload")
                     held_by = "no_slot"
                     break
                 nxt = self._pop_request()
@@ -4807,7 +4972,11 @@ class GenerationEngine:
                     # rounds (no head-of-line blocking of in-flight streams).
                     # sp>1 keeps whole-prompt prefill: the sp axis bounds
                     # per-chip work.
-                    self._prefills[slot] = _PrefillState(req=req, ids=list(ids))
+                    # (a block configuration prefills whole blocks alone: the
+                    # prompt's last P mod L tokens start its first block, `tail`)
+                    cut = self._whole_blocks(len(ids))
+                    self._prefills[slot] = _PrefillState(
+                        req=req, ids=list(ids[:cut]), tail=list(ids[cut:]))
                     self._prefill_q.append(slot)
                     # ledger: reserve the prompt's blocks for the whole
                     # chunked prefill (the rows are written incrementally
@@ -4881,7 +5050,9 @@ class GenerationEngine:
         (`mixed_round_fn`), "" where they may: `other` (the decode step is
         neither `_decode_step_q8` nor `hybrid_decode_step` on one chip with the
         int8 cache: a mesh, a bf16 or latent cache, the XLA path, routed experts
-        or sliding windows in the dense family, a cache shorter than a rung)."""
+        or sliding windows in the dense family, a cache shorter than a rung; a
+        configuration that generates by diffusion over blocks, whose round is a
+        block round with no step for a prompt to ride: memory.BLOCK_OFF)."""
         return self._ride_why
 
     def _round_carries(self, nact: int, group: _PrefillGroup | None) -> bool:
@@ -5057,8 +5228,7 @@ class GenerationEngine:
         one suffix token must remain — the suffix chunk produces the
         first-sample logits). `count` False only asks: the hit and miss
         counters and the LRU order stay as they are (_may_ride)."""
-        if self._state_pool is not None:
-            self._state_pool.note_off("prefix_cache")
+        self._note_off("prefix_cache")
         if not self._prefix_budget or not self._prefix_cache:
             return None
         t = tuple(ids)
@@ -5435,8 +5605,7 @@ class GenerationEngine:
         if not self._runs("prefix_cache"):
             # a peer's prefix holds KV rows and no recurrent state (or, for a
             # counted latent pair, bare rows of both members): never here
-            if self._state_pool is not None:
-                self._state_pool.note_off("prefix_cache")
+            self._note_off("prefix_cache")
             with self.stats_lock:
                 self.prefix_import_rejects_total += 1
             return False
@@ -5626,13 +5795,24 @@ class GenerationEngine:
         fpack = np.zeros((2 * Ab,), dtype=np.float32)
         ipack[Ab : 2 * Ab] = 1  # dummy rows: 1 harmless token
         fpack[Ab:] = 1.0  # top_p
+        L = self._block
+        if L:
+            # whole blocks alone are prefilled, and nothing is sampled: each
+            # slot's first block as it starts rides behind the packed ints
+            bucket = self._bucket(max(1, max(self._whole_blocks(len(ids)) for _, _, ids in batch)))
+            tokens = np.zeros((Ab, bucket), dtype=np.int32)
+            ipack = np.concatenate([ipack, np.full((Ab * L,), self.cfg.mask_token_id, np.int32)])
         for i, (slot, req, ids) in enumerate(batch):
-            tokens[i, : len(ids)] = ids
+            whole = self._whole_blocks(len(ids))
+            tokens[i, :whole] = ids[:whole]
             ipack[i] = slot
-            ipack[Ab + i] = len(ids)
+            ipack[Ab + i] = whole
             ipack[2 * Ab + i] = req.top_k
             fpack[i] = req.temperature
             fpack[Ab + i] = req.top_p
+            if L:
+                at = 3 * Ab + 2 + i * L
+                ipack[at : at + L] = self._first_block(ids[whole:])
         ipack[3 * Ab] = A
         ipack[3 * Ab + 1] = self._next_counter()
         # constrained admissions: the first sampled token rides the same
@@ -5652,8 +5832,7 @@ class GenerationEngine:
             sum(len(ids) for _, _, ids in batch), held_by,
         )
         self._adm.own(self._own_reason(batch), A)
-        if self._state_pool is not None:
-            self._state_pool.note_off("mixed_round")
+        self._note_off("mixed_round")
         if first:
             # jit traces and compiles inside the call: the wall up to its
             # return is the compile's, and the ledger's context closes here,
@@ -5717,7 +5896,8 @@ class GenerationEngine:
                 self._free_now(slot)
                 continue
             s.prefill_compute_s += wall_a_token * P
-            self._first_token(slot, s, int(toks0[i]), adm.mark)
+            if not self._block:  # a block configuration's comes with its first round
+                self._first_token(slot, s, int(toks0[i]), adm.mark)
 
     def _activate_state(
         self, slot: int, req: GenRequest, ids: list[int], tok0: int
@@ -5772,6 +5952,12 @@ class GenerationEngine:
         mgr.note_admit_cost(mgr.blocks_for(want) - shared_full)
         self._slots[slot] = s
         self._lengths[slot] = P
+        if self._block:
+            # the cache holds the prompt's whole blocks: the slot's length is
+            # its first block's first position, and the prompt's last P mod L
+            # tokens stand fixed at the block's front (the recovery mirror)
+            self._lengths[slot] = self._whole_blocks(P)
+            self._last_tok[slot] = self._first_block(ids[self._lengths[slot]:])
         if self._state_pool is not None:
             self._state_pool.admitted_total += 1  # the slot's state row is this prompt's
         self._temp[slot] = req.temperature
@@ -5786,16 +5972,41 @@ class GenerationEngine:
             s.spec.extend(ids)
         return s
 
+    def _whole_blocks(self, n: int) -> int:
+        """Of a prompt of `n` tokens, those an admission prefills: all of them,
+        or for a block configuration its whole blocks (the rest start the
+        slot's first block: `_first_block`)."""
+        return n - n % self._block if self._block else n
+
+    def _first_block(self, tail: list[int]) -> Any:
+        """A slot's first block as it starts: the prompt's last P mod L
+        tokens, fixed, then masks."""
+        row = np.full((self._block,), self.cfg.mask_token_id, dtype=np.int32)
+        row[: len(tail)] = tail
+        return row
+
     def _first_token(
         self, slot: int, s: _Slot, tok0: int, mark: tuple | None = None
     ) -> None:
         """The host has read a seated slot's first token: the TTFT stamp
         and its records, the recovery mirror, the token's emission (`mark`:
         _put_text), and the hand-over of a prefill-role engine."""
+        self._last_tok[slot] = tok0
+        self._first_stamp(slot, s)
+        # tok0's KV will be written at position P in the first decode round.
+        self._emit_token(slot, s, tok0, pos=s.prompt_len - 1, mark=mark)
+        if self._exports_after_prefill(s.req) and not s.done and not s.aborted:
+            # disaggregated mode: this engine spent the prefill and emitted
+            # the first token; the decode-role peer continues from here
+            self._migrate_export_slot(slot, s)
+
+    def _first_stamp(self, slot: int, s: _Slot) -> None:
+        """A slot's first token is in the host's hands (an admission's read,
+        or for a block configuration its first block round's fetch): the TTFT
+        stamp and its records."""
         req = s.req
         P = s.prompt_len
         s.first_token_at = time.time()
-        self._last_tok[slot] = tok0
         ttft_ms = (s.first_token_at - req.created_at) * 1000.0
         with self.stats_lock:
             self.total_requests += 1
@@ -5829,12 +6040,6 @@ class GenerationEngine:
                     "sched_starved_rounds": self._sched.starved_rounds,
                 },
             )
-        # tok0's KV will be written at position P in the first decode round.
-        self._emit_token(slot, s, tok0, pos=P - 1, mark=mark)
-        if self._exports_after_prefill(req) and not s.done and not s.aborted:
-            # disaggregated mode: this engine spent the prefill and emitted
-            # the first token; the decode-role peer continues from here
-            self._migrate_export_slot(slot, s)
 
     def _prefill_backlog(self) -> int:
         """Prompt tokens not yet written for live mid-prefill slots."""
@@ -5903,8 +6108,7 @@ class GenerationEngine:
             return None
         if self.ragged_prefill:
             return self._stage_ragged_group(budget)
-        if self._state_pool is not None:
-            self._state_pool.note_off("ragged_prefill")  # a bucketed chunk group instead
+        self._note_off("ragged_prefill")  # a bucketed chunk group instead
         group: list[int] = []
         metas: list[tuple[int, _PrefillState, int]] = []
         try:  # staging bugs must also fail over to waiters
@@ -6181,6 +6385,21 @@ class GenerationEngine:
             cn_payload = self._cn_payload(
                 [st.req.cn for _, _, st in fin], len(fin)
             )
+            if self._block:
+                # nothing is sampled: the finishing slots' first blocks as
+                # they start go to the device's start buffer with their
+                # sampling parameters, and their first tokens come with their
+                # first block round (_emit_round)
+                self._dx(
+                    "bsample", group.gid, rows, slots_fin, temps, topks, topps,
+                    0, None, np.stack([self._first_block(st.tail) for _, _, st in fin])
+                    if fin else np.zeros((0, self._block), np.int32),
+                )
+                for _, slot, st in fin:
+                    self._prefill_q.remove(slot)
+                    self._seat(slot, st.req, st.ids + st.tail)
+                    del self._prefills[slot]
+                return
             toks0 = self._dx(
                 "bsample", group.gid, rows, slots_fin, temps, topks, topps,
                 self._next_counter(), cn_payload,
@@ -6578,8 +6797,7 @@ class GenerationEngine:
         # chaos site: a failed round must fail active slots with error
         # events, not hang callers (the poisoned-round guard in _run)
         maybe_fail("engine.decode", f"active={len(active)}")
-        if self._state_pool is not None:
-            self._state_pool.note_off("speculation")  # a decode round, dispatched with no draft
+        self._note_off("speculation")  # a decode round, dispatched with no draft
         round_t0 = time.perf_counter()
         B = self.max_slots
         nact = len(active)
@@ -6717,10 +6935,14 @@ class GenerationEngine:
             first = self._note_exec_shape("decode", Ba, compact,
                                           self._phys is not None)
             t0c = time.perf_counter()
-            out = self._dx(
-                "decode", "plain", 0, packed, (), compact, 0,
-                self._paged_payload(),
-            )
+            if self._block:  # the block round in the decode round's place
+                with TraceAnnotation("engine.block", rid=self._rid_dispatched + 1):
+                    out = self._dx("decode", "plain", 0, packed, (), compact, 0, None)
+            else:
+                out = self._dx(
+                    "decode", "plain", 0, packed, (), compact, 0,
+                    self._paged_payload(),
+                )
             if first:
                 self._compile_obs(
                     "decode", (Ba, compact, self._phys is not None),
@@ -6761,6 +6983,9 @@ class GenerationEngine:
                 padded_tokens=ride.rung, queued=self._admit.qsize(),
                 held_by=ride.held_by, t=time.monotonic(),
             )
+        elif self._block:
+            self._flight.event(
+                "block", rid=self._rid_dispatched, rows=len(active), t=time.monotonic())
         else:
             self._flight.event(
                 phase_name,
@@ -6797,12 +7022,14 @@ class GenerationEngine:
             ride.adm.round = disp  # the read of its first tokens ends the round
         return disp
 
-    @staticmethod
-    def _round_prog(group: _PrefillGroup | None, ride: _Ride | None) -> str:
+    def _round_prog(self, group: _PrefillGroup | None, ride: _Ride | None) -> str:
         """A round's step program as the account of rounds keys it
         (telemetry/perf.py:RoundAccount), known before the dispatch: the
         plain round, the mixed round by its rung, the round fused with a
-        chunk group by its phase."""
+        chunk group by its phase; `block` for a configuration that generates
+        by diffusion over blocks, whose every round is a block round."""
+        if self._block:
+            return "block"
         if group is not None:
             return "fused_rag" if group.ragged else "fused"
         return "plain" if ride is None else f"mixed_{ride.rung}"
@@ -6824,6 +7051,9 @@ class GenerationEngine:
         t_wait = time.perf_counter()
         with TraceAnnotation("engine.fetch.sync"):
             out = np.asarray(disp.out)  # [K, Ba] — the only host sync per round
+        row_passes = None
+        if self._block:  # the row behind the block's tokens: each row's denoising passes
+            row_passes, out = out[self._block], np.delete(out, self._block, axis=0)
         if self._experts is not None:
             # rows past the K of tokens: the expert layer's counts [2, L, 5]
             K, L = self.decode_chunk, self._experts.n_layers
@@ -6877,7 +7107,8 @@ class GenerationEngine:
             g = s.generated
             fin = False
             base_b = int(disp.base[b])
-            for k in range(K):
+            # (a first block's leading positions are the prompt's own)
+            for k in range(max(0, s.prompt_len - base_b) if self._block else 0, K):
                 if int(out[k, col]) == eos:
                     fin = True
                     break
@@ -6885,7 +7116,7 @@ class GenerationEngine:
                 if g >= s.req.max_tokens:
                     fin = True
                     break
-                if base_b + k + 1 + K > S:
+                if self._at_cap(base_b + k):
                     fin = True
                     break
             if fin:
@@ -6898,7 +7129,13 @@ class GenerationEngine:
                 # lengths were advanced optimistically at dispatch (the
                 # pipelined loop stages later rounds before this fetch) —
                 # only the recovery mirror updates here
-                self._last_tok[b] = out[-1, col]
+                self._last_tok[b] = self.cfg.mask_token_id if self._block else out[-1, col]
+        if self._block_book is not None:
+            fixed = [min(self._block, max(0, s.prompt_len - int(disp.base[b])))
+                     for b, s, _ in disp.entries]
+            self._block_book.fetched(
+                [int(row_passes[col]) for _, _, col in disp.entries],
+                rows * self._block - sum(fixed), sum(fixed))
         self._rid_fetched = max(self._rid_fetched, disp.rid)
         if self._cooling:
             # the fetch that ends a freed slot's fence stamps it: from here
@@ -7013,7 +7250,15 @@ class GenerationEngine:
             finish = None
             base_b = int(p.base[b])
             gen_before = s.generated
-            for k in range(K):
+            k0 = 0
+            if self._block:
+                # a block round's first tokens are the slot's first: the TTFT
+                # stamp is this emission's; a first block's leading positions
+                # are the prompt's own and are not delivered
+                k0 = max(0, s.prompt_len - base_b)
+                if not s.first_token_at:
+                    self._first_stamp(b, s)
+            for k in range(k0, K):
                 emit, finish = self._process_token(s, int(p.out[k, col]), base_b + k)
                 if emit:
                     parts.append(emit)
@@ -7037,6 +7282,8 @@ class GenerationEngine:
                 self._finish_slot(b, s, finish)
         delivered = self.total_tokens - before
         self._perf.rounds.delivered(p.prog, delivered)
+        if self._block_book is not None:
+            self._block_book.delivered(delivered)
         self._flight.event(
             "emit", rid=p.rid, rows=len(p.entries), delivered=delivered,
             texts=texts, held=held,
@@ -7139,6 +7386,15 @@ class GenerationEngine:
             return False
         return True
 
+    def _at_cap(self, pos: int) -> bool:
+        """Whether the token at cache position `pos` is a sequence's last for
+        want of room: the next round's writes would pass the cache's end. A
+        block configuration's next round is the next BLOCK, so only a block's
+        last position can be the cap."""
+        if self._block and (pos + 1) % self._block:
+            return False
+        return pos + 1 + self.decode_chunk > self.max_seq_len
+
     def _process_token(self, s: _Slot, tok: int, pos: int) -> tuple[str, str | None]:
         """Advance one slot by one token WITHOUT delivering events: returns
         (text to emit, finish_reason | None). Event delivery is the caller's
@@ -7191,7 +7447,7 @@ class GenerationEngine:
                 s.text = total
             if finish is None and s.generated >= req.max_tokens:
                 finish = "length"
-            if finish is None and pos + 1 + self.decode_chunk > self.max_seq_len:
+            if finish is None and self._at_cap(pos):
                 finish = "length"
         if finish is not None and s.pending:
             # End of stream: flush any buffered partial decode (unless we cut

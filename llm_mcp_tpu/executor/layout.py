@@ -16,12 +16,15 @@ any of that again:
     tiny-kexaone "int8"          False  True   True  "win"       gqa_int8
     tiny-lfm2 "int8"             False  True   True  "state"     gqa_int8
     tiny-joyai "int8"            True   True   False ""          mla_int8   (counted)
+    tiny-sdar "int8"             False  True   True  ""          gqa_int8   (counted)
 
-`latent`: MLA's two asymmetric members (models/mla.py). `counted`: a latent pair
-whose expert layer counts its work (`moe.share_form`): the counts [2, Le, 5] ride
-the second member beside the rope keys, {"v": the rope keys, "moe": the counts},
-as they ride a hybrid pair's; `without` is then `memory.COUNTED_OFF`, what takes
-the second member for bare rows. `wrapped`: the second member is such a dict
+`latent`: MLA's two asymmetric members (models/mla.py). `counted`: a latent or a
+dense pair whose expert layer counts its work (`moe.share_form`): the counts
+[2, Le, 5] ride the second member beside the rope keys (the V rows of a dense
+pair: none in the fused form), {"v": those rows, "moe": the counts}, as they ride
+a hybrid pair's; `without` is then `memory.COUNTED_OFF`, what takes the second
+member for bare rows, or `memory.BLOCK_OFF` where the configuration generates by
+diffusion over blocks (`cfg.block_len`). `wrapped`: the second member is such a dict
 (a slot member, or the counts), and the full-length rows are its "v". `fused`: int8 GQA, V
 rides `cache["k"]`'s head axis and `cache["v"]` is the empty dict
 (models/llama.py:init_kv_cache). `slot_member`: the member of `cache["v"]` that
@@ -47,7 +50,7 @@ from ..models.llama import fuse_prompt_kv, init_kv_cache, quantize_kv
 from ..models.moe import share_form
 from ..parallel.sharding import kv_cache_specs, kv_pool_specs, named_shardings
 from ..telemetry.perf import layout_name
-from .memory import COUNTED_OFF, RECURRENT_OFF
+from .memory import BLOCK_OFF, COUNTED_OFF, RECURRENT_OFF
 from .physical import pool_like
 
 
@@ -76,7 +79,7 @@ class CacheLayout:
 
     @property
     def counted(self) -> bool:
-        return self.latent and share_form(self.cfg)
+        return not self.cfg.recurrent and share_form(self.cfg)
 
     @property
     def wrapped(self) -> bool:
@@ -88,7 +91,11 @@ class CacheLayout:
 
     @property
     def without(self) -> Mapping[str, str]:
-        return RECURRENT_OFF if self.slot_member else COUNTED_OFF if self.counted else {}
+        if self.slot_member:
+            return RECURRENT_OFF
+        if self.cfg.block_len:
+            return BLOCK_OFF
+        return COUNTED_OFF if self.counted else {}
 
     # -- how it is made ------------------------------------------------------
 

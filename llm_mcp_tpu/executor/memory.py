@@ -54,7 +54,7 @@ from typing import Any
 
 from ..utils.locks import OrderedLock
 
-__all__ = ["COUNTED_OFF", "ExpertCounts", "KVPool", "KVSnapshot", "RECURRENT_OFF", "StatePool",
+__all__ = ["BLOCK_OFF", "COUNTED_OFF", "ExpertCounts", "KVPool", "KVSnapshot", "RECURRENT_OFF", "StatePool",
            "build_state_pool",
            "pytree_nbytes", "bucket_len"]
 
@@ -349,6 +349,19 @@ COUNTED_OFF = {
                     "member here is the rope keys AND the expert counts",
     "offload": "a preempted slot's snapshot cuts bare rows out of both members",
     "migration": "the wire format of a moved sequence holds bare rows of both members",
+}
+# What a configuration that generates by diffusion over blocks runs without
+# (`cfg.block_len`; its round is `engine.block_round_fn`): every program that
+# takes a step to yield one token a sequence, and, because its expert counts
+# ride the dense pair's second member, what COUNTED_OFF lists. Each with the
+# counter of the times it would have engaged (`perf_stats()["blocks"]["off"]`).
+BLOCK_OFF = {
+    **COUNTED_OFF,
+    "speculation": "a draft continues a sequence token by token; a block's tokens are chosen together",
+    "mixed_round": "a prompt rides a decode step's weight pass; a block round has no such step",
+    "fused_round": "a chunk group rides a decode round's dispatch; it runs between two block rounds",
+    "constrain": "an automaton masks the next token given the last; a block's positions unmask in any order",
+    "ragged_prefill": "the packed prompt kernel masks causally inside a row, not by block",
 }
 # What the pool counts beside them, in the same block (`off`), that is NOT off:
 # whole prompts ride a decode round's weight pass in a recurrent configuration

@@ -196,6 +196,7 @@ def _flash_prefill_kernel(
     scale: float,
     block_k: int,
     softcap: float,
+    block_len: int = 0,
 ):
     """One grid cell = one query block of the G query heads that share a KV
     head: their rows are ONE [G*BQ, hd] operand against each K and V block
@@ -227,7 +228,13 @@ def _flash_prefill_kernel(
     the float32 scores; m, l, acc and the exponentials are float32; p is
     rounded to v's dtype for the second product, as the XLA arm of
     `models/llama.py:prefill_attn` rounds its probabilities (float32 inputs
-    stay float32 throughout)."""
+    stay float32 throughout).
+
+    With `block_len` L (a configuration that generates by diffusion over
+    blocks, `cfg.block_len`; L divides both block sizes) a row sees every key of
+    its own block of L positions too: the key blocks a cell runs and the ones
+    that need no mask are the causal ones (a block of L never straddles a key
+    block), and only the edge blocks' mask reads `k // L <= q // L`."""
     b = pl.program_id(0)
     qi = pl.program_id(2)
     _, G, bq, hd = q_ref.shape
@@ -266,7 +273,10 @@ def _flash_prefill_kernel(
             s = jnp.tanh(s / softcap) * softcap
         if masked:
             k_pos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
-            mask = (k_pos <= q_pos) & (k_pos < valid_len)
+            q_edge = q_pos
+            if block_len:  # the last position of the row's own block
+                q_edge = jax.lax.div(q_pos, block_len) * block_len + (block_len - 1)
+            mask = (k_pos <= q_edge) & (k_pos < valid_len)
             mask &= jnp.logical_not(windowed) | (q_pos - k_pos < window)
             s = jnp.where(mask, s, NEG_INF)
         m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
@@ -321,7 +331,8 @@ def prefill_block(group: int, seq_len: int) -> int:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_q", "block_k", "interpret", "softcap", "scale")
+    jax.jit,
+    static_argnames=("block_q", "block_k", "interpret", "softcap", "scale", "block_len"),
 )
 def flash_prefill_attention(
     q: jnp.ndarray,  # [B, H, S, hd]
@@ -335,6 +346,7 @@ def flash_prefill_attention(
     block_q: int = 0,  # 0 = the rule (`prefill_block`); given only by
     block_k: int = 0,  #   scripts/flash_prefill_sweep.py and tests
     interpret: bool | None = None,
+    block_len: int = 0,  # causal between blocks of this many positions, whole inside one
 ) -> jnp.ndarray:
     """Causal + length-masked GQA flash attention. Returns [B, H, S, hd]; rows
     at or past a prompt's length hold nothing a caller may read."""
@@ -352,6 +364,9 @@ def flash_prefill_attention(
         block_k=bk,
         softcap=softcap,
     )
+    if block_len:
+        assert bq % block_len == 0 and bk % block_len == 0, (bq, bk, block_len)
+        kernel = functools.partial(kernel, block_len=block_len)
     win = jnp.reshape(jnp.asarray(window, dtype=jnp.int32), (1,))
     return pl.pallas_call(
         kernel,

@@ -167,6 +167,23 @@ class ModelConfig:
     # qk_norm over the WHOLE projection width, one weight vector each for q
     # and k (OLMo), instead of a head at a time over head_dim (Qwen3)
     qk_norm_whole: bool = False
+    # Generation by diffusion over blocks (SDAR; models/llama.py:block_pass,
+    # executor/engine.py:block_round_fn): positions lie in blocks of
+    # `block_len`, a query sees every key of its own and of earlier blocks
+    # (bidirectional inside a block, a prompt included), logits are UNSHIFTED
+    # (a position holding `mask_token_id` predicts its own token), and a block
+    # of masks is filled over denoising passes that write no cache, `block_len /
+    # denoise_steps` positions due a pass by `unmask_rule`
+    # ("low_confidence_dynamic": every masked position whose sampled token's
+    # probability passes `unmask_threshold` where at least that many do, else
+    # the due number of most confident ones; "low_confidence_static": the
+    # latter alone), then committed by one pass that writes its keys and
+    # values. 0 = a causal decoder that yields one token a step.
+    block_len: int = 0
+    denoise_steps: int = 0
+    unmask_rule: str = ""  # low_confidence_dynamic | low_confidence_static
+    unmask_threshold: float = 0.0
+    mask_token_id: int = 0
     # serving metadata
     params_b: float = 0.0
     tie_embeddings: bool = False
@@ -320,6 +337,8 @@ class ModelConfig:
             ng = len(self.gqa_layers)
             attn = (ng * gqa + (self.n_layers - ng) * kda) // self.n_layers
         per_layer_rest = attn + 2 * self.dim  # + norms
+        if self.block_len and self.qk_norm:  # exact, to the parameter: the two head norms
+            per_layer_rest += 2 * hd
         embed = self.vocab_size * self.dim
         head = 0 if self.tie_embeddings or self.arch == "encoder" else self.vocab_size * self.dim
         return embed + self.n_layers * per_layer_rest + ffn_total + head + self.dim
@@ -1064,6 +1083,70 @@ MODEL_CONFIGS: dict[str, ModelConfig] = {
         qk_norm=True,
         tie_embeddings=True,
         params_b=0.001,
+    ),
+    # SDAR-30B-A3B-Chat (JetLM/SDAR-30B-A3B-Chat config.json, `sdar_moe`) as ONE
+    # CHIP of an 8-way expert-parallel group: Qwen3-MoE's stack (GQA 32 over 4
+    # heads of 128 with q/k norm, every layer 128 softmax-routed experts of 768,
+    # 8 a token, renormalised), experts 0-15 of the published 128 held (the
+    # router keeps its 128 columns), every width, all 48 layers and all 151,936
+    # vocabulary rows the published ones; `ffn_hidden` 6144 is stated and
+    # builds nothing. It generates by diffusion over blocks of 4: the block
+    # length, the steps, the unmask rule, its threshold and the mask's id are
+    # the released sampler's, which the published config does not state:
+    # benchmark/configs/sdar-30b-a3b-ep8-bf16.json lists them as assumed.
+    "sdar-30b-a3b-ep8": ModelConfig(
+        name="sdar-30b-a3b-ep8",
+        vocab_size=151_936,
+        dim=2048,
+        n_layers=48,
+        n_heads=32,
+        n_kv_heads=4,
+        ffn_hidden=6144,
+        head_dim=128,
+        rope_theta=1_000_000.0,
+        norm_eps=1e-6,
+        max_seq_len=32_768,
+        qk_norm=True,
+        n_experts=16,
+        n_router_experts=128,
+        experts_per_tok=8,
+        moe_ffn_hidden=768,
+        norm_topk_prob=True,
+        router_score="softmax",
+        block_len=4,
+        denoise_steps=4,
+        unmask_rule="low_confidence_dynamic",
+        unmask_threshold=0.9,
+        mask_token_id=151_669,
+        params_b=5.16,
+    ),
+    # the same structure at toy size: three layers of 16 routed experts of
+    # which 4 are held, 2 a token; the mask is the last id of the vocabulary
+    "tiny-sdar": ModelConfig(
+        name="tiny-sdar",
+        vocab_size=512,
+        dim=64,
+        n_layers=3,
+        n_heads=4,
+        n_kv_heads=2,
+        ffn_hidden=128,
+        head_dim=16,
+        rope_theta=10_000.0,
+        norm_eps=1e-6,
+        max_seq_len=512,
+        qk_norm=True,
+        n_experts=4,
+        n_router_experts=16,
+        experts_per_tok=2,
+        moe_ffn_hidden=32,
+        norm_topk_prob=True,
+        router_score="softmax",
+        block_len=4,
+        denoise_steps=4,
+        unmask_rule="low_confidence_dynamic",
+        unmask_threshold=0.9,
+        mask_token_id=511,
+        params_b=0.0002,
     ),
     "tiny-mistral": ModelConfig(
         name="tiny-mistral",

@@ -79,7 +79,7 @@ from ..kernels.attention import (
 )
 from . import kda, shortconv, ssm
 from .configs import ModelConfig
-from .moe import init_moe_layer_params, moe_share_ffn
+from .moe import BANKS, init_moe_layer_params
 
 Params = dict[str, Any]
 
@@ -264,10 +264,7 @@ def _gqa_view(cfg: ModelConfig, n_layers: int | None = None) -> ModelConfig:
 
     return dataclasses.replace(
         cfg, n_layers=cfg.n_attn_layers if n_layers is None else n_layers, gqa_layers=(),
-        sliding_window=0, sliding_windows=())
-
-
-BANKS = ("w1e", "w3e", "w2e")  # never sliced by layer: moe.moe_share_ffn says why
+        sliding_window=0, sliding_windows=(), n_experts=0)  # the counts ride this pair's own member
 
 
 def _at(tree, i):
@@ -281,18 +278,11 @@ def _at(tree, i):
 def _ffn(cfg: ModelConfig, lp: Params, banks: Params, li, h: jnp.ndarray, valid, prompt=None):
     """Feed-forward of layer `li` and residual add on [..., D]: (h, counts [5]
     of the expert layer, None for the dense gated MLP); with `prompt` [N] (a
-    mixed step's rows: which are a prompt's) the counts of each phase, [2, 5]."""
-    from .llama import _ffn_residual, _residual, _sub_in, _sub_out
+    mixed step's rows: which are a prompt's) the counts of each phase, [2, 5].
+    An expert layer here is always the share form (`llama._ffn`)."""
+    from .llama import _ffn as ffn
 
-    if not cfg.n_experts:
-        return _ffn_residual(cfg, lp, h), None
-    with jax.named_scope("ffn"):
-        x = _sub_in(cfg, h, lp["ffn_norm"])
-        y, counts = moe_share_ffn(
-            cfg, lp, x.reshape(-1, x.shape[-1]),
-            valid=None if valid is None else valid.reshape(-1), banks=banks, layer=li,
-            prompt=prompt)
-        return _residual(cfg, h, _sub_out(cfg, y.reshape(h.shape), lp["ffn_norm"])), counts
+    return ffn(cfg, lp, banks if cfg.n_experts else None, li, h, valid, prompt)
 
 
 def _counted(cache_v: dict, phase: int, counts) -> dict:
@@ -693,7 +683,7 @@ def hybrid_mixed_step(
             ctx_d = decode_attend_q8(
                 q[:B].reshape(B, Hkv, H // Hkv, hd), k[:B], v[:B], cache_k, kv_v, ig, lengths,
                 scale=cfg.attn_scale).reshape(B, H * hd)
-            ctx_p = packed_prompt_attn(cfg, q[B:], k[B:], v[B:], p_rowids)
+            ctx_p = packed_prompt_attn(cfg, q[B:], k[B:], v[B:], p_rowids, p_positions)
             h = _attn_residual(cfg, lp, jnp.concatenate([ctx_d, ctx_p]), h, x)
         with jax.named_scope("kv_append"):
             fused = fuse_prompt_kv(

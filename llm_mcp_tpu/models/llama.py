@@ -20,6 +20,17 @@ with an in-process model. TPU-first choices:
     "xla" uses einsum contractions (GQA) that XLA maps onto the MXU. Both
     paths share every other op, and tests assert they agree.
 
+A configuration that generates by diffusion over BLOCKS (`cfg.block_len`: SDAR)
+runs here too: positions lie in blocks of L, a query sees the keys of its own and
+of every earlier block (`_in_block`: `prefill_masks`, the prompt kernel's edge
+blocks, `_chunk_attention`, `packed_prompt_attn`), logits are unshifted, and a
+block is filled by `block_denoise` (a pass over the bucketed chunk's machinery
+that writes no cache, the sampler with its probability, `block_unmask`) and
+committed by `block_pass(commit=True)`; executor/engine.py:block_round_fn loops
+them. Where the preset states a share of the published experts the feed-forward
+is `moe.moe_share_ffn` with the banks stacked (`moe.expert_stack`, `_ffn`) and the
+counts ride the cache pair's second member (`init_kv_cache`).
+
 Layout conventions:
   params["layers"][name]: [L, ...] stacked weights
   KV cache: k, v: [L, B, Hkv, S, hd]
@@ -53,7 +64,7 @@ from ..kernels.attention import (
 from ..ops.norms import rms_norm as _rms_norm
 from ..ops.rope import rope_tables, apply_rope
 from .configs import ModelConfig
-from .moe import init_moe_layer_params, moe_ffn
+from .moe import expert_stack, init_moe_layer_params, moe_ffn, moe_share_ffn, share_form
 from .quant import (
     embed_lookup,
     logits_head,
@@ -205,7 +216,7 @@ def init_kv_cache(
     if quantized:
         P = kv_heads_abreast(Hkv, hd)
         p = scale_pack_width(Hkv, P * hd, dtype)
-        return {
+        pair = {
             "k": {
                 "q": jnp.zeros(
                     (cfg.n_layers, batch, 2 * Hkv // P + p, max_seq, P * hd), dtype=jnp.int8
@@ -216,7 +227,14 @@ def init_kv_cache(
             },
             "v": {},
         }
-    return {"k": jnp.zeros(shape, dtype=dtype), "v": jnp.zeros(shape, dtype=dtype)}
+    else:
+        pair = {"k": jnp.zeros(shape, dtype=dtype), "v": jnp.zeros(shape, dtype=dtype)}
+    if share_form(cfg):
+        # the expert layer's counts ride the pair's second member, beside the
+        # V rows (none in the fused form): as a latent pair's ride beside its
+        # rope keys (models/mla.py; models/hybrid.py says what they are)
+        pair["v"] = {"v": pair["v"], "moe": jnp.zeros((2, cfg.n_layers, 5), jnp.int32)}
+    return pair
 
 
 def quantize_kv(kv: jnp.ndarray, scale_dtype=None) -> dict[str, jnp.ndarray]:
@@ -408,6 +426,47 @@ def _ffn_residual(
     return _residual(cfg, h, _sub_out(cfg, out, lp["ffn_norm"]))
 
 
+def _ffn(cfg: ModelConfig, lp: Params, banks: Params | None, li, h, valid=None, prompt=None):
+    """Feed-forward half of layer `li` and residual add on [..., D]: (h, counts
+    [5] of the expert layer in the share form (`banks`: `moe.expert_stack`),
+    None for every other feed-forward); with `prompt` [N] (a mixed step's rows:
+    which are a prompt's) the counts of each phase, [2, 5]. The one share-form
+    layer of the three families (models/hybrid.py and models/mla.py call this)."""
+    if banks is None:
+        return _ffn_residual(cfg, lp, h, moe_valid=valid), None
+    with jax.named_scope("ffn"):
+        x = _sub_in(cfg, h, lp["ffn_norm"])
+        y, counts = moe_share_ffn(
+            cfg, lp, x.reshape(-1, x.shape[-1]),
+            valid=None if valid is None else valid.reshape(-1), banks=banks, layer=li,
+            prompt=prompt)
+        return _residual(cfg, h, _sub_out(cfg, y.reshape(h.shape), lp["ffn_norm"])), counts
+
+
+def _rows(cache_v: Any) -> Any:
+    """The rows of the cache pair's second member (a dense pair's V rows, a
+    latent pair's rope keys: models/mla.py): the member itself, or its "v" where
+    the expert counts ride beside them (`init_kv_cache`, `mla.init_mla_cache`)."""
+    return cache_v["v"] if isinstance(cache_v, dict) and "moe" in cache_v else cache_v
+
+
+def _second(cache_v: Any, new_v: Any, phase: int, counts) -> Any:
+    """The pair's second member after a call: the new rows, and where the
+    member carries the expert counts, this call's [L, 5] added onto the running
+    sums (decode steps and a block round's passes under 0, prefills under 1)."""
+    if counts is None or not (isinstance(cache_v, dict) and "moe" in cache_v):
+        return new_v
+    return {"v": new_v, "moe": cache_v["moe"].at[phase].add(counts)}
+
+
+def _in_block(cfg: ModelConfig, q_pos: jnp.ndarray, k_pos: jnp.ndarray) -> jnp.ndarray:
+    """Which keys a query sees of positions at or after its own, beside itself:
+    none in a causal decoder; with `cfg.block_len` those of its own block."""
+    if not cfg.block_len:
+        return k_pos <= q_pos
+    return k_pos // cfg.block_len <= q_pos // cfg.block_len
+
+
 def layer_windows(cfg: ModelConfig) -> jnp.ndarray:
     """Per-layer attention window sizes, [L] int32 (0 = global attention): the
     family's published list (`cfg.sliding_windows`; a fixed period is written
@@ -445,6 +504,9 @@ def prefill_masks(
     # Causal + padding mask, computed once: [B, S, S] would be big at long S,
     # so use [1, S, S] causal and fold padding via key-validity [B, 1, S].
     causal = jnp.tril(jnp.ones((S, S), dtype=bool))[None]  # [1, S, S]
+    if cfg.block_len:  # causal between blocks, whole inside one
+        pos = jnp.arange(S, dtype=jnp.int32)
+        causal = _in_block(cfg, pos[:, None], pos[None, :])[None]
     valid_k = (jnp.arange(S)[None, :] < lengths[:, None])[:, None, :]  # [B, 1, S]
     return cos, sin, causal & valid_k
 
@@ -510,6 +572,7 @@ def prefill_attn(
                 window=window,
                 softcap=cfg.attn_softcap,
                 scale=cfg.attn_scale,
+                **({"block_len": cfg.block_len} if cfg.block_len else {}),
             )
             ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, H * hd)
         else:
@@ -562,6 +625,7 @@ def llama_prefill(
     B, S = tokens.shape
     h = _embed_in(cfg, params, tokens)  # [B, S, D]
     cos, sin, mask = prefill_masks(cfg, S, lengths)
+    banks, stack = expert_stack(cfg, params["layers"])
 
     def layer(h, xs):
         lp, win = xs
@@ -572,7 +636,25 @@ def llama_prefill(
             return h, (fuse_prompt_kv(kh, vh), {})
         return h, (kh, vh)
 
-    h, (ks, vs) = jax.lax.scan(layer, h, (params["layers"], layer_windows(cfg)))
+    def share_layer(carry, xs):
+        # the share form: the attention half as above, then the dropless
+        # expert layer over the prompts' own tokens, its banks stacked
+        h, li = carry
+        lp, win = xs
+        h, (kh, vh) = prefill_attn(cfg, lp, h, cos, sin, mask, lengths, attn_impl, win)
+        h, counts = _ffn(cfg, lp, banks, li, h,
+                         valid=jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None])
+        kv = (fuse_prompt_kv(kh, vh), {}) if quant_kv else (kh, vh)
+        return (h, li + 1), (*kv, counts)
+
+    if banks is None:
+        h, (ks, vs) = jax.lax.scan(layer, h, (stack, layer_windows(cfg)))
+    else:
+        (h, _), (ks, vs, counts) = jax.lax.scan(
+            share_layer, (h, jnp.int32(0)), (stack, layer_windows(cfg)))
+        # the call's expert counts [L, 5] beside the rows, as hybrid_prefill
+        # hands them over: the engine adds them once (`hybrid.add_counts`)
+        vs = {"v": vs, "moe": counts}
 
     last = jnp.take_along_axis(
         h, (lengths - 1)[:, None, None].astype(jnp.int32), axis=1
@@ -689,7 +771,9 @@ def mixed_step_supported(cfg: ModelConfig) -> bool:
     `hybrid.hybrid_mixed_step`: a stack with recurrent layers, with rope on its
     attention layers or without, whatever its feed-forward; a stack of window and
     global layers keeps rings, which the mixed step does not write, and takes
-    `admit_fn`."""
+    `admit_fn`. A configuration that generates by diffusion over blocks
+    (`cfg.block_len`: routed experts in the dense family) has no decode step for
+    a prompt to ride: it answers False here and its admissions take `admit_fn`."""
     if cfg.kv_lora_rank or cfg.sliding_window or cfg.attn_softcap:
         return False
     if cfg.recurrent:
@@ -703,6 +787,7 @@ def packed_prompt_attn(
     k: jnp.ndarray,  # [T, Hkv, hd] roped
     v: jnp.ndarray,  # [T, Hkv, hd]
     rowids: jnp.ndarray,  # [T] int32, sorted; pads carry the row count
+    positions: jnp.ndarray | None = None,  # [T] int32: each token's place in its prompt
 ) -> jnp.ndarray:
     """Causal self-attention of several whole prompts packed back to back:
     `prefill_attn`'s product under a segment-and-causal mask, in the
@@ -710,14 +795,18 @@ def packed_prompt_attn(
     and weighted sum; the context rounded to the activations' type once), so
     a riding prompt's K/V and first token are admit_fn's to float32 rounding.
     A token sees the tokens of its own prompt at or before it (packed order
-    is position order inside a prompt); pads see each other, finite garbage
-    nobody reads."""
+    is position order inside a prompt), and with `cfg.block_len` those of its
+    own block of positions too (`positions` says where a token lies in its
+    prompt); pads see each other, finite garbage nobody reads."""
     T, H, hd = q.shape
     Hkv = k.shape[1]
     qg = q.reshape(T, Hkv, H // Hkv, hd).astype(jnp.float32) * cfg.attn_scale
     scores = jnp.einsum("qhgd,khd->hgqk", qg, k.astype(jnp.float32))
     t = jnp.arange(T, dtype=jnp.int32)
     mask = (rowids[:, None] == rowids[None, :]) & (t[None, :] <= t[:, None])
+    if cfg.block_len:
+        mask = (rowids[:, None] == rowids[None, :]) & _in_block(
+            cfg, positions[:, None], positions[None, :])
     scores = jnp.where(mask[None, None], scores, jnp.float32(-1e30))
     probs = jax.nn.softmax(scores, axis=-1)
     ctx = jnp.einsum("hgqk,khd->qhgd", probs, v.astype(jnp.float32))
@@ -812,7 +901,7 @@ def mixed_step_q8(
                 block_tables=None if paged is None else paged["tbl"],
                 pool_k=None if paged is None else paged["k"],
             ).reshape(B, H * hd)
-            ctx_p = packed_prompt_attn(cfg, q[B:], k[B:], v[B:], p_rowids)
+            ctx_p = packed_prompt_attn(cfg, q[B:], k[B:], v[B:], p_rowids, p_positions)
             h = _attn_residual(cfg, lp, jnp.concatenate([ctx_d, ctx_p]), h)
         h = _ffn_residual(cfg, lp, h)
         with jax.named_scope("kv_append"):
@@ -915,7 +1004,15 @@ def _chunk_attention(
     [Lw, B, .., R, hd], position p at index p mod R. The past segment is
     the whole ring under a mask of each row's own positions, the window is in
     both masks, and `write` lands each row's last R VALID positions at their
-    wrapped indices (a padding row of a ragged chunk would replace a live one)."""
+    wrapped indices (a padding row of a ragged chunk would replace a live one).
+
+    With `cfg.block_len` the chunk's own segment is masked by block (a query
+    sees the VALID keys of its own block of positions too; the past lies in
+    earlier blocks whole), and a block round's passes (`block_pass`) come
+    through here: `slots` None says that row a IS cache row a (the whole batch
+    in order: the past rows are the layer's slice, no row is gathered), and
+    `write(..., keep=[A] bool)` leaves a row's cache as it stands where `keep`
+    is false (a parked row, whose start lies at the cache's end)."""
     quantized = isinstance(cache_k, dict)
     # fused quantized cache: axis 2 of "q" is 2*Hkv/P + p and its rows P*hd
     # wide — take Hkv and hd from cfg, P from the cache
@@ -928,8 +1025,10 @@ def _chunk_attention(
     Sk = S if ring else min(skey, S) if skey else S
     assert not ring or paged is None, "a ring is never paged"
     neg = jnp.float32(-1e30)
-    slots = jnp.asarray(slots, dtype=jnp.int32)
+    in_order = slots is None
+    slots = jnp.arange(A, dtype=jnp.int32) if in_order else jnp.asarray(slots, dtype=jnp.int32)
     starts = jnp.asarray(starts, dtype=jnp.int32)
+    assert not in_order or (A == B and paged is None and not ring), (A, B)
 
     # Block-indirect past reads: gather each slot's PAST rows through its
     # block table (shared prefix blocks resolve to pool rows) instead of a
@@ -954,6 +1053,11 @@ def _chunk_attention(
     self_mask = jnp.broadcast_to(
         (c_idx[None, :] <= c_idx[:, None])[None], (A, C, C)
     )
+    if cfg.block_len:
+        # by block, on absolute positions; a padding key of a ragged chunk
+        # must not be seen by the valid queries of its block
+        self_mask = _in_block(cfg, q_pos[:, :, None], q_pos[:, None, :]) & (
+            c_idx[None, None, :] < jnp.asarray(nvalid, jnp.int32)[:, None, None])
     if ring:
         # index j holds the last position before the chunk that wraps onto it
         # (negative: never written by this sequence), seen inside the window
@@ -999,15 +1103,24 @@ def _chunk_attention(
                         ptbl, nbs=nbs_full,
                     )[:, : 2 * Hkv, :Sk]  # [A, 2*Hkv, Sk]
                 else:
-                    pays = jnp.stack(
-                        [
-                            jax.lax.dynamic_slice(
-                                ck_all["q"], (li, slots[a], 0, 0, 0),
-                                (1, 1, 2 * Hkv // P, Sk, P * hd),
-                            )[0, 0]
-                            for a in range(A)
-                        ]
-                    )  # [A, 2*Hkv/P, Sk, P*hd] int8
+                    if in_order:
+                        # the whole batch in order: the layer's payload as it lies.
+                        # (The scales keep their row-by-row slices below: cut
+                        # whole out of the stack, the compiler re-laid ALL
+                        # layers' scales every layer of every pass, 43% of a
+                        # block round; PERF.md section 6, PR 59)
+                        pays = jax.lax.dynamic_slice(
+                            ck_all["q"], (li, 0, 0, 0, 0), (1, A, 2 * Hkv // P, Sk, P * hd))[0]
+                    else:
+                        pays = jnp.stack(
+                            [
+                                jax.lax.dynamic_slice(
+                                    ck_all["q"], (li, slots[a], 0, 0, 0),
+                                    (1, 1, 2 * Hkv // P, Sk, P * hd),
+                                )[0, 0]
+                                for a in range(A)
+                            ]
+                        )  # [A, 2*Hkv/P, Sk, P*hd] int8
                     srows = jnp.stack(
                         [
                             jax.lax.dynamic_slice(
@@ -1029,6 +1142,9 @@ def _chunk_attention(
                     jax.lax.dynamic_index_in_dim(paged["v"], li, 0, keepdims=False),
                     ptbl, nbs=nbs_full,
                 )[:, :, :Sk]
+            elif in_order:
+                krows = jax.lax.dynamic_slice(ck_all, (li, 0, 0, 0, 0), (1, A, Hkv, Sk, hd))[0]
+                vrows = jax.lax.dynamic_slice(cv_all, (li, 0, 0, 0, 0), (1, A, Hkv, Sk, hd))[0]
             else:
                 krows = jnp.stack(
                     [
@@ -1081,7 +1197,15 @@ def _chunk_attention(
             h = _attn_residual(cfg, lp, ctx, h, x)
         return h, kh, vh
 
-    def write(ck_all, cv_all, kh, vh, li):
+    def write(ck_all, cv_all, kh, vh, li, keep=None):
+        def put(plane, rows, a, tail):
+            # row a's rows at its start; with `keep`, what stands there where false
+            new = rows[a][None, None].astype(plane.dtype)
+            at = (li, slots[a], 0, starts[a]) + (0,) * tail
+            if keep is not None:
+                new = jnp.where(keep[a], new, jax.lax.dynamic_slice(plane, at, new.shape))
+            return jax.lax.dynamic_update_slice(plane, new, at)
+
         with jax.named_scope("kv_append"):
             if ring:
                 # the chunk's row that index j holds after it; negative: an
@@ -1109,23 +1233,31 @@ def _chunk_attention(
                 # (K|V|packed scales) + plain scales, so later readers — decode
                 # kernels included — see a consistent fused entry
                 fused = fuse_prompt_kv(kh, vh, scale_dtype=ck_all["s"].dtype)
-                for a in range(A):
-                    ck_all = {
-                        "q": jax.lax.dynamic_update_slice(
-                            ck_all["q"], fused["q"][a][None, None], (li, slots[a], 0, starts[a], 0)
-                        ),
-                        "s": jax.lax.dynamic_update_slice(
-                            ck_all["s"], fused["s"][a][None, None], (li, slots[a], 0, starts[a])
-                        ),
-                    }
+                if in_order:
+                    # A block's few positions a row. The payload rows land row by
+                    # row in place; the plain scales as ONE update of the layer's
+                    # [A, 2 Hkv, S] (a megabyte read, a select a position, written back):
+                    # 64 updates of [2 Hkv, 4] made the compiler lay ALL layers'
+                    # scales out heads-minor and back, twice a layer, 120 ms of a
+                    # 276 ms round (chip trace, PERF.md section 6, PR 59)
+                    pay = ck_all["q"]
+                    for a in range(A):
+                        pay = put(pay, fused["q"], a, 1)
+                    cur = jax.lax.dynamic_slice(ck_all["s"], (li, 0, 0, 0), (1, A, 2 * Hkv, S))[0]
+                    off = jnp.arange(S, dtype=jnp.int32)[None, :] - starts[:, None]  # [A, S]
+                    for j in range(C):  # selects, not a gather: a gather of [A, 2 Hkv, S] took 6.7 ms a layer
+                        at_j = off == j if keep is None else (off == j) & keep[:, None]
+                        cur = jnp.where(at_j[:, None, :], fused["s"][:, :, j : j + 1], cur)
+                    ck_all = {"q": pay, "s": jax.lax.dynamic_update_slice(
+                        ck_all["s"], cur[None], (li, 0, 0, 0))}
+                else:
+                    for a in range(A):
+                        ck_all = {"q": put(ck_all["q"], fused["q"], a, 1),
+                                  "s": put(ck_all["s"], fused["s"], a, 0)}
             else:
                 for a in range(A):
-                    ck_all = jax.lax.dynamic_update_slice(
-                        ck_all, kh[a][None, None].astype(ck_all.dtype), (li, slots[a], 0, starts[a], 0)
-                    )
-                    cv_all = jax.lax.dynamic_update_slice(
-                        cv_all, vh[a][None, None].astype(cv_all.dtype), (li, slots[a], 0, starts[a], 0)
-                    )
+                    ck_all = put(ck_all, kh, a, 1)
+                    cv_all = put(cv_all, vh, a, 1)
         return ck_all, cv_all
 
     return h, attend, write
@@ -1215,23 +1347,24 @@ def llama_prefill_chunk_batch(
     c_idx = jnp.arange(C, dtype=jnp.int32)
     h, attend, write = _chunk_attention(
         cfg, params, cache_k, tokens, slots, starts, nvalid, skey=skey, paged=paged)
+    banks, stack = expert_stack(cfg, params["layers"])
+    pair_v, cache_v = cache_v, _rows(cache_v)
 
     def layer(carry, xs):
         lp, win = xs
         h, ck_all, cv_all, li = carry
         h, kh, vh = attend(h, ck_all, cv_all, li, lp, win)
-        h = _ffn_residual(
-            cfg, lp, h, moe_valid=c_idx[None, :] < nvalid[:, None]
-        )
+        h, counts = _ffn(cfg, lp, banks, li, h, valid=c_idx[None, :] < nvalid[:, None])
         # ---- writes last: in-place (write-after-read) ----
         ck_all, cv_all = write(ck_all, cv_all, kh, vh, li)
-        return (h, ck_all, cv_all, li + 1), None
+        return (h, ck_all, cv_all, li + 1), counts
 
-    (h, new_k, new_v, _), _ = jax.lax.scan(
+    (h, new_k, new_v, _), counts = jax.lax.scan(
         layer,
         (h, cache_k, cache_v, jnp.int32(0)),
-        (params["layers"], layer_windows(cfg)),
+        (stack, layer_windows(cfg)),
     )
+    new_v = _second(pair_v, new_v, 1, counts)
     if all_logits:
         return _logits(cfg, params, h), new_k, new_v  # [A, C, V]
     last = jnp.take_along_axis(
@@ -1265,6 +1398,115 @@ def llama_prefill_chunk(
         skey=skey,
         paged=paged,
     )
+
+
+def block_pass(
+    cfg: ModelConfig,
+    params: Params,
+    cache_k: Any,
+    cache_v: Any,
+    tokens: jnp.ndarray,  # [A, L] int32: a block a row (masks, fixed and unmasked tokens)
+    slots: jnp.ndarray | None,  # [A] int32 cache rows; None: row a is cache row a
+    starts: jnp.ndarray,  # [A] int32: each block's first position, a multiple of L
+    live: jnp.ndarray,  # [A] bool: rows that hold a sequence (a parked row routes nothing)
+    commit: bool,  # STATIC: write the block's keys and values, return no logits
+    skey: int = 0,  # STATIC bound on the past key range (0 = the whole cache row)
+) -> tuple[jnp.ndarray | None, Any, Any]:
+    """One pass of a block round (`cfg.block_len`; executor/engine.py:
+    block_round_fn) over the bucketed chunk's machinery: the L positions of
+    each row's block against the cache of every earlier block and, whole,
+    against each other. A DENOISING pass (`commit` False) writes nothing (the
+    layer scan does not even carry the cache) and returns the logits at every
+    position [A, L, V], UNSHIFTED: the row at a position that holds the mask is
+    the distribution of that position's own token. The COMMIT pass runs the
+    block's final tokens, writes their keys and values at [start, start + L) of
+    each live row and returns no logits (the head is not run). Either way the
+    second member's expert counts, where it carries them, take the pass's under
+    phase 0."""
+    A, L = tokens.shape
+    nvalid = jnp.full((A,), L, jnp.int32)
+    h, attend, write = _chunk_attention(cfg, params, cache_k, tokens, slots, starts, nvalid, skey=skey)
+    banks, stack = expert_stack(cfg, params["layers"])
+    rows_v = _rows(cache_v)
+    valid = jnp.broadcast_to(live[:, None], (A, L))
+    xs = (stack, layer_windows(cfg))
+
+    if not commit:
+        def layer(carry, xs):
+            lp, win = xs
+            h, li = carry
+            h, _, _ = attend(h, cache_k, rows_v, li, lp, win)
+            h, counts = _ffn(cfg, lp, banks, li, h, valid=valid)
+            return (h, li + 1), counts
+
+        (h, _), counts = jax.lax.scan(layer, (h, jnp.int32(0)), xs)
+        return _logits(cfg, params, h), cache_k, _second(cache_v, rows_v, 0, counts)
+
+    def commit_layer(carry, xs):
+        lp, win = xs
+        h, ck_all, cv_all, li = carry
+        h, kh, vh = attend(h, ck_all, cv_all, li, lp, win)
+        h, counts = _ffn(cfg, lp, banks, li, h, valid=valid)
+        ck_all, cv_all = write(ck_all, cv_all, kh, vh, li, keep=live)
+        return (h, ck_all, cv_all, li + 1), counts
+
+    (_, new_k, new_v, _), counts = jax.lax.scan(
+        commit_layer, (h, cache_k, rows_v, jnp.int32(0)), xs)
+    return None, new_k, _second(cache_v, new_v, 0, counts)
+
+
+def block_unmask(
+    cfg: ModelConfig,
+    tokens: jnp.ndarray,  # [A, L] int32 before the pass
+    x0: jnp.ndarray,  # [A, L] int32: the pass's sample at every position
+    p: jnp.ndarray,  # [A, L] float32: each sample's probability after the request's filters
+) -> jnp.ndarray:
+    """A denoising pass's unmask rule: the block with the positions due this
+    pass filled from `x0`. Of a row's positions that still hold the mask, n =
+    L / denoise_steps are due: the n of highest `p` (`low_confidence_static`);
+    under `low_confidence_dynamic` every one whose `p` passes the threshold
+    where at least n do, else those n. A position that holds a token keeps it;
+    a row with fewer than n masks left fills them all."""
+    L = tokens.shape[1]
+    due = L // cfg.denoise_steps
+    masked = tokens == cfg.mask_token_id
+    conf = jnp.where(masked, p, -jnp.inf)
+    # a position's rank by confidence among its row's (ties: the earlier first)
+    rank = jnp.argsort(jnp.argsort(-conf, axis=1, stable=True), axis=1, stable=True)
+    take = masked & (rank < due)
+    if cfg.unmask_rule == "low_confidence_dynamic":
+        high = masked & (p > cfg.unmask_threshold)
+        take = jnp.where(jnp.sum(high, axis=1, keepdims=True) >= due, high, take)
+    return jnp.where(take, x0, tokens)
+
+
+def block_denoise(
+    cfg: ModelConfig, params: Params, cache_k: Any, cache_v: Any,
+    tokens: jnp.ndarray, slots, starts, live,
+    key: jax.Array, temp, topk, topp,  # the pass's draw; [A] sampling parameters a row
+    allowed: jnp.ndarray | None = None,  # [V] bool: ids the sampler may emit
+    skey: int = 0,
+) -> tuple[jnp.ndarray, Any, jnp.ndarray]:
+    """One denoising pass of a block round and its unmask rule: (the block
+    after the pass [A, L], the second member with the pass's expert counts,
+    the pass's logits [A, L, V]). Every position is sampled with its row's
+    parameters (`ops/sampling.py:sample_tokens_p`: the token AND its
+    probability after temperature, top-k and top-p; greedy is top-1 at
+    probability 1, so a greedy row fills its whole block in its first pass
+    under the dynamic rule); only the due masked ones are kept."""
+    from ..ops.sampling import sample_tokens_p
+
+    A, L = tokens.shape
+    with jax.named_scope("block.denoise"):
+        logits, _, cache_v = block_pass(
+            cfg, params, cache_k, cache_v, tokens, slots, starts, live, commit=False, skey=skey)
+    with jax.named_scope("block.unmask"):
+        lg = logits if allowed is None else jnp.where(allowed, logits, -jnp.inf)
+        x0, p = sample_tokens_p(
+            lg.reshape(A * L, -1), key, jnp.repeat(temp, L), jnp.repeat(topk, L),
+            jnp.repeat(topp, L), active=jnp.repeat(live, L))
+        new = block_unmask(cfg, tokens, x0.reshape(A, L), p.reshape(A, L))
+    return new, cache_v, logits
 
 
 def ragged_write_rows(

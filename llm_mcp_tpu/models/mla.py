@@ -79,17 +79,19 @@ from ..kernels.attention import (
 from ..ops.norms import rms_norm as _rms_norm
 from ..ops.rope import apply_rope, rope_tables
 from .configs import ModelConfig
-from .hybrid import BANKS, _ffn as _share_ffn
-from .moe import share_form
+from .moe import expert_stack, share_form
 from .quant import qdot
 
 # llama.py imports this module only lazily inside its dispatch functions, so
 # pulling the shared decoder helpers in at module level is cycle-free
 from .llama import (
     _embed_in,
+    _ffn as _share_ffn,
     _ffn_residual,
     _logits,
     _norm,
+    _rows,
+    _second,
     quantize_kv,
     ragged_write_rows,
 )
@@ -273,16 +275,6 @@ def _queries(cfg: ModelConfig, lp: Params, x: jnp.ndarray):
     return q[..., :dn], q[..., dn:]
 
 
-def _expert_stack(cfg: ModelConfig, layers: Params) -> tuple[Params | None, Params]:
-    """(banks, rest) of the expert layers' stacked tree. In the share form the
-    banks go to `moe_share_ffn` whole with the layer's index and the layer scan
-    slices the rest alone (a slice of a stack that feeds a grouped kernel is
-    copied out every step: moe.moe_share_ffn); otherwise (None, everything)."""
-    if not share_form(cfg):
-        return None, layers
-    return ({n: layers[n] for n in BANKS}, {n: v for n, v in layers.items() if n not in BANKS})
-
-
 def _n_dense(params: Params) -> int:
     return params["dense_layers"]["attn_norm"].shape[0] if "dense_layers" in params else 0
 
@@ -294,21 +286,6 @@ def _ffn(cfg: ModelConfig, lp: Params, banks: Params | None, le, h, valid=None, 
     if banks is not None and "router" in lp:
         return _share_ffn(cfg, lp, banks, le, h, valid)
     return _ffn_residual(cfg, lp, h, moe_capacity=capacity, moe_valid=valid), None
-
-
-def _rows(cache_r: Any) -> Any:
-    """The rope keys of the cache pair's second member: the member itself, or
-    its "v" where the expert counts ride beside them."""
-    return cache_r["v"] if isinstance(cache_r, dict) and "v" in cache_r else cache_r
-
-
-def _second(cache_r: Any, new_r: Any, phase: int, counts) -> Any:
-    """The pair's second member after a call: the new rope keys, and where the
-    member carries the expert counts, this call's [Le, 5] added onto the running
-    sums (decode steps under 0, prefills under 1)."""
-    if counts is None or not (isinstance(cache_r, dict) and "moe" in cache_r):
-        return new_r
-    return {"v": new_r, "moe": cache_r["moe"].at[phase].add(counts)}
 
 
 def _prefill_attn(cfg: ModelConfig, lp: Params, h, cos, sin, valid_k):
@@ -386,7 +363,7 @@ def mla_prefill(
     positions = jnp.arange(S, dtype=jnp.int32)[None, :]
     cos, sin = rope_tables(cfg, dr, positions)  # [1, S, dr/2]
     valid_k = jnp.arange(S, dtype=jnp.int32)[None, :] < lengths[:, None]  # [B, S]
-    banks, stack = _expert_stack(cfg, params["layers"])
+    banks, stack = expert_stack(cfg, params["layers"])
 
     def scan_layer(carry, lp):
         h, le = carry
@@ -458,7 +435,7 @@ def mla_prefill_chunk_batch(
     L, B, _, S, R = (cache_c["q"] if quantized else cache_c).shape
     Pr = S // cache_r["q"].shape[3] if quantized else 1  # positions abreast
     A, C = tokens.shape
-    banks, stack = _expert_stack(cfg, params["layers"])
+    banks, stack = expert_stack(cfg, params["layers"])
     k_dense = _n_dense(params)
     Sk = min(skey, S) if skey else S
     scale = mla_scale(cfg)
@@ -683,7 +660,7 @@ def mla_prefill_chunk_ragged(
     quantized = isinstance(cache_c, dict)
     L, B, _, S, R = (cache_c["q"] if quantized else cache_c).shape
     T = tokens.shape[0]
-    banks, stack = _expert_stack(cfg, params["layers"])
+    banks, stack = expert_stack(cfg, params["layers"])
     k_dense = _n_dense(params)
     Rn = slots.shape[0]
     scale = mla_scale(cfg)
@@ -809,7 +786,7 @@ def mla_decode_step(
     quantized = isinstance(cache_c, dict)
     L, B, _, S, R = (cache_c["q"] if quantized else cache_c).shape
     Ba = tokens.shape[0]
-    banks, stack = _expert_stack(cfg, params["layers"])
+    banks, stack = expert_stack(cfg, params["layers"])
     k_dense = _n_dense(params)
     # in the share form a parked or padding row routes nothing and counts nothing
     live = lengths < S if banks is not None else None
@@ -1022,7 +999,7 @@ def mtp_logits(cfg, params, mtp, h, next_tokens, lengths):
     x = qdot(jnp.concatenate([
         _norm(cfg, h, mtp["hnorm"]), _norm(cfg, _embed_in(cfg, params, next_tokens), mtp["enorm"]),
     ], axis=-1), mtp["eh_proj"])
-    banks, stack = _expert_stack(cfg, mtp["layers"])
+    banks, stack = expert_stack(cfg, mtp["layers"])
     lp = jax.tree.map(lambda a: a[0], stack)
     x, _, _ = _prefill_attn(cfg, lp, x, cos, sin, valid)
     x, _ = _ffn(cfg, lp, banks, 0, x, valid=valid)

@@ -1,10 +1,27 @@
-"""Mixture-of-Experts FFN with GShard-style capacity dispatch (TPU-first).
+"""Mixture-of-Experts feed-forward layers, in two forms (TPU-first).
+
+Which form a configuration's expert layer takes follows from what its preset
+states, with no switch (`share_form`):
+
+  - **`moe_share_ffn`: the dropless share** of ONE member of an expert-parallel
+    group. The decoder of unlike layers (models/hybrid.py) has no other form;
+    the latent-attention family (models/mla.py) and the dense family
+    (models/llama.py: whole-prompt prefill, bucketed chunk and a block round's
+    passes) take it where the preset states a share of the published experts
+    (`n_router_experts`) or a sigmoid router. Every (row, chosen expert) pair
+    that lands on a held expert is kept, sorted in front and multiplied by the
+    repo's grouped kernels (kernels/grouped.py) over a window of them; the
+    banks go in STACKED over the layers and the layer's work is counted
+    (`ExpertCounts`, `perf_stats()["experts"]`).
+  - **`moe_ffn`: GShard-style capacity dispatch**, for the presets that state
+    no share (Mixtral-class models in the dense family, DeepSeek-V2's softmax
+    presets in the latent family), below.
 
 The reference has no MoE (no model execution at all — Ollama serves Mixtral
 et al. as opaque names in the catalog, `discovery.go:526-551`). Here MoE is a
 real sharded subsystem so Mixtral-class models run in-process.
 
-TPU-first design choices:
+TPU-first design choices of the capacity dispatch:
 
   - **Dense dispatch via one-hot matmuls** (Switch/GShard formulation): the
     token→expert routing is expressed as two einsums against a [T, E, C]
@@ -42,6 +59,19 @@ def share_form(cfg: ModelConfig) -> bool:
     of which `moe_ffn` computes. From what the preset states, no switch. (The
     decoder of unlike layers, models/hybrid.py, has no other form.)"""
     return bool(cfg.n_experts and (cfg.n_router_experts or cfg.router_score == "sigmoid"))
+
+
+BANKS = ("w1e", "w3e", "w2e")  # never sliced by layer: moe_share_ffn says why
+
+
+def expert_stack(cfg: ModelConfig, layers: dict[str, Any]) -> tuple[dict | None, dict]:
+    """(banks, rest) of the stacked layers. In the share form the expert banks
+    go to `moe_share_ffn` whole with the layer's index and a layer scan slices
+    the rest alone (a slice of a stack that feeds a grouped kernel is copied out
+    every step: `moe_share_ffn`); else (None, everything)."""
+    if not share_form(cfg):
+        return None, layers
+    return ({n: layers[n] for n in BANKS}, {n: v for n, v in layers.items() if n not in BANKS})
 
 
 def expert_capacity(cfg: ModelConfig, n_tokens: int) -> int:

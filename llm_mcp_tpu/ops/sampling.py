@@ -117,7 +117,8 @@ def _sample_windowed(
     top_p: jnp.ndarray,
     n_cand: int,
     exact: bool = False,
-) -> jnp.ndarray:
+    with_p: bool = False,  # static: (tokens, each one's probability among what the filters left)
+) -> jnp.ndarray | tuple[jnp.ndarray, jnp.ndarray]:
     B, V = logits.shape
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
@@ -153,7 +154,55 @@ def _sample_windowed(
     choice = jnp.argmax(final + gumbel, axis=-1)  # [B]
     sampled = jnp.take_along_axis(cand_idx, choice[:, None], axis=1)[:, 0].astype(jnp.int32)
 
-    return jnp.where(temperature <= 0.0, greedy, sampled)
+    tokens = jnp.where(temperature <= 0.0, greedy, sampled)
+    if not with_p:
+        return tokens
+    p = jnp.take_along_axis(jax.nn.softmax(final, axis=-1), choice[:, None], axis=1)[:, 0]
+    return tokens, jnp.where(temperature <= 0.0, 1.0, p)
+
+
+def sample_tokens_p(
+    logits: jnp.ndarray,  # [B, V] float32
+    rng: jax.Array,
+    temperature: jnp.ndarray,  # [B]
+    top_k: jnp.ndarray,  # [B] int32 (0 = disabled)
+    top_p: jnp.ndarray,  # [B] float32 (1.0 = disabled)
+    active: jnp.ndarray | None = None,  # [B] bool: rows whose sample matters
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """`sample_tokens` that also says how sure it was: (tokens [B] int32, p [B]
+    float32), p the probability of the sampled token under the distribution it
+    was drawn from, AFTER temperature, top-k and top-p (what a diffusion
+    sampler's confidence rule compares; executor/engine.py:block_round_fn). A
+    greedy row (temperature <= 0) is top-1: its token is the argmax and p is
+    1.0. The same three runtime paths as `sample_tokens`, and the same draws:
+    with one key the tokens are `sample_tokens`' own."""
+    B, V = logits.shape
+    n_cand = min(_CANDIDATES, V)
+
+    def _pred(cond: jnp.ndarray) -> jnp.ndarray:
+        return jnp.all(jnp.where(active, cond, True) if active is not None else cond)
+
+    def _all_greedy(_):
+        return jnp.argmax(logits, axis=-1).astype(jnp.int32), jnp.ones((B,), jnp.float32)
+
+    def _plain_temp(_):
+        scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
+        g = jax.random.gumbel(rng, (B, V), dtype=jnp.float32)
+        tok = jnp.argmax(scaled + g, axis=-1).astype(jnp.int32)
+        top = jnp.take_along_axis(scaled, tok[:, None], axis=1)[:, 0]
+        return tok, jnp.exp(top - jax.nn.logsumexp(scaled, axis=-1))
+
+    def _windowed(_):
+        return _sample_windowed(
+            logits, rng, temperature, top_k, top_p, n_cand, with_p=True)
+
+    plain = _pred((top_k <= 0) & (top_p >= 1.0) & (temperature > 0.0))
+    return jax.lax.cond(
+        _pred(temperature <= 0.0),
+        _all_greedy,
+        lambda _: jax.lax.cond(plain, _plain_temp, _windowed, None),
+        None,
+    )
 
 
 def spec_verify(

@@ -72,6 +72,7 @@ from typing import Any
 __all__ = [
     "AUX_COMPILE_PHASES",
     "AdmitAccount",
+    "BlockAccount",
     "CACHE_LAYOUTS",
     "DISPATCH_PHASES",
     "ModelShape",
@@ -467,6 +468,17 @@ class AdmitAccount:
 # longer than this (and than twice the retiring program's mean told device
 # seconds) is a stall: a round is 36-122 ms in every cell, and 200 ms is the
 # threshold builders' scripts have walked the ring with since PR 38.
+# A device trace names a program after its traced function (`jit_<name>`). The
+# engine's PLAIN round has ONE name in a trace whatever the configuration's
+# round is made of: `decode_chunk` one-token steps (`engine.decode_chunk_fn`)
+# or, for a configuration that generates by diffusion over blocks, a block's
+# denoising passes and its commit (`engine.block_round_fn`, which takes this
+# name where it is jitted). Whoever reads "the plain round" off a trace reads
+# `jit_` + this; `perf_stats()["rounds"]["by_program"]` tells the two apart
+# (`plain`, `block`), as the ring does (`decode`, `block`).
+# doc/observability.md, "The plain round's name in a trace".
+PLAIN_ROUND_TRACE_NAME = "decode_chunk_fn"
+
 STALL_S = 0.2
 STALL_ROWS = 16  # the newest stalls kept whole
 
@@ -629,6 +641,58 @@ class RoundAccount:
             }
 
 
+class BlockAccount:
+    """The block rounds of a configuration that generates by diffusion over
+    blocks (`cfg.block_len`; engine.block_round_fn), in sums since boot
+    (`perf_stats()["blocks"]`): a reader takes the difference over its window.
+    Written by the engine's thread alone, read from any.
+
+    `fetched` books a round at its fetch: `rounds`, `rows` (a row is one
+    sequence's block), `passes` (the denoising passes the round's program ran:
+    its loop ends when no live row holds a mask, so the most any row took),
+    `commits` (one a round), `unmasked` (positions a denoising pass filled,
+    over the rows), `remainder_tokens` (positions a first block held fixed: the
+    prompt's last P mod L tokens) and `by_passes` {passes: blocks that took so
+    many}. `delivered` books what the round's emission handed on. `off` counts
+    the times each feature such a configuration runs without would have
+    engaged (`memory.BLOCK_OFF`, the one list)."""
+
+    SUMS = ("rounds", "rows", "passes", "commits", "unmasked", "remainder_tokens", "delivered")
+
+    def __init__(self, lock: threading.Lock, off: Any) -> None:
+        self._lock = lock
+        self._sums = dict.fromkeys(self.SUMS, 0)
+        self._by_passes: dict[int, int] = {}
+        self.off = dict.fromkeys(off, 0)
+
+    def fetched(self, row_passes: list[int], unmasked: int, remainder: int) -> None:
+        """One round: each live row's denoising passes, the positions they
+        filled and the positions that were the prompt's."""
+        with self._lock:
+            s = self._sums
+            s["rounds"] += 1
+            s["rows"] += len(row_passes)
+            s["passes"] += max(row_passes, default=0)
+            s["commits"] += 1
+            s["unmasked"] += unmasked
+            s["remainder_tokens"] += remainder
+            for n in row_passes:
+                self._by_passes[n] = self._by_passes.get(n, 0) + 1
+
+    def delivered(self, tokens: int) -> None:
+        with self._lock:
+            self._sums["delivered"] += tokens
+
+    def note_off(self, feature: str) -> None:
+        self.off[feature] += 1
+
+    def stats(self) -> dict[str, Any]:
+        with self._lock:
+            return {**self._sums,
+                    "by_passes": {str(k): v for k, v in sorted(self._by_passes.items())},
+                    "off": dict(self.off)}
+
+
 class PerfObservatory:
     """Per-process-engine perf state: ITL window, goodput ledger, sampled
     phase attribution, and the roofline evaluation. All writers are the
@@ -703,9 +767,18 @@ class PerfObservatory:
         # and tokens of the SAME rounds, the roofline's measured token rate
         # (observe_device)
         self.rounds = RoundAccount(self._lock)
+        # the block rounds' own book, for a configuration that has them
+        # (`count_blocks`): None for every other
+        self.blocks: BlockAccount | None = None
         # live decode-shape EMAs feeding the roofline (mean context, rows)
         self._ctx_ema = 0.0
         self._rows_ema = 0.0
+
+    def count_blocks(self, off: Any) -> BlockAccount:
+        """Open the block rounds' book (once, where the engine is built):
+        `stats()["blocks"]` from here on; `off` the features to count."""
+        self.blocks = BlockAccount(self._lock, off)
+        return self.blocks
 
     # -- sampling cadence --------------------------------------------------
 
@@ -1063,4 +1136,5 @@ class PerfObservatory:
             "phases": self.phase_attribution(),
             "roofline": self.roofline(),
             "rounds": self.rounds.stats(),
+            **({} if self.blocks is None else {"blocks": self.blocks.stats()}),
         }

@@ -40,6 +40,8 @@ import json
 import os
 import statistics
 import sys
+import time
+import types
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
@@ -88,6 +90,9 @@ def main() -> int:
     ap.add_argument("--first-seed", type=int, default=3200006000)
     ap.add_argument("--prompt-bytes", type=int, default=0,
                     help="the prompt's bytes instead of the configuration's reference_request")
+    ap.add_argument("--tokens", type=int, default=0,
+                    help="the tokens served instead of the configuration's reference_request")
+    ap.add_argument("--only", default="", help="of the module's controls, these alone (comma-separated)")
     ap.add_argument("--ride", action="store_true",
                     help="serve beside decoding rows, so that the prompt rides a decode round")
     args = ap.parse_args()
@@ -108,18 +113,20 @@ def main() -> int:
 
     from llm_mcp_tpu.executor import GenerationEngine
     from llm_mcp_tpu.utils import config as ucfg
+    from llm_mcp_tpu.utils.tokens import messages_to_prompt
 
     ucfg.enable_compile_cache()
     name, module = bench_run.load_reference(config)
     controls = tuple(getattr(module, "CONTROLS", CONTROLS))
+    if args.only:
+        controls = tuple(c for c in controls if c in args.only.split(","))
     gen = GenerationEngine(
         env["TPU_MODEL"], max_slots=int(env["TPU_MAX_SLOTS"]), max_seq_len=int(env["TPU_MAX_SEQ_LEN"]),
         dtype=jnp.bfloat16, kv_quant=env["TPU_KV_QUANT"], seed=int(config.get("weights_seed", 0)),
         **({"prefill_chunk": int(env["TPU_PREFILL_CHUNK"])} if "TPU_PREFILL_CHUNK" in env else {}),
     ).start()
-    if args.prompt_bytes:
-        config = dict(config, reference_request=dict(
-            config.get("reference_request", {}), prompt_bytes=args.prompt_bytes))
+    asked = {k: v for k, v in (("prompt_bytes", args.prompt_bytes), ("tokens", args.tokens)) if v}
+    config = dict(config, reference_request=dict(config.get("reference_request", {}), **asked))
     n_bytes, n_tokens = correctness.reference_request(config, gen.max_seq_len)
     mask = gen._allowed_mask
     allowed = np.arange(gen.cfg.vocab_size) if mask is None else np.flatnonzero(np.asarray(mask))
@@ -127,7 +134,6 @@ def main() -> int:
     busy: dict = {}
     if args.ride:
         import threading
-        import time
 
         stop = threading.Event()
         rows = gen.max_slots * 3 // 4
@@ -147,44 +153,56 @@ def main() -> int:
 
         gen._process_token = tap
         try:
-            gen.generate(trafficgen.text(n_bytes, seed, "ref"), max_tokens=n_tokens, temperature=0.0)
+            # the prompt as `/v1/chat/completions` renders the harness's one user message
+            gen.generate(messages_to_prompt([{"role": "user", "content": trafficgen.text(n_bytes, seed, "ref")}]),
+                         max_tokens=n_tokens, temperature=0.0)
         finally:
             del gen._process_token
         return got["ids"], got["out"]
 
-    def held(ids, out) -> tuple[float, str]:
-        """(worst regret, "") where the comparison passes; where it refuses,
-        its message and the worst regret by the same formula over all tokens
-        (the comparison stops at the first token over the limit)."""
+    def held(ids, out) -> tuple[float, str, int]:
+        """(worst regret, "", tokens over the limit) where the comparison
+        passes; where it refuses, its message and the worst regret by the same
+        formula over all tokens (the comparison stops at the first token over
+        the limit). The reference's rows are computed once and handed to the
+        comparison as they are."""
+        kept = {}
+
+        def once(*a):
+            t0 = time.monotonic()
+            kept["ref"] = module.logits(*a)
+            spent.append(time.monotonic() - t0)
+            return kept["ref"]
+
+        shim = types.SimpleNamespace(SERVED_TOL_REL=module.SERVED_TOL_REL, logits=once)
         try:
-            return correctness.hold_to_reference(module, gen, ids, out)["worst_regret_rel"], ""
+            why = ""
+            correctness.hold_to_reference(shim, gen, ids, out)
         except AssertionError as e:
-            seq = ids + out[:-1]
-            rows = np.arange(len(ids) - 1, len(seq))
-            seq = np.asarray(seq + [0] * (-len(seq) % correctness.PAD_TO), np.int32)
-            ref = module.logits(gen.cfg, gen.params, seq, rows, allowed)
-            worst = max(float((np.max(r) - r[np.flatnonzero(allowed == t)[0]]) / np.max(np.abs(r)))
-                        for r, t in zip(ref, out))
-            return worst, str(e)
+            why = str(e)
+        rel = [float((np.max(r) - r[np.flatnonzero(allowed == t)[0]]) / np.max(np.abs(r)))
+               for r, t in zip(kept["ref"], out)]
+        return max(rel), why, sum(x > module.SERVED_TOL_REL for x in rel)
 
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
-    lines = []
+    lines, spent = [], []  # spent: seconds of each call of the reference's logits
     served = [serve(args.first_seed + i) for i in range(args.seeds)]
     admit = gen.perf_stats()["admit"]
     if args.ride:
         stop.set()
     for i, (ids, out) in enumerate(served):
-        value, why = held(ids, out)
-        lines.append({"seed": args.first_seed + i, "prompt_tokens": len(ids), "program": value,
-                      "program_refused": why})
+        value, why, over = held(ids, out)
+        lines.append({"seed": args.first_seed + i, "prompt_tokens": len(ids), "served": len(out),
+                      "distinct": len(set(out)), "program": value, "program_refused": why})
         print(json.dumps(lines[-1]), flush=True)
     for lower in controls:
         module.LOWER = lower
         jax.clear_caches()
         for i, (ids, out) in enumerate(served[: args.controls]):
-            value, why = held(ids, out)
+            value, why, over = held(ids, out)
             lines[i][lower], lines[i][lower + "_refused"] = value, why
-            print(json.dumps({"seed": lines[i]["seed"], lower: value, "refused": why}), flush=True)
+            print(json.dumps({"seed": lines[i]["seed"], lower: value, "tokens_over": over,
+                              "refused": why}), flush=True)
     module.LOWER = None
     jax.clear_caches()
     gen.shutdown()
@@ -197,11 +215,12 @@ def main() -> int:
 
     result = {"tolerance": float(module.SERVED_TOL_REL), "reference": name,
               "request": {"prompt_bytes": n_bytes, "tokens": n_tokens},
+              "reference_s": {"first": spent[0], "median": statistics.median(spent)},
               "admit_by_shape": dict(admit["by_shape"]),
               "admit": {"rides": admit["rides"], "own_prompts": admit["own_prompts"]},
               **{key: summary(key) for key in ("program", *controls)}}
     print("SUMMARY", json.dumps(result), flush=True)
-    with open(os.path.join(ROOT, "chiprun_out", f"{name}_tolerance.json"), "w") as f:
+    with open(os.path.join(ROOT, "chiprun_out", f"{name}_tolerance_{n_bytes}_{n_tokens}.json"), "w") as f:
         json.dump({"summary": result, "seeds": lines}, f)
     return 0 if result["program"]["not_correct"] == 0 else 1
 

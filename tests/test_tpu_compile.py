@@ -1068,3 +1068,111 @@ def test_joyai_step_programs_fit_with_the_banks_whole_beside_the_latent_cache(
         assert "bf16[39,1536,6144]" not in "".join(
             line for line in text.splitlines() if " copy(" in line)  # `w_uq` read in place
     assert mem.alias_size_in_bytes > 0.99 * latent  # the latent pair updated in place
+
+
+# -- generation by diffusion over blocks: the block round and the admit programs ----
+
+
+@pytest.fixture(scope="module")
+def sdar(one_chip):
+    """`sdar-30b-a3b-ep8`, whole depth, 64 slots x 1024, as its cell boots it."""
+    return hybrid_shapes("sdar-30b-a3b-ep8", one_chip, SOLAR_SLOTS, SOLAR_S)
+
+
+def sdar_program(which: str, cfg):
+    """The block configuration's step programs as the engine builds them: the
+    block round (`engine.block_round_fn`: a while loop of denoising passes over
+    the whole batch in order, the unmask rule with the sampler, the commit
+    pass), the admit program with the counted dense pair's row inserts, and the
+    bucketed chunk (`solar_program`'s)."""
+    from llm_mcp_tpu.executor.engine import _put_rows
+    from llm_mcp_tpu.models import hybrid, llama
+
+    def block(params, ck, cv, first, starts, counter):
+        live = starts < ck["q"].shape[3]
+        temp = jnp.full(starts.shape, 0.7, jnp.float32)
+        topk, topp = jnp.zeros(starts.shape, I32), jnp.ones(starts.shape, jnp.float32)
+
+        def denoise(carry):
+            tokens, passes, rng, moe, n = carry
+            rng, sub = jax.random.split(rng)
+            new, cv_p, _ = llama.block_denoise(
+                cfg, params, ck, dict(cv, moe=moe), tokens, None, starts, live, sub, temp, topk, topp)
+            return new, passes + jnp.any(tokens == cfg.mask_token_id, axis=1), rng, cv_p["moe"], n + 1
+
+        tokens, passes, _, moe, _ = jax.lax.while_loop(
+            lambda c: jnp.any((c[0] == cfg.mask_token_id) & live[:, None]) & (c[4] < cfg.denoise_steps),
+            denoise,
+            (first, jnp.zeros(starts.shape, I32), jax.random.fold_in(jax.random.PRNGKey(1), counter[0]),
+             cv["moe"], jnp.int32(0)))
+        _, ck, cv = llama.block_pass(
+            cfg, params, ck, dict(cv, moe=moe), tokens, None, starts, live, commit=True)
+        return jnp.concatenate([tokens.T, passes[None]]), ck, cv
+
+    def admit(params, ck, cv, tokens, lengths, slots):
+        logits, ks, vs = llama.llama_prefill(
+            cfg, params, tokens, lengths, attn_impl="pallas", quant_kv=True)
+
+        def body(i, cc):
+            ck, cv = cc
+            ck = jax.tree.map(
+                lambda c, r: _put_rows(c, jax.lax.dynamic_slice_in_dim(r, i, 1, 1), slots[i], 0), ck, ks)
+            return ck, cv
+
+        ck, cv = jax.lax.fori_loop(0, tokens.shape[0], body, (ck, cv))
+        return logits, ck, hybrid.add_counts(cv, vs)
+
+    return {"block": block, "admit": admit}.get(which) or solar_program(which, cfg)
+
+
+@pytest.mark.parametrize("which,operands", [
+    ("block", [(64, 4), (64,), (1,)]),  # every slot a row: 256 rows a pass
+    ("admit", [(4, 128), (4,), (4,)]),  # the cell's largest admit program, 512 padded tokens
+    ("admit", [(1, 64), (1,), (1,)]),  # and its smallest
+    ("chunk", [(2, 512), (2,), (2,), (2,)]),  # two prompts' second chunks of 512
+])
+def test_sdar_step_programs_fit_with_the_banks_whole_beside_the_fused_cache(
+    sd, sdar, chip_kernels, which, operands
+):
+    """The block round of 64 rows (256 rows a pass), the admit programs the
+    traffic meets and a bucketed chunk of `sdar-30b-a3b-ep8` (the published
+    widths, all 48 layers, 16 of 128 experts of 2048 x 768 a layer, the whole
+    vocabulary) at its cell's 64 slots x 1024 compile for the described v5e. The
+    two grouped expert kernels in every program, the prompt kernel in the admit
+    programs, every one a Mosaic call with no fall to its reference. Each fits
+    the chip beside 10.33 GB of weights and the 3.67 GB fused int8 cache; the
+    temporaries hold no copy of a layer's banks (151 MB a layer: the stack goes
+    in whole), and the cache is updated in place, neither copied nor re-laid:
+    a denoising pass does not even carry it. GiB in PERF.md section 4 as
+    "described-chip compile"."""
+    cfg, params, cache = sdar
+    falls = dict(A.reference_falls)
+    compiled = jax.jit(sdar_program(which, cfg), donate_argnums=(1, 2)).lower(
+        params, cache["k"], cache["v"], *(sd(shape, I32) for shape in operands)).compile()
+    assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
+    text = compiled.as_text()
+    assert grouped_kernels_in(text)
+    assert ("flash_prefill_attn" in text) == (which == "admit")
+    assert cache["k"]["q"].shape == (48, 64, 9, 1024, 128) and cache["v"]["v"] == {}
+    assert cache["v"]["moe"].shape == (2, 48, 5)
+    assert cache_relayouts(text, cache["k"]["q"].shape) == []
+    # nor of the plain scales beside it: cut out of the stack a layer's worth at
+    # a time, all 48 layers' were re-laid every layer of every pass (120 ms of a
+    # 276 ms round on the chip; PERF.md section 6, PR 59)
+    assert [ln for ln in text.splitlines() if "= bf16[48,64,8,1024]{" in ln and " copy(" in ln] == []
+    assert params["layers"]["w1e"].shape == (48, 16, 2048, 768)
+    nbytes = lambda tree: sum(  # noqa: E731
+        int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(tree))
+    weights, kv = nbytes(params), nbytes(cache) - cache["v"]["moe"].size * 4
+    assert weights == 2 * cfg.param_count() == 10_329_944_064
+    assert kv == 48 * 64 * 1024 * (9 * 128 + 8 * 2) == 3_674_210_304
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"sdar {which} {operands}: {total / 2**30:.2f} GiB, of it temporaries "
+          f"{mem.temp_size_in_bytes / 2**30:.3f} GiB, arguments "
+          f"{mem.argument_size_in_bytes / 2**30:.2f} GiB (weights {weights / 2**30:.2f}, "
+          f"KV cache {kv / 2**30:.2f} logical)")
+    assert total < 15.0 * 2**30
+    # no copy of a layer's banks: 0.14 GiB a layer would be 6.75 GiB a pass
+    assert mem.temp_size_in_bytes < 1.0 * 2**30
