@@ -50,6 +50,7 @@ from jax.profiler import TraceAnnotation
 
 from ..kernels.attention import (
     AttnStream,
+    BlockAttnStream,
     mla_stream_block,
     pallas_supported,
     ragged_prefill_max_tokens,
@@ -71,6 +72,8 @@ from ..models.llama import (
     llama_prefill_chunk_batch,
     llama_prefill_chunk_ragged,
     llama_decode_step,
+    _cache_shape,
+    block_attn_arm,
     block_denoise,
     block_pass,
     mixed_step_q8,
@@ -794,8 +797,8 @@ class GenerationEngine:
             if isinstance(self._cv, dict) and "moe" in self._cv else None)
         # what the blocked int8 decode-attention arm streams, where decode
         # rounds run it (int8 GQA cache read by the Pallas kernel): None else
-        # (and never for a block configuration: a block pass's attention is the
-        # bucketed chunk's, no decode arm runs)
+        # (and never for a block configuration: no decode arm runs, and what a
+        # block pass's attention streams is in perf_stats()["blocks"]["attn"])
         self._attn_stream = None
         if layout.fused and self.decode_impl == "pallas" and not self._block:
             self._attn_stream = AttnStream(self._ck["q"].shape, kv_heads=self.cfg.n_kv_heads)
@@ -1558,7 +1561,9 @@ class GenerationEngine:
         )
         # the block rounds' book and the counters of what such a configuration
         # runs without (perf_stats()["blocks"]): None for every other
-        self._block_book = self._perf.count_blocks(layout.without) if self._block else None
+        self._block_book = self._perf.count_blocks(layout.without, BlockAttnStream(
+            block_attn_arm(self.cfg, self._ck, self.decode_impl)[0],
+            _cache_shape(self._ck))) if self._block else None
         # Workload capture + latency waterfall (telemetry/workload.py).
         # The capture ring is process-shared (like the flight recorder) so
         # a fleet of engines streams one trace; the waterfall is per-engine
@@ -2359,7 +2364,7 @@ class GenerationEngine:
                 rng, sub = jax.random.split(rng)
                 new, cv_p, _ = block_denoise(
                     cfg, params, ck, dict(cv, moe=moe) if counted else cv, tokens,
-                    slot_ids, starts, live, sub, temp, topk, topp, allowed=mask)
+                    slot_ids, starts, live, sub, temp, topk, topp, allowed=mask, attn_impl=impl)
                 passes = passes + (jnp.any(tokens == MASK, axis=1) & live)
                 return new, passes, rng, cv_p["moe"] if counted else moe, n + 1
 
@@ -2370,7 +2375,7 @@ class GenerationEngine:
             with jax.named_scope("block.commit"):
                 _, ck, cv = block_pass(
                     cfg, params, ck, dict(cv, moe=moe) if counted else cv, tokens,
-                    slot_ids, starts, live, commit=True)
+                    slot_ids, starts, live, commit=True, attn_impl=impl)
             fresh = jnp.where(live[:, None], MASK, first)
             d_last = fresh if slot_ids is None else d_last.at[slot_ids].set(fresh)
             out = jnp.concatenate([tokens.T, passes[None]])  # [L + 1, Ba]
@@ -7135,7 +7140,8 @@ class GenerationEngine:
                      for b, s, _ in disp.entries]
             self._block_book.fetched(
                 [int(row_passes[col]) for _, _, col in disp.entries],
-                rows * self._block - sum(fixed), sum(fixed))
+                rows * self._block - sum(fixed), sum(fixed),
+                [int(disp.base[b]) for b, _, _ in disp.entries], out.shape[1])
         self._rid_fetched = max(self._rid_fetched, disp.rid)
         if self._cooling:
             # the fetch that ends a freed slot's fence stamps it: from here

@@ -1062,6 +1062,40 @@ class AttnStream:
                 **({"window": self.window, "ring_tokens": self.seq_len} if self.window else {})}
 
 
+class BlockAttnStream:
+    """Host-side book of what a block pass's attention streams
+    (`perf_stats()["blocks"]["attn"]`), as `AttnStream` is the decode arms':
+    over the passes of the rounds fetched, for ONE layer's call a pass, cache
+    positions fetched and positions live (each seated row's past, [0, start):
+    the block's own L keys come from registers). On the kernel's arm
+    (`block_attend_q8`, `arm` "pallas") a row streams its past in whole blocks
+    of `block_tokens` and a parked or padding row nothing; on the XLA arm
+    (`block_tokens` 0) every row of the batch streams its whole cache row."""
+
+    def __init__(self, arm: str, cache_q_shape: tuple[int, ...]):
+        _, _, rows, self.seq_len, row_lanes = cache_q_shape
+        self.arm = arm
+        self.block_tokens = q8_block_tokens(rows, self.seq_len, row_lanes) if arm == "pallas" else 0
+        self.passes = self.tokens_streamed = self.tokens_live = 0
+
+    def fetched(self, starts: list[int], batch_rows: int, passes: int) -> None:
+        """A round whose seated rows' blocks started at `starts`, in a batch of
+        `batch_rows` rows, ran `passes` passes (the denoising ones and the commit)."""
+        st = np.asarray(starts, np.int64)
+        st = st[st < self.seq_len]
+        self.passes += passes
+        self.tokens_live += passes * int(st.sum())
+        self.tokens_streamed += passes * (
+            int(block_row_blocks(st, self.seq_len, self.block_tokens, xp=np).sum()) * self.block_tokens
+            if self.block_tokens else batch_rows * self.seq_len)
+
+    def stats(self) -> dict:
+        return {"arm": self.arm, "block_tokens": self.block_tokens, "passes": self.passes,
+                "tokens_streamed": self.tokens_streamed, "tokens_live": self.tokens_live,
+                "live_over_streamed": round(self.tokens_live / self.tokens_streamed, 4)
+                if self.tokens_streamed else None}
+
+
 def kv_heads_abreast(n_kv_heads: int, head_dim: int) -> int:
     """P: KV heads that lie side by side in one row of the fused int8 cache.
     Where a head is narrower than the 128 lanes and divides them, as many as
@@ -1600,6 +1634,267 @@ def decode_attend_q8(
         == jnp.arange(n_slots * nbs, dtype=block_tables.dtype).reshape(n_slots, nbs)
     )
     return jax.lax.cond(ident, run_contig, run_paged)
+
+
+# ---------------------------------------------------------------------------
+# A block pass's attention over the fused int8 cache (`cfg.block_len`)
+# ---------------------------------------------------------------------------
+
+
+def block_row_blocks(starts, seq_len: int, block_s: int, xp=jnp):
+    """Blocks of `block_s` cache positions the block-attention arm streams for
+    a row whose block starts at `starts`: the past [0, start) rounded up to
+    whole blocks. None for a reply's first block at position 0, and none for a
+    parked row (start >= seq_len: its output is discarded, and its own L keys
+    give the softmax something to sum). `xp` as in `blocked_row_blocks`."""
+    return xp.where(starts >= seq_len, 0, (starts + block_s - 1) // block_s)
+
+
+def _block_attend_q8_kernel(
+    li_ref,  # [1] int32 (scalar prefetch) — layer index
+    ids_ref,  # [A] int32 (scalar prefetch) — cache row per batch row
+    starts_ref,  # [A] int32 (scalar prefetch) — each block's first position
+    cum_ref,  # [A + 1] int32 (scalar prefetch) — running sum of the rows'
+    #           block counts: row b's cells are cum[b] .. cum[b + 1] - 1
+    nxt_ref,  # [A + 1] int32 (scalar prefetch) — cache row of the first batch
+    #           row that has a cell ([0]) and of the next one after row b
+    #           ([b + 1]): a row without a past has none and is stepped over
+    q_ref,  # [1, L, R, P*G, W] VMEM — the block's L positions, each
+    #         `q_abreast`'s rows: R = Hkv / P cache rows of P heads abreast, a
+    #         head's G query heads in its own lanes, W = P*hd
+    ks_ref,  # [1, L, R, W] VMEM — the block's own keys (post-rope), abreast
+    vs_ref,  # [1, L, R, W] VMEM
+    pay_hbm,  # [Lyr, B, 2*R + p, S, W] int8 — fused K|V(|packed scales)
+    #           payload, stays in HBM (ANY), DMA'd per block
+    s_hbm,  # [Lyr, B, 2*Hkv, S] — plain scales (read only when packed=False)
+    o_ref,  # [1, L, R, P*G, W] VMEM out
+    pay_buf,  # VMEM scratch [2, Hh, BS, W] int8 (double buffer);
+    #           Hh = 2*R + 1 when packed else 2*R
+    s_buf,  # [2, 2*Hkv, BS] (unused when packed)
+    sems,  # DMA semaphores [2, 2]
+    *,
+    scale: float,
+    block_s: int,
+    packed: bool,
+    scale_dtype,
+):
+    """The attention of one row's BLOCK of L positions (`cfg.block_len`;
+    models/llama.py:block_pass): the L positions folded into the query-row
+    axis, M = P*L*G query rows a cache row (a head's L*G rows together, so
+    that `_scales_by_row` spreads the scales as for a decode step's), against
+    the row's past [0, start), streamed out of HBM in blocks as
+    `_attend_q8_blocked_kernel` streams a decode step's, and against the
+    block's own L keys, every one of them (inside a block the mask is whole).
+    The fold is made HERE, of float32 pieces of whole tiles (G = 8 rows), and
+    undone where the output is stored: the queries come and the contexts go as
+    the projections around the call have them, [L, heads, hd] a row, and no
+    transpose of either stands in the program.
+
+    The SELF segment comes first and from registers, exact: it starts the
+    online softmax, so a row with no past block (a reply's first block at
+    position 0, a parked row) runs no cell at all and still divides by a sum.
+    The PAST segment is `ceil(start / BS)` cells; the cells of the whole batch
+    are ONE pipeline (`_attend_q8_blocked_kernel` says why), which steps over
+    the rows that have none (`nxt_ref`).
+
+    Precision is the bucketed chunk's (`models/llama.py:_chunk_attention`),
+    NOT the decode kernels': the int8 rows are converted in VMEM to the
+    queries' dtype and multiplied as such (float32 accumulator), the K scales
+    multiply the scores after the product and the V scales the probabilities
+    before theirs, the softmax is float32. No query or probability is
+    requantized: a block pass states bfloat16 against int8 rows, and is bound
+    by bytes and overheads, not by the MXU."""
+    b = pl.program_id(0)
+    li = li_ref[0]
+    BS = block_s
+    _, L, R, PG, _ = q_ref.shape
+    Hkv = s_buf.shape[1] // 2
+    P = Hkv // R
+    G = PG // P
+    n_rows = ids_ref.shape[0]
+    row = ids_ref[b]
+    start_pos = starts_ref[b]
+    c0 = cum_ref[b]
+    nblk = cum_ref[b + 1] - c0
+    total = cum_ref[n_rows]
+
+    def copies(row, j, slot):
+        if packed:
+            return (
+                pltpu.make_async_copy(
+                    pay_hbm.at[li, row, :, pl.ds(j * BS, BS), :],
+                    pay_buf.at[slot],
+                    sems.at[slot, 0],
+                ),
+            )
+        return (
+            pltpu.make_async_copy(
+                pay_hbm.at[li, row, pl.ds(0, 2 * R), pl.ds(j * BS, BS), :],
+                pay_buf.at[slot],
+                sems.at[slot, 0],
+            ),
+            pltpu.make_async_copy(
+                s_hbm.at[li, row, :, pl.ds(j * BS, BS)],
+                s_buf.at[slot],
+                sems.at[slot, 1],
+            ),
+        )
+
+    def start(row, j, slot):
+        for c in copies(row, j, slot):
+            c.start()
+
+    def wait(row, j, slot):
+        for c in copies(row, j, slot):
+            c.wait()
+
+    @pl.when((b == 0) & (total > 0))
+    def _first_cell():  # the one copy nothing runs ahead of
+        start(nxt_ref[0], 0, 0)
+
+    # head p's rows of position l at [(p*L + l)*G, +G)
+    qf = jnp.concatenate(
+        [q_ref[0, l, :, p * G : (p + 1) * G].astype(jnp.float32)
+         for p in range(P) for l in range(L)], axis=1)  # [R, M, W]
+    q = qf.astype(q_ref.dtype)
+    # the block's own keys: L scores a query row, exact
+    s_self = [
+        jnp.sum(qf * ks_ref[0, t].astype(jnp.float32)[:, None, :], axis=-1, keepdims=True) * scale
+        for t in range(L)
+    ]  # L x [R, M, 1]
+    m0 = functools.reduce(jnp.maximum, s_self)
+    p_self = [jnp.exp(s - m0) for s in s_self]
+    l0 = functools.reduce(jnp.add, p_self)
+    acc0 = functools.reduce(
+        jnp.add,
+        [p * vs_ref[0, t].astype(jnp.float32)[:, None, :] for t, p in enumerate(p_self)],
+    )  # [R, M, W]
+
+    def body(j, carry):
+        acc, m, l = carry
+        c = c0 + j
+        slot = jax.lax.rem(c, 2)
+        last = j + 1 == nblk
+
+        @pl.when(c + 1 < total)
+        def _prefetch():  # the batch's next cell: this row's, or the next row's that has one
+            start(jnp.where(last, nxt_ref[b + 1], row), jnp.where(last, 0, j + 1), 1 - slot)
+
+        wait(row, j, slot)
+        buf = pay_buf[slot]  # [Hh, BS, W] int8 — k rows, v rows(, scales)
+        if packed:
+            ss = _unpack_scale_lanes(buf[2 * R], 2 * Hkv, scale_dtype)
+        else:
+            ss = s_buf[slot].astype(jnp.float32)
+        kss, vss = ss[:Hkv], ss[Hkv:]  # [Hkv, BS] f32
+        s = jax.lax.dot_general(
+            q, buf[:R].astype(q.dtype), (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )  # [R, M, BS]
+        s = s * _scales_by_row(kss, P, L * G) * scale
+        seen = j * BS + jax.lax.broadcasted_iota(jnp.int32, (1, 1, BS), 2) < start_pos
+        s = jnp.where(seen, s, NEG_INF)
+
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        ctx = jax.lax.dot_general(
+            (p * _scales_by_row(vss, P, L * G)).astype(q.dtype),
+            buf[R : 2 * R].astype(q.dtype),
+            (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )  # [R, M, W]
+        return acc * alpha + ctx, m_new, l_new
+
+    acc, _, l = jax.lax.fori_loop(0, nblk, body, (acc0, m0, l0))
+    out = (acc / l).astype(o_ref.dtype)
+    for p in range(P):
+        for t in range(L):
+            o_ref[0, t, :, p * G : (p + 1) * G] = out[:, (p * L + t) * G : (p * L + t + 1) * G]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "scale", "block_s"))
+def block_attend_q8(
+    q: jnp.ndarray,  # [A, L, Hkv, G, hd] — the block's queries (post-rope)
+    k_self: jnp.ndarray,  # [A, Hkv, L, hd] — the block's own keys (post-rope)
+    v_self: jnp.ndarray,  # [A, Hkv, L, hd]
+    cache_k: dict,  # FUSED: {"q": int8 [Lyr,B,2*Hkv/P+p,S,P*hd], "s": [Lyr,B,2*Hkv,S]}
+    layer: jnp.ndarray,  # scalar int32
+    starts: jnp.ndarray,  # [A] int32 — each block's first position; >= S: parked
+    *,
+    slot_ids: jnp.ndarray | None = None,  # [A] int32 cache rows (None = 1:1)
+    scale: float = 0.0,  # query scale (0 = head_dim**-0.5)
+    interpret: bool | None = None,
+    block_s: int | None = None,  # None = the rule (`q8_block_tokens`); tests give others
+) -> jnp.ndarray:
+    """Attention of a block pass (`cfg.block_len`; models/llama.py:block_pass)
+    over the FUSED int8 KV cache for one layer: every query of a row's block
+    of L positions against the row's cache [0, start) (PRE-write: the commit
+    pass reads, then writes) and against the block's own L keys and values,
+    which are given exact. Only the blocks that hold past positions leave HBM
+    (`block_row_blocks`), and the layer is cut out of the stacked cache by the
+    kernel's copies: no slice of a layer's payload stands in the program.
+
+    The caller has checked that the arm fits (`blocked_arm_fits`, a block size
+    that divides the row). Returns ctx [A, L, Hkv, G, hd]; a parked row's is
+    its own keys' and is discarded."""
+    A, L, Hkv, G, hd = q.shape
+    S = cache_k["q"].shape[3]
+    interp = _interpret() if interpret is None else interpret
+    sc = scale or hd**-0.5
+    _, p, P = fused_q8_heads(cache_k)
+    R, PG, W = Hkv // P, P * G, cache_k["q"].shape[4]
+    assert W == P * hd, (cache_k["q"].shape, hd)
+    BS = block_s or q8_block_tokens(2 * R + p, S, W)
+    assert BS and blocked_arm_fits(W, interp), (cache_k["q"].shape, BS)
+    # 1-DMA packed blocks need the scale pseudo-head present in the layout
+    packed = p == 1 and os.environ.get("LLM_MCP_TPU_Q8_SCALE_PACK", "1") != "0"
+    Hh = 2 * R + 1 if packed else 2 * R
+
+    ids = jnp.arange(A, dtype=jnp.int32) if slot_ids is None else slot_ids.astype(jnp.int32)
+    starts = starts.astype(jnp.int32)
+    nblk = block_row_blocks(starts, S, BS)
+    cum = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(nblk)])
+    # the batch row that holds the first cell at or after row b: the least
+    # index with a cell, taken from the back (A where none: any row will do)
+    idx = jnp.where(nblk > 0, jnp.arange(A, dtype=jnp.int32), A)
+    at_or_after = jax.lax.cummin(idx, reverse=True)
+    nxt = ids[jnp.minimum(jnp.concatenate([at_or_after, jnp.full((1,), A, jnp.int32)]), A - 1)]
+
+    qw = q_abreast(q, P)  # [A, L, R, P*G, W]
+    ks = jnp.swapaxes(kv_abreast(k_self, P), 1, 2)  # [A, L, R, W]
+    vs = jnp.swapaxes(kv_abreast(v_self, P), 1, 2)
+    kernel = functools.partial(
+        _block_attend_q8_kernel, scale=sc, block_s=BS, packed=packed,
+        scale_dtype=cache_k["s"].dtype,
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,  # layer [1], cache rows, starts, cells, next rows [A + 1]
+        grid=(A,),
+        in_specs=[
+            pl.BlockSpec((1, L, R, PG, W), lambda b, *_: (b, 0, 0, 0, 0)),
+            pl.BlockSpec((1, L, R, W), lambda b, *_: (b, 0, 0, 0)),
+            pl.BlockSpec((1, L, R, W), lambda b, *_: (b, 0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),  # fused payload, all layers
+            pl.BlockSpec(memory_space=pl.ANY),  # plain scales
+        ],
+        out_specs=pl.BlockSpec((1, L, R, PG, W), lambda b, *_: (b, 0, 0, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, Hh, BS, W), jnp.int8),
+            pltpu.VMEM((2, 2 * Hkv, BS), cache_k["s"].dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+        ],
+    )
+    out = pl.pallas_call(
+        kernel, grid_spec=grid_spec, interpret=interp,
+        out_shape=jax.ShapeDtypeStruct((A, L, R, PG, W), q.dtype),
+        name="block_attn_q8",  # no reader of `decode_attn*` picks it up
+        # sequential: a cell's copy is started in the grid step before its own
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), ids, starts, cum, nxt,
+      qw, ks, vs, cache_k["q"], cache_k["s"])
+    return ctx_apart(out, P)
 
 
 def _attend_bf16_kernel(

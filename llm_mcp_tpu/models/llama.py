@@ -44,9 +44,13 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.attention import (
+    _interpret,
+    _note_fall,
     _q8_step_rows,
     append_kv_bf16,
     append_kv_q8,
+    block_attend_q8,
+    blocked_arm_fits,
     ctx_apart,
     decode_attend_bf16,
     decode_attend_q8,
@@ -56,6 +60,7 @@ from ..kernels.attention import (
     kv_abreast,
     kv_heads_abreast,
     paged_gather,
+    q8_block_tokens,
     q_abreast,
     ragged_prefill_attend_bf16,
     ragged_prefill_attend_q8,
@@ -1206,6 +1211,31 @@ def _chunk_attention(
                 new = jnp.where(keep[a], new, jax.lax.dynamic_slice(plane, at, new.shape))
             return jax.lax.dynamic_update_slice(plane, new, at)
 
+        def put_kept(plane, rows, a, tail):
+            # a block's few positions of row a into the fused int8 cache (a block
+            # pass's commit: `keep` is given), in `ragged_write_rows`'s form: the
+            # window of a tile's positions that holds them (32 rows of the int8
+            # payload, 128 lanes of the plain scales) is read, the new rows
+            # selected into it and the window written back. An update of
+            # [rows, 4, W] is laid out heads-minor (4 positions pad a tile of 32
+            # eightfold), and once no slice of a layer's payload stood in the
+            # program to say otherwise (the block pass reads it through a Mosaic
+            # call), the compiler re-laid the WHOLE cache to suit it, in and out,
+            # every layer; updates of [2 Hkv, 4] did the same to all layers'
+            # scales (described-chip compile, PR 60; chip trace, PR 59)
+            Wn = min(S, 32 if tail else 128)
+            new = rows[a][None, None].astype(plane.dtype)  # [1, 1, rows, C(, W)]: positions at axis 3
+            lo = jnp.clip(starts[a], 0, S - Wn)
+            at = (li, slots[a], 0, lo) + (0,) * tail
+            whole = jax.lax.dynamic_update_slice(
+                jnp.zeros(new.shape[:3] + (Wn,) + new.shape[4:], plane.dtype), new,
+                (0, 0, 0, starts[a] - lo) + (0,) * tail)
+            pos = lo + jnp.arange(Wn, dtype=jnp.int32)
+            lands = (pos >= starts[a]) & (pos < starts[a] + C) & keep[a]
+            lands = lands.reshape((1, 1, 1, Wn) + (1,) * tail)
+            cur = jax.lax.dynamic_slice(plane, at, whole.shape)
+            return jax.lax.dynamic_update_slice(plane, jnp.where(lands, whole, cur), at)
+
         with jax.named_scope("kv_append"):
             if ring:
                 # the chunk's row that index j holds after it; negative: an
@@ -1233,6 +1263,7 @@ def _chunk_attention(
                 # (K|V|packed scales) + plain scales, so later readers — decode
                 # kernels included — see a consistent fused entry
                 fused = fuse_prompt_kv(kh, vh, scale_dtype=ck_all["s"].dtype)
+                to = put if keep is None else put_kept
                 if in_order:
                     # A block's few positions a row. The payload rows land row by
                     # row in place; the plain scales as ONE update of the layer's
@@ -1242,7 +1273,7 @@ def _chunk_attention(
                     # 276 ms round (chip trace, PERF.md section 6, PR 59)
                     pay = ck_all["q"]
                     for a in range(A):
-                        pay = put(pay, fused["q"], a, 1)
+                        pay = to(pay, fused["q"], a, 1)
                     cur = jax.lax.dynamic_slice(ck_all["s"], (li, 0, 0, 0), (1, A, 2 * Hkv, S))[0]
                     off = jnp.arange(S, dtype=jnp.int32)[None, :] - starts[:, None]  # [A, S]
                     for j in range(C):  # selects, not a gather: a gather of [A, 2 Hkv, S] took 6.7 ms a layer
@@ -1252,8 +1283,8 @@ def _chunk_attention(
                         ck_all["s"], cur[None], (li, 0, 0, 0))}
                 else:
                     for a in range(A):
-                        ck_all = {"q": put(ck_all["q"], fused["q"], a, 1),
-                                  "s": put(ck_all["s"], fused["s"], a, 0)}
+                        ck_all = {"q": to(ck_all["q"], fused["q"], a, 1),
+                                  "s": to(ck_all["s"], fused["s"], a, 0)}
             else:
                 for a in range(A):
                     ck_all = put(ck_all, kh, a, 1)
@@ -1400,6 +1431,57 @@ def llama_prefill_chunk(
     )
 
 
+def block_attn_arm(cfg: ModelConfig, cache_k: Any, attn_impl: str) -> tuple[str, str]:
+    """(arm, why not the kernel) of a block pass's attention, from what the
+    code can observe: "pallas" (`kernels/attention.py:block_attend_q8`) for
+    the fused int8 cache in rows of whole lanes with a block size that divides
+    them, where the kernels were asked for and nothing caps or windows the
+    scores; "xla" (`_chunk_attention`'s) for anything else. `cache_k` may be
+    shapes: the engine asks for its book (`perf_stats()["blocks"]["attn"]`)."""
+    if attn_impl != "pallas":
+        return "xla", f"attn_impl={attn_impl}"
+    if not isinstance(cache_k, dict):
+        return "xla", "no int8 cache"
+    if cfg.attn_softcap or cfg.sliding_window:
+        return "xla", "softcap or sliding window"
+    rows, S, W = cache_k["q"].shape[2:]
+    if not blocked_arm_fits(W, _interpret()):
+        return "xla", f"rows of {W} lanes"
+    if not q8_block_tokens(rows, S, W):
+        return "xla", f"S={S}: no block size"
+    return "pallas", ""
+
+
+def _block_attend(cfg: ModelConfig, shape: tuple[int, int], slots, starts):
+    """`attend` of a block pass on the kernel's arm, in `_chunk_attention`'s
+    place and with its signature: the same attention half of a layer
+    (`_sub_in`, `_qkv`, rope, `_attn_residual`) around ONE call that reads the
+    stacked cache as it lies. Row a is cache row `slots[a]` (None: row a); no
+    mask is built here: the past is [0, start) and the block sees itself whole."""
+    A, L = shape
+    Hkv, hd, H = cfg.n_kv_heads, cfg.resolved_head_dim, cfg.n_heads
+    starts = jnp.asarray(starts, dtype=jnp.int32)
+    cos, sin = rope_tables(cfg, hd, starts[:, None] + jnp.arange(L, dtype=jnp.int32)[None, :])
+
+    def attend(h, ck_all, cv_all, li, lp, win, rope=None):
+        del cv_all, win  # the fused cache holds V beside K; nothing slides here
+        with jax.named_scope("attn"):
+            x = _sub_in(cfg, h, lp["attn_norm"])
+            q, k, v = _qkv(cfg, lp, x)
+            q, k = q.reshape(A, L, H, hd), k.reshape(A, L, Hkv, hd)
+            if cfg.use_rope if rope is None else rope:
+                q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+            kh = k.transpose(0, 2, 1, 3)  # [A, Hkv, L, hd]
+            vh = v.reshape(A, L, Hkv, hd).transpose(0, 2, 1, 3)
+            ctx = block_attend_q8(
+                q.reshape(A, L, Hkv, H // Hkv, hd), kh, vh, ck_all, li, starts,
+                slot_ids=slots, scale=cfg.attn_scale)
+            h = _attn_residual(cfg, lp, ctx.reshape(A, L, H * hd), h, x)
+        return h, kh, vh
+
+    return attend
+
+
 def block_pass(
     cfg: ModelConfig,
     params: Params,
@@ -1411,6 +1493,7 @@ def block_pass(
     live: jnp.ndarray,  # [A] bool: rows that hold a sequence (a parked row routes nothing)
     commit: bool,  # STATIC: write the block's keys and values, return no logits
     skey: int = 0,  # STATIC bound on the past key range (0 = the whole cache row)
+    attn_impl: str = "xla",  # STATIC: "pallas" asks for the kernel (`block_attn_arm`)
 ) -> tuple[jnp.ndarray | None, Any, Any]:
     """One pass of a block round (`cfg.block_len`; executor/engine.py:
     block_round_fn) over the bucketed chunk's machinery: the L positions of
@@ -1422,10 +1505,18 @@ def block_pass(
     block's final tokens, writes their keys and values at [start, start + L) of
     each live row and returns no logits (the head is not run). Either way the
     second member's expert counts, where it carries them, take the pass's under
-    phase 0."""
+    phase 0. The attention is the kernel's where `block_attn_arm` says so
+    (`_block_attend`: the row's live blocks alone leave HBM), else the
+    bucketed chunk's, which reads every row whole; the embedding and `write`
+    are the chunk's on both."""
     A, L = tokens.shape
     nvalid = jnp.full((A,), L, jnp.int32)
     h, attend, write = _chunk_attention(cfg, params, cache_k, tokens, slots, starts, nvalid, skey=skey)
+    arm, why = block_attn_arm(cfg, cache_k, attn_impl)
+    if arm == "pallas":
+        attend = _block_attend(cfg, (A, L), slots, starts)
+    else:
+        _note_fall("block_attn_q8", why, _interpret())
     banks, stack = expert_stack(cfg, params["layers"])
     rows_v = _rows(cache_v)
     valid = jnp.broadcast_to(live[:, None], (A, L))
@@ -1486,6 +1577,7 @@ def block_denoise(
     key: jax.Array, temp, topk, topp,  # the pass's draw; [A] sampling parameters a row
     allowed: jnp.ndarray | None = None,  # [V] bool: ids the sampler may emit
     skey: int = 0,
+    attn_impl: str = "xla",
 ) -> tuple[jnp.ndarray, Any, jnp.ndarray]:
     """One denoising pass of a block round and its unmask rule: (the block
     after the pass [A, L], the second member with the pass's expert counts,
@@ -1499,7 +1591,8 @@ def block_denoise(
     A, L = tokens.shape
     with jax.named_scope("block.denoise"):
         logits, _, cache_v = block_pass(
-            cfg, params, cache_k, cache_v, tokens, slots, starts, live, commit=False, skey=skey)
+            cfg, params, cache_k, cache_v, tokens, slots, starts, live, commit=False, skey=skey,
+            attn_impl=attn_impl)
     with jax.named_scope("block.unmask"):
         lg = logits if allowed is None else jnp.where(allowed, logits, -jnp.inf)
         x0, p = sample_tokens_p(

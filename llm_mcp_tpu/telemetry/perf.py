@@ -655,21 +655,27 @@ class BlockAccount:
     prompt's last P mod L tokens) and `by_passes` {passes: blocks that took so
     many}. `delivered` books what the round's emission handed on. `off` counts
     the times each feature such a configuration runs without would have
-    engaged (`memory.BLOCK_OFF`, the one list)."""
+    engaged (`memory.BLOCK_OFF`, the one list). `attn` is the book of what the
+    passes' attention streams and by which arm
+    (`kernels/attention.py:BlockAttnStream`, the engine's)."""
 
     SUMS = ("rounds", "rows", "passes", "commits", "unmasked", "remainder_tokens", "delivered")
 
-    def __init__(self, lock: threading.Lock, off: Any) -> None:
+    def __init__(self, lock: threading.Lock, off: Any, attn: Any) -> None:
         self._lock = lock
         self._sums = dict.fromkeys(self.SUMS, 0)
         self._by_passes: dict[int, int] = {}
         self.off = dict.fromkeys(off, 0)
+        self._attn = attn
 
-    def fetched(self, row_passes: list[int], unmasked: int, remainder: int) -> None:
+    def fetched(self, row_passes: list[int], unmasked: int, remainder: int,
+                starts: list[int], batch_rows: int) -> None:
         """One round: each live row's denoising passes, the positions they
-        filled and the positions that were the prompt's."""
+        filled and the positions that were the prompt's; where each live row's
+        block started and the rows of the batch it went out in."""
         with self._lock:
             s = self._sums
+            self._attn.fetched(starts, batch_rows, max(row_passes, default=0) + 1)
             s["rounds"] += 1
             s["rows"] += len(row_passes)
             s["passes"] += max(row_passes, default=0)
@@ -690,7 +696,7 @@ class BlockAccount:
         with self._lock:
             return {**self._sums,
                     "by_passes": {str(k): v for k, v in sorted(self._by_passes.items())},
-                    "off": dict(self.off)}
+                    "off": dict(self.off), "attn": self._attn.stats()}
 
 
 class PerfObservatory:
@@ -774,10 +780,11 @@ class PerfObservatory:
         self._ctx_ema = 0.0
         self._rows_ema = 0.0
 
-    def count_blocks(self, off: Any) -> BlockAccount:
+    def count_blocks(self, off: Any, attn: Any) -> BlockAccount:
         """Open the block rounds' book (once, where the engine is built):
-        `stats()["blocks"]` from here on; `off` the features to count."""
-        self.blocks = BlockAccount(self._lock, off)
+        `stats()["blocks"]` from here on; `off` the features to count, `attn`
+        the book of the passes' attention."""
+        self.blocks = BlockAccount(self._lock, off, attn)
         return self.blocks
 
     # -- sampling cadence --------------------------------------------------

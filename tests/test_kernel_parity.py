@@ -153,6 +153,99 @@ def test_q8_gqa_blocked_parity(monkeypatch, case, pack, block, ids_kind, form):
             rtol=0, atol=1e-6)
 
 
+# -- a block pass's attention (`cfg.block_len`) over the fused int8 cache ----------
+
+
+def _block_attend_reference(q, ks, vs, ck, layer, starts, sc, ids):
+    """The XLA arm's arithmetic (`models/llama.py:_chunk_attention` for a block
+    pass) in float32 on the same fused cache: the past [0, start) with the K
+    scales on the scores and the V scales on the probabilities, the block's own
+    L keys whole, one softmax over both. q [A, L, Hkv, G, hd], ks / vs
+    [A, Hkv, L, hd] -> [A, L, Hkv, G, hd]."""
+    Hkv, _, P = A.fused_q8_heads(ck)
+    S = ck["q"].shape[3]
+    pay, ss = ck["q"][layer], ck["s"][layer].astype(jnp.float32)
+    if ids is not None:
+        pay, ss = pay[ids], ss[ids]
+    kf, vf = A.fused_kv(pay, Hkv, P)
+    kss, vss = ss[:, :Hkv, None, None, :], ss[:, Hkv:, None, None, :]
+    past = jnp.einsum("alhgd,ahsd->ahgls", q, kf.astype(jnp.float32)) * kss * sc
+    seen = (jnp.arange(S)[None, :] < starts[:, None])[:, None, None, None, :]
+    own = jnp.einsum("alhgd,ahtd->ahglt", q, ks) * sc
+    p = jax.nn.softmax(jnp.concatenate([jnp.where(seen, past, A.NEG_INF), own], -1), -1)
+    return (jnp.einsum("ahgls,ahsd->alhgd", p[..., :S] * vss, vf.astype(jnp.float32))
+            + jnp.einsum("ahglt,ahtd->alhgd", p[..., S:], vs))
+
+
+# a block's first position by (blocks, offset), S four blocks; the offsets are
+# multiples of L = 4 as a block's start is. "parked" is start >= S.
+BLOCK_STARTS = {
+    "edges": [(0, 0), (0, 4), (1, -4), (1, 0), (4, -4)],  # no past block, L, one short
+    #   of a block edge, a block edge, the last block of the row
+    "parked_beside_live": [PARKED, (2, 8), PARKED, (0, 0), (0, 12)],
+    "parked_last": [(1, 4), (3, 0), PARKED],
+    "no_row_has_a_past": [(0, 0), PARKED, (0, 0)],
+    "one_row": [(2, 4)],
+}
+
+
+@pytest.mark.parametrize("pack", ["1", "0"], ids=["packed_scales", "plain_scales"])
+@pytest.mark.parametrize("form", ["heads_of_128", "heads_of_64_abreast"])
+@pytest.mark.parametrize("ids_kind", [None, "perm"], ids=["in_order", "compact_permuted"])
+@pytest.mark.parametrize("case", sorted(BLOCK_STARTS))
+def test_block_attend_q8_parity(monkeypatch, case, ids_kind, form, pack):
+    """`block_attend_q8` in interpret mode against the XLA arm's arithmetic on
+    the same fused cache: a row's L = 4 positions x G query heads against its
+    past in blocks (none for a first block at 0 and for a parked row, whose
+    cells the batch's one pipeline steps over) and its own L keys whole; the
+    whole batch in order and a permuted compaction; a head a row (P = 1) and
+    two heads of 64 abreast (P = 2); the scales packed beside the payload and
+    copied apart."""
+    monkeypatch.setenv("LLM_MCP_TPU_Q8_SCALE_PACK", pack)
+    A.block_attend_q8.clear_cache()  # the knob is read at trace time
+    rng = np.random.default_rng(11)
+    rows = BLOCK_STARTS[case]
+    abreast = form == "heads_of_64_abreast"
+    Lyr, B, Hkv, hd, G, L, BS = 2, len(rows), 4 if abreast else 2, 64 if abreast else 128, 2, 4, 32
+    S = 4 * BS
+    ck, _ = _fused_q8_cache(rng, Lyr, B, Hkv, S, hd, abreast=abreast)
+    assert A.fused_q8_heads(ck)[1:] == (1, 2 if abreast else 1)  # the pseudo-head is there
+    starts = jnp.asarray([a * BS + b for a, b in rows], jnp.int32)
+    ids = jnp.asarray(rng.permutation(B), jnp.int32) if ids_kind else None
+    q = jnp.asarray(rng.standard_normal((B, L, Hkv, G, hd)), jnp.float32)
+    ks = jnp.asarray(rng.standard_normal((B, Hkv, L, hd)), jnp.float32)
+    vs = jnp.asarray(rng.standard_normal((B, Hkv, L, hd)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        out = A.block_attend_q8(
+            q, ks, vs, ck, jnp.int32(1), starts, slot_ids=ids, interpret=True, block_s=BS)
+        want = _block_attend_reference(q, ks, vs, ck, 1, starts, hd**-0.5, ids)
+    assert out.shape == want.shape and not bool(jnp.isnan(out).any())
+    seated = (starts < S)[:, None, None, None, None]  # a parked row's output is discarded
+    # float32 on both sides: the blocks' online softmax against the whole one
+    assert float(jnp.max(jnp.abs(jnp.where(seated, out - want, 0.0)))) < 1e-5
+
+
+def test_block_row_blocks_counts_what_the_arm_streams():
+    """ceil(start / BS) blocks of the past; none at 0 and none for a parked row."""
+    starts = np.asarray([0, 4, 252, 256, 260, 1020, 1024, 2000])
+    assert list(A.block_row_blocks(starts, 1024, 256, xp=np)) == [0, 1, 1, 1, 2, 4, 0, 0]
+    assert list(np.asarray(A.block_row_blocks(jnp.asarray(starts), 1024, 256))) == [0, 1, 1, 1, 2, 4, 0, 0]
+
+
+@pytest.mark.parametrize("arm,block,streamed", [("pallas", 256, 3 * (256 + 512)), ("xla", 0, 3 * 4 * 1024)])
+def test_block_attn_stream_counts_a_rounds_passes(arm, block, streamed):
+    """The host's book of a block round (`perf_stats()["blocks"]["attn"]`): two
+    seated rows at 100 and 300 of a batch of four, two denoising passes and the
+    commit. The kernel's arm streams whole blocks of the seated rows' pasts,
+    the XLA arm every row of the batch whole; live is the pasts' positions."""
+    book = A.BlockAttnStream(arm, (48, 64, 9, 1024, 128))
+    book.fetched([100, 300], 4, 3)
+    st = book.stats()
+    assert st["arm"] == arm and st["block_tokens"] == block and st["passes"] == 3
+    assert st["tokens_live"] == 3 * 400 and st["tokens_streamed"] == streamed
+    assert st["live_over_streamed"] == round(1200 / streamed, 4)
+
+
 def test_q8_block_tokens_is_a_function_of_the_caches_shape():
     """The three shapes the benchmark's cells run (PERF.md section 6, PR 36):
     17 payload heads take the coarse block, 61 the finer one; a block always
@@ -1012,6 +1105,7 @@ KERNEL_PARITY = {
     "_decode_attn_kernel": ("tests/test_kernels.py", "test_decode_attention_matches_reference"),
     "_attend_q8_kernel": ("tests/test_kernel_parity.py", "test_q8_gqa_whole_parity"),
     "_attend_q8_blocked_kernel": ("tests/test_kernel_parity.py", "test_q8_gqa_blocked_parity"),
+    "_block_attend_q8_kernel": ("tests/test_kernel_parity.py", "test_block_attend_q8_parity"),
     "_attend_bf16_kernel": ("tests/test_kernel_parity.py", "test_bf16_gqa_parity"),
     "_attend_bf16_blocked_kernel": ("tests/test_kernel_parity.py", "test_bf16_gqa_parity"),
     "_attend_q8_mla_kernel": ("tests/test_kernel_parity.py", "test_mla_whole_s_parity"),

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import llm_mcp_tpu.kernels.attention as A
 from llm_mcp_tpu.models import llama, moe
 from llm_mcp_tpu.models.configs import get_config
 from llm_mcp_tpu.ops.sampling import sample_tokens, sample_tokens_p
@@ -73,6 +74,34 @@ def model(ref):
 def _float32_products():
     with jax.default_matmul_precision("highest"):
         yield
+
+
+# through the int8 cache (the kernel's arm reads no other) a pass reads its past
+# quantized, and against the float32 reference a position's largest difference
+# over its largest |logit| is taken by the MEDIAN over a block's positions (a
+# maximum catches the position whose router choice the rounding moved:
+# tests/test_joyai.py): 0.005-0.041 read here, keys and values 0.01 and less. The two ARMS on the same int8 cache are held to each
+# other at `TOL`, every pass and the commit.
+TOL_Q8 = 0.08
+
+
+def _far(got, want, q8):
+    """How far a block's logits [L, V] (or a row's keys [.., n, hd]) lie from
+    the reference's: the largest difference through the float cache, the median
+    position's relative one through the int8 cache."""
+    diff = np.max(np.abs(got - want), axis=-1)
+    return float(np.median(diff / np.max(np.abs(want), axis=-1))) if q8 else float(np.max(diff))
+
+
+def _kv_of(cfg, ck, cv):
+    """(K, V) [Lyr, B, Hkv, S, hd] float32 of either cache: the float pair as it
+    is, the fused int8 payload times its plain scales."""
+    if not isinstance(ck, dict):
+        return np.asarray(ck), np.asarray(cv["v"])
+    Hkv, _, P = A.fused_q8_heads(ck)
+    k, v = A.fused_kv(ck["q"], Hkv, P)
+    sc = np.asarray(ck["s"], np.float32)[..., None]
+    return np.asarray(k, np.float32) * sc[:, :, :Hkv], np.asarray(v, np.float32) * sc[:, :, Hkv:]
 
 
 def _filled(cfg, params, toks, n, slots=2, seq=128, quantized=False):
@@ -216,11 +245,14 @@ def test_packed_prompts_under_the_block_mask(model, ref):
 # -- (b) every pass of a multi-block reply ----------------------------------------------
 
 
-def _by_hand(cfg, params, ck, cv, first, start, key, temp, n_blocks, allowed=None, threshold=None):
+def _by_hand(cfg, params, ck, cv, first, start, key, temp, n_blocks, allowed=None, threshold=None,
+             attn_impl="xla"):
     """A reply of `n_blocks` blocks through the program's own passes, one call a
     pass as `engine.block_round_fn` makes them (row 1 of a batch of 2 in order,
     row 0 parked): ([per block: [per pass: (block before, logits, block after)]],
-    the final blocks, ck, cv). The round's key splits once a pass."""
+    the final blocks, ck, cv). The round's key splits once a pass. On the
+    kernel's arm every pass is also held to the XLA arm's logits on the same
+    cache and block."""
     if threshold is not None:
         cfg = dataclasses.replace(cfg, unmask_threshold=threshold)
     S = ck.shape[3] if not isinstance(ck, dict) else ck["q"].shape[3]
@@ -236,12 +268,25 @@ def _by_hand(cfg, params, ck, cv, first, start, key, temp, n_blocks, allowed=Non
         while (block == cfg.mask_token_id).any():
             rng, sub = jax.random.split(rng)
             both = jnp.asarray(np.stack([block, block]))
+            if attn_impl == "pallas":
+                other, _, _ = llama.block_pass(
+                    cfg, params, ck, cv, both, None, starts, live, commit=False, attn_impl="xla")
             new, cv, lg = llama.block_denoise(
-                cfg, params, ck, cv, both, None, starts, live, sub, t, k, p, allowed=allowed)
+                cfg, params, ck, cv, both, None, starts, live, sub, t, k, p, allowed=allowed,
+                attn_impl=attn_impl)
+            if attn_impl == "pallas":
+                assert np.max(np.abs(np.asarray(lg[1] - other[1]))) < TOL
             passes.append((block, np.asarray(lg[1]), np.asarray(new[1])))
             block = np.asarray(new[1])
+        final = jnp.asarray(np.stack([block, block]))
+        if attn_impl == "pallas":  # the commit's reads and its writes, arm against arm
+            _, ck_x, _ = llama.block_pass(
+                cfg, params, ck, cv, final, None, starts, live, commit=True, attn_impl="xla")
         _, ck, cv = llama.block_pass(
-            cfg, params, ck, cv, jnp.asarray(np.stack([block, block])), None, starts, live, commit=True)
+            cfg, params, ck, cv, final, None, starts, live, commit=True, attn_impl=attn_impl)
+        if attn_impl == "pallas":
+            assert all(np.max(np.abs(a - b)) < TOL for a, b in zip(
+                _kv_of(cfg, ck, cv), _kv_of(cfg, ck_x, cv)))
         trail.append(passes)
         blocks.append(block)
         block = np.full(L, cfg.mask_token_id, np.int32)
@@ -253,20 +298,28 @@ def _by_hand(cfg, params, ck, cv, first, start, key, temp, n_blocks, allowed=Non
     (0.0, None, "one"),  # greedy is top-1 at probability 1: the whole block in its first pass
     (0.7, 0.005, "between"),  # a threshold between: some passes fill several positions
 ], ids=["sampled_four_passes", "greedy_one_pass", "threshold_between"])
-def test_every_pass_and_the_committed_cache_of_a_reply(model, ref, temp, threshold, n_passes):
+@pytest.mark.parametrize("attn_impl", ["xla", "pallas"])
+def test_every_pass_and_the_committed_cache_of_a_reply(model, ref, temp, threshold, n_passes, attn_impl):
     """A prompt of 34 tokens (8 whole blocks prefilled, 2 tokens fixed at the
     first block's front), then three blocks. Every denoising pass's logits are
     the reference's full forward of [everything committed ; the block as the
     pass saw it] at the block's positions; the order of unmasking is the
     published rule's on the pass's own samples; the committed keys and values
-    are the whole-prompt prefill's of the final sequence."""
+    are the whole-prompt prefill's of the final sequence. On the XLA arm through
+    the float cache; on the kernel's (`block_attend_q8`, interpreted) through
+    the fused int8 cache of 96 positions, three blocks of 32 a row, so that the
+    second and third blocks of the reply read two blocks of their past."""
     cfg, params, toks, _ = model
     P, P0 = 34, 32
-    ck, cv = _filled(cfg, params, toks, P0)
+    q8 = attn_impl == "pallas"
+    tol = TOL_Q8 if q8 else TOL
+    ck, cv = _filled(cfg, params, toks, P0, seq=96 if q8 else 128, quantized=q8)
+    assert llama.block_attn_arm(cfg, ck, attn_impl)[0] == attn_impl
     first = np.full(L, cfg.mask_token_id, np.int32)
     first[: P - P0] = toks[P0:P]
     trail, blocks, ck, cv = _by_hand(
-        cfg, params, ck, cv, first, P0, jax.random.PRNGKey(7), temp, 3, threshold=threshold)
+        cfg, params, ck, cv, first, P0, jax.random.PRNGKey(7), temp, 3, threshold=threshold,
+        attn_impl=attn_impl)
     seq = list(toks[:P0])
     took = []  # (masks a block started with, passes it took)
     for passes, final in zip(trail, blocks):
@@ -274,13 +327,14 @@ def test_every_pass_and_the_committed_cache_of_a_reply(model, ref, temp, thresho
         for before, lg, after in passes:
             want = ref.forward(cfg, params, np.asarray(seq + list(before), np.int32),
                                np.arange(len(seq), len(seq) + L))
-            assert np.max(np.abs(lg - want)) < TOL
+            assert _far(lg, want, q8) < tol
             # the rule, on the samples the pass must have drawn: what it filled
             # it filled with a token, and only where the mask stood
             filled = before != after
             assert (before[filled] == cfg.mask_token_id).all() and filled.any()
-            if temp == 0.0:
-                assert (after == np.where(before == cfg.mask_token_id, np.argmax(want, -1), before)).all()
+            if temp == 0.0:  # (through the int8 cache a near-tie may fall the other way)
+                assert (after == np.where(
+                    before == cfg.mask_token_id, np.argmax(lg if q8 else want, -1), before)).all()
         assert not (final == cfg.mask_token_id).any()
         seq += list(final)
     assert took[0][0] == 2 and took[1][0] == took[2][0] == 4
@@ -294,9 +348,10 @@ def test_every_pass_and_the_committed_cache_of_a_reply(model, ref, temp, thresho
     # the committed cache is the prefill's of the final sequence, under the block mask
     n = len(seq)
     want_k, want_v = _filled(cfg, params, np.asarray(seq, np.int32), n)
-    assert np.max(np.abs(np.asarray(ck[:, 1, :, :n] - want_k[:, 1, :, :n]))) < TOL
-    assert np.max(np.abs(np.asarray(cv["v"][:, 1, :, :n] - want_v["v"][:, 1, :, :n]))) < TOL
-    assert np.max(np.abs(np.asarray(ck[:, 0]))) == 0.0  # the parked row was not written
+    got_k, got_v = _kv_of(cfg, ck, cv)
+    assert _far(got_k[:, 1, :, :n], np.asarray(want_k[:, 1, :, :n]), q8) < tol
+    assert _far(got_v[:, 1, :, :n], np.asarray(want_v["v"][:, 1, :, :n]), q8) < tol
+    assert np.max(np.abs(got_k[:, 0])) == 0.0  # the parked row was not written
     # the expert counts of every pass, denoising and commit, under the decode phase
     calls = sum(len(p) for p in trail) + 3
     assert (np.asarray(cv["moe"])[0, :, 4] == calls).all() and (np.asarray(cv["moe"])[0, :, 0] == L * calls).all()
@@ -609,16 +664,23 @@ def test_a_long_prompt_takes_chunks_between_block_rounds(engine, no_eos):
     assert after["off"]["ragged_prefill"] > before["off"]["ragged_prefill"]
 
 
-def test_the_engines_round_is_the_passes_driven_by_hand(ref):
+@pytest.mark.parametrize("attn_impl", ["xla", "pallas"])
+def test_the_engines_round_is_the_passes_driven_by_hand(ref, monkeypatch, attn_impl):
     """ONE dispatch of `block_round_fn` against `block_denoise` / `block_pass`
     called pass by pass with the round's own keys: the same tokens, the same
     passes a row, the same cache; and the round's successor is dispatched on the
-    device's own start buffer before this one is fetched."""
-    eng = _engine(kv_quant="")  # float caches: what it serves is the reference's own choice
+    device's own start buffer before this one is fetched. On the XLA arm with
+    float caches (what it serves is the reference's own choice), on the
+    kernel's with the fused int8 cache, which the engine's round reads through
+    `block_attend_q8` as the passes by hand do."""
+    monkeypatch.setenv("LLM_MCP_TPU_ATTN", attn_impl)
+    q8 = attn_impl == "pallas"
+    eng = _engine(kv_quant="int8" if q8 else "")
     try:
         cfg, params = eng.cfg, eng.params
+        assert eng.decode_impl == attn_impl == eng.perf_stats()["blocks"]["attn"]["arm"]
         toks = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (40,), 3, 250), np.int32)
-        ck, cv = _filled(cfg, params, toks, 32, slots=2, seq=128)
+        ck, cv = _filled(cfg, params, toks, 32, slots=2, seq=128, quantized=q8)
         eng._ck, eng._cv = ck, cv
         first = np.full((2, L), cfg.mask_token_id, np.int32)
         first[1, :2] = toks[32:34]
@@ -632,7 +694,7 @@ def test_the_engines_round_is_the_passes_driven_by_hand(ref):
         assert not isinstance(d_last, np.ndarray) and (np.asarray(d_last)[1] == cfg.mask_token_id).all()
         assert (np.asarray(d_last)[0] == first[0]).all()  # a parked row's start stands
         out = np.asarray(out)
-        ck0, cv0 = _filled(cfg, params, toks, 32, slots=2, seq=128)
+        ck0, cv0 = _filled(cfg, params, toks, 32, slots=2, seq=128, quantized=q8)
 
         def by_hand():
             rng, block, n = key, first[1].copy(), 0
@@ -644,20 +706,61 @@ def test_the_engines_round_is_the_passes_driven_by_hand(ref):
                 new, cvh, _ = llama.block_denoise(
                     cfg, params, ck0, cvh, both, None, starts, live, sub,
                     jnp.asarray([0.0, 0.7]), jnp.zeros((2,), jnp.int32), jnp.ones((2,)),
-                    allowed=eng._allowed_mask)
+                    allowed=eng._allowed_mask, attn_impl=attn_impl)
                 block, n = np.asarray(new[1]), n + 1
             _, ckh, cvh = llama.block_pass(
                 cfg, params, ck0, cvh, jnp.asarray(np.stack([first[0], block])), None, starts, live,
-                commit=True)
+                commit=True, attn_impl=attn_impl)
             return block, n, ckh, cvh
 
         block, n, ckh, cvh = by_hand()
         assert list(out[:L, 1]) == list(block) and out[L, 1] == n == 2 and out[L, 0] == 0
-        assert np.max(np.abs(np.asarray(ck1 - ckh))) < TOL
+        assert all(np.max(np.abs(a - b)) < TOL for a, b in zip(_kv_of(cfg, ck1, cv1), _kv_of(cfg, ckh, cvh)))
         assert (np.asarray(cv1["moe"]) == np.asarray(cvh["moe"])).all()
         assert (out[L + 1 :].reshape(-1)[:30].reshape(2, 3, 5) == np.asarray(cv1["moe"])).all()
     finally:
         eng.shutdown()
+
+
+def test_a_block_pass_on_a_float_cache_takes_the_xla_arm_and_says_so_once(model, monkeypatch):
+    """The kernel reads the fused int8 cache alone: asked for on a float cache
+    (and on a compiled path: interpreted runs take exact math by design and
+    note nothing), a pass takes the bucketed chunk's XLA attention, gives the
+    logits it gives unasked, and notes ONE fall with the reason."""
+    cfg, params, toks, _ = model
+    ck, cv = _filled(cfg, params, toks, 32)
+    assert llama.block_attn_arm(cfg, ck, "pallas") == ("xla", "no int8 cache")
+    assert llama.block_attn_arm(cfg, ck, "xla") == ("xla", "attn_impl=xla")
+    q8 = _filled(cfg, params, toks, 32, quantized=True)[0]
+    assert llama.block_attn_arm(cfg, q8, "pallas") == ("pallas", "")
+    assert llama.block_attn_arm(dataclasses.replace(cfg, attn_softcap=30.0), q8, "pallas")[0] == "xla"
+    block = jnp.full((2, L), cfg.mask_token_id, jnp.int32)
+    args = (cfg, params, ck, cv, block, None, jnp.asarray([128, 32]), jnp.asarray([False, True]))
+    want, _, _ = llama.block_pass(*args, commit=False)
+    monkeypatch.setattr(llama, "_interpret", lambda: False)
+    monkeypatch.setattr(A, "reference_falls", {})
+    got, _, _ = llama.block_pass(*args, commit=False, attn_impl="pallas")
+    assert A.reference_falls == {"block_attn_q8": 1}
+    assert np.max(np.abs(np.asarray(got[1] - want[1]))) == 0.0
+
+
+def test_the_blocks_book_names_the_arm_and_what_it_streams(engine, no_eos):
+    """`perf_stats()["blocks"]["attn"]`: the arm in force, and over a reply's
+    rounds the positions the passes' attention fetched against the positions
+    live, both growing every round, live never the larger."""
+    arm = llama.block_attn_arm(engine.cfg, engine._ck, engine.decode_impl)[0]
+    seen = [engine.perf_stats()["blocks"]]
+    for _ in range(2):
+        engine.generate("a prompt of some length, for a past", max_tokens=8, temperature=0.0)
+        seen.append(engine.perf_stats()["blocks"])
+    for before, after in zip(seen, seen[1:]):
+        a, b = before["attn"], after["attn"]
+        assert b["arm"] == arm and b["block_tokens"] == (128 if arm == "pallas" else 0)
+        assert b["passes"] - a["passes"] == (after["passes"] - before["passes"]) + (
+            after["commits"] - before["commits"]) > 0
+        assert b["tokens_live"] > a["tokens_live"] and b["tokens_streamed"] > a["tokens_streamed"]
+        assert b["tokens_live"] <= b["tokens_streamed"]
+        assert b["live_over_streamed"] == round(b["tokens_live"] / b["tokens_streamed"], 4)
 
 
 def _covering_tokenizer(cfg):

@@ -21,6 +21,8 @@ entries nor leave entries no chip can load.
 
 import os
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -1088,7 +1090,7 @@ def sdar_program(which: str, cfg):
     from llm_mcp_tpu.executor.engine import _put_rows
     from llm_mcp_tpu.models import hybrid, llama
 
-    def block(params, ck, cv, first, starts, counter):
+    def block(params, ck, cv, first, starts, counter, slots=None):
         live = starts < ck["q"].shape[3]
         temp = jnp.full(starts.shape, 0.7, jnp.float32)
         topk, topp = jnp.zeros(starts.shape, I32), jnp.ones(starts.shape, jnp.float32)
@@ -1097,7 +1099,8 @@ def sdar_program(which: str, cfg):
             tokens, passes, rng, moe, n = carry
             rng, sub = jax.random.split(rng)
             new, cv_p, _ = llama.block_denoise(
-                cfg, params, ck, dict(cv, moe=moe), tokens, None, starts, live, sub, temp, topk, topp)
+                cfg, params, ck, dict(cv, moe=moe), tokens, slots, starts, live, sub, temp, topk, topp,
+                attn_impl="pallas")
             return new, passes + jnp.any(tokens == cfg.mask_token_id, axis=1), rng, cv_p["moe"], n + 1
 
         tokens, passes, _, moe, _ = jax.lax.while_loop(
@@ -1106,7 +1109,8 @@ def sdar_program(which: str, cfg):
             (first, jnp.zeros(starts.shape, I32), jax.random.fold_in(jax.random.PRNGKey(1), counter[0]),
              cv["moe"], jnp.int32(0)))
         _, ck, cv = llama.block_pass(
-            cfg, params, ck, dict(cv, moe=moe), tokens, None, starts, live, commit=True)
+            cfg, params, ck, dict(cv, moe=moe), tokens, slots, starts, live, commit=True,
+            attn_impl="pallas")
         return jnp.concatenate([tokens.T, passes[None]]), ck, cv
 
     def admit(params, ck, cv, tokens, lengths, slots):
@@ -1122,11 +1126,12 @@ def sdar_program(which: str, cfg):
         ck, cv = jax.lax.fori_loop(0, tokens.shape[0], body, (ck, cv))
         return logits, ck, hybrid.add_counts(cv, vs)
 
-    return {"block": block, "admit": admit}.get(which) or solar_program(which, cfg)
+    return {"block": block, "block_compact": block, "admit": admit}.get(which) or solar_program(which, cfg)
 
 
 @pytest.mark.parametrize("which,operands", [
     ("block", [(64, 4), (64,), (1,)]),  # every slot a row: 256 rows a pass
+    ("block_compact", [(32, 4), (32,), (1,), (32,)]),  # half the slots seated: rows by slot id
     ("admit", [(4, 128), (4,), (4,)]),  # the cell's largest admit program, 512 padded tokens
     ("admit", [(1, 64), (1,), (1,)]),  # and its smallest
     ("chunk", [(2, 512), (2,), (2,), (2,)]),  # two prompts' second chunks of 512
@@ -1143,8 +1148,13 @@ def test_sdar_step_programs_fit_with_the_banks_whole_beside_the_fused_cache(
     the chip beside 10.33 GB of weights and the 3.67 GB fused int8 cache; the
     temporaries hold no copy of a layer's banks (151 MB a layer: the stack goes
     in whole), and the cache is updated in place, neither copied nor re-laid:
-    a denoising pass does not even carry it. GiB in PERF.md section 4 as
-    "described-chip compile"."""
+    a denoising pass does not even carry it. The block round's passes read it
+    through `block_attn_q8` (kernels/attention.py:block_attend_q8), in order and
+    by slot id: no slice of a layer's payload is cut out of the stack (67 MB a
+    layer and pass before PR 60), and the commit's writes after the kernel's
+    read leave its layout alone (written as updates of `[9, 4, 128]` they made
+    the compiler re-lay all 48 layers heads-minor, 6 GB, and back, every layer).
+    GiB in PERF.md section 4 as "described-chip compile"."""
     cfg, params, cache = sdar
     falls = dict(A.reference_falls)
     compiled = jax.jit(sdar_program(which, cfg), donate_argnums=(1, 2)).lower(
@@ -1153,6 +1163,13 @@ def test_sdar_step_programs_fit_with_the_banks_whole_beside_the_fused_cache(
     text = compiled.as_text()
     assert grouped_kernels_in(text)
     assert ("flash_prefill_attn" in text) == (which == "admit")
+    assert ("block_attn_q8" in text) == which.startswith("block")
+    if which.startswith("block"):
+        # every pass's attention is the kernel's: the denoising loop's and the commit's
+        assert text.count("custom_call_target=\"tpu_custom_call\"") >= 6
+        cut = [ln.strip()[:160] for ln in text.splitlines()
+               if " dynamic-slice(" in ln and re.search(r"= s8\[(1,)?\d+,[89],1024,128\]", ln)]
+        assert cut == [], cut
     assert cache["k"]["q"].shape == (48, 64, 9, 1024, 128) and cache["v"]["v"] == {}
     assert cache["v"]["moe"].shape == (2, 48, 5)
     assert cache_relayouts(text, cache["k"]["q"].shape) == []
@@ -1174,5 +1191,9 @@ def test_sdar_step_programs_fit_with_the_banks_whole_beside_the_fused_cache(
           f"{mem.argument_size_in_bytes / 2**30:.2f} GiB (weights {weights / 2**30:.2f}, "
           f"KV cache {kv / 2**30:.2f} logical)")
     assert total < 15.0 * 2**30
-    # no copy of a layer's banks: 0.14 GiB a layer would be 6.75 GiB a pass
-    assert mem.temp_size_in_bytes < 1.0 * 2**30
+    # no copy of a layer's banks: 0.14 GiB a layer would be 6.75 GiB a pass. (The
+    # block round's 1.24 GiB: with no slice of a layer's payload left in it the
+    # compiler transposes the wq / wk / wv STACKS once a round, 0.95 GiB outside
+    # the loops, where it transposed a layer's slice inside them every layer of
+    # every pass before)
+    assert mem.temp_size_in_bytes < (1.5 if which.startswith("block") else 1.0) * 2**30
