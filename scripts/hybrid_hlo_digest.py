@@ -24,20 +24,26 @@ The whole-prompt prefill is lowered on the Pallas arm since PR 51, as the cells
 run it (before, on the XLA arm, a change to `flash_prefill_attention` moved no
 digest: PR 51 read the three prefills alone differ from dd59802's).
 
-`--chip` (PR 55) lowers the CELLS' configurations instead, at their published
-widths, 64 slots x 1024 in bfloat16, FOR the TPU (`lowering_platforms`, no chip
+`--chip` (PR 55) lowers the CELLS' programs instead: every row of
+tests/cell_programs.py (PR 61: the table tests/test_tpu_compile.py compiles for
+the described chip, so what is hashed here is what is compiled there), which is
+each benchmark configuration at its published widths with its cell's slots and
+length, and each program the cell dispatches (the decode round of 4 steps, the
+admit programs, the bucketed and the packed chunk, the mixed round at both
+rungs, SDAR's block round whole and compact) at the operand shapes its traffic
+gives it, 44 programs. They are lowered FOR the TPU (`lowering_platforms`, no chip
 and no libtpu; the platform question answered "tpu", so the dispatchers take
 the kernels with interpret mode off and the arms a chip would compile): a
 Mosaic kernel is then a `tpu_custom_call` whose body is serialised bytecode
 WITH the source lines of the kernel's Python, which move with every edit above
 them, so each body is read back and printed without locations before the text
-is hashed. PR 55 read the fifteen programs of the four cells of heads of 128
+is hashed. `--tree` names the tree whose `llm_mcp_tpu` is lowered; the table is
+this tree's. PR 55 read the fifteen programs of the four cells of heads of 128
 equal between a600a99 and its own tree (the tiny presets cannot show that: all
 but `tiny-lfm2` have heads no row of 128 lanes holds whole), and Granite's and
-LFM2's, heads of 64, all differ. PR 58 added `joyai-llm-flash-ep16`, the latent
-family's cell, with its four programs (decode, bucketed chunk, whole-prompt
-prefill, and `ragged`, the packed chunk on the kernel arm), and read the other
-six cells' 23 equal between 934ceba and its own tree.
+LFM2's, heads of 64, all differ; PR 58 added the latent family's cell. Until PR
+61 this mode hashed ONE step of each kind at shapes of its own (64 x 1024, 256
+packed tokens), from a second hand copy of the programs that lacked SDAR.
 """
 
 from __future__ import annotations
@@ -50,8 +56,6 @@ import sys
 from functools import partial
 
 PRESETS = ("tiny-solar", "tiny-olmo-hybrid", "tiny-granite-hybrid", "tiny-kexaone", "tiny-lfm2")
-CELLS = ("qwen3-8b", "solar-open2-250b-ep8", "olmo-hybrid-7b-d20", "granite-4.0-h-micro",
-         "k-exaone-236b-ep8", "lfm2-8b-a1b-d14", "joyai-llm-flash-ep16")
 
 
 def without_locations(text: str) -> str:
@@ -83,62 +87,51 @@ def main() -> int:
     ap.add_argument("--chip", action="store_true")
     args = ap.parse_args()
     sys.path.insert(0, os.path.abspath(args.tree))
+    sys.path.insert(1, os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "tests"))
 
+    import cell_programs
     import jax
     import jax.numpy as jnp
     from jax._src.lib.mlir import passmanager
 
-    from llm_mcp_tpu.models import hybrid, llama
+    from llm_mcp_tpu.models import llama
     from llm_mcp_tpu.models.configs import MODEL_CONFIGS, get_config
     from llm_mcp_tpu.utils import platform
 
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32)
+    def digest(name, tag, lowered):
+        module = lowered.compiler_ir("stablehlo")
+        with module.context:
+            passmanager.PassManager.parse("builtin.module(cse,canonicalize,cse)").run(
+                module.operation)
+        text = without_locations(str(module)) if args.chip else str(module)
+        if args.out:
+            os.makedirs(args.out, exist_ok=True)
+            with open(os.path.join(args.out, f"{name}.{tag}.mlir"), "w") as f:
+                f.write(text)
+        print(name, tag, hashlib.sha1(text.encode()).hexdigest()[:12], flush=True)
 
-    B, S, T, R, dtype = (64, 1024, 256, 4, jnp.bfloat16) if args.chip else (4, 128, 128, 4, jnp.float32)
     if args.chip:
         platform.device_platform = lambda: "tpu"
-    for name in CELLS if args.chip else PRESETS:
+        for cell, which, operands in cell_programs.ROWS:
+            spec = cell_programs.CELLS[cell]
+            if spec.config not in MODEL_CONFIGS:  # a tree from before the configuration
+                continue
+            digest(spec.config, cell_programs.row_id(cell, which, operands),
+                   cell_programs.traced(cell, which, operands).lower(lowering_platforms=("tpu",)))
+        return 0
+    for name in PRESETS:
         if name not in MODEL_CONFIGS:  # a tree from before the preset
             continue
         cfg = get_config(name)
         if args.lin_value_dim and cfg.lin_heads:
             cfg = dataclasses.replace(cfg, lin_value_dim=args.lin_value_dim)
         params = jax.eval_shape(
-            partial(llama.init_llama_params, cfg, jax.random.PRNGKey(0), dtype=dtype))
+            partial(llama.init_llama_params, cfg, jax.random.PRNGKey(0), dtype=jnp.float32))
         cache = jax.eval_shape(
-            partial(llama.init_kv_cache, cfg, B, S, dtype=dtype, quantized=True))
-        programs = {
-            "decode": (lambda p, ck, cv, *a: llama.llama_decode_step(
-                cfg, p, ck, cv, *a, attn_impl="pallas"), (i32(B), i32(B))),
-            "mixed": (lambda p, ck, cv, *a: hybrid.hybrid_mixed_step(cfg, p, ck, cv, *a),
-                      (i32(B), i32(B), i32(T), i32(T), i32(T), i32(R), i32(R))),
-            "chunk": (lambda p, ck, cv, *a: llama.llama_prefill_chunk_batch(
-                cfg, p, ck, cv, *a, skey=64), (i32(2, 32), i32(2), i32(2), i32(2))),
-            "prefill": (lambda p, ck, cv, *a: llama.llama_prefill(
-                cfg, p, *a, attn_impl="pallas", quant_kv=True), (i32(2, 64), i32(2))),
-        }
-        if not llama.mixed_step_supported(cfg):  # a stack with rings takes admit programs alone
-            del programs["mixed"]
-        if cfg.kv_lora_rank:  # the latent family packs its chunks (ragged prefill stays on)
-            programs["ragged"] = (lambda p, ck, cv, *a: llama.llama_prefill_chunk_ragged(
-                cfg, p, ck, cv, *a, impl="kernel"),
-                (i32(T), i32(T), i32(T), i32(R), i32(R), i32(R)))
-        elif not cfg.gqa_layers:  # the dense family's mixed step is `llama.mixed_step_q8`
-            programs["mixed"] = (lambda p, ck, cv, *a: llama.mixed_step_q8(cfg, p, ck, cv, *a),
-                                 programs["mixed"][1])
-        for tag, (fn, operands) in programs.items():
-            module = jax.jit(fn).trace(params, cache["k"], cache["v"], *operands).lower(
-                lowering_platforms=("tpu" if args.chip else "cpu",)).compiler_ir("stablehlo")
-            with module.context:
-                passmanager.PassManager.parse("builtin.module(cse,canonicalize,cse)").run(
-                    module.operation)
-            text = without_locations(str(module)) if args.chip else str(module)
-            if args.out:
-                os.makedirs(args.out, exist_ok=True)
-                with open(os.path.join(args.out, f"{name}.{tag}.mlir"), "w") as f:
-                    f.write(text)
-            print(name, tag, hashlib.sha1(text.encode()).hexdigest()[:12], flush=True)
+            partial(llama.init_kv_cache, cfg, 4, 128, dtype=jnp.float32, quantized=True))
+        for tag, (fn, operands) in cell_programs.preset_steps(cfg, 4, 128, 4).items():
+            digest(name, tag, jax.jit(fn).trace(params, cache["k"], cache["v"], *operands).lower(
+                lowering_platforms=("cpu",)))
     return 0
 
 

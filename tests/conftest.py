@@ -7,6 +7,14 @@ first `import jax` anywhere in the test session.
 """
 
 import os
+import sys
+
+# A test run writes no bytecode into the tree it tests (a `__pycache__/` beside
+# the readers of benchmark/layer_metrics/ is a directory where a test of
+# tests/benchmark/ lists files: ROADMAP B1 (0)); the children the tests start
+# inherit the variable.
+sys.dont_write_bytecode = True
+os.environ.setdefault("PYTHONDONTWRITEBYTECODE", "1")
 
 # Force the CPU whatever the session's environment preselects: unit tests
 # target the virtual mesh; chip_smoke.py and the serving entry points use the
@@ -51,6 +59,25 @@ enable_compile_cache(min_compile_s=0.2)
 os.environ.setdefault("TPU_WARMUP", "0")
 
 import pytest  # noqa: E402
+
+
+@pytest.fixture(autouse=True)
+def _a_cases_engines_end_with_it():
+    """tests/family.py: an engine a case built for itself ends with the case."""
+    import family
+
+    yield
+    family.case_ends()
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _a_modules_engines_end_with_it():
+    """tests/family.py: no shared engine, and no function compiled for a
+    module's cases, outlives the module."""
+    import family
+
+    yield
+    family.module_ends()
 
 
 @pytest.fixture()

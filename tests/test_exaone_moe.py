@@ -8,7 +8,6 @@ weights: logits, not tokens, through every path a sequence can take, and the
 engine's book of the two kinds."""
 
 import dataclasses
-import importlib.util
 import json
 import os
 
@@ -29,6 +28,13 @@ from llm_mcp_tpu.models.llama import (
     llama_prefill_chunk_batch,
 )
 
+from family import reference_for, reference_source, retrace, stepwise  # noqa: E402
+
+# every model call of this file is ONE trace and ONE compile a (configuration, shape):
+# called bare, a step dispatches its primitives one by one and lowers its kernels again
+llama_decode_step, llama_prefill, llama_prefill_chunk_batch = map(
+    stepwise, (llama_decode_step, llama_prefill, llama_prefill_chunk_batch))
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOL = 1e-4  # float32 against float32, of logits whose largest is about 4
 # Through the int8 cache and rings, on the MEDIAN over positions of a row's
@@ -43,11 +49,7 @@ T = 256  # eight windows of 32; the ring of 128 wraps at half of it
 
 @pytest.fixture(scope="module")
 def ref():
-    spec = importlib.util.spec_from_file_location(
-        "exaone_moe", os.path.join(ROOT, "benchmark", "references", "exaone_moe.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return reference_for("exaone_moe")
 
 
 @pytest.fixture(scope="module")
@@ -100,8 +102,7 @@ def _through_the_cache(cfg, params, toks, quantized, chunks, upto, slot=2):
 
 
 def test_the_reference_shares_no_code_with_the_program():
-    src = open(os.path.join(ROOT, "benchmark", "references", "exaone_moe.py")).read()
-    assert "llm_mcp_tpu" not in src.split('"""', 2)[2]  # the docstring names the files
+    assert "llm_mcp_tpu" not in reference_source("exaone_moe")  # its docstring names the files
 
 
 def test_the_windows_are_the_published_list_and_a_period_is_written_out():
@@ -160,12 +161,12 @@ def test_each_control_reads_outside_the_tolerance(model, ref, control):
     outside it, so the comparison can tell each from the program."""
     cfg, params, toks, want = model
     ref.LOWER = control
-    jax.clear_caches()
+    retrace(ref)
     try:
         other = ref.logits(cfg, params, toks, np.arange(T), np.arange(cfg.vocab_size))
     finally:
         ref.LOWER = None
-        jax.clear_caches()
+        retrace(ref)
     # readings: lost_ring 0.130, rope_global 0.55, no_scale 0.74, fp8 1.85, no_window 3.9
     assert np.median(np.max(np.abs(other[170:] - want[170:]), axis=1)) > TOL_Q8, control
 
@@ -383,6 +384,9 @@ def test_the_int8_engine_is_held_as_the_harness_holds_it(engine, ref):
 
 
 def test_window_layers_hold_a_ring_a_slot_and_not_the_whole_length(engine):
+    # (a reply of its own: under `--dist load` this case may be the first of its
+    # worker to see the engine, and the window book below counts decode steps)
+    engine.generate("a ring a slot", max_tokens=3, temperature=0.0)
     kinds = engine.perf_stats()["kv_kinds"]
     assert kinds["full"]["layers"] == 1 and kinds["window"]["layers"] == 4
     assert kinds["full"]["positions"] == 2 * 512 and kinds["window"]["positions"] == 2 * 128

@@ -6,7 +6,6 @@ tokens, through every path a sequence can take; and the engine's slot
 life-cycle around the state pool."""
 
 import dataclasses
-import importlib.util
 import os
 
 import jax
@@ -14,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from family import reference_for, reference_source, retrace, stepwise
 from llm_mcp_tpu.kernels.grouped import ROW_TILE, grouped_ffn, grouped_reference, tile_visits
 from llm_mcp_tpu.kernels.kda import kda_decode_step, kda_decode_step_reference
 from llm_mcp_tpu.models import moe
@@ -28,16 +28,16 @@ from llm_mcp_tpu.models.llama import (
 )
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# every model call of this file is ONE trace and ONE compile a (configuration, shape):
+# called bare, a step dispatches its primitives one by one and lowers its kernels again
+llama_decode_step, llama_prefill, llama_prefill_chunk_batch = map(
+    stepwise, (llama_decode_step, llama_prefill, llama_prefill_chunk_batch))
 TOL = 1e-4  # float32 against float32, of logits whose largest is about 4
 
 
 @pytest.fixture(scope="module")
 def ref():
-    spec = importlib.util.spec_from_file_location(
-        "solar_open2", os.path.join(ROOT, "benchmark", "references", "solar_open2.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return reference_for("solar_open2")
 
 
 @pytest.fixture(scope="module")
@@ -58,8 +58,7 @@ def _float32_products():
 
 
 def test_the_reference_shares_no_code_with_the_program():
-    src = open(os.path.join(ROOT, "benchmark", "references", "solar_open2.py")).read()
-    assert "llm_mcp_tpu" not in src.split('"""', 2)[2]  # the docstring names the files
+    assert "llm_mcp_tpu" not in reference_source("solar_open2")  # its docstring names the files
 
 
 def test_full_prefill_of_rows_of_unlike_lengths(model):
@@ -482,6 +481,7 @@ def test_engine_serves_the_references_choice_in_fresh_and_reused_slots(engine, r
     and again: with 2 slots the later requests land in used slots."""
     allowed = np.flatnonzero(np.asarray(engine._allowed_mask))
     prompts = ["amber basil", "x" * 70 + " cedar dune ember", "y" * 45, "fjord grove " * 6]
+    admitted = engine.perf_stats()["state_pool"]["admitted_total"]  # (the module's engine: what other cases of this worker were served)
     for prompt in prompts:
         ids, out = _serve(engine, prompt)
         seq = ids + out[:-1]
@@ -492,7 +492,7 @@ def test_engine_serves_the_references_choice_in_fresh_and_reused_slots(engine, r
             regret = float(np.max(want[k]) - want[k, np.flatnonzero(allowed == tok)[0]])
             assert regret < 1e-3, (prompt[:12], k, regret)
     pool = engine.perf_stats()["state_pool"]
-    assert pool["admitted_total"] == len(prompts) > pool["slots"]
+    assert pool["admitted_total"] - admitted == len(prompts) > pool["slots"]
     assert pool["live_slots"] == 0 and pool["bytes"] == pool["bytes_per_slot"] * 2
     decode, prefill = np.asarray(engine.perf_stats()["experts"]["counts"])
     assert (decode[:, 4] > 0).all() and (prefill[:, 4] > 0).all()
@@ -710,7 +710,7 @@ def test_the_harness_comparison_passes_the_program_and_refuses_a_float8_referenc
         assert correctness.hold_to_reference(ref, eng, ids, out)["worst_regret_rel"] < 1e-3
         for lower, refused in (("state_bf16", False), ("fp8", True)):
             ref.LOWER = lower
-            jax.clear_caches()
+            retrace(ref)
             if refused:
                 with pytest.raises(AssertionError, match="under the reference's choice"):
                     correctness.hold_to_reference(ref, eng, ids, out)
@@ -718,7 +718,7 @@ def test_the_harness_comparison_passes_the_program_and_refuses_a_float8_referenc
                 assert correctness.hold_to_reference(ref, eng, ids, out)["worst_regret_rel"] < 0.1
     finally:
         ref.LOWER = None
-        jax.clear_caches()
+        retrace(ref)
         eng.shutdown()
 
 
@@ -727,11 +727,7 @@ def test_the_harness_comparison_passes_the_program_and_refuses_a_float8_referenc
 
 @pytest.fixture(scope="module")
 def olmo_ref():
-    spec = importlib.util.spec_from_file_location(
-        "olmo_hybrid", os.path.join(ROOT, "benchmark", "references", "olmo_hybrid.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return reference_for("olmo_hybrid")
 
 
 def _unlike_norms(params, key=11):
@@ -765,8 +761,7 @@ def olmo(olmo_ref):
 
 
 def test_the_olmo_reference_shares_no_code_with_the_program():
-    src = open(os.path.join(ROOT, "benchmark", "references", "olmo_hybrid.py")).read()
-    assert "llm_mcp_tpu" not in src.split('"""', 2)[2]  # the docstring names the files
+    assert "llm_mcp_tpu" not in reference_source("olmo_hybrid")  # its docstring names the files
 
 
 def test_olmo_full_prefill_of_rows_of_unlike_lengths(olmo):
@@ -996,7 +991,7 @@ def test_the_harness_comparison_passes_the_olmo_program_and_refuses_its_controls
         assert correctness.hold_to_reference(olmo_ref, eng, ids, out)["worst_regret_rel"] < 1e-3
         for lower in olmo_ref.CONTROLS:
             olmo_ref.LOWER = lower
-            jax.clear_caches()
+            retrace(olmo_ref)
             if lower in ("fp8", "lost_state"):
                 with pytest.raises(AssertionError, match="under the reference's choice"):
                     correctness.hold_to_reference(olmo_ref, eng, ids, out)
@@ -1004,7 +999,7 @@ def test_the_harness_comparison_passes_the_olmo_program_and_refuses_its_controls
                 assert correctness.hold_to_reference(olmo_ref, eng, ids, out)["worst_regret_rel"] < 0.2
     finally:
         olmo_ref.LOWER = None
-        jax.clear_caches()
+        retrace(olmo_ref)
         eng.shutdown()
 
 
@@ -1013,11 +1008,7 @@ def test_the_harness_comparison_passes_the_olmo_program_and_refuses_its_controls
 
 @pytest.fixture(scope="module")
 def granite_ref():
-    spec = importlib.util.spec_from_file_location(
-        "granite_hybrid", os.path.join(ROOT, "benchmark", "references", "granite_hybrid.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return reference_for("granite_hybrid")
 
 
 def _unlike_ones(params, key=13):
@@ -1058,8 +1049,7 @@ GRANITE_TOL = 5e-6
 
 
 def test_the_granite_reference_shares_no_code_with_the_program():
-    src = open(os.path.join(ROOT, "benchmark", "references", "granite_hybrid.py")).read()
-    assert "llm_mcp_tpu" not in src.split('"""', 2)[2]  # the docstring names the files
+    assert "llm_mcp_tpu" not in reference_source("granite_hybrid")  # its docstring names the files
 
 
 def test_granite_full_prefill_of_rows_of_unlike_lengths(granite):
@@ -1126,12 +1116,12 @@ def test_granite_two_chunks_then_decode_through_cache_and_pool_in_a_reused_slot(
     if not quantized:
         # tight enough that a state rounded to bfloat16 after every token fails it
         granite_ref.LOWER = "state_bf16"
-        jax.clear_caches()
+        retrace(granite_ref)
         try:
             lower = granite_ref.logits(cfg, params, toks, np.arange(50, 66), np.arange(cfg.vocab_size))
         finally:
             granite_ref.LOWER = None
-            jax.clear_caches()
+            retrace(granite_ref)
         assert np.max(np.abs(np.stack(got) - lower)) > 4 * tol
 
 
@@ -1314,7 +1304,7 @@ def test_the_harness_comparison_passes_the_granite_program_and_refuses_its_contr
         assert correctness.hold_to_reference(granite_ref, eng, ids, out)["worst_regret_rel"] < 1e-3
         for lower in granite_ref.CONTROLS:
             granite_ref.LOWER = lower
-            jax.clear_caches()
+            retrace(granite_ref)
             if lower in ("fp8", "lost_state"):
                 with pytest.raises(AssertionError, match="under the reference's choice"):
                     correctness.hold_to_reference(granite_ref, eng, ids, out)
@@ -1322,7 +1312,7 @@ def test_the_harness_comparison_passes_the_granite_program_and_refuses_its_contr
                 assert correctness.hold_to_reference(granite_ref, eng, ids, out)["worst_regret_rel"] < 0.2
     finally:
         granite_ref.LOWER = None
-        jax.clear_caches()
+        retrace(granite_ref)
         eng.shutdown()
 
 

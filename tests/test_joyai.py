@@ -9,7 +9,6 @@ layer; the counts member from the step programs to `perf_stats()["experts"]`; th
 prediction module; the reference's controls."""
 
 import dataclasses
-import importlib.util
 import os
 
 import jax
@@ -27,6 +26,13 @@ from llm_mcp_tpu.models.llama import (
     llama_prefill_chunk_batch,
     llama_prefill_chunk_ragged,
 )
+
+from family import reference_for, reference_source, retrace, stepwise  # noqa: E402
+
+# every model call of this file is ONE trace and ONE compile a (configuration, shape):
+# called bare, a step dispatches its primitives one by one and lowers its kernels again
+llama_decode_step, llama_prefill, llama_prefill_chunk_batch, llama_prefill_chunk_ragged = map(
+    stepwise, (llama_decode_step, llama_prefill, llama_prefill_chunk_batch, llama_prefill_chunk_ragged))
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # float32 against float32, of logits whose largest is about 2: the program's
@@ -50,17 +56,9 @@ def _rel(got, want):
     return np.max(np.abs(got - want), axis=-1) / np.max(np.abs(want), axis=-1)
 
 
-def _load(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(ROOT, "benchmark", "references", name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 @pytest.fixture(scope="module")
 def ref():
-    return _load("joyai_flash")
+    return reference_for("joyai_flash")
 
 
 def _unlike_ones(params, key=13):
@@ -99,8 +97,7 @@ def _float32_products():
 
 
 def test_the_reference_shares_no_code_with_the_program():
-    src = open(os.path.join(ROOT, "benchmark", "references", "joyai_flash.py")).read()
-    assert "llm_mcp_tpu" not in src.split('"""', 2)[2]  # the docstring names the files
+    assert "llm_mcp_tpu" not in reference_source("joyai_flash")  # its docstring names the files
 
 
 def test_the_presets_are_the_published_structure(model):
@@ -315,12 +312,12 @@ def test_the_controls_the_configuration_states_pass_and_the_others_do_not(model,
     try:
         for control in ref.CONTROLS:
             ref.LOWER = control
-            jax.clear_caches()
+            retrace(ref)
             got = ref.logits(cfg, params, toks, np.arange(96), np.arange(cfg.vocab_size))
             moved[control] = float(np.median(_rel(got, want)))
     finally:
         ref.LOWER = None
-        jax.clear_caches()
+        retrace(ref)
     assert moved["bf16"] < TOL_INT8 and moved["int8_latent"] < TOL_INT8, moved
     for control in ("fp8", "no_scale", "rope_halves"):
         assert moved[control] > 0.2, moved

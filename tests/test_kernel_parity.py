@@ -19,6 +19,7 @@ import numpy as np
 import pytest
 
 import llm_mcp_tpu.kernels.attention as A
+from family import compiled_once
 from llm_mcp_tpu.models.quant import pack_scales, scale_pack_width
 
 FILLS = (0.0, 0.4, 0.9)
@@ -118,7 +119,6 @@ def test_q8_gqa_blocked_parity(monkeypatch, case, pack, block, ids_kind, form):
     rows = PIPELINE_CASES[case]
     monkeypatch.setenv("LLM_MCP_TPU_Q8_DECODE", "blocked")
     monkeypatch.setenv("LLM_MCP_TPU_Q8_SCALE_PACK", pack)
-    A.decode_attend_q8.clear_cache()  # env knobs are read at trace time
     rng = np.random.default_rng(7)
     abreast = form == "abreast"
     if rows is None:
@@ -134,20 +134,26 @@ def test_q8_gqa_blocked_parity(monkeypatch, case, pack, block, ids_kind, form):
     nk = jnp.asarray(rng.standard_normal((B, Hkv, hd)), jnp.float32)
     nv = jnp.asarray(rng.standard_normal((B, Hkv, hd)), jnp.float32)
     ids = jnp.asarray(rng.permutation(B), jnp.int32) if ids_kind else None
-    out = A.decode_attend_q8(
-        q, nk, nv, ck, cv, jnp.int32(1), lens, slot_ids=ids, interpret=True, **kw
-    )
-    ref = A._decode_attend_q8_fallback(
-        q, nk, nv, ck, cv, jnp.int32(1), lens, hd**-0.5, ids
-    )
+
+    def kernel(q, nk, nv, ck, lens, ids):
+        A.decode_attend_q8.clear_cache()  # env knobs are read at trace time
+        return A.decode_attend_q8(
+            q, nk, nv, ck, {}, jnp.int32(1), lens, slot_ids=ids, interpret=True, **kw)
+
+    # the cases of one (knobs, shapes) differ in the lengths alone: one executable
+    key = ("q8_blocked", pack, block, ids_kind, form, B, S)
+    out = compiled_once(key, kernel, q, nk, nv, ck, lens, ids)
+    ref = compiled_once(
+        key + ("fallback",),
+        lambda q, nk, nv, ck, lens, ids: A._decode_attend_q8_fallback(
+            q, nk, nv, ck, {}, jnp.int32(1), lens, hd**-0.5, ids),
+        q, nk, nv, ck, lens, ids)
     seated = (lens < S)[:, None, None, None]  # a parked row's output is discarded
     # tolerance covers the kernel's q/prob int8 requantization
     assert float(jnp.max(jnp.abs(jnp.where(seated, out - ref, 0.0)))) < 0.05
     assert not bool(jnp.isnan(jnp.where(seated, out, 0.0)).any())
     if abreast:  # a row of two heads gives each head what its own row gave it
-        apart = A.decode_attend_q8(
-            q, nk, nv, _heads_apart(ck), cv, jnp.int32(1), lens, slot_ids=ids,
-            interpret=True, **kw)
+        apart = compiled_once(key + ("apart",), kernel, q, nk, nv, _heads_apart(ck), lens, ids)
         np.testing.assert_allclose(
             np.asarray(jnp.where(seated, out, 0.0)), np.asarray(jnp.where(seated, apart, 0.0)),
             rtol=0, atol=1e-6)
@@ -202,7 +208,6 @@ def test_block_attend_q8_parity(monkeypatch, case, ids_kind, form, pack):
     two heads of 64 abreast (P = 2); the scales packed beside the payload and
     copied apart."""
     monkeypatch.setenv("LLM_MCP_TPU_Q8_SCALE_PACK", pack)
-    A.block_attend_q8.clear_cache()  # the knob is read at trace time
     rng = np.random.default_rng(11)
     rows = BLOCK_STARTS[case]
     abreast = form == "heads_of_64_abreast"
@@ -215,10 +220,19 @@ def test_block_attend_q8_parity(monkeypatch, case, ids_kind, form, pack):
     q = jnp.asarray(rng.standard_normal((B, L, Hkv, G, hd)), jnp.float32)
     ks = jnp.asarray(rng.standard_normal((B, Hkv, L, hd)), jnp.float32)
     vs = jnp.asarray(rng.standard_normal((B, Hkv, L, hd)), jnp.float32)
-    with jax.default_matmul_precision("highest"):
-        out = A.block_attend_q8(
+
+    def kernel(q, ks, vs, ck, starts, ids):
+        A.block_attend_q8.clear_cache()  # the knob is read at trace time
+        return A.block_attend_q8(
             q, ks, vs, ck, jnp.int32(1), starts, slot_ids=ids, interpret=True, block_s=BS)
-        want = _block_attend_reference(q, ks, vs, ck, 1, starts, hd**-0.5, ids)
+
+    with jax.default_matmul_precision("highest"):
+        # the cases of one (knob, form, batch) differ in the starts alone: one executable
+        out = compiled_once(("block_attend", pack, form, ids_kind, B), kernel, q, ks, vs, ck, starts, ids)
+        want = compiled_once(
+            ("block_attend_reference", form, ids_kind, B),
+            lambda q, ks, vs, ck, starts, ids: _block_attend_reference(q, ks, vs, ck, 1, starts, hd**-0.5, ids),
+            q, ks, vs, ck, starts, ids)
     assert out.shape == want.shape and not bool(jnp.isnan(out).any())
     seated = (starts < S)[:, None, None, None, None]  # a parked row's output is discarded
     # float32 on both sides: the blocks' online softmax against the whole one

@@ -7,7 +7,6 @@ prompt, bucketed chunks, decode through cache and tails, a mixed step, a reused
 slot), and the engine's slot life-cycle around a pool of kilobytes."""
 
 import dataclasses
-import importlib.util
 import os
 
 import jax
@@ -26,6 +25,13 @@ from llm_mcp_tpu.models.llama import (
     llama_prefill_chunk_batch,
 )
 
+from family import reference_for, reference_source, retrace, stepwise  # noqa: E402
+
+# every model call of this file is ONE trace and ONE compile a (configuration, shape):
+# called bare, a step dispatches its primitives one by one and lowers its kernels again
+llama_decode_step, llama_prefill, llama_prefill_chunk_batch = map(
+    stepwise, (llama_decode_step, llama_prefill, llama_prefill_chunk_batch))
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # float32 against float32, of logits whose largest is about 3: the program's
 # chunked products and the reference's whole-sequence ones differ by rounding
@@ -34,17 +40,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOL = 1e-4
 
 
-def _load(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(ROOT, "benchmark", "references", name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
 @pytest.fixture(scope="module")
 def ref():
-    return _load("lfm2_moe")
+    return reference_for("lfm2_moe")
 
 
 def _unlike_ones(params, key=13):
@@ -85,8 +83,7 @@ def _float32_products():
 
 
 def test_the_reference_shares_no_code_with_the_program():
-    src = open(os.path.join(ROOT, "benchmark", "references", "lfm2_moe.py")).read()
-    assert "llm_mcp_tpu" not in src.split('"""', 2)[2]  # the docstring names the files
+    assert "llm_mcp_tpu" not in reference_source("lfm2_moe")  # its docstring names the files
 
 
 def test_the_preset_is_the_published_shape(model):
@@ -377,12 +374,12 @@ def test_the_tolerance_refuses_a_lowered_forward(model, ref, lower, least):
     got, _, _ = _decode(cfg, params, ck, cv, toks, 50, 16, slot=0)
     assert np.max(np.abs(got - want[50:66])) < TOL
     ref.LOWER = lower
-    jax.clear_caches()
+    retrace(ref)
     try:
         lowered = ref.logits(cfg, params, toks, np.arange(49, 66), np.arange(cfg.vocab_size))[1:]
     finally:
         ref.LOWER = None
-        jax.clear_caches()
+        retrace(ref)
     assert np.max(np.abs(got - lowered)) > least * TOL
 
 
@@ -430,6 +427,7 @@ def test_engine_serves_the_references_choice_whole_chunked_and_in_reused_slots(e
     eng = engine
     allowed = np.flatnonzero(np.asarray(eng._allowed_mask))
     prompts = ["amber basil", "x" * 70 + " cedar dune ember", "y" * 45, "fjord grove " * 6, "kelp"]
+    admitted = eng.perf_stats()["state_pool"]["admitted_total"]  # (the module's engine: what other cases of this worker were served)
     for prompt in prompts:
         ids, out = _serve(eng, prompt)
         seq = ids + out[:-1]
@@ -445,7 +443,7 @@ def test_engine_serves_the_references_choice_whole_chunked_and_in_reused_slots(e
     cfg = eng.cfg
     assert pool["layout"] == {"conv": [8, 2, 2 * cfg.dim]} and "S" not in pool["layout"]
     assert pool["bytes"] == 8 * 2 * 2 * cfg.dim * 4 == pool["bytes_per_slot"] * 2
-    assert pool["admitted_total"] == len(prompts) > pool["slots"] and pool["live_slots"] == 0
+    assert pool["admitted_total"] - admitted == len(prompts) > pool["slots"] and pool["live_slots"] == 0
     assert (eng.state_dtype, eng.weights_dtype, eng.expert_dtype) == ("float32",) * 3
     assert eng._layout.slot_member == "state" and eng._layout.name == "gqa_int8"
     assert not any(eng._runs(f) for f in ("prefix_cache", "offload", "migration", "speculation",
@@ -476,7 +474,7 @@ def test_the_controls_fail_correct_on_the_twin(engine, ref):
     try:
         for lower in ref.CONTROLS:
             ref.LOWER = lower
-            jax.clear_caches()
+            retrace(ref)
             try:
                 correctness.hold_to_reference(ref, engine, ids, out)
                 refused[lower] = False
@@ -485,5 +483,5 @@ def test_the_controls_fail_correct_on_the_twin(engine, ref):
                 refused[lower] = True
     finally:
         ref.LOWER = None
-        jax.clear_caches()
+        retrace(ref)
     assert refused == {"fp8": True, "lost_tail": True, "no_gate": True, "bias_weighs": False}

@@ -11,36 +11,20 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from llm_mcp_tpu.executor import GenerationEngine
+from family import device_state as _state, engine_for, engine_of_its_own, restore as _restore
 from llm_mcp_tpu.executor.engine import GenRequest
 from llm_mcp_tpu.kernels.attention import fused_q8_heads
 
 S, B, K = 128, 8, 2
 
 
-_built: list = []  # the engines of the test that is running (_engines_end)
-
-
-def _engine(monkeypatch, model="tiny-llm", attn="pallas", **kw):
-    monkeypatch.setenv("LLM_MCP_TPU_ATTN", attn)
+def _engine(monkeypatch, model="tiny-llm", attn="pallas", own=False, **kw):
+    """The module's engine of these options as it was built (tests/family.py): a
+    case that only calls its step programs shares it; one that starts it, queues
+    requests or patches it takes one of its `own`."""
     kw = {"max_slots": B, "max_seq_len": S, "dtype": jnp.float32, "decode_chunk": K,
           "quant": "int8", "kv_quant": "int8", "prefill_chunk": 64, **kw}
-    _built.append(GenerationEngine(model, **kw))
-    return _built[-1]
-
-
-@pytest.fixture(autouse=True)
-def _engines_end():
-    """Every engine a test built ends with it (a file that imports `_engine`
-    imports this too). An engine that is built and never started keeps its
-    watchdog's thread, the thread keeps the engine, and the engine its
-    executables: 3,000-4,500 memory maps a hybrid test that never came back,
-    65,000 of 65,530 by the end of tests/test_mixed_round_hybrid.py in one
-    process, and past the limit the next compile or load segfaulted (PR 54;
-    the driver's `--dist load` deals every file's tests to every worker)."""
-    yield
-    while _built:  # a started engine's test shuts it down itself; this ends a built one's watchdog
-        _built.pop()._stop_evt.set()
+    return (engine_of_its_own if own else engine_for)(monkeypatch, model, attn, **kw)
 
 
 def _prompt(rng, n):
@@ -58,16 +42,6 @@ def _seed_rows(eng, rng, rows):
         eng._ops["admit"](tokens, ipack, np.asarray([0.0, 1.0], np.float32))
         lengths[slot] = n
     return lengths
-
-
-def _state(eng):
-    return jax.tree.map(np.asarray, (eng._ck, eng._cv, eng._d_temp, eng._d_topk,
-                                     eng._d_topp, eng._d_last_tok))
-
-
-def _restore(eng, state):
-    (eng._ck, eng._cv, eng._d_temp, eng._d_topk, eng._d_topp,
-     eng._d_last_tok) = jax.tree.map(jnp.asarray, state)
 
 
 def _ride_arrays(eng, prompts, slots, counter, rung):
@@ -266,7 +240,7 @@ def test_a_queued_request_rides_a_round_beside_active_rows(monkeypatch):
 def rides_beside_active_rows(monkeypatch, model):
     monkeypatch.setenv("TPU_PERF_SAMPLE", "1")
     kw = {} if model == "tiny-llm" else {"model": model, "quant": ""}
-    eng = _engine(monkeypatch, max_slots=4, decode_chunk=2, **kw).start()
+    eng = _engine(monkeypatch, own=True, max_slots=4, decode_chunk=2, **kw).start()
     try:
         alone = eng.generate("the quick brown fox rides along", max_tokens=10, temperature=0.0)
         # three of four rows decoding: a full-batch round (pow2 of 3 = 4)
@@ -324,7 +298,7 @@ def test_what_may_not_ride_takes_admit_fn_and_says_why(monkeypatch, why):
         kw = dict(max_slots=4)
     if why == "over the cap":
         kw.update(max_seq_len=512, prefill_chunk=512)
-    eng = _engine(monkeypatch, **kw).start()
+    eng = _engine(monkeypatch, own=True, **kw).start()
     try:
         busy = []
         if reason != "no active rows":
@@ -368,7 +342,7 @@ def test_every_mixed_shape_the_engine_dispatches_is_in_the_zoo(monkeypatch):
 
 def every_mixed_shape_is_in_the_zoo(monkeypatch, model):
     base = {} if model == "tiny-llm" else {"model": model, "quant": ""}
-    eng = _engine(monkeypatch, max_seq_len=256, **base)
+    eng = _engine(monkeypatch, own=True, max_seq_len=256, **base)
     phys = eng._phys is not None
     zoo = eng.warmup_shape_zoo()
     # a configuration with recurrent layers rides at the largest rung alone
@@ -389,7 +363,7 @@ def every_mixed_shape_is_in_the_zoo(monkeypatch, model):
     # admit_fn lists no mixed step and refuses a prior that carries one
     short = _engine(monkeypatch, max_seq_len=128, **base)
     assert [k[0] for ph, k in short.warmup_shape_zoo() if ph == "mixed"] == [128]
-    xla = _engine(monkeypatch, attn="xla", **base)
+    xla = _engine(monkeypatch, attn="xla", own=True, **base)
     assert xla._ride_off() == "other"
     assert not [ph for ph, _ in xla.warmup_shape_zoo() if ph == "mixed"]
     assert not xla._warmup_key_fits("mixed", (128, False))
@@ -432,7 +406,7 @@ def test_a_round_carries_prompts_only_at_a_full_batch_with_nothing_ahead_of_the_
     held back for a ride would hold both) keep the iteration on admit_fn."""
     from types import SimpleNamespace
 
-    eng = _engine(monkeypatch, max_slots=16)
+    eng = _engine(monkeypatch, own=True, max_slots=16)
     assert not eng._round_carries(0, None) and eng._ride_state == "no active rows"
     assert not eng._round_carries(2, None) and eng._ride_state == "compact"
     assert eng._round_carries(9, None) and eng._ride_state == "other"
@@ -448,7 +422,7 @@ def test_a_staged_batch_is_cut_at_the_cap_and_keeps_the_queues_order(monkeypatch
     does not leads the queue again, and a prompt that may not ride (here: a
     logit bias, whose first token is read at once) stops the staging and stays
     queued for `_admit_pending`."""
-    eng = _engine(monkeypatch, max_seq_len=512, prefill_chunk=512, max_slots=8)
+    eng = _engine(monkeypatch, own=True, max_seq_len=512, prefill_chunk=512, max_slots=8)
     mk = lambda n, **kw: GenRequest(prompt_ids=list(range(3, 3 + n)), max_tokens=4, **kw)  # noqa: E731
     reqs = [mk(100), mk(100), mk(100), mk(20)]
     for r in reqs:

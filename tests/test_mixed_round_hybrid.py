@@ -1,26 +1,28 @@
 """Admitted prompts riding a decode round in a configuration with recurrent
 layers (`models/hybrid.py:hybrid_mixed_step`, PR 42): held to `admit_fn`
 followed by a plain round for the four kinds of recurrent layer (the fourth, a
-gated short convolution, has a tail and no matrix state: PR 52), and the
-engine's loop, zoo and warm-up plan with such a preset. The helpers and the
-dense family's cases are tests/test_mixed_round.py's."""
+gated short convolution, has a tail and no matrix state, rope on its attention
+layers and two leading dense layers with pool rows of their own: PR 52), and
+the engine's loop, zoo and warm-up plan with such a preset. The helpers and the
+dense family's cases are tests/test_mixed_round.py's. A kind's cases run
+together (`scope="module"` groups them) and share the kind's engine, which ends
+before the next kind's is built (tests/family.py: one preset's engines at a
+time; while every case built its own and none ended, the fifth preset's had to
+run in a process of their own)."""
 
 import numpy as np
 import pytest
 
 from llm_mcp_tpu.kernels.attention import fused_q8_heads
 
-from test_mixed_round import (  # noqa: F401 (_engines_end: an autouse fixture)
-    B, K, S, _admit_arrays, _engine, _engines_end, _prompt, _restore, _ride_arrays, _seed_rows, _state,
+from test_mixed_round import (
+    B, K, S, _admit_arrays, _engine, _prompt, _restore, _ride_arrays, _seed_rows, _state,
     every_mixed_shape_is_in_the_zoo, rides_beside_active_rows,
     test_the_plans_module_is_the_one_the_live_call_lowers as plans_module_is_the_live_calls)
 
 HYBRIDS = {"kda": "tiny-solar", "gdn": "tiny-olmo-hybrid", "ssm": "tiny-granite-hybrid",
            "conv": "tiny-lfm2"}
-# the kinds this file runs; "conv" runs the same bodies from tests/test_mixed_round_lfm2.py,
-# a file and so (xdist's `loadfile`) a process of its own: one more preset's engines
-# in this process and the XLA:CPU loader segfaulted reading a cached executable
-KINDS = ("kda", "gdn", "ssm")
+every_kind = pytest.mark.parametrize("kind", list(HYBRIDS), scope="module")
 HYBRID_CASES = {
     # name: (decoding rows {slot: length}, prompt lengths, their slots)
     "one_prompt": ({0: 20, 1: 33, 3: 9}, [37], [2]),
@@ -51,7 +53,7 @@ def _kv_close(eng, ck, ck_ref, slot, n):
 
 
 @pytest.mark.parametrize("case", list(HYBRID_CASES))
-@pytest.mark.parametrize("kind", KINDS)
+@every_kind
 def test_a_hybrid_mixed_round_is_admit_fn_and_a_plain_round(monkeypatch, kind, case):
     """What admit_fn then decode_chunk_fn leave, mixed_round_fn leaves in a
     configuration with recurrent layers of each kind: the prompts' first
@@ -133,7 +135,7 @@ def test_a_hybrid_mixed_round_is_admit_fn_and_a_plain_round(monkeypatch, kind, c
         assert "moe" not in got[1]
 
 
-@pytest.mark.parametrize("kind", KINDS)
+@every_kind
 def test_two_prompts_packed_in_one_rung_do_not_see_each_other(monkeypatch, kind):
     """A prompt packed behind another leaves the KV rows, the state and the
     convolution tail it leaves riding alone, and takes the same first token:
@@ -166,15 +168,16 @@ def test_two_prompts_packed_in_one_rung_do_not_see_each_other(monkeypatch, kind)
 # -- the engine's loop, its zoo and its plan with recurrent layers -------------------------
 
 
-@pytest.mark.parametrize("kind", KINDS)
+@every_kind
 def test_a_queued_request_rides_a_round_with_recurrent_layers(monkeypatch, kind):
     rides_beside_active_rows(monkeypatch, HYBRIDS[kind])
 
 
-def test_every_mixed_shape_of_a_recurrent_configuration_is_in_the_zoo(monkeypatch):
-    every_mixed_shape_is_in_the_zoo(monkeypatch, "tiny-granite-hybrid")
+@pytest.mark.parametrize("kind", ["ssm", "conv"])
+def test_every_mixed_shape_of_a_recurrent_configuration_is_in_the_zoo(monkeypatch, kind):
+    every_mixed_shape_is_in_the_zoo(monkeypatch, HYBRIDS[kind])
 
 
-@pytest.mark.parametrize("kind", ["gdn", "kda"])
+@every_kind
 def test_the_plans_hybrid_mixed_round_is_the_one_the_live_call_lowers(monkeypatch, kind):
     plans_module_is_the_live_calls(monkeypatch, "mixed", HYBRIDS[kind])

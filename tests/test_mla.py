@@ -20,6 +20,12 @@ from llm_mcp_tpu.models import (
     llama_prefill,
 )
 
+from family import stepwise  # noqa: E402
+
+# every model call of this file is ONE trace and ONE compile a (configuration, shape):
+# called bare, a step dispatches its primitives one by one and lowers its kernels again
+llama_decode_step, llama_prefill = map(stepwise, (llama_decode_step, llama_prefill))
+
 
 @pytest.fixture(scope="module")
 def setup():

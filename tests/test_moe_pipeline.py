@@ -25,6 +25,12 @@ from llm_mcp_tpu.parallel.mesh import make_mesh, mesh_axis_sizes
 from llm_mcp_tpu.parallel.sharding import llama_param_specs, shard_pytree
 from llm_mcp_tpu.parallel.pipeline import pipeline_prefill, stack_stages
 
+from family import stepwise  # noqa: E402
+
+# every model call of this file is ONE trace and ONE compile a (configuration, shape):
+# called bare, a step dispatches its primitives one by one and lowers its kernels again
+llama_decode_step, llama_prefill = map(stepwise, (llama_decode_step, llama_prefill))
+
 MOE = get_config("tiny-moe")
 DENSE = get_config("tiny-llm")
 

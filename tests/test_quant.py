@@ -20,6 +20,12 @@ from llm_mcp_tpu.models.quant import (
     quantized_bytes,
 )
 
+from family import stepwise  # noqa: E402
+
+# every model call of this file is ONE trace and ONE compile a (configuration, shape):
+# called bare, a step dispatches its primitives one by one and lowers its kernels again
+llama_decode_step, llama_prefill = map(stepwise, (llama_decode_step, llama_prefill))
+
 
 def test_quantize_weight_roundtrip_error_bounded():
     key = jax.random.PRNGKey(0)

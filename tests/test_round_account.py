@@ -18,7 +18,7 @@ from llm_mcp_tpu.telemetry import perf
 from llm_mcp_tpu.telemetry import recorder as flight
 from llm_mcp_tpu.telemetry.perf import STALL_ROWS, STALL_S, RoundAccount
 from llm_mcp_tpu.telemetry.recorder import CompileLedger, FlightRecorder
-from test_mixed_round import _drain, _engine, _engines_end, _submit, _wait_active  # noqa: F401 (a fixture)
+from test_mixed_round import _drain, _engine, _submit, _wait_active
 
 K = 2  # decode_chunk
 PHASES = dict.fromkeys(("dispatch", "fetch", "admit", "prefill", "emit", "idle"), 0.0)
@@ -254,7 +254,7 @@ def test_a_round_ends_at_the_first_read_that_waited_for_its_program(bare):
 def test_the_account_is_the_rings_count_of_a_served_batch_with_rides(monkeypatch, tmp_path):
     rec = FlightRecorder(capacity=8192, dump_dir=str(tmp_path))
     prev = flight.set_recorder(rec)
-    eng = _engine(monkeypatch, max_slots=4, decode_chunk=K).start()
+    eng = _engine(monkeypatch, own=True, max_slots=4, decode_chunk=K).start()
     try:
         eng.generate("warm the shapes", max_tokens=6, temperature=0.0)
         long = [_submit(eng, f"row {i} keeps decoding for a while", max_tokens=120) for i in range(3)]

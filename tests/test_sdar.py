@@ -12,7 +12,6 @@ last is fetched; and with `block_len` 0 the programs of the causal presets as
 they were."""
 
 import dataclasses
-import importlib.util
 import os
 
 import jax
@@ -20,6 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import cell_programs
+from family import reference_for, reference_source, retrace, stepwise
 import llm_mcp_tpu.kernels.attention as A
 from llm_mcp_tpu.models import llama, moe
 from llm_mcp_tpu.models.configs import get_config
@@ -32,19 +33,13 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # logit by 0.1 and more (`test_the_controls_...`)
 TOL = 1e-4
 L = 4
-
-
-def _load(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(ROOT, "benchmark", "references", name + ".py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+# a reply driven by hand calls a pass after a pass at one shape: one trace and one compile
+block_pass, block_denoise = stepwise(llama.block_pass), stepwise(llama.block_denoise)
 
 
 @pytest.fixture(scope="module")
 def ref():
-    return _load("sdar_moe")
+    return reference_for("sdar_moe")
 
 
 def _unlike_ones(params, key=13):
@@ -118,8 +113,7 @@ def _filled(cfg, params, toks, n, slots=2, seq=128, quantized=False):
 
 
 def test_the_reference_shares_no_code_with_the_program():
-    src = open(os.path.join(ROOT, "benchmark", "references", "sdar_moe.py")).read()
-    assert "llm_mcp_tpu" not in src.split('"""', 2)[2]  # the docstring names the files
+    assert "llm_mcp_tpu" not in reference_source("sdar_moe")  # its docstring names the files
 
 
 def test_the_presets_are_the_published_structure(model):
@@ -183,12 +177,12 @@ def test_the_causal_mask_is_another_program(model, ref):
     rows read otherwise, by far more than rounding."""
     cfg, params, toks, want = model
     ref.LOWER = "causal"
-    jax.clear_caches()
+    retrace(ref)
     try:
         causal = ref.forward(cfg, params, toks[:40])
     finally:
         ref.LOWER = None
-        jax.clear_caches()
+        retrace(ref)
     assert np.max(np.abs(causal - want[:40])) > 0.05
 
 
@@ -269,9 +263,9 @@ def _by_hand(cfg, params, ck, cv, first, start, key, temp, n_blocks, allowed=Non
             rng, sub = jax.random.split(rng)
             both = jnp.asarray(np.stack([block, block]))
             if attn_impl == "pallas":
-                other, _, _ = llama.block_pass(
+                other, _, _ = block_pass(
                     cfg, params, ck, cv, both, None, starts, live, commit=False, attn_impl="xla")
-            new, cv, lg = llama.block_denoise(
+            new, cv, lg = block_denoise(
                 cfg, params, ck, cv, both, None, starts, live, sub, t, k, p, allowed=allowed,
                 attn_impl=attn_impl)
             if attn_impl == "pallas":
@@ -279,6 +273,8 @@ def _by_hand(cfg, params, ck, cv, first, start, key, temp, n_blocks, allowed=Non
             passes.append((block, np.asarray(lg[1]), np.asarray(new[1])))
             block = np.asarray(new[1])
         final = jnp.asarray(np.stack([block, block]))
+        # (the commits dispatched bare, three a reply: compiled whole, an int8 entry on a
+        # rounding edge falls the other way on one arm and not on the other)
         if attn_impl == "pallas":  # the commit's reads and its writes, arm against arm
             _, ck_x, _ = llama.block_pass(
                 cfg, params, ck, cv, final, None, starts, live, commit=True, attn_impl="xla")
@@ -471,12 +467,12 @@ def test_the_controls_move_what_the_request_is_held_to(model, ref):
     assert np.max(np.abs(base[:2] - ref.forward(cfg, params, first, [34, 35], cols))) < TOL
     for control in ("no_commit", "causal", "fp8"):
         ref.LOWER = control
-        jax.clear_caches()
+        retrace(ref)
         try:
             moved = ref.logits(cfg, params, seq, rows, cols)
         finally:
             ref.LOWER = None
-            jax.clear_caches()
+            retrace(ref)
         far = np.max(np.abs(moved - base), axis=-1) / np.max(np.abs(base), axis=-1)
         assert np.median(far[2:]) > 0.02, (control, far)  # the first block sees the prompt alone
 
@@ -508,13 +504,13 @@ def test_the_harness_comparison_sees_a_lost_commit_and_a_causal_mask(ref, seed):
         assert notes["worst_regret_rel"] < ref.SERVED_TOL_REL / 2 and notes["tolerance"] == ref.SERVED_TOL_REL
         for control in ("no_commit", "causal"):
             ref.LOWER = control
-            jax.clear_caches()
+            retrace(ref)
             try:
                 with pytest.raises(AssertionError, match="under the reference's choice"):
                     correctness.hold_to_reference(ref, eng, ids, toks)
             finally:
                 ref.LOWER = None
-                jax.clear_caches()
+                retrace(ref)
     finally:
         eng.shutdown()
 
@@ -703,12 +699,12 @@ def test_the_engines_round_is_the_passes_driven_by_hand(ref, monkeypatch, attn_i
             while (block == cfg.mask_token_id).any():
                 rng, sub = jax.random.split(rng)
                 both = jnp.asarray(np.stack([first[0], block]))
-                new, cvh, _ = llama.block_denoise(
+                new, cvh, _ = block_denoise(
                     cfg, params, ck0, cvh, both, None, starts, live, sub,
                     jnp.asarray([0.0, 0.7]), jnp.zeros((2,), jnp.int32), jnp.ones((2,)),
                     allowed=eng._allowed_mask, attn_impl=attn_impl)
                 block, n = np.asarray(new[1]), n + 1
-            _, ckh, cvh = llama.block_pass(
+            _, ckh, cvh = block_pass(
                 cfg, params, ck0, cvh, jnp.asarray(np.stack([first[0], block])), None, starts, live,
                 commit=True, attn_impl=attn_impl)
             return block, n, ckh, cvh
@@ -956,20 +952,11 @@ def test_with_block_len_0_the_programs_are_the_parents(name):
     cfg = get_config(name)
     assert not cfg.block_len and not moe.share_form(cfg)
 
-    def i32(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32)
-
     params = jax.eval_shape(partial(llama.init_llama_params, cfg, jax.random.PRNGKey(0), dtype=jnp.float32))
     cache = jax.eval_shape(partial(llama.init_kv_cache, cfg, 4, 128, dtype=jnp.float32, quantized=True))
     assert cache["v"] == {}  # no counts ride a pair that states no share
-    programs = {
-        "decode": (lambda p, ck, cv, *a: llama.llama_decode_step(
-            cfg, p, ck, cv, *a, attn_impl="pallas"), (i32(4), i32(4))),
-        "chunk": (lambda p, ck, cv, *a: llama.llama_prefill_chunk_batch(
-            cfg, p, ck, cv, *a, skey=64), (i32(2, 32), i32(2), i32(2), i32(2))),
-        "prefill": (lambda p, ck, cv, *a: llama.llama_prefill(
-            cfg, p, *a, attn_impl="pallas", quant_kv=True), (i32(2, 64), i32(2))),
-    }
+    programs = {tag: p for tag, p in cell_programs.preset_steps(cfg, 4, 128, 4).items()
+                if tag in PARENTS[name]}
     got = {}
     with jax.default_matmul_precision("default"):  # as the parent's were lowered
         for tag, (fn, operands) in programs.items():

@@ -29,6 +29,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+import cell_programs
 from llm_mcp_tpu.kernels import attention as A
 
 L, B, HKV, G, HD, S, BT = 32, 32, 8, 4, 128, 2048, 64
@@ -299,151 +300,6 @@ def chip_kernels(monkeypatch):
     jax.clear_caches()
 
 
-@pytest.mark.parametrize(
-    "weights, attn, n_layers, slots, relaid",
-    [("int8", "pallas", 36, 32, ()), ("bf16", "pallas", 16, 8, ("wq", "wk")),
-     ("int8", "xla", 36, 32, ())],
-    ids=["int8", "bf16", "int8_xla_attention"],
-)
-def test_decode_step_reads_stacked_weights_in_place(
-    one_chip, chip_kernels, weights, attn, n_layers, slots, relaid
-):
-    """The decode round at Qwen3-8B widths, from shapes alone: 4 steps in a
-    scan as the engine's `decode_body` has them, int8 weights + int8 KV
-    (`_decode_step_q8`, the whole model), bf16 weights + bf16 KV
-    (`_decode_step_bf16`; 16 layers and 8 slots, so that bf16 fits the chip),
-    and the XLA-attention scan of `llama_decode_step` that windows, softcaps
-    and meshes take.
-    No loop body may make a weight-sized array in HBM: with `unroll=4` on the
-    layer scans each round copied every group of four layers' weights out of
-    the stacked tree (six instructions here, 0.74 GiB of temporaries, more
-    than half of a round's device time on the chip).
-
-    What the bf16 program still does, ONCE a round and outside both loops: the
-    compiler re-lays out the whole `wq` and `wk` stacks for the slices it
-    prefetches into on-chip memory (PERF.md §7). The case holds it to that."""
-    import dataclasses
-    import importlib.util
-    from functools import partial
-
-    from llm_mcp_tpu.models import llama, quant
-    from llm_mcp_tpu.models.configs import get_config
-
-    spec = importlib.util.spec_from_file_location(
-        "rehearse_tpu_compile",
-        os.path.join(os.path.dirname(__file__), "..", "scripts", "rehearse_tpu_compile.py"))
-    rehearse = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(rehearse)
-
-    cfg = dataclasses.replace(get_config("qwen3-8b"), n_layers=n_layers)
-
-    def init():
-        if weights == "bf16":
-            return llama.init_llama_params(cfg, jax.random.PRNGKey(0), dtype=BF)
-        p = quant.init_llama_params_quantized(cfg, jax.random.PRNGKey(0), scale_dtype=BF)
-        return quant.fuse_layer_weights(quant.quantize_params(p))
-
-    def decode_round(params, ck, cv, tokens, lengths):
-        def step(carry, _):
-            ck, cv, toks, lens = carry
-            logits, ck, cv = llama.llama_decode_step(
-                cfg, params, ck, cv, toks, lens, attn_impl=attn)
-            new = jnp.argmax(logits, axis=-1).astype(I32)
-            return (ck, cv, new, lens + 1), new
-
-        (ck, cv, _, _), out = jax.lax.scan(step, (ck, cv, tokens, lengths), None, length=4)
-        return out, ck, cv
-
-    params = jax.eval_shape(init)
-    cache = jax.eval_shape(partial(
-        llama.init_kv_cache, cfg, slots, S, dtype=BF, quantized=weights == "int8"))
-    params, cache = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), (params, cache))
-    rows = jax.ShapeDtypeStruct((slots,), I32, sharding=one_chip)
-
-    compiled = jax.jit(decode_round, donate_argnums=(1, 2)).lower(
-        params, cache["k"], cache["v"], rows, rows).compile()
-    text = compiled.as_text()
-    assert ("tpu_custom_call" in text) == (attn == "pallas")
-
-    made = rehearse.stacked_weight_producers(
-        text, rehearse.stacked_weight_dims(params["layers"]))
-    assert [m for m in made if m[0] != "ENTRY"] == [], "a loop body copies weights"
-    whole = [params["layers"][k] for k in relaid]
-    assert sorted(made) == sorted(
-        ("ENTRY", "copy", f"bf16[{','.join(map(str, w.shape))}]") for w in whole)
-    temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < (256 << 20) + sum(w.size * w.dtype.itemsize for w in whole)
-
-
-@pytest.mark.parametrize("rung", [128, 256])
-def test_mixed_round_fits_beside_decode_closed(one_chip, chip_kernels, rung):
-    """The mixed round at `decode_closed`'s shapes, from shapes alone as the
-    engine's `mixed_round_fn` has it: Qwen3-8B int8, 32 rows, the int8 cache at
-    2,048, the packed prompt buffer of one rung, 4 prompt rows, then 3 plain
-    steps in a scan. The cache is updated in place (the prompts' rows go in
-    through `write_prompt_rows` after the decode rows' append), no loop body
-    copies weights, and the program stays inside what the cell has left: its
-    peak is 13.77 GB of the chip's 15.75 GiB (PERF.md section 4), the plain round
-    compiles to 12.74 GiB and the ragged chunk programs to 13.34."""
-    import importlib.util
-    from functools import partial
-
-    from llm_mcp_tpu.models import llama, quant
-    from llm_mcp_tpu.models.configs import get_config
-
-    spec = importlib.util.spec_from_file_location(
-        "rehearse_tpu_compile",
-        os.path.join(os.path.dirname(__file__), "..", "scripts", "rehearse_tpu_compile.py"))
-    rehearse = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(rehearse)
-    cfg = get_config("qwen3-8b")
-    slots, R = 32, 4
-
-    def init():
-        p = quant.init_llama_params_quantized(cfg, jax.random.PRNGKey(0), scale_dtype=BF)
-        return quant.fuse_layer_weights(quant.quantize_params(p))
-
-    def mixed_round(params, ck, cv, tokens, lengths, p_tokens, p_rowids, p_positions,
-                    p_slots, p_last):
-        logits, ck, cv = llama.mixed_step_q8(
-            cfg, params, ck, cv, tokens, lengths, p_tokens, p_rowids, p_positions,
-            p_slots, p_last)
-        new = jnp.argmax(logits, axis=-1).astype(I32)
-
-        def step(carry, _):
-            ck, cv, toks, lens = carry
-            logits, ck, cv = llama.llama_decode_step(
-                cfg, params, ck, cv, toks, lens, attn_impl="pallas")
-            new = jnp.argmax(logits, axis=-1).astype(I32)
-            return (ck, cv, new, lens + 1), new
-
-        (ck, cv, _, _), out = jax.lax.scan(
-            step, (ck, cv, new[:slots], lengths + 1), None, length=3)
-        return jnp.concatenate([new[None, :slots], out]), new[slots:], ck, cv
-
-    params = jax.eval_shape(init)
-    cache = jax.eval_shape(partial(llama.init_kv_cache, cfg, slots, S, dtype=BF, quantized=True))
-    params, cache = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), (params, cache))
-    vec = lambda n: jax.ShapeDtypeStruct((n,), I32, sharding=one_chip)  # noqa: E731
-
-    compiled = jax.jit(mixed_round, donate_argnums=(1, 2)).lower(
-        params, cache["k"], cache["v"], vec(slots), vec(slots), vec(rung), vec(rung),
-        vec(rung), vec(R), vec(R)).compile()
-    text = compiled.as_text()
-    assert "tpu_custom_call" in text
-    made = rehearse.stacked_weight_producers(
-        text, rehearse.stacked_weight_dims(params["layers"]))
-    assert made == [], "the mixed round copies weights"
-    mem = compiled.memory_analysis()
-    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
-    # the cache and nothing weight-sized beside it in temporaries; the whole
-    # under the 13.34 GiB of the ragged programs the cell already holds
-    assert mem.temp_size_in_bytes < (512 << 20), mem.temp_size_in_bytes
-    assert total < int(13.34 * (1 << 30)), total
-
-
 # -- the hybrid decoder's step programs at the published Solar-Open2 widths ---------
 
 SOLAR_SLOTS, SOLAR_S = 64, 1024
@@ -524,411 +380,6 @@ def grouped_kernels_in(text: str) -> bool:
     return "%grouped_swiglu" in text and "%grouped_down" in text
 
 
-def hybrid_shapes(name: str, one_chip, slots: int, seq: int):
-    """(cfg, params, cache) of a hybrid preset as shapes on the described chip:
-    bf16 weights, int8 KV for the GQA layers, the float32 state pool beside it."""
-    from functools import partial
-
-    from llm_mcp_tpu.models import llama
-    from llm_mcp_tpu.models.configs import get_config
-
-    cfg = get_config(name)
-    params = jax.eval_shape(partial(llama.init_llama_params, cfg, jax.random.PRNGKey(0), dtype=BF))
-    cache = jax.eval_shape(partial(
-        llama.init_kv_cache, cfg, slots, seq, dtype=BF, quantized=True))
-    return (cfg, *jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip), (params, cache)))
-
-
-@pytest.fixture(scope="module")
-def solar(one_chip):
-    """`solar-open2-250b-ep8`, 64 slots x 1024."""
-    return hybrid_shapes("solar-open2-250b-ep8", one_chip, SOLAR_SLOTS, SOLAR_S)
-
-
-@pytest.fixture(scope="module")
-def olmo(one_chip):
-    """`olmo-hybrid-7b-d20`, 64 slots x 1024, as its cell boots it."""
-    return hybrid_shapes("olmo-hybrid-7b-d20", one_chip, SOLAR_SLOTS, SOLAR_S)
-
-
-def solar_program(which: str, cfg):
-    """The three step programs the cell dispatches, as the engine builds them
-    (`decode_body`'s scan of 4 steps; `admit_fn`'s prefill and row inserts; the
-    bucketed chunk), without the sampler."""
-    from llm_mcp_tpu.models import hybrid, llama
-
-    def decode(params, ck, cv, tokens, lengths, ids, steps=4):
-        def step(carry, _):
-            ck, cv, toks, lens = carry
-            logits, ck, cv = llama.llama_decode_step(
-                cfg, params, ck, cv, toks, lens, attn_impl="pallas", slot_ids=ids)
-            return (ck, cv, jnp.argmax(logits, axis=-1).astype(I32), lens + 1), None
-
-        (ck, cv, toks, _), _ = jax.lax.scan(step, (ck, cv, tokens, lengths), None, length=steps)
-        return toks, ck, cv
-
-    def admit(params, ck, cv, tokens, lengths, slots):
-        logits, ks, vs = llama.llama_prefill(
-            cfg, params, tokens, lengths, attn_impl="pallas", quant_kv=True)
-
-        def body(i, cc):
-            ck, cv = cc
-            ck = {"q": jax.lax.dynamic_update_slice(
-                      ck["q"], jax.lax.dynamic_slice_in_dim(ks["q"], i, 1, 1), (0, slots[i], 0, 0, 0)),
-                  "s": jax.lax.dynamic_update_slice(
-                      ck["s"], jax.lax.dynamic_slice_in_dim(ks["s"], i, 1, 1), (0, slots[i], 0, 0))}
-            return ck, dict(cv, **hybrid.insert_state_row(cv, vs, i, slots[i]))
-
-        ck, cv = jax.lax.fori_loop(0, tokens.shape[0], body, (ck, cv))
-        return logits, ck, hybrid.add_counts(cv, vs)
-
-    def chunk(params, ck, cv, tokens, slots, starts, nvalid):
-        return llama.llama_prefill_chunk_batch(
-            cfg, params, ck, cv, tokens, slots, starts, nvalid, skey=min(512, tokens.shape[1]))
-
-    def mixed(params, ck, cv, tokens, lengths, p_tokens, p_rowids, p_positions, p_slots, p_last):
-        # `mixed_round_fn`: the first step carries the packed prompts, three plain ones follow
-        logits, ck, cv = hybrid.hybrid_mixed_step(
-            cfg, params, ck, cv, tokens, lengths, p_tokens, p_rowids, p_positions, p_slots, p_last)
-        new = jnp.argmax(logits, axis=-1).astype(I32)
-        n = tokens.shape[0]
-        toks, ck, cv = decode(params, ck, cv, new[:n], lengths + 1, None, steps=3)
-        return toks, new[n:], ck, cv
-
-    return {"decode": decode, "admit": admit, "chunk": chunk, "mixed": mixed}[which]
-
-
-@pytest.mark.parametrize("which,operands", [
-    ("decode", [(64,), (64,), (64,)]),  # every slot a row
-    ("admit", [(4, 256), (4,), (4,)]),  # four prompts in the 256 bucket
-    ("chunk", [(2, 512), (2,), (2,), (2,)]),  # two prompts' second chunks of 512
-    ("admit", [(1, 64), (1,), (1,)]),  # the smallest prompts: few rows, as a decode round
-    ("admit", [(2, 256), (2,), (2,)]),  # the cell's largest admit program, 512 padded tokens
-])
-def test_solar_step_programs_fit_and_keep_state_and_banks_in_place(
-    sd, solar, chip_kernels, which, operands
-):
-    """Each compiles for the described v5e with its kernels (the state kernel
-    and the attention kernels in decode, the two grouped expert kernels in every
-    program since PR 45: the decode round's 64 rows through a window of one row
-    tile, with no fall to their reference), fits the chip, and makes no second
-    copy of the state pool (0.75 GiB) nor of an expert bank (a slice of a
-    stacked bank that feeds a grouped product was copied out, 0.39 GiB a bank
-    and layer, until the banks went in whole: models/moe.py): the decode
-    round's temporaries are 0.09 GiB. Bytes in PERF.md section 4 as
-    "described-chip compile"."""
-    cfg, params, cache = solar
-    falls = dict(A.reference_falls)
-    compiled = jax.jit(solar_program(which, cfg), donate_argnums=(1, 2)).lower(
-        params, cache["k"], cache["v"], *(sd(shape, I32) for shape in operands)).compile()
-    assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
-    text = compiled.as_text()
-    assert ("kda_decode_step" in text) == (which == "decode")
-    assert grouped_kernels_in(text)
-    mem = compiled.memory_analysis()
-    if which == "decode":
-        assert mem.temp_size_in_bytes < 0.21 * 2**30  # no bank copied out of the stack
-    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    print(f"solar {which}: {total / 2**30:.2f} GiB, of it temporaries "
-          f"{mem.temp_size_in_bytes / 2**30:.2f} GiB")
-    assert total < 15.75 * 2**30
-    assert mem.temp_size_in_bytes < 0.7 * 2**30
-    assert mem.alias_size_in_bytes > 0.9 * 2**30  # KV cache and state pool updated in place
-
-
-@pytest.mark.parametrize("which,operands", [
-    ("decode", [(64,), (64,), (64,)]),  # every slot a row
-    ("admit", [(4, 128), (4,), (4,)]),  # the cell's largest admit program, 512 padded tokens
-    ("chunk", [(2, 512), (2,), (2,), (2,)]),  # two prompts' second chunks of 512
-])
-def test_olmo_hybrid_step_programs_fit_beside_64_slots(sd, olmo, chip_kernels, which, operands):
-    """The decode round, an admit and a chunk program of `olmo-hybrid-7b-d20`
-    at its cell's 64 slots x 1024 compile for the described v5e with their
-    kernels: `gdn_decode_step` and the decode attention and append kernels at
-    30 KV heads, group 1, as Mosaic calls with no fall to their reference; the
-    flash prefill kernel in the admit program. Each fits under the 15.0 GiB at which
-    ISSUE 35 would have taken 48 slots, and updates the 2.38 GiB KV cache and
-    the 2.04 GiB state pool in place. The argument bytes hold the pool at its
-    logical size. GiB in PERF.md section 4 as "described-chip compile"."""
-    from llm_mcp_tpu.models import kda
-
-    cfg, params, cache = olmo
-    falls = dict(A.reference_falls)
-    compiled = jax.jit(solar_program(which, cfg), donate_argnums=(1, 2)).lower(
-        params, cache["k"], cache["v"], *(sd(shape, I32) for shape in operands)).compile()
-    assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
-    text = compiled.as_text()
-    # the bucketed chunk's attention over [past | self] is `jax.numpy` for every
-    # configuration (llama._chunk_attention: PERF.md section 7); the chunked
-    # recurrence of a prompt is the chunk kernel, one Mosaic call a layer
-    assert ("%gdn_chunk_scan" in text) == (which != "decode") and "%ssd_chunk_scan" not in text
-    assert ("%gdn_decode_step" in text) == (which == "decode") and "%kda_decode_step" not in text
-    if which == "decode":
-        assert "decode_attn_q8" in text and "append_kv_q8" in text
-    if which == "admit":
-        assert "flash_prefill_attn" in text
-    S = cache["v"]["state"]["S"]
-    assert S.shape == (15, 64, 15, 96, 384) and kda.state_abreast(cfg) == 2
-    mem = compiled.memory_analysis()
-    leaves = jax.tree.leaves((params, cache)) + [sd(shape, I32) for shape in operands]
-    logical = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in leaves)
-    assert mem.argument_size_in_bytes < logical * 1.002  # nothing pads: the pool least of all
-    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    print(f"olmo {which}: {total / 2**30:.2f} GiB, of it temporaries "
-          f"{mem.temp_size_in_bytes / 2**30:.2f} GiB, arguments "
-          f"{mem.argument_size_in_bytes / 2**30:.2f} GiB")
-    assert total < 15.0 * 2**30
-    assert mem.alias_size_in_bytes > 4.3 * 2**30  # KV cache and state pool updated in place
-
-
-@pytest.fixture(scope="module")
-def granite(one_chip):
-    """`granite-4.0-h-micro`, whole, 64 slots x 1024, as its cell boots it."""
-    return hybrid_shapes("granite-4.0-h-micro", one_chip, SOLAR_SLOTS, SOLAR_S)
-
-
-@pytest.mark.parametrize("which,operands", [
-    ("decode", [(64,), (64,), (64,)]),  # every slot a row
-    ("admit", [(4, 128), (4,), (4,)]),  # the cell's largest admit program, 512 padded tokens
-    ("admit", [(1, 64), (1,), (1,)]),  # and its smallest
-    ("chunk", [(2, 512), (2,), (2,), (2,)]),  # two prompts' second chunks of 512
-])
-def test_granite_step_programs_fit_beside_64_slots(sd, granite, chip_kernels, which, operands):
-    """The decode round, two admit programs and a chunk program of
-    `granite-4.0-h-micro` at its cell's 64 slots x 1024 compile for the
-    described v5e with their kernels: `ssd_decode_step` on the pool's
-    [36, 64, 32, 128, 128] (two heads of 64 values abreast), the decode attention
-    (both arms under the dispatcher's `cond`: the cache's heads of 64 lie two
-    abreast in rows of 128 lanes, which the blocked arm's copies cut) and the
-    append kernel on those rows, as Mosaic calls with no fall to their
-    reference; the flash prefill kernel in the admit programs. No program copies
-    the cache to another layout (at [4, 64, 17, 1024, 64] every one did, 0.27
-    GiB there and 0.27 back: the decode round's temporaries were 0.56 GiB). Each
-    fits under 15.0 GiB and updates the KV cache and the 4.5 GiB state pool in
-    place; the pool's bytes as the compiler lays it out are its logical bytes.
-    GiB in PERF.md section 4 as "described-chip compile"."""
-    from llm_mcp_tpu.models import ssm
-
-    cfg, params, cache = granite
-    falls = dict(A.reference_falls)
-    compiled = jax.jit(solar_program(which, cfg), donate_argnums=(1, 2)).lower(
-        params, cache["k"], cache["v"], *(sd(shape, I32) for shape in operands)).compile()
-    assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
-    text = compiled.as_text()
-    assert ("%ssd_chunk_scan" in text) == (which != "decode") and "%gdn_chunk_scan" not in text
-    assert ("%ssd_decode_step" in text) == (which == "decode")
-    assert "%kda_decode_step" not in text and "%gdn_decode_step" not in text
-    if which == "decode":
-        assert "decode_attn_q8_whole" in text and "append_kv_q8" in text
-        assert "decode_attn_q8_blocked" in text
-    if which == "admit":
-        assert "flash_prefill_attn" in text
-    S = cache["v"]["state"]["S"]
-    assert S.shape == (36, 64, 32, 128, 128) and ssm.state_abreast(cfg) == 2
-    assert cache["k"]["q"].shape == (4, 64, 9, 1024, 128)
-    assert cache_relayouts(text, cache["k"]["q"].shape) == []
-    pool = jax.jit(lambda s: s + 1.0).lower(S).compile().memory_analysis()
-    assert pool.argument_size_in_bytes == 36 * 64 * 64 * 128 * 64 * 4
-    mem = compiled.memory_analysis()
-    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    print(f"granite {which} {operands[0]}: {total / 2**30:.2f} GiB, of it temporaries "
-          f"{mem.temp_size_in_bytes / 2**30:.2f} GiB, arguments "
-          f"{mem.argument_size_in_bytes / 2**30:.2f} GiB")
-    assert total < 15.0 * 2**30
-    assert mem.alias_size_in_bytes > 4.5 * 2**30  # KV cache and state pool updated in place
-    if which == "decode":  # 0.05 GiB; 0.56 with the cache re-laid and back
-        assert mem.temp_size_in_bytes < 0.15 * 2**30
-
-
-@pytest.fixture(scope="module")
-def kexaone(one_chip):
-    """`k-exaone-236b-ep8`, 64 slots x 4096, as its cell boots it."""
-    return hybrid_shapes("k-exaone-236b-ep8", one_chip, SOLAR_SLOTS, 4096)
-
-
-@pytest.mark.parametrize("which,operands", [
-    ("decode", [(64,), (64,), (64,)]),  # every slot a row
-    ("admit", [(1, 1024), (1,), (1,)]),  # the cell's admit shapes: a prompt of 769-1024 tokens,
-    ("admit", [(1, 768), (1,), (1,)]),  # and one of 640-768
-    ("admit", [(2, 512), (2,), (2,)]),  # two shorter prompts: 1024 rows through the expert layer too
-    ("chunk", [(1, 1024), (1,), (1,), (1,)]),  # a prompt over 1024 tokens: its second chunk
-])
-def test_kexaone_step_programs_fit_and_keep_both_kinds_of_cache_in_place(
-        sd, kexaone, chip_kernels, which, operands):
-    """The decode round, the admit programs and a chunk program of
-    `k-exaone-236b-ep8` at its cell's 64 slots x 4096 compile for the described
-    v5e with their kernels: the decode attention in BOTH arms (the blocked or
-    whole-S arm over the global layer's cache, the window arm over the rings),
-    the append kernel twice (the cache, the rings), the flash prefill kernel in
-    the admit programs, as Mosaic calls with no fall to their reference. Window
-    layers hold a ring of 128 positions a slot and not 4096: the arguments are
-    the weights, 0.58 GB of the global layer's cache and 0.07 GB of rings. Each
-    program fits under 12 GiB and updates both kinds in place: no copy of a
-    whole cache member among the temporaries. Every program's expert layers are
-    the grouped kernels over a window of the pairs held here (one row tile for
-    the decode round's 64 rows since PR 45; 1,280 rows of the 8,192 a 1,024-row
-    prompt has: `moe.window_rows`), and the admit programs'
-    temporaries stay under 0.55 GiB: the sorted copies of all 8,192 pairs' rows
-    stood at 0.72 and 0.64 (PR 43's tree, 1 x 1024 and 2 x 512; 0.41 and 0.31
-    now). The chunk's 0.99 GiB are its attention over [past | self], the same
-    on both trees. GiB in PERF.md section 4 as "described-chip compile"."""
-    cfg, params, cache = kexaone
-    ring, full = cache["v"]["win"]["k"], cache["k"]
-    assert full["q"].shape == (1, 64, 17, 4096, 128) and ring["q"].shape == (4, 64, 17, 128, 128)
-    assert ring["s"].shape == (4, 64, 16, 128) and cache["v"]["win"]["v"] == {}
-    assert cache["v"]["moe"].shape == (2, 4, 5) and "state" not in cache["v"]
-    falls = dict(A.reference_falls)
-    compiled = jax.jit(solar_program(which, cfg), donate_argnums=(1, 2)).lower(
-        params, cache["k"], cache["v"], *(sd(shape, I32) for shape in operands)).compile()
-    assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
-    text = compiled.as_text()
-    if which == "decode":
-        assert "decode_attn_win_q8" in text and "decode_attn_q8_blocked" in text
-        assert text.count("append_kv_q8") >= 2
-    if which == "admit":
-        assert "flash_prefill_attn" in text and "decode_attn" not in text
-    assert grouped_kernels_in(text)
-    mem = compiled.memory_analysis()
-    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    print(f"kexaone {which} {operands[0]}: {total / 2**30:.2f} GiB, of it temporaries "
-          f"{mem.temp_size_in_bytes / 2**30:.2f} GiB, arguments "
-          f"{mem.argument_size_in_bytes / 2**30:.2f} GiB")
-    caches = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
-    weights = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(params))
-    assert round(weights / 1e9, 2) == 7.42 and 0.64e9 < caches < 0.66e9
-    assert mem.argument_size_in_bytes < weights + caches + 2**20  # rings, not 4 x 4096 positions
-    limit = {"decode": 0.7, "admit": 0.55, "chunk": 1.1}[which]
-    assert total < 12.0 * 2**30 and mem.temp_size_in_bytes < limit * 2**30
-    assert mem.alias_size_in_bytes > 0.99 * caches  # the cache and the rings updated in place
-
-
-@pytest.fixture(scope="module")
-def lfm2(one_chip):
-    """`lfm2-8b-a1b-d14`, 64 slots x 1024, as its cell boots it."""
-    return hybrid_shapes("lfm2-8b-a1b-d14", one_chip, SOLAR_SLOTS, SOLAR_S)
-
-
-@pytest.mark.parametrize("which,operands", [
-    ("decode", [(64,), (64,), (64,)]),  # every slot a row
-    ("admit", [(4, 128), (4,), (4,)]),  # the cell's largest admit program, 512 padded tokens
-    ("admit", [(1, 64), (1,), (1,)]),  # and its smallest
-    ("chunk", [(2, 512), (2,), (2,), (2,)]),  # two prompts' second chunks of 512
-    ("mixed", 128), ("mixed", 256),  # the round that carries prompts, at both rungs
-])
-def test_lfm2_step_programs_fit_with_the_banks_whole_and_the_tails_in_place(
-    sd, lfm2, chip_kernels, which, operands
-):
-    """The decode round of 64 rows, the admit programs the traffic meets, a
-    chunk program and the mixed round at both rungs of `lfm2-8b-a1b-d14` (the
-    published widths, 14 layers, all 32 experts of 2048 x 1792 a layer) at its
-    cell's 64 slots x 1024 compile for the described v5e: the two grouped expert
-    kernels at banks of [2048, 1792] (one column block of two banks, 14.7 MB) and
-    [1792, 2048], the decode attention (both arms: heads of 64 WITH rotation,
-    two abreast in rows of 128 lanes) and the append kernel on those rows, the
-    flash prefill kernel in the admit programs, every one a Mosaic call with no
-    fall to its reference. No program copies the cache to another layout. Each
-    fits the chip;
-    the temporaries hold no copy of a layer's banks (0.66 GiB a layer; the
-    stack goes in whole) nor of a leading layer's feed-forward (84 MB, unstacked:
-    a slice of a stack at a fixed index was copied out every step), and the KV
-    cache and the tails (5.5 MiB: a pool with no matrix state) are updated in
-    place. GiB in PERF.md section 4 as "described-chip compile"."""
-    cfg, params, cache = lfm2
-    falls = dict(A.reference_falls)
-    vec = lambda n: sd((n,), I32)  # noqa: E731
-    args = ((vec(64), vec(64), vec(operands), vec(operands), vec(operands), vec(4), vec(4))
-            if which == "mixed" else tuple(sd(shape, I32) for shape in operands))
-    compiled = jax.jit(solar_program(which, cfg), donate_argnums=(1, 2)).lower(
-        params, cache["k"], cache["v"], *args).compile()
-    assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
-    text = compiled.as_text()
-    assert grouped_kernels_in(text)
-    # no state kernel: no matrix state
-    assert not any(f"%{k}_{form}" in text for k in ("kda", "gdn", "ssd")
-                   for form in ("decode_step", "chunk_scan"))
-    if which in ("decode", "mixed"):
-        assert "decode_attn_q8_whole" in text and "append_kv_q8" in text
-        assert "decode_attn_q8_blocked" in text
-    if which == "admit":
-        assert "flash_prefill_attn" in text
-    state = cache["v"]["state"]
-    assert set(state) == {"conv"} and state["conv"].shape == (11, 64, 2 * 2048)
-    assert cache["k"]["q"].shape == (3, 64, 9, 1024, 128)
-    assert cache_relayouts(text, cache["k"]["q"].shape) == []
-    assert params["layers"]["w1e"].shape == (12, 32, 2048, 1792) and len(params["first"]) == 2
-    nbytes = lambda tree: sum(  # noqa: E731
-        int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(tree))
-    weights, pool, kv = nbytes(params), nbytes(state), nbytes(cache["k"])
-    # bfloat16 but for the twelve selection biases [32], float32
-    assert weights == 2 * cfg.param_count() + 2 * 12 * 32 == 9_334_155_520 and pool == 64 * 90_112
-    mem = compiled.memory_analysis()
-    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    print(f"lfm2 {which} {operands}: {total / 2**30:.2f} GiB, of it temporaries "
-          f"{mem.temp_size_in_bytes / 2**30:.3f} GiB, arguments "
-          f"{mem.argument_size_in_bytes / 2**30:.2f} GiB (weights {weights / 2**30:.2f}, "
-          f"KV cache {kv / 2**30:.2f}, tails {pool / 2**20:.1f} MiB)")
-    assert total < 12.0 * 2**30
-    # under one layer's banks; a decode or mixed round's are 0.02 and 0.05 GiB
-    # (0.42 and 0.45 with the cache of 0.20 GiB re-laid and back)
-    assert mem.temp_size_in_bytes < (0.15 if which in ("decode", "mixed") else 0.6) * 2**30
-    assert mem.alias_size_in_bytes > 0.99 * (pool + kv)  # KV cache and tails updated in place
-
-
-@pytest.mark.parametrize("rung", [128, 256])
-@pytest.mark.parametrize("name,kernel,limit,temps", [
-    ("solar", "%kda_decode_step", 15.75, 0.25), ("olmo", "%gdn_decode_step", 15.0, 0.35),
-    # the prompts' states ride the scan (its cache of 0.27 GiB was re-laid for the
-    # kernels and back besides, 1.06 GiB in all, while its heads of 64 lay a row each)
-    ("granite", "%ssd_decode_step", 15.0, 0.75)])
-def test_hybrid_mixed_round_fits_beside_its_decode_round(
-    sd, request, chip_kernels, name, kernel, limit, temps, rung
-):
-    """The mixed round of the three benchmark configurations with recurrent
-    layers (`hybrid_mixed_step`, then three plain steps) at their cells' 64
-    slots x 1024 and both rungs of the packed prompt buffer, four prompt rows,
-    compiles for the described v5e: the decode rows' state kernel, decode
-    attention and append kernel as Mosaic calls with no fall to their reference,
-    inside the limit its decode round is held to, the KV cache and the state
-    pool updated in place, and among the temporaries no copy of the pool (0.78,
-    2.04 and 4.56 GiB: each is larger than all of them together; carried with
-    the pool's own last two axes, Granite's prompt states made the compiler
-    re-lay the whole pool out) nor of Olmo-Hybrid's cache (2.42 GiB). GiB in
-    PERF.md section 4 as "described-chip compile"."""
-    cfg, params, cache = request.getfixturevalue(name)
-    falls = dict(A.reference_falls)
-    vec = lambda n: sd((n,), I32)  # noqa: E731
-    compiled = jax.jit(solar_program("mixed", cfg), donate_argnums=(1, 2)).lower(
-        params, cache["k"], cache["v"], vec(64), vec(64), vec(rung), vec(rung), vec(rung),
-        vec(4), vec(4)).compile()
-    assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
-    text = compiled.as_text()
-    assert kernel in text and "decode_attn_q8" in text and "append_kv_q8" in text
-    # the prompts' recurrence with one decay a head is the chunk kernel; Solar's, a
-    # decay a key channel, stays the loop of `jax.numpy` (models/kda.py)
-    scan = kernel.replace("decode_step", "chunk_scan")
-    assert (scan in text) == (name != "solar") and ("chunk_scan" in text) == (name != "solar")
-    assert grouped_kernels_in(text) == (name == "solar")  # its 64 + rung rows through the expert kernels
-    nbytes = lambda tree: sum(  # noqa: E731
-        int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(tree))
-    pool, kv = nbytes(cache["v"]["state"]), nbytes(cache["k"])
-    mem = compiled.memory_analysis()
-    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    print(f"{name} mixed {rung}: {total / 2**30:.2f} GiB, of it temporaries "
-          f"{mem.temp_size_in_bytes / 2**30:.2f} GiB (pool {pool / 2**30:.2f}, "
-          f"KV cache {kv / 2**30:.2f})")
-    assert total < limit * 2**30
-    assert mem.temp_size_in_bytes < temps * 2**30 < pool
-    assert mem.alias_size_in_bytes > 0.99 * (pool + kv)
-    assert cache_relayouts(text, cache["k"]["q"].shape) == []
-
-
 def test_a_fall_to_the_reference_is_counted(tmp_path):
     """What `compile_for_chip` and chip_smoke.py's zero-fall check stand on: a
     shape gate that fails with interpret=False is counted and lands in the
@@ -967,233 +418,337 @@ def test_a_fall_to_the_reference_is_counted(tmp_path):
     assert len(flight.get_recorder().snapshot(etype="kernel_fall")) == before
 
 
-@pytest.fixture(scope="module")
-def joyai(one_chip):
-    """`joyai-llm-flash-ep16`, whole depth, 64 slots x 1024, as its cell boots it."""
-    return hybrid_shapes("joyai-llm-flash-ep16", one_chip, SOLAR_SLOTS, SOLAR_S)
+# -- every cell's step programs, whole, at the published widths ---------------------
+#
+# The programs and their operand shapes are tests/cell_programs.py's rows; what
+# each cell's programs are held to is a row of HELD beside them. A rule says in
+# which of a cell's programs a kernel's name must stand in the compiled text.
+
+GiB = 2**30
 
 
-def joyai_program(which: str, cfg):
-    """The latent family's step programs as the engine builds them: the decode
-    round and the bucketed chunk are `solar_program`'s (the same dispatch through
-    models/llama.py); the admit program inserts rows of BOTH members of the
-    latent pair, as `engine._insert_row` does for a counted pair; the packed
-    chunk is the ragged program."""
-    from llm_mcp_tpu.executor.engine import _put_rows
-    from llm_mcp_tpu.models import hybrid, llama
-
-    def admit(params, ck, cv, tokens, lengths, slots):
-        logits, ks, vs = llama.llama_prefill(
-            cfg, params, tokens, lengths, attn_impl="pallas", quant_kv=True)
-
-        def put(c, rows, i, slot):  # `engine._insert_kv`'s: a prompt's rope keys lie apart
-            return _put_rows(c, jax.lax.dynamic_slice_in_dim(rows, i, 1, 1), slot, 0)
-
-        def body(i, cc):
-            ck, cv = cc
-            ck = jax.tree.map(lambda c, r: put(c, r, i, slots[i]), ck, ks)
-            return ck, dict(cv, v=jax.tree.map(lambda c, r: put(c, r, i, slots[i]), cv["v"], vs["v"]))
-
-        ck, cv = jax.lax.fori_loop(0, tokens.shape[0], body, (ck, cv))
-        return logits, ck, hybrid.add_counts(cv, vs)
-
-    def ragged(params, ck, cv, tokens, rowids, positions, slots, starts, last_idx):
-        return llama.llama_prefill_chunk_ragged(
-            cfg, params, ck, cv, tokens, rowids, positions, slots, starts, last_idx, impl="kernel")
-
-    return {"admit": admit, "ragged": ragged}.get(which) or solar_program(which, cfg)
+def only(*programs):
+    return lambda which, found: found == (which in programs)
 
 
-@pytest.mark.parametrize("which,operands", [
-    ("decode", [(64,), (64,), (64,)]),  # every slot a row
-    ("admit", [(4, 128), (4,), (4,)]),  # the cell's largest admit program, 512 padded tokens
-    ("admit", [(1, 64), (1,), (1,)]),  # and its smallest
-    ("chunk", [(2, 512), (2,), (2,), (2,)]),  # two prompts' second chunks of 512
-    ("ragged", [(512,), (512,), (512,), (4,), (4,), (4,)]),  # a packed buffer of 512 tokens, four rows
-])
-def test_joyai_step_programs_fit_with_the_banks_whole_beside_the_latent_cache(
-    sd, joyai, chip_kernels, which, operands
-):
-    """The decode round of 64 rows, the admit programs the traffic meets, a
-    bucketed chunk and a packed chunk of `joyai-llm-flash-ep16` (the published
-    widths, all 40 layers, 16 of 256 experts of 2048 x 768 a layer) at its cell's
-    64 slots x 1024 compile for the described v5e: the MLA step programs' first
-    compile for the chip (ROADMAP B2, debt (d)). The two grouped expert kernels in
-    every program, the latent decode attention as the whole-S arm
-    (`decode_attn_mla_q8_whole`: 1024 positions fit its VMEM budget) in the decode
-    round, `ragged_prefill_attn_mla` in the packed chunk, every one a Mosaic call
-    with no fall to its reference. Each fits the chip beside 9.55 GB of weights
-    and the 1.52 GB latent cache; the temporaries hold no copy of a layer's banks
-    (151 MB a layer: the stack goes in whole) nor of the leading dense layer's
-    feed-forward (a stack of ONE layer scanned once: sliced in place), and the
-    latent pair is updated in place, neither member copied or re-laid (the int8
-    rope keys lie two positions abreast in rows of whole lanes: until PR 58 their
-    rows of 64 lanes were re-laid four times a decode round). GiB in PERF.md section 4 as "described-chip
-    compile"."""
-    cfg, params, cache = joyai
-    falls = dict(A.reference_falls)
-    compiled = jax.jit(joyai_program(which, cfg), donate_argnums=(1, 2)).lower(
-        params, cache["k"], cache["v"], *(sd(shape, I32) for shape in operands)).compile()
-    assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
-    text = compiled.as_text()
-    assert grouped_kernels_in(text)
-    assert ("decode_attn_mla_q8_whole" in text) == (which == "decode")
-    assert "decode_attn_mla_q8_blocked" not in text and "decode_attn_mla_q8_paged" not in text
-    assert ("ragged_prefill_attn_mla" in text) == (which == "ragged")
-    # the rope keys two positions abreast in rows of the 128 lanes (`positions_abreast`)
+def within(*programs):  # there at least (nothing is said of the others)
+    return lambda which, found: found or which not in programs
+
+
+def absent_in(*programs):
+    return lambda which, found: not found or which not in programs
+
+
+def everywhere(which, found):
+    return found
+
+
+def nowhere(which, found):
+    return not found
+
+
+def nbytes(tree) -> int:
+    return sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(tree))
+
+
+def _rehearse():
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "rehearse_tpu_compile",
+        os.path.join(os.path.dirname(__file__), "..", "scripts", "rehearse_tpu_compile.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def qwen3_also(cell, which, operands, cfg, params, cache, text, mem):
+    """Qwen3-8B, `decode_closed`'s widths. The decode round (int8 weights + int8
+    KV, `_decode_step_q8`, the whole model; bf16 weights + bf16 KV,
+    `_decode_step_bf16`, 16 layers and 8 slots so that bf16 fits the chip; the
+    XLA-attention scan of `llama_decode_step` that windows, softcaps and meshes
+    take): no loop body may make a weight-sized array in HBM (with `unroll=4` on
+    the layer scans each round copied every group of four layers' weights out of
+    the stacked tree: six instructions, 0.74 GiB of temporaries, more than half
+    of a round's device time on the chip). What the bf16 program still does,
+    ONCE a round and outside both loops: the compiler re-lays out the whole `wq`
+    and `wk` stacks for the slices it prefetches into on-chip memory (PERF.md
+    section 7); the case holds it to that. The mixed round (`mixed_step_q8`, then 3
+    plain steps, 4 prompt rows, one rung of the packed buffer): the cache is
+    updated in place (the prompts' rows go in through `write_prompt_rows` after
+    the decode rows' append), no loop body copies weights, and the program stays
+    inside what the cell has left: its peak is 13.77 GB of the chip's 15.75 GiB
+    (PERF.md section 4), the plain round compiles to 12.74 GiB and the ragged
+    chunk programs to 13.34."""
+    rehearse = _rehearse()
+    made = rehearse.stacked_weight_producers(text, rehearse.stacked_weight_dims(params["layers"]))
+    if which == "mixed":
+        assert made == [], "the mixed round copies weights"
+        # the cache and nothing weight-sized beside it in temporaries; the whole
+        # under the 13.34 GiB of the ragged programs the cell already holds
+        assert mem.temp_size_in_bytes < (512 << 20), mem.temp_size_in_bytes
+        total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+        assert total < int(13.34 * GiB), total
+        return
+    assert [m for m in made if m[0] != "ENTRY"] == [], "a loop body copies weights"
+    whole = [params["layers"][k] for k in (("wq", "wk") if cell == "qwen3_bf16" else ())]
+    assert sorted(made) == sorted(
+        ("ENTRY", "copy", f"bf16[{','.join(map(str, w.shape))}]") for w in whole)
+    assert mem.temp_size_in_bytes < (256 << 20) + sum(w.size * w.dtype.itemsize for w in whole)
+
+
+def olmo_also(cell, which, operands, cfg, params, cache, text, mem):
+    """The argument bytes hold the pool at its logical size: nothing pads, the
+    pool (15 x [96, 384]: 30 heads of [96, 192] two abreast) least of all."""
+    from llm_mcp_tpu.models import kda
+
+    assert cache["v"]["state"]["S"].shape == (15, 64, 15, 96, 384) and kda.state_abreast(cfg) == 2
+    if which != "mixed":
+        logical = nbytes((params, cache)) + sum(int(np.prod(shape)) * 4 for shape in operands)
+        assert mem.argument_size_in_bytes < logical * 1.002
+
+
+def granite_also(cell, which, operands, cfg, params, cache, text, mem):
+    """The pool's [36, 64, 32, 128, 128] (two heads of 64 values abreast), whose
+    bytes as the compiler lays it out are its logical bytes; the cache's heads
+    of 64 two abreast in rows of 128 lanes."""
+    from llm_mcp_tpu.models import ssm
+
+    S = cache["v"]["state"]["S"]
+    assert S.shape == (36, 64, 32, 128, 128) and ssm.state_abreast(cfg) == 2
+    assert cache["k"]["q"].shape == (4, 64, 9, 1024, 128)
+    if which != "mixed":
+        pool = jax.jit(lambda s: s + 1.0).lower(S).compile().memory_analysis()
+        assert pool.argument_size_in_bytes == 36 * 64 * 64 * 128 * 64 * 4
+
+
+def kexaone_also(cell, which, operands, cfg, params, cache, text, mem):
+    """Window layers hold a ring of 128 positions a slot and not 4096: the
+    arguments are the weights, 0.58 GB of the global layer's cache and 0.07 GB
+    of rings."""
+    ring, full = cache["v"]["win"]["k"], cache["k"]
+    assert full["q"].shape == (1, 64, 17, 4096, 128) and ring["q"].shape == (4, 64, 17, 128, 128)
+    assert ring["s"].shape == (4, 64, 16, 128) and cache["v"]["win"]["v"] == {}
+    assert cache["v"]["moe"].shape == (2, 4, 5) and "state" not in cache["v"]
+    if which == "decode":
+        assert text.count("append_kv_q8") >= 2  # the cache, the rings
+    caches, weights = nbytes(cache), nbytes(params)
+    assert round(weights / 1e9, 2) == 7.42 and 0.64e9 < caches < 0.66e9
+    assert mem.argument_size_in_bytes < weights + caches + 2**20  # rings, not 4 x 4096 positions
+
+
+def lfm2_also(cell, which, operands, cfg, params, cache, text, mem):
+    """The tails (5.5 MiB: a pool with no matrix state), the cache's heads of 64
+    WITH rotation two abreast, all 32 experts of 2048 x 1792 a layer, the two
+    leading dense layers unstacked."""
+    state = cache["v"]["state"]
+    assert set(state) == {"conv"} and state["conv"].shape == (11, 64, 2 * 2048)
+    assert cache["k"]["q"].shape == (3, 64, 9, 1024, 128)
+    assert params["layers"]["w1e"].shape == (12, 32, 2048, 1792) and len(params["first"]) == 2
+    # bfloat16 but for the twelve selection biases [32], float32
+    assert nbytes(params) == 2 * cfg.param_count() + 2 * 12 * 32 == 9_334_155_520
+    assert nbytes(state) == 64 * 90_112
+
+
+def joyai_also(cell, which, operands, cfg, params, cache, text, mem):
+    """The latent pair: the rope keys two positions abreast in rows of the 128
+    lanes (`positions_abreast`); 580 bytes a position and layer."""
     assert cache["k"]["q"].shape == (40, 64, 1, 1024, 512) and cache["v"]["v"]["q"].shape == (40, 64, 1, 512, 128)
     assert cache["v"]["moe"].shape == (2, 39, 5)
-    # no copy of either member of the latent pair, to another layout or otherwise
-    assert cache_relayouts(text, cache["k"]["q"].shape) == []
-    assert cache_relayouts(text, cache["v"]["v"]["q"].shape) == []
+    assert cache_relayouts(text, cache["v"]["v"]["q"].shape) == []  # nor of the other member
     assert params["layers"]["w1e"].shape == (39, 16, 2048, 768) and params["dense_layers"]["w1"].shape == (1, 2048, 7168)
-    nbytes = lambda tree: sum(  # noqa: E731
-        int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(tree))
-    weights, latent = nbytes(params), nbytes(cache) - cache["v"]["moe"].size * 4
     # bfloat16 but for the 39 selection biases [256], float32
-    assert weights == 2 * cfg.param_count() + 2 * 39 * 256 == 9_553_062_912
-    assert latent == 40 * 64 * 1024 * (512 + 64 + 4) == 1_520_435_200  # 580 bytes a position and layer
-    mem = compiled.memory_analysis()
-    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    print(f"joyai {which} {operands}: {total / 2**30:.2f} GiB, of it temporaries "
-          f"{mem.temp_size_in_bytes / 2**30:.3f} GiB, arguments "
-          f"{mem.argument_size_in_bytes / 2**30:.2f} GiB (weights {weights / 2**30:.2f}, "
-          f"latent cache {latent / 2**30:.2f} logical)")
-    assert total < 14.5 * 2**30
-    # No copy of a layer's banks (0.14 GiB a layer and step would be 5.5 GiB a
-    # round) nor of `w_uq` (0.69 GiB until its columns were `[H dn | H dr]`). What
-    # the decode round still holds, every ROUND: `w_ukv` transposed whole for the
-    # absorbed products (0.30) and `w_dkv` (0.09): ROADMAP B2.
-    assert mem.temp_size_in_bytes < (0.5 if which == "decode" else 1.0) * 2**30
+    assert nbytes(params) == 2 * cfg.param_count() + 2 * 39 * 256 == 9_553_062_912
+    assert nbytes(cache) - cache["v"]["moe"].size * 4 == 40 * 64 * 1024 * (512 + 64 + 4) == 1_520_435_200
     if which == "decode":
         assert "bf16[39,1536,6144]" not in "".join(
             line for line in text.splitlines() if " copy(" in line)  # `w_uq` read in place
-    assert mem.alias_size_in_bytes > 0.99 * latent  # the latent pair updated in place
 
 
-# -- generation by diffusion over blocks: the block round and the admit programs ----
-
-
-@pytest.fixture(scope="module")
-def sdar(one_chip):
-    """`sdar-30b-a3b-ep8`, whole depth, 64 slots x 1024, as its cell boots it."""
-    return hybrid_shapes("sdar-30b-a3b-ep8", one_chip, SOLAR_SLOTS, SOLAR_S)
-
-
-def sdar_program(which: str, cfg):
-    """The block configuration's step programs as the engine builds them: the
-    block round (`engine.block_round_fn`: a while loop of denoising passes over
-    the whole batch in order, the unmask rule with the sampler, the commit
-    pass), the admit program with the counted dense pair's row inserts, and the
-    bucketed chunk (`solar_program`'s)."""
-    from llm_mcp_tpu.executor.engine import _put_rows
-    from llm_mcp_tpu.models import hybrid, llama
-
-    def block(params, ck, cv, first, starts, counter, slots=None):
-        live = starts < ck["q"].shape[3]
-        temp = jnp.full(starts.shape, 0.7, jnp.float32)
-        topk, topp = jnp.zeros(starts.shape, I32), jnp.ones(starts.shape, jnp.float32)
-
-        def denoise(carry):
-            tokens, passes, rng, moe, n = carry
-            rng, sub = jax.random.split(rng)
-            new, cv_p, _ = llama.block_denoise(
-                cfg, params, ck, dict(cv, moe=moe), tokens, slots, starts, live, sub, temp, topk, topp,
-                attn_impl="pallas")
-            return new, passes + jnp.any(tokens == cfg.mask_token_id, axis=1), rng, cv_p["moe"], n + 1
-
-        tokens, passes, _, moe, _ = jax.lax.while_loop(
-            lambda c: jnp.any((c[0] == cfg.mask_token_id) & live[:, None]) & (c[4] < cfg.denoise_steps),
-            denoise,
-            (first, jnp.zeros(starts.shape, I32), jax.random.fold_in(jax.random.PRNGKey(1), counter[0]),
-             cv["moe"], jnp.int32(0)))
-        _, ck, cv = llama.block_pass(
-            cfg, params, ck, dict(cv, moe=moe), tokens, slots, starts, live, commit=True,
-            attn_impl="pallas")
-        return jnp.concatenate([tokens.T, passes[None]]), ck, cv
-
-    def admit(params, ck, cv, tokens, lengths, slots):
-        logits, ks, vs = llama.llama_prefill(
-            cfg, params, tokens, lengths, attn_impl="pallas", quant_kv=True)
-
-        def body(i, cc):
-            ck, cv = cc
-            ck = jax.tree.map(
-                lambda c, r: _put_rows(c, jax.lax.dynamic_slice_in_dim(r, i, 1, 1), slots[i], 0), ck, ks)
-            return ck, cv
-
-        ck, cv = jax.lax.fori_loop(0, tokens.shape[0], body, (ck, cv))
-        return logits, ck, hybrid.add_counts(cv, vs)
-
-    return {"block": block, "block_compact": block, "admit": admit}.get(which) or solar_program(which, cfg)
-
-
-@pytest.mark.parametrize("which,operands", [
-    ("block", [(64, 4), (64,), (1,)]),  # every slot a row: 256 rows a pass
-    ("block_compact", [(32, 4), (32,), (1,), (32,)]),  # half the slots seated: rows by slot id
-    ("admit", [(4, 128), (4,), (4,)]),  # the cell's largest admit program, 512 padded tokens
-    ("admit", [(1, 64), (1,), (1,)]),  # and its smallest
-    ("chunk", [(2, 512), (2,), (2,), (2,)]),  # two prompts' second chunks of 512
-])
-def test_sdar_step_programs_fit_with_the_banks_whole_beside_the_fused_cache(
-    sd, sdar, chip_kernels, which, operands
-):
-    """The block round of 64 rows (256 rows a pass), the admit programs the
-    traffic meets and a bucketed chunk of `sdar-30b-a3b-ep8` (the published
-    widths, all 48 layers, 16 of 128 experts of 2048 x 768 a layer, the whole
-    vocabulary) at its cell's 64 slots x 1024 compile for the described v5e. The
-    two grouped expert kernels in every program, the prompt kernel in the admit
-    programs, every one a Mosaic call with no fall to its reference. Each fits
-    the chip beside 10.33 GB of weights and the 3.67 GB fused int8 cache; the
-    temporaries hold no copy of a layer's banks (151 MB a layer: the stack goes
-    in whole), and the cache is updated in place, neither copied nor re-laid:
-    a denoising pass does not even carry it. The block round's passes read it
-    through `block_attn_q8` (kernels/attention.py:block_attend_q8), in order and
-    by slot id: no slice of a layer's payload is cut out of the stack (67 MB a
-    layer and pass before PR 60), and the commit's writes after the kernel's
-    read leave its layout alone (written as updates of `[9, 4, 128]` they made
-    the compiler re-lay all 48 layers heads-minor, 6 GB, and back, every layer).
-    GiB in PERF.md section 4 as "described-chip compile"."""
-    cfg, params, cache = sdar
-    falls = dict(A.reference_falls)
-    compiled = jax.jit(sdar_program(which, cfg), donate_argnums=(1, 2)).lower(
-        params, cache["k"], cache["v"], *(sd(shape, I32) for shape in operands)).compile()
-    assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
-    text = compiled.as_text()
-    assert grouped_kernels_in(text)
-    assert ("flash_prefill_attn" in text) == (which == "admit")
-    assert ("block_attn_q8" in text) == which.startswith("block")
-    if which.startswith("block"):
+def sdar_also(cell, which, operands, cfg, params, cache, text, mem):
+    """The fused int8 cache of 48 layers and the counts beside it; a block
+    round's passes read it through `block_attn_q8`, in order and by slot id."""
+    if which == "block":
         # every pass's attention is the kernel's: the denoising loop's and the commit's
         assert text.count("custom_call_target=\"tpu_custom_call\"") >= 6
         cut = [ln.strip()[:160] for ln in text.splitlines()
                if " dynamic-slice(" in ln and re.search(r"= s8\[(1,)?\d+,[89],1024,128\]", ln)]
-        assert cut == [], cut
+        assert cut == [], cut  # no slice of a layer's payload cut out of the stack (67 MB a layer and pass before PR 60)
     assert cache["k"]["q"].shape == (48, 64, 9, 1024, 128) and cache["v"]["v"] == {}
     assert cache["v"]["moe"].shape == (2, 48, 5)
-    assert cache_relayouts(text, cache["k"]["q"].shape) == []
-    # nor of the plain scales beside it: cut out of the stack a layer's worth at
-    # a time, all 48 layers' were re-laid every layer of every pass (120 ms of a
-    # 276 ms round on the chip; PERF.md section 6, PR 59)
+    # nor a copy of the plain scales beside the payload: cut out of the stack a
+    # layer's worth at a time, all 48 layers' were re-laid every layer of every
+    # pass (120 ms of a 276 ms round on the chip; PERF.md section 6, PR 59)
     assert [ln for ln in text.splitlines() if "= bf16[48,64,8,1024]{" in ln and " copy(" in ln] == []
     assert params["layers"]["w1e"].shape == (48, 16, 2048, 768)
-    nbytes = lambda tree: sum(  # noqa: E731
-        int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(tree))
-    weights, kv = nbytes(params), nbytes(cache) - cache["v"]["moe"].size * 4
-    assert weights == 2 * cfg.param_count() == 10_329_944_064
-    assert kv == 48 * 64 * 1024 * (9 * 128 + 8 * 2) == 3_674_210_304
-    mem = compiled.memory_analysis()
-    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
-             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
-    print(f"sdar {which} {operands}: {total / 2**30:.2f} GiB, of it temporaries "
-          f"{mem.temp_size_in_bytes / 2**30:.3f} GiB, arguments "
-          f"{mem.argument_size_in_bytes / 2**30:.2f} GiB (weights {weights / 2**30:.2f}, "
-          f"KV cache {kv / 2**30:.2f} logical)")
-    assert total < 15.0 * 2**30
-    # no copy of a layer's banks: 0.14 GiB a layer would be 6.75 GiB a pass. (The
-    # block round's 1.24 GiB: with no slice of a layer's payload left in it the
-    # compiler transposes the wq / wk / wv STACKS once a round, 0.95 GiB outside
+    assert nbytes(params) == 2 * cfg.param_count() == 10_329_944_064
+    assert nbytes(cache) - cache["v"]["moe"].size * 4 == 48 * 64 * 1024 * (9 * 128 + 8 * 2) == 3_674_210_304
+
+
+def _pool_and_kv(cache) -> int:
+    return nbytes(cache["v"]["state"]) + nbytes(cache["k"])
+
+
+_STATE_KERNELS = [f"%{k}_{form}" for k in ("kda", "gdn", "ssd") for form in ("decode_step", "chunk_scan")]
+_QWEN3 = dict(kernels={"tpu_custom_call": everywhere}, also=qwen3_also)
+
+# cell: `kernels` {name in the text: rule}; `grouped`: the rule for both of
+# kernels/grouped.py's calls (and no product over all the pairs); `total` and
+# `temps` [GiB] by program ("": the others); `alias` [bytes]: what at least is
+# updated in place; `in_place`: the programs whose text copies no int8 array of
+# the cache's size; `pool_above_temps`: where the state pool (0.78, 2.04 and 4.56
+# GiB) is larger than all the temporaries the program may hold; `also`: what only this cell states. GiB in PERF.md section 4
+# as "described-chip compile".
+HELD = {
+    "qwen3": _QWEN3, "qwen3_bf16": _QWEN3,
+    "qwen3_xla_attention": dict(kernels={"tpu_custom_call": nowhere}, also=qwen3_also),
+    # the state kernel and the attention kernels in decode, the two grouped expert
+    # kernels in every program since PR 45 (the decode round's 64 rows through a
+    # window of one row tile); no second copy of the state pool (0.75 GiB) nor of
+    # an expert bank (a slice of a stacked bank that feeds a grouped product was
+    # copied out, 0.39 GiB a bank and layer, until the banks went in whole:
+    # models/moe.py): the decode round's temporaries are 0.09 GiB. The mixed
+    # round's prompts' recurrence, a decay a key channel, stays the loop of
+    # `jax.numpy` (models/kda.py); its 64 + rung rows go through the expert kernels
+    "solar": dict(
+        kernels={"kda_decode_step": only("decode", "mixed"), "%kda_decode_step": within("mixed"),
+                 "decode_attn_q8": within("mixed"), "append_kv_q8": within("mixed"),
+                 "chunk_scan": absent_in("mixed")},
+        grouped=everywhere, total={"": 15.75}, temps={"decode": 0.21, "mixed": 0.25, "": 0.7},
+        alias=lambda which, cache: 0.99 * _pool_and_kv(cache) if which == "mixed" else 0.9 * GiB,
+        in_place=("mixed",), pool_above_temps=("mixed",)),
+    # `gdn_decode_step` and the decode attention and append kernels at 30 KV heads,
+    # group 1; the flash prefill kernel in the admit program; the chunked recurrence
+    # of a prompt is the chunk kernel, one Mosaic call a layer (the bucketed chunk's
+    # attention over [past | self] is `jax.numpy` for every configuration,
+    # llama._chunk_attention: PERF.md section 7). Each under the 15.0 GiB at which
+    # ISSUE 35 would have taken 48 slots, the 2.38 GiB KV cache and the 2.04 GiB
+    # state pool in place; the mixed round copies neither pool nor cache (2.42 GiB)
+    "olmo": dict(
+        kernels={"%gdn_chunk_scan": only("admit", "chunk", "mixed"), "%ssd_chunk_scan": nowhere,
+                 "%gdn_decode_step": only("decode", "mixed"), "%kda_decode_step": nowhere,
+                 "decode_attn_q8": within("decode", "mixed"), "append_kv_q8": within("decode", "mixed"),
+                 "flash_prefill_attn": within("admit")},
+        grouped=absent_in("mixed"), total={"": 15.0}, temps={"mixed": 0.35},
+        alias=lambda which, cache: 0.99 * _pool_and_kv(cache) if which == "mixed" else 4.3 * GiB,
+        in_place=("mixed",), pool_above_temps=("mixed",), also=olmo_also),
+    # `ssd_decode_step`, the decode attention (both arms under the dispatcher's
+    # `cond`: heads of 64 two abreast, which the blocked arm's copies cut) and the
+    # append kernel on those rows; no program copies the cache to another layout
+    # (at [4, 64, 17, 1024, 64] every one did, 0.27 GiB there and 0.27 back: the
+    # decode round's temporaries were 0.56 GiB, 0.05 now). The 4.5 GiB state pool in
+    # place; carried with the pool's own last two axes, the mixed round's prompt
+    # states made the compiler re-lay the whole pool out (their states ride the scan)
+    "granite": dict(
+        kernels={"%ssd_chunk_scan": only("admit", "chunk", "mixed"), "%gdn_chunk_scan": nowhere,
+                 "%ssd_decode_step": only("decode", "mixed"), "%kda_decode_step": nowhere,
+                 "%gdn_decode_step": nowhere, "decode_attn_q8_whole": within("decode"),
+                 "decode_attn_q8_blocked": within("decode"), "decode_attn_q8": within("mixed"),
+                 "append_kv_q8": within("decode", "mixed"), "flash_prefill_attn": within("admit")},
+        grouped=absent_in("mixed"), total={"": 15.0}, temps={"decode": 0.15, "mixed": 0.75},
+        alias=lambda which, cache: 0.99 * _pool_and_kv(cache) if which == "mixed" else 4.5 * GiB,
+        in_place=("decode", "admit", "chunk", "mixed"), pool_above_temps=("mixed",), also=granite_also),
+    # the decode attention in BOTH arms (blocked or whole-S over the global layer's
+    # cache, the window arm over the rings), the append kernel twice; every program's
+    # expert layers are the grouped kernels over a window of the pairs held here
+    # (one row tile for the decode round's 64 rows since PR 45; 1,280 rows of the
+    # 8,192 a 1,024-row prompt has: `moe.window_rows`); the admit programs'
+    # temporaries under 0.55 GiB (the sorted copies of all 8,192 pairs' rows stood
+    # at 0.72 and 0.64, PR 43's tree; 0.41 and 0.31 now); the chunk's 0.99 GiB are
+    # its attention over [past | self]. Both kinds of cache in place: no copy of a
+    # whole cache member among the temporaries
+    "kexaone": dict(
+        kernels={"decode_attn_win_q8": within("decode"), "decode_attn_q8_blocked": within("decode"),
+                 "flash_prefill_attn": within("admit"), "decode_attn": absent_in("admit")},
+        grouped=everywhere, total={"": 12.0}, temps={"decode": 0.7, "admit": 0.55, "chunk": 1.1},
+        alias=lambda which, cache: 0.99 * nbytes(cache), also=kexaone_also),
+    # the two grouped expert kernels at banks of [2048, 1792] (one column block of
+    # two banks, 14.7 MB) and [1792, 2048]; no state kernel: no matrix state. The
+    # temporaries hold no copy of a layer's banks (0.66 GiB a layer; the stack goes
+    # in whole) nor of a leading layer's feed-forward (84 MB, unstacked: a slice of
+    # a stack at a fixed index was copied out every step): a decode or mixed
+    # round's are 0.02 and 0.05 GiB (0.42 and 0.45 with the cache re-laid and back)
+    "lfm2": dict(
+        kernels={**{k: nowhere for k in _STATE_KERNELS},
+                 "decode_attn_q8_whole": within("decode", "mixed"),
+                 "decode_attn_q8_blocked": within("decode", "mixed"),
+                 "append_kv_q8": within("decode", "mixed"), "flash_prefill_attn": within("admit")},
+        grouped=everywhere, total={"": 12.0}, temps={"decode": 0.15, "mixed": 0.15, "": 0.6},
+        alias=lambda which, cache: 0.99 * _pool_and_kv(cache),
+        in_place=("decode", "admit", "chunk", "mixed"), also=lfm2_also),
+    # the MLA step programs' first compile for the chip (ROADMAP B2, debt (d)): the
+    # latent decode attention as the whole-S arm (1024 positions fit its VMEM
+    # budget), `ragged_prefill_attn_mla` in the packed chunk; beside 9.55 GB of
+    # weights and the 1.52 GB latent cache. No copy of a layer's banks (0.14 GiB a
+    # layer and step would be 5.5 GiB a round) nor of `w_uq` (0.69 GiB until its
+    # columns were `[H dn | H dr]`), nor of the leading dense layer's feed-forward (a
+    # stack of ONE layer scanned once: sliced in place). What the decode round still
+    # holds, every ROUND: `w_ukv` transposed whole for the absorbed products (0.30)
+    # and `w_dkv` (0.09): ROADMAP B2. Neither member of the pair copied or re-laid
+    # (until PR 58 the rope keys' rows of 64 lanes were re-laid four times a round)
+    "joyai": dict(
+        kernels={"decode_attn_mla_q8_whole": only("decode"), "decode_attn_mla_q8_blocked": nowhere,
+                 "decode_attn_mla_q8_paged": nowhere, "ragged_prefill_attn_mla": only("ragged")},
+        grouped=everywhere, total={"": 14.5}, temps={"decode": 0.5, "": 1.0},
+        alias=lambda which, cache: 0.99 * (nbytes(cache) - cache["v"]["moe"].size * 4),
+        in_place=("decode", "admit", "chunk", "ragged"), also=joyai_also),
+    # beside 10.33 GB of weights and the 3.67 GB fused int8 cache, which a denoising
+    # pass does not even carry; the commit's writes after the kernel's read leave its
+    # layout alone (written as updates of `[9, 4, 128]` they made the compiler re-lay
+    # all 48 layers heads-minor, 6 GB, and back, every layer). No copy of a layer's
+    # banks: 0.14 GiB a layer would be 6.75 GiB a pass. (The block round's 1.24 GiB:
+    # the compiler transposes the wq / wk / wv STACKS once a round, 0.95 GiB outside
     # the loops, where it transposed a layer's slice inside them every layer of
     # every pass before)
-    assert mem.temp_size_in_bytes < (1.5 if which.startswith("block") else 1.0) * 2**30
+    "sdar": dict(
+        kernels={"flash_prefill_attn": only("admit"), "block_attn_q8": only("block")},
+        grouped=everywhere, total={"": 15.0}, temps={"block": 1.5, "": 1.0},
+        in_place=("block", "admit", "chunk"), also=sdar_also),
+}
+
+
+def test_every_cell_of_the_table_is_held_to_something():
+    assert set(HELD) == set(cell_programs.CELLS)
+    assert len({cell_programs.row_id(*row) for row in cell_programs.ROWS}) == len(cell_programs.ROWS)
+
+
+@pytest.mark.parametrize("cell,which,operands", cell_programs.ROWS,
+                         ids=[cell_programs.row_id(*row) for row in cell_programs.ROWS])
+def test_a_cells_step_program_compiles_for_the_chip_inside_what_the_cell_has(
+    one_chip, chip_kernels, cell, which, operands
+):
+    """A row of tests/cell_programs.py (the decode or block round, an admit, a
+    chunk or a mixed program of a benchmark configuration at its published
+    widths and its cell's slots and length) compiles for the described v5e from
+    shapes alone, with its kernels as Mosaic calls and no fall to their
+    reference, fits the chip inside the cell's limit, updates its cache (and
+    state pool, rings or tails) in place and copies none of them to another
+    layout, and holds no weight-sized temporary: HELD's row for the cell."""
+    held = HELD[cell]
+    cfg, params, cache = cell_programs.shapes(cell, one_chip)
+    falls = dict(A.reference_falls)
+    compiled = cell_programs.traced(cell, which, operands, one_chip).lower().compile()
+    assert A.reference_falls == falls, "a kernel fell to its reference in this compile"
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    for name, rule in held["kernels"].items():
+        assert rule(which, name in text), (name, which)
+    if "grouped" in held:
+        assert held["grouped"](which, grouped_kernels_in(text))
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    print(f"{cell_programs.row_id(cell, which, operands)}: {total / GiB:.2f} GiB, of it temporaries "
+          f"{mem.temp_size_in_bytes / GiB:.3f} GiB, arguments {mem.argument_size_in_bytes / GiB:.2f} GiB "
+          f"(weights {nbytes(params) / GiB:.2f}, caches {nbytes(cache) / GiB:.2f})")
+    if "total" in held:
+        assert total < held["total"].get(which, held["total"][""]) * GiB
+    temps = held.get("temps", {})
+    if which in temps or "" in temps:
+        assert mem.temp_size_in_bytes < temps.get(which, temps.get("")) * GiB
+    if which in held.get("pool_above_temps", ()):  # the pool is larger than all the temporaries together
+        assert temps[which] * GiB < nbytes(cache["v"]["state"])
+    if "alias" in held:
+        assert mem.alias_size_in_bytes > held["alias"](which, cache)
+    if which in held.get("in_place", ()):
+        assert cache_relayouts(text, cache["k"]["q"].shape) == []
+    if "also" in held:
+        held["also"](cell, which, operands, cfg, params, cache, text, mem)
